@@ -21,12 +21,11 @@ def _detect() -> str:
         assert name in SUPPORTED, \
             f"DS_ACCELERATOR={name!r} not in {SUPPORTED}"
         return name
-    try:
-        import jax
-        if any(d.platform == "tpu" for d in jax.local_devices()):
-            return "tpu"
-    except Exception:
-        pass
+    # a backend that fails to start raises here: a chip that cannot be
+    # reached is an error to see, not a reason to report "cpu"
+    import jax
+    if any(d.platform == "tpu" for d in jax.local_devices()):
+        return "tpu"
     return "cpu"
 
 

@@ -29,8 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from deepspeed_tpu.parallel import mesh as mesh_lib
-
 from deepspeed_tpu.comm.compression import core, qwz
 
 
@@ -42,7 +40,7 @@ def fast_regather(secondary: jax.Array, dim: int, fast_axis: str,
     chunk back to back; each gathered member must slot in at position
     (slow_stripe, member) of the full dim.
     """
-    w_fast = mesh_lib.manual_axis_size(fast_axis)
+    w_fast = lax.axis_size(fast_axis)
     parts = lax.all_gather(secondary.astype(out_dtype), fast_axis,
                            axis=0, tiled=False)      # [Wf, ..., Ws*g, ...]
     shape = parts.shape
@@ -72,7 +70,7 @@ def slow_gather_secondary(x: jax.Array, dim: int, axes: Sequence[str],
     from deepspeed_tpu.comm.comm import compressed_op_span
 
     slow = axes[0]
-    w_slow = mesh_lib.manual_axis_size(slow)
+    w_slow = lax.axis_size(slow)
     m = x.size
     if quantize_bits is not None:
         return qwz.quantized_all_gather(
@@ -110,7 +108,7 @@ def hierarchical_gather(x: jax.Array, dim: int, axes: Sequence[str],
     from deepspeed_tpu.comm.comm import compressed_op_span
 
     slow, fast = axes
-    w_slow = mesh_lib.manual_axis_size(slow)
+    w_slow = lax.axis_size(slow)
 
     # dim now Ws*g: the fast-axis shard of the full dim
     secondary = slow_gather_secondary(x, dim, axes, quantize_bits=quantize_bits,
@@ -118,7 +116,7 @@ def hierarchical_gather(x: jax.Array, dim: int, axes: Sequence[str],
                                       secondary_dtype=secondary_dtype)
 
     def _fast(sec):
-        w_fast = mesh_lib.manual_axis_size(fast)
+        w_fast = lax.axis_size(fast)
         with compressed_op_span(
                 "hpz_fast_all_gather",
                 logical_bytes=qwz.logical_bytes(

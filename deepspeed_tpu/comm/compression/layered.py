@@ -43,11 +43,6 @@ from jax import lax
 from deepspeed_tpu.comm.compression import hpz as hpz_mod
 from deepspeed_tpu.comm.compression import qgz, qwz
 
-try:  # jax >= 0.4.x keeps this private; absence just disables staging
-    from jax._src.sharding_impls import TransferToMemoryKind as _Transfer
-except ImportError:  # pragma: no cover - older/newer jax layouts
-    _Transfer = None
-
 _scope = threading.local()
 
 
@@ -61,16 +56,11 @@ def _stage_to_device(x):
     backward rule is untouched (cotangents stay in device memory with the
     gradient accumulator).  Whole-tree host→device transfers inside the
     scan body are exactly what ``tools/check_overlap_structure.py`` lints
-    against; this per-slice form is the sanctioned site.  On backends
-    without memory-kind support (CPU tests) the transfer is an identity,
-    keeping layered-vs-bulk parity bitwise.
+    against; this per-slice form is the sanctioned site.  On the CPU
+    backend a put to device memory is already the identity, which keeps
+    layered-vs-bulk parity bitwise.
     """
-    if _Transfer is None:
-        return x
-    try:
-        return jax.device_put(x, _Transfer("device"))
-    except Exception:
-        return x
+    return jax.device_put(x, jax.memory.Space.Device)
 
 
 @contextlib.contextmanager

@@ -28,8 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from deepspeed_tpu.parallel import mesh as mesh_lib
-
 from deepspeed_tpu.comm.compression import core
 
 
@@ -40,7 +38,7 @@ def quantized_reduce_scatter_1d(y: jax.Array, axis: str, pos: int,
     everyone's quantized slice ``j``, dequantizes, and sums in fp32.
     Returns ``y`` with dim ``pos`` reduced to size 1.
     """
-    w = mesh_lib.manual_axis_size(axis)
+    w = lax.axis_size(axis)
     z = jnp.moveaxis(y, pos, 0)                       # [w, ...rest]
     rest_shape = z.shape[1:]
     m = math.prod(rest_shape) if rest_shape else 1
@@ -69,7 +67,7 @@ def hierarchical_reduce_scatter(g: jax.Array, dim: int, axes: Sequence[str],
     from deepspeed_tpu.comm.comm import compressed_op_span
 
     axes = (axes,) if isinstance(axes, str) else tuple(axes)
-    sizes = [mesh_lib.manual_axis_size(a) for a in axes]
+    sizes = [lax.axis_size(a) for a in axes]
     world = 1
     for s in sizes:
         world *= s

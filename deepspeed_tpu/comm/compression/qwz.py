@@ -20,15 +20,13 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from deepspeed_tpu.parallel import mesh as mesh_lib
-
 from deepspeed_tpu.comm.compression import core
 
 
 def _axes_world(axes: Sequence[str]) -> int:
     w = 1
     for a in axes:
-        w *= mesh_lib.manual_axis_size(a)
+        w *= lax.axis_size(a)
     return w
 
 

@@ -37,7 +37,6 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
 from deepspeed_tpu.comm.compression import layered as zero_layered
@@ -677,17 +676,6 @@ def gpt_forward(cfg: GPTConfig, params: Dict, input_ids: Array,
     return (logits, aux_total) if with_aux else logits
 
 
-def _pallas_ce_wanted(N: int, E: int, V: int) -> bool:
-    """Route the loss through the fused Pallas CE kernel when enabled
-    (``DST_PALLAS_CE``) and the shape/mesh is supported; any failure here
-    means the XLA chunked path below — never an error."""
-    try:
-        from deepspeed_tpu.ops.pallas import cross_entropy as _pce
-        return _pce.pallas_ce_enabled() and _pce.ce_supported(N, E, V)
-    except Exception:
-        return False
-
-
 def chunked_cross_entropy(x: Array, head: Array, labels: Array,
                           vocab_size: int, n_chunks: int = 0,
                           head_b: Optional[Array] = None) -> Array:
@@ -706,8 +694,8 @@ def chunked_cross_entropy(x: Array, head: Array, labels: Array,
     B, S, E = x.shape
     V = head.shape[0]
     N = B * S
-    if _pallas_ce_wanted(N, E, V):
-        from deepspeed_tpu.ops.pallas import cross_entropy as _pce
+    from deepspeed_tpu.ops.pallas import cross_entropy as _pce
+    if _pce.pallas_ce_enabled() and _pce.ce_supported(N, E, V):
         return _pce.fused_cross_entropy(x.reshape(N, E), head,
                                         labels.reshape(N), vocab_size,
                                         head_b=head_b)
@@ -800,54 +788,17 @@ def gpt_loss(cfg: GPTConfig, params: Dict, input_ids: Array, labels: Array,
 # softmax_context kernel + inference_context.h workspace, SURVEY.md §2.3)
 # --------------------------------------------------------------------------- #
 def init_kv_cache(cfg: GPTConfig, batch: int, max_len: int) -> Dict:
-    """Per-layer K/V cache, stacked [L, B, max_len, Hkv, D] (scan-friendly;
-    GQA stores only the kv heads).  Sharded: batch over DP, heads over
-    tensor."""
-    L, H, D = cfg.n_layer, cfg.kv_heads, cfg.head_dim
-    shape = (L, batch, max_len, H, D)
+    """Per-layer K/V cache, stacked [L, B, max_len, Hkv*D] (scan-friendly;
+    GQA stores only the kv heads).  Heads are folded into the lane
+    dimension — the layout the decode kernel can DMA at D=64
+    (``ops/pallas/decode_attention.py``).  Sharded: batch over DP, heads
+    over tensor."""
+    shape = (cfg.n_layer, batch, max_len, cfg.kv_heads * cfg.head_dim)
     k = jnp.zeros(shape, cfg.dtype)
     v = jnp.zeros(shape, cfg.dtype)
-    spec = (None, mesh_lib.BATCH_AXES, None, "tensor", None)
+    spec = (None, mesh_lib.BATCH_AXES, None, "tensor")
     return {"k": _constrain(k, *spec), "v": _constrain(v, *spec),
             "pos": jnp.zeros((), jnp.int32)}
-
-
-def _cached_attention(q, ck, cv, pos, bias=None):
-    """q: [B, S_q, H, D] attends causally to cache positions <= its own
-    global position (query i sits at ``pos + i``).  Static shapes:
-    full-cache attention with masking — the standard TPU decode pattern.
-
-    GQA-aware: the cache may carry only ``Hkv`` heads; attention is
-    computed GROUPED against the un-expanded cache (no [B, T, H, D]
-    materialization — the bandwidth saving is the point of GQA).
-    ``bias``: additive [1, H, S_q, T] logit bias (ALiBi)."""
-    B, Sq, H, D = q.shape
-    T, Hkv = ck.shape[1], ck.shape[2]
-    from deepspeed_tpu.ops.pallas.decode_attention import (
-        decode_attention, pallas_decode_enabled)
-    if bias is None and Hkv == H and pallas_decode_enabled():
-        # DEFAULT-ON where supported (graduated from the r5 opt-in): the
-        # Pallas decode kernel DMAs only the pos+Sq valid cache blocks and
-        # fuses score/softmax/PV — the einsum below is ~45% of per-token
-        # decode time.  ``DST_PALLAS_DECODE=0`` opts out; on CPU the lax
-        # fallback below stays the default (the interpreter is far slower
-        # than the einsum).  See README § Pallas decode kernel status.
-        return decode_attention(q, ck, cv, pos)
-    G = H // Hkv
-    scale = 1.0 / np.sqrt(D)
-    qg = q.reshape(B, Sq, Hkv, G, D)
-    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg.astype(jnp.float32),
-                   ck.astype(jnp.float32)) * scale       # [B, Hkv, G, Sq, T]
-    if bias is not None:
-        s = s + bias.astype(jnp.float32).reshape(
-            bias.shape[0], Hkv, G, *bias.shape[2:])
-    kpos = jax.lax.broadcasted_iota(jnp.int32, (Sq, T), 1)
-    qpos = pos + jax.lax.broadcasted_iota(jnp.int32, (Sq, T), 0)
-    mask = kpos <= qpos
-    s = jnp.where(mask[None, None, None], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(q.dtype), cv)
-    return out.reshape(B, Sq, H, D)
 
 
 def gpt_apply_with_cache(cfg: GPTConfig, params: Dict, input_ids: Array,
@@ -857,6 +808,7 @@ def gpt_apply_with_cache(cfg: GPTConfig, params: Dict, input_ids: Array,
     (S_new = prompt length) and decode (S_new = 1) — one compiled program
     per S_new."""
     assert cfg.scan_layers, "KV-cache path requires scan_layers"
+    from deepspeed_tpu.ops.pallas.decode_attention import decode_attention
     B, S = input_ids.shape
     H, D, E = cfg.n_head, cfg.head_dim, cfg.n_embd
     dt = cfg.dtype
@@ -880,7 +832,7 @@ def gpt_apply_with_cache(cfg: GPTConfig, params: Dict, input_ids: Array,
         attn_bias = None
 
     def layer(carry, p):
-        # the FULL stacked [L, B, T, Hkv, D] cache rides the scan carry and
+        # the FULL stacked [L, B, T, Hkv*D] cache rides the scan carry and
         # is updated in place per layer — stacked scan outputs (`ys`) would
         # copy the whole cache every decode step (measured: ~40% of decode
         # time went to those copies before this layout)
@@ -898,12 +850,14 @@ def gpt_apply_with_cache(cfg: GPTConfig, params: Dict, input_ids: Array,
         # expansion to n_head happens at attention time
         zero = jnp.zeros((), jnp.int32)
         ck_full = jax.lax.dynamic_update_slice(
-            ck_full, k.astype(ck_full.dtype)[None], (li, zero, pos, zero, zero))
+            ck_full, k.astype(ck_full.dtype).reshape(1, B, S, -1),
+            (li, zero, pos, zero))
         cv_full = jax.lax.dynamic_update_slice(
-            cv_full, v.astype(cv_full.dtype)[None], (li, zero, pos, zero, zero))
+            cv_full, v.astype(cv_full.dtype).reshape(1, B, S, -1),
+            (li, zero, pos, zero))
         ck = jax.lax.dynamic_index_in_dim(ck_full, li, 0, keepdims=False)
         cv = jax.lax.dynamic_index_in_dim(cv_full, li, 0, keepdims=False)
-        o = _cached_attention(q, ck, cv, pos, bias=attn_bias).reshape(B, S, E)
+        o = decode_attention(q, ck, cv, pos, bias=attn_bias).reshape(B, S, E)
         o = o @ _wget(p, "out_w", dt)
         if cfg.use_bias:
             o = o + p["out_b"].astype(dt)
@@ -946,7 +900,13 @@ def gpt_generate(cfg: GPTConfig, params: Dict, input_ids: Array,
         f"prompt ({S}) + max_new_tokens ({max_new_tokens}) exceeds "
         f"n_positions ({cfg.n_positions}); the KV cache cannot grow past it")
     max_len = max_len or (S + max_new_tokens)
-    cache = init_kv_cache(cfg, B, max_len)
+    # The barrier keeps the zeros: the TPU compiler otherwise turns a cache
+    # that is born inside this program into an uninitialised AllocateBuffer
+    # (it takes the layer loop's partial dynamic-update-slice for a full
+    # overwrite), and attention then multiplies the never-written rows'
+    # garbage by its zero probabilities: 0 * NaN.  Seen on the chip as
+    # token 0 for every position (PERF.md, PR 21).
+    cache = jax.lax.optimization_barrier(init_kv_cache(cfg, B, max_len))
     logits, cache = gpt_apply_with_cache(cfg, params, input_ids, cache)
     if prompt_len is None:
         last = logits[:, -1]
@@ -998,7 +958,7 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
     ``input_ids`` [B, S] — S = 1 for decode, a chunk for chunked prefill;
     ``positions`` [B] — per-row global position of the first token (tokens
     already resident in the row's cache); ``k_pages``/``v_pages``
-    [L, NB, BS, Hkv, D] — the global arena (block 0 is the trash block);
+    [L, NB, BS, Hkv*D] — the global arena (block 0 is the trash block);
     ``block_tables`` [B, MB] — logical→physical block map per row;
     ``write_blocks``/``write_offsets`` [B, S] — physical (block, offset)
     each new token's K/V lands in (invalid/padded tokens point at the trash
@@ -1045,8 +1005,10 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
         # scatter the new K/V into the arena through the write map; rows
         # that must not write (padding, inactive slots) carry trash-block
         # coordinates, so the scatter itself needs no predication
-        kp = kp.at[li, write_blocks, write_offsets].set(k.astype(kp.dtype))
-        vp = vp.at[li, write_blocks, write_offsets].set(v.astype(vp.dtype))
+        kp = kp.at[li, write_blocks, write_offsets].set(
+            k.astype(kp.dtype).reshape(B, S, -1))
+        vp = vp.at[li, write_blocks, write_offsets].set(
+            v.astype(vp.dtype).reshape(B, S, -1))
         kl = jax.lax.dynamic_index_in_dim(kp, li, 0, keepdims=False)
         vl = jax.lax.dynamic_index_in_dim(vp, li, 0, keepdims=False)
         o = paged_attention(q, kl, vl, block_tables, positions,

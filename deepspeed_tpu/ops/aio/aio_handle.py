@@ -4,14 +4,17 @@ Reference surface: ``deepspeed/ops/op_builder/async_io.py`` (builder) +
 ``csrc/aio/py_lib/deepspeed_py_aio_handle.cpp`` (``aio_handle`` with
 ``pread/pwrite/async_pread/async_pwrite/wait``).  The native engine is
 ``csrc/aio/dst_aio.cpp`` in this repo, compiled on first use with g++
-into a cached shared object and driven through ctypes (no pybind11 in
-the toolchain).  Buffers are numpy arrays (pinned-host staging is the
+into ``build/`` (not committed; rebuilt when missing or when the hash of
+the source recorded beside it differs) and driven through ctypes (no
+pybind11 in the toolchain).  Buffers are numpy arrays (pinned-host staging is the
 caller's concern — see runtime/swap_tensor/).
 """
 
 import ctypes
+import hashlib
 import os
 import subprocess
+import tempfile
 import threading
 from typing import Optional
 
@@ -22,6 +25,7 @@ _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 _SRC = os.path.join(_REPO_ROOT, "csrc", "aio", "dst_aio.cpp")
 _BUILD_DIR = os.path.join(_REPO_ROOT, "build")
 _SO = os.path.join(_BUILD_DIR, "libdst_aio.so")
+_SO_HASH = _SO + ".sha256"       # hash of the source the .so was built from
 
 _lib = None
 _lib_lock = threading.Lock()
@@ -44,18 +48,40 @@ class AsyncIOBuilder:
         return _SO
 
 
+def _build_if_stale():
+    """Compile the engine unless ``build/`` holds one built from this very
+    source.  Decided by content hash, not mtime: a copy of the tree (an
+    archive, a checkout) does not preserve mtimes.  Each build lands under
+    its own temporary name and is renamed into place, so concurrent first
+    uses (test workers) never load a half-written file."""
+    with open(_SRC, "rb") as f:
+        src_hash = hashlib.sha256(f.read()).hexdigest()
+    if os.path.exists(_SO) and os.path.exists(_SO_HASH):
+        with open(_SO_HASH) as f:
+            if f.read().strip() == src_hash:
+                return
+    os.makedirs(_BUILD_DIR, exist_ok=True)
+    fd, tmp = tempfile.mkstemp(dir=_BUILD_DIR, suffix=".so.tmp")
+    os.close(fd)
+    try:
+        subprocess.run(["g++", "-O2", "-shared", "-fPIC", "-std=c++17",
+                        "-pthread", _SRC, "-o", tmp],
+                       check=True, capture_output=True)
+        os.replace(tmp, _SO)
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+    with open(tmp + ".hash", "w") as f:
+        f.write(src_hash)
+    os.replace(tmp + ".hash", _SO_HASH)
+
+
 def _load_lib():
     global _lib
     with _lib_lock:
         if _lib is not None:
             return _lib
-        if (not os.path.exists(_SO)
-                or os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
-            os.makedirs(_BUILD_DIR, exist_ok=True)
-            cmd = ["g++", "-O2", "-shared", "-fPIC", "-std=c++17",
-                   "-pthread", _SRC, "-o", _SO + ".tmp"]
-            subprocess.run(cmd, check=True, capture_output=True)
-            os.replace(_SO + ".tmp", _SO)
+        _build_if_stale()
         lib = ctypes.CDLL(_SO)
         lib.dst_aio_create.restype = ctypes.c_void_p
         lib.dst_aio_create.argtypes = [ctypes.c_int, ctypes.c_long, ctypes.c_int]

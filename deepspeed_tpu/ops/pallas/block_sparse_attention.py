@@ -30,7 +30,7 @@ Design notes:
 - ``causal=True`` additionally applies the elementwise triangular mask on
   diagonal blocks (block-level causality should already be in the layout;
   the flag makes within-block masking exact).
-- ``interpret=True`` off-TPU runs the same kernels on CPU for CI parity
+- On the ``cpu`` platform the same kernels run interpreted, for CI parity
   against the masked-dense jnp reference, the analogue of the reference's
   ``tests/unit/ops/sparse_attention/test_sparse_attention.py``.
 """
@@ -44,19 +44,12 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deepspeed_tpu.ops import pallas as _pallas
+
 NEG_INF = -1e30
 
-# jax < 0.5 spells the Pallas compiler-params type ``TPUCompilerParams``.
-_SEMANTICS4 = (getattr(pltpu, "CompilerParams", None)
-               or pltpu.TPUCompilerParams)(
+_SEMANTICS4 = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
-
-
-def _interpret() -> bool:
-    try:
-        return jax.devices()[0].platform != "tpu"
-    except Exception:
-        return True
 
 
 # --------------------------------------------------------------------------- #
@@ -184,7 +177,7 @@ def _fwd(q, k, v, row_lut, row_cnt, *, scale, causal, bs):
             jax.ShapeDtypeStruct((B, H, S, 1), jnp.float32),
         ],
         compiler_params=_SEMANTICS4,
-        interpret=_interpret(),
+        interpret=_pallas.interpret(),
     )(row_cnt, row_lut, q, k, v)
     return o, lse
 
@@ -291,7 +284,7 @@ def _bwd_impl(q, k, v, o, lse, do, luts, *, scale, causal, bs):
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
         compiler_params=_SEMANTICS4,
-        interpret=_interpret(),
+        interpret=_pallas.interpret(),
     )(row_cnt, row_lut, q, k, v, do, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -307,7 +300,7 @@ def _bwd_impl(q, k, v, o, lse, do, luts, *, scale, causal, bs):
         out_shape=[jax.ShapeDtypeStruct((B, H, S, D), k.dtype),
                    jax.ShapeDtypeStruct((B, H, S, D), v.dtype)],
         compiler_params=_SEMANTICS4,
-        interpret=_interpret(),
+        interpret=_pallas.interpret(),
     )(col_cnt, col_lut, q, k, v, do, lse, delta)
     return dq, dk, dv
 

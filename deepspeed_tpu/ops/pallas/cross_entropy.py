@@ -38,19 +38,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 spells these ``TPUCompilerParams`` / ``TPUMemorySpace``.
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
+from deepspeed_tpu.ops import pallas as _pallas
 
 _ROW_BLOCK = 128          # fp32 sublane-multiple; rows are padded up to it
 _VMEM_BLOCK_BYTES = 4 << 20   # budget for one [bv, E] head block in VMEM
-
-
-def _interpret() -> bool:
-    try:
-        return jax.devices()[0].platform != "tpu"
-    except Exception:
-        return True
 
 
 def pallas_ce_enabled() -> bool:
@@ -60,7 +51,7 @@ def pallas_ce_enabled() -> bool:
         return False
     if flag in ("1", "on", "true"):
         return True
-    return not _interpret()
+    return _pallas.platform() == "tpu"
 
 
 def _vocab_block(V: int, E: int) -> Optional[int]:
@@ -156,9 +147,10 @@ def _fwd_rows(x2, head, head_b, lab2, vocab_size, bn, bv):
         out_shape=[jax.ShapeDtypeStruct((Np, 1), jnp.float32),
                    jax.ShapeDtypeStruct((Np, 1), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((bn, 1), jnp.float32)] * 3,
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=_pallas.interpret(),
+        name="ce_fwd",
     )(*args)
     return nll, lse
 
@@ -251,9 +243,10 @@ def _bwd_rows(x2, head, head_b, lab2, lse, gr, vocab_size, bn, bv):
                            if has_bias else []),
         out_specs=pl.BlockSpec((bn, E), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Np, E), jnp.float32),
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=_pallas.interpret(),
+        name="ce_bwd_dx",
     )(*args, *bias_args)
 
     # transposed grid: vocab outer, rows accumulated
@@ -275,9 +268,10 @@ def _bwd_rows(x2, head, head_b, lab2, lse, gr, vocab_size, bn, bv):
                             if has_bias else []),
         out_specs=out_specs,
         out_shape=out_shape,
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=_pallas.interpret(),
+        name="ce_bwd_dh",
     )(*args, *bias_args)
     if has_bias:
         dh, db = dh

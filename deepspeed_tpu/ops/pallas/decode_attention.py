@@ -2,27 +2,37 @@
 
 The reference's decode hot loop is the fused ``softmax_context`` CUDA kernel
 (``csrc/transformer/inference/csrc/pt_binding.cpp:1717-1781``) reading a
-KV-cache workspace (``inference_context.h``).  Round 1 shipped a plain-jnp
-full-cache attention that reads all ``max_len`` positions every step; this
-kernel reads ONLY the ``pos + S_q`` valid positions:
+KV-cache workspace (``inference_context.h``).  The plain-jnp path attends
+over all ``max_len`` cache positions every step; these kernels read ONLY
+the ``pos + S_q`` valid positions:
 
 * ``pos`` arrives via scalar prefetch; the kernel loop runs a STATIC trip
   count (``T/bk``, known at compile time) and predicates each iteration's
   whole copy+compute block on ``j < ceil((pos+S_q)/bk)`` — invalid cache
-  blocks are neither DMA'd nor computed (decode is HBM-bound; at
-  pos ≪ max_len this is the whole win).  The earlier revision bounded the
-  ``fori_loop`` itself by the data-dependent count, which wedged a v5e on
-  first hardware contact; the static bound removes that mechanism, and
-  ``start()``/``wait()`` are paired inside the same predicated branch so
-  the DMA semaphores stay balanced on every control path.
+  blocks are neither DMA'd nor computed.  ``start()``/``wait()`` are paired
+  inside the same predicated branch so the DMA semaphores stay balanced on
+  every control path.
 * K/V stay in HBM (``MemorySpace.ANY``); each valid block is staged into a
   VMEM scratch buffer with an explicit ``make_async_copy`` keyed by the
   dynamic block index.
 * Online softmax in fp32 registers, exactly like the training flash kernel.
 
 Layouts: q ``[B, S_q, H, D]`` (S_q = 1 for decode, small for chunked
-prefill), cache ``[B, T, H, D]``.  Tested against the jnp reference via the
-interpreter on CPU and on hardware by ``tools/decode_bench.py``.
+prefill); the cache keeps the heads FOLDED INTO THE LANE DIMENSION —
+``[B, T, H*D]``, pages ``[NB, BS, H*D]``.  Mosaic requires the trailing two
+dimensions of a DMA slice to be aligned to the (sublane, 128) tile, so a
+``[bk, H, D]`` slice of a ``[B, T, H, D]`` cache is refused at every GPT-2
+head shape (H=12/25 against the sublane tile, D=64 against the 128 lanes);
+``[bk, H*D]`` is aligned whenever ``H*D % 128 == 0``, and the folded cache
+carries no lane padding at D=64.  Inside the kernel a head is a static
+128-aligned lane slice; two D=64 heads share one slice and are separated by
+zeroing the other head's query lanes (the MXU contracts 128 wide anyway).
+
+What has been shown: parity against the jnp reference through the Pallas
+interpreter (``tests/unit/ops/test_decode_attention.py``,
+``test_paged_attention.py``), ahead-of-time compilation for v5e at the
+shapes :func:`kernel_shape_ok` admits (``tests/unit/ops/test_chip_compile.py``),
+and a run on the chip through ``chip_smoke.py`` (CHANGES.md PR 21).
 """
 
 import functools
@@ -36,154 +46,227 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
+from deepspeed_tpu.ops import pallas as _pallas
+
 NEG_INF = -1e30
-
-# jax < 0.5 spells the Pallas memory-space enum ``TPUMemorySpace``.
-_MEMSPACE = getattr(pltpu, "MemorySpace", None) or pltpu.TPUMemorySpace
+_LANES = 128
 
 
-def _interpret() -> bool:
-    try:
-        return jax.devices()[0].platform != "tpu"
-    except Exception:
-        return True
+def _kernel_wanted(env_name: str) -> bool:
+    """``DST_PALLAS_DECODE`` / ``DST_PALLAS_PAGED``: ``0`` opts out, ``1``
+    forces the kernel (through the interpreter on CPU, for parity tests);
+    unset, the kernel runs on TPU and the einsum on CPU, where the
+    interpreter is orders of magnitude slower than the einsum it would
+    replace.  Necessary, not sufficient: :func:`kernel_shape_ok` decides."""
+    env = os.environ.get(env_name)
+    if env in ("0", "1"):
+        return env == "1"
+    return _pallas.platform() == "tpu"
 
 
-def pallas_decode_enabled() -> bool:
-    """Default-on policy for the fused decode kernel (README § Pallas decode
-    kernel status): ON where supported (TPU hardware), with
-    ``DST_PALLAS_DECODE=0`` as the opt-out; ``DST_PALLAS_DECODE=1`` forces
-    it on everywhere (including the CPU interpreter, for parity tests).
-    On CPU the default stays the lax/jnp fallback — the interpreter is
-    orders of magnitude slower than the fused einsum it would replace."""
-    env = os.environ.get("DST_PALLAS_DECODE")
-    if env == "0":
-        return False
-    if env == "1":
-        return True
-    return not _interpret()
+def kernel_shape_ok(H: int, Hkv: int, D: int, block: int, dtype) -> bool:
+    """THE shape gate of both kernels — what the v5e compiler accepts
+    (``tests/unit/ops/test_chip_compile.py`` pins both sides of it):
+
+    * MHA only (the folded cache is indexed by query head);
+    * a head is a whole number of 128-lane tiles, or several heads fill one
+      exactly (D=64 needs an even H: gpt2 12 / medium 16 / large 20 pass,
+      gpt2-xl's 25 heads do not, and take the einsum path);
+    * the DMA'd block (``bk`` cache rows / one page) is a multiple of the
+      cache dtype's sublane tile (8 fp32, 16 bf16, 32 int8).
+    """
+    sublane = 8 * 4 // np.dtype(dtype).itemsize
+    lanes_ok = D % _LANES == 0 or (_LANES % D == 0 and (H * D) % _LANES == 0)
+    return Hkv == H and lanes_ok and block % sublane == 0
 
 
-def _paged_kernel_enabled() -> bool:
-    """Same policy for the paged (block-table) kernel; independent opt-out
-    so the serving path can be steered separately (DST_PALLAS_PAGED)."""
-    env = os.environ.get("DST_PALLAS_PAGED")
-    if env == "0":
-        return False
-    if env == "1":
-        return True
-    return not _interpret()
+def _lane_slices(H, D):
+    """(slice width W, heads per slice, slice count): a head is a whole
+    number of 128-lane tiles, or ``128 // D`` heads share one."""
+    W = max(D, _LANES)
+    return W, W // D, H * D // W
+
+
+def _attend_block(qm, k_buf, v_buf, valid, carry, *, scale, H, D):
+    """One online-softmax update of every head against the staged
+    ``[bk, H*D]`` K/V block.  ``qm[h]`` is head ``h``'s query in its lane
+    slice (other heads' lanes zeroed), ``valid`` the ``[Sq, bk]`` causal
+    mask; carry = (m[h], l[h] per head ``[Sq, 1]``; acc[g] per lane slice
+    ``[Sq, W]``)."""
+    W, hpg, n_slices = _lane_slices(H, D)
+    m, l, acc = (list(c) for c in carry)
+    Sq = valid.shape[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (Sq, W), 1)
+    for g in range(n_slices):
+        k = k_buf[:, g * W:(g + 1) * W]           # [bk, W], aligned slice
+        v = v_buf[:, g * W:(g + 1) * W]
+        alpha_g = pv_g = None
+        for i in range(hpg):
+            h = g * hpg + i
+            # bf16 MXU operands, fp32 accumulation; contract the W lanes
+            s = jax.lax.dot_general(qm[h], k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32) * scale
+            s = jnp.where(valid, s, NEG_INF)      # [Sq, bk]
+            m_new = jnp.maximum(m[h], jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m[h] - m_new)
+            l[h] = l[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            m[h] = m_new
+            pv = jax.lax.dot_general(p.astype(v.dtype), v,
+                                     (((1,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            if i == 0:
+                alpha_g, pv_g = jnp.broadcast_to(alpha, (Sq, W)), pv
+            else:                                 # head i owns lanes [iD, (i+1)D)
+                own = lane >= i * D
+                alpha_g = jnp.where(own, alpha, alpha_g)
+                pv_g = jnp.where(own, pv, pv_g)
+        acc[g] = acc[g] * alpha_g + pv_g
+    return tuple(m), tuple(l), tuple(acc)
+
+
+def _split_heads(q, H, D):
+    """``[Sq, H*D]`` → per-head ``[Sq, W]`` lane slices with the slice's
+    other heads zeroed (loop-invariant, built once per grid step)."""
+    W, hpg, _ = _lane_slices(H, D)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (q.shape[0], W), 1)
+    out = []
+    for h in range(H):
+        g, i = divmod(h, hpg)
+        qs = q[:, g * W:(g + 1) * W]
+        if hpg > 1:
+            qs = jnp.where((lane >= i * D) & (lane < (i + 1) * D), qs,
+                           jnp.zeros_like(qs))
+        out.append(qs)
+    return out
+
+
+def _init_carry(Sq, H, D):
+    W, _, n_slices = _lane_slices(H, D)
+    return (tuple(jnp.full((Sq, 1), NEG_INF, jnp.float32) for _ in range(H)),
+            tuple(jnp.zeros((Sq, 1), jnp.float32) for _ in range(H)),
+            tuple(jnp.zeros((Sq, W), jnp.float32) for _ in range(n_slices)))
+
+
+def _write_out(o_ref, carry, H, D):
+    W, hpg, n_slices = _lane_slices(H, D)
+    _, l, acc = carry
+    Sq = acc[0].shape[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (Sq, W), 1)
+    for g in range(n_slices):
+        l_g = jnp.broadcast_to(l[g * hpg], (Sq, W))
+        for i in range(1, hpg):
+            l_g = jnp.where(lane >= i * D, l[g * hpg + i], l_g)
+        out = acc[g] / jnp.maximum(l_g, 1e-30)
+        o_ref[0, :, g * W:(g + 1) * W] = out.astype(o_ref.dtype)
 
 
 def _decode_kernel(pos_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf,
-                   sem_k, sem_v, *, scale, bk, Sq, H, nk_max):
-    """Grid (B,): ONE [bk, H, D] DMA per cache block serves every head
-    (batched dot_general over the head dim) — the per-(b, h) grid of the
-    round-4 kernel both re-streamed the cache H times and sliced the
-    tiled H dim to 1, which Mosaic rejects on hardware.
+                   sem_k, sem_v, *, scale, bk, Sq, H, D, nk_max):
+    """Grid (B,): ONE ``[bk, H*D]`` DMA per cache block serves every head.
 
-    The loop bound is STATIC (``nk_max = T // bk``): the round-5 kernel
-    bounded the fori_loop by the data-dependent live-block count, and that
-    dynamically-bounded DMA sequence wedged a v5e on first hardware
-    contact.  Here every iteration instead predicates its copy+compute
-    block on ``j < nk`` via ``lax.cond`` — dead blocks cost no HBM traffic
-    and no MXU work, and both DMAs start AND wait inside the same branch,
-    so semaphores stay balanced whichever way the predicate resolves."""
+    The loop bound is STATIC (``nk_max = T // bk``); every iteration
+    predicates its copy+compute block on ``j < nk`` via ``lax.cond`` — dead
+    blocks cost no HBM traffic and no MXU work, and both DMAs start AND
+    wait inside the same branch, so semaphores stay balanced whichever way
+    the predicate resolves."""
     b = pl.program_id(0)
     pos = pos_ref[0]
-    q = q_ref[0]                                  # [Sq, H, D], storage dtype
+    qm = _split_heads(q_ref[0], H, D)
     nk = (pos + Sq + bk - 1) // bk                # live (DMA'd) block count
 
     def live(j, carry):
-        m, l, acc = carry                         # [H,Sq,1] [H,Sq,1] [H,Sq,D]
-        cp_k = pltpu.make_async_copy(k_hbm.at[b, pl.ds(j * bk, bk), :, :],
+        cp_k = pltpu.make_async_copy(k_hbm.at[b, pl.ds(j * bk, bk), :],
                                      k_buf, sem_k)
-        cp_v = pltpu.make_async_copy(v_hbm.at[b, pl.ds(j * bk, bk), :, :],
+        cp_v = pltpu.make_async_copy(v_hbm.at[b, pl.ds(j * bk, bk), :],
                                      v_buf, sem_v)
         cp_k.start()
         cp_v.start()
         cp_k.wait()
         cp_v.wait()
-        k = k_buf[...]                            # [bk, H, D]
-        v = v_buf[...]
-        # batch over H (axis 1 of both operands), contract D: [H, Sq, bk];
-        # bf16 MXU operands with fp32 accumulation
-        s = jax.lax.dot_general(q, k, (((2,), (2,)), ((1,), (1,))),
-                                preferred_element_type=jnp.float32) * scale
         rows = jax.lax.broadcasted_iota(jnp.int32, (Sq, bk), 0)
         cols = j * bk + jax.lax.broadcasted_iota(jnp.int32, (Sq, bk), 1)
-        s = jnp.where((cols <= pos + rows)[None], s, NEG_INF)   # causal
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        # batch H (p axis 0 / v axis 1), contract bk: [H, Sq, D]
-        acc = acc * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((2,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)
-        return m_new, l, acc
+        return _attend_block(qm, k_buf, v_buf, cols <= pos + rows, carry,
+                             scale=scale, H=H, D=D)
 
     def body(j, carry):
         return jax.lax.cond(j < nk, lambda c: live(j, c), lambda c: c, carry)
 
-    D = q.shape[-1]
-    m0 = jnp.full((H, Sq, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((H, Sq, 1), jnp.float32)
-    a0 = jnp.zeros((H, Sq, D), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, nk_max, body, (m0, l0, a0))
-    out = acc / jnp.maximum(l, 1e-30)             # [H, Sq, D]
-    o_ref[0] = out.transpose(1, 0, 2).astype(o_ref.dtype)
+    carry = jax.lax.fori_loop(0, nk_max, body, _init_carry(Sq, H, D))
+    _write_out(o_ref, carry, H, D)
 
 
 def _decode_call(q, ck, cv, pos, *, bk):
-    """q [B,Sq,H,D], cache [B,T,H,D], pos scalar → out [B,Sq,H,D]."""
+    """q [B,Sq,H,D], cache [B,T,H*D], pos scalar → out [B,Sq,H,D]."""
     B, Sq, H, D = q.shape
-    scale = 1.0 / np.sqrt(D)
+    HD = H * D
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, Sq, H, D), lambda b, pos_ref: (b, 0, 0, 0)),
-            pl.BlockSpec(memory_space=_MEMSPACE.ANY),
-            pl.BlockSpec(memory_space=_MEMSPACE.ANY),
+            pl.BlockSpec((1, Sq, HD), lambda b, pos_ref: (b, 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
         ],
-        out_specs=pl.BlockSpec((1, Sq, H, D), lambda b, pos_ref: (b, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, Sq, HD), lambda b, pos_ref: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((bk, H, D), ck.dtype),
-            pltpu.VMEM((bk, H, D), cv.dtype),
+            pltpu.VMEM((bk, HD), ck.dtype),
+            pltpu.VMEM((bk, HD), cv.dtype),
             pltpu.SemaphoreType.DMA,
             pltpu.SemaphoreType.DMA,
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_decode_kernel, scale=scale, bk=bk, Sq=Sq, H=H,
-                          nk_max=ck.shape[1] // bk),
+        functools.partial(_decode_kernel, scale=1.0 / np.sqrt(D), bk=bk,
+                          Sq=Sq, H=H, D=D, nk_max=ck.shape[1] // bk),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Sq, H, D), q.dtype),
-        interpret=_interpret(),
-    )(jnp.asarray(pos, jnp.int32).reshape(1), q, ck, cv)
-    return out
+        out_shape=jax.ShapeDtypeStruct((B, Sq, HD), q.dtype),
+        interpret=_pallas.interpret(),
+        name="decode_attention",
+    )(jnp.asarray(pos, jnp.int32).reshape(1), q.reshape(B, Sq, HD), ck, cv)
+    return out.reshape(B, Sq, H, D)
 
 
-def decode_attention_reference(q, ck, cv, pos):
-    """Plain-jnp full-cache decode attention (the round-1 path; kept as the
-    parity reference and the fallback for unsupported shapes/backends)."""
+def decode_attention_reference(q, ck, cv, pos, bias=None):
+    """Plain-jnp full-cache decode attention: the parity reference, and the
+    stated path for every shape :func:`kernel_shape_ok` refuses.
+
+    q ``[B, S_q, H, D]`` attends causally to cache positions <= its own
+    global position (query i of row b sits at ``pos[b] + i``; ``pos`` is a
+    scalar or ``[B]``); cache ``[B, T, Hkv*D]``.  GQA-aware: attention is
+    computed GROUPED against the un-expanded cache.  ``bias``: additive
+    ``[1|B, H, S_q, T]`` logit bias (ALiBi).
+
+    On every path here the rows past the last query's position are masked by
+    a probability of exactly 0, not skipped: they must hold finite values
+    (the zeros of ``init_kv_cache`` / ``init_arena``, or stale K/V), because
+    0 * NaN is NaN."""
     B, Sq, H, D = q.shape
     T = ck.shape[1]
+    Hkv = ck.shape[2] // D
+    G = H // Hkv
     scale = 1.0 / np.sqrt(D)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
-                   ck.astype(jnp.float32)) * scale
-    kpos = jax.lax.broadcasted_iota(jnp.int32, (Sq, T), 1)
-    qpos = pos + jax.lax.broadcasted_iota(jnp.int32, (Sq, T), 0)
-    s = jnp.where((kpos <= qpos)[None, None], s, NEG_INF)
+    ck = ck.reshape(B, T, Hkv, D)
+    cv = cv.reshape(B, T, Hkv, D)
+    qg = q.reshape(B, Sq, Hkv, G, D)
+    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg.astype(jnp.float32),
+                   ck.astype(jnp.float32)) * scale       # [B, Hkv, G, Sq, T]
+    if bias is not None:
+        s = s + bias.astype(jnp.float32).reshape(
+            bias.shape[0], Hkv, G, *bias.shape[2:])
+    kpos = jax.lax.broadcasted_iota(jnp.int32, (Sq, T), 1)[None]
+    qpos = (jnp.broadcast_to(pos, (B,))[:, None, None]
+            + jax.lax.broadcasted_iota(jnp.int32, (Sq, T), 0)[None])
+    s = jnp.where((kpos <= qpos)[:, None, None], s, NEG_INF)   # [B, Sq, T]
     p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", p.astype(q.dtype), cv)
+    out = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(q.dtype), cv)
+    return out.reshape(B, Sq, H, D)
 
 
 # --------------------------------------------------------------------------- #
 # Paged (block-table) decode attention — the serving-engine fast path.
 #
-# The KV cache is a global arena of fixed-size blocks ([NB, BS, Hkv, D] per
+# The KV cache is a global arena of fixed-size blocks ([NB, BS, Hkv*D] per
 # layer); a sequence's logical positions map to physical blocks through its
 # block-table row.  Queries for row ``b`` sit at global positions
 # ``lengths[b] + arange(S_q)`` and attend causally to the gathered cache —
@@ -192,43 +275,28 @@ def decode_attention_reference(q, ck, cv, pos):
 # --------------------------------------------------------------------------- #
 def paged_attention_reference(q, k_pages, v_pages, block_tables, lengths,
                               bias=None):
-    """jnp paged attention (parity reference and CPU/default path).
+    """jnp paged attention: the parity reference, and the stated path for
+    every shape :func:`kernel_shape_ok` refuses — the pages gathered through
+    the table, then :func:`decode_attention_reference` with per-row
+    positions.
 
-    q ``[B, Sq, H, D]``; pages ``[NB, BS, Hkv, D]`` (block 0 is the shared
+    q ``[B, Sq, H, D]``; pages ``[NB, BS, Hkv*D]`` (block 0 is the shared
     trash block); ``block_tables`` ``[B, MB]`` int32 physical block ids in
     logical order; ``lengths`` ``[B]`` int32 — tokens already in the cache
     for each row, i.e. the global position of the row's first query.
     ``bias``: optional additive ``[B, H, Sq, T]`` logit bias (ALiBi),
-    T = MB * BS.  GQA-aware: grouped against the un-expanded Hkv pages.
+    T = MB * BS.
     """
-    B, Sq, H, D = q.shape
-    NB, BS, Hkv, _ = k_pages.shape
-    MB = block_tables.shape[1]
-    T = MB * BS
-    G = H // Hkv
-    scale = 1.0 / np.sqrt(D)
-    # gather [B, MB, BS, Hkv, D] -> [B, T, Hkv, D]: the T dim is the
+    B = q.shape[0]
+    # gather [B, MB, BS, Hkv*D] -> [B, T, Hkv*D]: the T dim is the
     # sequence's LOGICAL positions 0..T-1 (tables are logically ordered)
-    ck = k_pages[block_tables].reshape(B, T, Hkv, D)
-    cv = v_pages[block_tables].reshape(B, T, Hkv, D)
-    qg = q.reshape(B, Sq, Hkv, G, D)
-    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg.astype(jnp.float32),
-                   ck.astype(jnp.float32)) * scale      # [B, Hkv, G, Sq, T]
-    if bias is not None:
-        s = s + bias.astype(jnp.float32).reshape(
-            bias.shape[0], Hkv, G, *bias.shape[2:])
-    kpos = jax.lax.broadcasted_iota(jnp.int32, (Sq, T), 1)[None]
-    qpos = (lengths[:, None, None]
-            + jax.lax.broadcasted_iota(jnp.int32, (Sq, T), 0)[None])
-    mask = kpos <= qpos                                 # [B, Sq, T]
-    s = jnp.where(mask[:, None, None], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(q.dtype), cv)
-    return out.reshape(B, Sq, H, D)
+    ck = k_pages[block_tables].reshape(B, -1, k_pages.shape[-1])
+    cv = v_pages[block_tables].reshape(B, -1, v_pages.shape[-1])
+    return decode_attention_reference(q, ck, cv, lengths, bias=bias)
 
 
 def _paged_kernel(len_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf,
-                  sem_k, sem_v, *, scale, bs, Sq, H, MB):
+                  sem_k, sem_v, *, scale, bs, Sq, H, D, MB):
     """Grid (B,): per row, DMA ONLY the ``ceil((len+Sq)/bs)`` live physical
     blocks through the block table (scalar-prefetched, so the dynamic block
     index is known before the DMA is issued) — the same one-copy-serves-
@@ -243,11 +311,10 @@ def _paged_kernel(len_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf,
     padded tail's scores)."""
     b = pl.program_id(0)
     seq_len = len_ref[b]
-    q = q_ref[0]                                  # [Sq, H, D]
+    qm = _split_heads(q_ref[0], H, D)
     nk = (seq_len + Sq + bs - 1) // bs            # live (DMA'd) block count
 
     def live(j, carry):
-        m, l, acc = carry
         phys = tbl_ref[b * MB + j]                # logical block j -> physical
         cp_k = pltpu.make_async_copy(k_hbm.at[phys], k_buf, sem_k)
         cp_v = pltpu.make_async_copy(v_hbm.at[phys], v_buf, sem_v)
@@ -255,111 +322,100 @@ def _paged_kernel(len_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf,
         cp_v.start()
         cp_k.wait()
         cp_v.wait()
-        k = k_buf[...]                            # [bs, H, D]
-        v = v_buf[...]
-        s = jax.lax.dot_general(q, k, (((2,), (2,)), ((1,), (1,))),
-                                preferred_element_type=jnp.float32) * scale
         rows = jax.lax.broadcasted_iota(jnp.int32, (Sq, bs), 0)
         cols = j * bs + jax.lax.broadcasted_iota(jnp.int32, (Sq, bs), 1)
-        s = jnp.where((cols <= seq_len + rows)[None], s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((2,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)
-        return m_new, l, acc
+        return _attend_block(qm, k_buf, v_buf, cols <= seq_len + rows, carry,
+                             scale=scale, H=H, D=D)
 
     def body(j, carry):
         return jax.lax.cond(j < nk, lambda c: live(j, c), lambda c: c, carry)
 
-    D = q.shape[-1]
-    m0 = jnp.full((H, Sq, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((H, Sq, 1), jnp.float32)
-    a0 = jnp.zeros((H, Sq, D), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, MB, body, (m0, l0, a0))
-    out = acc / jnp.maximum(l, 1e-30)
-    o_ref[0] = out.transpose(1, 0, 2).astype(o_ref.dtype)
+    carry = jax.lax.fori_loop(0, MB, body, _init_carry(Sq, H, D))
+    _write_out(o_ref, carry, H, D)
 
 
 def _paged_call(q, k_pages, v_pages, block_tables, lengths):
     B, Sq, H, D = q.shape
-    NB, BS, Hkv, _ = k_pages.shape
+    NB, BS, HD = k_pages.shape
     MB = block_tables.shape[1]
-    scale = 1.0 / np.sqrt(D)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                    # lengths, flat block tables
         grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, Sq, H, D), lambda b, len_ref, tbl_ref: (b, 0, 0, 0)),
-            pl.BlockSpec(memory_space=_MEMSPACE.ANY),
-            pl.BlockSpec(memory_space=_MEMSPACE.ANY),
+            pl.BlockSpec((1, Sq, HD), lambda b, len_ref, tbl_ref: (b, 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
         ],
-        out_specs=pl.BlockSpec((1, Sq, H, D),
-                               lambda b, len_ref, tbl_ref: (b, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, Sq, HD),
+                               lambda b, len_ref, tbl_ref: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((BS, H, D), k_pages.dtype),
-            pltpu.VMEM((BS, H, D), v_pages.dtype),
+            pltpu.VMEM((BS, HD), k_pages.dtype),
+            pltpu.VMEM((BS, HD), v_pages.dtype),
             pltpu.SemaphoreType.DMA,
             pltpu.SemaphoreType.DMA,
         ],
     )
-    return pl.pallas_call(
-        functools.partial(_paged_kernel, scale=scale, bs=BS, Sq=Sq, H=H, MB=MB),
+    out = pl.pallas_call(
+        functools.partial(_paged_kernel, scale=1.0 / np.sqrt(D), bs=BS, Sq=Sq,
+                          H=H, D=D, MB=MB),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Sq, H, D), q.dtype),
-        interpret=_interpret(),
+        out_shape=jax.ShapeDtypeStruct((B, Sq, HD), q.dtype),
+        interpret=_pallas.interpret(),
+        name="paged_attention",
     )(jnp.asarray(lengths, jnp.int32),
       jnp.asarray(block_tables, jnp.int32).reshape(-1),
-      q, k_pages, v_pages)
+      q.reshape(B, Sq, HD), k_pages, v_pages)
+    return out.reshape(B, Sq, H, D)
+
+
+def _mesh_divisors():
+    """(batch, tensor) shard counts of the active mesh, (1, 1) without one."""
+    from deepspeed_tpu.parallel import mesh as mesh_lib
+    if not mesh_lib.has_mesh():
+        return 1, 1
+    mesh = mesh_lib.get_mesh()
+    return (int(np.prod([mesh.shape[a] for a in mesh_lib.BATCH_AXES])),
+            int(mesh.shape["tensor"]))
 
 
 def paged_attention(q, k_pages, v_pages, block_tables, lengths, bias=None):
-    """Block-table KV attention for the serving engine; dispatches to the
-    paged Pallas kernel where supported (TPU, MHA, no bias — DST_PALLAS_PAGED
-    overrides), else the jnp gather reference.  Sharded meshes fall back to
-    the reference path (the gather partitions cleanly under SPMD; the kernel
+    """Block-table KV attention for the serving engine: the paged Pallas
+    kernel where :func:`kernel_shape_ok` admits the shape (no bias, one
+    device), else the jnp gather reference.  Sharded meshes take the
+    reference path (the gather partitions cleanly under SPMD; the kernel
     does not shard the global block arena)."""
     B, Sq, H, D = q.shape
-    Hkv = k_pages.shape[2]
-    if (bias is not None or Hkv != H or D % 8 != 0
-            or not _paged_kernel_enabled()):
-        return paged_attention_reference(q, k_pages, v_pages, block_tables,
-                                         lengths, bias=bias)
-    from deepspeed_tpu.parallel import mesh as mesh_lib
-    if mesh_lib.has_mesh():
-        mesh = mesh_lib.get_mesh()
-        batch_div = int(np.prod([mesh.shape[a] for a in mesh_lib.BATCH_AXES]))
-        if batch_div > 1 or int(mesh.shape["tensor"]) > 1:
-            return paged_attention_reference(q, k_pages, v_pages, block_tables,
-                                             lengths, bias=bias)
-    return _paged_call(q, k_pages, v_pages, block_tables, lengths)
+    _, BS, HkvD = k_pages.shape
+    if (bias is None and _kernel_wanted("DST_PALLAS_PAGED")
+            and kernel_shape_ok(H, HkvD // D, D, BS, k_pages.dtype)
+            and _mesh_divisors() == (1, 1)):
+        return _paged_call(q, k_pages, v_pages, block_tables, lengths)
+    return paged_attention_reference(q, k_pages, v_pages, block_tables,
+                                     lengths, bias=bias)
 
 
-def decode_attention(q, ck, cv, pos, *, block_k: Optional[int] = None):
-    """KV-cache attention for prefill/decode; dispatches to the Pallas
-    kernel when shapes allow, under shard_map when a mesh is active
-    (batch over data/fsdp/expert, heads over tensor — decode never shards
-    the cache length)."""
+def decode_attention(q, ck, cv, pos, bias=None, *,
+                     block_k: Optional[int] = None):
+    """KV-cache attention for prefill/decode: the Pallas kernel where
+    :func:`kernel_shape_ok` admits the shape (no bias), under shard_map
+    when a mesh is active (batch over data/fsdp/expert, heads over tensor —
+    decode never shards the cache length); else the einsum reference."""
     B, Sq, H, D = q.shape
     T = ck.shape[1]
     bk = block_k or min(128, T)
-    if T % bk != 0 or D % 8 != 0:
-        return decode_attention_reference(q, ck, cv, pos)
-
-    from deepspeed_tpu.parallel import mesh as mesh_lib
+    batch_div, tp = _mesh_divisors()
+    if (bias is not None or not _kernel_wanted("DST_PALLAS_DECODE")
+            or T % bk != 0 or B % batch_div != 0 or H % tp != 0
+            or not kernel_shape_ok(H // tp, ck.shape[2] // D // tp, D, bk,
+                                   ck.dtype)):
+        return decode_attention_reference(q, ck, cv, pos, bias=bias)
     call = functools.partial(_decode_call, bk=bk)
-    if mesh_lib.has_mesh():
-        mesh = mesh_lib.get_mesh()
-        batch_div = int(np.prod([mesh.shape[a] for a in mesh_lib.BATCH_AXES]))
-        tp = int(mesh.shape["tensor"])
-        if batch_div > 1 or tp > 1:
-            if B % batch_div != 0 or H % tp != 0:
-                return decode_attention_reference(q, ck, cv, pos)
-            qspec = P(mesh_lib.BATCH_AXES, None, "tensor", None)
-            return mesh_lib.shard_map(
-                call, mesh=mesh,
-                in_specs=(qspec, qspec, qspec, P()),
-                out_specs=qspec, check_vma=False)(q, ck, cv, pos)
+    if batch_div > 1 or tp > 1:
+        from deepspeed_tpu.parallel import mesh as mesh_lib
+        qspec = P(mesh_lib.BATCH_AXES, None, "tensor", None)
+        cspec = P(mesh_lib.BATCH_AXES, None, "tensor")
+        return jax.shard_map(
+            call, mesh=mesh_lib.get_mesh(),
+            in_specs=(qspec, cspec, cspec, P()),
+            out_specs=qspec, check_vma=False)(q, ck, cv, pos)
     return call(q, ck, cv, pos)

@@ -52,8 +52,9 @@ the exact re-shard ``parallel/sequence.py:ulysses_attention`` expresses as
 sharding constraints.  Ring attention (O(S/sp) memory) remains the explicit
 alternative for sequences too long to replicate per-device.
 
-``interpret=True`` (automatic off-TPU) runs the same kernels through the
-Pallas interpreter so CPU CI validates them against the jnp reference — the
+On the ``cpu`` platform (``ops.pallas.interpret``) the same kernels run
+through the Pallas interpreter so CPU CI validates them against the jnp
+reference — the
 analogue of the reference's kernel-vs-HF-modeling parity tests
 (``tests/unit/ops/accelerators/test_accelerator_forward.py``).
 """
@@ -69,22 +70,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
+from deepspeed_tpu.ops import pallas as _pallas
+
 NEG_INF = -1e30
 
-# jax < 0.5 spells these ``TPUCompilerParams`` / ``TPUMemorySpace``.
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-_MEMSPACE = getattr(pltpu, "MemorySpace", None) or pltpu.TPUMemorySpace
-
-_PARALLEL3 = _COMPILER_PARAMS(
+_PARALLEL3 = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "parallel"))
-
-
-def _interpret() -> bool:
-    try:
-        return jax.devices()[0].platform != "tpu"
-    except Exception:
-        return True
 
 
 #: (q_shape, reason-class) combos already warned about — the demotion is
@@ -221,7 +212,7 @@ def _fwd(q, k, v, bias, slopes, *, causal, scale, bq=None, bk=None):
         in_specs.append(_bias_spec_qrows(bias, bq, S))
         args.append(bias)
     if slopes is not None:
-        in_specs.append(pl.BlockSpec(memory_space=_MEMSPACE.SMEM))
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.MemorySpace.SMEM))
         args.append(slopes)
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal, bq=bq, bk=bk,
@@ -238,7 +229,8 @@ def _fwd(q, k, v, bias, slopes, *, causal, scale, bq=None, bk=None):
             jax.ShapeDtypeStruct((B, H, S, 1), jnp.float32),
         ],
         compiler_params=_PARALLEL3,
-        interpret=_interpret(),
+        interpret=_pallas.interpret(),
+        name="flash_fwd",
     )(*args)
     return o, lse
 
@@ -375,7 +367,7 @@ def flash_block_bwd(q, k, v, do, lse, delta, bias=None, slopes=None, *,
         dq_specs.append(_bias_spec_qrows(bias, bq_, S))
     if slopes is not None:
         dq_in.append(slopes)
-        dq_specs.append(pl.BlockSpec(memory_space=_MEMSPACE.SMEM))
+        dq_specs.append(pl.BlockSpec(memory_space=pltpu.MemorySpace.SMEM))
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal, bq=bq_,
                           bk=bk_, S=S, has_bias=bias is not None,
@@ -385,7 +377,8 @@ def flash_block_bwd(q, k, v, do, lse, delta, bias=None, slopes=None, *,
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
         compiler_params=_PARALLEL3,
-        interpret=_interpret(),
+        interpret=_pallas.interpret(),
+        name="flash_bwd_dq",
     )(*dq_in)
 
     # dK/dV: grid over KV heads; q/do/lse/delta delivered group-at-a-time
@@ -400,7 +393,7 @@ def flash_block_bwd(q, k, v, do, lse, delta, bias=None, slopes=None, *,
         dkv_specs.append(_bias_spec_kcols(bias, group, bk_, S))
     if slopes is not None:
         dkv_in.append(slopes)
-        dkv_specs.append(pl.BlockSpec(memory_space=_MEMSPACE.SMEM))
+        dkv_specs.append(pl.BlockSpec(memory_space=pltpu.MemorySpace.SMEM))
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal, bq=bq_,
                           bk=bk_, S=S, group=group, has_bias=bias is not None,
@@ -412,7 +405,8 @@ def flash_block_bwd(q, k, v, do, lse, delta, bias=None, slopes=None, *,
         out_shape=[jax.ShapeDtypeStruct((B, Hkv, S, D), k.dtype),
                    jax.ShapeDtypeStruct((B, Hkv, S, D), v.dtype)],
         compiler_params=_PARALLEL3,
-        interpret=_interpret(),
+        interpret=_pallas.interpret(),
+        name="flash_bwd_dkv",
     )(*dkv_in)
     return dq, dk, dv
 
@@ -508,6 +502,8 @@ def flash_attention(q, k, v, *, causal: bool = True, bias=None, alibi=None,
             if B % batch_div != 0 or H % head_div != 0 or Hkv % head_div != 0:
                 # a bare pallas_call has no SPMD partitioning rule; on shapes
                 # the shard_map can't split, use the jnp path XLA can shard
+                _fallback_warn_once(q.shape, f"mesh ({batch_div},{head_div}) "
+                                    f"does not divide B={B}, H={H}, Hkv={Hkv}")
                 from deepspeed_tpu.ops.attention import reference_attention
                 return reference_attention(q, k, v, causal=causal, bias=bias,
                                            alibi=alibi)
@@ -529,14 +525,6 @@ def flash_attention(q, k, v, *, causal: bool = True, bias=None, alibi=None,
                 sl = rest[-1] if ns else None
                 return _flash_bshd(q, k, v, b, sl, causal, scale, block_q, block_k)
 
-            from deepspeed_tpu.parallel.mesh import shard_map
-            return shard_map(inner, mesh=mesh, in_specs=tuple(in_specs),
-                             out_specs=spec, check_vma=False)(*args)
-    try:
-        return _flash_bshd(q, k, v, bias, slopes, causal, scale,
-                           block_q, block_k)
-    except Exception as e:  # Mosaic lowering failure → demote, don't wedge
-        _fallback_warn_once(q.shape, f"kernel lowering failed: {e}")
-        from deepspeed_tpu.ops.attention import reference_attention
-        return reference_attention(q, k, v, causal=causal, bias=bias,
-                                   alibi=alibi)
+            return jax.shard_map(inner, mesh=mesh, in_specs=tuple(in_specs),
+                                 out_specs=spec, check_vma=False)(*args)
+    return _flash_bshd(q, k, v, bias, slopes, causal, scale, block_q, block_k)

@@ -33,21 +33,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 spells these ``TPUCompilerParams`` / ``TPUMemorySpace``.
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-_MEMSPACE = getattr(pltpu, "MemorySpace", None) or pltpu.TPUMemorySpace
+from deepspeed_tpu.ops import pallas as _pallas
 
 _LANE = 128
 _SUBLANE = 8
 _INT32_MAX = jnp.iinfo(jnp.int32).max
-
-
-def _interpret() -> bool:
-    try:
-        return jax.devices()[0].platform != "tpu"
-    except Exception:
-        return True
 
 
 def fused_opt_enabled() -> bool:
@@ -57,7 +47,7 @@ def fused_opt_enabled() -> bool:
         return False
     if flag in ("1", "on", "true"):
         return True
-    return not _interpret()
+    return _pallas.platform() == "tpu"
 
 
 # --------------------------------------------------------------------------- #
@@ -181,15 +171,16 @@ def fused_leaf_update(p, g, mu, nu, scal, *, b1, b2, eps, wd):
     out = pl.pallas_call(
         functools.partial(_adam_kernel, b1=b1, b2=b2, eps=eps, wd=wd),
         grid=(rows // br,),
-        in_specs=[pl.BlockSpec(memory_space=_MEMSPACE.SMEM),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.MemorySpace.SMEM),
                   blk(pdt), blk(g2.dtype), blk(jnp.float32),
                   blk(jnp.float32)],
         out_specs=[blk(pdt), blk(jnp.float32), blk(jnp.float32)],
         out_shape=[jax.ShapeDtypeStruct((rows, _LANE), pdt),
                    jax.ShapeDtypeStruct((rows, _LANE), jnp.float32),
                    jax.ShapeDtypeStruct((rows, _LANE), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS(dimension_semantics=("arbitrary",)),
-        interpret=_interpret(),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        interpret=_pallas.interpret(),
+        name="fused_adam",
     )(scal.astype(jnp.float32), p2, g2, mu2, nu2)
     def unflat(a, dt):
         return a.reshape(-1)[:n].reshape(shape).astype(dt)

@@ -107,13 +107,12 @@ class MeshSpec:
         n = int(np.prod(shape))
         assert n == len(devices), f"{shape} needs {n} devices, have {len(devices)}"
         if len(devices) > 1 and devices[0].platform == "tpu":
-            try:
-                from jax.experimental import mesh_utils
-                dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
-                return Mesh(dev_array, MESH_AXES)
-            except Exception:
-                pass
-        dev_array = np.asarray(devices).reshape(shape)
+            # topology-aware placement (innermost axes on nearest-neighbour
+            # ICI); a shape it cannot place is the caller's error to see
+            from jax.experimental import mesh_utils
+            dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
+        else:
+            dev_array = np.asarray(devices).reshape(shape)
         return Mesh(dev_array, MESH_AXES)
 
 
@@ -217,34 +216,6 @@ def batch_sharding(mesh: Optional[Mesh] = None) -> NamedSharding:
 def replicated(mesh: Optional[Mesh] = None) -> NamedSharding:
     mesh = mesh or get_mesh()
     return NamedSharding(mesh, PartitionSpec())
-
-
-def shard_map(f, mesh: Mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` across JAX versions.  Newer releases expose it at
-    the top level with ``check_vma``; older ones only have
-    ``jax.experimental.shard_map.shard_map`` with ``check_rep`` (same
-    meaning).  New subsystems route through this so they run on either."""
-    import inspect
-    if hasattr(jax, "shard_map"):
-        sm = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as sm
-    kw = ("check_vma" if "check_vma" in inspect.signature(sm).parameters
-          else "check_rep")
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              **{kw: check_vma})
-
-
-def manual_axis_size(name: str) -> int:
-    """Static size of a named mesh axis from inside a ``shard_map`` body,
-    across JAX versions (``lax.axis_size`` is newer than the pinned
-    toolchain; older releases answer via ``core.axis_frame``)."""
-    from jax import lax as _lax
-    if hasattr(_lax, "axis_size"):
-        return int(_lax.axis_size(name))
-    from jax import core as _core
-    frame = _core.axis_frame(name)
-    return int(getattr(frame, "size", frame))
 
 
 @functools.lru_cache(None)

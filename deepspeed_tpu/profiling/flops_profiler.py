@@ -32,8 +32,6 @@ def analyze_fn_cost(fn, *args, **kwargs) -> Dict[str, float]:
         lowered = jax.jit(fn).lower(*args, **kwargs)
         compiled = lowered.compile()
         cost = compiled.cost_analysis()
-        if isinstance(cost, list):  # older jax returns [dict]
-            cost = cost[0]
         return {
             "flops": float(cost.get("flops", 0.0)),
             "bytes_accessed": float(cost.get("bytes accessed", cost.get("bytes_accessed", 0.0))),

@@ -34,7 +34,6 @@ import jax.numpy as jnp
 from deepspeed_tpu.comm.compression.core import (  # noqa: F401 — public API
     CompressionState, ef_compensate, ef_residual, init_compression_state,
     padded_size, sign_scale, zeroed_compression_state)
-from deepspeed_tpu.parallel import mesh as mesh_lib
 
 # kept under its historical private name for callers that reached in
 _sign_scale = sign_scale
@@ -56,7 +55,7 @@ def compressed_allreduce(x: jax.Array, state: CompressionState,
     ``x`` is this device's flat fp32 vector (unpadded length); returns the
     compressed mean (same shape) and the updated error buffers.
     """
-    world = mesh_lib.manual_axis_size(axis_name)
+    world = jax.lax.axis_size(axis_name)
     n = x.shape[0]
     n_pad = state.worker_error.shape[0]
     chunk = n_pad // world

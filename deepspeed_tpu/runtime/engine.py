@@ -1101,7 +1101,7 @@ class DeepSpeedEngine:
             return loss, grads
 
         gspec = jax.tree.map(lambda _: PartitionSpec("data"), self.state.params)
-        fn = mesh_lib.shard_map(local, mesh=self.mesh,
+        fn = jax.shard_map(local, mesh=self.mesh,
                                 in_specs=(pspec, bspec, PartitionSpec(), PartitionSpec()),
                                 out_specs=(PartitionSpec(), gspec), check_vma=False)
         return jax.jit(fn)
@@ -1141,7 +1141,7 @@ class DeepSpeedEngine:
 
         gspec = jax.tree.map(lambda _: PartitionSpec("data"), self.state.params)
         rspec = jax.tree.map(lambda _: PartitionSpec(), self.state.params)
-        fn = mesh_lib.shard_map(
+        fn = jax.shard_map(
             compress, mesh=self.mesh,
             in_specs=(gspec, rspec, PartitionSpec("data"), PartitionSpec("data"),
                       PartitionSpec()),
@@ -1386,7 +1386,7 @@ class DeepSpeedEngine:
                 full = jax.tree.unflatten(treedef, fulls)
                 return loss_and_grads(full, batch, rng, scale)
 
-            fn = mesh_lib.shard_map(
+            fn = jax.shard_map(
                 body, mesh=self.mesh,
                 in_specs=(sec_specs, bspec, PartitionSpec(), PartitionSpec()),
                 out_specs=(PartitionSpec(), gspecs), check_vma=False)
@@ -1419,7 +1419,7 @@ class DeepSpeedEngine:
 
         out_specs = ((PartitionSpec(), gspecs, sec_specs) if cc["hpz"]
                      else (PartitionSpec(), gspecs))
-        fn = mesh_lib.shard_map(
+        fn = jax.shard_map(
             body, mesh=self.mesh,
             in_specs=(pspecs, bspec, PartitionSpec(), PartitionSpec()),
             out_specs=out_specs, check_vma=False)
@@ -1535,7 +1535,7 @@ class DeepSpeedEngine:
                         block_size=cc["block"]))
             return jax.tree.unflatten(treedef, outs)
 
-        fn = mesh_lib.shard_map(body, mesh=self.mesh, in_specs=(pspecs,),
+        fn = jax.shard_map(body, mesh=self.mesh, in_specs=(pspecs,),
                                 out_specs=sec_specs, check_vma=False)
         return jax.jit(fn)
 
@@ -1630,7 +1630,7 @@ class DeepSpeedEngine:
                 jax.tree.structure(self.state.params),
                 [sec_spec(s, d)
                  for s, d in zip(jax.tree.leaves(pspecs), plan)])
-            fn = mesh_lib.shard_map(
+            fn = jax.shard_map(
                 run, mesh=self.mesh,
                 in_specs=(pspecs, sec_specs, bspec, PartitionSpec(),
                           PartitionSpec()),
@@ -1640,7 +1640,7 @@ class DeepSpeedEngine:
         def body(params, batch, rng, scale):
             return run(params, None, batch, rng, scale)
 
-        fn = mesh_lib.shard_map(
+        fn = jax.shard_map(
             body, mesh=self.mesh,
             in_specs=(pspecs, bspec, PartitionSpec(), PartitionSpec()),
             out_specs=(PartitionSpec(), gspecs), check_vma=False)

@@ -27,7 +27,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from deepspeed_tpu.runtime.zero.policy import ZeroShardingPolicy
-from deepspeed_tpu.utils.logging import logger
 
 Pytree = Any
 
@@ -156,7 +155,7 @@ def gather_partitioned_params(params: Pytree, shardings: Pytree,
 
     out_specs = jax.tree.map(lambda _: PartitionSpec(), specs,
                              is_leaf=lambda s: isinstance(s, PartitionSpec))
-    fn = mesh_lib.shard_map(body, mesh=mesh, in_specs=(specs,),
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(specs,),
                             out_specs=out_specs, check_vma=False)
     return jax.jit(fn)(params)
 
@@ -214,20 +213,23 @@ def offload_shardings(shardings: Pytree, device: str,
     lives in ``deepspeed_tpu.runtime.swap_tensor``).
 
     Scalars/counters stay on device (offloading them buys nothing and some
-    backends reject host-placed scalars).  Support is probed with the same
-    mechanism the engine uses (jit out_shardings), not a bare device_put."""
+    backends reject host-placed scalars).  A backend that cannot place on
+    ``pinned_host`` (the CPU backend) raises: offload that was asked for and
+    silently kept on the device would hide a memory cliff until the real
+    size.  Support is probed with the same mechanism the engine uses (jit
+    out_shardings), not a bare device_put."""
     if device in (None, "none"):
         return shardings
     import jax.numpy as jnp
+    mesh = jax.tree.leaves(shardings)[0].mesh
+    sample = NamedSharding(mesh, PartitionSpec(), memory_kind="pinned_host")
     try:
-        mesh = jax.tree.leaves(shardings)[0].mesh
-        sample = NamedSharding(mesh, PartitionSpec(), memory_kind="pinned_host")
         jax.jit(lambda: jnp.zeros((256,), jnp.float32), out_shardings=sample)()
-    except Exception as e:  # noqa: BLE001 — backend-dependent support
-        logger.warning(
-            f"offload to '{device}' requested but this backend does not "
-            f"support pinned_host placement ({e}); keeping device placement")
-        return shardings
+    except jax.errors.JaxRuntimeError as e:
+        raise RuntimeError(
+            f"offload to '{device}' requested but the "
+            f"{mesh.devices.flat[0].platform} backend cannot place on "
+            f"pinned_host") from e
 
     if shapes is None:
         return jax.tree.map(lambda s: s.with_memory_kind("pinned_host"), shardings)

@@ -3,8 +3,9 @@
 The serving-side analogue of ZeRO-Infinity's memory virtualization (arxiv
 2104.07857): a sequence's LOGICAL KV memory is decoupled from PHYSICAL HBM
 placement, so arena capacity — not batch shape — is the binding constraint.
-The device arena is ``[n_layer, num_blocks, block_size, kv_heads, head_dim]``
-per K and V; this module owns the host-side bookkeeping:
+The device arena is ``[n_layer, num_blocks, block_size, kv_heads * head_dim]``
+per K and V (heads folded into the lane dimension, the layout the paged
+kernel can DMA); this module owns the host-side bookkeeping:
 
 * a free list of physical block ids (block 0 is reserved as the TRASH
   block: padded/inactive tokens scatter their K/V there, so the compiled
@@ -231,10 +232,10 @@ class PagedKVAllocator:
 
 def init_arena(cfg, num_blocks: int, block_size: int, dtype=None):
     """Device arena pair for ``models/gpt.py:gpt_paged_step``:
-    K/V ``[n_layer, num_blocks, block_size, kv_heads, head_dim]``."""
+    K/V ``[n_layer, num_blocks, block_size, kv_heads * head_dim]``."""
     import jax.numpy as jnp
     dtype = dtype or cfg.dtype
-    shape = (cfg.n_layer, num_blocks, block_size, cfg.kv_heads, cfg.head_dim)
+    shape = (cfg.n_layer, num_blocks, block_size, cfg.kv_heads * cfg.head_dim)
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
 
 

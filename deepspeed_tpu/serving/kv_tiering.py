@@ -115,7 +115,7 @@ class KVTieringManager:
         """Bounded copy ring, device→host: dispatch up to ``ring_depth``
         chunk gathers before draining the oldest (``np.asarray`` is the
         D2H sync point), so the transfer overlaps the next gather's
-        dispatch.  → one ``[2, L, n_blocks, BS, H, D]`` host array."""
+        dispatch.  → one ``[2, L, n_blocks, BS, H*D]`` host array."""
         import jax.numpy as jnp
         gather, _ = self._copy_fns(kp)
         CH = self.spill_chunk_blocks
@@ -145,15 +145,15 @@ class KVTieringManager:
         import jax.numpy as jnp
         _, scatter = self._copy_fns(kp)
         CH = self.spill_chunk_blocks
-        L, _, BS, H, D = kp.shape
+        L, _, BS, HD = kp.shape
         hk, hv = data[0], data[1]
         for off in range(0, len(dest_blocks), CH):
             chunk = dest_blocks[off:off + CH]
             n = len(chunk)
             idx = np.zeros((CH,), np.int32)   # pad lanes scatter to trash
             idx[:n] = chunk
-            kb = np.zeros((L, CH, BS, H, D), hk.dtype)
-            vb = np.zeros((L, CH, BS, H, D), hk.dtype)
+            kb = np.zeros((L, CH, BS, HD), hk.dtype)
+            vb = np.zeros((L, CH, BS, HD), hk.dtype)
             kb[:, :n] = hk[:, off:off + n]
             vb[:, :n] = hv[:, off:off + n]
             kp, vp = scatter(kp, vp, jnp.asarray(idx),
@@ -163,8 +163,8 @@ class KVTieringManager:
     # ---- capacity ------------------------------------------------------- #
     def chunk_bytes(self, kp, n_blocks: int) -> int:
         """Spill footprint of ``n_blocks`` arena blocks (K and V)."""
-        L, _, BS, H, D = kp.shape
-        return 2 * L * int(n_blocks) * BS * H * D * np.dtype(kp.dtype).itemsize
+        L, _, BS, HD = kp.shape
+        return 2 * L * int(n_blocks) * BS * HD * np.dtype(kp.dtype).itemsize
 
     def can_spill(self, nbytes: int) -> bool:
         """Whether the spill budget admits ``nbytes`` more.  Budget 0 is

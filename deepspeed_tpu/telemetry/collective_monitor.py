@@ -479,10 +479,9 @@ def gather_windows_over_mesh(views, width=None, axis="obs"):
     def _gather(block):          # [1, N] local shard = one rank's vector
         return C.all_gather(block[0], group=axis, axis=0, tiled=False)[None]
 
-    from jax.experimental.shard_map import shard_map
     arr = jax.device_put(stacked, NamedSharding(mesh, P(axis, None)))
-    gathered = jax.jit(shard_map(_gather, mesh=mesh, in_specs=P(axis, None),
-                                 out_specs=P(axis, None)))(arr)
+    gathered = jax.jit(jax.shard_map(_gather, mesh=mesh, in_specs=P(axis, None),
+                                     out_specs=P(axis, None)))(arr)
     # every shard holds the full [R, N] gather; read rank 0's copy
     rows = np.asarray(gathered.addressable_shards[0].data)[0]
     return [unpack_window(rows[i], metas[i], ranks[i], base_us)

@@ -358,10 +358,9 @@ def fold_packed_over_mesh(vectors: Sequence[Sequence[float]],
                                summed[nc:nc + ng], summed[nc + ng:]])
         return out[None, :]
 
-    from jax.experimental.shard_map import shard_map
     arr = jax.device_put(stacked, NamedSharding(mesh, P(axis, None)))
-    folded = jax.jit(shard_map(_fold, mesh=mesh, in_specs=P(axis, None),
-                               out_specs=P(axis, None)))(arr)
+    folded = jax.jit(jax.shard_map(_fold, mesh=mesh, in_specs=P(axis, None),
+                                   out_specs=P(axis, None)))(arr)
     # every shard holds the same folded vector; read rank 0's copy
     return np.asarray(folded.addressable_shards[0].data)[0]
 

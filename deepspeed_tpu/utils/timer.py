@@ -184,8 +184,7 @@ class ThroughputTimer:
     """Samples/sec tracker (the role of reference ``utils/timer.py:137``).
 
     TPU-native design point: never fence the device on a per-step basis.
-    Dispatch is fully asynchronous (and on tunneled runtimes a device sync
-    costs a network round-trip), so a per-step start/stop sync — the
+    Dispatch is fully asynchronous, so a per-step start/stop sync — the
     reference's CUDA-event pattern — serializes the pipeline and *is itself*
     the bottleneck.  Instead, steps are only counted between report
     boundaries; the device is drained once per ``steps_per_output`` window

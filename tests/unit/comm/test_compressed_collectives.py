@@ -11,7 +11,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from deepspeed_tpu.comm.compression import hpz, qgz, qwz
 from deepspeed_tpu.comm.compression.core import quantization_error_bound
-from deepspeed_tpu.parallel import mesh as mesh_lib
 
 
 def _mesh1():
@@ -23,7 +22,7 @@ def _mesh2():
 
 
 def _run(mesh, axes, body, xs, out_spec=P()):
-    fn = jax.jit(mesh_lib.shard_map(body, mesh=mesh, in_specs=(P(axes),),
+    fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(axes),),
                                     out_specs=out_spec, check_vma=False))
     return np.asarray(fn(xs))
 
@@ -73,7 +72,7 @@ class TestQwz:
             return q, e
 
         mesh = _mesh1()
-        fn = jax.jit(mesh_lib.shard_map(body, mesh=mesh, in_specs=(P("fsdp"),),
+        fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P("fsdp"),),
                                         out_specs=(P(), P()), check_vma=False))
         got, exact = map(np.asarray, fn(xs))
         np.testing.assert_array_equal(got, exact)
@@ -99,7 +98,7 @@ class TestQgz:
             return h[None], e[None]
 
         mesh = _mesh1()
-        fn = jax.jit(mesh_lib.shard_map(body, mesh=mesh, in_specs=(P("fsdp"),),
+        fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P("fsdp"),),
                                         out_specs=(P("fsdp"), P("fsdp")),
                                         check_vma=False))
         h, e = map(np.asarray, fn(xs))
@@ -156,7 +155,7 @@ class TestHpz:
 
         mesh = _mesh2()
         # sec is sharded over fsdp at dim 0: spec P("fsdp")
-        fn = jax.jit(mesh_lib.shard_map(
+        fn = jax.jit(jax.shard_map(
             body, mesh=mesh, in_specs=(P(axes),),
             out_specs=(P(), P("fsdp"), P(), P()), check_vma=False))
         full, sec, again, exact = map(np.asarray, fn(xs))
@@ -178,7 +177,7 @@ class TestHpz:
             return full, hpz.fast_regather(sec, 0, "fsdp", w_slow=2)
 
         mesh = _mesh2()
-        fn = jax.jit(mesh_lib.shard_map(
+        fn = jax.jit(jax.shard_map(
             body, mesh=mesh, in_specs=(P(axes),),
             out_specs=(P(), P()), check_vma=False))
         full, again = map(np.asarray, fn(xs))

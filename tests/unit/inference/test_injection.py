@@ -280,7 +280,8 @@ class TestLlamaGQA:
         # the cache stores only the kv heads (the GQA memory win);
         # batch must divide the active data axis for placement
         cache = engine.module.init_cache(8, 32)
-        assert cache["k"].shape[3] == 2
+        cfg = engine.module.cfg
+        assert cache["k"].shape[3] == 2 * cfg.head_dim   # [L, B, T, Hkv*D]
 
     def test_logits_parity_tp2(self, tiny_gqa):
         """TP x GQA: kv heads shard over the tensor axis."""

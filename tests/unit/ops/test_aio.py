@@ -60,6 +60,23 @@ class TestAIOHandle:
         assert b.load() is not None
         assert os.path.exists(b.so_path())
 
+    def test_rebuild_decided_by_source_hash(self, handle, tmp_path, monkeypatch):
+        """A copy of the tree keeps no mtimes: the build is reused only when
+        the source hash recorded beside it matches, rebuilt otherwise."""
+        from deepspeed_tpu.ops.aio import aio_handle as ah
+        monkeypatch.setattr(ah, "_BUILD_DIR", str(tmp_path))
+        monkeypatch.setattr(ah, "_SO", str(tmp_path / "libdst_aio.so"))
+        monkeypatch.setattr(ah, "_SO_HASH", str(tmp_path / "libdst_aio.so.sha256"))
+        ah._build_if_stale()                       # missing -> built
+        good = (tmp_path / "libdst_aio.so.sha256").read_text()
+        built = os.stat(ah._SO).st_ino
+        ah._build_if_stale()                       # same hash -> reused
+        assert os.stat(ah._SO).st_ino == built
+        (tmp_path / "libdst_aio.so.sha256").write_text("stale")
+        ah._build_if_stale()                       # hash differs -> rebuilt
+        assert os.stat(ah._SO).st_ino != built
+        assert (tmp_path / "libdst_aio.so.sha256").read_text() == good
+
 
 class TestSwappers:
     def test_async_tensor_swapper(self, tmp_path):
@@ -99,6 +116,7 @@ class TestSwappers:
         assert cfg["block_size"] == 1 << 20
 
 
+@pytest.mark.usefixtures("offload_on_device")
 class TestZeroInfinityEngine:
     def test_nvme_offload_training(self, tmp_path):
         """offload_optimizer.device='nvme': state lives on disk between
@@ -139,6 +157,7 @@ class TestZeroInfinityEngine:
         assert engine2.global_steps == 5
 
 
+@pytest.mark.usefixtures("offload_on_device")
 class TestOffloadOptimizerConfigHonored:
     def test_pipeline_write_and_buffer_count_flow_through(self, tmp_path):
         """The engine must build the optimizer swapper from the user's
@@ -166,6 +185,7 @@ class TestOffloadOptimizerConfigHonored:
         assert mk({})._pipeline_write is False
 
 
+@pytest.mark.usefixtures("offload_on_device")
 class TestNvmeCheckpointResume:
     def test_load_checkpoint_with_nvme_offload(self, tmp_path):
         """Resuming a ZeRO-Infinity run: the restore target must come from

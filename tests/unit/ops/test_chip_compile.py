@@ -1,0 +1,173 @@
+"""Ahead-of-time compiles for the chip: what the v5e compiler accepts.
+
+The TPU compiler is installed here and compiles for a chip that is described
+and not attached (``/opt/skills/guides/on-chip-measurement`` §2 step 3).
+Interpret mode cannot see what it refuses — a DMA slice not aligned to the
+tiling, too much VMEM — so every Pallas kernel the GPT-2 train and serve
+paths select is compiled here at gpt2 (12 heads) and gpt2-xl (25 heads)
+shapes, D=64, bf16.  Nothing runs: a compile that passes is not a chip run
+(``chip_smoke.py`` is).  The test steers ``ops.pallas`` to the chip itself;
+the program has no switch for it.
+"""
+
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from deepspeed_tpu.ops import pallas
+from deepspeed_tpu.ops.pallas import cross_entropy as ce
+from deepspeed_tpu.ops.pallas import decode_attention as da
+from deepspeed_tpu.ops.pallas import flash_attention as fa
+from deepspeed_tpu.ops.pallas import fused_optim as fo
+
+BF16 = jnp.bfloat16
+# (heads, n_embd); D = 64 and vocab 50304 (50257 padded) for both
+WIDTHS = {"gpt2": (12, 768), "gpt2-xl": (25, 1600)}
+V, D, S, B = 50304, 64, 1024, 8
+CHUNK, MAX_BLOCKS = 64, 32          # serving prefill_chunk, table width
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """One described v5e chip; the persistent compile cache stays off
+    around these compiles (an entry written without a chip cannot be read
+    back, and the next compile would warn)."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # nothing is attached, only described: several test workers may load
+    # the compiler at once (libtpu otherwise keeps one process per host)
+    os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # noqa: BLE001 — no TPU compiler in this install
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(autouse=True)
+def on_the_chip(monkeypatch):
+    monkeypatch.setattr(pallas, "platform", lambda: "tpu")
+    monkeypatch.setattr(pallas, "interpret", lambda: False)
+    for var in ("DST_PALLAS_DECODE", "DST_PALLAS_PAGED"):
+        monkeypatch.delenv(var, raising=False)
+
+
+def _compiled_text(chip, fn, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=chip) for s, dt in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _flash_fwd(H, E):
+    qkv = ((B, S, H, D), BF16)
+    return (lambda q, k, v: fa.flash_attention(q, k, v, causal=True),
+            qkv, qkv, qkv)
+
+
+def _flash_bwd(H, E):
+    qkv = ((B, S, H, D), BF16)
+    loss = lambda q, k, v: fa.flash_attention(
+        q, k, v, causal=True).astype(jnp.float32).sum()
+    return jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv
+
+
+def _fused_ce(H, E):
+    """Forward and both backward kernels, at half the smoke batch's rows."""
+    N = 4096
+    loss = lambda x, head, lab: ce.fused_cross_entropy(x, head, lab, 50257)
+    return (jax.value_and_grad(loss, argnums=(0, 1)),
+            ((N, E), BF16), ((V, E), BF16), ((N,), jnp.int32))
+
+
+def _fused_adam(H, E):
+    """The leaf shapes of the model: embedding, an MLP matrix, a bias."""
+    def step(*leaves):
+        scal = jnp.ones((5,), jnp.float32)
+        return [fo.fused_leaf_update(p, p, p, p, scal, b1=0.9, b2=0.999,
+                                     eps=1e-8, wd=0.01) for p in leaves]
+    f32 = jnp.float32
+    return step, ((50257, E), f32), ((E, 4 * E), f32), ((E,), f32)
+
+
+def _decode(Sq):
+    def case(H, E):
+        cache = ((B, S, H * D), BF16)
+        return (da.decode_attention, ((B, Sq, H, D), BF16), cache, cache,
+                ((), jnp.int32))
+    return case
+
+
+def _paged(Sq, BS):
+    def case(H, E):
+        pages = ((512, BS, H * D), BF16)
+        return (da.paged_attention, ((B, Sq, H, D), BF16), pages, pages,
+                ((B, MAX_BLOCKS), jnp.int32), ((B,), jnp.int32))
+    return case
+
+
+TRAIN = {"flash_fwd": _flash_fwd, "flash_bwd": _flash_bwd,
+         "fused_ce": _fused_ce, "fused_adam": _fused_adam}
+# case, and the block the kernel would DMA (bk = 128 cache rows / one page)
+SERVE = {"decode_sq1": (_decode(1), 128),
+         "decode_chunk": (_decode(CHUNK), 128),
+         "paged_sq1_bs16": (_paged(1, 16), 16),
+         "paged_chunk_bs16": (_paged(CHUNK, 16), 16),
+         "paged_sq1_bs32": (_paged(1, 32), 32),
+         "paged_chunk_bs32": (_paged(CHUNK, 32), 32)}
+
+
+@pytest.mark.parametrize("width", list(WIDTHS))
+@pytest.mark.parametrize("kernel", list(TRAIN))
+def test_train_kernel_compiles(chip, kernel, width):
+    fn, *shapes = TRAIN[kernel](*WIDTHS[width])
+    assert "tpu_custom_call" in _compiled_text(chip, fn, *shapes)
+    assert not fa._FALLBACK_WARNED
+
+
+@pytest.mark.parametrize("width", list(WIDTHS))
+@pytest.mark.parametrize("kernel", list(SERVE))
+def test_serve_kernel_compiles_or_gate_says_einsum(chip, kernel, width):
+    """Through the public dispatch, as the model calls it: where
+    ``kernel_shape_ok`` admits the shape the program holds the kernel and
+    the chip's compiler accepts it; where it does not (gpt2-xl: 25 heads of
+    64 are 1600 lanes, not a multiple of 128) the program is the einsum."""
+    H, E = WIDTHS[width]
+    case, block = SERVE[kernel]
+    fn, *shapes = case(H, E)
+    has_kernel = "tpu_custom_call" in _compiled_text(chip, fn, *shapes)
+    assert has_kernel == da.kernel_shape_ok(H, H, D, block, BF16)
+    assert has_kernel == (width == "gpt2")
+
+
+def test_generate_keeps_its_cache_zero_filled(chip):
+    """``generate()`` builds its KV cache inside the program.  The TPU
+    compiler turned those zeros into an uninitialised ``AllocateBuffer``
+    (it takes the layer loop's partial dynamic-update-slice for a full
+    overwrite), and attention multiplied the never-written rows' garbage by
+    its zero probabilities: on the chip, token 0 everywhere once the garbage
+    held a NaN.  ``gpt_generate`` keeps the zeros behind an optimization
+    barrier; no buffer of the cache's shape may come from AllocateBuffer."""
+    from deepspeed_tpu.models.gpt import GPT, gpt_config
+    cfg = gpt_config("gpt2", n_layer=2)
+    model = GPT(cfg)
+    prompt, new = 150, 16                # T=166: rows 150.. stay unwritten
+    params = jax.tree.map(
+        lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=chip),
+        jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
+    ids = jax.ShapeDtypeStruct((1, prompt), jnp.int32, sharding=chip)
+    text = jax.jit(lambda p, i: model.generate(p, i, new)).lower(
+        params, ids).compile().as_text()
+    cache = f"bf16[{cfg.n_layer},1,{prompt + new},{cfg.n_embd}]"
+    assert cache in text
+    assert not [line for line in text.splitlines()
+                if "AllocateBuffer" in line and f"= {cache}" in line]

@@ -1,5 +1,5 @@
 """Paged (block-table) decode attention: reference parity, Pallas-interpret
-parity, masking of stale arena contents, and the default-on env policy."""
+parity, masking of stale arena contents, and the kernel selection policy."""
 
 import numpy as np
 import pytest
@@ -7,19 +7,31 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from deepspeed_tpu.ops.pallas import decode_attention as da
 from deepspeed_tpu.ops.pallas.decode_attention import (
-    _paged_kernel_enabled, decode_attention_reference, paged_attention,
-    paged_attention_reference, pallas_decode_enabled)
+    _kernel_wanted, decode_attention_reference, paged_attention,
+    paged_attention_reference)
 
 
-def make_paged(B=2, Sq=1, H=4, D=16, Hkv=None, NB=24, BS=8, MB=8, seed=0,
-               length=20):
-    """Random arena + per-row tables mapping logical block j to a distinct
-    physical block, plus the dense gathered equivalent."""
+@pytest.fixture
+def kernel_calls(monkeypatch):
+    """Force the kernel on and count how often dispatch reaches it."""
+    monkeypatch.setenv("DST_PALLAS_PAGED", "1")
+    calls = []
+    real = da._paged_call
+    monkeypatch.setattr(da, "_paged_call",
+                        lambda *a, **k: calls.append(1) or real(*a, **k))
+    return calls
+
+
+def make_paged(B=2, Sq=1, H=8, D=16, Hkv=None, NB=24, BS=8, MB=8, seed=0,
+               length=20, dtype=np.float32):
+    """Random arena (pages ``[NB, BS, Hkv*D]``, heads folded into lanes) +
+    per-row tables mapping logical block j to a distinct physical block."""
     Hkv = Hkv or H
     rng = np.random.default_rng(seed)
-    k_pages = rng.standard_normal((NB, BS, Hkv, D)).astype(np.float32)
-    v_pages = rng.standard_normal((NB, BS, Hkv, D)).astype(np.float32)
+    k_pages = rng.standard_normal((NB, BS, Hkv * D)).astype(np.float32)
+    v_pages = rng.standard_normal((NB, BS, Hkv * D)).astype(np.float32)
     tables = np.zeros((B, MB), np.int32)
     free = list(range(1, NB))
     rng.shuffle(free)
@@ -28,8 +40,9 @@ def make_paged(B=2, Sq=1, H=4, D=16, Hkv=None, NB=24, BS=8, MB=8, seed=0,
             tables[b, j] = free.pop()
     q = rng.standard_normal((B, Sq, H, D)).astype(np.float32)
     lengths = np.full((B,), length, np.int32)
-    return (jnp.asarray(q), jnp.asarray(k_pages), jnp.asarray(v_pages),
-            jnp.asarray(tables), jnp.asarray(lengths))
+    return (jnp.asarray(q, dtype), jnp.asarray(k_pages, dtype),
+            jnp.asarray(v_pages, dtype), jnp.asarray(tables),
+            jnp.asarray(lengths))
 
 
 def test_reference_matches_dense_cache():
@@ -38,8 +51,8 @@ def test_reference_matches_dense_cache():
     q, kp, vp, tables, lengths = make_paged(Sq=1, length=20)
     B, Sq, H, D = q.shape
     T = tables.shape[1] * kp.shape[1]
-    ck = kp[tables].reshape(B, T, H, D)
-    cv = vp[tables].reshape(B, T, H, D)
+    ck = kp[tables].reshape(B, T, H * D)
+    cv = vp[tables].reshape(B, T, H * D)
     ref = decode_attention_reference(q, ck, cv, jnp.asarray(20, jnp.int32))
     out = paged_attention_reference(q, kp, vp, tables, lengths)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -51,8 +64,8 @@ def test_reference_gqa_matches_expanded_heads():
     B, Sq, H, D = q.shape
     T = tables.shape[1] * kp.shape[1]
     # expand 2 kv heads to 8 query heads and use the dense MHA reference
-    ck = jnp.repeat(kp[tables].reshape(B, T, 2, D), 4, axis=2)
-    cv = jnp.repeat(vp[tables].reshape(B, T, 2, D), 4, axis=2)
+    ck = jnp.repeat(kp[tables].reshape(B, T, 2, D), 4, axis=2).reshape(B, T, H * D)
+    cv = jnp.repeat(vp[tables].reshape(B, T, 2, D), 4, axis=2).reshape(B, T, H * D)
     ref = decode_attention_reference(q, ck, cv, jnp.asarray(13, jnp.int32))
     out = paged_attention_reference(q, kp, vp, tables, lengths)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -81,61 +94,76 @@ def test_stale_arena_contents_masked():
 
 
 @pytest.mark.parametrize("Sq,length", [(1, 20), (1, 0), (4, 9)])
-def test_pallas_kernel_parity(monkeypatch, Sq, length):
+def test_pallas_kernel_parity(kernel_calls, Sq, length):
     """Forced-on Pallas paged kernel (interpret mode on CPU) vs the jnp
     reference, decode and chunked-prefill shapes, per-row lengths."""
-    monkeypatch.setenv("DST_PALLAS_PAGED", "1")
     q, kp, vp, tables, lengths = make_paged(Sq=Sq, length=length, seed=3)
     lengths = jnp.asarray([length, max(0, length - 5)], jnp.int32)
     ref = paged_attention_reference(q, kp, vp, tables, lengths)
     out = paged_attention(q, kp, vp, tables, lengths)
+    assert kernel_calls
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
 
 
+@pytest.mark.parametrize("H,kernel", [(12, True), (25, False)])
+def test_gpt2_head_shapes(kernel_calls, H, kernel):
+    """GPT-2 head shapes, D=64 bf16, 16-row pages, a prefill chunk that
+    crosses a page: 12 heads run the kernel; gpt2-xl's 25 (1600 lanes, not
+    a multiple of 128) are routed to the gather reference by the gate."""
+    q, kp, vp, tables, lengths = make_paged(
+        Sq=4, H=H, D=64, BS=16, MB=4, length=29, seed=7, dtype=jnp.bfloat16)
+    out = paged_attention(q, kp, vp, tables, lengths)
+    assert bool(kernel_calls) == kernel
+    ref = paged_attention_reference(
+        q.astype(jnp.float32), kp.astype(jnp.float32),
+        vp.astype(jnp.float32), tables, lengths)
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref),
+                               atol=2e-2, rtol=2e-2)
+
+
 @pytest.mark.parametrize("Sq,length", [(4, 62), (8, 57)])
-def test_pallas_kernel_padded_chunk_overhang(monkeypatch, Sq, length):
+def test_pallas_kernel_padded_chunk_overhang(kernel_calls, Sq, length):
     """A padded prefill chunk can push ``length + Sq`` past the table
     capacity ``MB*BS`` (prefill_chunk not dividing the tail): the kernel's
     static MB-bound loop must keep every ``tbl_ref`` read inside the row —
     the old data-dependent trip count ran ``ceil((length+Sq)/BS) > MB``
     iterations and gathered a garbage physical block id — and still match
     the reference exactly."""
-    monkeypatch.setenv("DST_PALLAS_PAGED", "1")
     q, kp, vp, tables, lengths = make_paged(Sq=Sq, length=length, seed=5)
     MB, BS = tables.shape[1], kp.shape[1]
     assert length + Sq > MB * BS          # the overhang this test is about
     ref = paged_attention_reference(q, kp, vp, tables, lengths)
     out = paged_attention(q, kp, vp, tables, lengths)
+    assert kernel_calls
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
 
 
-def test_dispatch_falls_back_on_bias_and_gqa(monkeypatch):
-    """Unsupported kernel shapes (ALiBi bias, grouped heads) must route to
+def test_dispatch_takes_reference_on_bias_and_gqa(kernel_calls):
+    """Shapes outside the kernel (ALiBi bias, grouped heads) must route to
     the reference even when the kernel is forced on."""
-    monkeypatch.setenv("DST_PALLAS_PAGED", "1")
     q, kp, vp, tables, lengths = make_paged(H=8, Hkv=2, length=10)
     out = paged_attention(q, kp, vp, tables, lengths)     # GQA -> reference
     ref = paged_attention_reference(q, kp, vp, tables, lengths)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref))
     q2, kp2, vp2, tables2, lengths2 = make_paged(length=10)
     T = tables2.shape[1] * kp2.shape[1]
-    bias = jnp.zeros((2, 4, 1, T), jnp.float32)
+    bias = jnp.zeros((2, 8, 1, T), jnp.float32)
     out2 = paged_attention(q2, kp2, vp2, tables2, lengths2, bias=bias)
     ref2 = paged_attention_reference(q2, kp2, vp2, tables2, lengths2,
                                      bias=bias)
     np.testing.assert_allclose(np.asarray(out2), np.asarray(ref2))
+    assert not kernel_calls
 
 
 def test_env_policy_default_on_with_opt_out(monkeypatch):
-    """Graduation contract: default-on where supported (off on CPU, where
-    only the interpreter exists), ``=0`` opt-out, ``=1`` force-on."""
-    for fn, var in ((pallas_decode_enabled, "DST_PALLAS_DECODE"),
-                    (_paged_kernel_enabled, "DST_PALLAS_PAGED")):
+    """Unset, the kernels are wanted on TPU only (on CPU only the
+    interpreter exists); ``=0`` opts out, ``=1`` forces them on."""
+    for var in ("DST_PALLAS_DECODE", "DST_PALLAS_PAGED"):
         monkeypatch.delenv(var, raising=False)
-        assert fn() == (jax.default_backend() != "cpu")
+        assert _kernel_wanted(var) == (jax.default_backend() == "tpu")
         monkeypatch.setenv(var, "0")
-        assert fn() is False
+        assert _kernel_wanted(var) is False
         monkeypatch.setenv(var, "1")
-        assert fn() is True
+        assert _kernel_wanted(var) is True

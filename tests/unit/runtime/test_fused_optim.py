@@ -270,6 +270,7 @@ def swapped_state(engine):
     return engine.optimizer_swapper.swap_in()
 
 
+@pytest.mark.usefixtures("offload_on_device")
 class TestOffloadWalk:
 
     def test_walk_matches_unfused_offload(self, monkeypatch, tmp_path):

@@ -15,7 +15,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 import deepspeed_tpu
-from deepspeed_tpu.parallel import mesh as mesh_lib
 from deepspeed_tpu.runtime.comm.compressed import (
     CompressionState, compressed_allreduce, compressed_bytes,
     init_compression_state, padded_size)
@@ -30,7 +29,7 @@ def _run(xs, we, se, mesh):
         out, st = compressed_allreduce(x[0], CompressionState(we[0], se[0]), "data")
         return out[None], st.worker_error[None], st.server_error[None]
 
-    g = jax.jit(mesh_lib.shard_map(f, mesh=mesh,
+    g = jax.jit(jax.shard_map(f, mesh=mesh,
                                    in_specs=(P("data"), P("data"), P("data")),
                                    out_specs=(P("data"), P("data"), P("data")),
                                    check_vma=False))
@@ -83,7 +82,7 @@ class TestCompressedAllreduce:
             (_, _, acc), _ = jax.lax.scan(step, init, None, length=iters)
             return acc[None]
 
-        g = jax.jit(mesh_lib.shard_map(
+        g = jax.jit(jax.shard_map(
             f, mesh=mesh, in_specs=(P("data"), P("data"), P("data")),
             out_specs=P("data"), check_vma=False))
         acc = np.asarray(g(xs, WE, SE))[0]

@@ -3,8 +3,8 @@ copied into device memory inside the compiled step and stream back to the
 host tier through out_shardings — the XLA host-offload idiom the ZeRO-
 Offload path rides.  The memory-kind move itself needs hardware with a
 ``pinned_host`` space (TPU); those tests skip on CPU, where the
-warn-and-continue fallback plus the no-retrace discipline are covered
-instead."""
+``offload_on_device`` fixture keeps device placement explicitly and the
+no-retrace discipline is covered instead."""
 
 import numpy as np
 import pytest
@@ -72,10 +72,11 @@ class TestDeviceView:
         assert f._cache_size() == 1
 
 
+@pytest.mark.usefixtures("offload_on_device")
 class TestOffloadParamCpuFallback:
-    """On backends without pinned_host the cpu offload request warns and
-    keeps device placement — training must be untouched (bitwise) and the
-    layered step it implies must not retrace."""
+    """With the host tier explicitly kept in device memory
+    (``offload_on_device``) training must be untouched (bitwise) and the
+    layered step offload implies must not retrace."""
 
     def _engine(self, **zero_over):
         from deepspeed_tpu.models.gpt import GPT, GPTConfig

@@ -101,16 +101,28 @@ def test_materialize_and_gather_roundtrip():
     np.testing.assert_allclose(np.asarray(new["w"])[0, :3], 7.0)
 
 
-def test_offload_param_config_parses_and_engine_runs():
-    """offload_param on a backend without pinned_host must warn-and-continue
-    (loudly, once) rather than crash; on TPU the memory kind is honored —
-    exercised by tools/offload_check.py."""
+def _offload_engine():
     cfg = gpt_config("tiny", attn_impl="reference")
     config = dict(STAGE3_CONFIG)
     config["zero_optimization"] = {"stage": 3, "param_shard_min_size": 0,
                                    "offload_param": {"device": "cpu"},
                                    "offload_optimizer": {"device": "cpu"}}
     engine, _, _, _ = deepspeed_tpu.initialize(model=GPT(cfg), config=config)
+    return cfg, engine
+
+
+def test_offload_param_config_parses_and_engine_runs(offload_on_device):
+    """offload_param with the host tier explicitly in device memory (the
+    CPU backend has no pinned_host) trains; on TPU the memory kind is
+    honored — exercised by tools/offload_check.py."""
+    cfg, engine = _offload_engine()
     ids = np.random.default_rng(0).integers(0, cfg.vocab_size, (1, 8, 64)).astype(np.int32)
     loss = engine.train_batch(batch=(jnp.asarray(ids), jnp.asarray(ids)))
     assert np.isfinite(float(loss))
+
+
+def test_offload_raises_without_pinned_host():
+    """Offload that was asked for is never silently kept on the device:
+    a backend that cannot place on pinned_host (this one) is an error."""
+    with pytest.raises(RuntimeError, match="cannot place on pinned_host"):
+        _offload_engine()

@@ -11,13 +11,13 @@ import jax.numpy as jnp  # noqa: E402
 from deepspeed_tpu.runtime.offload import TIER_HOST, TIER_NVME  # noqa: E402
 from deepspeed_tpu.serving.kv_tiering import KVTieringManager  # noqa: E402
 
-L, NB, BS, H, D = 2, 12, 4, 2, 3
+L, NB, BS, HD = 2, 12, 4, 6      # pages fold heads into lanes: H*D
 
 
 def make_arena(seed=0):
     rng = np.random.default_rng(seed)
-    kp = jnp.asarray(rng.normal(size=(L, NB, BS, H, D)).astype(np.float32))
-    vp = jnp.asarray(rng.normal(size=(L, NB, BS, H, D)).astype(np.float32))
+    kp = jnp.asarray(rng.normal(size=(L, NB, BS, HD)).astype(np.float32))
+    vp = jnp.asarray(rng.normal(size=(L, NB, BS, HD)).astype(np.float32))
     return kp, vp
 
 

@@ -311,7 +311,6 @@ class TestFacadeInstrumentation:
     teardown_method = setup_method
 
     def test_staged_collectives_get_seq_fp_and_span_args(self):
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
 
         mon = cm.CollectiveMonitor(rank=0)
@@ -325,8 +324,8 @@ class TestFacadeInstrumentation:
                 y = C.all_reduce(x, group="dp")
                 return C.all_gather(y, group="dp", axis=0, tiled=True)
 
-            fn = jax.jit(shard_map(prog, mesh=mesh, in_specs=P("dp"),
-                                   out_specs=P(None), check_rep=False))
+            fn = jax.jit(jax.shard_map(prog, mesh=mesh, in_specs=P("dp"),
+                                       out_specs=P(None), check_vma=False))
             x = jnp.arange(8.0)
             fn(x).block_until_ready()
         finally:
@@ -351,11 +350,10 @@ class TestFacadeInstrumentation:
         assert mon.seq == 2
 
     def test_facade_works_with_no_monitor_installed(self):
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         mesh = Mesh(np.array(jax.devices()), ("dp",))
-        fn = jax.jit(shard_map(lambda x: C.all_reduce(x, group="dp"),
-                               mesh=mesh, in_specs=P("dp"), out_specs=P("dp")))
+        fn = jax.jit(jax.shard_map(lambda x: C.all_reduce(x, group="dp"),
+                                   mesh=mesh, in_specs=P("dp"), out_specs=P("dp")))
         out = fn(jnp.ones(8))
         assert float(out[0]) == 8.0
 
@@ -375,7 +373,6 @@ class TestStragglerE2E:
     teardown_method = setup_method
 
     def _replay_views(self):
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
 
         mesh = Mesh(np.array(jax.devices()), ("dp",))
@@ -392,8 +389,8 @@ class TestStragglerE2E:
                     y = C.all_gather(x, group="dp", axis=0, tiled=True)
                     return C.all_reduce(y, group="dp")
 
-                jax.jit(shard_map(prog, mesh=mesh, in_specs=P("dp"),
-                                  out_specs=P(None), check_rep=False))(
+                jax.jit(jax.shard_map(prog, mesh=mesh, in_specs=P("dp"),
+                                      out_specs=P(None), check_vma=False))(
                     jnp.ones(8)).block_until_ready()
             finally:
                 C.configure_collective_monitor(None)

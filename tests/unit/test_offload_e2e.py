@@ -64,6 +64,7 @@ def _steps(engine, n=3):
     return losses
 
 
+@pytest.mark.usefixtures("offload_on_device")
 class TestBeyondHBMProof:
     def test_plain_refused_offload_trains_with_parity_and_audit(self, tmp_path):
         # 1) the budget refuses the plain stage-3 step at init
@@ -112,6 +113,7 @@ class TestBeyondHBMProof:
                                    "nvme_path": str(tmp_path / "nvme")})
 
 
+@pytest.mark.usefixtures("offload_on_device")
 class TestOffloadComposesWithCompression:
     """The Frontier-recipe composition: the offload prefetch ring under
     the ZeRO++ wire formats (qwZ quantized gathers, qgZ hierarchical
@@ -141,6 +143,7 @@ class TestOffloadComposesWithCompression:
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+@pytest.mark.usefixtures("offload_on_device")
 class TestRollbackCoherence:
     def test_nvme_tier_resynced_after_checkpoint_load(self, tmp_path):
         """Chunks staged from an abandoned trajectory must never be read

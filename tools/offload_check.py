@@ -1,5 +1,5 @@
 """On-device ZeRO-Offload check: optimizer + param state in pinned host
-memory on a real TPU (exits 0/PASS on TPU, 0/SKIP elsewhere).
+memory on a real TPU (exits 0/PASS on TPU; any other platform is an error).
 
 Proves the ``offload_optimizer``/``offload_param`` path is honored by the
 backend — the round-1 verdict called the blanket-warning version "a claim,
@@ -19,9 +19,8 @@ def main() -> int:
     import numpy as np
 
     if jax.devices()[0].platform != "tpu":
-        print("SKIP: no TPU attached")
-        return 0
-    print("DEVICES_OK", flush=True)   # claim completed (see run_tpu_tool)
+        print(f"FAIL: needs a TPU, found {jax.devices()[0].platform}")
+        return 1
 
     import deepspeed_tpu
     from deepspeed_tpu.models.gpt import GPT, gpt_config
