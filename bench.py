@@ -288,64 +288,6 @@ def bench_decode(dtype=None):
     return rec
 
 
-def _zero3_overlap_fractions():
-    """Overlap fraction of the ZeRO-3 collective schedule, measured
-    through the real telemetry pipeline: a tiny scan GPT runs one traced
-    step with the layered stage-3 step (``overlap_comm`` on) and one with
-    the bulk step, the engine emits its schedule lanes anchored in the
-    measured fwd span, ``telemetry_close`` exports the rank trace, and
-    ``tools/trace_merge.compute_overlap`` reads the fraction back off the
-    merged timeline — the same walkthrough README § "Compute–communication
-    overlap" documents.  Returns {"layered": f, "bulk": f} (None entries
-    when a path yields no trace)."""
-    import tempfile
-
-    import jax
-    import jax.numpy as jnp
-    import deepspeed_tpu
-    from deepspeed_tpu.models.gpt import GPT, GPTConfig
-
-    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-    from tools import trace_merge
-
-    n_dev = jax.device_count()
-    ids = np.random.default_rng(0).integers(
-        0, 128, (n_dev, 32)).astype(np.int32)
-    out = {}
-    # bulk comparator needs an active compressed-collective config (that
-    # is the path that emits the bulk schedule lanes) — qwZ int8 here
-    for key, zero_over in (
-            ("layered", {"overlap_comm": True}),
-            ("bulk", {"overlap_comm": False, "zero_quantized_weights": True})):
-        with tempfile.TemporaryDirectory() as td:
-            model = GPT(GPTConfig(vocab_size=128, n_positions=32, n_embd=64,
-                                  n_layer=4, n_head=4, dtype=jnp.float32,
-                                  attn_impl="reference"))
-            engine, _, _, _ = deepspeed_tpu.initialize(
-                model=model, model_parameters=model.init_params(jax.random.key(0)),
-                config={"train_micro_batch_size_per_gpu": 1,
-                        "optimizer": {"type": "Adam", "params": {"lr": 1e-3}},
-                        "zero_optimization": {"stage": 3, **zero_over},
-                        "steps_per_print": 10 ** 9,
-                        "telemetry": {"enabled": True, "tracing": True,
-                                      "trace_dir": td,
-                                      "watchdog_enabled": False}},
-                seed=7)
-            loss = engine.forward(ids, ids)
-            engine.backward(loss)
-            engine.step()
-            engine.telemetry_close()
-            path = os.path.join(td, "trace_rank0.json")
-            try:
-                merged = trace_merge.merge_traces(
-                    [trace_merge.load_rank_trace(path)])
-                ov = trace_merge.compute_overlap(merged["traceEvents"])
-            except (trace_merge.TraceFormatError, OSError):
-                ov = None
-            out[key] = round(ov["fraction"], 3) if ov else None
-    return out
-
-
 def _train_kernel_truth():
     """Kernel-truth attribution for the train rung: where the step's FLOPs
     and wall-time actually go, measured through the real pipeline rather
@@ -363,9 +305,6 @@ def _train_kernel_truth():
       fwd+bwd+step total (the update's share of the step wall-clock; the
       micro forward/backward/step path is driven so the per-phase spans
       exist — the fused train_batch path is one jitted program).
-    * ``overlap_fraction`` — collective-concurrent-with-compute fraction
-      off the schedule lanes (None when no comm lanes were emitted, e.g.
-      single device).
     """
     import tempfile
 
@@ -408,9 +347,8 @@ def _train_kernel_truth():
             [trace_merge.load_rank_trace(
                 os.path.join(td, "trace_rank0.json"))], flops=flops)
         events = merged["traceEvents"]
-        ov = trace_merge.compute_overlap(events)
 
-        out = {"overlap_fraction": round(ov["fraction"], 3) if ov else None}
+        out = {}
         if flops and flops.get("modules"):
             total = sum(m["flops"] for m in flops["modules"])
 
@@ -461,11 +399,7 @@ def bench_comm():
     reduction — exactly what the comms logger / ``tools/comm_audit.py``
     report in training — with the measured step times alongside (on CPU
     meshes the quantized path is *slower*; the win is wire bytes, which
-    is what an ICI/DCN-bound real topology converts into time).  The
-    ``overlap_fraction`` column is the layered stage-3 schedule's
-    collective-concurrent-with-compute fraction off a traced run
-    (``overlap_fraction_bulk`` is the same readout for the bulk step —
-    expected ~0)."""
+    is what an ICI/DCN-bound real topology converts into time)."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -550,12 +484,6 @@ def bench_comm():
     }
     rec["collective_health"] = _collective_health_block(
         cm.fold_windows([mon.window_view()]), mon)
-    try:
-        fractions = _zero3_overlap_fractions()
-        rec["overlap_fraction"] = fractions["layered"]
-        rec["overlap_fraction_bulk"] = fractions["bulk"]
-    except Exception as e:   # the volume headline must survive a trace miss
-        rec["overlap_error"] = f"{type(e).__name__}: {str(e)[:120]}"
     print(json.dumps(rec))
     return rec
 

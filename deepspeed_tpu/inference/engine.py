@@ -24,7 +24,6 @@ and optionally captures CUDA graphs.  TPU-native redesign:
 import inspect
 import time
 from collections import OrderedDict
-from contextlib import nullcontext
 from typing import Any, Callable, Optional
 
 import jax
@@ -34,7 +33,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
 from deepspeed_tpu.parallel import mesh as mesh_lib
-from deepspeed_tpu.telemetry.tracing import get_global_tracer
+from deepspeed_tpu.telemetry.tracing import maybe_span
 from deepspeed_tpu.utils.logging import log_dist
 
 
@@ -157,8 +156,7 @@ class InferenceEngine:
 
     # ------------------------------------------------------------------ #
     def _span(self, name, **args):
-        tr = self.tracer if self.tracer is not None else get_global_tracer()
-        return tr.span(name, **args) if tr is not None else nullcontext()
+        return maybe_span(name, self.tracer, **args)
 
     def _record_request(self, op, t0, out, new_tokens=0):
         """Per-request telemetry record.  Blocks on the request's own output

@@ -34,7 +34,6 @@ TPU-first redesign:
 
 import os
 import time
-from contextlib import nullcontext
 from functools import partial
 from typing import Any, Callable, Dict, Iterable, Optional, Tuple
 
@@ -52,6 +51,7 @@ from deepspeed_tpu.runtime.lr_schedules import get_lr_schedule
 from deepspeed_tpu.runtime.optimizers import get_optimizer
 from deepspeed_tpu.runtime.stability import init_sentinel_state, sentinel_observe
 from deepspeed_tpu.runtime.zero.policy import ZeroShardingPolicy
+from deepspeed_tpu.telemetry.tracing import maybe_span
 from deepspeed_tpu.testing.fault_injection import fault_point, numeric_fault
 from deepspeed_tpu.utils.logging import log_dist, logger
 from deepspeed_tpu.utils.timer import (BACKWARD_GLOBAL_TIMER, BACKWARD_MICRO_TIMER,
@@ -1796,9 +1796,13 @@ class DeepSpeedEngine:
 
         return acc
 
+    @jax.named_scope("optimizer")
     def _apply_updates(self, params, opt_state, grads, scaler, skipped,
                        momentum_mode=False, sentinel=None, loss=None):
         """One optimizer step: unscale, clip, overflow-gate, update, rescale.
+        Every op of it is traced under the ``optimizer`` scope, so a device
+        trace can give the step's share to this layer whichever program
+        (fused, apply, layered, cc) it was compiled into.
 
         The reference splits this across ``_take_model_step:1924`` and each
         optimizer's ``step``; here it is a single XLA program with donated
@@ -2068,9 +2072,8 @@ class DeepSpeedEngine:
         if self.watchdog is not None:
             self.watchdog.arm(f"fwd step={self.global_steps}")
 
-        fwd_mode = None
         with self._span("fwd", step=self.global_steps,
-                        micro_step=self.micro_steps) as fwd_rec:
+                        micro_step=self.micro_steps):
             if self._in_training_mode:
                 def _dispatch_train():
                     # build-if-needed + run, as ONE unit: when recovery is
@@ -2113,7 +2116,7 @@ class DeepSpeedEngine:
                                 self.state.scaler.scale)
                         self._grads_are_local = False
                         self._append_cc_bytes(reuse=use_reuse, layered=True)
-                        return "layered", loss, grads
+                        return loss, grads
                     if self._cc_active():
                         # ZeRO++ path: explicit (compressed) gather +
                         # hierarchical reduce-scatter programs instead of
@@ -2141,7 +2144,7 @@ class DeepSpeedEngine:
                                 loss, grads = out
                         self._grads_are_local = False
                         self._append_cc_bytes(reuse=use_reuse)
-                        return "bulk", loss, grads
+                        return loss, grads
                     if self._onebit_active():
                         # post-freeze 1-bit path: gradients stay per-device
                         # here and travel compressed at the gas boundary
@@ -2153,16 +2156,16 @@ class DeepSpeedEngine:
                             self.state.params, batch, self._next_rng(),
                             self.state.scaler.scale)
                         self._grads_are_local = True
-                        return None, loss, grads
+                        return loss, grads
                     if self._grad_step is None:
                         self._grad_step = self._build_grad_step()
                     loss, grads = self._grad_step(self.state.params, batch,
                                                   self._next_rng(),
                                                   self.state.scaler.scale)
                     self._grads_are_local = False
-                    return None, loss, grads
+                    return loss, grads
 
-                fwd_mode, loss, grads = self._run_bounded(
+                loss, grads = self._run_bounded(
                     _dispatch_train, op=f"train_step:{self.global_steps}")
                 self._cached_grads = grads
                 self._cached_loss = loss
@@ -2172,21 +2175,6 @@ class DeepSpeedEngine:
                 loss = self._eval_step(self.state.params, batch, self._next_rng())
                 self._cached_loss = loss
 
-        if (fwd_mode is not None and self.tracer is not None
-                and fwd_rec is not None and fwd_rec.get("t1") is not None):
-            # analytic zero3.comm / zero3.compute lanes inside the measured
-            # step window — host spans fire at trace time and cannot see
-            # device concurrency, so the schedule the program structure
-            # admits is emitted explicitly (trace_merge computes the
-            # overlap fraction from these lanes)
-            from deepspeed_tpu.telemetry.tracing import emit_zero3_schedule
-            n = self._cc.get("n_layer") or getattr(
-                getattr(self.module, "cfg", None), "n_layer", None) or 1
-            emit_zero3_schedule(self.tracer, fwd_rec["t0"], fwd_rec["t1"],
-                                n_blocks=n, layered=(fwd_mode == "layered"),
-                                depth=self._cc.get("prefetch_depth", 1),
-                                offload=(fwd_mode == "layered"
-                                         and bool(self._cc.get("offload"))))
         self.timers(FORWARD_MICRO_TIMER).stop(sync=False)
         return loss
 
@@ -2809,11 +2797,9 @@ class DeepSpeedEngine:
         return self._config.monitor_enabled
 
     def _span(self, name, **args):
-        """Tracer span, or inert context when tracing is off (the hot path
-        then takes no tracing branch at all)."""
-        if self.tracer is None:
-            return nullcontext()
-        return self.tracer.span(name, **args)
+        """A span on the profiler's clock, and in this engine's tracer
+        ring where one is configured (``telemetry/tracing.py``)."""
+        return maybe_span(name, self.tracer, **args)
 
     def telemetry_flush(self):
         """Drain buffered telemetry records to all sinks now (one device

@@ -27,7 +27,6 @@ future work and is rejected at ``submit()``.
 """
 
 import time
-from contextlib import nullcontext
 from typing import Any, Dict, List, Optional
 
 import numpy as np
@@ -44,10 +43,31 @@ from deepspeed_tpu.serving.scheduler import (DECODE, EXPIRED, FINISHED,
                                              AdmissionController,
                                              DeadlineExceeded, Request,
                                              ServingScheduler, ShedError)
-from deepspeed_tpu.telemetry.tracing import get_global_tracer
+from deepspeed_tpu.telemetry.tracing import maybe_span
 from deepspeed_tpu.testing.fault_injection import (FaultInjected, fault_point,
                                                    release_wedges)
 from deepspeed_tpu.utils.logging import log_dist
+
+
+#: The leaf spans that tile ``ServingEngine.step()``, in the order the work
+#: happens (PERF.md § 3 copies this list).  On the profiler's line they are
+#: SIBLINGS under the caller's own span, with no ``serve.step`` round them:
+#: an idle gap of the device is then named by the phase the host was in.
+#: ``submit()`` is ``serve.submit``; a request's first token leaves one
+#: zero-length ``serve.first_token`` with its waits as stats.
+SERVE_STEP_SPANS = (
+    "serve.admit",              # deadlines, shed ladder, sched.admit
+    "serve.prefill.build",      # next_prefill, ids, block table, write map
+    "serve.prefill.dispatch",   # uploads and the call of the compiled step
+    "serve.prefill.fetch",      # the chunk's token row back on the host
+    "serve.prefill.commit",     # prefilled, prefix insert, first token
+    "serve.grow",               # sort, ensure_capacity, decode_batch
+    "serve.decode.build",
+    "serve.decode.dispatch",
+    "serve.decode.fetch",
+    "serve.decode.commit",
+    "serve.stats",              # ledger, stats dict, gauges, emit
+)
 
 
 class ServeStepTimeout(RuntimeError):
@@ -323,8 +343,7 @@ class ServingEngine:
 
     # ------------------------------------------------------------------ #
     def _span(self, name, **args):
-        tr = self.tracer if self.tracer is not None else get_global_tracer()
-        return tr.span(name, **args) if tr is not None else nullcontext()
+        return maybe_span(name, self.tracer, **args)
 
     def _emit(self, kind, payload, step=None):
         if (self.ledger is not None and kind == "kv_restage"
@@ -408,28 +427,41 @@ class ServingEngine:
             }, step=self.step_count)
 
     # ---- bounded dispatch + incident recovery -------------------------- #
-    def _dispatch(self, phase: str, *args):
-        """Run one compiled step under the ``serve_step_timeout_s``
-        deadline (inline when unbounded).  The host materialization of the
+    def _dispatch(self, phase: str, inputs, stats):
+        """Run one compiled step over the host-built ``inputs`` (ids,
+        positions, tables, write blocks, write offsets) under the
+        ``serve_step_timeout_s`` deadline (inline when unbounded), and
+        return its token row on the host.  The host materialization of the
         token row happens *inside* the bounded callable — that device sync
-        is exactly where a wedged program parks the thread.  The first
-        dispatch of each phase (and the first after an incident re-jit)
-        runs inline: it compiles, and compile time is not a wedge."""
+        is exactly where a wedged program parks the thread — so the
+        ``dispatch`` and ``fetch`` spans go to the worker thread with it.
+        The first dispatch of each phase (and the first after an incident
+        re-jit) runs inline: it compiles, and compile time is not a wedge."""
+        import jax.numpy as jnp
+        ids, positions, tables, wb, wo = inputs
+
         def work():
-            fault_point("serve.step", step=self.step_count, phase=phase)
-            tokens, kp, vp = self._step_fn(self.params, *args)
-            return np.asarray(tokens), kp, vp
+            with self._span(f"serve.{phase}.dispatch", **stats):
+                fault_point("serve.step", step=self.step_count, phase=phase)
+                tokens, kp, vp = self._step_fn(
+                    self.params, jnp.asarray(ids), jnp.asarray(positions),
+                    self._k_pages, self._v_pages, jnp.asarray(tables),
+                    jnp.asarray(wb), jnp.asarray(wo))
+            with self._span(f"serve.{phase}.fetch", **stats):
+                return np.asarray(tokens), kp, vp
         if self._bounded is None or phase not in self._warm_phases:
             out = work()
             self._warm_phases.add(phase)
-            return out
-        try:
-            return self._bounded.run(work, op=phase, noun="serve step")
-        except CollectiveTimeout as e:
-            raise ServeStepTimeout(
-                f"serve {phase} step {self.step_count} exceeded its "
-                f"{e.deadline_s:.3f}s deadline", op=phase,
-                deadline_s=e.deadline_s, step=self.step_count) from e
+        else:
+            try:
+                out = self._bounded.run(work, op=phase, noun="serve step")
+            except CollectiveTimeout as e:
+                raise ServeStepTimeout(
+                    f"serve {phase} step {self.step_count} exceeded its "
+                    f"{e.deadline_s:.3f}s deadline", op=phase,
+                    deadline_s=e.deadline_s, step=self.step_count) from e
+        tokens, self._k_pages, self._v_pages = out
+        return tokens
 
     def _recover_incident(self, err: ServeStepTimeout):
         """In-process recovery from a wedged compiled step: drop the
@@ -512,56 +544,57 @@ class ServingEngine:
     def submit(self, prompt, max_new_tokens: Optional[int] = None,
                slo: str = "standard", temperature: float = 0.0) -> ServeFuture:
         """Queue one request; returns a :class:`ServeFuture`."""
-        if temperature:
-            raise NotImplementedError(
-                "serving is greedy-only in this PR (temperature=0)")
-        if slo not in SLO_PRIORITY:
-            raise ValueError(
-                f"unknown slo class {slo!r}; expected one of "
-                f"{sorted(SLO_PRIORITY)} (a typo here would otherwise "
-                "silently demote the request to 'standard')")
-        cfg, mcfg = self._config, self.module.cfg
-        if not self.admission.admit_ok(slo):
-            self._emit("serve_shed", {
-                "event": "rejected", "slo": slo,
-                "level": self.admission.level,
-                "level_name": self.admission.level_name,
+        with self._span("serve.submit"):
+            if temperature:
+                raise NotImplementedError(
+                    "serving is greedy-only in this PR (temperature=0)")
+            if slo not in SLO_PRIORITY:
+                raise ValueError(
+                    f"unknown slo class {slo!r}; expected one of "
+                    f"{sorted(SLO_PRIORITY)} (a typo here would otherwise "
+                    "silently demote the request to 'standard')")
+            cfg, mcfg = self._config, self.module.cfg
+            if not self.admission.admit_ok(slo):
+                self._emit("serve_shed", {
+                    "event": "rejected", "slo": slo,
+                    "level": self.admission.level,
+                    "level_name": self.admission.level_name,
+                    "queue_depth": len(self.sched.waiting),
+                }, step=self.step_count)
+                raise ShedError(
+                    f"admission ladder at {self.admission.level_name!r} is "
+                    f"shedding {slo!r}-class requests (retry later or raise "
+                    "the class)", slo=slo, level=self.admission.level)
+            prompt = [int(t) for t in np.asarray(prompt).reshape(-1)]
+            assert prompt, "empty prompt"
+            mnt = int(max_new_tokens or cfg.max_new_tokens_default)
+            # brownout rung: degrade before rejecting
+            mnt = self.admission.cap_new_tokens(mnt)
+            total = len(prompt) + mnt
+            if total > mcfg.n_positions:
+                raise ValueError(f"prompt+max_new_tokens {total} exceeds "
+                                 f"n_positions {mcfg.n_positions}")
+            if self.alloc.blocks_for_tokens(total) > min(
+                    cfg.num_blocks - 1, self.max_blocks_per_seq):
+                raise ArenaExhausted(
+                    f"request needs {self.alloc.blocks_for_tokens(total)} "
+                    f"blocks; arena ceiling is "
+                    f"{min(cfg.num_blocks - 1, self.max_blocks_per_seq)}")
+            self._rid_counter += 1
+            req = Request(rid=self._rid_counter, prompt=prompt,
+                          max_new_tokens=mnt, slo=slo, arrival=self._clock())
+            dl = float((cfg.deadline_ms or {}).get(slo, 0.0) or 0.0)
+            if dl > 0.0:
+                req.deadline_at = req.arrival + dl / 1e3
+            self.sched.submit(req)
+            fut = ServeFuture(self, req)
+            self._futures[req.rid] = fut
+            self._emit("serve_request", {
+                "event": "submitted", "rid": req.rid, "slo": slo,
+                "prompt_tokens": len(prompt), "max_new_tokens": mnt,
                 "queue_depth": len(self.sched.waiting),
             }, step=self.step_count)
-            raise ShedError(
-                f"admission ladder at {self.admission.level_name!r} is "
-                f"shedding {slo!r}-class requests (retry later or raise "
-                "the class)", slo=slo, level=self.admission.level)
-        prompt = [int(t) for t in np.asarray(prompt).reshape(-1)]
-        assert prompt, "empty prompt"
-        mnt = int(max_new_tokens or cfg.max_new_tokens_default)
-        # brownout rung: degrade before rejecting
-        mnt = self.admission.cap_new_tokens(mnt)
-        total = len(prompt) + mnt
-        if total > mcfg.n_positions:
-            raise ValueError(f"prompt+max_new_tokens {total} exceeds "
-                             f"n_positions {mcfg.n_positions}")
-        if self.alloc.blocks_for_tokens(total) > min(
-                cfg.num_blocks - 1, self.max_blocks_per_seq):
-            raise ArenaExhausted(
-                f"request needs {self.alloc.blocks_for_tokens(total)} blocks; "
-                f"arena ceiling is "
-                f"{min(cfg.num_blocks - 1, self.max_blocks_per_seq)}")
-        self._rid_counter += 1
-        req = Request(rid=self._rid_counter, prompt=prompt,
-                      max_new_tokens=mnt, slo=slo, arrival=self._clock())
-        dl = float((cfg.deadline_ms or {}).get(slo, 0.0) or 0.0)
-        if dl > 0.0:
-            req.deadline_at = req.arrival + dl / 1e3
-        self.sched.submit(req)
-        fut = ServeFuture(self, req)
-        self._futures[req.rid] = fut
-        self._emit("serve_request", {
-            "event": "submitted", "rid": req.rid, "slo": slo,
-            "prompt_tokens": len(prompt), "max_new_tokens": mnt,
-            "queue_depth": len(self.sched.waiting),
-        }, step=self.step_count)
-        return fut
+            return fut
 
     # ------------------------------------------------------------------ #
     def step(self) -> Dict[str, Any]:
@@ -570,20 +603,26 @@ class ServingEngine:
         decode-ready sequence.  Returns the step stats.  A wedged compiled
         dispatch raises :class:`ServeStepTimeout` *after* in-process
         recovery (see :meth:`_recover_incident`)."""
-        self._expire_deadlines()
-        self._update_admission()
-        self.sched.admit()
+        with self._span("serve.admit") as sp:
+            self._expire_deadlines()
+            self._update_admission()
+            sp.set(admitted=len(self.sched.admit(self._clock())))
         prefill_tokens = 0
         t_step = time.monotonic() if self.registry is not None else 0.0
         try:
-            with self._span("serve.step", step=self.step_count):
+            with self._span("serve.prefill.build") as sp:
                 pf = self.sched.next_prefill()
                 if pf is not None:
                     req, start, n = pf
-                    with self._span("serve.prefill", rid=req.rid, start=start,
-                                    tokens=n):
-                        self._run_prefill(req, start, n)
-                    prefill_tokens = n
+                    at = {"rid": req.rid, "start": start, "tokens": n}
+                    sp.set(**at)
+                    inputs = self._prefill_inputs(req, start, n)
+            if pf is not None:
+                tokens = self._dispatch("prefill", inputs, at)
+                with self._span("serve.prefill.commit", **at):
+                    self._commit_prefill(req, n, tokens)
+                prefill_tokens = n
+            with self._span("serve.grow") as sp:
                 # growth pass, oldest/strongest first: each decode step
                 # writes one token per sequence, so capacity must exist
                 # before the batch is built; eviction here removes victims
@@ -594,17 +633,30 @@ class ServingEngine:
                     if r.state == DECODE:      # not evicted by an earlier r
                         self.sched.ensure_capacity(r, r.prefilled + 1)
                 decode = self.sched.decode_batch()
-                if decode:
-                    t_dec = (time.monotonic() if self.registry is not None
-                             else 0.0)
-                    with self._span("serve.decode", batch=len(decode)):
-                        self._run_decode(decode)
-                    if self.registry is not None:
-                        self._h_decode.observe(
-                            (time.monotonic() - t_dec) * 1e3)
+                sp.set(batch=len(decode))
+            if decode:
+                t_dec = (time.monotonic() if self.registry is not None
+                         else 0.0)
+                at = {"batch": len(decode)}
+                with self._span("serve.decode.build", **at):
+                    inputs = self._decode_inputs(decode)
+                tokens = self._dispatch("decode", inputs, at)
+                with self._span("serve.decode.commit", **at):
+                    for r in decode:
+                        r.prefilled += 1      # the fed token's KV is resident
+                        self._append_token(r, int(tokens[r.slot, 0]))
+                if self.registry is not None:
+                    self._h_decode.observe((time.monotonic() - t_dec) * 1e3)
         except ServeStepTimeout as err:
             self._recover_incident(err)
             raise
+        with self._span("serve.stats"):
+            return self._close_step(len(decode), prefill_tokens, t_step)
+
+    def _close_step(self, decode_batch: int, prefill_tokens: int,
+                    t_step: float) -> Dict[str, Any]:
+        """What a clean step ends with: the incident latch, the ledger, the
+        stats dict, gauges and the periodic ``serve_step`` record."""
         if self._incident is not None:
             # first clean step after an incident: release the latch
             self._emit("serve_incident", {
@@ -617,7 +669,7 @@ class ServingEngine:
             self.ledger.on_step(self.step_count,
                                 offload_wait_s=self._restage_wait_ms / 1e3)
             self._restage_wait_ms = 0.0
-        stats = dict(self.sched.stats(), decode_batch=len(decode),
+        stats = dict(self.sched.stats(), decode_batch=decode_batch,
                      prefill_tokens=prefill_tokens,
                      tokens_generated=self.tokens_generated,
                      shed_level=self.admission.level,
@@ -748,20 +800,20 @@ class ServingEngine:
         return futures
 
     # ------------------------------------------------------------------ #
-    def _run_prefill(self, req: Request, start: int, n: int):
-        import jax.numpy as jnp
+    def _prefill_inputs(self, req: Request, start: int, n: int):
+        """Host arrays of one prompt chunk; stamps the residency's first."""
+        if req.prefill_started_at is None:
+            req.prefill_started_at = self._clock()
+        req.prefill_chunks += 1
         C = self._config.prefill_chunk
-        MB = self.max_blocks_per_seq
-        ctx = req.context
         ids = np.zeros((1, C), np.int32)
-        ids[0, :n] = ctx[start:start + n]
+        ids[0, :n] = req.context[start:start + n]
         positions = np.asarray([start], np.int32)
         tables = self.alloc.block_table(req.rid)[None]           # [1, MB]
         wb, wo = self.alloc.write_map(req.rid, start, C, n_valid=n)
-        tokens, self._k_pages, self._v_pages = self._dispatch(
-            "prefill", jnp.asarray(ids), jnp.asarray(positions),
-            self._k_pages, self._v_pages, jnp.asarray(tables),
-            jnp.asarray(wb[None]), jnp.asarray(wo[None]))
+        return ids, positions, tables, wb[None], wo[None]
+
+    def _commit_prefill(self, req: Request, n: int, tokens):
         req.prefilled += n
         if req.prefilled >= req.prefill_len:
             if self.prefix is not None and not self.admission.brownout:
@@ -776,8 +828,8 @@ class ServingEngine:
             req.state = DECODE
             self._append_token(req, int(tokens[0, n - 1]))
 
-    def _run_decode(self, reqs: List[Request]):
-        import jax.numpy as jnp
+    def _decode_inputs(self, reqs: List[Request]):
+        """Host arrays of one decode step over every slot."""
         B = self._config.max_batch_size
         MB = self.max_blocks_per_seq
         ids = np.zeros((B, 1), np.int32)
@@ -791,19 +843,22 @@ class ServingEngine:
             positions[s] = r.prefilled
             tables[s] = self.alloc.block_table(r.rid)
             wb[s], wo[s] = self.alloc.write_map(r.rid, r.prefilled, 1)
-        tokens, self._k_pages, self._v_pages = self._dispatch(
-            "decode", jnp.asarray(ids), jnp.asarray(positions),
-            self._k_pages, self._v_pages, jnp.asarray(tables),
-            jnp.asarray(wb), jnp.asarray(wo))
-        for r in reqs:
-            r.prefilled += 1          # the fed token's KV is now resident
-            self._append_token(r, int(tokens[r.slot, 0]))
+        return ids, positions, tables, wb, wo
 
     def _append_token(self, req: Request, tok: int):
         req.generated.append(tok)
         self.tokens_generated += 1
         if req.first_token_at is None:
             req.first_token_at = self._clock()
+            # the program's own TTFT, split where it was spent; the three
+            # waits sum to first_token_at - arrival
+            ms = lambda a, b: (b - a) * 1e3
+            with self._span(
+                    "serve.first_token", rid=req.rid, chunks=req.prefill_chunks,
+                    queue_ms=ms(req.arrival, req.admitted_at),
+                    lane_wait_ms=ms(req.admitted_at, req.prefill_started_at),
+                    prefill_ms=ms(req.prefill_started_at, req.first_token_at)):
+                pass
         if req.done(self._config.eos_token_id):
             req.finished_at = self._clock()
             self.sched.finish(req)

@@ -170,6 +170,11 @@ class Request:
     spilled_tokens: int = 0            # context tokens the spill covers
     spills: int = 0
     restages: int = 0
+    # host clock, stamped where it happens; the two residency stamps are
+    # taken anew when a preempted request is admitted again
+    admitted_at: Optional[float] = None         # given a slot (admit)
+    prefill_started_at: Optional[float] = None  # first prompt chunk dispatched
+    prefill_chunks: int = 0                     # chunks of this residency
     first_token_at: Optional[float] = None
     finished_at: Optional[float] = None
     deadline_at: Optional[float] = None   # host clock; None = no deadline
@@ -256,9 +261,11 @@ class ServingScheduler:
         return best
 
     # ---- admission -------------------------------------------------------- #
-    def admit(self) -> List[Request]:
+    def admit(self, now: Optional[float] = None) -> List[Request]:
         """Fill free decode slots from the waiting queue.  Returns the
-        newly admitted requests (their prefill starts next step)."""
+        newly admitted requests (their prefill starts next step).  ``now``
+        is the engine's clock, as ``Request.arrival`` is: it stamps
+        ``admitted_at``."""
         admitted = []
         while self._free_slots:
             req = self._pop_best_waiting()
@@ -292,6 +299,8 @@ class ServingScheduler:
                 return admitted
             req.slot = self._free_slots.pop()
             req.admit_seq = next(self._admit_counter)
+            req.admitted_at, req.prefill_started_at = now, None
+            req.prefill_chunks = 0
             req.prefill_len = target
             if req.spilled:
                 self._resume_from_spill(req)

@@ -1,29 +1,44 @@
-"""Span-based distributed tracing — the "where inside a step" layer.
+"""Span-based tracing — the "where inside a step" layer.
 
 The :class:`~deepspeed_tpu.telemetry.hub.TelemetryHub` answers *how fast*
-a step was; the :class:`Tracer` answers *where inside the step the time
-went*.  Engines and the comm facade open nested spans around phases
-(``fwd``/``bwd``/``step``), collectives (``comm.all_reduce``), pipeline
-schedule slots, inference prefill/decode, and checkpoint save/load.
+a step was; spans answer *where inside the step the time went*.  Engines
+and the comm facade open spans around phases (``fwd``/``bwd``/``step``,
+the ``serve.*`` phases of a serving step), collectives
+(``comm.all_reduce``), pipeline schedule slots, inference prefill/decode,
+and checkpoint save/load.
+
+One clock, one switch.  Every span is a ``jax.profiler.TraceAnnotation``:
+whenever a profiler session is active (a ``ProfilerWindow`` capture, the
+benchmark's ``--trace 1``) it lies in the same ``.xplane.pb`` as the
+device ops, on the device's clock, with its scalar attributes as the
+event's stats; without a session it is an inactive TraceMe (well under a
+microsecond).  Nothing configures that.  Where a :class:`Tracer` is
+configured (``telemetry.tracing`` / the watchdog) the same span is ALSO
+recorded into its ring, with parent ids, for the operator's Chrome export,
+the watchdog's heartbeat and the flight recorder.
 
 Design constraints (shared with the hub):
 
-* **Zero-sync.**  Opening/closing a span is two ``time.monotonic_ns``
-  reads and a list append.  Attribute values are stored by reference —
-  a still-in-flight ``jax.Array`` attr is never forced until export (and
-  the flight recorder deliberately never forces it at all: forcing blocks
-  during the very hangs it exists to diagnose).
-* **Monotonic clock only for durations.**  Wall-clock time appears in
+* **Zero-sync.**  Opening/closing a span is an annotation, and with a
+  ring two ``time.monotonic_ns`` reads and a list append.  Ring attribute
+  values are stored by reference — a still-in-flight ``jax.Array`` attr is
+  never forced until export (and the flight recorder deliberately never
+  forces it at all: forcing blocks during the very hangs it exists to
+  diagnose).  Only ``bool``/``int``/``float``/``str`` attributes go to the
+  profiler; anything else stays in the ring alone.
+* **Spans never touch the program.**  A span is host-side only: it enters
+  no ``jax.named_scope``, so a step traced under ``fwd`` or
+  ``train_batch`` lowers to the same HLO, metadata included, with tracing
+  on or off.  Op ownership comes from the scopes the model and the engine
+  open themselves (``attn``, ``mlp``, ``optimizer`` ...).
+* **Monotonic clock only for ring durations.**  Wall-clock time appears in
   exactly one place — the per-tracer clock anchor used by
   ``tools/trace_merge.py`` to align rank timelines — and is statically
   policed by ``tools/check_monotonic.py``.
-* **Double-duty annotation.**  ``span()`` also enters ``jax.named_scope``
-  so that spans opened around traced code show up in XLA profiles
-  (``ProfilerWindow`` captures) under the same names.
 * **Bounded memory.**  Completed spans live in a ring (``capacity``);
   overflow increments ``dropped`` instead of growing without bound.
 
-Export is Chrome-trace / Perfetto JSON (``traceEvents`` with complete
+Ring export is Chrome-trace / Perfetto JSON (``traceEvents`` with complete
 ``X`` duration events), one file per rank; ``tools/trace_merge.py`` folds
 N rank files onto one clock-aligned timeline.
 """
@@ -31,29 +46,53 @@ N rank files onto one clock-aligned timeline.
 import itertools
 import json
 import os
-import re
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager, nullcontext
 from typing import Any, Callable, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from deepspeed_tpu.utils.logging import logger
 
 #: the only clock spans are timed with (see tools/check_monotonic.py)
 _mono_ns = time.monotonic_ns
 
-_SCOPE_SANITIZE = re.compile(r"[^A-Za-z0-9_.-]")
+#: attribute types that go to the profiler as an event's stats; anything
+#: else (a ``jax.Array`` above all) is never looked at, let alone forced
+_SCALARS = (bool, int, float, str)
 
 
-def _named_scope(name: str):
-    """``jax.named_scope`` with a sanitized name; inert if jax is absent
-    or rejects the name (tracing must never be a reason to crash)."""
-    try:
-        import jax
-        return jax.named_scope(_SCOPE_SANITIZE.sub("_", name) or "span")
-    except Exception:
-        return nullcontext()
+class Span:
+    """One program span: a ``TraceAnnotation`` always, and a record in
+    ``tracer``'s ring where there is one.  ``set()`` adds attributes known
+    only once the work is done (a count of what was admitted)."""
+
+    __slots__ = ("_ann", "_tracer", "_name", "_args", "_rec")
+
+    def __init__(self, tracer: Optional["Tracer"], name: str,
+                 args: Dict[str, Any]):
+        self._ann = TraceAnnotation(name, **{
+            k: v for k, v in args.items() if isinstance(v, _SCALARS)})
+        self._tracer = tracer if tracer is not None and not tracer.closed else None
+        self._name, self._args, self._rec = name, args, None
+
+    def __enter__(self):
+        self._ann.__enter__()
+        if self._tracer is not None:
+            self._rec = self._tracer._open_span(self._name, self._args)
+        return self
+
+    def __exit__(self, *exc):
+        if self._rec is not None:
+            self._tracer._close_span(self._rec)
+        self._ann.__exit__(*exc)
+        return False
+
+    def set(self, **args):
+        self._ann.set_metadata(**args)
+        if self._rec is not None:
+            self._rec["args"] = dict(self._rec["args"] or {}, **args)
 
 
 class Tracer:
@@ -67,13 +106,11 @@ class Tracer:
 
     def __init__(self, rank: int = 0, capacity: int = 65536,
                  clock: Optional[Callable[[], int]] = None,
-                 heartbeat: Optional[Callable[[], None]] = None,
-                 use_named_scope: bool = True):
+                 heartbeat: Optional[Callable[[], None]] = None):
         self.rank = int(rank)
         self.capacity = max(1, int(capacity))
         self._clock = clock or _mono_ns
         self.heartbeat = heartbeat
-        self.use_named_scope = use_named_scope
         self.completed = deque(maxlen=self.capacity)
         self.dropped = 0
         self._ids = itertools.count(1)
@@ -97,14 +134,12 @@ class Tracer:
             self.dropped += 1
         self.completed.append(rec)
 
-    @contextmanager
-    def span(self, name: str, **args):
-        """Open a nested span; attributes are stored by reference (never
-        forced here).  Also enters ``jax.named_scope(name)`` so traced
-        code inside the span is annotated in XLA profiles."""
-        if self.closed:
-            yield
-            return
+    def span(self, name: str, **args) -> Span:
+        """Open a nested span (a context manager); attributes are stored
+        by reference (never forced here)."""
+        return Span(self, name, args)
+
+    def _open_span(self, name: str, args: Dict[str, Any]) -> Dict[str, Any]:
         if self.heartbeat is not None:
             self.heartbeat()
         stack = self._stack()
@@ -119,20 +154,19 @@ class Tracer:
             "args": args or None,
         }
         stack.append(rec)
-        scope = _named_scope(name) if self.use_named_scope else nullcontext()
-        try:
-            with scope:
-                yield rec
-        finally:
-            rec["t1"] = self._clock()
-            if stack and stack[-1] is rec:
-                stack.pop()
-            else:  # defensive: unbalanced exit from another thread/path
-                try:
-                    stack.remove(rec)
-                except ValueError:
-                    pass
-            self._append(rec)
+        return rec
+
+    def _close_span(self, rec: Dict[str, Any]):
+        rec["t1"] = self._clock()
+        stack = self._stack()
+        if stack and stack[-1] is rec:
+            stack.pop()
+        else:  # defensive: unbalanced exit from another thread/path
+            try:
+                stack.remove(rec)
+            except ValueError:
+                pass
+        self._append(rec)
 
     def instant(self, name: str, **args):
         """Zero-duration marker (Chrome ``ph: "i"``) — e.g. a collective
@@ -278,64 +312,8 @@ def get_global_tracer() -> Optional[Tracer]:
     return _GLOBAL_TRACER
 
 
-def maybe_span(name: str, **args):
-    """A span on the global tracer, or an inert context when tracing is
-    off — the one-liner instrumentation points use."""
-    t = _GLOBAL_TRACER
-    return t.span(name, **args) if t is not None else nullcontext()
-
-
-# --------------------------------------------------------------------------- #
-# ZeRO-3 schedule lanes — the compute/communication overlap record
-# --------------------------------------------------------------------------- #
-def emit_zero3_schedule(tracer: Tracer, t0_ns: int, t1_ns: int,
-                        n_blocks: int, layered: bool, depth: int = 1,
-                        offload: bool = False):
-    """Emit synthetic ``zero3.comm`` / ``zero3.compute`` lanes describing
-    the stage-3 step's dependence structure inside the measured fwd window.
-
-    Host-side spans fire at TRACE time (they nest inside the fwd span and
-    observe no device concurrency), so real gather/compute simultaneity is
-    invisible to the tracer.  What IS knowable host-side is the schedule
-    the program structure admits — the same convention the pipeline
-    schedule-slot lanes use.  The bulk step's all-gather strictly precedes
-    the first block and its reduce-scatter strictly follows the last
-    (overlap fraction ~0); the layered step issues block *i+depth*'s
-    gather alongside block *i*'s compute and block *i*'s reduce-scatter
-    alongside the backward of block *i+1* (overlap fraction L/(L+2)).
-
-    ``tools/trace_merge.py`` computes the overlap fraction from these
-    lanes via interval intersection on ``args.kind``.
-    """
-    L = max(1, int(n_blocks))
-    span = max(1, int(t1_ns) - int(t0_ns))
-    slots = L + 2
-    dt = span / slots
-
-    def at(i):
-        return int(t0_ns + i * dt)
-
-    if layered:
-        for i in range(L):
-            if offload:
-                # the host→HBM stage of slice i rides the same ring slot
-                # as its gather (it feeds the gather's wire bytes), hidden
-                # under block i-depth's compute like the collective
-                tracer.add_span("offload.stage", at(i), at(i + 1),
-                                track="offload.stage", kind="comm",
-                                block=i, depth=depth)
-            tracer.add_span("zero3.gather", at(i), at(i + 1),
-                            track="zero3.comm", kind="comm", block=i,
-                            depth=depth)
-            tracer.add_span("zero3.block", at(i + 1), at(i + 2),
-                            track="zero3.compute", kind="compute", block=i)
-            tracer.add_span("zero3.reduce_scatter", at(i + 2), at(i + 3),
-                            track="zero3.comm", kind="comm", block=i)
-    else:
-        tracer.add_span("zero3.all_gather", at(0), at(1),
-                        track="zero3.comm", kind="comm")
-        for i in range(L):
-            tracer.add_span("zero3.block", at(i + 1), at(i + 2),
-                            track="zero3.compute", kind="compute", block=i)
-        tracer.add_span("zero3.reduce_scatter", at(L + 1), at(L + 2),
-                        track="zero3.comm", kind="comm")
+def maybe_span(name: str, tracer: Optional[Tracer] = None, **args) -> Span:
+    """The one entry instrumentation points use: a span on the profiler's
+    clock, recorded too in ``tracer``'s ring (or the global tracer's)
+    where one is configured."""
+    return Span(tracer if tracer is not None else _GLOBAL_TRACER, name, args)

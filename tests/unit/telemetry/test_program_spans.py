@@ -1,0 +1,278 @@
+"""The program's spans on the profiler's clock (``telemetry/tracing.py``): a
+span is a ``jax.profiler.TraceAnnotation`` whether or not a ``Tracer`` is
+configured, never touches the traced program, and the serving engine tiles
+its step with them and stamps a request where things happen."""
+
+import glob
+import os
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deepspeed_tpu.models.gpt import GPT, GPTConfig
+from deepspeed_tpu.serving import DeepSpeedServingConfig, ServingEngine
+from deepspeed_tpu.serving.engine import SERVE_STEP_SPANS
+from deepspeed_tpu.telemetry import Tracer, maybe_span, set_global_tracer
+
+
+def _capture(tmp_path, body):
+    """Run ``body`` under a profiler session; -> the host events by name."""
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level, opts.host_tracer_level = 0, 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(os.path.join(str(tmp_path), "plugins/profile/*/*.xplane.pb"))[-1]
+    host = ProfileData.from_file(path).find_plane_with_name("/host:CPU")
+    return {e.name: (e.duration_ns, dict(e.stats))
+            for line in host.lines for e in line.events}
+
+
+def _open_and_close(span):
+    with span:
+        pass
+
+
+# ---- the span itself --------------------------------------------------------- #
+def test_span_is_in_a_profiler_capture_with_its_scalar_stats(tmp_path):
+    device_value = jnp.ones((4,))
+
+    def body():
+        with maybe_span("serve.decode.build", batch=7, rate=0.5, slo="standard",
+                        loss=device_value) as sp:
+            time.sleep(0.001)
+            sp.set(admitted=3)
+    events = _capture(tmp_path, body)
+    duration_ns, stats = events["serve.decode.build"]     # the name is kept clean
+    assert duration_ns >= 1e6
+    assert stats == {"batch": 7, "rate": 0.5, "slo": "standard", "admitted": 3}
+
+
+def test_span_without_a_session_or_a_tracer_is_inert():
+    set_global_tracer(None)
+    with maybe_span("serve.admit", rid=1) as sp:
+        sp.set(admitted=0)                    # nothing to write to: no error
+
+
+def test_array_attribute_is_never_forced_and_stays_in_the_ring_only(tmp_path):
+    class Exploding:
+        def __array__(self, *a, **k):
+            raise AssertionError("forced")
+        __float__ = __int__ = __str__ = __repr__ = __array__
+    tr = Tracer()
+    bomb = Exploding()
+    events = _capture(tmp_path, lambda: _open_and_close(tr.span("fwd", step=3, loss=bomb)))
+    assert events["fwd"][1] == {"step": 3}
+    assert tr.snapshot()[-1]["args"]["loss"] is bomb
+
+
+def test_one_entry_records_on_the_profiler_and_in_the_ring(tmp_path):
+    tr = Tracer()
+
+    def body():
+        with maybe_span("train_batch", tr, step=1):
+            with maybe_span("serve.admit", tr) as sp:
+                sp.set(admitted=2)
+    events = _capture(tmp_path, body)
+    assert {"train_batch", "serve.admit"} <= set(events)
+    inner, outer = tr.snapshot()
+    assert inner["name"] == "serve.admit" and inner["parent"] == outer["sid"]
+    assert inner["args"] == {"admitted": 2} and outer["args"] == {"step": 1}
+
+
+def test_a_closed_tracer_still_leaves_the_annotation(tmp_path):
+    tr = Tracer()
+    tr.close()
+    events = _capture(tmp_path, lambda: _open_and_close(tr.span("late")))
+    assert "late" in events and tr.snapshot() == []
+
+
+# ---- spans never touch the program ------------------------------------------ #
+def _lowered(tracer):
+    def step(x, w):
+        with jax.named_scope("mlp"):
+            return jnp.tanh(x @ w).sum()
+    with maybe_span("train_batch", tracer, step=0):
+        with maybe_span("fwd", tracer):
+            return jax.jit(jax.grad(step)).lower(
+                jnp.ones((4, 8)), jnp.ones((8, 8))).as_text(debug_info=True)
+
+
+def test_lowered_program_is_identical_with_and_without_a_tracer():
+    # from one line: the text records the call stack's line numbers
+    plain, traced = [_lowered(tracer) for tracer in (None, Tracer())]
+    assert traced == plain
+    assert "mlp" in plain and "train_batch" not in plain and "fwd/" not in plain
+
+
+def test_engine_step_lowers_identically_with_tracing_configured(tmp_path):
+    """The fused train step of a tiny engine, lowered under the engine's own
+    spans: byte-identical whether ``telemetry.tracing`` built a Tracer or not,
+    and every op of ``_apply_updates`` is under the ``optimizer`` scope."""
+    import deepspeed_tpu
+    from deepspeed_tpu.parallel import mesh as mesh_lib
+
+    def lowered(telemetry):
+        model = GPT(GPTConfig(vocab_size=64, n_positions=16, n_embd=16, n_layer=1,
+                              n_head=2, dtype="float32"))
+        config = {"train_micro_batch_size_per_gpu": 1,
+                  "optimizer": {"type": "Adam", "params": {"lr": 1e-3}},
+                  "steps_per_print": 10 ** 9}
+        if telemetry:
+            config["telemetry"] = {"enabled": True, "tracing": True,
+                                   "trace_dir": str(tmp_path),
+                                   "watchdog_enabled": False}
+        engine, _, _, _ = deepspeed_tpu.initialize(
+            model=model, model_parameters=model.init_params(jax.random.PRNGKey(0)),
+            config=config, seed=3)
+        assert (engine.tracer is not None) == telemetry
+        ids = np.zeros((1, jax.device_count(), 16), np.int32)
+        st = engine.state
+        carry = (st.params, st.opt_state, st.scaler, st.skipped)
+        with engine._span("train_batch", step=0):
+            text = engine._build_fused_step().lower(
+                carry, (ids, ids), jax.random.PRNGKey(0)).as_text(debug_info=True)
+        engine.close()
+        mesh_lib.reset_mesh()
+        return text
+
+    plain, traced = [lowered(telemetry) for telemetry in (False, True)]
+    assert traced == plain
+    assert "/optimizer/" in plain and "train_batch" not in plain
+
+
+# ---- the serving engine's spans and stamps ----------------------------------- #
+@pytest.fixture(scope="module")
+def tiny_model():
+    model = GPT(GPTConfig(vocab_size=128, n_positions=128, n_embd=32, n_layer=2,
+                          n_head=4, dtype="float32"))
+    return model, model.init_params(jax.random.PRNGKey(0))
+
+
+def _engine(tiny_model, tracer=None, **over):
+    model, params = tiny_model
+    cfg = dict(block_size=8, num_blocks=64, max_batch_size=4, prefill_chunk=8,
+               dtype="float32")
+    cfg.update(over)
+    return ServingEngine(model, config=DeepSpeedServingConfig(**cfg), params=params,
+                         tracer=tracer)
+
+
+def test_leaf_spans_tile_the_step(tiny_model):
+    tr = Tracer()
+    eng = _engine(tiny_model, tracer=tr)
+    rng = np.random.default_rng(0)
+    for n in (20, 5, 11):
+        eng.submit(list(rng.integers(1, 128, size=n)), max_new_tokens=6)
+    eng.step()                                  # compiles both programs
+    eng.step()
+    covered = whole = 0.0
+    seen = set()
+    for _ in range(6):
+        mark = len(tr.snapshot())
+        t0 = time.monotonic_ns()
+        eng.step()
+        t1 = time.monotonic_ns()
+        # (a request's serve.first_token mark sits inside the commit it came in)
+        step = [r for r in tr.snapshot()[mark:] if r["name"] != "serve.first_token"]
+        assert all(r["depth"] == 0 and r["parent"] == 0 for r in step), \
+            "leaves are siblings: nothing encloses them"
+        assert [r["name"] for r in step] == [
+            n for n in SERVE_STEP_SPANS if n in {r["name"] for r in step}], \
+            "in the order the work happens"
+        seen |= {r["name"] for r in step}
+        covered += sum(r["t1"] - r["t0"] for r in step)
+        whole += t1 - t0
+    assert seen == set(SERVE_STEP_SPANS)
+    assert covered / whole >= 0.95, covered / whole
+    eng.close()
+
+
+def test_spans_carry_counts_where_the_work_happens(tiny_model):
+    tr = Tracer()
+    eng = _engine(tiny_model, tracer=tr)
+    fut = eng.submit(list(range(1, 13)), max_new_tokens=3)
+    eng.step()
+    by = {r["name"]: r["args"] for r in tr.snapshot()}
+    assert by["serve.submit"] is None
+    assert by["serve.admit"] == {"admitted": 1}
+    chunk = {"rid": fut.request.rid, "start": 0, "tokens": 8}
+    for name in ("serve.prefill.build", "serve.prefill.dispatch",
+                 "serve.prefill.fetch", "serve.prefill.commit"):
+        assert by[name] == chunk, name
+    assert by["serve.grow"] == {"batch": 0} and "serve.decode.build" not in by
+    eng.step()                                  # last chunk, then the first decode
+    by = {r["name"]: r["args"] for r in tr.snapshot()}
+    for name in ("serve.decode.build", "serve.decode.dispatch",
+                 "serve.decode.fetch", "serve.decode.commit"):
+        assert by[name] == {"batch": 1}, name
+    eng.close()
+
+
+def test_request_is_stamped_where_it_happens(tiny_model):
+    eng = _engine(tiny_model)
+    a = eng.submit(list(range(1, 20)), max_new_tokens=4).request
+    b = eng.submit(list(range(1, 10)), max_new_tokens=4).request
+    assert a.admitted_at is None and a.prefill_started_at is None
+    eng.step()                                  # both admitted; a's first chunk
+    assert a.arrival <= a.admitted_at <= a.prefill_started_at
+    assert b.admitted_at == a.admitted_at and b.prefill_started_at is None
+    eng.run()
+    for r in (a, b):
+        assert r.arrival <= r.admitted_at <= r.prefill_started_at <= r.first_token_at
+    assert b.prefill_started_at > a.prefill_started_at    # one lane: b waited
+    assert (a.prefill_chunks, b.prefill_chunks) == (3, 2)
+    eng.close()
+
+
+def test_preempted_request_is_stamped_anew(tiny_model):
+    eng = _engine(tiny_model)
+    r = eng.submit(list(range(1, 12)), max_new_tokens=8).request
+    while r.first_token_at is None:
+        eng.step()
+    first = (r.admitted_at, r.prefill_started_at, r.first_token_at)
+    eng.sched.preempt(r)
+    assert r.preemptions == 1
+    eng.run()
+    assert r.admitted_at > first[0] and r.prefill_started_at > first[1]
+    assert r.admitted_at <= r.prefill_started_at
+    assert r.first_token_at == first[2], "the first token came once"
+    assert r.prefill_chunks == 2                # 11 + 1 tokens of context again
+    eng.close()
+
+
+def test_first_token_stats_sum_to_the_programs_ttft(tiny_model):
+    tr = Tracer()
+    eng = _engine(tiny_model, tracer=tr)
+    futs = [eng.submit(list(range(1, n)), max_new_tokens=3) for n in (25, 6, 14)]
+    eng.run()
+    marks = [r for r in tr.snapshot() if r["name"] == "serve.first_token"]
+    assert len(marks) == len(futs), "one a request"
+    for f in futs:
+        r = f.request
+        st = next(m["args"] for m in marks if m["args"]["rid"] == r.rid)
+        assert st["queue_ms"] + st["lane_wait_ms"] + st["prefill_ms"] == pytest.approx(
+            (r.first_token_at - r.arrival) * 1e3, abs=1e-6)
+        assert min(st["queue_ms"], st["lane_wait_ms"], st["prefill_ms"]) >= 0.0
+        assert st["chunks"] == r.prefill_chunks == -(-len(r.prompt) // 8)
+    eng.close()
+
+
+def test_first_token_is_on_the_profilers_line_with_its_stats(tiny_model, tmp_path):
+    eng = _engine(tiny_model)
+    eng.submit([1, 2, 3], max_new_tokens=2).result()      # both programs warm
+    fut = eng.submit(list(range(1, 12)), max_new_tokens=2)
+    events = _capture(tmp_path, fut.result)
+    assert set(SERVE_STEP_SPANS) <= set(events)
+    _, st = events["serve.first_token"]
+    r = fut.request
+    assert st["rid"] == r.rid and st["chunks"] == 2
+    assert st["queue_ms"] + st["lane_wait_ms"] + st["prefill_ms"] == pytest.approx(
+        (r.first_token_at - r.arrival) * 1e3, abs=1e-6)
+    eng.close()

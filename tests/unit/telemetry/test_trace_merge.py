@@ -30,7 +30,7 @@ _WALL_BASE_NS = 1_700_000_000_000_000_000   # pinned anchor for exact skew
 
 def write_rank_trace(tmp_path, rank, wall_offset_ns=0):
     """A synthetic rank trace through the real Tracer export path."""
-    tr = Tracer(rank=rank, use_named_scope=False)
+    tr = Tracer(rank=rank)
     tr.epoch_wall_ns = _WALL_BASE_NS + wall_offset_ns  # skewed host clock
     with tr.span("train_batch", step=1):
         with tr.span("comm.all_reduce", op="all_reduce", bytes=4096):

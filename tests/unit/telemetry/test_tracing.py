@@ -26,7 +26,6 @@ class FakeClock:
 
 def make_tracer(**kw):
     clock = FakeClock()
-    kw.setdefault("use_named_scope", False)
     return Tracer(rank=kw.pop("rank", 0), clock=clock, **kw), clock
 
 

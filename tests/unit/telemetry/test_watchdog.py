@@ -87,7 +87,7 @@ class TestWatchdog:
     def test_tracer_spans_pet_the_watchdog(self):
         clock = FakeClock()
         wd = HangWatchdog(timeout_s=10.0, clock=clock)
-        tr = Tracer(clock=clock, heartbeat=wd.pet, use_named_scope=False)
+        tr = Tracer(clock=clock, heartbeat=wd.pet)
         wd.arm("step")
         clock.advance_s(9)
         with tr.span("comm.all_reduce"):      # collective beats
@@ -120,7 +120,7 @@ class TestFlightRecorder:
             hub.record_step(s, loss=0.5 / s, lr=1e-3)
         hub.flush()
         hub.record_step(3, loss=jnp.float32(0.1), lr=1e-3)  # stays pending
-        tracer = Tracer(use_named_scope=False)
+        tracer = Tracer()
         return hub, tracer
 
     def test_stall_dump_contains_everything(self, tmp_path):

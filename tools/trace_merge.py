@@ -87,71 +87,6 @@ def merge_traces(docs, flops=None) -> dict:
     return merged
 
 
-def _interval_union(intervals):
-    """Sorted, merged [start, end) intervals."""
-    out = []
-    for s, e in sorted(i for i in intervals if i[1] > i[0]):
-        if out and s <= out[-1][1]:
-            out[-1][1] = max(out[-1][1], e)
-        else:
-            out.append([s, e])
-    return out
-
-
-def _union_len(intervals):
-    return sum(e - s for s, e in intervals)
-
-
-def _intersect_len(a, b):
-    """Total overlap between two merged interval lists."""
-    total, i, j = 0.0, 0, 0
-    while i < len(a) and j < len(b):
-        s = max(a[i][0], b[j][0])
-        e = min(a[i][1], b[j][1])
-        if e > s:
-            total += e - s
-        if a[i][1] <= b[j][1]:
-            i += 1
-        else:
-            j += 1
-    return total
-
-
-def compute_overlap(events):
-    """Overlap fraction of collective time with compute time, from spans
-    tagged ``args.kind`` = "comm"/"compute" (the zero3 schedule lanes the
-    engine emits).  Per-pid interval intersection over the union of each
-    kind, summed across pids:
-
-        fraction = sum_pid |comm ∩ compute| / sum_pid |comm|
-
-    Returns ``{"comm_us", "compute_us", "overlap_us", "fraction"}`` or
-    None when no kind-tagged comm spans exist (nothing to measure).
-    """
-    by_pid = {}
-    for ev in events:
-        if ev.get("ph") != "X":
-            continue
-        kind = (ev.get("args") or {}).get("kind")
-        if kind not in ("comm", "compute"):
-            continue
-        ts = float(ev.get("ts", 0.0))
-        dur = float(ev.get("dur", 0.0))
-        by_pid.setdefault(ev.get("pid", 0), {"comm": [], "compute": []})[
-            kind].append((ts, ts + dur))
-    comm_us = compute_us = overlap_us = 0.0
-    for lanes in by_pid.values():
-        comm = _interval_union(lanes["comm"])
-        compute = _interval_union(lanes["compute"])
-        comm_us += _union_len(comm)
-        compute_us += _union_len(compute)
-        overlap_us += _intersect_len(comm, compute)
-    if comm_us <= 0:
-        return None
-    return {"comm_us": comm_us, "compute_us": compute_us,
-            "overlap_us": overlap_us, "fraction": overlap_us / comm_us}
-
-
 def load_collective_records(jsonl_paths):
     """Merge the ``collective_window`` records of telemetry JSONL files
     into a ``{"rank:seq": record}`` join table (later windows win per
@@ -235,19 +170,10 @@ def main(argv=None) -> int:
         else:
             print("trace_merge: --collectives: no collective_window "
                   "records found", file=sys.stderr)
-    overlap = compute_overlap(merged["traceEvents"])
-    if overlap is not None:
-        merged["metadata"]["overlap"] = overlap
     with open(args.output, "w") as f:
         json.dump(merged, f)
     n = len(merged["traceEvents"])
     print(f"wrote {args.output}: {n} events from {len(docs)} rank(s)")
-    if args.flops and overlap is not None:
-        print("zero3 overlap fraction: "
-              f"{overlap['fraction']:.3f} "
-              f"({overlap['overlap_us']:.0f}us of "
-              f"{overlap['comm_us']:.0f}us collective time concurrent "
-              "with compute)")
     return 0
 
 
