@@ -6,15 +6,19 @@ KV-cache workspace (``inference_context.h``).  The plain-jnp path attends
 over all ``max_len`` cache positions every step; these kernels read ONLY
 the ``pos + S_q`` valid positions:
 
-* ``pos`` arrives via scalar prefetch; the kernel loop runs a STATIC trip
-  count (``T/bk``, known at compile time) and predicates each iteration's
-  whole copy+compute block on ``j < ceil((pos+S_q)/bk)`` — invalid cache
-  blocks are neither DMA'd nor computed.  ``start()``/``wait()`` are paired
-  inside the same predicated branch so the DMA semaphores stay balanced on
-  every control path.
+* ``pos`` arrives via scalar prefetch; the dense-cache kernel's loop runs a
+  STATIC trip count (``T/bk``, known at compile time) and predicates each
+  iteration's whole copy+compute block on ``j < ceil((pos+S_q)/bk)`` —
+  invalid cache blocks are neither DMA'd nor computed.  ``start()``/
+  ``wait()`` are paired inside the same predicated branch so the DMA
+  semaphores stay balanced on every control path.
 * K/V stay in HBM (``MemorySpace.ANY``); each valid block is staged into a
   VMEM scratch buffer with an explicit ``make_async_copy`` keyed by the
   dynamic block index.
+* The paged kernel stages a TILE of ``G`` pages (:func:`paged_tile_pages`,
+  about 128 rows) per online-softmax update, all its live pages in flight
+  together, and fetches the next tile into a second buffer while this one
+  is attended; a tile's copies are started and waited for by one loop.
 * Online softmax in fp32 registers, exactly like the training flash kernel.
 
 Layouts: q ``[B, S_q, H, D]`` (S_q = 1 for decode, small for chunked
@@ -295,42 +299,125 @@ def paged_attention_reference(q, k_pages, v_pages, block_tables, lengths,
     return decode_attention_reference(q, ck, cv, lengths, bias=bias)
 
 
+# One tile of the paged kernel holds about this many cache rows: the 128 keys
+# the dense kernel attends at a time (a full MXU pass; the softmax update and
+# the 2*H matmul round trips are paid once per tile, not once per page).
+_TILE_ROWS = 128
+# K and V, two tiles each, may take this much VMEM (of the 16 MiB a v5e
+# kernel is given by default, next to q, o and the softmax carries).
+_TILE_VMEM_BYTES = 4 * 1024 * 1024
+
+
+def paged_tile_pages(BS: int, MB: int, Sq: int, lanes: int, dtype) -> int:
+    """``G``, the pages the paged kernel fetches and attends as ONE tile —
+    from the static shapes alone (page rows ``BS``, table width ``MB``,
+    queries per row ``Sq``, cache lanes ``H*D``, cache dtype): as many
+    pages as make :data:`_TILE_ROWS` rows, halved until the four tile
+    buffers fit :data:`_TILE_VMEM_BYTES`, never more than the table holds.
+    ``MB`` need not be a multiple of ``G``: the last tile is short.
+
+    ``Sq`` does not enter yet: on the chip decode (``Sq = 1``) ran best at
+    128 rows and the prefill chunk (``Sq = 64``) within 0.03 ms a step of
+    its best (PERF.md § 6, PR 26); a rule that tells them apart belongs
+    here."""
+    del Sq
+    row_bytes = lanes * np.dtype(dtype).itemsize
+    G = max(1, _TILE_ROWS // BS)
+    while G > 1 and 4 * G * BS * row_bytes > _TILE_VMEM_BYTES:
+        G //= 2
+    return min(G, MB)
+
+
 def _paged_kernel(len_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf,
-                  sem_k, sem_v, *, scale, bs, Sq, H, D, MB):
+                  sem, slot_ref, *, scale, bs, Sq, H, D, MB, G):
     """Grid (B,): per row, DMA ONLY the ``ceil((len+Sq)/bs)`` live physical
     blocks through the block table (scalar-prefetched, so the dynamic block
     index is known before the DMA is issued) — the same one-copy-serves-
-    every-head layout as ``_decode_kernel``.
+    every-head layout as ``_decode_kernel`` — in TILES of ``G`` pages:
 
-    Like ``_decode_kernel``, the loop bound is STATIC (``MB``, the block
-    table's row width) and liveness is a per-iteration ``lax.cond``
-    predicate — no dynamically-bounded DMA sequence, and ``j`` can never
-    reach ``MB``, so the table read ``tbl_ref[b*MB + j]`` is in-bounds by
-    construction even when a padded prefill chunk pushes
-    ``len + Sq`` past ``MB * bs`` (the causal mask already discards the
-    padded tail's scores)."""
+    * page ``j`` of tile ``t`` (logical block ``t*G + j``) lands in rows
+      ``[j*bs, (j+1)*bs)`` of a ``[G*bs, H*D]`` buffer; all of a tile's
+      live pages are started before any is waited for, and the tile is
+      attended ONCE (one online-softmax update per ``G*bs`` keys);
+    * two buffers per operand, used in turn: before tile ``t`` is waited
+      for, the copies of the NEXT tile are started into the other buffer
+      and fly while ``t`` is attended — the row's tile ``t+1``, or after
+      the row's last tile the first tile of row ``b+1`` (``slot_ref``
+      carries the buffer that tile sits in to the next grid step; only row
+      0 starts its own first tile).  The grid runs in order
+      (``dimension_semantics`` "arbitrary"), which this rests on;
+    * the loop runs over the row's ``ceil(nk/G)`` live tiles.  A tile's
+      copies are started and waited for by the SAME loop over its
+      ``min(G, nk - t*G)`` live pages, and every tile started is waited
+      for in the loop of its row, so the DMA semaphores stay balanced on
+      every control path.  ``nk`` is clamped to ``MB``, so the table read
+      ``tbl_ref[row*MB + page]`` is in-bounds by construction even when a
+      padded prefill chunk pushes ``len + Sq`` past ``MB * bs``; the mask's
+      ``cols < nk*bs`` discards what such a chunk would see past the table
+      in a short last tile (``MB % G != 0``).
+
+    Only live pages are fetched (an idle slot costs its one trash-block
+    DMA).  A tile's page slots that were NOT fetched are masked to a
+    probability of exactly 0, and 0 * NaN is NaN in ``p @ v``: both buffers
+    are ZEROED ONCE, in the first grid step (scratch outlives a grid step),
+    so a slot holds zeros or what an earlier DMA left there from a block
+    some table listed — finite either way."""
     b = pl.program_id(0)
     seq_len = len_ref[b]
     qm = _split_heads(q_ref[0], H, D)
-    nk = (seq_len + Sq + bs - 1) // bs            # live (DMA'd) block count
+    rows_t = G * bs
 
-    def live(j, carry):
-        phys = tbl_ref[b * MB + j]                # logical block j -> physical
-        cp_k = pltpu.make_async_copy(k_hbm.at[phys], k_buf, sem_k)
-        cp_v = pltpu.make_async_copy(v_hbm.at[phys], v_buf, sem_v)
-        cp_k.start()
-        cp_v.start()
-        cp_k.wait()
-        cp_v.wait()
-        rows = jax.lax.broadcasted_iota(jnp.int32, (Sq, bs), 0)
-        cols = j * bs + jax.lax.broadcasted_iota(jnp.int32, (Sq, bs), 1)
-        return _attend_block(qm, k_buf, v_buf, cols <= seq_len + rows, carry,
+    def pages_of(row):                            # live (DMA'd) pages
+        return jnp.minimum((len_ref[row] + Sq + bs - 1) // bs, MB)
+
+    nk = pages_of(b)
+    nt = (nk + G - 1) // G                        # live tiles, >= 1
+
+    def tile_copies(row, t, slot, do):
+        """``do`` (start or wait) the K and V copy of every live page of
+        tile ``t`` of ``row``; a wait needs the shapes only, so it shares
+        the code."""
+        def page(j, c):
+            phys = tbl_ref[row * MB + t * G + j]  # logical block -> physical
+            dst = pl.ds(pl.multiple_of(j * bs, bs), bs)
+            do(pltpu.make_async_copy(k_hbm.at[phys], k_buf.at[slot, dst],
+                                     sem.at[0, slot]))
+            do(pltpu.make_async_copy(v_hbm.at[phys], v_buf.at[slot, dst],
+                                     sem.at[1, slot]))
+            return c
+
+        jax.lax.fori_loop(0, jnp.clip(pages_of(row) - t * G, 0, G), page, 0)
+
+    start = lambda cp: cp.start()
+
+    @pl.when(b == 0)
+    def _():
+        k_buf[...] = jnp.zeros_like(k_buf)
+        v_buf[...] = jnp.zeros_like(v_buf)
+        slot_ref[0] = 0
+        tile_copies(0, 0, 0, start)
+
+    slot0 = slot_ref[0]                           # where this row's tile 0 is
+
+    def tile(t, carry):
+        slot = (slot0 + t) % 2
+        last = t + 1 == nt          # then the next tile is row b+1's first
+
+        @pl.when(jnp.logical_not(last) | (b + 1 < pl.num_programs(0)))
+        def _():
+            tile_copies(jnp.where(last, b + 1, b), jnp.where(last, 0, t + 1),
+                        1 - slot, start)
+
+        tile_copies(b, t, slot, lambda cp: cp.wait())
+        rows = jax.lax.broadcasted_iota(jnp.int32, (Sq, rows_t), 0)
+        cols = t * rows_t + jax.lax.broadcasted_iota(jnp.int32,
+                                                     (Sq, rows_t), 1)
+        valid = (cols <= seq_len + rows) & (cols < nk * bs)
+        return _attend_block(qm, k_buf.at[slot], v_buf.at[slot], valid, carry,
                              scale=scale, H=H, D=D)
 
-    def body(j, carry):
-        return jax.lax.cond(j < nk, lambda c: live(j, c), lambda c: c, carry)
-
-    carry = jax.lax.fori_loop(0, MB, body, _init_carry(Sq, H, D))
+    carry = jax.lax.fori_loop(0, nt, tile, _init_carry(Sq, H, D))
+    slot_ref[0] = (slot0 + nt) % 2
     _write_out(o_ref, carry, H, D)
 
 
@@ -338,6 +425,7 @@ def _paged_call(q, k_pages, v_pages, block_tables, lengths):
     B, Sq, H, D = q.shape
     NB, BS, HD = k_pages.shape
     MB = block_tables.shape[1]
+    G = paged_tile_pages(BS, MB, Sq, HD, k_pages.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                    # lengths, flat block tables
         grid=(B,),
@@ -349,17 +437,19 @@ def _paged_call(q, k_pages, v_pages, block_tables, lengths):
         out_specs=pl.BlockSpec((1, Sq, HD),
                                lambda b, len_ref, tbl_ref: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((BS, HD), k_pages.dtype),
-            pltpu.VMEM((BS, HD), v_pages.dtype),
-            pltpu.SemaphoreType.DMA,
-            pltpu.SemaphoreType.DMA,
+            pltpu.VMEM((2, G * BS, HD), k_pages.dtype),
+            pltpu.VMEM((2, G * BS, HD), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),      # [K|V, tile buffer]
+            pltpu.SMEM((1,), jnp.int32),          # buffer of the row's tile 0
         ],
     )
     out = pl.pallas_call(
         functools.partial(_paged_kernel, scale=1.0 / np.sqrt(D), bs=BS, Sq=Sq,
-                          H=H, D=D, MB=MB),
+                          H=H, D=D, MB=MB, G=G),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Sq, HD), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=_pallas.interpret(),
         name="paged_attention",
     )(jnp.asarray(lengths, jnp.int32),
@@ -378,6 +468,19 @@ def _mesh_divisors():
             int(mesh.shape["tensor"]))
 
 
+def paged_kernel_tile_pages(Sq, H, Hkv, D, BS, MB, dtype, bias=False) -> int:
+    """The ``G`` of the kernel :func:`paged_attention` builds for these
+    shapes under the mesh and the ``DST_PALLAS_PAGED`` of now; 0 where it
+    takes the jnp gather reference (a bias, GQA, lanes the gate refuses, a
+    sharded mesh, the kernel opted out).  The serving engine reports it as
+    the ``paged_tile_pages`` of its stats."""
+    if (bias or not _kernel_wanted("DST_PALLAS_PAGED")
+            or not kernel_shape_ok(H, Hkv, D, BS, dtype)
+            or _mesh_divisors() != (1, 1)):
+        return 0
+    return paged_tile_pages(BS, MB, Sq, Hkv * D, dtype)
+
+
 def paged_attention(q, k_pages, v_pages, block_tables, lengths, bias=None):
     """Block-table KV attention for the serving engine: the paged Pallas
     kernel where :func:`kernel_shape_ok` admits the shape (no bias, one
@@ -386,9 +489,8 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, bias=None):
     does not shard the global block arena)."""
     B, Sq, H, D = q.shape
     _, BS, HkvD = k_pages.shape
-    if (bias is None and _kernel_wanted("DST_PALLAS_PAGED")
-            and kernel_shape_ok(H, HkvD // D, D, BS, k_pages.dtype)
-            and _mesh_divisors() == (1, 1)):
+    if paged_kernel_tile_pages(Sq, H, HkvD // D, D, BS, block_tables.shape[1],
+                               k_pages.dtype, bias is not None):
         return _paged_call(q, k_pages, v_pages, block_tables, lengths)
     return paged_attention_reference(q, k_pages, v_pages, block_tables,
                                      lengths, bias=bias)

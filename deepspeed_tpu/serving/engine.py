@@ -275,6 +275,16 @@ class ServingEngine:
             self.sched.prefix_cache = self.prefix
             self.sched.on_prefix_hit = self._on_prefix_hit
 
+        # which attention the decode program gets (static per engine): the
+        # paged kernel's pages per tile, 0 on the einsum path; a stat of
+        # every step and of the ``serve.stats`` span
+        from deepspeed_tpu.ops.pallas.decode_attention import (
+            paged_kernel_tile_pages)
+        self.paged_tile_pages = paged_kernel_tile_pages(
+            1, mcfg.n_head, mcfg.kv_heads, mcfg.head_dim, cfg.block_size,
+            self.max_blocks_per_seq, self.dtype,
+            bias=mcfg.position_encoding == "alibi")
+
         # ---- the (single) jitted step ------------------------------------ #
         def step_fn(params, ids, positions, kp, vp, tables, wb, wo):
             logits, kp, vp = model.paged_step(params, ids, positions, kp, vp,
@@ -650,7 +660,8 @@ class ServingEngine:
         except ServeStepTimeout as err:
             self._recover_incident(err)
             raise
-        with self._span("serve.stats"):
+        with self._span("serve.stats",
+                        paged_tile_pages=self.paged_tile_pages):
             return self._close_step(len(decode), prefill_tokens, t_step)
 
     def _close_step(self, decode_batch: int, prefill_tokens: int,
@@ -674,6 +685,7 @@ class ServingEngine:
                      tokens_generated=self.tokens_generated,
                      shed_level=self.admission.level,
                      incidents=self.incident_count,
+                     paged_tile_pages=self.paged_tile_pages,
                      elapsed_ms=(time.monotonic() - self._started) * 1000.0)
         if self.tiering is not None:
             stats.update(self.tiering.stats())

@@ -28,7 +28,7 @@ BF16 = jnp.bfloat16
 # (heads, n_embd); D = 64 and vocab 50304 (50257 padded) for both
 WIDTHS = {"gpt2": (12, 768), "gpt2-xl": (25, 1600)}
 V, D, S, B = 50304, 64, 1024, 8
-CHUNK, MAX_BLOCKS = 64, 32          # serving prefill_chunk, table width
+CHUNK, MAX_BLOCKS = 64, 64          # serving prefill_chunk, table width
 
 
 @pytest.fixture(scope="module")
@@ -134,19 +134,30 @@ def test_train_kernel_compiles(chip, kernel, width):
     assert not fa._FALLBACK_WARNED
 
 
-@pytest.mark.parametrize("width", list(WIDTHS))
+# gpt2-large's 20 heads are 1280 lanes, the widest GPT-2 the gate admits
+SERVE_WIDTHS = {**WIDTHS, "gpt2-large": (20, 1280)}
+
+
+@pytest.mark.parametrize("width", list(SERVE_WIDTHS))
 @pytest.mark.parametrize("kernel", list(SERVE))
 def test_serve_kernel_compiles_or_gate_says_einsum(chip, kernel, width):
     """Through the public dispatch, as the model calls it: where
     ``kernel_shape_ok`` admits the shape the program holds the kernel and
     the chip's compiler accepts it; where it does not (gpt2-xl: 25 heads of
-    64 are 1600 lanes, not a multiple of 128) the program is the einsum."""
-    H, E = WIDTHS[width]
+    64 are 1600 lanes, not a multiple of 128) the program is the einsum.
+    The paged cases are the tiled kernel (``paged_tile_pages`` pages a
+    tile, two tile buffers an operand)."""
+    H, E = SERVE_WIDTHS[width]
     case, block = SERVE[kernel]
     fn, *shapes = case(H, E)
     has_kernel = "tpu_custom_call" in _compiled_text(chip, fn, *shapes)
     assert has_kernel == da.kernel_shape_ok(H, H, D, block, BF16)
-    assert has_kernel == (width == "gpt2")
+    assert has_kernel == (width != "gpt2-xl")
+    if kernel.startswith("paged"):
+        Sq = shapes[0][0][1]
+        assert da.paged_kernel_tile_pages(
+            Sq, H, H, D, block, MAX_BLOCKS, BF16) == (
+                128 // block if has_kernel else 0)
 
 
 def test_generate_keeps_its_cache_zero_filled(chip):

@@ -10,7 +10,7 @@ import jax.numpy as jnp
 from deepspeed_tpu.ops.pallas import decode_attention as da
 from deepspeed_tpu.ops.pallas.decode_attention import (
     _kernel_wanted, decode_attention_reference, paged_attention,
-    paged_attention_reference)
+    paged_attention_reference, paged_kernel_tile_pages, paged_tile_pages)
 
 
 @pytest.fixture
@@ -138,6 +138,86 @@ def test_pallas_kernel_padded_chunk_overhang(kernel_calls, Sq, length):
     assert kernel_calls
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
+
+
+# the tiled kernel's borders: 16-row pages, a table of 20 (two whole tiles of
+# G = 8 pages and a short one of 4), two D=64 heads sharing one lane slice
+T_BS, T_MB, T_H, T_D = 16, 20, 2, 64
+T_G = paged_tile_pages(T_BS, T_MB, 1, T_H * T_D, np.float32)
+TILE = T_G * T_BS
+# resident lengths on and round the page and tile borders, and the rows by
+# which the queries' end passes the table's last row: "full" fills the table
+# exactly, "over" is a padded chunk that spills 5 rows past it
+BORDERS = {"0": 0, "15": 15, "16": 16, "17": 17, "tile-1": TILE - 1,
+           "tile": TILE, "tile+1": TILE + 1}
+PAST_TABLE = {"full": 0, "over": 5}
+
+
+@pytest.mark.parametrize("Sq", [1, 64])
+@pytest.mark.parametrize("border", [*BORDERS, *PAST_TABLE])
+def test_tiled_kernel_parity_at_page_and_tile_borders(kernel_calls, border,
+                                                      Sq):
+    """The tiled kernel (interpreter) against the gather reference: a row
+    at the border under test beside an idle slot (length 0, every table
+    entry the trash block), a short row (one page at Sq=1) and a full row,
+    with EVERY arena block that no table lists filled with NaN — a page
+    slot of a tile that was not fetched must not reach ``p @ v`` as
+    whatever the buffer held."""
+    assert T_G == 8 and T_MB % T_G != 0
+    full = T_MB * T_BS - Sq
+    length = (BORDERS[border] if border in BORDERS
+              else full + PAST_TABLE[border])
+    lengths = np.asarray([length, 0, 5, full], np.int32)
+    q, kp, vp, tables, _ = make_paged(B=4, Sq=Sq, H=T_H, D=T_D, NB=96,
+                                      BS=T_BS, MB=T_MB, seed=11)
+    tables = np.asarray(tables).copy()
+    live = np.minimum(-(-(lengths + Sq) // T_BS), T_MB)
+    for b in range(4):
+        tables[b, live[b]:] = 0               # the engine pads with trash
+    tables[1] = 0                             # idle slot
+    unlisted = np.setdiff1d(np.arange(kp.shape[0]), tables.ravel())
+    assert unlisted.size > 10 and 0 not in unlisted
+    kp = kp.at[unlisted].set(np.nan)
+    vp = vp.at[unlisted].set(np.nan)
+    tables, lengths = jnp.asarray(tables), jnp.asarray(lengths)
+    ref = paged_attention_reference(q, kp, vp, tables, lengths)
+    out = paged_attention(q, kp, vp, tables, lengths)
+    assert kernel_calls
+    assert np.isfinite(np.asarray(ref)).all()
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+
+
+def test_tile_pages_follow_shapes_only():
+    """``G`` is a function of the static shapes: about 128 rows a tile,
+    never more pages than the table has, fewer where four tile buffers
+    would not fit the VMEM budget; the same for the prefill chunk and for
+    decode; and a table width that ``G`` does not divide is fine (the
+    parity test above runs 20 pages as 8 + 8 + 4)."""
+    bf16 = jnp.bfloat16
+    assert paged_tile_pages(16, 64, 1, 768, bf16) == 8       # gpt2 decode
+    assert paged_tile_pages(16, 64, 64, 768, bf16) == 8      # gpt2 chunk
+    assert paged_tile_pages(32, 32, 1, 768, bf16) == 4
+    assert paged_tile_pages(16, 64, 1, 1280, bf16) == 8      # gpt2-large
+    assert paged_tile_pages(128, 8, 1, 768, bf16) == 1       # a page is a tile
+    assert paged_tile_pages(256, 8, 1, 768, bf16) == 1
+    assert paged_tile_pages(16, 3, 1, 768, bf16) == 3        # short table
+    assert paged_tile_pages(8, 20, 1, 128, np.float32) == 16
+    # the VMEM budget: 4 buffers x G*BS rows x lanes x itemsize
+    for lanes in (768, 8192, 32768):
+        G = paged_tile_pages(16, 64, 1, lanes, np.float32)
+        assert G == 1 or 4 * G * 16 * lanes * 4 <= da._TILE_VMEM_BYTES
+    assert paged_tile_pages(16, 64, 1, 32768, np.float32) < 8
+
+
+def test_dispatch_reports_its_tile(kernel_calls):
+    """What the dispatch reports (and the serving engine's stats carry):
+    ``G`` where the kernel runs, 0 where the einsum does."""
+    bf16 = jnp.bfloat16
+    assert paged_kernel_tile_pages(1, 12, 12, 64, 16, 64, bf16) == 8
+    assert paged_kernel_tile_pages(1, 12, 12, 64, 16, 64, bf16, bias=True) == 0
+    assert paged_kernel_tile_pages(1, 25, 25, 64, 16, 64, bf16) == 0   # xl
+    assert paged_kernel_tile_pages(1, 8, 2, 64, 16, 64, bf16) == 0     # GQA
 
 
 def test_dispatch_takes_reference_on_bias_and_gqa(kernel_calls):

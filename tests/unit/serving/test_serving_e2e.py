@@ -185,3 +185,29 @@ def test_submit_rejects_oversized_and_sampled(tiny_model):
         # a typo'd SLO class must fail fast, not silently demote the
         # request to 'standard' priority
         eng.submit([1, 2], max_new_tokens=4, slo="rt")
+
+
+@pytest.mark.parametrize("n_head,tiled", [(12, True), (25, False)])
+def test_step_stats_say_which_paged_kernel(monkeypatch, n_head, tiled):
+    """``step()``'s stats carry ``paged_tile_pages``: the pages per tile of
+    the paged kernel the decode program was built with (forced through the
+    interpreter here, at GPT-2's 12 heads of 64 and 16-row pages), 0 where
+    ``paged_attention`` takes the einsum (gpt2-xl's 25 heads are 1600
+    lanes).  Static per engine, and the tokens are the dense path's."""
+    monkeypatch.setenv("DST_PALLAS_PAGED", "1")
+    cfg = GPTConfig(vocab_size=128, n_positions=256, n_embd=64 * n_head,
+                    n_layer=1, n_head=n_head, dtype="float32")
+    model = GPT(cfg)
+    params = model.init_params(jax.random.PRNGKey(0))
+    scfg = DeepSpeedServingConfig(block_size=16, num_blocks=40,
+                                  max_batch_size=2, prefill_chunk=16,
+                                  dtype="float32")
+    eng = ServingEngine(model, config=scfg, params=params)
+    assert eng.paged_tile_pages == (8 if tiled else 0)
+    prompt = list(np.random.default_rng(0).integers(1, 128, size=20))
+    fut = eng.submit(prompt, max_new_tokens=3)
+    seen = set()
+    while eng.sched.has_work:
+        seen.add(eng.step()["paged_tile_pages"])
+    assert seen == {eng.paged_tile_pages}
+    assert fut.token_ids == sequential_reference(model, params, prompt, 3)

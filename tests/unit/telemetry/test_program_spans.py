@@ -212,6 +212,8 @@ def test_spans_carry_counts_where_the_work_happens(tiny_model):
     for name in ("serve.decode.build", "serve.decode.dispatch",
                  "serve.decode.fetch", "serve.decode.commit"):
         assert by[name] == {"batch": 1}, name
+    # which paged kernel the engine runs (0: the einsum, as on this CPU)
+    assert by["serve.stats"] == {"paged_tile_pages": eng.paged_tile_pages}
     eng.close()
 
 
