@@ -95,6 +95,9 @@ class GPTConfig:
     rope_interleaved: bool = False
     # untied lm_head bias (GPT-J checkpoints carry one)
     head_bias: bool = False
+    # RMSNorm over the WHOLE q and k projections (weights [H*D], [Hkv*D]),
+    # before the split into heads and before rope (OLMoE)
+    qk_norm: bool = False
     # activation fake-quant (compression_training.activation_quantization;
     # reference QuantAct, compression/basic_layer.py:404): bits on the
     # normed inputs of the attention and MLP linears, STE gradients
@@ -102,9 +105,16 @@ class GPTConfig:
     activation_quant_type: str = "symmetric"
     # --- mixture-of-experts (reference deepspeed/moe): >0 replaces every
     # block's MLP with a top-k gated expert bank sharded over the 'expert'
-    # mesh axis; the load-balance aux loss is added in gpt_loss ----------- #
+    # mesh axis; the load-balance aux loss is added in gpt_loss.  The
+    # experts are the block's own MLP (``mlp_type``, ``use_bias``,
+    # ``activation``), ``moe_expert_hidden`` (default ``ffn_dim``) wide ---- #
     moe_num_experts: int = 0
     moe_top_k: int = 1
+    # 'gshard': top-1/top-2 with a capacity that drops what does not fit
+    # (``moe/sharded_moe.py``; the capacity knobs below are its own);
+    # 'dropless': softmax over all experts, any top-k, every assignment
+    # computed (``moe/dropless.py``): the one a server can use
+    moe_router: str = "gshard"
     moe_capacity_factor: float = 1.25
     moe_eval_capacity_factor: float = 2.0
     moe_min_capacity: int = 4
@@ -123,7 +133,12 @@ class GPTConfig:
         self.ffn_dim = self.intermediate_size or 4 * self.n_embd
         assert self.position_encoding in ("learned", "rope", "alibi")
         assert self.block_type in ("sequential", "parallel", "parallel_single_ln")
-        assert self.moe_top_k in (1, 2), "top-1 and top-2 gating supported" 
+        assert self.moe_router in ("gshard", "dropless")
+        if self.moe_num_experts > 0:
+            most = self.moe_num_experts if self.moe_router == "dropless" else 2
+            assert 1 <= self.moe_top_k <= most, (
+                f"moe_top_k {self.moe_top_k}: the {self.moe_router} router "
+                f"takes 1..{most}")
         assert self.norm in ("layernorm", "rmsnorm")
         assert self.mlp_type in ("standard", "swiglu")
 
@@ -156,6 +171,21 @@ def llama_config(vocab_size=32000, n_positions=2048, n_embd=512, n_layer=4,
               activation="gelu")
     kw.update(overrides)
     return GPTConfig(**kw)
+
+
+def olmoe_config(vocab_size=50304, n_positions=4096, n_embd=2048, n_layer=16,
+                 n_head=16, intermediate_size=1024, num_experts=64, top_k=8,
+                 **overrides) -> GPTConfig:
+    """OLMoE family (defaults: OLMoE-1B-7B): the LLaMA-style block with an
+    RMSNorm over the whole q and k projections, and every MLP a bank of
+    SwiGLU experts ``intermediate_size`` wide behind a dropless softmax
+    top-k router; SiLU, no bias, untied head."""
+    kw = dict(qk_norm=True, moe_num_experts=num_experts, moe_top_k=top_k,
+              moe_router="dropless")
+    kw.update(overrides)
+    return llama_config(vocab_size=vocab_size, n_positions=n_positions,
+                        n_embd=n_embd, n_layer=n_layer, n_head=n_head,
+                        intermediate_size=intermediate_size, **kw)
 
 
 def bloom_config(vocab_size=250880, n_positions=2048, n_embd=512, n_layer=4,
@@ -197,18 +227,25 @@ def _init_block(cfg: GPTConfig, rng: Array) -> Dict:
         "proj_w": _dense_init(ks[3], I, (I, E), scale=proj_scale),
         "proj_b": jnp.zeros((E,), jnp.float32),
     }
+    if cfg.qk_norm:
+        out["q_norm_g"] = jnp.ones((cfg.n_head * cfg.head_dim,), jnp.float32)
+        out["k_norm_g"] = jnp.ones((cfg.kv_heads * cfg.head_dim,), jnp.float32)
     if cfg.moe_num_experts > 0:
-        # the MLP becomes a gated expert bank (reference moe/layer.py:16);
-        # dense fc/proj weights are dropped from the pytree
-        from deepspeed_tpu.moe.experts import Experts, FFNExpert
-        ex = Experts(FFNExpert(E, cfg.moe_expert_hidden or I),
-                     cfg.moe_num_experts)
-        km = jax.random.split(jax.random.fold_in(rng, 1234), 2)
+        # the MLP becomes a gated expert bank (reference moe/layer.py:16):
+        # the dense fc/proj leaves, stacked over experts, as wi/bi/wo/bo
+        N, Ie = cfg.moe_num_experts, cfg.moe_expert_hidden or I
+        km = jax.random.split(jax.random.fold_in(rng, 1234), 3)
         for k in ("fc_w", "fc_b", "proj_w", "proj_b"):
             del out[k]
+        up = (2 if cfg.mlp_type == "swiglu" else 1) * Ie
+        experts = {"wi": _dense_init(km[1], E, (N, E, up)),
+                   "wo": _dense_init(km[2], Ie, (N, Ie, E), scale=proj_scale)}
+        if cfg.use_bias:
+            experts.update(bi=jnp.zeros((N, up), jnp.float32),
+                           bo=jnp.zeros((N, E), jnp.float32))
         out["moe"] = {
-            "gate": {"wg": _dense_init(km[0], E, (E, cfg.moe_num_experts))},
-            "experts": ex.init_params(km[1]),
+            "gate": {"wg": _dense_init(km[0], E, (E, N))},
+            "experts": experts,
         }
     return out
 
@@ -229,7 +266,13 @@ def init_gpt_params(cfg: GPTConfig, rng: Array) -> Dict:
     E, L = cfg.n_embd, cfg.n_layer
 
     if cfg.scan_layers:
-        blocks = jax.vmap(partial(_init_block, cfg))(jax.random.split(k_blocks, L))
+        # an expert bank is built a layer at a time (lax.map): the random
+        # bits of one OLMoE layer are a gigabyte, and a caller that casts
+        # the tree inside the same jit then never holds all layers in fp32
+        over_layers = jax.lax.map if cfg.moe_num_experts > 0 else (
+            lambda f, keys: jax.vmap(f)(keys))
+        blocks = over_layers(partial(_init_block, cfg),
+                             jax.random.split(k_blocks, L))
     else:
         blocks = {f"h{i}": _init_block(cfg, k)
                   for i, k in enumerate(jax.random.split(k_blocks, L))}
@@ -275,17 +318,17 @@ def gpt_partition_specs(cfg: GPTConfig) -> Dict:
         if cfg.moe_num_experts > 0:
             for k in ("fc_w", "fc_b", "proj_w", "proj_b"):
                 del keys[k]
+        if cfg.qk_norm:
+            keys.update(q_norm_g=PartitionSpec(), k_norm_g=PartitionSpec())
         specs = {k: PartitionSpec(*pre, *s) for k, s in keys.items()}
         if cfg.moe_num_experts > 0:
-            specs["moe"] = {
-                "gate": {"wg": PartitionSpec(*pre)},
-                "experts": {
-                    "wi": PartitionSpec(*pre, "expert", None, "tensor"),
-                    "bi": PartitionSpec(*pre, "expert", "tensor"),
-                    "wo": PartitionSpec(*pre, "expert", "tensor", None),
-                    "bo": PartitionSpec(*pre, "expert", None),
-                },
-            }
+            experts = {"wi": PartitionSpec(*pre, "expert", None, "tensor"),
+                       "wo": PartitionSpec(*pre, "expert", "tensor", None)}
+            if cfg.use_bias:
+                experts.update(bi=PartitionSpec(*pre, "expert", "tensor"),
+                               bo=PartitionSpec(*pre, "expert", None))
+            specs["moe"] = {"gate": {"wg": PartitionSpec(*pre)},
+                            "experts": experts}
         return specs
 
     if cfg.scan_layers:
@@ -382,6 +425,29 @@ def _split_qkv(cfg: "GPTConfig", qkv: Array):
             v.reshape(B, S, Hkv, D))
 
 
+def _project_qkv(cfg: "GPTConfig", p: Dict, h: Array, dt, positions: Array):
+    """The normed input ``h [B, S, E]`` -> q ``[B,S,H,D]``, k and v
+    ``[B,S,Hkv,D]``: the fused projection, its bias, the q/k RMSNorm over
+    all lanes (``qk_norm``), the split into heads, rope at ``positions``
+    (``[S]`` or ``[B, S]``)."""
+    qkv = h @ _wget(p, "qkv_w", dt)
+    if cfg.use_bias:
+        qkv = qkv + p["qkv_b"].astype(dt)
+    if cfg.qk_norm:
+        nq, nk = cfg.n_head * cfg.head_dim, cfg.kv_heads * cfg.head_dim
+        qkv = jnp.concatenate([
+            rms_norm(qkv[..., :nq], p["q_norm_g"], eps=cfg.ln_eps),
+            rms_norm(qkv[..., nq:nq + nk], p["k_norm_g"], eps=cfg.ln_eps),
+            qkv[..., nq + nk:]], axis=-1)
+    q, k, v = _split_qkv(cfg, qkv)
+    if cfg.position_encoding == "rope":
+        q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_dim,
+                       cfg.rope_interleaved)
+        k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_dim,
+                       cfg.rope_interleaved)
+    return q, k, v
+
+
 def _wget(p: Dict, key: str, dt) -> Array:
     """Weight fetch that transparently dequantizes int8-injected params
     (``module_inject/quantization.py``; reference GroupQuantizer +
@@ -394,48 +460,86 @@ def _wget(p: Dict, key: str, dt) -> Array:
     return w.astype(dt)
 
 
-def _mlp(cfg: "GPTConfig", p: Dict, h: Array, dt) -> Array:
-    up = h @ _wget(p, "fc_w", dt)
+def _mlp(cfg: "GPTConfig", p: Dict, h: Array, dt, matmul=jnp.matmul,
+         bias=lambda b: b) -> Array:
+    """The block's MLP.  An expert bank runs the same arithmetic on stacked
+    leaves: ``matmul`` then multiplies each row by its own expert's matrix
+    and ``bias`` picks each row its expert's bias (``_ffn``)."""
+    up = matmul(h, _wget(p, "fc_w", dt))
     if cfg.use_bias:
-        up = up + p["fc_b"].astype(dt)
+        up = up + bias(p["fc_b"].astype(dt))
     if cfg.mlp_type == "swiglu":
         gate, val = jnp.split(up, 2, axis=-1)
         h = jax.nn.silu(gate) * val
     else:
         h = _activation(up, cfg.activation)
-    out = h @ _wget(p, "proj_w", dt)
+    out = matmul(h, _wget(p, "proj_w", dt))
     if cfg.use_bias:
-        out = out + p["proj_b"].astype(dt)
+        out = out + bias(p["proj_b"].astype(dt))
     return out
 
 
+_EXPERT_LEAVES = {"wi": "fc_w", "bi": "fc_b", "wo": "proj_w", "bo": "proj_b"}
+
+
 def _ffn(cfg: "GPTConfig", p: Dict, h: Array, dt, rng=None,
-         train: bool = False) -> Tuple[Array, Array]:
+         train: bool = False, live: Optional[Array] = None
+         ) -> Tuple[Array, Array, Optional[Array]]:
     """Dense MLP or top-k gated MoE expert bank (reference ``moe/layer.py:16``
-    when ``moe_num_experts > 0``).  Returns ``(y, aux_loss)``; the aux loss
-    is zero on the dense path."""
+    when ``moe_num_experts > 0``).  Returns ``(y, aux_loss, expert_counts)``:
+    on the dense path the aux loss is zero and the counts None; the counts
+    (``[experts]`` int32, assignments of this call) leave out the rows
+    ``live [tokens]`` marks as carrying no request."""
     if cfg.moe_num_experts == 0:
-        return _mlp(cfg, p, h, dt), jnp.zeros((), jnp.float32)
-    from deepspeed_tpu.moe.experts import FFNExpert
+        return _mlp(cfg, p, h, dt), jnp.zeros((), jnp.float32), None
+    from deepspeed_tpu.moe import dropless
     from deepspeed_tpu.moe.sharded_moe import (moe_dispatch_combine,
                                                top1gating, top2gating)
-    E = cfg.n_embd
+    E, N = cfg.n_embd, cfg.moe_num_experts
     lead = h.shape[:-1]
     xt = h.reshape(-1, E)
-    logits = xt.astype(jnp.float32) @ p["moe"]["gate"]["wg"].astype(jnp.float32)
-    cf = cfg.moe_capacity_factor if train else cfg.moe_eval_capacity_factor
-    if cfg.moe_top_k == 1:
-        l_aux, combine, dispatch, _ = top1gating(
-            logits, capacity_factor=cf, min_capacity=cfg.moe_min_capacity,
-            noise_rng=rng if train else None)
+    bank = {_EXPERT_LEAVES[k]: v for k, v in p["moe"]["experts"].items()}
+    with jax.named_scope("moe"):
+        with jax.named_scope("moe_router"):
+            logits = xt.astype(jnp.float32) @ p["moe"]["gate"]["wg"].astype(
+                jnp.float32)
+            if cfg.moe_router == "dropless":
+                probs, weights, experts = dropless.softmax_topk(
+                    logits, cfg.moe_top_k)
+                l_aux = dropless.load_balance_loss(probs, experts)
+                counts = dropless.expert_counts(experts, N, live)
+        if cfg.moe_router == "dropless":
+            y = dropless.dropless_moe(
+                xt, weights, experts, N,
+                lambda rows, matmul, pick: _mlp(cfg, bank, rows, dt, matmul, pick))
+        else:
+            cf = cfg.moe_capacity_factor if train else cfg.moe_eval_capacity_factor
+            gating = top1gating if cfg.moe_top_k == 1 else top2gating
+            l_aux, combine, dispatch, counts = gating(
+                logits, capacity_factor=cf, min_capacity=cfg.moe_min_capacity,
+                noise_rng=rng if train else None)
+            y = moe_dispatch_combine(
+                xt, combine, dispatch,
+                lambda q, rows: _mlp(cfg, q, rows, dt), bank)
+            counts = counts.astype(jnp.int32)
+    return y.reshape(*lead, E).astype(dt), l_aux.astype(jnp.float32), counts
+
+
+def _block_tail(cfg: "GPTConfig", p: Dict, x: Array, h: Array, o: Array, dt,
+                live: Optional[Array] = None) -> Tuple[Array, Optional[Array]]:
+    """The block after attention, by ``block_type``, on the inference
+    paths: ``x`` the block's input, ``h`` its normed form (what attention
+    read), ``o`` attention's output.  Returns the block's output and
+    ``_ffn``'s expert counts."""
+    if cfg.block_type == "sequential":
+        x = x + o
+        z = _norm(cfg, x, p["ln2_g"], p["ln2_b"])
     else:
-        l_aux, combine, dispatch, _ = top2gating(
-            logits, capacity_factor=cf, min_capacity=cfg.moe_min_capacity,
-            noise_rng=rng if train else None)
-    expert = FFNExpert(E, cfg.moe_expert_hidden or cfg.ffn_dim)
-    y = moe_dispatch_combine(xt, combine, dispatch, expert,
-                             p["moe"]["experts"])
-    return y.reshape(*lead, E).astype(dt), l_aux.astype(jnp.float32)
+        z = h if cfg.block_type == "parallel_single_ln" else _norm(
+            cfg, x, p["ln2_g"], p["ln2_b"])
+        x = x + o
+    f, _, counts = _ffn(cfg, p, z, dt, live=live)
+    return x + f, counts
 
 
 def layer_norm(x: Array, g: Array, b: Array, eps: float = 1e-5) -> Array:
@@ -473,14 +577,7 @@ def gpt_block(cfg: GPTConfig, p: Dict, x: Array, rng: Optional[Array],
 
     with jax.named_scope("attn"):
         h = _maybe_actq(cfg, _norm(cfg, x, p["ln1_g"], p["ln1_b"]))
-        qkv = h @ _wget(p, "qkv_w", dt)
-        if cfg.use_bias:
-            qkv = qkv + p["qkv_b"].astype(dt)
-        q, k, v = _split_qkv(cfg, qkv)
-        if cfg.position_encoding == "rope":
-            pos = jnp.arange(S)
-            q = apply_rope(q, pos, cfg.rope_theta, cfg.rope_dim, cfg.rope_interleaved)
-            k = apply_rope(k, pos, cfg.rope_theta, cfg.rope_dim, cfg.rope_interleaved)
+        q, k, v = _project_qkv(cfg, p, h, dt, jnp.arange(S))
         # grouped K/V go to the attention op as-is: the Pallas kernel (and
         # the GQA-aware jnp reference) consume Hkv < H heads natively, so
         # training saves the K/V-expansion HBM the round-3 path paid here
@@ -506,15 +603,15 @@ def gpt_block(cfg: GPTConfig, p: Dict, x: Array, rng: Optional[Array],
         if cfg.block_type == "sequential":
             x = _constrain(x + o, mesh_lib.BATCH_AXES, "seq", None)
             h2 = _maybe_actq(cfg, _norm(cfg, x, p["ln2_g"], p["ln2_b"]))
-            f, moe_aux = _ffn(cfg, p, h2, dt, rng=r[1], train=train)
+            f, moe_aux, _ = _ffn(cfg, p, h2, dt, rng=r[1], train=train)
             x = x + _dropout(f, cfg.dropout, r[2], train)
         elif cfg.block_type == "parallel":
             # GPT-NeoX use_parallel_residual: x + attn(ln1 x) + mlp(ln2 x)
             h2 = _norm(cfg, x, p["ln2_g"], p["ln2_b"])
-            f, moe_aux = _ffn(cfg, p, h2, dt, rng=r[1], train=train)
+            f, moe_aux, _ = _ffn(cfg, p, h2, dt, rng=r[1], train=train)
             x = x + o + _dropout(f, cfg.dropout, r[2], train)
         else:   # parallel_single_ln (GPT-J): one LN feeds attn AND mlp
-            f, moe_aux = _ffn(cfg, p, h, dt, rng=r[1], train=train)
+            f, moe_aux, _ = _ffn(cfg, p, h, dt, rng=r[1], train=train)
             x = x + o + _dropout(f, cfg.dropout, r[2], train)
     return _constrain(x, mesh_lib.BATCH_AXES, "seq", None), moe_aux
 
@@ -838,14 +935,7 @@ def gpt_apply_with_cache(cfg: GPTConfig, params: Dict, input_ids: Array,
         # time went to those copies before this layout)
         x, ck_full, cv_full, li = carry
         h = _norm(cfg, x, p["ln1_g"], p["ln1_b"])
-        qkv = h @ _wget(p, "qkv_w", dt)
-        if cfg.use_bias:
-            qkv = qkv + p["qkv_b"].astype(dt)
-        q, k, v = _split_qkv(cfg, qkv)
-        if cfg.position_encoding == "rope":
-            rpos = pos + jnp.arange(S)
-            q = apply_rope(q, rpos, cfg.rope_theta, cfg.rope_dim, cfg.rope_interleaved)
-            k = apply_rope(k, rpos, cfg.rope_theta, cfg.rope_dim, cfg.rope_interleaved)
+        q, k, v = _project_qkv(cfg, p, h, dt, pos + jnp.arange(S))
         # the cache stores only kv_heads heads (the GQA memory win);
         # expansion to n_head happens at attention time
         zero = jnp.zeros((), jnp.int32)
@@ -861,18 +951,7 @@ def gpt_apply_with_cache(cfg: GPTConfig, params: Dict, input_ids: Array,
         o = o @ _wget(p, "out_w", dt)
         if cfg.use_bias:
             o = o + p["out_b"].astype(dt)
-        if cfg.block_type == "sequential":
-            x = x + o
-            h2 = _norm(cfg, x, p["ln2_g"], p["ln2_b"])
-            f, _ = _ffn(cfg, p, h2, dt, train=False)
-            x = x + f
-        elif cfg.block_type == "parallel":
-            h2 = _norm(cfg, x, p["ln2_g"], p["ln2_b"])
-            f, _ = _ffn(cfg, p, h2, dt, train=False)
-            x = x + o + f
-        else:   # parallel_single_ln
-            f, _ = _ffn(cfg, p, h, dt, train=False)
-            x = x + o + f
+        x, _ = _block_tail(cfg, p, x, h, o, dt)
         return (x, ck_full, cv_full, li + 1), None
 
     (x, new_k, new_v, _), _ = jax.lax.scan(
@@ -952,7 +1031,7 @@ def gpt_generate(cfg: GPTConfig, params: Dict, input_ids: Array,
 def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
                    positions: Array, k_pages: Array, v_pages: Array,
                    block_tables: Array, write_blocks: Array,
-                   write_offsets: Array) -> Tuple[Array, Array, Array]:
+                   write_offsets: Array, with_expert_counts: bool = False):
     """One fused step over the paged arena.
 
     ``input_ids`` [B, S] — S = 1 for decode, a chunk for chunked prefill;
@@ -962,7 +1041,16 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
     ``block_tables`` [B, MB] — logical→physical block map per row;
     ``write_blocks``/``write_offsets`` [B, S] — physical (block, offset)
     each new token's K/V lands in (invalid/padded tokens point at the trash
-    block).  Returns (logits [B, S, V] fp32, k_pages, v_pages).
+    block).  Returns (logits [B, S, V] fp32, k_pages, v_pages), and with
+    ``with_expert_counts`` (an MoE model) a fourth: the assignments per
+    expert ``[experts]`` int32, summed over layers, of the rows that carry a
+    request (those whose K/V does not go to the trash block).
+
+    Rows without a request (idle decode slots, the padding of a prompt
+    chunk) run through every layer like the others and are discarded by the
+    caller.  Under the dropless router they displace nothing; a router with
+    a capacity would let them push live tokens out, so ``init_serving``
+    refuses it.
     """
     assert cfg.scan_layers, "paged serving path requires scan_layers"
     from deepspeed_tpu.ops.pallas.decode_attention import paged_attention
@@ -973,6 +1061,7 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
     T = MB * BS
     dt = cfg.dtype
     pos2d = positions[:, None] + jnp.arange(S)[None]          # [B, S]
+    live = (write_blocks != 0).reshape(-1) if with_expert_counts else None
 
     x = params["wte"].astype(dt)[input_ids]
     if cfg.position_encoding == "learned":
@@ -992,52 +1081,38 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
 
     def layer(carry, p):
         x, kp, vp, li = carry
-        h = _norm(cfg, x, p["ln1_g"], p["ln1_b"])
-        qkv = h @ _wget(p, "qkv_w", dt)
-        if cfg.use_bias:
-            qkv = qkv + p["qkv_b"].astype(dt)
-        q, k, v = _split_qkv(cfg, qkv)
-        if cfg.position_encoding == "rope":
-            q = apply_rope(q, pos2d, cfg.rope_theta, cfg.rope_dim,
-                           cfg.rope_interleaved)
-            k = apply_rope(k, pos2d, cfg.rope_theta, cfg.rope_dim,
-                           cfg.rope_interleaved)
-        # scatter the new K/V into the arena through the write map; rows
-        # that must not write (padding, inactive slots) carry trash-block
-        # coordinates, so the scatter itself needs no predication
-        kp = kp.at[li, write_blocks, write_offsets].set(
-            k.astype(kp.dtype).reshape(B, S, -1))
-        vp = vp.at[li, write_blocks, write_offsets].set(
-            v.astype(vp.dtype).reshape(B, S, -1))
-        kl = jax.lax.dynamic_index_in_dim(kp, li, 0, keepdims=False)
-        vl = jax.lax.dynamic_index_in_dim(vp, li, 0, keepdims=False)
-        o = paged_attention(q, kl, vl, block_tables, positions,
-                            bias=attn_bias).reshape(B, S, E)
-        o = o @ _wget(p, "out_w", dt)
-        if cfg.use_bias:
-            o = o + p["out_b"].astype(dt)
-        if cfg.block_type == "sequential":
-            x = x + o
-            h2 = _norm(cfg, x, p["ln2_g"], p["ln2_b"])
-            f, _ = _ffn(cfg, p, h2, dt, train=False)
-            x = x + f
-        elif cfg.block_type == "parallel":
-            h2 = _norm(cfg, x, p["ln2_g"], p["ln2_b"])
-            f, _ = _ffn(cfg, p, h2, dt, train=False)
-            x = x + o + f
-        else:   # parallel_single_ln
-            f, _ = _ffn(cfg, p, h, dt, train=False)
-            x = x + o + f
-        return (x, kp, vp, li + 1), None
+        with jax.named_scope("attn"):
+            h = _norm(cfg, x, p["ln1_g"], p["ln1_b"])
+            q, k, v = _project_qkv(cfg, p, h, dt, pos2d)
+            # scatter the new K/V into the arena through the write map; rows
+            # that must not write (padding, inactive slots) carry trash-block
+            # coordinates, so the scatter itself needs no predication
+            kp = kp.at[li, write_blocks, write_offsets].set(
+                k.astype(kp.dtype).reshape(B, S, -1))
+            vp = vp.at[li, write_blocks, write_offsets].set(
+                v.astype(vp.dtype).reshape(B, S, -1))
+            kl = jax.lax.dynamic_index_in_dim(kp, li, 0, keepdims=False)
+            vl = jax.lax.dynamic_index_in_dim(vp, li, 0, keepdims=False)
+            o = paged_attention(q, kl, vl, block_tables, positions,
+                                bias=attn_bias).reshape(B, S, E)
+            o = o @ _wget(p, "out_w", dt)
+            if cfg.use_bias:
+                o = o + p["out_b"].astype(dt)
+        with jax.named_scope("mlp"):
+            x, counts = _block_tail(cfg, p, x, h, o, dt, live)
+        return (x, kp, vp, li + 1), counts
 
-    (x, k_pages, v_pages, _), _ = jax.lax.scan(
+    (x, k_pages, v_pages, _), counts = jax.lax.scan(
         layer, (x, k_pages, v_pages, jnp.zeros((), jnp.int32)),
         params["blocks"])
-    x = _norm(cfg, x, params["lnf_g"], params["lnf_b"])
-    head = params["lm_head"] if cfg.untied_head else params["wte"]
-    logits = (x @ head.astype(dt).T).astype(jnp.float32)
-    if cfg.head_bias:
-        logits = logits + params["lm_head_b"].astype(jnp.float32)
+    with jax.named_scope("head"):
+        x = _norm(cfg, x, params["lnf_g"], params["lnf_b"])
+        head = params["lm_head"] if cfg.untied_head else params["wte"]
+        logits = (x @ head.astype(dt).T).astype(jnp.float32)
+        if cfg.head_bias:
+            logits = logits + params["lm_head_b"].astype(jnp.float32)
+    if with_expert_counts:
+        return logits, k_pages, v_pages, counts.sum(axis=0)
     return logits, k_pages, v_pages
 
 
@@ -1204,23 +1279,31 @@ class GPT:
                             prompt_len=prompt_len)
 
     def paged_step(self, params, input_ids, positions, k_pages, v_pages,
-                   block_tables, write_blocks, write_offsets):
+                   block_tables, write_blocks, write_offsets, **kw):
         """Serving-engine protocol: one step over the paged KV arena
         (``deepspeed_tpu/serving/engine.py``)."""
         return gpt_paged_step(self.cfg, params, input_ids, positions,
                               k_pages, v_pages, block_tables,
-                              write_blocks, write_offsets)
+                              write_blocks, write_offsets, **kw)
 
-    def num_params(self) -> int:
+    def num_params(self, active: bool = False) -> int:
+        """Parameters held; ``active``: those one token multiplies by (an
+        MoE model's router and ``moe_top_k`` of its experts)."""
         cfg = self.cfg
-        E, L, I = cfg.n_embd, cfg.n_layer, cfg.ffn_dim
+        E, L = cfg.n_embd, cfg.n_layer
+        b = int(cfg.use_bias)
+        I = (cfg.moe_num_experts and cfg.moe_expert_hidden) or cfg.ffn_dim
         fc_out = 2 * I if cfg.mlp_type == "swiglu" else I
-        per_block = (E * cfg.qkv_dim + cfg.qkv_dim      # qkv (GQA-sized)
-                     + E * E + E                        # attn out
-                     + E * fc_out + fc_out              # mlp up (gate|up)
-                     + I * E + E                        # mlp down
-                     + 4 * E)                           # two norms
-        total = cfg.padded_vocab * E + L * per_block + 2 * E
+        mlp = E * fc_out + I * E + b * (fc_out + E)     # up (gate|up), down
+        if cfg.moe_num_experts:
+            mlp = E * cfg.moe_num_experts + mlp * (
+                cfg.moe_top_k if active else cfg.moe_num_experts)
+        qk_norm = (cfg.n_head + cfg.kv_heads) * cfg.head_dim * int(cfg.qk_norm)
+        norm = (2 if cfg.norm == "layernorm" else 1) * E   # gain (and shift)
+        per_block = (E * cfg.qkv_dim + b * cfg.qkv_dim   # qkv (GQA-sized)
+                     + E * E + b * E                     # attn out
+                     + mlp + qk_norm + 2 * norm)
+        total = cfg.padded_vocab * E + L * per_block + norm
         if cfg.position_encoding == "learned":
             total += cfg.n_positions * E
         if cfg.untied_head:
@@ -1228,8 +1311,9 @@ class GPT:
         return total
 
     def flops_per_token(self, seq_len: int) -> float:
-        """Training FLOPs/token ≈ 6N + attention term (PaLM appendix B)."""
+        """Training FLOPs/token ≈ 6N + attention term (PaLM appendix B),
+        N the parameters a token is multiplied by."""
         cfg = self.cfg
-        n = self.num_params()
+        n = self.num_params(active=True)
         attn = 12 * cfg.n_layer * cfg.n_embd * seq_len
         return 6 * n + attn

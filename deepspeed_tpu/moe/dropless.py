@@ -1,0 +1,78 @@
+"""Dropless top-k routing: every assignment the router makes is computed.
+
+The GShard router beside this one (``sharded_moe.py``) gives each expert a
+fixed capacity and drops what does not fit, through dense one-hot
+``[T, E, C]`` tensors.  Models of the OLMoE / DeepSeek line never drop a
+token, and at 64 experts with 8 a token that tensor is larger than the work.
+Here the ``T*k`` assignments are sorted by expert, the rows gathered in that
+order, and the bank runs as grouped matrix multiplications
+(``ops/pallas/grouped_matmul.py``: a kernel that streams the bank once on
+one TPU chip, ``jax.lax.ragged_dot`` elsewhere), so the shapes are static and
+nothing depends on how even the routing is.
+
+The four stages open the scopes ``moe_router``, ``moe_dispatch``,
+``moe_experts`` and ``moe_combine`` (the caller opens ``moe`` round them):
+per-layer metrics read a trace by these names.
+"""
+
+from typing import Callable, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from deepspeed_tpu.ops.pallas.grouped_matmul import grouped_matmul
+
+Array = jax.Array
+
+
+def softmax_topk(logits: Array, k: int) -> Tuple[Array, Array, Array]:
+    """``logits [T, E]`` -> (probs ``[T, E]``, weights ``[T, k]``, experts
+    ``[T, k]`` int32).  The softmax is over ALL experts, in float32, and the
+    weights are the raw probabilities of the chosen ``k`` (not renormalised:
+    OLMoE's ``norm_topk_prob`` false)."""
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    weights, experts = jax.lax.top_k(probs, k)
+    return probs, weights, experts.astype(jnp.int32)
+
+
+def load_balance_loss(probs: Array, experts: Array) -> Array:
+    """Switch/GShard's ``E * sum_e(mean prob_e * share of assignments_e)``
+    over all ``k`` choices: 1.0 when routing is even."""
+    E = probs.shape[-1]
+    share = expert_counts(experts, E).astype(jnp.float32) / experts.size
+    return E * jnp.sum(jnp.mean(probs, axis=0) * share)
+
+
+def expert_counts(experts: Array, num_experts: int,
+                  live: Optional[Array] = None) -> Array:
+    """Assignments per expert, ``[E]`` int32; ``live [T]`` leaves the rows
+    that carry no request out of the count."""
+    flat = experts.reshape(-1)
+    w = None if live is None else jnp.repeat(live.astype(jnp.int32),
+                                             experts.shape[-1])
+    return jnp.bincount(flat, weights=w, length=num_experts).astype(jnp.int32)
+
+
+def dropless_moe(x: Array, weights: Array, experts: Array, num_experts: int,
+                 expert_fn: Callable) -> Array:
+    """``x [T, M]`` through its ``k`` experts each, weighted and summed, in
+    float32.
+
+    ``expert_fn(rows, matmul, pick)`` is the expert's own arithmetic on the
+    sorted rows ``[T*k, M]``: ``matmul(rows, w)`` multiplies each row by ITS
+    expert's slice of a stacked ``w [E, in, out]``, and ``pick(b)`` gives
+    each row its expert's slice of a stacked ``b [E, out]`` (a bias)."""
+    T, k = experts.shape
+    with jax.named_scope("moe_dispatch"):
+        flat = experts.reshape(-1)
+        order = jnp.argsort(flat, stable=True)        # assignments by expert
+        sizes = expert_counts(experts, num_experts)
+        rows = x[order // k]                          # [T*k, M]
+    with jax.named_scope("moe_experts"):
+        y = expert_fn(rows, lambda a, w: grouped_matmul(a, w, sizes),
+                      lambda b: b[flat[order]])
+    with jax.named_scope("moe_combine"):
+        # back to token order by a gather (no scatter-add), then the
+        # weighted sum over the k choices
+        y = y[jnp.argsort(order)].reshape(T, k, -1)
+        return jnp.einsum("tkm,tk->tm", y.astype(jnp.float32), weights)

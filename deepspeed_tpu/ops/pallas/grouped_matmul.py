@@ -1,0 +1,172 @@
+"""Grouped matrix multiplication for an expert bank:
+``rows [A, K]``, sorted by group, times ``w [G, K, N]`` — row ``r`` by the
+matrix of the group it lies in, ``group_sizes [G]`` rows a group.
+
+``jax.lax.ragged_dot`` computes the same and is the path everywhere but on
+one TPU chip.  At a server's shapes (OLMoE: 1,024 rows over 64 experts, 16 a
+group) the work is READING the bank, and the chip's ``ragged_dot`` took 2.7
+times the time the bank's bytes need (PERF.md § 6, PR 27).  This kernel
+streams the bank once, in tiles of whole columns:
+
+* the rows are cut into tiles of ``tm``; a VISIT is a (row tile, group) pair
+  in which the group has rows, and the visits are walked in row order (at
+  most ``A/tm + G - 1`` of them; after JAX's ``pallas.ops.tpu.megablox``);
+* the grid is (column tile, visit): a visit multiplies its whole row tile
+  ``[tm, K]`` by the group's ``[K, tn]`` and stores only the group's rows.
+  Consecutive visits of one group share the weight block and consecutive
+  visits of one row tile the output block, so Pallas fetches each weight
+  tile once and writes each output tile once;
+* ``K`` is never cut: a weight tile is ``K x tn``, as large as
+  ``_WEIGHT_TILE_BYTES`` allows, and the two in flight are the kernel's VMEM.
+
+The backward pass is ``ragged_dot``'s own (a ``custom_vjp`` round the
+forward kernel): training shapes are compute bound and XLA's is fine there.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from deepspeed_tpu.ops import pallas as _pallas
+
+_ROW_TILE = 128
+# one weight tile (K x tn); two are in flight.  8 MiB at K = 2048 in bf16 is
+# the whole width of OLMoE's gate|up matrix (on the chip 3% faster than 4 MiB)
+_WEIGHT_TILE_BYTES = 8 * 1024 * 1024
+_VMEM_LIMIT_BYTES = 32 * 1024 * 1024
+
+
+def _column_tile(K: int, N: int, itemsize: int) -> int:
+    """The widest multiple of 128 that divides ``N`` whose ``K x tn`` tile
+    fits ``_WEIGHT_TILE_BYTES``; 0 where there is none."""
+    for tn in range(N, 0, -128):
+        if N % tn == 0 and K * tn * itemsize <= _WEIGHT_TILE_BYTES:
+            return tn
+    return 0
+
+
+def kernel_shape_ok(A: int, K: int, N: int, dtype) -> bool:
+    """What the kernel takes: whole row tiles, lane-aligned ``K`` and ``N``,
+    bf16 or float32, and a weight tile that fits."""
+    dtype = np.dtype(dtype)
+    return (A % _ROW_TILE == 0 and K % 128 == 0 and N % 128 == 0
+            and dtype in (np.dtype(jnp.bfloat16), np.dtype(np.float32))
+            and _column_tile(K, N, dtype.itemsize) > 0)
+
+
+def kernel_wanted() -> bool:
+    """``DST_PALLAS_GROUPED``: ``0`` opts out, ``1`` forces the kernel
+    (through the interpreter on the CPU, for parity tests); unset, the
+    kernel runs on one TPU chip and ``ragged_dot`` everywhere else (a mesh
+    shards the bank over ``expert``, which the kernel does not)."""
+    env = os.environ.get("DST_PALLAS_GROUPED")
+    if env in ("0", "1"):
+        return env == "1"
+    from deepspeed_tpu.parallel import mesh as mesh_lib
+    one_device = not mesh_lib.has_mesh() or mesh_lib.get_mesh().size == 1
+    return _pallas.platform() == "tpu" and one_device
+
+
+def visits(group_sizes, A: int, tm: int):
+    """The kernel's walk: (group offsets ``[G+1]``, group of each visit,
+    row tile of each visit, number of visits ``[1]``), the two lists
+    ``A/tm + G - 1`` long and padded with their last real entry."""
+    G = group_sizes.shape[0]
+    n_max = A // tm + G - 1
+    ends = jnp.cumsum(group_sizes.astype(jnp.int32))
+    starts = ends - group_sizes
+    first = starts // tm
+    # tiles a group has rows in: first .. (end-1)//tm; none when empty
+    n_tiles = jnp.where(group_sizes > 0, (ends - 1) // tm - first + 1, 0)
+    before = jnp.cumsum(n_tiles) - n_tiles          # visits of earlier groups
+    total = jnp.sum(n_tiles)
+    v = jnp.minimum(jnp.arange(n_max), jnp.maximum(total - 1, 0))
+    group = jnp.searchsorted(jnp.cumsum(n_tiles), v, side="right").astype(jnp.int32)
+    group = jnp.minimum(group, G - 1)
+    tile = first[group] + (v - before[group])
+    offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends])
+    return (offsets, group, tile.astype(jnp.int32),
+            total.astype(jnp.int32).reshape(1))
+
+
+def _kernel(off_ref, grp_ref, tile_ref, n_ref, lhs_ref, rhs_ref, out_ref, *, tm):
+    v = pl.program_id(1)
+
+    @pl.when(v < n_ref[0])
+    def _():
+        g, t = grp_ref[v], tile_ref[v]
+        acc = jnp.dot(lhs_ref[...], rhs_ref[...],
+                      preferred_element_type=jnp.float32)          # [tm, tn]
+        row = t * tm + jax.lax.broadcasted_iota(jnp.int32, acc.shape, 0)
+        mine = (row >= off_ref[g]) & (row < off_ref[g + 1])
+        # the first visit of a row tile starts from zeros, later ones from
+        # what the groups before left in the resident output block
+        first = (v == 0) | (tile_ref[jnp.maximum(v - 1, 0)] != t)
+        keep = jnp.logical_and(jnp.logical_not(mine), jnp.logical_not(first))
+        out = jnp.where(mine, acc.astype(out_ref.dtype),
+                        jnp.zeros(acc.shape, out_ref.dtype))
+        out_ref[...] = jnp.where(keep, out_ref[...], out)
+
+
+def _call(lhs, rhs, group_sizes):
+    A, K = lhs.shape
+    G, _, N = rhs.shape
+    tm = _ROW_TILE
+    tn = _column_tile(K, N, lhs.dtype.itemsize)
+    meta = visits(group_sizes, A, tm)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(N // tn, A // tm + G - 1),
+        in_specs=[
+            pl.BlockSpec((tm, K), lambda n, v, off, grp, tile, cnt: (tile[v], 0)),
+            pl.BlockSpec((None, K, tn),
+                         lambda n, v, off, grp, tile, cnt: (grp[v], 0, n)),
+        ],
+        out_specs=pl.BlockSpec((tm, tn),
+                               lambda n, v, off, grp, tile, cnt: (tile[v], n)),
+    )
+    return pl.pallas_call(
+        functools.partial(_kernel, tm=tm),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((A, N), lhs.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+        interpret=_pallas.interpret(),
+        name="grouped_matmul",
+    )(*meta, lhs, rhs)
+
+
+@jax.custom_vjp
+def _grouped(lhs, rhs, group_sizes):
+    return _call(lhs, rhs, group_sizes)
+
+
+def _grouped_fwd(lhs, rhs, group_sizes):
+    return _call(lhs, rhs, group_sizes), (lhs, rhs, group_sizes)
+
+
+def _grouped_bwd(res, dy):
+    lhs, rhs, group_sizes = res
+    _, vjp = jax.vjp(lambda a, w: jax.lax.ragged_dot(a, w, group_sizes), lhs, rhs)
+    return (*vjp(dy), None)
+
+
+_grouped.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+def grouped_matmul(lhs, rhs, group_sizes):
+    """``lhs [A, K]`` (rows sorted by group, ``sum(group_sizes) == A``) times
+    ``rhs [G, K, N]`` -> ``[A, N]`` in ``lhs``'s type: the kernel where
+    :func:`kernel_wanted` and :func:`kernel_shape_ok` say so, else
+    ``jax.lax.ragged_dot``."""
+    rhs = rhs.astype(lhs.dtype)
+    A, K = lhs.shape
+    if kernel_wanted() and kernel_shape_ok(A, K, rhs.shape[2], lhs.dtype):
+        return _grouped(lhs, rhs, group_sizes.astype(jnp.int32))
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes)

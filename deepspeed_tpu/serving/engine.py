@@ -237,6 +237,16 @@ class ServingEngine:
             model.cfg = dataclasses.replace(model.cfg, dtype=self.dtype)
         self.module = model
         mcfg = model.cfg
+        # experts of an MoE model (0: dense).  Every step routes the rows
+        # that carry no request too (idle slots, chunk padding): a router
+        # with a capacity would let them push live tokens out of an expert
+        self._moe_experts = int(getattr(mcfg, "moe_num_experts", 0))
+        if self._moe_experts and mcfg.moe_router != "dropless":
+            raise ValueError(
+                f"init_serving: the {mcfg.moe_router!r} router drops tokens "
+                f"beyond an expert's capacity, and a serve step's idle slots "
+                f"and chunk padding count against it; serve an MoE model "
+                f"with moe_router='dropless'")
 
         if params is None:
             assert hasattr(model, "init_params"), (
@@ -287,12 +297,18 @@ class ServingEngine:
 
         # ---- the (single) jitted step ------------------------------------ #
         def step_fn(params, ids, positions, kp, vp, tables, wb, wo):
-            logits, kp, vp = model.paged_step(params, ids, positions, kp, vp,
-                                              tables, wb, wo)
+            moe = {"with_expert_counts": True} if self._moe_experts else {}
+            logits, kp, vp, *counts = model.paged_step(
+                params, ids, positions, kp, vp, tables, wb, wo, **moe)
             if mcfg.padded_vocab != mcfg.vocab_size:
                 vmask = jnp.arange(mcfg.padded_vocab) < mcfg.vocab_size
                 logits = jnp.where(vmask[None, None], logits, -1e30)
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32), kp, vp
+            tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            if counts:
+                # an MoE model's expert counts ride behind the token row in
+                # the one int32 array the host fetches: no second transfer
+                tokens = jnp.concatenate([tokens.reshape(-1), *counts])
+            return tokens, kp, vp
 
         # arena donation = in-place KV update; CPU can't donate (jax warns
         # and copies), so only donate on real accelerators
@@ -323,6 +339,7 @@ class ServingEngine:
         self._incident: Optional[Dict[str, Any]] = None  # /healthz latch
 
         self._rid_counter = 0
+        self._expert_counts = np.zeros((0,), np.int32)   # of the last program
         self._futures: Dict[int, ServeFuture] = {}
         self.step_count = 0
         self.tokens_generated = 0
@@ -470,8 +487,11 @@ class ServingEngine:
                     f"serve {phase} step {self.step_count} exceeded its "
                     f"{e.deadline_s:.3f}s deadline", op=phase,
                     deadline_s=e.deadline_s, step=self.step_count) from e
-        tokens, self._k_pages, self._v_pages = out
-        return tokens
+        row, self._k_pages, self._v_pages = out
+        row = row.reshape(-1)
+        n = row.size - self._moe_experts
+        self._expert_counts = row[n:]
+        return row[:n].reshape(ids.shape)
 
     def _recover_incident(self, err: ServeStepTimeout):
         """In-process recovery from a wedged compiled step: drop the
@@ -651,7 +671,8 @@ class ServingEngine:
                 with self._span("serve.decode.build", **at):
                     inputs = self._decode_inputs(decode)
                 tokens = self._dispatch("decode", inputs, at)
-                with self._span("serve.decode.commit", **at):
+                moe_stats = self._moe_stats()
+                with self._span("serve.decode.commit", **at, **moe_stats):
                     for r in decode:
                         r.prefilled += 1      # the fed token's KV is resident
                         self._append_token(r, int(tokens[r.slot, 0]))
@@ -662,7 +683,19 @@ class ServingEngine:
             raise
         with self._span("serve.stats",
                         paged_tile_pages=self.paged_tile_pages):
-            return self._close_step(len(decode), prefill_tokens, t_step)
+            stats = self._close_step(len(decode), prefill_tokens, t_step)
+            if decode:
+                stats.update(moe_stats)
+            return stats
+
+    def _moe_stats(self) -> Dict[str, float]:
+        """How the last program's live rows spread over the experts (summed
+        over layers); nothing for a dense model."""
+        counts = self._expert_counts
+        if not counts.size or not counts.any():
+            return {}
+        return {"moe_load_max_over_mean": float(counts.max() / counts.mean()),
+                "moe_experts_touched": int((counts > 0).sum())}
 
     def _close_step(self, decode_batch: int, prefill_tokens: int,
                     t_step: float) -> Dict[str, Any]:

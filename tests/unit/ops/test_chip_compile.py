@@ -160,6 +160,38 @@ def test_serve_kernel_compiles_or_gate_says_einsum(chip, kernel, width):
                 128 // block if has_kernel else 0)
 
 
+@pytest.mark.parametrize("rows,Sq", [(128, 1), (1, CHUNK)])
+def test_paged_kernel_compiles_at_olmoe_heads(chip, rows, Sq):
+    """OLMoE-1B-7B's attention: 16 heads of D=128 (one head a lane slice,
+    where GPT-2's D=64 puts two in one), 2048 lanes, a table of 256 blocks
+    for 4096 positions, 128 decode rows or one prompt chunk.  On the
+    admitted side of the gate: the program holds the tiled kernel (8 pages
+    a tile: 4 x 8 x 16 x 4096 B are 2 of its 4 MiB) and the chip's compiler
+    accepts it."""
+    H, D128, BS, MB = 16, 128, 16, 256
+    assert da.kernel_shape_ok(H, H, D128, BS, BF16)
+    pages = ((4097, BS, H * D128), BF16)
+    text = _compiled_text(chip, da.paged_attention, ((rows, Sq, H, D128), BF16),
+                          pages, pages, ((rows, MB), jnp.int32), ((rows,), jnp.int32))
+    assert "tpu_custom_call" in text
+    assert da.paged_kernel_tile_pages(Sq, H, H, D128, BS, MB, BF16) == 8
+
+
+@pytest.mark.parametrize("rows", [1024, 512])
+@pytest.mark.parametrize("K,N", [(2048, 2048), (1024, 2048)])
+def test_grouped_matmul_compiles_at_olmoe_bank(chip, monkeypatch, rows, K, N):
+    """The expert bank's two matmuls (gate|up ``[64, 2048, 2048]``, down
+    ``[64, 1024, 2048]``) at the serve cell's 1,024 (128 rows x top 8) and
+    512 (a prompt chunk) assignments: the program holds the kernel and the
+    chip's compiler accepts its blocks and its VMEM."""
+    from deepspeed_tpu.ops.pallas import grouped_matmul as gm
+    monkeypatch.delenv("DST_PALLAS_GROUPED", raising=False)
+    assert gm.kernel_shape_ok(rows, K, N, BF16)
+    text = _compiled_text(chip, gm.grouped_matmul, ((rows, K), BF16),
+                          ((64, K, N), BF16), ((64,), jnp.int32))
+    assert "tpu_custom_call" in text and "grouped_matmul" in text
+
+
 def test_generate_keeps_its_cache_zero_filled(chip):
     """``generate()`` builds its KV cache inside the program.  The TPU
     compiler turned those zeros into an uninitialised ``AllocateBuffer``
