@@ -304,13 +304,13 @@ def serve_phase(seed):
     check(sum(len(p) > chunk for p in prompts) >= 2 and len(prompts) >= 4,
           "serve: the request mix must span several prefill chunks")
 
-    # warm both compiled programs (prefill chunk, decode batch) first, so
-    # compile time and steady time are told apart
+    # warm the one compiled program (decode rows and the prompt chunk share
+    # it) first, so compile time and steady time are told apart
     t0 = time.perf_counter()
     engine.submit(prompts[-1][:chunk + 1], max_new_tokens=2).result()
     compile_s = time.perf_counter() - t0
-    check(engine.compiled_programs() == 2,
-          f"serve: {engine.compiled_programs()} compiled programs, not 2")
+    check(engine.compiled_programs() == 1,
+          f"serve: {engine.compiled_programs()} compiled programs, not 1")
 
     t0 = time.perf_counter()
     futures = [engine.submit(p, max_new_tokens=n)
@@ -319,24 +319,20 @@ def serve_phase(seed):
     wall = time.perf_counter() - t0
     ttft_ms = [1e3 * (f.request.first_token_at - f.request.arrival)
                for f in futures]
-    check(engine.compiled_programs() == 2, "serve: a request recompiled")
+    check(engine.compiled_programs() == 1, "serve: a request recompiled")
 
-    # which attention ran, from the two programs' compiled text
-    B, MB = SERVE["max_batch_size"], engine.max_blocks_per_seq
+    # which attention ran, from the program's compiled text: every decode
+    # slot and every token of the prompt chunk is a row of one query
+    rows, MB = SERVE["max_batch_size"] + chunk, engine.max_blocks_per_seq
     i32 = jnp.int32
-    shapes = {"prefill": (1, chunk), "decode": (B, 1)}
-    kernels = {}
-    for phase, (b, s) in shapes.items():
-        compiled = engine._step_fn.lower(
-            engine.params, jnp.zeros((b, s), i32), jnp.zeros((b,), i32),
-            engine._k_pages, engine._v_pages, jnp.zeros((b, MB), i32),
-            jnp.zeros((b, s), i32), jnp.zeros((b, s), i32)).compile()
-        kernels[phase] = kernels_in(compiled)
+    kernels = kernels_in(engine._step_fn.lower(
+        engine.params, jnp.zeros((rows, 1), i32), jnp.zeros((rows,), i32),
+        engine._k_pages, engine._v_pages, jnp.zeros((rows, MB), i32),
+        jnp.zeros((rows, 1), i32), jnp.zeros((rows, 1), i32)).compile())
     H, D = cfg.n_head, cfg.head_dim
     paged_ok = kernel_shape_ok(H, cfg.kv_heads, D, SERVE["block_size"], jnp.bfloat16)
-    for phase, found in kernels.items():
-        check(bool(found.get("paged_attention")) == paged_ok,
-              f"serve {phase}: kernels {found}, shape gate says kernel={paged_ok}")
+    check(bool(kernels.get("paged_attention")) == paged_ok,
+          f"serve: kernels {kernels}, shape gate says kernel={paged_ok}")
 
     # model.generate, one request at a time: the other decode path (dense
     # cache, decode kernel).  Two bf16 programs that sum in different orders

@@ -1034,7 +1034,10 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
                    write_offsets: Array, with_expert_counts: bool = False):
     """One fused step over the paged arena.
 
-    ``input_ids`` [B, S] — S = 1 for decode, a chunk for chunked prefill;
+    ``input_ids`` [B, S] — a row holds S consecutive tokens of one sequence;
+    the serving engine runs S = 1, a decode slot or ONE token of the step's
+    prompt chunk a row (all new K/V is scattered before a layer attends, so
+    a chunk's row sees the chunk's earlier tokens through its block table);
     ``positions`` [B] — per-row global position of the first token (tokens
     already resident in the row's cache); ``k_pages``/``v_pages``
     [L, NB, BS, Hkv*D] — the global arena (block 0 is the trash block);
@@ -1046,8 +1049,8 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
     expert ``[experts]`` int32, summed over layers, of the rows that carry a
     request (those whose K/V does not go to the trash block).
 
-    Rows without a request (idle decode slots, the padding of a prompt
-    chunk) run through every layer like the others and are discarded by the
+    Rows without a request (idle decode slots, the rows past a prompt
+    chunk's tokens) run through every layer like the others and are discarded by the
     caller.  Under the dropless router they displace nothing; a router with
     a capacity would let them push live tokens out, so ``init_serving``
     refuses it.

@@ -2,17 +2,23 @@
 
 The inference stack's ``generate()`` serves one static batch per call; this
 engine serves a *stream*: requests join and leave the decode batch every
-step without recompilation.  The trick is shape discipline — exactly TWO
-programs are ever compiled, both traces of one jitted step function:
+step without recompilation.  The trick is shape discipline — exactly ONE
+program is ever compiled, and a step runs it ONCE: ``[max_batch_size +
+prefill_chunk, 1]`` tokens over the arena, every row one query.
 
-* **decode**: ``[max_batch_size, 1]`` tokens over the arena — every active
-  sequence advances one token; inactive slots carry trash-block write
-  coordinates and all-trash block tables, so batch composition is pure
-  traced *data*;
-* **prefill**: ``[1, prefill_chunk]`` tokens — one prompt chunk per step
-  (chunked prefill), so a long prompt never stalls the decode batch for
-  more than one chunk's latency.
+* rows ``0 .. max_batch_size - 1`` are the **decode** slots — every active
+  sequence advances one token;
+* rows ``max_batch_size ..`` are the step's **prompt chunk** (chunked
+  prefill: at most one chunk of at most ``prefill_chunk`` tokens a step, so
+  a long prompt never holds the decode rows back for more than a chunk) —
+  token ``i`` of the chunk is a row of its own at position ``start + i``
+  with the request's block table.  Every layer scatters all new K/V before
+  it attends, so the row sees keys ``0 .. start + i``, its own chunk's
+  included.
 
+Rows that carry nothing (an idle slot, the chunk's rows past its tokens,
+all of them in a step without a chunk) carry trash-block write coordinates
+and all-trash block tables, so what a step holds is pure traced *data*.
 Block tables, positions, and write maps are int32 inputs produced by the
 host-side :class:`PagedKVAllocator` / :class:`ServingScheduler`; the arena
 arrays are donated back to the step on accelerators, so the KV cache is
@@ -53,25 +59,29 @@ from deepspeed_tpu.utils.logging import log_dist
 #: happens (PERF.md § 3 copies this list).  On the profiler's line they are
 #: SIBLINGS under the caller's own span, with no ``serve.step`` round them:
 #: an idle gap of the device is then named by the phase the host was in.
+#: A step runs ONE program, so it opens one ``dispatch`` and one ``fetch``:
+#: the ``serve.decode.*`` pair when the program carries a decode row (stat
+#: ``batch``: every live row, chunk tokens included), the ``serve.prefill.*``
+#: pair when it carries a prompt chunk alone; both have ``chunk_tokens``.
 #: ``submit()`` is ``serve.submit``; a request's first token leaves one
 #: zero-length ``serve.first_token`` with its waits as stats.
 SERVE_STEP_SPANS = (
     "serve.admit",              # deadlines, shed ladder, sched.admit
-    "serve.prefill.build",      # next_prefill, ids, block table, write map
-    "serve.prefill.dispatch",   # uploads and the call of the compiled step
-    "serve.prefill.fetch",      # the chunk's token row back on the host
-    "serve.prefill.commit",     # prefilled, prefix insert, first token
     "serve.grow",               # sort, ensure_capacity, decode_batch
-    "serve.decode.build",
+    "serve.prefill.build",      # next_prefill, the chunk's rows
+    "serve.decode.build",       # the decode slots' rows
+    "serve.prefill.dispatch",   # uploads and the call of the compiled step
+    "serve.prefill.fetch",      # the token row back on the host
     "serve.decode.dispatch",
     "serve.decode.fetch",
+    "serve.prefill.commit",     # prefilled, prefix insert, first token
     "serve.decode.commit",
     "serve.stats",              # ledger, stats dict, gauges, emit
 )
 
 
 class ServeStepTimeout(RuntimeError):
-    """A compiled serve step (decode or prefill dispatch) exceeded
+    """A compiled serve step exceeded
     ``serve_step_timeout_s``.  Raised *after* the engine has recovered
     in-process (programs re-jitted, arena rebuilt, every in-flight request
     requeued for recompute) — ``run()``/``result()`` keep driving; a bare
@@ -238,14 +248,14 @@ class ServingEngine:
         self.module = model
         mcfg = model.cfg
         # experts of an MoE model (0: dense).  Every step routes the rows
-        # that carry no request too (idle slots, chunk padding): a router
+        # that carry no request too (idle slots, idle chunk rows): a router
         # with a capacity would let them push live tokens out of an expert
         self._moe_experts = int(getattr(mcfg, "moe_num_experts", 0))
         if self._moe_experts and mcfg.moe_router != "dropless":
             raise ValueError(
                 f"init_serving: the {mcfg.moe_router!r} router drops tokens "
                 f"beyond an expert's capacity, and a serve step's idle slots "
-                f"and chunk padding count against it; serve an MoE model "
+                f"and idle chunk rows count against it; serve an MoE model "
                 f"with moe_router='dropless'")
 
         if params is None:
@@ -329,11 +339,11 @@ class ServingEngine:
             self._bounded = BoundedCollective(
                 deadline_s=float(cfg.serve_step_timeout_s),
                 on_timeout=lambda err: release_wedges())
-        # phases whose program has already compiled: the first dispatch of
-        # each phase runs inline (unbounded) because XLA compilation is
-        # legitimate work that routinely exceeds a steady-state step
-        # deadline — bounding it would fire a spurious incident at startup
-        self._warm_phases: set = set()
+        # whether the program has compiled: the first dispatch runs inline
+        # (unbounded) because XLA compilation is legitimate work that
+        # routinely exceeds a steady-state step deadline — bounding it would
+        # fire a spurious incident at startup
+        self._warm = False
         self.incident_count = 0
         self.last_recovery_s = 0.0
         self._incident: Optional[Dict[str, Any]] = None  # /healthz latch
@@ -462,8 +472,11 @@ class ServingEngine:
         token row happens *inside* the bounded callable — that device sync
         is exactly where a wedged program parks the thread — so the
         ``dispatch`` and ``fetch`` spans go to the worker thread with it.
-        The first dispatch of each phase (and the first after an incident
-        re-jit) runs inline: it compiles, and compile time is not a wedge."""
+        ``phase`` names the span pair and the fault point: ``decode`` when
+        the program carries a decode row, ``prefill`` when it carries a
+        prompt chunk alone.  The first dispatch (and the first after an
+        incident re-jit) runs inline: it compiles, and compile time is not a
+        wedge."""
         import jax.numpy as jnp
         ids, positions, tables, wb, wo = inputs
 
@@ -475,10 +488,10 @@ class ServingEngine:
                     self._k_pages, self._v_pages, jnp.asarray(tables),
                     jnp.asarray(wb), jnp.asarray(wo))
             with self._span(f"serve.{phase}.fetch", **stats):
-                return np.asarray(tokens), kp, vp
-        if self._bounded is None or phase not in self._warm_phases:
+                return np.asarray(tokens).reshape(-1), kp, vp
+        if self._bounded is None or not self._warm:
             out = work()
-            self._warm_phases.add(phase)
+            self._warm = True
         else:
             try:
                 out = self._bounded.run(work, op=phase, noun="serve step")
@@ -488,10 +501,9 @@ class ServingEngine:
                     f"{e.deadline_s:.3f}s deadline", op=phase,
                     deadline_s=e.deadline_s, step=self.step_count) from e
         row, self._k_pages, self._v_pages = out
-        row = row.reshape(-1)
         n = row.size - self._moe_experts
         self._expert_counts = row[n:]
-        return row[:n].reshape(ids.shape)
+        return row[:n]
 
     def _recover_incident(self, err: ServeStepTimeout):
         """In-process recovery from a wedged compiled step: drop the
@@ -520,7 +532,7 @@ class ServingEngine:
             self.tiering.drain()
         self._step_fn = jax.jit(self._raw_step_fn,
                                 donate_argnums=self._donate)
-        self._warm_phases.clear()   # fresh jit: first dispatches recompile
+        self._warm = False          # fresh jit: the first dispatch recompiles
         self.alloc = PagedKVAllocator(cfg.num_blocks, cfg.block_size,
                                       self.max_blocks_per_seq)
         self._k_pages, self._v_pages = init_arena(
@@ -566,8 +578,9 @@ class ServingEngine:
         }, step=self.step_count)
 
     def compiled_programs(self) -> int:
-        """Number of XLA programs behind the serving step (the e2e test
-        asserts this stays <= 2: one decode trace + one prefill trace)."""
+        """Number of XLA programs behind the serving step: 1 once anything
+        ran, whatever the traffic (decode rows and the prompt chunk share
+        the one ``[max_batch_size + prefill_chunk, 1]`` trace)."""
         return int(self._step_fn._cache_size())
 
     # ------------------------------------------------------------------ #
@@ -629,34 +642,25 @@ class ServingEngine:
     # ------------------------------------------------------------------ #
     def step(self) -> Dict[str, Any]:
         """One engine step: expire deadlines, advance the shed ladder,
-        admit, run one prefill chunk, run one decode step over every
-        decode-ready sequence.  Returns the step stats.  A wedged compiled
-        dispatch raises :class:`ServeStepTimeout` *after* in-process
-        recovery (see :meth:`_recover_incident`)."""
+        admit, grow the decode-ready sequences, then run ONE program over
+        every decode-ready sequence and one prompt chunk, and commit both
+        from its one token row.  A request whose prompt ends in this step's
+        chunk gets its first token here and decodes from the next step.
+        Returns the step stats.  A wedged compiled dispatch raises
+        :class:`ServeStepTimeout` *after* in-process recovery (see
+        :meth:`_recover_incident`)."""
         with self._span("serve.admit") as sp:
             self._expire_deadlines()
             self._update_admission()
             sp.set(admitted=len(self.sched.admit(self._clock())))
-        prefill_tokens = 0
         t_step = time.monotonic() if self.registry is not None else 0.0
+        n_chunk, moe_stats = 0, {}
         try:
-            with self._span("serve.prefill.build") as sp:
-                pf = self.sched.next_prefill()
-                if pf is not None:
-                    req, start, n = pf
-                    at = {"rid": req.rid, "start": start, "tokens": n}
-                    sp.set(**at)
-                    inputs = self._prefill_inputs(req, start, n)
-            if pf is not None:
-                tokens = self._dispatch("prefill", inputs, at)
-                with self._span("serve.prefill.commit", **at):
-                    self._commit_prefill(req, n, tokens)
-                prefill_tokens = n
             with self._span("serve.grow") as sp:
                 # growth pass, oldest/strongest first: each decode step
                 # writes one token per sequence, so capacity must exist
                 # before the batch is built; eviction here removes victims
-                # from `active`
+                # from `active`, which is why the chunk is chosen after it
                 decode = sorted(self.sched.decode_batch(),
                                 key=lambda r: (r.priority, r.admit_seq))
                 for r in decode:
@@ -664,18 +668,45 @@ class ServingEngine:
                         self.sched.ensure_capacity(r, r.prefilled + 1)
                 decode = self.sched.decode_batch()
                 sp.set(batch=len(decode))
+            with self._span("serve.prefill.build") as sp:
+                pf = self.sched.next_prefill()
+                runs = pf is not None or bool(decode)   # else: no program
+                if runs:
+                    inputs = self._idle_inputs()
+                if pf is not None:
+                    req, start, n_chunk = pf
+                    chunk = {"rid": req.rid, "start": start,
+                             "tokens": n_chunk}
+                    sp.set(**chunk)
+                    self._chunk_rows(inputs, req, start, n_chunk)
             if decode:
+                # serve_decode_step_ms: a step with decode rows, from their
+                # build to their commit, so the step's ONE program with the
+                # chunk's rows and the chunk's commit; a step with a chunk
+                # alone is in serve_step_ms only
                 t_dec = (time.monotonic() if self.registry is not None
                          else 0.0)
-                at = {"batch": len(decode)}
-                with self._span("serve.decode.build", **at):
-                    inputs = self._decode_inputs(decode)
-                tokens = self._dispatch("decode", inputs, at)
+                with self._span("serve.decode.build", batch=len(decode)):
+                    self._decode_rows(inputs, decode)
+            if runs:
+                # one dispatch/fetch pair a program: named for the decode
+                # rows when it carries any (`batch`: every live row)
+                phase, at = (("decode", {"batch": len(decode) + n_chunk})
+                             if decode else ("prefill", chunk))
+                tokens = self._dispatch(phase, inputs,
+                                        dict(at, chunk_tokens=n_chunk))
                 moe_stats = self._moe_stats()
-                with self._span("serve.decode.commit", **at, **moe_stats):
+            if pf is not None:
+                with self._span("serve.prefill.commit", **chunk):
+                    # the chunk's last token is the row that yields the next
+                    self._commit_prefill(req, n_chunk, int(
+                        tokens[self._config.max_batch_size + n_chunk - 1]))
+            if decode:
+                with self._span("serve.decode.commit", batch=len(decode),
+                                **moe_stats):
                     for r in decode:
                         r.prefilled += 1      # the fed token's KV is resident
-                        self._append_token(r, int(tokens[r.slot, 0]))
+                        self._append_token(r, int(tokens[r.slot]))
                 if self.registry is not None:
                     self._h_decode.observe((time.monotonic() - t_dec) * 1e3)
         except ServeStepTimeout as err:
@@ -683,9 +714,8 @@ class ServingEngine:
             raise
         with self._span("serve.stats",
                         paged_tile_pages=self.paged_tile_pages):
-            stats = self._close_step(len(decode), prefill_tokens, t_step)
-            if decode:
-                stats.update(moe_stats)
+            stats = self._close_step(len(decode), n_chunk, int(runs), t_step)
+            stats.update(moe_stats)
             return stats
 
     def _moe_stats(self) -> Dict[str, float]:
@@ -698,7 +728,7 @@ class ServingEngine:
                 "moe_experts_touched": int((counts > 0).sum())}
 
     def _close_step(self, decode_batch: int, prefill_tokens: int,
-                    t_step: float) -> Dict[str, Any]:
+                    programs: int, t_step: float) -> Dict[str, Any]:
         """What a clean step ends with: the incident latch, the ledger, the
         stats dict, gauges and the periodic ``serve_step`` record."""
         if self._incident is not None:
@@ -714,7 +744,7 @@ class ServingEngine:
                                 offload_wait_s=self._restage_wait_ms / 1e3)
             self._restage_wait_ms = 0.0
         stats = dict(self.sched.stats(), decode_batch=decode_batch,
-                     prefill_tokens=prefill_tokens,
+                     prefill_tokens=prefill_tokens, programs=programs,
                      tokens_generated=self.tokens_generated,
                      shed_level=self.admission.level,
                      incidents=self.incident_count,
@@ -845,20 +875,30 @@ class ServingEngine:
         return futures
 
     # ------------------------------------------------------------------ #
-    def _prefill_inputs(self, req: Request, start: int, n: int):
-        """Host arrays of one prompt chunk; stamps the residency's first."""
+    def _idle_inputs(self):
+        """Host arrays (ids, positions, tables, write blocks, write offsets)
+        of a step in which no row carries anything: every slot and every
+        chunk row writes to the trash block through an all-trash table."""
+        R = self._config.max_batch_size + self._config.prefill_chunk
+        return (np.zeros((R, 1), np.int32), np.zeros((R,), np.int32),
+                np.zeros((R, self.max_blocks_per_seq), np.int32),
+                np.zeros((R, 1), np.int32), np.zeros((R, 1), np.int32))
+
+    def _chunk_rows(self, inputs, req: Request, start: int, n: int):
+        """One prompt chunk into the rows behind the slots, a token a row;
+        stamps the residency's first."""
         if req.prefill_started_at is None:
             req.prefill_started_at = self._clock()
         req.prefill_chunks += 1
-        C = self._config.prefill_chunk
-        ids = np.zeros((1, C), np.int32)
-        ids[0, :n] = req.context[start:start + n]
-        positions = np.asarray([start], np.int32)
-        tables = self.alloc.block_table(req.rid)[None]           # [1, MB]
-        wb, wo = self.alloc.write_map(req.rid, start, C, n_valid=n)
-        return ids, positions, tables, wb[None], wo[None]
+        ids, positions, tables, wb, wo = inputs
+        first = self._config.max_batch_size
+        rows = slice(first, first + n)
+        ids[rows, 0] = req.context[start:start + n]
+        positions[rows] = np.arange(start, start + n)
+        tables[rows] = self.alloc.block_table(req.rid)
+        wb[rows, 0], wo[rows, 0] = self.alloc.write_map(req.rid, start, n)
 
-    def _commit_prefill(self, req: Request, n: int, tokens):
+    def _commit_prefill(self, req: Request, n: int, token: int):
         req.prefilled += n
         if req.prefilled >= req.prefill_len:
             if self.prefix is not None and not self.admission.brownout:
@@ -871,24 +911,17 @@ class ServingEngine:
             # the chunk holding the last context token also yields the next
             # token — first-token latency includes no extra decode step
             req.state = DECODE
-            self._append_token(req, int(tokens[0, n - 1]))
+            self._append_token(req, token)
 
-    def _decode_inputs(self, reqs: List[Request]):
-        """Host arrays of one decode step over every slot."""
-        B = self._config.max_batch_size
-        MB = self.max_blocks_per_seq
-        ids = np.zeros((B, 1), np.int32)
-        positions = np.zeros((B,), np.int32)
-        tables = np.zeros((B, MB), np.int32)      # trash-only for idle slots
-        wb = np.zeros((B, 1), np.int32)
-        wo = np.zeros((B, 1), np.int32)
+    def _decode_rows(self, inputs, reqs: List[Request]):
+        """Every decode-ready sequence into the row of its slot."""
+        ids, positions, tables, wb, wo = inputs
         for r in reqs:
             s = r.slot
             ids[s, 0] = r.context[-1]
             positions[s] = r.prefilled
             tables[s] = self.alloc.block_table(r.rid)
             wb[s], wo[s] = self.alloc.write_map(r.rid, r.prefilled, 1)
-        return ids, positions, tables, wb, wo
 
     def _append_token(self, req: Request, tok: int):
         req.generated.append(tok)

@@ -26,7 +26,7 @@ kernel can DMA); this module owns the host-side bookkeeping:
 All methods are O(blocks touched); nothing here ever touches jax.
 """
 
-from typing import Dict, List, Optional
+from typing import Dict, List
 
 import numpy as np
 
@@ -179,25 +179,19 @@ class PagedKVAllocator:
         table[:len(owned)] = owned
         return table
 
-    def write_map(self, seq_id, start: int, n_tokens: int,
-                  n_valid: Optional[int] = None):
+    def write_map(self, seq_id, start: int, n_tokens: int):
         """Physical (block, offset) for tokens at logical positions
-        ``start .. start + n_tokens - 1``; positions past ``n_valid``
-        (pad tail of a bucketed prefill chunk) are routed to the trash
-        block.  → ([n_tokens] int32 blocks, [n_tokens] int32 offsets)."""
+        ``start .. start + n_tokens - 1``.
+        → ([n_tokens] int32 blocks, [n_tokens] int32 offsets)."""
         owned = self._owned.get(seq_id, ())
         pos = start + np.arange(int(n_tokens))
         logical = pos // self.block_size
-        nv = int(n_tokens) if n_valid is None else min(int(n_valid), int(n_tokens))
-        assert nv == 0 or logical[nv - 1] < max(len(owned), 1), (
-            f"write past allocation: pos {pos[nv - 1]} needs block "
-            f"{logical[nv - 1]}, own {len(owned)}")
+        assert not n_tokens or logical[-1] < max(len(owned), 1), (
+            f"write past allocation: pos {pos[-1]} needs block "
+            f"{logical[-1]}, own {len(owned)}")
         phys = np.asarray([owned[b] if b < len(owned) else self.TRASH
                            for b in logical], np.int32)
-        off = (pos % self.block_size).astype(np.int32)
-        if n_valid is not None and n_valid < n_tokens:
-            phys[n_valid:] = self.TRASH
-        return phys, off
+        return phys, (pos % self.block_size).astype(np.int32)
 
     # -- invariants (tests) ------------------------------------------------ #
     def check_consistent(self):

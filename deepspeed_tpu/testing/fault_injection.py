@@ -82,7 +82,8 @@ SITES = (
     "engine.create", "engine.save", "engine.post_save", "engine.commit",
     "engine.load",
     # serving resilience plane: `serve.step` fires inside the bounded
-    # compiled-step dispatch (ctx: step, phase=prefill|decode) — wedge it
+    # compiled-step dispatch (ctx: step; phase=decode when the one program
+    # carries a decode row, prefill when a prompt chunk alone) — wedge it
     # to drive a ServeStepTimeout incident; `serve.restage` fires before a
     # tiered KV restore (ctx: rid) — raise to force the recompute fallback
     "serve.step", "serve.restage",

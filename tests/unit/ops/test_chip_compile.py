@@ -177,6 +177,21 @@ def test_paged_kernel_compiles_at_olmoe_heads(chip, rows, Sq):
     assert da.paged_kernel_tile_pages(Sq, H, H, D128, BS, MB, BF16) == 8
 
 
+@pytest.mark.parametrize("slots,H,head_dim,MB", [(256, 12, 64, 64), (128, 16, 128, 256)],
+                         ids=["gpt2-124m", "olmoe-1b-7b"])
+def test_paged_kernel_compiles_at_a_serve_steps_rows(chip, slots, H, head_dim, MB):
+    """The one program of a serve step: every decode slot and every token of
+    the prompt chunk is a single-query row, ``slots + CHUNK`` of them, and
+    their flattened block tables (320 x 64 and 192 x 256 int32: 80 and 192
+    KiB) are the kernel's scalar prefetch, at the benchmark cells' sizes."""
+    rows, BS = slots + CHUNK, 16
+    pages = ((1025, BS, H * head_dim), BF16)
+    text = _compiled_text(chip, da.paged_attention, ((rows, 1, H, head_dim), BF16),
+                          pages, pages, ((rows, MB), jnp.int32), ((rows,), jnp.int32))
+    assert "tpu_custom_call" in text
+    assert da.paged_kernel_tile_pages(1, H, H, head_dim, BS, MB, BF16) == 8
+
+
 @pytest.mark.parametrize("rows", [1024, 512])
 @pytest.mark.parametrize("K,N", [(2048, 2048), (1024, 2048)])
 def test_grouped_matmul_compiles_at_olmoe_bank(chip, monkeypatch, rows, K, N):

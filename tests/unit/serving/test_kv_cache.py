@@ -75,7 +75,7 @@ def test_max_blocks_per_seq_raises():
         a.allocate("s", 12)            # 3 blocks > max 2
 
 
-def test_write_map_positions_and_pad_tail():
+def test_write_map_positions():
     a = make(block_size=4)
     a.allocate("s", 12)
     tbl = a.block_table("s")
@@ -83,9 +83,10 @@ def test_write_map_positions_and_pad_tail():
     # logical positions 5..8 -> (block 1, off 1..3) then (block 2, off 0)
     assert list(offs) == [1, 2, 3, 0]
     assert list(blocks) == [tbl[1], tbl[1], tbl[1], tbl[2]]
-    # padded prefill chunk: the invalid tail routes to the trash block
-    blocks, offs = a.write_map("s", 8, 4, n_valid=2)
-    assert (blocks[:2] == tbl[2]).all() and (blocks[2:] == 0).all()
+    # a short last chunk maps its own tokens and nothing past them (the
+    # rows behind it stay on the trash block: the engine never asks)
+    blocks, offs = a.write_map("s", 8, 2)
+    assert (blocks == tbl[2]).all() and list(offs) == [0, 1]
 
 
 def test_write_past_allocation_asserts():

@@ -48,9 +48,11 @@ def test_mid_run_scrape_shows_live_serving_metrics(tiny_model, tmp_path):
     prompts = [list(rng.integers(1, 128, size=n)) for n in (5, 9, 7, 12)]
     futs = [eng.submit(p, max_new_tokens=6) for p in prompts]
 
-    # drive until half the requests finished, then scrape MID-RUN:
-    # the engine is still holding arena blocks and decoding
-    while sum(f.done for f in futs) < 2:
+    # drive until half the requests finished and the periodic flush has
+    # drained their events, then scrape MID-RUN: the engine is still holding
+    # arena blocks and decoding
+    while (sum(f.done for f in futs) < 2
+           or eng.step_count % scfg.telemetry_every):
         eng.step()
     assert not all(f.done for f in futs)
 
@@ -72,7 +74,7 @@ def test_mid_run_scrape_shows_live_serving_metrics(tiny_model, tmp_path):
 
     eng.run()
     assert all(f.done for f in futs)
-    assert eng.compiled_programs() <= 2     # instrumentation stayed host-side
+    assert eng.compiled_programs() == 1     # instrumentation stayed host-side
 
     # post-run: drained counters agree with the scheduler's view
     hub.flush()

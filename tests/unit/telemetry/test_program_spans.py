@@ -165,14 +165,21 @@ def _engine(tiny_model, tracer=None, **over):
 
 
 def test_leaf_spans_tile_the_step(tiny_model):
+    """Every leaf is a sibling, in the order of ``SERVE_STEP_SPANS``, and what
+    lies under no leaf in a warm step is the spans' own bookkeeping, a few
+    microseconds at each of the step's eight to ten boundaries: a fixed cost
+    that does not grow with the program, so it is held to a budget in
+    microseconds (on the chip a step is tens of milliseconds).  As a share of
+    this toy's 1.7 ms step it is 7 to 10%, one program a step or two; the
+    share read 0.99 here only while a compile fell inside the steps counted."""
     tr = Tracer()
     eng = _engine(tiny_model, tracer=tr)
     rng = np.random.default_rng(0)
     for n in (20, 5, 11):
         eng.submit(list(rng.integers(1, 128, size=n)), max_new_tokens=6)
-    eng.step()                                  # compiles both programs
+    eng.step()                                  # compiles the program
     eng.step()
-    covered = whole = 0.0
+    uncovered_us = []
     seen = set()
     for _ in range(6):
         mark = len(tr.snapshot())
@@ -186,34 +193,84 @@ def test_leaf_spans_tile_the_step(tiny_model):
         assert [r["name"] for r in step] == [
             n for n in SERVE_STEP_SPANS if n in {r["name"] for r in step}], \
             "in the order the work happens"
+        assert t0 <= step[0]["t0"] and step[-1]["t1"] <= t1
         seen |= {r["name"] for r in step}
-        covered += sum(r["t1"] - r["t0"] for r in step)
-        whole += t1 - t0
+        uncovered_us.append(((t1 - t0) - sum(r["t1"] - r["t0"] for r in step)) / 1e3)
+    # steps 3..8: the first prompt's last chunk alone (the pair that names a
+    # program with no decode row), then chunks beside decode rows, then
+    # decode rows alone
     assert seen == set(SERVE_STEP_SPANS)
-    assert covered / whole >= 0.95, covered / whole
+    assert sorted(uncovered_us)[len(uncovered_us) // 2] <= 250.0, uncovered_us
     eng.close()
+
+
+def _step_spans(tr, eng):
+    """{span name: [args of each event]} of one ``eng.step()``, and its stats."""
+    mark = len(tr.snapshot())
+    stats = eng.step()
+    by = {}
+    for r in tr.snapshot()[mark:]:
+        by.setdefault(r["name"], []).append(r["args"])
+    return by, stats
 
 
 def test_spans_carry_counts_where_the_work_happens(tiny_model):
     tr = Tracer()
     eng = _engine(tiny_model, tracer=tr)
     fut = eng.submit(list(range(1, 13)), max_new_tokens=3)
-    eng.step()
-    by = {r["name"]: r["args"] for r in tr.snapshot()}
-    assert by["serve.submit"] is None
-    assert by["serve.admit"] == {"admitted": 1}
+    (submitted,) = tr.snapshot()
+    assert (submitted["name"], submitted["args"]) == ("serve.submit", None)
+    by, stats = _step_spans(tr, eng)            # a chunk, and no decode row yet
+    assert by["serve.admit"] == [{"admitted": 1}]
     chunk = {"rid": fut.request.rid, "start": 0, "tokens": 8}
-    for name in ("serve.prefill.build", "serve.prefill.dispatch",
-                 "serve.prefill.fetch", "serve.prefill.commit"):
-        assert by[name] == chunk, name
-    assert by["serve.grow"] == {"batch": 0} and "serve.decode.build" not in by
-    eng.step()                                  # last chunk, then the first decode
-    by = {r["name"]: r["args"] for r in tr.snapshot()}
-    for name in ("serve.decode.build", "serve.decode.dispatch",
-                 "serve.decode.fetch", "serve.decode.commit"):
-        assert by[name] == {"batch": 1}, name
+    for name in ("serve.prefill.build", "serve.prefill.commit"):
+        assert by[name] == [chunk], name
+    for name in ("serve.prefill.dispatch", "serve.prefill.fetch"):
+        assert by[name] == [dict(chunk, chunk_tokens=8)], name
+    assert by["serve.grow"] == [{"batch": 0}]
+    assert not any(n.startswith("serve.decode.") for n in by)
+    assert (stats["programs"], stats["prefill_tokens"], stats["decode_batch"]) == (1, 8, 0)
+    eng.step()                                  # last chunk: the first token
+    by, stats = _step_spans(tr, eng)            # the first decode step
+    for name in ("serve.decode.build", "serve.decode.commit"):
+        assert by[name] == [{"batch": 1}], name
+    for name in ("serve.decode.dispatch", "serve.decode.fetch"):
+        assert by[name] == [{"batch": 1, "chunk_tokens": 0}], name
+    assert by["serve.prefill.build"] == [None]
+    assert not any(n in by for n in ("serve.prefill.dispatch", "serve.prefill.fetch",
+                                     "serve.prefill.commit"))
     # which paged kernel the engine runs (0: the einsum, as on this CPU)
-    assert by["serve.stats"] == {"paged_tile_pages": eng.paged_tile_pages}
+    assert by["serve.stats"] == [{"paged_tile_pages": eng.paged_tile_pages}]
+    assert (stats["programs"], stats["prefill_tokens"], stats["decode_batch"]) == (1, 0, 1)
+    fut.result()
+    assert eng.step()["programs"] == 0          # nothing to run: no program
+    eng.close()
+
+
+def test_a_chunk_beside_decode_rows_is_one_dispatch_and_one_fetch(tiny_model):
+    """The step's one program is named for its decode rows; its ``batch``
+    counts every row that carries a request, the chunk's tokens included
+    (``benchmarks/readers/moe.py`` takes it for the rows of one bank call)."""
+    tr = Tracer()
+    eng = _engine(tiny_model, tracer=tr)
+    first = eng.submit([5, 6, 7], max_new_tokens=8)
+    eng.step()                                  # its whole prompt: first token
+    late = eng.submit(list(range(1, 12)), max_new_tokens=4)
+    for start, n in ((0, 8), (8, 3)):
+        by, stats = _step_spans(tr, eng)
+        chunk = {"rid": late.request.rid, "start": start, "tokens": n}
+        dispatched = [n_ for n_ in by if n_.endswith((".dispatch", ".fetch"))]
+        assert sorted(dispatched) == ["serve.decode.dispatch", "serve.decode.fetch"]
+        for name in dispatched:
+            assert by[name] == [{"batch": 1 + n, "chunk_tokens": n}], name
+        assert by["serve.prefill.build"] == by["serve.prefill.commit"] == [chunk]
+        assert by["serve.decode.build"] == by["serve.decode.commit"] == [{"batch": 1}]
+        assert (stats["programs"], stats["prefill_tokens"], stats["decode_batch"]) \
+            == (1, n, 1)
+    assert len(late.request.generated) == 1, "decodes from the next step"
+    assert len(first.request.generated) == 3
+    eng.run()
+    assert eng.compiled_programs() == 1
     eng.close()
 
 
@@ -268,7 +325,7 @@ def test_first_token_stats_sum_to_the_programs_ttft(tiny_model):
 
 def test_first_token_is_on_the_profilers_line_with_its_stats(tiny_model, tmp_path):
     eng = _engine(tiny_model)
-    eng.submit([1, 2, 3], max_new_tokens=2).result()      # both programs warm
+    eng.submit([1, 2, 3], max_new_tokens=2).result()      # the program warm
     fut = eng.submit(list(range(1, 12)), max_new_tokens=2)
     events = _capture(tmp_path, fut.result)
     assert set(SERVE_STEP_SPANS) <= set(events)

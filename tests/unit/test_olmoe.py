@@ -155,36 +155,44 @@ SERVING = {"block_size": 8, "num_blocks": 64, "max_batch_size": 4,
 
 
 def test_prefill_in_chunks_then_decode_through_the_engine(tiny):
-    """Three prompt chunks (the last padded), then decode beside a second
-    request and two idle slots: every served token is the reference's best
-    by its full forward pass, teacher-forced, within ``TOL`` of logit."""
+    """Three prompt chunks (the last short), then the second request's chunk
+    in one program with the first's decode row, then both decode beside two
+    idle slots: every served token is the reference's best by its full
+    forward pass, teacher-forced, within ``TOL`` of logit."""
     model, params = tiny
     eng = deepspeed_tpu.init_serving(model=model, params=params,
                                      config={"serving": SERVING})
     prompts = [list(map(int, _ids(19, seed=6))), list(map(int, _ids(5, seed=7)))]
     futures = [eng.submit(p, max_new_tokens=n) for p, n in zip(prompts, (12, 7))]
-    stats = []
+    stats, assigned = [], []
     while not all(f.done for f in futures):
         stats.append(eng.step())
+        assigned.append(int(eng._expert_counts.sum()))
     assert futures[0].request.prefill_chunks == 3
-    # the expert counts came back in the token row's fetch: still one program
-    # a phase, each with one int32 array (and the arena) as its result
-    assert eng.compiled_programs() == 2
-    out = jax.eval_shape(eng._raw_step_fn, eng.params, jnp.zeros((4, 1), jnp.int32),
-                         jnp.zeros((4,), jnp.int32), eng._k_pages, eng._v_pages,
-                         jnp.zeros((4, 16), jnp.int32), jnp.zeros((4, 1), jnp.int32),
-                         jnp.zeros((4, 1), jnp.int32))[0]
-    assert out.shape == (4 + 8,) and out.dtype == jnp.int32
+    # the one program's counts cover every row that carries a request (the
+    # decode rows and the chunk's tokens: k assignments a layer each) and no
+    # idle slot, no row past the chunk's tokens
+    k, layers = model.cfg.moe_top_k, model.cfg.n_layer
+    assert assigned == [(s["decode_batch"] + s["prefill_tokens"]) * k * layers
+                        for s in stats]
+    assert any(s["decode_batch"] and 0 < s["prefill_tokens"] < 8 for s in stats)
+    # the expert counts came back in the token row's fetch: still the one
+    # program, with one int32 array (and the arena) as its result
+    assert eng.compiled_programs() == 1
+    rows = 4 + 8                                  # slots + the chunk's rows
+    out = jax.eval_shape(eng._raw_step_fn, eng.params, jnp.zeros((rows, 1), jnp.int32),
+                         jnp.zeros((rows,), jnp.int32), eng._k_pages, eng._v_pages,
+                         jnp.zeros((rows, 16), jnp.int32), jnp.zeros((rows, 1), jnp.int32),
+                         jnp.zeros((rows, 1), jnp.int32))[0]
+    assert out.shape == (rows + 8,) and out.dtype == jnp.int32
     for prompt, f in zip(prompts, futures):
         seq = jnp.asarray(prompt + f.result())
         logits = olmoe_logits(params, seq, **REF)
         for t in range(len(prompt) - 1, len(seq) - 1):
             gap = float(logits[t].max() - logits[t, seq[t + 1]])
             assert gap < TOL, (t, gap)
-    decode = [s for s in stats if s["decode_batch"]]
-    assert decode and all(1.0 <= s["moe_load_max_over_mean"] <= 8.0
-                          and 1 <= s["moe_experts_touched"] <= 8 for s in decode)
-    assert all("moe_load_max_over_mean" not in s for s in stats if not s["decode_batch"])
+    assert all(s["programs"] == 1 and 1.0 <= s["moe_load_max_over_mean"] <= 8.0
+               and 1 <= s["moe_experts_touched"] <= 8 for s in stats)
     eng.close()
 
 

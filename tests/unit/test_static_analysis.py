@@ -59,7 +59,7 @@ class TestRepoClean:
                                         "pallas-discipline", "jaxpr",
                                         "stale-pragma"]
         jx = report["meta"]["jaxpr"]
-        for program in ("layered-step", "bulk-step", "serving-decode"):
+        for program in ("layered-step", "bulk-step", "serving-step"):
             assert jx[program]["clean"] is True, jx[program]
         # the layered step really contains collectives (the check is not
         # vacuous), and their extracted order is the cross-shard proof

@@ -3,7 +3,7 @@
 The AST passes read what the source *says*; this pass reads what the
 compiler *gets*.  It builds the three tentpole step programs on an
 8-virtual-device CPU mesh — the layered ZeRO-3 training step, the bulk
-explicit-collective step, and the paged serving decode step — traces
+explicit-collective step, and the paged serving step — traces
 each to a jaxpr with :func:`jax.make_jaxpr` (no compilation, no
 execution), and asserts two structural properties:
 
@@ -230,7 +230,7 @@ def trace_programs() -> Dict[str, object]:
         eng_b.state.params, batch_b, eng_b._next_rng(),
         eng_b.state.scaler.scale)
 
-    # -- paged serving decode step -------------------------------------- #
+    # -- paged serving step (decode rows + prompt chunk) ------------------ #
     import jax.numpy as jnp
     from deepspeed_tpu.models.gpt import GPT, GPTConfig
     from deepspeed_tpu.serving import DeepSpeedServingConfig, ServingEngine
@@ -240,8 +240,8 @@ def trace_programs() -> Dict[str, object]:
         smodel, DeepSpeedServingConfig(block_size=8, num_blocks=128,
                                        max_batch_size=8, prefill_chunk=16,
                                        dtype="float32"), seed=0)
-    B, MB = 8, srv.max_blocks_per_seq
-    out["serving-decode"] = jax.make_jaxpr(srv._step_fn)(
+    B, MB = 8 + 16, srv.max_blocks_per_seq     # slots + the chunk's rows
+    out["serving-step"] = jax.make_jaxpr(srv._step_fn)(
         srv.params, jnp.zeros((B, 1), jnp.int32), jnp.zeros((B,), jnp.int32),
         srv._k_pages, srv._v_pages, jnp.zeros((B, MB), jnp.int32),
         jnp.zeros((B, 1), jnp.int32), jnp.zeros((B, 1), jnp.int32))
