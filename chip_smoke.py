@@ -32,6 +32,7 @@ import os
 import re
 import sys
 import time
+from unittest import mock
 
 # The real sizes.  A rehearsal on the CPU mesh replaces these, the platform
 # check and ``kernels_in`` from its own script (.claude/skills/verify).
@@ -215,6 +216,7 @@ def first_step(model, micro, seed, batch):
 def train_phase(seed):
     import deepspeed_tpu
     from deepspeed_tpu.models.gpt import GPT, gpt_config
+    from deepspeed_tpu.ops import pallas
     from deepspeed_tpu.parallel import mesh as mesh_lib
 
     micro, seq = TRAIN["micro"], TRAIN["seq"]
@@ -246,21 +248,17 @@ def train_phase(seed):
     mesh_lib.reset_mesh()
 
     # the same first steps on the reference paths: jnp attention, the XLA
-    # cross-entropy and the optax update, chosen here with what the program
-    # already has (attn_impl and the kernels' existing opt-outs).  remat
-    # changes no value: without it the jnp attention keeps [B, H, S, S]
-    # per layer and the step needs 15.6 GB of the chip's 16.
-    ref_cfg = gpt_config(**MODEL, attn_impl="reference", remat=True)
-    opt_outs = {"DST_PALLAS_CE": "0", "DST_PALLAS_FUSED_OPT": "0"}
-    os.environ.update(opt_outs)
-    try:
+    # cross-entropy and the optax update, chosen here by replacing
+    # ``ops.pallas``'s selection rule while the step is built and traced
+    # (the program has no switch for it).  remat changes no value: without
+    # it the jnp attention keeps [B, H, S, S] per layer and the step needs
+    # 15.6 GB of the chip's 16.
+    ref_cfg = gpt_config(**MODEL, remat=True)
+    with mock.patch.object(pallas, "use_kernel", lambda name: False):
         ref_engine, _, _, _ = deepspeed_tpu.initialize(
             model=GPT(ref_cfg), config=train_config(micro), seed=seed)
         ref_losses, _ = run_steps(ref_engine, batches[:TRAIN["ref_steps"]])
         ref_kernels = kernels_in(compiled_fused_step(ref_engine, batches[0]))
-    finally:
-        for name in opt_outs:
-            del os.environ[name]
     check(not ref_kernels, f"reference step holds kernels: {ref_kernels}")
     diffs = [abs(a - b) for a, b in zip(losses, ref_losses)]
     check(max(diffs) <= LOSS_TOL,

@@ -178,7 +178,6 @@ class ClosedLoopAutotuner:
                 break
             cfg = apply_patch(self.base_config, cand.patch)
             res = self.scheduler.run_trial(cand.cid, cfg,
-                                           extra_env=cand.env(),
                                            patch=cand.patch,
                                            knobs=cand.knobs)
             self.trials.append(res)
@@ -209,12 +208,8 @@ class ClosedLoopAutotuner:
         if self.best is None:
             return None
         cfg = apply_patch(self.base_config, self.best.patch)
-        cand_env = {k[len("env."):]: str(v)
-                    for k, v in self.best.patch.items()
-                    if k.startswith("env.")}
         self.verification = self.scheduler.run_trial(
-            "verify", cfg, extra_env=cand_env, patch=self.best.patch,
-            knobs=self.best.knobs)
+            "verify", cfg, patch=self.best.patch, knobs=self.best.knobs)
         self.write_artifacts()
         return self.verification
 

@@ -5,11 +5,9 @@ closed loop searches the knobs that actually move goodput on the
 PR 1-18 stack — each declared as a :class:`Knob` with its dotted
 ``ds_config`` path, candidate values, and an optional coherence guard so
 the cartesian product never emits configs the engine would reject for
-structural (not memory) reasons.  Three path namespaces:
+structural (not memory) reasons.  Two path namespaces:
 
 * ``a.b.c``  — nested ``ds_config`` key, applied with ``set_nested``;
-* ``env.X``  — an environment variable for the trial subprocess (the
-  fused-kernel gates ``DST_PALLAS_*`` are env-scoped, not config keys);
 * ``mesh``   — the whole mesh-axes dict (mesh shape is one knob whose
   value is the axis mapping, not six independent knobs that would
   mostly multiply to the wrong device count).
@@ -28,10 +26,6 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from deepspeed_tpu.autotuning.utils import set_nested
 
-#: trial-subprocess env namespace inside a patch
-ENV_PREFIX = "env."
-
-
 @dataclass(frozen=True)
 class Knob:
     """One tunable axis: a name, the config path it patches, and the
@@ -41,7 +35,7 @@ class Knob:
     name: str
     path: str
     values: Tuple[Any, ...]
-    kind: str = "runtime"            # mesh|zero|batch|offload|kernel|serving
+    kind: str = "runtime"            # mesh|zero|batch|offload|serving
     only_if: Optional[Dict[str, Tuple[Any, ...]]] = None
 
     def guard_ok(self, chosen: Dict[str, Any]) -> bool:
@@ -86,10 +80,6 @@ KNOB_CATALOG: Tuple[Knob, ...] = (
          (None, "cpu", "nvme"), kind="offload", only_if={"zero_stage": (3,)}),
     Knob("offload_optimizer", "zero_optimization.offload_optimizer.device",
          (None, "cpu", "nvme"), kind="offload", only_if={"zero_stage": (3,)}),
-    # fused-kernel gates (env-scoped tri-state: unset = TPU-only default)
-    Knob("pallas_ce", "env.DST_PALLAS_CE", ("0", "1"), kind="kernel"),
-    Knob("pallas_fused_opt", "env.DST_PALLAS_FUSED_OPT", ("0", "1"),
-         kind="kernel"),
     # serving arena / chunked prefill
     Knob("serve_num_blocks", "serving.num_blocks", (128, 256, 512),
          kind="serving"),
@@ -109,22 +99,11 @@ class UnknownKnobError(ValueError):
 class Candidate:
     """One point of the search space: the normalized config patch."""
     cid: str
-    patch: Dict[str, Any]            # dotted path -> value (incl. env.*)
+    patch: Dict[str, Any]            # dotted path -> value
     knobs: Dict[str, Any] = field(default_factory=dict)   # name -> value
 
     def key(self) -> str:
         return json.dumps(self.patch, sort_keys=True, default=str)
-
-    def env(self) -> Dict[str, str]:
-        """The env-var slice of the patch (trial subprocess scope)."""
-        return {p[len(ENV_PREFIX):]: str(v)
-                for p, v in self.patch.items()
-                if p.startswith(ENV_PREFIX) and v is not None}
-
-    def config_patch(self) -> Dict[str, Any]:
-        """The ds_config slice of the patch (dotted paths)."""
-        return {p: v for p, v in self.patch.items()
-                if not p.startswith(ENV_PREFIX)}
 
 
 class SearchSpace:
@@ -190,12 +169,9 @@ class SearchSpace:
 
 def apply_patch(base_config: Dict, patch: Dict[str, Any]) -> Dict:
     """Base ds_config + dotted-path patch -> the trial config (deep copy;
-    ``env.*`` entries are skipped — they scope to the subprocess, and a
-    ``mesh`` whole-dict value replaces the mesh block)."""
+    a ``mesh`` whole-dict value replaces the mesh block)."""
     cfg = copy.deepcopy(base_config)
     for path, value in patch.items():
-        if path.startswith(ENV_PREFIX):
-            continue
         if path == "mesh" and isinstance(value, dict):
             cfg["mesh"] = dict(value)
             continue
@@ -214,10 +190,5 @@ def patch_diff(base_config: Dict, patch: Dict[str, Any]) -> Dict[str, Dict]:
             cur = cur[part]
         return cur
 
-    diff = {}
-    for path, value in sorted(patch.items()):
-        if path.startswith(ENV_PREFIX):
-            diff[path] = {"from": None, "to": value}
-        else:
-            diff[path] = {"from": _get(base_config, path), "to": value}
-    return diff
+    return {path: {"from": _get(base_config, path), "to": value}
+            for path, value in sorted(patch.items())}

@@ -12,7 +12,7 @@ before JAX initializes — same trick as ``tests/conftest.py``).
 
 Timing: a K-deep chain of collectives inside one jitted ``fori_loop``, ended
 by a single scalar fetch; two chain lengths are differenced so dispatch and
-host round-trip costs cancel (the ``bench.py`` methodology).
+host round-trip costs cancel.
 """
 
 import argparse

@@ -31,7 +31,6 @@ TPU-first design decisions:
 
 import dataclasses
 import math
-import os
 from functools import partial
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -791,19 +790,19 @@ def chunked_cross_entropy(x: Array, head: Array, labels: Array,
     B, S, E = x.shape
     V = head.shape[0]
     N = B * S
+    from deepspeed_tpu.ops import pallas as _pallas
     from deepspeed_tpu.ops.pallas import cross_entropy as _pce
-    if _pce.pallas_ce_enabled() and _pce.ce_supported(N, E, V):
+    if _pallas.use_kernel("ce") and _pce.ce_supported(N, E, V):
         return _pce.fused_cross_entropy(x.reshape(N, E), head,
                                         labels.reshape(N), vocab_size,
                                         head_b=head_b)
     if n_chunks <= 0:
         # chunking trades ~1/3 extra head FLOPs (backward recompute) for
-        # the [N, V] memory.  Measured on v5e (r5): chunking LOSES while the
-        # block fits (micro 8 x 512 x 50k = 823 MiB: 90.6 unchunked vs 84.6
-        # chunked TFLOPs end-to-end) — the recompute costs more than the
-        # saved traffic — so the default only chunks past ~900 MiB, where
-        # capacity (OOM at micro 24+) forces it
-        threshold = int(os.environ.get("DST_CE_CHUNK_MIB", "900")) * 2 ** 20
+        # the [N, V] memory, so only chunk past 900 MiB, where capacity
+        # forces it (micro 8 x 512 x 50k = 823 MiB stays whole).  The
+        # threshold is not measured since the record it came from went
+        # (ROADMAP D10); S3 decides
+        threshold = 900 * 2 ** 20
         if N * V * 4 <= threshold:
             n_chunks = 1
         else:

@@ -94,11 +94,12 @@ def reference_attention(q, k, v, *, causal: bool = True, bias=None, alibi=None):
 
 
 def flash_attention(q, k, v, *, causal: bool = True, bias=None, alibi=None):
-    """Pallas flash attention on TPU (grouped-KV + bias/alibi native).  On
-    the cpu platform (the test mesh) this is the reference path: the
-    interpreted kernel is orders of magnitude slower than the einsum."""
+    """Pallas flash attention on TPU (grouped-KV + bias/alibi native); the
+    reference path where ``ops.pallas``'s rule says kernels do not run (the
+    cpu test mesh: the interpreted kernel is orders of magnitude slower
+    than the einsum)."""
     from deepspeed_tpu.ops import pallas
-    if pallas.platform() == "cpu":
+    if not pallas.use_kernel("flash_attention"):
         return reference_attention(q, k, v, causal=causal, bias=bias, alibi=alibi)
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention as fa
     return fa(q, k, v, causal=causal, bias=bias, alibi=alibi)

@@ -19,16 +19,13 @@ only by the online-softmax rescale rounding (≤ a few ulp).  Masked padded
 vocab columns use the same ``-1e9`` sentinel as the reference so the two
 paths mask identically.
 
-Env: ``DST_PALLAS_CE`` — ``1``/``on`` force-enables (interpret mode makes
-this valid on CPU), ``0``/``off`` disables, unset enables on TPU backends
-only.  The wrapper in ``models/gpt.py`` falls back to the reference
-implementation whenever :func:`ce_supported` says the shape or mesh
-doesn't fit (vocab not a multiple of 128, multi-device mesh — a bare
-``pallas_call`` has no SPMD partitioning rule).
+The wrapper in ``models/gpt.py`` takes this kernel where
+``ops.pallas.use_kernel("ce")`` says kernels run and :func:`ce_supported`
+admits the shape and mesh (vocab a multiple of 128, one device), and the
+reference implementation everywhere else.
 """
 
 import functools
-import os
 from typing import Optional
 
 import numpy as np
@@ -44,16 +41,6 @@ _ROW_BLOCK = 128          # fp32 sublane-multiple; rows are padded up to it
 _VMEM_BLOCK_BYTES = 4 << 20   # budget for one [bv, E] head block in VMEM
 
 
-def pallas_ce_enabled() -> bool:
-    """Tri-state ``DST_PALLAS_CE``: forced on/off, else on-if-TPU."""
-    flag = os.environ.get("DST_PALLAS_CE", "").strip().lower()
-    if flag in ("0", "off", "false"):
-        return False
-    if flag in ("1", "on", "true"):
-        return True
-    return _pallas.platform() == "tpu"
-
-
 def _vocab_block(V: int, E: int) -> Optional[int]:
     for bv in (2048, 1024, 512, 256, 128):
         if V % bv == 0 and bv * max(E, 1) * 4 <= _VMEM_BLOCK_BYTES:
@@ -65,14 +52,12 @@ def ce_supported(N: int, E: int, V: int) -> bool:
     """Shape + mesh gate for the fused path.  The kernel handles any row
     count (rows pad to the block) but needs the vocab to tile into lane
     blocks, and runs un-sharded — under a >1-device mesh the vocab is
-    tensor-parallel and the reference path (which XLA partitions) wins."""
-    if _vocab_block(V, E) is None:
-        return False
+    tensor-parallel and the reference path (which XLA partitions) wins,
+    except inside a manual (``shard_map``) region, where the arrays are
+    already one device's."""
     from deepspeed_tpu.parallel import mesh as mesh_lib
-    if mesh_lib.has_mesh() and not mesh_lib.in_manual_mode():
-        if int(np.prod(list(mesh_lib.get_mesh().shape.values()))) > 1:
-            return False
-    return True
+    return (_vocab_block(V, E) is not None
+            and (_pallas.single_device() or mesh_lib.in_manual_mode()))
 
 
 # --------------------------------------------------------------------------- #

@@ -40,7 +40,6 @@ and a run on the chip through ``chip_smoke.py`` (CHANGES.md PR 21).
 """
 
 import functools
-import os
 from typing import Optional
 
 import jax
@@ -54,18 +53,6 @@ from deepspeed_tpu.ops import pallas as _pallas
 
 NEG_INF = -1e30
 _LANES = 128
-
-
-def _kernel_wanted(env_name: str) -> bool:
-    """``DST_PALLAS_DECODE`` / ``DST_PALLAS_PAGED``: ``0`` opts out, ``1``
-    forces the kernel (through the interpreter on CPU, for parity tests);
-    unset, the kernel runs on TPU and the einsum on CPU, where the
-    interpreter is orders of magnitude slower than the einsum it would
-    replace.  Necessary, not sufficient: :func:`kernel_shape_ok` decides."""
-    env = os.environ.get(env_name)
-    if env in ("0", "1"):
-        return env == "1"
-    return _pallas.platform() == "tpu"
 
 
 def kernel_shape_ok(H: int, Hkv: int, D: int, block: int, dtype) -> bool:
@@ -470,13 +457,13 @@ def _mesh_divisors():
 
 def paged_kernel_tile_pages(Sq, H, Hkv, D, BS, MB, dtype, bias=False) -> int:
     """The ``G`` of the kernel :func:`paged_attention` builds for these
-    shapes under the mesh and the ``DST_PALLAS_PAGED`` of now; 0 where it
-    takes the jnp gather reference (a bias, GQA, lanes the gate refuses, a
-    sharded mesh, the kernel opted out).  The serving engine reports it as
-    the ``paged_tile_pages`` of its stats."""
-    if (bias or not _kernel_wanted("DST_PALLAS_PAGED")
+    shapes under the mesh of now; 0 where it takes the jnp gather reference
+    (a bias, GQA, lanes the gate refuses, a mesh of several devices, a
+    platform that is not a TPU).  The serving engine reports it as the
+    ``paged_tile_pages`` of its stats."""
+    if (bias or not _pallas.use_kernel("paged_attention")
             or not kernel_shape_ok(H, Hkv, D, BS, dtype)
-            or _mesh_divisors() != (1, 1)):
+            or not _pallas.single_device()):
         return 0
     return paged_tile_pages(BS, MB, Sq, Hkv * D, dtype)
 
@@ -506,7 +493,7 @@ def decode_attention(q, ck, cv, pos, bias=None, *,
     T = ck.shape[1]
     bk = block_k or min(128, T)
     batch_div, tp = _mesh_divisors()
-    if (bias is not None or not _kernel_wanted("DST_PALLAS_DECODE")
+    if (bias is not None or not _pallas.use_kernel("decode_attention")
             or T % bk != 0 or B % batch_div != 0 or H % tp != 0
             or not kernel_shape_ok(H // tp, ck.shape[2] // D // tp, D, bk,
                                    ck.dtype)):

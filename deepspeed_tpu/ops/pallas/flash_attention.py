@@ -60,7 +60,6 @@ analogue of the reference's kernel-vs-HF-modeling parity tests
 """
 
 import functools
-import os
 from typing import Optional
 
 import jax
@@ -101,7 +100,7 @@ def _block_sizes(S: int, bq: Optional[int], bk: Optional[int]):
     S=512 (fewer online-softmax rescales, larger MXU tiles) and also wins
     at S=1024 over (256, 1024).
 
-    Requested sizes (user/env) are CLAMPED to the largest divisor of S at
+    Requested sizes (the caller's) are CLAMPED to the largest divisor of S at
     most the request — never asserted on — so an odd S degrades to a
     smaller block or to the reference fallback instead of crashing.  For
     S below the cap this yields the full-S block, which is always a legal
@@ -472,8 +471,6 @@ def flash_attention(q, k, v, *, causal: bool = True, bias=None, alibi=None,
     (sequence-sharded inputs are thereby Ulysses-re-sharded to full-seq,
     split-head form before the kernel — see module docstring)."""
     from deepspeed_tpu.ops.attention import canonical_bias
-    block_q = block_q or int(os.environ.get("DST_FLASH_BQ", "0")) or None
-    block_k = block_k or int(os.environ.get("DST_FLASH_BK", "0")) or None
     B, S, H, D = q.shape
     Hkv = k.shape[2]
     block_q, block_k = _block_sizes(S, block_q, block_k)

@@ -20,12 +20,11 @@ Supported chains: ``optax.adamw`` (static lr or schedule) and
 ``adam_w_mode`` (the default).  Anything else (``add_decayed_weights``
 *before* adam = L2 mode, lamb, onebit, client chains) makes
 :func:`match_adam_chain` return ``None`` and the engine keeps the optax
-path.  Env: ``DST_PALLAS_FUSED_OPT`` — ``1`` forces (interpret mode on
-CPU), ``0`` disables, unset enables on TPU.
+path.  The engine takes the kernel where ``ops.pallas``'s rule says so
+(``runtime/engine.py:_fused_opt_active``).
 """
 
 import functools
-import os
 from typing import Any, Callable, Dict, Optional, Tuple, Union
 
 import jax
@@ -38,16 +37,6 @@ from deepspeed_tpu.ops import pallas as _pallas
 _LANE = 128
 _SUBLANE = 8
 _INT32_MAX = jnp.iinfo(jnp.int32).max
-
-
-def fused_opt_enabled() -> bool:
-    """Tri-state ``DST_PALLAS_FUSED_OPT``: forced on/off, else on-if-TPU."""
-    flag = os.environ.get("DST_PALLAS_FUSED_OPT", "").strip().lower()
-    if flag in ("0", "off", "false"):
-        return False
-    if flag in ("1", "on", "true"):
-        return True
-    return _pallas.platform() == "tpu"
 
 
 # --------------------------------------------------------------------------- #

@@ -24,7 +24,6 @@ forward kernel): training shapes are compute bound and XLA's is fine there.
 """
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -57,19 +56,6 @@ def kernel_shape_ok(A: int, K: int, N: int, dtype) -> bool:
     return (A % _ROW_TILE == 0 and K % 128 == 0 and N % 128 == 0
             and dtype in (np.dtype(jnp.bfloat16), np.dtype(np.float32))
             and _column_tile(K, N, dtype.itemsize) > 0)
-
-
-def kernel_wanted() -> bool:
-    """``DST_PALLAS_GROUPED``: ``0`` opts out, ``1`` forces the kernel
-    (through the interpreter on the CPU, for parity tests); unset, the
-    kernel runs on one TPU chip and ``ragged_dot`` everywhere else (a mesh
-    shards the bank over ``expert``, which the kernel does not)."""
-    env = os.environ.get("DST_PALLAS_GROUPED")
-    if env in ("0", "1"):
-        return env == "1"
-    from deepspeed_tpu.parallel import mesh as mesh_lib
-    one_device = not mesh_lib.has_mesh() or mesh_lib.get_mesh().size == 1
-    return _pallas.platform() == "tpu" and one_device
 
 
 def visits(group_sizes, A: int, tm: int):
@@ -162,11 +148,13 @@ _grouped.defvjp(_grouped_fwd, _grouped_bwd)
 
 def grouped_matmul(lhs, rhs, group_sizes):
     """``lhs [A, K]`` (rows sorted by group, ``sum(group_sizes) == A``) times
-    ``rhs [G, K, N]`` -> ``[A, N]`` in ``lhs``'s type: the kernel where
-    :func:`kernel_wanted` and :func:`kernel_shape_ok` say so, else
-    ``jax.lax.ragged_dot``."""
+    ``rhs [G, K, N]`` -> ``[A, N]`` in ``lhs``'s type: the kernel on one TPU
+    chip where :func:`kernel_shape_ok` admits the shapes, else
+    ``jax.lax.ragged_dot`` (a mesh shards the bank over ``expert``, which
+    the kernel does not)."""
     rhs = rhs.astype(lhs.dtype)
     A, K = lhs.shape
-    if kernel_wanted() and kernel_shape_ok(A, K, rhs.shape[2], lhs.dtype):
+    if (_pallas.use_kernel("grouped_matmul") and _pallas.single_device()
+            and kernel_shape_ok(A, K, rhs.shape[2], lhs.dtype)):
         return _grouped(lhs, rhs, group_sizes.astype(jnp.int32))
     return jax.lax.ragged_dot(lhs, rhs, group_sizes)

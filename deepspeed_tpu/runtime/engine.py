@@ -775,13 +775,13 @@ class DeepSpeedEngine:
     # ------------------------------------------------------------------ #
     def _fused_opt_active(self) -> bool:
         """Static gate for the fused Adam kernel: a fusable factory config
-        (``_fused_opt_spec``), env opt-in, and an unsharded step — a bare
-        ``pallas_call`` has no SPMD rule, so any >1-device mesh keeps the
-        optax path."""
+        (``_fused_opt_spec``) and ``ops.pallas``'s rule — a TPU and an
+        unsharded step (a bare ``pallas_call`` has no SPMD rule, so any
+        >1-device mesh keeps the optax path)."""
         if getattr(self, "_fused_opt_spec", None) is None:
             return False
-        from deepspeed_tpu.ops.pallas import fused_optim
-        return fused_optim.fused_opt_enabled() and self.mesh.size == 1
+        from deepspeed_tpu.ops import pallas
+        return pallas.use_kernel("fused_adam") and pallas.single_device()
 
     def _fused_offload_walk_ready(self) -> bool:
         """Whether this step can run the leaf-streamed NVMe walk: fused

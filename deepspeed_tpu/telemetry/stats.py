@@ -179,7 +179,7 @@ def finalize_report(tool: str, report: Dict[str, Any],
 
     The one output path shared by every report CLI (``serve_report``,
     ``offload_audit``, ``stability_report``, ``obs_report``,
-    ``goodput_report``, ``bench_trend``): adds the uniform envelope keys
+    ``goodput_report``): adds the uniform envelope keys
     *into* the report (``tool``, ``report_schema`` — existing top-level
     payload fields stay where tests and downstream autotuners expect
     them), merges ``gates`` under ``report["gates"]`` when given, prints

@@ -1,6 +1,6 @@
 """Persistent XLA compile cache for the entry scripts.
 
-Called by ``chip_smoke.py`` and ``bench.py`` — never at import, and never
+Called by ``chip_smoke.py`` (``benchmarks/`` keeps its own) — never at import, and never
 by the tests: on the CPU backend a cached executable reloaded in a fresh
 process can fail its target-feature check and yield zero-filled outputs
 (``tests/conftest.py``).

@@ -40,6 +40,21 @@ def _reset_mesh():
 
 
 @pytest.fixture
+def kernels(monkeypatch):
+    """``kernels("ce", ...)`` replaces ``ops.pallas``'s selection rule for
+    the rest of the test: the named kernels run (through the interpreter
+    here, where their own gates admit the call), every other selection
+    takes its reference; ``kernels()`` is the rule as it stands on the CPU.
+    The rule is read while a program is traced, so a test that wants both
+    sides at one shape builds a new jitted function (or engine) for each."""
+    from deepspeed_tpu.ops import pallas
+
+    def choose(*names):
+        monkeypatch.setattr(pallas, "use_kernel", lambda name: name in names)
+    return choose
+
+
+@pytest.fixture
 def offload_on_device(monkeypatch):
     """The CPU backend cannot place on ``pinned_host``, and
     ``offload_shardings`` raises there rather than quietly keeping device

@@ -42,27 +42,21 @@ class TestSearchSpace:
         with pytest.raises(UnknownKnobError, match="no values"):
             SearchSpace({"zero_stage": ()})
 
-    def test_env_knobs_split_from_config_patch(self):
-        cands = SearchSpace({"pallas_ce": ("0", "1"),
-                             "zero_stage": (3,)}).enumerate()
-        on = next(c for c in cands if c.knobs["pallas_ce"] == "1")
-        assert on.env() == {"DST_PALLAS_CE": "1"}
-        assert on.config_patch() == {"zero_optimization.stage": 3}
-
     def test_apply_patch_and_diff(self):
         base = {"train_micro_batch_size_per_gpu": 1,
                 "zero_optimization": {"stage": 1}}
         patch = {"zero_optimization.stage": 3,
                  "train_micro_batch_size_per_gpu": 4,
-                 "env.DST_PALLAS_CE": "1"}
+                 "zero_optimization.prefetch_depth": 2}
         cfg = apply_patch(base, patch)
         assert cfg["zero_optimization"]["stage"] == 3
         assert cfg["train_micro_batch_size_per_gpu"] == 4
-        assert "env.DST_PALLAS_CE" not in cfg          # subprocess-scoped
+        assert cfg["zero_optimization"]["prefetch_depth"] == 2
         assert base["zero_optimization"]["stage"] == 1  # base untouched
         diff = patch_diff(base, patch)
         assert diff["zero_optimization.stage"] == {"from": 1, "to": 3}
-        assert diff["env.DST_PALLAS_CE"] == {"from": None, "to": "1"}
+        assert diff["zero_optimization.prefetch_depth"] == {"from": None,
+                                                            "to": 2}
 
     def test_mesh_knob_replaces_whole_dict(self):
         cfg = apply_patch({"mesh": {"data": 8}}, {"mesh": {"data": 4,

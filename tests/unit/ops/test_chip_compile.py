@@ -59,8 +59,6 @@ def chip():
 def on_the_chip(monkeypatch):
     monkeypatch.setattr(pallas, "platform", lambda: "tpu")
     monkeypatch.setattr(pallas, "interpret", lambda: False)
-    for var in ("DST_PALLAS_DECODE", "DST_PALLAS_PAGED"):
-        monkeypatch.delenv(var, raising=False)
 
 
 def _compiled_text(chip, fn, *shapes):
@@ -194,13 +192,12 @@ def test_paged_kernel_compiles_at_a_serve_steps_rows(chip, slots, H, head_dim, M
 
 @pytest.mark.parametrize("rows", [1024, 512])
 @pytest.mark.parametrize("K,N", [(2048, 2048), (1024, 2048)])
-def test_grouped_matmul_compiles_at_olmoe_bank(chip, monkeypatch, rows, K, N):
+def test_grouped_matmul_compiles_at_olmoe_bank(chip, rows, K, N):
     """The expert bank's two matmuls (gate|up ``[64, 2048, 2048]``, down
     ``[64, 1024, 2048]``) at the serve cell's 1,024 (128 rows x top 8) and
     512 (a prompt chunk) assignments: the program holds the kernel and the
     chip's compiler accepts its blocks and its VMEM."""
     from deepspeed_tpu.ops.pallas import grouped_matmul as gm
-    monkeypatch.delenv("DST_PALLAS_GROUPED", raising=False)
     assert gm.kernel_shape_ok(rows, K, N, BF16)
     text = _compiled_text(chip, gm.grouped_matmul, ((rows, K), BF16),
                           ((64, K, N), BF16), ((64,), jnp.int32))

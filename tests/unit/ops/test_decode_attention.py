@@ -1,7 +1,7 @@
 """Pallas decode-attention kernel parity (CPU interpreter) and its shape gate.
 
-On CPU the einsum is the default path; ``DST_PALLAS_DECODE=1`` forces the
-kernel through the interpreter.  Every parity case counts the kernel calls,
+On CPU the einsum is the default path; the ``kernels`` fixture replaces
+``ops.pallas``'s rule so the kernel runs through the interpreter.  Every parity case counts the kernel calls,
 so a gate that quietly routed to the reference cannot pass vacuously."""
 
 import numpy as np
@@ -16,9 +16,9 @@ from deepspeed_tpu.ops.pallas.decode_attention import (
 
 
 @pytest.fixture
-def kernel_calls(monkeypatch):
+def kernel_calls(monkeypatch, kernels):
     """Force the kernel on and count how often dispatch reaches it."""
-    monkeypatch.setenv("DST_PALLAS_DECODE", "1")
+    kernels("decode_attention")
     calls = []
     real = da._decode_call
     monkeypatch.setattr(da, "_decode_call",

@@ -317,7 +317,7 @@ def test_block_fitting_and_fallback_telemetry():
     bq, bk = fa._block_sizes(1000, None, None)
     assert 1000 % bq == 0 and 1000 % bk == 0
     assert not fa._blocks_lowerable(1000, bq, bk)
-    # explicit DST_FLASH_BQ/BK-style requests are clamped, never trusted
+    # explicit block_q=/block_k= requests are clamped, never trusted
     assert fa._block_sizes(64, 256, 512) == (64, 64)
 
     fa._FALLBACK_WARNED.clear()

@@ -27,22 +27,17 @@ def _sizes(case, A, G, rng):
     return sizes
 
 
-@pytest.fixture()
-def forced(monkeypatch):
-    monkeypatch.setenv("DST_PALLAS_GROUPED", "1")
-
-
 @pytest.mark.parametrize("case", ["random", "all_on_one", "empty_groups", "tile_aligned"])
 @pytest.mark.parametrize("A,G,K,N", [(256, 8, 128, 256), (384, 16, 256, 128)])
-def test_kernel_equals_ragged_dot(forced, case, A, G, K, N):
+def test_kernel_equals_ragged_dot(case, A, G, K, N):
     rng = np.random.default_rng(A + G)
     sizes = jnp.asarray(_sizes(case, A, G, rng))
     assert int(sizes.sum()) == A
     lhs = jnp.asarray(rng.standard_normal((A, K)), jnp.float32)
     rhs = jnp.asarray(rng.standard_normal((G, K, N)), jnp.float32)
     with jax.default_matmul_precision("highest"):
-        assert gm.kernel_wanted() and gm.kernel_shape_ok(A, K, N, lhs.dtype)
-        got = jax.jit(gm.grouped_matmul)(lhs, rhs, sizes)
+        assert gm.kernel_shape_ok(A, K, N, lhs.dtype)
+        got = jax.jit(gm._grouped)(lhs, rhs, sizes)
         want = jax.lax.ragged_dot(lhs, rhs, sizes)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4, rtol=1e-5)
 
@@ -58,7 +53,7 @@ def test_visits_walk_every_group_with_rows_once_a_tile():
     np.testing.assert_array_equal(np.asarray(tile), [0, 0, 1, 2, 3, 3, 3, 3, 3])
 
 
-def test_gradients_are_ragged_dots(forced):
+def test_gradients_are_ragged_dots():
     rng = np.random.default_rng(3)
     A, G, K, N = 256, 8, 128, 128
     sizes = jnp.asarray(_sizes("empty_groups", A, G, rng))
@@ -67,24 +62,23 @@ def test_gradients_are_ragged_dots(forced):
     target = jnp.asarray(rng.standard_normal((A, N)), jnp.float32)
     with jax.default_matmul_precision("highest"):
         loss = lambda f: lambda a, w: jnp.sum(f(a, w, sizes) * target)
-        got = jax.grad(loss(gm.grouped_matmul), argnums=(0, 1))(lhs, rhs)
+        got = jax.grad(loss(gm._grouped), argnums=(0, 1))(lhs, rhs)
         want = jax.grad(loss(jax.lax.ragged_dot), argnums=(0, 1))(lhs, rhs)
     for g, w in zip(got, want):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=1e-4, rtol=1e-5)
     assert not np.asarray(got[1][4]).any()          # an empty group's matrix
 
 
-def test_the_gate(monkeypatch):
-    """On the CPU, unforced, and for shapes the kernel does not take, the
-    call is ``ragged_dot``."""
-    monkeypatch.delenv("DST_PALLAS_GROUPED", raising=False)
-    assert not gm.kernel_wanted()                    # the platform is the CPU
+def test_the_gate(kernels):
+    """For shapes the kernel does not take the call is ``ragged_dot``, even
+    where the rule says kernels run (the rule's own three states are in
+    ``test_kernel_selection.py``)."""
     assert gm.kernel_shape_ok(1024, 2048, 2048, jnp.bfloat16)
     assert gm._column_tile(2048, 2048, 2) == 2048 and gm._column_tile(2048, 4096, 2) == 2048
     assert not gm.kernel_shape_ok(1000, 2048, 2048, jnp.bfloat16)    # no whole row tiles
     assert not gm.kernel_shape_ok(1024, 2048, 2000, jnp.bfloat16)
     assert not gm.kernel_shape_ok(1024, 2048, 2048, jnp.int8)
-    monkeypatch.setenv("DST_PALLAS_GROUPED", "1")
+    kernels("grouped_matmul")
     sizes = jnp.asarray([3, 4], jnp.int32)
     out = gm.grouped_matmul(jnp.ones((7, 8)), jnp.ones((2, 8, 4)), sizes)   # refused shape
     np.testing.assert_array_equal(np.asarray(out), np.full((7, 4), 8.0))

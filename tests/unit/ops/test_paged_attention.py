@@ -4,19 +4,18 @@ parity, masking of stale arena contents, and the kernel selection policy."""
 import numpy as np
 import pytest
 
-import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.ops.pallas import decode_attention as da
 from deepspeed_tpu.ops.pallas.decode_attention import (
-    _kernel_wanted, decode_attention_reference, paged_attention,
-    paged_attention_reference, paged_kernel_tile_pages, paged_tile_pages)
+    decode_attention_reference, paged_attention, paged_attention_reference,
+    paged_kernel_tile_pages, paged_tile_pages)
 
 
 @pytest.fixture
-def kernel_calls(monkeypatch):
+def kernel_calls(monkeypatch, kernels):
     """Force the kernel on and count how often dispatch reaches it."""
-    monkeypatch.setenv("DST_PALLAS_PAGED", "1")
+    kernels("paged_attention")
     calls = []
     real = da._paged_call
     monkeypatch.setattr(da, "_paged_call",
@@ -235,15 +234,3 @@ def test_dispatch_takes_reference_on_bias_and_gqa(kernel_calls):
                                      bias=bias)
     np.testing.assert_allclose(np.asarray(out2), np.asarray(ref2))
     assert not kernel_calls
-
-
-def test_env_policy_default_on_with_opt_out(monkeypatch):
-    """Unset, the kernels are wanted on TPU only (on CPU only the
-    interpreter exists); ``=0`` opts out, ``=1`` forces them on."""
-    for var in ("DST_PALLAS_DECODE", "DST_PALLAS_PAGED"):
-        monkeypatch.delenv(var, raising=False)
-        assert _kernel_wanted(var) == (jax.default_backend() == "tpu")
-        monkeypatch.setenv(var, "0")
-        assert _kernel_wanted(var) is False
-        monkeypatch.setenv(var, "1")
-        assert _kernel_wanted(var) is True

@@ -5,10 +5,8 @@ fp32 forward is BITWISE equal to the reference path — the kernel performs
 literally the same op sequence (f32 dot, same -1e9 vocab mask, max,
 exp-shift, sum, log, slice-then-mean) — including the multi-vocab-block
 online-softmax sweep; gradients agree to a few ulp (the backward
-recomputes scores rather than saving them).  Also covers the env gate,
-the shape/mesh support gate, and the ``chunked_cross_entropy`` wiring."""
-
-import os
+recomputes scores rather than saving them).  Also covers the shape/mesh
+support gate and the ``chunked_cross_entropy`` wiring."""
 
 import numpy as np
 import pytest
@@ -30,15 +28,11 @@ def make_inputs(N=200, E=64, V=256, dtype=jnp.float32, bias=False, seed=0):
 
 
 def reference_ce(x, head, labels, vocab_size, head_b=None):
-    """The XLA path, with the fused route forced off for the call."""
-    os.environ["DST_PALLAS_CE"] = "0"
-    try:
-        N, E = x.shape
-        return chunked_cross_entropy(x.reshape(1, N, E), head,
-                                     labels.reshape(1, N), vocab_size,
-                                     head_b=head_b)
-    finally:
-        os.environ.pop("DST_PALLAS_CE", None)
+    """The XLA path: what ``chunked_cross_entropy`` takes on the CPU."""
+    N, E = x.shape
+    return chunked_cross_entropy(x.reshape(1, N, E), head,
+                                 labels.reshape(1, N), vocab_size,
+                                 head_b=head_b)
 
 
 # --------------------------------------------------------------------------- #
@@ -113,17 +107,6 @@ def test_jit_parity():
 # --------------------------------------------------------------------------- #
 # gates + wiring
 # --------------------------------------------------------------------------- #
-def test_env_gate(monkeypatch):
-    monkeypatch.setenv("DST_PALLAS_CE", "0")
-    assert not pce.pallas_ce_enabled()
-    monkeypatch.setenv("DST_PALLAS_CE", "1")
-    assert pce.pallas_ce_enabled()
-    monkeypatch.delenv("DST_PALLAS_CE")
-    # unset: on-if-TPU — this suite runs on CPU
-    assert pce.pallas_ce_enabled() == (
-        jax.devices()[0].platform == "tpu")
-
-
 def test_supported_gate():
     assert pce.ce_supported(64, 64, 256)
     assert not pce.ce_supported(64, 64, 100)    # no 128-multiple block
@@ -141,14 +124,14 @@ def test_supported_gate_rejects_multi_device_mesh():
         mesh_lib.reset_mesh()
 
 
-def test_chunked_ce_routes_through_kernel(monkeypatch):
+def test_chunked_ce_routes_through_kernel(monkeypatch, kernels):
     """chunked_cross_entropy must dispatch to the fused kernel when the
-    env forces it on, and the result must equal the forced-off path."""
+    rule says kernels run, and the result must equal the reference path."""
     x, head, labels, _ = make_inputs(N=64, E=32, V=128)
     x3 = x.reshape(2, 32, 32)
     l2 = labels.reshape(2, 32)
 
-    monkeypatch.setenv("DST_PALLAS_CE", "1")
+    kernels("ce")
     called = {}
     orig = pce.fused_cross_entropy
 
@@ -160,6 +143,6 @@ def test_chunked_ce_routes_through_kernel(monkeypatch):
     on = chunked_cross_entropy(x3, head, l2, 128)
     assert called.get("yes"), "fused kernel was not dispatched"
 
-    monkeypatch.setenv("DST_PALLAS_CE", "0")
+    kernels()
     off = chunked_cross_entropy(x3, head, l2, 128)
     np.testing.assert_array_equal(np.asarray(on), np.asarray(off))

@@ -13,6 +13,8 @@ import deepspeed_tpu
 from deepspeed_tpu.runtime.checkpoint_engine import (LocalCheckpointEngine,
                                                      OrbaxCheckpointEngine,
                                                      get_checkpoint_engine)
+from deepspeed_tpu.runtime.checkpointing import wait_for_finalizer
+from deepspeed_tpu.testing import fault_injection
 
 
 class TestEngines:
@@ -75,16 +77,41 @@ class TestEngineIntegration:
         return x, y
 
     def test_async_save_roundtrip(self, tmp_path):
+        """An async save is another reader's to load once the SAVER has
+        joined its finalizer (commit, promote, ``latest``): its own next
+        save/load/close does, and so does this explicit join."""
         engine = self._engine({"async_save": True})
         self._step(engine)
         engine.save_checkpoint(str(tmp_path))
         assert isinstance(engine.checkpoint_engine, OrbaxCheckpointEngine)
         assert engine.checkpoint_engine.async_save
+        wait_for_finalizer(engine)
         p0 = jax.tree.leaves(engine.state.params)[0]
         engine2 = self._engine({"async_save": True})
         engine2.load_checkpoint(str(tmp_path))
         np.testing.assert_allclose(jax.tree.leaves(engine2.state.params)[0], p0)
         assert engine2.global_steps == 1
+
+    def test_async_save_in_flight_is_invisible_to_another_engine(self, tmp_path):
+        """Until the commit, the bytes sit in a staging directory and
+        ``latest`` has not moved: a second engine loads nothing (and no
+        torn state), and the whole checkpoint once the saver is through."""
+        engine = self._engine({"async_save": True})
+        self._step(engine)
+        fault_injection.install_plan(
+            [{"site": "ckpt.pre_commit", "action": "wedge", "max_wedge_s": 60}])
+        try:
+            engine.save_checkpoint(str(tmp_path))
+            engine2 = self._engine({"async_save": True})
+            assert engine2.load_checkpoint(str(tmp_path)) == (None, {})
+            assert engine2.global_steps == 0
+            assert engine._ckpt_finalizer.is_alive()
+        finally:
+            fault_injection.release_wedges()
+            wait_for_finalizer(engine)
+            fault_injection.clear_plan()
+        path, _ = engine2.load_checkpoint(str(tmp_path))
+        assert path is not None and engine2.global_steps == 1
 
 
 class TestCrossTopologyRestore:

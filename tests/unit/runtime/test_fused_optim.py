@@ -167,16 +167,6 @@ def test_spec_from_config():
                     "lr": 1e-3}
 
 
-def test_env_gate(monkeypatch):
-    monkeypatch.setenv("DST_PALLAS_FUSED_OPT", "0")
-    assert not fused_optim.fused_opt_enabled()
-    monkeypatch.setenv("DST_PALLAS_FUSED_OPT", "1")
-    assert fused_optim.fused_opt_enabled()
-    monkeypatch.delenv("DST_PALLAS_FUSED_OPT")
-    assert fused_optim.fused_opt_enabled() == (
-        jax.devices()[0].platform == "tpu")
-
-
 # --------------------------------------------------------------------------- #
 # engine e2e (single-device mesh: the fused gate's supported regime)
 # --------------------------------------------------------------------------- #
@@ -201,8 +191,8 @@ def batch(step):
     return x, y
 
 
-def run_engine(monkeypatch, fused, config, n=3, hooks=None):
-    monkeypatch.setenv("DST_PALLAS_FUSED_OPT", "1" if fused else "0")
+def run_engine(kernels, fused, config, n=3, hooks=None):
+    kernels("fused_adam") if fused else kernels()
     try:
         engine = one_device_engine(config)
         assert engine._fused_opt_active() == fused
@@ -235,19 +225,19 @@ class TestEngineParity:
         ("qgZ", {"zero_quantized_gradients": True}),
         ("hpZ", {"zero_hpz_partition_size": 2}),
     ])
-    def test_fused_matches_unfused(self, monkeypatch, mode, zero_over):
-        """DST_PALLAS_FUSED_OPT must be numerically invisible: ulp-tight
+    def test_fused_matches_unfused(self, kernels, mode, zero_over):
+        """The fused kernel must be numerically invisible: ulp-tight
         parameters after 3 steps under every compression config."""
         cfg = adamw_config(**zero_over)
-        e_off = run_engine(monkeypatch, fused=False, config=cfg)
-        e_on = run_engine(monkeypatch, fused=True, config=cfg)
+        e_off = run_engine(kernels, fused=False, config=cfg)
+        e_on = run_engine(kernels, fused=True, config=cfg)
         assert_tree_close(e_off.state.params, e_on.state.params,
                           f"params diverged under {mode}")
         assert_tree_close(e_off.state.opt_state, e_on.state.opt_state,
                           f"opt state diverged under {mode}")
 
-    def test_gate_rejects_multi_device_mesh(self, monkeypatch):
-        monkeypatch.setenv("DST_PALLAS_FUSED_OPT", "1")
+    def test_gate_rejects_multi_device_mesh(self, kernels):
+        kernels("fused_adam")
         model = SimpleModel(hidden_dim=HIDDEN, nlayers=2)
         engine, _, _, _ = deepspeed_tpu.initialize(
             model=model,
@@ -273,7 +263,7 @@ def swapped_state(engine):
 @pytest.mark.usefixtures("offload_on_device")
 class TestOffloadWalk:
 
-    def test_walk_matches_unfused_offload(self, monkeypatch, tmp_path):
+    def test_walk_matches_unfused_offload(self, kernels, tmp_path):
         """The leaf-streamed NVMe walk vs the whole-tree-materializing
         unfused offload step: ulp-tight params AND moments on disk,
         with the state never resident after a step."""
@@ -283,9 +273,9 @@ class TestOffloadWalk:
             assert engine.state.opt_state is None   # swapped back out
             ready.append(engine._fused_offload_walk_ready())
 
-        e_off = run_engine(monkeypatch, fused=False,
+        e_off = run_engine(kernels, fused=False,
                            config=offload_config(tmp_path / "off"))
-        e_on = run_engine(monkeypatch, fused=True,
+        e_on = run_engine(kernels, fused=True,
                           config=offload_config(tmp_path / "on"),
                           hooks=check)
         assert all(ready), "fused walk was not active for every step"
@@ -294,14 +284,13 @@ class TestOffloadWalk:
         assert_tree_close(swapped_state(e_off), swapped_state(e_on),
                           "NVMe-resident moments diverged")
 
-    def test_rollback_resync(self, monkeypatch, tmp_path):
+    def test_rollback_resync(self, kernels, tmp_path):
         """Checkpoint save → further steps → load (the PR 5 rollback): the
         loader re-persists the swapped state, and the fused walk must read
         the restored moments — matching an unfused engine driven
         through the identical sequence."""
         def run(fused, sub):
-            monkeypatch.setenv("DST_PALLAS_FUSED_OPT",
-                               "1" if fused else "0")
+            kernels("fused_adam") if fused else kernels()
             try:
                 engine = one_device_engine(
                     offload_config(tmp_path / sub / "nvme"))
@@ -333,7 +322,7 @@ class TestOffloadWalk:
         assert_tree_close(swapped_state(e_off), swapped_state(e_on),
                           "moments diverged after rollback-resync")
 
-    def test_no_new_traced_programs_per_step(self, monkeypatch, tmp_path):
+    def test_no_new_traced_programs_per_step(self, kernels, tmp_path):
         """The per-leaf jits must be traced once per leaf shape, not per
         step — a retrace per step would re-introduce the dispatch cost
         the fusion exists to remove."""
@@ -347,7 +336,7 @@ class TestOffloadWalk:
                     "scalars": engine._fused_scalars_jit._cache_size(),
                     "incr": engine._fused_incr_jit._cache_size()})
 
-        engine = run_engine(monkeypatch, fused=True,
+        engine = run_engine(kernels, fused=True,
                             config=offload_config(tmp_path), n=5,
                             hooks=record)
         assert sizes["prelude"] == 1 and sizes["scalars"] == 1

@@ -188,13 +188,13 @@ def test_submit_rejects_oversized_and_sampled(tiny_model):
 
 
 @pytest.mark.parametrize("n_head,tiled", [(12, True), (25, False)])
-def test_step_stats_say_which_paged_kernel(monkeypatch, n_head, tiled):
+def test_step_stats_say_which_paged_kernel(kernels, n_head, tiled):
     """``step()``'s stats carry ``paged_tile_pages``: the pages per tile of
     the paged kernel the decode program was built with (forced through the
     interpreter here, at GPT-2's 12 heads of 64 and 16-row pages), 0 where
     ``paged_attention`` takes the einsum (gpt2-xl's 25 heads are 1600
     lanes).  Static per engine, and the tokens are the dense path's."""
-    monkeypatch.setenv("DST_PALLAS_PAGED", "1")
+    kernels("paged_attention")
     cfg = GPTConfig(vocab_size=128, n_positions=256, n_embd=64 * n_head,
                     n_layer=1, n_head=n_head, dtype="float32")
     model = GPT(cfg)

@@ -1,7 +1,6 @@
 """CLI-level tests for the goodput tooling: ``tools/goodput_report.py``
 (JSONL fold + EFFICIENCY.json artifact input, gates, 0/1/2 exits),
-``tools/bench_trend.py`` (cross-round trend with degraded-round
-exclusion), and the uniform ``--json`` envelope (``tool`` +
+and the uniform ``--json`` envelope (``tool`` +
 ``report_schema`` keys from ``telemetry/stats.py:finalize_report``)
 shared by every report CLI."""
 
@@ -121,82 +120,6 @@ class TestGoodputReport:
         tool = _tool("goodput_report")
         assert tool.main([str(path)]) == 2
         assert tool.main([str(tmp_path / "missing.jsonl")]) == 2
-
-
-def _round(n, rc=0, parsed=None):
-    return {"n": n, "cmd": "python bench.py", "rc": rc, "tail": "",
-            "parsed": parsed}
-
-
-def _write_rounds(tmp_path, rounds):
-    for doc in rounds:
-        with open(tmp_path / f"BENCH_r{doc['n']:02d}.json", "w") as f:
-            json.dump(doc, f)
-
-
-class TestBenchTrend:
-    def test_flat_series_ok(self, tmp_path):
-        _write_rounds(tmp_path, [
-            _round(1, parsed={"metric": "m", "value": 60.0}),
-            _round(2, parsed={"metric": "m", "value": 61.0}),
-            _round(3, parsed={"metric": "m", "value": 60.5}),
-        ])
-        tool = _tool("bench_trend")
-        out = tmp_path / "trend.json"
-        assert tool.main([str(tmp_path), "--json", str(out)]) == 0
-        rep = json.loads(out.read_text())
-        assert rep["tool"] == "bench_trend"
-        assert rep["report_schema"] == 1
-        assert rep["rounds_usable"] == 3
-        assert rep["latest_value"] == 60.5 and rep["best_value"] == 61.0
-        assert not rep["regressed"]
-
-    def test_degraded_and_failed_rounds_excluded(self, tmp_path):
-        _write_rounds(tmp_path, [
-            _round(1, parsed={"metric": "m", "value": 60.0}),
-            _round(2, rc=1, parsed=None),                       # crashed
-            _round(3, parsed={"metric": "m", "value": 1.0,
-                              "degraded": True,
-                              "degraded_reason": "backend down"}),
-            _round(4, rc=2, parsed={"metric": "BACKEND UNAVAILABLE",
-                                    "error": "no tpu"}),        # no value
-            _round(5, parsed={"metric": "m", "value": 59.0}),
-        ])
-        tool = _tool("bench_trend")
-        out = tmp_path / "trend.json"
-        # the degraded value-1.0 round must NOT read as a regression
-        assert tool.main([str(tmp_path), "--json", str(out)]) == 0
-        rep = json.loads(out.read_text())
-        assert rep["rounds_usable"] == 2
-        assert rep["rounds_excluded"] == 3
-        reasons = " ".join(e["reason"] for e in rep["excluded"])
-        assert "degraded" in reasons and "rc=1" in reasons
-
-    def test_regression_fails_exit_1(self, tmp_path):
-        _write_rounds(tmp_path, [
-            _round(1, parsed={"metric": "m", "value": 60.0}),
-            _round(2, parsed={"metric": "m", "value": 40.0}),
-        ])
-        tool = _tool("bench_trend")
-        assert tool.main([str(tmp_path)]) == 1
-        assert tool.main([str(tmp_path), "--max-regression", "0.5"]) == 0
-
-    def test_metric_rename_starts_fresh_series(self, tmp_path):
-        _write_rounds(tmp_path, [
-            _round(1, parsed={"metric": "old", "value": 900.0}),
-            _round(2, parsed={"metric": "new", "value": 10.0}),
-        ])
-        tool = _tool("bench_trend")
-        out = tmp_path / "trend.json"
-        assert tool.main([str(tmp_path), "--json", str(out)]) == 0
-        rep = json.loads(out.read_text())
-        assert rep["rounds_in_series"] == [2]
-
-    def test_no_usable_rounds_exit_2(self, tmp_path):
-        _write_rounds(tmp_path, [_round(1, rc=1)])
-        tool = _tool("bench_trend")
-        assert tool.main([str(tmp_path)]) == 2
-        assert tool.main([str(tmp_path / "empty")]) == 2
 
 
 class TestUniformJsonEnvelope:

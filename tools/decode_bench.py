@@ -25,10 +25,6 @@ def main() -> int:
         print(f"FAIL: needs a TPU, found {jax.devices()[0].platform}")
         return 1
 
-    # force the kernel paths regardless of ambient opt-outs
-    os.environ["DST_PALLAS_DECODE"] = "1"
-    os.environ["DST_PALLAS_PAGED"] = "1"
-
     from deepspeed_tpu.ops.pallas.decode_attention import (
         decode_attention, decode_attention_reference, paged_attention,
         paged_attention_reference)
