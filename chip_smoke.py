@@ -44,6 +44,9 @@ SERVE = dict(block_size=16, num_blocks=512, max_batch_size=8, prefill_chunk=64,
              # cache the decode kernel tiles, 5+16 and 150+16 one it does not
              requests=((5, 16), (48, 16), (112, 16), (150, 16), (240, 16)))
 SHARDED = dict(micro_per_chip=8, seq=1024, steps=4)
+# the Pallas kernels the one-chip train step must hold, by ``pallas_call`` name
+TRAIN_KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "ce_fwd",
+                 "ce_bwd", "fused_adam")
 # bf16 tolerance on a loss near 10.8: the kernels keep fp32 accumulators
 # but round probabilities and activations to bf16 at other points than the
 # reference paths do; a wrong kernel moves the loss by far more.
@@ -214,9 +217,11 @@ def first_step(model, micro, seed, batch):
 
 
 def train_phase(seed):
+    import jax.numpy as jnp
     import deepspeed_tpu
     from deepspeed_tpu.models.gpt import GPT, gpt_config
     from deepspeed_tpu.ops import pallas
+    from deepspeed_tpu.ops.pallas.cross_entropy import ce_blocks
     from deepspeed_tpu.parallel import mesh as mesh_lib
 
     micro, seq = TRAIN["micro"], TRAIN["seq"]
@@ -238,11 +243,14 @@ def train_phase(seed):
     check_losses("train", losses)
     compiled = compiled_fused_step(engine, batches[0])
     kernels = kernels_in(compiled)
-    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "ce_fwd",
-                 "ce_bwd_dx", "ce_bwd_dh", "fused_adam"):
+    for name in TRAIN_KERNELS:
         check(kernels.get(name),
               f"train: kernel {name} not in the compiled step: {kernels}")
     no_flash_demotion()
+    # the tile the two ce_* kernels work on, from the same pure function
+    # of the call's shapes that the step took it from
+    blocks = ce_blocks(micro * seq, cfg.n_embd, cfg.padded_vocab, jnp.bfloat16)
+    check(blocks, "train: the fused cross-entropy has no tile for this shape")
     engine.close()
     del engine, compiled
     mesh_lib.reset_mesh()
@@ -273,7 +281,7 @@ def train_phase(seed):
          second_compile_cache_hit=again["cache_hit"],
          step_s=[round(s, 4) for s in secs], losses=losses,
          reference_losses=ref_losses, max_loss_diff=max(diffs),
-         loss_tolerance=LOSS_TOL, kernels=kernels,
+         loss_tolerance=LOSS_TOL, kernels=kernels, ce_blocks=list(blocks),
          peak_hbm_bytes=peak_hbm_bytes())
 
 

@@ -79,12 +79,14 @@ def _flash_bwd(H, E):
     return jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv
 
 
-def _fused_ce(H, E):
-    """Forward and both backward kernels, at half the smoke batch's rows."""
-    N = 4096
-    loss = lambda x, head, lab: ce.fused_cross_entropy(x, head, lab, 50257)
+def _fused_ce(H, E, vocab=V):
+    """The forward and the backward kernel at the train cell's rows (micro
+    8 x 1024), on the tile ``ce_blocks`` takes for the width."""
+    N = B * S
+    real = 50257 if vocab == V else vocab
+    loss = lambda x, head, lab: ce.fused_cross_entropy(x, head, lab, real)
     return (jax.value_and_grad(loss, argnums=(0, 1)),
-            ((N, E), BF16), ((V, E), BF16), ((N,), jnp.int32))
+            ((N, E), BF16), ((vocab, E), BF16), ((N,), jnp.int32))
 
 
 def _fused_adam(H, E):
@@ -130,6 +132,23 @@ def test_train_kernel_compiles(chip, kernel, width):
     fn, *shapes = TRAIN[kernel](*WIDTHS[width])
     assert "tpu_custom_call" in _compiled_text(chip, fn, *shapes)
     assert not fa._FALLBACK_WARNED
+
+
+@pytest.mark.parametrize("E,vocab,blocks", [
+    (2048, V, (512, 384)),        # OLMoE's width: fewer rows, two row sweeps
+    (768, 65536, (256, 2048)),    # a power-of-two vocab: 2,048 columns
+])
+def test_fused_ce_compiles_on_its_chosen_tile(chip, E, vocab, blocks):
+    """The tile is chosen from the shape (``ce_blocks``) and so are the rows
+    whose dx the backward holds (``ce_row_sweeps``), their VMEM reckoned by
+    hand: the chip's compiler has to agree beyond the two widths of
+    ``test_train_kernel_compiles`` ((1024, 384) at both; one sweep of 24 MiB
+    at 768, two at 1,600), wherever a GPT-family loss could bring the
+    kernels on one device."""
+    assert ce.ce_blocks(B * S, E, vocab, BF16) == blocks
+    fn, *shapes = _fused_ce(None, E, vocab)
+    text = _compiled_text(chip, fn, *shapes)
+    assert "ce_fwd" in text and "ce_bwd" in text
 
 
 # gpt2-large's 20 heads are 1280 lanes, the widest GPT-2 the gate admits

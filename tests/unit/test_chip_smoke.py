@@ -34,6 +34,21 @@ def test_refuses_to_run_on_cpu():
     assert "deepspeed_tpu" not in proc.stderr   # refused before building
 
 
+def test_train_kernels_are_names_the_kernel_files_give():
+    """The train phase asserts kernels by ``pallas_call`` name: each name
+    it waits for is one a kernel file gives (a renamed or merged kernel,
+    like the cross-entropy's one backward, must be followed here)."""
+    import re
+    from deepspeed_tpu.ops.pallas import (cross_entropy, flash_attention,
+                                          fused_optim)
+    given = set()
+    for module in (cross_entropy, flash_attention, fused_optim):
+        with open(module.__file__) as f:
+            given |= set(re.findall(r'name="(\w+)"', f.read()))
+    assert set(chip_smoke.TRAIN_KERNELS) <= given
+    assert {n for n in given if n.startswith("ce_")} == {"ce_fwd", "ce_bwd"}
+
+
 @pytest.fixture
 def off_chip_main(monkeypatch):
     """``main()`` past the platform check, with nothing that outlives the
