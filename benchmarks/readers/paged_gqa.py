@@ -1,0 +1,27 @@
+"""Reader for the paged kernel over grouped K/V heads with a window
+(``paged_gqa_attention`` of ``ops/pallas/decode_attention.py``): its share of
+its roofline.  The operations and bytes are the kind's count of what the
+program ran (``kinds/serve_backlog_resident.py:attention_counters``, from
+``lib/arith_window.py``: every row, a window layer at the pages it can see),
+left in the run's counters.  A program without the kernel (a parent commit,
+a multi-head model) gives nothing to read: None, and the metric is left out
+of the line."""
+
+from benchmarks.lib import arith
+
+KERNEL = "paged_gqa_attention"
+
+
+def roofline(run):
+    """The least time for the operations and bytes the kernel needed over
+    the traced stretch over its time there."""
+    t, c = run["trace"], run["counters"]
+    if t is None or "paged_gqa_bytes" not in c:
+        return None
+    took = t.op_seconds().get(KERNEL)
+    if not took:
+        return None
+    bound_s, which = arith.roofline_seconds(c["paged_gqa_flops"], c["paged_gqa_bytes"],
+                                            run["peaks"])
+    run["notes"].setdefault("roofline_bound", {})[KERNEL] = which
+    return 100.0 * bound_s / took

@@ -32,7 +32,7 @@ TPU-first design decisions:
 import dataclasses
 import math
 from functools import partial
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -42,6 +42,14 @@ from deepspeed_tpu.comm.compression import layered as zero_layered
 from deepspeed_tpu.parallel import mesh as mesh_lib
 
 Array = jax.Array
+
+
+class LayerKind(NamedTuple):
+    """What distinguishes the attention of one layer of a ``layer_pattern``:
+    ``window`` keys a query sees, itself counted (None: every earlier key),
+    and whether q and k are rotated (``rope``) or carry no position."""
+    window: Optional[int] = None
+    rope: bool = True
 
 
 @dataclasses.dataclass
@@ -76,12 +84,22 @@ class GPTConfig:
     position_encoding: str = "learned"   # 'learned' | 'rope' | 'alibi'
     norm: str = "layernorm"              # 'layernorm' | 'rmsnorm'
     mlp_type: str = "standard"           # 'standard' | 'swiglu'
+    # the gate's activation of a 'swiglu' MLP: 'silu' (SwiGLU), 'relu' (ReGLU)
+    glu_activation: str = "silu"
     intermediate_size: Optional[int] = None   # default 4*n_embd
     use_bias: bool = True                # LLaMA-style blocks are bias-free
     rope_theta: float = 10000.0
     # grouped-query attention: number of K/V heads (None = n_head = MHA;
     # 1 = MQA).  The KV cache stores only n_kv_head heads — the GQA win.
     n_kv_head: Optional[int] = None
+    # width of a head where it is not n_embd // n_head (a published size:
+    # SmallThinker's 28 heads of 128 on a hidden size of 2560)
+    head_dim: Optional[int] = None
+    # one period of the stack, a LayerKind a layer: layer ``l`` is of kind
+    # ``layer_pattern[l % len]``.  None is the one-entry pattern every
+    # homogeneous model has (full causal attention, rope iff
+    # ``position_encoding`` says so); ``n_layer`` is whole periods
+    layer_pattern: Optional[Tuple[LayerKind, ...]] = None
     # pad vocab to a multiple (MXU-friendly, and divisible by tensor axis)
     vocab_multiple: int = 128
     # block topology: 'sequential' (GPT-2/OPT/LLaMA), 'parallel' (GPT-NeoX
@@ -119,12 +137,28 @@ class GPTConfig:
     moe_min_capacity: int = 4
     moe_aux_coeff: float = 0.01
     moe_expert_hidden: Optional[int] = None
+    # dropless router: weights are the softmax over the CHOSEN logits
+    # (``norm_topk_prob``), not the chosen entries of the softmax over all
+    moe_norm_topk: bool = False
+    # what the router reads: 'post_attn' (the MLP's normed input) or
+    # 'pre_attn' (the normed input attention reads: SmallThinker routes
+    # before attention)
+    moe_router_input: str = "post_attn"
 
     def __post_init__(self):
         self.padded_vocab = int(
             math.ceil(self.vocab_size / self.vocab_multiple) * self.vocab_multiple)
-        assert self.n_embd % self.n_head == 0
-        self.head_dim = self.n_embd // self.n_head
+        if self.head_dim is None:
+            assert self.n_embd % self.n_head == 0
+            self.head_dim = self.n_embd // self.n_head
+        self.attn_dim = self.n_head * self.head_dim
+        self.pattern = tuple(LayerKind(*k) for k in self.layer_pattern or (
+            LayerKind(None, self.position_encoding == "rope"),))
+        assert self.n_layer % len(self.pattern) == 0, (
+            f"n_layer {self.n_layer} is not whole periods of "
+            f"{len(self.pattern)} layers")
+        assert self.glu_activation in ("silu", "relu")
+        assert self.moe_router_input in ("post_attn", "pre_attn")
         self.kv_heads = self.n_kv_head or self.n_head
         assert self.n_head % self.kv_heads == 0, \
             f"n_head {self.n_head} not divisible by n_kv_head {self.kv_heads}"
@@ -187,6 +221,29 @@ def olmoe_config(vocab_size=50304, n_positions=4096, n_embd=2048, n_layer=16,
                         intermediate_size=intermediate_size, **kw)
 
 
+def smallthinker_config(vocab_size=151936, n_positions=16384, n_embd=2560,
+                        n_layer=52, n_head=28, n_kv_head=4, head_dim=128,
+                        intermediate_size=768, num_experts=64, top_k=6,
+                        window=4096, **overrides) -> GPTConfig:
+    """SmallThinker family (defaults: SmallThinker-21B-A3B): a period of four
+    layers, the first full causal attention WITHOUT position encoding, the
+    other three attention over a ``window`` of keys with rope; grouped K/V
+    heads of a width that is not ``n_embd // n_head``; every MLP a bank of
+    ReGLU experts behind a dropless router that reads the input ATTENTION
+    reads and weighs its ``top_k`` by the softmax over their own logits;
+    RMSNorm (eps 1e-6), no bias, untied head, rope theta 1.5e6."""
+    kw = dict(n_kv_head=n_kv_head, head_dim=head_dim, rope_theta=1.5e6,
+              ln_eps=1e-6, glu_activation="relu",
+              layer_pattern=(LayerKind(None, False),) + 3 * (LayerKind(window, True),),
+              moe_num_experts=num_experts, moe_top_k=top_k,
+              moe_router="dropless", moe_norm_topk=True,
+              moe_router_input="pre_attn")
+    kw.update(overrides)
+    return llama_config(vocab_size=vocab_size, n_positions=n_positions,
+                        n_embd=n_embd, n_layer=n_layer, n_head=n_head,
+                        intermediate_size=intermediate_size, **kw)
+
+
 def bloom_config(vocab_size=250880, n_positions=2048, n_embd=512, n_layer=4,
                  n_head=8, **overrides) -> GPTConfig:
     """BLOOM family: ALiBi positions, GELU MLP, tied embeddings
@@ -217,7 +274,7 @@ def _init_block(cfg: GPTConfig, rng: Array) -> Dict:
         "ln1_b": jnp.zeros((E,), jnp.float32),
         "qkv_w": _dense_init(ks[0], E, (E, cfg.qkv_dim)),
         "qkv_b": jnp.zeros((cfg.qkv_dim,), jnp.float32),
-        "out_w": _dense_init(ks[1], E, (E, E), scale=proj_scale),
+        "out_w": _dense_init(ks[1], E, (cfg.attn_dim, E), scale=proj_scale),
         "out_b": jnp.zeros((E,), jnp.float32),
         "ln2_g": jnp.ones((E,), jnp.float32),
         "ln2_b": jnp.zeros((E,), jnp.float32),
@@ -362,6 +419,8 @@ def _activation(x: Array, kind: str) -> Array:
         return jax.nn.gelu(x, approximate=False)
     if kind == "relu":
         return jax.nn.relu(x)
+    if kind == "silu":
+        return jax.nn.silu(x)
     if kind == "gelu_quick":       # CLIP's quick_gelu: x * sigmoid(1.702x)
         return x * jax.nn.sigmoid(1.702 * x)
     raise ValueError(f"unknown activation {kind!r}")
@@ -424,11 +483,12 @@ def _split_qkv(cfg: "GPTConfig", qkv: Array):
             v.reshape(B, S, Hkv, D))
 
 
-def _project_qkv(cfg: "GPTConfig", p: Dict, h: Array, dt, positions: Array):
+def _project_qkv(cfg: "GPTConfig", p: Dict, h: Array, dt, positions: Array,
+                 kind: Optional[LayerKind] = None):
     """The normed input ``h [B, S, E]`` -> q ``[B,S,H,D]``, k and v
     ``[B,S,Hkv,D]``: the fused projection, its bias, the q/k RMSNorm over
     all lanes (``qk_norm``), the split into heads, rope at ``positions``
-    (``[S]`` or ``[B, S]``)."""
+    (``[S]`` or ``[B, S]``) where the layer's ``kind`` ropes."""
     qkv = h @ _wget(p, "qkv_w", dt)
     if cfg.use_bias:
         qkv = qkv + p["qkv_b"].astype(dt)
@@ -439,7 +499,7 @@ def _project_qkv(cfg: "GPTConfig", p: Dict, h: Array, dt, positions: Array):
             rms_norm(qkv[..., nq:nq + nk], p["k_norm_g"], eps=cfg.ln_eps),
             qkv[..., nq + nk:]], axis=-1)
     q, k, v = _split_qkv(cfg, qkv)
-    if cfg.position_encoding == "rope":
+    if (kind or cfg.pattern[0]).rope:
         q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_dim,
                        cfg.rope_interleaved)
         k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_dim,
@@ -469,7 +529,7 @@ def _mlp(cfg: "GPTConfig", p: Dict, h: Array, dt, matmul=jnp.matmul,
         up = up + bias(p["fc_b"].astype(dt))
     if cfg.mlp_type == "swiglu":
         gate, val = jnp.split(up, 2, axis=-1)
-        h = jax.nn.silu(gate) * val
+        h = _activation(gate, cfg.glu_activation) * val
     else:
         h = _activation(up, cfg.activation)
     out = matmul(h, _wget(p, "proj_w", dt))
@@ -482,13 +542,16 @@ _EXPERT_LEAVES = {"wi": "fc_w", "bi": "fc_b", "wo": "proj_w", "bo": "proj_b"}
 
 
 def _ffn(cfg: "GPTConfig", p: Dict, h: Array, dt, rng=None,
-         train: bool = False, live: Optional[Array] = None
+         train: bool = False, live: Optional[Array] = None,
+         attn_in: Optional[Array] = None
          ) -> Tuple[Array, Array, Optional[Array]]:
     """Dense MLP or top-k gated MoE expert bank (reference ``moe/layer.py:16``
     when ``moe_num_experts > 0``).  Returns ``(y, aux_loss, expert_counts)``:
     on the dense path the aux loss is zero and the counts None; the counts
     (``[experts]`` int32, assignments of this call) leave out the rows
-    ``live [tokens]`` marks as carrying no request."""
+    ``live [tokens]`` marks as carrying no request.  ``attn_in`` is the
+    normed input attention read, which a ``moe_router_input`` of
+    ``pre_attn`` routes by."""
     if cfg.moe_num_experts == 0:
         return _mlp(cfg, p, h, dt), jnp.zeros((), jnp.float32), None
     from deepspeed_tpu.moe import dropless
@@ -500,11 +563,13 @@ def _ffn(cfg: "GPTConfig", p: Dict, h: Array, dt, rng=None,
     bank = {_EXPERT_LEAVES[k]: v for k, v in p["moe"]["experts"].items()}
     with jax.named_scope("moe"):
         with jax.named_scope("moe_router"):
-            logits = xt.astype(jnp.float32) @ p["moe"]["gate"]["wg"].astype(
+            routed = (attn_in.reshape(-1, E)
+                      if cfg.moe_router_input == "pre_attn" else xt)
+            logits = routed.astype(jnp.float32) @ p["moe"]["gate"]["wg"].astype(
                 jnp.float32)
             if cfg.moe_router == "dropless":
                 probs, weights, experts = dropless.softmax_topk(
-                    logits, cfg.moe_top_k)
+                    logits, cfg.moe_top_k, cfg.moe_norm_topk)
                 l_aux = dropless.load_balance_loss(probs, experts)
                 counts = dropless.expert_counts(experts, N, live)
         if cfg.moe_router == "dropless":
@@ -537,7 +602,7 @@ def _block_tail(cfg: "GPTConfig", p: Dict, x: Array, h: Array, o: Array, dt,
         z = h if cfg.block_type == "parallel_single_ln" else _norm(
             cfg, x, p["ln2_g"], p["ln2_b"])
         x = x + o
-    f, _, counts = _ffn(cfg, p, z, dt, live=live)
+    f, _, counts = _ffn(cfg, p, z, dt, live=live, attn_in=h)
     return x + f, counts
 
 
@@ -565,18 +630,56 @@ def _maybe_actq(cfg: "GPTConfig", h: Array) -> Array:
                                quant_type=cfg.activation_quant_type)
 
 
+def _scan_layers(n_kinds: int, layer_fn: Callable, carry, xs):
+    """``lax.scan`` over the stacked layers ``xs`` (leaves ``[L, ...]``) of a
+    stack that repeats in a period of ``n_kinds`` layers:
+    ``layer_fn(j, carry, x) -> (carry, y)`` runs one layer of the period's
+    ``j``-th kind (static).  The leaves are read as ``[periods, period,
+    ...]``: the scan runs over periods and its body walks the period, taking
+    layer ``period * n_kinds + j`` out of each leaf itself (one dynamic
+    slice of the whole stack a layer, as a scan over layers takes it: a
+    slice of a slice is a copy of the period's weights).  A period of one is
+    the plain scan over layers."""
+    if n_kinds == 1:
+        return jax.lax.scan(partial(layer_fn, 0), carry, xs)
+    n_layer = jax.tree.leaves(xs)[0].shape[0]
+
+    def period(carry, i):
+        ys = []
+        for j in range(n_kinds):
+            x = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
+                a, i * n_kinds + j, 0, keepdims=False), xs)
+            carry, y = layer_fn(j, carry, x)
+            ys.append(y)
+        return carry, jax.tree.map(lambda *a: jnp.stack(a), *ys)
+
+    carry, ys = jax.lax.scan(period, carry, jnp.arange(n_layer // n_kinds))
+    return carry, jax.tree.map(lambda a: a.reshape(-1, *a.shape[2:]), ys)
+
+
+def _window_bias(S: int, window: int) -> Array:
+    """``[S, S]`` additive mask of a window layer on the dense path: query
+    ``t`` sees keys ``t - window + 1 .. t`` (the causal half is the
+    attention op's)."""
+    far = jnp.arange(S)[:, None] - jnp.arange(S)[None, :] >= window
+    return jnp.where(far, -1e30, 0.0).astype(jnp.float32)
+
+
 def gpt_block(cfg: GPTConfig, p: Dict, x: Array, rng: Optional[Array],
-              train: bool, attention_fn: Callable) -> Tuple[Array, Array]:
-    """One transformer block on ``x: [batch, seq, embd]``.  Returns
-    ``(x, moe_aux)``; the aux term is zero for dense blocks."""
+              train: bool, attention_fn: Callable,
+              kind: Optional[LayerKind] = None) -> Tuple[Array, Array]:
+    """One transformer block on ``x: [batch, seq, embd]``, of the pattern's
+    ``kind`` (default: its first).  Returns ``(x, moe_aux)``; the aux term
+    is zero for dense blocks."""
     B, S, E = x.shape
     H, D = cfg.n_head, cfg.head_dim
     dt = x.dtype
+    kind = kind or cfg.pattern[0]
     r = (jax.random.split(rng, 3) if rng is not None else (None, None, None))
 
     with jax.named_scope("attn"):
         h = _maybe_actq(cfg, _norm(cfg, x, p["ln1_g"], p["ln1_b"]))
-        q, k, v = _project_qkv(cfg, p, h, dt, jnp.arange(S))
+        q, k, v = _project_qkv(cfg, p, h, dt, jnp.arange(S), kind)
         # grouped K/V go to the attention op as-is: the Pallas kernel (and
         # the GQA-aware jnp reference) consume Hkv < H heads natively, so
         # training saves the K/V-expansion HBM the round-3 path paid here
@@ -590,9 +693,15 @@ def gpt_block(cfg: GPTConfig, p: Dict, x: Array, rng: Optional[Array],
             from deepspeed_tpu.ops.attention import alibi_slopes
             o = attention_fn(q, k, v, causal=True,
                              alibi=jnp.asarray(alibi_slopes(H)))
+        elif kind.window is not None:
+            # the flash kernel has no window: the masked einsum, whatever
+            # ``attn_impl`` says (README: a window layer is served, not trained)
+            from deepspeed_tpu.ops.attention import reference_attention
+            o = reference_attention(q, k, v, causal=True,
+                                    bias=_window_bias(S, kind.window))
         else:
             o = attention_fn(q, k, v, causal=True)
-        o = o.reshape(B, S, E)
+        o = o.reshape(B, S, cfg.attn_dim)
         o = o @ _wget(p, "out_w", dt)
         if cfg.use_bias:
             o = o + p["out_b"].astype(dt)
@@ -602,15 +711,18 @@ def gpt_block(cfg: GPTConfig, p: Dict, x: Array, rng: Optional[Array],
         if cfg.block_type == "sequential":
             x = _constrain(x + o, mesh_lib.BATCH_AXES, "seq", None)
             h2 = _maybe_actq(cfg, _norm(cfg, x, p["ln2_g"], p["ln2_b"]))
-            f, moe_aux, _ = _ffn(cfg, p, h2, dt, rng=r[1], train=train)
+            f, moe_aux, _ = _ffn(cfg, p, h2, dt, rng=r[1], train=train,
+                                 attn_in=h)
             x = x + _dropout(f, cfg.dropout, r[2], train)
         elif cfg.block_type == "parallel":
             # GPT-NeoX use_parallel_residual: x + attn(ln1 x) + mlp(ln2 x)
             h2 = _norm(cfg, x, p["ln2_g"], p["ln2_b"])
-            f, moe_aux, _ = _ffn(cfg, p, h2, dt, rng=r[1], train=train)
+            f, moe_aux, _ = _ffn(cfg, p, h2, dt, rng=r[1], train=train,
+                                 attn_in=h)
             x = x + o + _dropout(f, cfg.dropout, r[2], train)
         else:   # parallel_single_ln (GPT-J): one LN feeds attn AND mlp
-            f, moe_aux, _ = _ffn(cfg, p, h, dt, rng=r[1], train=train)
+            f, moe_aux, _ = _ffn(cfg, p, h, dt, rng=r[1], train=train,
+                                 attn_in=h)
             x = x + o + _dropout(f, cfg.dropout, r[2], train)
     return _constrain(x, mesh_lib.BATCH_AXES, "seq", None), moe_aux
 
@@ -654,11 +766,14 @@ def gpt_forward(cfg: GPTConfig, params: Dict, input_ids: Array,
             x = x + params["wpe"].astype(dt)[:S][None]
         x = _dropout(x, cfg.dropout, rng, train)
 
-    body = partial(gpt_block, cfg, train=train, attention_fn=attention_fn)
+    # one body a kind of the layer pattern (a kind is static)
+    bodies = [partial(gpt_block, cfg, train=train, attention_fn=attention_fn,
+                      kind=kind) for kind in cfg.pattern]
     if cfg.remat:
         from deepspeed_tpu.runtime.activation_checkpointing.checkpointing import (
             checkpoint_policy)
-        body = jax.checkpoint(body, policy=checkpoint_policy())
+        bodies = [jax.checkpoint(b, policy=checkpoint_policy()) for b in bodies]
+    n_kinds = len(bodies)
 
     # random-LTD: each block trains on its own sorted random token subset,
     # the rest riding the residual stream (data_pipeline/data_routing)
@@ -678,7 +793,8 @@ def gpt_forward(cfg: GPTConfig, params: Dict, input_ids: Array,
 
     zero_aux = jnp.zeros((), jnp.float32)
 
-    def apply_block(p, x, r, idx=None, ltd_this_layer=True):
+    def apply_block(p, x, r, idx=None, ltd_this_layer=True, j=0):
+        body = bodies[j]
         if ltd_on and idx is not None and ltd_this_layer:
             sub, aux = body(p, jnp.take(x, idx, axis=1), r)
             return x.at[:, idx].set(sub), aux
@@ -708,6 +824,9 @@ def gpt_forward(cfg: GPTConfig, params: Dict, input_ids: Array,
             # start/done hides it under the matmuls) before consuming the
             # ring head.  The gathers' custom-vjp backward reduce-scatters
             # each block's grads as its backward slice completes.
+            assert n_kinds == 1, (
+                "layered ZeRO-3 prefetch walks identical layers; a layer "
+                "pattern of several kinds is not trained (README)")
             blocks = params["blocks"]
             depth = pf.clamped_depth(cfg.n_layer)
             ring = tuple(pf.gather_block(blocks, jnp.int32(k))
@@ -731,10 +850,11 @@ def gpt_forward(cfg: GPTConfig, params: Dict, input_ids: Array,
                 ((x, aux_total), _), _ = jax.lax.scan(
                     scan_body, ((x, zero_aux), ring), xs)
         else:
-            def scan_body(carry, layer):
+            def scan_body(j, carry, layer):
                 x, aux_sum = carry
                 r = layer["r"] if use_rngs else None
-                run = lambda xx: apply_block(layer["p"], xx, r, layer.get("idx"))
+                run = lambda xx: apply_block(layer["p"], xx, r,
+                                             layer.get("idx"), j=j)
                 if pld_on:   # lax.cond: a dropped block really skips its FLOPs
                     x, aux = jax.lax.cond(layer["keep"], run,
                                           lambda xx: (xx, zero_aux), x)
@@ -743,14 +863,15 @@ def gpt_forward(cfg: GPTConfig, params: Dict, input_ids: Array,
                 return (x, aux_sum + aux), None
 
             with jax.named_scope("blocks"):
-                (x, aux_total), _ = jax.lax.scan(scan_body, (x, zero_aux), xs)
+                (x, aux_total), _ = _scan_layers(n_kinds, scan_body,
+                                                 (x, zero_aux), xs)
     else:
         for i in range(cfg.n_layer):
             r = jax.random.fold_in(rng, i) if (rng is not None and train) else None
             p = params["blocks"][f"h{i}"]
             ltd_this = cfg.ltd_layers is None or i in cfg.ltd_layers
             run = lambda xx: apply_block(p, xx, r, ltd_idx[i] if ltd_on else None,
-                                         ltd_this)
+                                         ltd_this, j=i % n_kinds)
             if pld_on:
                 x, aux = jax.lax.cond(pld_keep[i], run,
                                       lambda xx: (xx, zero_aux), x)
@@ -904,6 +1025,9 @@ def gpt_apply_with_cache(cfg: GPTConfig, params: Dict, input_ids: Array,
     (S_new = prompt length) and decode (S_new = 1) — one compiled program
     per S_new."""
     assert cfg.scan_layers, "KV-cache path requires scan_layers"
+    assert len(cfg.pattern) == 1, (
+        "the dense-cache generate() path walks identical layers; a model "
+        "with a layer pattern is served through init_serving()")
     from deepspeed_tpu.ops.pallas.decode_attention import decode_attention
     B, S = input_ids.shape
     H, D, E = cfg.n_head, cfg.head_dim, cfg.n_embd
@@ -946,7 +1070,8 @@ def gpt_apply_with_cache(cfg: GPTConfig, params: Dict, input_ids: Array,
             (li, zero, pos, zero))
         ck = jax.lax.dynamic_index_in_dim(ck_full, li, 0, keepdims=False)
         cv = jax.lax.dynamic_index_in_dim(cv_full, li, 0, keepdims=False)
-        o = decode_attention(q, ck, cv, pos, bias=attn_bias).reshape(B, S, E)
+        o = decode_attention(q, ck, cv, pos, bias=attn_bias).reshape(
+            B, S, cfg.attn_dim)
         o = o @ _wget(p, "out_w", dt)
         if cfg.use_bias:
             o = o + p["out_b"].astype(dt)
@@ -1043,7 +1168,13 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
     ``block_tables`` [B, MB] — logical→physical block map per row;
     ``write_blocks``/``write_offsets`` [B, S] — physical (block, offset)
     each new token's K/V lands in (invalid/padded tokens point at the trash
-    block).  Returns (logits [B, S, V] fp32, k_pages, v_pages), and with
+    block).  A model with a ``layer_pattern`` of ``P`` kinds keeps its
+    layers in ``P`` GROUPS by position in the period: the arena is
+    ``[n_layer / P, pages, BS, Hkv*D]`` (layer ``l`` is index ``l // P`` of
+    group ``l % P``; a page holds one block of every layer of ONE group),
+    and ``block_tables`` and ``write_blocks`` are sequences of ``P`` arrays,
+    a group each — a window group's table is a ring, logical block ``b`` in
+    column ``b % MB`` (``serving/kv_cache.py``).  Returns (logits [B, S, V] fp32, k_pages, v_pages), and with
     ``with_expert_counts`` (an MoE model) a fourth: the assignments per
     expert ``[experts]`` int32, summed over layers, of the rows that carry a
     request (those whose K/V does not go to the trash block).
@@ -1055,15 +1186,19 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
     refuses it.
     """
     assert cfg.scan_layers, "paged serving path requires scan_layers"
-    from deepspeed_tpu.ops.pallas.decode_attention import paged_attention
+    from deepspeed_tpu.ops.pallas.decode_attention import paged_layer_attention
     B, S = input_ids.shape
     H, E = cfg.n_head, cfg.n_embd
-    MB = block_tables.shape[1]
+    n_kinds = len(cfg.pattern)
+    if not isinstance(block_tables, (tuple, list)):
+        block_tables, write_blocks = (block_tables,), (write_blocks,)
+    assert len(block_tables) == len(write_blocks) == n_kinds
+    MB = block_tables[0].shape[1]
     BS = k_pages.shape[2]
     T = MB * BS
     dt = cfg.dtype
     pos2d = positions[:, None] + jnp.arange(S)[None]          # [B, S]
-    live = (write_blocks != 0).reshape(-1) if with_expert_counts else None
+    live = (write_blocks[0] != 0).reshape(-1) if with_expert_counts else None
 
     x = params["wte"].astype(dt)[input_ids]
     if cfg.position_encoding == "learned":
@@ -1081,31 +1216,34 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
     else:
         attn_bias = None
 
-    def layer(carry, p):
+    def layer(j, carry, p):
+        # ``li``: the layer's index inside its group ``j`` (the period)
         x, kp, vp, li = carry
+        kind, wblocks = cfg.pattern[j], write_blocks[j]
         with jax.named_scope("attn"):
             h = _norm(cfg, x, p["ln1_g"], p["ln1_b"])
-            q, k, v = _project_qkv(cfg, p, h, dt, pos2d)
+            q, k, v = _project_qkv(cfg, p, h, dt, pos2d, kind)
             # scatter the new K/V into the arena through the write map; rows
             # that must not write (padding, inactive slots) carry trash-block
             # coordinates, so the scatter itself needs no predication
-            kp = kp.at[li, write_blocks, write_offsets].set(
+            kp = kp.at[li, wblocks, write_offsets].set(
                 k.astype(kp.dtype).reshape(B, S, -1))
-            vp = vp.at[li, write_blocks, write_offsets].set(
+            vp = vp.at[li, wblocks, write_offsets].set(
                 v.astype(vp.dtype).reshape(B, S, -1))
-            kl = jax.lax.dynamic_index_in_dim(kp, li, 0, keepdims=False)
-            vl = jax.lax.dynamic_index_in_dim(vp, li, 0, keepdims=False)
-            o = paged_attention(q, kl, vl, block_tables, positions,
-                                bias=attn_bias).reshape(B, S, E)
+            with jax.named_scope(
+                    "attn_full" if kind.window is None else "attn_window"):
+                o = paged_layer_attention(
+                    q, kp, vp, li, block_tables[j], positions, bias=attn_bias,
+                    window=kind.window).reshape(B, S, cfg.attn_dim)
             o = o @ _wget(p, "out_w", dt)
             if cfg.use_bias:
                 o = o + p["out_b"].astype(dt)
         with jax.named_scope("mlp"):
             x, counts = _block_tail(cfg, p, x, h, o, dt, live)
-        return (x, kp, vp, li + 1), counts
+        return (x, kp, vp, li + int(j == n_kinds - 1)), counts
 
-    (x, k_pages, v_pages, _), counts = jax.lax.scan(
-        layer, (x, k_pages, v_pages, jnp.zeros((), jnp.int32)),
+    (x, k_pages, v_pages, _), counts = _scan_layers(
+        n_kinds, layer, (x, k_pages, v_pages, jnp.zeros((), jnp.int32)),
         params["blocks"])
     with jax.named_scope("head"):
         x = _norm(cfg, x, params["lnf_g"], params["lnf_b"])
@@ -1303,7 +1441,7 @@ class GPT:
         qk_norm = (cfg.n_head + cfg.kv_heads) * cfg.head_dim * int(cfg.qk_norm)
         norm = (2 if cfg.norm == "layernorm" else 1) * E   # gain (and shift)
         per_block = (E * cfg.qkv_dim + b * cfg.qkv_dim   # qkv (GQA-sized)
-                     + E * E + b * E                     # attn out
+                     + cfg.attn_dim * E + b * E          # attn out
                      + mlp + qk_norm + 2 * norm)
         total = cfg.padded_vocab * E + L * per_block + norm
         if cfg.position_encoding == "learned":

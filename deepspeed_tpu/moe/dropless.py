@@ -20,18 +20,27 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.ops.pallas.grouped_matmul import grouped_matmul
+from deepspeed_tpu.ops.pallas.grouped_matmul import (grouped_matmul,
+                                                     rows_to_whole_tiles)
 
 Array = jax.Array
 
 
-def softmax_topk(logits: Array, k: int) -> Tuple[Array, Array, Array]:
+def softmax_topk(logits: Array, k: int, renormalise: bool = False
+                 ) -> Tuple[Array, Array, Array]:
     """``logits [T, E]`` -> (probs ``[T, E]``, weights ``[T, k]``, experts
     ``[T, k]`` int32).  The softmax is over ALL experts, in float32, and the
-    weights are the raw probabilities of the chosen ``k`` (not renormalised:
-    OLMoE's ``norm_topk_prob`` false)."""
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    weights, experts = jax.lax.top_k(probs, k)
+    weights are the raw probabilities of the chosen ``k`` (OLMoE's
+    ``norm_topk_prob`` false) or, with ``renormalise``, those divided by
+    their sum: the softmax over the ``k`` chosen logits alone
+    (SmallThinker's ``norm_topk_prob`` true), computed as that."""
+    logits = logits.astype(jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    if renormalise:
+        chosen, experts = jax.lax.top_k(logits, k)
+        weights = jax.nn.softmax(chosen, axis=-1)
+    else:
+        weights, experts = jax.lax.top_k(probs, k)
     return probs, weights, experts.astype(jnp.int32)
 
 
@@ -63,14 +72,22 @@ def dropless_moe(x: Array, weights: Array, experts: Array, num_experts: int,
     expert's slice of a stacked ``w [E, in, out]``, and ``pick(b)`` gives
     each row its expert's slice of a stacked ``b [E, out]`` (a bias)."""
     T, k = experts.shape
+    # rows added behind the sorted assignments so that the kernel's whole
+    # row tiles hold them (0 where they already do, or no kernel runs): they
+    # lie in no group, so the bank computes nothing for them, and are cut off
+    pad = rows_to_whole_tiles(T * k, x.shape[1], x.dtype)
     with jax.named_scope("moe_dispatch"):
         flat = experts.reshape(-1)
         order = jnp.argsort(flat, stable=True)        # assignments by expert
         sizes = expert_counts(experts, num_experts)
         rows = x[order // k]                          # [T*k, M]
+        if pad:
+            rows = jnp.pad(rows, ((0, pad), (0, 0)))
     with jax.named_scope("moe_experts"):
         y = expert_fn(rows, lambda a, w: grouped_matmul(a, w, sizes),
-                      lambda b: b[flat[order]])
+                      lambda b: b[jnp.pad(flat[order], (0, pad))])
+        if pad:
+            y = y[:T * k]
     with jax.named_scope("moe_combine"):
         # back to token order by a gather (no scatter-add), then the
         # weighted sum over the k choices

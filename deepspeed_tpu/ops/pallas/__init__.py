@@ -33,7 +33,8 @@ def use_kernel(name: str) -> bool:
     than the reference it would replace.  Necessary, not sufficient: the
     kernel's own shape gate and sharding rule decide after it.  ``name`` is
     the kernel's (``ce``, ``fused_adam``, ``flash_attention``,
-    ``decode_attention``, ``paged_attention``, ``grouped_matmul``) and is
+    ``decode_attention``, ``paged_attention``, ``paged_gqa_attention``,
+    ``grouped_matmul``) and is
     not read here: a test's replacement answers for one kernel by it."""
     del name
     return platform() == "tpu"

@@ -218,7 +218,8 @@ def _decode_call(q, ck, cv, pos, *, bk):
     return out.reshape(B, Sq, H, D)
 
 
-def decode_attention_reference(q, ck, cv, pos, bias=None):
+def decode_attention_reference(q, ck, cv, pos, bias=None, kpos=None,
+                               window=None):
     """Plain-jnp full-cache decode attention: the parity reference, and the
     stated path for every shape :func:`kernel_shape_ok` refuses.
 
@@ -226,7 +227,10 @@ def decode_attention_reference(q, ck, cv, pos, bias=None):
     global position (query i of row b sits at ``pos[b] + i``; ``pos`` is a
     scalar or ``[B]``); cache ``[B, T, Hkv*D]``.  GQA-aware: attention is
     computed GROUPED against the un-expanded cache.  ``bias``: additive
-    ``[1|B, H, S_q, T]`` logit bias (ALiBi).
+    ``[1|B, H, S_q, T]`` logit bias (ALiBi).  ``kpos [B, T]`` gives each
+    cache row its key's position where that is not the row's index (a ring
+    of pages; a negative position is no key), and ``window`` lets a query at
+    ``t`` see the keys ``t - window + 1 .. t`` only.
 
     On every path here the rows past the last query's position are masked by
     a probability of exactly 0, not skipped: they must hold finite values
@@ -245,10 +249,14 @@ def decode_attention_reference(q, ck, cv, pos, bias=None):
     if bias is not None:
         s = s + bias.astype(jnp.float32).reshape(
             bias.shape[0], Hkv, G, *bias.shape[2:])
-    kpos = jax.lax.broadcasted_iota(jnp.int32, (Sq, T), 1)[None]
+    kpos = (jax.lax.broadcasted_iota(jnp.int32, (Sq, T), 1)[None]
+            if kpos is None else kpos[:, None, :])
     qpos = (jnp.broadcast_to(pos, (B,))[:, None, None]
             + jax.lax.broadcasted_iota(jnp.int32, (Sq, T), 0)[None])
-    s = jnp.where((kpos <= qpos)[:, None, None], s, NEG_INF)   # [B, Sq, T]
+    seen = kpos <= qpos                                        # [B, Sq, T]
+    if window is not None:
+        seen = seen & (kpos > qpos - window) & (kpos >= 0)
+    s = jnp.where(seen[:, None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(q.dtype), cv)
     return out.reshape(B, Sq, H, D)
@@ -265,7 +273,7 @@ def decode_attention_reference(q, ck, cv, pos, bias=None):
 # logical sequence memory decoupled from physical HBM placement.
 # --------------------------------------------------------------------------- #
 def paged_attention_reference(q, k_pages, v_pages, block_tables, lengths,
-                              bias=None):
+                              bias=None, window=None):
     """jnp paged attention: the parity reference, and the stated path for
     every shape :func:`kernel_shape_ok` refuses — the pages gathered through
     the table, then :func:`decode_attention_reference` with per-row
@@ -276,14 +284,26 @@ def paged_attention_reference(q, k_pages, v_pages, block_tables, lengths,
     logical order; ``lengths`` ``[B]`` int32 — tokens already in the cache
     for each row, i.e. the global position of the row's first query.
     ``bias``: optional additive ``[B, H, Sq, T]`` logit bias (ALiBi),
-    T = MB * BS.
+    T = MB * BS.  With a ``window`` the table is a RING: logical block ``b``
+    sits in column ``b % MB`` (``serving/kv_cache.py`` gives a window
+    group's pages back and keeps the table as wide as the window needs), so
+    a column's block is the newest one congruent to it, counted back from
+    the block of the row's last query.
     """
     B = q.shape[0]
+    if window is not None:
+        MB, BS = block_tables.shape[1], k_pages.shape[1]
+        newest = (jnp.asarray(lengths, jnp.int32) + q.shape[1] - 1) // BS
+        block = newest[:, None] - (newest[:, None] - jnp.arange(MB)[None]) % MB
+        kpos = (block[:, :, None] * BS + jnp.arange(BS)[None, None]).reshape(B, -1)
+    else:
+        kpos = None
     # gather [B, MB, BS, Hkv*D] -> [B, T, Hkv*D]: the T dim is the
     # sequence's LOGICAL positions 0..T-1 (tables are logically ordered)
     ck = k_pages[block_tables].reshape(B, -1, k_pages.shape[-1])
     cv = v_pages[block_tables].reshape(B, -1, v_pages.shape[-1])
-    return decode_attention_reference(q, ck, cv, lengths, bias=bias)
+    return decode_attention_reference(q, ck, cv, lengths, bias=bias,
+                                      kpos=kpos, window=window)
 
 
 # One tile of the paged kernel holds about this many cache rows: the 128 keys
@@ -443,6 +463,240 @@ def _paged_call(q, k_pages, v_pages, block_tables, lengths):
       jnp.asarray(block_tables, jnp.int32).reshape(-1),
       q.reshape(B, Sq, HD), k_pages, v_pages)
     return out.reshape(B, Sq, H, D)
+
+
+# --------------------------------------------------------------------------- #
+# Paged attention over grouped K/V heads, with an optional window, on the
+# WHOLE arena.  The successor of ``_paged_kernel`` (ROADMAP S1): the layer is
+# a scalar, so no layer of K and V is sliced out and copied for the kernel.
+# --------------------------------------------------------------------------- #
+def gqa_kernel_shape_ok(H: int, Hkv: int, D: int, block: int, dtype) -> bool:
+    """What :func:`_paged_gqa_kernel` takes: ``H = g * Hkv`` query heads, a
+    head a whole number of 128-lane tiles (the K/V heads are then aligned
+    lane slices of the folded page), a page a multiple of the sublane tile."""
+    sublane = 8 * 4 // np.dtype(dtype).itemsize
+    return H % Hkv == 0 and D % _LANES == 0 and block % sublane == 0
+
+
+def _paged_gqa_kernel(lay_ref, len_ref, tbl_ref, nxt_ref, q_ref, k_hbm, v_hbm,
+                      o_ref, k_buf, v_buf, sem, slot_ref, *, scale, bs, Sq,
+                      Hkv, D, MB, G, window):
+    """Grid (B,), a row a step, as ``_paged_kernel`` (tiles of ``G`` pages,
+    two buffers an operand, the next tile — the next row's first after the
+    row's last — fetched while this one is attended; read its docstring for
+    the semaphore and zero-fill invariants, which are kept).  What differs:
+
+    * K and V are the arena ``[layers, pages, bs, Hkv*D]`` whole, and the
+      layer's index arrives as a scalar: a page is ``k_hbm.at[layer, phys]``;
+    * the tables are not a scalar prefetch (256 rows of 1,024 blocks are the
+      whole 1 MiB of SMEM): a step is handed its row's table ``tbl_ref
+      [1, MB]`` and the next row's ``nxt_ref`` as SMEM blocks;
+    * per K/V head (a ``D``-lane slice of the staged tile) the ``g`` query
+      heads that share it are the rows of ONE ``[M, D] x [D, keys]`` product
+      (``q_ref`` is ``[1, Hkv, M, D]``, row ``i * Sq + s`` head ``i`` of the
+      group at query ``s``, padded to the sublane tile);
+    * with a ``window`` a row starts at the page of its first query's oldest
+      visible key, ``(len - window + 1) // bs``, masks inside it, and reads
+      the table as a ring (logical block ``b`` in column ``b % MB``).  A
+      query whose every key of a tile is masked (``Sq > 1`` only) adds
+      exactly nothing there: its probabilities are forced to 0."""
+    b = pl.program_id(0)
+    layer = lay_ref[0]
+    seq_len = len_ref[b]
+    rows_t = G * bs
+    M = q_ref.shape[2]
+
+    def first_page(row):
+        if window is None:
+            return 0
+        return jnp.maximum(len_ref[row] - (window - 1), 0) // bs
+
+    def pages_of(row):                            # live (DMA'd) pages
+        newest = (len_ref[row] + Sq - 1) // bs
+        return jnp.minimum(newest + 1 - first_page(row), MB)
+
+    p0, nk = first_page(b), pages_of(b)
+    nt = (nk + G - 1) // G                        # live tiles, >= 1
+
+    def tile_copies(row, t, slot, do):
+        first = first_page(row)
+
+        def page(j, c):
+            logical = first + t * G + j
+            col = logical % MB if window is not None else logical
+            phys = jnp.where(row == b, tbl_ref[0, col], nxt_ref[0, col])
+            dst = pl.ds(pl.multiple_of(j * bs, bs), bs)
+            do(pltpu.make_async_copy(k_hbm.at[layer, phys],
+                                     k_buf.at[slot, dst], sem.at[0, slot]))
+            do(pltpu.make_async_copy(v_hbm.at[layer, phys],
+                                     v_buf.at[slot, dst], sem.at[1, slot]))
+            return c
+
+        jax.lax.fori_loop(0, jnp.clip(pages_of(row) - t * G, 0, G), page, 0)
+
+    start = lambda cp: cp.start()
+
+    @pl.when(b == 0)
+    def _():
+        k_buf[...] = jnp.zeros_like(k_buf)
+        v_buf[...] = jnp.zeros_like(v_buf)
+        slot_ref[0] = 0
+        tile_copies(0, 0, 0, start)
+
+    slot0 = slot_ref[0]
+    q = [q_ref[0, h] for h in range(Hkv)]         # [M, D] each
+
+    def tile(t, carry):
+        slot = (slot0 + t) % 2
+        last = t + 1 == nt
+
+        @pl.when(jnp.logical_not(last) | (b + 1 < pl.num_programs(0)))
+        def _():
+            tile_copies(jnp.where(last, b + 1, b), jnp.where(last, 0, t + 1),
+                        1 - slot, start)
+
+        tile_copies(b, t, slot, lambda cp: cp.wait())
+        qpos = seq_len + jax.lax.broadcasted_iota(jnp.int32, (M, rows_t), 0) % Sq
+        cols = (p0 + t * G) * bs + jax.lax.broadcasted_iota(
+            jnp.int32, (M, rows_t), 1)
+        valid = (cols <= qpos) & (cols < (p0 + nk) * bs)
+        if window is not None:
+            valid = valid & (cols > qpos - window)
+        m, l, acc = (list(c) for c in carry)
+        for h in range(Hkv):
+            k = k_buf[slot, :, h * D:(h + 1) * D]             # [rows_t, D]
+            v = v_buf[slot, :, h * D:(h + 1) * D]
+            s = jax.lax.dot_general(q[h], k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32) * scale
+            s = jnp.where(valid, s, NEG_INF)                  # [M, rows_t]
+            m_new = jnp.maximum(m[h], jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            if window is not None:
+                p = jnp.where(valid, p, 0.0)
+            alpha = jnp.exp(m[h] - m_new)
+            l[h] = l[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            m[h] = m_new
+            acc[h] = acc[h] * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        return tuple(m), tuple(l), tuple(acc)
+
+    carry = (tuple(jnp.full((M, 1), NEG_INF, jnp.float32) for _ in range(Hkv)),
+             tuple(jnp.zeros((M, 1), jnp.float32) for _ in range(Hkv)),
+             tuple(jnp.zeros((M, D), jnp.float32) for _ in range(Hkv)))
+    _, l, acc = jax.lax.fori_loop(0, nt, tile, carry)
+    slot_ref[0] = (slot0 + nt) % 2
+    for h in range(Hkv):
+        o_ref[0, h] = (acc[h] / jnp.maximum(l[h], 1e-30)).astype(o_ref.dtype)
+
+
+def _paged_gqa_call(q, k_arena, v_arena, layer, block_tables, lengths, window):
+    B, Sq, H, D = q.shape
+    _, _, BS, lanes = k_arena.shape
+    Hkv = lanes // D
+    g = H // Hkv
+    MB = block_tables.shape[1]
+    G = paged_tile_pages(BS, MB, Sq, lanes, k_arena.dtype)
+    block_tables = jnp.asarray(block_tables, jnp.int32)[:, None, :]
+    sublane = 8 * 4 // np.dtype(q.dtype).itemsize
+    M = -(-g * Sq // sublane) * sublane
+    # [B, Sq, Hkv, g, D] -> [B, Hkv, g*Sq, D]: a K/V head's queries together
+    qg = q.reshape(B, Sq, Hkv, g, D).transpose(0, 2, 3, 1, 4).reshape(
+        B, Hkv, g * Sq, D)
+    qg = jnp.pad(qg, ((0, 0), (0, 0), (0, M - g * Sq), (0, 0)))
+    row = lambda b, *_: (b, 0, 0, 0)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,                    # layer, lengths
+        grid=(B,),
+        in_specs=[
+            # [B, 1, MB]: a block's last two dimensions are the array's
+            pl.BlockSpec((None, 1, MB), lambda b, *_: (b, 0, 0),
+                         memory_space=pltpu.MemorySpace.SMEM),
+            pl.BlockSpec((None, 1, MB),
+                         lambda b, *_: (jnp.minimum(b + 1, B - 1), 0, 0),
+                         memory_space=pltpu.MemorySpace.SMEM),
+            pl.BlockSpec((1, Hkv, M, D), row),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, Hkv, M, D), row),
+        scratch_shapes=[
+            pltpu.VMEM((2, G * BS, lanes), k_arena.dtype),
+            pltpu.VMEM((2, G * BS, lanes), v_arena.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),      # [K|V, tile buffer]
+            pltpu.SMEM((1,), jnp.int32),          # buffer of the row's tile 0
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_paged_gqa_kernel, scale=1.0 / np.sqrt(D), bs=BS,
+                          Sq=Sq, Hkv=Hkv, D=D, MB=MB, G=G, window=window),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, M, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=_pallas.interpret(),
+        name="paged_gqa_attention",
+    )(jnp.asarray(layer, jnp.int32).reshape(1), jnp.asarray(lengths, jnp.int32),
+      block_tables, block_tables, qg, k_arena, v_arena)
+    out = out[:, :, :g * Sq].reshape(B, Hkv, g, Sq, D)
+    return out.transpose(0, 3, 1, 2, 4).reshape(B, Sq, H, D)
+
+
+def paged_gqa_tile_pages(Sq, H, Hkv, D, BS, MB, dtype) -> int:
+    """As :func:`paged_kernel_tile_pages`, of the kernel
+    ``paged_gqa_attention`` (which takes no bias)."""
+    if (not _pallas.use_kernel("paged_gqa_attention")
+            or not gqa_kernel_shape_ok(H, Hkv, D, BS, dtype)
+            or not _pallas.single_device()):
+        return 0
+    return paged_tile_pages(BS, MB, Sq, Hkv * D, dtype)
+
+
+def paged_gqa_attention(q, k_arena, v_arena, layer, block_tables, lengths,
+                        window=None):
+    """Block-table attention of layer ``layer`` of the arena
+    ``[layers, pages, BS, Hkv*D]``: q ``[B, Sq, H, D]`` with ``H = g * Hkv``,
+    ``block_tables [B, MB]`` (a ring under a ``window``, see
+    :func:`paged_attention_reference`), ``lengths [B]``.  The kernel where
+    :func:`paged_gqa_tile_pages` says so, else the layer sliced out and the
+    gather reference."""
+    B, Sq, H, D = q.shape
+    _, _, BS, lanes = k_arena.shape
+    if paged_gqa_tile_pages(Sq, H, lanes // D, D, BS, block_tables.shape[1],
+                            k_arena.dtype):
+        return _paged_gqa_call(q, k_arena, v_arena, layer, block_tables,
+                               lengths, window)
+    kl = jax.lax.dynamic_index_in_dim(k_arena, layer, 0, keepdims=False)
+    vl = jax.lax.dynamic_index_in_dim(v_arena, layer, 0, keepdims=False)
+    return paged_attention_reference(q, kl, vl, block_tables, lengths,
+                                     window=window)
+
+
+def paged_layer_attention(q, k_arena, v_arena, layer, block_tables, lengths,
+                          bias=None, window=None):
+    """What ``gpt_paged_step`` calls a layer.  Grouped K/V heads or a window
+    go to :func:`paged_gqa_attention`; multi-head attention over every key
+    keeps the layer sliced out of the arena and :func:`paged_attention`
+    until the benchmark can see that copy disappear (ROADMAP S1, S0 (a))."""
+    D = q.shape[3]
+    assert bias is None or window is None, (
+        "a window layer with an additive bias has no paged path")
+    if bias is None and (window is not None
+                         or k_arena.shape[3] // D != q.shape[2]):
+        return paged_gqa_attention(q, k_arena, v_arena, layer, block_tables,
+                                   lengths, window)
+    kl = jax.lax.dynamic_index_in_dim(k_arena, layer, 0, keepdims=False)
+    vl = jax.lax.dynamic_index_in_dim(v_arena, layer, 0, keepdims=False)
+    return paged_attention(q, kl, vl, block_tables, lengths, bias=bias)
+
+
+def paged_layer_tile_pages(Sq, H, Hkv, D, BS, MB, dtype, bias=False,
+                           window=None) -> int:
+    """Pages a tile of the kernel :func:`paged_layer_attention` builds for
+    these shapes (0: a gather reference), by its rule."""
+    if not bias and (window is not None or Hkv != H):
+        return paged_gqa_tile_pages(Sq, H, Hkv, D, BS, MB, dtype)
+    return paged_kernel_tile_pages(Sq, H, Hkv, D, BS, MB, dtype, bias)
 
 
 def _mesh_divisors():

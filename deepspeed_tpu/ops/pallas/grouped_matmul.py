@@ -58,6 +58,17 @@ def kernel_shape_ok(A: int, K: int, N: int, dtype) -> bool:
             and _column_tile(K, N, dtype.itemsize) > 0)
 
 
+def rows_to_whole_tiles(A: int, K: int, dtype) -> int:
+    """Rows a caller adds behind its ``A`` sorted rows (in no group) so that
+    :func:`grouped_matmul` takes the kernel and not ``ragged_dot``: up to
+    the next whole row tile, where the kernel would run at all."""
+    pad = -A % _ROW_TILE
+    if pad and _pallas.use_kernel("grouped_matmul") and _pallas.single_device() \
+            and kernel_shape_ok(A + pad, K, 128, dtype):
+        return pad
+    return 0
+
+
 def visits(group_sizes, A: int, tm: int):
     """The kernel's walk: (group offsets ``[G+1]``, group of each visit,
     row tile of each visit, number of visits ``[1]``), the two lists

@@ -271,8 +271,17 @@ class ServingEngine:
         # ---- paged arena + control plane --------------------------------- #
         self.max_blocks_per_seq = (cfg.max_blocks_per_seq
                                    or -(-mcfg.n_positions // cfg.block_size))
-        self.alloc = PagedKVAllocator(cfg.num_blocks, cfg.block_size,
-                                      self.max_blocks_per_seq)
+        # a layer group a kind of the model's layer pattern, named by its
+        # window: ``num_blocks`` blocks of ALL layers are ``num_blocks *
+        # groups`` pages of one group's layers each, one pool
+        self._windows = tuple(kind.window for kind in mcfg.pattern)
+        if len(self._windows) > 1 and (cfg.kv_tiering or cfg.prefix_cache):
+            raise ValueError(
+                "init_serving: kv_tiering and prefix_cache share and spill "
+                "blocks of ONE table a sequence; this model's layer pattern "
+                f"keeps {len(self._windows)} (windows {self._windows}), and "
+                "a window group gives its blocks back")
+        self.alloc = self._new_allocator()
         self.sched = ServingScheduler(cfg, self.alloc, cfg.max_batch_size)
         self.sched.on_preempt = self._on_preempt
         self._k_pages, self._v_pages = init_arena(
@@ -299,11 +308,11 @@ class ServingEngine:
         # paged kernel's pages per tile, 0 on the einsum path; a stat of
         # every step and of the ``serve.stats`` span
         from deepspeed_tpu.ops.pallas.decode_attention import (
-            paged_kernel_tile_pages)
-        self.paged_tile_pages = paged_kernel_tile_pages(
+            paged_layer_tile_pages)
+        self.paged_tile_pages = paged_layer_tile_pages(
             1, mcfg.n_head, mcfg.kv_heads, mcfg.head_dim, cfg.block_size,
             self.max_blocks_per_seq, self.dtype,
-            bias=mcfg.position_encoding == "alibi")
+            bias=mcfg.position_encoding == "alibi", window=self._windows[0])
 
         # ---- the (single) jitted step ------------------------------------ #
         def step_fn(params, ids, positions, kp, vp, tables, wb, wo):
@@ -379,6 +388,12 @@ class ServingEngine:
             ranks=[0])
 
     # ------------------------------------------------------------------ #
+    def _new_allocator(self) -> PagedKVAllocator:
+        cfg = self._config
+        return PagedKVAllocator(cfg.num_blocks * len(self._windows),
+                                cfg.block_size, self.max_blocks_per_seq,
+                                windows=self._windows, chunk=cfg.prefill_chunk)
+
     def _span(self, name, **args):
         return maybe_span(name, self.tracer, **args)
 
@@ -483,10 +498,11 @@ class ServingEngine:
         def work():
             with self._span(f"serve.{phase}.dispatch", **stats):
                 fault_point("serve.step", step=self.step_count, phase=phase)
+                up = lambda group: tuple(jnp.asarray(a) for a in group)
                 tokens, kp, vp = self._step_fn(
                     self.params, jnp.asarray(ids), jnp.asarray(positions),
-                    self._k_pages, self._v_pages, jnp.asarray(tables),
-                    jnp.asarray(wb), jnp.asarray(wo))
+                    self._k_pages, self._v_pages, up(tables), up(wb),
+                    jnp.asarray(wo))
             with self._span(f"serve.{phase}.fetch", **stats):
                 return np.asarray(tokens).reshape(-1), kp, vp
         if self._bounded is None or not self._warm:
@@ -533,8 +549,7 @@ class ServingEngine:
         self._step_fn = jax.jit(self._raw_step_fn,
                                 donate_argnums=self._donate)
         self._warm = False          # fresh jit: the first dispatch recompiles
-        self.alloc = PagedKVAllocator(cfg.num_blocks, cfg.block_size,
-                                      self.max_blocks_per_seq)
+        self.alloc = self._new_allocator()
         self._k_pages, self._v_pages = init_arena(
             mcfg, cfg.num_blocks, cfg.block_size, dtype=self.dtype)
         if self.prefix is not None:
@@ -617,12 +632,14 @@ class ServingEngine:
             if total > mcfg.n_positions:
                 raise ValueError(f"prompt+max_new_tokens {total} exceeds "
                                  f"n_positions {mcfg.n_positions}")
-            if self.alloc.blocks_for_tokens(total) > min(
-                    cfg.num_blocks - 1, self.max_blocks_per_seq):
+            if (self.alloc.blocks_for_tokens(total) > self.max_blocks_per_seq
+                    or self.alloc.pages_for_tokens(total, total)
+                    > self.alloc.num_blocks - 1):
                 raise ArenaExhausted(
                     f"request needs {self.alloc.blocks_for_tokens(total)} "
-                    f"blocks; arena ceiling is "
-                    f"{min(cfg.num_blocks - 1, self.max_blocks_per_seq)}")
+                    f"blocks ({self.alloc.pages_for_tokens(total, total)} "
+                    f"pages); a table holds {self.max_blocks_per_seq}, the "
+                    f"arena {self.alloc.num_blocks - 1} pages")
             self._rid_counter += 1
             req = Request(rid=self._rid_counter, prompt=prompt,
                           max_new_tokens=mnt, slo=slo, arrival=self._clock())
@@ -663,13 +680,21 @@ class ServingEngine:
                 # from `active`, which is why the chunk is chosen after it
                 decode = sorted(self.sched.decode_batch(),
                                 key=lambda r: (r.priority, r.admit_seq))
+                given_back = self.alloc.given_back_ever
                 for r in decode:
                     if r.state == DECODE:      # not evicted by an earlier r
                         self.sched.ensure_capacity(r, r.prefilled + 1)
-                decode = self.sched.decode_batch()
-                sp.set(batch=len(decode))
-            with self._span("serve.prefill.build") as sp:
+                # the step's prompt chunk asks too: a window group holds a
+                # long prompt a ring at a time (a table that only grows has
+                # it all since admission, and this asks for nothing)
                 pf = self.sched.next_prefill()
+                if pf is not None:
+                    self.sched.ensure_capacity(pf[0], pf[1] + pf[2])
+                decode = self.sched.decode_batch()
+                sp.set(batch=len(decode), pages_full=self.alloc.pages_full,
+                       pages_window=self.alloc.pages_window,
+                       pages_given_back=self.alloc.given_back_ever - given_back)
+            with self._span("serve.prefill.build") as sp:
                 runs = pf is not None or bool(decode)   # else: no program
                 if runs:
                     inputs = self._idle_inputs()
@@ -881,8 +906,9 @@ class ServingEngine:
         chunk row writes to the trash block through an all-trash table."""
         R = self._config.max_batch_size + self._config.prefill_chunk
         return (np.zeros((R, 1), np.int32), np.zeros((R,), np.int32),
-                np.zeros((R, self.max_blocks_per_seq), np.int32),
-                np.zeros((R, 1), np.int32), np.zeros((R, 1), np.int32))
+                tuple(np.zeros((R, w), np.int32) for w in self.alloc.widths),
+                tuple(np.zeros((R, 1), np.int32) for _ in self.alloc.widths),
+                np.zeros((R, 1), np.int32))
 
     def _chunk_rows(self, inputs, req: Request, start: int, n: int):
         """One prompt chunk into the rows behind the slots, a token a row;
@@ -895,8 +921,10 @@ class ServingEngine:
         rows = slice(first, first + n)
         ids[rows, 0] = req.context[start:start + n]
         positions[rows] = np.arange(start, start + n)
-        tables[rows] = self.alloc.block_table(req.rid)
-        wb[rows, 0], wo[rows, 0] = self.alloc.write_map(req.rid, start, n)
+        for g in range(self.alloc.n_groups):
+            tables[g][rows] = self.alloc.block_table(req.rid, g)
+            wb[g][rows, 0], wo[rows, 0] = self.alloc.write_map(
+                req.rid, start, n, g)
 
     def _commit_prefill(self, req: Request, n: int, token: int):
         req.prefilled += n
@@ -918,10 +946,14 @@ class ServingEngine:
         ids, positions, tables, wb, wo = inputs
         for r in reqs:
             s = r.slot
-            ids[s, 0] = r.context[-1]
+            # the context's last token, without building the context: at
+            # 10,000 tokens a row the copies were a millisecond a step and
+            # most of the host's jitter (PERF.md § 6, PR 31)
+            ids[s, 0] = (r.generated or r.prompt)[-1]
             positions[s] = r.prefilled
-            tables[s] = self.alloc.block_table(r.rid)
-            wb[s], wo[s] = self.alloc.write_map(r.rid, r.prefilled, 1)
+            for g in range(self.alloc.n_groups):
+                tables[g][s] = self.alloc.block_table(r.rid, g)
+                wb[g][s], wo[s] = self.alloc.write_map(r.rid, r.prefilled, 1, g)
 
     def _append_token(self, req: Request, tok: int):
         req.generated.append(tok)

@@ -26,7 +26,7 @@ kernel can DMA); this module owns the host-side bookkeeping:
 All methods are O(blocks touched); nothing here ever touches jax.
 """
 
-from typing import Dict, List
+from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
@@ -35,99 +35,196 @@ class ArenaExhausted(Exception):
     """No free blocks and the caller chose not to (or could not) evict."""
 
 
+def window_table_blocks(window: int, chunk: int, block_size: int) -> int:
+    """Width of a window group's block table: the blocks that hold the keys
+    ``start - window + 1 .. start + chunk - 1`` a prompt chunk at ``start``
+    reads and writes, wherever ``start`` falls in a block."""
+    return -(-(window + chunk - 1) // block_size) + 1
+
+
+class _Run:
+    """One sequence's blocks in ONE group, in logical order: ``blocks[i]`` is
+    logical block ``first + i``.  A full group's ``first`` stays 0."""
+    __slots__ = ("first", "blocks", "given_back", "grow")
+
+    def __init__(self):
+        self.first, self.blocks, self.given_back, self.grow = 0, [], 0, 0
+
+    @property
+    def end(self) -> int:
+        return self.first + len(self.blocks)
+
+
 class PagedKVAllocator:
     """Host-side free-list allocator over ``num_blocks`` physical blocks.
 
     Block 0 is the trash block and is never handed out; usable capacity is
     ``num_blocks - 1`` blocks = ``(num_blocks - 1) * block_size`` tokens.
+
+    ``windows`` names the model's layer GROUPS (``models/gpt.py``: layers by
+    position in the period of the layer pattern), an entry a group: None for
+    full attention, else the keys a query sees.  A sequence holds one block
+    table a group, all from the ONE pool of equal blocks (a block then holds
+    one group's layers: a PAGE; ``num_blocks`` counts pages).  A full
+    group's table only grows.  A window group gives a block back once its
+    last key is out of every later query's window (:meth:`allocate` is told
+    how many tokens are ``resident``), and its table is a RING as wide as
+    the window and one prompt chunk of ``chunk`` tokens need
+    (:func:`window_table_blocks`): logical block ``b`` sits in column
+    ``b % width``.  The default, one full group, is the allocator every
+    homogeneous model has.
     """
 
     TRASH = 0
 
     def __init__(self, num_blocks: int, block_size: int,
-                 max_blocks_per_seq: int):
+                 max_blocks_per_seq: int,
+                 windows: Sequence[Optional[int]] = (None,), chunk: int = 1):
         assert num_blocks >= 2, "arena needs >= 1 usable block + trash"
         assert block_size >= 1 and max_blocks_per_seq >= 1
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
         self.max_blocks_per_seq = int(max_blocks_per_seq)
+        self.windows = tuple(windows)
+        self.n_groups = len(self.windows)
+        # columns of each group's table
+        self.widths = tuple(
+            self.max_blocks_per_seq if w is None else min(
+                self.max_blocks_per_seq,
+                window_table_blocks(w, chunk, self.block_size))
+            for w in self.windows)
         # LIFO free list: recently-freed blocks are reused first (their
         # pages are hot, and stale contents are fully overwritten before
         # any masked-in position can read them)
         self._free: List[int] = list(range(self.num_blocks - 1, 0, -1))
-        self._owned: Dict[object, List[int]] = {}   # seq id -> blocks, logical order
+        self._owned: Dict[object, List[_Run]] = {}   # seq id -> a run a group
         # block id -> total references (sequence owners + prefix-cache pins);
         # a block is live iff it has an entry here, free iff it is in _free
         self._refs: Dict[int, int] = {}
         self.eviction_count = 0
+        # pages the live sequences hold in full and in window groups, those
+        # their window groups have given back (what tables that only grow
+        # would still hold), and all that were ever given back
+        self.pages_full = self.pages_window = 0
+        self.given_back_total = self.given_back_ever = 0
 
     # -- capacity queries -------------------------------------------------- #
     @property
     def free_blocks(self) -> int:
-        return len(self._free)
+        """Free capacity in blocks of ALL layers (a page of each group)."""
+        return len(self._free) // self.n_groups
 
     @property
     def blocks_in_use(self) -> int:
-        return (self.num_blocks - 1) - len(self._free)
+        """Pages in use in the caller's unit, blocks of ALL layers (rounded
+        up): a share of ``num_blocks / n_groups``."""
+        return -(-((self.num_blocks - 1) - len(self._free)) // self.n_groups)
 
     def blocks_for_tokens(self, n_tokens: int) -> int:
         return -(-max(0, int(n_tokens)) // self.block_size)
 
+    def first_live_block(self, group: int, resident: int) -> int:
+        """The oldest logical block of ``group`` that a query at position
+        ``resident`` or later can still see."""
+        w = self.windows[group]
+        return 0 if w is None else max(0, resident - w + 1) // self.block_size
+
+    def pages_for_tokens(self, n_tokens: int, resident: int = 0) -> int:
+        """Pages a sequence holds, over all groups, with ``resident`` tokens
+        behind it and blocks up to ``n_tokens``."""
+        need = self.blocks_for_tokens(n_tokens)
+        return sum(min(need - self.first_live_block(g, resident), self.widths[g])
+                   for g in range(self.n_groups))
+
     def capacity_tokens(self) -> int:
         """Largest single-sequence footprint this arena can ever hold."""
-        return min(self.num_blocks - 1, self.max_blocks_per_seq) * self.block_size
+        return min((self.num_blocks - 1) // self.n_groups,
+                   self.max_blocks_per_seq) * self.block_size
 
     def can_allocate(self, seq_id, n_tokens: int) -> bool:
-        need = self.blocks_for_tokens(n_tokens) - len(self._owned.get(seq_id, ()))
-        return need <= self.free_blocks
+        held = sum(len(r.blocks) for r in self._owned.get(seq_id, ()))
+        return self.pages_for_tokens(n_tokens) - held <= len(self._free)
 
     # -- lifecycle --------------------------------------------------------- #
-    def allocate(self, seq_id, n_tokens: int) -> bool:
-        """Grow ``seq_id``'s block list to cover ``n_tokens`` logical
-        tokens.  Returns False when the free list cannot cover the growth —
-        the scheduler then evicts a victim and retries.
+    def allocate(self, seq_id, n_tokens: int, resident: int = 0) -> bool:
+        """Grow ``seq_id``'s block lists to cover ``n_tokens`` logical
+        tokens, ``resident`` of which are behind every query still to come:
+        a window group first gives back the blocks no such query can see,
+        and grows no further than its ring (``widths``) holds — a long
+        prompt's later chunks ask again as they come.  Returns False when
+        the free list cannot cover the growth — the scheduler then evicts a
+        victim and retries.
         Raises when a single sequence exceeds ``max_blocks_per_seq``.
 
         Partial-growth contract: a failed growth is all-or-nothing.  The
         free-list check happens before any block is popped, so on False a
-        nonempty owner's ``_owned`` list is byte-identical to before the
-        call (the scheduler may already have written KV into those blocks;
-        mutating the list here would orphan live device state), and an
-        owner that was empty is removed rather than left as a zero-block
-        entry.  The post-assert pins this down so a future rewrite of the
-        growth loop cannot quietly reintroduce partial growth."""
-        owned = self._owned.setdefault(seq_id, [])
-        before = len(owned)
-        need = self.blocks_for_tokens(n_tokens)
+        nonempty owner's lists are as they were after the giving back (the
+        scheduler may already have written KV into those blocks; mutating
+        them here would orphan live device state), and an owner that was
+        empty is removed rather than left as a zero-block entry."""
+        bs = self.block_size
+        need = -(-max(0, int(n_tokens)) // bs)
         if need > self.max_blocks_per_seq:
             raise ArenaExhausted(
                 f"sequence needs {need} blocks > max_blocks_per_seq "
                 f"{self.max_blocks_per_seq}")
-        grow = need - before
-        if grow <= 0:
+        runs = self._owned.get(seq_id)
+        new = runs is None
+        if new:
+            runs = [_Run() for _ in range(self.n_groups)]
+        total = 0
+        for run, window, width in zip(runs, self.windows, self.widths):
+            if window is not None:
+                self._give_back(run, max(0, resident - window + 1) // bs)
+            run.grow = min(need, run.first + width) - run.first - len(run.blocks)
+            if run.grow > 0:
+                total += run.grow
+        if total == 0:                  # every decode step inside a block
             return True
-        if grow > len(self._free):
-            if not owned:
-                del self._owned[seq_id]
-            assert len(self._owned.get(seq_id, ())) == before, (
-                "failed growth mutated _owned")
+        if total > len(self._free):
             return False
-        for _ in range(grow):
-            b = self._free.pop()
-            self._refs[b] = 1
-            owned.append(b)
+        if new:
+            self._owned[seq_id] = runs
+        for run, window in zip(runs, self.windows):
+            for _ in range(run.grow):
+                b = self._free.pop()
+                self._refs[b] = 1
+                run.blocks.append(b)
+            if run.grow > 0 and window is None:
+                self.pages_full += run.grow
+            elif run.grow > 0:
+                self.pages_window += run.grow
         return True
+
+    def _give_back(self, run: _Run, first_live: int) -> None:
+        """A window group's blocks below ``first_live`` return to the pool."""
+        n = min(max(0, first_live - run.first), len(run.blocks))
+        for b in run.blocks[:n]:
+            self.unref(b)
+        del run.blocks[:n]
+        run.first += n
+        run.given_back += n
+        self.pages_window -= n
+        self.given_back_total += n
+        self.given_back_ever += n
 
     def free(self, seq_id) -> int:
         """Drop ``seq_id``'s reference on every owned block; blocks whose
         last reference this was return to the free list.  Idempotent on
         unknown ids (a finished-then-evicted race is not an error)."""
-        blocks = self._owned.pop(seq_id, [])
-        # unref in reverse logical order so unshared blocks re-enter the
-        # LIFO free list in the same order the pre-refcount free() used
-        for b in reversed(blocks):
-            self.unref(b)
-        return len(blocks)
+        n = 0
+        for g, run in enumerate(self._owned.pop(seq_id, ())):
+            # unref in reverse logical order so unshared blocks re-enter the
+            # LIFO free list in the same order the pre-refcount free() used
+            for b in reversed(run.blocks):
+                self.unref(b)
+            n += len(run.blocks)
+            if self.windows[g] is None:
+                self.pages_full -= len(run.blocks)
+            else:
+                self.pages_window -= len(run.blocks)
+                self.given_back_total -= run.given_back
+        return n
 
     def evict(self, seq_id) -> int:
         """Preemption-path free: same reclamation, counted separately so
@@ -160,37 +257,60 @@ class PagedKVAllocator:
         logical prefix, copy-free: each gains a reference.  Must precede
         any private growth — the shared blocks are the sequence's first
         logical blocks, and they are full by construction, so every later
-        write lands past them in private blocks (structural COW)."""
-        assert not self._owned.get(seq_id), (
+        write lands past them in private blocks (structural COW).  One
+        group only: a shared block holds every layer's keys."""
+        assert self.n_groups == 1, "a prefix is shared under one group only"
+        assert seq_id not in self._owned, (
             f"adopt must precede private growth for {seq_id}")
         for b in blocks:
             self.ref(b)
-        self._owned[seq_id] = list(blocks)
+        run = _Run()
+        run.blocks = list(blocks)
+        self._owned[seq_id] = [run]
+        self.pages_full += len(blocks)
 
-    def owned_blocks(self, seq_id) -> List[int]:
-        """Copy of ``seq_id``'s physical block list, logical order."""
-        return list(self._owned.get(seq_id, ()))
+    def owned_blocks(self, seq_id, group: int = 0) -> List[int]:
+        """Copy of ``seq_id``'s physical block list in ``group``, logical
+        order (from the group's oldest live block)."""
+        runs = self._owned.get(seq_id)
+        return list(runs[group].blocks) if runs else []
 
     # -- table / write-map construction (traced-input shaping) ------------- #
-    def block_table(self, seq_id) -> np.ndarray:
-        """[max_blocks_per_seq] int32 physical ids, trash-padded."""
-        table = np.full((self.max_blocks_per_seq,), self.TRASH, np.int32)
-        owned = self._owned.get(seq_id, ())
-        table[:len(owned)] = owned
+    def block_table(self, seq_id, group: int = 0) -> np.ndarray:
+        """[widths[group]] int32 physical ids, trash-padded; a window
+        group's is the ring (logical block ``b`` in column ``b % width``)."""
+        width = self.widths[group]
+        table = np.full((width,), self.TRASH, np.int32)
+        runs = self._owned.get(seq_id)
+        if runs:
+            run = runs[group]
+            if self.windows[group] is None:
+                table[:len(run.blocks)] = run.blocks
+            elif run.blocks:
+                table[np.arange(run.first, run.end) % width] = run.blocks
         return table
 
-    def write_map(self, seq_id, start: int, n_tokens: int):
+    def write_map(self, seq_id, start: int, n_tokens: int, group: int = 0):
         """Physical (block, offset) for tokens at logical positions
         ``start .. start + n_tokens - 1``.
         → ([n_tokens] int32 blocks, [n_tokens] int32 offsets)."""
-        owned = self._owned.get(seq_id, ())
+        runs = self._owned.get(seq_id)
+        run = runs[group] if runs else _Run()
+        if n_tokens == 1 and run.blocks:         # a decode row, every step
+            block, offset = divmod(int(start), self.block_size)
+            assert run.first <= block < run.end, (
+                f"write outside allocation: position {start} needs block "
+                f"{block}, own {run.first}..{run.end - 1}")
+            return (np.asarray([run.blocks[block - run.first]], np.int32),
+                    np.asarray([offset], np.int32))
         pos = start + np.arange(int(n_tokens))
         logical = pos // self.block_size
-        assert not n_tokens or logical[-1] < max(len(owned), 1), (
-            f"write past allocation: pos {pos[-1]} needs block "
-            f"{logical[-1]}, own {len(owned)}")
-        phys = np.asarray([owned[b] if b < len(owned) else self.TRASH
-                           for b in logical], np.int32)
+        assert not n_tokens or (run.first <= logical[0]
+                                and logical[-1] < max(run.end, 1)), (
+            f"write outside allocation: positions {pos[0]}..{pos[-1]} need "
+            f"blocks {logical[0]}..{logical[-1]}, own {run.first}..{run.end - 1}")
+        phys = np.asarray([run.blocks[b - run.first] if b < run.end
+                           else self.TRASH for b in logical], np.int32)
         return phys, (pos % self.block_size).astype(np.int32)
 
     # -- invariants (tests) ------------------------------------------------ #
@@ -198,15 +318,30 @@ class PagedKVAllocator:
         """Every physical block is exactly one of: trash, free, or live
         with refcount >= 1 — and a live block's references account for
         every sequence holding it (sharing beyond the owner count is the
-        prefix cache's pin).  Raises AssertionError on violation."""
+        prefix cache's pin); a run fits its group's table; the page counts
+        are the runs'.  Raises AssertionError on violation."""
         owners: Dict[int, int] = {}
-        for seq_id, blocks in self._owned.items():
+        full = window = given_back = 0
+        for seq_id, runs in self._owned.items():
             in_seq = set()
-            for b in blocks:
-                assert 0 < b < self.num_blocks, f"bad block id {b}"
-                assert b not in in_seq, f"block {b} twice in {seq_id}"
-                in_seq.add(b)
-                owners[b] = owners.get(b, 0) + 1
+            for g, run in enumerate(runs):
+                assert len(run.blocks) <= self.widths[g], (
+                    f"{seq_id}: {len(run.blocks)} blocks in group {g}'s "
+                    f"table of {self.widths[g]}")
+                assert run.first == 0 or self.windows[g] is not None
+                if self.windows[g] is None:
+                    full += len(run.blocks)
+                else:
+                    window += len(run.blocks)
+                    given_back += run.given_back
+                for b in run.blocks:
+                    assert 0 < b < self.num_blocks, f"bad block id {b}"
+                    assert b not in in_seq, f"block {b} twice in {seq_id}"
+                    in_seq.add(b)
+                    owners[b] = owners.get(b, 0) + 1
+        assert (full, window, given_back) == (
+            self.pages_full, self.pages_window, self.given_back_total), (
+            "page counts drifted from the runs")
         for b, refs in self._refs.items():
             assert 0 < b < self.num_blocks, f"bad live block id {b}"
             assert refs >= 1, f"live block {b} with refcount {refs}"
@@ -225,11 +360,16 @@ class PagedKVAllocator:
 
 
 def init_arena(cfg, num_blocks: int, block_size: int, dtype=None):
-    """Device arena pair for ``models/gpt.py:gpt_paged_step``:
-    K/V ``[n_layer, num_blocks, block_size, kv_heads * head_dim]``."""
+    """Device arena pair for ``models/gpt.py:gpt_paged_step``: K/V
+    ``[n_layer / P, num_blocks * P, block_size, kv_heads * head_dim]`` for a
+    layer pattern of ``P`` kinds (``num_blocks`` counts blocks of ALL
+    layers; a page holds a block of the ``n_layer / P`` layers of one
+    group).  ``P = 1``: ``[n_layer, num_blocks, ...]``."""
     import jax.numpy as jnp
     dtype = dtype or cfg.dtype
-    shape = (cfg.n_layer, num_blocks, block_size, cfg.kv_heads * cfg.head_dim)
+    P = len(cfg.pattern)
+    shape = (cfg.n_layer // P, num_blocks * P, block_size,
+             cfg.kv_heads * cfg.head_dim)
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
 
 

@@ -12,6 +12,11 @@ Scheduling policy (see README § Serving):
   blocks are free, the best waiting request — ordered by (SLO priority,
   submit order) — is admitted.  Head-of-line blocking on an arena-full
   condition is deliberate: skipping ahead would starve long prompts.
+* **What a request holds** follows from the model's layer groups
+  (``kv_cache.PagedKVAllocator``): admission takes what the first chunks
+  need of a window group and all of the prompt of a full one, every later
+  chunk and every decode step asks again, and a window group gives back what
+  is out of every later query's window before it grows.
 * **Chunked prefill**: one prompt chunk (``prefill_chunk`` tokens) is
   processed per engine step, so a long prompt never stalls the decode
   batch for more than one chunk's latency.
@@ -388,14 +393,16 @@ class ServingScheduler:
             self.on_preempt(victim)
 
     def ensure_capacity(self, req: Request, n_tokens: int) -> None:
-        """Guarantee ``req`` owns blocks for ``n_tokens`` context tokens,
+        """Guarantee ``req`` owns blocks for ``n_tokens`` context tokens
+        (the allocator is told how many are resident, ``req.prefilled``: a
+        window group gives back what no later query sees before it grows),
         walking the reclamation ladder under arena pressure.  The victim
         order excludes the requester, so the loop strictly shrinks the
         active set and terminates; if the requester alone exceeds the
         arena we raise — host/NVMe tiers cannot substitute for device
         residency of the decode window, so this holds even when every
         other sequence has been spilled rather than destroyed."""
-        while not self.alloc.allocate(req.rid, n_tokens):
+        while not self.alloc.allocate(req.rid, n_tokens, req.prefilled):
             if self._reclaim_prefix(req, n_tokens):
                 continue
             victim = self._growth_victim(req)
@@ -510,6 +517,11 @@ class ServingScheduler:
             "free_slots": len(self._free_slots),
             "blocks_in_use": self.alloc.blocks_in_use,
             "blocks_free": self.alloc.free_blocks,
+            # pages (a block of ONE layer group) held under full and under
+            # window groups, and what the latter have given back
+            "pages_full": self.alloc.pages_full,
+            "pages_window": self.alloc.pages_window,
+            "pages_given_back": self.alloc.given_back_total,
             "preemptions": self.preemption_count,
             "finished": self.finished_count,
             "expired": self.expired_count,

@@ -138,3 +138,42 @@ def test_bias_is_each_rows_own_experts():
                                 lambda rows, matmul, pick: rows + pick(bias))
     want = (weights[..., None] * (x[:, None] + bias[experts])).sum(axis=1)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+
+
+def test_renormalised_weights_are_the_softmax_over_the_chosen_logits():
+    """``norm_topk_prob`` true: the softmax over the k chosen logits alone,
+    which is the softmax over all renormalised over the k."""
+    logits = jnp.asarray([[0.0, np.log(2.0), np.log(4.0), np.log(1.0)],
+                          [3.0, 3.0, -1.0, 3.0]], jnp.float32)
+    probs, weights, experts = dropless.softmax_topk(logits, 2, renormalise=True)
+    raw_probs, raw, raw_experts = dropless.softmax_topk(logits, 2)
+    np.testing.assert_array_equal(np.asarray(experts), np.asarray(raw_experts))
+    np.testing.assert_array_equal(np.asarray(probs), np.asarray(raw_probs))
+    np.testing.assert_allclose(np.asarray(weights[0]), [4 / 6, 2 / 6], rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(weights),
+                               np.asarray(raw / raw.sum(-1, keepdims=True)), rtol=1e-6)
+
+
+@pytest.mark.parametrize("T,k", [(20, 3), (43, 6), (64, 2)])
+def test_rows_are_padded_to_the_kernels_whole_tiles(kernels, monkeypatch, T, k):
+    """Where the kernel runs, ``T * k`` assignments that are not whole
+    128-row tiles are padded up to them (rows in no group, cut off again)
+    and take the kernel, not ``ragged_dot``; the result is that of every
+    expert then mask.  Whole tiles (64 x 2) are passed as they are."""
+    from deepspeed_tpu.ops.pallas import grouped_matmul as gm
+    kernels("grouped_matmul")
+    rows_seen = []
+    real = gm._grouped
+    monkeypatch.setattr(gm, "_grouped",
+                        lambda a, w, s: rows_seen.append(a.shape[0]) or real(a, w, s))
+    with jax.default_matmul_precision("highest"):
+        keys = jax.random.split(jax.random.PRNGKey(T + k), 3)
+        E, M, I = 8, 128, 128
+        x = jax.random.normal(keys[0], (T, M))
+        wi, wo = _bank(keys[1], E, M, I)
+        weights, experts = _routing("random", T, E, k, keys[2])
+        got = _grouped(x, weights, experts, wi, wo)
+        want = _every_expert_then_mask(x, weights, experts, wi, wo)
+    assert rows_seen == [-(-T * k // 128) * 128] * 2          # gate|up, down
+    assert got.shape == (T, M)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-4, rtol=2e-4)

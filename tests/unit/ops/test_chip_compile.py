@@ -209,6 +209,29 @@ def test_paged_kernel_compiles_at_a_serve_steps_rows(chip, slots, H, head_dim, M
     assert da.paged_kernel_tile_pages(1, H, H, head_dim, BS, MB, BF16) == 8
 
 
+@pytest.mark.parametrize("window,MB", [(None, 1024), (4096, 271)],
+                         ids=["full", "window"])
+def test_paged_gqa_kernel_compiles_at_smallthinker_heads(chip, window, MB):
+    """SmallThinker-21B-A3B's attention as its serve cell runs it: 28 query
+    heads on 4 K/V heads of D=128 (7 rows of one product a K/V lane slice),
+    pages of 16, ``Sq = 1``, 32 slots + a chunk of 224 = 256 rows, the arena
+    of two-layer pages WHOLE with the layer a scalar.  A full layer's table
+    is 1,024 blocks wide (256 of them are the whole 1 MiB of SMEM, which is
+    why a step is handed its row's table as a block); a window layer's is
+    the ring of ``(4096 + 224 - 1) / 16 + 1`` blocks."""
+    H, Hkv, D128, BS, rows = 28, 4, 128, 16, 256
+    assert da.gqa_kernel_shape_ok(H, Hkv, D128, BS, BF16)
+    assert not da.kernel_shape_ok(H, Hkv, D128, BS, BF16)     # the old gate: MHA only
+    arena = ((2, 57344, BS, Hkv * D128), BF16)
+    fn = lambda q, k, v, layer, tables, lengths: da.paged_layer_attention(
+        q, k, v, layer, tables, lengths, window=window)
+    text = _compiled_text(chip, fn, ((rows, 1, H, D128), BF16), arena, arena,
+                          ((), jnp.int32), ((rows, MB), jnp.int32), ((rows,), jnp.int32))
+    assert "tpu_custom_call" in text and "paged_gqa_attention" in text
+    assert "dynamic-slice" not in text        # no layer of K and V sliced out
+    assert da.paged_layer_tile_pages(1, H, Hkv, D128, BS, MB, BF16, window=window) == 8
+
+
 @pytest.mark.parametrize("rows", [1024, 512])
 @pytest.mark.parametrize("K,N", [(2048, 2048), (1024, 2048)])
 def test_grouped_matmul_compiles_at_olmoe_bank(chip, rows, K, N):
@@ -219,6 +242,20 @@ def test_grouped_matmul_compiles_at_olmoe_bank(chip, rows, K, N):
     from deepspeed_tpu.ops.pallas import grouped_matmul as gm
     assert gm.kernel_shape_ok(rows, K, N, BF16)
     text = _compiled_text(chip, gm.grouped_matmul, ((rows, K), BF16),
+                          ((64, K, N), BF16), ((64,), jnp.int32))
+    assert "tpu_custom_call" in text and "grouped_matmul" in text
+
+
+@pytest.mark.parametrize("K,N", [(2560, 1536), (768, 2560)])
+def test_grouped_matmul_compiles_at_smallthinker_bank(chip, K, N):
+    """SmallThinker's bank (gate|up ``[64, 2560, 1536]``, down ``[64, 768,
+    2560]``) at the serve cell's 256 rows x top 6 = 1,536 assignments,
+    twelve whole row tiles."""
+    from deepspeed_tpu.ops.pallas import grouped_matmul as gm
+    assert gm.kernel_shape_ok(1536, K, N, BF16)
+    assert gm.rows_to_whole_tiles(1536, K, BF16) == 0
+    assert gm.rows_to_whole_tiles(250 * 6, K, BF16) == 36     # 1,500 -> 1,536
+    text = _compiled_text(chip, gm.grouped_matmul, ((1536, K), BF16),
                           ((64, K, N), BF16), ((64,), jnp.int32))
     assert "tpu_custom_call" in text and "grouped_matmul" in text
 

@@ -1,0 +1,107 @@
+"""The paged kernel over grouped K/V heads with an optional window
+(``paged_gqa_attention``), through the Pallas interpreter, and the gather
+reference's window, both against attention written out over each row's
+LOGICAL keys: ``g`` in {1, 7}, with and without a window, ragged lengths, an
+idle row, tables that are rings where there is a window."""
+
+import numpy as np
+import pytest
+
+import jax.numpy as jnp
+
+from deepspeed_tpu.ops.pallas import decode_attention as da
+
+D, BS = 128, 8
+
+
+def make(g, Hkv, window, lengths, Sq, MB, layers=2, seed=0):
+    """An arena of ``layers`` layers in which layer 1 holds each row's live
+    blocks (layer 0 holds noise: a kernel that reads the wrong layer fails),
+    the tables, and the expected output from the logical keys."""
+    rng = np.random.default_rng(seed)
+    B, H, lanes = len(lengths), g * Hkv, Hkv * D
+    pages = 1 + sum(-(-(n + Sq) // BS) for n in lengths)
+    k_arena = rng.standard_normal((layers, pages, BS, lanes)).astype(np.float32)
+    v_arena = rng.standard_normal((layers, pages, BS, lanes)).astype(np.float32)
+    q = rng.standard_normal((B, Sq, H, D)).astype(np.float32)
+    tables = np.zeros((B, MB), np.int32)
+    want = np.zeros((B, Sq, H, D), np.float32)
+    free = list(range(1, pages))
+    rng.shuffle(free)
+    for b, n in enumerate(lengths):
+        if n == 0:                      # an idle row: all trash, any output
+            continue
+        T = n + Sq
+        k = rng.standard_normal((T, Hkv, D)).astype(np.float32)
+        v = rng.standard_normal((T, Hkv, D)).astype(np.float32)
+        first = 0 if window is None else max(0, n - window + 1) // BS
+        for blk in range(first, -(-T // BS)):
+            phys = free.pop()
+            tables[b, blk % MB if window else blk] = phys
+            rows = slice(blk * BS, min((blk + 1) * BS, T))
+            k_arena[1, phys, :rows.stop - rows.start] = k[rows].reshape(-1, lanes)
+            v_arena[1, phys, :rows.stop - rows.start] = v[rows].reshape(-1, lanes)
+        for s in range(Sq):
+            t = n + s
+            lo = 0 if window is None else max(0, t - window + 1)
+            for h in range(H):
+                sc = k[lo:t + 1, h // g] @ q[b, s, h] / np.sqrt(D)
+                p = np.exp(sc - sc.max())
+                want[b, s, h] = (p / p.sum()) @ v[lo:t + 1, h // g]
+    return (jnp.asarray(q), jnp.asarray(k_arena), jnp.asarray(v_arena),
+            jnp.asarray(tables), jnp.asarray(lengths, jnp.int32), want)
+
+
+CASES = [  # g, window, Sq, lengths (0: an idle row)
+    (1, None, 1, (150, 0, 7, 129)),
+    (7, None, 1, (150, 0, 7, 129)),
+    (1, 40, 1, (150, 0, 7, 39, 40, 41)),
+    (7, 40, 1, (150, 0, 7, 39, 40, 41)),
+    (7, 40, 3, (150, 38, 7)),
+    (7, None, 3, (150, 7)),
+]
+
+
+@pytest.mark.parametrize("g,window,Sq,lengths", CASES)
+def test_kernel_and_reference_equal_attention_over_the_logical_keys(
+        kernels, g, window, Sq, lengths):
+    MB = 24 if window is None else -(-(window + Sq - 1) // BS) + 1
+    q, ka, va, tables, lens, want = make(g, 2, window, lengths, Sq, MB)
+    live = np.asarray(lengths) > 0
+    ref = da.paged_attention_reference(q, ka[1], va[1], tables, lens,
+                                       window=window)
+    np.testing.assert_allclose(np.asarray(ref)[live], want[live], atol=2e-5)
+    kernels("paged_gqa_attention")
+    assert da.paged_gqa_tile_pages(Sq, 2 * g, 2, D, BS, MB, jnp.float32) > 0
+    out = da.paged_gqa_attention(q, ka, va, jnp.asarray(1), tables, lens,
+                                 window=window)
+    assert np.isfinite(np.asarray(out)).all()       # the idle row too
+    np.testing.assert_allclose(np.asarray(out)[live], want[live], atol=2e-5)
+
+
+def test_the_layer_rule_keeps_multi_head_full_attention_on_the_old_kernel(
+        kernels, monkeypatch):
+    """``paged_layer_attention``: grouped K/V heads or a window take the new
+    kernel, multi-head attention over every key the layer slice and
+    ``paged_attention`` (ROADMAP S1)."""
+    kernels("paged_gqa_attention", "paged_attention")
+    seen = []
+    monkeypatch.setattr(da, "_paged_gqa_call",
+                        lambda *a: seen.append("gqa") or a[0])
+    monkeypatch.setattr(da, "_paged_call",
+                        lambda *a: seen.append("mha") or a[0])
+    for g, window in ((1, None), (7, None), (1, 40)):
+        q, ka, va, tables, lens, _ = make(g, 2, window, (9,), 1, 8)
+        da.paged_layer_attention(q, ka, va, jnp.asarray(1), tables, lens,
+                                 window=window)
+    assert seen == ["mha", "gqa", "gqa"]
+
+
+def test_a_window_layer_with_a_bias_is_refused():
+    """No paged path masks a window under an additive bias (ALiBi): the rule
+    refuses the pair and does not drop the window."""
+    q, ka, va, tables, lens, _ = make(1, 2, 40, (9,), 1, 8)
+    bias = jnp.zeros((q.shape[0], q.shape[2], 1, tables.shape[1] * ka.shape[2]))
+    with pytest.raises(AssertionError, match="window layer with an additive bias"):
+        da.paged_layer_attention(q, ka, va, jnp.asarray(1), tables, lens,
+                                 bias=bias, window=40)

@@ -203,3 +203,64 @@ def test_allocator_fuzz_random_interleavings():
         a.unref(b)
     a.check_consistent()
     assert a.free_blocks == 15
+
+
+# ---- layer groups: a window group gives its pages back ------------------------- #
+def _grouped(num_pages=64, chunk=8):
+    """Blocks of 4 tokens; a full group and two groups with a window of 16;
+    tables 32 wide, the window groups' a ring of (16 + 8 - 1) / 4 -> 6, + 1."""
+    return PagedKVAllocator(num_pages, 4, 32, windows=(None, 16, 16), chunk=chunk)
+
+
+def test_window_group_frees_exactly_the_pages_out_of_the_window():
+    a = _grouped()
+    assert a.widths == (32, 7, 7) and a.n_groups == 3
+    # admission of a 40-token prompt: all of it under the full group, a ring
+    # under each window group
+    assert a.allocate("s", 40)
+    assert [len(a.owned_blocks("s", g)) for g in range(3)] == [10, 7, 7]
+    a.check_consistent()
+    held = set(a.owned_blocks("s", 1))
+    for resident in range(0, 60):
+        # the chunk or decode step at ``resident`` writes one token
+        assert a.allocate("s", max(40, resident + 1), resident)
+        a.check_consistent()
+        first = max(0, resident - 16 + 1) // 4       # block of the oldest visible key
+        end = min(-(-max(40, resident + 1) // 4), first + 7)
+        for g in (1, 2):
+            table = a.block_table("s", g)
+            run = a.owned_blocks("s", g)
+            assert len(run) == end - first
+            # the ring: logical block b in column b % 7, the rest trash
+            assert [table[b % 7] for b in range(first, end)] == run
+            assert (table != 0).sum() == len(run)
+        assert len(a.owned_blocks("s", 0)) == -(-max(40, resident + 1) // 4)
+        held |= set(a.owned_blocks("s", 1))
+    assert a.given_back_total == 2 * first and a.pages_window == 2 * (end - first)
+    # what a window group gave back went to the one pool
+    assert a.free_blocks == (63 - a.pages_full - a.pages_window) // 3
+    blocks, offsets = a.write_map("s", 59, 1, group=2)
+    assert blocks[0] == a.owned_blocks("s", 2)[-1] and offsets[0] == 3
+    with pytest.raises(AssertionError, match="outside allocation"):
+        a.write_map("s", 8, 1, group=1)              # block 2 was given back
+    assert a.free("s") == 15 + 2 * (end - first)
+    a.check_consistent()
+    assert a.pages_full == a.pages_window == a.given_back_total == 0
+    assert a.given_back_ever == 2 * first
+
+
+def test_grouped_growth_is_all_or_nothing_and_counts_every_group():
+    a = _grouped(num_pages=20)                        # 19 usable pages
+    assert a.pages_for_tokens(40) == 10 + 7 + 7
+    assert a.pages_for_tokens(40, resident=40) == 10 + 2 * 4
+    assert not a.can_allocate("s", 40) and not a.allocate("s", 40)
+    a.check_consistent()
+    assert a.blocks_in_use == 0 and "s" not in a._owned
+    assert a.allocate("s", 24)                        # 6 + 6 + 6
+    assert a.blocks_in_use == 6 and a.free_blocks == 0
+    before = [a.owned_blocks("s", g) for g in range(3)]
+    assert not a.allocate("s", 32, resident=24)       # needs 2 + 2 + 2, 1 free
+    assert [a.owned_blocks("s", g)[-4:] for g in range(3)] == [b[-4:] for b in before]
+    a.check_consistent()
+    with pytest.raises(AssertionError, match="one group"):
+        a.adopt("t", [1])

@@ -142,8 +142,8 @@ def test_the_program_has_one_shape_whatever_the_step_holds(model_and_params):
     dispatch = eng._dispatch
 
     def spy(phase, inputs, stats):
-        shapes.add(tuple(a.shape for a in inputs))
-        ids, positions, tables, wb, wo = inputs
+        ids, positions, (tables,), (wb,), wo = inputs      # one layer group
+        shapes.add(tuple(a.shape for a in (ids, positions, tables, wb, wo)))
         live = wb[:, 0] != 0
         assert int(live.sum()) == stats.get("batch", stats["chunk_tokens"])
         assert not tables[~live].any() and not wo[~live].any(), "idle rows: trash only"

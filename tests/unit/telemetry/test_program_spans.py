@@ -227,7 +227,9 @@ def test_spans_carry_counts_where_the_work_happens(tiny_model):
         assert by[name] == [chunk], name
     for name in ("serve.prefill.dispatch", "serve.prefill.fetch"):
         assert by[name] == [dict(chunk, chunk_tokens=8)], name
-    assert by["serve.grow"] == [{"batch": 0}]
+    # 12 prompt tokens in blocks of 8: two pages, one full group, no window
+    assert by["serve.grow"] == [{"batch": 0, "pages_full": 2, "pages_window": 0,
+                                 "pages_given_back": 0}]
     assert not any(n.startswith("serve.decode.") for n in by)
     assert (stats["programs"], stats["prefill_tokens"], stats["decode_batch"]) == (1, 8, 0)
     eng.step()                                  # last chunk: the first token

@@ -1,0 +1,169 @@
+"""On-device agreement of a benchmark configuration AS SERVED with its plain
+reference, at the published widths: the chip comparison of the
+``model-configs`` guide § 3 point 3, for any configuration file that names a
+``serve`` block and a ``reference`` (``benchmarks/configs/olmoe-1b-7b.json``,
+``smallthinker-21b-a3b.json``).
+
+Run standalone on a TPU host (``chiprun --chips 1 -- python
+tools/serve_parity.py benchmarks/configs/smallthinker-21b-a3b.json``); any
+other platform is an error (exit 1).  Seeded bf16 weights; a seeded sample of
+prompts (one of them longer than the model's window, where it has one) goes
+through ``init_serving()`` / ``submit().result()`` with the file's slots and
+chunk (prefill in chunks, then decode, on the program's kernels), and each
+served sequence through the file's reference in one full float32 forward pass:
+
+* on LOGITS, teacher-forced: for every served token, the reference's best
+  logit at that position less the reference's logit of the token served.
+  ``TIE_TOL`` (0.0625, the benchmark's) is the most a served token may lose
+  by: bf16 cannot promise the same argmax (PERF.md § 2);
+* the router, where the reference's module has a ``*_router_choices``: the
+  program's block in bf16 (``gpt_block``, layer by layer) against the
+  reference's float32 router, as the number of (layer, token) pairs whose
+  top-k SET differs (the last places swap on rounding).
+
+Prints one JSON line and exits 0 when every gap is inside ``--margin``
+(``TIE_TOL`` by default).  A configuration whose reference names a ``hidden``
+(a long context: SmallThinker) goes through the comparison that decides its
+cell's ``correct`` instead, all served sequences as one run's sample
+(``benchmarks/kinds/serve_backlog_resident.py:check_sample``: the gross limit
+on every token's gap and the limit on the median noise scale), and exits 0
+when that counts nothing wrong.  ``--bank float8_e4m3fn`` serves with the
+expert bank rounded through that type: the reading a limit must REFUSE, exit 1.
+"""
+
+import argparse
+import gc
+import importlib
+import json
+import os
+import sys
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+TIE_TOL = 0.0625
+PROMPTS, NEW = ((100, 11), (64, 12), (17, 13), (129, 14)), 48
+PARITY_BLOCKS = 1025            # blocks of ALL layers in the tool's arena
+
+
+def router_sets_that_differ(cfg, params, seq, choices_fn):
+    """(layer, token) pairs whose top-k set under the program's own bf16
+    block differs from the reference's float32 router, and their number."""
+    import jax
+    import numpy as np
+    from deepspeed_tpu.models import gpt
+    from deepspeed_tpu.moe import dropless
+    from deepspeed_tpu.ops.attention import get_attention_fn
+    recorded, real = [], dropless.softmax_topk
+
+    def recording(logits, k, *rest):
+        res = real(logits, k, *rest)
+        recorded.append(np.sort(np.asarray(res[2]), axis=-1))
+        return res
+    dropless.softmax_topk = recording
+    try:
+        x = params["wte"][seq][None]
+        for layer in range(cfg.n_layer):
+            p = jax.tree.map(lambda a: a[layer], params["blocks"])
+            x, _ = gpt.gpt_block(cfg, p, x, None, False, get_attention_fn("reference"),
+                                 kind=cfg.pattern[layer % len(cfg.pattern)])
+    finally:
+        dropless.softmax_topk = real
+    want = np.sort(np.asarray(choices_fn(params, seq)), axis=-1)        # [L, S, k]
+    return int((np.stack(recorded) != want).any(axis=-1).sum()), int(want[..., 0].size)
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("config", help="a file of benchmarks/configs/")
+    ap.add_argument("--margin", type=float, default=TIE_TOL,
+                    help="the most a served token may lose by")
+    ap.add_argument("--new", type=int, default=NEW, help="tokens served a prompt")
+    ap.add_argument("--bank", default=None, metavar="DTYPE",
+                    help="serve with the expert bank rounded through this type "
+                         "(float8_e4m3fn: what a bank in the precision below "
+                         "bf16 loses; the reference keeps the weights whole)")
+    args = ap.parse_args(argv)
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    if jax.devices()[0].platform != "tpu":
+        print(f"FAIL: needs a TPU, found {jax.devices()[0].platform}")
+        return 1
+    import deepspeed_tpu
+    from benchmarks.kinds.serve_backlog_resident import check_sample
+    from benchmarks.lib.build import model_from
+    from benchmarks.lib.cells import load_json, resolve
+
+    config = load_json(args.config)
+    model = model_from(config)
+    cfg, ref = model.cfg, config["reference"]
+    dtype = jnp.dtype(config["dtype"])
+    params = jax.jit(lambda key: jax.tree.map(lambda p: p.astype(dtype),
+                                              model.init_params(key)))(
+        jax.random.PRNGKey(27))
+    served_params = params
+    if args.bank:
+        low = lambda w: w.astype(jnp.dtype(args.bank)).astype(w.dtype)
+        served_params = dict(params, blocks=dict(params["blocks"], moe=dict(
+            params["blocks"]["moe"],
+            experts=jax.tree.map(low, params["blocks"]["moe"]["experts"]))))
+    eng = deepspeed_tpu.init_serving(model=model, params=served_params, config={
+        "serving": dict(config["serve"]["serving"], num_blocks=PARITY_BLOCKS)})
+    rng = np.random.default_rng(27)
+    lengths = [n for n, _ in PROMPTS]
+    window = max((k.window or 0) for k in cfg.pattern)
+    if window:
+        lengths.append(window + 200)            # prefill AND decode past the window
+    prompts = [rng.integers(0, cfg.vocab_size, n).tolist() for n in lengths]
+    futures = [eng.submit(p, max_new_tokens=args.new) for p in prompts]
+    served = [f.result() for f in futures]
+    tile_pages = eng.paged_tile_pages
+    eng.close()
+    del eng, futures, served_params      # the arena, and a rounded bank's copy
+    gc.collect()
+
+    out = {"config": os.path.basename(args.config), "layers": cfg.n_layer,
+           "device": jax.devices()[0].device_kind, "paged_tile_pages": tile_pages,
+           "margin": args.margin, "bank": args.bank or config["dtype"], "sequences": []}
+    kw = ref["kwargs"]
+    check = None
+    if "hidden" in ref:          # a long context: the comparison of its cell's kind
+        check = check_sample(model, params, ref, list(zip(prompts, served)))
+        out.update(wrong=check["wrong"], noise_scale_median=check["noise_scale_median"])
+        found = iter(zip(check["largest"], check["mean"], check["noise_scale"]))
+
+        def gaps_of(prompt, new):
+            worst, mean, scale = next(found)
+            return worst, mean, {"noise_scale": scale}
+    else:
+        logits_fn = jax.jit(lambda p, i: resolve(ref["logits"])(p, i, **kw))
+
+        def gaps_of(prompt, new):
+            seq = jnp.asarray(prompt + new, jnp.int32)
+            lg = logits_fn(params, seq)
+            at = jnp.arange(len(prompt) - 1, len(seq) - 1)
+            gaps = lg[at].max(-1) - lg[at, seq[at + 1]]
+            return float(gaps.max()), float(gaps.mean()), {}
+    module = importlib.import_module(ref["logits"].partition(":")[0])
+    choices = next((getattr(module, n) for n in dir(module)
+                    if n.endswith("_router_choices")), None)
+    for prompt, new in zip(prompts, served):
+        worst, mean, more = gaps_of(prompt, new)
+        row = dict({"prompt_tokens": len(prompt), "served_tokens": len(new),
+                    "largest_logit_gap": worst, "mean_logit_gap": mean}, **more)
+        if choices is not None:
+            fn = jax.jit(lambda p, i: choices(p, i, **kw))
+            row["topk_sets_that_differ"], row["of_layer_token_pairs"] = (
+                router_sets_that_differ(cfg, params, jnp.asarray(prompt + new, jnp.int32), fn))
+        out["sequences"].append(row)
+    out["largest_logit_gap"] = max(s["largest_logit_gap"] for s in out["sequences"])
+    inside = (out["largest_logit_gap"] <= args.margin if check is None
+              else check["wrong"] == 0)
+    out["ok"] = bool(inside and tile_pages > 0)
+    print(json.dumps(out))
+    return 0 if out["ok"] else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())
