@@ -329,12 +329,9 @@ def serve_phase(seed):
 
     # which attention ran, from the program's compiled text: every decode
     # slot and every token of the prompt chunk is a row of one query
-    rows, MB = SERVE["max_batch_size"] + chunk, engine.max_blocks_per_seq
-    i32 = jnp.int32
     kernels = kernels_in(engine._step_fn.lower(
-        engine.params, jnp.zeros((rows, 1), i32), jnp.zeros((rows,), i32),
-        engine._k_pages, engine._v_pages, jnp.zeros((rows, MB), i32),
-        jnp.zeros((rows, 1), i32), jnp.zeros((rows, 1), i32)).compile())
+        engine.params, jnp.zeros((engine._layout.packed_size,), jnp.int32),
+        engine._k_pages, engine._v_pages, engine._tables).compile())
     H, D = cfg.n_head, cfg.head_dim
     paged_ok = kernel_shape_ok(H, cfg.kv_heads, D, SERVE["block_size"], jnp.bfloat16)
     check(bool(kernels.get("paged_attention")) == paged_ok,

@@ -19,8 +19,19 @@ prefill_chunk, 1]`` tokens over the arena, every row one query.
 Rows that carry nothing (an idle slot, the chunk's rows past its tokens,
 all of them in a step without a chunk) carry trash-block write coordinates
 and all-trash block tables, so what a step holds is pure traced *data*.
-Block tables, positions, and write maps are int32 inputs produced by the
-host-side :class:`PagedKVAllocator` / :class:`ServingScheduler`; the arena
+
+The block tables are STATE of the step program, not an input: one
+``[max_batch_size, width]`` int32 table a layer group lives on the device
+beside the arena (all groups in one flat array, :class:`StepLayout`), row
+``s`` the table of the sequence in slot ``s``, donated to the step and
+returned by it.  A table changes by an entry every ``block_size`` tokens, so
+a step uploads ONE small int32 array (:meth:`ServingEngine._pack`): a token,
+a position, a slot and a live flag for every row, the slots whose row goes
+back to trash, and the entries the host-side :class:`PagedKVAllocator`
+recorded since the last step.  The program applies them, gathers each row's
+table by its slot, and computes the write coordinates from the table and the
+position (:func:`unpack_step`), so ``model.paged_step`` gets what whole
+tables built on the host would give it, value for value.  The arena
 arrays are donated back to the step on accelerators, so the KV cache is
 updated in place.  The e2e contract (tests/unit/serving): greedy outputs
 are token-identical to sequential ``generate()``, even across
@@ -33,7 +44,7 @@ future work and is rejected at ``submit()``.
 """
 
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -68,9 +79,9 @@ from deepspeed_tpu.utils.logging import log_dist
 SERVE_STEP_SPANS = (
     "serve.admit",              # deadlines, shed ladder, sched.admit
     "serve.grow",               # sort, ensure_capacity, decode_batch
-    "serve.prefill.build",      # next_prefill, the chunk's rows
-    "serve.decode.build",       # the decode slots' rows
-    "serve.prefill.dispatch",   # uploads and the call of the compiled step
+    "serve.prefill.build",      # the tables' edits, the chunk's rows, packed
+    "serve.decode.build",       # the decode slots' rows, packed
+    "serve.prefill.dispatch",   # the one upload and the call of the step
     "serve.prefill.fetch",      # the token row back on the host
     "serve.decode.dispatch",
     "serve.decode.fetch",
@@ -78,6 +89,73 @@ SERVE_STEP_SPANS = (
     "serve.decode.commit",
     "serve.stats",              # ledger, stats dict, gauges, emit
 )
+
+
+class StepLayout(NamedTuple):
+    """Shapes of the step's one upload and of the table state it edits.
+
+    The state is one flat int32 array: group ``g``'s ``[slots, widths[g]]``
+    table at ``offsets[g]``, row-major.  The upload is one flat int32 array:
+    ``[rows, 4]`` (token, position, slot, live), then ``[slots]`` (1: the
+    slot's row goes back to trash, before any entry), then ``[edits, 2]``
+    (address in the state, block), padded with addresses past the state's
+    end, which the scatter drops."""
+    slots: int                  # decode rows; rows of each table
+    rows: int                   # slots + prefill_chunk: rows of the program
+    widths: Tuple[int, ...]     # columns of each layer group's table
+    offsets: Tuple[int, ...]    # where each group's table starts in the state
+    block_size: int
+    edits: int                  # entries one upload can change
+
+    @classmethod
+    def of(cls, alloc: PagedKVAllocator, slots: int, chunk: int):
+        """``edits``: one admission of the longest sequence the tables hold
+        (it is handed its prompt's blocks at once), and beside it a block a
+        group for every decode row and a chunk's blocks."""
+        grow = slots + -(-chunk // alloc.block_size) + 1
+        offsets = tuple(slots * sum(alloc.widths[:g])
+                        for g in range(alloc.n_groups))
+        return cls(slots, slots + chunk, alloc.widths, offsets,
+                   alloc.block_size, sum(alloc.widths) + alloc.n_groups * grow)
+
+    @property
+    def state_size(self) -> int:
+        return self.slots * sum(self.widths)
+
+    @property
+    def packed_size(self) -> int:
+        return 4 * self.rows + self.slots + 2 * self.edits
+
+
+def unpack_step(lay: StepLayout, packed, state):
+    """The traced head of the step: one upload and the table state ->
+    ``(ids, positions, state, tables, write_blocks, write_offsets)``, the
+    state with this step's edits in it and the rest as
+    ``model.paged_step`` takes them.  A row that is not live reads and
+    writes the trash block through an all-trash table."""
+    import jax.numpy as jnp
+    TRASH = PagedKVAllocator.TRASH
+    R, S = lay.rows, lay.slots
+    rows = packed[:4 * R].reshape(R, 4)
+    ids, positions, slot = rows[:, 0:1], rows[:, 1], rows[:, 2]
+    live = rows[:, 3:4] != 0
+    cleared = packed[4 * R:4 * R + S, None] != 0
+    edits = packed[4 * R + S:].reshape(lay.edits, 2)
+    views = lambda flat: [flat[at:at + S * w].reshape(S, w)
+                          for at, w in zip(lay.offsets, lay.widths)]
+    state = jnp.concatenate([jnp.where(cleared, TRASH, t).reshape(-1)
+                             for t in views(state)])
+    state = state.at[edits[:, 0]].set(edits[:, 1], mode="drop")
+    logical = positions // lay.block_size
+    tables, write_blocks = [], []
+    for table, w in zip(views(state), lay.widths):
+        table = jnp.where(live, table[slot], TRASH)                # [R, w]
+        tables.append(table)
+        write_blocks.append(jnp.take_along_axis(
+            table, (logical % w)[:, None], axis=1))
+    write_offsets = jnp.where(live, positions[:, None] % lay.block_size, 0)
+    return (ids, positions, state, tuple(tables), tuple(write_blocks),
+            write_offsets)
 
 
 class ServeStepTimeout(RuntimeError):
@@ -286,6 +364,9 @@ class ServingEngine:
         self.sched.on_preempt = self._on_preempt
         self._k_pages, self._v_pages = init_arena(
             mcfg, cfg.num_blocks, cfg.block_size, dtype=self.dtype)
+        self._layout = StepLayout.of(self.alloc, cfg.max_batch_size,
+                                     cfg.prefill_chunk)
+        self._tables = self._empty_tables()
 
         # ---- tiered spill/restage + prefix sharing (both opt-in) ---------- #
         self.tiering: Optional[KVTieringManager] = None
@@ -315,7 +396,11 @@ class ServingEngine:
             bias=mcfg.position_encoding == "alibi", window=self._windows[0])
 
         # ---- the (single) jitted step ------------------------------------ #
-        def step_fn(params, ids, positions, kp, vp, tables, wb, wo):
+        layout = self._layout
+
+        def step_fn(params, packed, kp, vp, state):
+            ids, positions, state, tables, wb, wo = unpack_step(
+                layout, packed, state)
             moe = {"with_expert_counts": True} if self._moe_experts else {}
             logits, kp, vp, *counts = model.paged_step(
                 params, ids, positions, kp, vp, tables, wb, wo, **moe)
@@ -327,11 +412,11 @@ class ServingEngine:
                 # an MoE model's expert counts ride behind the token row in
                 # the one int32 array the host fetches: no second transfer
                 tokens = jnp.concatenate([tokens.reshape(-1), *counts])
-            return tokens, kp, vp
+            return tokens, kp, vp, state
 
-        # arena donation = in-place KV update; CPU can't donate (jax warns
-        # and copies), so only donate on real accelerators
-        donate = (3, 4) if jax.default_backend() != "cpu" else ()
+        # arena and table donation = in-place update; CPU can't donate (jax
+        # warns and copies), so only donate on real accelerators
+        donate = (2, 3, 4) if jax.default_backend() != "cpu" else ()
         self._raw_step_fn = step_fn
         self._donate = donate
         self._step_fn = jax.jit(step_fn, donate_argnums=donate)
@@ -393,6 +478,13 @@ class ServingEngine:
         return PagedKVAllocator(cfg.num_blocks * len(self._windows),
                                 cfg.block_size, self.max_blocks_per_seq,
                                 windows=self._windows, chunk=cfg.prefill_chunk)
+
+    def _empty_tables(self):
+        """The table state of an engine nobody is in: all trash, on the
+        device (a transfer: nothing compiles)."""
+        import jax
+        return jax.device_put(np.full((self._layout.state_size,),
+                                      PagedKVAllocator.TRASH, np.int32))
 
     def _span(self, name, **args):
         return maybe_span(name, self.tracer, **args)
@@ -479,9 +571,10 @@ class ServingEngine:
             }, step=self.step_count)
 
     # ---- bounded dispatch + incident recovery -------------------------- #
-    def _dispatch(self, phase: str, inputs, stats):
-        """Run one compiled step over the host-built ``inputs`` (ids,
-        positions, tables, write blocks, write offsets) under the
+    def _dispatch(self, phase: str, packed, reload, stats):
+        """Run one compiled step over the step's one upload ``packed``
+        (:meth:`_pack`; ``reload``: the tables whole, when the edits did not
+        fit it) under the
         ``serve_step_timeout_s`` deadline (inline when unbounded), and
         return its token row on the host.  The host materialization of the
         token row happens *inside* the bounded callable — that device sync
@@ -492,19 +585,17 @@ class ServingEngine:
         prompt chunk alone.  The first dispatch (and the first after an
         incident re-jit) runs inline: it compiles, and compile time is not a
         wedge."""
-        import jax.numpy as jnp
-        ids, positions, tables, wb, wo = inputs
+        import jax
 
         def work():
             with self._span(f"serve.{phase}.dispatch", **stats):
                 fault_point("serve.step", step=self.step_count, phase=phase)
-                up = lambda group: tuple(jnp.asarray(a) for a in group)
-                tokens, kp, vp = self._step_fn(
-                    self.params, jnp.asarray(ids), jnp.asarray(positions),
-                    self._k_pages, self._v_pages, up(tables), up(wb),
-                    jnp.asarray(wo))
+                state = (self._tables if reload is None
+                         else jax.device_put(reload))
+                tokens, kp, vp, state = self._step_fn(
+                    self.params, packed, self._k_pages, self._v_pages, state)
             with self._span(f"serve.{phase}.fetch", **stats):
-                return np.asarray(tokens).reshape(-1), kp, vp
+                return np.asarray(tokens).reshape(-1), kp, vp, state
         if self._bounded is None or not self._warm:
             out = work()
             self._warm = True
@@ -516,7 +607,7 @@ class ServingEngine:
                     f"serve {phase} step {self.step_count} exceeded its "
                     f"{e.deadline_s:.3f}s deadline", op=phase,
                     deadline_s=e.deadline_s, step=self.step_count) from e
-        row, self._k_pages, self._v_pages = out
+        row, self._k_pages, self._v_pages, self._tables = out
         n = row.size - self._moe_experts
         self._expert_counts = row[n:]
         return row[:n]
@@ -552,6 +643,7 @@ class ServingEngine:
         self.alloc = self._new_allocator()
         self._k_pages, self._v_pages = init_arena(
             mcfg, cfg.num_blocks, cfg.block_size, dtype=self.dtype)
+        self._tables = self._empty_tables()     # nobody has a slot again
         if self.prefix is not None:
             # cached pins point at pre-incident arena content: rebuild
             self.prefix = PrefixCache(self.alloc,
@@ -672,6 +764,7 @@ class ServingEngine:
             sp.set(admitted=len(self.sched.admit(self._clock())))
         t_step = time.monotonic() if self.registry is not None else 0.0
         n_chunk, moe_stats = 0, {}
+        table_stats = {"table_edits": 0, "table_reloads": 0, "upload_bytes": 0}
         try:
             with self._span("serve.grow") as sp:
                 # growth pass, oldest/strongest first: each decode step
@@ -697,13 +790,13 @@ class ServingEngine:
             with self._span("serve.prefill.build") as sp:
                 runs = pf is not None or bool(decode)   # else: no program
                 if runs:
-                    inputs = self._idle_inputs()
+                    packed, rows, reload, table_stats = self._pack()
                 if pf is not None:
                     req, start, n_chunk = pf
                     chunk = {"rid": req.rid, "start": start,
                              "tokens": n_chunk}
                     sp.set(**chunk)
-                    self._chunk_rows(inputs, req, start, n_chunk)
+                    self._chunk_rows(rows, req, start, n_chunk)
             if decode:
                 # serve_decode_step_ms: a step with decode rows, from their
                 # build to their commit, so the step's ONE program with the
@@ -712,13 +805,13 @@ class ServingEngine:
                 t_dec = (time.monotonic() if self.registry is not None
                          else 0.0)
                 with self._span("serve.decode.build", batch=len(decode)):
-                    self._decode_rows(inputs, decode)
+                    self._decode_rows(rows, decode)
             if runs:
                 # one dispatch/fetch pair a program: named for the decode
                 # rows when it carries any (`batch`: every live row)
                 phase, at = (("decode", {"batch": len(decode) + n_chunk})
                              if decode else ("prefill", chunk))
-                tokens = self._dispatch(phase, inputs,
+                tokens = self._dispatch(phase, packed, reload,
                                         dict(at, chunk_tokens=n_chunk))
                 moe_stats = self._moe_stats()
             if pf is not None:
@@ -737,10 +830,10 @@ class ServingEngine:
         except ServeStepTimeout as err:
             self._recover_incident(err)
             raise
-        with self._span("serve.stats",
-                        paged_tile_pages=self.paged_tile_pages):
+        with self._span("serve.stats", paged_tile_pages=self.paged_tile_pages,
+                        **table_stats):
             stats = self._close_step(len(decode), n_chunk, int(runs), t_step)
-            stats.update(moe_stats)
+            stats.update(moe_stats, **table_stats)
             return stats
 
     def _moe_stats(self) -> Dict[str, float]:
@@ -900,31 +993,49 @@ class ServingEngine:
         return futures
 
     # ------------------------------------------------------------------ #
-    def _idle_inputs(self):
-        """Host arrays (ids, positions, tables, write blocks, write offsets)
-        of a step in which no row carries anything: every slot and every
-        chunk row writes to the trash block through an all-trash table."""
-        R = self._config.max_batch_size + self._config.prefill_chunk
-        return (np.zeros((R, 1), np.int32), np.zeros((R,), np.int32),
-                tuple(np.zeros((R, w), np.int32) for w in self.alloc.widths),
-                tuple(np.zeros((R, 1), np.int32) for _ in self.alloc.widths),
-                np.zeros((R, 1), np.int32))
+    def _pack(self):
+        """The step's one upload (:class:`StepLayout`), with no row live yet
+        and what the allocator recorded since the last program in it
+        -> (the flat array, its ``[rows, 4]`` view for the row builders, the
+        tables whole or None, the step's table stats).  When the edits do
+        not fit (several long admissions in one step) the tables go whole,
+        beside an upload that edits nothing."""
+        lay = self._layout
+        packed = np.zeros((lay.packed_size,), np.int32)
+        rows = packed[:4 * lay.rows].reshape(lay.rows, 4)
+        cleared, edits = self.alloc.drain_edits()
+        at = 4 * lay.rows + lay.slots
+        packed[at::2] = lay.state_size              # past the end: dropped
+        reload = None
+        if len(edits) > lay.edits:
+            reload = np.concatenate([t.reshape(-1) for t in
+                                     self.alloc.slot_tables(lay.slots)])
+        else:
+            packed[4 * lay.rows:at][cleared] = 1
+            if edits:
+                g, slot, col, block = np.asarray(edits, np.int32).T
+                offsets, widths = np.asarray(lay.offsets), np.asarray(lay.widths)
+                packed[at:at + 2 * len(edits):2] = (
+                    offsets[g] + slot * widths[g] + col)
+                packed[at + 1:at + 2 * len(edits):2] = block
+        stats = {"table_edits": 0 if reload is not None
+                 else len(cleared) + len(edits),
+                 "table_reloads": int(reload is not None),
+                 "upload_bytes": packed.nbytes + (
+                     0 if reload is None else reload.nbytes)}
+        return packed, rows, reload, stats
 
-    def _chunk_rows(self, inputs, req: Request, start: int, n: int):
-        """One prompt chunk into the rows behind the slots, a token a row;
-        stamps the residency's first."""
+    def _chunk_rows(self, rows, req: Request, start: int, n: int):
+        """One prompt chunk into the rows behind the slots, a token a row,
+        all reading the table of the request's slot; stamps the residency's
+        first."""
         if req.prefill_started_at is None:
             req.prefill_started_at = self._clock()
         req.prefill_chunks += 1
-        ids, positions, tables, wb, wo = inputs
         first = self._config.max_batch_size
-        rows = slice(first, first + n)
-        ids[rows, 0] = req.context[start:start + n]
-        positions[rows] = np.arange(start, start + n)
-        for g in range(self.alloc.n_groups):
-            tables[g][rows] = self.alloc.block_table(req.rid, g)
-            wb[g][rows, 0], wo[rows, 0] = self.alloc.write_map(
-                req.rid, start, n, g)
+        rows[first:first + n, 0] = req.context[start:start + n]
+        rows[first:first + n, 1] = np.arange(start, start + n)
+        rows[first:first + n, 2:] = req.slot, 1
 
     def _commit_prefill(self, req: Request, n: int, token: int):
         req.prefilled += n
@@ -941,19 +1052,15 @@ class ServingEngine:
             req.state = DECODE
             self._append_token(req, token)
 
-    def _decode_rows(self, inputs, reqs: List[Request]):
-        """Every decode-ready sequence into the row of its slot."""
-        ids, positions, tables, wb, wo = inputs
-        for r in reqs:
-            s = r.slot
-            # the context's last token, without building the context: at
-            # 10,000 tokens a row the copies were a millisecond a step and
-            # most of the host's jitter (PERF.md § 6, PR 31)
-            ids[s, 0] = (r.generated or r.prompt)[-1]
-            positions[s] = r.prefilled
-            for g in range(self.alloc.n_groups):
-                tables[g][s] = self.alloc.block_table(r.rid, g)
-                wb[g][s], wo[s] = self.alloc.write_map(r.rid, r.prefilled, 1, g)
+    def _decode_rows(self, rows, reqs: List[Request]):
+        """Every decode-ready sequence into the row of its slot: the
+        context's last token (without building the context) at the position
+        behind what is resident."""
+        slots = [r.slot for r in reqs]
+        rows[slots, 0] = [(r.generated or r.prompt)[-1] for r in reqs]
+        rows[slots, 1] = [r.prefilled for r in reqs]
+        rows[slots, 2] = slots
+        rows[slots, 3] = 1
 
     def _append_token(self, req: Request, tok: int):
         req.generated.append(tok)

@@ -23,10 +23,17 @@ kernel can DMA); this module owns the host-side bookkeeping:
   with tiering enabled, its block contents are spilled to host/NVMe first
   and *restored* on re-admission (``serving/kv_tiering.py``).
 
+The serving engine keeps every slot's tables ON THE DEVICE and changes them
+by edits (``serving/engine.py``): a sequence that has a slot (:meth:`bind`)
+leaves a record of every table entry that changes, which the engine takes
+once a step (:meth:`drain_edits`).  :meth:`block_table` and
+:meth:`write_map` build whole tables as before; they are what the engine
+reloads from, and the oracle the tests hold the edited tables to.
+
 All methods are O(blocks touched); nothing here ever touches jax.
 """
 
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -107,6 +114,12 @@ class PagedKVAllocator:
         # would still hold), and all that were ever given back
         self.pages_full = self.pages_window = 0
         self.given_back_total = self.given_back_ever = 0
+        # what changed in the slots' tables since the engine last asked:
+        # seq id -> its slot, slot -> {(group, column): block}, and the slots
+        # whose whole row went back to trash BEFORE those entries
+        self._slot: Dict[object, int] = {}
+        self._edits: Dict[int, Dict[Tuple[int, int], int]] = {}
+        self._cleared: set = set()
 
     # -- capacity queries -------------------------------------------------- #
     @property
@@ -172,10 +185,12 @@ class PagedKVAllocator:
         new = runs is None
         if new:
             runs = [_Run() for _ in range(self.n_groups)]
+        slot = self._slot.get(seq_id)
         total = 0
-        for run, window, width in zip(runs, self.windows, self.widths):
+        for g, (run, window, width) in enumerate(
+                zip(runs, self.windows, self.widths)):
             if window is not None:
-                self._give_back(run, max(0, resident - window + 1) // bs)
+                self._give_back(run, max(0, resident - window + 1) // bs, g, slot)
             run.grow = min(need, run.first + width) - run.first - len(run.blocks)
             if run.grow > 0:
                 total += run.grow
@@ -185,22 +200,28 @@ class PagedKVAllocator:
             return False
         if new:
             self._owned[seq_id] = runs
-        for run, window in zip(runs, self.windows):
+        for g, (run, window) in enumerate(zip(runs, self.windows)):
+            end = run.end
             for _ in range(run.grow):
                 b = self._free.pop()
                 self._refs[b] = 1
                 run.blocks.append(b)
+            if run.grow > 0 and slot is not None:
+                self._record(g, slot, end, run.blocks[end - run.first:])
             if run.grow > 0 and window is None:
                 self.pages_full += run.grow
             elif run.grow > 0:
                 self.pages_window += run.grow
         return True
 
-    def _give_back(self, run: _Run, first_live: int) -> None:
+    def _give_back(self, run: _Run, first_live: int, group: int,
+                   slot: Optional[int]) -> None:
         """A window group's blocks below ``first_live`` return to the pool."""
         n = min(max(0, first_live - run.first), len(run.blocks))
         for b in run.blocks[:n]:
             self.unref(b)
+        if n and slot is not None:
+            self._record(group, slot, run.first, [self.TRASH] * n)
         del run.blocks[:n]
         run.first += n
         run.given_back += n
@@ -213,6 +234,11 @@ class PagedKVAllocator:
         last reference this was return to the free list.  Idempotent on
         unknown ids (a finished-then-evicted race is not an error)."""
         n = 0
+        slot = self._slot.pop(seq_id, None)
+        if slot is not None:
+            # the row goes to trash whole, whatever was still to be said of it
+            self._edits.pop(slot, None)
+            self._cleared.add(slot)
         for g, run in enumerate(self._owned.pop(seq_id, ())):
             # unref in reverse logical order so unshared blocks re-enter the
             # LIFO free list in the same order the pre-refcount free() used
@@ -275,7 +301,48 @@ class PagedKVAllocator:
         runs = self._owned.get(seq_id)
         return list(runs[group].blocks) if runs else []
 
-    # -- table / write-map construction (traced-input shaping) ------------- #
+    # -- the slots' tables as edits (the engine's device tables) ------------ #
+    def bind(self, seq_id, slot: int) -> None:
+        """``seq_id`` decodes in ``slot`` until it is freed: row ``slot`` of
+        the engine's tables is its :meth:`block_table` from now on.  What it
+        holds already (admission allocates a prompt's blocks, and adopts a
+        cached prefix, before a slot is chosen) is recorded here."""
+        assert slot not in self._slot.values(), f"slot {slot} is taken"
+        self._slot[seq_id] = slot
+        for g, run in enumerate(self._owned.get(seq_id, ())):
+            self._record(g, slot, run.first, run.blocks)
+
+    def _record(self, group: int, slot: int, first: int, blocks) -> None:
+        """Logical blocks ``first ..`` of ``slot``'s run in ``group`` are now
+        ``blocks`` (column ``logical % width``, as :meth:`block_table`)."""
+        row, width = self._edits.setdefault(slot, {}), self.widths[group]
+        for logical, b in enumerate(blocks, first):
+            row[(group, logical % width)] = b
+
+    def drain_edits(self):
+        """What changed in the slots' tables since the last call, in the
+        order it applies: the slots whose row is all trash again, then
+        ``(group, slot, column, block)`` entries, each entry once (the last
+        word on it)."""
+        cleared = list(self._cleared)
+        edits = [(g, slot, col, b) for slot, row in self._edits.items()
+                 for (g, col), b in row.items()]
+        self._cleared, self._edits = set(), {}
+        return cleared, edits
+
+    def slot_tables(self, num_slots: int) -> List[np.ndarray]:
+        """Every slot's tables whole, ``[num_slots, widths[g]]`` int32 a
+        group: what all edits so far add up to (a slot nobody holds is all
+        trash).  The engine sends these when a step's edits outgrow its one
+        upload; pending edits are in them, so it drains and drops those."""
+        tables = [np.full((num_slots, w), self.TRASH, np.int32)
+                  for w in self.widths]
+        for seq_id, slot in self._slot.items():
+            for g, table in enumerate(tables):
+                table[slot] = self.block_table(seq_id, g)
+        return tables
+
+    # -- table / write-map construction (the reload, and the tests' oracle) - #
     def block_table(self, seq_id, group: int = 0) -> np.ndarray:
         """[widths[group]] int32 physical ids, trash-padded; a window
         group's is the ring (logical block ``b`` in column ``b % width``)."""
@@ -296,13 +363,6 @@ class PagedKVAllocator:
         → ([n_tokens] int32 blocks, [n_tokens] int32 offsets)."""
         runs = self._owned.get(seq_id)
         run = runs[group] if runs else _Run()
-        if n_tokens == 1 and run.blocks:         # a decode row, every step
-            block, offset = divmod(int(start), self.block_size)
-            assert run.first <= block < run.end, (
-                f"write outside allocation: position {start} needs block "
-                f"{block}, own {run.first}..{run.end - 1}")
-            return (np.asarray([run.blocks[block - run.first]], np.int32),
-                    np.asarray([offset], np.int32))
         pos = start + np.arange(int(n_tokens))
         logical = pos // self.block_size
         assert not n_tokens or (run.first <= logical[0]

@@ -303,6 +303,7 @@ class ServingScheduler:
                 self.waiting.appendleft(req)
                 return admitted
             req.slot = self._free_slots.pop()
+            self.alloc.bind(req.rid, req.slot)
             req.admit_seq = next(self._admit_counter)
             req.admitted_at, req.prefill_started_at = now, None
             req.prefill_chunks = 0

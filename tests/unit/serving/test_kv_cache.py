@@ -264,3 +264,60 @@ def test_grouped_growth_is_all_or_nothing_and_counts_every_group():
     a.check_consistent()
     with pytest.raises(AssertionError, match="one group"):
         a.adopt("t", [1])
+
+
+# ---- the slots' tables as edits (the engine's device tables) -------------------- #
+def _apply(tables, cleared, edits):
+    """What the step program does with a drain: rows to trash, then entries."""
+    for slot in cleared:
+        for t in tables:
+            t[slot] = 0
+    for g, slot, col, block in edits:
+        tables[g][slot, col] = block
+
+
+@pytest.mark.parametrize("bound_first", [False, True],
+                         ids=["allocate_then_bind", "bind_then_allocate"])
+def test_edits_add_up_to_the_block_tables(bound_first):
+    """A sequence's tables, kept only by applying what ``drain_edits`` says,
+    equal ``block_table`` after every step of a prompt and sixty decode steps
+    past a window; an unbound sequence says nothing; a ring column given back
+    and taken again in one drain is said once."""
+    a = _grouped()
+    tables = [np.zeros((4, w), np.int32) for w in a.widths]
+    if bound_first:
+        a.bind("s", 2)
+    assert a.allocate("s", 40)
+    assert a.allocate("other", 12)                    # never has a slot
+    if not bound_first:
+        assert a.drain_edits() == ([], [])
+        a.bind("s", 2)
+    cleared, edits = a.drain_edits()
+    assert cleared == [] and len(edits) == 10 + 7 + 7
+    _apply(tables, cleared, edits)
+    for resident in range(40, 100):
+        assert a.allocate("s", resident + 1, resident)
+        cleared, edits = a.drain_edits()
+        # the first step behind the prompt turns the rings over (each column
+        # once: given back and taken again); after it a block a group at most
+        assert cleared == [] and len(edits) <= (3 if resident > 40 else 1 + 7 + 7)
+        assert len({e[:3] for e in edits}) == len(edits)
+        _apply(tables, cleared, edits)
+        for g in range(3):
+            np.testing.assert_array_equal(tables[g][2], a.block_table("s", g))
+            assert not tables[g][[0, 1, 3]].any()
+    np.testing.assert_array_equal(np.stack([t[2] for t in a.slot_tables(4)][1:]),
+                                  np.stack([t[2] for t in tables][1:]))
+    # freed: the row goes back whole, and nothing is left to say of it
+    a.allocate("s", 101, 100)
+    a.free("s")
+    assert a.drain_edits() == ([2], [])
+    a.bind("other", 2)                                # the slot is free again
+    cleared, edits = a.drain_edits()
+    _apply(tables, [2], [])
+    _apply(tables, cleared, edits)
+    for g in range(3):
+        np.testing.assert_array_equal(tables[g][2], a.block_table("other", g))
+    with pytest.raises(AssertionError, match="taken"):
+        a.bind("third", 2)
+    a.check_consistent()

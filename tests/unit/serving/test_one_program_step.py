@@ -1,6 +1,6 @@
 """One program a serve step: the prompt chunk rides with the decode rows.
 
-A step builds ONE set of inputs, ``[max_batch_size + prefill_chunk, 1]``,
+A step builds ONE upload for ``[max_batch_size + prefill_chunk, 1]`` rows,
 dispatches one program, fetches one token row and commits the chunk and the
 decode rows from it.  Whatever the traffic puts in the rows (a multi-chunk
 prompt arriving while others decode, a prefix-cache hit, a preemption with
@@ -18,6 +18,7 @@ import jax
 
 from deepspeed_tpu.models.gpt import GPT, GPTConfig
 from deepspeed_tpu.serving import DeepSpeedServingConfig, ServingEngine
+from deepspeed_tpu.serving.engine import unpack_step
 from deepspeed_tpu.telemetry.tracing import Tracer
 
 MODELS = {
@@ -135,15 +136,20 @@ def test_mixed_traffic_is_token_identical_in_one_program(model_and_params, traff
 
 # ---- the step itself ------------------------------------------------------------ #
 def test_the_program_has_one_shape_whatever_the_step_holds(model_and_params):
-    """Chunk alone, chunk beside decode rows, decode rows alone: the same
-    ``[slots + chunk, 1]`` inputs, told apart only by what is in the rows."""
+    """Chunk alone, chunk beside decode rows, decode rows alone: the same one
+    upload, and the program makes of it the same ``[slots + chunk, 1]`` rows,
+    told apart only by what is in them."""
     eng = engine(model_and_params)
     shapes, kinds = set(), set()
     dispatch = eng._dispatch
+    unpack = jax.jit(unpack_step, static_argnums=0)
 
-    def spy(phase, inputs, stats):
-        ids, positions, (tables,), (wb,), wo = inputs      # one layer group
-        shapes.add(tuple(a.shape for a in (ids, positions, tables, wb, wo)))
+    def spy(phase, packed, reload, stats):
+        assert reload is None
+        ids, positions, _, (tables,), (wb,), wo = jax.tree.map(      # one layer group
+            np.asarray, unpack(eng._layout, packed, eng._tables))
+        shapes.add((packed.shape,) + tuple(
+            a.shape for a in (ids, positions, tables, wb, wo)))
         live = wb[:, 0] != 0
         assert int(live.sum()) == stats.get("batch", stats["chunk_tokens"])
         assert not tables[~live].any() and not wo[~live].any(), "idle rows: trash only"
@@ -153,7 +159,7 @@ def test_the_program_has_one_shape_whatever_the_step_holds(model_and_params):
             assert (np.diff(positions[SLOTS:SLOTS + n]) == 1).all()
             assert (tables[SLOTS:SLOTS + n] == tables[SLOTS]).all()
         kinds.add((phase, bool(n), bool(live[:SLOTS].any())))
-        return dispatch(phase, inputs, stats)
+        return dispatch(phase, packed, reload, stats)
 
     eng._dispatch = spy
     eng.submit(prompts_of(6, (11,))[0], max_new_tokens=5)
@@ -161,7 +167,8 @@ def test_the_program_has_one_shape_whatever_the_step_holds(model_and_params):
     eng.submit(prompts_of(7, (13,))[0], max_new_tokens=3)
     eng.run()
     R = SLOTS + CHUNK
-    assert shapes == {((R, 1), (R,), (R, eng.max_blocks_per_seq), (R, 1), (R, 1))}
+    assert shapes == {((eng._layout.packed_size,), (R, 1), (R,),
+                       (R, eng.max_blocks_per_seq), (R, 1), (R, 1))}
     assert kinds == {("prefill", True, False), ("decode", True, True),
                      ("decode", False, True)}
     assert eng.compiled_programs() == 1

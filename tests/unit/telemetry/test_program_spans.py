@@ -241,11 +241,52 @@ def test_spans_carry_counts_where_the_work_happens(tiny_model):
     assert by["serve.prefill.build"] == [None]
     assert not any(n in by for n in ("serve.prefill.dispatch", "serve.prefill.fetch",
                                      "serve.prefill.commit"))
-    # which paged kernel the engine runs (0: the einsum, as on this CPU)
-    assert by["serve.stats"] == [{"paged_tile_pages": eng.paged_tile_pages}]
+    # which paged kernel the engine runs (0: the einsum, as on this CPU), and
+    # what the step's tables cost: the one upload, no entry changed (the
+    # request was handed its two blocks at admission), nothing reloaded
+    table = {"table_edits": 0, "table_reloads": 0,
+             "upload_bytes": 4 * eng._layout.packed_size}
+    assert by["serve.stats"] == [dict(table, paged_tile_pages=eng.paged_tile_pages)]
+    assert {k: stats[k] for k in table} == table
     assert (stats["programs"], stats["prefill_tokens"], stats["decode_batch"]) == (1, 0, 1)
     fut.result()
-    assert eng.step()["programs"] == 0          # nothing to run: no program
+    idle = eng.step()                           # nothing to run: no program,
+    assert (idle["programs"], idle["upload_bytes"]) == (0, 0)       # no upload
+    eng.close()
+
+
+@pytest.mark.parametrize("wide", [16, 4])
+def test_table_stats_over_plain_decode(tiny_model, wide):
+    """``table_edits``, ``table_reloads`` and ``upload_bytes`` in ``step()``'s
+    stats and on ``serve.stats``.  Over plain decode nothing is reloaded and
+    a row gains an entry every ``block_size`` tokens; the one upload has one
+    size whatever the step holds, and it does not follow the ARENA (a table
+    ``wide`` blocks wide costs an entry a column in the room kept for one
+    admission, and no more: the parent uploaded ``rows x wide`` a step)."""
+    tr = Tracer()
+    eng = _engine(tiny_model, tracer=tr, max_blocks_per_seq=wide,
+                  num_blocks=64 if wide == 16 else 256)
+    lay = eng._layout
+    assert lay.edits == wide + (4 + 1 + 1)      # an admission, 4 rows, a chunk
+    assert 4 * lay.packed_size == 4 * (4 * 12 + 4 + 2 * lay.edits)
+    futs = [eng.submit(list(range(1, n)), max_new_tokens=20) for n in (4, 7, 10)]
+    seen = []
+    while not all(f.done for f in futs):
+        by, stats = _step_spans(tr, eng)
+        (on_span,) = by["serve.stats"]
+        assert {k: on_span[k] for k in ("table_edits", "table_reloads",
+                                        "upload_bytes")} == {
+            k: stats[k] for k in ("table_edits", "table_reloads", "upload_bytes")}
+        seen.append(stats)
+    assert {s["upload_bytes"] for s in seen} == {4 * lay.packed_size}
+    assert not any(s["table_reloads"] for s in seen)
+    # prompts of 3, 6 and 9 tokens are handed 1, 1 and 2 blocks of 8 as they
+    # are bound; with 20 new tokens each they cross into a next block 2, 3
+    # and 2 times more; a finished request's row goes back to trash with the
+    # next program, which the last to finish does not see
+    assert sum(s["table_edits"] for s in seen) == 4 + (2 + 3 + 2) + 2
+    assert seen[0]["table_edits"] == 4 and max(
+        s["table_edits"] for s in seen[1:]) <= 2
     eng.close()
 
 

@@ -180,10 +180,9 @@ def test_prefill_in_chunks_then_decode_through_the_engine(tiny):
     # program, with one int32 array (and the arena) as its result
     assert eng.compiled_programs() == 1
     rows = 4 + 8                                  # slots + the chunk's rows
-    out = jax.eval_shape(eng._raw_step_fn, eng.params, jnp.zeros((rows, 1), jnp.int32),
-                         jnp.zeros((rows,), jnp.int32), eng._k_pages, eng._v_pages,
-                         jnp.zeros((rows, 16), jnp.int32), jnp.zeros((rows, 1), jnp.int32),
-                         jnp.zeros((rows, 1), jnp.int32))[0]
+    out = jax.eval_shape(eng._raw_step_fn, eng.params,
+                         jnp.zeros((eng._layout.packed_size,), jnp.int32),
+                         eng._k_pages, eng._v_pages, eng._tables)[0]
     assert out.shape == (rows + 8,) and out.dtype == jnp.int32
     for prompt, f in zip(prompts, futures):
         seq = jnp.asarray(prompt + f.result())

@@ -240,11 +240,11 @@ def trace_programs() -> Dict[str, object]:
         smodel, DeepSpeedServingConfig(block_size=8, num_blocks=128,
                                        max_batch_size=8, prefill_chunk=16,
                                        dtype="float32"), seed=0)
-    B, MB = 8 + 16, srv.max_blocks_per_seq     # slots + the chunk's rows
+    # the step's one upload (8 slots + the chunk's 16 rows, the tables'
+    # edits) beside its state: the arena and the slots' tables
     out["serving-step"] = jax.make_jaxpr(srv._step_fn)(
-        srv.params, jnp.zeros((B, 1), jnp.int32), jnp.zeros((B,), jnp.int32),
-        srv._k_pages, srv._v_pages, jnp.zeros((B, MB), jnp.int32),
-        jnp.zeros((B, 1), jnp.int32), jnp.zeros((B, 1), jnp.int32))
+        srv.params, jnp.zeros((srv._layout.packed_size,), jnp.int32),
+        srv._k_pages, srv._v_pages, srv._tables)
     return out
 
 
