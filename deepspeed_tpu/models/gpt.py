@@ -52,6 +52,25 @@ class LayerKind(NamedTuple):
     rope: bool = True
 
 
+class YarnRope(NamedTuple):
+    """A rope whose frequencies are stretched by YaRN (the source's
+    ``rope_parameters``): lane pair ``i`` turns at ``theta^(-i/half)``, at
+    that over ``factor``, or at a blend of the two on the linear ramp between
+    the correction dimensions of ``beta_fast`` and ``beta_slow`` turns in
+    ``original_positions``.  ``mscale_all_dim`` scales the softmax (by the
+    square of :func:`yarn_mscale`; the cos/sin factor ``mscale /
+    mscale_all_dim`` is their ratio), and ``query_beta`` the query of
+    position ``p`` by ``1 + query_beta * ln(1 + p // original_positions)``
+    (``llama_4_scaling_beta``)."""
+    factor: float
+    original_positions: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+    query_beta: float = 0.0
+
+
 @dataclasses.dataclass
 class GPTConfig:
     vocab_size: int = 50257
@@ -144,6 +163,30 @@ class GPTConfig:
     # 'pre_attn' (the normed input attention reads: SmallThinker routes
     # before attention)
     moe_router_input: str = "post_attn"
+    # dropless router: what an expert's score is, 'softmax' over all experts
+    # or each logit's 'sigmoid' (the DeepSeek-V3 line: the ``moe_top_k``
+    # largest of score + bias, weighed by their scores)
+    moe_scoring: str = "softmax"
+    # experts every token goes through, beside the routed ones: ONE MLP of
+    # ``moe_shared_experts * moe_expert_hidden``, added unweighted
+    moe_shared_experts: int = 0
+    # ``(first, count)``: the bank holds only the experts ``first .. first +
+    # count - 1`` of the ``moe_num_experts`` the router chooses among (one
+    # chip's share of an expert-parallel layer); what the others would add
+    # is left out and the partial result goes on
+    moe_experts_held: Optional[Tuple[int, int]] = None
+    # --- latent attention (MLA, the DeepSeek-V2 line); ``kv_lora_rank``
+    # selects it.  The query goes down to ``q_lora_rank``, through an RMSNorm
+    # and up to ``n_head`` heads of ``head_dim``, whose LAST ``qk_rope_dim``
+    # lanes are rotated; keys and values come from ONE latent of
+    # ``kv_lora_rank`` a token (normed) and ONE rotated key of
+    # ``qk_rope_dim`` all heads share, which is all a cache holds; a head's
+    # value is ``v_head_dim`` wide (default ``head_dim``) ------------------ #
+    q_lora_rank: Optional[int] = None
+    kv_lora_rank: Optional[int] = None
+    qk_rope_dim: Optional[int] = None
+    v_head_dim: Optional[int] = None
+    rope_yarn: Optional[YarnRope] = None
 
     def __post_init__(self):
         self.padded_vocab = int(
@@ -174,6 +217,40 @@ class GPTConfig:
                 f"takes 1..{most}")
         assert self.norm in ("layernorm", "rmsnorm")
         assert self.mlp_type in ("standard", "swiglu")
+        assert self.moe_scoring in ("softmax", "sigmoid")
+        if self.moe_experts_held is not None:
+            first, count = self.moe_experts_held
+            assert self.moe_router == "dropless" and count >= 1 and \
+                0 <= first and first + count <= self.moe_num_experts, (
+                    f"moe_experts_held {self.moe_experts_held} of "
+                    f"{self.moe_num_experts} experts behind the "
+                    f"{self.moe_router} router")
+        # (first, count) of the experts the bank holds, whole or a share
+        self.bank_experts = self.moe_experts_held or (0, self.moe_num_experts)
+        self.v_head_dim = self.v_head_dim or self.head_dim
+        if self.rope_yarn is not None:
+            self.rope_yarn = YarnRope(*self.rope_yarn)
+        if self.kv_lora_rank:
+            assert self.q_lora_rank and self.qk_rope_dim and \
+                self.qk_rope_dim < self.head_dim and len(self.pattern) == 1 \
+                and self.kv_heads == self.n_head and not self.qk_norm and \
+                self.position_encoding == "rope", (
+                    "latent attention: q_lora_rank, qk_rope_dim (under "
+                    "head_dim), rope, one kind of layer, a head a query head")
+        else:
+            assert self.v_head_dim == self.head_dim
+
+    @property
+    def cache_lanes(self) -> Tuple[int, ...]:
+        """THE cache spec: lanes of each array the paged arena holds a token
+        a layer (``serving/kv_cache.py:init_arena``).  K and V, the heads
+        folded into the lanes; under latent attention ONE array, ``[latent |
+        rotated key]`` and zeros up to whole 128-lane tiles (the paged
+        kernel stages a page whole and multiplies all its lanes: 320 numbers
+        lie in 384 lanes, 768 B a token a layer in bf16 for the 640 cached)."""
+        if self.kv_lora_rank:
+            return (-(-(self.kv_lora_rank + self.qk_rope_dim) // 128) * 128,)
+        return (self.kv_heads * self.head_dim,) * 2
 
 
 # Model zoo (GPT-2 sizes; the 1.5B "xl" is the north-star model).
@@ -244,6 +321,36 @@ def smallthinker_config(vocab_size=151936, n_positions=16384, n_embd=2560,
                         intermediate_size=intermediate_size, **kw)
 
 
+def mistral4_config(vocab_size=131072, n_positions=1048576, n_embd=4096,
+                    n_layer=36, n_head=32, head_dim=128, q_lora_rank=1024,
+                    kv_lora_rank=256, qk_rope_dim=64, v_head_dim=128,
+                    intermediate_size=2048, num_experts=128, top_k=4,
+                    shared_experts=1, experts_held=None,
+                    rope_yarn=(128.0, 8192, 32.0, 1.0, 1.0, 1.0, 0.1),
+                    **overrides) -> GPTConfig:
+    """Mistral 4 family (defaults: Mistral-Small-4-119B; the DeepSeek-V3
+    line's layer): latent attention (a low-rank query with an RMSNorm inside,
+    ONE normed latent and ONE rotated key a token for keys and values, rope
+    on the last ``qk_rope_dim`` lanes of a head in interleaved pairs, YaRN
+    frequencies and scales), and every MLP a bank of SwiGLU experts behind a
+    dropless sigmoid router (the ``top_k`` largest of score + bias, weighed
+    by their scores renormalised) beside ``shared_experts`` every token goes
+    through; ``experts_held = (first, count)`` keeps one chip's share of the
+    bank.  RMSNorm (eps 1e-6), no bias, untied head."""
+    kw = dict(head_dim=head_dim, q_lora_rank=q_lora_rank,
+              kv_lora_rank=kv_lora_rank, qk_rope_dim=qk_rope_dim,
+              v_head_dim=v_head_dim, rope_interleaved=True,
+              rope_yarn=tuple(rope_yarn), ln_eps=1e-6,
+              moe_num_experts=num_experts, moe_top_k=top_k,
+              moe_router="dropless", moe_scoring="sigmoid",
+              moe_norm_topk=True, moe_shared_experts=shared_experts,
+              moe_experts_held=tuple(experts_held) if experts_held else None)
+    kw.update(overrides)
+    return llama_config(vocab_size=vocab_size, n_positions=n_positions,
+                        n_embd=n_embd, n_layer=n_layer, n_head=n_head,
+                        intermediate_size=intermediate_size, **kw)
+
+
 def bloom_config(vocab_size=250880, n_positions=2048, n_embd=512, n_layer=4,
                  n_head=8, **overrides) -> GPTConfig:
     """BLOOM family: ALiBi positions, GELU MLP, tied embeddings
@@ -274,7 +381,8 @@ def _init_block(cfg: GPTConfig, rng: Array) -> Dict:
         "ln1_b": jnp.zeros((E,), jnp.float32),
         "qkv_w": _dense_init(ks[0], E, (E, cfg.qkv_dim)),
         "qkv_b": jnp.zeros((cfg.qkv_dim,), jnp.float32),
-        "out_w": _dense_init(ks[1], E, (cfg.attn_dim, E), scale=proj_scale),
+        "out_w": _dense_init(ks[1], E, (cfg.n_head * cfg.v_head_dim, E),
+                             scale=proj_scale),
         "out_b": jnp.zeros((E,), jnp.float32),
         "ln2_g": jnp.ones((E,), jnp.float32),
         "ln2_b": jnp.zeros((E,), jnp.float32),
@@ -286,6 +394,20 @@ def _init_block(cfg: GPTConfig, rng: Array) -> Dict:
     if cfg.qk_norm:
         out["q_norm_g"] = jnp.ones((cfg.n_head * cfg.head_dim,), jnp.float32)
         out["k_norm_g"] = jnp.ones((cfg.kv_heads * cfg.head_dim,), jnp.float32)
+    if cfg.kv_lora_rank:
+        # latent attention: the fused qkv gives way to the two low-rank query
+        # projections and the joint K/V down- and up-projection
+        Rq, R, H = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.n_head
+        ka = jax.random.split(jax.random.fold_in(rng, 4321), 4)
+        del out["qkv_w"], out["qkv_b"]
+        out.update(
+            q_a_w=_dense_init(ka[0], E, (E, Rq)),
+            q_a_norm_g=jnp.ones((Rq,), jnp.float32),
+            q_b_w=_dense_init(ka[1], Rq, (Rq, H * cfg.head_dim)),
+            kv_a_w=_dense_init(ka[2], E, (E, R + cfg.qk_rope_dim)),
+            kv_a_norm_g=jnp.ones((R,), jnp.float32),
+            kv_b_w=_dense_init(ka[3], R, (R, H * (
+                cfg.head_dim - cfg.qk_rope_dim + cfg.v_head_dim))))
     if cfg.moe_num_experts > 0:
         # the MLP becomes a gated expert bank (reference moe/layer.py:16):
         # the dense fc/proj leaves, stacked over experts, as wi/bi/wo/bo
@@ -293,16 +415,28 @@ def _init_block(cfg: GPTConfig, rng: Array) -> Dict:
         km = jax.random.split(jax.random.fold_in(rng, 1234), 3)
         for k in ("fc_w", "fc_b", "proj_w", "proj_b"):
             del out[k]
-        up = (2 if cfg.mlp_type == "swiglu" else 1) * Ie
-        experts = {"wi": _dense_init(km[1], E, (N, E, up)),
-                   "wo": _dense_init(km[2], Ie, (N, Ie, E), scale=proj_scale)}
+        glu = 2 if cfg.mlp_type == "swiglu" else 1
+        up = glu * Ie
+        G = cfg.bank_experts[1]     # held by the bank; the router stays N wide
+        experts = {"wi": _dense_init(km[1], E, (G, E, up)),
+                   "wo": _dense_init(km[2], Ie, (G, Ie, E), scale=proj_scale)}
         if cfg.use_bias:
-            experts.update(bi=jnp.zeros((N, up), jnp.float32),
-                           bo=jnp.zeros((N, E), jnp.float32))
+            experts.update(bi=jnp.zeros((G, up), jnp.float32),
+                           bo=jnp.zeros((G, E), jnp.float32))
         out["moe"] = {
             "gate": {"wg": _dense_init(km[0], E, (E, N))},
             "experts": experts,
         }
+        if cfg.moe_scoring == "sigmoid":
+            # the score-correction bias: chooses, never weighs
+            out["moe"]["gate"]["bias"] = jnp.zeros((N,), jnp.float32)
+        if cfg.moe_shared_experts:
+            assert not cfg.use_bias, "a shared expert carries no bias"
+            Is = cfg.moe_shared_experts * Ie
+            ksh = jax.random.split(jax.random.fold_in(rng, 5678), 2)
+            out["moe"]["shared"] = {
+                "wi": _dense_init(ksh[0], E, (E, glu * Is)),
+                "wo": _dense_init(ksh[1], Is, (Is, E), scale=proj_scale)}
     return out
 
 
@@ -376,6 +510,16 @@ def gpt_partition_specs(cfg: GPTConfig) -> Dict:
                 del keys[k]
         if cfg.qk_norm:
             keys.update(q_norm_g=PartitionSpec(), k_norm_g=PartitionSpec())
+        if cfg.kv_lora_rank:
+            # the up-projections make heads: column-parallel; the two
+            # down-projections are shared by all heads
+            del keys["qkv_w"], keys["qkv_b"]
+            keys.update(q_a_w=PartitionSpec(None, None),
+                        q_a_norm_g=PartitionSpec(),
+                        q_b_w=PartitionSpec(None, "tensor"),
+                        kv_a_w=PartitionSpec(None, None),
+                        kv_a_norm_g=PartitionSpec(),
+                        kv_b_w=PartitionSpec(None, "tensor"))
         specs = {k: PartitionSpec(*pre, *s) for k, s in keys.items()}
         if cfg.moe_num_experts > 0:
             experts = {"wi": PartitionSpec(*pre, "expert", None, "tensor"),
@@ -385,6 +529,12 @@ def gpt_partition_specs(cfg: GPTConfig) -> Dict:
                                bo=PartitionSpec(*pre, "expert", None))
             specs["moe"] = {"gate": {"wg": PartitionSpec(*pre)},
                             "experts": experts}
+            if cfg.moe_scoring == "sigmoid":
+                specs["moe"]["gate"]["bias"] = PartitionSpec(*pre)
+            if cfg.moe_shared_experts:
+                specs["moe"]["shared"] = {
+                    "wi": PartitionSpec(*pre, None, "tensor"),
+                    "wo": PartitionSpec(*pre, "tensor", None)}
         return specs
 
     if cfg.scan_layers:
@@ -438,9 +588,33 @@ def _norm(cfg: "GPTConfig", x: Array, g: Array, b: Array) -> Array:
     return layer_norm(x, g, b, eps=cfg.ln_eps)
 
 
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature for a stretch of ``factor``."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_inv_freq(rd: int, theta: float, yarn: YarnRope) -> Array:
+    """``[rd / 2]`` turns a position of the ``rd`` rotated lanes under YaRN:
+    pair ``i`` keeps ``theta^(-2i/rd)`` below the correction dimension of
+    ``beta_fast`` (it turns often enough in the original range), takes it
+    over ``factor`` above that of ``beta_slow``, and blends the two on the
+    linear ramp between."""
+    half = rd // 2
+    base = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    # the pair that makes ``turns`` turns over the original positions
+    dim_of = lambda turns: rd * math.log(
+        yarn.original_positions / (turns * 2 * math.pi)) / (2 * math.log(theta))
+    low = max(math.floor(dim_of(yarn.beta_fast)), 0)
+    high = min(math.ceil(dim_of(yarn.beta_slow)), rd - 1)
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return base / yarn.factor * ramp + base * (1.0 - ramp)
+
+
 def apply_rope(x: Array, positions: Array, theta: float = 10000.0,
                rope_dim: Optional[int] = None,
-               interleaved: bool = False) -> Array:
+               interleaved: bool = False,
+               yarn: Optional[YarnRope] = None) -> Array:
     """Rotary position embedding on [B, S, H, D].
 
     Default: LLaMA/NeoX half-split pairing over the full head dim.
@@ -448,15 +622,24 @@ def apply_rope(x: Array, positions: Array, theta: float = 10000.0,
     ``rotary_dim``, NeoX ``rotary_pct``); ``interleaved`` uses GPT-J's
     rotate-every-two pairing ((0,1),(2,3),...).  ``positions`` is ``[S]``
     (shared across the batch) or ``[B, S]`` (per-row — the continuous-
-    batching decode path, where every slot sits at its own position)."""
+    batching decode path, where every slot sits at its own position).
+    ``yarn`` stretches the frequencies (:func:`yarn_inv_freq`) and scales
+    cos and sin by its ``mscale`` over ``mscale_all_dim``."""
     B, S, H, D = x.shape
     rd = rope_dim or D
     xr = x[..., :rd].astype(jnp.float32)
     half = rd // 2
-    freqs = (1.0 / theta) ** (jnp.arange(half, dtype=jnp.float32) / half)
+    if yarn is None:
+        freqs = (1.0 / theta) ** (jnp.arange(half, dtype=jnp.float32) / half)
+    else:
+        freqs = yarn_inv_freq(rd, theta, yarn)
     angles = positions[..., None].astype(jnp.float32) * freqs   # [(B,) S, half]
     cos = jnp.cos(angles)
     sin = jnp.sin(angles)
+    if yarn is not None:
+        m = (yarn_mscale(yarn.factor, yarn.mscale)
+             / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+        cos, sin = cos * m, sin * m
     if angles.ndim == 2:            # [S, half] -> broadcast over batch
         cos, sin = cos[None, :, None, :], sin[None, :, None, :]
     else:                           # [B, S, half] -> per-row positions
@@ -488,7 +671,11 @@ def _project_qkv(cfg: "GPTConfig", p: Dict, h: Array, dt, positions: Array,
     """The normed input ``h [B, S, E]`` -> q ``[B,S,H,D]``, k and v
     ``[B,S,Hkv,D]``: the fused projection, its bias, the q/k RMSNorm over
     all lanes (``qk_norm``), the split into heads, rope at ``positions``
-    (``[S]`` or ``[B, S]``) where the layer's ``kind`` ropes."""
+    (``[S]`` or ``[B, S]``) where the layer's ``kind`` ropes.  Latent
+    attention in its PLAIN form: every head's own key and value made from
+    the latent (v ``[B,S,H,v_head_dim]``)."""
+    if cfg.kv_lora_rank:
+        return _latent_plain_qkv(cfg, p, *_latent_project(cfg, p, h, dt, positions), dt)
     qkv = h @ _wget(p, "qkv_w", dt)
     if cfg.use_bias:
         qkv = qkv + p["qkv_b"].astype(dt)
@@ -505,6 +692,54 @@ def _project_qkv(cfg: "GPTConfig", p: Dict, h: Array, dt, positions: Array,
         k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_dim,
                        cfg.rope_interleaved)
     return q, k, v
+
+
+def _latent_project(cfg: "GPTConfig", p: Dict, h: Array, dt, positions: Array):
+    """Latent attention's projections of the normed input ``h [B, S, E]`` ->
+    (q ``[B,S,H,head_dim]``: a head's lanes ``[no position | rotated]``,
+    cache ``[B,S,kv_lora_rank + qk_rope_dim]``: ``[normed latent | the ONE
+    rotated key]``), which is all that either form of the attention reads.
+    The softmax's YaRN scale (``mscale_all_dim``: the scores times
+    :func:`yarn_mscale` squared) and the query's scale by its position are
+    IN q, so both forms divide by ``sqrt(head_dim)`` and nothing else."""
+    B, S, _ = h.shape
+    H, R, dr = cfg.n_head, cfg.kv_lora_rank, cfg.qk_rope_dim
+    yarn = cfg.rope_yarn
+    rope = lambda t: apply_rope(t, positions, cfg.rope_theta,
+                                interleaved=cfg.rope_interleaved, yarn=yarn)
+    cq = rms_norm(h @ _wget(p, "q_a_w", dt), p["q_a_norm_g"], eps=cfg.ln_eps)
+    q = (cq @ _wget(p, "q_b_w", dt)).reshape(B, S, H, cfg.head_dim)
+    q = jnp.concatenate([q[..., :-dr], rope(q[..., -dr:])], axis=-1)
+    if yarn is not None:
+        pos = positions if positions.ndim == 2 else positions[None]
+        by = yarn_mscale(yarn.factor, yarn.mscale_all_dim) ** 2 * (
+            1.0 + yarn.query_beta * jnp.log1p(
+                (pos // yarn.original_positions).astype(jnp.float32)))
+        q = (q.astype(jnp.float32) * by[:, :, None, None]).astype(dt)
+    kv = h @ _wget(p, "kv_a_w", dt)
+    c = rms_norm(kv[..., :R], p["kv_a_norm_g"], eps=cfg.ln_eps)
+    k_rope = rope(kv[..., None, R:])[:, :, 0]
+    return q, jnp.concatenate([c, k_rope], axis=-1)
+
+
+def _latent_up(cfg: "GPTConfig", p: Dict, dt):
+    """The K/V up-projection by head: ``W_UK [R, H, head_dim - qk_rope_dim]``
+    and ``W_UV [R, H, v_head_dim]``."""
+    w = _wget(p, "kv_b_w", dt).reshape(cfg.kv_lora_rank, cfg.n_head, -1)
+    return w[..., :cfg.head_dim - cfg.qk_rope_dim], w[..., -cfg.v_head_dim:]
+
+
+def _latent_plain_qkv(cfg: "GPTConfig", p: Dict, q: Array, cache: Array, dt):
+    """The plain form: each head's key ``[its own from the latent | the
+    shared rotated key]`` and its value from the latent."""
+    R = cfg.kv_lora_rank
+    w_uk, w_uv = _latent_up(cfg, p, dt)
+    c, k_rope = cache[..., :R], cache[..., None, R:]
+    k_nope = jnp.einsum("bsr,rhd->bshd", c, w_uk)
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_rope, (*k_nope.shape[:3], k_rope.shape[-1]))],
+        axis=-1)
+    return q, k, jnp.einsum("bsr,rhd->bshd", c, w_uv)
 
 
 def _wget(p: Dict, key: str, dt) -> Array:
@@ -568,14 +803,20 @@ def _ffn(cfg: "GPTConfig", p: Dict, h: Array, dt, rng=None,
             logits = routed.astype(jnp.float32) @ p["moe"]["gate"]["wg"].astype(
                 jnp.float32)
             if cfg.moe_router == "dropless":
-                probs, weights, experts = dropless.softmax_topk(
-                    logits, cfg.moe_top_k, cfg.moe_norm_topk)
+                if cfg.moe_scoring == "sigmoid":
+                    probs, weights, experts = dropless.sigmoid_topk(
+                        logits, cfg.moe_top_k, p["moe"]["gate"]["bias"],
+                        cfg.moe_norm_topk)
+                else:
+                    probs, weights, experts = dropless.softmax_topk(
+                        logits, cfg.moe_top_k, cfg.moe_norm_topk)
                 l_aux = dropless.load_balance_loss(probs, experts)
                 counts = dropless.expert_counts(experts, N, live)
         if cfg.moe_router == "dropless":
             y = dropless.dropless_moe(
                 xt, weights, experts, N,
-                lambda rows, matmul, pick: _mlp(cfg, bank, rows, dt, matmul, pick))
+                lambda rows, matmul, pick: _mlp(cfg, bank, rows, dt, matmul, pick),
+                held=cfg.moe_experts_held)
         else:
             cf = cfg.moe_capacity_factor if train else cfg.moe_eval_capacity_factor
             gating = top1gating if cfg.moe_top_k == 1 else top2gating
@@ -586,6 +827,11 @@ def _ffn(cfg: "GPTConfig", p: Dict, h: Array, dt, rng=None,
                 xt, combine, dispatch,
                 lambda q, rows: _mlp(cfg, q, rows, dt), bank)
             counts = counts.astype(jnp.int32)
+        if cfg.moe_shared_experts:
+            with jax.named_scope("moe_shared"):
+                shared = {"fc_w": p["moe"]["shared"]["wi"],
+                          "proj_w": p["moe"]["shared"]["wo"]}
+                y = y + _mlp(cfg, shared, xt, dt).astype(y.dtype)
     return y.reshape(*lead, E).astype(dt), l_aux.astype(jnp.float32), counts
 
 
@@ -699,9 +945,13 @@ def gpt_block(cfg: GPTConfig, p: Dict, x: Array, rng: Optional[Array],
             from deepspeed_tpu.ops.attention import reference_attention
             o = reference_attention(q, k, v, causal=True,
                                     bias=_window_bias(S, kind.window))
+        elif cfg.v_head_dim != D:
+            # the kernels take values as wide as keys: the einsum
+            from deepspeed_tpu.ops.attention import reference_attention
+            o = reference_attention(q, k, v, causal=True)
         else:
             o = attention_fn(q, k, v, causal=True)
-        o = o.reshape(B, S, cfg.attn_dim)
+        o = o.reshape(B, S, H * cfg.v_head_dim)
         o = o @ _wget(p, "out_w", dt)
         if cfg.use_bias:
             o = o + p["out_b"].astype(dt)
@@ -1028,6 +1278,9 @@ def gpt_apply_with_cache(cfg: GPTConfig, params: Dict, input_ids: Array,
     assert len(cfg.pattern) == 1, (
         "the dense-cache generate() path walks identical layers; a model "
         "with a layer pattern is served through init_serving()")
+    assert cfg.v_head_dim == cfg.head_dim, (
+        "the dense cache holds K and V of one width (latent attention in its "
+        "plain form, every head's own); init_serving() caches the latent")
     from deepspeed_tpu.ops.pallas.decode_attention import decode_attention
     B, S = input_ids.shape
     H, D, E = cfg.n_head, cfg.head_dim, cfg.n_embd
@@ -1168,9 +1421,13 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
     ``block_tables`` [B, MB] — logical→physical block map per row;
     ``write_blocks``/``write_offsets`` [B, S] — physical (block, offset)
     each new token's K/V lands in (invalid/padded tokens point at the trash
-    block).  A model with a ``layer_pattern`` of ``P`` kinds keeps its
-    layers in ``P`` GROUPS by position in the period: the arena is
-    ``[n_layer / P, pages, BS, Hkv*D]`` (layer ``l`` is index ``l // P`` of
+    block).  The arena is the arrays of ``cfg.cache_lanes``: under latent
+    attention ``k_pages`` is its ONE array ``[L, NB, BS, lanes]`` (a token's
+    ``[latent | rotated key]``, attended in the absorbed form: the queries
+    moved into the latent's space, the values read from the same vector)
+    and ``v_pages`` None, in and out.  A model with a ``layer_pattern`` of
+    ``P`` kinds keeps its layers in ``P`` GROUPS by position in the period:
+    the arena is ``[n_layer / P, pages, BS, Hkv*D]`` (layer ``l`` is index ``l // P`` of
     group ``l % P``; a page holds one block of every layer of ONE group),
     and ``block_tables`` and ``write_blocks`` are sequences of ``P`` arrays,
     a group each — a window group's table is a ring, logical block ``b`` in
@@ -1186,7 +1443,8 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
     refuses it.
     """
     assert cfg.scan_layers, "paged serving path requires scan_layers"
-    from deepspeed_tpu.ops.pallas.decode_attention import paged_layer_attention
+    from deepspeed_tpu.ops.pallas.decode_attention import (
+        paged_layer_attention, paged_mla_attention)
     B, S = input_ids.shape
     H, E = cfg.n_head, cfg.n_embd
     n_kinds = len(cfg.pattern)
@@ -1222,19 +1480,41 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
         kind, wblocks = cfg.pattern[j], write_blocks[j]
         with jax.named_scope("attn"):
             h = _norm(cfg, x, p["ln1_g"], p["ln1_b"])
-            q, k, v = _project_qkv(cfg, p, h, dt, pos2d, kind)
-            # scatter the new K/V into the arena through the write map; rows
-            # that must not write (padding, inactive slots) carry trash-block
-            # coordinates, so the scatter itself needs no predication
-            kp = kp.at[li, wblocks, write_offsets].set(
-                k.astype(kp.dtype).reshape(B, S, -1))
-            vp = vp.at[li, wblocks, write_offsets].set(
-                v.astype(vp.dtype).reshape(B, S, -1))
-            with jax.named_scope(
-                    "attn_full" if kind.window is None else "attn_window"):
-                o = paged_layer_attention(
-                    q, kp, vp, li, block_tables[j], positions, bias=attn_bias,
-                    window=kind.window).reshape(B, S, cfg.attn_dim)
+            if cfg.kv_lora_rank:
+                R, W = cfg.kv_lora_rank, kp.shape[-1]
+                q, cache = _latent_project(cfg, p, h, dt, pos2d)
+                w_uk, w_uv = _latent_up(cfg, p, dt)
+                kp = kp.at[li, wblocks, write_offsets].set(jnp.pad(
+                    cache.astype(kp.dtype),
+                    ((0, 0), (0, 0), (0, W - cache.shape[-1]))))
+                # a head's query in the cached vector's lanes: its
+                # no-position part through W_UK, its rotated part as it is
+                dr = cfg.qk_rope_dim
+                q = jnp.concatenate(
+                    [jnp.einsum("bshd,rhd->bshr", q[..., :-dr], w_uk),
+                     q[..., -dr:]], axis=-1)
+                q = jnp.pad(q, ((0, 0),) * 3 + ((0, W - q.shape[-1]),))
+                with jax.named_scope("attn_latent"):
+                    o = paged_mla_attention(
+                        q, kp, li, block_tables[j], positions,
+                        scale=1.0 / math.sqrt(cfg.head_dim), value_lanes=R)
+                o = jnp.einsum("bshr,rhd->bshd", o, w_uv).reshape(B, S, -1)
+            else:
+                q, k, v = _project_qkv(cfg, p, h, dt, pos2d, kind)
+                # scatter the new K/V into the arena through the write map;
+                # rows that must not write (padding, inactive slots) carry
+                # trash-block coordinates, so the scatter itself needs no
+                # predication
+                kp = kp.at[li, wblocks, write_offsets].set(
+                    k.astype(kp.dtype).reshape(B, S, -1))
+                vp = vp.at[li, wblocks, write_offsets].set(
+                    v.astype(vp.dtype).reshape(B, S, -1))
+                with jax.named_scope(
+                        "attn_full" if kind.window is None else "attn_window"):
+                    o = paged_layer_attention(
+                        q, kp, vp, li, block_tables[j], positions,
+                        bias=attn_bias, window=kind.window
+                    ).reshape(B, S, cfg.attn_dim)
             o = o @ _wget(p, "out_w", dt)
             if cfg.use_bias:
                 o = o + p["out_b"].astype(dt)
@@ -1436,12 +1716,21 @@ class GPT:
         fc_out = 2 * I if cfg.mlp_type == "swiglu" else I
         mlp = E * fc_out + I * E + b * (fc_out + E)     # up (gate|up), down
         if cfg.moe_num_experts:
-            mlp = E * cfg.moe_num_experts + mlp * (
-                cfg.moe_top_k if active else cfg.moe_num_experts)
+            # the router (and its bias), the experts HELD (or a token's),
+            # the shared expert
+            mlp = (cfg.moe_num_experts * (E + int(cfg.moe_scoring == "sigmoid"))
+                   + mlp * (cfg.moe_top_k if active else cfg.bank_experts[1])
+                   + mlp * cfg.moe_shared_experts)
         qk_norm = (cfg.n_head + cfg.kv_heads) * cfg.head_dim * int(cfg.qk_norm)
         norm = (2 if cfg.norm == "layernorm" else 1) * E   # gain (and shift)
-        per_block = (E * cfg.qkv_dim + b * cfg.qkv_dim   # qkv (GQA-sized)
-                     + cfg.attn_dim * E + b * E          # attn out
+        if cfg.kv_lora_rank:        # two low-rank chains and their norms
+            Rq, R, H = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.n_head
+            qkv = (E * Rq + Rq + Rq * H * cfg.head_dim
+                   + E * (R + cfg.qk_rope_dim) + R
+                   + R * H * (cfg.head_dim - cfg.qk_rope_dim + cfg.v_head_dim))
+        else:
+            qkv = E * cfg.qkv_dim + b * cfg.qkv_dim      # qkv (GQA-sized)
+        per_block = (qkv + cfg.n_head * cfg.v_head_dim * E + b * E  # attn out
                      + mlp + qk_norm + 2 * norm)
         total = cfg.padded_vocab * E + L * per_block + norm
         if cfg.position_encoding == "learned":

@@ -11,7 +11,8 @@ one TPU chip, ``jax.lax.ragged_dot`` elsewhere), so the shapes are static and
 nothing depends on how even the routing is.
 
 The four stages open the scopes ``moe_router``, ``moe_dispatch``,
-``moe_experts`` and ``moe_combine`` (the caller opens ``moe`` round them):
+``moe_experts`` and ``moe_combine`` (the caller opens ``moe`` round them, and
+``moe_shared`` inside it round an expert every token goes through):
 per-layer metrics read a trace by these names.
 """
 
@@ -44,6 +45,24 @@ def softmax_topk(logits: Array, k: int, renormalise: bool = False
     return probs, weights, experts.astype(jnp.int32)
 
 
+def sigmoid_topk(logits: Array, k: int, bias: Optional[Array] = None,
+                 renormalise: bool = True) -> Tuple[Array, Array, Array]:
+    """As :func:`softmax_topk` for the router of the DeepSeek-V3 line: an
+    expert's score is the SIGMOID of its own logit, in float32; the ``k``
+    largest of ``score + bias`` are chosen (the score-correction bias
+    chooses and never weighs) and weighed by their scores, with
+    ``renormalise`` divided by their sum (``norm_topk_prob``).  The first
+    result is the scores over their sum, for the load-balance loss."""
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    chosen = scores if bias is None else scores + bias.astype(jnp.float32)
+    experts = jax.lax.top_k(chosen, k)[1]
+    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    if renormalise:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    return (scores / jnp.sum(scores, axis=-1, keepdims=True), weights,
+            experts.astype(jnp.int32))
+
+
 def load_balance_loss(probs: Array, experts: Array) -> Array:
     """Switch/GShard's ``E * sum_e(mean prob_e * share of assignments_e)``
     over all ``k`` choices: 1.0 when routing is even."""
@@ -63,9 +82,17 @@ def expert_counts(experts: Array, num_experts: int,
 
 
 def dropless_moe(x: Array, weights: Array, experts: Array, num_experts: int,
-                 expert_fn: Callable) -> Array:
+                 expert_fn: Callable,
+                 held: Optional[Tuple[int, int]] = None) -> Array:
     """``x [T, M]`` through its ``k`` experts each, weighted and summed, in
     float32.
+
+    ``held = (first, count)``: the bank holds the experts ``first .. first +
+    count - 1`` alone (its stacked leaves are ``count`` long).  An
+    assignment to an expert that is not here sorts behind the last group and
+    lies in none, as the rows added for whole tiles do, so the bank computes
+    nothing for it, and it adds nothing to the sum: the result is this
+    bank's PART of the layer.
 
     ``expert_fn(rows, matmul, pick)`` is the expert's own arithmetic on the
     sorted rows ``[T*k, M]``: ``matmul(rows, w)`` multiplies each row by ITS
@@ -76,6 +103,10 @@ def dropless_moe(x: Array, weights: Array, experts: Array, num_experts: int,
     # row tiles hold them (0 where they already do, or no kernel runs): they
     # lie in no group, so the bank computes nothing for them, and are cut off
     pad = rows_to_whole_tiles(T * k, x.shape[1], x.dtype)
+    if held is not None:
+        first, num_experts = held
+        here = (experts >= first) & (experts < first + num_experts)
+        experts = jnp.where(here, experts - first, num_experts)
     with jax.named_scope("moe_dispatch"):
         flat = experts.reshape(-1)
         order = jnp.argsort(flat, stable=True)        # assignments by expert
@@ -92,4 +123,7 @@ def dropless_moe(x: Array, weights: Array, experts: Array, num_experts: int,
         # back to token order by a gather (no scatter-add), then the
         # weighted sum over the k choices
         y = y[jnp.argsort(order)].reshape(T, k, -1)
+        if held is not None:
+            # a row in no group is whatever the kernel's output buffer held
+            y = jnp.where(here[..., None], y, 0)
         return jnp.einsum("tkm,tk->tm", y.astype(jnp.float32), weights)

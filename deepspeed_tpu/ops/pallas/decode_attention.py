@@ -672,6 +672,200 @@ def paged_gqa_attention(q, k_arena, v_arena, layer, block_tables, lengths,
                                      window=window)
 
 
+# --------------------------------------------------------------------------- #
+# Paged LATENT attention (MLA in its absorbed form): every head reads ONE
+# cached vector a token, all its lanes as the key and its first lanes as the
+# value.  A sibling of ``_paged_gqa_kernel``, not a case of it: that one walks
+# K/V heads over two arenas; here there is one arena, one staged tile and one
+# product for all heads.
+# --------------------------------------------------------------------------- #
+# keys a tile: a key is 768 B here where SmallThinker's is 2,048, and the 32
+# heads are the rows of one small product, so a tile of 128 would be mostly
+# its own fixed cost
+_MLA_TILE_ROWS = 256
+
+
+def mla_kernel_shape_ok(lanes: int, value_lanes: int, block: int, dtype) -> bool:
+    """What :func:`_paged_mla_kernel` takes: the cached vector and its value
+    part whole 128-lane tiles, a page a multiple of the sublane tile."""
+    sublane = 8 * 4 // np.dtype(dtype).itemsize
+    return (lanes % _LANES == 0 and value_lanes % _LANES == 0
+            and 0 < value_lanes <= lanes and block % sublane == 0)
+
+
+def paged_mla_tile_pages(lanes, value_lanes, BS, MB, dtype) -> int:
+    """Pages a tile of the kernel ``paged_mla_attention`` holds for these
+    shapes; 0 where :func:`paged_mla_attention` takes the gather reference
+    (not a TPU, a mesh of several devices, lanes the gate refuses)."""
+    if (not _pallas.use_kernel("paged_mla_attention")
+            or not mla_kernel_shape_ok(lanes, value_lanes, BS, dtype)
+            or not _pallas.single_device()):
+        return 0
+    return min(max(1, _MLA_TILE_ROWS // BS), MB)
+
+
+def paged_mla_attention_reference(q, pages, block_tables, lengths, *, scale,
+                                  value_lanes):
+    """jnp latent attention over ONE layer's pages ``[NB, BS, W]``: q
+    ``[B, Sq, H, W]`` (query ``i`` of row ``b`` at position ``lengths[b] +
+    i``) against every cached vector up to its own position, all ``W`` lanes
+    the key, the first ``value_lanes`` the value -> ``[B, Sq, H,
+    value_lanes]``.  The parity reference, and the path wherever the
+    selection rule says no."""
+    B, Sq = q.shape[:2]
+    c = pages[block_tables].reshape(B, -1, pages.shape[-1])         # [B, T, W]
+    s = jnp.einsum("bqhw,bkw->bhqk", q.astype(jnp.float32),
+                   c.astype(jnp.float32)) * scale
+    T = c.shape[1]
+    seen = (jax.lax.broadcasted_iota(jnp.int32, (Sq, T), 1)[None]
+            <= jnp.asarray(lengths, jnp.int32)[:, None, None]
+            + jax.lax.broadcasted_iota(jnp.int32, (Sq, T), 0)[None])
+    p = jax.nn.softmax(jnp.where(seen[:, None], s, NEG_INF), axis=-1)
+    return jnp.einsum("bhqk,bkv->bqhv", p.astype(q.dtype), c[..., :value_lanes])
+
+
+def _paged_mla_kernel(lay_ref, len_ref, tbl_ref, nxt_ref, q_ref, c_hbm, o_ref,
+                      c_buf, sem, slot_ref, *, scale, bs, Sq, R, MB, G):
+    """Grid (B,), a row a step, as ``_paged_gqa_kernel`` without a window
+    (the arena whole and the layer a scalar, the row's table and the next
+    row's as SMEM blocks, tiles of ``G`` pages in two buffers, the next tile
+    fetched while this one is attended; ``_paged_kernel``'s docstring holds
+    the semaphore and zero-fill invariants, which are kept).  What differs:
+    ONE arena ``[layers, pages, bs, W]`` and one staged tile, which is the
+    key with all ``W`` lanes and the value with its first ``R``; all heads
+    are the rows of one ``[M, W] x [W, keys]`` product (``q_ref`` is ``[1, M,
+    W]``, row ``i * Sq + s`` head ``i`` at query ``s``)."""
+    b = pl.program_id(0)
+    layer = lay_ref[0]
+    seq_len = len_ref[b]
+    rows_t = G * bs
+    M = q_ref.shape[1]
+
+    def pages_of(row):                            # live (DMA'd) pages
+        return jnp.minimum((len_ref[row] + Sq + bs - 1) // bs, MB)
+
+    nk = pages_of(b)
+    nt = (nk + G - 1) // G                        # live tiles, >= 1
+
+    def tile_copies(row, t, slot, do):
+        def page(j, c):
+            col = t * G + j
+            phys = jnp.where(row == b, tbl_ref[0, col], nxt_ref[0, col])
+            dst = pl.ds(pl.multiple_of(j * bs, bs), bs)
+            do(pltpu.make_async_copy(c_hbm.at[layer, phys],
+                                     c_buf.at[slot, dst], sem.at[slot]))
+            return c
+
+        jax.lax.fori_loop(0, jnp.clip(pages_of(row) - t * G, 0, G), page, 0)
+
+    start = lambda cp: cp.start()
+
+    @pl.when(b == 0)
+    def _():
+        c_buf[...] = jnp.zeros_like(c_buf)
+        slot_ref[0] = 0
+        tile_copies(0, 0, 0, start)
+
+    slot0 = slot_ref[0]
+    q = q_ref[0]                                  # [M, W]
+
+    def tile(t, carry):
+        slot = (slot0 + t) % 2
+        last = t + 1 == nt
+
+        @pl.when(jnp.logical_not(last) | (b + 1 < pl.num_programs(0)))
+        def _():
+            tile_copies(jnp.where(last, b + 1, b), jnp.where(last, 0, t + 1),
+                        1 - slot, start)
+
+        tile_copies(b, t, slot, lambda cp: cp.wait())
+        qpos = seq_len + jax.lax.broadcasted_iota(jnp.int32, (M, rows_t), 0) % Sq
+        cols = t * rows_t + jax.lax.broadcasted_iota(jnp.int32, (M, rows_t), 1)
+        valid = (cols <= qpos) & (cols < nk * bs)
+        m, l, acc = carry
+        c = c_buf[slot]                                       # [rows_t, W]
+        s = jax.lax.dot_general(q, c, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        s = jnp.where(valid, s, NEG_INF)                      # [M, rows_t]
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc = acc * alpha + jax.lax.dot_general(
+            p.astype(c.dtype), c_buf[slot, :, :R], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    carry = (jnp.full((M, 1), NEG_INF, jnp.float32),
+             jnp.zeros((M, 1), jnp.float32), jnp.zeros((M, R), jnp.float32))
+    _, l, acc = jax.lax.fori_loop(0, nt, tile, carry)
+    slot_ref[0] = (slot0 + nt) % 2
+    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+
+def _paged_mla_call(q, arena, layer, block_tables, lengths, scale, R, G):
+    B, Sq, H, W = q.shape
+    BS = arena.shape[2]
+    MB = block_tables.shape[1]
+    block_tables = jnp.asarray(block_tables, jnp.int32)[:, None, :]
+    sublane = 8 * 4 // np.dtype(q.dtype).itemsize
+    M = -(-H * Sq // sublane) * sublane
+    # [B, Sq, H, W] -> [B, H*Sq, W]: a head's queries together
+    qm = jnp.pad(q.transpose(0, 2, 1, 3).reshape(B, H * Sq, W),
+                 ((0, 0), (0, M - H * Sq), (0, 0)))
+    row = lambda b, *_: (b, 0, 0)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,                    # layer, lengths
+        grid=(B,),
+        in_specs=[
+            pl.BlockSpec((None, 1, MB), row, memory_space=pltpu.MemorySpace.SMEM),
+            pl.BlockSpec((None, 1, MB),
+                         lambda b, *_: (jnp.minimum(b + 1, B - 1), 0, 0),
+                         memory_space=pltpu.MemorySpace.SMEM),
+            pl.BlockSpec((1, M, W), row),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, M, R), row),
+        scratch_shapes=[
+            pltpu.VMEM((2, G * BS, W), arena.dtype),
+            pltpu.SemaphoreType.DMA((2,)),        # a tile buffer each
+            pltpu.SMEM((1,), jnp.int32),          # buffer of the row's tile 0
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_paged_mla_kernel, scale=scale, bs=BS, Sq=Sq, R=R,
+                          MB=MB, G=G),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, M, R), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=_pallas.interpret(),
+        name="paged_mla_attention",
+    )(jnp.asarray(layer, jnp.int32).reshape(1), jnp.asarray(lengths, jnp.int32),
+      block_tables, block_tables, qm, arena)
+    return out[:, :H * Sq].reshape(B, H, Sq, R).transpose(0, 2, 1, 3)
+
+
+def paged_mla_attention(q, arena, layer, block_tables, lengths, *, scale,
+                        value_lanes):
+    """Block-table latent attention of layer ``layer`` of the ONE-array arena
+    ``[layers, pages, BS, W]``: q ``[B, Sq, H, W]`` (a head's query moved
+    into the cached vector's space, zeros where the vector is padding)
+    against each cached vector up to its position, times ``scale``; the
+    vector's first ``value_lanes`` lanes are the value -> ``[B, Sq, H,
+    value_lanes]``.  The kernel where :func:`paged_mla_tile_pages` says so,
+    else the layer sliced out and the gather reference."""
+    _, _, BS, W = arena.shape
+    G = paged_mla_tile_pages(W, value_lanes, BS, block_tables.shape[1],
+                             arena.dtype)
+    if G:
+        return _paged_mla_call(q, arena, layer, block_tables, lengths, scale,
+                               value_lanes, G)
+    pages = jax.lax.dynamic_index_in_dim(arena, layer, 0, keepdims=False)
+    return paged_mla_attention_reference(q, pages, block_tables, lengths,
+                                         scale=scale, value_lanes=value_lanes)
+
+
 def paged_layer_attention(q, k_arena, v_arena, layer, block_tables, lengths,
                           bias=None, window=None):
     """What ``gpt_paged_step`` calls a layer.  Grouped K/V heads or a window

@@ -9,8 +9,10 @@ Sizing guidance (README § Serving): ``block_size`` trades internal
 fragmentation (last-block waste, avg block_size/2 tokens per sequence)
 against block-table length and scatter/gather granularity — 16 suits toy
 and CPU runs, 32–64 suits real HBM arenas.  ``num_blocks`` bounds the
-arena: total KV bytes = 2 * n_layer * num_blocks * block_size * kv_heads *
-head_dim * dtype_bytes.
+arena: total cache bytes = n_layer * num_blocks * block_size * dtype_bytes *
+the lanes of the model's cache spec (``GPTConfig.cache_lanes``: ``2 * kv_heads
+* head_dim`` for K and V, 384 for a latent cache of 256 + 64;
+``serving/kv_cache.py:arena_bytes``).
 """
 
 from typing import Dict, Optional

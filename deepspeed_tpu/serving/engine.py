@@ -353,6 +353,11 @@ class ServingEngine:
         # window: ``num_blocks`` blocks of ALL layers are ``num_blocks *
         # groups`` pages of one group's layers each, one pool
         self._windows = tuple(kind.window for kind in mcfg.pattern)
+        if len(mcfg.cache_lanes) != 2 and (cfg.kv_tiering or cfg.prefix_cache):
+            raise ValueError(
+                "init_serving: kv_tiering and prefix_cache spill and share "
+                "blocks of a K and a V array; this model's cache spec is "
+                f"{mcfg.cache_lanes} (a latent cache)")
         if len(self._windows) > 1 and (cfg.kv_tiering or cfg.prefix_cache):
             raise ValueError(
                 "init_serving: kv_tiering and prefix_cache share and spill "
@@ -389,11 +394,20 @@ class ServingEngine:
         # paged kernel's pages per tile, 0 on the einsum path; a stat of
         # every step and of the ``serve.stats`` span
         from deepspeed_tpu.ops.pallas.decode_attention import (
-            paged_layer_tile_pages)
-        self.paged_tile_pages = paged_layer_tile_pages(
-            1, mcfg.n_head, mcfg.kv_heads, mcfg.head_dim, cfg.block_size,
-            self.max_blocks_per_seq, self.dtype,
-            bias=mcfg.position_encoding == "alibi", window=self._windows[0])
+            paged_layer_tile_pages, paged_mla_tile_pages)
+        if mcfg.kv_lora_rank:
+            self.paged_tile_pages = paged_mla_tile_pages(
+                mcfg.cache_lanes[0], mcfg.kv_lora_rank, cfg.block_size,
+                self.max_blocks_per_seq, self.dtype)
+        else:
+            self.paged_tile_pages = paged_layer_tile_pages(
+                1, mcfg.n_head, mcfg.kv_heads, mcfg.head_dim, cfg.block_size,
+                self.max_blocks_per_seq, self.dtype,
+                bias=mcfg.position_encoding == "alibi", window=self._windows[0])
+        # bytes the arena holds a token a layer (every array of the cache
+        # spec): a stat of the ``serve.stats`` span
+        self.cache_bytes_per_token = (sum(mcfg.cache_lanes)
+                                      * np.dtype(self.dtype).itemsize)
 
         # ---- the (single) jitted step ------------------------------------ #
         layout = self._layout
@@ -831,19 +845,25 @@ class ServingEngine:
             self._recover_incident(err)
             raise
         with self._span("serve.stats", paged_tile_pages=self.paged_tile_pages,
+                        cache_bytes_per_token=self.cache_bytes_per_token,
                         **table_stats):
             stats = self._close_step(len(decode), n_chunk, int(runs), t_step)
             stats.update(moe_stats, **table_stats)
             return stats
 
     def _moe_stats(self) -> Dict[str, float]:
-        """How the last program's live rows spread over the experts (summed
-        over layers); nothing for a dense model."""
+        """How the last program's live rows spread over the experts the
+        router chooses among (summed over layers); nothing for a dense
+        model."""
         counts = self._expert_counts
         if not counts.size or not counts.any():
             return {}
+        first, held = self.module.cfg.bank_experts
         return {"moe_load_max_over_mean": float(counts.max() / counts.mean()),
-                "moe_experts_touched": int((counts > 0).sum())}
+                "moe_experts_touched": int((counts > 0).sum()),
+                # of the live rows' assignments, those on experts held here
+                "moe_assignments": int(counts.sum()),
+                "moe_assignments_held": int(counts[first:first + held].sum())}
 
     def _close_step(self, decode_batch: int, prefill_tokens: int,
                     programs: int, t_step: float) -> Dict[str, Any]:

@@ -3,9 +3,10 @@
 The serving-side analogue of ZeRO-Infinity's memory virtualization (arxiv
 2104.07857): a sequence's LOGICAL KV memory is decoupled from PHYSICAL HBM
 placement, so arena capacity — not batch shape — is the binding constraint.
-The device arena is ``[n_layer, num_blocks, block_size, kv_heads * head_dim]``
-per K and V (heads folded into the lane dimension, the layout the paged
-kernel can DMA); this module owns the host-side bookkeeping:
+The device arena is ``[n_layer, num_blocks, block_size, lanes]`` for each array
+of the model's cache spec (``cfg.cache_lanes``: K and V with the heads folded
+into the lane dimension, the layout the paged kernel can DMA; ONE array under
+latent attention); this module owns the host-side bookkeeping:
 
 * a free list of physical block ids (block 0 is reserved as the TRASH
   block: padded/inactive tokens scatter their K/V there, so the compiled
@@ -420,19 +421,22 @@ class PagedKVAllocator:
 
 
 def init_arena(cfg, num_blocks: int, block_size: int, dtype=None):
-    """Device arena pair for ``models/gpt.py:gpt_paged_step``: K/V
-    ``[n_layer / P, num_blocks * P, block_size, kv_heads * head_dim]`` for a
-    layer pattern of ``P`` kinds (``num_blocks`` counts blocks of ALL
-    layers; a page holds a block of the ``n_layer / P`` layers of one
-    group).  ``P = 1``: ``[n_layer, num_blocks, ...]``."""
+    """Device arena for ``models/gpt.py:gpt_paged_step``, an array for each
+    entry of the model's cache spec (``cfg.cache_lanes``: lanes a token a
+    layer): ``[n_layer / P, num_blocks * P, block_size, lanes]`` for a layer
+    pattern of ``P`` kinds (``num_blocks`` counts blocks of ALL layers; a
+    page holds a block of the ``n_layer / P`` layers of one group).  ``P =
+    1``: ``[n_layer, num_blocks, ...]``.  Always a PAIR, as the step takes
+    it: K and V, or a latent cache's one array and None."""
     import jax.numpy as jnp
     dtype = dtype or cfg.dtype
     P = len(cfg.pattern)
-    shape = (cfg.n_layer // P, num_blocks * P, block_size,
-             cfg.kv_heads * cfg.head_dim)
-    return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+    arrays = [jnp.zeros((cfg.n_layer // P, num_blocks * P, block_size, lanes),
+                        dtype) for lanes in cfg.cache_lanes]
+    return tuple(arrays + [None] * (2 - len(arrays)))
 
 
 def arena_bytes(cfg, num_blocks: int, block_size: int, dtype_bytes: int = 2) -> int:
-    return (2 * cfg.n_layer * num_blocks * block_size * cfg.kv_heads
-            * cfg.head_dim * dtype_bytes)
+    """Bytes :func:`init_arena` holds: every array of the cache spec."""
+    return (cfg.n_layer * num_blocks * block_size * sum(cfg.cache_lanes)
+            * dtype_bytes)

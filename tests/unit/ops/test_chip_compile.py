@@ -282,3 +282,35 @@ def test_generate_keeps_its_cache_zero_filled(chip):
     assert cache in text
     assert not [line for line in text.splitlines()
                 if "AllocateBuffer" in line and f"= {cache}" in line]
+
+
+def test_paged_mla_kernel_compiles_at_mistral4_heads(chip):
+    """Mistral-Small-4's latent attention as its serve cell runs it: 32
+    heads the rows of ONE product against the cached vector (256 latent + 64
+    rope lanes, 384 in the arena), pages of 16, ``Sq = 1``, 128 slots + a
+    chunk of 384 = 512 rows, the one-array arena of 50,000 blocks WHOLE with
+    the layer a scalar, tables 1,024 blocks wide as SMEM blocks."""
+    H, W, R, BS, rows, MB = 32, 384, 256, 16, 512, 1024
+    assert da.mla_kernel_shape_ok(W, R, BS, BF16)
+    assert not da.mla_kernel_shape_ok(320, R, BS, BF16)       # the cache unpadded
+    fn = lambda q, arena, layer, tables, lengths: da.paged_mla_attention(
+        q, arena, layer, tables, lengths, scale=128 ** -0.5, value_lanes=R)
+    text = _compiled_text(chip, fn, ((rows, 1, H, W), BF16),
+                          ((5, 50000, BS, W), BF16), ((), jnp.int32),
+                          ((rows, MB), jnp.int32), ((rows,), jnp.int32))
+    assert "tpu_custom_call" in text and "paged_mla_attention" in text
+    assert "dynamic-slice" not in text        # no layer of the arena sliced out
+    assert da.paged_mla_tile_pages(W, R, BS, MB, BF16) == 16
+
+
+@pytest.mark.parametrize("K,N", [(4096, 4096), (2048, 4096)])
+def test_grouped_matmul_compiles_at_mistral4_held_bank(chip, K, N):
+    """The 32 held experts' bank (gate|up ``[32, 4096, 4096]``, down ``[32,
+    2048, 4096]``) at the serve cell's 512 rows x top 4 = 2,048 assignments,
+    sixteen whole row tiles, most of them in no group."""
+    from deepspeed_tpu.ops.pallas import grouped_matmul as gm
+    assert gm.kernel_shape_ok(2048, K, N, BF16)
+    assert gm.rows_to_whole_tiles(2048, K, BF16) == 0
+    text = _compiled_text(chip, gm.grouped_matmul, ((2048, K), BF16),
+                          ((32, K, N), BF16), ((32,), jnp.int32))
+    assert "tpu_custom_call" in text and "grouped_matmul" in text

@@ -241,12 +241,14 @@ def test_spans_carry_counts_where_the_work_happens(tiny_model):
     assert by["serve.prefill.build"] == [None]
     assert not any(n in by for n in ("serve.prefill.dispatch", "serve.prefill.fetch",
                                      "serve.prefill.commit"))
-    # which paged kernel the engine runs (0: the einsum, as on this CPU), and
+    # which paged kernel the engine runs (0: the einsum, as on this CPU), the
+    # bytes the arena holds a token a layer (the cache spec's arrays), and
     # what the step's tables cost: the one upload, no entry changed (the
     # request was handed its two blocks at admission), nothing reloaded
     table = {"table_edits": 0, "table_reloads": 0,
              "upload_bytes": 4 * eng._layout.packed_size}
-    assert by["serve.stats"] == [dict(table, paged_tile_pages=eng.paged_tile_pages)]
+    assert by["serve.stats"] == [dict(table, paged_tile_pages=eng.paged_tile_pages,
+                                      cache_bytes_per_token=eng.cache_bytes_per_token)]
     assert {k: stats[k] for k in table} == table
     assert (stats["programs"], stats["prefill_tokens"], stats["decode_batch"]) == (1, 0, 1)
     fut.result()

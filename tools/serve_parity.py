@@ -2,12 +2,13 @@
 reference, at the published widths: the chip comparison of the
 ``model-configs`` guide § 3 point 3, for any configuration file that names a
 ``serve`` block and a ``reference`` (``benchmarks/configs/olmoe-1b-7b.json``,
-``smallthinker-21b-a3b.json``).
+``smallthinker-21b-a3b.json``, ``mistral-small-4-119b.json``).
 
 Run standalone on a TPU host (``chiprun --chips 1 -- python
 tools/serve_parity.py benchmarks/configs/smallthinker-21b-a3b.json``); any
 other platform is an error (exit 1).  Seeded bf16 weights; a seeded sample of
-prompts (one of them longer than the model's window, where it has one) goes
+prompts (one of them longer than the model's window, where it has one, or
+than the original positions of its YaRN rope) goes
 through ``init_serving()`` / ``submit().result()`` with the file's slots and
 chunk (prefill in chunks, then decode, on the program's kernels), and each
 served sequence through the file's reference in one full float32 forward pass:
@@ -26,7 +27,8 @@ Prints one JSON line and exits 0 when every gap is inside ``--margin``
 (a long context: SmallThinker) goes through the comparison that decides its
 cell's ``correct`` instead, all served sequences as one run's sample
 (``benchmarks/kinds/serve_backlog_resident.py:check_sample``: the gross limit
-on every token's gap and the limit on the median noise scale), and exits 0
+on every token's gap and the limit on the median noise scale; under the
+limits of the cell's own kind where that has a ``judge``), and exits 0
 when that counts nothing wrong.  ``--bank float8_e4m3fn`` serves with the
 expert bank rounded through that type: the reading a limit must REFUSE, exit 1.
 """
@@ -72,6 +74,17 @@ def router_sets_that_differ(cfg, params, seq, choices_fn):
     return int((np.stack(recorded) != want).any(axis=-1).sum()), int(want[..., 0].size)
 
 
+def cell_kind(config_path):
+    """The traffic kind's module of the benchmark cell that runs this
+    configuration file (the first, where several do); None where none does."""
+    from benchmarks.lib import cells
+    bench = cells.load_benchmark()
+    names = {c["name"] for c in bench["configs"]
+             if os.path.basename(c["file"]) == os.path.basename(config_path)}
+    cell = next((w["name"] for w in bench["workloads"] if w["config"] in names), None)
+    return cells.Cell(cell).kind if cell else None
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("config", help="a file of benchmarks/configs/")
@@ -99,15 +112,22 @@ def main(argv=None) -> int:
     model = model_from(config)
     cfg, ref = model.cfg, config["reference"]
     dtype = jnp.dtype(config["dtype"])
-    params = jax.jit(lambda key: jax.tree.map(lambda p: p.astype(dtype),
-                                              model.init_params(key)))(
-        jax.random.PRNGKey(27))
-    served_params = params
+    make_params = jax.jit(lambda key: jax.tree.map(lambda p: p.astype(dtype),
+                                                   model.init_params(key)))
+    params = served_params = make_params(jax.random.PRNGKey(27))
     if args.bank:
-        low = lambda w: w.astype(jnp.dtype(args.bank)).astype(w.dtype)
-        served_params = dict(params, blocks=dict(params["blocks"], moe=dict(
-            params["blocks"]["moe"],
-            experts=jax.tree.map(low, params["blocks"]["moe"]["experts"]))))
+        # rounded in place (the tree donated): a bank and its rounded copy do
+        # not both fit beside an arena; the reference's weights are made
+        # again from the seed once the engine is gone
+        # the barrier keeps the rounding: XLA takes a convert down and up
+        # again inside one program for excess precision it may leave out
+        low = lambda w: jax.lax.optimization_barrier(
+            w.astype(jnp.dtype(args.bank))).astype(w.dtype)
+        served_params = jax.jit(lambda p: dict(p, blocks=dict(p["blocks"], moe=dict(
+            p["blocks"]["moe"],
+            experts=jax.tree.map(low, p["blocks"]["moe"]["experts"])))),
+            donate_argnums=0)(params)
+        params = None
     eng = deepspeed_tpu.init_serving(model=model, params=served_params, config={
         "serving": dict(config["serve"]["serving"], num_blocks=PARITY_BLOCKS)})
     rng = np.random.default_rng(27)
@@ -115,13 +135,17 @@ def main(argv=None) -> int:
     window = max((k.window or 0) for k in cfg.pattern)
     if window:
         lengths.append(window + 200)            # prefill AND decode past the window
+    if cfg.rope_yarn is not None:               # and past the stretched rope's
+        lengths.append(cfg.rope_yarn.original_positions + 100)      # original range
     prompts = [rng.integers(0, cfg.vocab_size, n).tolist() for n in lengths]
     futures = [eng.submit(p, max_new_tokens=args.new) for p in prompts]
     served = [f.result() for f in futures]
     tile_pages = eng.paged_tile_pages
     eng.close()
-    del eng, futures, served_params      # the arena, and a rounded bank's copy
+    del eng, futures, served_params      # the arena, and a rounded bank
     gc.collect()
+    if params is None:
+        params = make_params(jax.random.PRNGKey(27))
 
     out = {"config": os.path.basename(args.config), "layers": cfg.n_layer,
            "device": jax.devices()[0].device_kind, "paged_tile_pages": tile_pages,
@@ -130,6 +154,10 @@ def main(argv=None) -> int:
     check = None
     if "hidden" in ref:          # a long context: the comparison of its cell's kind
         check = check_sample(model, params, ref, list(zip(prompts, served)))
+        judge = getattr(cell_kind(args.config), "judge", None)
+        if judge is not None:    # the resident kind's check under the cell's own limits
+            check["wrong"] = judge(check["largest"], check["noise_scale"],
+                                   check["noise_scale_median"])
         out.update(wrong=check["wrong"], noise_scale_median=check["noise_scale_median"])
         found = iter(zip(check["largest"], check["mean"], check["noise_scale"]))
 
