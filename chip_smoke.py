@@ -327,8 +327,9 @@ def serve_phase(seed):
                for f in futures]
     check(engine.compiled_programs() == 1, "serve: a request recompiled")
 
-    # which attention ran, from the program's compiled text: every decode
-    # slot and every token of the prompt chunk is a row of one query
+    # which attention ran, from the program's compiled text: a layer holds
+    # the kernel twice, the decode slots a query a row and the prompt chunk
+    # packed, several queries a row
     kernels = kernels_in(engine._step_fn.lower(
         engine.params, jnp.zeros((engine._layout.packed_size,), jnp.int32),
         engine._k_pages, engine._v_pages, engine._tables).compile())

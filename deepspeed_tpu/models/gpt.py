@@ -1408,13 +1408,22 @@ def gpt_generate(cfg: GPTConfig, params: Dict, input_ids: Array,
 def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
                    positions: Array, k_pages: Array, v_pages: Array,
                    block_tables: Array, write_blocks: Array,
-                   write_offsets: Array, with_expert_counts: bool = False):
+                   write_offsets: Array, with_expert_counts: bool = False,
+                   chunk: int = 0):
     """One fused step over the paged arena.
 
     ``input_ids`` [B, S] — a row holds S consecutive tokens of one sequence;
-    the serving engine runs S = 1, a decode slot or ONE token of the step's
-    prompt chunk a row (all new K/V is scattered before a layer attends, so
-    a chunk's row sees the chunk's earlier tokens through its block table);
+    the serving engine runs S = 1, a decode slot or one token of the step's
+    prompt chunk a row, and names the chunk: the last ``chunk`` rows are
+    consecutive tokens of ONE sequence (or carry nothing).  Everything but
+    attention takes them as ``B`` rows like any others; a layer's attention
+    takes them PACKED, ``chunk / Sq`` rows of ``Sq`` queries under the table
+    and the position of the first, beside the other rows at one query each:
+    two calls of the same kernel, and each key of the chunk's context read
+    once a packed row and not once a token (``ops/pallas/decode_attention
+    .py:paged_chunk_queries`` takes ``Sq`` from the shapes).  All new K/V
+    is scattered before a layer attends, so a chunk's query sees the chunk's
+    earlier tokens through its block table;
     ``positions`` [B] — per-row global position of the first token (tokens
     already resident in the row's cache); ``k_pages``/``v_pages``
     [L, NB, BS, Hkv*D] — the global arena (block 0 is the trash block);
@@ -1446,6 +1455,7 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
     from deepspeed_tpu.ops.pallas.decode_attention import (
         paged_layer_attention, paged_mla_attention)
     B, S = input_ids.shape
+    assert not chunk or S == 1, "a prompt chunk is named among one-token rows"
     H, E = cfg.n_head, cfg.n_embd
     n_kinds = len(cfg.pattern)
     if not isinstance(block_tables, (tuple, list)):
@@ -1497,7 +1507,8 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
                 with jax.named_scope("attn_latent"):
                     o = paged_mla_attention(
                         q, kp, li, block_tables[j], positions,
-                        scale=1.0 / math.sqrt(cfg.head_dim), value_lanes=R)
+                        scale=1.0 / math.sqrt(cfg.head_dim), value_lanes=R,
+                        chunk=chunk)
                 o = jnp.einsum("bshr,rhd->bshd", o, w_uv).reshape(B, S, -1)
             else:
                 q, k, v = _project_qkv(cfg, p, h, dt, pos2d, kind)
@@ -1513,7 +1524,7 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
                         "attn_full" if kind.window is None else "attn_window"):
                     o = paged_layer_attention(
                         q, kp, vp, li, block_tables[j], positions,
-                        bias=attn_bias, window=kind.window
+                        bias=attn_bias, window=kind.window, chunk=chunk
                     ).reshape(B, S, cfg.attn_dim)
             o = o @ _wget(p, "out_w", dt)
             if cfg.use_bias:

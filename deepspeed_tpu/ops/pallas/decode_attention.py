@@ -335,6 +335,65 @@ def paged_tile_pages(BS: int, MB: int, Sq: int, lanes: int, dtype) -> int:
     return min(G, MB)
 
 
+# What a row of SEVERAL queries may take of a kernel's VMEM beside the tile
+# buffers: its query and output blocks (two buffers each), the accumulators,
+# the running maxima and sums, one product's scores and probabilities.
+_CHUNK_VMEM_BYTES = 4 * 1024 * 1024
+
+
+def paged_chunk_queries(chunk: int, rows_a_query: int, products: int,
+                        key_lanes: int, value_lanes: int, tile_keys: int,
+                        dtype) -> int:
+    """``Sq``, the consecutive queries of ONE sequence that a row of a
+    prompt chunk holds, from the static shapes alone: the largest divisor of
+    ``chunk`` whose row fits :data:`_CHUNK_VMEM_BYTES`.  A row of ``Sq``
+    queries reads each key of its context once where ``Sq`` single-query
+    rows read it ``Sq`` times, and the attend's work a key does not change,
+    so the most queries that fit is the rule.
+
+    A kernel attends ``products`` times a tile (a K/V head for
+    ``paged_gqa_attention``, once for ``paged_mla_attention``, a head for
+    ``paged_attention``), each a ``[M, key_lanes] x [key_lanes, tile_keys]``
+    product of ``M = rows_a_query * Sq`` rows (the query heads of a group,
+    all heads, one), padded to the sublane tile; a running maximum or sum is
+    a column and pads to a lane tile."""
+    item = np.dtype(dtype).itemsize
+    sublane = 8 * 4 // item
+
+    def row_bytes(Sq):
+        M = -(-rows_a_query * Sq // sublane) * sublane
+        blocks = 2 * (key_lanes + value_lanes) * item
+        carries = 4 * (value_lanes + 2 * _LANES)
+        return M * (products * (blocks + carries) + 2 * 4 * tile_keys)
+
+    return max((d for d in range(1, chunk + 1)
+                if chunk % d == 0 and row_bytes(d) <= _CHUNK_VMEM_BYTES),
+               default=1)
+
+
+def _rows_and_chunk(attend, chunk: int, Sq: int, q, block_tables, lengths,
+                    bias=None):
+    """``attend(q, block_tables, lengths, bias)`` over rows of ONE query
+    each, of which the last ``chunk`` (not all) are consecutive queries of one
+    sequence (a prompt chunk: the same table a row, positions rising by
+    one): those go as ``chunk / Sq`` rows of ``Sq`` queries, a row the table
+    and the position of its first, beside the others a query a row — two
+    calls of the one ``attend``, their outputs back in the rows' order.  A
+    chunk row whose first query carries nothing (an all-trash table at
+    position 0) reads the trash block as its queries did alone; one whose
+    later queries carry nothing computes them for nobody."""
+    n, rows = q.shape[0] - chunk, chunk // Sq
+    bc = None
+    if bias is not None:                # [B, H, 1, T] -> [rows, H, Sq, T]
+        H, T = bias.shape[1], bias.shape[3]
+        bc = bias[n:, :, 0].reshape(rows, Sq, H, T).transpose(0, 2, 1, 3)
+        bias = bias[:n]
+    o = attend(q[:n], block_tables[:n], lengths[:n], bias)
+    oc = attend(q[n:].reshape(rows, Sq, *q.shape[2:]), block_tables[n::Sq],
+                lengths[n::Sq], bc)
+    return jnp.concatenate([o, oc.reshape(chunk, 1, *oc.shape[2:])])
+
+
 def _paged_kernel(len_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf,
                   sem, slot_ref, *, scale, bs, Sq, H, D, MB, G):
     """Grid (B,): per row, DMA ONLY the ``ceil((len+Sq)/bs)`` live physical
@@ -693,6 +752,10 @@ def mla_kernel_shape_ok(lanes: int, value_lanes: int, block: int, dtype) -> bool
             and 0 < value_lanes <= lanes and block % sublane == 0)
 
 
+def _mla_tile_pages(BS: int, MB: int) -> int:
+    return min(max(1, _MLA_TILE_ROWS // BS), MB)
+
+
 def paged_mla_tile_pages(lanes, value_lanes, BS, MB, dtype) -> int:
     """Pages a tile of the kernel ``paged_mla_attention`` holds for these
     shapes; 0 where :func:`paged_mla_attention` takes the gather reference
@@ -701,7 +764,7 @@ def paged_mla_tile_pages(lanes, value_lanes, BS, MB, dtype) -> int:
             or not mla_kernel_shape_ok(lanes, value_lanes, BS, dtype)
             or not _pallas.single_device()):
         return 0
-    return min(max(1, _MLA_TILE_ROWS // BS), MB)
+    return _mla_tile_pages(BS, MB)
 
 
 def paged_mla_attention_reference(q, pages, block_tables, lengths, *, scale,
@@ -846,18 +909,34 @@ def _paged_mla_call(q, arena, layer, block_tables, lengths, scale, R, G):
     return out[:, :H * Sq].reshape(B, H, Sq, R).transpose(0, 2, 1, 3)
 
 
+def paged_mla_chunk_queries(chunk, H, lanes, value_lanes, BS, MB, dtype) -> int:
+    """:func:`paged_chunk_queries` of the call :func:`paged_mla_attention`
+    makes for a prompt chunk: all ``H`` heads the rows of one product."""
+    return paged_chunk_queries(chunk, H, 1, lanes, value_lanes,
+                               _mla_tile_pages(BS, MB) * BS, dtype)
+
+
 def paged_mla_attention(q, arena, layer, block_tables, lengths, *, scale,
-                        value_lanes):
+                        value_lanes, chunk: int = 0):
     """Block-table latent attention of layer ``layer`` of the ONE-array arena
     ``[layers, pages, BS, W]``: q ``[B, Sq, H, W]`` (a head's query moved
     into the cached vector's space, zeros where the vector is padding)
     against each cached vector up to its position, times ``scale``; the
     vector's first ``value_lanes`` lanes are the value -> ``[B, Sq, H,
-    value_lanes]``.  The kernel where :func:`paged_mla_tile_pages` says so,
-    else the layer sliced out and the gather reference."""
+    value_lanes]``.  With ``chunk`` the rows hold one query each and the
+    last ``chunk`` are a prompt chunk, attended packed
+    (:func:`_rows_and_chunk`).  The kernel where
+    :func:`paged_mla_tile_pages` says so, else the layer sliced out and the
+    gather reference."""
     _, _, BS, W = arena.shape
-    G = paged_mla_tile_pages(W, value_lanes, BS, block_tables.shape[1],
-                             arena.dtype)
+    MB = block_tables.shape[1]
+    if chunk:
+        attend = lambda q, tables, lens, _: paged_mla_attention(
+            q, arena, layer, tables, lens, scale=scale, value_lanes=value_lanes)
+        Sq = paged_mla_chunk_queries(chunk, q.shape[2], W, value_lanes, BS, MB,
+                                     q.dtype)
+        return _rows_and_chunk(attend, chunk, Sq, q, block_tables, lengths)
+    G = paged_mla_tile_pages(W, value_lanes, BS, MB, arena.dtype)
     if G:
         return _paged_mla_call(q, arena, layer, block_tables, lengths, scale,
                                value_lanes, G)
@@ -866,17 +945,31 @@ def paged_mla_attention(q, arena, layer, block_tables, lengths, *, scale,
                                          scale=scale, value_lanes=value_lanes)
 
 
+def _takes_gqa_kernel(H, Hkv, bias, window) -> bool:
+    """:func:`paged_layer_attention`'s rule."""
+    return not bias and (window is not None or Hkv != H)
+
+
 def paged_layer_attention(q, k_arena, v_arena, layer, block_tables, lengths,
-                          bias=None, window=None):
+                          bias=None, window=None, chunk: int = 0):
     """What ``gpt_paged_step`` calls a layer.  Grouped K/V heads or a window
     go to :func:`paged_gqa_attention`; multi-head attention over every key
     keeps the layer sliced out of the arena and :func:`paged_attention`
-    until the benchmark can see that copy disappear (ROADMAP S1, S0 (a))."""
-    D = q.shape[3]
+    until the benchmark can see that copy disappear (ROADMAP S1, S0 (a)).
+    With ``chunk`` the rows hold one query each and the last ``chunk`` are a
+    prompt chunk, attended packed (:func:`_rows_and_chunk`)."""
+    H, D = q.shape[2:]
+    Hkv = k_arena.shape[3] // D
     assert bias is None or window is None, (
         "a window layer with an additive bias has no paged path")
-    if bias is None and (window is not None
-                         or k_arena.shape[3] // D != q.shape[2]):
+    if chunk:
+        attend = lambda q, tables, lens, bias: paged_layer_attention(
+            q, k_arena, v_arena, layer, tables, lens, bias=bias, window=window)
+        Sq = paged_layer_chunk_queries(
+            chunk, H, Hkv, D, k_arena.shape[2], block_tables.shape[1], q.dtype,
+            bias is not None, window)
+        return _rows_and_chunk(attend, chunk, Sq, q, block_tables, lengths, bias)
+    if _takes_gqa_kernel(H, Hkv, bias is not None, window):
         return paged_gqa_attention(q, k_arena, v_arena, layer, block_tables,
                                    lengths, window)
     kl = jax.lax.dynamic_index_in_dim(k_arena, layer, 0, keepdims=False)
@@ -884,11 +977,24 @@ def paged_layer_attention(q, k_arena, v_arena, layer, block_tables, lengths,
     return paged_attention(q, kl, vl, block_tables, lengths, bias=bias)
 
 
+def paged_layer_chunk_queries(chunk, H, Hkv, D, BS, MB, dtype, bias=False,
+                              window=None) -> int:
+    """:func:`paged_chunk_queries` of the call :func:`paged_layer_attention`
+    makes for a prompt chunk, by its rule: a K/V head's ``H / Hkv`` query
+    heads the rows of one product, or a head a product of its own in its
+    lane slice."""
+    tile_keys = paged_tile_pages(BS, MB, 1, Hkv * D, dtype) * BS
+    if _takes_gqa_kernel(H, Hkv, bias, window):
+        return paged_chunk_queries(chunk, H // Hkv, Hkv, D, D, tile_keys, dtype)
+    W = _lane_slices(H, D)[0]
+    return paged_chunk_queries(chunk, 1, H, W, W, tile_keys, dtype)
+
+
 def paged_layer_tile_pages(Sq, H, Hkv, D, BS, MB, dtype, bias=False,
                            window=None) -> int:
     """Pages a tile of the kernel :func:`paged_layer_attention` builds for
     these shapes (0: a gather reference), by its rule."""
-    if not bias and (window is not None or Hkv != H):
+    if _takes_gqa_kernel(H, Hkv, bias, window):
         return paged_gqa_tile_pages(Sq, H, Hkv, D, BS, MB, dtype)
     return paged_kernel_tile_pages(Sq, H, Hkv, D, BS, MB, dtype, bias)
 

@@ -4,7 +4,7 @@ The inference stack's ``generate()`` serves one static batch per call; this
 engine serves a *stream*: requests join and leave the decode batch every
 step without recompilation.  The trick is shape discipline — exactly ONE
 program is ever compiled, and a step runs it ONCE: ``[max_batch_size +
-prefill_chunk, 1]`` tokens over the arena, every row one query.
+prefill_chunk, 1]`` tokens over the arena.
 
 * rows ``0 .. max_batch_size - 1`` are the **decode** slots — every active
   sequence advances one token;
@@ -12,9 +12,13 @@ prefill_chunk, 1]`` tokens over the arena, every row one query.
   prefill: at most one chunk of at most ``prefill_chunk`` tokens a step, so
   a long prompt never holds the decode rows back for more than a chunk) —
   token ``i`` of the chunk is a row of its own at position ``start + i``
-  with the request's block table.  Every layer scatters all new K/V before
-  it attends, so the row sees keys ``0 .. start + i``, its own chunk's
-  included.
+  with the request's block table, through everything but attention.  A
+  layer's attention takes the chunk PACKED, ``Sq`` consecutive tokens a row
+  under the table and the position of the first (``Sq`` from the shapes:
+  ``ops/pallas/decode_attention.py:paged_chunk_queries``), so a key of the
+  prompt is read once a packed row and not once a token.  Every layer
+  scatters all new K/V before it attends, so token ``i`` sees keys
+  ``0 .. start + i``, its own chunk's included.
 
 Rows that carry nothing (an idle slot, the chunk's rows past its tokens,
 all of them in a step without a chunk) carry trash-block write coordinates
@@ -99,9 +103,11 @@ class StepLayout(NamedTuple):
     ``[rows, 4]`` (token, position, slot, live), then ``[slots]`` (1: the
     slot's row goes back to trash, before any entry), then ``[edits, 2]``
     (address in the state, block), padded with addresses past the state's
-    end, which the scatter drops."""
+    end, which the scatter drops.  The rows behind the slots are the step's
+    prompt chunk, a token a row here and in the program, which the model
+    packs for attention alone (``paged_step``'s ``chunk``)."""
     slots: int                  # decode rows; rows of each table
-    rows: int                   # slots + prefill_chunk: rows of the program
+    rows: int                   # slots + prefill_chunk: tokens of the program
     widths: Tuple[int, ...]     # columns of each layer group's table
     offsets: Tuple[int, ...]    # where each group's table starts in the state
     block_size: int
@@ -394,16 +400,26 @@ class ServingEngine:
         # paged kernel's pages per tile, 0 on the einsum path; a stat of
         # every step and of the ``serve.stats`` span
         from deepspeed_tpu.ops.pallas.decode_attention import (
-            paged_layer_tile_pages, paged_mla_tile_pages)
+            paged_layer_chunk_queries, paged_layer_tile_pages,
+            paged_mla_chunk_queries, paged_mla_tile_pages)
         if mcfg.kv_lora_rank:
-            self.paged_tile_pages = paged_mla_tile_pages(
-                mcfg.cache_lanes[0], mcfg.kv_lora_rank, cfg.block_size,
-                self.max_blocks_per_seq, self.dtype)
+            shape = (mcfg.cache_lanes[0], mcfg.kv_lora_rank, cfg.block_size,
+                     self.max_blocks_per_seq, self.dtype)
+            self.paged_tile_pages = paged_mla_tile_pages(*shape)
+            queries = paged_mla_chunk_queries(cfg.prefill_chunk, mcfg.n_head,
+                                              *shape)
         else:
-            self.paged_tile_pages = paged_layer_tile_pages(
-                1, mcfg.n_head, mcfg.kv_heads, mcfg.head_dim, cfg.block_size,
-                self.max_blocks_per_seq, self.dtype,
-                bias=mcfg.position_encoding == "alibi", window=self._windows[0])
+            shape = (mcfg.n_head, mcfg.kv_heads, mcfg.head_dim, cfg.block_size,
+                     self.max_blocks_per_seq, self.dtype,
+                     mcfg.position_encoding == "alibi", self._windows[0])
+            self.paged_tile_pages = paged_layer_tile_pages(1, *shape)
+            queries = paged_layer_chunk_queries(cfg.prefill_chunk, *shape)
+        # how a layer's attention takes the step's prompt chunk (static too):
+        # ``queries`` consecutive tokens a row, so its calls run this many
+        # rows where the program holds ``slots + chunk`` tokens
+        self.chunk_queries_per_row = queries
+        self.attention_rows = (cfg.max_batch_size
+                               + cfg.prefill_chunk // queries)
         # bytes the arena holds a token a layer (every array of the cache
         # spec): a stat of the ``serve.stats`` span
         self.cache_bytes_per_token = (sum(mcfg.cache_lanes)
@@ -417,7 +433,8 @@ class ServingEngine:
                 layout, packed, state)
             moe = {"with_expert_counts": True} if self._moe_experts else {}
             logits, kp, vp, *counts = model.paged_step(
-                params, ids, positions, kp, vp, tables, wb, wo, **moe)
+                params, ids, positions, kp, vp, tables, wb, wo,
+                chunk=layout.rows - layout.slots, **moe)
             if mcfg.padded_vocab != mcfg.vocab_size:
                 vmask = jnp.arange(mcfg.padded_vocab) < mcfg.vocab_size
                 logits = jnp.where(vmask[None, None], logits, -1e30)
@@ -844,11 +861,17 @@ class ServingEngine:
         except ServeStepTimeout as err:
             self._recover_incident(err)
             raise
+        # how attention took the step: the queries a row of the chunk held
+        # (0: no chunk in the step) and the rows its calls ran
+        shape_stats = dict(
+            table_stats,
+            chunk_queries_per_row=self.chunk_queries_per_row if n_chunk else 0,
+            attention_rows=self.attention_rows if runs else 0)
         with self._span("serve.stats", paged_tile_pages=self.paged_tile_pages,
                         cache_bytes_per_token=self.cache_bytes_per_token,
-                        **table_stats):
+                        **shape_stats):
             stats = self._close_step(len(decode), n_chunk, int(runs), t_step)
-            stats.update(moe_stats, **table_stats)
+            stats.update(moe_stats, **shape_stats)
             return stats
 
     def _moe_stats(self) -> Dict[str, float]:

@@ -11,6 +11,7 @@ the program has no switch for it.
 """
 
 import os
+import re
 
 import pytest
 
@@ -194,18 +195,27 @@ def test_paged_kernel_compiles_at_olmoe_heads(chip, rows, Sq):
     assert da.paged_kernel_tile_pages(Sq, H, H, D128, BS, MB, BF16) == 8
 
 
+def _kernel_rows(text, kernel):
+    """Rows (the grid) of every call of ``kernel`` in a compiled program."""
+    return sorted(int(rows) for rows in re.findall(
+        rf"%{kernel}[.\d]* = \w+\[(\d+),[^\n]*tpu_custom_call", text))
+
+
 @pytest.mark.parametrize("slots,H,head_dim,MB", [(256, 12, 64, 64), (128, 16, 128, 256)],
                          ids=["gpt2-124m", "olmoe-1b-7b"])
 def test_paged_kernel_compiles_at_a_serve_steps_rows(chip, slots, H, head_dim, MB):
-    """The one program of a serve step: every decode slot and every token of
-    the prompt chunk is a single-query row, ``slots + CHUNK`` of them, and
-    their flattened block tables (320 x 64 and 192 x 256 int32: 80 and 192
-    KiB) are the kernel's scalar prefetch, at the benchmark cells' sizes."""
+    """A layer's attention in the one program of a serve step, at the
+    benchmark cells' sizes: ``slots`` decode rows a query each, their
+    flattened block tables (256 x 64 and 128 x 256 int32: 64 and 128 KiB)
+    the kernel's scalar prefetch, and the prompt chunk's 64 tokens as ONE row
+    of 64 queries; no call of ``slots + CHUNK`` single-query rows."""
     rows, BS = slots + CHUNK, 16
-    pages = ((1025, BS, H * head_dim), BF16)
-    text = _compiled_text(chip, da.paged_attention, ((rows, 1, H, head_dim), BF16),
-                          pages, pages, ((rows, MB), jnp.int32), ((rows,), jnp.int32))
-    assert "tpu_custom_call" in text
+    arena = ((2, 1025, BS, H * head_dim), BF16)
+    fn = lambda *a: da.paged_layer_attention(*a, chunk=CHUNK)
+    text = _compiled_text(chip, fn, ((rows, 1, H, head_dim), BF16), arena, arena,
+                          ((), jnp.int32), ((rows, MB), jnp.int32), ((rows,), jnp.int32))
+    assert da.paged_layer_chunk_queries(CHUNK, H, H, head_dim, BS, MB, BF16) == CHUNK
+    assert _kernel_rows(text, "paged_attention") == [1, slots]
     assert da.paged_kernel_tile_pages(1, H, H, head_dim, BS, MB, BF16) == 8
 
 
@@ -214,20 +224,24 @@ def test_paged_kernel_compiles_at_a_serve_steps_rows(chip, slots, H, head_dim, M
 def test_paged_gqa_kernel_compiles_at_smallthinker_heads(chip, window, MB):
     """SmallThinker-21B-A3B's attention as its serve cell runs it: 28 query
     heads on 4 K/V heads of D=128 (7 rows of one product a K/V lane slice),
-    pages of 16, ``Sq = 1``, 32 slots + a chunk of 224 = 256 rows, the arena
-    of two-layer pages WHOLE with the layer a scalar.  A full layer's table
-    is 1,024 blocks wide (256 of them are the whole 1 MiB of SMEM, which is
-    why a step is handed its row's table as a block); a window layer's is
-    the ring of ``(4096 + 224 - 1) / 16 + 1`` blocks."""
-    H, Hkv, D128, BS, rows = 28, 4, 128, 16, 256
+    pages of 16, the arena of two-layer pages WHOLE with the layer a scalar;
+    32 slots at ``Sq = 1`` and the chunk of 224 as 7 rows of 32 queries (224
+    rows of one product).  A full layer's table is 1,024 blocks wide (256 of
+    them are the whole 1 MiB of SMEM, which is why a step is handed its
+    row's table as a block); a window layer's is the ring of ``(4096 + 224 -
+    1) / 16 + 1`` blocks."""
+    H, Hkv, D128, BS, slots, chunk = 28, 4, 128, 16, 32, 224
+    rows = slots + chunk
     assert da.gqa_kernel_shape_ok(H, Hkv, D128, BS, BF16)
     assert not da.kernel_shape_ok(H, Hkv, D128, BS, BF16)     # the old gate: MHA only
     arena = ((2, 57344, BS, Hkv * D128), BF16)
     fn = lambda q, k, v, layer, tables, lengths: da.paged_layer_attention(
-        q, k, v, layer, tables, lengths, window=window)
+        q, k, v, layer, tables, lengths, window=window, chunk=chunk)
     text = _compiled_text(chip, fn, ((rows, 1, H, D128), BF16), arena, arena,
                           ((), jnp.int32), ((rows, MB), jnp.int32), ((rows,), jnp.int32))
-    assert "tpu_custom_call" in text and "paged_gqa_attention" in text
+    assert da.paged_layer_chunk_queries(chunk, H, Hkv, D128, BS, MB, BF16,
+                                        window=window) == 32
+    assert _kernel_rows(text, "paged_gqa_attention") == [chunk // 32, slots]
     assert "dynamic-slice" not in text        # no layer of K and V sliced out
     assert da.paged_layer_tile_pages(1, H, Hkv, D128, BS, MB, BF16, window=window) == 8
 
@@ -287,20 +301,99 @@ def test_generate_keeps_its_cache_zero_filled(chip):
 def test_paged_mla_kernel_compiles_at_mistral4_heads(chip):
     """Mistral-Small-4's latent attention as its serve cell runs it: 32
     heads the rows of ONE product against the cached vector (256 latent + 64
-    rope lanes, 384 in the arena), pages of 16, ``Sq = 1``, 128 slots + a
-    chunk of 384 = 512 rows, the one-array arena of 50,000 blocks WHOLE with
-    the layer a scalar, tables 1,024 blocks wide as SMEM blocks."""
-    H, W, R, BS, rows, MB = 32, 384, 256, 16, 512, 1024
+    rope lanes, 384 in the arena), pages of 16, the one-array arena of 50,000
+    blocks WHOLE with the layer a scalar, tables 1,024 blocks wide as SMEM
+    blocks; 128 slots at ``Sq = 1`` and the chunk of 384 as 24 rows of 16
+    queries (512 rows of the product)."""
+    H, W, R, BS, slots, chunk, MB = 32, 384, 256, 16, 128, 384, 1024
+    rows = slots + chunk
     assert da.mla_kernel_shape_ok(W, R, BS, BF16)
     assert not da.mla_kernel_shape_ok(320, R, BS, BF16)       # the cache unpadded
     fn = lambda q, arena, layer, tables, lengths: da.paged_mla_attention(
-        q, arena, layer, tables, lengths, scale=128 ** -0.5, value_lanes=R)
+        q, arena, layer, tables, lengths, scale=128 ** -0.5, value_lanes=R,
+        chunk=chunk)
     text = _compiled_text(chip, fn, ((rows, 1, H, W), BF16),
                           ((5, 50000, BS, W), BF16), ((), jnp.int32),
                           ((rows, MB), jnp.int32), ((rows,), jnp.int32))
-    assert "tpu_custom_call" in text and "paged_mla_attention" in text
+    assert da.paged_mla_chunk_queries(chunk, H, W, R, BS, MB, BF16) == 16
+    assert _kernel_rows(text, "paged_mla_attention") == [chunk // 16, slots]
     assert "dynamic-slice" not in text        # no layer of the arena sliced out
     assert da.paged_mla_tile_pages(W, R, BS, MB, BF16) == 16
+
+
+# a serve cell's model at its published widths (one period of its layers: the
+# program scans them), its slots, chunk and positions, its kernel and the
+# queries a row of the chunk holds
+SERVE_CELLS = {
+    "gpt2-124m": (lambda m: m.gpt_config("gpt2", n_layer=1, dtype=BF16),
+                  256, 64, 1024, "paged_attention", 64),
+    "olmoe-1b-7b": (lambda m: m.olmoe_config(n_layer=1, dtype=BF16),
+                    128, 64, 4096, "paged_attention", 64),
+    "smallthinker-21b-a3b": (lambda m: m.smallthinker_config(n_layer=4, dtype=BF16),
+                             32, 224, 16384, "paged_gqa_attention", 32),
+    "mistral-small-4-119b": (lambda m: m.mistral4_config(n_layer=1, dtype=BF16),
+                             128, 384, 16384, "paged_mla_attention", 16),
+}
+
+
+@pytest.mark.parametrize("cell", list(SERVE_CELLS))
+def test_the_step_program_attends_the_chunk_packed(chip, cell):
+    """The whole step of each serve configuration, compiled ahead of time:
+    every layer kind holds its paged kernel at TWO shapes, the decode slots a
+    query a row and the prompt chunk ``Sq > 1`` queries a row, and no
+    attention call runs ``slots + chunk`` rows."""
+    from deepspeed_tpu.models import gpt
+    from deepspeed_tpu.serving.kv_cache import init_arena, window_table_blocks
+    make, slots, chunk, positions, kernel, Sq = SERVE_CELLS[cell]
+    cfg = make(gpt)
+    model, rows, BS = gpt.GPT(cfg), slots + chunk, 16
+    shape = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=chip)
+    on_chip = lambda tree: jax.tree.map(lambda a: shape(a.shape, a.dtype), tree)
+    params = jax.tree.map(
+        lambda p: shape(p.shape, BF16 if jnp.issubdtype(p.dtype, jnp.floating)
+                        else p.dtype),
+        jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
+    kp, vp = on_chip(jax.eval_shape(lambda: init_arena(cfg, 1025, BS, dtype=BF16)))
+    widths = [positions // BS if kind.window is None
+              else window_table_blocks(kind.window, chunk, BS) for kind in cfg.pattern]
+    tables = tuple(shape((rows, w), jnp.int32) for w in widths)
+    coords = tuple(shape((rows, 1), jnp.int32) for _ in widths)
+    step = lambda *a: model.paged_step(*a, chunk=chunk)
+    text = jax.jit(step).lower(
+        params, shape((rows, 1), jnp.int32), shape((rows,), jnp.int32), kp, vp,
+        tables, coords, shape((rows, 1), jnp.int32)).compile().as_text()
+    assert chunk % Sq == 0 and Sq > 1
+    assert _kernel_rows(text, kernel) == sorted(
+        [slots, chunk // Sq] * len(cfg.pattern))
+
+
+# the four serve cells' attention: (chunk, rows a query, products, key lanes,
+# value lanes, keys a tile) -> queries a row
+CHUNK_SHAPES = {
+    "gpt2-124m": ((64, 1, 12, 128, 128, 128), 64),
+    "olmoe-1b-7b": ((64, 1, 16, 128, 128, 128), 64),
+    "smallthinker-21b-a3b": ((224, 7, 4, 128, 128, 128), 32),
+    "mistral-small-4-119b": ((384, 32, 1, 384, 256, 256), 16),
+}
+
+
+@pytest.mark.parametrize("cell", list(CHUNK_SHAPES))
+def test_chunk_queries_divide_the_chunk_and_fit_the_budget(cell, monkeypatch):
+    """``paged_chunk_queries``: the LARGEST divisor of the chunk whose row
+    fits the budget (so twice the budget never gives fewer queries, and a
+    budget of nothing gives one), at the four serve cells' shapes."""
+    shape, want = CHUNK_SHAPES[cell]
+    chunk = shape[0]
+    assert da.paged_chunk_queries(*shape, BF16) == want and chunk % want == 0
+    # float32 rows are twice as wide
+    assert da.paged_chunk_queries(*shape, jnp.float32) <= want
+    budget = da._CHUNK_VMEM_BYTES
+    seen = []
+    for scale in (0, 0.25, 0.5, 1, 2, 4, 64):
+        monkeypatch.setattr(da, "_CHUNK_VMEM_BYTES", int(budget * scale))
+        seen.append(da.paged_chunk_queries(*shape, BF16))
+        assert chunk % seen[-1] == 0
+    assert seen == sorted(seen) and seen[0] == 1 and seen[-1] == chunk
 
 
 @pytest.mark.parametrize("K,N", [(4096, 4096), (2048, 4096)])

@@ -2,7 +2,9 @@
 (``paged_gqa_attention``), through the Pallas interpreter, and the gather
 reference's window, both against attention written out over each row's
 LOGICAL keys: ``g`` in {1, 7}, with and without a window, ragged lengths, an
-idle row, tables that are rings where there is a window."""
+idle row, tables that are rings where there is a window; and at the ``Sq`` a
+prompt chunk's rows hold (``paged_chunk_queries``: 32 at SmallThinker's
+heads), where a row's queries span pages, tiles and the window's edge."""
 
 import numpy as np
 import pytest
@@ -59,6 +61,14 @@ CASES = [  # g, window, Sq, lengths (0: an idle row)
     (7, 40, 1, (150, 0, 7, 39, 40, 41)),
     (7, 40, 3, (150, 38, 7)),
     (7, None, 3, (150, 7)),
+    # a prompt chunk's row, 32 queries.  Window 100 in a ring of 18 pages of
+    # 8, tiles of 16 pages: from 300 the row reads pages 25..41 (the ring
+    # wraps twice), the last query's window starts inside the first tile,
+    # and every key of the second tile is masked for the first 28 queries;
+    # from 90 the first queries see every key and the last have lost some
+    (7, 100, 32, (300, 90, 7)),
+    (7, None, 32, (150, 7, 100)),
+    (1, 100, 32, (300,)),
 ]
 
 
@@ -73,6 +83,9 @@ def test_kernel_and_reference_equal_attention_over_the_logical_keys(
     np.testing.assert_allclose(np.asarray(ref)[live], want[live], atol=2e-5)
     kernels("paged_gqa_attention")
     assert da.paged_gqa_tile_pages(Sq, 2 * g, 2, D, BS, MB, jnp.float32) > 0
+    if Sq == 32:        # what the rule gives SmallThinker's heads and chunk
+        assert da.paged_layer_chunk_queries(224, 28, 4, D, 16, MB, jnp.bfloat16,
+                                            window=window) == Sq
     out = da.paged_gqa_attention(q, ka, va, jnp.asarray(1), tables, lens,
                                  window=window)
     assert np.isfinite(np.asarray(out)).all()       # the idle row too

@@ -12,8 +12,10 @@ that builder).  Held here, at every dispatch of seeded random traffic:
 * after the step the table state on the device equals ``block_table`` for
   every slot a sequence holds, and is all trash for every other slot (one
   freed in the step's commit: with the next upload);
-* the tokens of the step equal those of the parent's program fed the
-  oracle's arrays over the same arena (GPT-2, OLMoE, SmallThinker, tiny).
+* the tokens of the step's live rows equal those of the parent's program
+  (whole tables as inputs, and a prompt token a single-query row of its own)
+  fed the oracle's arrays over the same arena (GPT-2, OLMoE, SmallThinker,
+  tiny).
 """
 
 import json
@@ -154,8 +156,12 @@ class Watch:
                                  jnp.asarray(want[4]))
         tokens = self._dispatch(phase, packed, reload, stats)
         if self.parent is not None:
+            # the rows that carry a request: a chunk's rows past its tokens
+            # are computed for nobody (packed beside live queries they see
+            # the request's table, alone they saw the trash block)
+            rows = np.flatnonzero(wb[0][:, 0])
             np.testing.assert_array_equal(
-                tokens, np.asarray(theirs).reshape(-1)[:tokens.size],
+                tokens[rows], np.asarray(theirs).reshape(-1)[rows],
                 err_msg=f"tokens, step {self.steps}")
         self.steps += 1
         self.reloads += reload is not None

@@ -1,8 +1,9 @@
 """One program a serve step: the prompt chunk rides with the decode rows.
 
-A step builds ONE upload for ``[max_batch_size + prefill_chunk, 1]`` rows,
-dispatches one program, fetches one token row and commits the chunk and the
-decode rows from it.  Whatever the traffic puts in the rows (a multi-chunk
+A step builds ONE upload for ``[max_batch_size + prefill_chunk, 1]`` tokens,
+dispatches one program (whose attention takes the chunk's tokens packed,
+several queries a row: ``test_packed_chunk.py``), fetches one token row and
+commits the chunk and the decode rows from it.  Whatever the traffic puts in the rows (a multi-chunk
 prompt arriving while others decode, a prefix-cache hit, a preemption with
 recompute, a restore from a snapshot) and whatever the model (learned
 positions, ALiBi, rope with grouped K/V heads), every request's tokens are
@@ -17,6 +18,7 @@ import pytest
 import jax
 
 from deepspeed_tpu.models.gpt import GPT, GPTConfig
+from deepspeed_tpu.ops.pallas import decode_attention as da
 from deepspeed_tpu.serving import DeepSpeedServingConfig, ServingEngine
 from deepspeed_tpu.serving.engine import unpack_step
 from deepspeed_tpu.telemetry.tracing import Tracer
@@ -135,14 +137,20 @@ def test_mixed_traffic_is_token_identical_in_one_program(model_and_params, traff
 
 
 # ---- the step itself ------------------------------------------------------------ #
-def test_the_program_has_one_shape_whatever_the_step_holds(model_and_params):
+def test_the_program_has_one_shape_whatever_the_step_holds(model_and_params,
+                                                           monkeypatch):
     """Chunk alone, chunk beside decode rows, decode rows alone: the same one
-    upload, and the program makes of it the same ``[slots + chunk, 1]`` rows,
-    told apart only by what is in them."""
+    upload, and the program makes of it the same ``[slots + chunk, 1]``
+    tokens, told apart only by what is in them; its attention runs the slots
+    a query a row and the chunk as ONE row of its 8 queries (what the rule
+    gives these shapes), never ``slots + chunk`` rows."""
     eng = engine(model_and_params)
-    shapes, kinds = set(), set()
+    shapes, kinds, attended = set(), set(), set()
     dispatch = eng._dispatch
     unpack = jax.jit(unpack_step, static_argnums=0)
+    reference = da.paged_attention_reference
+    monkeypatch.setattr(da, "paged_attention_reference", lambda q, *a, **kw: (
+        attended.add(q.shape[:2]), reference(q, *a, **kw))[1])
 
     def spy(phase, packed, reload, stats):
         assert reload is None
@@ -171,6 +179,8 @@ def test_the_program_has_one_shape_whatever_the_step_holds(model_and_params):
                        (R, eng.max_blocks_per_seq), (R, 1), (R, 1))}
     assert kinds == {("prefill", True, False), ("decode", True, True),
                      ("decode", False, True)}
+    assert attended == {(SLOTS, 1), (1, CHUNK)}
+    assert (eng.chunk_queries_per_row, eng.attention_rows) == (CHUNK, SLOTS + 1)
     assert eng.compiled_programs() == 1
     eng.close()
 

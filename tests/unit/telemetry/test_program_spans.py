@@ -232,6 +232,11 @@ def test_spans_carry_counts_where_the_work_happens(tiny_model):
                                  "pages_given_back": 0}]
     assert not any(n.startswith("serve.decode.") for n in by)
     assert (stats["programs"], stats["prefill_tokens"], stats["decode_batch"]) == (1, 8, 0)
+    # how attention took the step: the chunk's 8 tokens the queries of ONE
+    # row beside the 4 slots' rows, on the span and in the stats
+    packed = {"chunk_queries_per_row": 8, "attention_rows": 4 + 1}
+    assert {k: by["serve.stats"][0][k] for k in packed} == packed
+    assert {k: stats[k] for k in packed} == packed
     eng.step()                                  # last chunk: the first token
     by, stats = _step_spans(tr, eng)            # the first decode step
     for name in ("serve.decode.build", "serve.decode.commit"):
@@ -244,16 +249,19 @@ def test_spans_carry_counts_where_the_work_happens(tiny_model):
     # which paged kernel the engine runs (0: the einsum, as on this CPU), the
     # bytes the arena holds a token a layer (the cache spec's arrays), and
     # what the step's tables cost: the one upload, no entry changed (the
-    # request was handed its two blocks at admission), nothing reloaded
+    # request was handed its two blocks at admission), nothing reloaded;
+    # no chunk in this step, so no queries a row of one, and the same rows
     table = {"table_edits": 0, "table_reloads": 0,
              "upload_bytes": 4 * eng._layout.packed_size}
     assert by["serve.stats"] == [dict(table, paged_tile_pages=eng.paged_tile_pages,
-                                      cache_bytes_per_token=eng.cache_bytes_per_token)]
+                                      cache_bytes_per_token=eng.cache_bytes_per_token,
+                                      chunk_queries_per_row=0, attention_rows=5)]
     assert {k: stats[k] for k in table} == table
     assert (stats["programs"], stats["prefill_tokens"], stats["decode_batch"]) == (1, 0, 1)
     fut.result()
     idle = eng.step()                           # nothing to run: no program,
     assert (idle["programs"], idle["upload_bytes"]) == (0, 0)       # no upload
+    assert (idle["chunk_queries_per_row"], idle["attention_rows"]) == (0, 0)
     eng.close()
 
 

@@ -325,13 +325,15 @@ def test_absorbed_attention_equals_plain_attention(tiny):
     assert float(jnp.abs(absorbed - plain[0]).max()) < 1e-6
 
 
-@pytest.mark.parametrize("Sq", [1, 3])
+@pytest.mark.parametrize("Sq", [1, 3, 16])
 def test_paged_mla_kernel_equals_the_gather_reference(kernels, Sq):
     """The kernel through the interpreter against the gather reference: 5
     heads (rows padded to the sublane tile), pages of 8 in tiles of 32
     (``_MLA_TILE_ROWS`` 256), rows whose context ends inside the first page,
     at a page's edge, past a tile and in the table's last page, an idle row
-    of trash, over layer 1 of a two-layer arena."""
+    of trash, over layer 1 of a two-layer arena; a query a row, three, and
+    the 16 a row of the serve cell's prompt chunk holds (its queries then
+    span pages, and from 255 + 9 the tile's edge)."""
     kernels("paged_mla_attention")
     B, H, W, R, BS, MB, NB = 6, 5, 256, 128, 8, 40, 64
     rng = np.random.default_rng(1)
@@ -341,6 +343,7 @@ def test_paged_mla_kernel_equals_the_gather_reference(kernels, Sq):
     tables = jnp.asarray(np.stack([rng.permutation(np.arange(1, NB))[:MB]
                                    for _ in range(B)]), jnp.int32).at[5].set(0)
     assert da.paged_mla_tile_pages(W, R, BS, MB, jnp.float32) == 32
+    assert da.paged_mla_chunk_queries(384, 32, 384, 256, 16, 1024, jnp.bfloat16) == 16
     got = jax.jit(lambda *a: da.paged_mla_attention(*a, scale=0.17, value_lanes=R))(
         q, arena, jnp.int32(1), tables, lengths)
     want = da.paged_mla_attention_reference(q, arena[1], tables, lengths, scale=0.17,
