@@ -78,8 +78,15 @@ from deepspeed_tpu.utils.logging import log_dist
 #: the ``serve.decode.*`` pair when the program carries a decode row (stat
 #: ``batch``: every live row, chunk tokens included), the ``serve.prefill.*``
 #: pair when it carries a prompt chunk alone; both have ``chunk_tokens``.
-#: ``submit()`` is ``serve.submit``; a request's first token leaves one
-#: zero-length ``serve.first_token`` with its waits as stats.
+#: ``submit()`` is ``serve.submit`` (stat ``rid``); a request's first token
+#: leaves one zero-length ``serve.first_token`` with its waits as stats.
+#: ``serve.stats`` carries what the step's tables and attention cost
+#: (``table_edits``, ``table_reloads``, ``upload_bytes``,
+#: ``chunk_queries_per_row``, ``attention_rows``) and, in a step that follows
+#: a step with a program, the step's turn-round as DURATIONS on the engine's
+#: one clock (:data:`TURNAROUND_STATS`): they need no alignment with any
+#: other line of a trace, and they are on the main thread's line whatever
+#: thread ran the dispatch.
 SERVE_STEP_SPANS = (
     "serve.admit",              # deadlines, shed ladder, sched.admit
     "serve.grow",               # sort, ensure_capacity, decode_batch
@@ -93,6 +100,31 @@ SERVE_STEP_SPANS = (
     "serve.decode.commit",
     "serve.stats",              # ledger, stats dict, gauges, emit
 )
+
+#: What a step says of its own turn-round, in ``step()``'s stats and on
+#: ``serve.stats``, from four stamps of ``ServingEngine._clock``: ``t_enter``
+#: and ``t_exit`` at ``step()``'s first and last line, ``t_launch`` when the
+#: call of the step program has returned (upload made, program enqueued) and
+#: ``t_result`` when its token row is on the host.  For the step that ran
+#: program n after program n-1:
+#:
+#: * ``turnaround_ms`` = ``t_launch(n) - t_result(n-1)``: the chip has no
+#:   program of this engine; the sum of the next three;
+#: * ``commit_ms`` = ``t_exit(n-1) - t_result(n-1)``: commit and stats of the
+#:   step before;
+#: * ``outside_ms`` = ``t_enter(n) - t_exit(n-1)``: the caller, between two
+#:   ``step()`` calls;
+#: * ``prepare_ms`` = ``t_launch(n) - t_enter(n)``: admit, grow, build,
+#:   upload, launch;
+#: * ``result_wait_ms`` = ``t_result(n) - t_launch(n)``: the host waiting; it
+#:   holds the program's device time and both wires.
+#:
+#: The first step, a step after one that ran no program or left the engine
+#: with no request, and the first step after an incident's re-jit carry none:
+#: there the chip waited for work or for the compiler, not for the host's
+#: turn-round.
+TURNAROUND_STATS = ("turnaround_ms", "commit_ms", "outside_ms", "prepare_ms",
+                    "result_wait_ms")
 
 
 class StepLayout(NamedTuple):
@@ -318,7 +350,7 @@ class ServingEngine:
             self._g_nvme_bytes = r.gauge("serve_kv_nvme_bytes")
             self._g_prefix_rate = r.gauge("prefix_hit_rate")
             self._h_step = r.histogram("serve_step_ms")
-            self._h_decode = r.histogram("serve_decode_step_ms")
+            self._h_turnaround = r.histogram("serve_turnaround_ms")
         self.dtype = cfg.jnp_dtype
         assert hasattr(model, "paged_step") and hasattr(model, "cfg"), (
             "ServingEngine needs a model with .cfg and .paged_step(...) "
@@ -398,7 +430,7 @@ class ServingEngine:
 
         # which attention the decode program gets (static per engine): the
         # paged kernel's pages per tile, 0 on the einsum path; a stat of
-        # every step and of the ``serve.stats`` span
+        # every step
         from deepspeed_tpu.ops.pallas.decode_attention import (
             paged_layer_chunk_queries, paged_layer_tile_pages,
             paged_mla_chunk_queries, paged_mla_tile_pages)
@@ -421,7 +453,7 @@ class ServingEngine:
         self.attention_rows = (cfg.max_batch_size
                                + cfg.prefill_chunk // queries)
         # bytes the arena holds a token a layer (every array of the cache
-        # spec): a stat of the ``serve.stats`` span
+        # spec)
         self.cache_bytes_per_token = (sum(mcfg.cache_lanes)
                                       * np.dtype(self.dtype).itemsize)
 
@@ -454,6 +486,11 @@ class ServingEngine:
 
         # ---- resilience plane -------------------------------------------- #
         self._clock = time.monotonic
+        # the last program's ``t_result`` (None: the step before left no
+        # work, or the program is not compiled yet) and the last step's ``t_exit``:
+        # what the next step's TURNAROUND_STATS are measured from
+        self._t_result: Optional[float] = None
+        self._t_exit = 0.0
         self.admission = AdmissionController(cfg)
         # bounded step dispatch: a wedged compiled program raises
         # ServeStepTimeout instead of parking the engine thread forever.
@@ -607,10 +644,12 @@ class ServingEngine:
         (:meth:`_pack`; ``reload``: the tables whole, when the edits did not
         fit it) under the
         ``serve_step_timeout_s`` deadline (inline when unbounded), and
-        return its token row on the host.  The host materialization of the
-        token row happens *inside* the bounded callable — that device sync
+        return its token row on the host with the stamps ``t_launch`` and
+        ``t_result`` (:data:`TURNAROUND_STATS`).  The host materialization of
+        the token row happens *inside* the bounded callable — that device sync
         is exactly where a wedged program parks the thread — so the
-        ``dispatch`` and ``fetch`` spans go to the worker thread with it.
+        ``dispatch`` and ``fetch`` spans go to the worker thread with it, and
+        the stamps are taken there and handed back.
         ``phase`` names the span pair and the fault point: ``decode`` when
         the program carries a decode row, ``prefill`` when it carries a
         prompt chunk alone.  The first dispatch (and the first after an
@@ -625,8 +664,10 @@ class ServingEngine:
                          else jax.device_put(reload))
                 tokens, kp, vp, state = self._step_fn(
                     self.params, packed, self._k_pages, self._v_pages, state)
+                t_launch = self._clock()
             with self._span(f"serve.{phase}.fetch", **stats):
-                return np.asarray(tokens).reshape(-1), kp, vp, state
+                row = np.asarray(tokens).reshape(-1)
+                return row, kp, vp, state, t_launch, self._clock()
         if self._bounded is None or not self._warm:
             out = work()
             self._warm = True
@@ -638,10 +679,10 @@ class ServingEngine:
                     f"serve {phase} step {self.step_count} exceeded its "
                     f"{e.deadline_s:.3f}s deadline", op=phase,
                     deadline_s=e.deadline_s, step=self.step_count) from e
-        row, self._k_pages, self._v_pages, self._tables = out
+        row, self._k_pages, self._v_pages, self._tables, t_launch, t_result = out
         n = row.size - self._moe_experts
         self._expert_counts = row[n:]
-        return row[:n]
+        return row[:n], t_launch, t_result
 
     def _recover_incident(self, err: ServeStepTimeout):
         """In-process recovery from a wedged compiled step: drop the
@@ -671,6 +712,7 @@ class ServingEngine:
         self._step_fn = jax.jit(self._raw_step_fn,
                                 donate_argnums=self._donate)
         self._warm = False          # fresh jit: the first dispatch recompiles
+        self._t_result = None       # and its wait is the compiler's
         self.alloc = self._new_allocator()
         self._k_pages, self._v_pages = init_arena(
             mcfg, cfg.num_blocks, cfg.block_size, dtype=self.dtype)
@@ -725,7 +767,7 @@ class ServingEngine:
     def submit(self, prompt, max_new_tokens: Optional[int] = None,
                slo: str = "standard", temperature: float = 0.0) -> ServeFuture:
         """Queue one request; returns a :class:`ServeFuture`."""
-        with self._span("serve.submit"):
+        with self._span("serve.submit") as sp:
             if temperature:
                 raise NotImplementedError(
                     "serving is greedy-only in this PR (temperature=0)")
@@ -769,6 +811,7 @@ class ServingEngine:
             dl = float((cfg.deadline_ms or {}).get(slo, 0.0) or 0.0)
             if dl > 0.0:
                 req.deadline_at = req.arrival + dl / 1e3
+            sp.set(rid=req.rid)
             self.sched.submit(req)
             fut = ServeFuture(self, req)
             self._futures[req.rid] = fut
@@ -789,12 +832,13 @@ class ServingEngine:
         Returns the step stats.  A wedged compiled dispatch raises
         :class:`ServeStepTimeout` *after* in-process recovery (see
         :meth:`_recover_incident`)."""
+        t_enter = self._clock()
         with self._span("serve.admit") as sp:
             self._expire_deadlines()
             self._update_admission()
             sp.set(admitted=len(self.sched.admit(self._clock())))
         t_step = time.monotonic() if self.registry is not None else 0.0
-        n_chunk, moe_stats = 0, {}
+        n_chunk, moe_stats, turnaround = 0, {}, {}
         table_stats = {"table_edits": 0, "table_reloads": 0, "upload_bytes": 0}
         try:
             with self._span("serve.grow") as sp:
@@ -829,12 +873,6 @@ class ServingEngine:
                     sp.set(**chunk)
                     self._chunk_rows(rows, req, start, n_chunk)
             if decode:
-                # serve_decode_step_ms: a step with decode rows, from their
-                # build to their commit, so the step's ONE program with the
-                # chunk's rows and the chunk's commit; a step with a chunk
-                # alone is in serve_step_ms only
-                t_dec = (time.monotonic() if self.registry is not None
-                         else 0.0)
                 with self._span("serve.decode.build", batch=len(decode)):
                     self._decode_rows(rows, decode)
             if runs:
@@ -842,8 +880,11 @@ class ServingEngine:
                 # rows when it carries any (`batch`: every live row)
                 phase, at = (("decode", {"batch": len(decode) + n_chunk})
                              if decode else ("prefill", chunk))
-                tokens = self._dispatch(phase, packed, reload,
-                                        dict(at, chunk_tokens=n_chunk))
+                tokens, t_launch, t_result = self._dispatch(
+                    phase, packed, reload, dict(at, chunk_tokens=n_chunk))
+                if self._t_result is not None:
+                    turnaround = self._turnaround(t_enter, t_launch, t_result)
+                self._t_result = t_result
                 moe_stats = self._moe_stats()
             if pf is not None:
                 with self._span("serve.prefill.commit", **chunk):
@@ -856,23 +897,37 @@ class ServingEngine:
                     for r in decode:
                         r.prefilled += 1      # the fed token's KV is resident
                         self._append_token(r, int(tokens[r.slot]))
-                if self.registry is not None:
-                    self._h_decode.observe((time.monotonic() - t_dec) * 1e3)
         except ServeStepTimeout as err:
             self._recover_incident(err)
             raise
         # how attention took the step: the queries a row of the chunk held
-        # (0: no chunk in the step) and the rows its calls ran
-        shape_stats = dict(
+        # (0: no chunk in the step) and the rows its calls ran; and the step's
+        # own turn-round, where there was one
+        on_span = dict(
             table_stats,
             chunk_queries_per_row=self.chunk_queries_per_row if n_chunk else 0,
-            attention_rows=self.attention_rows if runs else 0)
-        with self._span("serve.stats", paged_tile_pages=self.paged_tile_pages,
-                        cache_bytes_per_token=self.cache_bytes_per_token,
-                        **shape_stats):
-            stats = self._close_step(len(decode), n_chunk, int(runs), t_step)
-            stats.update(moe_stats, **shape_stats)
-            return stats
+            attention_rows=self.attention_rows if runs else 0, **turnaround)
+        with self._span("serve.stats", **on_span):
+            stats = self._close_step(len(decode), n_chunk, int(runs), t_step,
+                                     dict(moe_stats, **on_span))
+        if not runs or not self.sched.has_work:
+            self._t_result = None       # from here the chip waits for WORK
+        self._t_exit = self._clock()
+        return stats
+
+    def _turnaround(self, t_enter: float, t_launch: float,
+                    t_result: float) -> Dict[str, float]:
+        """:data:`TURNAROUND_STATS` of the step that launched a program at
+        ``t_launch`` after the one whose row came back at ``self._t_result``."""
+        before = self._t_result
+        out = {"turnaround_ms": (t_launch - before) * 1e3,
+               "commit_ms": (self._t_exit - before) * 1e3,
+               "outside_ms": (t_enter - self._t_exit) * 1e3,
+               "prepare_ms": (t_launch - t_enter) * 1e3,
+               "result_wait_ms": (t_result - t_launch) * 1e3}
+        if self.registry is not None:
+            self._h_turnaround.observe(out["turnaround_ms"])
+        return out
 
     def _moe_stats(self) -> Dict[str, float]:
         """How the last program's live rows spread over the experts the
@@ -889,9 +944,12 @@ class ServingEngine:
                 "moe_assignments_held": int(counts[first:first + held].sum())}
 
     def _close_step(self, decode_batch: int, prefill_tokens: int,
-                    programs: int, t_step: float) -> Dict[str, Any]:
+                    programs: int, t_step: float,
+                    of_the_program: Dict[str, Any]) -> Dict[str, Any]:
         """What a clean step ends with: the incident latch, the ledger, the
-        stats dict, gauges and the periodic ``serve_step`` record."""
+        stats dict (``of_the_program``: what ``step()`` counted and timed of
+        its program, in it and so in the record), gauges and the periodic
+        ``serve_step`` record."""
         if self._incident is not None:
             # first clean step after an incident: release the latch
             self._emit("serve_incident", {
@@ -910,7 +968,8 @@ class ServingEngine:
                      shed_level=self.admission.level,
                      incidents=self.incident_count,
                      paged_tile_pages=self.paged_tile_pages,
-                     elapsed_ms=(time.monotonic() - self._started) * 1000.0)
+                     elapsed_ms=(time.monotonic() - self._started) * 1000.0,
+                     **of_the_program)
         if self.tiering is not None:
             stats.update(self.tiering.stats())
         if self.prefix is not None:

@@ -154,7 +154,7 @@ class Watch:
                                  eng._k_pages, eng._v_pages,
                                  *(tuple(map(jnp.asarray, x)) for x in want[2:4]),
                                  jnp.asarray(want[4]))
-        tokens = self._dispatch(phase, packed, reload, stats)
+        tokens, *stamps = self._dispatch(phase, packed, reload, stats)
         if self.parent is not None:
             # the rows that carry a request: a chunk's rows past its tokens
             # are computed for nobody (packed beside live queries they see
@@ -165,7 +165,7 @@ class Watch:
                 err_msg=f"tokens, step {self.steps}")
         self.steps += 1
         self.reloads += reload is not None
-        return tokens
+        return (tokens, *stamps)
 
     def step(self):
         st = self.eng.step()

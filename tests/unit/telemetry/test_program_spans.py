@@ -4,6 +4,7 @@ configured, never touches the traced program, and the serving engine tiles
 its step with them and stamps a request where things happen."""
 
 import glob
+import json
 import os
 import time
 
@@ -14,7 +15,7 @@ import pytest
 
 from deepspeed_tpu.models.gpt import GPT, GPTConfig
 from deepspeed_tpu.serving import DeepSpeedServingConfig, ServingEngine
-from deepspeed_tpu.serving.engine import SERVE_STEP_SPANS
+from deepspeed_tpu.serving.engine import SERVE_STEP_SPANS, TURNAROUND_STATS
 from deepspeed_tpu.telemetry import Tracer, maybe_span, set_global_tracer
 
 
@@ -219,7 +220,8 @@ def test_spans_carry_counts_where_the_work_happens(tiny_model):
     eng = _engine(tiny_model, tracer=tr)
     fut = eng.submit(list(range(1, 13)), max_new_tokens=3)
     (submitted,) = tr.snapshot()
-    assert (submitted["name"], submitted["args"]) == ("serve.submit", None)
+    assert (submitted["name"], submitted["args"]) == (
+        "serve.submit", {"rid": fut.request.rid}), "joins the request's spans"
     by, stats = _step_spans(tr, eng)            # a chunk, and no decode row yet
     assert by["serve.admit"] == [{"admitted": 1}]
     chunk = {"rid": fut.request.rid, "start": 0, "tokens": 8}
@@ -246,23 +248,230 @@ def test_spans_carry_counts_where_the_work_happens(tiny_model):
     assert by["serve.prefill.build"] == [None]
     assert not any(n in by for n in ("serve.prefill.dispatch", "serve.prefill.fetch",
                                      "serve.prefill.commit"))
-    # which paged kernel the engine runs (0: the einsum, as on this CPU), the
-    # bytes the arena holds a token a layer (the cache spec's arrays), and
     # what the step's tables cost: the one upload, no entry changed (the
-    # request was handed its two blocks at admission), nothing reloaded;
-    # no chunk in this step, so no queries a row of one, and the same rows
+    # request was handed its two blocks at admission), nothing reloaded; no
+    # chunk in this step, so no queries a row of one, and the same rows; and
+    # the step's turn-round, since the step before ran a program.  The
+    # engine's constants (which paged kernel it runs, the bytes the arena
+    # holds a token a layer) are attributes of the engine and not written on
+    # every step's event; the first is in ``step()``'s stats
     table = {"table_edits": 0, "table_reloads": 0,
              "upload_bytes": 4 * eng._layout.packed_size}
-    assert by["serve.stats"] == [dict(table, paged_tile_pages=eng.paged_tile_pages,
-                                      cache_bytes_per_token=eng.cache_bytes_per_token,
-                                      chunk_queries_per_row=0, attention_rows=5)]
+    (on_span,) = by["serve.stats"]
+    assert on_span == dict(table, chunk_queries_per_row=0, attention_rows=5,
+                           **{k: stats[k] for k in TURNAROUND_STATS})
     assert {k: stats[k] for k in table} == table
+    assert stats["paged_tile_pages"] == eng.paged_tile_pages == 0   # the einsum
+    assert eng.cache_bytes_per_token == 2 * 32 * 4      # K and V, 32 lanes, f32
     assert (stats["programs"], stats["prefill_tokens"], stats["decode_batch"]) == (1, 0, 1)
     fut.result()
     idle = eng.step()                           # nothing to run: no program,
     assert (idle["programs"], idle["upload_bytes"]) == (0, 0)       # no upload
     assert (idle["chunk_queries_per_row"], idle["attention_rows"]) == (0, 0)
     eng.close()
+
+
+# ---- the step's own turn-round, as durations on the engine's one clock --------- #
+class _Ticks:
+    """A clock that reads 1, 2, 3 ...: every duration is a count of the
+    reads between two stamps, exact in floating point."""
+
+    def __init__(self):
+        self.now = 0.0
+
+    def __call__(self):
+        self.now += 1.0
+        return self.now
+
+
+def _turnaround(stats):
+    return {k: stats[k] for k in TURNAROUND_STATS if k in stats}
+
+
+def _stamped(eng):
+    """``eng._dispatch`` wrapped to keep each program's (t_launch, t_result)."""
+    kept, inner = [], eng._dispatch
+
+    def dispatch(*args):
+        out = inner(*args)
+        kept.append(out[1:])
+        return out
+    eng._dispatch = dispatch
+    return kept
+
+
+def test_turnaround_parts_sum_to_the_time_between_two_results(tiny_model):
+    eng = _engine(tiny_model)
+    eng._clock = _Ticks()
+    stamps = _stamped(eng)
+    eng.submit(list(range(1, 20)), max_new_tokens=6)
+    assert _turnaround(eng.step()) == {}, "the first step: nothing to turn round from"
+    for _ in range(6):                         # chunks, then decode rows
+        t = _turnaround(eng.step())
+        assert set(t) == set(TURNAROUND_STATS)
+        (_, result_before), (launch, result) = stamps[-2:]
+        assert t["commit_ms"] + t["outside_ms"] + t["prepare_ms"] == t["turnaround_ms"]
+        assert t["turnaround_ms"] == (launch - result_before) * 1e3
+        assert t["result_wait_ms"] == (result - launch) * 1e3 == 1e3    # one read on
+        assert sum(t.values()) - t["turnaround_ms"] == (result - result_before) * 1e3
+        assert min(t.values()) > 0.0
+    eng.close()
+
+
+def test_time_between_two_steps_is_outside_and_nowhere_else(tiny_model):
+    """What the caller does between two ``step()`` calls (here: reads of the
+    clock, as ``submit()`` makes one for a request's arrival) is
+    ``outside_ms``; no other part sees it."""
+    eng = _engine(tiny_model)
+    clock = eng._clock = _Ticks()
+    eng.submit(list(range(1, 8)), max_new_tokens=12)
+    eng.step()
+    eng.step()
+    plain = _turnaround(eng.step())
+    assert plain["outside_ms"] == 1e3           # t_exit to t_enter: one read on
+    clock.now += 5.0                            # the caller sleeps 5 ticks
+    slept = _turnaround(eng.step())
+    assert slept["outside_ms"] == plain["outside_ms"] + 5e3
+    assert slept["turnaround_ms"] == plain["turnaround_ms"] + 5e3
+    for k in ("commit_ms", "prepare_ms", "result_wait_ms"):
+        assert slept[k] == plain[k], k
+    eng.close()
+
+
+def test_a_sleep_between_two_steps_lands_in_outside_on_the_real_clock(tiny_model):
+    eng = _engine(tiny_model)
+    eng.submit(list(range(1, 8)), max_new_tokens=12)
+    for _ in range(3):
+        eng.step()
+    time.sleep(0.05)
+    t = _turnaround(eng.step())
+    assert 50.0 <= t["outside_ms"] <= t["turnaround_ms"]
+    assert t["commit_ms"] + t["prepare_ms"] + t["result_wait_ms"] < 50.0
+    assert t["commit_ms"] + t["outside_ms"] + t["prepare_ms"] == pytest.approx(
+        t["turnaround_ms"], abs=1e-6)
+    eng.close()
+
+
+def test_a_step_the_chip_waited_for_work_before_carries_no_turnaround(tiny_model):
+    """The first step, every step that runs no program, and the step after
+    one that left the engine with no request, whether or not an empty step
+    lies between: the chip waited for work there, not for the host."""
+    tr = Tracer()
+    eng = _engine(tiny_model, tracer=tr)
+    fut = eng.submit([1, 2, 3], max_new_tokens=3)
+    by, first = _step_spans(tr, eng)
+    assert _turnaround(first) == {} and not set(by["serve.stats"][0]) & set(TURNAROUND_STATS)
+    assert set(_turnaround(eng.step())) == set(TURNAROUND_STATS)
+    fut.result()
+    empty = eng.step()                          # nothing to run
+    assert empty["programs"] == 0 and _turnaround(empty) == {}
+    eng.submit([4, 5, 6], max_new_tokens=3)
+    by, after_empty = _step_spans(tr, eng)
+    assert after_empty["programs"] == 1 and _turnaround(after_empty) == {}
+    assert not set(by["serve.stats"][0]) & set(TURNAROUND_STATS)
+    assert set(_turnaround(eng.step())) == set(TURNAROUND_STATS)
+    eng.run()                                   # the last step had a program,
+    assert not eng.sched.has_work               # and left nothing to turn round to
+    eng.submit([7, 8, 9], max_new_tokens=3)     # a server: minutes later
+    assert _turnaround(eng.step()) == {}
+    assert set(_turnaround(eng.step())) == set(TURNAROUND_STATS)
+    eng.close()
+
+
+def test_the_first_step_after_an_incident_carries_no_turnaround(tiny_model):
+    from deepspeed_tpu.serving.engine import ServeStepTimeout
+    eng = _engine(tiny_model)
+    fut = eng.submit(list(range(1, 8)), max_new_tokens=8)
+    eng.step()
+    assert _turnaround(eng.step())
+    eng._recover_incident(ServeStepTimeout("wedged", op="decode", deadline_s=1.0,
+                                           step=eng.step_count))
+    assert _turnaround(eng.step()) == {}, "the re-jit's compile is no turn-round"
+    assert set(_turnaround(eng.step())) == set(TURNAROUND_STATS)
+    assert fut.result() is not None
+    eng.close()
+
+
+@pytest.mark.parametrize("timeout_s", [0.0, 30.0], ids=["inline", "bounded-worker"])
+def test_turnaround_is_on_the_main_threads_stats_span(tiny_model, timeout_s):
+    """With ``serve_step_timeout_s`` the dispatch and the fetch run on the
+    bounded worker's thread, their spans on its line; the stamps are taken
+    there and carried to ``serve.stats``, which the main thread opens: the
+    same keys, in ``step()``'s stats and on the span, value for value."""
+    import threading
+    tr = Tracer()
+    eng = _engine(tiny_model, tracer=tr, serve_step_timeout_s=timeout_s)
+    eng.submit(list(range(1, 12)), max_new_tokens=4)
+    eng.step()                                  # compiles, inline either way
+    for _ in range(3):
+        mark = len(tr.snapshot())
+        stats = eng.step()
+        recs = {r["name"]: r for r in tr.snapshot()[mark:]}
+        fetch = next(r for n, r in recs.items() if n.endswith(".fetch"))
+        main = threading.get_ident()
+        assert (fetch["tid"] != main) == bool(timeout_s)
+        assert recs["serve.stats"]["tid"] == main
+        on_span = recs["serve.stats"]["args"]
+        assert {k: on_span[k] for k in TURNAROUND_STATS} == _turnaround(stats)
+        assert set(_turnaround(stats)) == set(TURNAROUND_STATS)
+        # the fetch span lies inside the wait, whichever thread opened it
+        assert (fetch["t1"] - fetch["t0"]) / 1e6 <= stats["result_wait_ms"]
+    eng.close()
+
+
+def test_turnaround_is_on_the_profilers_line_a_step(tiny_model, tmp_path):
+    from jax.profiler import ProfileData
+    eng = _engine(tiny_model)
+    fut = eng.submit(list(range(1, 12)), max_new_tokens=4)
+    eng.step()
+    seen = []
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        while not fut.done:
+            seen.append(_turnaround(eng.step()))
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(os.path.join(str(tmp_path), "plugins/profile/*/*.xplane.pb"))[-1]
+    host = ProfileData.from_file(path).find_plane_with_name("/host:CPU")
+    events = sorted((e.start_ns, dict(e.stats)) for line in host.lines
+                    for e in line.events if e.name == "serve.stats")
+    assert len(events) == len(seen) >= 4
+    for (_, on_span), stats in zip(events, seen):
+        assert {k: on_span[k] for k in TURNAROUND_STATS} == pytest.approx(stats)
+        assert not {"paged_tile_pages", "cache_bytes_per_token"} & set(on_span)
+    eng.close()
+
+
+def test_registry_times_the_turnaround_and_no_decode_step(tiny_model, tmp_path):
+    """One histogram for one: ``serve_turnaround_ms`` where
+    ``serve_decode_step_ms`` was, observed in every step that carries the
+    stats; ``serve_step_ms`` as before."""
+    from deepspeed_tpu.runtime.config import DeepSpeedTelemetryConfig
+    from deepspeed_tpu.telemetry import TelemetryHub
+    hub = TelemetryHub.from_config(DeepSpeedTelemetryConfig(
+        enabled=True, jsonl_path=str(tmp_path / "t.jsonl"), flush_every=2))
+    model, params = tiny_model
+    eng = ServingEngine(model, params=params, telemetry=hub,
+                        config=DeepSpeedServingConfig(
+                            block_size=8, num_blocks=64, max_batch_size=4,
+                            prefill_chunk=8, dtype="float32", telemetry_every=2))
+    fut = eng.submit(list(range(1, 12)), max_new_tokens=4)
+    turns = []
+    while not fut.done:
+        turns.append(_turnaround(eng.step()))
+    hists = hub.registry.snapshot()["histograms"]
+    assert "serve_decode_step_ms" not in hists
+    assert hists["serve_step_ms"]["count"] == len(turns)
+    assert hists["serve_turnaround_ms"]["count"] == len(turns) - 1
+    assert hists["serve_turnaround_ms"]["sum"] == pytest.approx(
+        sum(t["turnaround_ms"] for t in turns[1:]))
+    # the periodic serve_step record carries the stats to an operator
+    hub.flush()
+    records = [json.loads(l) for l in open(tmp_path / "t.jsonl")]
+    steps = [r for r in records if r.get("kind") == "serve_step"]
+    assert steps and all(set(TURNAROUND_STATS) <= set(r) for r in steps)
+    eng.close()
+    hub.close()
 
 
 @pytest.mark.parametrize("wide", [16, 4])
