@@ -87,6 +87,7 @@ def run(cell, args, ctx):
     done = [s for s in srv.sent if s.request is not None
             and s.request.finished_at is not None and s.request.finished_at >= t0]
     ran_dry = not srv.has_work         # then the slots did not stay full
+    queue_left = srv.engine.sched.stats()["queue_depth"]
     short = sum(len(s.request.generated) != s.max_new for s in done)
     refused = sum(s.refused for s in srv.sent)
     checked, wrong, worst = srv.check_sample(
@@ -103,8 +104,11 @@ def run(cell, args, ctx):
         "attempted": len(done) + refused, "failed": wrong + short + refused,
         "end_to_end": {"serve_tokens_per_s": tokens / span_s},
         "counters": counters, "trace": trace,
+        "compared": {"largest_logit_gap": [worst, TIE_TOL], "tokens_wrong": [wrong, 0],
+                     "requests_short_of_their_tokens": [short, 0],
+                     "backlog_ran_dry": [int(ran_dry), 0]},
         "notes": {"checked": checked, "wrong": wrong, "largest_logit_gap": worst,
                   "tie_tolerance": TIE_TOL, "window_s": span_s, "tokens": tokens,
-                  "backlog_ran_dry": ran_dry,
+                  "backlog_ran_dry": ran_dry, "queue_left": queue_left,
                   "slow_steps": srv.slow_steps(steps, t0)},
     }

@@ -207,7 +207,8 @@ def attention_counters(srv, snaps, steps):
         positions, idle, layers, srv.block, srv.lanes, mcfg.n_head,
         mcfg.head_dim, srv.params["wte"].dtype.itemsize)
     return {"paged_gqa_flops": flops, "paged_gqa_bytes": nbytes,
-            "attention_rows_live": len(positions), "attention_rows_idle": idle}
+            "attention_rows_live": len(positions), "attention_rows_idle": idle,
+            "traced_step_rows": Serving.step_rows(steps)}
 
 
 def check_sample(model, params, reference, samples):
@@ -260,6 +261,15 @@ def check_sample(model, params, reference, samples):
     return dict(out, checked=len(samples), wrong=int(wrong), noise_scale_median=median)
 
 
+def compared(check, short, refused, ran_dry, filled, logit_margin, noise_limit):
+    """Each number the run is held to, beside its limit."""
+    return {"largest_logit_gap": [max(check["largest"]), logit_margin],
+            "noise_scale_median": [check.get("noise_scale_median"), noise_limit],
+            "requests_wrong": [check["wrong"], 0],
+            "requests_short_of_their_tokens": [short, 0], "requests_refused": [refused, 0],
+            "backlog_ran_dry": [int(ran_dry), 0], "cohort_not_filled": [int(not filled), 0]}
+
+
 def run(cell, args, ctx):
     mix = cell.traffic
     srv = Resident(cell, args, ctx)
@@ -304,6 +314,7 @@ def run(cell, args, ctx):
     done = [s for s in srv.sent if s.request is not None
             and s.request.finished_at is not None and s.request.finished_at >= t0]
     ran_dry = not srv.has_work         # then the slots did not stay full
+    queue_left = srv.engine.sched.stats()["queue_depth"]
     short = sum(len(s.request.generated) != s.max_new for s in done)
     refused = sum(s.refused for s in srv.sent)
     counters = dict(srv.step_counters(steps),
@@ -344,6 +355,8 @@ def run(cell, args, ctx):
         "attempted": attempted, "failed": wrong + short + refused,
         "end_to_end": {"serve_tokens_per_s": tokens / span_s},
         "counters": counters, "trace": trace,
+        "compared": compared(check, short, refused, ran_dry, filled,
+                             LOGIT_MARGIN, NOISE_LIMIT),
         "notes": {"checked": checked, "wrong": wrong,
                   "largest_logit_gap": max(check["largest"]),
                   "logit_gaps": check["largest"], "tie_tolerance": LOGIT_MARGIN,
@@ -352,6 +365,7 @@ def run(cell, args, ctx):
                   "mean_logit_gaps": check.get("mean"),
                   "not_the_references_best_share": check.get("share"),
                   "window_s": span_s, "tokens": tokens,
-                  "backlog_ran_dry": ran_dry, "cohort_filled": filled,
+                  "backlog_ran_dry": ran_dry, "queue_left": queue_left,
+                  "cohort_filled": filled,
                   "slow_steps": slow},
     }

@@ -62,6 +62,10 @@ def run(cell, args, ctx):
     wrong = judge(notes["logit_gaps"], notes["noise_scales"],
                   notes["noise_scale_median"])
     notes.update(wrong=wrong, tie_tolerance=LOGIT_MARGIN, noise_limit=NOISE_LIMIT)
+    out.setdefault("compared", {}).update(
+        largest_logit_gap=[max(notes["logit_gaps"]), LOGIT_MARGIN],
+        noise_scale_median=[notes["noise_scale_median"], NOISE_LIMIT],
+        requests_wrong=[wrong, 0])
     out.update(failed=wrong + other,
                correct=(wrong == 0 and other == 0 and not notes["backlog_ran_dry"]
                         and notes["cohort_filled"]))

@@ -152,6 +152,7 @@ def run(cell, args, ctx):
         "end_to_end": {"ttft_p90_ms": float(np.percentile(ttft, 90)),
                        "tpot_p90_ms": float(np.percentile(tpot, 90))},
         "counters": counters, "trace": trace,
+        "compared": {"largest_logit_gap": [worst, TIE_TOL], "tokens_wrong": [wrong, 0]},
         "notes": {"checked": checked, "wrong": wrong, "largest_logit_gap": worst,
                   "tie_tolerance": TIE_TOL, "rate_per_s": float(mix["rate_per_s"]),
                   "requests_with_ttft": len(ttft), "requests_with_tpot": len(tpot),

@@ -141,6 +141,11 @@ def run(cell, args, ctx):
                             "head_dim": mcfg.head_dim},
         },
         "trace": trace,
+        "compared": {"first_loss_minus_reference_abs": [abs(losses[0] - ref_loss),
+                                                        float(mix["reference_tolerance"])],
+                     "first_loss_above_ln_vocab": [losses[0] - math.log(vocab),
+                                                   list(FIRST_LOSS_BAND)],
+                     "loss_fall_at_least": [losses[0] - last, tol]},
         "notes": {"checks": checks, "warmup_losses": losses, "last_loss": last,
                   "reference_first_loss": ref_loss,
                   "first_loss_minus_reference": losses[0] - ref_loss,

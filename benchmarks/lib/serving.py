@@ -176,11 +176,18 @@ class Serving:
         return (flops + idle_rows * f) * mcfg.n_layer, \
             (nbytes + idle_rows * b) * mcfg.n_layer
 
+    @staticmethod
+    def step_rows(steps):
+        """The live rows (decode rows + prompt tokens) of each step."""
+        return [int(st[2] + st[3]) for st in steps]
+
     def paged_counters(self, snaps, steps):
-        """``paged_model`` over the traced stretch, as counters."""
+        """``paged_model`` over the traced stretch, as counters, and the
+        stretch's rows a step (``readers/step_share.py``)."""
         n_dec = sum(1 for st in steps if st[2] > 0)
         flops, nbytes = self.paged_model(snaps["before"], snaps["after"], n_dec)
-        return {"paged_flops": flops, "paged_bytes": nbytes}
+        return {"paged_flops": flops, "paged_bytes": nbytes,
+                "traced_step_rows": self.step_rows(steps)}
 
     def check_sample(self, candidates, k, rng):
         """A seeded sample of ``k`` requests with tokens, held to the plain

@@ -24,7 +24,7 @@ import re
 
 import numpy as np
 
-OPS_LINE, ASYNC_LINE = "XLA Ops", "Async XLA Ops"
+OPS_LINE, ASYNC_LINE, MODULES_LINE = "XLA Ops", "Async XLA Ops", "XLA Modules"
 COLLECTIVE = re.compile(
     r"^(all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute|"
     r"collective-broadcast|ragged-all-to-all)")
@@ -108,8 +108,9 @@ class DeviceTrace:
     """One chip's part of a trace, cut to the window between its first and
     last op."""
 
-    def __init__(self, plane_name, ops, asyncs):
+    def __init__(self, plane_name, ops, asyncs, program_runs=None):
         self.name = plane_name
+        self.program_runs = program_runs    # events of ``XLA Modules``; None: no such line
         self.ops = self_times([(op_name(n), s, d) for n, s, d in ops])
         self.asyncs = [(op_name(n), s, s + d) for n, s, d in asyncs]
         self.start = min(o[1] for o in self.ops)
@@ -169,7 +170,8 @@ class Trace:
                 if not ops:
                     continue
                 asyncs = _events(lines[ASYNC_LINE]) if ASYNC_LINE in lines else []
-                devices.append(DeviceTrace(plane.name, ops, asyncs))
+                runs = sum(1 for _ in lines[MODULES_LINE].events) if MODULES_LINE in lines else None
+                devices.append(DeviceTrace(plane.name, ops, asyncs, runs))
             elif plane.name == "/host:CPU":
                 # the thread that carries the benchmark's spans is the one
                 # that drives the program: its other events say what the
@@ -194,6 +196,11 @@ class Trace:
 
     def idle_share(self):
         return 1.0 - self.busy_s() / self.window_s()
+
+    def program_runs(self):
+        """Program runs the first chip's part of the trace holds (one event
+        of ``XLA Modules`` each), or None where it has no such line."""
+        return self.devices[0].program_runs
 
     def op_seconds(self):
         """name -> seconds of self time, averaged over the chips."""

@@ -1,6 +1,9 @@
 """Readers of the reduced device trace (``benchmarks/lib/trace.py``).  Every
-one returns None where the run was not traced, or the trace holds nothing of
-the kind."""
+one returns None where the run was not traced.  In a traced run a SHARE of
+the device's time is a number whatever the trace holds: an op that a change
+took out of the program reads 0.0, so the change shows as a gain and the line
+keeps the metric.  A quotient by a time (a roofline) has no value where its
+kernel took none, and stays None there."""
 
 from benchmarks.lib import arith
 
@@ -11,13 +14,13 @@ def device_idle_pct(run):
 
 
 def op_share_pct(run, ops):
-    """Self time of the named ops over the time the device was busy."""
+    """Self time of the named ops over the time the device was busy: 0.0
+    where the traced run holds no op of the names, None without a trace."""
     t = run["trace"]
     if t is None:
         return None
     secs = t.op_seconds()
-    found = [secs[o] for o in ops if o in secs]
-    return 100.0 * sum(found) / t.busy_s() if found else None
+    return 100.0 * sum(secs[o] for o in ops if o in secs) / t.busy_s()
 
 
 def collective_share_pct(run):
@@ -53,17 +56,26 @@ def flash_roofline(run, kernels):
     return 100.0 * least / took if took else None
 
 
-def paged_attention_roofline(run):
-    """The least time for the operations and bytes the paged kernel needed
-    over the traced window (``Serving.paged_model``) over its time there."""
-    t = run["trace"]
+def paged_work(run):
+    """(operations, bytes) the paged kernel needed over the traced stretch,
+    whichever kernel did it (``Serving.paged_model``); None without them."""
     c = run["counters"]
-    if t is None or "paged_bytes" not in c:
+    return (c["paged_flops"], c["paged_bytes"]) if "paged_bytes" in c else None
+
+
+def paged_attention_roofline(run, kernels=("paged_attention",)):
+    """The least time for the operations and bytes paged attention needed
+    over the traced window (``paged_work``) over the time there of the
+    ``kernels`` that do it, summed: the count is of the work, so the metric
+    outlives a kernel that another of the names replaces."""
+    t = run["trace"]
+    work = paged_work(run)
+    if t is None or work is None:
         return None
-    took = t.op_seconds().get("paged_attention")
+    secs = t.op_seconds()
+    took = sum(secs[k] for k in kernels if k in secs)
     if not took:
         return None
-    bound_s, which = arith.roofline_seconds(c["paged_flops"], c["paged_bytes"],
-                                            run["peaks"])
-    run["notes"].setdefault("roofline_bound", {})["paged_attention"] = which
+    bound_s, which = arith.roofline_seconds(*work, run["peaks"])
+    run["notes"].setdefault("roofline_bound", {})[kernels[0]] = which
     return 100.0 * bound_s / took

@@ -8,10 +8,11 @@ suffix are the compiler's and change with the program), and
 
 def share_pct(run, ops):
     """Self time of the ops named ``ops`` or ``<one of ops>.<suffix>`` over
-    the time the device was busy; None where the trace holds none of them."""
+    the time the device was busy: 0.0 where the traced run holds none of
+    them (a change took the op out of the program), None without a trace."""
     t = run["trace"]
     if t is None:
         return None
     found = [s for name, s in t.op_seconds().items()
              if any(name == o or name.startswith(o + ".") for o in ops)]
-    return 100.0 * sum(found) / t.busy_s() if found else None
+    return 100.0 * sum(found) / t.busy_s()

@@ -12,16 +12,23 @@ from benchmarks.lib import arith
 KERNEL = "paged_gqa_attention"
 
 
+def work(run):
+    """(operations, bytes) the kernel needed over the traced stretch, the
+    kind's count; None without it."""
+    c = run["counters"]
+    return (c["paged_gqa_flops"], c["paged_gqa_bytes"]) if "paged_gqa_bytes" in c else None
+
+
 def roofline(run):
     """The least time for the operations and bytes the kernel needed over
     the traced stretch over its time there."""
-    t, c = run["trace"], run["counters"]
-    if t is None or "paged_gqa_bytes" not in c:
+    t = run["trace"]
+    needed = work(run) if t is not None else None
+    if needed is None:
         return None
     took = t.op_seconds().get(KERNEL)
     if not took:
         return None
-    bound_s, which = arith.roofline_seconds(c["paged_gqa_flops"], c["paged_gqa_bytes"],
-                                            run["peaks"])
+    bound_s, which = arith.roofline_seconds(*needed, run["peaks"])
     run["notes"].setdefault("roofline_bound", {})[KERNEL] = which
     return 100.0 * bound_s / took

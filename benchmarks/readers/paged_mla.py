@@ -13,24 +13,33 @@ from benchmarks.lib import arith, arith_mla
 KERNEL = "paged_mla_attention"
 
 
-def roofline(run):
-    """The least time for the operations and bytes the kernel needed over
-    the traced stretch over its time there."""
+def work(run):
+    """(operations, bytes) latent attention needed over the traced stretch:
+    the kind's count of keys and rows at ``lib/arith_mla.py``'s cost of a
+    key; None without the count or on a model with K and V."""
     import jax.numpy as jnp
-    t, c = run["trace"], run["counters"]
-    if t is None or "paged_gqa_flops" not in c:
-        return None
-    took = t.op_seconds().get(KERNEL)
-    cfg = run["cell"].config
-    if not took or "kv_lora_rank" not in cfg:
+    c, cfg = run["counters"], run["cell"].config
+    if "paged_gqa_flops" not in c or "kv_lora_rank" not in cfg:
         return None
     layers = cfg["num_hidden_layers"]
     keys = arith_mla.keys_read(c["paged_gqa_flops"], cfg["num_attention_heads"],
                                cfg["head_dim"])
     rows = layers * (c["attention_rows_live"] + c["attention_rows_idle"])
-    flops, nbytes = arith_mla.latent_attention(
+    return arith_mla.latent_attention(
         keys, rows, cfg["num_attention_heads"], cfg["kv_lora_rank"],
         cfg["qk_rope_head_dim"], jnp.dtype(cfg["dtype"]).itemsize)
-    bound_s, which = arith.roofline_seconds(flops, nbytes, run["peaks"])
+
+
+def roofline(run):
+    """The least time for the operations and bytes the kernel needed over
+    the traced stretch over its time there."""
+    t = run["trace"]
+    needed = work(run) if t is not None else None
+    if needed is None:
+        return None
+    took = t.op_seconds().get(KERNEL)
+    if not took:
+        return None
+    bound_s, which = arith.roofline_seconds(*needed, run["peaks"])
     run["notes"].setdefault("roofline_bound", {})[KERNEL] = which
     return 100.0 * bound_s / took

@@ -6,8 +6,10 @@ Runs one cell of ``BENCHMARK.json`` on the machine it is started on, which
 must hold the TPU chips the cell asks for, and prints as the LAST line of its
 standard output one JSON object: ``correct``, ``attempted``, ``failed``,
 ``metrics`` (the cell's end-to-end metrics with ``--trace 0``, its per-layer
-metrics with ``--trace 1``) and ``device``.  The line before it splits
-``setup_s`` into its phases.  ``--rehearse`` runs the cell's control flow at a
+metrics with ``--trace 1``) and ``device``, and last in it ``compared``: each
+number the comparison that decides ``correct`` held to a limit, as ``[number,
+limit]``, which are also the last lines on standard error.  The line before
+it splits ``setup_s`` into its phases.  ``--rehearse`` runs the cell's control flow at a
 tiny size on the CPU and prints no device metric; ``--set key=value``
 overrides one number of the traffic file for a sweep by hand.  The driver
 passes neither.
@@ -38,6 +40,16 @@ def parse(argv):
     ap.add_argument("--rehearse", action="store_true")
     ap.add_argument("--set", action="append", default=[], metavar="KEY=VALUE")
     return ap.parse_args(argv)
+
+
+def emit(line, result):
+    """The result's line, ``compared`` last in it; then each number compared
+    beside its limit as the last lines of standard error."""
+    compared = result.get("compared", {})
+    print(json.dumps(dict(line, compared=compared)), flush=True)
+    for name, (value, limit) in compared.items():
+        print(f"compared {name} {value} limit {limit}", file=sys.stderr, flush=True)
+    return 0
 
 
 def main(argv=None):
@@ -105,8 +117,7 @@ def main(argv=None):
                                      "requests_measured", "finished_in_window")},
                     would_report=sorted(m["name"] for m in
                                         cell.end_to_end + cell.per_layer))
-        print(json.dumps(line), flush=True)
-        return 0
+        return emit(line, result)
     device = dict(device, memory_peak_bytes=result["counters"]["memory_peak_bytes"])
     if args.trace:
         trace = result["trace"]
@@ -122,8 +133,7 @@ def main(argv=None):
         line.update(metrics={m["name"]: {"value": float(values[m["name"]]),
                                          "unit": m["unit"]}
                              for m in cell.end_to_end}, device=device)
-    print(json.dumps(line), flush=True)
-    return 0
+    return emit(line, result)
 
 
 if __name__ == "__main__":

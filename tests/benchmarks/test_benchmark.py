@@ -78,7 +78,7 @@ def test_trace_readers_return_nothing_where_there_is_nothing(synthetic):
     run["trace"] = synthetic
     assert rd.device_idle_pct(run) == pytest.approx(25.0)
     assert rd.op_share_pct(run, ops=["paged_attention"]) == pytest.approx(60.0)
-    assert rd.op_share_pct(run, ops=["flash_fwd"]) is None
+    assert rd.op_share_pct(run, ops=["flash_fwd"]) == 0.0    # a traced run without the op
     assert rd.collective_exposed_pct(run) == pytest.approx(35.0)
 
 
@@ -345,6 +345,13 @@ def test_rehearsal_prints_a_last_line_of_the_contracts_shape(workload):
     assert done.returncode == 0, done.stderr[-2000:]
     line = json.loads(done.stdout.strip().splitlines()[-1])
     assert {"correct", "attempted", "failed", "metrics", "device"} <= set(line)
+    # each number compared beside its limit: last in the line, and the last
+    # lines of standard error
+    assert list(line)[-1] == "compared" and line["compared"]
+    assert all(len(pair) == 2 for pair in line["compared"].values())
+    last = done.stderr.strip().splitlines()[-len(line["compared"]):]
+    assert [l.split()[1] for l in last] == list(line["compared"])
+    assert all(l.startswith("compared ") and " limit " in l for l in last)
     assert line["correct"] is True and line["failed"] == 0 and line["attempted"] > 0
     assert line["device"]["platform"] == "cpu" and line["rehearsal"] is True
     assert line["metrics"] == {}, "a CPU run carries no device metric"

@@ -258,6 +258,10 @@ def test_the_bank_through_float8_is_refused_by_the_noise_limit_alone(monkeypatch
     out = _judged(monkeypatch, *FLOAT8)
     assert out["notes"]["wrong"] == 8 and out["correct"] is False
     assert max(FLOAT8[0]) < kind.LOGIT_MARGIN              # not by each limit
+    # each number beside the limit it was held to, this kind's
+    assert out["compared"]["largest_logit_gap"] == [2.88, kind.LOGIT_MARGIN]
+    assert out["compared"]["noise_scale_median"][1] == kind.NOISE_LIMIT
+    assert out["compared"]["requests_wrong"] == [8, 0]
     assert 2 * max(BF16[1]) < kind.NOISE_LIMIT < min(FLOAT8[1]) / 1.3
     # one noisy request does not fail a run whose median is sound
     scales = list(BF16[1])
