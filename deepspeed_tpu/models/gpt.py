@@ -742,34 +742,42 @@ def _latent_plain_qkv(cfg: "GPTConfig", p: Dict, q: Array, cache: Array, dt):
     return q, k, jnp.einsum("bsr,rhd->bshd", c, w_uv)
 
 
-def _wget(p: Dict, key: str, dt) -> Array:
-    """Weight fetch that transparently dequantizes int8-injected params
+def _wleaf(w, dt) -> Array:
+    """A weight leaf as the matmul reads it: an int8-injected one
     (``module_inject/quantization.py``; reference GroupQuantizer +
-    ``dequantize.cu``) — same model code serves fp and int8 weights."""
+    ``dequantize.cu``) dequantized, so the same model code serves fp and
+    int8 weights."""
     from deepspeed_tpu.module_inject.quantization import (dequantize_weight,
                                                           is_quantized_leaf)
-    w = p[key]
     if is_quantized_leaf(w):
         return dequantize_weight(w, dt)
     return w.astype(dt)
 
 
-def _mlp(cfg: "GPTConfig", p: Dict, h: Array, dt, matmul=jnp.matmul,
+def _wget(p: Dict, key: str, dt) -> Array:
+    return _wleaf(p[key], dt)
+
+
+def _mlp(cfg: "GPTConfig", p: Dict, h: Array, dt, matmul=None,
          bias=lambda b: b) -> Array:
     """The block's MLP.  An expert bank runs the same arithmetic on stacked
-    leaves: ``matmul`` then multiplies each row by its own expert's matrix
-    and ``bias`` picks each row its expert's bias (``_ffn``)."""
-    up = matmul(h, _wget(p, "fc_w", dt))
+    leaves: ``matmul(rows, leaf)`` then multiplies each row by its own
+    expert's matrix, taking the leaf AS IT IS STORED (it converts what it
+    reads, which need not be the whole leaf), and ``bias`` picks each row
+    its expert's bias (``_ffn``)."""
+    if matmul is None:
+        matmul = lambda a, w: a @ _wleaf(w, dt)
+    up = matmul(h, p["fc_w"])
     if cfg.use_bias:
-        up = up + bias(p["fc_b"].astype(dt))
+        up = up + bias(p["fc_b"]).astype(dt)
     if cfg.mlp_type == "swiglu":
         gate, val = jnp.split(up, 2, axis=-1)
         h = _activation(gate, cfg.glu_activation) * val
     else:
         h = _activation(up, cfg.activation)
-    out = matmul(h, _wget(p, "proj_w", dt))
+    out = matmul(h, p["proj_w"])
     if cfg.use_bias:
-        out = out + bias(p["proj_b"].astype(dt))
+        out = out + bias(p["proj_b"]).astype(dt)
     return out
 
 
@@ -778,7 +786,8 @@ _EXPERT_LEAVES = {"wi": "fc_w", "bi": "fc_b", "wo": "proj_w", "bo": "proj_b"}
 
 def _ffn(cfg: "GPTConfig", p: Dict, h: Array, dt, rng=None,
          train: bool = False, live: Optional[Array] = None,
-         attn_in: Optional[Array] = None
+         attn_in: Optional[Array] = None,
+         bank_at: Optional[Tuple[Dict, Array]] = None
          ) -> Tuple[Array, Array, Optional[Array]]:
     """Dense MLP or top-k gated MoE expert bank (reference ``moe/layer.py:16``
     when ``moe_num_experts > 0``).  Returns ``(y, aux_loss, expert_counts)``:
@@ -786,7 +795,11 @@ def _ffn(cfg: "GPTConfig", p: Dict, h: Array, dt, rng=None,
     (``[experts]`` int32, assignments of this call) leave out the rows
     ``live [tokens]`` marks as carrying no request.  ``attn_in`` is the
     normed input attention read, which a ``moe_router_input`` of
-    ``pre_attn`` routes by."""
+    ``pre_attn`` routes by.  The expert bank is ``p["moe"]["experts"]``, one
+    layer's, or with ``bank_at = (experts, layer)`` the layer ``layer`` (an
+    int32 scalar) of the STACKED leaves ``[L, experts, ...]``, which the
+    dropless router's kernel reads where they lie (the paged step; there
+    ``p["moe"]`` holds no ``experts``)."""
     if cfg.moe_num_experts == 0:
         return _mlp(cfg, p, h, dt), jnp.zeros((), jnp.float32), None
     from deepspeed_tpu.moe import dropless
@@ -795,7 +808,8 @@ def _ffn(cfg: "GPTConfig", p: Dict, h: Array, dt, rng=None,
     E, N = cfg.n_embd, cfg.moe_num_experts
     lead = h.shape[:-1]
     xt = h.reshape(-1, E)
-    bank = {_EXPERT_LEAVES[k]: v for k, v in p["moe"]["experts"].items()}
+    experts, layer = bank_at or (p["moe"]["experts"], None)
+    bank = {_EXPERT_LEAVES[k]: v for k, v in experts.items()}
     with jax.named_scope("moe"):
         with jax.named_scope("moe_router"):
             routed = (attn_in.reshape(-1, E)
@@ -816,8 +830,9 @@ def _ffn(cfg: "GPTConfig", p: Dict, h: Array, dt, rng=None,
             y = dropless.dropless_moe(
                 xt, weights, experts, N,
                 lambda rows, matmul, pick: _mlp(cfg, bank, rows, dt, matmul, pick),
-                held=cfg.moe_experts_held)
+                held=cfg.moe_experts_held, layer=layer)
         else:
+            assert layer is None, "a stacked bank is the dropless router's"
             cf = cfg.moe_capacity_factor if train else cfg.moe_eval_capacity_factor
             gating = top1gating if cfg.moe_top_k == 1 else top2gating
             l_aux, combine, dispatch, counts = gating(
@@ -836,11 +851,13 @@ def _ffn(cfg: "GPTConfig", p: Dict, h: Array, dt, rng=None,
 
 
 def _block_tail(cfg: "GPTConfig", p: Dict, x: Array, h: Array, o: Array, dt,
-                live: Optional[Array] = None) -> Tuple[Array, Optional[Array]]:
+                live: Optional[Array] = None,
+                bank_at: Optional[Tuple[Dict, Array]] = None
+                ) -> Tuple[Array, Optional[Array]]:
     """The block after attention, by ``block_type``, on the inference
     paths: ``x`` the block's input, ``h`` its normed form (what attention
-    read), ``o`` attention's output.  Returns the block's output and
-    ``_ffn``'s expert counts."""
+    read), ``o`` attention's output; ``bank_at`` is ``_ffn``'s.  Returns the
+    block's output and ``_ffn``'s expert counts."""
     if cfg.block_type == "sequential":
         x = x + o
         z = _norm(cfg, x, p["ln2_g"], p["ln2_b"])
@@ -848,7 +865,7 @@ def _block_tail(cfg: "GPTConfig", p: Dict, x: Array, h: Array, o: Array, dt,
         z = h if cfg.block_type == "parallel_single_ln" else _norm(
             cfg, x, p["ln2_g"], p["ln2_b"])
         x = x + o
-    f, _, counts = _ffn(cfg, p, z, dt, live=live, attn_in=h)
+    f, _, counts = _ffn(cfg, p, z, dt, live=live, attn_in=h, bank_at=bank_at)
     return x + f, counts
 
 
@@ -885,7 +902,10 @@ def _scan_layers(n_kinds: int, layer_fn: Callable, carry, xs):
     layer ``period * n_kinds + j`` out of each leaf itself (one dynamic
     slice of the whole stack a layer, as a scan over layers takes it: a
     slice of a slice is a copy of the period's weights).  A period of one is
-    the plain scan over layers."""
+    the plain scan over layers.  A slice that feeds XLA's own dot is read in
+    place; one that feeds a Pallas call is COPIED out first, so a caller
+    keeps such a leaf out of ``xs`` and hands the kernel the stack and the
+    layer's index (``gpt_paged_step`` and the expert bank)."""
     if n_kinds == 1:
         return jax.lax.scan(partial(layer_fn, 0), carry, xs)
     n_layer = jax.tree.leaves(xs)[0].shape[0]
@@ -1172,7 +1192,7 @@ def chunked_cross_entropy(x: Array, head: Array, labels: Array,
         # the [N, V] memory, so only chunk past 900 MiB, where capacity
         # forces it (micro 8 x 512 x 50k = 823 MiB stays whole).  The
         # threshold is not measured since the record it came from went
-        # (ROADMAP D10); S3 decides
+        # (ROADMAP D10); S6 decides
         threshold = 900 * 2 ** 20
         if N * V * 4 <= threshold:
             n_chunks = 1
@@ -1445,6 +1465,16 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
     expert ``[experts]`` int32, summed over layers, of the rows that carry a
     request (those whose K/V does not go to the trash block).
 
+    Under the dropless router the expert bank (``params["blocks"]["moe"]
+    ["experts"]``, leaves ``[L, experts, ...]``) is NOT among the leaves the
+    layer scan slices: the step closes over it, a layer hands
+    ``grouped_matmul`` the stack and its own index, and the kernel reads the
+    layer's tiles where they lie.  (A slice handed to a Pallas call is
+    copied out first: sliced like the other leaves, every layer's bank
+    would be written and read once more a step, more device time than its
+    matmuls: PERF.md § 6, PR 38.)  The router, a shared expert and every
+    dense leaf stay in the scan: XLA's own dots read a slice in place.
+
     Rows without a request (idle decode slots, the rows past a prompt
     chunk's tokens) run through every layer like the others and are discarded by the
     caller.  Under the dropless router they displace nothing; a router with
@@ -1483,6 +1513,12 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
             kpos - qpos).astype(jnp.float32)                  # [B, H, S, T]
     else:
         attn_bias = None
+
+    blocks, bank = params["blocks"], None
+    if cfg.moe_num_experts and cfg.moe_router == "dropless":
+        moe = dict(blocks["moe"])
+        bank = moe.pop("experts")
+        blocks = {**blocks, "moe": moe}
 
     def layer(j, carry, p):
         # ``li``: the layer's index inside its group ``j`` (the period)
@@ -1530,12 +1566,14 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
             if cfg.use_bias:
                 o = o + p["out_b"].astype(dt)
         with jax.named_scope("mlp"):
-            x, counts = _block_tail(cfg, p, x, h, o, dt, live)
+            x, counts = _block_tail(
+                cfg, p, x, h, o, dt, live,
+                bank_at=None if bank is None else (bank, li * n_kinds + j))
         return (x, kp, vp, li + int(j == n_kinds - 1)), counts
 
     (x, k_pages, v_pages, _), counts = _scan_layers(
         n_kinds, layer, (x, k_pages, v_pages, jnp.zeros((), jnp.int32)),
-        params["blocks"])
+        blocks)
     with jax.named_scope("head"):
         x = _norm(cfg, x, params["lnf_g"], params["lnf_b"])
         head = params["lm_head"] if cfg.untied_head else params["wte"]

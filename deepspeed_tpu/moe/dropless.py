@@ -83,7 +83,8 @@ def expert_counts(experts: Array, num_experts: int,
 
 def dropless_moe(x: Array, weights: Array, experts: Array, num_experts: int,
                  expert_fn: Callable,
-                 held: Optional[Tuple[int, int]] = None) -> Array:
+                 held: Optional[Tuple[int, int]] = None,
+                 layer: Optional[Array] = None) -> Array:
     """``x [T, M]`` through its ``k`` experts each, weighted and summed, in
     float32.
 
@@ -97,7 +98,12 @@ def dropless_moe(x: Array, weights: Array, experts: Array, num_experts: int,
     ``expert_fn(rows, matmul, pick)`` is the expert's own arithmetic on the
     sorted rows ``[T*k, M]``: ``matmul(rows, w)`` multiplies each row by ITS
     expert's slice of a stacked ``w [E, in, out]``, and ``pick(b)`` gives
-    each row its expert's slice of a stacked ``b [E, out]`` (a bias)."""
+    each row its expert's slice of a stacked ``b [E, out]`` (a bias).  Both
+    take the leaf AS STORED, of any type (``grouped_matmul`` converts what
+    it reads).  With ``layer`` (an int32 scalar) the leaves are those of ALL
+    layers, ``w [L, E, in, out]`` and ``b [L, E, out]``, and the two read
+    layer ``layer`` of them in place: the inference paths' form, which has
+    no gradient (``grouped_matmul``)."""
     T, k = experts.shape
     # rows added behind the sorted assignments so that the kernel's whole
     # row tiles hold them (0 where they already do, or no kernel runs): they
@@ -115,8 +121,9 @@ def dropless_moe(x: Array, weights: Array, experts: Array, num_experts: int,
         if pad:
             rows = jnp.pad(rows, ((0, pad), (0, 0)))
     with jax.named_scope("moe_experts"):
-        y = expert_fn(rows, lambda a, w: grouped_matmul(a, w, sizes),
-                      lambda b: b[jnp.pad(flat[order], (0, pad))])
+        of_row = jnp.pad(flat[order], (0, pad))
+        y = expert_fn(rows, lambda a, w: grouped_matmul(a, w, sizes, layer),
+                      lambda b: b[of_row] if layer is None else b[layer, of_row])
         if pad:
             y = y[:T * k]
     with jax.named_scope("moe_combine"):

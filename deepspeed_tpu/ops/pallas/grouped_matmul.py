@@ -19,8 +19,19 @@ streams the bank once, in tiles of whole columns:
 * ``K`` is never cut: a weight tile is ``K x tn``, as large as
   ``_WEIGHT_TILE_BYTES`` allows, and the two in flight are the kernel's VMEM.
 
+A server keeps its layers' banks STACKED, ``[L, G, K, N]``, and a Pallas
+call that is handed a slice of that gets a copy of it: every layer's bank
+written once more and read once more a step, more device time than the
+bank's matmuls (PERF.md § 6, PR 38).  So the kernel takes the stack and the
+layer's index (a fifth prefetched scalar; the weight's block index is
+``(layer, group, 0, column tile)``) and each tile's DMA starts where the
+layer lies.  It is ONE kernel: a bank of one layer is the stack of one.
+
 The backward pass is ``ragged_dot``'s own (a ``custom_vjp`` round the
 forward kernel): training shapes are compute bound and XLA's is fine there.
+Training differentiates a bank a layer at a time (its layer scan hands each
+layer its slice and collects the slice's gradient), so only the sliced form
+has a gradient; the stacked form refuses one.
 """
 
 import functools
@@ -91,7 +102,8 @@ def visits(group_sizes, A: int, tm: int):
             total.astype(jnp.int32).reshape(1))
 
 
-def _kernel(off_ref, grp_ref, tile_ref, n_ref, lhs_ref, rhs_ref, out_ref, *, tm):
+def _kernel(off_ref, grp_ref, tile_ref, n_ref, layer_ref, lhs_ref, rhs_ref,
+            out_ref, *, tm):
     v = pl.program_id(1)
 
     @pl.when(v < n_ref[0])
@@ -110,22 +122,26 @@ def _kernel(off_ref, grp_ref, tile_ref, n_ref, lhs_ref, rhs_ref, out_ref, *, tm)
         out_ref[...] = jnp.where(keep, out_ref[...], out)
 
 
-def _call(lhs, rhs, group_sizes):
+def _call(lhs, rhs, group_sizes, layer):
+    """``rhs [L, G, K, N]`` and ``layer [1]`` int32: a weight tile's DMA
+    starts at the layer's offset in the stack."""
     A, K = lhs.shape
-    G, _, N = rhs.shape
+    _, G, _, N = rhs.shape
     tm = _ROW_TILE
     tn = _column_tile(K, N, lhs.dtype.itemsize)
     meta = visits(group_sizes, A, tm)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=5,
         grid=(N // tn, A // tm + G - 1),
         in_specs=[
-            pl.BlockSpec((tm, K), lambda n, v, off, grp, tile, cnt: (tile[v], 0)),
-            pl.BlockSpec((None, K, tn),
-                         lambda n, v, off, grp, tile, cnt: (grp[v], 0, n)),
+            pl.BlockSpec((tm, K),
+                         lambda n, v, off, grp, tile, cnt, lay: (tile[v], 0)),
+            pl.BlockSpec((None, None, K, tn),
+                         lambda n, v, off, grp, tile, cnt, lay:
+                         (lay[0], grp[v], 0, n)),
         ],
-        out_specs=pl.BlockSpec((tm, tn),
-                               lambda n, v, off, grp, tile, cnt: (tile[v], n)),
+        out_specs=pl.BlockSpec(
+            (tm, tn), lambda n, v, off, grp, tile, cnt, lay: (tile[v], n)),
     )
     return pl.pallas_call(
         functools.partial(_kernel, tm=tm),
@@ -136,16 +152,17 @@ def _call(lhs, rhs, group_sizes):
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=_pallas.interpret(),
         name="grouped_matmul",
-    )(*meta, lhs, rhs)
+    )(*meta, layer, lhs, rhs)
 
 
 @jax.custom_vjp
 def _grouped(lhs, rhs, group_sizes):
-    return _call(lhs, rhs, group_sizes)
+    # a bank of one layer is the stack of one, at layer 0
+    return _call(lhs, rhs[None], group_sizes, jnp.zeros((1,), jnp.int32))
 
 
 def _grouped_fwd(lhs, rhs, group_sizes):
-    return _call(lhs, rhs, group_sizes), (lhs, rhs, group_sizes)
+    return _grouped(lhs, rhs, group_sizes), (lhs, rhs, group_sizes)
 
 
 def _grouped_bwd(res, dy):
@@ -157,15 +174,51 @@ def _grouped_bwd(res, dy):
 _grouped.defvjp(_grouped_fwd, _grouped_bwd)
 
 
-def grouped_matmul(lhs, rhs, group_sizes):
+@jax.custom_vjp
+def _grouped_in_stack(lhs, rhs, group_sizes, layer):
+    return _call(lhs, rhs, group_sizes, layer)
+
+
+def _no_stacked_gradient(*_):
+    raise NotImplementedError(
+        "grouped_matmul(..., layer=) reads one layer of a stacked bank in "
+        "place and is the inference paths' form: its gradient would be as "
+        "large as the whole stack.  To differentiate, slice the layer "
+        "(rhs[layer]) and call the sliced form, grouped_matmul(lhs, "
+        "rhs[layer], group_sizes)")
+
+
+_grouped_in_stack.defvjp(_no_stacked_gradient, _no_stacked_gradient)
+
+
+def grouped_matmul(lhs, rhs, group_sizes, layer=None):
     """``lhs [A, K]`` (rows sorted by group, ``sum(group_sizes) == A``) times
     ``rhs [G, K, N]`` -> ``[A, N]`` in ``lhs``'s type: the kernel on one TPU
     chip where :func:`kernel_shape_ok` admits the shapes, else
     ``jax.lax.ragged_dot`` (a mesh shards the bank over ``expert``, which
-    the kernel does not)."""
-    rhs = rhs.astype(lhs.dtype)
+    the kernel does not).
+
+    With ``layer`` (an int32 scalar, traced or not) ``rhs`` is the STACK
+    ``[L, G, K, N]`` and the product is with ``rhs[layer]``: the kernel reads
+    that layer's tiles where they lie, and the program holds no copy of the
+    layer.  Where the kernel does not run, or cannot read the leaf as it is
+    stored (a bank of another type than the rows', an int8-injected leaf
+    ``{"q8", "scale"}``), the layer is indexed here and converted alone,
+    never the stack.  The stacked form has no gradient (an error says so)."""
+    from deepspeed_tpu.module_inject.quantization import (dequantize_weight,
+                                                          is_quantized_leaf)
     A, K = lhs.shape
-    if (_pallas.use_kernel("grouped_matmul") and _pallas.single_device()
-            and kernel_shape_ok(A, K, rhs.shape[2], lhs.dtype)):
+    quantized = is_quantized_leaf(rhs)
+    N = (rhs["q8"] if quantized else rhs).shape[-1]
+    kernel = (_pallas.use_kernel("grouped_matmul") and _pallas.single_device()
+              and kernel_shape_ok(A, K, N, lhs.dtype))
+    if layer is not None:
+        if kernel and not quantized and rhs.dtype == lhs.dtype:
+            return _grouped_in_stack(lhs, rhs, group_sizes.astype(jnp.int32),
+                                     jnp.asarray(layer, jnp.int32).reshape(1))
+        rhs = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
+            a, layer, 0, keepdims=False), rhs)
+    rhs = dequantize_weight(rhs, lhs.dtype) if quantized else rhs.astype(lhs.dtype)
+    if kernel:
         return _grouped(lhs, rhs, group_sizes.astype(jnp.int32))
     return jax.lax.ragged_dot(lhs, rhs, group_sizes)

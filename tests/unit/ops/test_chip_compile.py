@@ -10,6 +10,7 @@ shapes, D=64, bf16.  Nothing runs: a compile that passes is not a chip run
 the program has no switch for it.
 """
 
+import dataclasses
 import os
 import re
 
@@ -246,32 +247,59 @@ def test_paged_gqa_kernel_compiles_at_smallthinker_heads(chip, window, MB):
     assert da.paged_layer_tile_pages(1, H, Hkv, D128, BS, MB, BF16, window=window) == 8
 
 
+def _bank_matmul_compiles(chip, rows, G, K, N, stacked):
+    """``grouped_matmul`` compiled for the chip on a bank ``[G, K, N]``, or
+    ``stacked`` on the 8 layers' ``[8, G, K, N]`` with a traced layer, which
+    the program must hand the kernel WHOLE: no slice and no copy of it."""
+    from deepspeed_tpu.ops.pallas import grouped_matmul as gm
+    assert gm.kernel_shape_ok(rows, K, N, BF16)
+    if not stacked:
+        text = _compiled_text(chip, gm.grouped_matmul, ((rows, K), BF16),
+                              ((G, K, N), BF16), ((G,), jnp.int32))
+    else:
+        text = _compiled_text(chip, gm.grouped_matmul, ((rows, K), BF16),
+                              ((8, G, K, N), BF16), ((G,), jnp.int32),
+                              ((), jnp.int32))
+        call, = _bank_calls(text)
+        assert f"bf16[8,{G},{K},{N}]" in call
+        assert not _bank_copies(text, G, K, N)
+    assert "tpu_custom_call" in text and "grouped_matmul" in text
+
+
+def _bank_calls(text):
+    """Every call of the kernel ``grouped_matmul`` in a compiled program,
+    the line with its operands' shapes."""
+    return re.findall(r"%grouped_matmul[.\d]* = [^\n]*tpu_custom_call[^\n]*", text)
+
+
+def _bank_copies(text, G, K, N):
+    """The lines of a compiled program that make an array of one layer's
+    bank (a slice or a copy out of the stack, fused or not)."""
+    return [line.strip()[:160] for line in text.splitlines()
+            if re.search(rf"= bf16\[(1,)?{G},{K},{N}\]", line)]
+
+
+@pytest.mark.parametrize("stacked", [False, True], ids=["a_layer", "stacked"])
 @pytest.mark.parametrize("rows", [1024, 512])
 @pytest.mark.parametrize("K,N", [(2048, 2048), (1024, 2048)])
-def test_grouped_matmul_compiles_at_olmoe_bank(chip, rows, K, N):
+def test_grouped_matmul_compiles_at_olmoe_bank(chip, rows, K, N, stacked):
     """The expert bank's two matmuls (gate|up ``[64, 2048, 2048]``, down
     ``[64, 1024, 2048]``) at the serve cell's 1,024 (128 rows x top 8) and
     512 (a prompt chunk) assignments: the program holds the kernel and the
     chip's compiler accepts its blocks and its VMEM."""
-    from deepspeed_tpu.ops.pallas import grouped_matmul as gm
-    assert gm.kernel_shape_ok(rows, K, N, BF16)
-    text = _compiled_text(chip, gm.grouped_matmul, ((rows, K), BF16),
-                          ((64, K, N), BF16), ((64,), jnp.int32))
-    assert "tpu_custom_call" in text and "grouped_matmul" in text
+    _bank_matmul_compiles(chip, rows, 64, K, N, stacked)
 
 
+@pytest.mark.parametrize("stacked", [False, True], ids=["a_layer", "stacked"])
 @pytest.mark.parametrize("K,N", [(2560, 1536), (768, 2560)])
-def test_grouped_matmul_compiles_at_smallthinker_bank(chip, K, N):
+def test_grouped_matmul_compiles_at_smallthinker_bank(chip, K, N, stacked):
     """SmallThinker's bank (gate|up ``[64, 2560, 1536]``, down ``[64, 768,
     2560]``) at the serve cell's 256 rows x top 6 = 1,536 assignments,
     twelve whole row tiles."""
     from deepspeed_tpu.ops.pallas import grouped_matmul as gm
-    assert gm.kernel_shape_ok(1536, K, N, BF16)
     assert gm.rows_to_whole_tiles(1536, K, BF16) == 0
     assert gm.rows_to_whole_tiles(250 * 6, K, BF16) == 36     # 1,500 -> 1,536
-    text = _compiled_text(chip, gm.grouped_matmul, ((1536, K), BF16),
-                          ((64, K, N), BF16), ((64,), jnp.int32))
-    assert "tpu_custom_call" in text and "grouped_matmul" in text
+    _bank_matmul_compiles(chip, 1536, 64, K, N, stacked)
 
 
 def test_generate_keeps_its_cache_zero_filled(chip):
@@ -322,8 +350,9 @@ def test_paged_mla_kernel_compiles_at_mistral4_heads(chip):
 
 
 # a serve cell's model at its published widths (one period of its layers: the
-# program scans them), its slots, chunk and positions, its kernel and the
-# queries a row of the chunk holds
+# program scans them; Mistral's bank one chip's 32 of 128 experts, as served),
+# its slots, chunk and positions, its kernel and the queries a row of the
+# chunk holds
 SERVE_CELLS = {
     "gpt2-124m": (lambda m: m.gpt_config("gpt2", n_layer=1, dtype=BF16),
                   256, 64, 1024, "paged_attention", 64),
@@ -331,21 +360,20 @@ SERVE_CELLS = {
                     128, 64, 4096, "paged_attention", 64),
     "smallthinker-21b-a3b": (lambda m: m.smallthinker_config(n_layer=4, dtype=BF16),
                              32, 224, 16384, "paged_gqa_attention", 32),
-    "mistral-small-4-119b": (lambda m: m.mistral4_config(n_layer=1, dtype=BF16),
+    "mistral-small-4-119b": (lambda m: m.mistral4_config(
+        n_layer=1, experts_held=(0, 32), dtype=BF16),
                              128, 384, 16384, "paged_mla_attention", 16),
 }
 
 
-@pytest.mark.parametrize("cell", list(SERVE_CELLS))
-def test_the_step_program_attends_the_chunk_packed(chip, cell):
-    """The whole step of each serve configuration, compiled ahead of time:
-    every layer kind holds its paged kernel at TWO shapes, the decode slots a
-    query a row and the prompt chunk ``Sq > 1`` queries a row, and no
-    attention call runs ``slots + chunk`` rows."""
+def _step_text(chip, cell, periods=1):
+    """(config, compiled text) of the whole step of a serve configuration
+    at ``periods`` periods of its layers."""
     from deepspeed_tpu.models import gpt
     from deepspeed_tpu.serving.kv_cache import init_arena, window_table_blocks
     make, slots, chunk, positions, kernel, Sq = SERVE_CELLS[cell]
     cfg = make(gpt)
+    cfg = dataclasses.replace(cfg, n_layer=cfg.n_layer * periods)
     model, rows, BS = gpt.GPT(cfg), slots + chunk, 16
     shape = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=chip)
     on_chip = lambda tree: jax.tree.map(lambda a: shape(a.shape, a.dtype), tree)
@@ -359,12 +387,49 @@ def test_the_step_program_attends_the_chunk_packed(chip, cell):
     tables = tuple(shape((rows, w), jnp.int32) for w in widths)
     coords = tuple(shape((rows, 1), jnp.int32) for _ in widths)
     step = lambda *a: model.paged_step(*a, chunk=chunk)
-    text = jax.jit(step).lower(
+    return cfg, jax.jit(step).lower(
         params, shape((rows, 1), jnp.int32), shape((rows,), jnp.int32), kp, vp,
         tables, coords, shape((rows, 1), jnp.int32)).compile().as_text()
+
+
+@pytest.mark.parametrize("cell", list(SERVE_CELLS))
+def test_the_step_program_attends_the_chunk_packed(chip, cell):
+    """The whole step of each serve configuration, compiled ahead of time:
+    every layer kind holds its paged kernel at TWO shapes, the decode slots a
+    query a row and the prompt chunk ``Sq > 1`` queries a row, and no
+    attention call runs ``slots + chunk`` rows."""
+    _, slots, chunk, _, kernel, Sq = SERVE_CELLS[cell]
+    cfg, text = _step_text(chip, cell)
     assert chunk % Sq == 0 and Sq > 1
     assert _kernel_rows(text, kernel) == sorted(
         [slots, chunk // Sq] * len(cfg.pattern))
+
+
+@pytest.mark.parametrize("cell", list(SERVE_CELLS))
+def test_the_step_program_reads_the_bank_in_place(chip, cell):
+    """The whole step at TWO periods of layers (the layer scan stays a loop,
+    and the bank's stack has a layer to be sliced out of): the program hands
+    ``grouped_matmul`` the stacked bank ``[L, experts, K, N]`` itself, twice
+    a layer kind, and makes NO array of one layer's bank (a bank sliced by
+    the scan like any leaf is copied out for the Pallas call, more device
+    time than its matmuls: PERF.md § 6, PR 38).  GPT-2 has no bank: no
+    ``grouped_matmul``, and the same attention rows as at one period."""
+    _, slots, chunk, _, kernel, Sq = SERVE_CELLS[cell]
+    cfg, text = _step_text(chip, cell, periods=2)
+    assert _kernel_rows(text, kernel) == sorted(
+        [slots, chunk // Sq] * len(cfg.pattern))
+    calls = _bank_calls(text)
+    if not cfg.moe_num_experts:
+        assert not calls and "grouped_matmul" not in text
+        return
+    from deepspeed_tpu.models.gpt import GPT
+    leaves = jax.eval_shape(GPT(cfg).init_params, jax.random.PRNGKey(0))[
+        "blocks"]["moe"]["experts"]
+    assert len(calls) == 2 * len(cfg.pattern)
+    for leaf in leaves.values():
+        L, G, K, N = leaf.shape
+        assert L == cfg.n_layer and not _bank_copies(text, G, K, N)
+        assert sum(f"bf16[{L},{G},{K},{N}]" in call for call in calls) == len(cfg.pattern)
 
 
 # the four serve cells' attention: (chunk, rows a query, products, key lanes,
@@ -396,14 +461,12 @@ def test_chunk_queries_divide_the_chunk_and_fit_the_budget(cell, monkeypatch):
     assert seen == sorted(seen) and seen[0] == 1 and seen[-1] == chunk
 
 
+@pytest.mark.parametrize("stacked", [False, True], ids=["a_layer", "stacked"])
 @pytest.mark.parametrize("K,N", [(4096, 4096), (2048, 4096)])
-def test_grouped_matmul_compiles_at_mistral4_held_bank(chip, K, N):
+def test_grouped_matmul_compiles_at_mistral4_held_bank(chip, K, N, stacked):
     """The 32 held experts' bank (gate|up ``[32, 4096, 4096]``, down ``[32,
     2048, 4096]``) at the serve cell's 512 rows x top 4 = 2,048 assignments,
     sixteen whole row tiles, most of them in no group."""
     from deepspeed_tpu.ops.pallas import grouped_matmul as gm
-    assert gm.kernel_shape_ok(2048, K, N, BF16)
     assert gm.rows_to_whole_tiles(2048, K, BF16) == 0
-    text = _compiled_text(chip, gm.grouped_matmul, ((2048, K), BF16),
-                          ((32, K, N), BF16), ((32,), jnp.int32))
-    assert "tpu_custom_call" in text and "grouped_matmul" in text
+    _bank_matmul_compiles(chip, 2048, 32, K, N, stacked)

@@ -30,6 +30,7 @@ import deepspeed_tpu
 from benchmarks.lib import reference_mistral4 as ref
 from deepspeed_tpu.models import gpt
 from deepspeed_tpu.models.gpt import GPT, YarnRope, mistral4_config
+from tests.unit.paged_bank import PATHS, bank_in_place_equals_bank_sliced
 from deepspeed_tpu.moe import dropless
 from deepspeed_tpu.ops.pallas import decode_attention as da
 
@@ -474,3 +475,19 @@ def test_a_latent_cache_refuses_tiering_and_the_prefix_cache(tiny):
         with pytest.raises(ValueError, match="latent cache"):
             deepspeed_tpu.init_serving(model=model, params=params, config={
                 "serving": dict(SERVING, **{knob: True})})
+
+
+@pytest.mark.parametrize("path", PATHS)
+def test_the_paged_step_reads_the_held_bank_in_place(path, kernels, monkeypatch):
+    """A period of one layer with ``held`` experts (the stack is ``[3, 4, K,
+    N]`` of 8 experts, and most assignments lie in no group), at widths the
+    kernel takes: against the step with each layer's bank sliced out by
+    hand, bit for bit; the counts are of all 8 experts."""
+    cfg = tiny_config(n_embd=128, intermediate_size=128, n_layer=3,
+                      experts_held=(2, 4))
+    params = GPT(cfg).init_params(jax.random.PRNGKey(2))
+    assert params["blocks"]["moe"]["experts"]["wi"].shape == (3, 4, 128, 256)
+    gate = params["blocks"]["moe"]["gate"]
+    params["blocks"]["moe"] = dict(params["blocks"]["moe"],
+                                   gate=dict(gate, wg=gate["wg"] * 20))
+    bank_in_place_equals_bank_sliced(cfg, params, path, kernels, monkeypatch)

@@ -24,6 +24,7 @@ import deepspeed_tpu
 from benchmarks.lib.reference_olmoe import olmoe_logits, olmoe_loss_sum
 from deepspeed_tpu.models import gpt as gpt_lib
 from deepspeed_tpu.models.gpt import GPT, olmoe_config
+from tests.unit.paged_bank import PATHS, bank_in_place_equals_bank_sliced
 
 TOL = 2e-5
 V, H = 500, 4
@@ -244,3 +245,17 @@ def test_rows_without_a_request_and_a_routers_capacity(tiny, router):
         with pytest.raises(ValueError, match="dropless"):
             deepspeed_tpu.init_serving(model=model, params=params,
                                        config={"serving": SERVING})
+
+
+@pytest.mark.parametrize("path", PATHS)
+def test_the_paged_step_reads_the_bank_in_place(path, kernels, monkeypatch):
+    """A period of ONE layer (the plain scan over three layers), at widths
+    the kernel takes: the step that hands ``grouped_matmul`` the stacked
+    bank and the layer's index against the step with each layer's bank
+    sliced out by hand, bit for bit."""
+    cfg = olmoe_config(vocab_size=V, n_positions=128, n_embd=128, n_layer=3,
+                       n_head=H, intermediate_size=128, num_experts=8, top_k=2,
+                       dtype=jnp.float32, moe_aux_coeff=0.0)
+    params = GPT(cfg).init_params(jax.random.PRNGKey(2))
+    params["blocks"]["moe"]["gate"]["wg"] = params["blocks"]["moe"]["gate"]["wg"] * 20
+    bank_in_place_equals_bank_sliced(cfg, params, path, kernels, monkeypatch)

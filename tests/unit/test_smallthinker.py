@@ -25,6 +25,7 @@ import pytest
 import deepspeed_tpu
 from benchmarks.lib.reference_smallthinker import smallthinker_logits
 from deepspeed_tpu.models.gpt import GPT, LayerKind, smallthinker_config
+from tests.unit.paged_bank import PATHS, bank_in_place_equals_bank_sliced
 
 TOL = 2e-5
 V, W, LAYERS = 500, 16, 8
@@ -265,3 +266,16 @@ def test_sharing_and_spilling_refuse_several_tables_a_sequence(tiny):
         with pytest.raises(ValueError, match="layer pattern"):
             deepspeed_tpu.init_serving(model=model, params=params, config={
                 "serving": dict(SERVING, **{knob: True})})
+
+
+@pytest.mark.parametrize("path", PATHS)
+def test_the_paged_step_reads_the_bank_in_place(path, kernels, monkeypatch):
+    """A period of FOUR layers (the scan over two periods, whose body takes
+    every other leaf out of the stack itself), at widths the kernel takes:
+    layer ``period * 4 + j`` of the stacked bank read where it lies against
+    the step with each layer's bank sliced out by hand, bit for bit."""
+    cfg = tiny_config(n_embd=128, intermediate_size=128)
+    assert len(cfg.pattern) == 4 and cfg.n_layer == 8
+    params = GPT(cfg).init_params(jax.random.PRNGKey(2))
+    params["blocks"]["moe"]["gate"]["wg"] = params["blocks"]["moe"]["gate"]["wg"] * 20
+    bank_in_place_equals_bank_sliced(cfg, params, path, kernels, monkeypatch)
