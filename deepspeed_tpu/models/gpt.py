@@ -45,11 +45,33 @@ Array = jax.Array
 
 
 class LayerKind(NamedTuple):
-    """What distinguishes the attention of one layer of a ``layer_pattern``:
-    ``window`` keys a query sees, itself counted (None: every earlier key),
-    and whether q and k are rotated (``rope``) or carry no position."""
+    """What distinguishes one layer of a ``layer_pattern``: ``window`` keys a
+    query sees, itself counted (None: every earlier key), whether q and k
+    are rotated (``rope``) or carry no position, and the layer's ``mixer``:
+    ``softmax`` attention over cached keys (every family but one), attention
+    over the blocks the query chooses (``sparse``: :class:`SparseSpec`), or
+    a ``linear`` recurrence whose cache is a state (``models/hybrid.py``).
+    ``depth`` is the layer's index in the PUBLISHED stack where that differs
+    from its place here (a linear layer's decay reads it)."""
     window: Optional[int] = None
     rope: bool = True
+    mixer: str = "softmax"
+    depth: Optional[int] = None
+
+
+class SparseSpec(NamedTuple):
+    """Block-sparse attention that chooses its own blocks (the InfLLM-V2
+    line of MiniCPM4): compressed keys are means of ``kernel`` keys every
+    ``stride``; a query with more than ``dense_len`` keys attends the
+    ``topk`` blocks of ``block`` keys that score highest, the first
+    ``init_blocks`` and those of its last ``window`` keys among them."""
+    kernel: int = 32
+    stride: int = 16
+    block: int = 64
+    topk: int = 64
+    init_blocks: int = 1
+    window: int = 2048
+    dense_len: int = 8192
 
 
 class YarnRope(NamedTuple):
@@ -187,6 +209,19 @@ class GPTConfig:
     qk_rope_dim: Optional[int] = None
     v_head_dim: Optional[int] = None
     rope_yarn: Optional[YarnRope] = None
+    # --- a stack whose layers differ in shape and follow no period (the
+    # MiniCPM-SALA family, ``models/hybrid.py``): ``layer_pattern`` then names
+    # EVERY layer, some of a ``mixer`` other than softmax, and the leaves are
+    # stacked by kind.  ``sparse`` is the sparse layers' selection; the
+    # MiniCPM scalings: ``x_0 = scale_emb * wte[ids]``, a block adds
+    # ``residual_scale * f(norm(x))``, the head reads ``norm(x) /
+    # head_divisor``; ``published_layers`` is the depth the decays and
+    # ``residual_scale`` were published for --------------------------------- #
+    sparse: Optional[SparseSpec] = None
+    scale_emb: float = 1.0
+    residual_scale: float = 1.0
+    head_divisor: float = 1.0
+    published_layers: Optional[int] = None
 
     def __post_init__(self):
         self.padded_vocab = int(
@@ -239,6 +274,45 @@ class GPTConfig:
                     "head_dim), rope, one kind of layer, a head a query head")
         else:
             assert self.v_head_dim == self.head_dim
+        # layers of each mixer, in the stack's order
+        self.mixers = tuple(k.mixer for k in self.pattern)
+        self.hybrid = any(m != "softmax" for m in self.mixers)
+        if self.hybrid:
+            assert len(self.pattern) == self.n_layer and all(
+                m in ("sparse", "linear") for m in self.mixers), (
+                    "a hybrid stack names every layer, sparse or linear")
+            self.sparse = SparseSpec(*(self.sparse or ()))
+            sp = self.sparse
+            assert sp.kernel == 2 * sp.stride and sp.block % sp.stride == 0 \
+                and sp.window % sp.block == 0 and sp.topk * sp.block >= \
+                sp.window + (sp.init_blocks + 1) * sp.block, sp
+            assert (self.norm == "rmsnorm" and self.mlp_type == "swiglu"
+                    and not self.use_bias and self.untied_head
+                    and not self.moe_num_experts and not self.kv_lora_rank
+                    and self.block_type == "sequential")
+
+    @property
+    def arena_layout(self) -> Tuple[int, int, Tuple[int, ...]]:
+        """(layers a page holds, pages a block of ALL of them takes, lanes of
+        each array): what ``serving/kv_cache.py:init_arena`` builds.  A layer
+        pattern of ``P`` kinds keeps ``P`` groups of ``n_layer / P`` layers;
+        a hybrid stack pages its sparse layers alone, a K/V head a page of
+        its own (the selection differs by K/V head, so the kernel walks a
+        list of pages a head), and its linear layers own no page."""
+        if self.hybrid:
+            return (self.mixers.count("sparse"), self.kv_heads,
+                    (self.head_dim,) * 2)
+        P = len(self.pattern)
+        return self.n_layer // P, P, self.cache_lanes
+
+    @property
+    def page_groups(self) -> Tuple[Optional[int], ...]:
+        """The layer groups that own pages, each named by its window (None:
+        every key is kept): a group a kind of a periodic pattern; a hybrid
+        stack's sparse layers ONE group, its linear layers none."""
+        if self.hybrid:
+            return (None,)
+        return tuple(kind.window for kind in self.pattern)
 
     @property
     def cache_lanes(self) -> Tuple[int, ...]:
@@ -351,6 +425,45 @@ def mistral4_config(vocab_size=131072, n_positions=1048576, n_embd=4096,
                         intermediate_size=intermediate_size, **kw)
 
 
+_SALA_MIXERS = {"minicpm4": "sparse", "lightning-attn": "linear"}
+
+
+def minicpm_sala_config(vocab_size=73448, n_positions=524288, n_embd=4096,
+                        n_head=32, n_kv_head=2, head_dim=128,
+                        intermediate_size=16384, mixer_types=None,
+                        first_layer=0, published_layers=32, scale_emb=12.0,
+                        scale_depth=1.4, dim_model_base=256, sparse=(),
+                        **overrides) -> GPTConfig:
+    """MiniCPM-SALA family (defaults: MiniCPM-SALA 9B's widths): a stack of
+    block-sparse attention layers (``"minicpm4"``: ``n_head`` query heads on
+    ``n_kv_head`` K/V heads, no rope, the query chooses its own blocks:
+    :class:`SparseSpec`) among Lightning linear-attention layers
+    (``"lightning-attn"``: ``n_head`` heads whose cache is a ``[head_dim,
+    head_dim]`` state, rope, an output norm), in the order ``mixer_types``
+    gives and in no period; both with an RMSNorm a head on q and k and a
+    sigmoid output gate; a dense SwiGLU MLP; the MiniCPM scalings of the
+    embedding (``scale_emb``), of every residual branch (``scale_depth /
+    sqrt(published_layers)``) and of the head's input (``n_embd /
+    dim_model_base``).  ``mixer_types`` may be a slice of the published
+    list, ``first_layer`` the published index of its first entry (a linear
+    layer's decay reads its published depth).  RMSNorm (eps 1e-6), no bias,
+    untied head, rope theta 10,000.  Served through ``init_serving()``
+    (``models/hybrid.py``); the dense paths refuse it."""
+    assert mixer_types, "mixer_types: 'minicpm4' or 'lightning-attn' a layer"
+    pattern = tuple(
+        LayerKind(None, _SALA_MIXERS[m] == "linear", _SALA_MIXERS[m],
+                  first_layer + i) for i, m in enumerate(mixer_types))
+    kw = dict(n_kv_head=n_kv_head, head_dim=head_dim, ln_eps=1e-6,
+              layer_pattern=pattern, sparse=tuple(sparse), scale_emb=scale_emb,
+              residual_scale=scale_depth / math.sqrt(published_layers),
+              head_divisor=n_embd / dim_model_base,
+              published_layers=published_layers)
+    kw.update(overrides)
+    return llama_config(vocab_size=vocab_size, n_positions=n_positions,
+                        n_embd=n_embd, n_layer=len(pattern), n_head=n_head,
+                        intermediate_size=intermediate_size, **kw)
+
+
 def bloom_config(vocab_size=250880, n_positions=2048, n_embd=512, n_layer=4,
                  n_head=8, **overrides) -> GPTConfig:
     """BLOOM family: ALiBi positions, GELU MLP, tied embeddings
@@ -455,7 +568,10 @@ def init_gpt_params(cfg: GPTConfig, rng: Array) -> Dict:
     k_embed, k_blocks = jax.random.split(rng)
     E, L = cfg.n_embd, cfg.n_layer
 
-    if cfg.scan_layers:
+    if cfg.hybrid:
+        from deepspeed_tpu.models import hybrid
+        blocks = hybrid.init_blocks(cfg, k_blocks)
+    elif cfg.scan_layers:
         # an expert bank is built a layer at a time (lax.map): the random
         # bits of one OLMoE layer are a gigabyte, and a caller that casts
         # the tree inside the same jit then never holds all layers in fp32
@@ -537,7 +653,10 @@ def gpt_partition_specs(cfg: GPTConfig) -> Dict:
                     "wo": PartitionSpec(*pre, "tensor", None)}
         return specs
 
-    if cfg.scan_layers:
+    if cfg.hybrid:
+        from deepspeed_tpu.models import hybrid
+        blocks = hybrid.block_partition_specs(cfg)
+    elif cfg.scan_layers:
         blocks = block_specs(True)
     else:
         blocks = {f"h{i}": block_specs(False) for i in range(cfg.n_layer)}
@@ -923,6 +1042,17 @@ def _scan_layers(n_kinds: int, layer_fn: Callable, carry, xs):
     return carry, jax.tree.map(lambda a: a.reshape(-1, *a.shape[2:]), ys)
 
 
+def _refuse_hybrid(cfg: "GPTConfig", path: str) -> None:
+    """The dense paths walk ONE stack of softmax layers: a stack with sparse
+    or linear layers is refused by the mechanism it would need."""
+    if cfg.hybrid:
+        raise NotImplementedError(
+            f"{path} has no chunked linear-attention scan (nor its backward) "
+            f"and no block selection for the {cfg.mixers.count('linear')} "
+            f"linear and {cfg.mixers.count('sparse')} sparse layers of this "
+            f"stack; serve it through init_serving() (models/hybrid.py)")
+
+
 def _window_bias(S: int, window: int) -> Array:
     """``[S, S]`` additive mask of a window layer on the dense path: query
     ``t`` sees keys ``t - window + 1 .. t`` (the causal half is the
@@ -1014,6 +1144,7 @@ def gpt_forward(cfg: GPTConfig, params: Dict, input_ids: Array,
     their FLOPs, matching the reference's speedup story.
     """
     from deepspeed_tpu.ops.attention import get_attention_fn
+    _refuse_hybrid(cfg, "gpt_forward (training and the dense forward pass)")
     attention_fn = attention_fn or get_attention_fn(cfg.attn_impl)
 
     B, S = input_ids.shape
@@ -1295,6 +1426,7 @@ def gpt_apply_with_cache(cfg: GPTConfig, params: Dict, input_ids: Array,
     (S_new = prompt length) and decode (S_new = 1) — one compiled program
     per S_new."""
     assert cfg.scan_layers, "KV-cache path requires scan_layers"
+    _refuse_hybrid(cfg, "the dense-cache generate() path")
     assert len(cfg.pattern) == 1, (
         "the dense-cache generate() path walks identical layers; a model "
         "with a layer pattern is served through init_serving()")
@@ -1750,7 +1882,14 @@ class GPT:
     def paged_step(self, params, input_ids, positions, k_pages, v_pages,
                    block_tables, write_blocks, write_offsets, **kw):
         """Serving-engine protocol: one step over the paged KV arena
-        (``deepspeed_tpu/serving/engine.py``)."""
+        (``deepspeed_tpu/serving/engine.py``).  A hybrid stack's step takes
+        and returns its state beside the pages (``aux``, ``slots``,
+        ``live``: ``models/hybrid.py:hybrid_paged_step``)."""
+        if self.cfg.hybrid:
+            from deepspeed_tpu.models import hybrid
+            return hybrid.hybrid_paged_step(
+                self.cfg, params, input_ids, positions, k_pages, v_pages,
+                block_tables, write_blocks, write_offsets, **kw)
         return gpt_paged_step(self.cfg, params, input_ids, positions,
                               k_pages, v_pages, block_tables,
                               write_blocks, write_offsets, **kw)
@@ -1759,6 +1898,9 @@ class GPT:
         """Parameters held; ``active``: those one token multiplies by (an
         MoE model's router and ``moe_top_k`` of its experts)."""
         cfg = self.cfg
+        if cfg.hybrid:
+            from deepspeed_tpu.models import hybrid
+            return hybrid.num_params(cfg)
         E, L = cfg.n_embd, cfg.n_layer
         b = int(cfg.use_bias)
         I = (cfg.moe_num_experts and cfg.moe_expert_hidden) or cfg.ffn_dim

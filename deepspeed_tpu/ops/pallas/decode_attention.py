@@ -310,6 +310,12 @@ def paged_attention_reference(q, k_pages, v_pages, block_tables, lengths,
 # the dense kernel attends at a time (a full MXU pass; the softmax update and
 # the 2*H matmul round trips are paid once per tile, not once per page).
 _TILE_ROWS = 128
+# ... or, where pages are long and narrow (64 keys of ONE K/V head: 16 KiB),
+# the eight page copies a tile of 16-key pages carries everywhere else, while
+# they stay inside this many bytes: two such pages alone would make a tile
+# whose fixed cost is most of its cost.  No tile of the 16-key arenas moves.
+_TILE_PAGES = 8
+_TILE_BYTES = 128 * 1024
 # K and V, two tiles each, may take this much VMEM (of the 16 MiB a v5e
 # kernel is given by default, next to q, o and the softmax carries).
 _TILE_VMEM_BYTES = 4 * 1024 * 1024
@@ -319,7 +325,8 @@ def paged_tile_pages(BS: int, MB: int, Sq: int, lanes: int, dtype) -> int:
     """``G``, the pages the paged kernel fetches and attends as ONE tile —
     from the static shapes alone (page rows ``BS``, table width ``MB``,
     queries per row ``Sq``, cache lanes ``H*D``, cache dtype): as many
-    pages as make :data:`_TILE_ROWS` rows, halved until the four tile
+    pages as make :data:`_TILE_ROWS` rows (or :data:`_TILE_PAGES` pages
+    inside :data:`_TILE_BYTES`, if that is more), halved until the four tile
     buffers fit :data:`_TILE_VMEM_BYTES`, never more than the table holds.
     ``MB`` need not be a multiple of ``G``: the last tile is short.
 
@@ -329,7 +336,8 @@ def paged_tile_pages(BS: int, MB: int, Sq: int, lanes: int, dtype) -> int:
     here."""
     del Sq
     row_bytes = lanes * np.dtype(dtype).itemsize
-    G = max(1, _TILE_ROWS // BS)
+    G = max(1, _TILE_ROWS // BS,
+            min(_TILE_PAGES, _TILE_BYTES // (BS * row_bytes)))
     while G > 1 and 4 * G * BS * row_bytes > _TILE_VMEM_BYTES:
         G //= 2
     return min(G, MB)
@@ -649,7 +657,8 @@ def _paged_gqa_kernel(lay_ref, len_ref, tbl_ref, nxt_ref, q_ref, k_hbm, v_hbm,
         o_ref[0, h] = (acc[h] / jnp.maximum(l[h], 1e-30)).astype(o_ref.dtype)
 
 
-def _paged_gqa_call(q, k_arena, v_arena, layer, block_tables, lengths, window):
+def _paged_gqa_call(q, k_arena, v_arena, layer, block_tables, lengths, window,
+                    name="paged_gqa_attention"):
     B, Sq, H, D = q.shape
     _, _, BS, lanes = k_arena.shape
     Hkv = lanes // D
@@ -694,7 +703,7 @@ def _paged_gqa_call(q, k_arena, v_arena, layer, block_tables, lengths, window):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=_pallas.interpret(),
-        name="paged_gqa_attention",
+        name=name,
     )(jnp.asarray(layer, jnp.int32).reshape(1), jnp.asarray(lengths, jnp.int32),
       block_tables, block_tables, qg, k_arena, v_arena)
     out = out[:, :, :g * Sq].reshape(B, Hkv, g, Sq, D)
@@ -729,6 +738,45 @@ def paged_gqa_attention(q, k_arena, v_arena, layer, block_tables, lengths,
     vl = jax.lax.dynamic_index_in_dim(v_arena, layer, 0, keepdims=False)
     return paged_attention_reference(q, kl, vl, block_tables, lengths,
                                      window=window)
+
+
+# --------------------------------------------------------------------------- #
+# Attention over the pages a query CHOSE (block-sparse attention whose block
+# is a page; ``models/hybrid.py``).  The selection differs by token and by K/V
+# head, so a row is one (token, K/V head): its table lists the chosen pages in
+# logical order, its length counts the keys in them before the query (the
+# query's own block ends the list), and its ``g`` query heads are the rows of
+# one product.  That is ``_paged_gqa_kernel`` over ONE K/V head, on an arena
+# that gives a K/V head a page of its own (``[layers, blocks * Hkv, BS, D]``):
+# the same body under a name of its own, so a trace tells the two apart.
+# --------------------------------------------------------------------------- #
+def paged_sparse_tile_pages(g, D, BS, columns, dtype) -> int:
+    """Pages a tile of the kernel ``paged_sparse_attention`` holds for rows
+    of ``g`` query heads on one K/V head of ``D`` lanes under tables of
+    ``columns`` chosen pages (0: the gather reference)."""
+    if (not _pallas.use_kernel("paged_sparse_attention")
+            or not gqa_kernel_shape_ok(g, 1, D, BS, dtype)
+            or not _pallas.single_device()):
+        return 0
+    return paged_tile_pages(BS, columns, 1, D, dtype)
+
+
+def paged_sparse_attention(q, k_arena, v_arena, layer, chosen, lengths):
+    """q ``[rows, 1, g, D]``, a row a (token, K/V head); the arena ``[layers,
+    pages, BS, D]``, a page one K/V head's block; ``chosen [rows, columns]``
+    the physical pages the row attends, in logical order; ``lengths [rows]``
+    the keys of those pages that lie before the query.  The kernel where
+    :func:`paged_sparse_tile_pages` says so, else the layer sliced out and
+    the gather reference."""
+    _, _, g, D = q.shape
+    BS = k_arena.shape[2]
+    assert k_arena.shape[3] == D, "a K/V head a page"
+    if paged_sparse_tile_pages(g, D, BS, chosen.shape[1], k_arena.dtype):
+        return _paged_gqa_call(q, k_arena, v_arena, layer, chosen, lengths, None,
+                               name="paged_sparse_attention")
+    kl = jax.lax.dynamic_index_in_dim(k_arena, layer, 0, keepdims=False)
+    vl = jax.lax.dynamic_index_in_dim(v_arena, layer, 0, keepdims=False)
+    return paged_attention_reference(q, kl, vl, chosen, lengths)
 
 
 # --------------------------------------------------------------------------- #

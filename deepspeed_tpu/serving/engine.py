@@ -390,7 +390,27 @@ class ServingEngine:
         # a layer group a kind of the model's layer pattern, named by its
         # window: ``num_blocks`` blocks of ALL layers are ``num_blocks *
         # groups`` pages of one group's layers each, one pool
-        self._windows = tuple(kind.window for kind in mcfg.pattern)
+        self._windows = mcfg.page_groups
+        self._hybrid = mcfg.hybrid
+        if self._hybrid:
+            from deepspeed_tpu.models import hybrid
+            for on, mechanism, what in (
+                    (cfg.prefix_cache, "prefix_cache", "shares full blocks of "
+                     "K and V between sequences"),
+                    (cfg.kv_tiering, "kv_tiering", "spills a sequence's blocks "
+                     "of K and V and restores them")):
+                if on:
+                    raise ValueError(
+                        f"init_serving: {mechanism} {what}; this model's "
+                        f"{mcfg.mixers.count('linear')} linear layers hold a "
+                        f"recurrent state a slot and its "
+                        f"{mcfg.mixers.count('sparse')} sparse layers a "
+                        f"compressed-key cache, which no block of K and V "
+                        f"carries (a preempted request is recomputed)")
+            if cfg.prefill_chunk % mcfg.sparse.stride:
+                raise ValueError(
+                    f"init_serving: prefill_chunk {cfg.prefill_chunk} is not "
+                    f"whole strides of {mcfg.sparse.stride} compressed keys")
         if len(mcfg.cache_lanes) != 2 and (cfg.kv_tiering or cfg.prefix_cache):
             raise ValueError(
                 "init_serving: kv_tiering and prefix_cache spill and share "
@@ -410,6 +430,9 @@ class ServingEngine:
         self._layout = StepLayout.of(self.alloc, cfg.max_batch_size,
                                      cfg.prefill_chunk)
         self._tables = self._empty_tables()
+        # what a hybrid stack caches beside K and V (compressed keys, the
+        # linear layers' states): donated state of the step like the arena
+        self._aux = self._new_aux()
 
         # ---- tiered spill/restage + prefix sharing (both opt-in) ---------- #
         self.tiering: Optional[KVTieringManager] = None
@@ -434,7 +457,16 @@ class ServingEngine:
         from deepspeed_tpu.ops.pallas.decode_attention import (
             paged_layer_chunk_queries, paged_layer_tile_pages,
             paged_mla_chunk_queries, paged_mla_tile_pages)
-        if mcfg.kv_lora_rank:
+        if self._hybrid:
+            # a sparse layer attends a row a (token, K/V head), under the
+            # table of the pages that token chose (``models/hybrid.py``)
+            from deepspeed_tpu.ops.pallas.decode_attention import (
+                paged_sparse_tile_pages)
+            self.paged_tile_pages = paged_sparse_tile_pages(
+                mcfg.n_head // mcfg.kv_heads, mcfg.head_dim, cfg.block_size,
+                hybrid.table_columns(mcfg, cfg.block_size), self.dtype)
+            queries = 1
+        elif mcfg.kv_lora_rank:
             shape = (mcfg.cache_lanes[0], mcfg.kv_lora_rank, cfg.block_size,
                      self.max_blocks_per_seq, self.dtype)
             self.paged_tile_pages = paged_mla_tile_pages(*shape)
@@ -451,7 +483,8 @@ class ServingEngine:
         # rows where the program holds ``slots + chunk`` tokens
         self.chunk_queries_per_row = queries
         self.attention_rows = (cfg.max_batch_size
-                               + cfg.prefill_chunk // queries)
+                               + cfg.prefill_chunk // queries) * (
+                                   mcfg.kv_heads if self._hybrid else 1)
         # bytes the arena holds a token a layer (every array of the cache
         # spec)
         self.cache_bytes_per_token = (sum(mcfg.cache_lanes)
@@ -460,13 +493,20 @@ class ServingEngine:
         # ---- the (single) jitted step ------------------------------------ #
         layout = self._layout
 
-        def step_fn(params, packed, kp, vp, state):
+        def step_fn(params, packed, kp, vp, state, aux=None):
             ids, positions, state, tables, wb, wo = unpack_step(
                 layout, packed, state)
-            moe = {"with_expert_counts": True} if self._moe_experts else {}
+            more = {"with_expert_counts": True} if self._moe_experts else {}
+            if aux is not None:
+                # a hybrid stack's step takes its state, and each row's slot
+                # and whether it carries a sequence, and gives the state back
+                rows = packed[:4 * layout.rows].reshape(layout.rows, 4)
+                more = {"aux": aux, "slots": rows[:, 2], "live": rows[:, 3] != 0}
             logits, kp, vp, *counts = model.paged_step(
                 params, ids, positions, kp, vp, tables, wb, wo,
-                chunk=layout.rows - layout.slots, **moe)
+                chunk=layout.rows - layout.slots, **more)
+            if aux is not None:
+                (aux,), counts = counts, []
             if mcfg.padded_vocab != mcfg.vocab_size:
                 vmask = jnp.arange(mcfg.padded_vocab) < mcfg.vocab_size
                 logits = jnp.where(vmask[None, None], logits, -1e30)
@@ -475,11 +515,13 @@ class ServingEngine:
                 # an MoE model's expert counts ride behind the token row in
                 # the one int32 array the host fetches: no second transfer
                 tokens = jnp.concatenate([tokens.reshape(-1), *counts])
-            return tokens, kp, vp, state
+            return tokens, kp, vp, state, aux
 
         # arena and table donation = in-place update; CPU can't donate (jax
         # warns and copies), so only donate on real accelerators
-        donate = (2, 3, 4) if jax.default_backend() != "cpu" else ()
+        donate = (2, 3, 4) + (5,) * self._hybrid
+        if jax.default_backend() == "cpu":
+            donate = ()
         self._raw_step_fn = step_fn
         self._donate = donate
         self._step_fn = jax.jit(step_fn, donate_argnums=donate)
@@ -546,6 +588,35 @@ class ServingEngine:
         return PagedKVAllocator(cfg.num_blocks * len(self._windows),
                                 cfg.block_size, self.max_blocks_per_seq,
                                 windows=self._windows, chunk=cfg.prefill_chunk)
+
+    def _new_aux(self):
+        """A hybrid stack's compressed keys and states, zeroed (None for
+        every other model).  Nothing else ever zeroes a state: a prompt chunk
+        at position 0 starts from zero whatever the slot holds."""
+        if not self._hybrid:
+            return None
+        from deepspeed_tpu.models import hybrid
+        cfg = self._config
+        return hybrid.init_aux(self.module.cfg, cfg.num_blocks, cfg.block_size,
+                               cfg.max_batch_size, self.dtype)
+
+    def _hybrid_stats(self, rows) -> Dict[str, int]:
+        """What the sparse and the linear layers did in a step, from its
+        rows' positions (``rows``: the upload's ``[rows, 4]`` view): keys the
+        live rows attended against the keys resident before them, summed
+        over sparse layers and K/V heads; live rows at or under
+        ``dense_len``; slots whose state started from zero (a chunk at
+        position 0); bytes of state held."""
+        from deepspeed_tpu.models import hybrid
+        mcfg = self.module.cfg
+        t = rows[rows[:, 3] != 0, 1]
+        per = mcfg.mixers.count("sparse") * mcfg.kv_heads
+        first = rows[self._config.max_batch_size]
+        return {"sparse_keys_attended": int(hybrid.keys_attended(mcfg, t).sum()) * per,
+                "sparse_keys_resident": int((t + 1).sum()) * per,
+                "sparse_rows_dense": int((t + 1 <= mcfg.sparse.dense_len).sum()),
+                "state_slots_reset": int(first[3] != 0 and first[1] == 0),
+                "state_bytes": int(self._aux["state"].nbytes)}
 
     def _empty_tables(self):
         """The table state of an engine nobody is in: all trash, on the
@@ -662,12 +733,13 @@ class ServingEngine:
                 fault_point("serve.step", step=self.step_count, phase=phase)
                 state = (self._tables if reload is None
                          else jax.device_put(reload))
-                tokens, kp, vp, state = self._step_fn(
-                    self.params, packed, self._k_pages, self._v_pages, state)
+                tokens, kp, vp, state, aux = self._step_fn(
+                    self.params, packed, self._k_pages, self._v_pages, state,
+                    self._aux)
                 t_launch = self._clock()
             with self._span(f"serve.{phase}.fetch", **stats):
                 row = np.asarray(tokens).reshape(-1)
-                return row, kp, vp, state, t_launch, self._clock()
+                return row, kp, vp, state, aux, t_launch, self._clock()
         if self._bounded is None or not self._warm:
             out = work()
             self._warm = True
@@ -679,7 +751,8 @@ class ServingEngine:
                     f"serve {phase} step {self.step_count} exceeded its "
                     f"{e.deadline_s:.3f}s deadline", op=phase,
                     deadline_s=e.deadline_s, step=self.step_count) from e
-        row, self._k_pages, self._v_pages, self._tables, t_launch, t_result = out
+        (row, self._k_pages, self._v_pages, self._tables, self._aux, t_launch,
+         t_result) = out
         n = row.size - self._moe_experts
         self._expert_counts = row[n:]
         return row[:n], t_launch, t_result
@@ -717,6 +790,7 @@ class ServingEngine:
         self._k_pages, self._v_pages = init_arena(
             mcfg, cfg.num_blocks, cfg.block_size, dtype=self.dtype)
         self._tables = self._empty_tables()     # nobody has a slot again
+        self._aux = self._new_aux()
         if self.prefix is not None:
             # cached pins point at pre-incident arena content: rebuild
             self.prefix = PrefixCache(self.alloc,
@@ -886,6 +960,8 @@ class ServingEngine:
                     turnaround = self._turnaround(t_enter, t_launch, t_result)
                 self._t_result = t_result
                 moe_stats = self._moe_stats()
+                if self._hybrid:
+                    table_stats.update(self._hybrid_stats(rows))
             if pf is not None:
                 with self._span("serve.prefill.commit", **chunk):
                     # the chunk's last token is the row that yields the next

@@ -426,17 +426,21 @@ def init_arena(cfg, num_blocks: int, block_size: int, dtype=None):
     layer): ``[n_layer / P, num_blocks * P, block_size, lanes]`` for a layer
     pattern of ``P`` kinds (``num_blocks`` counts blocks of ALL layers; a
     page holds a block of the ``n_layer / P`` layers of one group).  ``P =
-    1``: ``[n_layer, num_blocks, ...]``.  Always a PAIR, as the step takes
-    it: K and V, or a latent cache's one array and None."""
+    1``: ``[n_layer, num_blocks, ...]``.  A hybrid stack pages its sparse
+    layers alone, a K/V head a page: ``[sparse layers, num_blocks * Hkv,
+    block_size, head_dim]`` (``cfg.arena_layout`` says which; what such a
+    stack caches beside K and V is ``models/hybrid.py:init_aux``'s).  Always
+    a PAIR, as the step takes it: K and V, or a latent cache's one array and
+    None."""
     import jax.numpy as jnp
     dtype = dtype or cfg.dtype
-    P = len(cfg.pattern)
-    arrays = [jnp.zeros((cfg.n_layer // P, num_blocks * P, block_size, lanes),
-                        dtype) for lanes in cfg.cache_lanes]
+    layers, pages, lanes = cfg.arena_layout
+    arrays = [jnp.zeros((layers, num_blocks * pages, block_size, n), dtype)
+              for n in lanes]
     return tuple(arrays + [None] * (2 - len(arrays)))
 
 
 def arena_bytes(cfg, num_blocks: int, block_size: int, dtype_bytes: int = 2) -> int:
     """Bytes :func:`init_arena` holds: every array of the cache spec."""
-    return (cfg.n_layer * num_blocks * block_size * sum(cfg.cache_lanes)
-            * dtype_bytes)
+    layers, pages, lanes = cfg.arena_layout
+    return layers * pages * num_blocks * block_size * sum(lanes) * dtype_bytes

@@ -247,6 +247,56 @@ def test_paged_gqa_kernel_compiles_at_smallthinker_heads(chip, window, MB):
     assert da.paged_layer_tile_pages(1, H, Hkv, D128, BS, MB, BF16, window=window) == 8
 
 
+def test_paged_sparse_kernel_compiles_at_minicpm_sala_heads(chip):
+    """MiniCPM-SALA's sparse layers as served: a row a (token, K/V head) of 16
+    query heads of 128 lanes, under a table of the 128 pages it chose (every
+    block up to ``dense_len``; 64 beyond), pages of 64 keys of ONE K/V head;
+    16 slots and a chunk of 512 tokens are 1,056 rows."""
+    g, BS, columns, rows, D128 = 16, 64, 128, (16 + 512) * 2, 128
+    arena = ((4, 11264 * 2, BS, D128), BF16)
+    text = _compiled_text(chip, da.paged_sparse_attention, ((rows, 1, g, D128), BF16),
+                          arena, arena, ((), jnp.int32), ((rows, columns), jnp.int32),
+                          ((rows,), jnp.int32))
+    assert _kernel_rows(text, "paged_sparse_attention") == [rows]
+    assert "dynamic-slice" not in text        # no layer of K and V sliced out
+    # eight pages of 16 KiB a tile, where 128 rows would be two
+    assert da.paged_sparse_tile_pages(g, D128, BS, columns, BF16) == 8
+    assert da.paged_tile_pages(16, 1024, 1, 4 * D128, BF16) == 8      # SmallThinker's, as it was
+
+
+def test_the_hybrid_step_walks_its_runs_of_layers(chip):
+    """The whole step of a MiniCPM-SALA stack of S L L S S at the published
+    widths: a scan a run of one kind; in a run of sparse layers the kernel
+    once for the 16 decode slots and once, under the branch a step without a
+    prompt takes the other side of, for the chunk's 512 tokens (a row a
+    K/V head each); and no gather of chosen keys into a dense array."""
+    from deepspeed_tpu.models import gpt, hybrid
+    from deepspeed_tpu.serving.kv_cache import init_arena
+    S, L = "minicpm4", "lightning-attn"
+    cfg = gpt.minicpm_sala_config(mixer_types=[S, L, L, S, S], first_layer=9, dtype=BF16)
+    model, slots, chunk, BS, NB, MB = gpt.GPT(cfg), 16, 512, 64, 1025, 768
+    rows = slots + chunk
+    shape = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=chip)
+    on_chip = lambda tree: jax.tree.map(lambda a: shape(a.shape, a.dtype), tree)
+    params = jax.tree.map(lambda p: shape(p.shape, BF16),
+                          jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
+    kp, vp = on_chip(jax.eval_shape(lambda: init_arena(cfg, NB, BS, dtype=BF16)))
+    aux = on_chip(jax.eval_shape(lambda: hybrid.init_aux(cfg, NB, BS, slots, BF16)))
+    step = lambda p, ids, pos, kp, vp, tb, wb, wo, aux, sl, live: model.paged_step(
+        p, ids, pos, kp, vp, tb, wb, wo, chunk=chunk, aux=aux, slots=sl, live=live)
+    text = jax.jit(step).lower(
+        params, shape((rows, 1), jnp.int32), shape((rows,), jnp.int32), kp, vp,
+        shape((rows, MB), jnp.int32), shape((rows, 1), jnp.int32),
+        shape((rows, 1), jnp.int32), aux, shape((rows,), jnp.int32),
+        shape((rows,), jnp.bool_)).compile().as_text()
+    assert _kernel_rows(text, "paged_sparse_attention") == [
+        2 * slots, 2 * slots, 2 * chunk, 2 * chunk]              # S, and S S
+    assert text.count("conditional(") >= 2
+    # nothing as large as a row's chosen keys (64 pages x 64 keys x 128 lanes)
+    # times the rows is ever made: the selection went into the table
+    assert not re.search(rf"bf16\[{2 * rows},(64|128),64,128\]", text)
+
+
 def _bank_matmul_compiles(chip, rows, G, K, N, stacked):
     """``grouped_matmul`` compiled for the chip on a bank ``[G, K, N]``, or
     ``stacked`` on the 8 layers' ``[8, G, K, N]`` with a traced layer, which

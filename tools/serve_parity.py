@@ -2,13 +2,15 @@
 reference, at the published widths: the chip comparison of the
 ``model-configs`` guide § 3 point 3, for any configuration file that names a
 ``serve`` block and a ``reference`` (``benchmarks/configs/olmoe-1b-7b.json``,
-``smallthinker-21b-a3b.json``, ``mistral-small-4-119b.json``).
+``smallthinker-21b-a3b.json``, ``mistral-small-4-119b.json``,
+``minicpm-sala-9b.json``).
 
 Run standalone on a TPU host (``chiprun --chips 1 -- python
 tools/serve_parity.py benchmarks/configs/smallthinker-21b-a3b.json``); any
 other platform is an error (exit 1).  Seeded bf16 weights; a seeded sample of
-prompts (one of them longer than the model's window, where it has one, or
-than the original positions of its YaRN rope) goes
+prompts (one of them longer than the model's window, where it has one, than
+the original positions of its YaRN rope, or than the ``dense_len`` under
+which its sparse layers attend every key) goes
 through ``init_serving()`` / ``submit().result()`` with the file's slots and
 chunk (prefill in chunks, then decode, on the program's kernels), and each
 served sequence through the file's reference in one full float32 forward pass:
@@ -30,7 +32,13 @@ cell's ``correct`` instead, all served sequences as one run's sample
 on every token's gap and the limit on the median noise scale; under the
 limits of the cell's own kind where that has a ``judge``), and exits 0
 when that counts nothing wrong.  ``--bank float8_e4m3fn`` serves with the
-expert bank rounded through that type: the reading a limit must REFUSE, exit 1.
+expert bank rounded through that type: the reading a limit must REFUSE, exit 1;
+``--weights float8_e4m3fn`` does the same to every matrix of the blocks (a
+dense model's control).  ``--patch JSON`` lays a patch over the configuration
+file first: the program in FLOAT32 against the reference, at a few layers of
+the published widths, is ``--patch '{"dtype": "float32", "serve": {"serving":
+{"dtype": "float32"}}, "model": {"kwargs": {...}}, "reference": {"kwargs":
+{...}}}'`` and must read a largest gap of rounding's size.
 """
 
 import argparse
@@ -95,6 +103,11 @@ def main(argv=None) -> int:
                     help="serve with the expert bank rounded through this type "
                          "(float8_e4m3fn: what a bank in the precision below "
                          "bf16 loses; the reference keeps the weights whole)")
+    ap.add_argument("--weights", default=None, metavar="DTYPE",
+                    help="serve with every matrix of the blocks rounded through "
+                         "this type (the reference keeps the weights whole)")
+    ap.add_argument("--patch", default=None, metavar="JSON",
+                    help="laid over the configuration file before anything is built")
     args = ap.parse_args(argv)
     import jax
     import jax.numpy as jnp
@@ -106,23 +119,30 @@ def main(argv=None) -> int:
     import deepspeed_tpu
     from benchmarks.kinds.serve_backlog_resident import check_sample
     from benchmarks.lib.build import model_from
-    from benchmarks.lib.cells import load_json, resolve
+    from benchmarks.lib.cells import load_json, merge, resolve
 
     config = load_json(args.config)
+    if args.patch:
+        merge(config, json.loads(args.patch))
     model = model_from(config)
     cfg, ref = model.cfg, config["reference"]
     dtype = jnp.dtype(config["dtype"])
     make_params = jax.jit(lambda key: jax.tree.map(lambda p: p.astype(dtype),
                                                    model.init_params(key)))
     params = served_params = make_params(jax.random.PRNGKey(27))
+    # the barrier keeps a rounding: XLA takes a convert down and up again
+    # inside one program for excess precision it may leave out
+    low = lambda w: jax.lax.optimization_barrier(
+        w.astype(jnp.dtype(args.bank or args.weights))).astype(w.dtype)
+    if args.weights:
+        served_params = jax.jit(lambda p: dict(p, blocks=jax.tree_util.tree_map_with_path(
+            lambda path, w: low(w) if path[-1].key.endswith("_w") else w, p["blocks"])),
+            donate_argnums=0)(params)
+        params = None
     if args.bank:
         # rounded in place (the tree donated): a bank and its rounded copy do
         # not both fit beside an arena; the reference's weights are made
         # again from the seed once the engine is gone
-        # the barrier keeps the rounding: XLA takes a convert down and up
-        # again inside one program for excess precision it may leave out
-        low = lambda w: jax.lax.optimization_barrier(
-            w.astype(jnp.dtype(args.bank))).astype(w.dtype)
         served_params = jax.jit(lambda p: dict(p, blocks=dict(p["blocks"], moe=dict(
             p["blocks"]["moe"],
             experts=jax.tree.map(low, p["blocks"]["moe"]["experts"])))),
@@ -137,6 +157,8 @@ def main(argv=None) -> int:
         lengths.append(window + 200)            # prefill AND decode past the window
     if cfg.rope_yarn is not None:               # and past the stretched rope's
         lengths.append(cfg.rope_yarn.original_positions + 100)      # original range
+    if cfg.sparse is not None:                  # and past where every key is attended
+        lengths.append(cfg.sparse.dense_len + 200)
     prompts = [rng.integers(0, cfg.vocab_size, n).tolist() for n in lengths]
     futures = [eng.submit(p, max_new_tokens=args.new) for p in prompts]
     served = [f.result() for f in futures]
@@ -149,7 +171,8 @@ def main(argv=None) -> int:
 
     out = {"config": os.path.basename(args.config), "layers": cfg.n_layer,
            "device": jax.devices()[0].device_kind, "paged_tile_pages": tile_pages,
-           "margin": args.margin, "bank": args.bank or config["dtype"], "sequences": []}
+           "margin": args.margin, "bank": args.bank or config["dtype"],
+           "weights": args.weights or config["dtype"], "sequences": []}
     kw = ref["kwargs"]
     check = None
     if "hidden" in ref:          # a long context: the comparison of its cell's kind
