@@ -1615,7 +1615,7 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
     """
     assert cfg.scan_layers, "paged serving path requires scan_layers"
     from deepspeed_tpu.ops.pallas.decode_attention import (
-        paged_layer_attention, paged_mla_attention)
+        paged_layer_attention, paged_mla_attention, paged_mla_tile_runs)
     B, S = input_ids.shape
     assert not chunk or S == 1, "a prompt chunk is named among one-token rows"
     H, E = cfg.n_head, cfg.n_embd
@@ -1651,6 +1651,10 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
         moe = dict(blocks["moe"])
         bank = moe.pop("experts")
         blocks = {**blocks, "moe": moe}
+    # which tiles of the tables the latent kernel fetches with one copy: the
+    # same for every layer, so worked out here and not in the scan
+    tile_runs = (paged_mla_tile_runs(block_tables[0], k_pages, cfg.kv_lora_rank)
+                 if cfg.kv_lora_rank else None)
 
     def layer(j, carry, p):
         # ``li``: the layer's index inside its group ``j`` (the period)
@@ -1676,7 +1680,7 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
                     o = paged_mla_attention(
                         q, kp, li, block_tables[j], positions,
                         scale=1.0 / math.sqrt(cfg.head_dim), value_lanes=R,
-                        chunk=chunk)
+                        chunk=chunk, tile_runs=tile_runs)
                 o = jnp.einsum("bshr,rhd->bshd", o, w_uv).reshape(B, S, -1)
             else:
                 q, k, v = _project_qkv(cfg, p, h, dt, pos2d, kind)

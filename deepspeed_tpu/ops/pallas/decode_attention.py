@@ -389,15 +389,18 @@ def _rows_and_chunk(attend, chunk: int, Sq: int, q, block_tables, lengths,
     calls of the one ``attend``, their outputs back in the rows' order.  A
     chunk row whose first query carries nothing (an all-trash table at
     position 0) reads the trash block as its queries did alone; one whose
-    later queries carry nothing computes them for nobody."""
+    later queries carry nothing computes them for nobody.  ``block_tables``
+    may be a tuple of arrays a row (a table and what was worked out of it):
+    each is cut as the table is."""
     n, rows = q.shape[0] - chunk, chunk // Sq
     bc = None
     if bias is not None:                # [B, H, 1, T] -> [rows, H, Sq, T]
         H, T = bias.shape[1], bias.shape[3]
         bc = bias[n:, :, 0].reshape(rows, Sq, H, T).transpose(0, 2, 1, 3)
         bias = bias[:n]
-    o = attend(q[:n], block_tables[:n], lengths[:n], bias)
-    oc = attend(q[n:].reshape(rows, Sq, *q.shape[2:]), block_tables[n::Sq],
+    cut = lambda rows_of: jax.tree.map(rows_of, block_tables)
+    o = attend(q[:n], cut(lambda t: t[:n]), lengths[:n], bias)
+    oc = attend(q[n:].reshape(rows, Sq, *q.shape[2:]), cut(lambda t: t[n::Sq]),
                 lengths[n::Sq], bc)
     return jnp.concatenate([o, oc.reshape(chunk, 1, *oc.shape[2:])])
 
@@ -786,10 +789,17 @@ def paged_sparse_attention(q, k_arena, v_arena, layer, chosen, lengths):
 # K/V heads over two arenas; here there is one arena, one staged tile and one
 # product for all heads.
 # --------------------------------------------------------------------------- #
-# keys a tile: a key is 768 B here where SmallThinker's is 2,048, and the 32
-# heads are the rows of one small product, so a tile of 128 would be mostly
-# its own fixed cost
-_MLA_TILE_ROWS = 256
+# keys a tile, the unit of the COPY: a key is 768 B here where SmallThinker's
+# is 2,048, and what a tile costs is mostly fixed (0.37 us on a v5e beside
+# 0.18 us for every 256 keys once a tile is ONE copy; PERF.md § 6, PR 40).  A
+# tile of 1,024 would leave rows of a few thousand keys more of a last, short
+# tile, which is copied page by page, than it saves
+_MLA_TILE_ROWS = 512
+# keys an online-softmax update, the unit of the ATTEND: the 32 heads are the
+# rows of one small product, so 128 would be mostly its own fixed cost.  A
+# tile is attended in steps of this many keys, in order, so what comes out
+# does not depend on how many of them a copy brings
+_MLA_ATTEND_ROWS = 256
 
 
 def mla_kernel_shape_ok(lanes: int, value_lanes: int, block: int, dtype) -> bool:
@@ -802,6 +812,12 @@ def mla_kernel_shape_ok(lanes: int, value_lanes: int, block: int, dtype) -> bool
 
 def _mla_tile_pages(BS: int, MB: int) -> int:
     return min(max(1, _MLA_TILE_ROWS // BS), MB)
+
+
+def _mla_attend_rows(tile_rows: int) -> int:
+    """Keys an attend step of a tile of ``tile_rows`` takes: :data:`
+    _MLA_ATTEND_ROWS` where they divide the tile, else the whole tile."""
+    return tile_rows if tile_rows % _MLA_ATTEND_ROWS else _MLA_ATTEND_ROWS
 
 
 def paged_mla_tile_pages(lanes, value_lanes, BS, MB, dtype) -> int:
@@ -835,21 +851,68 @@ def paged_mla_attention_reference(q, pages, block_tables, lengths, *, scale,
     return jnp.einsum("bhqk,bkv->bqhv", p.astype(q.dtype), c[..., :value_lanes])
 
 
-def _paged_mla_kernel(lay_ref, len_ref, tbl_ref, nxt_ref, q_ref, c_hbm, o_ref,
-                      c_buf, sem, slot_ref, *, scale, bs, Sq, R, MB, G):
+def paged_mla_tile_runs(block_tables, arena, value_lanes):
+    """``[B, ceil(MB / G)]`` int32, 1 where a tile of ``G`` pages of a row's
+    table is a RUN that :func:`_paged_mla_kernel` fetches with one copy: its
+    ``G`` entries are consecutive pages in order, all inside the arena
+    (``tbl[t*G + j] == tbl[t*G] + j`` for every ``j < G`` and ``tbl[t*G] + G
+    <= pages``; no alignment is asked, so what a prompt's blocks happen to
+    form counts as what ``serving/kv_cache.py`` lays down in runs).  A short
+    last tile (``MB % G``) is none.  None where :func:`paged_mla_attention`
+    takes the gather reference.  The same for every layer: the step works it
+    out once, outside its scan over layers."""
+    _, NB, BS, W = arena.shape
+    B, MB = block_tables.shape
+    G = paged_mla_tile_pages(W, value_lanes, BS, MB, arena.dtype)
+    if not G:
+        return None
+    tiles = jnp.pad(jnp.asarray(block_tables, jnp.int32),
+                    ((0, 0), (0, -MB % G)), constant_values=-1).reshape(B, -1, G)
+    first = tiles[:, :, :1]
+    run = jnp.all(tiles - first == jnp.arange(G, dtype=jnp.int32), axis=-1)
+    return (run & (first[:, :, 0] + G <= NB)).astype(jnp.int32)
+
+
+def _paged_mla_kernel(lay_ref, len_ref, tbl_ref, nxt_ref, run_ref, nrun_ref,
+                      q_ref, c_hbm, o_ref, c_buf, sem, slot_ref, *, scale, bs,
+                      Sq, R, MB, G):
     """Grid (B,), a row a step, as ``_paged_gqa_kernel`` without a window
     (the arena whole and the layer a scalar, the row's table and the next
     row's as SMEM blocks, tiles of ``G`` pages in two buffers, the next tile
     fetched while this one is attended; ``_paged_kernel``'s docstring holds
-    the semaphore and zero-fill invariants, which are kept).  What differs:
-    ONE arena ``[layers, pages, bs, W]`` and one staged tile, which is the
-    key with all ``W`` lanes and the value with its first ``R``; all heads
-    are the rows of one ``[M, W] x [W, keys]`` product (``q_ref`` is ``[1, M,
-    W]``, row ``i * Sq + s`` head ``i`` at query ``s``)."""
+    the semaphore and zero-fill invariants, restated below for what differs).
+    ONE arena and one staged tile, which is the key with all ``W`` lanes and
+    the value with its first ``R``; all heads are the rows of one ``[M, W] x
+    [W, keys]`` product (``q_ref`` is ``[1, M, W]``, row ``i * Sq + s`` head
+    ``i`` at query ``s``).  A tile is the unit of the copy; it is attended in
+    steps of :func:`_mla_attend_rows` keys, one online-softmax update each, in
+    order (a step past the row's last key adds exactly nothing).
+
+    A tile is copied in one of TWO ways, by the flag of
+    :func:`paged_mla_tile_runs` (``run_ref [1, tiles]`` for the row,
+    ``nrun_ref`` for the next, beside the tables; the arena comes viewed
+    ``[layers, pages * bs, W]``):
+
+    * a RUN whose ``G`` pages are all live (``(t+1)*G <= nk``): ONE copy
+      of ``[G*bs, W]`` from row ``tbl[t*G] * bs`` of the layer, which
+      signals the buffer's semaphore ONCE;
+    * any other tile (no run, or the row's live pages end inside it): a
+      copy a live page, ``n = min(G, nk - t*G)`` signals.
+
+    The wait mirrors the start: the same two words (the flag, which row
+    ``b`` read through ``nrun_ref`` when it started row ``b + 1``'s first
+    tile and row ``b + 1`` reads through ``run_ref`` when it waits, and the
+    row's length) choose one wait of the whole buffer or ``n`` of a page, so
+    the semaphores stay balanced on every control path.  Both ways bring
+    live pages only, so a buffer holds zeros or what some table listed, as
+    ``_paged_kernel`` has it: a table may list the pages of a run before
+    they are written (a prompt's blocks are all taken at admission), and
+    those are fetched when the row has reached them, not before."""
     b = pl.program_id(0)
     layer = lay_ref[0]
     seq_len = len_ref[b]
     rows_t = G * bs
+    A = _mla_attend_rows(rows_t)
     M = q_ref.shape[1]
 
     def pages_of(row):                            # live (DMA'd) pages
@@ -859,15 +922,32 @@ def _paged_mla_kernel(lay_ref, len_ref, tbl_ref, nxt_ref, q_ref, c_hbm, o_ref,
     nt = (nk + G - 1) // G                        # live tiles, >= 1
 
     def tile_copies(row, t, slot, do):
-        def page(j, c):
-            col = t * G + j
-            phys = jnp.where(row == b, tbl_ref[0, col], nxt_ref[0, col])
-            dst = pl.ds(pl.multiple_of(j * bs, bs), bs)
-            do(pltpu.make_async_copy(c_hbm.at[layer, phys],
-                                     c_buf.at[slot, dst], sem.at[slot]))
-            return c
+        mine = row == b
+        entry = lambda col: jnp.where(mine, tbl_ref[0, col], nxt_ref[0, col])
+        live = pages_of(row) - t * G
 
-        jax.lax.fori_loop(0, jnp.clip(pages_of(row) - t * G, 0, G), page, 0)
+        def pages():
+            def page(j, c):
+                src = pl.ds(pl.multiple_of(entry(t * G + j) * bs, bs), bs)
+                dst = pl.ds(pl.multiple_of(j * bs, bs), bs)
+                do(pltpu.make_async_copy(c_hbm.at[layer, src],
+                                         c_buf.at[slot, dst], sem.at[slot]))
+                return c
+
+            jax.lax.fori_loop(0, jnp.clip(live, 0, G), page, 0)
+
+        if c_hbm.shape[1] < rows_t:         # an arena smaller than a tile
+            return pages()
+        is_run = (jnp.where(mine, run_ref[0, t], nrun_ref[0, t]) != 0) & (
+            live >= G)
+
+        @pl.when(is_run)
+        def _():
+            src = pl.ds(pl.multiple_of(entry(t * G) * bs, bs), rows_t)
+            do(pltpu.make_async_copy(c_hbm.at[layer, src], c_buf.at[slot],
+                                     sem.at[slot]))
+
+        pl.when(jnp.logical_not(is_run))(pages)
 
     start = lambda cp: cp.start()
 
@@ -890,22 +970,24 @@ def _paged_mla_kernel(lay_ref, len_ref, tbl_ref, nxt_ref, q_ref, c_hbm, o_ref,
                         1 - slot, start)
 
         tile_copies(b, t, slot, lambda cp: cp.wait())
-        qpos = seq_len + jax.lax.broadcasted_iota(jnp.int32, (M, rows_t), 0) % Sq
-        cols = t * rows_t + jax.lax.broadcasted_iota(jnp.int32, (M, rows_t), 1)
-        valid = (cols <= qpos) & (cols < nk * bs)
         m, l, acc = carry
-        c = c_buf[slot]                                       # [rows_t, W]
-        s = jax.lax.dot_general(q, c, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        s = jnp.where(valid, s, NEG_INF)                      # [M, rows_t]
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * alpha + jax.lax.dot_general(
-            p.astype(c.dtype), c_buf[slot, :, :R], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return m_new, l, acc
+        for lo in range(0, rows_t, A):            # the tile, A keys a step
+            qpos = seq_len + jax.lax.broadcasted_iota(jnp.int32, (M, A), 0) % Sq
+            cols = t * rows_t + lo + jax.lax.broadcasted_iota(jnp.int32, (M, A), 1)
+            valid = (cols <= qpos) & (cols < nk * bs)
+            c = c_buf[slot, lo:lo + A]                        # [A, W]
+            s = jax.lax.dot_general(q, c, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32) * scale
+            s = jnp.where(valid, s, NEG_INF)                  # [M, A]
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m - m_new)
+            l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc = acc * alpha + jax.lax.dot_general(
+                p.astype(c.dtype), c_buf[slot, lo:lo + A, :R],
+                (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            m = m_new
+        return m, l, acc
 
     carry = (jnp.full((M, 1), NEG_INF, jnp.float32),
              jnp.zeros((M, 1), jnp.float32), jnp.zeros((M, R), jnp.float32))
@@ -914,25 +996,28 @@ def _paged_mla_kernel(lay_ref, len_ref, tbl_ref, nxt_ref, q_ref, c_hbm, o_ref,
     o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
-def _paged_mla_call(q, arena, layer, block_tables, lengths, scale, R, G):
+def _paged_mla_call(q, arena, layer, block_tables, tile_runs, lengths, scale,
+                    R, G):
     B, Sq, H, W = q.shape
-    BS = arena.shape[2]
+    L, NB, BS, _ = arena.shape
     MB = block_tables.shape[1]
     block_tables = jnp.asarray(block_tables, jnp.int32)[:, None, :]
+    tile_runs = jnp.asarray(tile_runs, jnp.int32)[:, None, :]
     sublane = 8 * 4 // np.dtype(q.dtype).itemsize
     M = -(-H * Sq // sublane) * sublane
     # [B, Sq, H, W] -> [B, H*Sq, W]: a head's queries together
     qm = jnp.pad(q.transpose(0, 2, 1, 3).reshape(B, H * Sq, W),
                  ((0, 0), (0, M - H * Sq), (0, 0)))
     row = lambda b, *_: (b, 0, 0)
+    nxt = lambda b, *_: (jnp.minimum(b + 1, B - 1), 0, 0)
+    smem = lambda cols, at: pl.BlockSpec((None, 1, cols), at,
+                                         memory_space=pltpu.MemorySpace.SMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                    # layer, lengths
         grid=(B,),
         in_specs=[
-            pl.BlockSpec((None, 1, MB), row, memory_space=pltpu.MemorySpace.SMEM),
-            pl.BlockSpec((None, 1, MB),
-                         lambda b, *_: (jnp.minimum(b + 1, B - 1), 0, 0),
-                         memory_space=pltpu.MemorySpace.SMEM),
+            smem(MB, row), smem(MB, nxt),
+            smem(tile_runs.shape[2], row), smem(tile_runs.shape[2], nxt),
             pl.BlockSpec((1, M, W), row),
             pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
         ],
@@ -953,7 +1038,8 @@ def _paged_mla_call(q, arena, layer, block_tables, lengths, scale, R, G):
         interpret=_pallas.interpret(),
         name="paged_mla_attention",
     )(jnp.asarray(layer, jnp.int32).reshape(1), jnp.asarray(lengths, jnp.int32),
-      block_tables, block_tables, qm, arena)
+      block_tables, block_tables, tile_runs, tile_runs, qm,
+      arena.reshape(L, NB * BS, W))               # a page's rows lie together
     return out[:, :H * Sq].reshape(B, H, Sq, R).transpose(0, 2, 1, 3)
 
 
@@ -961,11 +1047,12 @@ def paged_mla_chunk_queries(chunk, H, lanes, value_lanes, BS, MB, dtype) -> int:
     """:func:`paged_chunk_queries` of the call :func:`paged_mla_attention`
     makes for a prompt chunk: all ``H`` heads the rows of one product."""
     return paged_chunk_queries(chunk, H, 1, lanes, value_lanes,
-                               _mla_tile_pages(BS, MB) * BS, dtype)
+                               _mla_attend_rows(_mla_tile_pages(BS, MB) * BS),
+                               dtype)
 
 
 def paged_mla_attention(q, arena, layer, block_tables, lengths, *, scale,
-                        value_lanes, chunk: int = 0):
+                        value_lanes, chunk: int = 0, tile_runs=None):
     """Block-table latent attention of layer ``layer`` of the ONE-array arena
     ``[layers, pages, BS, W]``: q ``[B, Sq, H, W]`` (a head's query moved
     into the cached vector's space, zeros where the vector is padding)
@@ -973,21 +1060,27 @@ def paged_mla_attention(q, arena, layer, block_tables, lengths, *, scale,
     vector's first ``value_lanes`` lanes are the value -> ``[B, Sq, H,
     value_lanes]``.  With ``chunk`` the rows hold one query each and the
     last ``chunk`` are a prompt chunk, attended packed
-    (:func:`_rows_and_chunk`).  The kernel where
-    :func:`paged_mla_tile_pages` says so, else the layer sliced out and the
-    gather reference."""
+    (:func:`_rows_and_chunk`).  ``tile_runs``: :func:`paged_mla_tile_runs` of
+    these tables, from a caller that has it already (every layer of a step
+    reads the same tables).  The kernel where :func:`paged_mla_tile_pages`
+    says so, else the layer sliced out and the gather reference."""
     _, _, BS, W = arena.shape
     MB = block_tables.shape[1]
+    G = paged_mla_tile_pages(W, value_lanes, BS, MB, arena.dtype)
+    if tile_runs is None:
+        tile_runs = paged_mla_tile_runs(block_tables, arena, value_lanes)
     if chunk:
-        attend = lambda q, tables, lens, _: paged_mla_attention(
-            q, arena, layer, tables, lens, scale=scale, value_lanes=value_lanes)
+        def attend(q, tables, lens, _):
+            tables, runs = tables
+            return paged_mla_attention(q, arena, layer, tables, lens, scale=scale,
+                                       value_lanes=value_lanes, tile_runs=runs)
         Sq = paged_mla_chunk_queries(chunk, q.shape[2], W, value_lanes, BS, MB,
                                      q.dtype)
-        return _rows_and_chunk(attend, chunk, Sq, q, block_tables, lengths)
-    G = paged_mla_tile_pages(W, value_lanes, BS, MB, arena.dtype)
+        return _rows_and_chunk(attend, chunk, Sq, q, (block_tables, tile_runs),
+                               lengths)
     if G:
-        return _paged_mla_call(q, arena, layer, block_tables, lengths, scale,
-                               value_lanes, G)
+        return _paged_mla_call(q, arena, layer, block_tables, tile_runs,
+                               lengths, scale, value_lanes, G)
     pages = jax.lax.dynamic_index_in_dim(arena, layer, 0, keepdims=False)
     return paged_mla_attention_reference(q, pages, block_tables, lengths,
                                          scale=scale, value_lanes=value_lanes)

@@ -81,7 +81,7 @@ from deepspeed_tpu.utils.logging import log_dist
 #: ``submit()`` is ``serve.submit`` (stat ``rid``); a request's first token
 #: leaves one zero-length ``serve.first_token`` with its waits as stats.
 #: ``serve.stats`` carries what the step's tables and attention cost
-#: (``table_edits``, ``table_reloads``, ``upload_bytes``,
+#: (``table_edits``, ``table_reloads``, ``upload_bytes``, ``tile_runs_pct``,
 #: ``chunk_queries_per_row``, ``attention_rows``) and, in a step that follows
 #: a step with a program, the step's turn-round as DURATIONS on the engine's
 #: one clock (:data:`TURNAROUND_STATS`): they need no alignment with any
@@ -392,6 +392,7 @@ class ServingEngine:
         # groups`` pages of one group's layers each, one pool
         self._windows = mcfg.page_groups
         self._hybrid = mcfg.hybrid
+        self._run_blocks = 1        # blocks the allocator lays down together
         if self._hybrid:
             from deepspeed_tpu.models import hybrid
             for on, mechanism, what in (
@@ -422,6 +423,48 @@ class ServingEngine:
                 "blocks of ONE table a sequence; this model's layer pattern "
                 f"keeps {len(self._windows)} (windows {self._windows}), and "
                 "a window group gives its blocks back")
+        # which attention the decode program gets (static per engine): the
+        # paged kernel's pages per tile, 0 on the einsum path; a stat of
+        # every step
+        from deepspeed_tpu.ops.pallas.decode_attention import (
+            paged_layer_chunk_queries, paged_layer_tile_pages,
+            paged_mla_chunk_queries, paged_mla_tile_pages)
+        if self._hybrid:
+            # a sparse layer attends a row a (token, K/V head), under the
+            # table of the pages that token chose (``models/hybrid.py``)
+            from deepspeed_tpu.ops.pallas.decode_attention import (
+                paged_sparse_tile_pages)
+            self.paged_tile_pages = paged_sparse_tile_pages(
+                mcfg.n_head // mcfg.kv_heads, mcfg.head_dim, cfg.block_size,
+                hybrid.table_columns(mcfg, cfg.block_size), self.dtype)
+            queries = 1
+        elif mcfg.kv_lora_rank:
+            shape = (mcfg.cache_lanes[0], mcfg.kv_lora_rank, cfg.block_size,
+                     self.max_blocks_per_seq, self.dtype)
+            self.paged_tile_pages = paged_mla_tile_pages(*shape)
+            queries = paged_mla_chunk_queries(cfg.prefill_chunk, mcfg.n_head,
+                                              *shape)
+            # the one kernel that fetches a tile of consecutive pages with
+            # one copy: its tables grow in runs of a tile
+            self._run_blocks = max(1, self.paged_tile_pages)
+        else:
+            shape = (mcfg.n_head, mcfg.kv_heads, mcfg.head_dim, cfg.block_size,
+                     self.max_blocks_per_seq, self.dtype,
+                     mcfg.position_encoding == "alibi", self._windows[0])
+            self.paged_tile_pages = paged_layer_tile_pages(1, *shape)
+            queries = paged_layer_chunk_queries(cfg.prefill_chunk, *shape)
+        # how a layer's attention takes the step's prompt chunk (static too):
+        # ``queries`` consecutive tokens a row, so its calls run this many
+        # rows where the program holds ``slots + chunk`` tokens
+        self.chunk_queries_per_row = queries
+        self.attention_rows = (cfg.max_batch_size
+                               + cfg.prefill_chunk // queries) * (
+                                   mcfg.kv_heads if self._hybrid else 1)
+        # bytes the arena holds a token a layer (every array of the cache
+        # spec)
+        self.cache_bytes_per_token = (sum(mcfg.cache_lanes)
+                                      * np.dtype(self.dtype).itemsize)
+
         self.alloc = self._new_allocator()
         self.sched = ServingScheduler(cfg, self.alloc, cfg.max_batch_size)
         self.sched.on_preempt = self._on_preempt
@@ -450,45 +493,6 @@ class ServingEngine:
                                       max_blocks=cfg.prefix_cache_blocks)
             self.sched.prefix_cache = self.prefix
             self.sched.on_prefix_hit = self._on_prefix_hit
-
-        # which attention the decode program gets (static per engine): the
-        # paged kernel's pages per tile, 0 on the einsum path; a stat of
-        # every step
-        from deepspeed_tpu.ops.pallas.decode_attention import (
-            paged_layer_chunk_queries, paged_layer_tile_pages,
-            paged_mla_chunk_queries, paged_mla_tile_pages)
-        if self._hybrid:
-            # a sparse layer attends a row a (token, K/V head), under the
-            # table of the pages that token chose (``models/hybrid.py``)
-            from deepspeed_tpu.ops.pallas.decode_attention import (
-                paged_sparse_tile_pages)
-            self.paged_tile_pages = paged_sparse_tile_pages(
-                mcfg.n_head // mcfg.kv_heads, mcfg.head_dim, cfg.block_size,
-                hybrid.table_columns(mcfg, cfg.block_size), self.dtype)
-            queries = 1
-        elif mcfg.kv_lora_rank:
-            shape = (mcfg.cache_lanes[0], mcfg.kv_lora_rank, cfg.block_size,
-                     self.max_blocks_per_seq, self.dtype)
-            self.paged_tile_pages = paged_mla_tile_pages(*shape)
-            queries = paged_mla_chunk_queries(cfg.prefill_chunk, mcfg.n_head,
-                                              *shape)
-        else:
-            shape = (mcfg.n_head, mcfg.kv_heads, mcfg.head_dim, cfg.block_size,
-                     self.max_blocks_per_seq, self.dtype,
-                     mcfg.position_encoding == "alibi", self._windows[0])
-            self.paged_tile_pages = paged_layer_tile_pages(1, *shape)
-            queries = paged_layer_chunk_queries(cfg.prefill_chunk, *shape)
-        # how a layer's attention takes the step's prompt chunk (static too):
-        # ``queries`` consecutive tokens a row, so its calls run this many
-        # rows where the program holds ``slots + chunk`` tokens
-        self.chunk_queries_per_row = queries
-        self.attention_rows = (cfg.max_batch_size
-                               + cfg.prefill_chunk // queries) * (
-                                   mcfg.kv_heads if self._hybrid else 1)
-        # bytes the arena holds a token a layer (every array of the cache
-        # spec)
-        self.cache_bytes_per_token = (sum(mcfg.cache_lanes)
-                                      * np.dtype(self.dtype).itemsize)
 
         # ---- the (single) jitted step ------------------------------------ #
         layout = self._layout
@@ -587,7 +591,8 @@ class ServingEngine:
         cfg = self._config
         return PagedKVAllocator(cfg.num_blocks * len(self._windows),
                                 cfg.block_size, self.max_blocks_per_seq,
-                                windows=self._windows, chunk=cfg.prefill_chunk)
+                                windows=self._windows, chunk=cfg.prefill_chunk,
+                                run_blocks=self._run_blocks)
 
     def _new_aux(self):
         """A hybrid stack's compressed keys and states, zeroed (None for
@@ -980,7 +985,7 @@ class ServingEngine:
         # (0: no chunk in the step) and the rows its calls ran; and the step's
         # own turn-round, where there was one
         on_span = dict(
-            table_stats,
+            table_stats, tile_runs_pct=self._tile_runs_pct(),
             chunk_queries_per_row=self.chunk_queries_per_row if n_chunk else 0,
             attention_rows=self.attention_rows if runs else 0, **turnaround)
         with self._span("serve.stats", **on_span):
@@ -990,6 +995,15 @@ class ServingEngine:
             self._t_result = None       # from here the chip waits for WORK
         self._t_exit = self._clock()
         return stats
+
+    def _tile_runs_pct(self) -> float:
+        """Of the tiles the live sequences' full-attention tables hold, the
+        share that are whole runs of consecutive pages: what the attention
+        kernel fetches with one copy where it can (the allocator's own
+        counts, nothing read from the device).  0 where the kernel copies
+        page by page (``run_blocks`` 1: no tile is counted)."""
+        held = self.alloc.tiles_held
+        return 100.0 * self.alloc.tiles_run / held if held else 0.0
 
     def _turnaround(self, t_enter: float, t_launch: float,
                     t_result: float) -> Dict[str, float]:

@@ -10,7 +10,10 @@ latent attention); this module owns the host-side bookkeeping:
 
 * a free list of physical block ids (block 0 is reserved as the TRASH
   block: padded/inactive tokens scatter their K/V there, so the compiled
-  step needs no write predication);
+  step needs no write predication); where the kernel that reads the arena
+  fetches a tile of consecutive pages with one copy, the free blocks are
+  also kept as whole RUNS of a tile, and a full-attention table grows a run
+  at a time (``run_blocks``, below);
 * a per-sequence block table in logical order, padded to
   ``max_blocks_per_seq`` with trash for the traced ``[B, MB]`` input;
 * **per-block refcounts**: a block may be shared by several sequences (the
@@ -53,10 +56,13 @@ def window_table_blocks(window: int, chunk: int, block_size: int) -> int:
 class _Run:
     """One sequence's blocks in ONE group, in logical order: ``blocks[i]`` is
     logical block ``first + i``.  A full group's ``first`` stays 0."""
-    __slots__ = ("first", "blocks", "given_back", "grow")
+    __slots__ = ("first", "blocks", "given_back", "grow", "streak", "tiles_run")
 
     def __init__(self):
         self.first, self.blocks, self.given_back, self.grow = 0, [], 0, 0
+        # under ``run_blocks > 1``, of a full group: how many of the last
+        # blocks are physically consecutive, and how many whole tiles are
+        self.streak = self.tiles_run = 0
 
     @property
     def end(self) -> int:
@@ -81,15 +87,41 @@ class PagedKVAllocator:
     (:func:`window_table_blocks`): logical block ``b`` sits in column
     ``b % width``.  The default, one full group, is the allocator every
     homogeneous model has.
+
+    ``run_blocks`` (``G``; the engine passes the pages of a tile of the
+    kernel that reads a full group, where that kernel fetches ``G``
+    consecutive pages with one copy; 1, the default, is the allocator as it
+    was, block id for block id).  A RUN is ``G`` physically consecutive
+    blocks aligned to ``G``.  A full group's table grows in runs: when a
+    sequence first needs logical block ``k*G`` it takes a whole free run for
+    logical blocks ``k*G .. k*G+G-1`` and is handed the run's blocks in
+    order as it grows, so every full tile of its table is one run.  What it
+    has not been handed yet is EARMARKED, NOT OWNED: those blocks have no
+    reference and count as free in :attr:`free_blocks`,
+    :attr:`blocks_in_use` and :meth:`can_allocate`, exactly as if they lay
+    on the free list.  A growth that the loose free blocks and the whole
+    free runs (broken up, if need be) cannot cover takes earmarked blocks
+    from the end of their runs, the youngest earmark first, before
+    :meth:`allocate` returns False: nothing is refused that ``run_blocks =
+    1`` would grant.  (The sequence robbed goes on with loose blocks for the
+    rest of that tile, which is then no run.)  A freed block joins the loose
+    ones, and when all ``G`` of a run are loose the run is whole again.
+    Window groups, :meth:`adopt`, :meth:`ref` and :meth:`unref` deal in
+    single blocks as before.  ``tiles_held`` and ``tiles_run`` count, over
+    the full groups of the live sequences, the tiles their tables hold (the
+    last one may be short) and those that are whole runs in order, aligned
+    or not: what the kernel fetches with one copy.
     """
 
     TRASH = 0
 
     def __init__(self, num_blocks: int, block_size: int,
                  max_blocks_per_seq: int,
-                 windows: Sequence[Optional[int]] = (None,), chunk: int = 1):
+                 windows: Sequence[Optional[int]] = (None,), chunk: int = 1,
+                 run_blocks: int = 1):
         assert num_blocks >= 2, "arena needs >= 1 usable block + trash"
         assert block_size >= 1 and max_blocks_per_seq >= 1
+        assert run_blocks >= 1
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
         self.max_blocks_per_seq = int(max_blocks_per_seq)
@@ -103,8 +135,25 @@ class PagedKVAllocator:
             for w in self.windows)
         # LIFO free list: recently-freed blocks are reused first (their
         # pages are hot, and stale contents are fully overwritten before
-        # any masked-in position can read them)
-        self._free: List[int] = list(range(self.num_blocks - 1, 0, -1))
+        # any masked-in position can read them).  A dict in insertion order:
+        # ``popitem`` is the list's ``pop``, and a run that re-forms takes
+        # its blocks out of the middle
+        G = self.run_blocks = int(run_blocks)
+        whole = range(G, self.num_blocks - G + 1, G) if G > 1 else ()
+        self._free: Dict[int, None] = dict.fromkeys(
+            b for b in range(self.num_blocks - 1, 0, -1)
+            if not (whole and whole[0] <= b < whole[-1] + G))
+        # the whole free runs, by their first block (LIFO, lowest first);
+        # how many blocks of each run are loose in ``_free``; the open
+        # earmarks, oldest first: a run's first block -> [the ``_Run`` it
+        # is for, lo, hi], blocks ``lo .. hi-1`` not handed out yet
+        self._free_runs: List[int] = list(reversed(whole))
+        self._loose_in: Dict[int, int] = {}
+        for b in (self._free if G > 1 else ()):
+            self._loose_in[b - b % G] = self._loose_in.get(b - b % G, 0) + 1
+        self._earmarks: Dict[int, list] = {}
+        self._earmarked = 0
+        self.tiles_held = self.tiles_run = 0
         self._owned: Dict[object, List[_Run]] = {}   # seq id -> a run a group
         # block id -> total references (sequence owners + prefix-cache pins);
         # a block is live iff it has an entry here, free iff it is in _free
@@ -124,15 +173,22 @@ class PagedKVAllocator:
 
     # -- capacity queries -------------------------------------------------- #
     @property
+    def free_pages(self) -> int:
+        """Pages nobody holds a reference on: loose, in whole free runs, and
+        earmarked."""
+        return (len(self._free) + self.run_blocks * len(self._free_runs)
+                + self._earmarked)
+
+    @property
     def free_blocks(self) -> int:
         """Free capacity in blocks of ALL layers (a page of each group)."""
-        return len(self._free) // self.n_groups
+        return self.free_pages // self.n_groups
 
     @property
     def blocks_in_use(self) -> int:
         """Pages in use in the caller's unit, blocks of ALL layers (rounded
         up): a share of ``num_blocks / n_groups``."""
-        return -(-((self.num_blocks - 1) - len(self._free)) // self.n_groups)
+        return -(-((self.num_blocks - 1) - self.free_pages) // self.n_groups)
 
     def blocks_for_tokens(self, n_tokens: int) -> int:
         return -(-max(0, int(n_tokens)) // self.block_size)
@@ -157,7 +213,7 @@ class PagedKVAllocator:
 
     def can_allocate(self, seq_id, n_tokens: int) -> bool:
         held = sum(len(r.blocks) for r in self._owned.get(seq_id, ()))
-        return self.pages_for_tokens(n_tokens) - held <= len(self._free)
+        return self.pages_for_tokens(n_tokens) - held <= self.free_pages
 
     # -- lifecycle --------------------------------------------------------- #
     def allocate(self, seq_id, n_tokens: int, resident: int = 0) -> bool:
@@ -175,7 +231,10 @@ class PagedKVAllocator:
         nonempty owner's lists are as they were after the giving back (the
         scheduler may already have written KV into those blocks; mutating
         them here would orphan live device state), and an owner that was
-        empty is removed rather than left as a zero-block entry."""
+        empty is removed rather than left as a zero-block entry.  Every free
+        page can be taken, an earmarked one too (the class docstring), so the
+        check is the same count under any ``run_blocks``, and no earmark is
+        touched by a growth that fails."""
         bs = self.block_size
         need = -(-max(0, int(n_tokens)) // bs)
         if need > self.max_blocks_per_seq:
@@ -197,16 +256,20 @@ class PagedKVAllocator:
                 total += run.grow
         if total == 0:                  # every decode step inside a block
             return True
-        if total > len(self._free):
+        if total > self.free_pages:
             return False
         if new:
             self._owned[seq_id] = runs
+        in_runs = self.run_blocks > 1
         for g, (run, window) in enumerate(zip(runs, self.windows)):
             end = run.end
-            for _ in range(run.grow):
-                b = self._free.pop()
-                self._refs[b] = 1
-                run.blocks.append(b)
+            if in_runs and window is None:
+                self._grow_in_runs(run, run.grow)
+            else:
+                for _ in range(run.grow):
+                    b = self._take_loose() if in_runs else self._free.popitem()[0]
+                    self._refs[b] = 1
+                    run.blocks.append(b)
             if run.grow > 0 and slot is not None:
                 self._record(g, slot, end, run.blocks[end - run.first:])
             if run.grow > 0 and window is None:
@@ -230,6 +293,89 @@ class PagedKVAllocator:
         self.given_back_total += n
         self.given_back_ever += n
 
+    # -- runs (``run_blocks > 1`` only) ------------------------------------- #
+    def _take_loose(self) -> int:
+        """A free block for whoever has no run to take it from: a loose one;
+        else a whole free run is broken up; else the youngest earmark loses
+        its last block."""
+        G = self.run_blocks
+        if not self._free:
+            if not self._free_runs:
+                base, mark = next(reversed(self._earmarks.items()))
+                mark[2] -= 1
+                self._earmarked -= 1
+                if mark[1] == mark[2]:
+                    del self._earmarks[base]
+                return mark[2]
+            base = self._free_runs.pop()
+            self._free.update(dict.fromkeys(range(base + G - 1, base - 1, -1)))
+            self._loose_in[base] = G
+        b, _ = self._free.popitem()
+        self._loose_in[b - b % G] -= 1
+        return b
+
+    def _grow_in_runs(self, run: _Run, n: int) -> None:
+        """``n`` more blocks for a full group's ``run``, each tile of its
+        table a whole free run while there is one."""
+        G, blocks = self.run_blocks, run.blocks
+        for i in range(len(blocks), len(blocks) + n):
+            j = i % G
+            # an open earmark of its own continues the table: the run whose
+            # first ``j`` blocks are the table's last ``j``
+            mark = self._earmarks.get(blocks[-1] - j + 1) if j else None
+            if mark is not None and mark[0] is run:
+                b = mark[1]
+                mark[1] += 1
+                self._earmarked -= 1
+                if mark[1] == mark[2]:
+                    del self._earmarks[b - j]
+            elif j == 0 and self._free_runs:
+                b = self._free_runs.pop()
+                self._earmarks[b] = [run, b + 1, b + G]
+                self._earmarked += G - 1
+            else:
+                b = self._take_loose()
+            self._refs[b] = 1
+            blocks.append(b)
+            self._count_tile(run, i)
+
+    def _count_tile(self, run: _Run, i: int) -> None:
+        """Logical block ``i`` has joined a full group's ``run``."""
+        blocks = run.blocks
+        run.streak = run.streak + 1 if i and blocks[i] == blocks[i - 1] + 1 else 1
+        j = i % self.run_blocks
+        if j == 0:
+            self.tiles_held += 1
+        elif j == self.run_blocks - 1 and run.streak >= self.run_blocks:
+            run.tiles_run += 1
+            self.tiles_run += 1
+
+    def _release(self, block: int) -> None:
+        """``block`` has lost its last reference: it is loose, and with it
+        its run may be whole again."""
+        self._free[block] = None
+        G = self.run_blocks
+        if G > 1:
+            base = block - block % G
+            n = self._loose_in[base] = self._loose_in.get(base, 0) + 1
+            if n == G:
+                for b in range(base, base + G):
+                    del self._free[b]
+                del self._loose_in[base]
+                self._free_runs.append(base)
+
+    def _drop_earmark(self, run: _Run) -> None:
+        """What was earmarked for ``run`` (it is being freed) is loose."""
+        G = self.run_blocks
+        j = len(run.blocks) % G
+        base = run.blocks[-1] - j + 1 if j else None
+        mark = self._earmarks.get(base)
+        if mark is not None and mark[0] is run:
+            del self._earmarks[base]
+            self._earmarked -= mark[2] - mark[1]
+            for b in range(mark[2] - 1, mark[1] - 1, -1):
+                self._release(b)
+
     def free(self, seq_id) -> int:
         """Drop ``seq_id``'s reference on every owned block; blocks whose
         last reference this was return to the free list.  Idempotent on
@@ -240,7 +386,12 @@ class PagedKVAllocator:
             # the row goes to trash whole, whatever was still to be said of it
             self._edits.pop(slot, None)
             self._cleared.add(slot)
+        in_runs = self.run_blocks > 1
         for g, run in enumerate(self._owned.pop(seq_id, ())):
+            if in_runs and self.windows[g] is None and run.blocks:
+                self._drop_earmark(run)
+                self.tiles_held -= -(-len(run.blocks) // self.run_blocks)
+                self.tiles_run -= run.tiles_run
             # unref in reverse logical order so unshared blocks re-enter the
             # LIFO free list in the same order the pre-refcount free() used
             for b in reversed(run.blocks):
@@ -276,7 +427,7 @@ class PagedKVAllocator:
             self._refs[block] = refs - 1
             return False
         del self._refs[block]
-        self._free.append(block)
+        self._release(block)
         return True
 
     def adopt(self, seq_id, blocks: List[int]) -> None:
@@ -293,6 +444,9 @@ class PagedKVAllocator:
             self.ref(b)
         run = _Run()
         run.blocks = list(blocks)
+        if self.run_blocks > 1:
+            for i in range(len(blocks)):
+                self._count_tile(run, i)
         self._owned[seq_id] = [run]
         self.pages_full += len(blocks)
 
@@ -380,9 +534,14 @@ class PagedKVAllocator:
         with refcount >= 1 — and a live block's references account for
         every sequence holding it (sharing beyond the owner count is the
         prefix cache's pin); a run fits its group's table; the page counts
-        are the runs'.  Raises AssertionError on violation."""
+        are the runs'.  Free is: loose, in a whole free run, or earmarked
+        (an earmark is the rest of the aligned run whose first blocks end its
+        owner's table), and the tile counts are the tables'.  Raises
+        AssertionError on violation."""
         owners: Dict[int, int] = {}
         full = window = given_back = 0
+        G = self.run_blocks
+        held = in_order = 0
         for seq_id, runs in self._owned.items():
             in_seq = set()
             for g, run in enumerate(runs):
@@ -392,6 +551,12 @@ class PagedKVAllocator:
                 assert run.first == 0 or self.windows[g] is not None
                 if self.windows[g] is None:
                     full += len(run.blocks)
+                    if G > 1:
+                        tiles = [run.blocks[i:i + G]
+                                 for i in range(0, len(run.blocks), G)]
+                        n = sum(t == list(range(t[0], t[0] + G)) for t in tiles)
+                        assert n == run.tiles_run, f"{seq_id}: runs miscounted"
+                        held, in_order = held + len(tiles), in_order + n
                 else:
                     window += len(run.blocks)
                     given_back += run.given_back
@@ -409,8 +574,28 @@ class PagedKVAllocator:
         for b, n in owners.items():
             assert n <= self._refs.get(b, 0), (
                 f"block {b}: {n} owners > {self._refs.get(b, 0)} refs")
+        assert (held, in_order) == (self.tiles_held, self.tiles_run), (
+            "tile counts drifted from the tables")
         free = set(self._free)
-        assert len(free) == len(self._free), "duplicate free-list entry"
+        for base in self._free_runs:
+            assert base % G == 0 and 0 < base <= self.num_blocks - G, (
+                f"no run starts at {base}")
+            free.update(range(base, base + G))
+        for base, (run, lo, hi) in self._earmarks.items():
+            assert base % G == 0 and base < lo < hi <= base + G, (
+                f"earmark {base}: {lo}..{hi}")
+            assert run.blocks[-(lo - base):] == list(range(base, lo)) and (
+                len(run.blocks) % G == lo - base), (
+                f"earmark {base} does not continue its owner's table")
+            assert any(run is r for runs in self._owned.values() for r in runs)
+            free.update(range(lo, hi))
+        assert self._earmarked == sum(
+            hi - lo for _, lo, hi in self._earmarks.values())
+        assert len(free) == self.free_pages, "a block is free twice"
+        for base in range(0, self.num_blocks, G) if G > 1 else ():
+            n = sum(b in self._free for b in range(base, base + G))
+            assert n == self._loose_in.get(base, 0) and n < G, (
+                f"run {base}: {n} loose, counted {self._loose_in.get(base, 0)}")
         assert not (free & self._refs.keys()), (
             f"blocks both free and live: {sorted(free & self._refs.keys())}")
         assert self.TRASH not in free and self.TRASH not in self._refs, (

@@ -382,7 +382,8 @@ def test_paged_mla_kernel_compiles_at_mistral4_heads(chip):
     rope lanes, 384 in the arena), pages of 16, the one-array arena of 50,000
     blocks WHOLE with the layer a scalar, tables 1,024 blocks wide as SMEM
     blocks; 128 slots at ``Sq = 1`` and the chunk of 384 as 24 rows of 16
-    queries (512 rows of the product)."""
+    queries (512 rows of the product); a tile of 32 pages, attended 256 keys
+    a step."""
     H, W, R, BS, slots, chunk, MB = 32, 384, 256, 16, 128, 384, 1024
     rows = slots + chunk
     assert da.mla_kernel_shape_ok(W, R, BS, BF16)
@@ -396,7 +397,7 @@ def test_paged_mla_kernel_compiles_at_mistral4_heads(chip):
     assert da.paged_mla_chunk_queries(chunk, H, W, R, BS, MB, BF16) == 16
     assert _kernel_rows(text, "paged_mla_attention") == [chunk // 16, slots]
     assert "dynamic-slice" not in text        # no layer of the arena sliced out
-    assert da.paged_mla_tile_pages(W, R, BS, MB, BF16) == 16
+    assert da.paged_mla_tile_pages(W, R, BS, MB, BF16) == 32
 
 
 # a serve cell's model at its published widths (one period of its layers: the
@@ -453,6 +454,37 @@ def test_the_step_program_attends_the_chunk_packed(chip, cell):
     assert chunk % Sq == 0 and Sq > 1
     assert _kernel_rows(text, kernel) == sorted(
         [slots, chunk // Sq] * len(cfg.pattern))
+
+
+@pytest.mark.parametrize("periods", [1, 2])
+def test_the_mistral_step_hands_the_latent_kernel_its_run_flags(chip, periods):
+    """The whole Mistral step with the flag block: each call of
+    ``paged_mla_attention`` takes, beside its row's table and the next row's
+    (1,024 blocks), their flags (32 tiles of 32 pages) as SMEM blocks, and the
+    arena viewed ``[layers, pages * 16, 384]`` (a bitcast: no copy of it is
+    made for the kernel).  The flags are the same for every layer: the
+    ``[rows, 32, 32]`` comparison that makes them stands outside the loop
+    over layers."""
+    _, slots, chunk, _, kernel, Sq = SERVE_CELLS["mistral-small-4-119b"]
+    _, text = _step_text(chip, "mistral-small-4-119b", periods=periods)
+    calls = [line for line in text.splitlines()
+             if re.search(rf"%{kernel}[.\d]* = ", line) and "tpu_custom_call" in line]
+    assert len(calls) == 2
+    arena = f"bf16[{periods},{1025 * 16},384]"
+    for line, rows in zip(sorted(calls, key=lambda l: "[128,1,1024]" in l),
+                          (chunk // Sq, slots)):
+        operands = line[line.index("operand_layout_constraints="):
+                        line.index("frontend_attributes=")]
+        assert operands.count(f"s32[{rows},1,1024]") == 2
+        assert operands.count(f"s32[{rows},1,32]") == 2
+        assert arena in operands
+    assert not re.search(rf"= {re.escape(arena)}\S* copy\(", text)
+    rows = slots + chunk
+    made = [line for line in text.splitlines() if f"s32[{rows},32,32]" in line
+            and " = " in line and "parameter(" not in line]
+    assert made
+    body = text[:text.index("ENTRY ")]
+    assert not [line for line in made if line in body and " fusion(" in line]
 
 
 @pytest.mark.parametrize("cell", list(SERVE_CELLS))

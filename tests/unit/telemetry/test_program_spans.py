@@ -250,12 +250,13 @@ def test_spans_carry_counts_where_the_work_happens(tiny_model):
                                      "serve.prefill.commit"))
     # what the step's tables cost: the one upload, no entry changed (the
     # request was handed its two blocks at admission), nothing reloaded; no
-    # chunk in this step, so no queries a row of one, and the same rows; and
-    # the step's turn-round, since the step before ran a program.  The
+    # chunk in this step, so no queries a row of one, and the same rows; no
+    # tile of the tables a run (this kernel copies page by page); and the
+    # step's turn-round, since the step before ran a program.  The
     # engine's constants (which paged kernel it runs, the bytes the arena
     # holds a token a layer) are attributes of the engine and not written on
     # every step's event; the first is in ``step()``'s stats
-    table = {"table_edits": 0, "table_reloads": 0,
+    table = {"table_edits": 0, "table_reloads": 0, "tile_runs_pct": 0.0,
              "upload_bytes": 4 * eng._layout.packed_size}
     (on_span,) = by["serve.stats"]
     assert on_span == dict(table, chunk_queries_per_row=0, attention_rows=5,

@@ -329,18 +329,18 @@ def test_absorbed_attention_equals_plain_attention(tiny):
 @pytest.mark.parametrize("Sq", [1, 3, 16])
 def test_paged_mla_kernel_equals_the_gather_reference(kernels, Sq):
     """The kernel through the interpreter against the gather reference: 5
-    heads (rows padded to the sublane tile), pages of 8 in tiles of 32
-    (``_MLA_TILE_ROWS`` 256), rows whose context ends inside the first page,
+    heads (rows padded to the sublane tile), pages of 16 in tiles of 32
+    (``_MLA_TILE_ROWS`` 512), rows whose context ends inside the first page,
     at a page's edge, past a tile and in the table's last page, an idle row
     of trash, over layer 1 of a two-layer arena; a query a row, three, and
     the 16 a row of the serve cell's prompt chunk holds (its queries then
-    span pages, and from 255 + 9 the tile's edge)."""
+    span pages, and from 511 + 9 the tile's edge; 255 + 9 an attend step's)."""
     kernels("paged_mla_attention")
-    B, H, W, R, BS, MB, NB = 6, 5, 256, 128, 8, 40, 64
+    B, H, W, R, BS, MB, NB = 6, 5, 256, 128, 16, 40, 64
     rng = np.random.default_rng(1)
     arena = jnp.asarray(rng.standard_normal((2, NB, BS, W)), jnp.float32)
     q = jnp.asarray(rng.standard_normal((B, Sq, H, W)), jnp.float32)
-    lengths = jnp.asarray([3, 7, 8, 255 + 9, MB * BS - Sq, 0], jnp.int32)
+    lengths = jnp.asarray([3, 15, 255 + 9, 511 + 9, MB * BS - Sq, 0], jnp.int32)
     tables = jnp.asarray(np.stack([rng.permutation(np.arange(1, NB))[:MB]
                                    for _ in range(B)]), jnp.int32).at[5].set(0)
     assert da.paged_mla_tile_pages(W, R, BS, MB, jnp.float32) == 32
@@ -453,8 +453,39 @@ def test_the_engine_on_the_kernel_serves_the_reference_paths_logits(kernels):
     want_tokens, want, _, _ = served_logits(cfg, params, prompt, 9)
     kernels("paged_mla_attention")
     tokens, got, stats, _ = served_logits(cfg, params, prompt, 9)
-    assert stats[0]["paged_tile_pages"] == 32 and tokens == want_tokens
+    assert stats[0]["paged_tile_pages"] == 64 and tokens == want_tokens
     assert float(np.abs(got - want).max()) < TOL
+    # 39 pages hold no run of 64, and the reference path lays none
+    assert stats[-2]["tile_runs_pct"] == 0.0
+
+
+@pytest.mark.parametrize("num_blocks", [40, 11])
+def test_the_engine_lays_runs_of_a_tile_and_the_kernel_fetches_them(
+        kernels, monkeypatch, num_blocks):
+    """Tiles of 4 pages (the rule's constant lowered for the size of the
+    test): the allocator the engine builds grows a table in aligned runs of
+    the kernel's tile, the step's flags send those tiles through the ONE
+    copy, the stat counts them, and the logits are the reference path's.  In
+    an arena of 11 blocks the last tiles find no whole run, take loose blocks
+    and go page by page in the same program."""
+    cfg = tiny_config(v_head_dim=32)
+    params = lively(GPT(cfg).init_params(jax.random.PRNGKey(1)))
+    prompt = list(map(int, _ids(53, seed=8)))
+    serving = dict(SERVING, num_blocks=num_blocks)
+    want_tokens, want, _, _ = served_logits(cfg, params, prompt, 20, serving)
+    kernels("paged_mla_attention")
+    monkeypatch.setattr(da, "_MLA_TILE_ROWS", 32)
+    model = Recording(cfg)
+    eng = deepspeed_tpu.init_serving(model=model, params=params,
+                                     config={"serving": serving})
+    assert eng.paged_tile_pages == eng.alloc.run_blocks == 4
+    eng.close()
+    tokens, got, stats, _ = served_logits(cfg, params, prompt, 20, serving)
+    assert tokens == want_tokens and float(np.abs(got - want).max()) < TOL
+    # before the last step frees them, 72 tokens are 9 pages: two whole
+    # tiles and a short one
+    assert stats[-2]["tile_runs_pct"] == pytest.approx(
+        100 * (2 if num_blocks == 40 else 1) / 3)
 
 
 def test_arena_bytes_are_the_cache_specs():
