@@ -48,15 +48,19 @@ class LayerKind(NamedTuple):
     """What distinguishes one layer of a ``layer_pattern``: ``window`` keys a
     query sees, itself counted (None: every earlier key), whether q and k
     are rotated (``rope``) or carry no position, and the layer's ``mixer``:
-    ``softmax`` attention over cached keys (every family but one), attention
-    over the blocks the query chooses (``sparse``: :class:`SparseSpec`), or
-    a ``linear`` recurrence whose cache is a state (``models/hybrid.py``).
+    ``softmax`` attention over cached keys (most families), attention over
+    the blocks the query chooses (``sparse``: :class:`SparseSpec`), a
+    ``linear`` recurrence whose cache is a state, or attention inside a
+    latent convolved over time (``cca``; all three ``models/hybrid.py``).
     ``depth`` is the layer's index in the PUBLISHED stack where that differs
-    from its place here (a linear layer's decay reads it)."""
+    from its place here (a linear layer's decay reads it).  ``ffn`` is the
+    layer's feed-forward, ``mlp`` or ``moe`` (None: the model's one kind, an
+    expert bank where ``moe_num_experts`` says so)."""
     window: Optional[int] = None
     rope: bool = True
     mixer: str = "softmax"
     depth: Optional[int] = None
+    ffn: Optional[str] = None
 
 
 class SparseSpec(NamedTuple):
@@ -189,6 +193,12 @@ class GPTConfig:
     # or each logit's 'sigmoid' (the DeepSeek-V3 line: the ``moe_top_k``
     # largest of score + bias, weighed by their scores)
     moe_scoring: str = "softmax"
+    # a width here makes the router an MLP that wide over a stream the
+    # routers carry from layer to layer beside the residual (the ZAYA1 line:
+    # ``moe/dropless.py:stream_mlp_logits``; the largest of softmax + bias,
+    # weighed by the softmax): a second carry of the layer walk, which
+    # ``models/hybrid.py`` alone has
+    moe_router_hidden: Optional[int] = None
     # experts every token goes through, beside the routed ones: ONE MLP of
     # ``moe_shared_experts * moe_expert_hidden``, added unweighted
     moe_shared_experts: int = 0
@@ -274,34 +284,45 @@ class GPTConfig:
                     "head_dim), rope, one kind of layer, a head a query head")
         else:
             assert self.v_head_dim == self.head_dim
-        # layers of each mixer, in the stack's order
+        # each layer's mixer and feed-forward, in the stack's order
         self.mixers = tuple(k.mixer for k in self.pattern)
+        self.ffns = tuple(k.ffn or ("moe" if self.moe_num_experts else "mlp")
+                          for k in self.pattern)
         self.hybrid = any(m != "softmax" for m in self.mixers)
         if self.hybrid:
             assert len(self.pattern) == self.n_layer and all(
-                m in ("sparse", "linear") for m in self.mixers), (
-                    "a hybrid stack names every layer, sparse or linear")
+                m in ("sparse", "linear", "cca") for m in self.mixers), (
+                    "a hybrid stack names every layer: sparse, linear or cca")
             self.sparse = SparseSpec(*(self.sparse or ()))
             sp = self.sparse
             assert sp.kernel == 2 * sp.stride and sp.block % sp.stride == 0 \
                 and sp.window % sp.block == 0 and sp.topk * sp.block >= \
                 sp.window + (sp.init_blocks + 1) * sp.block, sp
             assert (self.norm == "rmsnorm" and self.mlp_type == "swiglu"
-                    and not self.use_bias and self.untied_head
-                    and not self.moe_num_experts and not self.kv_lora_rank
+                    and not self.use_bias and not self.kv_lora_rank
                     and self.block_type == "sequential")
+            assert all(f in ("mlp", "moe") for f in self.ffns) and (
+                "moe" not in self.ffns or (
+                    self.moe_router == "dropless" and self.moe_router_hidden
+                    and not self.moe_shared_experts
+                    and self.moe_experts_held is None)), (
+                        "a hybrid stack's expert layers: the whole bank "
+                        "behind the dropless MLP router with its stream")
+        else:
+            assert not self.moe_router_hidden, (
+                "the router's stream is a second carry of the layer walk, "
+                "which models/hybrid.py alone has")
 
     @property
     def arena_layout(self) -> Tuple[int, int, Tuple[int, ...]]:
         """(layers a page holds, pages a block of ALL of them takes, lanes of
         each array): what ``serving/kv_cache.py:init_arena`` builds.  A layer
         pattern of ``P`` kinds keeps ``P`` groups of ``n_layer / P`` layers;
-        a hybrid stack pages its sparse layers alone, a K/V head a page of
-        its own (the selection differs by K/V head, so the kernel walks a
-        list of pages a head), and its linear layers own no page."""
+        a hybrid stack pages the layers of its one mixer that caches K and V
+        (``models/hybrid.py:arena_layout``)."""
         if self.hybrid:
-            return (self.mixers.count("sparse"), self.kv_heads,
-                    (self.head_dim,) * 2)
+            from deepspeed_tpu.models import hybrid
+            return hybrid.arena_layout(self)
         P = len(self.pattern)
         return self.n_layer // P, P, self.cache_lanes
 
@@ -309,7 +330,7 @@ class GPTConfig:
     def page_groups(self) -> Tuple[Optional[int], ...]:
         """The layer groups that own pages, each named by its window (None:
         every key is kept): a group a kind of a periodic pattern; a hybrid
-        stack's sparse layers ONE group, its linear layers none."""
+        stack's layers that cache K and V ONE group, the others none."""
         if self.hybrid:
             return (None,)
         return tuple(kind.window for kind in self.pattern)
@@ -461,6 +482,35 @@ def minicpm_sala_config(vocab_size=73448, n_positions=524288, n_embd=4096,
     kw.update(overrides)
     return llama_config(vocab_size=vocab_size, n_positions=n_positions,
                         n_embd=n_embd, n_layer=len(pattern), n_head=n_head,
+                        intermediate_size=intermediate_size, **kw)
+
+
+def zaya_config(vocab_size=262272, n_positions=131072, n_embd=2048, n_layer=40,
+                n_head=8, n_kv_head=2, head_dim=128, intermediate_size=2048,
+                num_experts=16, top_k=1, router_hidden=256, cca_time0=2,
+                cca_time1=2, partial_rotary_factor=0.5, **overrides) -> GPTConfig:
+    """ZAYA1 family (defaults: ZAYA1-8B): every layer attention computed
+    inside a convolved latent (``cca``, ``models/hybrid.py:cca_mixer``:
+    ``n_head`` query heads on ``n_kv_head`` K/V heads whose packed latents go
+    through two causal convolutions over time of ``cca_time0`` and
+    ``cca_time1`` taps, half the value lanes the previous token's, rope on
+    the first ``partial_rotary_factor`` of a head's lanes) and then a bank of
+    SwiGLU experts with ``top_k`` a token behind an MLP router
+    ``router_hidden`` wide that carries a stream of its own from layer to
+    layer.  RMSNorm (eps 1e-5), no bias but the convolutions', the head tied
+    to the embedding, rope theta 5e6.  Served through ``init_serving()``
+    (``models/hybrid.py``); the dense paths refuse it."""
+    assert (cca_time0, cca_time1) == (2, 2), (
+        "the cca mixer is written for two taps a convolution: a slot keeps "
+        "the last cca_time0 + cca_time1 - 2 = 2 packed latents")
+    kw = dict(n_kv_head=n_kv_head, head_dim=head_dim, rope_theta=5e6,
+              rope_dim=int(head_dim * partial_rotary_factor), untied_head=False,
+              layer_pattern=n_layer * (LayerKind(None, True, "cca", ffn="moe"),),
+              moe_num_experts=num_experts, moe_top_k=top_k,
+              moe_router="dropless", moe_router_hidden=router_hidden)
+    kw.update(overrides)
+    return llama_config(vocab_size=vocab_size, n_positions=n_positions,
+                        n_embd=n_embd, n_layer=n_layer, n_head=n_head,
                         intermediate_size=intermediate_size, **kw)
 
 
@@ -1043,14 +1093,13 @@ def _scan_layers(n_kinds: int, layer_fn: Callable, carry, xs):
 
 
 def _refuse_hybrid(cfg: "GPTConfig", path: str) -> None:
-    """The dense paths walk ONE stack of softmax layers: a stack with sparse
-    or linear layers is refused by the mechanism it would need."""
+    """The dense paths walk ONE stack of softmax layers: a stack with layers
+    of another mixer is refused by the mechanisms it would need."""
     if cfg.hybrid:
+        from deepspeed_tpu.models import hybrid
         raise NotImplementedError(
-            f"{path} has no chunked linear-attention scan (nor its backward) "
-            f"and no block selection for the {cfg.mixers.count('linear')} "
-            f"linear and {cfg.mixers.count('sparse')} sparse layers of this "
-            f"stack; serve it through init_serving() (models/hybrid.py)")
+            f"{path} has {hybrid.what_a_dense_path_lacks(cfg)}; serve this "
+            f"stack through init_serving() (models/hybrid.py)")
 
 
 def _window_bias(S: int, window: int) -> Array:

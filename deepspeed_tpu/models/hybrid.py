@@ -1,6 +1,11 @@
-"""A stack whose layers differ in shape and follow no period: the
-MiniCPM-SALA family (``models/gpt.py:minicpm_sala_config``) on the serving
-path.  ``cfg.pattern`` names EVERY layer; two mixers live here:
+"""A stack whose layers are not softmax attention over their own token's
+keys, on the serving path: ``cfg.pattern`` names EVERY layer (its mixer and
+its feed-forward), and ONE walk (:func:`hybrid_paged_step`) runs the stack
+in runs of a mixer, dispatching on both.  The MiniCPM-SALA family
+(``models/gpt.py:minicpm_sala_config``: sparse and linear layers in no
+period over a dense MLP) and the ZAYA1 family (``zaya_config``: ``cca``
+layers over an expert bank) are entries of :data:`MIXERS` and
+:data:`FEED_FORWARDS`.  The mixers:
 
 * ``sparse`` (the InfLLM-V2 line of MiniCPM4): grouped-query attention
   without rope whose query, once more than ``dense_len`` keys lie before it,
@@ -23,12 +28,13 @@ path.  ``cfg.pattern`` names EVERY layer; two mixers live here:
   state, so a slot bound to a new sequence, and one whose request was
   preempted and is prefilled again, needs no other reset.
 
-The leaves are stacked BY KIND (``params["blocks"]["sparse"]`` ``[4, ...]``,
+The leaves are stacked BY MIXER (``params["blocks"]["sparse"]`` ``[4, ...]``,
 ``["linear"]`` ``[12, ...]``: no projection is padded to another kind's
-width), the step walks the stack in RUNS of one kind (a ``lax.scan`` a run,
-a layer's leaves taken from the kind's stack by its index), and only the
-sparse layers own pages: ``cfg.arena_layout``.  What is not K and V rides in
-``aux``: the compressed keys' pages and the states (:func:`init_aux`).
+width), the step walks the stack in RUNS of one mixer (a ``lax.scan`` a run,
+a layer's leaves taken from the mixer's stack by its index), and only the
+mixer that caches K and V owns pages: :func:`arena_layout`.  What is not K
+and V rides in ``aux``: the compressed keys' pages and the states
+(:func:`init_aux`).
 
 A step WITHOUT a prompt chunk (two of three in a long run) is the same
 program taking the other side of a few ``lax.cond``: the chunk's rows carry
@@ -38,7 +44,7 @@ rows alone (:func:`_rows_that_carry`).
 """
 
 import math
-from typing import Dict, List, Tuple
+from typing import Any, Dict, List, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -53,8 +59,8 @@ HIGHEST = jax.lax.Precision.HIGHEST
 
 def layer_runs(cfg) -> List[Tuple[str, int, int]]:
     """The stack as runs of one mixer: (mixer, index of the run's first layer
-    in its kind's stack, layers)."""
-    runs, seen = [], {"sparse": 0, "linear": 0}
+    in its mixer's stack, layers)."""
+    runs, seen = [], dict.fromkeys(MIXERS, 0)
     for m in cfg.mixers:
         if runs and runs[-1][0] == m:
             runs[-1][2] += 1
@@ -62,6 +68,15 @@ def layer_runs(cfg) -> List[Tuple[str, int, int]]:
             runs.append([m, seen[m], 1])
         seen[m] += 1
     return [tuple(r) for r in runs]
+
+
+def ffn_of(cfg, mixer: str) -> str:
+    """The feed-forward of ``mixer``'s layers: one kind a mixer, because a
+    mixer's leaves are one stack."""
+    kinds = {f for m, f in zip(cfg.mixers, cfg.ffns) if m == mixer}
+    assert len(kinds) == 1, (
+        f"the {mixer} layers' leaves are ONE stack: one feed-forward, {kinds}")
+    return kinds.pop()
 
 
 def linear_decay(cfg) -> np.ndarray:
@@ -76,39 +91,123 @@ def linear_decay(cfg) -> np.ndarray:
                            + 1e-5)).astype(np.float32)
 
 
+def arena_layout(cfg) -> Tuple[int, int, Tuple[int, ...]]:
+    """``GPTConfig.arena_layout`` of a hybrid stack: the sparse layers own
+    the pages, a K/V head a page of its own (the selection differs by K/V
+    head, so the kernel walks a list of pages a head); the cca layers own
+    them, a page a block of all K/V heads like any grouped-query model's;
+    the linear layers own none."""
+    paged = {m for m in cfg.mixers if m != "linear"}
+    assert len(paged) <= 1, f"one mixer's layers own the pages, not {paged}"
+    if "cca" in paged:
+        return cfg.mixers.count("cca"), 1, (cfg.kv_heads * cfg.head_dim,) * 2
+    return cfg.mixers.count("sparse"), cfg.kv_heads, (cfg.head_dim,) * 2
+
+
+def what_a_dense_path_lacks(cfg) -> str:
+    """Why ``gpt_forward`` / ``gpt_loss`` / ``generate()`` refuse this stack,
+    by the mechanisms its mixers would need there."""
+    n = cfg.mixers.count
+    lacks = {"linear": f"no chunked linear-attention scan (nor its backward) "
+                       f"for the {n('linear')} linear layers",
+             "sparse": f"no block selection for the {n('sparse')} sparse layers",
+             "cca": f"no convolution over time of the packed q/k latents (nor "
+                    f"its backward) and no second carry for the router's "
+                    f"stream of the {n('cca')} cca layers"}
+    return " and ".join(lacks[m] for m in ("linear", "sparse", "cca")
+                        if m in cfg.mixers)
+
+
+def what_no_block_carries(cfg) -> str:
+    """What this stack caches that no block of K and V holds: why the prefix
+    cache and tiered KV refuse it."""
+    n = cfg.mixers.count
+    holds = {"linear": f"{n('linear')} linear layers hold a recurrent state a slot",
+             "sparse": f"{n('sparse')} sparse layers a compressed-key cache",
+             "cca": f"{n('cca')} cca layers hold a convolution state a slot "
+                    f"(the last two packed latents and the next token's "
+                    f"shifted value half)"}
+    return "this model's " + " and its ".join(
+        holds[m] for m in ("linear", "sparse", "cca") if m in cfg.mixers)
+
+
 # --------------------------------------------------------------------------- #
-# Parameters, stacked by kind
+# Parameters, stacked by mixer
 # --------------------------------------------------------------------------- #
-def _leaf_shapes(cfg, mixer: str) -> Dict[str, Tuple[int, ...]]:
-    E, I, D = cfg.n_embd, cfg.ffn_dim, cfg.head_dim
-    A = cfg.n_head * D
-    shapes = {"ln1_g": (E,), "ln2_g": (E,), "q_norm_g": (D,), "k_norm_g": (D,),
-              "gate_w": (E, A), "out_w": (A, E),
-              "fc_w": (E, 2 * I), "proj_w": (I, E)}
+_GATED = lambda cfg: {"q_norm_g": (cfg.head_dim,), "k_norm_g": (cfg.head_dim,),
+                      "gate_w": (cfg.n_embd, cfg.attn_dim),
+                      "out_w": (cfg.attn_dim, cfg.n_embd)}
+
+
+def _cca_widths(cfg) -> Tuple[int, int]:
+    """(lanes of the packed latent ``u = [q | k]``, lanes of HALF the value:
+    the half the current token gives, as wide as the half the one before it
+    gave)."""
+    assert cfg.kv_heads % 2 == 0, "half the K/V heads' values are the previous token's"
+    return (cfg.n_head + cfg.kv_heads) * cfg.head_dim, cfg.kv_heads * cfg.head_dim // 2
+
+
+def _mixer_shapes(cfg, mixer: str) -> Dict:
+    E, D, A = cfg.n_embd, cfg.head_dim, cfg.attn_dim
     if mixer == "sparse":
-        shapes.update(q_w=(E, A), kv_w=(E, 2 * cfg.kv_heads * D))
-    else:
-        shapes.update(qkv_w=(E, 3 * A), onorm_g=(A,))
-    return shapes
+        return dict(_GATED(cfg), q_w=(E, A), kv_w=(E, 2 * cfg.kv_heads * D))
+    if mixer == "linear":
+        return dict(_GATED(cfg), qkv_w=(E, 3 * A), onorm_g=(A,))
+    U, Vh = _cca_widths(cfg)
+    # qkv_w: [W_q | W_k | W_v1 (this token's value half) | W_v2 (the next
+    # token's)]; conv0 depthwise over the packed latent, conv1 a map a head
+    # a tap; k_scale_g the learned scale of a K/V head's normed key
+    return {"qkv_w": (E, U + 2 * Vh), "out_w": (A, E),
+            "conv0_w": (2, U), "conv0_b": (U,),
+            "conv1_w": (2, U // D, D, D), "conv1_b": (U,),
+            "k_scale_g": (cfg.kv_heads,)}
+
+
+def _ffn_shapes(cfg, ffn: str) -> Dict:
+    E = cfg.n_embd
+    if ffn == "mlp":
+        return {"fc_w": (E, 2 * cfg.ffn_dim), "proj_w": (cfg.ffn_dim, E)}
+    N, R, I = cfg.moe_num_experts, cfg.moe_router_hidden, cfg.moe_expert_hidden or cfg.ffn_dim
+    # the router: down to its stream's width, the stream of the layer before
+    # times stream_g, a norm, three matrices; balance_bias chooses and never
+    # weighs.  The bank is a group of its own, as the other MoE families' is
+    return {"router_in_w": (E, R), "stream_g": (), "router_norm_g": (R,),
+            "router_w1": (R, R), "router_w2": (R, R), "router_w3": (R, N),
+            "balance_bias": (N,),
+            "experts": {"wi": (N, E, 2 * I), "wo": (N, I, E)}}
+
+
+def _leaf_shapes(cfg, mixer: str) -> Dict:
+    return {"ln1_g": (cfg.n_embd,), "ln2_g": (cfg.n_embd,),
+            **_mixer_shapes(cfg, mixer), **_ffn_shapes(cfg, ffn_of(cfg, mixer))}
+
+
+def _is_shape(x) -> bool:
+    return isinstance(x, tuple)
 
 
 def init_blocks(cfg, rng: Array) -> Dict:
-    """``{"sparse": leaves [sparse layers, ...], "linear": leaves [linear
-    layers, ...]}``: norm gains 1, every matrix normal 0.02."""
-    def one(mixer, key):
-        shapes = _leaf_shapes(cfg, mixer)
+    """``{mixer: leaves [that mixer's layers, ...]}``: gains (``*_g``) 1, the
+    balancing bias 0, every other leaf normal 0.02."""
+    def leaf(name, key, shape):
+        if name.endswith("_g"):
+            return jnp.ones(shape, jnp.float32)
+        if name == "balance_bias":
+            return jnp.zeros(shape, jnp.float32)
+        return gpt._dense_init(key, shape[0], shape)
+
+    def one(shapes, key):
         keys = jax.random.split(key, len(shapes))
-        return {name: jnp.ones(shape, jnp.float32) if name.endswith("_g")
-                else gpt._dense_init(k, shape[0], shape)
+        return {name: leaf(name, k, shape) if _is_shape(shape) else one(shape, k)
                 for k, (name, shape) in zip(keys, sorted(shapes.items()))}
 
     out = {}
-    for n, mixer in enumerate(("sparse", "linear")):
+    for n, mixer in enumerate(MIXERS):
         count = cfg.mixers.count(mixer)
         if count:
             # a layer at a time: one layer's random bits are a gigabyte
             out[mixer] = jax.lax.map(
-                lambda k, mixer=mixer: one(mixer, k),
+                lambda k, mixer=mixer: one(_leaf_shapes(cfg, mixer), k),
                 jax.random.split(jax.random.fold_in(rng, n), count))
     return out
 
@@ -116,18 +215,26 @@ def init_blocks(cfg, rng: Array) -> Dict:
 def block_partition_specs(cfg) -> Dict:
     column = {"q_w", "kv_w", "qkv_w", "gate_w", "fc_w"}
     row = {"out_w", "proj_w"}
-    spec = lambda name: PartitionSpec(
-        None, *((None, "tensor") if name in column else ("tensor", None)
-                if name in row else ()))
-    return {m: {name: spec(name) for name in _leaf_shapes(cfg, m)}
-            for m in ("sparse", "linear") if m in cfg.mixers}
+    bank = {"wi": ("expert", None, "tensor"), "wo": ("expert", "tensor", None)}
+
+    def spec(path, _):
+        name = path[-1].key
+        return PartitionSpec(None, *(bank[name] if len(path) > 1 else
+                                     (None, "tensor") if name in column else
+                                     ("tensor", None) if name in row else ()))
+    return {m: jax.tree_util.tree_map_with_path(spec, _leaf_shapes(cfg, m),
+                                                is_leaf=_is_shape)
+            for m in MIXERS if m in cfg.mixers}
 
 
 def num_params(cfg) -> int:
-    per_kind = {m: sum(math.prod(s) for s in _leaf_shapes(cfg, m).values())
-                for m in ("sparse", "linear")}
-    return (sum(per_kind[m] for m in cfg.mixers)
-            + 2 * cfg.padded_vocab * cfg.n_embd + cfg.n_embd)
+    """Parameters the tree holds (its one zero leaf the sources lack,
+    ``lnf_b``, apart): each layer's leaves, the final norm, the embedding
+    and, where it is untied, the head."""
+    per = {m: sum(math.prod(s) for s in jax.tree.leaves(
+        _leaf_shapes(cfg, m), is_leaf=_is_shape)) for m in set(cfg.mixers)}
+    return (sum(per[m] for m in cfg.mixers) + cfg.n_embd
+            + (1 + cfg.untied_head) * cfg.padded_vocab * cfg.n_embd)
 
 
 # --------------------------------------------------------------------------- #
@@ -142,22 +249,33 @@ def keys_a_page(cfg) -> int:
 
 
 def init_aux(cfg, num_blocks: int, block_size: int, slots: int, dtype) -> Dict:
-    """``kc [sparse layers, num_blocks, keys_a_page * Hkv * D]``: the
-    compressed keys of a page, reached through the sparse layers' block
-    table like K and V; ``state [linear layers, slots, H, D, D]`` float32: a
-    linear layer's cache, a slot's whatever its length."""
-    assert block_size == cfg.sparse.block, (
-        f"a sparse layer selects blocks of {cfg.sparse.block} keys and a "
-        f"selected block is a page: block_size {block_size}")
-    D = cfg.head_dim
-    return {"kc": jnp.zeros((cfg.mixers.count("sparse"), num_blocks,
-                             keys_a_page(cfg) * cfg.kv_heads * D), dtype),
-            "state": jnp.zeros((cfg.mixers.count("linear"), slots,
-                                cfg.n_head, D, D), jnp.float32)}
+    """What the stack's mixers cache beside K and V.  ``kc [sparse layers,
+    num_blocks, keys_a_page * Hkv * D]``: the compressed keys of a page,
+    reached through the sparse layers' block table like K and V; ``state
+    [linear layers, slots, H, D, D]`` float32: a linear layer's cache, a
+    slot's whatever its length; ``cca_state [cca layers, slots, 2 U + Vh]``
+    (a stack with cca layers): a slot's ``[u_{t-1} | u_{t-2} | W_v2 h_{t-1}]``
+    in the type they were computed in."""
+    D, out = cfg.head_dim, {}
+    if "cca" in cfg.mixers:
+        U, Vh = _cca_widths(cfg)
+        out["cca_state"] = jnp.zeros((cfg.mixers.count("cca"), slots,
+                                      2 * U + Vh), dtype)
+    if "sparse" in cfg.mixers or "linear" in cfg.mixers:
+        assert "sparse" not in cfg.mixers or block_size == cfg.sparse.block, (
+            f"a sparse layer selects blocks of {cfg.sparse.block} keys and a "
+            f"selected block is a page: block_size {block_size}")
+        out.update(
+            kc=jnp.zeros((cfg.mixers.count("sparse"), num_blocks,
+                          keys_a_page(cfg) * cfg.kv_heads * D), dtype),
+            state=jnp.zeros((cfg.mixers.count("linear"), slots,
+                             cfg.n_head, D, D), jnp.float32))
+    return out
 
 
 def aux_bytes(cfg, num_blocks: int, slots: int, dtype_bytes: int = 2):
-    """(bytes of compressed keys, bytes of state) :func:`init_aux` holds."""
+    """(bytes of compressed keys, bytes of state) :func:`init_aux` holds for
+    a stack of sparse and linear layers."""
     held = jax.eval_shape(lambda: init_aux(cfg, num_blocks, cfg.sparse.block,
                                            slots, jnp.float32))
     return held["kc"].size * dtype_bytes, held["state"].size * 4
@@ -180,6 +298,21 @@ def keys_attended(cfg, positions: np.ndarray) -> np.ndarray:
                     (held - 1) * sp.block + t % sp.block + 1)
 
 
+class _Step(NamedTuple):
+    """What every layer of a step reads of its rows: ``positions``, ``live``
+    and ``slots`` ``[B]``, the one page group's ``tables [B, MB]`` and where
+    each row's token is written (``write_blocks``, ``write_offsets`` ``[B,
+    1]``), the rows of the prompt chunk (static) and the activations' type."""
+    positions: Array
+    live: Array
+    slots: Array
+    tables: Array
+    write_blocks: Array
+    write_offsets: Array
+    chunk: int
+    dt: Any
+
+
 class _LayerLeaves:
     """Layer ``i``'s leaves of a kind's stack, each taken out of the stack
     WHERE IT IS READ: a slice made once outside a ``lax.cond`` would be the
@@ -197,7 +330,7 @@ class _LayerLeaves:
 
 def _rows_that_carry(fn, xs, chunk: int, live):
     """``fn(*xs)``, a function of each row alone (a projection, the MLP, the
-    head): over all rows in a step with a prompt chunk, over the decode rows
+    head; an array or a tuple of them): over all rows in a step with a prompt chunk, over the decode rows
     alone (zeros behind them) in a step without one, where the chunk's rows
     carry nothing and nobody reads what they give.  Two of three steps of a
     long run carry no chunk, and 512 of their 528 rows would go through every
@@ -207,8 +340,9 @@ def _rows_that_carry(fn, xs, chunk: int, live):
     n_dec = xs[0].shape[0] - chunk
 
     def decode_rows_alone():
-        y = fn(*(x[:n_dec] for x in xs))
-        return jnp.pad(y, ((0, chunk),) + ((0, 0),) * (y.ndim - 1))
+        return jax.tree.map(
+            lambda y: jnp.pad(y, ((0, chunk),) + ((0, 0),) * (y.ndim - 1)),
+            fn(*(x[:n_dec] for x in xs)))
 
     return jax.lax.cond(live[n_dec], lambda: fn(*xs), decode_rows_alone)
 
@@ -303,12 +437,14 @@ def _select(cfg, q, kc_rows, positions, BS: int):
     return blocks, jnp.where(dense, t, (held - 1) * BS + t % BS)
 
 
-def sparse_mixer(cfg, p, h, dt, kp, vp, kc, li, positions, live, tables,
-                 write_blocks, write_offsets, chunk: int):
+def sparse_mixer(cfg, p, h, kp, vp, held, li, step: _Step):
     """One sparse layer's attention over the rows ``h [B, E]`` (a token a
     row; the last ``chunk`` consecutive tokens of one sequence): -> (the
-    mixer's output ``[B, E]`` before the residual, kp, vp, kc)."""
+    mixer's output ``[B, E]`` before the residual, kp, vp, held with its
+    compressed keys ``kc`` written)."""
     from deepspeed_tpu.ops.pallas.decode_attention import paged_sparse_attention
+    positions, live, _, tables, write_blocks, write_offsets, chunk, dt = step
+    kc = held["kc"]
     B = h.shape[0]
     H, Hkv, D = cfg.n_head, cfg.kv_heads, cfg.head_dim
     g, BS = H // Hkv, kp.shape[2]
@@ -348,7 +484,7 @@ def sparse_mixer(cfg, p, h, dt, kp, vp, kc, li, positions, live, tables,
         o = jnp.concatenate([o, jax.lax.cond(
             live[n_dec], lambda: attend(slice(n_dec, B), True),
             lambda: jnp.zeros((chunk, H * D), o.dtype))])
-    return _gated_out(p, o, h, dt, chunk, live), kp, vp, kc
+    return _gated_out(p, o, h, dt, chunk, live), kp, vp, dict(held, kc=kc)
 
 
 # --------------------------------------------------------------------------- #
@@ -377,12 +513,14 @@ def linear_chunk(q, k, v, s_in, decay, live):
     return o, s_out
 
 
-def linear_mixer(cfg, p, h, dt, state, li, decay, positions, live, slots,
-                 chunk: int):
+def linear_mixer(cfg, p, h, kp, vp, held, li, step: _Step):
     """One linear layer over the rows ``h [B, E]``: a decode row updates the
     state of its slot (row ``s`` is slot ``s``) and reads it; the prompt
     chunk runs the chunked form from the state of ITS slot, from zero where
-    it starts at position 0.  -> (output ``[B, E]``, state)."""
+    it starts at position 0.  -> (output ``[B, E]``, the pages as they came,
+    held with its ``state`` moved on)."""
+    positions, live, slots, _, _, _, chunk, dt = step
+    state, decay = held["state"], jnp.asarray(linear_decay(cfg))[li]
     B = h.shape[0]
     H, D = cfg.n_head, cfg.head_dim
     n_dec = B - chunk
@@ -410,7 +548,153 @@ def linear_mixer(cfg, p, h, dt, state, li, decay, positions, live, slots,
         o = jnp.concatenate([o, oc])
     state = jax.lax.dynamic_update_index_in_dim(state, s_all, li, 0)
     o = gpt.rms_norm(o.reshape(B, H * D), p["onorm_g"], eps=cfg.ln_eps).astype(dt)
-    return _gated_out(p, o, h, dt, chunk, live), state
+    return _gated_out(p, o, h, dt, chunk, live), kp, vp, dict(held, state=state)
+
+
+# --------------------------------------------------------------------------- #
+# The cca mixer
+# --------------------------------------------------------------------------- #
+def _cca_neighbours(u, v_next, s_all, slots, positions, live, chunk: int):
+    """What each row's two tokens before it gave, and the state after the
+    step.  ``u [B, U]`` the rows' packed latents, ``v_next [B, Vh]`` the value
+    half each gives the token after it, ``s_all [slots, 2 U + Vh]`` the
+    layer's states ``[u_{t-1} | u_{t-2} | value half for t]``.  A decode row
+    (row ``s`` is slot ``s``) reads its slot's state and shifts its own
+    latent in; the prompt chunk's rows read the rows before them, its first
+    two the state of ITS slot, and leave the last live tokens' there.  ->
+    (``u_{t-1}``, ``u_{t-2}`` ``[B, U]``, the previous token's value half
+    ``[B, Vh]``, s_all), all zero where the position lies before 0."""
+    U, n_dec = u.shape[1], u.shape[0] - chunk
+    assert s_all.shape[0] == n_dec, "a decode row a slot"
+    prev1, prev2, v_prev = s_all[:, :U], s_all[:, U:2 * U], s_all[:, 2 * U:]
+    grown = jnp.concatenate([u[:n_dec], prev1, v_next[:n_dec]], axis=1)
+    s_all = jnp.where(live[:n_dec, None], grown.astype(s_all.dtype), s_all)
+    if chunk:
+        slot = slots[n_dec]
+        kept = jax.lax.dynamic_index_in_dim(s_all, slot, 0, keepdims=False)
+        # the slot's sequence from two tokens before the chunk on
+        seq_u = jnp.concatenate([kept[None, U:2 * U], kept[None, :U],
+                                 u[n_dec:].astype(kept.dtype)])
+        seq_v = jnp.concatenate([kept[None, 2 * U:],
+                                 v_next[n_dec:].astype(kept.dtype)])
+        prev1 = jnp.concatenate([prev1, seq_u[1:chunk + 1]])
+        prev2 = jnp.concatenate([prev2, seq_u[:chunk]])
+        v_prev = jnp.concatenate([v_prev, seq_v[:chunk]])
+        # behind the chunk's ``n`` live tokens lie the rows n, n + 1 of seq_u
+        # (n = 0, a step without a chunk: the state as it was)
+        n = jnp.sum(live[n_dec:])
+        last = jax.lax.dynamic_slice_in_dim(seq_u, n, 2)
+        s_all = jax.lax.dynamic_update_index_in_dim(s_all, jnp.concatenate([
+            last[1], last[0],
+            jax.lax.dynamic_index_in_dim(seq_v, n, 0, keepdims=False)]), slot, 0)
+    behind = lambda a, k: jnp.where((positions >= k)[:, None], a, 0)
+    return behind(prev1, 1), behind(prev2, 2), behind(v_prev, 1), s_all
+
+
+def cca_mixer(cfg, p, h, kp, vp, held, li, step: _Step):
+    """One cca layer's attention over the rows ``h [B, E]``: -> (the mixer's
+    output ``[B, E]`` before the residual, kp, vp, held with its
+    ``cca_state`` moved on).  The arithmetic between the projections and the
+    cached K and V (the convolutions, the q-k mean, the norms) is float32;
+    the pages and the state keep ``dt``."""
+    from deepspeed_tpu.ops.pallas.decode_attention import paged_layer_attention
+    positions, live, slots, tables, write_blocks, write_offsets, chunk, dt = step
+    state = held["cca_state"]
+    B = h.shape[0]
+    H, Hkv, D = cfg.n_head, cfg.kv_heads, cfg.head_dim
+    g, (U, Vh) = H // Hkv, _cca_widths(cfg)
+    f32 = lambda name: p[name].astype(jnp.float32)
+    qkv = _rows_that_carry(lambda r: r @ gpt._wget(p, "qkv_w", dt), (h,), chunk, live)
+    with jax.named_scope("cca_mix"):
+        u, v_now, v_next = qkv[:, :U], qkv[:, U:U + Vh], qkv[:, U + Vh:]
+        s_all = jax.lax.dynamic_index_in_dim(state, li, 0, keepdims=False)
+        prev1, prev2, v_prev, s_all = _cca_neighbours(
+            u, v_next, s_all, slots, positions, live, chunk)
+        state = jax.lax.dynamic_update_index_in_dim(state, s_all, li, 0)
+        u, prev1, prev2 = (a.astype(jnp.float32) for a in (u, prev1, prev2))
+        # two taps over time a channel, then two a head: a_t and a_{t-1}
+        w0, b0 = f32("conv0_w"), f32("conv0_b")
+        a = jnp.stack([w0[0] * prev2 + w0[1] * prev1 + b0,
+                       w0[0] * prev1 + w0[1] * u + b0], axis=1)
+        c = jnp.einsum("btgi,tgio->bgo", a.reshape(B, 2, H + Hkv, D),
+                       f32("conv1_w"), precision=HIGHEST
+                       ) + f32("conv1_b").reshape(H + Hkv, D)
+        # the q-k mean of the latents BEFORE the convolutions
+        mean_q = 0.5 * (u[:, :H * D].reshape(B, Hkv, g, D)
+                        + u[:, H * D:].reshape(B, Hkv, 1, D))
+        q = c[:, :H].reshape(B, Hkv, g, D) + mean_q
+        k = c[:, H:] + mean_q.mean(axis=2)
+        unit = lambda t: t * (math.sqrt(D) * jax.lax.rsqrt(jnp.maximum(
+            jnp.sum(jnp.square(t), axis=-1, keepdims=True), 1e-30)))
+        rope = lambda t: gpt.apply_rope(t, positions[:, None], cfg.rope_theta,
+                                        cfg.rope_dim)
+        q = rope(unit(q).reshape(B, 1, H, D)).astype(dt)
+        k = rope((unit(k) * f32("k_scale_g")[:, None]).reshape(B, 1, Hkv, D))
+        # the first half of the K/V heads' values is this token's, the
+        # second the one before it's
+        v = jnp.concatenate([v_now, v_prev.astype(v_now.dtype)], axis=1)
+        kp = kp.at[li, write_blocks, write_offsets].set(
+            k.astype(kp.dtype).reshape(B, 1, Hkv * D))
+        vp = vp.at[li, write_blocks, write_offsets].set(
+            v.astype(vp.dtype).reshape(B, 1, Hkv * D))
+    with jax.named_scope("cca_attend"):
+        o = paged_layer_attention(q, kp, vp, li, tables, positions,
+                                  chunk=chunk).reshape(B, H * D)
+    o = _rows_that_carry(lambda r: r @ gpt._wget(p, "out_w", dt), (o,), chunk, live)
+    return o, kp, vp, dict(held, cca_state=state)
+
+
+# --------------------------------------------------------------------------- #
+# The feed-forwards
+# --------------------------------------------------------------------------- #
+def mlp_ffn(cfg, p, bank, li, x, stream, step: _Step):
+    """The dense SwiGLU over the normed residual: -> (its output, the
+    routers' stream as it came, no expert counts)."""
+    live, chunk, dt = step.live, step.chunk, step.dt
+    mlp = lambda r: gpt._mlp(cfg, p, gpt.rms_norm(r, p["ln2_g"], eps=cfg.ln_eps), dt)
+    return _rows_that_carry(mlp, (x,), chunk, live), stream, None
+
+
+def moe_ffn(cfg, p, bank, li, x, stream, step: _Step):
+    """A bank of SwiGLU experts with ``moe_top_k`` a token behind the MLP
+    router, which reads the layer's normed input and the stream ``[B, R]``
+    the router before it left.  ``bank``: the mixer's stacked expert leaves
+    ``[layers, experts, ...]``, which ``grouped_matmul`` reads at layer
+    ``li`` where they lie.  -> (output, the stream for the next layer, the
+    live rows' assignments an expert ``[experts]`` int32)."""
+    from deepspeed_tpu.moe import dropless
+    live, chunk, dt = step.live, step.chunk, step.dt
+    N = cfg.moe_num_experts
+    leaves = {"fc_w": bank["wi"], "proj_w": bank["wo"]}
+
+    def rows(x, stream):
+        z = gpt.rms_norm(x, p["ln2_g"], eps=cfg.ln_eps)
+        with jax.named_scope("moe"):
+            with jax.named_scope("moe_router"):
+                stream, logits = dropless.stream_mlp_logits(
+                    z, stream, p["router_in_w"], p["stream_g"],
+                    p["router_norm_g"], [p["router_w1"], p["router_w2"],
+                                         p["router_w3"]], cfg.ln_eps)
+                _, weights, experts = dropless.biased_softmax_topk(
+                    logits, cfg.moe_top_k, p["balance_bias"])
+            y = dropless.dropless_moe(
+                z, weights, experts, N,
+                lambda r, matmul, pick: gpt._mlp(cfg, leaves, r, dt, matmul, pick),
+                layer=li)
+        return y.astype(dt), stream, experts
+
+    y, stream, experts = _rows_that_carry(rows, (x, stream), chunk, live)
+    return y, stream, dropless.expert_counts(experts, N, live)
+
+
+# the entries the walk dispatches on: a layer names one of each
+# (``LayerKind.mixer``, ``LayerKind.ffn``); a mixer's is its function and the
+# scope its device ops are traced under.  The order is the order of the
+# mixers' stacks in ``init_blocks``
+MIXERS = {"sparse": (sparse_mixer, lambda: jax.named_scope("attn_sparse")),
+          "linear": (linear_mixer, lambda: jax.named_scope("attn_linear")),
+          "cca": (cca_mixer, lambda: jax.named_scope("attn_cca"))}
+FEED_FORWARDS = {"mlp": mlp_ffn, "moe": moe_ffn}
 
 
 # --------------------------------------------------------------------------- #
@@ -419,55 +703,60 @@ def linear_mixer(cfg, p, h, dt, state, li, decay, positions, live, slots,
 def hybrid_paged_step(cfg, params: Dict, input_ids: Array, positions: Array,
                       k_pages: Array, v_pages: Array, block_tables,
                       write_blocks, write_offsets, chunk: int = 0, aux=None,
-                      slots=None, live=None):
+                      slots=None, live=None, with_expert_counts: bool = False):
     """``models/gpt.py:gpt_paged_step`` for a hybrid stack: ``input_ids [B,
     1]``, a token a row, the last ``chunk`` rows a prompt chunk; the arena is
-    the SPARSE layers' (``cfg.arena_layout``), ``block_tables`` and
-    ``write_blocks`` one group's; ``aux`` is :func:`init_aux`'s pair,
-    ``slots [B]`` the slot a row's sequence holds and ``live [B]`` whether it
-    carries one.  -> (logits ``[B, 1, V]`` float32, k_pages, v_pages, aux)."""
+    that of the layers that cache K and V (``cfg.arena_layout``),
+    ``block_tables`` and ``write_blocks`` one group's; ``aux`` is
+    :func:`init_aux`'s, ``slots [B]`` the slot a row's sequence holds and
+    ``live [B]`` whether it carries one.  The walk's carry is the residual,
+    the routers' stream (zero before the first layer; None in a stack
+    without expert layers) and the caches.  -> (logits ``[B, 1, V]`` float32,
+    k_pages, v_pages, aux) and with ``with_expert_counts`` the live rows'
+    assignments ``[layers, experts]`` int32."""
     B, S = input_ids.shape
     assert S == 1 and aux is not None, "a token a row, with the stack's state"
-    assert not chunk or chunk % cfg.sparse.stride == 0, (
+    assert not chunk or "sparse" not in cfg.mixers or chunk % cfg.sparse.stride == 0, (
         f"a prompt chunk of {chunk} is not whole strides of {cfg.sparse.stride}")
     if isinstance(block_tables, (tuple, list)):
         (block_tables,), (write_blocks,) = block_tables, write_blocks
     dt, rs = cfg.dtype, cfg.residual_scale
-    decays = jnp.asarray(linear_decay(cfg))
     blocks = params["blocks"]
-    wb, wo = write_blocks.reshape(B, 1), write_offsets.reshape(B, 1)
+    step = _Step(positions, live, slots, block_tables, write_blocks.reshape(B, 1),
+                 write_offsets.reshape(B, 1), chunk, dt)
     x = params["wte"].astype(dt)[input_ids[:, 0]] * jnp.asarray(cfg.scale_emb, dt)
 
     def layer(mixer, carry, i):
-        x, kp, vp, kc, state = carry
+        x, stream, kp, vp, held = carry
         p = _LayerLeaves(blocks[mixer], i)
+        mix, scope = MIXERS[mixer]
         with jax.named_scope("attn"):
             h = gpt.rms_norm(x, p["ln1_g"], eps=cfg.ln_eps)
-            if mixer == "sparse":
-                with jax.named_scope("attn_sparse"):
-                    o, kp, vp, kc = sparse_mixer(
-                        cfg, p, h, dt, kp, vp, kc, i, positions, live,
-                        block_tables, wb, wo, chunk)
-            else:
-                with jax.named_scope("attn_linear"):
-                    o, state = linear_mixer(
-                        cfg, p, h, dt, state, i, decays[i], positions, live,
-                        slots, chunk)
+            with scope():
+                o, kp, vp, held = mix(cfg, p, h, kp, vp, held, i, step)
         with jax.named_scope("mlp"):
             x = x + rs * o
-            mlp = lambda r: gpt._mlp(cfg, p, gpt.rms_norm(r, p["ln2_g"], eps=cfg.ln_eps), dt)
-            x = x + rs * _rows_that_carry(mlp, (x,), chunk, live)
-        return (x, kp, vp, kc, state), None
+            y, stream, counts = FEED_FORWARDS[ffn_of(cfg, mixer)](
+                cfg, p, blocks[mixer].get("experts"), i, x, stream, step)
+            x = x + rs * y
+        return (x, stream, kp, vp, held), counts
 
-    carry = (x, k_pages, v_pages, aux["kc"], aux["state"])
+    stream = (jnp.zeros((B, cfg.moe_router_hidden), jnp.float32)
+              if "moe" in cfg.ffns else None)
+    carry, counts = (x, stream, k_pages, v_pages, dict(aux)), []
     for mixer, first, count in layer_runs(cfg):
-        carry, _ = jax.lax.scan(lambda c, i, m=mixer: layer(m, c, i), carry,
-                                first + jnp.arange(count, dtype=jnp.int32))
-    x, k_pages, v_pages, kc, state = carry
+        carry, ys = jax.lax.scan(lambda c, i, m=mixer: layer(m, c, i), carry,
+                                 first + jnp.arange(count, dtype=jnp.int32))
+        counts.append(ys)
+    x, _, k_pages, v_pages, aux = carry
     with jax.named_scope("head"):
         x = gpt.rms_norm(x, params["lnf_g"], eps=cfg.ln_eps) / jnp.asarray(
             cfg.head_divisor, dt)
+        head = params["lm_head"] if cfg.untied_head else params["wte"]
         logits = _rows_that_carry(
-            lambda r: (r @ params["lm_head"].astype(dt).T).astype(jnp.float32),
+            lambda r: (r @ head.astype(dt).T).astype(jnp.float32),
             (x,), chunk, live)
-    return logits[:, None], k_pages, v_pages, {"kc": kc, "state": state}
+    if with_expert_counts:
+        return (logits[:, None], k_pages, v_pages, aux,
+                jnp.concatenate([c for c in counts if c is not None]))
+    return logits[:, None], k_pages, v_pages, aux

@@ -16,7 +16,7 @@ The four stages open the scopes ``moe_router``, ``moe_dispatch``,
 per-layer metrics read a trace by these names.
 """
 
-from typing import Callable, Optional, Tuple
+from typing import Callable, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -60,6 +60,40 @@ def sigmoid_topk(logits: Array, k: int, bias: Optional[Array] = None,
     if renormalise:
         weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
     return (scores / jnp.sum(scores, axis=-1, keepdims=True), weights,
+            experts.astype(jnp.int32))
+
+
+def stream_mlp_logits(h: Array, prev: Array, w_in: Array, scale: Array,
+                      norm_g: Array, mlp: Sequence[Array], eps: float
+                      ) -> Tuple[Array, Array]:
+    """The logits of a router that carries a stream of its own from layer to
+    layer (the ZAYA1 line): ``stream = h W_in + scale * prev`` (``h [T, M]``
+    the layer's normed input, ``prev [T, R]`` the stream the layer before
+    left, ``scale`` a learned scalar: the stream is an average over depth),
+    then an RMSNorm and an MLP of tanh-GELUs without bias, ``mlp`` its
+    matrices ``[R, R] ... [R, E]``.  Everything in float32 at the highest
+    precision: with ONE expert a token a swapped expert is the token's whole
+    routed output.  -> (stream ``[T, R]`` for the next layer, logits ``[T,
+    E]``)."""
+    f32 = lambda a: a.astype(jnp.float32)
+    dot = lambda a, w: jnp.dot(a, f32(w), precision=jax.lax.Precision.HIGHEST)
+    stream = dot(f32(h), w_in) + f32(scale) * prev
+    z = stream * jax.lax.rsqrt(jnp.mean(jnp.square(stream), -1, keepdims=True) + eps)
+    z = z * f32(norm_g)
+    for w in mlp[:-1]:
+        z = jax.nn.gelu(dot(z, w), approximate=True)
+    return stream, dot(z, mlp[-1])
+
+
+def biased_softmax_topk(logits: Array, k: int, bias: Array
+                        ) -> Tuple[Array, Array, Array]:
+    """As :func:`softmax_topk` under a balancing bias: the softmax over ALL
+    experts in float32, the ``k`` largest of ``probs + bias`` chosen (the
+    bias chooses and never weighs), weighed by their own probabilities, not
+    renormalised."""
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    experts = jax.lax.top_k(probs + bias.astype(jnp.float32), k)[1]
+    return (probs, jnp.take_along_axis(probs, experts, axis=-1),
             experts.astype(jnp.int32))
 
 

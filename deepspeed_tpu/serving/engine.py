@@ -367,6 +367,9 @@ class ServingEngine:
         # that carry no request too (idle slots, idle chunk rows): a router
         # with a capacity would let them push live tokens out of an expert
         self._moe_experts = int(getattr(mcfg, "moe_num_experts", 0))
+        # the rows of expert counts a program hands back behind its tokens:
+        # one summed over layers, or a hybrid stack's row a layer
+        self._moe_count_rows = mcfg.ffns.count("moe") if mcfg.hybrid else 1
         if self._moe_experts and mcfg.moe_router != "dropless":
             raise ValueError(
                 f"init_serving: the {mcfg.moe_router!r} router drops tokens "
@@ -402,13 +405,11 @@ class ServingEngine:
                      "of K and V and restores them")):
                 if on:
                     raise ValueError(
-                        f"init_serving: {mechanism} {what}; this model's "
-                        f"{mcfg.mixers.count('linear')} linear layers hold a "
-                        f"recurrent state a slot and its "
-                        f"{mcfg.mixers.count('sparse')} sparse layers a "
-                        f"compressed-key cache, which no block of K and V "
-                        f"carries (a preempted request is recomputed)")
-            if cfg.prefill_chunk % mcfg.sparse.stride:
+                        f"init_serving: {mechanism} {what}; "
+                        f"{hybrid.what_no_block_carries(mcfg)}, which no "
+                        f"block of K and V carries (a preempted request is "
+                        f"recomputed)")
+            if "sparse" in mcfg.mixers and cfg.prefill_chunk % mcfg.sparse.stride:
                 raise ValueError(
                     f"init_serving: prefill_chunk {cfg.prefill_chunk} is not "
                     f"whole strides of {mcfg.sparse.stride} compressed keys")
@@ -429,7 +430,9 @@ class ServingEngine:
         from deepspeed_tpu.ops.pallas.decode_attention import (
             paged_layer_chunk_queries, paged_layer_tile_pages,
             paged_mla_chunk_queries, paged_mla_tile_pages)
-        if self._hybrid:
+        # rows of the attention's calls a token (a sparse layer's: K/V heads)
+        rows_a_token = 1
+        if "sparse" in mcfg.mixers:
             # a sparse layer attends a row a (token, K/V head), under the
             # table of the pages that token chose (``models/hybrid.py``)
             from deepspeed_tpu.ops.pallas.decode_attention import (
@@ -437,7 +440,7 @@ class ServingEngine:
             self.paged_tile_pages = paged_sparse_tile_pages(
                 mcfg.n_head // mcfg.kv_heads, mcfg.head_dim, cfg.block_size,
                 hybrid.table_columns(mcfg, cfg.block_size), self.dtype)
-            queries = 1
+            queries, rows_a_token = 1, mcfg.kv_heads
         elif mcfg.kv_lora_rank:
             shape = (mcfg.cache_lanes[0], mcfg.kv_lora_rank, cfg.block_size,
                      self.max_blocks_per_seq, self.dtype)
@@ -458,8 +461,7 @@ class ServingEngine:
         # rows where the program holds ``slots + chunk`` tokens
         self.chunk_queries_per_row = queries
         self.attention_rows = (cfg.max_batch_size
-                               + cfg.prefill_chunk // queries) * (
-                                   mcfg.kv_heads if self._hybrid else 1)
+                               + cfg.prefill_chunk // queries) * rows_a_token
         # bytes the arena holds a token a layer (every array of the cache
         # spec)
         self.cache_bytes_per_token = (sum(mcfg.cache_lanes)
@@ -505,12 +507,12 @@ class ServingEngine:
                 # a hybrid stack's step takes its state, and each row's slot
                 # and whether it carries a sequence, and gives the state back
                 rows = packed[:4 * layout.rows].reshape(layout.rows, 4)
-                more = {"aux": aux, "slots": rows[:, 2], "live": rows[:, 3] != 0}
+                more.update(aux=aux, slots=rows[:, 2], live=rows[:, 3] != 0)
             logits, kp, vp, *counts = model.paged_step(
                 params, ids, positions, kp, vp, tables, wb, wo,
                 chunk=layout.rows - layout.slots, **more)
             if aux is not None:
-                (aux,), counts = counts, []
+                aux, *counts = counts
             if mcfg.padded_vocab != mcfg.vocab_size:
                 vmask = jnp.arange(mcfg.padded_vocab) < mcfg.vocab_size
                 logits = jnp.where(vmask[None, None], logits, -1e30)
@@ -518,7 +520,8 @@ class ServingEngine:
             if counts:
                 # an MoE model's expert counts ride behind the token row in
                 # the one int32 array the host fetches: no second transfer
-                tokens = jnp.concatenate([tokens.reshape(-1), *counts])
+                tokens = jnp.concatenate(
+                    [tokens.reshape(-1), *(c.reshape(-1) for c in counts)])
             return tokens, kp, vp, state, aux
 
         # arena and table donation = in-place update; CPU can't donate (jax
@@ -606,22 +609,27 @@ class ServingEngine:
                                cfg.max_batch_size, self.dtype)
 
     def _hybrid_stats(self, rows) -> Dict[str, int]:
-        """What the sparse and the linear layers did in a step, from its
-        rows' positions (``rows``: the upload's ``[rows, 4]`` view): keys the
-        live rows attended against the keys resident before them, summed
-        over sparse layers and K/V heads; live rows at or under
-        ``dense_len``; slots whose state started from zero (a chunk at
-        position 0); bytes of state held."""
+        """What a hybrid stack's layers did in a step, from its rows'
+        positions (``rows``: the upload's ``[rows, 4]`` view): slots whose
+        state started from zero (a chunk at position 0) and bytes of state
+        held (``state_bytes`` the linear layers', ``cca_state_bytes`` the cca
+        layers'); of the sparse layers, keys the live rows attended against
+        the keys resident before them, summed over sparse layers and K/V
+        heads, and live rows at or under ``dense_len``."""
         from deepspeed_tpu.models import hybrid
         mcfg = self.module.cfg
-        t = rows[rows[:, 3] != 0, 1]
-        per = mcfg.mixers.count("sparse") * mcfg.kv_heads
         first = rows[self._config.max_batch_size]
-        return {"sparse_keys_attended": int(hybrid.keys_attended(mcfg, t).sum()) * per,
-                "sparse_keys_resident": int((t + 1).sum()) * per,
-                "sparse_rows_dense": int((t + 1 <= mcfg.sparse.dense_len).sum()),
-                "state_slots_reset": int(first[3] != 0 and first[1] == 0),
-                "state_bytes": int(self._aux["state"].nbytes)}
+        out = {"state_slots_reset": int(first[3] != 0 and first[1] == 0)}
+        out.update({name + "_bytes": int(a.nbytes)
+                    for name, a in self._aux.items() if name.endswith("state")})
+        if "sparse" in mcfg.mixers:
+            t = rows[rows[:, 3] != 0, 1]
+            per = mcfg.mixers.count("sparse") * mcfg.kv_heads
+            out.update(
+                sparse_keys_attended=int(hybrid.keys_attended(mcfg, t).sum()) * per,
+                sparse_keys_resident=int((t + 1).sum()) * per,
+                sparse_rows_dense=int((t + 1 <= mcfg.sparse.dense_len).sum()))
+        return out
 
     def _empty_tables(self):
         """The table state of an engine nobody is in: all trash, on the
@@ -758,7 +766,7 @@ class ServingEngine:
                     deadline_s=e.deadline_s, step=self.step_count) from e
         (row, self._k_pages, self._v_pages, self._tables, self._aux, t_launch,
          t_result) = out
-        n = row.size - self._moe_experts
+        n = row.size - self._moe_experts * self._moe_count_rows
         self._expert_counts = row[n:]
         return row[:n], t_launch, t_result
 
@@ -1022,13 +1030,16 @@ class ServingEngine:
     def _moe_stats(self) -> Dict[str, float]:
         """How the last program's live rows spread over the experts the
         router chooses among (summed over layers); nothing for a dense
-        model."""
-        counts = self._expert_counts
+        model.  ``moe_experts_touched``: the experts with an assignment, of
+        each layer where the program counts a layer apart (a hybrid stack),
+        else of the sum over layers."""
+        by_layer = self._expert_counts.reshape(-1, self._moe_experts or 1)
+        counts = by_layer.sum(axis=0)
         if not counts.size or not counts.any():
             return {}
         first, held = self.module.cfg.bank_experts
         return {"moe_load_max_over_mean": float(counts.max() / counts.mean()),
-                "moe_experts_touched": int((counts > 0).sum()),
+                "moe_experts_touched": int((by_layer > 0).sum()),
                 # of the live rows' assignments, those on experts held here
                 "moe_assignments": int(counts.sum()),
                 "moe_assignments_held": int(counts[first:first + held].sum())}

@@ -297,6 +297,67 @@ def test_the_hybrid_step_walks_its_runs_of_layers(chip):
     assert not re.search(rf"bf16\[{2 * rows},(64|128),64,128\]", text)
 
 
+def test_paged_gqa_kernel_compiles_at_zaya_heads(chip):
+    """ZAYA1-8B's attention as its serve cell runs it: 8 query heads on 2 K/V
+    heads of D=128 (4 rows of one product a K/V lane slice), pages of 64
+    tokens of 256 lanes (32 KiB), the arena of 4,000 blocks of 20 layers
+    WHOLE with the layer a scalar, tables 256 blocks wide; 48 slots at ``Sq =
+    1`` and the chunk of 208 as 2 rows of 104 queries (416 rows of one
+    product); a tile of 4 pages, 256 keys."""
+    H, Hkv, D128, BS, slots, chunk, MB = 8, 2, 128, 64, 48, 208, 256
+    rows = slots + chunk
+    assert da.gqa_kernel_shape_ok(H, Hkv, D128, BS, BF16)
+    arena = ((20, 4000, BS, Hkv * D128), BF16)
+    fn = lambda q, k, v, layer, tables, lengths: da.paged_layer_attention(
+        q, k, v, layer, tables, lengths, chunk=chunk)
+    text = _compiled_text(chip, fn, ((rows, 1, H, D128), BF16), arena, arena,
+                          ((), jnp.int32), ((rows, MB), jnp.int32), ((rows,), jnp.int32))
+    assert da.paged_layer_chunk_queries(chunk, H, Hkv, D128, BS, MB, BF16) == 104
+    assert _kernel_rows(text, "paged_gqa_attention") == [chunk // 104, slots]
+    assert "dynamic-slice" not in text        # no layer of K and V sliced out
+    assert da.paged_layer_tile_pages(1, H, Hkv, D128, BS, MB, BF16) == 4
+
+
+def test_the_zaya_step_reads_its_bank_and_its_pages_where_they_lie(chip):
+    """The whole step of two ZAYA1-8B layers at the published widths, 48
+    slots and a chunk of 208: the cca mixer's attention is the paged GQA
+    kernel at two shapes; the top-1 bank's two matmuls take the STACKED
+    leaves (no layer's bank sliced or copied out) at 256 assignments (two
+    whole row tiles) and, under the branch a step without a prompt chunk
+    takes, at the 48 decode rows alone (one tile)."""
+    from deepspeed_tpu.models import gpt, hybrid
+    from deepspeed_tpu.ops.pallas import grouped_matmul as gm
+    from deepspeed_tpu.serving.kv_cache import init_arena
+    cfg = gpt.zaya_config(n_layer=2, dtype=BF16)
+    model, slots, chunk, BS, NB, MB = gpt.GPT(cfg), 48, 208, 64, 257, 256
+    rows = slots + chunk
+    assert gm.kernel_shape_ok(rows, 2048, 4096, BF16) and gm.kernel_shape_ok(rows, 2048, 2048, BF16)
+    assert gm.rows_to_whole_tiles(rows, 2048, BF16) == 0
+    assert gm.rows_to_whole_tiles(slots, 2048, BF16) == 80        # 48 -> 128
+    shape = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=chip)
+    on_chip = lambda tree: jax.tree.map(lambda a: shape(a.shape, a.dtype), tree)
+    params = jax.tree.map(lambda p: shape(p.shape, BF16),
+                          jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
+    kp, vp = on_chip(jax.eval_shape(lambda: init_arena(cfg, NB, BS, dtype=BF16)))
+    aux = on_chip(jax.eval_shape(lambda: hybrid.init_aux(cfg, NB, BS, slots, BF16)))
+    assert aux["cca_state"].shape == (2, slots, 2 * 1280 + 128)
+    step = lambda p, ids, pos, kp, vp, tb, wb, wo, aux, sl, live: model.paged_step(
+        p, ids, pos, kp, vp, tb, wb, wo, chunk=chunk, aux=aux, slots=sl, live=live,
+        with_expert_counts=True)
+    text = jax.jit(step).lower(
+        params, shape((rows, 1), jnp.int32), shape((rows,), jnp.int32), kp, vp,
+        shape((rows, MB), jnp.int32), shape((rows, 1), jnp.int32),
+        shape((rows, 1), jnp.int32), aux, shape((rows,), jnp.int32),
+        shape((rows,), jnp.bool_)).compile().as_text()
+    assert _kernel_rows(text, "paged_gqa_attention") == [chunk // 104, slots]
+    calls = _bank_calls(text)
+    assert len(calls) == 4 and all("bf16[2,16,2048," in call for call in calls)
+    assert sorted(int(r) for r in re.findall(
+        r"%grouped_matmul[.\d]* = bf16\[(\d+),", text)) == [128, 128, rows, rows]
+    assert not _bank_copies(text, 16, 2048, 4096) and not _bank_copies(text, 16, 2048, 2048)
+    assert text.count("conditional(") >= 1
+
+
 def _bank_matmul_compiles(chip, rows, G, K, N, stacked):
     """``grouped_matmul`` compiled for the chip on a bank ``[G, K, N]``, or
     ``stacked`` on the 8 layers' ``[8, G, K, N]`` with a traced layer, which

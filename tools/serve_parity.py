@@ -3,7 +3,7 @@ reference, at the published widths: the chip comparison of the
 ``model-configs`` guide § 3 point 3, for any configuration file that names a
 ``serve`` block and a ``reference`` (``benchmarks/configs/olmoe-1b-7b.json``,
 ``smallthinker-21b-a3b.json``, ``mistral-small-4-119b.json``,
-``minicpm-sala-9b.json``).
+``minicpm-sala-9b.json``, ``zaya1-8b.json``).
 
 Run standalone on a TPU host (``chiprun --chips 1 -- python
 tools/serve_parity.py benchmarks/configs/smallthinker-21b-a3b.json``); any
@@ -142,11 +142,13 @@ def main(argv=None) -> int:
     if args.bank:
         # rounded in place (the tree donated): a bank and its rounded copy do
         # not both fit beside an arena; the reference's weights are made
-        # again from the seed once the engine is gone
-        served_params = jax.jit(lambda p: dict(p, blocks=dict(p["blocks"], moe=dict(
-            p["blocks"]["moe"],
-            experts=jax.tree.map(low, p["blocks"]["moe"]["experts"])))),
-            donate_argnums=0)(params)
+        # again from the seed once the engine is gone.  The bank is the
+        # group ``experts`` wherever the family keeps it
+        # (``blocks/moe/experts``, a hybrid stack's ``blocks/<mixer>/experts``)
+        served_params = jax.jit(lambda p: dict(p, blocks=jax.tree_util.tree_map_with_path(
+            lambda path, w: low(w) if any(
+                getattr(k, "key", None) == "experts" for k in path) else w, p["blocks"])),
+            donate_argnums=0)(served_params)
         params = None
     eng = deepspeed_tpu.init_serving(model=model, params=served_params, config={
         "serving": dict(config["serve"]["serving"], num_blocks=PARITY_BLOCKS)})
@@ -157,7 +159,7 @@ def main(argv=None) -> int:
         lengths.append(window + 200)            # prefill AND decode past the window
     if cfg.rope_yarn is not None:               # and past the stretched rope's
         lengths.append(cfg.rope_yarn.original_positions + 100)      # original range
-    if cfg.sparse is not None:                  # and past where every key is attended
+    if "sparse" in cfg.mixers:                  # and past where every key is attended
         lengths.append(cfg.sparse.dense_len + 200)
     prompts = [rng.integers(0, cfg.vocab_size, n).tolist() for n in lengths]
     futures = [eng.submit(p, max_new_tokens=args.new) for p in prompts]
