@@ -1086,19 +1086,25 @@ def paged_mla_attention(q, arena, layer, block_tables, lengths, *, scale,
                                          scale=scale, value_lanes=value_lanes)
 
 
-def _takes_gqa_kernel(H, Hkv, bias, window) -> bool:
-    """:func:`paged_layer_attention`'s rule."""
-    return not bias and (window is not None or Hkv != H)
+def _takes_gqa_kernel(H, Hkv, D, bias, window) -> bool:
+    """:func:`paged_layer_attention`'s rule: no bias, and a window, grouped
+    K/V heads, or heads that are whole lane tiles (what
+    :func:`gqa_kernel_shape_ok` asks of a head)."""
+    return not bias and (window is not None or Hkv != H or D % _LANES == 0)
 
 
 def paged_layer_attention(q, k_arena, v_arena, layer, block_tables, lengths,
                           bias=None, window=None, chunk: int = 0):
-    """What ``gpt_paged_step`` calls a layer.  Grouped K/V heads or a window
-    go to :func:`paged_gqa_attention`; multi-head attention over every key
-    keeps the layer sliced out of the arena and :func:`paged_attention`
-    until the benchmark can see that copy disappear (ROADMAP S1, S0 (a)).
-    With ``chunk`` the rows hold one query each and the last ``chunk`` are a
-    prompt chunk, attended packed (:func:`_rows_and_chunk`)."""
+    """What ``gpt_paged_step`` calls a layer.  Grouped K/V heads, a window,
+    or multi-head attention whose heads are whole 128-lane tiles (OLMoE's 16
+    of 128: a group of one) go to :func:`paged_gqa_attention`, which takes
+    the arena whole.  Multi-head attention at ``D = 64`` over every key
+    keeps the layer sliced out of the arena and :func:`paged_attention`: the
+    successor has no two-heads-a-lane-slice case (``_attend_block``), and
+    ``decode-heavy``'s backlog cannot outlast a 124M step without the copy
+    (ROADMAP S1, S0 (l)).  So does a bias (ALiBi), which the successor does
+    not take.  With ``chunk`` the rows hold one query each and the last
+    ``chunk`` are a prompt chunk, attended packed (:func:`_rows_and_chunk`)."""
     H, D = q.shape[2:]
     Hkv = k_arena.shape[3] // D
     assert bias is None or window is None, (
@@ -1110,7 +1116,7 @@ def paged_layer_attention(q, k_arena, v_arena, layer, block_tables, lengths,
             chunk, H, Hkv, D, k_arena.shape[2], block_tables.shape[1], q.dtype,
             bias is not None, window)
         return _rows_and_chunk(attend, chunk, Sq, q, block_tables, lengths, bias)
-    if _takes_gqa_kernel(H, Hkv, bias is not None, window):
+    if _takes_gqa_kernel(H, Hkv, D, bias is not None, window):
         return paged_gqa_attention(q, k_arena, v_arena, layer, block_tables,
                                    lengths, window)
     kl = jax.lax.dynamic_index_in_dim(k_arena, layer, 0, keepdims=False)
@@ -1125,7 +1131,7 @@ def paged_layer_chunk_queries(chunk, H, Hkv, D, BS, MB, dtype, bias=False,
     heads the rows of one product, or a head a product of its own in its
     lane slice."""
     tile_keys = paged_tile_pages(BS, MB, 1, Hkv * D, dtype) * BS
-    if _takes_gqa_kernel(H, Hkv, bias, window):
+    if _takes_gqa_kernel(H, Hkv, D, bias, window):
         return paged_chunk_queries(chunk, H // Hkv, Hkv, D, D, tile_keys, dtype)
     W = _lane_slices(H, D)[0]
     return paged_chunk_queries(chunk, 1, H, W, W, tile_keys, dtype)
@@ -1135,7 +1141,7 @@ def paged_layer_tile_pages(Sq, H, Hkv, D, BS, MB, dtype, bias=False,
                            window=None) -> int:
     """Pages a tile of the kernel :func:`paged_layer_attention` builds for
     these shapes (0: a gather reference), by its rule."""
-    if _takes_gqa_kernel(H, Hkv, bias, window):
+    if _takes_gqa_kernel(H, Hkv, D, bias, window):
         return paged_gqa_tile_pages(Sq, H, Hkv, D, BS, MB, dtype)
     return paged_kernel_tile_pages(Sq, H, Hkv, D, BS, MB, dtype, bias)
 

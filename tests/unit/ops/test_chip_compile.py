@@ -202,22 +202,30 @@ def _kernel_rows(text, kernel):
         rf"%{kernel}[.\d]* = \w+\[(\d+),[^\n]*tpu_custom_call", text))
 
 
-@pytest.mark.parametrize("slots,H,head_dim,MB", [(256, 12, 64, 64), (128, 16, 128, 256)],
+@pytest.mark.parametrize("slots,H,head_dim,MB,kernel", [
+    (256, 12, 64, 64, "paged_attention"), (128, 16, 128, 256, "paged_gqa_attention")],
                          ids=["gpt2-124m", "olmoe-1b-7b"])
-def test_paged_kernel_compiles_at_a_serve_steps_rows(chip, slots, H, head_dim, MB):
+def test_paged_kernel_compiles_at_a_serve_steps_rows(chip, slots, H, head_dim, MB, kernel):
     """A layer's attention in the one program of a serve step, at the
-    benchmark cells' sizes: ``slots`` decode rows a query each, their
-    flattened block tables (256 x 64 and 128 x 256 int32: 64 and 128 KiB)
-    the kernel's scalar prefetch, and the prompt chunk's 64 tokens as ONE row
-    of 64 queries; no call of ``slots + CHUNK`` single-query rows."""
+    benchmark cells' sizes: ``slots`` decode rows a query each and the prompt
+    chunk's 64 tokens as ONE row of 64 queries; no call of ``slots + CHUNK``
+    single-query rows.  GPT-2's heads of 64 (two a lane tile) keep the layer
+    sliced out of the arena and ``paged_attention``, the flattened block
+    tables (256 x 64 int32: 64 KiB) its scalar prefetch; OLMoE's heads of 128
+    are whole lane tiles, a group of ONE on a K/V head each: the arena whole
+    through ``paged_gqa_attention`` (a row's table of 256 blocks an SMEM
+    block), and no layer of K and V sliced out."""
     rows, BS = slots + CHUNK, 16
     arena = ((2, 1025, BS, H * head_dim), BF16)
     fn = lambda *a: da.paged_layer_attention(*a, chunk=CHUNK)
     text = _compiled_text(chip, fn, ((rows, 1, H, head_dim), BF16), arena, arena,
                           ((), jnp.int32), ((rows, MB), jnp.int32), ((rows,), jnp.int32))
     assert da.paged_layer_chunk_queries(CHUNK, H, H, head_dim, BS, MB, BF16) == CHUNK
-    assert _kernel_rows(text, "paged_attention") == [1, slots]
+    assert _kernel_rows(text, kernel) == [1, slots]
     assert da.paged_kernel_tile_pages(1, H, H, head_dim, BS, MB, BF16) == 8
+    assert da.paged_layer_tile_pages(1, H, H, head_dim, BS, MB, BF16) == 8
+    # D = 64 did not move: the layer's slice is still there for its kernel
+    assert ("dynamic-slice" in text) == (kernel == "paged_attention")
 
 
 @pytest.mark.parametrize("window,MB", [(None, 1024), (4096, 271)],
@@ -469,7 +477,7 @@ SERVE_CELLS = {
     "gpt2-124m": (lambda m: m.gpt_config("gpt2", n_layer=1, dtype=BF16),
                   256, 64, 1024, "paged_attention", 64),
     "olmoe-1b-7b": (lambda m: m.olmoe_config(n_layer=1, dtype=BF16),
-                    128, 64, 4096, "paged_attention", 64),
+                    128, 64, 4096, "paged_gqa_attention", 64),
     "smallthinker-21b-a3b": (lambda m: m.smallthinker_config(n_layer=4, dtype=BF16),
                              32, 224, 16384, "paged_gqa_attention", 32),
     "mistral-small-4-119b": (lambda m: m.mistral4_config(
@@ -478,9 +486,9 @@ SERVE_CELLS = {
 }
 
 
-def _step_text(chip, cell, periods=1):
+def _step_text(chip, cell, periods=1, blocks=1025):
     """(config, compiled text) of the whole step of a serve configuration
-    at ``periods`` periods of its layers."""
+    at ``periods`` periods of its layers, over an arena of ``blocks``."""
     from deepspeed_tpu.models import gpt
     from deepspeed_tpu.serving.kv_cache import init_arena, window_table_blocks
     make, slots, chunk, positions, kernel, Sq = SERVE_CELLS[cell]
@@ -493,7 +501,7 @@ def _step_text(chip, cell, periods=1):
         lambda p: shape(p.shape, BF16 if jnp.issubdtype(p.dtype, jnp.floating)
                         else p.dtype),
         jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
-    kp, vp = on_chip(jax.eval_shape(lambda: init_arena(cfg, 1025, BS, dtype=BF16)))
+    kp, vp = on_chip(jax.eval_shape(lambda: init_arena(cfg, blocks, BS, dtype=BF16)))
     widths = [positions // BS if kind.window is None
               else window_table_blocks(kind.window, chunk, BS) for kind in cfg.pattern]
     tables = tuple(shape((rows, w), jnp.int32) for w in widths)
@@ -515,6 +523,21 @@ def test_the_step_program_attends_the_chunk_packed(chip, cell):
     assert chunk % Sq == 0 and Sq > 1
     assert _kernel_rows(text, kernel) == sorted(
         [slots, chunk // Sq] * len(cfg.pattern))
+
+
+def test_the_olmoe_step_reads_its_pages_where_they_lie(chip):
+    """The whole step of two OLMoE layers at the published widths, 128 slots
+    and a chunk of 64, over the serve cell's arena of 4,097 blocks: the
+    attention is ``paged_gqa_attention`` on the arena whole, and the program
+    makes NO array of one layer's K or V (0.27 GB each, copied out in every
+    layer of every step until PR 43: PERF.md § 6)."""
+    _, slots, chunk, _, kernel, Sq = SERVE_CELLS["olmoe-1b-7b"]
+    cfg, text = _step_text(chip, "olmoe-1b-7b", periods=2, blocks=4097)
+    assert cfg.n_layer == 2 and cfg.n_head == cfg.kv_heads == 16 and cfg.head_dim == 128
+    assert _kernel_rows(text, kernel) == [chunk // Sq, slots]
+    assert not _kernel_rows(text, "paged_attention")
+    assert "bf16[2,4097,16,2048]" in text           # the arena itself
+    assert not re.search(r"bf16\[(1,)?4097,16,2048\]", text)
 
 
 @pytest.mark.parametrize("periods", [1, 2])
