@@ -1664,7 +1664,8 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
     """
     assert cfg.scan_layers, "paged serving path requires scan_layers"
     from deepspeed_tpu.ops.pallas.decode_attention import (
-        paged_layer_attention, paged_mla_attention, paged_mla_tile_runs)
+        paged_layer_attention, paged_layer_run_pages, paged_mla_attention,
+        paged_mla_tile_pages, paged_tile_runs)
     B, S = input_ids.shape
     assert not chunk or S == 1, "a prompt chunk is named among one-token rows"
     H, E = cfg.n_head, cfg.n_embd
@@ -1700,10 +1701,19 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
         moe = dict(blocks["moe"])
         bank = moe.pop("experts")
         blocks = {**blocks, "moe": moe}
-    # which tiles of the tables the latent kernel fetches with one copy: the
-    # same for every layer, so worked out here and not in the scan
-    tile_runs = (paged_mla_tile_runs(block_tables[0], k_pages, cfg.kv_lora_rank)
-                 if cfg.kv_lora_rank else None)
+    # which tiles of each group's tables its kernel fetches with one copy
+    # (None: it copies page by page): the same for every layer, so worked
+    # out here and not in the scan
+    def run_pages(kind, tables):
+        if cfg.kv_lora_rank:
+            return paged_mla_tile_pages(k_pages.shape[-1], cfg.kv_lora_rank, BS,
+                                        tables.shape[1], k_pages.dtype)
+        return paged_layer_run_pages(
+            H, cfg.kv_heads, cfg.head_dim, BS, tables.shape[1], k_pages.dtype,
+            attn_bias is not None, kind.window)
+
+    tile_runs = [paged_tile_runs(tables, k_pages.shape[1], run_pages(kind, tables))
+                 for kind, tables in zip(cfg.pattern, block_tables)]
 
     def layer(j, carry, p):
         # ``li``: the layer's index inside its group ``j`` (the period)
@@ -1729,7 +1739,7 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
                     o = paged_mla_attention(
                         q, kp, li, block_tables[j], positions,
                         scale=1.0 / math.sqrt(cfg.head_dim), value_lanes=R,
-                        chunk=chunk, tile_runs=tile_runs)
+                        chunk=chunk, tile_runs=tile_runs[j])
                 o = jnp.einsum("bshr,rhd->bshd", o, w_uv).reshape(B, S, -1)
             else:
                 q, k, v = _project_qkv(cfg, p, h, dt, pos2d, kind)
@@ -1745,8 +1755,8 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
                         "attn_full" if kind.window is None else "attn_window"):
                     o = paged_layer_attention(
                         q, kp, vp, li, block_tables[j], positions,
-                        bias=attn_bias, window=kind.window, chunk=chunk
-                    ).reshape(B, S, cfg.attn_dim)
+                        bias=attn_bias, window=kind.window, chunk=chunk,
+                        tile_runs=tile_runs[j]).reshape(B, S, cfg.attn_dim)
             o = o @ _wget(p, "out_w", dt)
             if cfg.use_bias:
                 o = o + p["out_b"].astype(dt)

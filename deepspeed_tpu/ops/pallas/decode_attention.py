@@ -405,6 +405,104 @@ def _rows_and_chunk(attend, chunk: int, Sq: int, q, block_tables, lengths,
     return jnp.concatenate([o, oc.reshape(chunk, 1, *oc.shape[2:])])
 
 
+# A tile that is fetched as RUNS (``paged_tile_runs``) may hold this many keys,
+# and this many bytes of K and V together: the unit of the COPY, where
+# :func:`paged_tile_pages` stays the unit of the attend.  On a v5e a tile costs
+# 0.24 us whatever it holds beside 0.21 us for every 128 KiB (PERF.md § 6,
+# PR 44), so once a tile is one copy it pays to bring more with it, while that
+# fixed part is a fifth of the tile and more: ZAYA1's 256 keys (256 KiB) ran
+# 18% faster at 512 and SmallThinker's 128 (256 KiB) 24% faster at 512, where
+# OLMoE's 128 keys, 1 MiB already, ran 8% SLOWER at 256 (rows of 27 pages in
+# the mean: a row's last, short tile is copied page by page, and a larger tile
+# makes it longer)
+_RUN_TILE_ROWS = 512
+_RUN_TILE_BYTES = 1024 * 1024
+
+
+def paged_run_tile_pages(BS: int, MB: int, lanes: int, dtype) -> int:
+    """``G`` of a tile whose pages, where they lie together, are ONE copy an
+    operand: the attend's pages (:func:`paged_tile_pages`) doubled while the
+    tile stays inside :data:`_RUN_TILE_ROWS` keys and :data:`_RUN_TILE_BYTES`
+    (two such tiles an operand are then inside :data:`_TILE_VMEM_BYTES`) and
+    the table holds it: whole attend steps."""
+    page_bytes = 2 * BS * lanes * np.dtype(dtype).itemsize      # K and V
+    G = paged_tile_pages(BS, MB, 1, lanes, dtype)
+    while (2 * G * BS <= _RUN_TILE_ROWS and 2 * G <= MB
+           and 2 * G * page_bytes <= _RUN_TILE_BYTES):
+        G *= 2
+    return G
+
+
+def paged_tile_runs(block_tables, pages: int, G: int):
+    """``[B, ceil(MB / G)]`` int32, 1 where a tile of ``G`` pages of a row's
+    table is a RUN that a paged kernel fetches with one copy
+    (:func:`_tile_copies`): its ``G`` entries are consecutive pages in order,
+    all inside the arena of ``pages`` (``tbl[t*G + j] == tbl[t*G] + j`` for
+    every ``j < G`` and ``tbl[t*G] + G <= pages``; no alignment is asked, so
+    what a prompt's blocks happen to form counts as what
+    ``serving/kv_cache.py`` lays down in runs).  A short last tile (``MB %
+    G``) is none.  None where ``G`` is 0 (no kernel that fetches runs reads
+    these tables).  The same for every layer: the step works it out once,
+    outside its scan over layers."""
+    if not G:
+        return None
+    B, MB = block_tables.shape
+    tiles = jnp.pad(jnp.asarray(block_tables, jnp.int32),
+                    ((0, 0), (0, -MB % G)), constant_values=-1).reshape(B, -1, G)
+    first = tiles[:, :, :1]
+    run = jnp.all(tiles - first == jnp.arange(G, dtype=jnp.int32), axis=-1)
+    return (run & (first[:, :, 0] + G <= pages)).astype(jnp.int32)
+
+
+def _tile_copies(do, operands, entry, first_col, live, G: int, bs: int,
+                 run=None):
+    """``do`` (start or wait: a wait needs the shapes only, so it shares the
+    code) the copies that bring ONE tile of ``G`` pages into a buffer of each
+    operand, for every paged kernel that takes the arena whole.  ``operands``:
+    a ``(source, buffer, semaphore)`` each, ``source(page, n)`` the ``n``
+    pages from physical ``page`` of the layer's arena, ``buffer(rows)`` the
+    tile's buffer, whole or its ``rows``, and ``semaphore()`` the buffer's;
+    ``entry(col)`` the physical page in column ``col`` of the row's table,
+    ``first_col()`` the tile's first column (worked out where it is read,
+    inside the loop over pages) and ``live`` how many of its pages the row
+    has reached (any integer).
+
+    * ``run`` None: a copy a live page, ``min(G, live)`` signals a semaphore;
+    * ``run`` the tile's flag of :func:`paged_tile_runs`: where it is set AND
+      all ``G`` pages are live, ONE copy of ``G`` pages from ``entry(
+      first_col())`` an operand, which signals its semaphore ONCE; any other
+      tile page by page as above.
+
+    A start and its wait are handed the same two words (the flag and the
+    row's length), so they choose alike and the semaphores balance on every
+    path.  Both ways bring live pages only, so a buffer holds zeros or what
+    some table listed, as ``_paged_kernel`` has it: a table may list the
+    pages of a run before they are written (a prompt's blocks are all taken
+    at admission), and those are fetched when the row has reached them."""
+    def pages():
+        def page(j, c):
+            phys = entry(first_col() + j)
+            srcs = [source(phys, 1) for source, _, _ in operands]
+            dst = pl.ds(pl.multiple_of(j * bs, bs), bs)
+            for src, (_, buffer, sem) in zip(srcs, operands):
+                do(pltpu.make_async_copy(src, buffer(dst), sem()))
+            return c
+
+        jax.lax.fori_loop(0, jnp.clip(live, 0, G), page, 0)
+
+    if run is None:
+        return pages()
+    is_run = (run != 0) & (live >= G)
+
+    @pl.when(is_run)
+    def _():
+        phys = entry(first_col())
+        for source, buffer, sem in operands:
+            do(pltpu.make_async_copy(source(phys, G), buffer(), sem()))
+
+    pl.when(jnp.logical_not(is_run))(pages)
+
+
 def _paged_kernel(len_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf,
                   sem, slot_ref, *, scale, bs, Sq, H, D, MB, G):
     """Grid (B,): per row, DMA ONLY the ``ceil((len+Sq)/bs)`` live physical
@@ -548,9 +646,8 @@ def gqa_kernel_shape_ok(H: int, Hkv: int, D: int, block: int, dtype) -> bool:
     return H % Hkv == 0 and D % _LANES == 0 and block % sublane == 0
 
 
-def _paged_gqa_kernel(lay_ref, len_ref, tbl_ref, nxt_ref, q_ref, k_hbm, v_hbm,
-                      o_ref, k_buf, v_buf, sem, slot_ref, *, scale, bs, Sq,
-                      Hkv, D, MB, G, window):
+def _paged_gqa_kernel(lay_ref, len_ref, tbl_ref, nxt_ref, *refs, scale, bs, Sq,
+                      Hkv, D, MB, G, A, window, runs):
     """Grid (B,), a row a step, as ``_paged_kernel`` (tiles of ``G`` pages,
     two buffers an operand, the next tile — the next row's first after the
     row's last — fetched while this one is attended; read its docstring for
@@ -569,7 +666,21 @@ def _paged_gqa_kernel(lay_ref, len_ref, tbl_ref, nxt_ref, q_ref, k_hbm, v_hbm,
       visible key, ``(len - window + 1) // bs``, masks inside it, and reads
       the table as a ring (logical block ``b`` in column ``b % MB``).  A
       query whose every key of a tile is masked (``Sq > 1`` only) adds
-      exactly nothing there: its probabilities are forced to 0."""
+      exactly nothing there: its probabilities are forced to 0;
+    * with ``runs`` (a full group whose tables grow in runs of a tile) the
+      row's flags of :func:`paged_tile_runs` and the next row's follow the
+      tables as SMEM blocks (``run_ref``, ``nrun_ref`` ``[1, tiles]``), the
+      arenas come viewed ``[layers, pages * bs, lanes]``, and a tile whose
+      flag is set and whose ``G`` pages are all live is ONE copy an operand
+      (:func:`_tile_copies`).  There a tile is the unit of the copy alone: it
+      is attended in steps of ``A`` keys, the tile of a call without flags,
+      one online-softmax update each, in order (a step past the row's last
+      key adds exactly nothing), so the flags and the tile's size change
+      nothing in what comes out.  Without ``runs`` the kernel is what it
+      was: a copy a page, the tile attended whole."""
+    if runs:
+        run_ref, nrun_ref, *refs = refs
+    q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, slot_ref = refs
     b = pl.program_id(0)
     layer = lay_ref[0]
     seq_len = len_ref[b]
@@ -591,18 +702,25 @@ def _paged_gqa_kernel(lay_ref, len_ref, tbl_ref, nxt_ref, q_ref, k_hbm, v_hbm,
     def tile_copies(row, t, slot, do):
         first = first_page(row)
 
-        def page(j, c):
-            logical = first + t * G + j
+        def entry(logical):
             col = logical % MB if window is not None else logical
-            phys = jnp.where(row == b, tbl_ref[0, col], nxt_ref[0, col])
-            dst = pl.ds(pl.multiple_of(j * bs, bs), bs)
-            do(pltpu.make_async_copy(k_hbm.at[layer, phys],
-                                     k_buf.at[slot, dst], sem.at[0, slot]))
-            do(pltpu.make_async_copy(v_hbm.at[layer, phys],
-                                     v_buf.at[slot, dst], sem.at[1, slot]))
-            return c
+            return jnp.where(row == b, tbl_ref[0, col], nxt_ref[0, col])
 
-        jax.lax.fori_loop(0, jnp.clip(pages_of(row) - t * G, 0, G), page, 0)
+        def operand(hbm, buf, i):
+            if runs:        # the arena ``[layers, pages * bs, lanes]``
+                source = lambda page, n: hbm.at[layer, pl.ds(
+                    pl.multiple_of(page * bs, bs), n * bs)]
+            else:           # ``[layers, pages, bs, lanes]``, a page a copy
+                source = lambda page, n: hbm.at[layer, page]
+            return (source, lambda *rows: buf.at[(slot, *rows)],
+                    lambda: sem.at[i, slot])
+
+        live = pages_of(row) - t * G
+        run = None
+        if runs and k_hbm.shape[1] >= rows_t:   # else an arena under a tile
+            run = jnp.where(row == b, run_ref[0, t], nrun_ref[0, t])
+        _tile_copies(do, [operand(k_hbm, k_buf, 0), operand(v_hbm, v_buf, 1)],
+                     entry, lambda: first + t * G, live, G, bs, run)
 
     start = lambda cp: cp.start()
 
@@ -626,29 +744,32 @@ def _paged_gqa_kernel(lay_ref, len_ref, tbl_ref, nxt_ref, q_ref, k_hbm, v_hbm,
                         1 - slot, start)
 
         tile_copies(b, t, slot, lambda cp: cp.wait())
-        qpos = seq_len + jax.lax.broadcasted_iota(jnp.int32, (M, rows_t), 0) % Sq
-        cols = (p0 + t * G) * bs + jax.lax.broadcasted_iota(
-            jnp.int32, (M, rows_t), 1)
-        valid = (cols <= qpos) & (cols < (p0 + nk) * bs)
-        if window is not None:
-            valid = valid & (cols > qpos - window)
         m, l, acc = (list(c) for c in carry)
-        for h in range(Hkv):
-            k = k_buf[slot, :, h * D:(h + 1) * D]             # [rows_t, D]
-            v = v_buf[slot, :, h * D:(h + 1) * D]
-            s = jax.lax.dot_general(q[h], k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32) * scale
-            s = jnp.where(valid, s, NEG_INF)                  # [M, rows_t]
-            m_new = jnp.maximum(m[h], jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)
+        for lo in range(0, rows_t, A):            # the tile, A keys a step
+            qpos = seq_len + jax.lax.broadcasted_iota(jnp.int32, (M, A), 0) % Sq
+            cols = (p0 + t * G) * bs + jax.lax.broadcasted_iota(
+                jnp.int32, (M, A), 1)
+            if lo:
+                cols = cols + lo
+            valid = (cols <= qpos) & (cols < (p0 + nk) * bs)
             if window is not None:
-                p = jnp.where(valid, p, 0.0)
-            alpha = jnp.exp(m[h] - m_new)
-            l[h] = l[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            m[h] = m_new
-            acc[h] = acc[h] * alpha + jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+                valid = valid & (cols > qpos - window)
+            for h in range(Hkv):
+                k = k_buf[slot, lo:lo + A, h * D:(h + 1) * D]     # [A, D]
+                v = v_buf[slot, lo:lo + A, h * D:(h + 1) * D]
+                s = jax.lax.dot_general(q[h], k, (((1,), (1,)), ((), ())),
+                                        preferred_element_type=jnp.float32) * scale
+                s = jnp.where(valid, s, NEG_INF)                  # [M, A]
+                m_new = jnp.maximum(m[h], jnp.max(s, axis=-1, keepdims=True))
+                p = jnp.exp(s - m_new)
+                if window is not None:
+                    p = jnp.where(valid, p, 0.0)
+                alpha = jnp.exp(m[h] - m_new)
+                l[h] = l[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+                m[h] = m_new
+                acc[h] = acc[h] * alpha + jax.lax.dot_general(
+                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
         return tuple(m), tuple(l), tuple(acc)
 
     carry = (tuple(jnp.full((M, 1), NEG_INF, jnp.float32) for _ in range(Hkv)),
@@ -661,13 +782,14 @@ def _paged_gqa_kernel(lay_ref, len_ref, tbl_ref, nxt_ref, q_ref, k_hbm, v_hbm,
 
 
 def _paged_gqa_call(q, k_arena, v_arena, layer, block_tables, lengths, window,
-                    name="paged_gqa_attention"):
+                    tile_runs=None, name="paged_gqa_attention"):
     B, Sq, H, D = q.shape
-    _, _, BS, lanes = k_arena.shape
+    L, NB, BS, lanes = k_arena.shape
     Hkv = lanes // D
     g = H // Hkv
     MB = block_tables.shape[1]
     G = paged_tile_pages(BS, MB, Sq, lanes, k_arena.dtype)
+    A = G * BS      # keys an attend step: the tile of a call without flags
     block_tables = jnp.asarray(block_tables, jnp.int32)[:, None, :]
     sublane = 8 * 4 // np.dtype(q.dtype).itemsize
     M = -(-g * Sq // sublane) * sublane
@@ -676,16 +798,28 @@ def _paged_gqa_call(q, k_arena, v_arena, layer, block_tables, lengths, window,
         B, Hkv, g * Sq, D)
     qg = jnp.pad(qg, ((0, 0), (0, 0), (0, M - g * Sq), (0, 0)))
     row = lambda b, *_: (b, 0, 0, 0)
+    # a row's table and the next row's, ``[B, 1, cols]``: a block's last two
+    # dimensions are the array's
+    smem = lambda cols: [
+        pl.BlockSpec((None, 1, cols), at, memory_space=pltpu.MemorySpace.SMEM)
+        for at in (lambda b, *_: (b, 0, 0),
+                   lambda b, *_: (jnp.minimum(b + 1, B - 1), 0, 0))]
+    tables, specs = [block_tables, block_tables], smem(MB)
+    if tile_runs is not None:
+        assert window is None, "a ring holds no runs"
+        G = paged_run_tile_pages(BS, MB, lanes, k_arena.dtype)
+        tile_runs = jnp.asarray(tile_runs, jnp.int32)[:, None, :]
+        tiles = tile_runs.shape[2]
+        assert tiles == -(-MB // G), (tile_runs.shape, MB, G)
+        tables += [tile_runs, tile_runs]
+        specs += smem(tiles)
+        # a page's rows lie together: a run of pages is one slice
+        k_arena = k_arena.reshape(L, NB * BS, lanes)
+        v_arena = v_arena.reshape(L, NB * BS, lanes)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                    # layer, lengths
         grid=(B,),
-        in_specs=[
-            # [B, 1, MB]: a block's last two dimensions are the array's
-            pl.BlockSpec((None, 1, MB), lambda b, *_: (b, 0, 0),
-                         memory_space=pltpu.MemorySpace.SMEM),
-            pl.BlockSpec((None, 1, MB),
-                         lambda b, *_: (jnp.minimum(b + 1, B - 1), 0, 0),
-                         memory_space=pltpu.MemorySpace.SMEM),
+        in_specs=specs + [
             pl.BlockSpec((1, Hkv, M, D), row),
             pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
             pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
@@ -700,7 +834,8 @@ def _paged_gqa_call(q, k_arena, v_arena, layer, block_tables, lengths, window,
     )
     out = pl.pallas_call(
         functools.partial(_paged_gqa_kernel, scale=1.0 / np.sqrt(D), bs=BS,
-                          Sq=Sq, Hkv=Hkv, D=D, MB=MB, G=G, window=window),
+                          Sq=Sq, Hkv=Hkv, D=D, MB=MB, G=G, A=A, window=window,
+                          runs=tile_runs is not None),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, M, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -708,7 +843,7 @@ def _paged_gqa_call(q, k_arena, v_arena, layer, block_tables, lengths, window,
         interpret=_pallas.interpret(),
         name=name,
     )(jnp.asarray(layer, jnp.int32).reshape(1), jnp.asarray(lengths, jnp.int32),
-      block_tables, block_tables, qg, k_arena, v_arena)
+      *tables, qg, k_arena, v_arena)
     out = out[:, :, :g * Sq].reshape(B, Hkv, g, Sq, D)
     return out.transpose(0, 3, 1, 2, 4).reshape(B, Sq, H, D)
 
@@ -724,19 +859,22 @@ def paged_gqa_tile_pages(Sq, H, Hkv, D, BS, MB, dtype) -> int:
 
 
 def paged_gqa_attention(q, k_arena, v_arena, layer, block_tables, lengths,
-                        window=None):
+                        window=None, tile_runs=None):
     """Block-table attention of layer ``layer`` of the arena
     ``[layers, pages, BS, Hkv*D]``: q ``[B, Sq, H, D]`` with ``H = g * Hkv``,
     ``block_tables [B, MB]`` (a ring under a ``window``, see
-    :func:`paged_attention_reference`), ``lengths [B]``.  The kernel where
-    :func:`paged_gqa_tile_pages` says so, else the layer sliced out and the
-    gather reference."""
+    :func:`paged_attention_reference`), ``lengths [B]``.  ``tile_runs``:
+    :func:`paged_tile_runs` of these tables at :func:`paged_layer_run_pages`
+    pages a tile, from a caller whose tables grow in runs (no window); None
+    is the kernel that copies page by page, and the same numbers to the bit.
+    The kernel where :func:`paged_gqa_tile_pages` says so, else the layer
+    sliced out and the gather reference."""
     B, Sq, H, D = q.shape
     _, _, BS, lanes = k_arena.shape
     if paged_gqa_tile_pages(Sq, H, lanes // D, D, BS, block_tables.shape[1],
                             k_arena.dtype):
         return _paged_gqa_call(q, k_arena, v_arena, layer, block_tables,
-                               lengths, window)
+                               lengths, window, tile_runs)
     kl = jax.lax.dynamic_index_in_dim(k_arena, layer, 0, keepdims=False)
     vl = jax.lax.dynamic_index_in_dim(v_arena, layer, 0, keepdims=False)
     return paged_attention_reference(q, kl, vl, block_tables, lengths,
@@ -851,28 +989,6 @@ def paged_mla_attention_reference(q, pages, block_tables, lengths, *, scale,
     return jnp.einsum("bhqk,bkv->bqhv", p.astype(q.dtype), c[..., :value_lanes])
 
 
-def paged_mla_tile_runs(block_tables, arena, value_lanes):
-    """``[B, ceil(MB / G)]`` int32, 1 where a tile of ``G`` pages of a row's
-    table is a RUN that :func:`_paged_mla_kernel` fetches with one copy: its
-    ``G`` entries are consecutive pages in order, all inside the arena
-    (``tbl[t*G + j] == tbl[t*G] + j`` for every ``j < G`` and ``tbl[t*G] + G
-    <= pages``; no alignment is asked, so what a prompt's blocks happen to
-    form counts as what ``serving/kv_cache.py`` lays down in runs).  A short
-    last tile (``MB % G``) is none.  None where :func:`paged_mla_attention`
-    takes the gather reference.  The same for every layer: the step works it
-    out once, outside its scan over layers."""
-    _, NB, BS, W = arena.shape
-    B, MB = block_tables.shape
-    G = paged_mla_tile_pages(W, value_lanes, BS, MB, arena.dtype)
-    if not G:
-        return None
-    tiles = jnp.pad(jnp.asarray(block_tables, jnp.int32),
-                    ((0, 0), (0, -MB % G)), constant_values=-1).reshape(B, -1, G)
-    first = tiles[:, :, :1]
-    run = jnp.all(tiles - first == jnp.arange(G, dtype=jnp.int32), axis=-1)
-    return (run & (first[:, :, 0] + G <= NB)).astype(jnp.int32)
-
-
 def _paged_mla_kernel(lay_ref, len_ref, tbl_ref, nxt_ref, run_ref, nrun_ref,
                       q_ref, c_hbm, o_ref, c_buf, sem, slot_ref, *, scale, bs,
                       Sq, R, MB, G):
@@ -888,26 +1004,14 @@ def _paged_mla_kernel(lay_ref, len_ref, tbl_ref, nxt_ref, run_ref, nrun_ref,
     steps of :func:`_mla_attend_rows` keys, one online-softmax update each, in
     order (a step past the row's last key adds exactly nothing).
 
-    A tile is copied in one of TWO ways, by the flag of
-    :func:`paged_mla_tile_runs` (``run_ref [1, tiles]`` for the row,
-    ``nrun_ref`` for the next, beside the tables; the arena comes viewed
-    ``[layers, pages * bs, W]``):
-
-    * a RUN whose ``G`` pages are all live (``(t+1)*G <= nk``): ONE copy
-      of ``[G*bs, W]`` from row ``tbl[t*G] * bs`` of the layer, which
-      signals the buffer's semaphore ONCE;
-    * any other tile (no run, or the row's live pages end inside it): a
-      copy a live page, ``n = min(G, nk - t*G)`` signals.
-
-    The wait mirrors the start: the same two words (the flag, which row
-    ``b`` read through ``nrun_ref`` when it started row ``b + 1``'s first
-    tile and row ``b + 1`` reads through ``run_ref`` when it waits, and the
-    row's length) choose one wait of the whole buffer or ``n`` of a page, so
-    the semaphores stay balanced on every control path.  Both ways bring
-    live pages only, so a buffer holds zeros or what some table listed, as
-    ``_paged_kernel`` has it: a table may list the pages of a run before
-    they are written (a prompt's blocks are all taken at admission), and
-    those are fetched when the row has reached them, not before."""
+    A tile is copied by :func:`_tile_copies`, under the flag of
+    :func:`paged_tile_runs` (``run_ref [1, tiles]`` for the row, ``nrun_ref``
+    for the next, beside the tables; the arena comes viewed ``[layers, pages
+    * bs, W]``): a RUN whose ``G`` pages are all live (``(t+1)*G <= nk``) is
+    ONE copy of ``[G*bs, W]`` from row ``tbl[t*G] * bs`` of the layer, any
+    other tile a copy a live page.  The flag is the word row ``b`` read
+    through ``nrun_ref`` when it started row ``b + 1``'s first tile and row
+    ``b + 1`` reads through ``run_ref`` when it waits."""
     b = pl.program_id(0)
     layer = lay_ref[0]
     seq_len = len_ref[b]
@@ -925,29 +1029,14 @@ def _paged_mla_kernel(lay_ref, len_ref, tbl_ref, nxt_ref, run_ref, nrun_ref,
         mine = row == b
         entry = lambda col: jnp.where(mine, tbl_ref[0, col], nxt_ref[0, col])
         live = pages_of(row) - t * G
-
-        def pages():
-            def page(j, c):
-                src = pl.ds(pl.multiple_of(entry(t * G + j) * bs, bs), bs)
-                dst = pl.ds(pl.multiple_of(j * bs, bs), bs)
-                do(pltpu.make_async_copy(c_hbm.at[layer, src],
-                                         c_buf.at[slot, dst], sem.at[slot]))
-                return c
-
-            jax.lax.fori_loop(0, jnp.clip(live, 0, G), page, 0)
-
-        if c_hbm.shape[1] < rows_t:         # an arena smaller than a tile
-            return pages()
-        is_run = (jnp.where(mine, run_ref[0, t], nrun_ref[0, t]) != 0) & (
-            live >= G)
-
-        @pl.when(is_run)
-        def _():
-            src = pl.ds(pl.multiple_of(entry(t * G) * bs, bs), rows_t)
-            do(pltpu.make_async_copy(c_hbm.at[layer, src], c_buf.at[slot],
-                                     sem.at[slot]))
-
-        pl.when(jnp.logical_not(is_run))(pages)
+        source = lambda page, n: c_hbm.at[layer, pl.ds(
+            pl.multiple_of(page * bs, bs), n * bs)]
+        run = None
+        if c_hbm.shape[1] >= rows_t:            # else an arena under a tile
+            run = jnp.where(mine, run_ref[0, t], nrun_ref[0, t])
+        _tile_copies(do, [(source, lambda *rows: c_buf.at[(slot, *rows)],
+                           lambda: sem.at[slot])], entry, lambda: t * G, live,
+                     G, bs, run)
 
     start = lambda cp: cp.start()
 
@@ -1060,15 +1149,16 @@ def paged_mla_attention(q, arena, layer, block_tables, lengths, *, scale,
     vector's first ``value_lanes`` lanes are the value -> ``[B, Sq, H,
     value_lanes]``.  With ``chunk`` the rows hold one query each and the
     last ``chunk`` are a prompt chunk, attended packed
-    (:func:`_rows_and_chunk`).  ``tile_runs``: :func:`paged_mla_tile_runs` of
-    these tables, from a caller that has it already (every layer of a step
-    reads the same tables).  The kernel where :func:`paged_mla_tile_pages`
-    says so, else the layer sliced out and the gather reference."""
+    (:func:`_rows_and_chunk`).  ``tile_runs``: :func:`paged_tile_runs` of
+    these tables at :func:`paged_mla_tile_pages` pages a tile, from a caller
+    that has it already (every layer of a step reads the same tables).  The
+    kernel where :func:`paged_mla_tile_pages` says so, else the layer sliced
+    out and the gather reference."""
     _, _, BS, W = arena.shape
     MB = block_tables.shape[1]
     G = paged_mla_tile_pages(W, value_lanes, BS, MB, arena.dtype)
     if tile_runs is None:
-        tile_runs = paged_mla_tile_runs(block_tables, arena, value_lanes)
+        tile_runs = paged_tile_runs(block_tables, arena.shape[1], G)
     if chunk:
         def attend(q, tables, lens, _):
             tables, runs = tables
@@ -1094,11 +1184,15 @@ def _takes_gqa_kernel(H, Hkv, D, bias, window) -> bool:
 
 
 def paged_layer_attention(q, k_arena, v_arena, layer, block_tables, lengths,
-                          bias=None, window=None, chunk: int = 0):
+                          bias=None, window=None, chunk: int = 0,
+                          tile_runs=None):
     """What ``gpt_paged_step`` calls a layer.  Grouped K/V heads, a window,
     or multi-head attention whose heads are whole 128-lane tiles (OLMoE's 16
     of 128: a group of one) go to :func:`paged_gqa_attention`, which takes
-    the arena whole.  Multi-head attention at ``D = 64`` over every key
+    the arena whole, and with ``tile_runs`` (:func:`paged_tile_runs` of these
+    tables at :func:`paged_layer_run_pages` pages, worked out once a step) a
+    tile of pages that lie together with one copy.  Multi-head attention at
+    ``D = 64`` over every key
     keeps the layer sliced out of the arena and :func:`paged_attention`: the
     successor has no two-heads-a-lane-slice case (``_attend_block``), and
     ``decode-heavy``'s backlog cannot outlast a 124M step without the copy
@@ -1111,17 +1205,34 @@ def paged_layer_attention(q, k_arena, v_arena, layer, block_tables, lengths,
         "a window layer with an additive bias has no paged path")
     if chunk:
         attend = lambda q, tables, lens, bias: paged_layer_attention(
-            q, k_arena, v_arena, layer, tables, lens, bias=bias, window=window)
+            q, k_arena, v_arena, layer, tables[0], lens, bias=bias,
+            window=window, tile_runs=tables[1])
         Sq = paged_layer_chunk_queries(
             chunk, H, Hkv, D, k_arena.shape[2], block_tables.shape[1], q.dtype,
             bias is not None, window)
-        return _rows_and_chunk(attend, chunk, Sq, q, block_tables, lengths, bias)
+        return _rows_and_chunk(attend, chunk, Sq, q, (block_tables, tile_runs),
+                               lengths, bias)
     if _takes_gqa_kernel(H, Hkv, D, bias is not None, window):
         return paged_gqa_attention(q, k_arena, v_arena, layer, block_tables,
-                                   lengths, window)
+                                   lengths, window, tile_runs)
     kl = jax.lax.dynamic_index_in_dim(k_arena, layer, 0, keepdims=False)
     vl = jax.lax.dynamic_index_in_dim(v_arena, layer, 0, keepdims=False)
     return paged_attention(q, kl, vl, block_tables, lengths, bias=bias)
+
+
+def paged_layer_run_pages(H, Hkv, D, BS, MB, dtype, bias=False,
+                          window=None) -> int:
+    """Pages of a tile that the kernel :func:`paged_layer_attention` builds
+    for these shapes fetches with ONE copy an operand where they lie together
+    (:func:`paged_run_tile_pages`): what :func:`paged_tile_runs` flags and,
+    told by ``init_serving``, the allocator lays down in runs.  0 where it
+    copies page by page: ``paged_attention`` at ``D = 64`` or under a bias, a
+    window group's ring (it gives pages back, and runs in a ring are a design
+    of their own: ROADMAP S3 (b)), or a gather reference."""
+    if (window is not None or not _takes_gqa_kernel(H, Hkv, D, bias, window)
+            or not paged_gqa_tile_pages(1, H, Hkv, D, BS, MB, dtype)):
+        return 0
+    return paged_run_tile_pages(BS, MB, Hkv * D, dtype)
 
 
 def paged_layer_chunk_queries(chunk, H, Hkv, D, BS, MB, dtype, bias=False,

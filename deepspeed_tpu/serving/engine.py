@@ -428,8 +428,9 @@ class ServingEngine:
         # paged kernel's pages per tile, 0 on the einsum path; a stat of
         # every step
         from deepspeed_tpu.ops.pallas.decode_attention import (
-            paged_layer_chunk_queries, paged_layer_tile_pages,
-            paged_mla_chunk_queries, paged_mla_tile_pages)
+            paged_layer_chunk_queries, paged_layer_run_pages,
+            paged_layer_tile_pages, paged_mla_chunk_queries,
+            paged_mla_tile_pages)
         # rows of the attention's calls a token (a sparse layer's: K/V heads)
         rows_a_token = 1
         if "sparse" in mcfg.mixers:
@@ -447,15 +448,22 @@ class ServingEngine:
             self.paged_tile_pages = paged_mla_tile_pages(*shape)
             queries = paged_mla_chunk_queries(cfg.prefill_chunk, mcfg.n_head,
                                               *shape)
-            # the one kernel that fetches a tile of consecutive pages with
-            # one copy: its tables grow in runs of a tile
+            # a kernel that fetches a tile of consecutive pages with one
+            # copy: its tables grow in runs of a tile
             self._run_blocks = max(1, self.paged_tile_pages)
         else:
             shape = (mcfg.n_head, mcfg.kv_heads, mcfg.head_dim, cfg.block_size,
                      self.max_blocks_per_seq, self.dtype,
-                     mcfg.position_encoding == "alibi", self._windows[0])
-            self.paged_tile_pages = paged_layer_tile_pages(1, *shape)
-            queries = paged_layer_chunk_queries(cfg.prefill_chunk, *shape)
+                     mcfg.position_encoding == "alibi")
+            self.paged_tile_pages = paged_layer_tile_pages(
+                1, *shape, self._windows[0])
+            queries = paged_layer_chunk_queries(cfg.prefill_chunk, *shape,
+                                                self._windows[0])
+            # so does the kernel that serves a group over every key where a
+            # head is whole lane tiles (0 pages: it copies page by page, as
+            # every kernel does on a window group's ring)
+            if None in self._windows:
+                self._run_blocks = max(1, paged_layer_run_pages(*shape))
         # how a layer's attention takes the step's prompt chunk (static too):
         # ``queries`` consecutive tokens a row, so its calls run this many
         # rows where the program holds ``slots + chunk`` tokens
@@ -1008,8 +1016,10 @@ class ServingEngine:
         """Of the tiles the live sequences' full-attention tables hold, the
         share that are whole runs of consecutive pages: what the attention
         kernel fetches with one copy where it can (the allocator's own
-        counts, nothing read from the device).  0 where the kernel copies
-        page by page (``run_blocks`` 1: no tile is counted)."""
+        counts, nothing read from the device; ``paged_mla_attention`` and,
+        over a group without a window, ``paged_gqa_attention``).  0 where
+        the kernel copies page by page (``run_blocks`` 1: no tile is
+        counted)."""
         held = self.alloc.tiles_held
         return 100.0 * self.alloc.tiles_run / held if held else 0.0
 

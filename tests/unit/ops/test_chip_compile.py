@@ -326,6 +326,53 @@ def test_paged_gqa_kernel_compiles_at_zaya_heads(chip):
     assert da.paged_layer_tile_pages(1, H, Hkv, D128, BS, MB, BF16) == 4
 
 
+def _gqa_calls(text):
+    return [line for line in text.splitlines()
+            if re.search(r"%paged_gqa_attention[.\d]* = ", line) and "tpu_custom_call" in line]
+
+
+def _operands(call):
+    return call[call.index("operand_layout_constraints="):call.index("frontend_attributes=")]
+
+
+@pytest.mark.parametrize("H,Hkv,BS,slots,chunk,MB,arena,attend,copy", [
+    (8, 2, 64, 48, 208, 256, (20, 4000), 4, 8),          # 256 keys an update, 512 a copy
+    (16, 16, 16, 128, 64, 256, (16, 4097), 8, 8),        # 128 keys: 1 MiB already
+    (28, 4, 16, 32, 224, 1024, (2, 57344), 8, 32),       # 128 and 512
+], ids=["zaya", "olmoe", "smallthinker-full"])
+def test_paged_gqa_kernel_compiles_with_run_flags(chip, H, Hkv, BS, slots, chunk, MB,
+                                                  arena, attend, copy):
+    """The full group of each cell ``paged_gqa_attention`` serves, as its step
+    calls it since PR 44: beside its row's table and the next row's, their
+    flags (a word a tile of ``copy`` pages) as SMEM blocks, and the arenas
+    viewed ``[layers, pages * BS, lanes]`` (a bitcast: no copy of them is made
+    for the kernel), so that a tile of pages that lie together is one DMA an
+    operand; the attend keeps the tile of a call without flags."""
+    D128, rows, (L, NB) = 128, slots + chunk, arena
+    shape = (H, Hkv, D128, BS, MB, BF16)
+    assert da.paged_layer_tile_pages(1, *shape) == attend
+    assert da.paged_layer_run_pages(*shape) == copy
+    tiles = MB // copy
+    fn = lambda q, k, v, layer, tables, lengths: da.paged_layer_attention(
+        q, k, v, layer, tables, lengths, chunk=chunk,
+        tile_runs=da.paged_tile_runs(tables, NB, copy))
+    pages = ((L, NB, BS, Hkv * D128), BF16)
+    text = _compiled_text(chip, fn, ((rows, 1, H, D128), BF16), pages, pages,
+                          ((), jnp.int32), ((rows, MB), jnp.int32), ((rows,), jnp.int32))
+    Sq = da.paged_layer_chunk_queries(chunk, *shape)
+    calls = _gqa_calls(text)
+    assert _kernel_rows(text, "paged_gqa_attention") == sorted([chunk // Sq, slots])
+    view = f"bf16[{L},{NB * BS},{Hkv * D128}]"
+    for call in calls:
+        operands = _operands(call)
+        n = slots if f"s32[{slots},1,{MB}]" in operands else chunk // Sq
+        assert operands.count(f"s32[{n},1,{MB}]") == 2
+        assert operands.count(f"s32[{n},1,{tiles}]") == 2
+        assert operands.count(view) == 2
+    assert not re.search(rf"= {re.escape(view)}\S* copy\(", text)
+    assert "dynamic-slice" not in text.replace("dynamic-slice(s32", "")
+
+
 def test_the_zaya_step_reads_its_bank_and_its_pages_where_they_lie(chip):
     """The whole step of two ZAYA1-8B layers at the published widths, 48
     slots and a chunk of 208: the cca mixer's attention is the paged GQA
@@ -358,6 +405,11 @@ def test_the_zaya_step_reads_its_bank_and_its_pages_where_they_lie(chip):
         shape((rows, 1), jnp.int32), aux, shape((rows,), jnp.int32),
         shape((rows,), jnp.bool_)).compile().as_text()
     assert _kernel_rows(text, "paged_gqa_attention") == [chunk // 104, slots]
+    # each takes its rows' run flags (32 tiles of 8 pages) and the arena viewed
+    # ``[layers, pages * 64, 256]``
+    for call in _gqa_calls(text):
+        assert _operands(call).count(",1,32]") == 2
+        assert _operands(call).count(f"bf16[2,{NB * BS},256]") == 2
     calls = _bank_calls(text)
     assert len(calls) == 4 and all("bf16[2,16,2048," in call for call in calls)
     assert sorted(int(r) for r in re.findall(
@@ -566,6 +618,38 @@ def test_the_mistral_step_hands_the_latent_kernel_its_run_flags(chip, periods):
     rows = slots + chunk
     made = [line for line in text.splitlines() if f"s32[{rows},32,32]" in line
             and " = " in line and "parameter(" not in line]
+    assert made
+    body = text[:text.index("ENTRY ")]
+    assert not [line for line in made if line in body and " fusion(" in line]
+
+
+@pytest.mark.parametrize("cell,tiles,windows", [
+    ("olmoe-1b-7b", 32, 0), ("smallthinker-21b-a3b", 32, 3)])
+def test_the_step_hands_a_full_group_its_run_flags(chip, cell, tiles, windows):
+    """The whole step of the two ``gpt_paged_step`` models whose full group
+    ``paged_gqa_attention`` serves: its two calls (decode rows, packed chunk)
+    take the flags of their rows' tables beside them and the arena viewed
+    ``[layers, pages * 16, lanes]``; a window group's calls take the tables
+    alone and the arena with its pages a dimension, as they always did.  The
+    flags are made once, outside the loop over layers."""
+    _, slots, chunk, positions, kernel, Sq = SERVE_CELLS[cell]
+    cfg, text = _step_text(chip, cell, periods=2)
+    MB, lanes = positions // 16, cfg.kv_heads * cfg.head_dim
+    groups, pages = len(cfg.pattern), 1025 * len(cfg.pattern)
+    flagged = [c for c in _gqa_calls(text) if f"bf16[2,{pages * 16},{lanes}]" in _operands(c)]
+    plain = [c for c in _gqa_calls(text) if f"bf16[2,{pages},16,{lanes}]" in _operands(c)]
+    assert len(flagged) == 2 and len(plain) == 2 * windows == 2 * (groups - 1)
+    for call in flagged:
+        operands = _operands(call)
+        n = slots if f"s32[{slots},1,{MB}]" in operands else chunk // Sq
+        assert operands.count(f"s32[{n},1,{MB}]") == 2
+        assert operands.count(f"s32[{n},1,{tiles}]") == 2
+    for call in plain:
+        assert f",1,{tiles}]" not in _operands(call)
+    rows = slots + chunk
+    made = [line for line in text.splitlines()
+            if f"s32[{rows},{tiles},{MB // tiles}]" in line and " = " in line
+            and "parameter(" not in line]
     assert made
     body = text[:text.index("ENTRY ")]
     assert not [line for line in made if line in body and " fusion(" in line]

@@ -15,6 +15,13 @@ NB = 8 * G + 5
 SCALE = 0.17
 
 
+def mla_tile_runs(tables, arena):
+    """The flags of ``tables`` at the pages a tile of the latent kernel holds
+    for them (None where it takes the reference), as the step works them out."""
+    return da.paged_tile_runs(tables, arena.shape[1], da.paged_mla_tile_pages(
+        W, R, BS, tables.shape[1], arena.dtype))
+
+
 def runs_of(*firsts):
     """A table of whole runs starting at these pages."""
     return np.concatenate([np.arange(f, f + G) for f in firsts])
@@ -74,8 +81,7 @@ def test_the_kernel_on_runs_equals_the_gather_reference(kernels, case, Sq):
     clean[:, untouched] = 0.0
     q = jnp.asarray(rng.standard_normal((B, Sq, H, W)), jnp.float32)
     assert da.paged_mla_tile_pages(W, R, BS, MB, jnp.float32) == G
-    flags = np.asarray(da.paged_mla_tile_runs(jnp.asarray(tables),
-                                              jnp.asarray(arena), R))
+    flags = np.asarray(mla_tile_runs(jnp.asarray(tables), jnp.asarray(arena)))
     want_flags = {"runs": [[1, 1, 1]] * 3,
                   "mixed": [[1, 0, 1], [0, 1, 1], [0, 0, 0], [0, 0, 0]],
                   "broken": [[0, 1, 1], [1, 0, 1], [0, 1, 1]],
@@ -115,7 +121,7 @@ def test_a_packed_chunk_beside_decode_rows_takes_the_rows_flags(kernels, runs):
     call = lambda **kw: jax.jit(lambda *a: da.paged_mla_attention(
         *a, scale=SCALE, value_lanes=R, chunk=chunk, **kw))(
             q, arena, jnp.int32(0), tables, lengths)
-    flags = da.paged_mla_tile_runs(tables, arena, R)
+    flags = mla_tile_runs(tables, arena)
     assert flags.shape == (slots + chunk, MB // G)
     assert flags[slots:].tolist() == [[int(runs)] * 3] * chunk
     got, handed = call(), call(tile_runs=flags)
@@ -129,12 +135,12 @@ def test_a_table_that_is_no_whole_number_of_tiles_has_no_run_in_its_last(kernels
     kernels("paged_mla_attention")
     arena = jnp.zeros((1, NB, BS, W), jnp.float32)
     tables = jnp.asarray([np.arange(G, G + 40)], jnp.int32)        # 32 + 8
-    assert da.paged_mla_tile_runs(tables, arena, R).tolist() == [[1, 0]]
+    assert mla_tile_runs(tables, arena).tolist() == [[1, 0]]
     # consecutive pages of which the last would lie past the arena are none
     ends = jnp.asarray([np.arange(NB - G, NB), np.arange(NB - G + 1, NB + 1)])
-    assert da.paged_mla_tile_runs(ends, arena, R).tolist() == [[1], [0]]
+    assert mla_tile_runs(ends, arena).tolist() == [[1], [0]]
     kernels()
-    assert da.paged_mla_tile_runs(tables, arena, R) is None         # the reference
+    assert mla_tile_runs(tables, arena) is None         # the reference
 
 
 @pytest.mark.parametrize("Sq", [1, 16])
