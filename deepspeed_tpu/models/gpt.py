@@ -347,6 +347,33 @@ class GPTConfig:
             return (-(-(self.kv_lora_rank + self.qk_rope_dim) // 128) * 128,)
         return (self.kv_heads * self.head_dim,) * 2
 
+    def paged_plans(self, block_size: int, widths, chunk: int, dtype):
+        """The paged attention of each page group (:attr:`page_groups`; its
+        table ``widths[group]`` columns wide), a ``ops/pallas/
+        decode_attention.py:PagedAttention`` each: THE one place a model's
+        fields name a family of kernels.  ``init_serving`` reads its stats
+        and the allocator's ``run_blocks`` from the plans, and the step
+        functions, which are also called without an engine, build them again
+        from the shapes of the arena they are handed, the same arguments: a
+        plan handed down beside them would be a second way in."""
+        from deepspeed_tpu.ops.pallas import decode_attention as da
+        H, Hkv, D = self.n_head, self.kv_heads, self.head_dim
+        if "sparse" in self.mixers:
+            # the sparse layers own the pages (``hybrid.arena_layout``); cca
+            # layers' are K and V like any grouped-query model's
+            from deepspeed_tpu.models import hybrid
+            return (da.chosen_plan(
+                Hkv, H // Hkv, D, block_size,
+                hybrid.table_columns(self, block_size), dtype),)
+        if self.kv_lora_rank:
+            return tuple(da.latent_plan(
+                self.cache_lanes[0], self.kv_lora_rank, H, block_size, MB,
+                chunk, dtype, 1.0 / math.sqrt(D)) for MB in widths)
+        return tuple(da.softmax_plan(
+            H, Hkv, D, block_size, MB, chunk, dtype,
+            bias=self.position_encoding == "alibi", window=window)
+            for window, MB in zip(self.page_groups, widths))
+
 
 # Model zoo (GPT-2 sizes; the 1.5B "xl" is the north-star model).
 GPT_PRESETS: Dict[str, Dict] = {
@@ -1663,9 +1690,6 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
     refuses it.
     """
     assert cfg.scan_layers, "paged serving path requires scan_layers"
-    from deepspeed_tpu.ops.pallas.decode_attention import (
-        paged_layer_attention, paged_layer_run_pages, paged_mla_attention,
-        paged_mla_tile_pages, paged_tile_runs)
     B, S = input_ids.shape
     assert not chunk or S == 1, "a prompt chunk is named among one-token rows"
     H, E = cfg.n_head, cfg.n_embd
@@ -1704,16 +1728,10 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
     # which tiles of each group's tables its kernel fetches with one copy
     # (None: it copies page by page): the same for every layer, so worked
     # out here and not in the scan
-    def run_pages(kind, tables):
-        if cfg.kv_lora_rank:
-            return paged_mla_tile_pages(k_pages.shape[-1], cfg.kv_lora_rank, BS,
-                                        tables.shape[1], k_pages.dtype)
-        return paged_layer_run_pages(
-            H, cfg.kv_heads, cfg.head_dim, BS, tables.shape[1], k_pages.dtype,
-            attn_bias is not None, kind.window)
-
-    tile_runs = [paged_tile_runs(tables, k_pages.shape[1], run_pages(kind, tables))
-                 for kind, tables in zip(cfg.pattern, block_tables)]
+    plans = cfg.paged_plans(BS, [t.shape[1] for t in block_tables], chunk,
+                            k_pages.dtype)
+    tile_runs = [plan.tile_runs(tables, k_pages.shape[1])
+                 for plan, tables in zip(plans, block_tables)]
 
     def layer(j, carry, p):
         # ``li``: the layer's index inside its group ``j`` (the period)
@@ -1722,7 +1740,7 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
         with jax.named_scope("attn"):
             h = _norm(cfg, x, p["ln1_g"], p["ln1_b"])
             if cfg.kv_lora_rank:
-                R, W = cfg.kv_lora_rank, kp.shape[-1]
+                W = kp.shape[-1]
                 q, cache = _latent_project(cfg, p, h, dt, pos2d)
                 w_uk, w_uv = _latent_up(cfg, p, dt)
                 kp = kp.at[li, wblocks, write_offsets].set(jnp.pad(
@@ -1736,10 +1754,9 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
                      q[..., -dr:]], axis=-1)
                 q = jnp.pad(q, ((0, 0),) * 3 + ((0, W - q.shape[-1]),))
                 with jax.named_scope("attn_latent"):
-                    o = paged_mla_attention(
-                        q, kp, li, block_tables[j], positions,
-                        scale=1.0 / math.sqrt(cfg.head_dim), value_lanes=R,
-                        chunk=chunk, tile_runs=tile_runs[j])
+                    o = plans[j].attend(
+                        q, (kp, None), li, block_tables[j], positions,
+                        tile_runs=tile_runs[j], chunk=chunk)
                 o = jnp.einsum("bshr,rhd->bshd", o, w_uv).reshape(B, S, -1)
             else:
                 q, k, v = _project_qkv(cfg, p, h, dt, pos2d, kind)
@@ -1753,10 +1770,10 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
                     v.astype(vp.dtype).reshape(B, S, -1))
                 with jax.named_scope(
                         "attn_full" if kind.window is None else "attn_window"):
-                    o = paged_layer_attention(
-                        q, kp, vp, li, block_tables[j], positions,
-                        bias=attn_bias, window=kind.window, chunk=chunk,
-                        tile_runs=tile_runs[j]).reshape(B, S, cfg.attn_dim)
+                    o = plans[j].attend(
+                        q, (kp, vp), li, block_tables[j], positions,
+                        tile_runs=tile_runs[j], chunk=chunk,
+                        bias=attn_bias).reshape(B, S, cfg.attn_dim)
             o = o @ _wget(p, "out_w", dt)
             if cfg.use_bias:
                 o = o + p["out_b"].astype(dt)

@@ -303,9 +303,9 @@ class _Step(NamedTuple):
     and ``slots`` ``[B]``, the one page group's ``tables [B, MB]`` and where
     each row's token is written (``write_blocks``, ``write_offsets`` ``[B,
     1]``), the rows of the prompt chunk (static) and the activations' type;
-    ``tile_runs``: which tiles of ``tables`` the kernel that attends over
-    every key fetches with one copy (``ops/pallas/decode_attention.py:
-    paged_tile_runs``; None: none)."""
+    ``plan``: the paged attention of the page group (``GPTConfig.
+    paged_plans``), and ``tile_runs`` which tiles of ``tables`` its kernel
+    fetches with one copy (``plan.tile_runs``; None: none)."""
     positions: Array
     live: Array
     slots: Array
@@ -314,6 +314,7 @@ class _Step(NamedTuple):
     write_offsets: Array
     chunk: int
     dt: Any
+    plan: Any
     tile_runs: Any = None
 
 
@@ -446,8 +447,7 @@ def sparse_mixer(cfg, p, h, kp, vp, held, li, step: _Step):
     row; the last ``chunk`` consecutive tokens of one sequence): -> (the
     mixer's output ``[B, E]`` before the residual, kp, vp, held with its
     compressed keys ``kc`` written)."""
-    from deepspeed_tpu.ops.pallas.decode_attention import paged_sparse_attention
-    positions, live, _, tables, write_blocks, write_offsets, chunk, dt, _ = step
+    positions, live, _, tables, write_blocks, write_offsets, chunk, dt, plan, _ = step
     kc = held["kc"]
     B = h.shape[0]
     H, Hkv, D = cfg.n_head, cfg.kv_heads, cfg.head_dim
@@ -477,8 +477,8 @@ def sparse_mixer(cfg, p, h, kp, vp, held, li, step: _Step):
             chosen = jnp.take_along_axis(tb[:, None], blocks, axis=2)
             chosen = (chosen * Hkv + jnp.arange(Hkv)[None, :, None]).reshape(n * Hkv, -1)
         with jax.named_scope("sparse_attend"):
-            return paged_sparse_attention(
-                q[rows].reshape(n * Hkv, 1, g, D), kp, vp, li, chosen,
+            return plan.attend(
+                q[rows].reshape(n * Hkv, 1, g, D), (kp, vp), li, chosen,
                 jnp.repeat(at, Hkv)).reshape(n, H * D)
 
     o = attend(slice(0, n_dec), False)
@@ -523,7 +523,7 @@ def linear_mixer(cfg, p, h, kp, vp, held, li, step: _Step):
     chunk runs the chunked form from the state of ITS slot, from zero where
     it starts at position 0.  -> (output ``[B, E]``, the pages as they came,
     held with its ``state`` moved on)."""
-    positions, live, slots, _, _, _, chunk, dt, _ = step
+    positions, live, slots, _, _, _, chunk, dt, _, _ = step
     state, decay = held["state"], jnp.asarray(linear_decay(cfg))[li]
     B = h.shape[0]
     H, D = cfg.n_head, cfg.head_dim
@@ -601,9 +601,8 @@ def cca_mixer(cfg, p, h, kp, vp, held, li, step: _Step):
     ``cca_state`` moved on).  The arithmetic between the projections and the
     cached K and V (the convolutions, the q-k mean, the norms) is float32;
     the pages and the state keep ``dt``."""
-    from deepspeed_tpu.ops.pallas.decode_attention import paged_layer_attention
     (positions, live, slots, tables, write_blocks, write_offsets, chunk, dt,
-     tile_runs) = step
+     plan, tile_runs) = step
     state = held["cca_state"]
     B = h.shape[0]
     H, Hkv, D = cfg.n_head, cfg.kv_heads, cfg.head_dim
@@ -643,8 +642,8 @@ def cca_mixer(cfg, p, h, kp, vp, held, li, step: _Step):
         vp = vp.at[li, write_blocks, write_offsets].set(
             v.astype(vp.dtype).reshape(B, 1, Hkv * D))
     with jax.named_scope("cca_attend"):
-        o = paged_layer_attention(q, kp, vp, li, tables, positions, chunk=chunk,
-                                  tile_runs=tile_runs).reshape(B, H * D)
+        o = plan.attend(q, (kp, vp), li, tables, positions, chunk=chunk,
+                        tile_runs=tile_runs).reshape(B, H * D)
     o = _rows_that_carry(lambda r: r @ gpt._wget(p, "out_w", dt), (o,), chunk, live)
     return o, kp, vp, dict(held, cca_state=state)
 
@@ -719,8 +718,6 @@ def hybrid_paged_step(cfg, params: Dict, input_ids: Array, positions: Array,
     without expert layers) and the caches.  -> (logits ``[B, 1, V]`` float32,
     k_pages, v_pages, aux) and with ``with_expert_counts`` the live rows'
     assignments ``[layers, experts]`` int32."""
-    from deepspeed_tpu.ops.pallas.decode_attention import (
-        paged_layer_run_pages, paged_tile_runs)
     B, S = input_ids.shape
     assert S == 1 and aux is not None, "a token a row, with the stack's state"
     assert not chunk or "sparse" not in cfg.mixers or chunk % cfg.sparse.stride == 0, (
@@ -729,16 +726,13 @@ def hybrid_paged_step(cfg, params: Dict, input_ids: Array, positions: Array,
         (block_tables,), (write_blocks,) = block_tables, write_blocks
     dt, rs = cfg.dtype, cfg.residual_scale
     blocks = params["blocks"]
-    # which tiles of the tables the cca layers' kernel fetches with one copy:
+    # which tiles of the tables the page group's kernel fetches with one copy:
     # the same for every layer, so worked out here and not in the walk
-    tile_runs = None
-    if "cca" in cfg.mixers:
-        tile_runs = paged_tile_runs(
-            block_tables, k_pages.shape[1], paged_layer_run_pages(
-                cfg.n_head, cfg.kv_heads, cfg.head_dim, k_pages.shape[2],
-                block_tables.shape[1], k_pages.dtype))
+    (plan,) = cfg.paged_plans(k_pages.shape[2], (block_tables.shape[1],), chunk,
+                              k_pages.dtype)
     step = _Step(positions, live, slots, block_tables, write_blocks.reshape(B, 1),
-                 write_offsets.reshape(B, 1), chunk, dt, tile_runs)
+                 write_offsets.reshape(B, 1), chunk, dt, plan,
+                 plan.tile_runs(block_tables, k_pages.shape[1]))
     x = params["wte"].astype(dt)[input_ids[:, 0]] * jnp.asarray(cfg.scale_emb, dt)
 
     def layer(mixer, carry, i):

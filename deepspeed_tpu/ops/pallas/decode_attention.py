@@ -40,7 +40,7 @@ and a run on the chip through ``chip_smoke.py`` (CHANGES.md PR 21).
 """
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -596,11 +596,10 @@ def _paged_kernel(len_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf,
     _write_out(o_ref, carry, H, D)
 
 
-def _paged_call(q, k_pages, v_pages, block_tables, lengths):
+def _paged_call(q, k_pages, v_pages, block_tables, lengths, G):
     B, Sq, H, D = q.shape
     NB, BS, HD = k_pages.shape
     MB = block_tables.shape[1]
-    G = paged_tile_pages(BS, MB, Sq, HD, k_pages.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                    # lengths, flat block tables
         grid=(B,),
@@ -781,14 +780,14 @@ def _paged_gqa_kernel(lay_ref, len_ref, tbl_ref, nxt_ref, *refs, scale, bs, Sq,
         o_ref[0, h] = (acc[h] / jnp.maximum(l[h], 1e-30)).astype(o_ref.dtype)
 
 
-def _paged_gqa_call(q, k_arena, v_arena, layer, block_tables, lengths, window,
-                    tile_runs=None, name="paged_gqa_attention"):
+def _paged_gqa_call(q, k_arena, v_arena, layer, block_tables, lengths, plan,
+                    tile_runs=None):
     B, Sq, H, D = q.shape
     L, NB, BS, lanes = k_arena.shape
     Hkv = lanes // D
     g = H // Hkv
     MB = block_tables.shape[1]
-    G = paged_tile_pages(BS, MB, Sq, lanes, k_arena.dtype)
+    G, window = plan.tile_pages, plan.window
     A = G * BS      # keys an attend step: the tile of a call without flags
     block_tables = jnp.asarray(block_tables, jnp.int32)[:, None, :]
     sublane = 8 * 4 // np.dtype(q.dtype).itemsize
@@ -806,8 +805,8 @@ def _paged_gqa_call(q, k_arena, v_arena, layer, block_tables, lengths, window,
                    lambda b, *_: (jnp.minimum(b + 1, B - 1), 0, 0))]
     tables, specs = [block_tables, block_tables], smem(MB)
     if tile_runs is not None:
-        assert window is None, "a ring holds no runs"
-        G = paged_run_tile_pages(BS, MB, lanes, k_arena.dtype)
+        assert plan.run_pages, "a ring holds no runs, nor a tile of chosen pages"
+        G = plan.run_pages
         tile_runs = jnp.asarray(tile_runs, jnp.int32)[:, None, :]
         tiles = tile_runs.shape[2]
         assert tiles == -(-MB // G), (tile_runs.shape, MB, G)
@@ -841,83 +840,11 @@ def _paged_gqa_call(q, k_arena, v_arena, layer, block_tables, lengths, window,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=_pallas.interpret(),
-        name=name,
+        name=plan.kernel,
     )(jnp.asarray(layer, jnp.int32).reshape(1), jnp.asarray(lengths, jnp.int32),
       *tables, qg, k_arena, v_arena)
     out = out[:, :, :g * Sq].reshape(B, Hkv, g, Sq, D)
     return out.transpose(0, 3, 1, 2, 4).reshape(B, Sq, H, D)
-
-
-def paged_gqa_tile_pages(Sq, H, Hkv, D, BS, MB, dtype) -> int:
-    """As :func:`paged_kernel_tile_pages`, of the kernel
-    ``paged_gqa_attention`` (which takes no bias)."""
-    if (not _pallas.use_kernel("paged_gqa_attention")
-            or not gqa_kernel_shape_ok(H, Hkv, D, BS, dtype)
-            or not _pallas.single_device()):
-        return 0
-    return paged_tile_pages(BS, MB, Sq, Hkv * D, dtype)
-
-
-def paged_gqa_attention(q, k_arena, v_arena, layer, block_tables, lengths,
-                        window=None, tile_runs=None):
-    """Block-table attention of layer ``layer`` of the arena
-    ``[layers, pages, BS, Hkv*D]``: q ``[B, Sq, H, D]`` with ``H = g * Hkv``,
-    ``block_tables [B, MB]`` (a ring under a ``window``, see
-    :func:`paged_attention_reference`), ``lengths [B]``.  ``tile_runs``:
-    :func:`paged_tile_runs` of these tables at :func:`paged_layer_run_pages`
-    pages a tile, from a caller whose tables grow in runs (no window); None
-    is the kernel that copies page by page, and the same numbers to the bit.
-    The kernel where :func:`paged_gqa_tile_pages` says so, else the layer
-    sliced out and the gather reference."""
-    B, Sq, H, D = q.shape
-    _, _, BS, lanes = k_arena.shape
-    if paged_gqa_tile_pages(Sq, H, lanes // D, D, BS, block_tables.shape[1],
-                            k_arena.dtype):
-        return _paged_gqa_call(q, k_arena, v_arena, layer, block_tables,
-                               lengths, window, tile_runs)
-    kl = jax.lax.dynamic_index_in_dim(k_arena, layer, 0, keepdims=False)
-    vl = jax.lax.dynamic_index_in_dim(v_arena, layer, 0, keepdims=False)
-    return paged_attention_reference(q, kl, vl, block_tables, lengths,
-                                     window=window)
-
-
-# --------------------------------------------------------------------------- #
-# Attention over the pages a query CHOSE (block-sparse attention whose block
-# is a page; ``models/hybrid.py``).  The selection differs by token and by K/V
-# head, so a row is one (token, K/V head): its table lists the chosen pages in
-# logical order, its length counts the keys in them before the query (the
-# query's own block ends the list), and its ``g`` query heads are the rows of
-# one product.  That is ``_paged_gqa_kernel`` over ONE K/V head, on an arena
-# that gives a K/V head a page of its own (``[layers, blocks * Hkv, BS, D]``):
-# the same body under a name of its own, so a trace tells the two apart.
-# --------------------------------------------------------------------------- #
-def paged_sparse_tile_pages(g, D, BS, columns, dtype) -> int:
-    """Pages a tile of the kernel ``paged_sparse_attention`` holds for rows
-    of ``g`` query heads on one K/V head of ``D`` lanes under tables of
-    ``columns`` chosen pages (0: the gather reference)."""
-    if (not _pallas.use_kernel("paged_sparse_attention")
-            or not gqa_kernel_shape_ok(g, 1, D, BS, dtype)
-            or not _pallas.single_device()):
-        return 0
-    return paged_tile_pages(BS, columns, 1, D, dtype)
-
-
-def paged_sparse_attention(q, k_arena, v_arena, layer, chosen, lengths):
-    """q ``[rows, 1, g, D]``, a row a (token, K/V head); the arena ``[layers,
-    pages, BS, D]``, a page one K/V head's block; ``chosen [rows, columns]``
-    the physical pages the row attends, in logical order; ``lengths [rows]``
-    the keys of those pages that lie before the query.  The kernel where
-    :func:`paged_sparse_tile_pages` says so, else the layer sliced out and
-    the gather reference."""
-    _, _, g, D = q.shape
-    BS = k_arena.shape[2]
-    assert k_arena.shape[3] == D, "a K/V head a page"
-    if paged_sparse_tile_pages(g, D, BS, chosen.shape[1], k_arena.dtype):
-        return _paged_gqa_call(q, k_arena, v_arena, layer, chosen, lengths, None,
-                               name="paged_sparse_attention")
-    kl = jax.lax.dynamic_index_in_dim(k_arena, layer, 0, keepdims=False)
-    vl = jax.lax.dynamic_index_in_dim(v_arena, layer, 0, keepdims=False)
-    return paged_attention_reference(q, kl, vl, chosen, lengths)
 
 
 # --------------------------------------------------------------------------- #
@@ -956,17 +883,6 @@ def _mla_attend_rows(tile_rows: int) -> int:
     """Keys an attend step of a tile of ``tile_rows`` takes: :data:`
     _MLA_ATTEND_ROWS` where they divide the tile, else the whole tile."""
     return tile_rows if tile_rows % _MLA_ATTEND_ROWS else _MLA_ATTEND_ROWS
-
-
-def paged_mla_tile_pages(lanes, value_lanes, BS, MB, dtype) -> int:
-    """Pages a tile of the kernel ``paged_mla_attention`` holds for these
-    shapes; 0 where :func:`paged_mla_attention` takes the gather reference
-    (not a TPU, a mesh of several devices, lanes the gate refuses)."""
-    if (not _pallas.use_kernel("paged_mla_attention")
-            or not mla_kernel_shape_ok(lanes, value_lanes, BS, dtype)
-            or not _pallas.single_device()):
-        return 0
-    return _mla_tile_pages(BS, MB)
 
 
 def paged_mla_attention_reference(q, pages, block_tables, lengths, *, scale,
@@ -1132,12 +1048,200 @@ def _paged_mla_call(q, arena, layer, block_tables, tile_runs, lengths, scale,
     return out[:, :H * Sq].reshape(B, H, Sq, R).transpose(0, 2, 1, 3)
 
 
-def paged_mla_chunk_queries(chunk, H, lanes, value_lanes, BS, MB, dtype) -> int:
-    """:func:`paged_chunk_queries` of the call :func:`paged_mla_attention`
-    makes for a prompt chunk: all ``H`` heads the rows of one product."""
-    return paged_chunk_queries(chunk, H, 1, lanes, value_lanes,
-                               _mla_attend_rows(_mla_tile_pages(BS, MB) * BS),
-                               dtype)
+# --------------------------------------------------------------------------- #
+# The plan of a page group: which kernel its layers get and at which sizes.
+# Decided HERE, once, from static shapes and the platform, by a constructor a
+# family; ``models/gpt.py:GPTConfig.paged_plans`` says which family each of a
+# model's page groups is, and ``init_serving``, both step functions and the
+# kernels' calls read the answer.
+# --------------------------------------------------------------------------- #
+class PagedAttention(NamedTuple):
+    """The paged attention of ONE page group's layers, static Python.  The
+    allocator's ``run_blocks``, the flags of :meth:`tile_runs` and the tile a
+    kernel copies are all ``run_pages`` of the one plan, so they cannot
+    differ (if they did the tokens would stay right and every tile fall back
+    to a copy a page: 11-28% of a step, PERF.md section 6, PRs 40 and 44)."""
+    kernel: Optional[str]       # the ``pallas_call``'s name; None: the gather reference
+    tile_pages: int             # pages a tile of the ATTEND holds (0 on a reference)
+    run_pages: int              # pages ONE copy brings where they lie together
+                                # (what the allocator lays in runs); 0: a copy a page
+    chunk_queries: int          # ``Sq`` of a prompt chunk's packed row
+    rows_a_token: int = 1       # rows of the calls a token (chosen pages: K/V heads)
+    window: Optional[int] = None    # K and V: the keys a query sees (the table a ring)
+    value_lanes: int = 0        # a latent cache: its value's lanes (0: K and V arenas)
+    scale: float = 0.0          # a latent cache: what its logits are multiplied by
+
+    def tile_runs(self, block_tables, pages: int):
+        """:func:`paged_tile_runs` of a step's tables at ``run_pages``: the
+        same for every layer, so worked out once a group, outside the scan."""
+        return paged_tile_runs(block_tables, pages, self.run_pages)
+
+    def attend(self, q, arenas, layer, block_tables, lengths, *, tile_runs=None,
+               chunk: int = 0, bias=None):
+        """Attention of layer ``layer`` of the group's ``arenas`` (K and V
+        ``[layers, pages, BS, lanes]``, or a latent cache's one array and
+        None): q ``[B, Sq, H, D]``, row ``b``'s queries at ``lengths[b] +
+        arange(Sq)`` under ``block_tables [B, MB]``.  With ``chunk`` the rows
+        hold one query each and the last ``chunk`` are a prompt chunk,
+        attended packed, ``chunk_queries`` a row (:func:`_rows_and_chunk`).
+        ``tile_runs``: :meth:`tile_runs` of these tables, from a caller whose
+        tables grow in runs; None copies page by page (a latent cache's kernel
+        works the flags out itself), the same numbers to the bit.  The kernel, or
+        where ``kernel`` is None the layer sliced out and the reference."""
+        k_arena, v_arena = arenas
+        if self.value_lanes and self.kernel and tile_runs is None:
+            tile_runs = self.tile_runs(block_tables, k_arena.shape[1])
+        if chunk:
+            attend = lambda q, tables, lens, bias: self.attend(
+                q, arenas, layer, tables[0], lens, tile_runs=tables[1], bias=bias)
+            return _rows_and_chunk(attend, chunk, self.chunk_queries, q,
+                                   (block_tables, tile_runs), lengths, bias)
+        if self.value_lanes:
+            if not self.kernel:
+                pages = jax.lax.dynamic_index_in_dim(k_arena, layer, 0, keepdims=False)
+                return paged_mla_attention_reference(
+                    q, pages, block_tables, lengths, scale=self.scale,
+                    value_lanes=self.value_lanes)
+            return _paged_mla_call(q, k_arena, layer, block_tables, tile_runs,
+                                   lengths, self.scale, self.value_lanes,
+                                   self.run_pages)
+        if self.kernel and self.kernel != "paged_attention":
+            return _paged_gqa_call(q, k_arena, v_arena, layer, block_tables,
+                                   lengths, self, tile_runs)
+        kl = jax.lax.dynamic_index_in_dim(k_arena, layer, 0, keepdims=False)
+        vl = jax.lax.dynamic_index_in_dim(v_arena, layer, 0, keepdims=False)
+        if self.kernel:
+            return _paged_call(q, kl, vl, block_tables, lengths, self.tile_pages)
+        return paged_attention_reference(q, kl, vl, block_tables, lengths,
+                                         bias=bias, window=self.window)
+
+
+def softmax_plan(H, Hkv, D, BS, MB, chunk, dtype, bias=False, window=None,
+                 name=None) -> PagedAttention:
+    """Softmax attention over cached K and V: ``H`` query heads on ``Hkv``
+    K/V heads of ``D`` lanes, pages of ``BS`` keys under tables of ``MB``
+    columns, a prompt chunk of ``chunk`` tokens, the cache's ``dtype``; under
+    an additive ``bias`` (ALiBi) or a ``window``.  THE rule: grouped K/V
+    heads, a window, or multi-head attention whose heads are whole 128-lane
+    tiles (OLMoE's 16 of 128: a group of one) go to ``paged_gqa_attention``,
+    which takes the arena whole and, over every key, a tile of pages that lie
+    together with one copy (:func:`paged_run_tile_pages`; not a window
+    group's ring, which gives pages back: runs in a ring are a design of
+    their own, ROADMAP S3 (b)).  Multi-head attention at ``D = 64`` over
+    every key keeps the layer sliced out of the arena and ``paged_attention``:
+    the successor has no two-heads-a-lane-slice case (``_attend_block``), and
+    ``decode-heavy``'s backlog cannot outlast a 124M step without the copy
+    (ROADMAP S1, S0 (l)).  So does a bias, which neither kernel takes: the
+    reference.  A packed row is a K/V head's ``H / Hkv`` query heads the rows
+    of one product, or a head a product of its own in its lane slice.
+    ``name`` is one of the family's kernels' whatever the rule says: an entry
+    point called with arrays alone is that kernel or the reference."""
+    assert not bias or window is None, (
+        "a window layer with an additive bias has no paged path")
+    if name is None:
+        whole = not bias and (window is not None or Hkv != H or D % _LANES == 0)
+        name = "paged_gqa_attention" if whole else "paged_attention"
+    whole = name != "paged_attention"       # the arena whole, not a layer's slice
+    gate = gqa_kernel_shape_ok if whole else kernel_shape_ok
+    runs = (not bias and _pallas.use_kernel(name)
+            and gate(H, Hkv, D, BS, dtype) and _pallas.single_device())
+    G = paged_tile_pages(BS, MB, 1, Hkv * D, dtype)
+    if whole:
+        queries = paged_chunk_queries(chunk, H // Hkv, Hkv, D, D, G * BS, dtype)
+    else:
+        W = _lane_slices(H, D)[0]
+        queries = paged_chunk_queries(chunk, 1, H, W, W, G * BS, dtype)
+    in_runs = runs and whole and window is None
+    return PagedAttention(
+        name if runs else None, G if runs else 0,
+        paged_run_tile_pages(BS, MB, Hkv * D, dtype) if in_runs else 0,
+        queries, window=window)
+
+
+def chosen_plan(Hkv, g, D, BS, columns, dtype) -> PagedAttention:
+    """Attention over the pages a query CHOSE (block-sparse attention whose
+    block is a page; ``models/hybrid.py``).  The selection differs by token
+    and by K/V head, so a row is one (token, K/V head), ``Hkv`` rows a token:
+    its table lists the ``columns`` chosen pages in logical order, its length
+    counts the keys in them before the query (the query's own block ends the
+    list), and its ``g`` query heads are the rows of one product.  That is
+    ``_paged_gqa_kernel`` over ONE K/V head, on an arena that gives a K/V
+    head a page of its own (``[layers, blocks * Hkv, BS, D]``): the same body
+    under a name of its own, so a trace tells the two apart.  Pages that
+    were chosen lie in no runs: a copy a page."""
+    return softmax_plan(g, 1, D, BS, columns, 0, dtype,
+                        name="paged_sparse_attention")._replace(
+                            run_pages=0, rows_a_token=Hkv)
+
+
+def latent_plan(lanes, value_lanes, H, BS, MB, chunk, dtype, scale) -> PagedAttention:
+    """Latent attention (MLA in its absorbed form): ``H`` heads read ONE
+    cached vector of ``lanes`` a token, all of it the key and its first
+    ``value_lanes`` the value, the logits times ``scale``.  A tile of
+    :func:`_mla_tile_pages` is the unit of the copy and of the runs, attended
+    in steps of :func:`_mla_attend_rows` keys; all heads are the rows of a
+    packed row's one product."""
+    runs = (_pallas.use_kernel("paged_mla_attention")
+            and mla_kernel_shape_ok(lanes, value_lanes, BS, dtype)
+            and _pallas.single_device())
+    G = _mla_tile_pages(BS, MB)
+    queries = paged_chunk_queries(chunk, H, 1, lanes, value_lanes,
+                                  _mla_attend_rows(G * BS), dtype)
+    tile = G if runs else 0
+    return PagedAttention("paged_mla_attention" if runs else None, tile, tile,
+                          queries, value_lanes=value_lanes, scale=scale)
+
+
+# The entry points with arrays alone (the kernels' tests, tools): each builds
+# its family's plan from the arrays' shapes, the one derivation.
+def paged_attention(q, k_pages, v_pages, block_tables, lengths, bias=None):
+    """Block-table attention over ONE layer's pages ``[NB, BS, Hkv*D]``: the
+    kernel ``paged_attention`` where :func:`kernel_shape_ok` admits the shape
+    (no bias, one device), else the jnp gather reference.  Sharded meshes
+    take the reference (the gather partitions cleanly under SPMD; the kernel
+    does not shard the global block arena)."""
+    H, D = q.shape[2:]
+    plan = softmax_plan(H, k_pages.shape[2] // D, D, k_pages.shape[1],
+                        block_tables.shape[1], 0, k_pages.dtype,
+                        bias is not None, name="paged_attention")
+    if plan.kernel:
+        return _paged_call(q, k_pages, v_pages, block_tables, lengths,
+                           plan.tile_pages)
+    return paged_attention_reference(q, k_pages, v_pages, block_tables,
+                                     lengths, bias=bias)
+
+
+def paged_layer_attention(q, k_arena, v_arena, layer, block_tables, lengths,
+                          bias=None, window=None, chunk: int = 0,
+                          tile_runs=None, name=None):
+    """:meth:`PagedAttention.attend` of :func:`softmax_plan` for the arrays'
+    shapes: what a step's layer of K and V runs."""
+    H, D = q.shape[2:]
+    _, _, BS, lanes = k_arena.shape
+    plan = softmax_plan(H, lanes // D, D, BS, block_tables.shape[1], chunk,
+                        k_arena.dtype, bias is not None, window, name)
+    return plan.attend(q, (k_arena, v_arena), layer, block_tables, lengths,
+                       tile_runs=tile_runs, chunk=chunk, bias=bias)
+
+
+def paged_gqa_attention(q, k_arena, v_arena, layer, block_tables, lengths,
+                        window=None, tile_runs=None):
+    """The same by the kernel ``paged_gqa_attention`` (or the reference)
+    whatever the family's rule says of the shapes."""
+    return paged_layer_attention(q, k_arena, v_arena, layer, block_tables,
+                                 lengths, window=window, tile_runs=tile_runs,
+                                 name="paged_gqa_attention")
+
+
+def paged_sparse_attention(q, k_arena, v_arena, layer, chosen, lengths):
+    """q ``[rows, 1, g, D]``, a row a (token, K/V head); the arena ``[layers,
+    pages, BS, D]``, a page one K/V head's block; ``chosen [rows, columns]``
+    the physical pages the row attends, in logical order; ``lengths [rows]``
+    the keys of those pages that lie before the query: :func:`chosen_plan`."""
+    _, _, g, D = q.shape
+    assert k_arena.shape[3] == D, "a K/V head a page"
+    plan = chosen_plan(1, g, D, k_arena.shape[2], chosen.shape[1], k_arena.dtype)
+    return plan.attend(q, (k_arena, v_arena), layer, chosen, lengths)
 
 
 def paged_mla_attention(q, arena, layer, block_tables, lengths, *, scale,
@@ -1145,116 +1249,13 @@ def paged_mla_attention(q, arena, layer, block_tables, lengths, *, scale,
     """Block-table latent attention of layer ``layer`` of the ONE-array arena
     ``[layers, pages, BS, W]``: q ``[B, Sq, H, W]`` (a head's query moved
     into the cached vector's space, zeros where the vector is padding)
-    against each cached vector up to its position, times ``scale``; the
-    vector's first ``value_lanes`` lanes are the value -> ``[B, Sq, H,
-    value_lanes]``.  With ``chunk`` the rows hold one query each and the
-    last ``chunk`` are a prompt chunk, attended packed
-    (:func:`_rows_and_chunk`).  ``tile_runs``: :func:`paged_tile_runs` of
-    these tables at :func:`paged_mla_tile_pages` pages a tile, from a caller
-    that has it already (every layer of a step reads the same tables).  The
-    kernel where :func:`paged_mla_tile_pages` says so, else the layer sliced
-    out and the gather reference."""
+    against each cached vector up to its position -> ``[B, Sq, H,
+    value_lanes]``: :func:`latent_plan` for the arrays' shapes."""
     _, _, BS, W = arena.shape
-    MB = block_tables.shape[1]
-    G = paged_mla_tile_pages(W, value_lanes, BS, MB, arena.dtype)
-    if tile_runs is None:
-        tile_runs = paged_tile_runs(block_tables, arena.shape[1], G)
-    if chunk:
-        def attend(q, tables, lens, _):
-            tables, runs = tables
-            return paged_mla_attention(q, arena, layer, tables, lens, scale=scale,
-                                       value_lanes=value_lanes, tile_runs=runs)
-        Sq = paged_mla_chunk_queries(chunk, q.shape[2], W, value_lanes, BS, MB,
-                                     q.dtype)
-        return _rows_and_chunk(attend, chunk, Sq, q, (block_tables, tile_runs),
-                               lengths)
-    if G:
-        return _paged_mla_call(q, arena, layer, block_tables, tile_runs,
-                               lengths, scale, value_lanes, G)
-    pages = jax.lax.dynamic_index_in_dim(arena, layer, 0, keepdims=False)
-    return paged_mla_attention_reference(q, pages, block_tables, lengths,
-                                         scale=scale, value_lanes=value_lanes)
-
-
-def _takes_gqa_kernel(H, Hkv, D, bias, window) -> bool:
-    """:func:`paged_layer_attention`'s rule: no bias, and a window, grouped
-    K/V heads, or heads that are whole lane tiles (what
-    :func:`gqa_kernel_shape_ok` asks of a head)."""
-    return not bias and (window is not None or Hkv != H or D % _LANES == 0)
-
-
-def paged_layer_attention(q, k_arena, v_arena, layer, block_tables, lengths,
-                          bias=None, window=None, chunk: int = 0,
-                          tile_runs=None):
-    """What ``gpt_paged_step`` calls a layer.  Grouped K/V heads, a window,
-    or multi-head attention whose heads are whole 128-lane tiles (OLMoE's 16
-    of 128: a group of one) go to :func:`paged_gqa_attention`, which takes
-    the arena whole, and with ``tile_runs`` (:func:`paged_tile_runs` of these
-    tables at :func:`paged_layer_run_pages` pages, worked out once a step) a
-    tile of pages that lie together with one copy.  Multi-head attention at
-    ``D = 64`` over every key
-    keeps the layer sliced out of the arena and :func:`paged_attention`: the
-    successor has no two-heads-a-lane-slice case (``_attend_block``), and
-    ``decode-heavy``'s backlog cannot outlast a 124M step without the copy
-    (ROADMAP S1, S0 (l)).  So does a bias (ALiBi), which the successor does
-    not take.  With ``chunk`` the rows hold one query each and the last
-    ``chunk`` are a prompt chunk, attended packed (:func:`_rows_and_chunk`)."""
-    H, D = q.shape[2:]
-    Hkv = k_arena.shape[3] // D
-    assert bias is None or window is None, (
-        "a window layer with an additive bias has no paged path")
-    if chunk:
-        attend = lambda q, tables, lens, bias: paged_layer_attention(
-            q, k_arena, v_arena, layer, tables[0], lens, bias=bias,
-            window=window, tile_runs=tables[1])
-        Sq = paged_layer_chunk_queries(
-            chunk, H, Hkv, D, k_arena.shape[2], block_tables.shape[1], q.dtype,
-            bias is not None, window)
-        return _rows_and_chunk(attend, chunk, Sq, q, (block_tables, tile_runs),
-                               lengths, bias)
-    if _takes_gqa_kernel(H, Hkv, D, bias is not None, window):
-        return paged_gqa_attention(q, k_arena, v_arena, layer, block_tables,
-                                   lengths, window, tile_runs)
-    kl = jax.lax.dynamic_index_in_dim(k_arena, layer, 0, keepdims=False)
-    vl = jax.lax.dynamic_index_in_dim(v_arena, layer, 0, keepdims=False)
-    return paged_attention(q, kl, vl, block_tables, lengths, bias=bias)
-
-
-def paged_layer_run_pages(H, Hkv, D, BS, MB, dtype, bias=False,
-                          window=None) -> int:
-    """Pages of a tile that the kernel :func:`paged_layer_attention` builds
-    for these shapes fetches with ONE copy an operand where they lie together
-    (:func:`paged_run_tile_pages`): what :func:`paged_tile_runs` flags and,
-    told by ``init_serving``, the allocator lays down in runs.  0 where it
-    copies page by page: ``paged_attention`` at ``D = 64`` or under a bias, a
-    window group's ring (it gives pages back, and runs in a ring are a design
-    of their own: ROADMAP S3 (b)), or a gather reference."""
-    if (window is not None or not _takes_gqa_kernel(H, Hkv, D, bias, window)
-            or not paged_gqa_tile_pages(1, H, Hkv, D, BS, MB, dtype)):
-        return 0
-    return paged_run_tile_pages(BS, MB, Hkv * D, dtype)
-
-
-def paged_layer_chunk_queries(chunk, H, Hkv, D, BS, MB, dtype, bias=False,
-                              window=None) -> int:
-    """:func:`paged_chunk_queries` of the call :func:`paged_layer_attention`
-    makes for a prompt chunk, by its rule: a K/V head's ``H / Hkv`` query
-    heads the rows of one product, or a head a product of its own in its
-    lane slice."""
-    tile_keys = paged_tile_pages(BS, MB, 1, Hkv * D, dtype) * BS
-    if _takes_gqa_kernel(H, Hkv, D, bias, window):
-        return paged_chunk_queries(chunk, H // Hkv, Hkv, D, D, tile_keys, dtype)
-    W = _lane_slices(H, D)[0]
-    return paged_chunk_queries(chunk, 1, H, W, W, tile_keys, dtype)
-
-
-def paged_layer_tile_pages(Sq, H, Hkv, D, BS, MB, dtype, bias=False,
-                           window=None) -> int:
-    """Pages a tile of the kernel :func:`paged_layer_attention` builds for
-    these shapes (0: a gather reference), by its rule."""
-    if _takes_gqa_kernel(H, Hkv, D, bias, window):
-        return paged_gqa_tile_pages(Sq, H, Hkv, D, BS, MB, dtype)
-    return paged_kernel_tile_pages(Sq, H, Hkv, D, BS, MB, dtype, bias)
+    plan = latent_plan(W, value_lanes, q.shape[2], BS, block_tables.shape[1],
+                       chunk, arena.dtype, scale)
+    return plan.attend(q, (arena, None), layer, block_tables, lengths,
+                       tile_runs=tile_runs, chunk=chunk)
 
 
 def _mesh_divisors():
@@ -1265,34 +1266,6 @@ def _mesh_divisors():
     mesh = mesh_lib.get_mesh()
     return (int(np.prod([mesh.shape[a] for a in mesh_lib.BATCH_AXES])),
             int(mesh.shape["tensor"]))
-
-
-def paged_kernel_tile_pages(Sq, H, Hkv, D, BS, MB, dtype, bias=False) -> int:
-    """The ``G`` of the kernel :func:`paged_attention` builds for these
-    shapes under the mesh of now; 0 where it takes the jnp gather reference
-    (a bias, GQA, lanes the gate refuses, a mesh of several devices, a
-    platform that is not a TPU).  The serving engine reports it as the
-    ``paged_tile_pages`` of its stats."""
-    if (bias or not _pallas.use_kernel("paged_attention")
-            or not kernel_shape_ok(H, Hkv, D, BS, dtype)
-            or not _pallas.single_device()):
-        return 0
-    return paged_tile_pages(BS, MB, Sq, Hkv * D, dtype)
-
-
-def paged_attention(q, k_pages, v_pages, block_tables, lengths, bias=None):
-    """Block-table KV attention for the serving engine: the paged Pallas
-    kernel where :func:`kernel_shape_ok` admits the shape (no bias, one
-    device), else the jnp gather reference.  Sharded meshes take the
-    reference path (the gather partitions cleanly under SPMD; the kernel
-    does not shard the global block arena)."""
-    B, Sq, H, D = q.shape
-    _, BS, HkvD = k_pages.shape
-    if paged_kernel_tile_pages(Sq, H, HkvD // D, D, BS, block_tables.shape[1],
-                               k_pages.dtype, bias is not None):
-        return _paged_call(q, k_pages, v_pages, block_tables, lengths)
-    return paged_attention_reference(q, k_pages, v_pages, block_tables,
-                                     lengths, bias=bias)
 
 
 def decode_attention(q, ck, cv, pos, bias=None, *,

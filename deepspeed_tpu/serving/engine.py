@@ -56,7 +56,7 @@ from deepspeed_tpu.comm.bounded import BoundedCollective, CollectiveTimeout
 from deepspeed_tpu.runtime.offload import StagingError
 from deepspeed_tpu.serving.config import DeepSpeedServingConfig
 from deepspeed_tpu.serving.kv_cache import (ArenaExhausted, PagedKVAllocator,
-                                            init_arena)
+                                            init_arena, table_widths)
 from deepspeed_tpu.serving.kv_tiering import KVTieringManager
 from deepspeed_tpu.serving.prefix_cache import PrefixCache
 from deepspeed_tpu.serving.scheduler import (DECODE, EXPIRED, FINISHED,
@@ -395,7 +395,6 @@ class ServingEngine:
         # groups`` pages of one group's layers each, one pool
         self._windows = mcfg.page_groups
         self._hybrid = mcfg.hybrid
-        self._run_blocks = 1        # blocks the allocator lays down together
         if self._hybrid:
             from deepspeed_tpu.models import hybrid
             for on, mechanism, what in (
@@ -424,52 +423,25 @@ class ServingEngine:
                 "blocks of ONE table a sequence; this model's layer pattern "
                 f"keeps {len(self._windows)} (windows {self._windows}), and "
                 "a window group gives its blocks back")
-        # which attention the decode program gets (static per engine): the
-        # paged kernel's pages per tile, 0 on the einsum path; a stat of
-        # every step
-        from deepspeed_tpu.ops.pallas.decode_attention import (
-            paged_layer_chunk_queries, paged_layer_run_pages,
-            paged_layer_tile_pages, paged_mla_chunk_queries,
-            paged_mla_tile_pages)
-        # rows of the attention's calls a token (a sparse layer's: K/V heads)
-        rows_a_token = 1
-        if "sparse" in mcfg.mixers:
-            # a sparse layer attends a row a (token, K/V head), under the
-            # table of the pages that token chose (``models/hybrid.py``)
-            from deepspeed_tpu.ops.pallas.decode_attention import (
-                paged_sparse_tile_pages)
-            self.paged_tile_pages = paged_sparse_tile_pages(
-                mcfg.n_head // mcfg.kv_heads, mcfg.head_dim, cfg.block_size,
-                hybrid.table_columns(mcfg, cfg.block_size), self.dtype)
-            queries, rows_a_token = 1, mcfg.kv_heads
-        elif mcfg.kv_lora_rank:
-            shape = (mcfg.cache_lanes[0], mcfg.kv_lora_rank, cfg.block_size,
-                     self.max_blocks_per_seq, self.dtype)
-            self.paged_tile_pages = paged_mla_tile_pages(*shape)
-            queries = paged_mla_chunk_queries(cfg.prefill_chunk, mcfg.n_head,
-                                              *shape)
-            # a kernel that fetches a tile of consecutive pages with one
-            # copy: its tables grow in runs of a tile
-            self._run_blocks = max(1, self.paged_tile_pages)
-        else:
-            shape = (mcfg.n_head, mcfg.kv_heads, mcfg.head_dim, cfg.block_size,
-                     self.max_blocks_per_seq, self.dtype,
-                     mcfg.position_encoding == "alibi")
-            self.paged_tile_pages = paged_layer_tile_pages(
-                1, *shape, self._windows[0])
-            queries = paged_layer_chunk_queries(cfg.prefill_chunk, *shape,
-                                                self._windows[0])
-            # so does the kernel that serves a group over every key where a
-            # head is whole lane tiles (0 pages: it copies page by page, as
-            # every kernel does on a window group's ring)
-            if None in self._windows:
-                self._run_blocks = max(1, paged_layer_run_pages(*shape))
-        # how a layer's attention takes the step's prompt chunk (static too):
-        # ``queries`` consecutive tokens a row, so its calls run this many
-        # rows where the program holds ``slots + chunk`` tokens
-        self.chunk_queries_per_row = queries
-        self.attention_rows = (cfg.max_batch_size
-                               + cfg.prefill_chunk // queries) * rows_a_token
+        # which attention the one program gets, a plan a page group (static
+        # per engine; the step builds the same from the arena's shapes).  The
+        # stats of every step are GROUP 0's: the paged kernel's pages a tile
+        # (0 on the einsum path), and how its calls take the prompt chunk,
+        # ``chunk_queries_per_row`` consecutive tokens a row, so they run
+        # ``attention_rows`` rows where the program holds ``slots + chunk``
+        # tokens.  The allocator takes ONE ``run_blocks``, the blocks it lays
+        # down together: the ``run_pages`` of the group over every key, whose
+        # kernel fetches a tile of consecutive pages with one copy (a window
+        # group's plan says 0, as every plan that copies page by page)
+        plans = mcfg.paged_plans(
+            cfg.block_size, table_widths(self._windows, self.max_blocks_per_seq,
+                                         cfg.prefill_chunk, cfg.block_size),
+            cfg.prefill_chunk, self.dtype)
+        self.paged_tile_pages = plans[0].tile_pages
+        self.chunk_queries_per_row = queries = plans[0].chunk_queries
+        self.attention_rows = (cfg.max_batch_size + cfg.prefill_chunk
+                               // queries) * plans[0].rows_a_token
+        self._run_blocks = max(1, *(plan.run_pages for plan in plans))
         # bytes the arena holds a token a layer (every array of the cache
         # spec)
         self.cache_bytes_per_token = (sum(mcfg.cache_lanes)

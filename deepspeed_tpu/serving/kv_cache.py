@@ -53,6 +53,16 @@ def window_table_blocks(window: int, chunk: int, block_size: int) -> int:
     return -(-(window + chunk - 1) // block_size) + 1
 
 
+def table_widths(windows, max_blocks_per_seq: int, chunk: int,
+                 block_size: int) -> Tuple[int, ...]:
+    """Columns of each group's block table: a full group's grows to
+    ``max_blocks_per_seq``, a window group's is its ring."""
+    return tuple(
+        max_blocks_per_seq if w is None else min(
+            max_blocks_per_seq, window_table_blocks(w, chunk, block_size))
+        for w in windows)
+
+
 class _Run:
     """One sequence's blocks in ONE group, in logical order: ``blocks[i]`` is
     logical block ``first + i``.  A full group's ``first`` stays 0."""
@@ -127,12 +137,8 @@ class PagedKVAllocator:
         self.max_blocks_per_seq = int(max_blocks_per_seq)
         self.windows = tuple(windows)
         self.n_groups = len(self.windows)
-        # columns of each group's table
-        self.widths = tuple(
-            self.max_blocks_per_seq if w is None else min(
-                self.max_blocks_per_seq,
-                window_table_blocks(w, chunk, self.block_size))
-            for w in self.windows)
+        self.widths = table_widths(self.windows, self.max_blocks_per_seq,
+                                   chunk, self.block_size)
         # LIFO free list: recently-freed blocks are reused first (their
         # pages are hot, and stale contents are fully overwritten before
         # any masked-in position can read them).  A dict in insertion order:

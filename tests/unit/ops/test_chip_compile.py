@@ -173,10 +173,9 @@ def test_serve_kernel_compiles_or_gate_says_einsum(chip, kernel, width):
     assert has_kernel == da.kernel_shape_ok(H, H, D, block, BF16)
     assert has_kernel == (width != "gpt2-xl")
     if kernel.startswith("paged"):
-        Sq = shapes[0][0][1]
-        assert da.paged_kernel_tile_pages(
-            Sq, H, H, D, block, MAX_BLOCKS, BF16) == (
-                128 // block if has_kernel else 0)
+        assert da.softmax_plan(
+            H, H, D, block, MAX_BLOCKS, 0, BF16, name="paged_attention"
+        ).tile_pages == (128 // block if has_kernel else 0)
 
 
 @pytest.mark.parametrize("rows,Sq", [(128, 1), (1, CHUNK)])
@@ -193,7 +192,8 @@ def test_paged_kernel_compiles_at_olmoe_heads(chip, rows, Sq):
     text = _compiled_text(chip, da.paged_attention, ((rows, Sq, H, D128), BF16),
                           pages, pages, ((rows, MB), jnp.int32), ((rows,), jnp.int32))
     assert "tpu_custom_call" in text
-    assert da.paged_kernel_tile_pages(Sq, H, H, D128, BS, MB, BF16) == 8
+    assert da.softmax_plan(H, H, D128, BS, MB, 0, BF16,
+                           name="paged_attention").tile_pages == 8
 
 
 def _kernel_rows(text, kernel):
@@ -220,10 +220,12 @@ def test_paged_kernel_compiles_at_a_serve_steps_rows(chip, slots, H, head_dim, M
     fn = lambda *a: da.paged_layer_attention(*a, chunk=CHUNK)
     text = _compiled_text(chip, fn, ((rows, 1, H, head_dim), BF16), arena, arena,
                           ((), jnp.int32), ((rows, MB), jnp.int32), ((rows,), jnp.int32))
-    assert da.paged_layer_chunk_queries(CHUNK, H, H, head_dim, BS, MB, BF16) == CHUNK
+    plan = da.softmax_plan(H, H, head_dim, BS, MB, CHUNK, BF16)
+    assert plan.chunk_queries == CHUNK
     assert _kernel_rows(text, kernel) == [1, slots]
-    assert da.paged_kernel_tile_pages(1, H, H, head_dim, BS, MB, BF16) == 8
-    assert da.paged_layer_tile_pages(1, H, H, head_dim, BS, MB, BF16) == 8
+    assert da.softmax_plan(H, H, head_dim, BS, MB, 0, BF16,
+                           name="paged_attention").tile_pages == 8
+    assert plan.kernel == kernel and plan.tile_pages == 8
     # D = 64 did not move: the layer's slice is still there for its kernel
     assert ("dynamic-slice" in text) == (kernel == "paged_attention")
 
@@ -248,11 +250,11 @@ def test_paged_gqa_kernel_compiles_at_smallthinker_heads(chip, window, MB):
         q, k, v, layer, tables, lengths, window=window, chunk=chunk)
     text = _compiled_text(chip, fn, ((rows, 1, H, D128), BF16), arena, arena,
                           ((), jnp.int32), ((rows, MB), jnp.int32), ((rows,), jnp.int32))
-    assert da.paged_layer_chunk_queries(chunk, H, Hkv, D128, BS, MB, BF16,
-                                        window=window) == 32
+    plan = da.softmax_plan(H, Hkv, D128, BS, MB, chunk, BF16, window=window)
+    assert plan.chunk_queries == 32
     assert _kernel_rows(text, "paged_gqa_attention") == [chunk // 32, slots]
     assert "dynamic-slice" not in text        # no layer of K and V sliced out
-    assert da.paged_layer_tile_pages(1, H, Hkv, D128, BS, MB, BF16, window=window) == 8
+    assert plan.tile_pages == 8
 
 
 def test_paged_sparse_kernel_compiles_at_minicpm_sala_heads(chip):
@@ -268,7 +270,7 @@ def test_paged_sparse_kernel_compiles_at_minicpm_sala_heads(chip):
     assert _kernel_rows(text, "paged_sparse_attention") == [rows]
     assert "dynamic-slice" not in text        # no layer of K and V sliced out
     # eight pages of 16 KiB a tile, where 128 rows would be two
-    assert da.paged_sparse_tile_pages(g, D128, BS, columns, BF16) == 8
+    assert da.chosen_plan(2, g, D128, BS, columns, BF16).tile_pages == 8
     assert da.paged_tile_pages(16, 1024, 1, 4 * D128, BF16) == 8      # SmallThinker's, as it was
 
 
@@ -320,10 +322,11 @@ def test_paged_gqa_kernel_compiles_at_zaya_heads(chip):
         q, k, v, layer, tables, lengths, chunk=chunk)
     text = _compiled_text(chip, fn, ((rows, 1, H, D128), BF16), arena, arena,
                           ((), jnp.int32), ((rows, MB), jnp.int32), ((rows,), jnp.int32))
-    assert da.paged_layer_chunk_queries(chunk, H, Hkv, D128, BS, MB, BF16) == 104
+    plan = da.softmax_plan(H, Hkv, D128, BS, MB, chunk, BF16)
+    assert plan.chunk_queries == 104
     assert _kernel_rows(text, "paged_gqa_attention") == [chunk // 104, slots]
     assert "dynamic-slice" not in text        # no layer of K and V sliced out
-    assert da.paged_layer_tile_pages(1, H, Hkv, D128, BS, MB, BF16) == 4
+    assert plan.tile_pages == 4
 
 
 def _gqa_calls(text):
@@ -349,17 +352,17 @@ def test_paged_gqa_kernel_compiles_with_run_flags(chip, H, Hkv, BS, slots, chunk
     for the kernel), so that a tile of pages that lie together is one DMA an
     operand; the attend keeps the tile of a call without flags."""
     D128, rows, (L, NB) = 128, slots + chunk, arena
-    shape = (H, Hkv, D128, BS, MB, BF16)
-    assert da.paged_layer_tile_pages(1, *shape) == attend
-    assert da.paged_layer_run_pages(*shape) == copy
+    plan = da.softmax_plan(H, Hkv, D128, BS, MB, chunk, BF16)
+    assert plan.tile_pages == attend
+    assert plan.run_pages == copy
     tiles = MB // copy
     fn = lambda q, k, v, layer, tables, lengths: da.paged_layer_attention(
         q, k, v, layer, tables, lengths, chunk=chunk,
-        tile_runs=da.paged_tile_runs(tables, NB, copy))
+        tile_runs=plan.tile_runs(tables, NB))
     pages = ((L, NB, BS, Hkv * D128), BF16)
     text = _compiled_text(chip, fn, ((rows, 1, H, D128), BF16), pages, pages,
                           ((), jnp.int32), ((rows, MB), jnp.int32), ((rows,), jnp.int32))
-    Sq = da.paged_layer_chunk_queries(chunk, *shape)
+    Sq = plan.chunk_queries
     calls = _gqa_calls(text)
     assert _kernel_rows(text, "paged_gqa_attention") == sorted([chunk // Sq, slots])
     view = f"bf16[{L},{NB * BS},{Hkv * D128}]"
@@ -515,10 +518,11 @@ def test_paged_mla_kernel_compiles_at_mistral4_heads(chip):
     text = _compiled_text(chip, fn, ((rows, 1, H, W), BF16),
                           ((5, 50000, BS, W), BF16), ((), jnp.int32),
                           ((rows, MB), jnp.int32), ((rows,), jnp.int32))
-    assert da.paged_mla_chunk_queries(chunk, H, W, R, BS, MB, BF16) == 16
+    plan = da.latent_plan(W, R, H, BS, MB, chunk, BF16, 128 ** -0.5)
+    assert plan.chunk_queries == 16
     assert _kernel_rows(text, "paged_mla_attention") == [chunk // 16, slots]
     assert "dynamic-slice" not in text        # no layer of the arena sliced out
-    assert da.paged_mla_tile_pages(W, R, BS, MB, BF16) == 32
+    assert plan.tile_pages == plan.run_pages == 32
 
 
 # a serve cell's model at its published widths (one period of its layers: the

@@ -9,7 +9,7 @@ import jax.numpy as jnp
 from deepspeed_tpu.ops.pallas import decode_attention as da
 from deepspeed_tpu.ops.pallas.decode_attention import (
     decode_attention_reference, paged_attention, paged_attention_reference,
-    paged_kernel_tile_pages, paged_tile_pages)
+    paged_tile_pages)
 
 
 @pytest.fixture
@@ -212,11 +212,12 @@ def test_tile_pages_follow_shapes_only():
 def test_dispatch_reports_its_tile(kernel_calls):
     """What the dispatch reports (and the serving engine's stats carry):
     ``G`` where the kernel runs, 0 where the einsum does."""
-    bf16 = jnp.bfloat16
-    assert paged_kernel_tile_pages(1, 12, 12, 64, 16, 64, bf16) == 8
-    assert paged_kernel_tile_pages(1, 12, 12, 64, 16, 64, bf16, bias=True) == 0
-    assert paged_kernel_tile_pages(1, 25, 25, 64, 16, 64, bf16) == 0   # xl
-    assert paged_kernel_tile_pages(1, 8, 2, 64, 16, 64, bf16) == 0     # GQA
+    tile = lambda H, Hkv, bias=False: da.softmax_plan(
+        H, Hkv, 64, 16, 64, 0, jnp.bfloat16, bias, name="paged_attention").tile_pages
+    assert tile(12, 12) == 8
+    assert tile(12, 12, bias=True) == 0
+    assert tile(25, 25) == 0   # xl
+    assert tile(8, 2) == 0     # GQA
 
 
 def test_dispatch_takes_reference_on_bias_and_gqa(kernel_calls):

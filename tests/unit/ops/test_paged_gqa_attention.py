@@ -86,10 +86,11 @@ def test_kernel_and_reference_equal_attention_over_the_logical_keys(
                                        window=window)
     np.testing.assert_allclose(np.asarray(ref)[live], want[live], atol=2e-5)
     kernels("paged_gqa_attention")
-    assert da.paged_gqa_tile_pages(Sq, 2 * g, 2, D, BS, MB, jnp.float32) > 0
+    assert da.softmax_plan(2 * g, 2, D, BS, MB, 0, jnp.float32,
+                           name="paged_gqa_attention").tile_pages > 0
     if Sq == 32:        # what the rule gives SmallThinker's heads and chunk
-        assert da.paged_layer_chunk_queries(224, 28, 4, D, 16, MB, jnp.bfloat16,
-                                            window=window) == Sq
+        assert da.softmax_plan(28, 4, D, 16, MB, 224, jnp.bfloat16,
+                               window=window).chunk_queries == Sq
     out = da.paged_gqa_attention(q, ka, va, jnp.asarray(1), tables, lens,
                                  window=window)
     assert np.isfinite(np.asarray(out)).all()       # the idle row too
@@ -137,12 +138,11 @@ def test_multi_head_attention_of_whole_lane_tiles_is_a_group_of_one(
         called.append(q.shape[:2]) or call(q, *a)))
     out = da.paged_layer_attention(q, ka, va, jnp.asarray(1), tables, lens,
                                    chunk=chunk)
-    Sq = chunk and da.paged_layer_chunk_queries(chunk, Hkv, Hkv, D, BS,
-                                                tables.shape[1], jnp.float32)
+    plan = da.softmax_plan(Hkv, Hkv, D, BS, tables.shape[1], chunk, jnp.float32)
+    Sq = chunk and plan.chunk_queries
     assert called == [(len(lengths), 1)] + ([(chunk // Sq, Sq)] if chunk else [])
     assert Sq == chunk              # the whole chunk one row at these sizes
-    assert da.paged_layer_tile_pages(1, Hkv, Hkv, D, BS, tables.shape[1],
-                                     jnp.float32) > 0
+    assert plan.tile_pages > 0
     carries = np.asarray(lens) > 0
     carries[len(lengths):] = live
     assert np.isfinite(np.asarray(out)).all()       # idle rows too
@@ -173,8 +173,10 @@ def test_the_layer_rule(kernels, monkeypatch, g, window, head_dim, alibi, kernel
     da.paged_layer_attention(q, ka, va, jnp.asarray(1), tables, lens,
                              bias=bias, window=window)
     assert seen == ([kernel] if kernel else [])
-    assert bool(da.paged_layer_tile_pages(
-        1, 2 * g, 2, head_dim, BS, 8, jnp.float32, alibi, window)) == bool(kernel)
+    plan = da.softmax_plan(2 * g, 2, head_dim, BS, 8, 0, jnp.float32, alibi, window)
+    assert plan.kernel == {"mha": "paged_attention", "gqa": "paged_gqa_attention",
+                           None: None}[kernel]
+    assert bool(plan.tile_pages) == bool(kernel)
 
 
 def test_a_window_layer_with_a_bias_is_refused():

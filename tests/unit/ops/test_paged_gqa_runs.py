@@ -19,8 +19,8 @@ D, Hkv = 128, 2                                   # 256 lanes a page
 
 def flags_of(tables, g, dtype=jnp.float32):
     """The flags of ``tables`` as a step works them out for a full group."""
-    pages = da.paged_layer_run_pages(g * Hkv, Hkv, D, BS, tables.shape[1], dtype)
-    return pages, da.paged_tile_runs(jnp.asarray(tables), NB, pages)
+    plan = da.softmax_plan(g * Hkv, Hkv, D, BS, tables.shape[1], 0, dtype)
+    return plan.run_pages, plan.tile_runs(jnp.asarray(tables), NB)
 
 
 def arenas(rng, untouched=(), layers=2):
@@ -78,7 +78,7 @@ def test_the_layers_call_cuts_the_flags_as_it_cuts_the_tables(kernels, runs, g, 
     kernels("paged_gqa_attention")
     rng = np.random.default_rng(3)
     chunk, slots = 32, 3
-    pages = da.paged_layer_run_pages(g * Hkv, Hkv, D, BS, MB, dtype)
+    pages = da.softmax_plan(g * Hkv, Hkv, D, BS, MB, 0, dtype).run_pages
     assert pages == G
     table = (runs_of(G, 4 * G, 2 * G) if runs
              else rng.permutation(np.arange(1, NB))[:MB])
@@ -135,13 +135,14 @@ def test_a_call_without_flags_is_the_program_that_copies_page_by_page(kernels, c
 
 
 def test_no_window_group_and_no_reference_path_asks_for_runs(kernels):
-    shape = (4 * Hkv, Hkv, D, BS, MB, jnp.bfloat16)
-    assert da.paged_layer_run_pages(*shape) == 0           # the CPU's rule
+    run_pages = lambda H=4 * Hkv, Hkv=Hkv, D=D, MB=MB, **rule: da.softmax_plan(
+        H, Hkv, D, BS, MB, 0, jnp.bfloat16, **rule).run_pages
+    assert run_pages() == 0                                 # the CPU's rule
     kernels("paged_gqa_attention")
-    assert da.paged_layer_run_pages(*shape) == G
-    assert da.paged_layer_run_pages(*shape, window=64) == 0
-    assert da.paged_layer_run_pages(*shape, bias=True) == 0
-    assert da.paged_layer_run_pages(12, 12, 64, BS, 64, jnp.bfloat16) == 0   # D = 64
+    assert run_pages() == G
+    assert run_pages(window=64) == 0
+    assert run_pages(bias=True) == 0
+    assert run_pages(12, 12, 64, 64) == 0                   # D = 64
     assert da.paged_tile_runs(jnp.zeros((2, MB), jnp.int32), NB, 0) is None
 
 

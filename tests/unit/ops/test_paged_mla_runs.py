@@ -15,11 +15,14 @@ NB = 8 * G + 5
 SCALE = 0.17
 
 
+def plan_of(MB, dtype):
+    return da.latent_plan(W, R, H, BS, MB, 0, dtype, SCALE)
+
+
 def mla_tile_runs(tables, arena):
     """The flags of ``tables`` at the pages a tile of the latent kernel holds
     for them (None where it takes the reference), as the step works them out."""
-    return da.paged_tile_runs(tables, arena.shape[1], da.paged_mla_tile_pages(
-        W, R, BS, tables.shape[1], arena.dtype))
+    return plan_of(tables.shape[1], arena.dtype).tile_runs(tables, arena.shape[1])
 
 
 def runs_of(*firsts):
@@ -80,7 +83,7 @@ def test_the_kernel_on_runs_equals_the_gather_reference(kernels, case, Sq):
     arena[:, untouched] = np.nan          # unwritten garbage past the lengths
     clean[:, untouched] = 0.0
     q = jnp.asarray(rng.standard_normal((B, Sq, H, W)), jnp.float32)
-    assert da.paged_mla_tile_pages(W, R, BS, MB, jnp.float32) == G
+    assert plan_of(MB, jnp.float32).run_pages == G
     flags = np.asarray(mla_tile_runs(jnp.asarray(tables), jnp.asarray(arena)))
     want_flags = {"runs": [[1, 1, 1]] * 3,
                   "mixed": [[1, 0, 1], [0, 1, 1], [0, 0, 0], [0, 0, 0]],
@@ -157,7 +160,7 @@ def test_what_comes_out_does_not_depend_on_how_many_keys_a_copy_brings(
     got = {}
     for rows in (512, 256):
         monkeypatch.setattr(da, "_MLA_TILE_ROWS", rows)
-        assert da.paged_mla_tile_pages(W, R, BS, MB, jnp.bfloat16) == rows // BS
+        assert plan_of(MB, jnp.bfloat16).tile_pages == rows // BS
         got[rows] = jax.jit(lambda *a: da.paged_mla_attention(
             *a, scale=SCALE, value_lanes=R))(
                 q, arena, jnp.int32(1), jnp.asarray(tables), jnp.asarray(lengths))

@@ -36,6 +36,18 @@ MODELS = {
     # D = 64: the sliced layer and ``paged_attention``, which copies page by page
     "gpt2": lambda: gpt.gpt_config("tiny", n_embd=128, n_head=2, n_layer=2,
                                    vocab_size=V, n_positions=256, dtype=jnp.float32),
+    # the two families whose kernels are not this file's (``tests/unit/ops/
+    # test_paged_plans.py``).  The latent cache's 128 + 16 lanes in 256, under
+    # tables of 32 pages: a tile of 32
+    "mistral": lambda: gpt.mistral4_config(
+        vocab_size=V, n_positions=256, n_embd=64, intermediate_size=32, n_layer=2,
+        n_head=4, head_dim=32, q_lora_rank=48, kv_lora_rank=128, qk_rope_dim=16,
+        v_head_dim=24, num_experts=4, top_k=2, dtype="float32"),
+    # a page a block of 8 keys of one K/V head of 128 lanes
+    "minicpm": lambda: gpt.minicpm_sala_config(
+        vocab_size=V, n_positions=256, n_embd=64, intermediate_size=32, n_head=4,
+        n_kv_head=2, head_dim=128, mixer_types=["minicpm4", "lightning-attn"],
+        sparse=(4, 2, 8, 4, 1, 16, 32), dtype="float32"),
 }
 
 
@@ -105,7 +117,9 @@ def test_the_engine_lays_runs_and_serves_the_tokens_of_run_blocks_1(
     # three tables hold are runs while all are resident
     assert max(s["tile_runs_pct"] for s in stats) > 50.0
     assert stats[0]["tile_runs_pct"] > 0.0
-    monkeypatch.setattr(da, "paged_layer_run_pages", lambda *a, **kw: 0)
+    plan = da.softmax_plan
+    monkeypatch.setattr(da, "softmax_plan",
+                        lambda *a, **kw: plan(*a, **kw)._replace(run_pages=0))
     plain, plain_stats, run_blocks = served(model, params, prompts, new)
     assert run_blocks == 1 and all(s["tile_runs_pct"] == 0.0 for s in plain_stats)
     assert tokens == plain and [len(t) for t in tokens] == list(new)

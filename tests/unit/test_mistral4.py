@@ -343,8 +343,9 @@ def test_paged_mla_kernel_equals_the_gather_reference(kernels, Sq):
     lengths = jnp.asarray([3, 15, 255 + 9, 511 + 9, MB * BS - Sq, 0], jnp.int32)
     tables = jnp.asarray(np.stack([rng.permutation(np.arange(1, NB))[:MB]
                                    for _ in range(B)]), jnp.int32).at[5].set(0)
-    assert da.paged_mla_tile_pages(W, R, BS, MB, jnp.float32) == 32
-    assert da.paged_mla_chunk_queries(384, 32, 384, 256, 16, 1024, jnp.bfloat16) == 16
+    assert da.latent_plan(W, R, H, BS, MB, 0, jnp.float32, 0.17).tile_pages == 32
+    assert da.latent_plan(384, 256, 32, 16, 1024, 384, jnp.bfloat16,
+                          0.17).chunk_queries == 16
     got = jax.jit(lambda *a: da.paged_mla_attention(*a, scale=0.17, value_lanes=R))(
         q, arena, jnp.int32(1), tables, lengths)
     want = da.paged_mla_attention_reference(q, arena[1], tables, lengths, scale=0.17,

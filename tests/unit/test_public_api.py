@@ -47,3 +47,30 @@ def test_bool_flags_honor_false():
 def test_unknown_attribute_raises():
     with pytest.raises(AttributeError):
         deepspeed.definitely_not_an_export
+
+
+@pytest.mark.parametrize("source", ["__init__.py", "models/gpt.py"])
+def test_the_train_path_imports_no_serving_code(source):
+    """What ``import deepspeed_tpu`` and ``from deepspeed_tpu.models import
+    gpt`` load is what a train cell's ``setup_s`` pays for: the paged kernels,
+    the hybrid walk and the serving engine are imported inside the functions
+    that serve (read from the source: an import of the package costs seconds
+    here)."""
+    import ast
+    import os
+    tree = ast.parse(open(os.path.join(os.path.dirname(deepspeed.__file__), source)).read())
+    # module level: not inside a function or a class, whatever else nests it
+    level = list(tree.body)
+    for node in level:
+        if not isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef, ast.ClassDef)):
+            level.extend(ast.iter_child_nodes(node))
+    names = set()
+    for node in level:
+        if isinstance(node, ast.Import):
+            names.update(alias.name for alias in node.names)
+        elif isinstance(node, ast.ImportFrom):
+            names.update(f"{node.module}.{alias.name}" for alias in node.names)
+    assert any(name.startswith("deepspeed_tpu.") for name in names)
+    lazy = ("ops.pallas.decode_attention", "models.hybrid", "serving")
+    assert not [name for name in names if any(
+        f".{part}." in f".{name}." for part in lazy)], names
