@@ -50,8 +50,10 @@ class LayerKind(NamedTuple):
     are rotated (``rope``) or carry no position, and the layer's ``mixer``:
     ``softmax`` attention over cached keys (most families), attention over
     the blocks the query chooses (``sparse``: :class:`SparseSpec`), a
-    ``linear`` recurrence whose cache is a state, or attention inside a
-    latent convolved over time (``cca``; all three ``models/hybrid.py``).
+    ``linear`` recurrence whose cache is a state, attention inside a latent
+    convolved over time (``cca``), the gated ``delta`` rule whose state is
+    corrected before it is written, or, beside one of those in the same
+    walk, ``full`` softmax attention over pages (all ``models/hybrid.py``).
     ``depth`` is the layer's index in the PUBLISHED stack where that differs
     from its place here (a linear layer's decay reads it).  ``ffn`` is the
     layer's feed-forward, ``mlp`` or ``moe`` (None: the model's one kind, an
@@ -232,6 +234,22 @@ class GPTConfig:
     residual_scale: float = 1.0
     head_divisor: float = 1.0
     published_layers: Optional[int] = None
+    # --- the gated delta rule (the Olmo-Hybrid family's ``delta`` layers,
+    # ``models/hybrid.py:delta_mixer``): ``delta_heads`` heads, a key of
+    # ``delta_key_dim`` and a value of ``delta_value_dim`` lanes, behind a
+    # causal convolution over time of ``delta_conv`` taps; with
+    # ``delta_neg_eigval`` the write strength is ``2 sigmoid`` and the
+    # transition's eigenvalue reaches -1 --------------------------------- #
+    delta_heads: int = 0
+    delta_key_dim: int = 0
+    delta_value_dim: int = 0
+    delta_conv: int = 4
+    delta_neg_eigval: bool = False
+    # where a block's two norms sit: on each sublayer's OUTPUT (the OLMo 2/3
+    # block, ``x + norm(f(x))``: mixer and MLP read the residual as it is)
+    # and not on its input.  The hybrid walk reads it; the dense paths are
+    # pre-norm
+    norm_after: bool = False
 
     def __post_init__(self):
         self.padded_vocab = int(
@@ -291,8 +309,13 @@ class GPTConfig:
         self.hybrid = any(m != "softmax" for m in self.mixers)
         if self.hybrid:
             assert len(self.pattern) == self.n_layer and all(
-                m in ("sparse", "linear", "cca") for m in self.mixers), (
-                    "a hybrid stack names every layer: sparse, linear or cca")
+                m in ("sparse", "linear", "cca", "delta", "full")
+                for m in self.mixers) and set(self.mixers) != {"full"}, (
+                    "a hybrid stack names every layer: sparse, linear, cca, "
+                    "delta, or full beside one of them")
+            assert "delta" not in self.mixers or (
+                self.delta_heads and self.delta_key_dim and self.delta_value_dim
+                and self.delta_conv >= 2), "a delta layer's heads and widths"
             self.sparse = SparseSpec(*(self.sparse or ()))
             sp = self.sparse
             assert sp.kernel == 2 * sp.stride and sp.block % sp.stride == 0 \
@@ -308,7 +331,12 @@ class GPTConfig:
                     and self.moe_experts_held is None)), (
                         "a hybrid stack's expert layers: the whole bank "
                         "behind the dropless MLP router with its stream")
+            assert not self.norm_after or "moe" not in self.ffns, (
+                "the norm on a sublayer's output is the dense MLP's")
         else:
+            assert not self.norm_after, (
+                "the norm on a sublayer's output is read by the hybrid walk "
+                "(models/hybrid.py) alone")
             assert not self.moe_router_hidden, (
                 "the router's stream is a second carry of the layer walk, "
                 "which models/hybrid.py alone has")
@@ -360,7 +388,9 @@ class GPTConfig:
         H, Hkv, D = self.n_head, self.kv_heads, self.head_dim
         if "sparse" in self.mixers:
             # the sparse layers own the pages (``hybrid.arena_layout``); cca
-            # layers' are K and V like any grouped-query model's
+            # layers' are K and V like any grouped-query model's, and a
+            # hybrid stack's ``full`` layers' plain softmax attention's: both
+            # land on ``softmax_plan`` below as OLMoE's layers do
             from deepspeed_tpu.models import hybrid
             return (da.chosen_plan(
                 Hkv, H // Hkv, D, block_size,
@@ -538,6 +568,45 @@ def zaya_config(vocab_size=262272, n_positions=131072, n_embd=2048, n_layer=40,
     kw.update(overrides)
     return llama_config(vocab_size=vocab_size, n_positions=n_positions,
                         n_embd=n_embd, n_layer=n_layer, n_head=n_head,
+                        intermediate_size=intermediate_size, **kw)
+
+
+_OLMO_HYBRID_MIXERS = {"linear_attention": "delta", "full_attention": "full"}
+
+
+def olmo_hybrid_config(vocab_size=100352, n_positions=65536, n_embd=3840,
+                       n_head=30, n_kv_head=30, head_dim=128,
+                       intermediate_size=11008, layer_types=None,
+                       linear_heads=30, linear_key_head_dim=96,
+                       linear_value_head_dim=192, linear_conv_kernel_dim=4,
+                       linear_allow_neg_eigval=True, **overrides) -> GPTConfig:
+    """Olmo-Hybrid family (defaults: Olmo-Hybrid-7B's widths): layers of the
+    gated delta rule (``"linear_attention"``: ``linear_heads`` heads whose
+    cache is a float32 state ``[linear_key_head_dim, linear_value_head_dim]``
+    a slot, corrected by what it returns for the token's key before the
+    token is written, the decay and the write strength computed from the
+    token, behind a causal convolution of ``linear_conv_kernel_dim`` taps
+    over the packed ``[q | k | v]``: ``models/hybrid.py:delta_mixer``) among
+    layers of plain softmax attention (``"full_attention"``: ``n_head`` heads
+    on ``n_kv_head`` K/V heads, an RMSNorm over the whole q and k
+    projections, NO position encoding: the delta layers order the tokens), in
+    the order ``layer_types`` gives (a slice of the published list is a
+    pipeline stage); the OLMo 2/3 block, each norm on its sublayer's OUTPUT;
+    a dense SwiGLU MLP.  RMSNorm (eps 1e-6), no bias, untied head.  Served
+    through ``init_serving()`` (``models/hybrid.py``); the dense paths refuse
+    it."""
+    assert layer_types, "layer_types: 'linear_attention' or 'full_attention' a layer"
+    pattern = tuple(LayerKind(None, False, _OLMO_HYBRID_MIXERS[t])
+                    for t in layer_types)
+    kw = dict(n_kv_head=n_kv_head, head_dim=head_dim, ln_eps=1e-6,
+              layer_pattern=pattern, qk_norm=True, norm_after=True,
+              delta_heads=linear_heads, delta_key_dim=linear_key_head_dim,
+              delta_value_dim=linear_value_head_dim,
+              delta_conv=linear_conv_kernel_dim,
+              delta_neg_eigval=linear_allow_neg_eigval)
+    kw.update(overrides)
+    return llama_config(vocab_size=vocab_size, n_positions=n_positions,
+                        n_embd=n_embd, n_layer=len(pattern), n_head=n_head,
                         intermediate_size=intermediate_size, **kw)
 
 

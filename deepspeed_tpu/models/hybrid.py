@@ -3,9 +3,11 @@ keys, on the serving path: ``cfg.pattern`` names EVERY layer (its mixer and
 its feed-forward), and ONE walk (:func:`hybrid_paged_step`) runs the stack
 in runs of a mixer, dispatching on both.  The MiniCPM-SALA family
 (``models/gpt.py:minicpm_sala_config``: sparse and linear layers in no
-period over a dense MLP) and the ZAYA1 family (``zaya_config``: ``cca``
-layers over an expert bank) are entries of :data:`MIXERS` and
-:data:`FEED_FORWARDS`.  The mixers:
+period over a dense MLP), the ZAYA1 family (``zaya_config``: ``cca`` layers
+over an expert bank) and the Olmo-Hybrid family (``olmo_hybrid_config``:
+``delta`` layers, three to every ``full`` layer, each norm on its sublayer's
+output) are entries of :data:`MIXERS` and :data:`FEED_FORWARDS`.  The mixers
+(``cca`` is described at :func:`cca_mixer`):
 
 * ``sparse`` (the InfLLM-V2 line of MiniCPM4): grouped-query attention
   without rope whose query, once more than ``dense_len`` keys lie before it,
@@ -26,7 +28,21 @@ layers over an expert bank) are entries of :data:`MIXERS` and
   (``O = ((Q K^T) . D) V + (Q . a) S_in``, every decay ``exp(-s_h n)`` of
   an ``n >= 0``).  A chunk that starts at position 0 starts from a zero
   state, so a slot bound to a new sequence, and one whose request was
-  preempted and is prefilled again, needs no other reset.
+  preempted and is prefilled again, needs no other reset;
+* ``delta`` (the gated delta rule, arXiv:2412.06464): a head's cache is a
+  float32 state ``[d_k, d_v]`` a slot that is CORRECTED before it is written:
+  ``S' = a_t S; S_t = S' + k_t (b_t (v_t - S'^T k_t))^T; o_t = S_t^T q_t``,
+  the decay ``a_t`` and the write strength ``b_t`` (up to 2: the transition's
+  eigenvalue reaches -1) computed from the token, behind a causal
+  convolution over time of the packed ``[q | k | v]`` whose last ``taps -
+  1`` rows a slot keeps too.  One update a decode row
+  (``ops/pallas/delta_rule.py``: the state read once and written once); over
+  a prompt chunk the chunked form with a unit lower-triangular solve a head
+  (:func:`delta_chunk`).  Both states start from zero BY POSITION, as the
+  linear layers' do;
+* ``full``: plain softmax attention over its own token's K and V, beside a
+  mixer that owns no pages: ``models/gpt.py``'s projection (with its q/k
+  norm over all lanes) and the page group's plan, CALLED from the walk.
 
 The leaves are stacked BY MIXER (``params["blocks"]["sparse"]`` ``[4, ...]``,
 ``["linear"]`` ``[12, ...]``: no projection is padded to another kind's
@@ -95,12 +111,13 @@ def arena_layout(cfg) -> Tuple[int, int, Tuple[int, ...]]:
     """``GPTConfig.arena_layout`` of a hybrid stack: the sparse layers own
     the pages, a K/V head a page of its own (the selection differs by K/V
     head, so the kernel walks a list of pages a head); the cca layers own
-    them, a page a block of all K/V heads like any grouped-query model's;
-    the linear layers own none."""
-    paged = {m for m in cfg.mixers if m != "linear"}
+    them, a page a block of all K/V heads like any grouped-query model's,
+    and so do the full layers; the linear and the delta layers own none."""
+    paged = {m for m in cfg.mixers if m not in ("linear", "delta")}
     assert len(paged) <= 1, f"one mixer's layers own the pages, not {paged}"
-    if "cca" in paged:
-        return cfg.mixers.count("cca"), 1, (cfg.kv_heads * cfg.head_dim,) * 2
+    for m in ("cca", "full"):
+        if m in paged:
+            return cfg.mixers.count(m), 1, (cfg.kv_heads * cfg.head_dim,) * 2
     return cfg.mixers.count("sparse"), cfg.kv_heads, (cfg.head_dim,) * 2
 
 
@@ -113,9 +130,11 @@ def what_a_dense_path_lacks(cfg) -> str:
              "sparse": f"no block selection for the {n('sparse')} sparse layers",
              "cca": f"no convolution over time of the packed q/k latents (nor "
                     f"its backward) and no second carry for the router's "
-                    f"stream of the {n('cca')} cca layers"}
-    return " and ".join(lacks[m] for m in ("linear", "sparse", "cca")
-                        if m in cfg.mixers)
+                    f"stream of the {n('cca')} cca layers",
+             "delta": f"no chunked delta-rule scan (nor its backward) and no "
+                      f"convolution over time of the packed q/k/v for the "
+                      f"{n('delta')} delta layers"}
+    return " and ".join(lacks[m] for m in lacks if m in cfg.mixers)
 
 
 def what_no_block_carries(cfg) -> str:
@@ -126,9 +145,11 @@ def what_no_block_carries(cfg) -> str:
              "sparse": f"{n('sparse')} sparse layers a compressed-key cache",
              "cca": f"{n('cca')} cca layers hold a convolution state a slot "
                     f"(the last two packed latents and the next token's "
-                    f"shifted value half)"}
+                    f"shifted value half)",
+             "delta": f"{n('delta')} delta layers hold a recurrent state and "
+                      f"a convolution state a slot"}
     return "this model's " + " and its ".join(
-        holds[m] for m in ("linear", "sparse", "cca") if m in cfg.mixers)
+        holds[m] for m in holds if m in cfg.mixers)
 
 
 # --------------------------------------------------------------------------- #
@@ -147,12 +168,31 @@ def _cca_widths(cfg) -> Tuple[int, int]:
     return (cfg.n_head + cfg.kv_heads) * cfg.head_dim, cfg.kv_heads * cfg.head_dim // 2
 
 
+def _delta_lanes(cfg) -> int:
+    """Lanes of a delta layer's packed ``[q | k | v]``."""
+    return cfg.delta_heads * (2 * cfg.delta_key_dim + cfg.delta_value_dim)
+
+
 def _mixer_shapes(cfg, mixer: str) -> Dict:
     E, D, A = cfg.n_embd, cfg.head_dim, cfg.attn_dim
     if mixer == "sparse":
         return dict(_GATED(cfg), q_w=(E, A), kv_w=(E, 2 * cfg.kv_heads * D))
     if mixer == "linear":
         return dict(_GATED(cfg), qkv_w=(E, 3 * A), onorm_g=(A,))
+    if mixer == "full":
+        return {"qkv_w": (E, cfg.qkv_dim), "out_w": (A, E),
+                "q_norm_g": (A,), "k_norm_g": (cfg.kv_heads * D,)}
+    if mixer == "delta":
+        # qkv_w: [W_q | W_k | W_v], the packed lanes the convolution runs
+        # over (conv_w: tap 0 the oldest token's); gate_w the output gate z;
+        # ba_w: [W_b | W_a], the write strength's and the decay's logit a
+        # head; onorm_g ONE gain for all heads
+        H, U = cfg.delta_heads, _delta_lanes(cfg)
+        return {"qkv_w": (E, U), "gate_w": (E, H * cfg.delta_value_dim),
+                "ba_w": (E, 2 * H), "conv_w": (cfg.delta_conv, U),
+                "a_log": (H,), "dt_bias": (H,),
+                "onorm_g": (cfg.delta_value_dim,),
+                "out_w": (H * cfg.delta_value_dim, E)}
     U, Vh = _cca_widths(cfg)
     # qkv_w: [W_q | W_k | W_v1 (this token's value half) | W_v2 (the next
     # token's)]; conv0 depthwise over the packed latent, conv1 a map a head
@@ -188,12 +228,17 @@ def _is_shape(x) -> bool:
 
 def init_blocks(cfg, rng: Array) -> Dict:
     """``{mixer: leaves [that mixer's layers, ...]}``: gains (``*_g``) 1, the
-    balancing bias 0, every other leaf normal 0.02."""
+    balancing bias and the decay's bias 0, a delta head ``h``'s ``a_log``
+    ``log(0.02 (h + 1))`` (with ``a_t`` near 0 the heads then forget over 72
+    down to 2.4 tokens: none is dead, none unbounded), every other leaf
+    normal 0.02."""
     def leaf(name, key, shape):
         if name.endswith("_g"):
             return jnp.ones(shape, jnp.float32)
-        if name == "balance_bias":
+        if name in ("balance_bias", "dt_bias"):
             return jnp.zeros(shape, jnp.float32)
+        if name == "a_log":
+            return jnp.log(0.02 * (jnp.arange(shape[0], dtype=jnp.float32) + 1.0))
         return gpt._dense_init(key, shape[0], shape)
 
     def one(shapes, key):
@@ -255,8 +300,19 @@ def init_aux(cfg, num_blocks: int, block_size: int, slots: int, dtype) -> Dict:
     [linear layers, slots, H, D, D]`` float32: a linear layer's cache, a
     slot's whatever its length; ``cca_state [cca layers, slots, 2 U + Vh]``
     (a stack with cca layers): a slot's ``[u_{t-1} | u_{t-2} | W_v2 h_{t-1}]``
-    in the type they were computed in."""
+    in the type they were computed in; ``delta_state [delta layers, slots,
+    d_k, H * d_v]`` float32 (a head's ``[d_k, d_v]`` beside the other heads'
+    on the lanes: ``ops/pallas/delta_rule.py`` says why) and ``delta_conv
+    [delta layers, slots, taps - 1, U]``: a slot's last packed ``[q | k |
+    v]`` rows, the oldest first."""
     D, out = cfg.head_dim, {}
+    if "delta" in cfg.mixers:
+        L, H = cfg.mixers.count("delta"), cfg.delta_heads
+        out.update(
+            delta_state=jnp.zeros((L, slots, cfg.delta_key_dim,
+                                   H * cfg.delta_value_dim), jnp.float32),
+            delta_conv=jnp.zeros((L, slots, cfg.delta_conv - 1,
+                                  _delta_lanes(cfg)), dtype))
     if "cca" in cfg.mixers:
         U, Vh = _cca_widths(cfg)
         out["cca_state"] = jnp.zeros((cfg.mixers.count("cca"), slots,
@@ -649,13 +705,174 @@ def cca_mixer(cfg, p, h, kp, vp, held, li, step: _Step):
 
 
 # --------------------------------------------------------------------------- #
+# The delta mixer
+# --------------------------------------------------------------------------- #
+def delta_chunk(q, k, v, g, beta, s_in, live):
+    """The gated delta rule over ``C`` consecutive tokens of one sequence
+    that enters with the state ``s_in [H, dk, dv]``, in its chunked form.
+    ``q`` (scaled), ``k`` ``[C, H, dk]``, ``v [C, H, dv]``, the log decay ``g
+    <= 0`` and the write strength ``beta`` ``[C, H]``, all float32; ``live
+    [C]``: the first ``n`` tokens carry the sequence (the others write
+    nothing and decay nothing).  -> (o ``[C, H, dv]``, the state after those
+    ``n``).  With ``B_i = exp(sum_{j <= i} g_j)`` and ``R_ij = B_i / B_j``:
+    the tokens' writes ``D`` solve the unit lower-triangular ``(I + L) D =
+    diag(beta) (V - diag(B) K S_in)``, ``L_ij = beta_i R_ij (k_i . k_j)`` for
+    ``j < i`` (token ``i``'s correction reads what the tokens before it
+    wrote); then ``O = diag(B) Q S_in + tril(R . Q K^T) D`` and ``S_out = B_C
+    S_in + sum_j R_Cj k_j d_j^T``.  Every decay formed is the exponential of
+    a number ``<= 0``: the mask goes on the exponent."""
+    C = q.shape[0]
+    g, beta = jnp.where(live[:, None], g, 0.0), jnp.where(live[:, None], beta, 0.0)
+    G = jnp.cumsum(g, axis=0).T                                    # [H, C]
+    i = jnp.arange(C)
+    after = i[:, None] >= i[None, :]                               # j <= i
+    R = jnp.exp(jnp.where(after, G[:, :, None] - G[:, None, :], -jnp.inf))
+    B = jnp.exp(G).T[:, :, None]                                   # [C, H, 1]
+    kk = jnp.einsum("ihd,jhd->hij", k, k, precision=HIGHEST)
+    L = jnp.where(i[:, None] > i[None, :], beta.T[:, :, None] * R * kk, 0.0)
+    rhs = beta[:, :, None] * (v - B * jnp.einsum(
+        "ihd,hde->ihe", k, s_in, precision=HIGHEST))
+    D = jax.lax.linalg.triangular_solve(
+        L, rhs.transpose(1, 0, 2), left_side=True, lower=True,
+        unit_diagonal=True)                                        # [H, C, dv]
+    A = R * jnp.einsum("ihd,jhd->hij", q, k, precision=HIGHEST)
+    o = (jnp.einsum("hij,hje->ihe", A, D, precision=HIGHEST)
+         + B * jnp.einsum("ihd,hde->ihe", q, s_in, precision=HIGHEST))
+    left = jnp.exp(G[:, -1:] - G)                                  # R_Cj  [H, C]
+    s_out = (jnp.exp(G[:, -1])[:, None, None] * s_in
+             + jnp.einsum("jhd,hje->hde", k * left.T[:, :, None], D,
+                          precision=HIGHEST))
+    return o, s_out
+
+
+def _delta_neighbours(u, c_all, slots, live, first, chunk: int):
+    """Each row's ``taps - 1`` packed rows before it, and the convolution
+    state after the step.  ``u [B, U]``; ``c_all [slots, taps - 1, U]`` the
+    layer's states, the oldest row first.  A decode row (row ``s`` is slot
+    ``s``) reads its slot's state and shifts its own row in; the prompt
+    chunk's rows read the rows before them, its first ones the state of ITS
+    slot (zero where the chunk starts at position ``first == 0``), and leave
+    the last live tokens' there.  -> (the rows before ``[B, taps - 1, U]``,
+    c_all)."""
+    n_dec, T = u.shape[0] - chunk, c_all.shape[1]
+    assert c_all.shape[0] == n_dec, "a decode row a slot"
+    before = c_all
+    grown = jnp.concatenate([c_all[:, 1:], u[:n_dec, None].astype(c_all.dtype)], 1)
+    c_all = jnp.where(live[:n_dec, None, None], grown, c_all)
+    if chunk:
+        slot = slots[n_dec]
+        kept = jax.lax.dynamic_index_in_dim(c_all, slot, 0, keepdims=False)
+        seq = jnp.concatenate([jnp.where(first == 0, 0, kept),
+                               u[n_dec:].astype(kept.dtype)])          # [T + C, U]
+        before = jnp.concatenate([before, jnp.stack(
+            [seq[j:j + chunk] for j in range(T)], axis=1)])
+        # behind the chunk's ``n`` live tokens lie the rows n .. n + T - 1
+        # (a step without a chunk leaves the slot its rows name as it is)
+        n = jnp.sum(live[n_dec:])
+        c_all = jax.lax.dynamic_update_index_in_dim(c_all, jnp.where(
+            live[n_dec], jax.lax.dynamic_slice_in_dim(seq, n, T), kept), slot, 0)
+    return before, c_all
+
+
+def delta_mixer(cfg, p, h, kp, vp, held, li, step: _Step):
+    """One delta layer over the rows ``h [B, E]``: a decode row corrects the
+    state of its slot (row ``s`` is slot ``s``), writes it and reads it; the
+    prompt chunk runs :func:`delta_chunk` from the state of ITS slot, from
+    zero where it starts at position 0.  -> (output ``[B, E]``, the pages as
+    they came, held with ``delta_state`` and ``delta_conv`` moved on).
+    Between the projections and the output gate everything is float32."""
+    from deepspeed_tpu.ops.pallas.delta_rule import delta_state_update
+    positions, live, slots, _, _, _, chunk, dt, _, _ = step
+    B = h.shape[0]
+    H, dk, dv = cfg.delta_heads, cfg.delta_key_dim, cfg.delta_value_dim
+    n_dec = B - chunk
+    first = positions[n_dec] if chunk else None
+    f32 = lambda name: p[name].astype(jnp.float32)
+    u, z, ba = _rows_that_carry(
+        lambda r: tuple(r @ gpt._wget(p, name, dt) for name in ("qkv_w", "gate_w", "ba_w")),
+        (h,), chunk, live)
+    with jax.named_scope("delta_conv"):
+        c_all = jax.lax.dynamic_index_in_dim(held["delta_conv"], li, 0, keepdims=False)
+        before, c_all = _delta_neighbours(u, c_all, slots, live, first, chunk)
+        conv = jax.lax.dynamic_update_index_in_dim(held["delta_conv"], c_all, li, 0)
+        w = f32("conv_w")
+        c = jax.nn.silu(jnp.einsum("btu,tu->bu", before.astype(jnp.float32), w[:-1])
+                        + w[-1] * u.astype(jnp.float32))
+        unit = lambda t: t * jax.lax.rsqrt(
+            jnp.sum(jnp.square(t), axis=-1, keepdims=True) + 1e-6)
+        q = unit(c[:, :H * dk].reshape(B, H, dk)) / math.sqrt(dk)
+        k = unit(c[:, H * dk:2 * H * dk].reshape(B, H, dk))
+        v = c[:, 2 * H * dk:].reshape(B, H, dv)
+        ba = ba.astype(jnp.float32)
+        beta = (2.0 if cfg.delta_neg_eigval else 1.0) * jax.nn.sigmoid(ba[:, :H])
+        g = -jnp.exp(f32("a_log")) * jax.nn.softplus(ba[:, H:] + f32("dt_bias"))
+    with jax.named_scope("delta_update"):
+        state, o = delta_state_update(
+            held["delta_state"], li, q[:n_dec], k[:n_dec], v[:n_dec],
+            jnp.exp(g[:n_dec]), beta[:n_dec], live[:n_dec])
+        if chunk:
+            slot = slots[n_dec]
+            at = (li, slot, 0, 0)
+            kept = jax.lax.dynamic_slice(state, at, (1, 1) + state.shape[2:])
+
+            def over_the_chunk():
+                s_in = kept.reshape(dk, H, dv).transpose(1, 0, 2)
+                oc, s_out = delta_chunk(q[n_dec:], k[n_dec:], v[n_dec:], g[n_dec:],
+                                        beta[n_dec:], jnp.where(first == 0, 0.0, s_in),
+                                        live[n_dec:])
+                return oc, s_out.transpose(1, 0, 2).reshape(kept.shape)
+
+            # a step without a chunk (three of four here) skips the solve,
+            # and leaves the slot its rows name (slot 0) as it is
+            oc, s_out = jax.lax.cond(
+                live[n_dec], over_the_chunk,
+                lambda: (jnp.zeros((chunk, H, dv), jnp.float32), kept))
+            state = jax.lax.dynamic_update_slice(state, s_out, at)
+            o = jnp.concatenate([o, oc])
+    # the norm a head (ONE gain for all heads), then the gate
+    y = gpt.rms_norm(o, f32("onorm_g"), eps=cfg.ln_eps).reshape(B, H * dv)
+    y = (y * jax.nn.silu(z.astype(jnp.float32))).astype(dt)
+    out = _rows_that_carry(lambda r: r @ gpt._wget(p, "out_w", dt), (y,), chunk, live)
+    return out, kp, vp, dict(held, delta_state=state, delta_conv=conv)
+
+
+# --------------------------------------------------------------------------- #
+# The full mixer
+# --------------------------------------------------------------------------- #
+def full_mixer(cfg, p, h, kp, vp, held, li, step: _Step):
+    """One layer of plain softmax attention over its own token's K and V, the
+    pages its own: ``models/gpt.py:_project_qkv`` (the fused projection, the
+    q/k norm over all lanes, rope where the layer's kind ropes) and the page
+    group's plan, as ``gpt_paged_step`` calls them.  -> (output ``[B, E]``,
+    kp, vp with the rows' K and V written, held as it came)."""
+    (positions, live, _, tables, write_blocks, write_offsets, chunk, dt, plan,
+     tile_runs) = step
+    B = h.shape[0]
+    kind = next(kind for kind in cfg.pattern if kind.mixer == "full")
+    q, k, v = _rows_that_carry(
+        lambda r, t: gpt._project_qkv(cfg, p, r[:, None], dt, t[:, None], kind),
+        (h, positions), chunk, live)
+    kp = kp.at[li, write_blocks, write_offsets].set(k.astype(kp.dtype).reshape(B, 1, -1))
+    vp = vp.at[li, write_blocks, write_offsets].set(v.astype(vp.dtype).reshape(B, 1, -1))
+    o = plan.attend(q, (kp, vp), li, tables, positions, chunk=chunk,
+                    tile_runs=tile_runs).reshape(B, cfg.attn_dim)
+    o = _rows_that_carry(lambda r: r @ gpt._wget(p, "out_w", dt), (o,), chunk, live)
+    return o, kp, vp, held
+
+
+# --------------------------------------------------------------------------- #
 # The feed-forwards
 # --------------------------------------------------------------------------- #
 def mlp_ffn(cfg, p, bank, li, x, stream, step: _Step):
-    """The dense SwiGLU over the normed residual: -> (its output, the
+    """The dense SwiGLU over the normed residual, or with ``cfg.norm_after``
+    over the residual as it is and normed on its way out: -> (its output, the
     routers' stream as it came, no expert counts)."""
     live, chunk, dt = step.live, step.chunk, step.dt
-    mlp = lambda r: gpt._mlp(cfg, p, gpt.rms_norm(r, p["ln2_g"], eps=cfg.ln_eps), dt)
+    norm = lambda r: gpt.rms_norm(r, p["ln2_g"], eps=cfg.ln_eps)
+    if cfg.norm_after:
+        mlp = lambda r: norm(gpt._mlp(cfg, p, r, dt))
+    else:
+        mlp = lambda r: gpt._mlp(cfg, p, norm(r), dt)
     return _rows_that_carry(mlp, (x,), chunk, live), stream, None
 
 
@@ -697,7 +914,9 @@ def moe_ffn(cfg, p, bank, li, x, stream, step: _Step):
 # mixers' stacks in ``init_blocks``
 MIXERS = {"sparse": (sparse_mixer, lambda: jax.named_scope("attn_sparse")),
           "linear": (linear_mixer, lambda: jax.named_scope("attn_linear")),
-          "cca": (cca_mixer, lambda: jax.named_scope("attn_cca"))}
+          "cca": (cca_mixer, lambda: jax.named_scope("attn_cca")),
+          "delta": (delta_mixer, lambda: jax.named_scope("attn_delta")),
+          "full": (full_mixer, lambda: jax.named_scope("attn_full"))}
 FEED_FORWARDS = {"mlp": mlp_ffn, "moe": moe_ffn}
 
 
@@ -713,7 +932,9 @@ def hybrid_paged_step(cfg, params: Dict, input_ids: Array, positions: Array,
     that of the layers that cache K and V (``cfg.arena_layout``),
     ``block_tables`` and ``write_blocks`` one group's; ``aux`` is
     :func:`init_aux`'s, ``slots [B]`` the slot a row's sequence holds and
-    ``live [B]`` whether it carries one.  The walk's carry is the residual,
+    ``live [B]`` whether it carries one.  A layer is ``x + f(norm(x))`` twice
+    or, with ``cfg.norm_after``, ``x + norm(f(x))``.  The walk's carry is the
+    residual,
     the routers' stream (zero before the first layer; None in a stack
     without expert layers) and the caches.  -> (logits ``[B, 1, V]`` float32,
     k_pages, v_pages, aux) and with ``with_expert_counts`` the live rows'
@@ -739,10 +960,16 @@ def hybrid_paged_step(cfg, params: Dict, input_ids: Array, positions: Array,
         x, stream, kp, vp, held = carry
         p = _LayerLeaves(blocks[mixer], i)
         mix, scope = MIXERS[mixer]
+        norm = lambda t: gpt.rms_norm(t, p["ln1_g"], eps=cfg.ln_eps)
         with jax.named_scope("attn"):
-            h = gpt.rms_norm(x, p["ln1_g"], eps=cfg.ln_eps)
+            # where the norm sits is the configuration's: on the mixer's
+            # input, or (``norm_after``) on its output, the mixer reading the
+            # residual as it is
+            h = x if cfg.norm_after else norm(x)
             with scope():
                 o, kp, vp, held = mix(cfg, p, h, kp, vp, held, i, step)
+            if cfg.norm_after:
+                o = norm(o)
         with jax.named_scope("mlp"):
             x = x + rs * o
             y, stream, counts = FEED_FORWARDS[ffn_of(cfg, mixer)](

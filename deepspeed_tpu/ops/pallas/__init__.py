@@ -34,8 +34,9 @@ def use_kernel(name: str) -> bool:
     kernel's own shape gate and sharding rule decide after it.  ``name`` is
     the kernel's (``ce``, ``fused_adam``, ``flash_attention``,
     ``decode_attention``, ``paged_attention``, ``paged_gqa_attention``,
-    ``paged_mla_attention``, ``paged_sparse_attention``, ``grouped_matmul``) and is
-    not read here: a test's replacement answers for one kernel by it."""
+    ``paged_mla_attention``, ``paged_sparse_attention``, ``grouped_matmul``,
+    ``delta_state_update``) and is not read here: a test's replacement
+    answers for one kernel by it."""
     del name
     return platform() == "tpu"
 

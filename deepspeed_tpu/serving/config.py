@@ -67,6 +67,14 @@ class DeepSpeedServingConfig(DeepSpeedConfigModel):
     queue_age_watermark_ms: float = 0.0
     brownout_max_new_tokens: int = 0  # brownout cap on max_new_tokens; 0 = off
     shed_recovery_steps: int = 16     # calm step evaluations per rung down
+    # ---- the host's wait for a step ---------------------------------------- #
+    # the thread that dispatched a step POLLS for its token row and does not
+    # sleep on it.  A host that sleeps 30 of every 32 ms is woken late and
+    # makes its turn-round on a core that was clocked down meanwhile: on a
+    # shared machine that is +1.8 ms a step in one run of six (PERF.md § 6,
+    # PR 47).  Costs a core; the GIL is let go every turn of the loop.  A
+    # step under `serve_step_timeout_s` sleeps as before.
+    poll_token_row: bool = False
     # ---- numerics / misc ------------------------------------------------- #
     dtype: str = "bfloat16"
     seed: int = 0

@@ -456,7 +456,8 @@ class ServingEngine:
                                      cfg.prefill_chunk)
         self._tables = self._empty_tables()
         # what a hybrid stack caches beside K and V (compressed keys, the
-        # linear layers' states): donated state of the step like the arena
+        # linear and the delta layers' states, the convolutions' last rows):
+        # donated state of the step like the arena
         self._aux = self._new_aux()
 
         # ---- tiered spill/restage + prefix sharing (both opt-in) ---------- #
@@ -593,15 +594,21 @@ class ServingEngine:
         positions (``rows``: the upload's ``[rows, 4]`` view): slots whose
         state started from zero (a chunk at position 0) and bytes of state
         held (``state_bytes`` the linear layers', ``cca_state_bytes`` the cca
-        layers'); of the sparse layers, keys the live rows attended against
+        layers', ``delta_state_bytes`` and ``delta_conv_bytes`` the delta
+        layers'); ``delta_state_moves``: the delta layers' states read and
+        written, one a live decode row a layer and one a layer for the
+        step's chunk; of the sparse layers, keys the live rows attended against
         the keys resident before them, summed over sparse layers and K/V
         heads, and live rows at or under ``dense_len``."""
         from deepspeed_tpu.models import hybrid
         mcfg = self.module.cfg
         first = rows[self._config.max_batch_size]
         out = {"state_slots_reset": int(first[3] != 0 and first[1] == 0)}
-        out.update({name + "_bytes": int(a.nbytes)
-                    for name, a in self._aux.items() if name.endswith("state")})
+        out.update({name + "_bytes": int(a.nbytes) for name, a in self._aux.items()
+                    if name.endswith(("state", "delta_conv"))})
+        if "delta" in mcfg.mixers:
+            decoding = int((rows[:self._config.max_batch_size, 3] != 0).sum())
+            out["delta_state_moves"] = (decoding + int(first[3] != 0)) * mcfg.mixers.count("delta")
         if "sparse" in mcfg.mixers:
             t = rows[rows[:, 3] != 0, 1]
             per = mcfg.mixers.count("sparse") * mcfg.kv_heads
@@ -731,6 +738,12 @@ class ServingEngine:
                     self._aux)
                 t_launch = self._clock()
             with self._span(f"serve.{phase}.fetch", **stats):
+                # (inline dispatch alone: under a deadline a wedged step
+                # would leave the abandoned worker spinning for good)
+                if self._config.poll_token_row and self._bounded is None:
+                    tokens.copy_to_host_async()
+                    while not tokens.is_ready():
+                        time.sleep(0)
                 row = np.asarray(tokens).reshape(-1)
                 return row, kp, vp, state, aux, t_launch, self._clock()
         if self._bounded is None or not self._warm:

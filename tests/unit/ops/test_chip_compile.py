@@ -724,3 +724,91 @@ def test_grouped_matmul_compiles_at_mistral4_held_bank(chip, K, N, stacked):
     from deepspeed_tpu.ops.pallas import grouped_matmul as gm
     assert gm.rows_to_whole_tiles(2048, K, BF16) == 0
     _bank_matmul_compiles(chip, 2048, 32, K, N, stacked)
+
+
+def test_paged_gqa_kernel_compiles_at_olmo_hybrid_heads(chip):
+    """Olmo-Hybrid-7B's full layers as its serve cell runs them: 30 query
+    heads on 30 K/V heads of D=128 (a group of ONE: neither a power of two
+    nor OLMoE's 16), pages of 16 tokens of 3,840 lanes, the arena of four
+    layers WHOLE with the layer a scalar, tables 128 blocks wide; 80 slots
+    at ``Sq = 1`` and the chunk of 176 packed.  ``softmax_plan`` picks
+    ``paged_gqa_attention`` as for OLMoE, and its tables grow in runs."""
+    H, D128, BS, slots, chunk, MB = 30, 128, 16, 80, 176, 128
+    rows = slots + chunk
+    assert da.gqa_kernel_shape_ok(H, H, D128, BS, BF16)
+    plan = da.softmax_plan(H, H, D128, BS, MB, chunk, BF16)
+    assert plan.kernel == "paged_gqa_attention" and plan.run_pages > 0
+    assert chunk % plan.chunk_queries == 0
+    arena = ((4, 4096, BS, H * D128), BF16)
+    fn = lambda q, k, v, layer, tables, lengths: da.paged_layer_attention(
+        q, k, v, layer, tables, lengths, chunk=chunk)
+    text = _compiled_text(chip, fn, ((rows, 1, H, D128), BF16), arena, arena,
+                          ((), jnp.int32), ((rows, MB), jnp.int32), ((rows,), jnp.int32))
+    assert _kernel_rows(text, "paged_gqa_attention") == [chunk // plan.chunk_queries, slots]
+    assert "dynamic-slice" not in text        # no layer of K and V sliced out
+
+
+def _state_calls(text):
+    """(layers, slots) of the stacked states each call of the kernel
+    ``delta_state_update`` takes and gives back."""
+    return [(int(a), int(b)) for a, b in re.findall(
+        r"%delta_state_update[.\d]* = \(f32\[(\d+),(\d+),96,5760\][^\n]*tpu_custom_call", text)]
+
+
+def test_the_delta_state_kernel_compiles_at_olmo_hybrid_states(chip):
+    """The decode rows' state update at the published widths: 80 slots of 30
+    heads of ``96 x 192`` float32, kept ``[12, 80, 96, 5760]`` (no lane of it
+    padding: a head's own ``[96, 192]`` tile would pad 192 lanes to 256),
+    the stack WHOLE with the layer a scalar and updated in place."""
+    from deepspeed_tpu.ops.pallas import delta_rule
+    L, n, H, dk, dv = 12, 80, 30, 96, 192
+    assert delta_rule.kernel_shape_ok(H, dk, dv, jnp.float32)
+    f32, sd = jnp.float32, lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=chip)
+    compiled = jax.jit(delta_rule.delta_state_update, donate_argnums=0).lower(
+        sd((L, n, dk, H * dv), f32), sd((), jnp.int32), sd((n, H, dk), f32),
+        sd((n, H, dk), f32), sd((n, H, dv), f32), sd((n, H), f32), sd((n, H), f32),
+        sd((n,), jnp.bool_)).compile()
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    assert _state_calls(text) == [(L, n)]                        # the stack, in place
+    state = L * n * dk * H * dv * 4
+    assert memory.alias_size_in_bytes >= state and memory.temp_size_in_bytes < 64 << 20
+    # the states as they are held: not a byte of padding
+    assert memory.argument_size_in_bytes - state < 16 << 20
+
+
+def test_the_olmo_hybrid_step_updates_its_states_in_place(chip):
+    """The whole step of one period (L L L F) of Olmo-Hybrid-7B at the
+    published widths, 80 slots and a chunk of 176: the delta layers' states
+    go through the kernel on the stacked array (no layer's 177 MB sliced or
+    copied out), the full layer's attention is the paged GQA kernel at two
+    shapes, and the chunked form with its solve sits under the branch a step
+    without a prompt chunk takes the other side of."""
+    from deepspeed_tpu.models import gpt, hybrid
+    from deepspeed_tpu.serving.kv_cache import init_arena
+    cfg = gpt.olmo_hybrid_config(
+        layer_types=3 * ["linear_attention"] + ["full_attention"], dtype=BF16)
+    model, slots, chunk, BS, NB, MB = gpt.GPT(cfg), 80, 176, 16, 1025, 128
+    rows = slots + chunk
+    shape = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=chip)
+    on_chip = lambda tree: jax.tree.map(lambda a: shape(a.shape, a.dtype), tree)
+    params = jax.tree.map(lambda p: shape(p.shape, BF16),
+                          jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
+    kp, vp = on_chip(jax.eval_shape(lambda: init_arena(cfg, NB, BS, dtype=BF16)))
+    aux = on_chip(jax.eval_shape(lambda: hybrid.init_aux(cfg, NB, BS, slots, BF16)))
+    assert aux["delta_state"].shape == (3, slots, 96, 5760) and aux["delta_state"].dtype == jnp.float32
+    assert aux["delta_conv"].shape == (3, slots, 3, 11520)
+    step = lambda p, ids, pos, kp, vp, tb, wb, wo, aux, sl, live: model.paged_step(
+        p, ids, pos, kp, vp, tb, wb, wo, chunk=chunk, aux=aux, slots=sl, live=live)
+    compiled = jax.jit(step, donate_argnums=(3, 4, 8)).lower(
+        params, shape((rows, 1), jnp.int32), shape((rows,), jnp.int32), kp, vp,
+        shape((rows, MB), jnp.int32), shape((rows, 1), jnp.int32),
+        shape((rows, 1), jnp.int32), aux, shape((rows,), jnp.int32),
+        shape((rows,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    plan = da.softmax_plan(30, 30, 128, BS, MB, chunk, BF16)
+    assert _kernel_rows(text, "paged_gqa_attention") == [chunk // plan.chunk_queries, slots]
+    assert _state_calls(text) == [(3, slots)]
+    assert text.count("conditional(") >= 2
+    # nothing as large as a layer's states is made beside them
+    assert not re.search(rf"f32\[{slots},96,5760\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30

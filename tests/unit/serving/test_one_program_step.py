@@ -136,6 +136,24 @@ def test_mixed_traffic_is_token_identical_in_one_program(model_and_params, traff
     eng.close()
 
 
+def test_a_host_that_polls_for_the_token_row_serves_the_same_tokens(model_and_params):
+    """``poll_token_row``: the dispatching thread spins on the row's readiness
+    and then reads it; the tokens, the stamps and the one program are those
+    of a host that sleeps on it."""
+    model, params = model_and_params
+    eng = engine(model_and_params, poll_token_row=True)
+    prompts, new = prompts_of(9, (5, 19, 8)), (10, 6, 12)
+    futs = [eng.submit(p, max_new_tokens=m) for p, m in zip(prompts, new)]
+    waits = []
+    while eng.sched.has_work:
+        waits.append(eng.step().get("result_wait_ms"))
+    for p, m, f in zip(prompts, new, futs):
+        assert f.done and f.token_ids == reference(model, params, p, m)
+    assert all(w is not None and w >= 0 for w in waits[1:])
+    assert eng.compiled_programs() == 1
+    eng.close()
+
+
 # ---- the step itself ------------------------------------------------------------ #
 def test_the_program_has_one_shape_whatever_the_step_holds(model_and_params,
                                                            monkeypatch):

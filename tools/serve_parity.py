@@ -3,14 +3,15 @@ reference, at the published widths: the chip comparison of the
 ``model-configs`` guide § 3 point 3, for any configuration file that names a
 ``serve`` block and a ``reference`` (``benchmarks/configs/olmoe-1b-7b.json``,
 ``smallthinker-21b-a3b.json``, ``mistral-small-4-119b.json``,
-``minicpm-sala-9b.json``, ``zaya1-8b.json``).
+``minicpm-sala-9b.json``, ``zaya1-8b.json``, ``olmo-hybrid-7b.json``).
 
 Run standalone on a TPU host (``chiprun --chips 1 -- python
 tools/serve_parity.py benchmarks/configs/smallthinker-21b-a3b.json``); any
 other platform is an error (exit 1).  Seeded bf16 weights; a seeded sample of
 prompts (one of them longer than the model's window, where it has one, than
-the original positions of its YaRN rope, or than the ``dense_len`` under
-which its sparse layers attend every key) goes
+the original positions of its YaRN rope, than the ``dense_len`` under
+which its sparse layers attend every key, or than two prompt chunks where
+its delta layers' chunked form must enter with a state) goes
 through ``init_serving()`` / ``submit().result()`` with the file's slots and
 chunk (prefill in chunks, then decode, on the program's kernels), and each
 served sequence through the file's reference in one full float32 forward pass:
@@ -161,6 +162,8 @@ def main(argv=None) -> int:
         lengths.append(cfg.rope_yarn.original_positions + 100)      # original range
     if "sparse" in cfg.mixers:                  # and past where every key is attended
         lengths.append(cfg.sparse.dense_len + 200)
+    if "delta" in cfg.mixers:                   # and a third chunk entered with both states
+        lengths.append(2 * config["serve"]["serving"]["prefill_chunk"] + 77)
     prompts = [rng.integers(0, cfg.vocab_size, n).tolist() for n in lengths]
     futures = [eng.submit(p, max_new_tokens=args.new) for p in prompts]
     served = [f.result() for f in futures]
