@@ -332,7 +332,8 @@ def serve_phase(seed):
     # packed, several queries a row
     kernels = kernels_in(engine._step_fn.lower(
         engine.params, jnp.zeros((engine._layout.packed_size,), jnp.int32),
-        engine._k_pages, engine._v_pages, engine._tables).compile())
+        engine._previous, engine._k_pages, engine._v_pages,
+        engine._tables).compile())
     H, D = cfg.n_head, cfg.head_dim
     paged_ok = kernel_shape_ok(H, cfg.kv_heads, D, SERVE["block_size"], jnp.bfloat16)
     check(bool(kernels.get("paged_attention")) == paged_ok,

@@ -42,6 +42,17 @@ are token-identical to sequential ``generate()``, even across
 preempt→evict→recompute cycles, because recompute re-prefills a prefix of
 the identical deterministic stream.
 
+A step is not always host, device, host.  The program takes the token array
+of the program before it as an input, ON THE DEVICE, and a row whose token
+the host has not seen yet says which entry of that array it is
+(:func:`unpack_step`), so the host can launch program n+1 while the chip runs
+program n and fetch n's token row behind the launch: everything but the
+token VALUES (positions, slots, pages, the next prompt chunk, who ends by
+``max_new_tokens``) it knows when it launches.  At most one program is ahead,
+and only while no arrival could have changed the next program anyway
+(:meth:`ServingEngine._stays_ahead`); whatever is unusual lands the row in
+flight first (:meth:`ServingEngine._drain`).
+
 Decoding is greedy (the sampler the sequential path uses at
 ``temperature=0``, including the padded-vocab mask); sampled decoding is
 future work and is rejected at ``submit()``.
@@ -71,22 +82,32 @@ from deepspeed_tpu.utils.logging import log_dist
 
 
 #: The leaf spans that tile ``ServingEngine.step()``, in the order the work
-#: happens (PERF.md § 3 copies this list).  On the profiler's line they are
-#: SIBLINGS under the caller's own span, with no ``serve.step`` round them:
-#: an idle gap of the device is then named by the phase the host was in.
-#: A step runs ONE program, so it opens one ``dispatch`` and one ``fetch``:
-#: the ``serve.decode.*`` pair when the program carries a decode row (stat
+#: happens in a step that is NOT dispatched ahead (PERF.md § 3 copies this
+#: list).  On the profiler's line they are SIBLINGS under the caller's own
+#: span, with no ``serve.step`` round them: an idle gap of the device is then
+#: named by the phase the host was in.
+#: A step launches at most ONE program, so it opens one ``dispatch``: the
+#: ``serve.decode.*`` one when the program carries a decode row (stat
 #: ``batch``: every live row, chunk tokens included), the ``serve.prefill.*``
-#: pair when it carries a prompt chunk alone; both have ``chunk_tokens``.
+#: one when it carries a prompt chunk alone; both have ``chunk_tokens``.  A
+#: ``fetch`` and the ``commit`` spans behind it belong to the program whose
+#: row they bring in, under that program's names and stats.  In a step
+#: dispatched ahead (:meth:`ServingEngine._stays_ahead`) they are the program
+#: BEFORE this step's, and they open behind this step's ``dispatch``: admit,
+#: grow, build, dispatch (n+1), fetch (n), commit (n), stats.  The step in
+#: which the engine stops being ahead opens the pair twice (n, then n+1); the
+#: step in which it starts opens none.
 #: ``submit()`` is ``serve.submit`` (stat ``rid``); a request's first token
 #: leaves one zero-length ``serve.first_token`` with its waits as stats.
 #: ``serve.stats`` carries what the step's tables and attention cost
 #: (``table_edits``, ``table_reloads``, ``upload_bytes``, ``tile_runs_pct``,
-#: ``chunk_queries_per_row``, ``attention_rows``) and, in a step that follows
-#: a step with a program, the step's turn-round as DURATIONS on the engine's
-#: one clock (:data:`TURNAROUND_STATS`): they need no alignment with any
-#: other line of a trace, and they are on the main thread's line whatever
-#: thread ran the dispatch.
+#: ``chunk_queries_per_row``, ``attention_rows``), ``dispatched_ahead`` (1:
+#: this step's program was launched before the row of the program before it
+#: was on the host) and, in a step that follows a step with a program, the
+#: step's turn-round as DURATIONS on the engine's one clock
+#: (:data:`TURNAROUND_STATS`): they need no alignment with any other line of
+#: a trace, and they are on the main thread's line whatever thread ran the
+#: fetch.
 SERVE_STEP_SPANS = (
     "serve.admit",              # deadlines, shed ladder, sched.admit
     "serve.grow",               # sort, ensure_capacity, decode_batch
@@ -96,7 +117,7 @@ SERVE_STEP_SPANS = (
     "serve.prefill.fetch",      # the token row back on the host
     "serve.decode.dispatch",
     "serve.decode.fetch",
-    "serve.prefill.commit",     # prefilled, prefix insert, first token
+    "serve.prefill.commit",     # the prompt's last chunk: the first token
     "serve.decode.commit",
     "serve.stats",              # ledger, stats dict, gauges, emit
 )
@@ -105,19 +126,29 @@ SERVE_STEP_SPANS = (
 #: ``serve.stats``, from four stamps of ``ServingEngine._clock``: ``t_enter``
 #: and ``t_exit`` at ``step()``'s first and last line, ``t_launch`` when the
 #: call of the step program has returned (upload made, program enqueued) and
-#: ``t_result`` when its token row is on the host.  For the step that ran
-#: program n after program n-1:
+#: ``t_result`` when a token row is on the host.  The host is SETTLED at the
+#: last of the two it took: nothing of the chip's is left for it to wait for
+#: or to hand over.  For the step that launched program n, ``settled`` being
+#: when the step before it was last so:
 #:
 #: * ``turnaround_ms`` = ``t_launch(n) - t_result(n-1)``: the chip has no
-#:   program of this engine; the sum of the next three;
-#: * ``commit_ms`` = ``t_exit(n-1) - t_result(n-1)``: commit and stats of the
-#:   step before;
-#: * ``outside_ms`` = ``t_enter(n) - t_exit(n-1)``: the caller, between two
+#:   program of this engine; the sum of the next three.  **0.0 in a step
+#:   dispatched ahead**: program n-1 was still the chip's when n was launched,
+#:   so there is no such stretch, and the next three are host time the chip
+#:   did not wait for;
+#: * ``commit_ms`` = ``t_exit(before) - settled``: commit and stats of the
+#:   step before (from its last row; from its launch, where it fetched none);
+#: * ``outside_ms`` = ``t_enter - t_exit(before)``: the caller, between two
 #:   ``step()`` calls;
-#: * ``prepare_ms`` = ``t_launch(n) - t_enter(n)``: admit, grow, build,
-#:   upload, launch;
-#: * ``result_wait_ms`` = ``t_result(n) - t_launch(n)``: the host waiting; it
-#:   holds the program's device time and both wires.
+#: * ``prepare_ms`` = ``t_launch(n) - t_enter``: admit, grow, build, upload,
+#:   launch;
+#: * ``result_wait_ms`` = the last ``t_result`` of this step ``- t_launch(n)``:
+#:   the host waiting behind its launch.  Not ahead, the row is n's own: the
+#:   program's device time and both wires.  Ahead, it is n-1's: what was left
+#:   of that program, about a device step less the host's three parts (a
+#:   launch that waits for the program before it shows here as nothing left
+#:   to wait for, and in ``prepare_ms``).  0.0 where the step fetched no row
+#:   behind its launch (the first step ahead).
 #:
 #: The first step, a step after one that ran no program or left the engine
 #: with no request, and the first step after an incident's re-jit carry none:
@@ -165,17 +196,23 @@ class StepLayout(NamedTuple):
         return 4 * self.rows + self.slots + 2 * self.edits
 
 
-def unpack_step(lay: StepLayout, packed, state):
-    """The traced head of the step: one upload and the table state ->
+def unpack_step(lay: StepLayout, packed, previous, state):
+    """The traced head of the step: one upload, the token array of the
+    program before and the table state ->
     ``(ids, positions, state, tables, write_blocks, write_offsets)``, the
     state with this step's edits in it and the rest as
-    ``model.paged_step`` takes them.  A row that is not live reads and
+    ``model.paged_step`` takes them.  A row's token ``-(1 + i)`` stands for
+    ``previous[i]``: the token the program before made in its row ``i``,
+    which the host had not seen when it packed this one (``i``: the row's own
+    slot where the sequence decoded there, ``slots + n_chunk - 1`` where its
+    prompt ended in that program's chunk).  A row that is not live reads and
     writes the trash block through an all-trash table."""
     import jax.numpy as jnp
     TRASH = PagedKVAllocator.TRASH
     R, S = lay.rows, lay.slots
     rows = packed[:4 * R].reshape(R, 4)
     ids, positions, slot = rows[:, 0:1], rows[:, 1], rows[:, 2]
+    ids = jnp.where(ids < 0, previous.reshape(-1)[jnp.maximum(-1 - ids, 0)], ids)
     live = rows[:, 3:4] != 0
     cleared = packed[4 * R:4 * R + S, None] != 0
     edits = packed[4 * R + S:].reshape(lay.edits, 2)
@@ -210,6 +247,20 @@ class ServeStepTimeout(RuntimeError):
         self.step = step
 
 
+class _Flight(NamedTuple):
+    """A program that is launched and whose token row is not committed yet:
+    what :meth:`ServingEngine._land` needs to fetch the row and commit it, and
+    what the program behind it needs to take its tokens on the device."""
+    tokens: Any                 # the device array: a token a row, then an MoE
+                                # model's expert counts
+    phase: str                  # names its dispatch/fetch pair and fault point
+    at: Dict[str, Any]          # that pair's span stats
+    decode: List[Tuple[Request, int]]       # each decode row's (request, slot)
+    chunk: Optional[Tuple[Request, int, bool, Dict[str, int]]]  # (request,
+                                # slot, the prompt's last?, rid/start/tokens)
+    feeds: Dict[int, int]       # rid -> the row whose token is its next input
+
+
 class ServeFuture:
     """Handle for one submitted request.  ``result()`` drives the engine's
     step loop until this request finishes (single-threaded serving — there
@@ -236,8 +287,9 @@ class ServeFuture:
         the caller inside one step forever).  Raises
         :class:`DeadlineExceeded` if the request's own SLO deadline
         cancelled it."""
+        eng = self._engine
         deadline = (None if timeout_s is None
-                    else self._engine._clock() + float(timeout_s))
+                    else eng._clock() + float(timeout_s))
         for _ in range(max_steps):
             if self.done:
                 return self.token_ids
@@ -245,12 +297,15 @@ class ServeFuture:
                 raise DeadlineExceeded(
                     f"request {self.request.rid} missed its "
                     f"{self.request.slo!r}-class deadline and was cancelled")
-            if deadline is not None and self._engine._clock() >= deadline:
+            if deadline is not None and eng._clock() >= deadline:
                 raise TimeoutError(
                     f"request {self.request.rid} unfinished after "
                     f"{timeout_s}s")
             try:
-                self._engine.step()
+                if eng._ends_in_flight(self.request):
+                    eng._drain()    # its last token is on its way: no launch
+                else:               # stands between the caller and the row
+                    eng.step()
             except ServeStepTimeout:
                 # the engine already recovered (state requeued for
                 # recompute); keep driving under the same bounds
@@ -480,9 +535,9 @@ class ServingEngine:
         # ---- the (single) jitted step ------------------------------------ #
         layout = self._layout
 
-        def step_fn(params, packed, kp, vp, state, aux=None):
+        def step_fn(params, packed, previous, kp, vp, state, aux=None):
             ids, positions, state, tables, wb, wo = unpack_step(
-                layout, packed, state)
+                layout, packed, previous, state)
             more = {"with_expert_counts": True} if self._moe_experts else {}
             if aux is not None:
                 # a hybrid stack's step takes its state, and each row's slot
@@ -497,45 +552,52 @@ class ServingEngine:
             if mcfg.padded_vocab != mcfg.vocab_size:
                 vmask = jnp.arange(mcfg.padded_vocab) < mcfg.vocab_size
                 logits = jnp.where(vmask[None, None], logits, -1e30)
-            tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            if counts:
-                # an MoE model's expert counts ride behind the token row in
-                # the one int32 array the host fetches: no second transfer
-                tokens = jnp.concatenate(
-                    [tokens.reshape(-1), *(c.reshape(-1) for c in counts)])
+            # an MoE model's expert counts ride behind the token row in the
+            # one flat int32 array the host fetches (no second transfer), and
+            # that the next program takes as its ``previous``
+            tokens = jnp.concatenate(
+                [jnp.argmax(logits, axis=-1).astype(jnp.int32).reshape(-1),
+                 *(c.reshape(-1) for c in counts)])
             return tokens, kp, vp, state, aux
 
         # arena and table donation = in-place update; CPU can't donate (jax
-        # warns and copies), so only donate on real accelerators
-        donate = (2, 3, 4) + (5,) * self._hybrid
+        # warns and copies), so only donate on real accelerators.  The token
+        # array is never donated: the host fetches it behind the next launch
+        donate = (3, 4, 5) + (6,) * self._hybrid
         if jax.default_backend() == "cpu":
             donate = ()
         self._raw_step_fn = step_fn
         self._donate = donate
         self._step_fn = jax.jit(step_fn, donate_argnums=donate)
 
+        # the program launched and not committed (at most one between two
+        # ``step()`` calls), the token array of the last program launched
+        # (zeros before the first: no row can ask for one), and how many steps
+        # launched theirs before the row of the one before was on the host
+        self._flight: Optional[_Flight] = None
+        self._previous = self._no_tokens()
+        self.steps_dispatched_ahead = 0
+
         # ---- resilience plane -------------------------------------------- #
         self._clock = time.monotonic
-        # the last program's ``t_result`` (None: the step before left no
-        # work, or the program is not compiled yet) and the last step's ``t_exit``:
-        # what the next step's TURNAROUND_STATS are measured from
-        self._t_result: Optional[float] = None
+        # when the host was last settled, the ``t_launch`` or ``t_result`` it
+        # took last (None: the step before left no work, or the program is
+        # not compiled yet), and the last step's ``t_exit``: what the next
+        # step's TURNAROUND_STATS are measured from
+        self._t_settled: Optional[float] = None
         self._t_exit = 0.0
         self.admission = AdmissionController(cfg)
-        # bounded step dispatch: a wedged compiled program raises
-        # ServeStepTimeout instead of parking the engine thread forever.
-        # on_timeout releases fault-injection wedges so the abandoned
-        # worker drains instead of leaking (mirrors comm/recovery.py).
+        # bounded fetch: a wedged compiled program raises ServeStepTimeout
+        # instead of parking the engine thread forever.  on_timeout releases
+        # fault-injection wedges so the abandoned worker drains instead of
+        # leaking (mirrors comm/recovery.py).  The launch runs inline: it
+        # compiles, and XLA compilation is legitimate work that routinely
+        # exceeds a steady-state step deadline
         self._bounded: Optional[BoundedCollective] = None
         if cfg.serve_step_timeout_s and cfg.serve_step_timeout_s > 0.0:
             self._bounded = BoundedCollective(
                 deadline_s=float(cfg.serve_step_timeout_s),
                 on_timeout=lambda err: release_wedges())
-        # whether the program has compiled: the first dispatch runs inline
-        # (unbounded) because XLA compilation is legitimate work that
-        # routinely exceeds a steady-state step deadline — bounding it would
-        # fire a spurious incident at startup
-        self._warm = False
         self.incident_count = 0
         self.last_recovery_s = 0.0
         self._incident: Optional[Dict[str, Any]] = None  # /healthz latch
@@ -618,6 +680,14 @@ class ServingEngine:
                 sparse_rows_dense=int((t + 1 <= mcfg.sparse.dense_len).sum()))
         return out
 
+    def _no_tokens(self):
+        """What a program before the first would have handed on: the token
+        array's shape, zeros, on the device (a transfer: nothing compiles)."""
+        import jax
+        return jax.device_put(np.zeros(
+            (self._layout.rows + self._moe_experts * self._moe_count_rows,),
+            np.int32))
+
     def _empty_tables(self):
         """The table state of an engine nobody is in: all trash, on the
         device (a transfer: nothing compiles)."""
@@ -669,13 +739,20 @@ class ServingEngine:
     # ---- request lifecycle robustness --------------------------------- #
     def _expire_deadlines(self):
         """Cancel every request whose per-class deadline has passed —
-        called at the step boundary, so a cancellation never races a
-        compiled dispatch.  Frees arena blocks + staged tier copies and
+        called at the step boundary with no row of such a request in flight,
+        so a cancellation never races a compiled dispatch.  Frees arena
+        blocks + staged tier copies and
         books the accumulated prefill as wasted compute."""
         if not self._config.deadline_ms:
             return
         now = self._clock()
-        for req in self.sched.expired(now):
+        expired = self.sched.expired(now)
+        if self._flight is not None and any(r.slot >= 0 for r in expired):
+            # a request with a slot may have a row in flight: its token lands
+            # first, and a request that this finishes is not cancelled
+            self._drain()
+            expired = self.sched.expired(now)
+        for req in expired:
             wasted = req.prefilled
             self.sched.cancel(req)
             if self.ledger is not None:
@@ -709,59 +786,126 @@ class ServingEngine:
                 "queue_age_ms": age * 1000.0, "ttft_state": state,
             }, step=self.step_count)
 
-    # ---- bounded dispatch + incident recovery -------------------------- #
+    # ---- launch, bounded fetch, commit + incident recovery -------------- #
     def _dispatch(self, phase: str, packed, reload, stats):
-        """Run one compiled step over the step's one upload ``packed``
+        """Launch one compiled step over the step's one upload ``packed``
         (:meth:`_pack`; ``reload``: the tables whole, when the edits did not
-        fit it) under the
-        ``serve_step_timeout_s`` deadline (inline when unbounded), and
-        return its token row on the host with the stamps ``t_launch`` and
-        ``t_result`` (:data:`TURNAROUND_STATS`).  The host materialization of
-        the token row happens *inside* the bounded callable — that device sync
-        is exactly where a wedged program parks the thread — so the
-        ``dispatch`` and ``fetch`` spans go to the worker thread with it, and
-        the stamps are taken there and handed back.
+        fit it) and the token array of the program before, and return its own
+        token array, still on the device, with the stamp ``t_launch``
+        (:data:`TURNAROUND_STATS`).  Inline, under the ``dispatch`` span:
+        nothing here waits for the chip (the program before may still be
+        running: its outputs are this one's donated inputs), and the first
+        call, and the first after an incident's re-jit, compiles.
         ``phase`` names the span pair and the fault point: ``decode`` when
         the program carries a decode row, ``prefill`` when it carries a
-        prompt chunk alone.  The first dispatch (and the first after an
-        incident re-jit) runs inline: it compiles, and compile time is not a
-        wedge."""
+        prompt chunk alone."""
         import jax
+        with self._span(f"serve.{phase}.dispatch", **stats):
+            state = self._tables if reload is None else jax.device_put(reload)
+            (tokens, self._k_pages, self._v_pages, self._tables,
+             self._aux) = self._step_fn(
+                 self.params, packed, self._previous, self._k_pages,
+                 self._v_pages, state, self._aux)
+            self._previous = tokens
+            return tokens, self._clock()
+
+    def _fetch(self, flight: _Flight):
+        """The token row of a launched program on the host, with the stamp
+        ``t_result``, under the ``serve_step_timeout_s`` deadline (inline when
+        unbounded).  This device sync is exactly where a wedged program parks
+        the thread, so it is the bounded callable: the ``fetch`` span goes to
+        the worker thread with it, and the stamp is taken there and handed
+        back.  A fetch over its deadline raises :class:`ServeStepTimeout`
+        AFTER the in-process recovery (:meth:`_recover_incident`)."""
+        phase, tokens = flight.phase, flight.tokens
 
         def work():
-            with self._span(f"serve.{phase}.dispatch", **stats):
+            with self._span(f"serve.{phase}.fetch", **flight.at):
                 fault_point("serve.step", step=self.step_count, phase=phase)
-                state = (self._tables if reload is None
-                         else jax.device_put(reload))
-                tokens, kp, vp, state, aux = self._step_fn(
-                    self.params, packed, self._k_pages, self._v_pages, state,
-                    self._aux)
-                t_launch = self._clock()
-            with self._span(f"serve.{phase}.fetch", **stats):
-                # (inline dispatch alone: under a deadline a wedged step
+                # (inline fetch alone: under a deadline a wedged step
                 # would leave the abandoned worker spinning for good)
                 if self._config.poll_token_row and self._bounded is None:
                     tokens.copy_to_host_async()
                     while not tokens.is_ready():
                         time.sleep(0)
-                row = np.asarray(tokens).reshape(-1)
-                return row, kp, vp, state, aux, t_launch, self._clock()
-        if self._bounded is None or not self._warm:
-            out = work()
-            self._warm = True
-        else:
-            try:
-                out = self._bounded.run(work, op=phase, noun="serve step")
-            except CollectiveTimeout as e:
-                raise ServeStepTimeout(
-                    f"serve {phase} step {self.step_count} exceeded its "
-                    f"{e.deadline_s:.3f}s deadline", op=phase,
-                    deadline_s=e.deadline_s, step=self.step_count) from e
-        (row, self._k_pages, self._v_pages, self._tables, self._aux, t_launch,
-         t_result) = out
+                return np.asarray(tokens).reshape(-1), self._clock()
+        if self._bounded is None:
+            return work()
+        try:
+            return self._bounded.run(work, op=phase, noun="serve step")
+        except CollectiveTimeout as e:
+            err = ServeStepTimeout(
+                f"serve {phase} step {self.step_count} exceeded its "
+                f"{e.deadline_s:.3f}s deadline", op=phase,
+                deadline_s=e.deadline_s, step=self.step_count)
+            self._recover_incident(err)
+            raise err from e
+
+    def _land(self, flight: _Flight) -> Dict[str, float]:
+        """Fetch a launched program's token row and commit it: the chunk's
+        last row yields the first token of a request whose prompt ended in
+        it, every decode row its sequence's next.  The positions moved when
+        the program was launched (:meth:`_launched`); only the token VALUES
+        waited for the row.  A row whose request no longer holds that slot
+        (it finished on an EOS the host saw a step late) is dropped.
+        -> the row's :meth:`_moe_stats`."""
+        row, self._t_settled = self._fetch(flight)
         n = row.size - self._moe_experts * self._moe_count_rows
-        self._expert_counts = row[n:]
-        return row[:n], t_launch, t_result
+        tokens, self._expert_counts = row[:n], row[n:]
+        moe_stats = self._moe_stats()
+        holds = lambda req, slot: self.sched.active.get(slot) is req
+        if flight.chunk is not None:
+            req, slot, last, chunk = flight.chunk
+            with self._span("serve.prefill.commit", **chunk):
+                # the chunk holding the last context token also yields the
+                # next token — first-token latency includes no extra decode
+                # step
+                if last and holds(req, slot):
+                    self._append_token(req, int(
+                        tokens[self._layout.slots + chunk["tokens"] - 1]))
+        if flight.decode:
+            with self._span("serve.decode.commit", batch=len(flight.decode),
+                            **moe_stats):
+                for req, slot in flight.decode:
+                    if holds(req, slot):
+                        self._append_token(req, int(tokens[slot]))
+        return moe_stats
+
+    def _drain(self) -> Dict[str, float]:
+        """Land the row in flight, if there is one (-> its
+        :meth:`_moe_stats`, or nothing).  Whatever is unusual
+        does this FIRST and then goes on as an engine that is never ahead
+        would: a preemption, a table reload, a restage, a deadline's
+        cancellation of a request with a slot, :meth:`snapshot`,
+        :meth:`close`, the last step of :meth:`ServeFuture.result`."""
+        flight, self._flight = self._flight, None
+        return self._land(flight) if flight is not None else {}
+
+    def _ends_in_flight(self, req: Request) -> bool:
+        """Whether the row in flight holds ``req``'s last token by
+        ``max_new_tokens``: the host knows that without the row, and the
+        program behind it has no row for ``req``."""
+        return (self._flight is not None and req.rid in self._flight.feeds
+                and len(req.generated) + 1 >= req.max_new_tokens)
+
+    def _decode_ready(self) -> List[Request]:
+        """The sequences with a decode row in the next program."""
+        return [r for r in self.sched.decode_batch()
+                if not self._ends_in_flight(r)]
+
+    def _stays_ahead(self) -> bool:
+        """Whether the program just launched stays in flight when ``step()``
+        returns, so that the next one is launched before its row is fetched:
+        exactly when an arrival in the meantime could not change the next
+        program anyway.  The waiting queue is not empty, or no slot is free,
+        or an admitted request still has prompt left (``next_prefill`` gives
+        the one prompt lane to the oldest such request: a newcomer would wait
+        behind it whatever happened).  Else (lane idle, queue empty, a slot
+        free) the step is launch, fetch, commit, and whoever arrives next
+        meets an engine that has seen every token."""
+        sched = self.sched
+        return bool(sched.waiting or not sched._free_slots
+                    or any(r.needs_prefill for r in sched.active.values()))
 
     def _recover_incident(self, err: ServeStepTimeout):
         """In-process recovery from a wedged compiled step: drop the
@@ -790,8 +934,10 @@ class ServingEngine:
             self.tiering.drain()
         self._step_fn = jax.jit(self._raw_step_fn,
                                 donate_argnums=self._donate)
-        self._warm = False          # fresh jit: the first dispatch recompiles
-        self._t_result = None       # and its wait is the compiler's
+        self._t_settled = None      # fresh jit: the next launch recompiles
+        # whatever was launched is lost with the arena; its tokens are
+        # computed again with the rest of each request
+        self._flight, self._previous = None, self._no_tokens()
         self.alloc = self._new_allocator()
         self._k_pages, self._v_pages = init_arena(
             mcfg, cfg.num_blocks, cfg.block_size, dtype=self.dtype)
@@ -905,97 +1051,165 @@ class ServingEngine:
     # ------------------------------------------------------------------ #
     def step(self) -> Dict[str, Any]:
         """One engine step: expire deadlines, advance the shed ladder,
-        admit, grow the decode-ready sequences, then run ONE program over
-        every decode-ready sequence and one prompt chunk, and commit both
-        from its one token row.  A request whose prompt ends in this step's
-        chunk gets its first token here and decodes from the next step.
-        Returns the step stats.  A wedged compiled dispatch raises
-        :class:`ServeStepTimeout` *after* in-process recovery (see
-        :meth:`_recover_incident`)."""
-        t_enter = self._clock()
+        admit, grow the decode-ready sequences, then launch ONE program over
+        every decode-ready sequence and one prompt chunk.  A request whose
+        prompt ends in this step's chunk decodes from the next program on.
+
+        What the step fetches and commits depends on what the scheduler
+        observes (:meth:`_stays_ahead`), not on any setting.  With the lane
+        idle, the queue empty and a slot free it is its own program's row:
+        launch, fetch, commit, and every token is on the host when the step
+        returns.  Under a backlog the program stays in flight instead, and
+        the NEXT step launches its program first and then fetches and commits
+        this one's row, so the row's way back, the commit, the caller's time
+        between two calls and the next admit, grow, build, upload and launch
+        all pass while the chip runs: the spans open as admit, grow, build,
+        dispatch (n+1), fetch (n), commit (n), stats.  A request then holds
+        its slot and its pages until the commit of its last token, one step
+        longer; an EOS is seen one step late and the extra row's token is
+        dropped.  What does not fit this (:meth:`_drain`) lands the row in
+        flight first.  Returns the step stats (of the program this step
+        launched; ``tokens_generated`` counts what is committed).  A wedged
+        program raises :class:`ServeStepTimeout` from the fetch of its row,
+        *after* in-process recovery (see :meth:`_recover_incident`)."""
+        t_enter, settled = self._clock(), self._t_settled
         with self._span("serve.admit") as sp:
             self._expire_deadlines()
             self._update_admission()
+            if self._flight is not None and self._admit_is_unusual():
+                self._drain()
             sp.set(admitted=len(self.sched.admit(self._clock())))
         t_step = time.monotonic() if self.registry is not None else 0.0
-        n_chunk, moe_stats, turnaround = 0, {}, {}
+        n_chunk, ahead, turnaround = 0, 0, {}
         table_stats = {"table_edits": 0, "table_reloads": 0, "upload_bytes": 0}
-        try:
-            with self._span("serve.grow") as sp:
-                # growth pass, oldest/strongest first: each decode step
-                # writes one token per sequence, so capacity must exist
-                # before the batch is built; eviction here removes victims
-                # from `active`, which is why the chunk is chosen after it
-                decode = sorted(self.sched.decode_batch(),
-                                key=lambda r: (r.priority, r.admit_seq))
-                given_back = self.alloc.given_back_ever
-                for r in decode:
-                    if r.state == DECODE:      # not evicted by an earlier r
-                        self.sched.ensure_capacity(r, r.prefilled + 1)
-                # the step's prompt chunk asks too: a window group holds a
-                # long prompt a ring at a time (a table that only grows has
-                # it all since admission, and this asks for nothing)
-                pf = self.sched.next_prefill()
-                if pf is not None:
-                    self.sched.ensure_capacity(pf[0], pf[1] + pf[2])
-                decode = self.sched.decode_batch()
-                sp.set(batch=len(decode), pages_full=self.alloc.pages_full,
-                       pages_window=self.alloc.pages_window,
-                       pages_given_back=self.alloc.given_back_ever - given_back)
-            with self._span("serve.prefill.build") as sp:
-                runs = pf is not None or bool(decode)   # else: no program
-                if runs:
-                    packed, rows, reload, table_stats = self._pack()
-                if pf is not None:
-                    req, start, n_chunk = pf
-                    chunk = {"rid": req.rid, "start": start,
-                             "tokens": n_chunk}
-                    sp.set(**chunk)
-                    self._chunk_rows(rows, req, start, n_chunk)
-            if decode:
-                with self._span("serve.decode.build", batch=len(decode)):
-                    self._decode_rows(rows, decode)
-            if runs:
-                # one dispatch/fetch pair a program: named for the decode
-                # rows when it carries any (`batch`: every live row)
-                phase, at = (("decode", {"batch": len(decode) + n_chunk})
-                             if decode else ("prefill", chunk))
-                tokens, t_launch, t_result = self._dispatch(
-                    phase, packed, reload, dict(at, chunk_tokens=n_chunk))
-                if self._t_result is not None:
-                    turnaround = self._turnaround(t_enter, t_launch, t_result)
-                self._t_result = t_result
-                moe_stats = self._moe_stats()
-                if self._hybrid:
-                    table_stats.update(self._hybrid_stats(rows))
+        with self._span("serve.grow") as sp:
+            # growth pass, oldest/strongest first: each decode step
+            # writes one token per sequence, so capacity must exist
+            # before the batch is built; eviction here removes victims
+            # from `active`, which is why the chunk is chosen after it.
+            # A growth that may need a victim lands the row in flight
+            # first (a decode row grows where its token opens a block, by
+            # at most a page a group)
+            decode = sorted(self._decode_ready(),
+                            key=lambda r: (r.priority, r.admit_seq))
+            given_back = self.alloc.given_back_ever
+            for r in decode:
+                if (self._flight is not None
+                        and r.prefilled % self.alloc.block_size == 0
+                        and self.alloc.free_pages < self.alloc.n_groups):
+                    self._drain()
+                if r.state == DECODE:      # not evicted by an earlier r
+                    self.sched.ensure_capacity(r, r.prefilled + 1)
+            # the step's prompt chunk asks too: a window group holds a
+            # long prompt a ring at a time (a table that only grows has
+            # it all since admission, and this asks for nothing)
+            pf = self.sched.next_prefill()
             if pf is not None:
-                with self._span("serve.prefill.commit", **chunk):
-                    # the chunk's last token is the row that yields the next
-                    self._commit_prefill(req, n_chunk, int(
-                        tokens[self._config.max_batch_size + n_chunk - 1]))
-            if decode:
-                with self._span("serve.decode.commit", batch=len(decode),
-                                **moe_stats):
-                    for r in decode:
-                        r.prefilled += 1      # the fed token's KV is resident
-                        self._append_token(r, int(tokens[r.slot]))
-        except ServeStepTimeout as err:
-            self._recover_incident(err)
-            raise
+                if (self._flight is not None and not self.alloc.can_allocate(
+                        pf[0].rid, pf[1] + pf[2])):
+                    self._drain()
+                self.sched.ensure_capacity(pf[0], pf[1] + pf[2])
+            decode = self._decode_ready()
+            sp.set(batch=len(decode), pages_full=self.alloc.pages_full,
+                   pages_window=self.alloc.pages_window,
+                   pages_given_back=self.alloc.given_back_ever - given_back)
+        with self._span("serve.prefill.build") as sp:
+            runs = pf is not None or bool(decode)   # else: no program
+            if runs:
+                packed, rows, reload, table_stats = self._pack()
+                if reload is not None and self._flight is not None:
+                    self._drain()
+                    decode = self._decode_ready()   # less who saw an EOS
+            if pf is not None:
+                req, start, n_chunk = pf
+                chunk = {"rid": req.rid, "start": start, "tokens": n_chunk}
+                sp.set(**chunk)
+                self._chunk_rows(rows, req, start, n_chunk)
+        if decode:
+            with self._span("serve.decode.build", batch=len(decode)):
+                self._decode_rows(rows, decode)
+        # dispatched ahead: the chip still has the program before
+        idle_since = None if self._flight is not None else self._t_settled
+        if runs:
+            # one dispatch a program, and later one fetch: named for the
+            # decode rows when it carries any (`batch`: every live row)
+            phase, at = (("decode", {"batch": len(decode) + n_chunk})
+                         if decode else ("prefill", chunk))
+            at = dict(at, chunk_tokens=n_chunk)
+            ahead = int(self._flight is not None)
+            tokens, t_launch = self._dispatch(phase, packed, reload, at)
+            self._t_settled = t_launch
+            launched = _Flight(
+                tokens, phase, at, [(r, r.slot) for r in decode],
+                pf and (req, req.slot, start + n_chunk >= req.prefill_len, chunk),
+                self._launched(decode, pf))
+            if self._hybrid:
+                table_stats.update(self._hybrid_stats(rows))
+        moe_stats = self._drain()       # the program before: behind the launch
+        if runs and self._stays_ahead():
+            self._flight = launched
+        elif runs:
+            moe_stats = self._land(launched)
+        if runs and settled is not None:
+            turnaround = self._turnaround(settled, t_enter, t_launch, idle_since)
         # how attention took the step: the queries a row of the chunk held
-        # (0: no chunk in the step) and the rows its calls ran; and the step's
-        # own turn-round, where there was one
+        # (0: no chunk in the step) and the rows its calls ran; whether the
+        # step was dispatched ahead, and its own turn-round, where there was
+        # one
+        self.steps_dispatched_ahead += ahead
         on_span = dict(
             table_stats, tile_runs_pct=self._tile_runs_pct(),
             chunk_queries_per_row=self.chunk_queries_per_row if n_chunk else 0,
-            attention_rows=self.attention_rows if runs else 0, **turnaround)
+            attention_rows=self.attention_rows if runs else 0,
+            dispatched_ahead=ahead, **turnaround)
         with self._span("serve.stats", **on_span):
             stats = self._close_step(len(decode), n_chunk, int(runs), t_step,
                                      dict(moe_stats, **on_span))
         if not runs or not self.sched.has_work:
-            self._t_result = None       # from here the chip waits for WORK
+            self._t_settled = None      # from here the chip waits for WORK
         self._t_exit = self._clock()
         return stats
+
+    def _admit_is_unusual(self) -> bool:
+        """Whether admission may do more than hand free slots to waiting
+        requests: restage a spilled request's blocks into the arena, or
+        preempt a weaker class for a stronger one (``slo_preemption``).  Both
+        land the row in flight first."""
+        waiting = self.sched.waiting
+        if not waiting or not self.sched._free_slots:
+            return False
+        if self.tiering is not None and any(r.spilled for r in waiting):
+            return True
+        return bool(self._config.slo_preemption and self.sched.active) and (
+            min(r.priority for r in waiting)
+            < max(r.priority for r in self.sched.active.values()))
+
+    def _launched(self, decode: List[Request], pf) -> Dict[int, int]:
+        """What the host knows of a program the moment it is launched, without
+        its row: its K/V is resident before anything later runs, so every
+        position moves now, and a prompt whose last chunk this was decodes
+        from the next program on.  -> the program's ``feeds``: for each
+        request whose next input is a token of this program, the row that
+        makes it."""
+        feeds = {}
+        for r in decode:
+            r.prefilled += 1            # the fed token's KV
+            feeds[r.rid] = r.slot
+        if pf is not None:
+            req, _, n = pf
+            req.prefilled += n
+            if req.prefilled >= req.prefill_len:
+                if self.prefix is not None and not self.admission.brownout:
+                    # the prompt's full blocks hold valid KV for every later
+                    # program: pin them for later requests sharing this
+                    # prefix (idempotent re-insert; paused under brownout —
+                    # pinning competes with admission for blocks exactly
+                    # when the arena is the bottleneck)
+                    self.prefix.insert(req.prompt,
+                                       self.alloc.owned_blocks(req.rid))
+                req.state = DECODE
+                feeds[req.rid] = self._layout.slots + n - 1
+        return feeds
 
     def _tile_runs_pct(self) -> float:
         """Of the tiles the live sequences' full-attention tables hold, the
@@ -1008,16 +1222,19 @@ class ServingEngine:
         held = self.alloc.tiles_held
         return 100.0 * self.alloc.tiles_run / held if held else 0.0
 
-    def _turnaround(self, t_enter: float, t_launch: float,
-                    t_result: float) -> Dict[str, float]:
+    def _turnaround(self, settled: float, t_enter: float, t_launch: float,
+                    idle_since: Optional[float]) -> Dict[str, float]:
         """:data:`TURNAROUND_STATS` of the step that launched a program at
-        ``t_launch`` after the one whose row came back at ``self._t_result``."""
-        before = self._t_result
-        out = {"turnaround_ms": (t_launch - before) * 1e3,
-               "commit_ms": (self._t_exit - before) * 1e3,
-               "outside_ms": (t_enter - self._t_exit) * 1e3,
-               "prepare_ms": (t_launch - t_enter) * 1e3,
-               "result_wait_ms": (t_result - t_launch) * 1e3}
+        ``t_launch``: ``settled`` is when the step before was last settled,
+        ``idle_since`` the last row on the host at the launch (None:
+        dispatched ahead, the chip had a program)."""
+        ms = lambda a, b: (b - a) * 1e3
+        out = {"turnaround_ms": 0.0 if idle_since is None
+               else ms(idle_since, t_launch),
+               "commit_ms": ms(settled, self._t_exit),
+               "outside_ms": ms(self._t_exit, t_enter),
+               "prepare_ms": ms(t_enter, t_launch),
+               "result_wait_ms": ms(t_launch, self._t_settled)}
         if self.registry is not None:
             self._h_turnaround.observe(out["turnaround_ms"])
         return out
@@ -1121,6 +1338,10 @@ class ServingEngine:
         if self._closed:
             return
         self._closed = True
+        try:
+            self._drain()       # no token of a launched program is lost
+        except ServeStepTimeout:
+            pass                # recovered: its requests wait, as after run()
         if self._bounded is not None:
             self._bounded.shutdown()
         if self.tiering is not None:
@@ -1137,8 +1358,14 @@ class ServingEngine:
         """JSON-ready warm-restart state: the scheduler queue + per-request
         progress — prompts, generated-so-far, remaining deadline — but NOT
         KV bytes (recompute on restore keeps the snapshot tiny and the
-        token streams identical).  Take it between steps; an elastic-agent
-        relaunch feeds it to :meth:`restore` on a fresh engine."""
+        token streams identical).  Take it between steps (a row in flight
+        lands first, so every token a program has made is in it); an
+        elastic-agent relaunch feeds it to :meth:`restore` on a fresh
+        engine."""
+        try:
+            self._drain()
+        except ServeStepTimeout:
+            pass                # recovered: every request waits, none lost
         now = self._clock()
         in_flight = sorted(
             list(self.sched.waiting) + list(self.sched.active.values()),
@@ -1235,27 +1462,15 @@ class ServingEngine:
         rows[first:first + n, 1] = np.arange(start, start + n)
         rows[first:first + n, 2:] = req.slot, 1
 
-    def _commit_prefill(self, req: Request, n: int, token: int):
-        req.prefilled += n
-        if req.prefilled >= req.prefill_len:
-            if self.prefix is not None and not self.admission.brownout:
-                # the prompt's full blocks now hold valid KV: pin them for
-                # later requests sharing this prefix (idempotent re-insert;
-                # paused under brownout — pinning competes with admission
-                # for blocks exactly when the arena is the bottleneck)
-                self.prefix.insert(req.prompt,
-                                   self.alloc.owned_blocks(req.rid))
-            # the chunk holding the last context token also yields the next
-            # token — first-token latency includes no extra decode step
-            req.state = DECODE
-            self._append_token(req, token)
-
     def _decode_rows(self, rows, reqs: List[Request]):
         """Every decode-ready sequence into the row of its slot: the
         context's last token (without building the context) at the position
-        behind what is resident."""
+        behind what is resident.  Where that token is a row of the program in
+        flight, the row says which (:func:`unpack_step`)."""
+        feeds = self._flight.feeds if self._flight is not None else {}
         slots = [r.slot for r in reqs]
-        rows[slots, 0] = [(r.generated or r.prompt)[-1] for r in reqs]
+        rows[slots, 0] = [-1 - feeds[r.rid] if r.rid in feeds
+                          else (r.generated or r.prompt)[-1] for r in reqs]
         rows[slots, 1] = [r.prefilled for r in reqs]
         rows[slots, 2] = slots
         rows[slots, 3] = 1

@@ -73,6 +73,16 @@ def engine(model_and_params, **over):
 
 
 # ---- the oracle: the step's inputs as whole tables built on the host ----------- #
+def last_token(eng, r):
+    """The last token of ``r``'s context: on the host, or still a row of the
+    program in flight (the engine is a step ahead: ``test_dispatch_ahead.py``),
+    which the next program takes on the device."""
+    flight = eng._flight
+    if flight is not None and r.rid in flight.feeds:
+        return int(np.asarray(flight.tokens)[flight.feeds[r.rid]])
+    return r.context[-1]
+
+
 def oracle_inputs(eng, decode, pf):
     """(ids, positions, tables, write blocks, write offsets) of the step that
     is about to run, a row a decode slot and a row a chunk token, from
@@ -93,7 +103,7 @@ def oracle_inputs(eng, decode, pf):
             tables[g][rows] = alloc.block_table(req.rid, g)
             wb[g][rows, 0], wo[rows, 0] = alloc.write_map(req.rid, start, n, g)
     for r in decode:
-        ids[r.slot, 0] = r.context[-1]
+        ids[r.slot, 0] = last_token(eng, r)
         positions[r.slot] = r.prefilled
         for g in range(alloc.n_groups):
             tables[g][r.slot] = alloc.block_table(r.rid, g)
@@ -137,11 +147,13 @@ class Watch:
     def __call__(self, phase, packed, reload, stats):
         eng = self.eng
         assert packed.shape == (eng._layout.packed_size,) and packed.dtype == np.int32
-        decode = [r for r in eng.sched.active.values() if r.state == DECODE]
+        # every decoding sequence but those whose last token is in flight
+        decode = [r for r in eng.sched.active.values() if r.state == DECODE
+                  and not eng._ends_in_flight(r)]
         want = oracle_inputs(eng, decode, eng.sched.next_prefill())
         state = eng._tables if reload is None else reload
         ids, positions, _, tables, wb, wo = jax.tree.map(
-            np.asarray, self.unpack(eng._layout, packed, state))
+            np.asarray, self.unpack(eng._layout, packed, eng._previous, state))
         got = (ids, positions, list(tables), list(wb), wo)
         for name, g, w in zip(("ids", "positions", "tables", "write blocks",
                                "write offsets"), got, want):
@@ -161,7 +173,7 @@ class Watch:
             # the request's table, alone they saw the trash block)
             rows = np.flatnonzero(wb[0][:, 0])
             np.testing.assert_array_equal(
-                tokens[rows], np.asarray(theirs).reshape(-1)[rows],
+                np.asarray(tokens)[rows], np.asarray(theirs).reshape(-1)[rows],
                 err_msg=f"tokens, step {self.steps}")
         self.steps += 1
         self.reloads += reload is not None

@@ -2,8 +2,9 @@
 
 A step builds ONE upload for ``[max_batch_size + prefill_chunk, 1]`` tokens,
 dispatches one program (whose attention takes the chunk's tokens packed,
-several queries a row: ``test_packed_chunk.py``), fetches one token row and
-commits the chunk and the decode rows from it.  Whatever the traffic puts in the rows (a multi-chunk
+several queries a row: ``test_packed_chunk.py``), and fetches each program's
+token row once and commits the chunk and the decode rows from it, in the same
+step or behind the next step's launch (``test_dispatch_ahead.py``).  Whatever the traffic puts in the rows (a multi-chunk
 prompt arriving while others decode, a prefix-cache hit, a preemption with
 recompute, a restore from a snapshot) and whatever the model (learned
 positions, ALiBi, rope with grouped K/V heads), every request's tokens are
@@ -112,9 +113,13 @@ def snapshot_restore(mp):
         old.submit(p, max_new_tokens=m)
     for _ in range(3):
         old.step()
-    states = sorted((r.prefilled, len(r.generated)) for r in old.sched.active.values())
-    assert states == [(7, 3), (16, 0)] and len(old.sched.waiting) == 1
+    states = lambda: sorted((r.prefilled, len(r.generated))
+                            for r in old.sched.active.values())
+    # a request waits, so the third program is in flight: its positions have
+    # moved, its token has not landed; the snapshot lands it first
+    assert states() == [(7, 2), (16, 0)] and len(old.sched.waiting) == 1
     snap = json.loads(json.dumps(old.snapshot()))
+    assert states() == [(7, 3), (16, 0)]
     old.close()
     eng = engine(mp, max_batch_size=2)
     futs = eng.restore(snap)
@@ -146,10 +151,14 @@ def test_a_host_that_polls_for_the_token_row_serves_the_same_tokens(model_and_pa
     futs = [eng.submit(p, max_new_tokens=m) for p, m in zip(prompts, new)]
     waits = []
     while eng.sched.has_work:
-        waits.append(eng.step().get("result_wait_ms"))
+        st = eng.step()
+        if st["programs"]:
+            waits.append(st.get("result_wait_ms"))
     for p, m, f in zip(prompts, new, futs):
         assert f.done and f.token_ids == reference(model, params, p, m)
-    assert all(w is not None and w >= 0 for w in waits[1:])
+    # (the first step's program stays in flight, prompt being left: it waits
+    # for no row, and the second waits for the first's)
+    assert all(w is not None and w >= 0 for w in waits[1:]) and len(waits) > 8
     assert eng.compiled_programs() == 1
     eng.close()
 
@@ -173,7 +182,8 @@ def test_the_program_has_one_shape_whatever_the_step_holds(model_and_params,
     def spy(phase, packed, reload, stats):
         assert reload is None
         ids, positions, _, (tables,), (wb,), wo = jax.tree.map(      # one layer group
-            np.asarray, unpack(eng._layout, packed, eng._tables))
+            np.asarray, unpack(eng._layout, packed, eng._previous, eng._tables))
+        assert (ids >= 0).all(), "a row that names a token of the program before got it"
         shapes.add((packed.shape,) + tuple(
             a.shape for a in (ids, positions, tables, wb, wo)))
         live = wb[:, 0] != 0
@@ -203,27 +213,39 @@ def test_the_program_has_one_shape_whatever_the_step_holds(model_and_params,
     eng.close()
 
 
-def test_a_step_opens_at_most_one_dispatch_and_one_fetch(model_and_params):
+def test_a_step_opens_at_most_one_dispatch_and_every_program_one_fetch(model_and_params):
+    """A step launches at most one program, under the span named for what the
+    program carries; every program's row is fetched ONCE, under the same
+    name and stats, in the step that launched it or (a prompt with chunks
+    left: dispatched ahead) behind the next step's launch."""
     tr = Tracer()
     eng = engine(model_and_params, tracer=tr)
     for p, m in zip(prompts_of(8, (3, 20, 9)), (9, 4, 6)):
         eng.submit(p, max_new_tokens=m)
-    seen = set()
+    seen, dispatched, fetched, ahead = set(), [], [], 0
     while eng.sched.has_work:
         mark = len(tr.snapshot())
         st = eng.step()
-        spans = {r["name"]: r["args"] for r in tr.snapshot()[mark:]}
-        pair = sorted(n for n in spans if n.endswith((".dispatch", ".fetch")))
+        spans = [(r["name"], r["args"]) for r in tr.snapshot()[mark:]]
+        launches = [(n, a) for n, a in spans if n.endswith(".dispatch")]
+        fetches = [(n, a) for n, a in spans if n.endswith(".fetch")]
         phase = "decode" if st["decode_batch"] else "prefill"
-        assert pair == [f"serve.{phase}.dispatch", f"serve.{phase}.fetch"]
-        assert st["programs"] == 1
-        for name in pair:
-            assert spans[name]["chunk_tokens"] == st["prefill_tokens"]
+        assert [n for n, _ in launches] == [f"serve.{phase}.dispatch"] * st["programs"]
+        assert len(fetches) <= 2
+        for _, args in launches:
+            assert args["chunk_tokens"] == st["prefill_tokens"]
             if phase == "decode":
-                assert spans[name]["batch"] == st["decode_batch"] + st["prefill_tokens"]
+                assert args["batch"] == st["decode_batch"] + st["prefill_tokens"]
             else:
-                assert spans[name]["tokens"] == st["prefill_tokens"]
+                assert args["tokens"] == st["prefill_tokens"]
+        if st["dispatched_ahead"]:          # the launch first, then the row before
+            names = [n for n, _ in spans]
+            assert names.index(launches[0][0]) < names.index(fetches[0][0])
+        dispatched += [(n.rsplit(".", 1)[0], a) for n, a in launches]
+        fetched += [(n.rsplit(".", 1)[0], a) for n, a in fetches]
+        ahead += st["dispatched_ahead"]
         seen.add((phase, bool(st["prefill_tokens"])))
+    assert fetched == dispatched and ahead == eng.steps_dispatched_ahead >= 2
     assert seen == {("prefill", True), ("decode", True), ("decode", False)}
     eng.close()
 
@@ -239,6 +261,7 @@ def test_the_last_chunk_gives_the_first_token_and_decode_starts_a_step_later(
         eng.step()
         got.append((r.prefilled, len(r.generated)))
     n = len(prompt)
+    # (the first chunk stays in flight: there is prompt left behind it)
     assert got == [(CHUNK, 0), (n, 1), (n + 1, 2), (n + 2, 3), (n + 3, 4)]
     assert r.generated == reference(model, params, prompt, 4)
     eng.close()

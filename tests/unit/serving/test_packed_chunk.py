@@ -76,7 +76,8 @@ class Both:
     def __call__(self, phase, packed, reload, stats):
         eng = self.eng
         ids, positions, _, tables, wb, wo = self.unpack(
-            eng._layout, packed, eng._tables if reload is None else reload)
+            eng._layout, packed, eng._previous,
+            eng._tables if reload is None else reload)
         args = (eng.params, ids, positions, eng._k_pages, eng._v_pages,
                 tables, wb, wo)
         got, want = (np.asarray(f(*args))[:, 0, :V]

@@ -166,13 +166,20 @@ def _engine(tiny_model, tracer=None, **over):
 
 
 def test_leaf_spans_tile_the_step(tiny_model):
-    """Every leaf is a sibling, in the order of ``SERVE_STEP_SPANS``, and what
+    """Every leaf is a sibling, in the order the work happens, and what
     lies under no leaf in a warm step is the spans' own bookkeeping, a few
     microseconds at each of the step's eight to ten boundaries: a fixed cost
     that does not grow with the program, so it is held to a budget in
     microseconds (on the chip a step is tens of milliseconds).  As a share of
     this toy's 1.7 ms step it is 7 to 10%, one program a step or two; the
-    share read 0.99 here only while a compile fell inside the steps counted."""
+    share read 0.99 here only while a compile fell inside the steps counted.
+
+    The order: admit, grow, the builds, ONE dispatch, then a fetch and the
+    commits of its row for each program that lands in the step: its own (the
+    order of ``SERVE_STEP_SPANS``, where the step is not dispatched ahead),
+    the one before it (dispatched ahead), or both (the step in which the
+    engine stops being ahead)."""
+    import re
     tr = Tracer()
     eng = _engine(tiny_model, tracer=tr)
     rng = np.random.default_rng(0)
@@ -181,25 +188,35 @@ def test_leaf_spans_tile_the_step(tiny_model):
     eng.step()                                  # compiles the program
     eng.step()
     uncovered_us = []
-    seen = set()
-    for _ in range(6):
+    seen, shapes = set(), set()
+    for _ in range(8):
         mark = len(tr.snapshot())
         t0 = time.monotonic_ns()
-        eng.step()
+        stats = eng.step()
         t1 = time.monotonic_ns()
         # (a request's serve.first_token mark sits inside the commit it came in)
         step = [r for r in tr.snapshot()[mark:] if r["name"] != "serve.first_token"]
         assert all(r["depth"] == 0 and r["parent"] == 0 for r in step), \
             "leaves are siblings: nothing encloses them"
-        assert [r["name"] for r in step] == [
-            n for n in SERVE_STEP_SPANS if n in {r["name"] for r in step}], \
-            "in the order the work happens"
+        names = [r["name"] for r in step]
+        kinds = " ".join(n.rsplit(".", 1)[-1] for n in names)
+        assert re.fullmatch(r"admit grow build( build)? dispatch"
+                            r"( fetch( commit){1,2}){1,2} stats", kinds), names
+        assert names[2:4][:kinds.count("build")] == [
+            "serve.prefill.build", "serve.decode.build"][:kinds.count("build")]
+        if not stats["dispatched_ahead"]:
+            assert names == [n for n in SERVE_STEP_SPANS if n in set(names)], \
+                "in the order of the list"
+        shapes.add((stats["dispatched_ahead"], kinds.count("fetch")))
         assert t0 <= step[0]["t0"] and step[-1]["t1"] <= t1
-        seen |= {r["name"] for r in step}
+        seen |= set(names)
         uncovered_us.append(((t1 - t0) - sum(r["t1"] - r["t0"] for r in step)) / 1e3)
-    # steps 3..8: the first prompt's last chunk alone (the pair that names a
-    # program with no decode row), then chunks beside decode rows, then
-    # decode rows alone
+    # steps 3..10: the first prompt's last chunk alone (the pair that names a
+    # program with no decode row) and the other prompts' chunks beside decode
+    # rows, each launched before the row of the one before it is fetched;
+    # the last chunk's step fetches that row and its own; then decode rows
+    # alone, each step its own row
+    assert shapes == {(1, 1), (1, 2), (0, 1)}
     assert seen == set(SERVE_STEP_SPANS)
     assert sorted(uncovered_us)[len(uncovered_us) // 2] <= 250.0, uncovered_us
     eng.close()
@@ -225,10 +242,12 @@ def test_spans_carry_counts_where_the_work_happens(tiny_model):
     by, stats = _step_spans(tr, eng)            # a chunk, and no decode row yet
     assert by["serve.admit"] == [{"admitted": 1}]
     chunk = {"rid": fut.request.rid, "start": 0, "tokens": 8}
-    for name in ("serve.prefill.build", "serve.prefill.commit"):
-        assert by[name] == [chunk], name
-    for name in ("serve.prefill.dispatch", "serve.prefill.fetch"):
-        assert by[name] == [dict(chunk, chunk_tokens=8)], name
+    assert by["serve.prefill.build"] == [chunk]
+    assert by["serve.prefill.dispatch"] == [dict(chunk, chunk_tokens=8)]
+    # prompt is left behind the chunk, so nothing that arrives could change
+    # the next program: this one stays in flight, its row is not fetched here
+    assert "serve.prefill.fetch" not in by and "serve.prefill.commit" not in by
+    assert (stats["dispatched_ahead"], by["serve.stats"][0]["dispatched_ahead"]) == (0, 0)
     # 12 prompt tokens in blocks of 8: two pages, one full group, no window
     assert by["serve.grow"] == [{"batch": 0, "pages_full": 2, "pages_window": 0,
                                  "pages_given_back": 0}]
@@ -239,7 +258,18 @@ def test_spans_carry_counts_where_the_work_happens(tiny_model):
     packed = {"chunk_queries_per_row": 8, "attention_rows": 4 + 1}
     assert {k: by["serve.stats"][0][k] for k in packed} == packed
     assert {k: stats[k] for k in packed} == packed
-    eng.step()                                  # last chunk: the first token
+    by, stats = _step_spans(tr, eng)            # last chunk: the first token
+    last = {"rid": fut.request.rid, "start": 8, "tokens": 4}
+    # launched before the first chunk's row was fetched; then that row, and
+    # (the lane idle, the queue empty, a slot free) its own: two fetches,
+    # each under the stats of the program whose row it brings
+    assert by["serve.prefill.build"] == [last]
+    assert by["serve.prefill.dispatch"] == [dict(last, chunk_tokens=4)]
+    assert by["serve.prefill.fetch"] == [dict(chunk, chunk_tokens=8),
+                                         dict(last, chunk_tokens=4)]
+    assert by["serve.prefill.commit"] == [chunk, last]
+    assert (stats["dispatched_ahead"], by["serve.stats"][0]["dispatched_ahead"]) == (1, 1)
+    assert eng.steps_dispatched_ahead == 1 and len(fut.request.generated) == 1
     by, stats = _step_spans(tr, eng)            # the first decode step
     for name in ("serve.decode.build", "serve.decode.commit"):
         assert by[name] == [{"batch": 1}], name
@@ -260,6 +290,7 @@ def test_spans_carry_counts_where_the_work_happens(tiny_model):
              "upload_bytes": 4 * eng._layout.packed_size}
     (on_span,) = by["serve.stats"]
     assert on_span == dict(table, chunk_queries_per_row=0, attention_rows=5,
+                           dispatched_ahead=0,
                            **{k: stats[k] for k in TURNAROUND_STATS})
     assert {k: stats[k] for k in table} == table
     assert stats["paged_tile_pages"] == eng.paged_tile_pages == 0   # the einsum
@@ -290,32 +321,80 @@ def _turnaround(stats):
 
 
 def _stamped(eng):
-    """``eng._dispatch`` wrapped to keep each program's (t_launch, t_result)."""
-    kept, inner = [], eng._dispatch
+    """``eng._dispatch`` and ``eng._fetch`` wrapped to keep each program's
+    ``t_launch`` and each row's ``t_result``, in the order they were taken."""
+    launches, results, dispatch, fetch = [], [], eng._dispatch, eng._fetch
 
-    def dispatch(*args):
-        out = inner(*args)
-        kept.append(out[1:])
-        return out
-    eng._dispatch = dispatch
-    return kept
+    def keeping(inner, kept):
+        def call(*args):
+            out = inner(*args)
+            kept.append(out[1])
+            return out
+        return call
+    eng._dispatch, eng._fetch = keeping(dispatch, launches), keeping(fetch, results)
+    return launches, results
 
 
 def test_turnaround_parts_sum_to_the_time_between_two_results(tiny_model):
+    """A lone request whose prompt is one chunk: no step is dispatched ahead,
+    and each step's program waits for the host's whole turn-round."""
     eng = _engine(tiny_model)
     eng._clock = _Ticks()
-    stamps = _stamped(eng)
-    eng.submit(list(range(1, 20)), max_new_tokens=6)
+    launches, results = _stamped(eng)
+    eng.submit(list(range(1, 8)), max_new_tokens=8)
     assert _turnaround(eng.step()) == {}, "the first step: nothing to turn round from"
-    for _ in range(6):                         # chunks, then decode rows
-        t = _turnaround(eng.step())
-        assert set(t) == set(TURNAROUND_STATS)
-        (_, result_before), (launch, result) = stamps[-2:]
+    for _ in range(6):                         # decode rows
+        stats = eng.step()
+        t = _turnaround(stats)
+        assert set(t) == set(TURNAROUND_STATS) and stats["dispatched_ahead"] == 0
+        result_before, result, launch = results[-2], results[-1], launches[-1]
         assert t["commit_ms"] + t["outside_ms"] + t["prepare_ms"] == t["turnaround_ms"]
         assert t["turnaround_ms"] == (launch - result_before) * 1e3
         assert t["result_wait_ms"] == (result - launch) * 1e3 == 1e3    # one read on
         assert sum(t.values()) - t["turnaround_ms"] == (result - result_before) * 1e3
         assert min(t.values()) > 0.0
+    assert eng.steps_dispatched_ahead == 0
+    eng.close()
+
+
+def test_a_step_dispatched_ahead_has_no_turnaround_and_its_parts_are_durations(tiny_model):
+    """Under a backlog (more requests than slots) a step launches its program
+    before the row of the one before it is on the host: the chip never has no
+    program, so ``turnaround_ms`` is 0.0; the four parts are still the
+    host's durations, and ``result_wait_ms`` is what the host waited behind
+    its launch for the row of the program BEFORE.  ``dispatched_ahead`` is in
+    the stats and on ``serve.stats``."""
+    tr = Tracer()
+    eng = _engine(tiny_model, tracer=tr, max_batch_size=2)
+    eng._clock = _Ticks()
+    launches, results = _stamped(eng)
+    for n in (3, 4, 5, 6, 7):
+        eng.submit(list(range(1, n + 1)), max_new_tokens=6)
+    first = eng.step()              # launches, and fetches nothing
+    assert _turnaround(first) == {} and first["dispatched_ahead"] == 0
+    second = _turnaround(eng.step())        # ahead; settled at that launch
+    assert second["turnaround_ms"] == 0.0 and second["commit_ms"] > 0.0
+    ahead = 0
+    while len(eng.sched.waiting) > 1:           # (the queue's last leaves it
+        exit_before = eng._t_exit               # empty beside a free slot)
+        by, stats = _step_spans(tr, eng)
+        t = _turnaround(stats)
+        assert stats["dispatched_ahead"] == by["serve.stats"][0]["dispatched_ahead"] == 1
+        assert {k: by["serve.stats"][0][k] for k in TURNAROUND_STATS} == t
+        assert t["turnaround_ms"] == 0.0
+        # one row came in this step, behind the launch; the row before it
+        # came in the step before
+        assert results[-2] < exit_before < launches[-1] < results[-1]
+        assert t["commit_ms"] == (exit_before - results[-2]) * 1e3 > 0.0    # from its row
+        assert t["outside_ms"] == 1e3                   # t_exit to t_enter
+        assert t["commit_ms"] + t["outside_ms"] + t["prepare_ms"] == (
+            launches[-1] - results[-2]) * 1e3
+        assert t["result_wait_ms"] == (results[-1] - launches[-1]) * 1e3 == 1e3
+        kinds = [n.rsplit(".", 1)[-1] for n in by]
+        assert kinds.index("dispatch") < kinds.index("fetch") and kinds.count("fetch") == 1
+        ahead += 1
+    assert ahead >= 4 and eng.steps_dispatched_ahead == ahead + 1
+    eng.run()
     eng.close()
 
 
@@ -382,7 +461,7 @@ def test_a_step_the_chip_waited_for_work_before_carries_no_turnaround(tiny_model
 def test_the_first_step_after_an_incident_carries_no_turnaround(tiny_model):
     from deepspeed_tpu.serving.engine import ServeStepTimeout
     eng = _engine(tiny_model)
-    fut = eng.submit(list(range(1, 8)), max_new_tokens=8)
+    fut = eng.submit(list(range(1, 5)), max_new_tokens=8)   # one chunk, requeued too
     eng.step()
     assert _turnaround(eng.step())
     eng._recover_incident(ServeStepTimeout("wedged", op="decode", deadline_s=1.0,
@@ -395,15 +474,16 @@ def test_the_first_step_after_an_incident_carries_no_turnaround(tiny_model):
 
 @pytest.mark.parametrize("timeout_s", [0.0, 30.0], ids=["inline", "bounded-worker"])
 def test_turnaround_is_on_the_main_threads_stats_span(tiny_model, timeout_s):
-    """With ``serve_step_timeout_s`` the dispatch and the fetch run on the
-    bounded worker's thread, their spans on its line; the stamps are taken
-    there and carried to ``serve.stats``, which the main thread opens: the
-    same keys, in ``step()``'s stats and on the span, value for value."""
+    """With ``serve_step_timeout_s`` the fetch runs on the bounded worker's
+    thread, its span on its line (the launch is inline, on the main thread's:
+    it does not wait for the chip); ``t_result`` is taken there and carried to
+    ``serve.stats``, which the main thread opens: the same keys, in
+    ``step()``'s stats and on the span, value for value."""
     import threading
     tr = Tracer()
     eng = _engine(tiny_model, tracer=tr, serve_step_timeout_s=timeout_s)
-    eng.submit(list(range(1, 12)), max_new_tokens=4)
-    eng.step()                                  # compiles, inline either way
+    eng.submit(list(range(1, 8)), max_new_tokens=5)
+    eng.step()                                  # compiles, in the inline launch
     for _ in range(3):
         mark = len(tr.snapshot())
         stats = eng.step()
@@ -411,6 +491,7 @@ def test_turnaround_is_on_the_main_threads_stats_span(tiny_model, timeout_s):
         fetch = next(r for n, r in recs.items() if n.endswith(".fetch"))
         main = threading.get_ident()
         assert (fetch["tid"] != main) == bool(timeout_s)
+        assert recs["serve.decode.dispatch"]["tid"] == main
         assert recs["serve.stats"]["tid"] == main
         on_span = recs["serve.stats"]["args"]
         assert {k: on_span[k] for k in TURNAROUND_STATS} == _turnaround(stats)
@@ -423,7 +504,7 @@ def test_turnaround_is_on_the_main_threads_stats_span(tiny_model, timeout_s):
 def test_turnaround_is_on_the_profilers_line_a_step(tiny_model, tmp_path):
     from jax.profiler import ProfileData
     eng = _engine(tiny_model)
-    fut = eng.submit(list(range(1, 12)), max_new_tokens=4)
+    fut = eng.submit(list(range(1, 8)), max_new_tokens=5)
     eng.step()
     seen = []
     jax.profiler.start_trace(str(tmp_path))
@@ -439,6 +520,7 @@ def test_turnaround_is_on_the_profilers_line_a_step(tiny_model, tmp_path):
     assert len(events) == len(seen) >= 4
     for (_, on_span), stats in zip(events, seen):
         assert {k: on_span[k] for k in TURNAROUND_STATS} == pytest.approx(stats)
+        assert on_span["dispatched_ahead"] == 0
         assert not {"paged_tile_pages", "cache_bytes_per_token"} & set(on_span)
     eng.close()
 
@@ -456,7 +538,7 @@ def test_registry_times_the_turnaround_and_no_decode_step(tiny_model, tmp_path):
                         config=DeepSpeedServingConfig(
                             block_size=8, num_blocks=64, max_batch_size=4,
                             prefill_chunk=8, dtype="float32", telemetry_every=2))
-    fut = eng.submit(list(range(1, 12)), max_new_tokens=4)
+    fut = eng.submit(list(range(1, 8)), max_new_tokens=5)
     turns = []
     while not fut.done:
         turns.append(_turnaround(eng.step()))
@@ -513,23 +595,29 @@ def test_table_stats_over_plain_decode(tiny_model, wide):
 def test_a_chunk_beside_decode_rows_is_one_dispatch_and_one_fetch(tiny_model):
     """The step's one program is named for its decode rows; its ``batch``
     counts every row that carries a request, the chunk's tokens included
-    (``benchmarks/readers/moe.py`` takes it for the rows of one bank call)."""
+    (``benchmarks/readers/moe.py`` takes it for the rows of one bank call).
+    Its row is fetched once, under the same name and stats: behind the next
+    step's launch where prompt was left behind its chunk."""
     tr = Tracer()
     eng = _engine(tiny_model, tracer=tr)
     first = eng.submit([5, 6, 7], max_new_tokens=8)
     eng.step()                                  # its whole prompt: first token
     late = eng.submit(list(range(1, 12)), max_new_tokens=4)
-    for start, n in ((0, 8), (8, 3)):
+    chunks = [{"rid": late.request.rid, "start": start, "tokens": n}
+              for start, n in ((0, 8), (8, 3))]
+    pairs = [{"batch": 1 + c["tokens"], "chunk_tokens": c["tokens"]} for c in chunks]
+    fetched = ([], pairs)                       # the second step fetches both rows
+    for chunk, pair, rows in zip(chunks, pairs, fetched):
         by, stats = _step_spans(tr, eng)
-        chunk = {"rid": late.request.rid, "start": start, "tokens": n}
-        dispatched = [n_ for n_ in by if n_.endswith((".dispatch", ".fetch"))]
-        assert sorted(dispatched) == ["serve.decode.dispatch", "serve.decode.fetch"]
-        for name in dispatched:
-            assert by[name] == [{"batch": 1 + n, "chunk_tokens": n}], name
-        assert by["serve.prefill.build"] == by["serve.prefill.commit"] == [chunk]
-        assert by["serve.decode.build"] == by["serve.decode.commit"] == [{"batch": 1}]
+        assert not any(n.endswith((".dispatch", ".fetch")) and "prefill" in n for n in by)
+        assert by["serve.decode.dispatch"] == [pair]
+        assert by.get("serve.decode.fetch", []) == rows
+        assert by["serve.prefill.build"] == [chunk]
+        assert by.get("serve.prefill.commit", []) == (chunks if rows else [])
+        assert by["serve.decode.build"] == [{"batch": 1}]
+        assert by.get("serve.decode.commit", []) == [{"batch": 1}] * len(rows)
         assert (stats["programs"], stats["prefill_tokens"], stats["decode_batch"]) \
-            == (1, n, 1)
+            == (1, chunk["tokens"], 1)
     assert len(late.request.generated) == 1, "decodes from the next step"
     assert len(first.request.generated) == 3
     eng.run()

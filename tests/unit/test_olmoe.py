@@ -165,11 +165,15 @@ def test_prefill_in_chunks_then_decode_through_the_engine(tiny):
                                      config={"serving": SERVING})
     prompts = [list(map(int, _ids(19, seed=6))), list(map(int, _ids(5, seed=7)))]
     futures = [eng.submit(p, max_new_tokens=n) for p, n in zip(prompts, (12, 7))]
-    stats, assigned = [], []
+    stats, assigned, land = [], [], eng._land
+    # a program's counts come in with its row, in the step that launched it
+    # or (prompt left behind its chunk: dispatched ahead) in the next
+    eng._land = lambda flight: (land(flight), assigned.append(
+        int(eng._expert_counts.sum())))[0]
     while not all(f.done for f in futures):
         stats.append(eng.step())
-        assigned.append(int(eng._expert_counts.sum()))
     assert futures[0].request.prefill_chunks == 3
+    assert [s["dispatched_ahead"] for s in stats[:5]] == [0, 1, 1, 1, 0]
     # the one program's counts cover every row that carries a request (the
     # decode rows and the chunk's tokens: k assignments a layer each) and no
     # idle slot, no row past the chunk's tokens
@@ -183,7 +187,8 @@ def test_prefill_in_chunks_then_decode_through_the_engine(tiny):
     rows = 4 + 8                                  # slots + the chunk's rows
     out = jax.eval_shape(eng._raw_step_fn, eng.params,
                          jnp.zeros((eng._layout.packed_size,), jnp.int32),
-                         eng._k_pages, eng._v_pages, eng._tables)[0]
+                         eng._previous, eng._k_pages, eng._v_pages,
+                         eng._tables)[0]
     assert out.shape == (rows + 8,) and out.dtype == jnp.int32
     for prompt, f in zip(prompts, futures):
         seq = jnp.asarray(prompt + f.result())
@@ -191,8 +196,9 @@ def test_prefill_in_chunks_then_decode_through_the_engine(tiny):
         for t in range(len(prompt) - 1, len(seq) - 1):
             gap = float(logits[t].max() - logits[t, seq[t + 1]])
             assert gap < TOL, (t, gap)
+    assert "moe_load_max_over_mean" not in stats[0], "no row came in yet"
     assert all(s["programs"] == 1 and 1.0 <= s["moe_load_max_over_mean"] <= 8.0
-               and 1 <= s["moe_experts_touched"] <= 8 for s in stats)
+               and 1 <= s["moe_experts_touched"] <= 8 for s in stats[1:])
     eng.close()
 
 

@@ -359,26 +359,32 @@ def test_the_steps_stats_are_the_rows_routing(loud):
     eng = deepspeed_tpu.init_serving(model=model, params=params,
                                      config={"serving": SERVING})
     a, b = eng.submit(_ids(30, 1), max_new_tokens=12), eng.submit(_ids(9, 2), max_new_tokens=12)
-    reqs, seen = (a.request, b.request), []
+    reqs, seen, routed, land = (a.request, b.request), [], [], eng._land
+    # a program's routing comes in with its row: in the step that launched it,
+    # or (prompt left behind its chunk: dispatched ahead) behind the next launch
+    eng._land = lambda flight: routed.append(land(flight)) or routed[-1]
     while not (a.done and b.done):
         before = [r.prefilled for r in reqs]
         st = eng.step()
         if st["programs"]:
             seen.append((st, [(b0, r.prefilled) for r, b0 in zip(reqs, before)]))
     eng.close()
+    assert len(routed) == len(seen) and eng.steps_dispatched_ahead == 5    # six chunks
     chosen = [reference_experts(params, np.concatenate([r.prompt, r.generated]).astype(np.int32))
               for r in reqs]
-    for st, spans in seen:
+    for (st, spans), moe in zip(seen, routed):
         rows = np.concatenate([c[:, lo:hi] for c, (lo, hi) in zip(chosen, spans)], axis=1)
         assert rows.shape[1] == st["decode_batch"] + st["prefill_tokens"]
-        assert st["moe_assignments"] == 3 * rows.shape[1]
+        assert moe["moe_assignments"] == 3 * rows.shape[1]
         by_layer = np.stack([np.bincount(r, minlength=4) for r in rows])
-        assert st["moe_experts_touched"] == int((by_layer > 0).sum())
+        assert moe["moe_experts_touched"] == int((by_layer > 0).sum())
         counts = by_layer.sum(0)
-        assert st["moe_load_max_over_mean"] == pytest.approx(counts.max() / counts.mean())
+        assert moe["moe_load_max_over_mean"] == pytest.approx(counts.max() / counts.mean())
         assert st["cca_state_bytes"] == 3 * SLOTS * 208 * 4
+        if not st["dispatched_ahead"] and "moe_assignments" in st:
+            assert {k: st[k] for k in moe} == moe       # its own row's, in its stats
     assert sum(st["state_slots_reset"] for st, _ in seen) == 2
-    assert any(st["moe_experts_touched"] < 3 * 4 for st, _ in seen)
+    assert any(moe["moe_experts_touched"] < 3 * 4 for moe in routed)
 
 
 # ---- (f) the published parameter count ------------------------------------------------ #

@@ -241,10 +241,11 @@ def trace_programs() -> Dict[str, object]:
                                        max_batch_size=8, prefill_chunk=16,
                                        dtype="float32"), seed=0)
     # the step's one upload (8 slots + the chunk's 16 rows, the tables'
-    # edits) beside its state: the arena and the slots' tables
+    # edits) and the token array of the program before, beside its state:
+    # the arena and the slots' tables
     out["serving-step"] = jax.make_jaxpr(srv._step_fn)(
         srv.params, jnp.zeros((srv._layout.packed_size,), jnp.int32),
-        srv._k_pages, srv._v_pages, srv._tables)
+        srv._previous, srv._k_pages, srv._v_pages, srv._tables)
     return out
 
 
