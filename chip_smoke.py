@@ -9,9 +9,10 @@ heads, vocab 50257, 1024 positions, bf16; nothing cut), with random weights
 and data made from ``--seed``:
 
 * train — ``deepspeed_tpu.initialize()`` → ``train_batch``: AdamW, bf16,
-  gradient clipping, the fused step, flash attention, fused cross-entropy
-  and fused Adam as the defaults select them; then the same first steps on
-  the kernels' reference paths, and the losses compared;
+  gradient clipping, the fused step, flash attention and fused
+  cross-entropy as the defaults select them (Adam is the optax chain, XLA's
+  fusions); then the same first steps on the kernels' reference paths, and
+  the losses compared;
 * serve — ``deepspeed_tpu.init_serving()`` → ``submit(...).result()`` on the
   same weights; every token, and every token of ``model.generate`` on the
   same prompt, must be the greedy one under the plain dense forward.
@@ -46,7 +47,7 @@ SERVE = dict(block_size=16, num_blocks=512, max_batch_size=8, prefill_chunk=64,
 SHARDED = dict(micro_per_chip=8, seq=1024, steps=4)
 # the Pallas kernels the one-chip train step must hold, by ``pallas_call`` name
 TRAIN_KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "ce_fwd",
-                 "ce_bwd", "fused_adam")
+                 "ce_bwd")
 # bf16 tolerance on a loss near 10.8: the kernels keep fp32 accumulators
 # but round probabilities and activations to bf16 at other points than the
 # reference paths do; a wrong kernel moves the loss by far more.

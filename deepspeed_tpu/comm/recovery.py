@@ -205,7 +205,10 @@ def resolve_rank_world(default_world=1):
 # --------------------------------------------------------------------------- #
 
 def _write_json_atomic(path, doc):
-    tmp = "%s.tmp.%d" % (path, os.getpid())
+    # a name of the thread's own: the heartbeat thread and the main thread
+    # (``advance_epoch``) write the same path, and on one name the second
+    # ``os.replace`` finds the file gone
+    tmp = "%s.tmp.%d.%d" % (path, os.getpid(), threading.get_ident())
     with open(tmp, "w") as f:
         json.dump(doc, f, sort_keys=True)
         f.write("\n")

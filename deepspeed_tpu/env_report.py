@@ -46,7 +46,9 @@ def op_compatibility():
         jax.block_until_ready(flash_attention(x, x, x, causal=True))
 
     def fused_adam():
-        import optax
+        # a compiled step's Adam: the optax chain, which XLA fuses into one
+        # memory pass a leaf (the Pallas kernel of ops/pallas/fused_optim.py
+        # serves the NVMe offload walk alone)
         from deepspeed_tpu.runtime.optimizers import get_optimizer
         tx = get_optimizer("adamw", {"lr": 1e-3})
         p = {"w": jnp.zeros((128,))}

@@ -774,10 +774,11 @@ class DeepSpeedEngine:
     # Fused Pallas optimizer step (ops/pallas/fused_optim.py)
     # ------------------------------------------------------------------ #
     def _fused_opt_active(self) -> bool:
-        """Static gate for the fused Adam kernel: a fusable factory config
-        (``_fused_opt_spec``) and ``ops.pallas``'s rule — a TPU and an
-        unsharded step (a bare ``pallas_call`` has no SPMD rule, so any
-        >1-device mesh keeps the optax path)."""
+        """Static gate for the fused Adam kernel.  It gates the NVMe walk
+        (``_fused_offload_step``) alone: a compiled step's Adam is the optax
+        chain on every mesh.  A fusable factory config (``_fused_opt_spec``)
+        and ``ops.pallas``'s rule — a TPU and an unsharded step (a bare
+        ``pallas_call`` has no SPMD rule)."""
         if getattr(self, "_fused_opt_spec", None) is None:
             return False
         from deepspeed_tpu.ops import pallas
@@ -1840,15 +1841,6 @@ class DeepSpeedEngine:
 
         def do_step(args):
             params, opt_state, grads = args
-            if not momentum_mode and self._fused_opt_active():
-                from deepspeed_tpu.ops.pallas import fused_optim
-                # the grads here are already unscaled + clipped, so the
-                # kernel's fold scalars are 1 and parity vs tx.update is
-                # bitwise; a chain the kernel can't fuse returns None
-                out = fused_optim.fused_adam_tree_update(
-                    self._fused_opt_spec, params, opt_state, grads)
-                if out is not None:
-                    return out
             updates, new_opt = self.tx.update(grads, opt_state, params)
             return jax.tree.map(lambda p, u: (p + u).astype(p.dtype), params, updates), new_opt
 

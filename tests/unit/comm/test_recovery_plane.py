@@ -121,6 +121,29 @@ class TestFileRendezvous:
         assert hb["step"] == 17 and hb["epoch"] == 2
         assert hb["pid"] == os.getpid()
 
+    def test_two_threads_beat_one_path(self, tmp_path):
+        """The heartbeat thread and the main thread (``advance_epoch``)
+        write one rank's heartbeat file: neither ``os.replace`` may find
+        its temporary file taken by the other."""
+        rdv = FileRendezvous(tmp_path, rank=1, world_size=2)
+        failed = []
+
+        def beat():
+            try:
+                for step in range(300):
+                    rdv.heartbeat(step=step)
+            except OSError as e:
+                failed.append(e)
+
+        threads = [threading.Thread(target=beat) for _ in range(4)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        assert not failed, failed
+        assert rdv.heartbeats()[1]["step"] == 299
+        assert os.listdir(tmp_path / "hb") == ["rank_1.json"]
+
     def test_abort_first_writer_wins(self, tmp_path):
         a = FileRendezvous(str(tmp_path), rank=0, world_size=2)
         b = FileRendezvous(str(tmp_path), rank=1, world_size=2)

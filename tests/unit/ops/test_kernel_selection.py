@@ -1,16 +1,16 @@
 """The one kernel-selection rule (``deepspeed_tpu/ops/pallas/__init__.py``):
 a kernel runs when ``use_kernel`` says kernels run here, its own shape gate
 admits the call and its own sharding rule holds; the reference otherwise.
-Each of the five selections is traced on each side of the rule and the
-``pallas_call``s in its jaxpr counted; nothing under ``ops/``, ``models/``
-or ``moe/`` may read the environment to decide."""
+Each of the four selections a program makes while it is traced is traced on
+each side of the rule and the ``pallas_call``s in its jaxpr counted; the
+fifth, ``fused_adam``, is made on the host for the NVMe walk; nothing under
+``ops/``, ``models/`` or ``moe/`` may read the environment to decide."""
 
 import pathlib
 import re
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
 import deepspeed_tpu
@@ -35,24 +35,6 @@ def _ce(refused):
     x, head = jnp.zeros((2, 32, 32)), jnp.zeros((256, 32))
     labels = jnp.zeros((2, 32), jnp.int32)
     return lambda x, h: chunked_cross_entropy(x, h, labels, 256), (x, head)
-
-
-def _fused_adam(refused):
-    """The engine's whole train step; refused by the engine's mesh (the
-    default one spans all eight devices)."""
-    model = SimpleModel(hidden_dim=32, nlayers=2)
-    engine, _, _, _ = deepspeed_tpu.initialize(
-        model=model,
-        model_parameters=model.init_params(jax.random.PRNGKey(0), batch_size=2),
-        config={"train_micro_batch_size_per_gpu": 8,
-                "optimizer": {"type": "AdamW", "params": {"lr": 1e-2}}},
-        mesh=None if refused else _set_mesh(1))
-    assert (engine.mesh.size == 1) == (not refused)
-    carry = (engine.state.params, engine.state.opt_state, engine.state.scaler,
-             engine.state.skipped)
-    dp = engine.mesh.size
-    batch = (np.zeros((1, 8 * dp, 32), np.float32), np.zeros((1, 8 * dp), np.int32))
-    return engine._build_fused_step(), (carry, batch, jax.random.PRNGKey(0))
 
 
 def _decode(refused):
@@ -82,7 +64,7 @@ def _grouped(refused):
     return gm.grouped_matmul, (jnp.zeros((A, 128)), jnp.zeros((8, 128, 128)), sizes)
 
 
-SELECTIONS = {"ce": _ce, "fused_adam": _fused_adam, "decode_attention": _decode,
+SELECTIONS = {"ce": _ce, "decode_attention": _decode,
               "paged_attention": _paged, "grouped_matmul": _grouped}
 
 
@@ -99,6 +81,30 @@ def test_the_rule_selects(kernels, name, state):
     # answers a second trace of one function at one shape from its cache
     calls = str(jax.make_jaxpr(lambda *a: fn(*a))(*args)).count("pallas_call")
     assert (calls > 0) == (state == "kernels"), calls
+
+
+@pytest.mark.usefixtures("offload_on_device")
+@pytest.mark.parametrize("state", ["cpu", "kernels", "refused"])
+def test_the_rule_selects_fused_adam_for_the_offload_walk(kernels, tmp_path,
+                                                          state):
+    """``fused_adam`` is selected on the host, a step at a time, for the NVMe
+    walk (no compiled step holds it: ``test_fused_optim.py``); refused by the
+    engine's mesh (the default one spans all eight devices)."""
+    if state != "cpu":
+        kernels("fused_adam")
+    refused = state == "refused"
+    model = SimpleModel(hidden_dim=32, nlayers=2)
+    engine, _, _, _ = deepspeed_tpu.initialize(
+        model=model,
+        model_parameters=model.init_params(jax.random.PRNGKey(0), batch_size=2),
+        config={"train_micro_batch_size_per_gpu": 8,
+                "optimizer": {"type": "AdamW", "params": {"lr": 1e-2}},
+                "zero_optimization": {
+                    "stage": 3, "offload_optimizer": {
+                        "device": "nvme", "nvme_path": str(tmp_path)}}},
+        mesh=None if refused else _set_mesh(1))
+    assert (engine.mesh.size == 1) == (not refused)
+    assert engine._fused_offload_walk_ready() == (state == "kernels")
 
 
 def test_no_selection_reads_the_environment():

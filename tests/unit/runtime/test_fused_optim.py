@@ -5,8 +5,10 @@ jit-to-jit — the kernel replays the exact optax 0.2.x op sequence, and
 every path the engine takes is jitted, so the honest comparison is
 compiled-vs-compiled (eager optax differs from ANY compiled form by FMA
 contraction, which is a property of compilation, not of this kernel).
-Covers the chain matcher, the config spec gate, engine e2e parity across
-the stage-3 compression modes, the NVMe leaf-streamed walk (offload
+The kernel has one caller, the NVMe leaf-streamed walk; a compiled step's
+Adam is the optax chain whatever the selection rule answers.  Covers the
+update composed as the walk composes it, the chain matcher, the config spec
+gate, that no compiled step program holds the kernel, the walk (offload
 on/off, checkpoint rollback-resync), and the no-retrace invariant."""
 
 import numpy as np
@@ -36,11 +38,11 @@ def assert_tree_equal(a, b, msg=""):
 
 
 def assert_tree_close(a, b, msg=""):
-    """Ulp-tight, for engine-level comparisons: the fused and unfused step
-    programs contain the same unscale/clip prelude, but the compiler fuses
-    it into a different consumer (pallas call vs optax tail) and may
-    FMA-contract it differently — a ~1-ulp wobble on the grads entering
-    the update.  The kernel itself is bitwise vs jitted optax (see
+    """Ulp-tight, for the walk against the whole-tree offload step: both
+    contain the same unscale/clip prelude, but the compiler fuses it into a
+    different consumer (pallas call vs optax tail) and may FMA-contract it
+    differently — a ~1-ulp wobble on the grads entering the update.  The
+    kernel itself is bitwise vs jitted optax (see
     ``test_tree_update_bitwise_vs_optax``)."""
     for pa, pb in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
         np.testing.assert_allclose(np.asarray(pa), np.asarray(pb),
@@ -83,9 +85,24 @@ def test_tree_update_bitwise_vs_optax(variant):
 
     @jax.jit
     def fused(p, s, g):
-        out = fused_optim.fused_adam_tree_update(spec, p, s, g)
-        assert out is not None
-        return out
+        # composed as the NVMe walk composes it (``_fused_offload_step``):
+        # the step's scalars once, the kernel a leaf, the counts beside it
+        adam_idx, sched_idx = fused_optim.match_adam_chain(s)
+        adam = s[adam_idx]
+        sc = s[sched_idx].count if sched_idx is not None else None
+        scal = jnp.stack([jnp.float32(1.0), jnp.float32(1.0),
+                          *fused_optim.step_scalars(spec, adam.count, sc)])
+        out = {k: fused_optim.fused_leaf_update(
+                   p[k], g[k], adam.mu[k], adam.nu[k], scal, b1=spec["b1"],
+                   b2=spec["b2"], eps=spec["eps"], wd=spec["wd"]) for k in p}
+        incr = fused_optim._safe_int32_increment
+        s2 = list(s)
+        s2[adam_idx] = type(adam)(count=incr(adam.count),
+                                  mu={k: out[k][1] for k in p},
+                                  nu={k: out[k][2] for k in p})
+        if sched_idx is not None:
+            s2[sched_idx] = type(s[sched_idx])(count=incr(sc))
+        return {k: out[k][0] for k in p}, tuple(s2)
 
     for step in range(4):
         g = make_tree(seed=10 + step)
@@ -168,7 +185,7 @@ def test_spec_from_config():
 
 
 # --------------------------------------------------------------------------- #
-# engine e2e (single-device mesh: the fused gate's supported regime)
+# engine e2e (single-device mesh: the gate's supported regime)
 # --------------------------------------------------------------------------- #
 HIDDEN = 32
 
@@ -226,15 +243,53 @@ class TestEngineParity:
         ("hpZ", {"zero_hpz_partition_size": 2}),
     ])
     def test_fused_matches_unfused(self, kernels, mode, zero_over):
-        """The fused kernel must be numerically invisible: ulp-tight
-        parameters after 3 steps under every compression config."""
+        """The gate reaches no compiled step: the same parameters, to the
+        bit, after 3 steps under every compression config, whichever way
+        the selection rule answers for ``fused_adam``."""
         cfg = adamw_config(**zero_over)
         e_off = run_engine(kernels, fused=False, config=cfg)
         e_on = run_engine(kernels, fused=True, config=cfg)
-        assert_tree_close(e_off.state.params, e_on.state.params,
+        assert_tree_equal(e_off.state.params, e_on.state.params,
                           f"params diverged under {mode}")
-        assert_tree_close(e_off.state.opt_state, e_on.state.opt_state,
+        assert_tree_equal(e_off.state.opt_state, e_on.state.opt_state,
                           f"opt state diverged under {mode}")
+
+    @pytest.mark.parametrize("variant", ["adamw_static", "adamw_sched",
+                                         "adam"])
+    def test_no_step_program_holds_the_kernel(self, kernels, variant):
+        """Where the rule says kernels run and the mesh is one device, the
+        fused train step and the apply step are still the optax chain: no
+        ``pallas_call`` in either (the model brings none of its own)."""
+        cfg = {"train_micro_batch_size_per_gpu": 8, "gradient_clipping": 1.0,
+               "optimizer": {"type": "Adam" if variant == "adam" else "AdamW",
+                             "params": {"lr": 1e-2}}}
+        if variant != "adam":
+            cfg["optimizer"]["params"]["weight_decay"] = 0.01
+        if variant == "adamw_sched":
+            cfg["scheduler"] = {"type": "WarmupLR",
+                                "params": {"warmup_min_lr": 0.0,
+                                           "warmup_max_lr": 1e-2,
+                                           "warmup_num_steps": 4}}
+        kernels("fused_adam")
+        try:
+            engine = one_device_engine(cfg)
+            assert engine._fused_opt_active()
+            assert callable(engine._fused_opt_spec["lr"]) == (
+                variant == "adamw_sched")
+            st = engine.state
+            carry = (st.params, st.opt_state, st.scaler, st.skipped)
+            x, y = batch(0)
+            programs = {
+                "fused": (engine._build_fused_step(),
+                          (carry, (x[None], y[None]), jax.random.PRNGKey(0))),
+                "apply": (engine._build_apply_step(),
+                          (st.params, st.opt_state, st.params, st.scaler,
+                           st.skipped))}
+            for name, (fn, args) in programs.items():
+                text = str(jax.make_jaxpr(lambda *a: fn(*a))(*args))
+                assert "pallas_call" not in text, name
+        finally:
+            mesh_lib.reset_mesh()
 
     def test_gate_rejects_multi_device_mesh(self, kernels):
         kernels("fused_adam")

@@ -39,14 +39,17 @@ def test_train_kernels_are_names_the_kernel_files_give():
     it waits for is one a kernel file gives (a renamed or merged kernel,
     like the cross-entropy's one backward, must be followed here)."""
     import re
-    from deepspeed_tpu.ops.pallas import (cross_entropy, flash_attention,
-                                          fused_optim)
+    from deepspeed_tpu.ops.pallas import cross_entropy, flash_attention
     given = set()
-    for module in (cross_entropy, flash_attention, fused_optim):
+    for module in (cross_entropy, flash_attention):
         with open(module.__file__) as f:
             given |= set(re.findall(r'name="(\w+)"', f.read()))
     assert set(chip_smoke.TRAIN_KERNELS) <= given
     assert {n for n in given if n.startswith("ce_")} == {"ce_fwd", "ce_bwd"}
+    # Adam is the optax chain in every compiled step: the five kernels of
+    # flash and cross-entropy, and no ``fused_adam`` (the NVMe walk's)
+    assert sorted(chip_smoke.TRAIN_KERNELS) == [
+        "ce_bwd", "ce_fwd", "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
 
 
 @pytest.fixture
