@@ -52,12 +52,15 @@ class LayerKind(NamedTuple):
     the blocks the query chooses (``sparse``: :class:`SparseSpec`), a
     ``linear`` recurrence whose cache is a state, attention inside a latent
     convolved over time (``cca``), the gated ``delta`` rule whose state is
-    corrected before it is written, or, beside one of those in the same
-    walk, ``full`` softmax attention over pages (all ``models/hybrid.py``).
+    corrected before it is written, attention over the TOKENS an indexer
+    scores highest (``indexed``: :class:`IndexerSpec`), or, beside one of
+    those in the same walk, ``full`` softmax attention over pages (all
+    ``models/hybrid.py``).
     ``depth`` is the layer's index in the PUBLISHED stack where that differs
     from its place here (a linear layer's decay reads it).  ``ffn`` is the
-    layer's feed-forward, ``mlp`` or ``moe`` (None: the model's one kind, an
-    expert bank where ``moe_num_experts`` says so)."""
+    layer's feed-forward, ``mlp``, ``moe`` (a bank behind the MLP router with
+    its stream) or ``moe_softmax`` (a bank behind the linear softmax router);
+    None: the model's one kind, ``moe`` where ``moe_num_experts`` says so."""
     window: Optional[int] = None
     rope: bool = True
     mixer: str = "softmax"
@@ -78,6 +81,17 @@ class SparseSpec(NamedTuple):
     init_blocks: int = 1
     window: int = 2048
     dense_len: int = 8192
+
+
+class IndexerSpec(NamedTuple):
+    """The lightning indexer of DeepSeek Sparse Attention (DeepSeek-V3.2-Exp;
+    the Keye-VL-2.0 line's ``sa_config``): ``heads`` index queries of
+    ``head_dim`` lanes a token score every cached index key (ONE of
+    ``head_dim`` lanes a token a layer), and the query attends the ``topk``
+    TOKENS it scores highest (``models/hybrid.py:indexed_mixer``)."""
+    heads: int = 16
+    head_dim: int = 64
+    topk: int = 2048
 
 
 class YarnRope(NamedTuple):
@@ -234,6 +248,8 @@ class GPTConfig:
     residual_scale: float = 1.0
     head_divisor: float = 1.0
     published_layers: Optional[int] = None
+    # the ``indexed`` layers' indexer (``models/hybrid.py:indexed_mixer``)
+    indexer: Optional[IndexerSpec] = None
     # --- the gated delta rule (the Olmo-Hybrid family's ``delta`` layers,
     # ``models/hybrid.py:delta_mixer``): ``delta_heads`` heads, a key of
     # ``delta_key_dim`` and a value of ``delta_value_dim`` lanes, behind a
@@ -309,13 +325,14 @@ class GPTConfig:
         self.hybrid = any(m != "softmax" for m in self.mixers)
         if self.hybrid:
             assert len(self.pattern) == self.n_layer and all(
-                m in ("sparse", "linear", "cca", "delta", "full")
+                m in ("sparse", "linear", "cca", "delta", "indexed", "full")
                 for m in self.mixers) and set(self.mixers) != {"full"}, (
                     "a hybrid stack names every layer: sparse, linear, cca, "
-                    "delta, or full beside one of them")
+                    "delta, indexed, or full beside one of them")
             assert "delta" not in self.mixers or (
                 self.delta_heads and self.delta_key_dim and self.delta_value_dim
                 and self.delta_conv >= 2), "a delta layer's heads and widths"
+            self.indexer = IndexerSpec(*(self.indexer or ()))
             self.sparse = SparseSpec(*(self.sparse or ()))
             sp = self.sparse
             assert sp.kernel == 2 * sp.stride and sp.block % sp.stride == 0 \
@@ -324,14 +341,29 @@ class GPTConfig:
             assert (self.norm == "rmsnorm" and self.mlp_type == "swiglu"
                     and not self.use_bias and not self.kv_lora_rank
                     and self.block_type == "sequential")
-            assert all(f in ("mlp", "moe") for f in self.ffns) and (
-                "moe" not in self.ffns or (
-                    self.moe_router == "dropless" and self.moe_router_hidden
-                    and not self.moe_shared_experts
-                    and self.moe_experts_held is None)), (
-                        "a hybrid stack's expert layers: the whole bank "
-                        "behind the dropless MLP router with its stream")
-            assert not self.norm_after or "moe" not in self.ffns, (
+            # a hybrid stack's expert layers: the WHOLE bank, no token
+            # dropped, behind the MLP router with its stream ("moe") or the
+            # linear softmax router ("moe_softmax").  Still unwritten in the
+            # walk: the sigmoid router with its correction bias, a shared
+            # expert beside the bank, a held share of the bank, and a router
+            # that reads the mixer's input (ROADMAP M9 (b))
+            banks = [f for f in self.ffns if f != "mlp"]
+            assert all(f in ("moe", "moe_softmax") for f in banks), self.ffns
+            assert not banks or (
+                self.moe_router == "dropless" and not self.moe_shared_experts
+                and self.moe_experts_held is None), (
+                    "a hybrid stack's expert layers: the whole bank behind a "
+                    "dropless router, no shared expert beside it")
+            assert "moe" not in banks or self.moe_router_hidden, (
+                "the 'moe' feed-forward is the MLP router with its stream: "
+                "moe_router_hidden")
+            assert "moe_softmax" not in banks or (
+                self.moe_scoring == "softmax" and not self.moe_router_hidden
+                and self.moe_router_input == "post_attn"), (
+                    "the 'moe_softmax' feed-forward is the linear softmax "
+                    "router over the MLP's normed input; the sigmoid router "
+                    "and a router before attention are the periodic path's")
+            assert not self.norm_after or not banks, (
                 "the norm on a sublayer's output is the dense MLP's")
         else:
             assert not self.norm_after, (
@@ -607,6 +639,35 @@ def olmo_hybrid_config(vocab_size=100352, n_positions=65536, n_embd=3840,
     kw.update(overrides)
     return llama_config(vocab_size=vocab_size, n_positions=n_positions,
                         n_embd=n_embd, n_layer=len(pattern), n_head=n_head,
+                        intermediate_size=intermediate_size, **kw)
+
+
+def keye_vl2_config(vocab_size=151936, n_positions=262144, n_embd=2048,
+                    n_layer=48, n_head=32, n_kv_head=4, head_dim=128,
+                    intermediate_size=768, num_experts=128, top_k=8,
+                    indexer=(16, 64, 2048), **overrides) -> GPTConfig:
+    """Keye-VL-2.0 family, the language model (defaults: Keye-VL-2.0-30B-A3B's
+    widths): every layer grouped-query attention (``n_head`` query heads on
+    ``n_kv_head`` K/V heads, an RMSNorm over each head's lanes of q and of k,
+    rope over all lanes) under DeepSeek Sparse Attention: a lightning
+    ``indexer`` (heads, lanes a head, ``topk``: :class:`IndexerSpec`) scores
+    every cached token and the query attends the ``topk`` TOKENS it scores
+    highest (``models/hybrid.py:indexed_mixer``); then a bank of
+    ``num_experts`` SwiGLU experts ``intermediate_size`` wide, ``top_k`` a
+    token renormalised behind a linear softmax router, no shared expert, no
+    token dropped.  RMSNorm (eps 1e-6), no bias, untied head, rope theta 1e7
+    (text positions: the three streams of ``mrope_section`` are one).  Served
+    through ``init_serving()`` (``models/hybrid.py``); the dense paths refuse
+    it."""
+    kw = dict(n_kv_head=n_kv_head, head_dim=head_dim, ln_eps=1e-6,
+              rope_theta=1e7, indexer=tuple(indexer),
+              layer_pattern=n_layer * (
+                  LayerKind(None, True, "indexed", ffn="moe_softmax"),),
+              moe_num_experts=num_experts, moe_top_k=top_k,
+              moe_router="dropless", moe_norm_topk=True)
+    kw.update(overrides)
+    return llama_config(vocab_size=vocab_size, n_positions=n_positions,
+                        n_embd=n_embd, n_layer=n_layer, n_head=n_head,
                         intermediate_size=intermediate_size, **kw)
 
 

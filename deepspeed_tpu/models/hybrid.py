@@ -4,10 +4,28 @@ its feed-forward), and ONE walk (:func:`hybrid_paged_step`) runs the stack
 in runs of a mixer, dispatching on both.  The MiniCPM-SALA family
 (``models/gpt.py:minicpm_sala_config``: sparse and linear layers in no
 period over a dense MLP), the ZAYA1 family (``zaya_config``: ``cca`` layers
-over an expert bank) and the Olmo-Hybrid family (``olmo_hybrid_config``:
+over an expert bank), the Olmo-Hybrid family (``olmo_hybrid_config``:
 ``delta`` layers, three to every ``full`` layer, each norm on its sublayer's
-output) are entries of :data:`MIXERS` and :data:`FEED_FORWARDS`.  The mixers
-(``cca`` is described at :func:`cca_mixer`):
+output) and the Keye-VL-2.0 family (``keye_vl2_config``: ``indexed`` layers
+over a bank behind a linear softmax router) are entries of :data:`MIXERS`
+and :data:`FEED_FORWARDS`.  The mixers (``cca`` is described at
+:func:`cca_mixer`):
+
+* ``indexed`` (DeepSeek Sparse Attention under grouped-query attention, the
+  Keye-VL-2.0 line): beside K and V a token caches ONE index key of 64 lanes
+  a layer (its own projection, a LayerNorm, rope), in pages under the same
+  block tables (``aux["ki"]``).  A query's 16 index heads score EVERY cached
+  token (``I = sum_j w_j relu(qI_j . kI_s)``: 128 B a key a layer read), the
+  ``topk`` tokens that score highest are found exactly (ties to the lower
+  position: a decode row's positions by a sort, a prompt chunk's mask by a
+  threshold found by bisection over the scores' bit patterns), and the
+  query's 32 heads attend those tokens alone.  A decode row gathers
+  their K and V a token at a time out of the pages (a token's four K/V
+  heads lie side by side in a page, so a key is one 1 KB row) and reads no
+  key it did not choose; a prompt chunk's 512 queries, each with a set of
+  its own, attend their sequence's K and V read once under the selection's
+  mask (``ops/pallas/indexed_attention.py``).  With ``topk`` keys or fewer
+  every key is chosen and the layer is plain causal attention;
 
 * ``sparse`` (the InfLLM-V2 line of MiniCPM4): grouped-query attention
   without rope whose query, once more than ``dense_len`` keys lie before it,
@@ -112,10 +130,12 @@ def arena_layout(cfg) -> Tuple[int, int, Tuple[int, ...]]:
     the pages, a K/V head a page of its own (the selection differs by K/V
     head, so the kernel walks a list of pages a head); the cca layers own
     them, a page a block of all K/V heads like any grouped-query model's,
-    and so do the full layers; the linear and the delta layers own none."""
+    and so do the indexed and the full layers (an indexed layer's index keys
+    ride in ``aux`` under the same tables); the linear and the delta layers
+    own none."""
     paged = {m for m in cfg.mixers if m not in ("linear", "delta")}
     assert len(paged) <= 1, f"one mixer's layers own the pages, not {paged}"
-    for m in ("cca", "full"):
+    for m in ("cca", "indexed", "full"):
         if m in paged:
             return cfg.mixers.count(m), 1, (cfg.kv_heads * cfg.head_dim,) * 2
     return cfg.mixers.count("sparse"), cfg.kv_heads, (cfg.head_dim,) * 2
@@ -133,7 +153,10 @@ def what_a_dense_path_lacks(cfg) -> str:
                     f"stream of the {n('cca')} cca layers",
              "delta": f"no chunked delta-rule scan (nor its backward) and no "
                       f"convolution over time of the packed q/k/v for the "
-                      f"{n('delta')} delta layers"}
+                      f"{n('delta')} delta layers",
+             "indexed": f"no lightning indexer, no cache of index keys and no "
+                        f"selection of the tokens a query attends for the "
+                        f"{n('indexed')} indexed layers"}
     return " and ".join(lacks[m] for m in lacks if m in cfg.mixers)
 
 
@@ -147,7 +170,9 @@ def what_no_block_carries(cfg) -> str:
                     f"(the last two packed latents and the next token's "
                     f"shifted value half)",
              "delta": f"{n('delta')} delta layers hold a recurrent state and "
-                      f"a convolution state a slot"}
+                      f"a convolution state a slot",
+             "indexed": f"{n('indexed')} indexed layers a cache of index keys "
+                        f"the selection scores"}
     return "this model's " + " and its ".join(
         holds[m] for m in holds if m in cfg.mixers)
 
@@ -182,6 +207,14 @@ def _mixer_shapes(cfg, mixer: str) -> Dict:
     if mixer == "full":
         return {"qkv_w": (E, cfg.qkv_dim), "out_w": (A, E),
                 "q_norm_g": (A,), "k_norm_g": (cfg.kv_heads * D,)}
+    if mixer == "indexed":
+        # index_w: [W_qI (heads x lanes) | W_kI (lanes) | W_w (heads)]; the
+        # index key's LayerNorm has a gain and a bias; q and k a norm a head
+        ix = cfg.indexer
+        return {"qkv_w": (E, cfg.qkv_dim), "out_w": (A, E),
+                "q_norm_g": (D,), "k_norm_g": (D,),
+                "index_w": (E, (ix.heads + 1) * ix.head_dim + ix.heads),
+                "ik_norm_g": (ix.head_dim,), "ik_norm_b": (ix.head_dim,)}
     if mixer == "delta":
         # qkv_w: [W_q | W_k | W_v], the packed lanes the convolution runs
         # over (conv_w: tap 0 the oldest token's); gate_w the output gate z;
@@ -208,13 +241,15 @@ def _ffn_shapes(cfg, ffn: str) -> Dict:
     if ffn == "mlp":
         return {"fc_w": (E, 2 * cfg.ffn_dim), "proj_w": (cfg.ffn_dim, E)}
     N, R, I = cfg.moe_num_experts, cfg.moe_router_hidden, cfg.moe_expert_hidden or cfg.ffn_dim
+    experts = {"wi": (N, E, 2 * I), "wo": (N, I, E)}
+    if ffn == "moe_softmax":
+        return {"router_w": (E, N), "experts": experts}
     # the router: down to its stream's width, the stream of the layer before
     # times stream_g, a norm, three matrices; balance_bias chooses and never
     # weighs.  The bank is a group of its own, as the other MoE families' is
     return {"router_in_w": (E, R), "stream_g": (), "router_norm_g": (R,),
             "router_w1": (R, R), "router_w2": (R, R), "router_w3": (R, N),
-            "balance_bias": (N,),
-            "experts": {"wi": (N, E, 2 * I), "wo": (N, I, E)}}
+            "balance_bias": (N,), "experts": experts}
 
 
 def _leaf_shapes(cfg, mixer: str) -> Dict:
@@ -228,14 +263,14 @@ def _is_shape(x) -> bool:
 
 def init_blocks(cfg, rng: Array) -> Dict:
     """``{mixer: leaves [that mixer's layers, ...]}``: gains (``*_g``) 1, the
-    balancing bias and the decay's bias 0, a delta head ``h``'s ``a_log``
+    balancing bias, the decay's bias and the index key's norm's 0, a delta head ``h``'s ``a_log``
     ``log(0.02 (h + 1))`` (with ``a_t`` near 0 the heads then forget over 72
     down to 2.4 tokens: none is dead, none unbounded), every other leaf
     normal 0.02."""
     def leaf(name, key, shape):
         if name.endswith("_g"):
             return jnp.ones(shape, jnp.float32)
-        if name in ("balance_bias", "dt_bias"):
+        if name in ("balance_bias", "dt_bias", "ik_norm_b"):
             return jnp.zeros(shape, jnp.float32)
         if name == "a_log":
             return jnp.log(0.02 * (jnp.arange(shape[0], dtype=jnp.float32) + 1.0))
@@ -304,8 +339,14 @@ def init_aux(cfg, num_blocks: int, block_size: int, slots: int, dtype) -> Dict:
     d_k, H * d_v]`` float32 (a head's ``[d_k, d_v]`` beside the other heads'
     on the lanes: ``ops/pallas/delta_rule.py`` says why) and ``delta_conv
     [delta layers, slots, taps - 1, U]``: a slot's last packed ``[q | k |
-    v]`` rows, the oldest first."""
+    v]`` rows, the oldest first; ``ki [indexed layers, num_blocks, block_size,
+    index lanes]``: the index keys of a page, reached through the block
+    tables like K and V (allocated, freed and re-bound with them: a slot
+    bound to a new sequence scores no former tenant's, BY POSITION)."""
     D, out = cfg.head_dim, {}
+    if "indexed" in cfg.mixers:
+        out["ki"] = jnp.zeros((cfg.mixers.count("indexed"), num_blocks,
+                               block_size, cfg.indexer.head_dim), dtype)
     if "delta" in cfg.mixers:
         L, H = cfg.mixers.count("delta"), cfg.delta_heads
         out.update(
@@ -837,6 +878,216 @@ def delta_mixer(cfg, p, h, kp, vp, held, li, step: _Step):
 
 
 # --------------------------------------------------------------------------- #
+# The indexed mixer
+# --------------------------------------------------------------------------- #
+def indexed_keys_attended(cfg, positions: np.ndarray) -> np.ndarray:
+    """Keys a query at each of ``positions`` attends in ONE indexed layer
+    (its heads share the set): all ``t + 1`` up to ``topk``, then ``topk``."""
+    return np.minimum(np.asarray(positions, np.int64) + 1, cfg.indexer.topk)
+
+
+# bits of the k-th largest score found a pass: ``2^bits - 1`` counts over the
+# scores a pass, ``32 / bits`` passes (PERF.md section 6, PR 51: the chip's
+# reading of 1, 2 and 4)
+_BITS_A_PASS = 2
+
+
+def _sortable(x: Array) -> Array:
+    """float32 -> uint32 in the same order (-inf lowest)."""
+    b = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    return jnp.where(b >> 31 == 1, ~b, b | jnp.uint32(1 << 31))
+
+
+def _counts_before(x: Array, G: int):
+    """``x [n, T]`` of 0 / 1, ``T`` whole groups of ``G`` -> (inclusive count
+    inside each group ``[n, T / G, G]``, inclusive count over the groups ``[n,
+    T / G]``), float32 and exact: a triangular product a group, then a
+    cumulative sum over the groups."""
+    n, T = x.shape
+    tri = (jnp.arange(G)[:, None] <= jnp.arange(G)[None]).astype(jnp.bfloat16)
+    inside = jnp.einsum("npg,gh->nph", x.reshape(n, T // G, G).astype(jnp.bfloat16),
+                        tri, preferred_element_type=jnp.float32)
+    return inside, jnp.cumsum(inside[..., -1], axis=1)
+
+
+def chosen_tokens(scores: Array, k: int, G: int) -> Array:
+    """Which ``k`` of ``scores [n, T]`` (float32) a row are largest, EXACTLY
+    (of equal scores the lower positions; never one at -inf, so a row with
+    fewer than ``k`` scores above it chooses fewer): ``[n, T]`` bool.  ``T``
+    is whole groups of ``G``.  No sort: the ``k``-th largest score is found
+    by bisection over the scores' bit patterns, :data:`_BITS_A_PASS` bits a
+    pass; what lies above it and the first of what equals it is the set.
+    A mask is what a prompt chunk's attention takes; where the positions
+    themselves are wanted (a decode row's gather) ``jax.lax.top_k`` gives the
+    same set."""
+    n, T = scores.shape
+    assert T % G == 0 and k <= T and 32 % _BITS_A_PASS == 0, (T, G, k)
+    u = _sortable(scores)
+    kth = jnp.zeros((n,), jnp.uint32)
+    digits = jnp.arange(1, 1 << _BITS_A_PASS, dtype=jnp.uint32)
+    for shift in range(32 - _BITS_A_PASS, -1, -_BITS_A_PASS):
+        cand = kth[:, None] | (digits << shift)[None]
+        enough = jnp.sum(u[:, None, :] >= cand[:, :, None], axis=-1) >= k
+        kth = kth | (jnp.sum(enough, axis=-1).astype(jnp.uint32) << shift)
+    above, equal = u > kth[:, None], u == kth[:, None]
+    wanted = (k - jnp.sum(above, axis=-1)).astype(jnp.float32)     # of the equal ones
+    inside, groups = _counts_before(equal, G)
+    rank = (inside + (groups - inside[..., -1])[..., None]).reshape(n, T)
+    return (above | (equal & (rank <= wanted[:, None]))) & (scores > -jnp.inf)
+
+
+def chosen_positions(scores: Array, k: int):
+    """The positions :func:`chosen_tokens` chooses, where they themselves are
+    wanted (a decode row's gather): -> (``[n, k]`` int32, which of them are
+    real ``[n, k]``: a row with fewer than ``k`` scores above -inf has
+    fewer).  A sort: of equal scores ``jax.lax.top_k`` takes the lower
+    position first, and over 8 rows it gives the positions as fast as the
+    bisection gives a mask (PERF.md section 6, PR 51)."""
+    top, at = jax.lax.top_k(scores, k)
+    return at, top > -jnp.inf
+
+
+def index_scores(cfg, qi: Array, w: Array, keys: Array) -> Array:
+    """``I_{t,s} = (lanes heads)^-1/2 sum_j w_{t,j} relu(qI_{t,j} . kI_s)``:
+    ``qi [n, heads, lanes]``, ``w [n, heads]`` float32, ``keys [n | 1, T,
+    lanes]`` in the type they are cached in (one row where the queries share
+    a sequence) -> ``[n, T]`` float32."""
+    ix = cfg.indexer
+    keys = keys.astype(qi.dtype)
+    eq = "njd,td->njt" if keys.shape[0] == 1 else "njd,ntd->njt"
+    s = jnp.einsum(eq, qi, keys[0] if keys.shape[0] == 1 else keys,
+                   preferred_element_type=jnp.float32)
+    return jnp.einsum("njt,nj->nt", jax.nn.relu(s), w) / math.sqrt(
+        ix.head_dim * ix.heads)
+
+
+# queries of a prompt chunk that are scored and select together: a tile's
+# scores are ``[tile, heads, keys]`` float32 (128 x 16 x 46,080 x 4 B = 377 MB)
+_CHUNK_TILE = 128
+
+
+def indexed_chunk_tile(chunk: int) -> int:
+    """Queries of a prompt chunk of ``chunk`` that select together: the whole
+    chunk, or a tile of it; 0 where the chunk is not whole tiles (which
+    ``init_serving`` refuses)."""
+    tile = min(chunk, _CHUNK_TILE)
+    return tile if chunk % tile == 0 else 0
+
+
+def indexed_mixer(cfg, p, h, kp, vp, held, li, step: _Step):
+    """One indexed layer's attention over the rows ``h [B, E]``: -> (the
+    mixer's output ``[B, E]`` before the residual, kp, vp, held with its
+    index keys ``ki`` written).  With ``h_t`` the layer's normed input:
+
+        q_t = rope(rms_h(W_q h_t)), k_t = rope(rms_h(W_k h_t)), v_t = W_v h_t
+        qI_{t,j} = rope(W_qI h_t)_j, kI_t = rope(LN(W_kI h_t)), w_t = W_w h_t
+        I_{t,s} = (lanes heads)^-1/2 sum_j w_{t,j} relu(qI_{t,j} . kI_s), s <= t
+        S_t = the topk positions s <= t of largest I_{t,s} (ties: the lower)
+        o_{t,h} = sum_{s in S_t} softmax_s(q_{t,h} . k_{s,g(h)} / sqrt(D)) v_{s,g(h)}
+
+    ``rms_h`` an RMS norm over a head's lanes with one gain for all heads,
+    ``LN`` a LayerNorm with gain and bias, rope the half-split rotation over
+    all lanes of a head (of an index head too).  The index keys are cached in
+    the pages' type; the scores are float32.  A decode row gathers its index
+    keys under its own table, sorts its scores for the ``topk`` positions and
+    gathers those tokens' K and V; the prompt chunk's rows share one table,
+    select a tile of :data:`_CHUNK_TILE` queries at a time, and attend the
+    sequence's K and V under the selection's mask
+    (``ops/pallas/indexed_attention.py``): the same mathematics."""
+    positions, live, _, tables, write_blocks, write_offsets, chunk, dt, _, _ = step
+    ix = cfg.indexer
+    B = h.shape[0]
+    H, Hkv, D = cfg.n_head, cfg.kv_heads, cfg.head_dim
+    g, BS, T = H // Hkv, kp.shape[2], tables.shape[1] * kp.shape[2]
+    K, n_dec = min(ix.topk, T), B - chunk
+    rope = lambda t, at: gpt.apply_rope(t[:, None], at[:, None], cfg.rope_theta)[:, 0]
+
+    def project(r, at):
+        q, k, v = gpt._split_qkv(cfg, (r @ gpt._wget(p, "qkv_w", dt))[:, None])
+        norm = lambda t, name: gpt.rms_norm(t[:, 0], p[name], eps=cfg.ln_eps)
+        return rope(norm(q, "q_norm_g"), at), rope(norm(k, "k_norm_g"), at), v[:, 0]
+
+    def index_project(r, at):
+        qi, ki, w = jnp.split(r @ gpt._wget(p, "index_w", dt),
+                              [ix.heads * ix.head_dim, (ix.heads + 1) * ix.head_dim], axis=-1)
+        ki = gpt.layer_norm(ki, p["ik_norm_g"], p["ik_norm_b"], eps=cfg.ln_eps)
+        return (rope(qi.reshape(-1, ix.heads, ix.head_dim), at),
+                rope(ki[:, None], at)[:, 0], w.astype(jnp.float32))
+
+    q, k, v = _rows_that_carry(project, (h, positions), chunk, live)
+    kp = kp.at[li, write_blocks, write_offsets].set(k.astype(kp.dtype).reshape(B, 1, -1))
+    vp = vp.at[li, write_blocks, write_offsets].set(v.astype(vp.dtype).reshape(B, 1, -1))
+    with jax.named_scope("index_score"):
+        qi, ki, w = _rows_that_carry(index_project, (h, positions), chunk, live)
+        pages = held["ki"].at[li, write_blocks, write_offsets].set(
+            ki.astype(held["ki"].dtype)[:, None])
+
+    def scores_of(qi, w, at, keys):
+        """``I_{t,s}`` of the queries at positions ``at [n]`` over their
+        sequences' index keys ``keys [n | 1, T, lanes]``, -inf past each."""
+        with jax.named_scope("index_score"):
+            return jnp.where(jnp.arange(T)[None] <= at[:, None],
+                             index_scores(cfg, qi, w, keys), -jnp.inf)
+
+    def under(tb):
+        """The index keys under the tables ``tb [n, MB]``, in logical order."""
+        with jax.named_scope("index_score"):
+            return pages[li, tb].reshape(tb.shape[0], T, ix.head_dim)
+
+    def decode_rows():
+        """A row its own sequence: the chosen tokens' K and V gathered out of
+        the pages a token at a time (a token's K/V heads lie side by side in
+        a page: one row a key), then attended densely."""
+        tb = tables[:n_dec]
+        scores = scores_of(qi[:n_dec], w[:n_dec], positions[:n_dec], under(tb))
+        with jax.named_scope("index_topk"):
+            at, real = chosen_positions(scores, K)
+        with jax.named_scope("index_attend"):
+            page = jnp.take_along_axis(tb, at // BS, axis=1)
+            kg = kp[li, page, at % BS].reshape(n_dec, K, Hkv, D)
+            vg = vp[li, page, at % BS].reshape(n_dec, K, Hkv, D)
+            s = jnp.einsum("nhgd,nkhd->nhgk", q[:n_dec].reshape(n_dec, Hkv, g, D), kg,
+                           preferred_element_type=jnp.float32) / math.sqrt(D)
+            a = jax.nn.softmax(jnp.where(real[:, None, None], s, -1e30), axis=-1)
+            return jnp.einsum("nhgk,nkhd->nhgd", a.astype(vg.dtype), vg,
+                              preferred_element_type=jnp.float32
+                              ).astype(dt).reshape(n_dec, H * D)
+
+    def over_the_chunk():
+        """One sequence under one table: each query chooses its tokens, a
+        tile of queries at a time, and the chunk attends the sequence's K
+        and V (read once) under the selection's mask."""
+        from deepspeed_tpu.ops.pallas.indexed_attention import masked_chunk_attention
+        tile = indexed_chunk_tile(chunk)
+        assert tile, f"a chunk of {chunk} is not whole tiles of {_CHUNK_TILE} queries"
+        tb = tables[n_dec:n_dec + 1]
+        keys = under(tb)
+        tiles = lambda a: a[n_dec:].reshape(chunk // tile, tile, *a.shape[1:])
+
+        def choose(a):
+            scores = scores_of(*a, keys)
+            with jax.named_scope("index_topk"):
+                return chosen_tokens(scores, K, BS)
+
+        chosen = jax.lax.map(choose, (tiles(qi), tiles(w), tiles(positions))
+                             ).reshape(chunk, T)
+        with jax.named_scope("index_attend"):
+            last = jnp.max(jnp.where(live[n_dec:], positions[n_dec:], 0))
+            return masked_chunk_attention(
+                q[n_dec:], kp[li, tb[0]].reshape(T, Hkv * D),
+                vp[li, tb[0]].reshape(T, Hkv * D), chosen, last).astype(dt)
+
+    o = decode_rows()
+    if chunk:
+        # a step without a prompt chunk skips the chunk rows' scores, their
+        # selection and their attend: nobody reads them
+        o = jnp.concatenate([o, jax.lax.cond(
+            live[n_dec], over_the_chunk, lambda: jnp.zeros((chunk, H * D), o.dtype))])
+    o = _rows_that_carry(lambda r: r @ gpt._wget(p, "out_w", dt), (o,), chunk, live)
+    return o, kp, vp, dict(held, ki=pages)
+
+
+# --------------------------------------------------------------------------- #
 # The full mixer
 # --------------------------------------------------------------------------- #
 def full_mixer(cfg, p, h, kp, vp, held, li, step: _Step):
@@ -876,36 +1127,58 @@ def mlp_ffn(cfg, p, bank, li, x, stream, step: _Step):
     return _rows_that_carry(mlp, (x,), chunk, live), stream, None
 
 
-def moe_ffn(cfg, p, bank, li, x, stream, step: _Step):
-    """A bank of SwiGLU experts with ``moe_top_k`` a token behind the MLP
-    router, which reads the layer's normed input and the stream ``[B, R]``
-    the router before it left.  ``bank``: the mixer's stacked expert leaves
-    ``[layers, experts, ...]``, which ``grouped_matmul`` reads at layer
-    ``li`` where they lie.  -> (output, the stream for the next layer, the
-    live rows' assignments an expert ``[experts]`` int32)."""
+def _stream_route(cfg, p, z, stream):
+    """The MLP router that reads the layer's normed input and the stream
+    ``[B, R]`` the router before it left (the ZAYA1 line): the largest of
+    softmax + balancing bias, weighed by the softmax."""
     from deepspeed_tpu.moe import dropless
-    live, chunk, dt = step.live, step.chunk, step.dt
-    N = cfg.moe_num_experts
-    leaves = {"fc_w": bank["wi"], "proj_w": bank["wo"]}
+    stream, logits = dropless.stream_mlp_logits(
+        z, stream, p["router_in_w"], p["stream_g"], p["router_norm_g"],
+        [p["router_w1"], p["router_w2"], p["router_w3"]], cfg.ln_eps)
+    _, weights, experts = dropless.biased_softmax_topk(
+        logits, cfg.moe_top_k, p["balance_bias"])
+    return stream, weights, experts
 
-    def rows(x, stream):
-        z = gpt.rms_norm(x, p["ln2_g"], eps=cfg.ln_eps)
-        with jax.named_scope("moe"):
-            with jax.named_scope("moe_router"):
-                stream, logits = dropless.stream_mlp_logits(
-                    z, stream, p["router_in_w"], p["stream_g"],
-                    p["router_norm_g"], [p["router_w1"], p["router_w2"],
-                                         p["router_w3"]], cfg.ln_eps)
-                _, weights, experts = dropless.biased_softmax_topk(
-                    logits, cfg.moe_top_k, p["balance_bias"])
-            y = dropless.dropless_moe(
-                z, weights, experts, N,
-                lambda r, matmul, pick: gpt._mlp(cfg, leaves, r, dt, matmul, pick),
-                layer=li)
-        return y.astype(dt), stream, experts
 
-    y, stream, experts = _rows_that_carry(rows, (x, stream), chunk, live)
-    return y, stream, dropless.expert_counts(experts, N, live)
+def _softmax_route(cfg, p, z, stream):
+    """The linear softmax router over the layer's normed input, in float32,
+    as ``models/gpt.py:_ffn`` routes (OLMoE's; with ``moe_norm_topk`` the
+    chosen weights renormalised: SmallThinker's, the Keye-VL-2.0 line's).
+    No stream: it goes on as it came (None)."""
+    from deepspeed_tpu.moe import dropless
+    logits = z.astype(jnp.float32) @ p["router_w"].astype(jnp.float32)
+    _, weights, experts = dropless.softmax_topk(logits, cfg.moe_top_k,
+                                                cfg.moe_norm_topk)
+    return stream, weights, experts
+
+
+def _moe_ffn(route):
+    """A bank of SwiGLU experts with ``moe_top_k`` a token behind ``route(cfg,
+    p, z, stream) -> (stream, weights, experts)``.  ``bank``: the mixer's
+    stacked expert leaves ``[layers, experts, ...]``, which ``grouped_matmul``
+    reads at layer ``li`` where they lie.  -> (output, the stream for the
+    next layer, the live rows' assignments an expert ``[experts]`` int32)."""
+    def ffn(cfg, p, bank, li, x, stream, step: _Step):
+        from deepspeed_tpu.moe import dropless
+        live, chunk, dt = step.live, step.chunk, step.dt
+        N = cfg.moe_num_experts
+        leaves = {"fc_w": bank["wi"], "proj_w": bank["wo"]}
+
+        def rows(x, stream=None):
+            z = gpt.rms_norm(x, p["ln2_g"], eps=cfg.ln_eps)
+            with jax.named_scope("moe"):
+                with jax.named_scope("moe_router"):
+                    stream, weights, experts = route(cfg, p, z, stream)
+                y = dropless.dropless_moe(
+                    z, weights, experts, N,
+                    lambda r, matmul, pick: gpt._mlp(cfg, leaves, r, dt, matmul, pick),
+                    layer=li)
+            return y.astype(dt), stream, experts
+
+        y, stream, experts = _rows_that_carry(
+            rows, (x,) if stream is None else (x, stream), chunk, live)
+        return y, stream, dropless.expert_counts(experts, N, live)
+    return ffn
 
 
 # the entries the walk dispatches on: a layer names one of each
@@ -916,8 +1189,10 @@ MIXERS = {"sparse": (sparse_mixer, lambda: jax.named_scope("attn_sparse")),
           "linear": (linear_mixer, lambda: jax.named_scope("attn_linear")),
           "cca": (cca_mixer, lambda: jax.named_scope("attn_cca")),
           "delta": (delta_mixer, lambda: jax.named_scope("attn_delta")),
+          "indexed": (indexed_mixer, lambda: jax.named_scope("attn_indexed")),
           "full": (full_mixer, lambda: jax.named_scope("attn_full"))}
-FEED_FORWARDS = {"mlp": mlp_ffn, "moe": moe_ffn}
+FEED_FORWARDS = {"mlp": mlp_ffn, "moe": _moe_ffn(_stream_route),
+                 "moe_softmax": _moe_ffn(_softmax_route)}
 
 
 # --------------------------------------------------------------------------- #
@@ -935,8 +1210,8 @@ def hybrid_paged_step(cfg, params: Dict, input_ids: Array, positions: Array,
     ``live [B]`` whether it carries one.  A layer is ``x + f(norm(x))`` twice
     or, with ``cfg.norm_after``, ``x + norm(f(x))``.  The walk's carry is the
     residual,
-    the routers' stream (zero before the first layer; None in a stack
-    without expert layers) and the caches.  -> (logits ``[B, 1, V]`` float32,
+    the routers' stream (zero before the first layer; None in a stack whose
+    routers carry none) and the caches.  -> (logits ``[B, 1, V]`` float32,
     k_pages, v_pages, aux) and with ``with_expert_counts`` the live rows'
     assignments ``[layers, experts]`` int32."""
     B, S = input_ids.shape

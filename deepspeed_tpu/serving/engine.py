@@ -424,7 +424,8 @@ class ServingEngine:
         self._moe_experts = int(getattr(mcfg, "moe_num_experts", 0))
         # the rows of expert counts a program hands back behind its tokens:
         # one summed over layers, or a hybrid stack's row a layer
-        self._moe_count_rows = mcfg.ffns.count("moe") if mcfg.hybrid else 1
+        self._moe_count_rows = (sum(f != "mlp" for f in mcfg.ffns)
+                                if mcfg.hybrid else 1)
         if self._moe_experts and mcfg.moe_router != "dropless":
             raise ValueError(
                 f"init_serving: the {mcfg.moe_router!r} router drops tokens "
@@ -467,6 +468,12 @@ class ServingEngine:
                 raise ValueError(
                     f"init_serving: prefill_chunk {cfg.prefill_chunk} is not "
                     f"whole strides of {mcfg.sparse.stride} compressed keys")
+            if ("indexed" in mcfg.mixers
+                    and not hybrid.indexed_chunk_tile(cfg.prefill_chunk)):
+                raise ValueError(
+                    f"init_serving: prefill_chunk {cfg.prefill_chunk} is not "
+                    f"whole tiles of queries that select their tokens together "
+                    f"(models/hybrid.py:indexed_chunk_tile)")
         if len(mcfg.cache_lanes) != 2 and (cfg.kv_tiering or cfg.prefix_cache):
             raise ValueError(
                 "init_serving: kv_tiering and prefix_cache spill and share "
@@ -661,13 +668,24 @@ class ServingEngine:
         written, one a live decode row a layer and one a layer for the
         step's chunk; of the sparse layers, keys the live rows attended against
         the keys resident before them, summed over sparse layers and K/V
-        heads, and live rows at or under ``dense_len``."""
+        heads, and live rows at or under ``dense_len``; of the indexed layers,
+        index keys the live rows scored (every key at or before them), keys
+        they attended (``topk`` at most) and keys resident before them,
+        summed over indexed layers, and the bytes of index keys held."""
         from deepspeed_tpu.models import hybrid
         mcfg = self.module.cfg
         first = rows[self._config.max_batch_size]
         out = {"state_slots_reset": int(first[3] != 0 and first[1] == 0)}
         out.update({name + "_bytes": int(a.nbytes) for name, a in self._aux.items()
                     if name.endswith(("state", "delta_conv"))})
+        if "indexed" in mcfg.mixers:
+            t = rows[rows[:, 3] != 0, 1]
+            per = mcfg.mixers.count("indexed")
+            resident = int((t + 1).sum()) * per         # every one of them is scored
+            out.update(
+                index_keys_scored=resident, indexed_keys_resident=resident,
+                indexed_keys_attended=int(hybrid.indexed_keys_attended(mcfg, t).sum()) * per,
+                index_key_bytes=int(self._aux["ki"].nbytes))
         if "delta" in mcfg.mixers:
             decoding = int((rows[:self._config.max_batch_size, 3] != 0).sum())
             out["delta_state_moves"] = (decoding + int(first[3] != 0)) * mcfg.mixers.count("delta")

@@ -812,3 +812,52 @@ def test_the_olmo_hybrid_step_updates_its_states_in_place(chip):
     # nothing as large as a layer's states is made beside them
     assert not re.search(rf"f32\[{slots},96,5760\]", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+def test_masked_chunk_attention_compiles_at_keye_vl2_heads(chip):
+    """Keye-VL-2.0's prompt chunk as served: 512 queries of 32 heads of 128 on
+    4 K/V heads, over a sequence's K and V of 46,080 keys (a table of 720
+    pages of 64) under a mask a query: the kernel, and nothing as large as
+    the dense scores."""
+    from deepspeed_tpu.ops.pallas import indexed_attention as ia
+    C, H, Hkv, D128, T = 512, 32, 4, 128, 720 * 64
+    assert ia.kernel_shape_ok(C, D128, T, BF16) and ia.key_tile(T) == 512
+    text = _compiled_text(chip, ia.masked_chunk_attention, ((C, H, D128), BF16),
+                          ((T, Hkv * D128), BF16), ((T, Hkv * D128), BF16),
+                          ((C, T), jnp.bool_), ((), jnp.int32))
+    assert text.count(f'"{ia.KERNEL}"') >= 1 or ia.KERNEL in text
+    assert not re.search(rf"f32\[\d+,\d+,{T}\]", text)
+
+
+def test_the_keye_vl2_step_selects_tokens_and_reads_its_bank_in_place(chip):
+    """The whole step of two indexed layers at the published widths, 8 slots
+    and a chunk of 512 under tables of 720 pages: the masked chunk attend
+    under the branch a step without a prompt skips, the bank's grouped
+    matmuls over the stacked leaves, ONE sort in the mixer (the 8 decode
+    rows' ``top_k``; the chunk's selection is a bisection), and no layer of K,
+    V or index keys sliced out of its arena."""
+    from deepspeed_tpu.models import gpt, hybrid
+    from deepspeed_tpu.ops.pallas import indexed_attention as ia
+    from deepspeed_tpu.serving.kv_cache import init_arena
+    cfg = gpt.keye_vl2_config(n_layer=2, dtype=BF16)
+    model, slots, chunk, BS, NB, MB = gpt.GPT(cfg), 8, 512, 64, 1025, 720
+    rows = slots + chunk
+    shape = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=chip)
+    on_chip = lambda tree: jax.tree.map(lambda a: shape(a.shape, a.dtype), tree)
+    params = jax.tree.map(lambda p: shape(p.shape, BF16),
+                          jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
+    kp, vp = on_chip(jax.eval_shape(lambda: init_arena(cfg, NB, BS, dtype=BF16)))
+    aux = on_chip(jax.eval_shape(lambda: hybrid.init_aux(cfg, NB, BS, slots, BF16)))
+    step = lambda p, ids, pos, kp, vp, tb, wb, wo, aux, sl, live: model.paged_step(
+        p, ids, pos, kp, vp, tb, wb, wo, chunk=chunk, aux=aux, slots=sl, live=live)
+    text = jax.jit(step).lower(
+        params, shape((rows, 1), jnp.int32), shape((rows,), jnp.int32), kp, vp,
+        shape((rows, MB), jnp.int32), shape((rows, 1), jnp.int32),
+        shape((rows, 1), jnp.int32), aux, shape((rows,), jnp.int32),
+        shape((rows,), jnp.bool_)).compile().as_text()
+    assert ia.KERNEL in text and "grouped_matmul" in text
+    assert text.count("conditional(") >= 2
+    sorts = [l for l in text.splitlines() if " sort(" in l and "attn_indexed" in l]
+    assert sorts and all("index_topk/top_k" in l and "branch_1_fun" not in l for l in sorts)
+    # a layer's pages are never copied out: [1025, 64, 512] K or V, [1025, 64, 64] index keys
+    assert not re.search(r"bf16\[1025,64,(512|64)\]\S* (dynamic-slice|copy)\(", text)

@@ -3,15 +3,17 @@ reference, at the published widths: the chip comparison of the
 ``model-configs`` guide § 3 point 3, for any configuration file that names a
 ``serve`` block and a ``reference`` (``benchmarks/configs/olmoe-1b-7b.json``,
 ``smallthinker-21b-a3b.json``, ``mistral-small-4-119b.json``,
-``minicpm-sala-9b.json``, ``zaya1-8b.json``, ``olmo-hybrid-7b.json``).
+``minicpm-sala-9b.json``, ``zaya1-8b.json``, ``olmo-hybrid-7b.json``,
+``keye-vl-2.0-30b-a3b.json``).
 
 Run standalone on a TPU host (``chiprun --chips 1 -- python
 tools/serve_parity.py benchmarks/configs/smallthinker-21b-a3b.json``); any
 other platform is an error (exit 1).  Seeded bf16 weights; a seeded sample of
 prompts (one of them longer than the model's window, where it has one, than
 the original positions of its YaRN rope, than the ``dense_len`` under
-which its sparse layers attend every key, or than two prompt chunks where
-its delta layers' chunked form must enter with a state) goes
+which its sparse layers attend every key, than the ``topk`` tokens its
+indexed layers keep, or than two prompt chunks where its delta layers'
+chunked form must enter with a state) goes
 through ``init_serving()`` / ``submit().result()`` with the file's slots and
 chunk (prefill in chunks, then decode, on the program's kernels), and each
 served sequence through the file's reference in one full float32 forward pass:
@@ -35,8 +37,11 @@ limits of the cell's own kind where that has a ``judge``), and exits 0
 when that counts nothing wrong.  ``--bank float8_e4m3fn`` serves with the
 expert bank rounded through that type: the reading a limit must REFUSE, exit 1;
 ``--weights float8_e4m3fn`` does the same to every matrix of the blocks (a
-dense model's control).  ``--patch JSON`` lays a patch over the configuration
-file first: the program in FLOAT32 against the reference, at a few layers of
+dense model's control); ``--index-keys float8_e4m3fn`` caches an indexed
+stack's index keys in that type (the weights whole: the selection alone is
+rounded).  ``--patch JSON`` lays a patch over the configuration
+file first, and ``--long TOKENS`` adds a prompt that long (a long-context
+cell's own lengths): the program in FLOAT32 against the reference, at a few layers of
 the published widths, is ``--patch '{"dtype": "float32", "serve": {"serving":
 {"dtype": "float32"}}, "model": {"kwargs": {...}}, "reference": {"kwargs":
 {...}}}'`` and must read a largest gap of rounding's size.
@@ -107,6 +112,12 @@ def main(argv=None) -> int:
     ap.add_argument("--weights", default=None, metavar="DTYPE",
                     help="serve with every matrix of the blocks rounded through "
                          "this type (the reference keeps the weights whole)")
+    ap.add_argument("--index-keys", default=None, metavar="DTYPE",
+                    help="cache an indexed stack's index keys in this type "
+                         "(models/hybrid.py:init_aux's ``ki``)")
+    ap.add_argument("--long", type=int, default=0, metavar="TOKENS",
+                    help="one more prompt, this long (the cell's contexts: "
+                         "the tool's arena holds 65,600 tokens in all)")
     ap.add_argument("--patch", default=None, metavar="JSON",
                     help="laid over the configuration file before anything is built")
     args = ap.parse_args(argv)
@@ -151,6 +162,12 @@ def main(argv=None) -> int:
                 getattr(k, "key", None) == "experts" for k in path) else w, p["blocks"])),
             donate_argnums=0)(served_params)
         params = None
+    if args.index_keys:
+        from deepspeed_tpu.models import hybrid
+        init_aux = hybrid.init_aux
+        hybrid.init_aux = lambda *a: {
+            name: leaf.astype(jnp.dtype(args.index_keys)) if name == "ki" else leaf
+            for name, leaf in init_aux(*a).items()}
     eng = deepspeed_tpu.init_serving(model=model, params=served_params, config={
         "serving": dict(config["serve"]["serving"], num_blocks=PARITY_BLOCKS)})
     rng = np.random.default_rng(27)
@@ -162,8 +179,12 @@ def main(argv=None) -> int:
         lengths.append(cfg.rope_yarn.original_positions + 100)      # original range
     if "sparse" in cfg.mixers:                  # and past where every key is attended
         lengths.append(cfg.sparse.dense_len + 200)
+    if "indexed" in cfg.mixers:                 # and past where every token is kept
+        lengths.append(cfg.indexer.topk + 200)
     if "delta" in cfg.mixers:                   # and a third chunk entered with both states
         lengths.append(2 * config["serve"]["serving"]["prefill_chunk"] + 77)
+    if args.long:
+        lengths.append(args.long)
     prompts = [rng.integers(0, cfg.vocab_size, n).tolist() for n in lengths]
     futures = [eng.submit(p, max_new_tokens=args.new) for p in prompts]
     served = [f.result() for f in futures]
@@ -177,7 +198,8 @@ def main(argv=None) -> int:
     out = {"config": os.path.basename(args.config), "layers": cfg.n_layer,
            "device": jax.devices()[0].device_kind, "paged_tile_pages": tile_pages,
            "margin": args.margin, "bank": args.bank or config["dtype"],
-           "weights": args.weights or config["dtype"], "sequences": []}
+           "weights": args.weights or config["dtype"],
+           "index_keys": args.index_keys or config["dtype"], "sequences": []}
     kw = ref["kwargs"]
     check = None
     if "hidden" in ref:          # a long context: the comparison of its cell's kind
