@@ -112,14 +112,16 @@ def _hop_bias(bias, src, Sl):
 
 
 def _alibi_shift(slopes, src, idx, Sl):
-    """Per-head constant ALiBi term for a whole hop block:
+    """Per-head constant ALiBi term for a whole hop block, to add to an
+    lse [B, H, Sl]:
     slope * (k_global - q_global) = slope*(src - idx)*Sl + local part."""
-    return (slopes[None, :, None, None]
+    return (slopes[None, :, None]
             * ((src - idx) * Sl).astype(jnp.float32))
 
 
 def _ring_fwd_impl(q, k, v, bias, slopes, causal, sp, scale, blk):
-    """[B, H, Sl, D] local shards inside shard_map.  Returns (o, lse).
+    """[B, H, Sl, D] local shards inside shard_map.  Returns (o, lse
+    [B, H, Sl], the flash kernels' own shape for row statistics).
 
     Hop 0 (the diagonal block — the only one needing a causal kernel) is
     peeled; hops 1..sp-1 run in a single rolled ``fori_loop`` so the flash
@@ -151,12 +153,13 @@ def _ring_fwd_impl(q, k, v, bias, slopes, causal, sp, scale, blk):
                 idx >= j,
                 lambda kv: hop(j, kv[0], kv[1], False),
                 lambda kv: (jnp.zeros((B, H, Sl, D), jnp.float32),
-                            jnp.full((B, H, Sl, 1), NEG_INF, jnp.float32)),
+                            jnp.full((B, H, Sl), NEG_INF, jnp.float32)),
                 (kc, vc))
         else:
             o_j, lse_j = hop(j, kc, vc, False)
         lse_new = jnp.logaddexp(lse, lse_j)
-        o = o * jnp.exp(lse - lse_new) + o_j * jnp.exp(lse_j - lse_new)
+        o = (o * jnp.exp(lse - lse_new)[..., None]
+             + o_j * jnp.exp(lse_j - lse_new)[..., None])
         return o, lse_new, kc, vc
 
     o, lse, _, _ = jax.lax.fori_loop(1, sp, body, (o, lse, k, v))
@@ -189,7 +192,7 @@ def _ring_flash_vjp_bwd(causal, sp, scale, blk, res, do):
     B, H, Sl, D = q.shape
     Hkv = k.shape[1]
     delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32),
-                    axis=-1, keepdims=True)                      # [B,H,Sl,1]
+                    axis=-1)                                     # [B,H,Sl]
 
     def hop_bwd(j, kc, vc, hop_causal):
         src = (idx - j) % sp

@@ -31,13 +31,14 @@ def test_auto_crossover(monkeypatch, S, expect_flash):
 
 
 def test_default_flash_blocks_are_tuned():
-    """_block_sizes must keep the measured-optimal (256, 512) defaults for
-    divisible sequence lengths (v5e r5 tuning), and take the full-S single
-    block below the caps (fewer online-softmax rescales; always a legal
-    Mosaic tile — the divisor hunt that used to land on (64, 64) for S=192
-    is what produced sub-sublane blocks at small prime S)."""
+    """_block_sizes must keep the measured-optimal (512, 512) defaults for
+    divisible sequence lengths (v5e, ``tools/flash_kernel_bench.py``, PR 52),
+    and take the full-S single block below the cap (fewer online-softmax
+    rescales; always a legal Mosaic tile — the divisor hunt that used to
+    land on (64, 64) for S=192 is what produced sub-sublane blocks at small
+    prime S)."""
     from deepspeed_tpu.ops.pallas.flash_attention import _block_sizes
-    assert _block_sizes(512, None, None) == (256, 512)
-    assert _block_sizes(1024, None, None) == (256, 512)
+    assert _block_sizes(512, None, None) == (512, 512)
+    assert _block_sizes(1024, None, None) == (512, 512)
     assert _block_sizes(128, None, None) == (128, 128)
     assert _block_sizes(192, None, None) == (192, 192)

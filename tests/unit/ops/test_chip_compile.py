@@ -136,6 +136,40 @@ def test_train_kernel_compiles(chip, kernel, width):
     assert not fa._FALLBACK_WARNED
 
 
+# what reaches the three flash kernels besides the two train cells' call:
+# (S, H, Hkv, D, causal, bias, alibi)
+FLASH_VARIANTS = {
+    "bert_not_causal": (512, 12, 12, 64, False, False, False),
+    "gqa_d128": (1024, 8, 2, 128, True, False, False),
+    "alibi": (1024, 12, 12, 64, True, False, True),
+    "dense_bias_d128": (2048, 4, 4, 128, True, True, False),
+    "s_no_multiple_of_128": (320, 4, 4, 64, True, False, False),
+    "s_under_a_lane_tile": (64, 4, 4, 64, True, False, False),
+}
+
+
+@pytest.mark.parametrize("variant", list(FLASH_VARIANTS))
+def test_flash_variant_compiles(chip, variant):
+    """Forward and backward through ``flash_attention`` with what the train
+    cells do not pass: the scores lie keys-major in all three kernels (a
+    query block is a tile's LANE dim, statistics are rows, products
+    transposed), and interpret mode cannot see a layout Mosaic refuses."""
+    S_, H, Hkv, D_, causal, bias, alibi = FLASH_VARIANTS[variant]
+    slopes = jnp.arange(1, H + 1, dtype=jnp.float32) / H if alibi else None
+
+    def loss(q, k, v, *b):
+        return fa.flash_attention(q, k, v, causal=causal, alibi=slopes,
+                                  bias=b[0] if bias else None
+                                  ).astype(jnp.float32).sum()
+
+    shapes = [((2, S_, H, D_), BF16)] + [((2, S_, Hkv, D_), BF16)] * 2
+    if bias:
+        shapes.append(((1, H, S_, S_), jnp.float32))
+    text = _compiled_text(chip, jax.grad(loss, argnums=(0, 1, 2)), *shapes)
+    assert text.count("tpu_custom_call") >= 3
+    assert not fa._FALLBACK_WARNED
+
+
 @pytest.mark.parametrize("E,vocab,blocks", [
     (2048, V, (512, 384)),        # OLMoE's width: fewer rows, two row sweeps
     (768, 65536, (256, 2048)),    # a power-of-two vocab: 2,048 columns
