@@ -97,13 +97,24 @@ from deepspeed_tpu.utils.logging import log_dist
 #: grow, build, dispatch (n+1), fetch (n), commit (n), stats.  The step in
 #: which the engine stops being ahead opens the pair twice (n, then n+1); the
 #: step in which it starts opens none.
+#: The engine numbers the programs it launches, 1, 2, 3 ...
+#: (``ServingEngine.programs_launched`` is the last number given), and the
+#: stat ``program`` is on every span that touches one: its ``dispatch``, its
+#: ``fetch``, its ``commit`` spans, whatever step opens them.  The ``fetch``
+#: that lands a program's row carries the program's own durations too
+#: (:data:`PROGRAM_STATS`): one such event a landed program.  A program an
+#: incident abandons (:meth:`ServingEngine._recover_incident`) leaves no such
+#: event, and its number is not given again.
 #: ``submit()`` is ``serve.submit`` (stat ``rid``); a request's first token
-#: leaves one zero-length ``serve.first_token`` with its waits as stats.
+#: leaves one zero-length ``serve.first_token`` with its waits as stats and
+#: the ``program`` whose row brought the token: a request's spans join on
+#: ``rid``, and its chunks' programs on ``rid`` and ``program``.
 #: ``serve.stats`` carries what the step's tables and attention cost
 #: (``table_edits``, ``table_reloads``, ``upload_bytes``, ``tile_runs_pct``,
 #: ``chunk_queries_per_row``, ``attention_rows``), ``dispatched_ahead`` (1:
 #: this step's program was launched before the row of the program before it
-#: was on the host) and, in a step that follows a step with a program, the
+#: was on the host), ``program`` (the one this step launched; absent where it
+#: launched none) and, in a step that follows a step with a program, the
 #: step's turn-round as DURATIONS on the engine's one clock
 #: (:data:`TURNAROUND_STATS`): they need no alignment with any other line of
 #: a trace, and they are on the main thread's line whatever thread ran the
@@ -156,6 +167,27 @@ SERVE_STEP_SPANS = (
 #: turn-round.
 TURNAROUND_STATS = ("turnaround_ms", "commit_ms", "outside_ms", "prepare_ms",
                     "result_wait_ms")
+
+#: What a PROGRAM says of itself when its row lands: what the one ``fetch``
+#: span that landed it (on whichever thread fetched) gains at its end, beside
+#: the ``program``, ``chunk_tokens`` and ``batch`` it opened with.  A step is
+#: not the unit: it launches program k and lands k-1, or k-1 and k, or none.
+#: ``ahead`` is 1 where the program was launched before the row of the program
+#: before it was on the host; the durations are on ``ServingEngine._clock``,
+#: from the program's own two stamps and the stamp of the row landed before
+#: it:
+#:
+#: * ``device_ms`` = ``t_result(k) - max(t_launch(k), t_result(k-1))``
+#:   (no row before it: from its launch).  Launched ahead, the period between
+#:   two rows while the chip is never without a program: the program's device
+#:   time, both stamps behind the same wire.  Not ahead, its device time and
+#:   both wires.  Over consecutive programs ``sum(device_ms) +
+#:   sum(turnaround_ms) = t_result(last) - t_result(first)``;
+#: * ``host_ms`` = ``commit_ms + outside_ms + prepare_ms`` of the step that
+#:   launched it: the host's part for this program, hidden or not.  Absent
+#:   where that step carries no turn-round (the chip waited for work or for
+#:   the compiler).
+PROGRAM_STATS = ("ahead", "device_ms", "host_ms")
 
 
 class StepLayout(NamedTuple):
@@ -259,6 +291,14 @@ class _Flight(NamedTuple):
     chunk: Optional[Tuple[Request, int, bool, Dict[str, int]]]  # (request,
                                 # slot, the prompt's last?, rid/start/tokens)
     feeds: Dict[int, int]       # rid -> the row whose token is its next input
+    t_launch: float
+    ahead: int                  # 1: launched with the program before in flight
+    host_ms: Optional[float]    # the host's part for it (PROGRAM_STATS)
+
+    @property
+    def number(self) -> int:
+        """The program's: 1 for the first the engine ever launched."""
+        return self.at["program"]
 
 
 class ServeFuture:
@@ -406,6 +446,7 @@ class ServingEngine:
             self._g_prefix_rate = r.gauge("prefix_hit_rate")
             self._h_step = r.histogram("serve_step_ms")
             self._h_turnaround = r.histogram("serve_turnaround_ms")
+            self._h_program = r.histogram("serve_program_ms")
         self.dtype = cfg.jnp_dtype
         assert hasattr(model, "paged_step") and hasattr(model, "cfg"), (
             "ServingEngine needs a model with .cfg and .paged_step(...) "
@@ -584,6 +625,8 @@ class ServingEngine:
         self._flight: Optional[_Flight] = None
         self._previous = self._no_tokens()
         self.steps_dispatched_ahead = 0
+        # the number of the last program launched (the first is 1)
+        self.programs_launched = 0
 
         # ---- resilience plane -------------------------------------------- #
         self._clock = time.monotonic
@@ -593,6 +636,9 @@ class ServingEngine:
         # step's TURNAROUND_STATS are measured from
         self._t_settled: Optional[float] = None
         self._t_exit = 0.0
+        # the ``t_result`` of the row landed last: where the device time of
+        # the next program starts, if it was launched before that
+        self._t_row = float("-inf")
         self.admission = AdmissionController(cfg)
         # bounded fetch: a wedged compiled program raises ServeStepTimeout
         # instead of parking the engine thread forever.  on_timeout releases
@@ -833,12 +879,15 @@ class ServingEngine:
         unbounded).  This device sync is exactly where a wedged program parks
         the thread, so it is the bounded callable: the ``fetch`` span goes to
         the worker thread with it, and the stamp is taken there and handed
-        back.  A fetch over its deadline raises :class:`ServeStepTimeout`
-        AFTER the in-process recovery (:meth:`_recover_incident`)."""
-        phase, tokens = flight.phase, flight.tokens
+        back with the program's record (:data:`PROGRAM_STATS`), which the span
+        carries as stats known at its end.  A fetch over its deadline raises
+        :class:`ServeStepTimeout` AFTER the in-process recovery
+        (:meth:`_recover_incident`), and the worker it abandoned writes no
+        record if it ever comes back."""
+        phase, tokens, incidents = flight.phase, flight.tokens, self.incident_count
 
         def work():
-            with self._span(f"serve.{phase}.fetch", **flight.at):
+            with self._span(f"serve.{phase}.fetch", **flight.at) as sp:
                 fault_point("serve.step", step=self.step_count, phase=phase)
                 # (inline fetch alone: under a deadline a wedged step
                 # would leave the abandoned worker spinning for good)
@@ -846,7 +895,14 @@ class ServingEngine:
                     tokens.copy_to_host_async()
                     while not tokens.is_ready():
                         time.sleep(0)
-                return np.asarray(tokens).reshape(-1), self._clock()
+                row, t_result = np.asarray(tokens).reshape(-1), self._clock()
+                record = {"ahead": flight.ahead, "device_ms": (
+                    t_result - max(flight.t_launch, self._t_row)) * 1e3}
+                if flight.host_ms is not None:
+                    record["host_ms"] = flight.host_ms
+                if self.incident_count == incidents:
+                    sp.set(**record)
+                return row, t_result, record["device_ms"]
         if self._bounded is None:
             return work()
         try:
@@ -867,26 +923,29 @@ class ServingEngine:
         waited for the row.  A row whose request no longer holds that slot
         (it finished on an EOS the host saw a step late) is dropped.
         -> the row's :meth:`_moe_stats`."""
-        row, self._t_settled = self._fetch(flight)
+        row, t_result, device_ms = self._fetch(flight)
+        self._t_settled = self._t_row = t_result
+        if self.registry is not None:
+            self._h_program.observe(device_ms)
         n = row.size - self._moe_experts * self._moe_count_rows
         tokens, self._expert_counts = row[:n], row[n:]
         moe_stats = self._moe_stats()
         holds = lambda req, slot: self.sched.active.get(slot) is req
         if flight.chunk is not None:
             req, slot, last, chunk = flight.chunk
-            with self._span("serve.prefill.commit", **chunk):
+            with self._span("serve.prefill.commit", program=flight.number, **chunk):
                 # the chunk holding the last context token also yields the
                 # next token — first-token latency includes no extra decode
                 # step
                 if last and holds(req, slot):
-                    self._append_token(req, int(
+                    self._append_token(req, flight.number, int(
                         tokens[self._layout.slots + chunk["tokens"] - 1]))
         if flight.decode:
             with self._span("serve.decode.commit", batch=len(flight.decode),
-                            **moe_stats):
+                            program=flight.number, **moe_stats):
                 for req, slot in flight.decode:
                     if holds(req, slot):
-                        self._append_token(req, int(tokens[slot]))
+                        self._append_token(req, flight.number, int(tokens[slot]))
         return moe_stats
 
     def _drain(self) -> Dict[str, float]:
@@ -1087,9 +1146,10 @@ class ServingEngine:
         longer; an EOS is seen one step late and the extra row's token is
         dropped.  What does not fit this (:meth:`_drain`) lands the row in
         flight first.  Returns the step stats (of the program this step
-        launched; ``tokens_generated`` counts what is committed).  A wedged
-        program raises :class:`ServeStepTimeout` from the fetch of its row,
-        *after* in-process recovery (see :meth:`_recover_incident`)."""
+        launched, under its number ``program``; ``tokens_generated`` counts
+        what is committed).  A wedged program raises :class:`ServeStepTimeout`
+        from the fetch of its row, *after* in-process recovery (see
+        :meth:`_recover_incident`)."""
         t_enter, settled = self._clock(), self._t_settled
         with self._span("serve.admit") as sp:
             self._expire_deadlines()
@@ -1097,8 +1157,7 @@ class ServingEngine:
             if self._flight is not None and self._admit_is_unusual():
                 self._drain()
             sp.set(admitted=len(self.sched.admit(self._clock())))
-        t_step = time.monotonic() if self.registry is not None else 0.0
-        n_chunk, ahead, turnaround = 0, 0, {}
+        n_chunk, ahead, host, turnaround, launched_stats = 0, 0, {}, {}, {}
         table_stats = {"table_edits": 0, "table_reloads": 0, "upload_bytes": 0}
         with self._span("serve.grow") as sp:
             # growth pass, oldest/strongest first: each decode step
@@ -1153,14 +1212,21 @@ class ServingEngine:
             # decode rows when it carries any (`batch`: every live row)
             phase, at = (("decode", {"batch": len(decode) + n_chunk})
                          if decode else ("prefill", chunk))
-            at = dict(at, chunk_tokens=n_chunk)
+            self.programs_launched += 1
+            launched_stats = {"program": self.programs_launched}
+            at = dict(at, chunk_tokens=n_chunk, **launched_stats)
             ahead = int(self._flight is not None)
             tokens, t_launch = self._dispatch(phase, packed, reload, at)
             self._t_settled = t_launch
+            if settled is not None:      # the host's parts, known at the launch
+                host = {"commit_ms": (self._t_exit - settled) * 1e3,
+                        "outside_ms": (t_enter - self._t_exit) * 1e3,
+                        "prepare_ms": (t_launch - t_enter) * 1e3}
             launched = _Flight(
                 tokens, phase, at, [(r, r.slot) for r in decode],
                 pf and (req, req.slot, start + n_chunk >= req.prefill_len, chunk),
-                self._launched(decode, pf))
+                self._launched(decode, pf), t_launch, ahead,
+                sum(host.values()) if host else None)
             if self._hybrid:
                 table_stats.update(self._hybrid_stats(rows))
         moe_stats = self._drain()       # the program before: behind the launch
@@ -1168,24 +1234,31 @@ class ServingEngine:
             self._flight = launched
         elif runs:
             moe_stats = self._land(launched)
-        if runs and settled is not None:
-            turnaround = self._turnaround(settled, t_enter, t_launch, idle_since)
+        if host:
+            turnaround = dict(
+                host, turnaround_ms=(0.0 if idle_since is None
+                                     else (t_launch - idle_since) * 1e3),
+                result_wait_ms=(self._t_settled - t_launch) * 1e3)
+            if self.registry is not None:
+                self._h_turnaround.observe(turnaround["turnaround_ms"])
         # how attention took the step: the queries a row of the chunk held
         # (0: no chunk in the step) and the rows its calls ran; whether the
         # step was dispatched ahead, and its own turn-round, where there was
-        # one
+        # one; the program it launched
         self.steps_dispatched_ahead += ahead
         on_span = dict(
             table_stats, tile_runs_pct=self._tile_runs_pct(),
             chunk_queries_per_row=self.chunk_queries_per_row if n_chunk else 0,
             attention_rows=self.attention_rows if runs else 0,
-            dispatched_ahead=ahead, **turnaround)
+            dispatched_ahead=ahead, **launched_stats, **turnaround)
         with self._span("serve.stats", **on_span):
-            stats = self._close_step(len(decode), n_chunk, int(runs), t_step,
+            stats = self._close_step(len(decode), n_chunk, int(runs),
                                      dict(moe_stats, **on_span))
         if not runs or not self.sched.has_work:
             self._t_settled = None      # from here the chip waits for WORK
         self._t_exit = self._clock()
+        if self.registry is not None:
+            self._h_step.observe((self._t_exit - t_enter) * 1e3)
         return stats
 
     def _admit_is_unusual(self) -> bool:
@@ -1240,23 +1313,6 @@ class ServingEngine:
         held = self.alloc.tiles_held
         return 100.0 * self.alloc.tiles_run / held if held else 0.0
 
-    def _turnaround(self, settled: float, t_enter: float, t_launch: float,
-                    idle_since: Optional[float]) -> Dict[str, float]:
-        """:data:`TURNAROUND_STATS` of the step that launched a program at
-        ``t_launch``: ``settled`` is when the step before was last settled,
-        ``idle_since`` the last row on the host at the launch (None:
-        dispatched ahead, the chip had a program)."""
-        ms = lambda a, b: (b - a) * 1e3
-        out = {"turnaround_ms": 0.0 if idle_since is None
-               else ms(idle_since, t_launch),
-               "commit_ms": ms(settled, self._t_exit),
-               "outside_ms": ms(self._t_exit, t_enter),
-               "prepare_ms": ms(t_enter, t_launch),
-               "result_wait_ms": ms(t_launch, self._t_settled)}
-        if self.registry is not None:
-            self._h_turnaround.observe(out["turnaround_ms"])
-        return out
-
     def _moe_stats(self) -> Dict[str, float]:
         """How the last program's live rows spread over the experts the
         router chooses among (summed over layers); nothing for a dense
@@ -1275,7 +1331,7 @@ class ServingEngine:
                 "moe_assignments_held": int(counts[first:first + held].sum())}
 
     def _close_step(self, decode_batch: int, prefill_tokens: int,
-                    programs: int, t_step: float,
+                    programs: int,
                     of_the_program: Dict[str, Any]) -> Dict[str, Any]:
         """What a clean step ends with: the incident latch, the ledger, the
         stats dict (``of_the_program``: what ``step()`` counted and timed of
@@ -1306,7 +1362,6 @@ class ServingEngine:
         if self.prefix is not None:
             stats.update(self.prefix.stats())
         if self.registry is not None:
-            self._h_step.observe((time.monotonic() - t_step) * 1e3)
             for gauge, key in ((self._g_queue, "queue_depth"),
                                (self._g_active, "active"),
                                (self._g_blocks, "blocks_in_use"),
@@ -1493,7 +1548,7 @@ class ServingEngine:
         rows[slots, 2] = slots
         rows[slots, 3] = 1
 
-    def _append_token(self, req: Request, tok: int):
+    def _append_token(self, req: Request, program: int, tok: int):
         req.generated.append(tok)
         self.tokens_generated += 1
         if req.first_token_at is None:
@@ -1502,7 +1557,8 @@ class ServingEngine:
             # waits sum to first_token_at - arrival
             ms = lambda a, b: (b - a) * 1e3
             with self._span(
-                    "serve.first_token", rid=req.rid, chunks=req.prefill_chunks,
+                    "serve.first_token", rid=req.rid, program=program,
+                    chunks=req.prefill_chunks,
                     queue_ms=ms(req.arrival, req.admitted_at),
                     lane_wait_ms=ms(req.admitted_at, req.prefill_started_at),
                     prefill_ms=ms(req.prefill_started_at, req.first_token_at)):

@@ -168,19 +168,6 @@ class Tracer:
                 pass
         self._append(rec)
 
-    def instant(self, name: str, **args):
-        """Zero-duration marker (Chrome ``ph: "i"``) — e.g. a collective
-        recorded at trace time, where host-side duration is meaningless."""
-        if self.closed:
-            return
-        stack = self._stack()
-        self._append({
-            "sid": next(self._ids), "name": name, "t0": self._clock(),
-            "t1": None, "tid": threading.get_ident(), "depth": len(stack),
-            "parent": stack[-1]["sid"] if stack else 0,
-            "args": args or None, "instant": True,
-        })
-
     def add_span(self, name: str, t0_ns: int, t1_ns: int,
                  track: Optional[str] = None, **args):
         """Record a retrospective span with explicit timestamps (used for
@@ -254,20 +241,15 @@ class Tracer:
             ev = {
                 "name": r["name"],
                 "cat": str(r["name"]).split(".", 1)[0],
+                "ph": "X",
                 "pid": self.rank,
                 "tid": tid_of[r["tid"]],
                 "ts": (r["t0"] - self.epoch_ns) / 1e3,
+                "dur": max((r["t1"] - r["t0"]) / 1e3, 0.0),
             }
             args = self._args_host(r.get("args"))
             if args:
                 ev["args"] = args
-            if r.get("instant"):
-                ev["ph"] = "i"
-                ev["s"] = "t"
-            else:
-                ev["ph"] = "X"
-                t1 = r["t1"] if r["t1"] is not None else r["t0"]
-                ev["dur"] = max((t1 - r["t0"]) / 1e3, 0.0)
             events.append(ev)
         return events
 

@@ -216,8 +216,12 @@ def test_the_program_has_one_shape_whatever_the_step_holds(model_and_params,
 def test_a_step_opens_at_most_one_dispatch_and_every_program_one_fetch(model_and_params):
     """A step launches at most one program, under the span named for what the
     program carries; every program's row is fetched ONCE, under the same
-    name and stats, in the step that launched it or (a prompt with chunks
-    left: dispatched ahead) behind the next step's launch."""
+    name and stats (its number among them; the fetch adds the program's own
+    record, ``PROGRAM_STATS``), in the step that launched it or (a prompt with
+    chunks left: dispatched ahead) behind the next step's launch."""
+    from deepspeed_tpu.serving.engine import PROGRAM_STATS
+    at_launch = lambda args: {k: v for k, v in args.items()
+                              if k not in PROGRAM_STATS}
     tr = Tracer()
     eng = engine(model_and_params, tracer=tr)
     for p, m in zip(prompts_of(8, (3, 20, 9)), (9, 4, 6)):
@@ -242,10 +246,11 @@ def test_a_step_opens_at_most_one_dispatch_and_every_program_one_fetch(model_and
             names = [n for n, _ in spans]
             assert names.index(launches[0][0]) < names.index(fetches[0][0])
         dispatched += [(n.rsplit(".", 1)[0], a) for n, a in launches]
-        fetched += [(n.rsplit(".", 1)[0], a) for n, a in fetches]
+        fetched += [(n.rsplit(".", 1)[0], at_launch(a)) for n, a in fetches]
         ahead += st["dispatched_ahead"]
         seen.add((phase, bool(st["prefill_tokens"])))
     assert fetched == dispatched and ahead == eng.steps_dispatched_ahead >= 2
+    assert [a["program"] for _, a in dispatched] == list(range(1, len(dispatched) + 1))
     assert seen == {("prefill", True), ("decode", True), ("decode", False)}
     eng.close()
 

@@ -4,6 +4,7 @@ configured, never touches the traced program, and the serving engine tiles
 its step with them and stamps a request where things happen."""
 
 import glob
+import itertools
 import json
 import os
 import time
@@ -15,7 +16,8 @@ import pytest
 
 from deepspeed_tpu.models.gpt import GPT, GPTConfig
 from deepspeed_tpu.serving import DeepSpeedServingConfig, ServingEngine
-from deepspeed_tpu.serving.engine import SERVE_STEP_SPANS, TURNAROUND_STATS
+from deepspeed_tpu.serving.engine import (PROGRAM_STATS, SERVE_STEP_SPANS,
+                                          TURNAROUND_STATS, ServeStepTimeout)
 from deepspeed_tpu.telemetry import Tracer, maybe_span, set_global_tracer
 
 
@@ -166,13 +168,13 @@ def _engine(tiny_model, tracer=None, **over):
 
 
 def test_leaf_spans_tile_the_step(tiny_model):
-    """Every leaf is a sibling, in the order the work happens, and what
-    lies under no leaf in a warm step is the spans' own bookkeeping, a few
-    microseconds at each of the step's eight to ten boundaries: a fixed cost
-    that does not grow with the program, so it is held to a budget in
-    microseconds (on the chip a step is tens of milliseconds).  As a share of
-    this toy's 1.7 ms step it is 7 to 10%, one program a step or two; the
-    share read 0.99 here only while a compile fell inside the steps counted.
+    """Every leaf is a sibling, in the order the work happens, and nothing of
+    a step lies under no leaf.  Held on the Tracer's injected clock, which
+    reads 0, 1, 2 ...: a span's open and its close are a read each, so a leaf
+    opens ONE read after the leaf before it closed exactly when no span was
+    opened in between, and the first opens one read after ``step()`` was
+    called.  (What the spans' own bookkeeping costs in microseconds is a
+    reading of the chip's host, PERF.md section 6, and no test's.)
 
     The order: admit, grow, the builds, ONE dispatch, then a fetch and the
     commits of its row for each program that lands in the step: its own (the
@@ -180,20 +182,20 @@ def test_leaf_spans_tile_the_step(tiny_model):
     the one before it (dispatched ahead), or both (the step in which the
     engine stops being ahead)."""
     import re
-    tr = Tracer()
+    ticks = itertools.count()
+    tr = Tracer(clock=lambda: next(ticks))
     eng = _engine(tiny_model, tracer=tr)
     rng = np.random.default_rng(0)
     for n in (20, 5, 11):
         eng.submit(list(rng.integers(1, 128, size=n)), max_new_tokens=6)
     eng.step()                                  # compiles the program
     eng.step()
-    uncovered_us = []
     seen, shapes = set(), set()
     for _ in range(8):
         mark = len(tr.snapshot())
-        t0 = time.monotonic_ns()
+        t0 = next(ticks)
         stats = eng.step()
-        t1 = time.monotonic_ns()
+        t1 = next(ticks)
         # (a request's serve.first_token mark sits inside the commit it came in)
         step = [r for r in tr.snapshot()[mark:] if r["name"] != "serve.first_token"]
         assert all(r["depth"] == 0 and r["parent"] == 0 for r in step), \
@@ -208,9 +210,10 @@ def test_leaf_spans_tile_the_step(tiny_model):
             assert names == [n for n in SERVE_STEP_SPANS if n in set(names)], \
                 "in the order of the list"
         shapes.add((stats["dispatched_ahead"], kinds.count("fetch")))
-        assert t0 <= step[0]["t0"] and step[-1]["t1"] <= t1
+        assert step[0]["t0"] == t0 + 1 and step[-1]["t1"] == t1 - 1
+        assert [b["t0"] - a["t1"] for a, b in zip(step, step[1:])] == [1] * (len(step) - 1), \
+            "no read of the tracer's clock between two leaves"
         seen |= set(names)
-        uncovered_us.append(((t1 - t0) - sum(r["t1"] - r["t0"] for r in step)) / 1e3)
     # steps 3..10: the first prompt's last chunk alone (the pair that names a
     # program with no decode row) and the other prompts' chunks beside decode
     # rows, each launched before the row of the one before it is fetched;
@@ -218,8 +221,9 @@ def test_leaf_spans_tile_the_step(tiny_model):
     # alone, each step its own row
     assert shapes == {(1, 1), (1, 2), (0, 1)}
     assert seen == set(SERVE_STEP_SPANS)
-    assert sorted(uncovered_us)[len(uncovered_us) // 2] <= 250.0, uncovered_us
     eng.close()
+
+
 
 
 def _step_spans(tr, eng):
@@ -230,6 +234,11 @@ def _step_spans(tr, eng):
     for r in tr.snapshot()[mark:]:
         by.setdefault(r["name"], []).append(r["args"])
     return by, stats
+
+
+def _less_record(events):
+    """The fetch events' stats as they were when the span opened."""
+    return [{k: v for k, v in args.items() if k not in PROGRAM_STATS} for args in events]
 
 
 def test_spans_carry_counts_where_the_work_happens(tiny_model):
@@ -243,11 +252,12 @@ def test_spans_carry_counts_where_the_work_happens(tiny_model):
     assert by["serve.admit"] == [{"admitted": 1}]
     chunk = {"rid": fut.request.rid, "start": 0, "tokens": 8}
     assert by["serve.prefill.build"] == [chunk]
-    assert by["serve.prefill.dispatch"] == [dict(chunk, chunk_tokens=8)]
+    assert by["serve.prefill.dispatch"] == [dict(chunk, chunk_tokens=8, program=1)]
     # prompt is left behind the chunk, so nothing that arrives could change
     # the next program: this one stays in flight, its row is not fetched here
     assert "serve.prefill.fetch" not in by and "serve.prefill.commit" not in by
     assert (stats["dispatched_ahead"], by["serve.stats"][0]["dispatched_ahead"]) == (0, 0)
+    assert stats["program"] == 1, "the step that starts lands no row"
     # 12 prompt tokens in blocks of 8: two pages, one full group, no window
     assert by["serve.grow"] == [{"batch": 0, "pages_full": 2, "pages_window": 0,
                                  "pages_given_back": 0}]
@@ -264,17 +274,18 @@ def test_spans_carry_counts_where_the_work_happens(tiny_model):
     # (the lane idle, the queue empty, a slot free) its own: two fetches,
     # each under the stats of the program whose row it brings
     assert by["serve.prefill.build"] == [last]
-    assert by["serve.prefill.dispatch"] == [dict(last, chunk_tokens=4)]
-    assert by["serve.prefill.fetch"] == [dict(chunk, chunk_tokens=8),
-                                         dict(last, chunk_tokens=4)]
-    assert by["serve.prefill.commit"] == [chunk, last]
+    assert by["serve.prefill.dispatch"] == [dict(last, chunk_tokens=4, program=2)]
+    assert _less_record(by["serve.prefill.fetch"]) == [
+        dict(chunk, chunk_tokens=8, program=1), dict(last, chunk_tokens=4, program=2)]
+    assert by["serve.prefill.commit"] == [dict(chunk, program=1), dict(last, program=2)]
     assert (stats["dispatched_ahead"], by["serve.stats"][0]["dispatched_ahead"]) == (1, 1)
+    assert stats["program"] == 2, "the step that stops being ahead lands two"
     assert eng.steps_dispatched_ahead == 1 and len(fut.request.generated) == 1
     by, stats = _step_spans(tr, eng)            # the first decode step
-    for name in ("serve.decode.build", "serve.decode.commit"):
-        assert by[name] == [{"batch": 1}], name
+    assert by["serve.decode.build"] == [{"batch": 1}]
+    assert by["serve.decode.commit"] == [{"batch": 1, "program": 3}]
     for name in ("serve.decode.dispatch", "serve.decode.fetch"):
-        assert by[name] == [{"batch": 1, "chunk_tokens": 0}], name
+        assert _less_record(by[name]) == [{"batch": 1, "chunk_tokens": 0, "program": 3}], name
     assert by["serve.prefill.build"] == [None]
     assert not any(n in by for n in ("serve.prefill.dispatch", "serve.prefill.fetch",
                                      "serve.prefill.commit"))
@@ -290,7 +301,7 @@ def test_spans_carry_counts_where_the_work_happens(tiny_model):
              "upload_bytes": 4 * eng._layout.packed_size}
     (on_span,) = by["serve.stats"]
     assert on_span == dict(table, chunk_queries_per_row=0, attention_rows=5,
-                           dispatched_ahead=0,
+                           dispatched_ahead=0, program=3,
                            **{k: stats[k] for k in TURNAROUND_STATS})
     assert {k: stats[k] for k in table} == table
     assert stats["paged_tile_pages"] == eng.paged_tile_pages == 0   # the einsum
@@ -299,6 +310,7 @@ def test_spans_carry_counts_where_the_work_happens(tiny_model):
     fut.result()
     idle = eng.step()                           # nothing to run: no program,
     assert (idle["programs"], idle["upload_bytes"]) == (0, 0)       # no upload
+    assert "program" not in idle
     assert (idle["chunk_queries_per_row"], idle["attention_rows"]) == (0, 0)
     eng.close()
 
@@ -496,8 +508,10 @@ def test_turnaround_is_on_the_main_threads_stats_span(tiny_model, timeout_s):
         on_span = recs["serve.stats"]["args"]
         assert {k: on_span[k] for k in TURNAROUND_STATS} == _turnaround(stats)
         assert set(_turnaround(stats)) == set(TURNAROUND_STATS)
-        # the fetch span lies inside the wait, whichever thread opened it
-        assert (fetch["t1"] - fetch["t0"]) / 1e6 <= stats["result_wait_ms"]
+        # the fetch span holds the stamp, whichever thread opened it: the wait
+        # the step says is what its program's record says (not ahead: from
+        # its own launch)
+        assert fetch["args"]["device_ms"] == stats["result_wait_ms"]
     eng.close()
 
 
@@ -528,7 +542,8 @@ def test_turnaround_is_on_the_profilers_line_a_step(tiny_model, tmp_path):
 def test_registry_times_the_turnaround_and_no_decode_step(tiny_model, tmp_path):
     """One histogram for one: ``serve_turnaround_ms`` where
     ``serve_decode_step_ms`` was, observed in every step that carries the
-    stats; ``serve_step_ms`` as before."""
+    stats; ``serve_program_ms`` at every landing; ``serve_step_ms`` a step,
+    from the step's own two stamps."""
     from deepspeed_tpu.runtime.config import DeepSpeedTelemetryConfig
     from deepspeed_tpu.telemetry import TelemetryHub
     hub = TelemetryHub.from_config(DeepSpeedTelemetryConfig(
@@ -539,7 +554,8 @@ def test_registry_times_the_turnaround_and_no_decode_step(tiny_model, tmp_path):
                             block_size=8, num_blocks=64, max_batch_size=4,
                             prefill_chunk=8, dtype="float32", telemetry_every=2))
     fut = eng.submit(list(range(1, 8)), max_new_tokens=5)
-    turns = []
+    turns, landed, fetch = [], [], eng._fetch
+    eng._fetch = lambda flight: landed.append(fetch(flight)) or landed[-1]
     while not fut.done:
         turns.append(_turnaround(eng.step()))
     hists = hub.registry.snapshot()["histograms"]
@@ -548,13 +564,294 @@ def test_registry_times_the_turnaround_and_no_decode_step(tiny_model, tmp_path):
     assert hists["serve_turnaround_ms"]["count"] == len(turns) - 1
     assert hists["serve_turnaround_ms"]["sum"] == pytest.approx(
         sum(t["turnaround_ms"] for t in turns[1:]))
+    # a landed program's device time, next to it; a lone request: one a step
+    assert hists["serve_program_ms"]["count"] == len(turns)
+    assert hists["serve_program_ms"]["sum"] == pytest.approx(sum(ms for _, _, ms in landed))
     # the periodic serve_step record carries the stats to an operator
     hub.flush()
     records = [json.loads(l) for l in open(tmp_path / "t.jsonl")]
     steps = [r for r in records if r.get("kind") == "serve_step"]
     assert steps and all(set(TURNAROUND_STATS) <= set(r) for r in steps)
+    assert all("program" in r for r in steps)
     eng.close()
     hub.close()
+
+
+# ---- the program, not the step: its number, and its own durations -------------- #
+def _landings(tr, mark=0):
+    """The stats of each landing event since ``mark``, in the order the rows
+    landed: a ``fetch`` span that carries the program's record."""
+    return [r["args"] for r in tr.snapshot()[mark:]
+            if r["name"].endswith(".fetch") and "device_ms" in r["args"]]
+
+
+def _clocked(tiny_model, **over):
+    """A traced engine on the ``_Ticks`` clock with its stamps kept."""
+    tr = Tracer()
+    eng = _engine(tiny_model, tracer=tr, **over)
+    eng._clock = _Ticks()
+    return (tr, eng) + _stamped(eng)
+
+
+def test_a_programs_number_is_on_every_span_that_touches_it_across_two_steps(tiny_model):
+    """Programs are numbered from 1 in the order they are launched.  Under a
+    backlog a program's dispatch is one step's and its fetch and commits the
+    next step's: the number is the same on all of them, on the ``serve.stats``
+    of the step that launched it and on the ``serve.first_token`` of each
+    request whose first token its row brought."""
+    tr = Tracer()
+    eng = _engine(tiny_model, tracer=tr, max_batch_size=2)
+    futs = [eng.submit(list(range(1, n + 1)), max_new_tokens=4) for n in (3, 12, 5, 6)]
+    by_step = []
+    while eng.sched.has_work:
+        by_step.append(_step_spans(tr, eng))
+    touched = {}            # program -> [(step, kind of span)]
+    for i, (by, stats) in enumerate(by_step):
+        launched = [a["program"] for n, ev in by.items() if n.endswith(".dispatch") for a in ev]
+        assert launched == ([stats["program"]] if stats["programs"] else [])
+        assert by["serve.stats"][0].get("program") == stats.get("program")
+        for name, events in by.items():
+            for args in events:
+                if name != "serve.stats" and "program" in (args or {}):
+                    touched.setdefault(args["program"], []).append((i, name.rsplit(".", 1)[-1]))
+    assert sorted(touched) == list(range(1, eng.programs_launched + 1)), "none skipped"
+    for number, spans in touched.items():
+        kinds = [k for _, k in spans]
+        assert kinds[0] == "dispatch" and kinds[1] == "fetch" and "commit" in kinds, number
+        assert set(kinds) <= {"dispatch", "fetch", "commit", "first_token"}
+    assert any(len({i for i, _ in spans}) == 2 for spans in touched.values()), \
+        "launched in one step, landed in the next"
+    # each request's first token names the program whose row brought it: the
+    # one that carried its prompt's last chunk
+    marks = {r["args"]["rid"]: r["args"]["program"] for r in tr.snapshot()
+             if r["name"] == "serve.first_token"}
+    last_chunk = {}
+    for by, _ in by_step:
+        for args in by.get("serve.prefill.commit", []):
+            last_chunk[args["rid"]] = args["program"]       # the last one stays
+    assert marks == last_chunk and set(marks) == {f.request.rid for f in futs}
+    eng.close()
+
+
+def test_a_lone_request_is_never_ahead_and_its_record_is_its_own_wait(tiny_model):
+    tr, eng, launches, results = _clocked(tiny_model)
+    eng.submit(list(range(1, 8)), max_new_tokens=8)
+    for k in range(1, 7):
+        stats = eng.step()
+        (rec,) = _landings(tr)[k - 1:]
+        assert (rec["program"], rec["ahead"], rec["chunk_tokens"]) == (k, 0, 7 * (k == 1))
+        assert rec["device_ms"] == (results[-1] - launches[-1]) * 1e3
+        assert rec["device_ms"] == stats["result_wait_ms"] if k > 1 else True
+        assert stats["program"] == k
+        if k == 1:
+            assert "host_ms" not in rec, "the chip waited for work, not for the host"
+        else:
+            assert rec["host_ms"] == stats["turnaround_ms"] == (
+                stats["commit_ms"] + stats["outside_ms"] + stats["prepare_ms"])
+    eng.close()
+
+
+def test_under_a_backlog_a_programs_device_time_runs_from_row_to_row(tiny_model):
+    """The step that starts being ahead lands nothing; every step after it
+    launches program k and lands k-1, whose ``device_ms`` is the period since
+    the row before it, not since its own launch; the step in which the
+    engine stops being ahead lands two, k-1 then k, and
+    k's device time starts at k-1's row though its launch came before that."""
+    tr, eng, launches, results = _clocked(tiny_model, max_batch_size=2)
+    for n in (3, 4, 5, 6, 7):
+        eng.submit(list(range(1, n + 1)), max_new_tokens=6)
+    first = eng.step()
+    assert first["program"] == 1 and _landings(tr) == []
+    stopped = None
+    while eng.sched.has_work:
+        mark = len(tr.snapshot())
+        stats = eng.step()
+        landed, k = _landings(tr, mark), stats.get("program")
+        if stats["dispatched_ahead"] and len(landed) == 1:
+            (rec,) = landed
+            assert rec["program"] == k - 1 and eng._flight.number == k
+            # its launch came before the row of the program before it, where
+            # that one was ahead too: then it ran from that row to its own
+            if rec["ahead"]:
+                assert launches[-2] < results[-2]
+                assert rec["device_ms"] == (results[-1] - results[-2]) * 1e3
+        elif stats["dispatched_ahead"]:
+            before, own = landed
+            assert (before["program"], own["program"]) == (k - 1, k) and stopped is None
+            assert own["ahead"] == 1 and launches[-1] < results[-2] < results[-1]
+            assert own["device_ms"] == (results[-1] - results[-2]) * 1e3
+            assert eng._flight is None
+            stopped = k
+    assert stopped is not None and eng.steps_dispatched_ahead >= 5
+    records = _landings(tr)
+    assert [r["program"] for r in records] == list(range(1, eng.programs_launched + 1))
+    assert all(r["ahead"] for r in records[1:stopped])
+    assert all("host_ms" in r for r in records[1:]) and "host_ms" not in records[0]
+    eng.close()
+
+
+def test_device_time_and_turnaround_sum_to_the_time_between_two_rows(tiny_model):
+    """Over consecutive programs, ahead or not: ``sum(device_ms) +
+    sum(turnaround_ms) = t_result(last) - t_result(first)``, exactly: the
+    chip either had a program of this engine or waited for the host."""
+    tr, eng, launches, results = _clocked(tiny_model, max_batch_size=2)
+    for n in (3, 12, 5, 6):
+        eng.submit(list(range(1, n + 1)), max_new_tokens=7)
+    turnaround = {}
+    while eng.sched.has_work:
+        stats = eng.step()
+        if stats["programs"]:
+            turnaround[stats["program"]] = stats.get("turnaround_ms")
+    records = _landings(tr)
+    assert len(records) == len(results) == eng.programs_launched >= 12
+    assert {r["ahead"] for r in records} == {0, 1}, "both kinds of step in the run"
+    total = sum(r["device_ms"] + turnaround[r["program"]] for r in records[1:])
+    assert total == (results[-1] - results[0]) * 1e3
+    for r in records[1:]:
+        assert (turnaround[r["program"]] == 0.0) == bool(r["ahead"])
+    eng.close()
+
+
+def test_a_drain_before_a_preemption_lands_the_row_as_its_own_event(tiny_model):
+    """An arena too small for its demand: a growth that needs a victim lands
+    the row in flight first (``_drain`` inside ``serve.grow``), so the step
+    launches a program that is NOT ahead and whose device time starts at its
+    own launch; every program still lands once, in order."""
+    tr, eng, launches, results = _clocked(tiny_model, block_size=4, num_blocks=10,
+                                          max_blocks_per_seq=9)
+    rng = np.random.default_rng(5)
+    for n, new in ((10, 20), (14, 16), (6, 24), (12, 12), (9, 18)):
+        eng.submit(list(map(int, rng.integers(1, 128, size=n))), max_new_tokens=new)
+    drained_then_launched = 0
+    while eng.sched.has_work:
+        in_flight = eng._flight
+        mark = len(tr.snapshot())
+        stats = eng.step()
+        names = [r["name"].rsplit(".", 1)[-1] for r in tr.snapshot()[mark:]]
+        if in_flight is not None and stats["programs"] and not stats["dispatched_ahead"]:
+            # the row landed before this step's dispatch, not behind it
+            assert names.index("fetch") < names.index("dispatch")
+            drained_then_launched += 1
+            own = [r for r in _landings(tr, mark) if r["program"] == stats["program"]]
+            if own:                     # (its own row landed in this step too)
+                assert own[0]["ahead"] == 0
+                assert own[0]["device_ms"] == (results[-1] - launches[-1]) * 1e3
+    assert eng.sched.preemption_count > 0 and drained_then_launched > 0
+    records = _landings(tr)
+    assert [r["program"] for r in records] == list(range(1, eng.programs_launched + 1))
+    assert all(r["device_ms"] > 0.0 for r in records)
+    eng.close()
+
+
+def test_the_first_program_after_the_chip_waited_carries_no_host_part(tiny_model):
+    """After an empty step, and after an incident's re-jit: the program's
+    record is there, with its own wait, and no ``host_ms``; an abandoned
+    program leaves no record and its number is not given again."""
+    tr, eng, launches, results = _clocked(tiny_model)
+    eng.submit([1, 2, 3], max_new_tokens=3).result()
+    assert eng.step()["programs"] == 0                      # nothing to run
+    eng.submit([4, 5, 6], max_new_tokens=8)
+    stats = eng.step()
+    rec = _landings(tr)[-1]
+    assert rec["program"] == stats["program"] == eng.programs_launched
+    assert "host_ms" not in rec and rec["device_ms"] == (results[-1] - launches[-1]) * 1e3
+    assert "host_ms" in (eng.step(), _landings(tr)[-1])[1]
+    eng.submit(list(range(1, 20)), max_new_tokens=2)        # prompt left: stays ahead
+    eng.step()
+    abandoned = eng._flight.number
+    landed = len(_landings(tr))
+    eng._recover_incident(ServeStepTimeout("wedged", op="decode", deadline_s=1.0,
+                                           step=eng.step_count))
+    assert eng._flight is None and eng.programs_launched == abandoned
+    after = eng.step()
+    assert after["program"] == abandoned + 1 and not _turnaround(after)
+    new = _landings(tr)[landed:]
+    assert abandoned not in [r["program"] for r in _landings(tr)]
+    assert all("host_ms" not in r for r in new if r["program"] == abandoned + 1)
+    eng.run()
+    assert [r["program"] for r in _landings(tr)] == [
+        k for k in range(1, eng.programs_launched + 1) if k != abandoned]
+    eng.close()
+
+
+def test_a_wedged_fetch_leaves_no_record_when_its_worker_comes_back(tiny_model):
+    """Under ``serve_step_timeout_s`` a wedged fetch is abandoned with its
+    worker; released, the worker ends its span, and writes no record on it:
+    the program's tokens are computed again under other numbers."""
+    import threading
+    from deepspeed_tpu.testing import fault_injection
+    tr = Tracer()
+    eng = _engine(tiny_model, tracer=tr, max_batch_size=2, serve_step_timeout_s=0.5)
+    for n in (5, 12, 7, 4):
+        eng.submit(list(range(1, n + 1)), max_new_tokens=6)
+    for _ in range(4):
+        eng.step()
+    wedged, behind = eng._flight.number, eng._flight.number + 1
+    fault_injection.install_plan([{"site": "serve.step", "action": "wedge", "on_hit": 1}])
+    try:
+        with pytest.raises(ServeStepTimeout):
+            eng.step()
+    finally:
+        fault_injection.clear_plan()
+    eng.run()
+    for t in threading.enumerate():
+        if t.name.startswith("ds-tpu-bounded") and t is not threading.current_thread():
+            t.join(timeout=0.2)     # (the engine's own worker stays: a daemon)
+    fetched = [r["args"] for r in tr.snapshot() if r["name"].endswith(".fetch")]
+    assert wedged in [a["program"] for a in fetched], "the abandoned span did end"
+    landed = [r["program"] for r in _landings(tr)]
+    assert wedged not in landed and behind not in landed
+    assert landed == [k for k in range(1, eng.programs_launched + 1)
+                      if k not in (wedged, behind)]
+    eng.close()
+
+
+def test_the_record_is_on_the_fetching_threads_line_and_the_number_on_the_main(tiny_model):
+    import threading
+    tr = Tracer()
+    eng = _engine(tiny_model, tracer=tr, serve_step_timeout_s=30.0)
+    eng.submit(list(range(1, 8)), max_new_tokens=5)
+    eng.step()                                  # compiles, in the inline launch
+    main = threading.get_ident()
+    for _ in range(3):
+        mark = len(tr.snapshot())
+        stats = eng.step()
+        recs = {r["name"]: r for r in tr.snapshot()[mark:]}
+        fetch = recs["serve.decode.fetch"]
+        assert fetch["tid"] != main and set(PROGRAM_STATS) <= set(fetch["args"])
+        assert fetch["args"]["program"] == stats["program"]
+        on_stats = recs["serve.stats"]
+        assert on_stats["tid"] == main and on_stats["args"]["program"] == stats["program"]
+        assert recs["serve.decode.commit"]["tid"] == main
+        assert recs["serve.decode.commit"]["args"]["program"] == stats["program"]
+    eng.close()
+
+
+def test_the_record_is_on_the_profilers_line_a_landed_program(tiny_model, tmp_path):
+    from jax.profiler import ProfileData
+    eng = _engine(tiny_model, max_batch_size=2)
+    for n in (3, 12, 5):
+        eng.submit(list(range(1, n + 1)), max_new_tokens=4)
+    eng.step()                                  # compiles; program 1 in flight
+    seen = []
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        while eng.sched.has_work:
+            seen.append(eng.step())
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(os.path.join(str(tmp_path), "plugins/profile/*/*.xplane.pb"))[-1]
+    host = ProfileData.from_file(path).find_plane_with_name("/host:CPU")
+    events = [(e.start_ns, e.name, dict(e.stats)) for line in host.lines for e in line.events]
+    landings = [st for _, n, st in sorted(events) if n.endswith(".fetch")]
+    assert all(set(PROGRAM_STATS) - {"host_ms"} <= set(st) for st in landings)
+    assert [st["program"] for st in landings] == list(range(1, eng.programs_launched + 1))
+    stats = [st for _, n, st in sorted(events) if n == "serve.stats"]
+    assert [st.get("program") for st in stats] == [s.get("program") for s in seen]
+    first = [st for _, n, st in events if n == "serve.first_token"]
+    assert len(first) == 3 and all(1 <= st["program"] <= eng.programs_launched for st in first)
+    eng.close()
+
 
 
 @pytest.mark.parametrize("wide", [16, 4])
@@ -605,17 +902,20 @@ def test_a_chunk_beside_decode_rows_is_one_dispatch_and_one_fetch(tiny_model):
     late = eng.submit(list(range(1, 12)), max_new_tokens=4)
     chunks = [{"rid": late.request.rid, "start": start, "tokens": n}
               for start, n in ((0, 8), (8, 3))]
-    pairs = [{"batch": 1 + c["tokens"], "chunk_tokens": c["tokens"]} for c in chunks]
+    pairs = [{"batch": 1 + c["tokens"], "chunk_tokens": c["tokens"], "program": 2 + i}
+             for i, c in enumerate(chunks)]
+    commits = [dict(c, program=pair["program"]) for c, pair in zip(chunks, pairs)]
     fetched = ([], pairs)                       # the second step fetches both rows
     for chunk, pair, rows in zip(chunks, pairs, fetched):
         by, stats = _step_spans(tr, eng)
         assert not any(n.endswith((".dispatch", ".fetch")) and "prefill" in n for n in by)
         assert by["serve.decode.dispatch"] == [pair]
-        assert by.get("serve.decode.fetch", []) == rows
+        assert _less_record(by.get("serve.decode.fetch", [])) == rows
         assert by["serve.prefill.build"] == [chunk]
-        assert by.get("serve.prefill.commit", []) == (chunks if rows else [])
+        assert by.get("serve.prefill.commit", []) == (commits if rows else [])
         assert by["serve.decode.build"] == [{"batch": 1}]
-        assert by.get("serve.decode.commit", []) == [{"batch": 1}] * len(rows)
+        assert by.get("serve.decode.commit", []) == [
+            {"batch": 1, "program": pair["program"]} for pair in rows]
         assert (stats["programs"], stats["prefill_tokens"], stats["decode_batch"]) \
             == (1, chunk["tokens"], 1)
     assert len(late.request.generated) == 1, "decodes from the next step"

@@ -35,7 +35,6 @@ def write_rank_trace(tmp_path, rank, wall_offset_ns=0):
     with tr.span("train_batch", step=1):
         with tr.span("comm.all_reduce", op="all_reduce", bytes=4096):
             pass
-    tr.instant("overflow")
     path = str(tmp_path / f"trace_rank{rank}.json")
     return tr.export_chrome_trace(path)
 
@@ -57,8 +56,6 @@ class TestTraceMerge:
             assert {"name", "ph", "pid", "tid"} <= set(ev)
             if ev["ph"] == "X":
                 assert "ts" in ev and "dur" in ev and ev["dur"] >= 0
-            elif ev["ph"] == "i":
-                assert "ts" in ev
         json.dumps(doc)      # round-trips as JSON
 
         # both ranks present as distinct pids
