@@ -8,10 +8,15 @@ architecture to fuse.
 
 TPU-first design decisions:
 
-* ``lax.scan`` over layers (``scan_layers=True``): one compiled block body
-  regardless of depth — compile time is O(1) in ``n_layer`` and parameters
-  carry a leading ``[n_layer, ...]`` dim that the ZeRO ``fsdp`` axis shards
-  naturally.
+* Stacked layers (``scan_layers=True``, a LAYOUT): the block parameters
+  carry a leading ``[n_layer, ...]`` dim, which checkpoints, the ZeRO policy
+  and every serving path read.  How the dense forward WALKS that stack is
+  ``layer_walk``'s to say, from ``cfg`` and the mesh: a ``lax.scan`` (one
+  compiled block body whatever the depth, compile time O(1) in ``n_layer``)
+  under remat and where the parameters arrive sharded over ``fsdp``; a
+  Python loop over static slices everywhere else, because a loop copies
+  every residual its backward needs into a stack and out again (compile
+  time is then O(``n_layer``)).  The serving steps keep their scans.
 * Megatron-style tensor parallelism is expressed purely as sharding
   metadata (``partition_specs``): QKV/MLP-up are column-parallel
   (output-dim ``tensor``), attn-out/MLP-down row-parallel (input-dim
@@ -121,6 +126,9 @@ class GPTConfig:
     n_layer: int = 12
     n_head: int = 12
     dropout: float = 0.0
+    # the LAYOUT of params["blocks"]: leaves stacked [n_layer, ...] (True) or
+    # a dict of per-layer trees h0, h1, ... (False).  Not the walk: whether a
+    # stacked layout is scanned or unrolled is ``layer_walk``'s rule
     scan_layers: bool = True
     remat: bool = False
     attn_impl: str = "auto"   # 'auto' | 'flash' | 'reference' | 'ring'
@@ -1220,8 +1228,10 @@ def _maybe_actq(cfg: "GPTConfig", h: Array) -> Array:
 
 
 def _scan_layers(n_kinds: int, layer_fn: Callable, carry, xs):
-    """``lax.scan`` over the stacked layers ``xs`` (leaves ``[L, ...]``) of a
-    stack that repeats in a period of ``n_kinds`` layers:
+    """The walk that IS a loop (the serving steps always, the dense forward
+    where ``layer_walk`` says "scan"): ``lax.scan`` over the stacked layers
+    ``xs`` (leaves ``[L, ...]``) of a stack that repeats in a period of
+    ``n_kinds`` layers:
     ``layer_fn(j, carry, x) -> (carry, y)`` runs one layer of the period's
     ``j``-th kind (static).  The leaves are read as ``[periods, period,
     ...]``: the scan runs over periods and its body walks the period, taking
@@ -1247,6 +1257,44 @@ def _scan_layers(n_kinds: int, layer_fn: Callable, carry, xs):
 
     carry, ys = jax.lax.scan(period, carry, jnp.arange(n_layer // n_kinds))
     return carry, jax.tree.map(lambda a: a.reshape(-1, *a.shape[2:]), ys)
+
+
+def layer_walk(cfg: "GPTConfig") -> str:
+    """How ``gpt_forward`` walks a STACKED ``params["blocks"]``: ``"scan"``,
+    one ``lax.scan`` over the layers, or ``"unrolled"``, a Python loop over
+    static slices ``leaf[i]`` that leaves no loop in the program.  The rule,
+    stated here and nowhere else, reads ``cfg`` and the mesh:
+
+    * a loop keeps what its backward needs STACKED: every residual of a layer
+      is copied into row ``i`` of an ``[n_layer, ...]`` buffer and copied out
+      again by the backward (at GPT-2 124M, micro 8 x 1024: 2.4 GB a step
+      each way, a sixth of the step, PERF.md § 6, PR 54).  Unrolled, a
+      residual stays where its producer wrote it.  So: unrolled, unless
+    * ``cfg.remat``: the loop then stashes ONE tensor a layer, the block's
+      input, and the block's own residuals live and die inside the body:
+      2.5% of GPT-2 XL's step, for which 48 layers unrolled would compile
+      for minutes; or
+    * the parameters reach the forward sharded over ``fsdp`` (ZeRO-3, the
+      layered prefetch): one body holds one gather a leaf, and the loop keeps
+      each next to its use.  Unrolled, the step's text held 448 all-gathers
+      where the scan's holds 25 (GPT-2 XL's widths, 16 layers, compiled ahead
+      of time for four v5e chips, PERF.md § 6, PR 54) and where they run is
+      the scheduler's to choose; that compile did NOT hoist them (its
+      temporaries fell, 3.1 to 1.6 GB), but no cell measures a sharded step
+      without remat, and 48 layers unrolled compile for minutes: the scan
+      stays until a cell says otherwise.
+
+    The per-layer layout (``scan_layers=False``) has no stack to scan.  The
+    rule cannot see whether a backward will follow (``train`` says dropout,
+    and a forward with ``train=False`` is differentiated too), so the
+    forward alone takes the same walk as its training step."""
+    if not cfg.scan_layers:
+        return "unrolled"
+    if cfg.remat or zero_layered.current_prefetch() is not None:
+        return "scan"
+    if mesh_lib.has_mesh() and mesh_lib.axis_size("fsdp") > 1:
+        return "scan"
+    return "unrolled"
 
 
 def _refuse_hybrid(cfg: "GPTConfig", path: str) -> None:
@@ -1374,12 +1422,22 @@ def gpt_forward(cfg: GPTConfig, params: Dict, input_ids: Array,
         x = _dropout(x, cfg.dropout, rng, train)
 
     # one body a kind of the layer pattern (a kind is static)
-    bodies = [partial(gpt_block, cfg, train=train, attention_fn=attention_fn,
-                      kind=kind) for kind in cfg.pattern]
+    def body_of(kind):
+        def layer(p, x, r):
+            return gpt_block(cfg, p, x, r, train, attention_fn, kind)
+        return layer
+
+    bodies = [body_of(kind) for kind in cfg.pattern]
+    walk = layer_walk(cfg)
     if cfg.remat:
         from deepspeed_tpu.runtime.activation_checkpointing.checkpointing import (
             checkpoint_policy)
         bodies = [jax.checkpoint(b, policy=checkpoint_policy()) for b in bodies]
+    elif walk == "unrolled":
+        # traced, differentiated and lowered ONCE a kind, called n_layer times
+        # (XLA inlines the calls): what an unrolled walk costs in set-up is
+        # then the compile alone, which the persistent cache answers
+        bodies = [jax.jit(b) for b in bodies]
     n_kinds = len(bodies)
 
     # random-LTD: each block trains on its own sorted random token subset,
@@ -1399,31 +1457,62 @@ def gpt_forward(cfg: GPTConfig, params: Dict, input_ids: Array,
         pld_keep = jax.random.bernoulli(jax.random.fold_in(rng, 55), keep_p)
 
     zero_aux = jnp.zeros((), jnp.float32)
+    use_rngs = rng is not None and train
 
-    def apply_block(p, x, r, idx=None, ltd_this_layer=True, j=0):
-        body = bodies[j]
-        if ltd_on and idx is not None and ltd_this_layer:
-            sub, aux = body(p, jnp.take(x, idx, axis=1), r)
-            return x.at[:, idx].set(sub), aux
-        return body(p, x, r)
+    def run_layer(j, carry, layer):
+        """One layer of the pattern's ``j``-th kind: ``layer`` holds its
+        parameters ``p``, its rng ``r``, and where they are on its random-LTD
+        index row ``idx`` and its PLD flag ``keep``."""
+        x, aux_sum = carry
+        body, p, idx = bodies[j], layer["p"], layer.get("idx")
+        r = layer["r"] if use_rngs else None
 
-    aux_total = zero_aux
-    if cfg.scan_layers:
-        use_rngs = rng is not None and train
-        rngs = (jax.random.split(jax.random.fold_in(rng, 7), cfg.n_layer)
-                if use_rngs else jnp.zeros((cfg.n_layer, 2), jnp.uint32))
-        pf = zero_layered.current_prefetch()
-        xs = {"r": rngs}
-        if pf is None:
-            xs["p"] = params["blocks"]
+        def run(xx):
+            if idx is None:
+                return body(p, xx, r)
+            sub, aux = body(p, jnp.take(xx, idx, axis=1), r)
+            return xx.at[:, idx].set(sub), aux
+
+        if pld_on:   # lax.cond: a dropped block really skips its FLOPs
+            x, aux = jax.lax.cond(layer["keep"], run,
+                                  lambda xx: (xx, zero_aux), x)
         else:
-            xs["i"] = jnp.arange(cfg.n_layer, dtype=jnp.int32)
+            x, aux = run(x)
+        return (x, aux_sum + aux), None
+
+    if cfg.scan_layers:
+        xs = {"r": (jax.random.split(jax.random.fold_in(rng, 7), cfg.n_layer)
+                    if use_rngs else jnp.zeros((cfg.n_layer, 2), jnp.uint32))}
         if ltd_on:
             xs["idx"] = ltd_idx
         if pld_on:
             xs["keep"] = pld_keep
-
-        if pf is not None:
+    pf = zero_layered.current_prefetch()
+    carry = (x, zero_aux)
+    with jax.named_scope("blocks"):
+        if walk == "unrolled":
+            if cfg.scan_layers:
+                # static slices leaf[i], for XLA's own dots a view; inside ONE
+                # jit, so that jax.grad meets one equation where it would
+                # differentiate n_layer x leaves slices one by one
+                layers = jax.jit(lambda t: [jax.tree.map(
+                    lambda a: jax.lax.index_in_dim(a, i, 0, keepdims=False), t)
+                    for i in range(cfg.n_layer)])(dict(xs, p=params["blocks"]))
+            else:
+                layers = [{"p": params["blocks"][f"h{i}"],
+                           "r": jax.random.fold_in(rng, i) if use_rngs else None}
+                          for i in range(cfg.n_layer)]
+                for i, layer in enumerate(layers):
+                    if ltd_on and (cfg.ltd_layers is None or i in cfg.ltd_layers):
+                        layer["idx"] = ltd_idx[i]
+                    if pld_on:
+                        layer["keep"] = pld_keep[i]
+            for i, layer in enumerate(layers):
+                carry, _ = run_layer(i % n_kinds, carry, layer)
+        elif pf is None:
+            carry, _ = _scan_layers(n_kinds, run_layer, carry,
+                                    dict(xs, p=params["blocks"]))
+        else:
             # Layered ZeRO-3: params["blocks"] are still SHARDED here —
             # the carry holds a ring of `depth` already-gathered block
             # slices, and each iteration issues block i+depth's gather
@@ -1438,53 +1527,17 @@ def gpt_forward(cfg: GPTConfig, params: Dict, input_ids: Array,
             depth = pf.clamped_depth(cfg.n_layer)
             ring = tuple(pf.gather_block(blocks, jnp.int32(k))
                          for k in range(depth))
+            xs["i"] = jnp.arange(cfg.n_layer, dtype=jnp.int32)
 
-            def scan_body(carry, layer):
-                (x, aux_sum), ring = carry
+            def ring_body(carry, layer):
+                carry, ring = carry
                 nxt = pf.gather_block(
                     blocks, jnp.minimum(layer["i"] + depth, cfg.n_layer - 1))
-                p = ring[0]
-                r = layer["r"] if use_rngs else None
-                run = lambda xx: apply_block(p, xx, r, layer.get("idx"))
-                if pld_on:
-                    x, aux = jax.lax.cond(layer["keep"], run,
-                                          lambda xx: (xx, zero_aux), x)
-                else:
-                    x, aux = run(x)
-                return ((x, aux_sum + aux), ring[1:] + (nxt,)), None
+                carry, _ = run_layer(0, carry, dict(layer, p=ring[0]))
+                return (carry, ring[1:] + (nxt,)), None
 
-            with jax.named_scope("blocks"):
-                ((x, aux_total), _), _ = jax.lax.scan(
-                    scan_body, ((x, zero_aux), ring), xs)
-        else:
-            def scan_body(j, carry, layer):
-                x, aux_sum = carry
-                r = layer["r"] if use_rngs else None
-                run = lambda xx: apply_block(layer["p"], xx, r,
-                                             layer.get("idx"), j=j)
-                if pld_on:   # lax.cond: a dropped block really skips its FLOPs
-                    x, aux = jax.lax.cond(layer["keep"], run,
-                                          lambda xx: (xx, zero_aux), x)
-                else:
-                    x, aux = run(x)
-                return (x, aux_sum + aux), None
-
-            with jax.named_scope("blocks"):
-                (x, aux_total), _ = _scan_layers(n_kinds, scan_body,
-                                                 (x, zero_aux), xs)
-    else:
-        for i in range(cfg.n_layer):
-            r = jax.random.fold_in(rng, i) if (rng is not None and train) else None
-            p = params["blocks"][f"h{i}"]
-            ltd_this = cfg.ltd_layers is None or i in cfg.ltd_layers
-            run = lambda xx: apply_block(p, xx, r, ltd_idx[i] if ltd_on else None,
-                                         ltd_this, j=i % n_kinds)
-            if pld_on:
-                x, aux = jax.lax.cond(pld_keep[i], run,
-                                      lambda xx: (xx, zero_aux), x)
-            else:
-                x, aux = run(x)
-            aux_total = aux_total + aux
+            (carry, _), _ = jax.lax.scan(ring_body, (carry, ring), xs)
+    x, aux_total = carry
 
     with jax.named_scope("head"):
         x = _norm(cfg, x, params["lnf_g"], params["lnf_b"])
