@@ -36,6 +36,7 @@ TPU-first design decisions:
 
 import dataclasses
 import math
+from contextlib import nullcontext
 from functools import partial
 from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
@@ -182,8 +183,24 @@ class GPTConfig:
     # untied lm_head bias (GPT-J checkpoints carry one)
     head_bias: bool = False
     # RMSNorm over the WHOLE q and k projections (weights [H*D], [Hkv*D]),
-    # before the split into heads and before rope (OLMoE)
-    qk_norm: bool = False
+    # before the split into heads and before rope (OLMoE); "head": over each
+    # HEAD's lanes, after the split and before rope, one gain of ``head_dim``
+    # the heads share (the afmoe line; the hybrid mixers norm theirs so)
+    qk_norm: Any = False
+    # attention's output gate: ``o * sigmoid(h W_g)`` before ``W_o``, ``h``
+    # the normed input attention read (leaf ``gate_w [E, H * v_head_dim]``)
+    attn_gate: bool = False
+    # a second norm a sublayer, on its OUTPUT, beside the one on its input:
+    # ``x + norm(f(norm(x)))``, four a layer (leaves ``post_attn_g``,
+    # ``post_mlp_g``).  The periodic walk reads it; ``norm_after`` below is
+    # the hybrid walk's.  ``post_attn_gain``: what ``post_attn_g`` is SEEDED
+    # at (a weight like any other, trained away from it).  At seeded weights
+    # softmax attention over thousands of random keys returns nearly one
+    # vector whatever the query, which a gain of 1 scales up to the
+    # embedding's size: the tokens of a step then share their router input
+    # and go to the same few experts (PERF.md § 6, PR 55)
+    norm_sandwich: bool = False
+    post_attn_gain: float = 1.0
     # activation fake-quant (compression_training.activation_quantization;
     # reference QuantAct, compression/basic_layer.py:404): bits on the
     # normed inputs of the attention and MLP linears, STE gradients
@@ -226,6 +243,18 @@ class GPTConfig:
     # experts every token goes through, beside the routed ones: ONE MLP of
     # ``moe_shared_experts * moe_expert_hidden``, added unweighted
     moe_shared_experts: int = 0
+    # times the chosen experts' weights, after they are renormalised (the
+    # DeepSeek-V3 line's ``routed_scaling_factor``, afmoe's ``route_scale``)
+    moe_route_scale: float = 1.0
+    # the FIRST layers of the stack whose feed-forward is the dense MLP,
+    # ``ffn_dim`` wide, where every later layer's is the bank
+    # (``first_k_dense_replace``, ``num_dense_layers``); the experts are
+    # ``moe_expert_hidden`` wide.  The leaves of the two kinds differ in
+    # shape, so the feed-forward is stacked BY KIND beside the attention
+    # leaves, which stay one stack over all layers: ``blocks["lead"]``
+    # ``[moe_dense_layers, ...]`` and ``blocks["moe"]`` ``[n_layer -
+    # moe_dense_layers, ...]``, a layer reading its own index of its kind
+    moe_dense_layers: int = 0
     # ``(first, count)``: the bank holds only the experts ``first .. first +
     # count - 1`` of the ``moe_num_experts`` the router chooses among (one
     # chip's share of an expert-parallel layer); what the others would add
@@ -247,7 +276,8 @@ class GPTConfig:
     # MiniCPM-SALA family, ``models/hybrid.py``): ``layer_pattern`` then names
     # EVERY layer, some of a ``mixer`` other than softmax, and the leaves are
     # stacked by kind.  ``sparse`` is the sparse layers' selection; the
-    # MiniCPM scalings: ``x_0 = scale_emb * wte[ids]``, a block adds
+    # MiniCPM scalings: ``x_0 = scale_emb * wte[ids]`` (every walk reads this
+    # one: afmoe's ``mup_enabled`` is ``sqrt(n_embd)``), a block adds
     # ``residual_scale * f(norm(x))``, the head reads ``norm(x) /
     # head_divisor``; ``published_layers`` is the depth the decays and
     # ``residual_scale`` were published for --------------------------------- #
@@ -377,6 +407,17 @@ class GPTConfig:
             assert not self.norm_after, (
                 "the norm on a sublayer's output is read by the hybrid walk "
                 "(models/hybrid.py) alone")
+            assert self.qk_norm in (False, True, "head"), self.qk_norm
+            assert not self.norm_sandwich or (
+                self.norm == "rmsnorm" and self.block_type == "sequential"), (
+                    "norms on both sides of a sublayer: RMSNorm, sequential")
+            assert not self.attn_gate or not self.kv_lora_rank
+            lead = self.moe_dense_layers
+            assert not lead or (
+                0 < lead < self.n_layer and self.moe_num_experts
+                and self.moe_router == "dropless" and self.scan_layers), (
+                    f"moe_dense_layers {lead}: the first layers of a stacked "
+                    f"stack of {self.n_layer} behind the dropless router")
             assert not self.moe_router_hidden, (
                 "the router's stream is a second carry of the layer walk, "
                 "which models/hybrid.py alone has")
@@ -543,6 +584,45 @@ def mistral4_config(vocab_size=131072, n_positions=1048576, n_embd=4096,
                         intermediate_size=intermediate_size, **kw)
 
 
+def trinity_config(vocab_size=200192, n_positions=262144, n_embd=3072,
+                   n_layer=60, n_head=48, n_kv_head=8, head_dim=128,
+                   intermediate_size=12288, moe_intermediate_size=3072,
+                   num_experts=256, top_k=4, shared_experts=1, dense_layers=6,
+                   route_scale=2.448, window=4096, experts_held=None,
+                   published_layers=60, **overrides) -> GPTConfig:
+    """Trinity family (``model_type`` afmoe; defaults: Trinity-Large-Preview):
+    a period of four layers, three attention over a ``window`` of keys with
+    rope and then one full causal attention WITHOUT position encoding;
+    grouped K/V heads, an RMSNorm a head on q and k, a sigmoid output gate
+    before ``W_o``; a norm on each sublayer's input AND output; the first
+    ``dense_layers`` layers a dense SwiGLU ``intermediate_size`` wide, every
+    later one a bank of SwiGLU experts ``moe_intermediate_size`` wide behind a
+    dropless sigmoid router (the ``top_k`` largest of score + bias, weighed
+    by their scores renormalised times ``route_scale``) beside
+    ``shared_experts`` every token goes through; ``experts_held = (first,
+    count)`` keeps one chip's share of the bank.  The embedding times
+    ``sqrt(n_embd)`` (``mup_enabled``); RMSNorm (eps 1e-5), no bias, untied
+    head, rope theta 10,000.  The norm on attention's output is seeded at
+    ``1 / sqrt(published_layers)`` (the family's depth-scaled sandwich norm,
+    as far as seeded routing needs it: ``post_attn_gain``), every other gain
+    at 1.  Served through ``init_serving()``."""
+    kw = dict(n_kv_head=n_kv_head, head_dim=head_dim, ln_eps=1e-5,
+              layer_pattern=3 * (LayerKind(window, True),) + (LayerKind(None, False),),
+              qk_norm="head", attn_gate=True, norm_sandwich=True,
+              post_attn_gain=1.0 / math.sqrt(published_layers),
+              published_layers=published_layers, scale_emb=math.sqrt(n_embd),
+              moe_num_experts=num_experts, moe_top_k=top_k,
+              moe_expert_hidden=moe_intermediate_size,
+              moe_dense_layers=dense_layers, moe_router="dropless",
+              moe_scoring="sigmoid", moe_norm_topk=True,
+              moe_route_scale=route_scale, moe_shared_experts=shared_experts,
+              moe_experts_held=tuple(experts_held) if experts_held else None)
+    kw.update(overrides)
+    return llama_config(vocab_size=vocab_size, n_positions=n_positions,
+                        n_embd=n_embd, n_layer=n_layer, n_head=n_head,
+                        intermediate_size=intermediate_size, **kw)
+
+
 _SALA_MIXERS = {"minicpm4": "sparse", "lightning-attn": "linear"}
 
 
@@ -697,12 +777,14 @@ def _dense_init(rng, fan_in, shape, scale=0.02):
     return (jax.random.normal(rng, shape, jnp.float32) * scale).astype(jnp.float32)
 
 
-def _init_block(cfg: GPTConfig, rng: Array) -> Dict:
-    """One transformer block's params (GPT-2 init: residual projections
-    scaled by 1/sqrt(2L))."""
-    E, I = cfg.n_embd, cfg.ffn_dim
-    fc_out = 2 * I if cfg.mlp_type == "swiglu" else I   # swiglu fuses gate|up
-    proj_scale = 0.02 / math.sqrt(2 * cfg.n_layer)
+def _proj_scale(cfg: GPTConfig) -> float:
+    """GPT-2 init: the residual projections scaled by 1/sqrt(2L)."""
+    return 0.02 / math.sqrt(2 * cfg.n_layer)
+
+
+def _init_attn(cfg: GPTConfig, rng: Array) -> Dict:
+    """One block's leaves outside its feed-forward: attention and the norms."""
+    E = cfg.n_embd
     ks = jax.random.split(rng, 4)
     out = {
         "ln1_g": jnp.ones((E,), jnp.float32),
@@ -710,18 +792,23 @@ def _init_block(cfg: GPTConfig, rng: Array) -> Dict:
         "qkv_w": _dense_init(ks[0], E, (E, cfg.qkv_dim)),
         "qkv_b": jnp.zeros((cfg.qkv_dim,), jnp.float32),
         "out_w": _dense_init(ks[1], E, (cfg.n_head * cfg.v_head_dim, E),
-                             scale=proj_scale),
+                             scale=_proj_scale(cfg)),
         "out_b": jnp.zeros((E,), jnp.float32),
         "ln2_g": jnp.ones((E,), jnp.float32),
         "ln2_b": jnp.zeros((E,), jnp.float32),
-        "fc_w": _dense_init(ks[2], E, (E, fc_out)),
-        "fc_b": jnp.zeros((fc_out,), jnp.float32),
-        "proj_w": _dense_init(ks[3], I, (I, E), scale=proj_scale),
-        "proj_b": jnp.zeros((E,), jnp.float32),
     }
-    if cfg.qk_norm:
+    if cfg.qk_norm == "head":
+        out["q_norm_g"] = jnp.ones((cfg.head_dim,), jnp.float32)
+        out["k_norm_g"] = jnp.ones((cfg.head_dim,), jnp.float32)
+    elif cfg.qk_norm:
         out["q_norm_g"] = jnp.ones((cfg.n_head * cfg.head_dim,), jnp.float32)
         out["k_norm_g"] = jnp.ones((cfg.kv_heads * cfg.head_dim,), jnp.float32)
+    if cfg.attn_gate:
+        out["gate_w"] = _dense_init(jax.random.fold_in(rng, 2468), E,
+                                    (E, cfg.n_head * cfg.v_head_dim))
+    if cfg.norm_sandwich:
+        out["post_attn_g"] = jnp.full((E,), cfg.post_attn_gain, jnp.float32)
+        out["post_mlp_g"] = jnp.ones((E,), jnp.float32)
     if cfg.kv_lora_rank:
         # latent attention: the fused qkv gives way to the two low-rank query
         # projections and the joint K/V down- and up-projection
@@ -736,36 +823,60 @@ def _init_block(cfg: GPTConfig, rng: Array) -> Dict:
             kv_a_norm_g=jnp.ones((R,), jnp.float32),
             kv_b_w=_dense_init(ka[3], R, (R, H * (
                 cfg.head_dim - cfg.qk_rope_dim + cfg.v_head_dim))))
-    if cfg.moe_num_experts > 0:
-        # the MLP becomes a gated expert bank (reference moe/layer.py:16):
-        # the dense fc/proj leaves, stacked over experts, as wi/bi/wo/bo
-        N, Ie = cfg.moe_num_experts, cfg.moe_expert_hidden or I
-        km = jax.random.split(jax.random.fold_in(rng, 1234), 3)
-        for k in ("fc_w", "fc_b", "proj_w", "proj_b"):
-            del out[k]
-        glu = 2 if cfg.mlp_type == "swiglu" else 1
-        up = glu * Ie
-        G = cfg.bank_experts[1]     # held by the bank; the router stays N wide
-        experts = {"wi": _dense_init(km[1], E, (G, E, up)),
-                   "wo": _dense_init(km[2], Ie, (G, Ie, E), scale=proj_scale)}
-        if cfg.use_bias:
-            experts.update(bi=jnp.zeros((G, up), jnp.float32),
-                           bo=jnp.zeros((G, E), jnp.float32))
-        out["moe"] = {
-            "gate": {"wg": _dense_init(km[0], E, (E, N))},
-            "experts": experts,
-        }
-        if cfg.moe_scoring == "sigmoid":
-            # the score-correction bias: chooses, never weighs
-            out["moe"]["gate"]["bias"] = jnp.zeros((N,), jnp.float32)
-        if cfg.moe_shared_experts:
-            assert not cfg.use_bias, "a shared expert carries no bias"
-            Is = cfg.moe_shared_experts * Ie
-            ksh = jax.random.split(jax.random.fold_in(rng, 5678), 2)
-            out["moe"]["shared"] = {
-                "wi": _dense_init(ksh[0], E, (E, glu * Is)),
-                "wo": _dense_init(ksh[1], Is, (Is, E), scale=proj_scale)}
     return out
+
+
+def _init_mlp(cfg: GPTConfig, rng: Array, biases: bool = True) -> Dict:
+    """One block's dense MLP, ``ffn_dim`` wide (``biases``: with the two
+    bias leaves, which a block holds as zeros even where nothing reads
+    them; a dense lead's stack holds them only where ``use_bias``)."""
+    E, I = cfg.n_embd, cfg.ffn_dim
+    fc_out = 2 * I if cfg.mlp_type == "swiglu" else I   # swiglu fuses gate|up
+    ks = jax.random.split(rng, 4)
+    out = {"fc_w": _dense_init(ks[2], E, (E, fc_out)),
+           "proj_w": _dense_init(ks[3], I, (I, E), scale=_proj_scale(cfg))}
+    if biases:
+        out.update(fc_b=jnp.zeros((fc_out,), jnp.float32),
+                   proj_b=jnp.zeros((E,), jnp.float32))
+    return out
+
+
+def _init_moe(cfg: GPTConfig, rng: Array) -> Dict:
+    """One block's gated expert bank (reference moe/layer.py:16): the dense
+    fc/proj leaves, stacked over experts, as wi/bi/wo/bo; the router; the
+    shared expert."""
+    E = cfg.n_embd
+    N, Ie = cfg.moe_num_experts, cfg.moe_expert_hidden or cfg.ffn_dim
+    km = jax.random.split(jax.random.fold_in(rng, 1234), 3)
+    glu = 2 if cfg.mlp_type == "swiglu" else 1
+    up = glu * Ie
+    G = cfg.bank_experts[1]     # held by the bank; the router stays N wide
+    experts = {"wi": _dense_init(km[1], E, (G, E, up)),
+               "wo": _dense_init(km[2], Ie, (G, Ie, E), scale=_proj_scale(cfg))}
+    if cfg.use_bias:
+        experts.update(bi=jnp.zeros((G, up), jnp.float32),
+                       bo=jnp.zeros((G, E), jnp.float32))
+    moe = {"gate": {"wg": _dense_init(km[0], E, (E, N))}, "experts": experts}
+    if cfg.moe_scoring == "sigmoid":
+        # the score-correction bias: chooses, never weighs
+        moe["gate"]["bias"] = jnp.zeros((N,), jnp.float32)
+    if cfg.moe_shared_experts:
+        assert not cfg.use_bias, "a shared expert carries no bias"
+        Is = cfg.moe_shared_experts * Ie
+        ksh = jax.random.split(jax.random.fold_in(rng, 5678), 2)
+        moe["shared"] = {
+            "wi": _dense_init(ksh[0], E, (E, glu * Is)),
+            "wo": _dense_init(ksh[1], Is, (Is, E), scale=_proj_scale(cfg))}
+    return moe
+
+
+def _init_block(cfg: GPTConfig, rng: Array) -> Dict:
+    """One transformer block's params: attention, and the feed-forward every
+    layer of the stack has (a stack with a dense lead builds its two kinds
+    apart: ``init_gpt_params``)."""
+    if cfg.moe_num_experts > 0:
+        return {**_init_attn(cfg, rng), "moe": _init_moe(cfg, rng)}
+    return {**_init_attn(cfg, rng), **_init_mlp(cfg, rng)}
 
 
 def _init_embed(cfg: GPTConfig, rng: Array) -> Dict:
@@ -792,8 +903,15 @@ def init_gpt_params(cfg: GPTConfig, rng: Array) -> Dict:
         # the tree inside the same jit then never holds all layers in fp32
         over_layers = jax.lax.map if cfg.moe_num_experts > 0 else (
             lambda f, keys: jax.vmap(f)(keys))
-        blocks = over_layers(partial(_init_block, cfg),
-                             jax.random.split(k_blocks, L))
+        keys, lead = jax.random.split(k_blocks, L), cfg.moe_dense_layers
+        if lead:
+            # the feed-forward by kind beside one stack of attention leaves
+            blocks = dict(over_layers(partial(_init_attn, cfg), keys),
+                          lead=over_layers(partial(_init_mlp, cfg, biases=cfg.use_bias),
+                                           keys[:lead]),
+                          moe=over_layers(partial(_init_moe, cfg), keys[lead:]))
+        else:
+            blocks = over_layers(partial(_init_block, cfg), keys)
     else:
         blocks = {f"h{i}": _init_block(cfg, k)
                   for i, k in enumerate(jax.random.split(k_blocks, L))}
@@ -841,6 +959,10 @@ def gpt_partition_specs(cfg: GPTConfig) -> Dict:
                 del keys[k]
         if cfg.qk_norm:
             keys.update(q_norm_g=PartitionSpec(), k_norm_g=PartitionSpec())
+        if cfg.attn_gate:
+            keys["gate_w"] = PartitionSpec(None, "tensor")
+        if cfg.norm_sandwich:
+            keys.update(post_attn_g=PartitionSpec(), post_mlp_g=PartitionSpec())
         if cfg.kv_lora_rank:
             # the up-projections make heads: column-parallel; the two
             # down-projections are shared by all heads
@@ -852,6 +974,11 @@ def gpt_partition_specs(cfg: GPTConfig) -> Dict:
                         kv_a_norm_g=PartitionSpec(),
                         kv_b_w=PartitionSpec(None, "tensor"))
         specs = {k: PartitionSpec(*pre, *s) for k, s in keys.items()}
+        if cfg.moe_dense_layers:
+            # the dense lead's MLP, a stack of its own
+            specs["lead"] = {k: PartitionSpec(*pre, *_BLOCK_SPECS[k])
+                             for k in ("fc_w", "fc_b", "proj_w", "proj_b")
+                             if cfg.use_bias or k.endswith("_w")}
         if cfg.moe_num_experts > 0:
             experts = {"wi": PartitionSpec(*pre, "expert", None, "tensor"),
                        "wo": PartitionSpec(*pre, "expert", "tensor", None)}
@@ -1004,7 +1131,8 @@ def _project_qkv(cfg: "GPTConfig", p: Dict, h: Array, dt, positions: Array,
                  kind: Optional[LayerKind] = None):
     """The normed input ``h [B, S, E]`` -> q ``[B,S,H,D]``, k and v
     ``[B,S,Hkv,D]``: the fused projection, its bias, the q/k RMSNorm over
-    all lanes (``qk_norm``), the split into heads, rope at ``positions``
+    all lanes or over each head's (``qk_norm``), the split into heads, rope
+    at ``positions``
     (``[S]`` or ``[B, S]``) where the layer's ``kind`` ropes.  Latent
     attention in its PLAIN form: every head's own key and value made from
     the latent (v ``[B,S,H,v_head_dim]``)."""
@@ -1013,13 +1141,16 @@ def _project_qkv(cfg: "GPTConfig", p: Dict, h: Array, dt, positions: Array,
     qkv = h @ _wget(p, "qkv_w", dt)
     if cfg.use_bias:
         qkv = qkv + p["qkv_b"].astype(dt)
-    if cfg.qk_norm:
+    if cfg.qk_norm is True:
         nq, nk = cfg.n_head * cfg.head_dim, cfg.kv_heads * cfg.head_dim
         qkv = jnp.concatenate([
             rms_norm(qkv[..., :nq], p["q_norm_g"], eps=cfg.ln_eps),
             rms_norm(qkv[..., nq:nq + nk], p["k_norm_g"], eps=cfg.ln_eps),
             qkv[..., nq + nk:]], axis=-1)
     q, k, v = _split_qkv(cfg, qkv)
+    if cfg.qk_norm == "head":
+        q = rms_norm(q, p["q_norm_g"], eps=cfg.ln_eps)
+        k = rms_norm(k, p["k_norm_g"], eps=cfg.ln_eps)
     if (kind or cfg.pattern[0]).rope:
         q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_dim,
                        cfg.rope_interleaved)
@@ -1092,6 +1223,24 @@ def _wget(p: Dict, key: str, dt) -> Array:
     return _wleaf(p[key], dt)
 
 
+def out_gate(p: Dict, o: Array, h: Array, dt) -> Array:
+    """Attention's output under its gate, ``o * sigmoid(h W_g)``: ``o`` the
+    heads' outputs side by side, ``h`` the normed input attention read."""
+    return o * jax.nn.sigmoid(h @ _wget(p, "gate_w", dt))
+
+
+def _attn_out(cfg: "GPTConfig", p: Dict, o: Array, h: Array, dt) -> Array:
+    """``o [..., H * v_head_dim]`` through the gate where the model has one
+    (``attn_gate``), then ``W_o`` and its bias."""
+    if cfg.attn_gate:
+        with jax.named_scope("attn_gate"):
+            o = out_gate(p, o, h, dt)
+    o = o @ _wget(p, "out_w", dt)
+    if cfg.use_bias:
+        o = o + p["out_b"].astype(dt)
+    return o
+
+
 def _mlp(cfg: "GPTConfig", p: Dict, h: Array, dt, matmul=None,
          bias=lambda b: b) -> Array:
     """The block's MLP.  An expert bank runs the same arithmetic on stacked
@@ -1123,19 +1272,24 @@ def _ffn(cfg: "GPTConfig", p: Dict, h: Array, dt, rng=None,
          attn_in: Optional[Array] = None,
          bank_at: Optional[Tuple[Dict, Array]] = None
          ) -> Tuple[Array, Array, Optional[Array]]:
-    """Dense MLP or top-k gated MoE expert bank (reference ``moe/layer.py:16``
-    when ``moe_num_experts > 0``).  Returns ``(y, aux_loss, expert_counts)``:
+    """Dense MLP or top-k gated MoE expert bank (reference ``moe/layer.py:16``),
+    by the leaves ``p`` holds: the bank where it carries ``"moe"`` (every
+    layer of an MoE model but its dense lead).  Returns ``(y, aux_loss, expert_counts)``:
     on the dense path the aux loss is zero and the counts None; the counts
     (``[experts]`` int32, assignments of this call) leave out the rows
-    ``live [tokens]`` marks as carrying no request.  ``attn_in`` is the
+    ``live [tokens]`` marks as carrying no request, and the dropless bank
+    computes nothing for them.  ``attn_in`` is the
     normed input attention read, which a ``moe_router_input`` of
     ``pre_attn`` routes by.  The expert bank is ``p["moe"]["experts"]``, one
     layer's, or with ``bank_at = (experts, layer)`` the layer ``layer`` (an
     int32 scalar) of the STACKED leaves ``[L, experts, ...]``, which the
     dropless router's kernel reads where they lie (the paged step; there
     ``p["moe"]`` holds no ``experts``)."""
-    if cfg.moe_num_experts == 0:
-        return _mlp(cfg, p, h, dt), jnp.zeros((), jnp.float32), None
+    if "moe" not in p:
+        # a layer of an MoE model's dense lead says so in the trace; a dense
+        # model's MLP keeps the names it has
+        with jax.named_scope("lead_mlp") if cfg.moe_num_experts else nullcontext():
+            return _mlp(cfg, p, h, dt), jnp.zeros((), jnp.float32), None
     from deepspeed_tpu.moe import dropless
     from deepspeed_tpu.moe.sharded_moe import (moe_dispatch_combine,
                                                top1gating, top2gating)
@@ -1154,7 +1308,7 @@ def _ffn(cfg: "GPTConfig", p: Dict, h: Array, dt, rng=None,
                 if cfg.moe_scoring == "sigmoid":
                     probs, weights, experts = dropless.sigmoid_topk(
                         logits, cfg.moe_top_k, p["moe"]["gate"]["bias"],
-                        cfg.moe_norm_topk)
+                        cfg.moe_norm_topk, cfg.moe_route_scale)
                 else:
                     probs, weights, experts = dropless.softmax_topk(
                         logits, cfg.moe_top_k, cfg.moe_norm_topk)
@@ -1164,7 +1318,7 @@ def _ffn(cfg: "GPTConfig", p: Dict, h: Array, dt, rng=None,
             y = dropless.dropless_moe(
                 xt, weights, experts, N,
                 lambda rows, matmul, pick: _mlp(cfg, bank, rows, dt, matmul, pick),
-                held=cfg.moe_experts_held, layer=layer)
+                held=cfg.moe_experts_held, layer=layer, live=live)
         else:
             assert layer is None, "a stacked bank is the dropless router's"
             cf = cfg.moe_capacity_factor if train else cfg.moe_eval_capacity_factor
@@ -1193,6 +1347,8 @@ def _block_tail(cfg: "GPTConfig", p: Dict, x: Array, h: Array, o: Array, dt,
     read), ``o`` attention's output; ``bank_at`` is ``_ffn``'s.  Returns the
     block's output and ``_ffn``'s expert counts."""
     if cfg.block_type == "sequential":
+        if cfg.norm_sandwich:
+            o = rms_norm(o, p["post_attn_g"], eps=cfg.ln_eps)
         x = x + o
         z = _norm(cfg, x, p["ln2_g"], p["ln2_b"])
     else:
@@ -1200,6 +1356,8 @@ def _block_tail(cfg: "GPTConfig", p: Dict, x: Array, h: Array, o: Array, dt,
             cfg, x, p["ln2_g"], p["ln2_b"])
         x = x + o
     f, _, counts = _ffn(cfg, p, z, dt, live=live, attn_in=h, bank_at=bank_at)
+    if cfg.norm_sandwich:
+        f = rms_norm(f, p["post_mlp_g"], eps=cfg.ln_eps)
     return x + f, counts
 
 
@@ -1227,7 +1385,7 @@ def _maybe_actq(cfg: "GPTConfig", h: Array) -> Array:
                                quant_type=cfg.activation_quant_type)
 
 
-def _scan_layers(n_kinds: int, layer_fn: Callable, carry, xs):
+def _scan_layers(n_kinds: int, layer_fn: Callable, carry, xs, start: int = 0):
     """The walk that IS a loop (the serving steps always, the dense forward
     where ``layer_walk`` says "scan"): ``lax.scan`` over the stacked layers
     ``xs`` (leaves ``[L, ...]``) of a stack that repeats in a period of
@@ -1241,8 +1399,10 @@ def _scan_layers(n_kinds: int, layer_fn: Callable, carry, xs):
     the plain scan over layers.  A slice that feeds XLA's own dot is read in
     place; one that feeds a Pallas call is COPIED out first, so a caller
     keeps such a leaf out of ``xs`` and hands the kernel the stack and the
-    layer's index (``gpt_paged_step`` and the expert bank)."""
-    if n_kinds == 1:
+    layer's index (``gpt_paged_step`` and the expert bank).  ``start``
+    (whole periods) leaves the first layers to the caller, who has walked
+    them itself."""
+    if n_kinds == 1 and not start:
         return jax.lax.scan(partial(layer_fn, 0), carry, xs)
     n_layer = jax.tree.leaves(xs)[0].shape[0]
 
@@ -1255,7 +1415,8 @@ def _scan_layers(n_kinds: int, layer_fn: Callable, carry, xs):
             ys.append(y)
         return carry, jax.tree.map(lambda *a: jnp.stack(a), *ys)
 
-    carry, ys = jax.lax.scan(period, carry, jnp.arange(n_layer // n_kinds))
+    carry, ys = jax.lax.scan(period, carry,
+                             jnp.arange(start // n_kinds, n_layer // n_kinds))
     return carry, jax.tree.map(lambda a: a.reshape(-1, *a.shape[2:]), ys)
 
 
@@ -1315,6 +1476,35 @@ def _window_bias(S: int, window: int) -> Array:
     return jnp.where(far, -1e30, 0.0).astype(jnp.float32)
 
 
+def _embed(cfg: "GPTConfig", wte: Array, input_ids: Array, dt) -> Array:
+    """``scale_emb * wte[ids]``, the product in float32 where there is one
+    (``sqrt(n_embd)`` has no bf16)."""
+    x = wte.astype(dt)[input_ids]
+    if cfg.scale_emb != 1.0:
+        x = (x.astype(jnp.float32) * cfg.scale_emb).astype(dt)
+    return x
+
+
+def _row(tree, i: int):
+    """Row ``i`` (static) of every leaf of a stacked tree."""
+    return jax.tree.map(
+        lambda a: jax.lax.index_in_dim(a, i, 0, keepdims=False), tree)
+
+
+def _layer_of(cfg: "GPTConfig", blocks: Dict, l: int) -> Dict:
+    """Layer ``l`` (static) of the stacked ``blocks`` as one block's tree:
+    row ``l`` of every leaf, or behind a dense lead (``moe_dense_layers``)
+    row ``l`` of the attention leaves beside the layer's own row of its
+    feed-forward's kind."""
+    lead = cfg.moe_dense_layers
+    if not lead:
+        return _row(blocks, l)
+    attn = {k: v for k, v in blocks.items() if k not in ("lead", "moe")}
+    if l < lead:
+        return {**_row(attn, l), **_row(blocks["lead"], l)}
+    return {**_row(attn, l), "moe": _row(blocks["moe"], l - lead)}
+
+
 def gpt_block(cfg: GPTConfig, p: Dict, x: Array, rng: Optional[Array],
               train: bool, attention_fn: Callable,
               kind: Optional[LayerKind] = None) -> Tuple[Array, Array]:
@@ -1355,10 +1545,9 @@ def gpt_block(cfg: GPTConfig, p: Dict, x: Array, rng: Optional[Array],
             o = reference_attention(q, k, v, causal=True)
         else:
             o = attention_fn(q, k, v, causal=True)
-        o = o.reshape(B, S, H * cfg.v_head_dim)
-        o = o @ _wget(p, "out_w", dt)
-        if cfg.use_bias:
-            o = o + p["out_b"].astype(dt)
+        o = _attn_out(cfg, p, o.reshape(B, S, H * cfg.v_head_dim), h, dt)
+        if cfg.norm_sandwich:
+            o = rms_norm(o, p["post_attn_g"], eps=cfg.ln_eps)
         o = _dropout(o, cfg.dropout, r[0], train)
 
     with jax.named_scope("mlp"):
@@ -1367,6 +1556,8 @@ def gpt_block(cfg: GPTConfig, p: Dict, x: Array, rng: Optional[Array],
             h2 = _maybe_actq(cfg, _norm(cfg, x, p["ln2_g"], p["ln2_b"]))
             f, moe_aux, _ = _ffn(cfg, p, h2, dt, rng=r[1], train=train,
                                  attn_in=h)
+            if cfg.norm_sandwich:
+                f = rms_norm(f, p["post_mlp_g"], eps=cfg.ln_eps)
             x = x + _dropout(f, cfg.dropout, r[2], train)
         elif cfg.block_type == "parallel":
             # GPT-NeoX use_parallel_residual: x + attn(ln1 x) + mlp(ln2 x)
@@ -1415,7 +1606,7 @@ def gpt_forward(cfg: GPTConfig, params: Dict, input_ids: Array,
         # lands batch/seq-sharded directly.
         input_ids = _constrain(input_ids, mesh_lib.BATCH_AXES, "seq")
         wte = _constrain(params["wte"], "tensor", None)
-        x = wte.astype(dt)[input_ids]
+        x = _embed(cfg, wte, input_ids, dt)
         x = _constrain(x, mesh_lib.BATCH_AXES, "seq", None)
         if cfg.position_encoding == "learned":
             x = x + params["wpe"].astype(dt)[:S][None]
@@ -1429,6 +1620,10 @@ def gpt_forward(cfg: GPTConfig, params: Dict, input_ids: Array,
 
     bodies = [body_of(kind) for kind in cfg.pattern]
     walk = layer_walk(cfg)
+    assert walk == "unrolled" or not cfg.moe_dense_layers, (
+        "a stack with a dense lead (moe_dense_layers) keeps its feed-forward "
+        "leaves by kind, which the scan over layers cannot walk: no remat and "
+        "no fsdp axis over it; it is served through init_serving()")
     if cfg.remat:
         from deepspeed_tpu.runtime.activation_checkpointing.checkpointing import (
             checkpoint_policy)
@@ -1495,9 +1690,9 @@ def gpt_forward(cfg: GPTConfig, params: Dict, input_ids: Array,
                 # static slices leaf[i], for XLA's own dots a view; inside ONE
                 # jit, so that jax.grad meets one equation where it would
                 # differentiate n_layer x leaves slices one by one
-                layers = jax.jit(lambda t: [jax.tree.map(
-                    lambda a: jax.lax.index_in_dim(a, i, 0, keepdims=False), t)
-                    for i in range(cfg.n_layer)])(dict(xs, p=params["blocks"]))
+                layers = jax.jit(lambda t: [
+                    dict(_row(t["xs"], i), p=_layer_of(cfg, t["p"], i))
+                    for i in range(cfg.n_layer)])({"xs": xs, "p": params["blocks"]})
             else:
                 layers = [{"p": params["blocks"][f"h{i}"],
                            "r": jax.random.fold_in(rng, i) if use_rngs else None}
@@ -1686,9 +1881,9 @@ def gpt_apply_with_cache(cfg: GPTConfig, params: Dict, input_ids: Array,
     per S_new."""
     assert cfg.scan_layers, "KV-cache path requires scan_layers"
     _refuse_hybrid(cfg, "the dense-cache generate() path")
-    assert len(cfg.pattern) == 1, (
+    assert len(cfg.pattern) == 1 and not cfg.moe_dense_layers, (
         "the dense-cache generate() path walks identical layers; a model "
-        "with a layer pattern is served through init_serving()")
+        "with a layer pattern or a dense lead is served through init_serving()")
     assert cfg.v_head_dim == cfg.head_dim, (
         "the dense cache holds K and V of one width (latent attention in its "
         "plain form, every head's own); init_serving() caches the latent")
@@ -1698,7 +1893,7 @@ def gpt_apply_with_cache(cfg: GPTConfig, params: Dict, input_ids: Array,
     dt = cfg.dtype
     pos = cache["pos"]
 
-    x = params["wte"].astype(dt)[input_ids]
+    x = _embed(cfg, params["wte"], input_ids, dt)
     if cfg.position_encoding == "learned":
         x = x + params["wpe"].astype(dt)[jnp.clip(pos + jnp.arange(S), 0,
                                                   cfg.n_positions - 1)][None]
@@ -1736,10 +1931,7 @@ def gpt_apply_with_cache(cfg: GPTConfig, params: Dict, input_ids: Array,
         cv = jax.lax.dynamic_index_in_dim(cv_full, li, 0, keepdims=False)
         o = decode_attention(q, ck, cv, pos, bias=attn_bias).reshape(
             B, S, cfg.attn_dim)
-        o = o @ _wget(p, "out_w", dt)
-        if cfg.use_bias:
-            o = o + p["out_b"].astype(dt)
-        x, _ = _block_tail(cfg, p, x, h, o, dt)
+        x, _ = _block_tail(cfg, p, x, h, _attn_out(cfg, p, o, h, dt), dt)
         return (x, ck_full, cv_full, li + 1), None
 
     (x, new_k, new_v, _), _ = jax.lax.scan(
@@ -1865,6 +2057,9 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
     would be written and read once more a step, more device time than its
     matmuls: PERF.md § 6, PR 38.)  The router, a shared expert and every
     dense leaf stay in the scan: XLA's own dots read a slice in place.
+    Behind a dense lead (``moe_dense_layers``) the bank's stack is
+    ``[expert layers, experts, ...]`` and a layer hands the kernel its index
+    among the EXPERT layers.
 
     Rows without a request (idle decode slots, the rows past a prompt
     chunk's tokens) run through every layer like the others and are discarded by the
@@ -1887,7 +2082,7 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
     pos2d = positions[:, None] + jnp.arange(S)[None]          # [B, S]
     live = (write_blocks[0] != 0).reshape(-1) if with_expert_counts else None
 
-    x = params["wte"].astype(dt)[input_ids]
+    x = _embed(cfg, params["wte"], input_ids, dt)
     if cfg.position_encoding == "learned":
         x = x + params["wpe"].astype(dt)[
             jnp.clip(pos2d, 0, cfg.n_positions - 1)]
@@ -1908,6 +2103,12 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
         moe = dict(blocks["moe"])
         bank = moe.pop("experts")
         blocks = {**blocks, "moe": moe}
+    # behind a dense lead the feed-forward leaves are stacked by kind: the
+    # walk slices the attention leaves, a layer its own row of its kind
+    lead, by_kind = cfg.moe_dense_layers, None
+    if lead:
+        blocks = dict(blocks)
+        by_kind = {"lead": blocks.pop("lead"), "moe": blocks.pop("moe")}
     # which tiles of each group's tables its kernel fetches with one copy
     # (None: it copies page by page): the same for every layer, so worked
     # out here and not in the scan
@@ -1916,8 +2117,10 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
     tile_runs = [plan.tile_runs(tables, k_pages.shape[1])
                  for plan, tables in zip(plans, block_tables)]
 
-    def layer(j, carry, p):
-        # ``li``: the layer's index inside its group ``j`` (the period)
+    def layer(j, carry, p, at=None):
+        # ``li``: the layer's index inside its group ``j`` (the period);
+        # ``at``: its index in the stack where that is static (the layers
+        # walked before the scan), which says whether it is of the dense lead
         x, kp, vp, li = carry
         kind, wblocks = cfg.pattern[j], write_blocks[j]
         with jax.named_scope("attn"):
@@ -1957,18 +2160,34 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
                         q, (kp, vp), li, block_tables[j], positions,
                         tile_runs=tile_runs[j], chunk=chunk,
                         bias=attn_bias).reshape(B, S, cfg.attn_dim)
-            o = o @ _wget(p, "out_w", dt)
-            if cfg.use_bias:
-                o = o + p["out_b"].astype(dt)
+            o = _attn_out(cfg, p, o, h, dt)
         with jax.named_scope("mlp"):
+            dense = at is not None and at < lead
+            of_bank = (li * n_kinds + j if at is None else at) - lead
+            if dense:
+                p = {**p, **_row(by_kind["lead"], at)}
+            elif lead:
+                p = {**p, "moe": jax.tree.map(
+                    lambda a: jax.lax.dynamic_index_in_dim(
+                        a, of_bank, 0, keepdims=False), by_kind["moe"])}
             x, counts = _block_tail(
                 cfg, p, x, h, o, dt, live,
-                bank_at=None if bank is None else (bank, li * n_kinds + j))
+                bank_at=None if bank is None or dense else (bank, of_bank))
+            if dense:
+                counts = jnp.zeros((cfg.moe_num_experts,), jnp.int32)
         return (x, kp, vp, li + int(j == n_kinds - 1)), counts
 
+    # behind a dense lead, the layers up to the first whole period of expert
+    # layers are walked here at static indices; the scan takes the rest
+    carry = (x, k_pages, v_pages, jnp.zeros((), jnp.int32))
+    first, walked = -(-lead // n_kinds) * n_kinds, []
+    for l in range(first):
+        carry, c = layer(l % n_kinds, carry, _row(blocks, l), at=l)
+        walked.append(c)
     (x, k_pages, v_pages, _), counts = _scan_layers(
-        n_kinds, layer, (x, k_pages, v_pages, jnp.zeros((), jnp.int32)),
-        blocks)
+        n_kinds, layer, carry, blocks, first)
+    if walked:
+        counts = jnp.concatenate([jnp.stack(walked), counts])
     with jax.named_scope("head"):
         x = _norm(cfg, x, params["lnf_g"], params["lnf_b"])
         head = params["lm_head"] if cfg.untied_head else params["wte"]
@@ -2169,14 +2388,22 @@ class GPT:
         I = (cfg.moe_num_experts and cfg.moe_expert_hidden) or cfg.ffn_dim
         fc_out = 2 * I if cfg.mlp_type == "swiglu" else I
         mlp = E * fc_out + I * E + b * (fc_out + E)     # up (gate|up), down
+        lead = 0
         if cfg.moe_num_experts:
+            # the dense lead's MLPs, ``ffn_dim`` wide, in place of a bank each
+            If = cfg.ffn_dim * (2 if cfg.mlp_type == "swiglu" else 1)
+            lead_mlp = E * If + cfg.ffn_dim * E + b * (If + E)
             # the router (and its bias), the experts HELD (or a token's),
             # the shared expert
             mlp = (cfg.moe_num_experts * (E + int(cfg.moe_scoring == "sigmoid"))
                    + mlp * (cfg.moe_top_k if active else cfg.bank_experts[1])
                    + mlp * cfg.moe_shared_experts)
-        qk_norm = (cfg.n_head + cfg.kv_heads) * cfg.head_dim * int(cfg.qk_norm)
-        norm = (2 if cfg.norm == "layernorm" else 1) * E   # gain (and shift)
+            lead = cfg.moe_dense_layers * (lead_mlp - mlp)
+        qk_norm = ((2 if cfg.qk_norm == "head" else cfg.n_head + cfg.kv_heads)
+                   * cfg.head_dim * int(bool(cfg.qk_norm)))
+        # gain (and shift), and the two on a sublayer's output
+        norm = (2 if cfg.norm == "layernorm" else 1) * E
+        gate = E * cfg.n_head * cfg.v_head_dim * int(cfg.attn_gate)
         if cfg.kv_lora_rank:        # two low-rank chains and their norms
             Rq, R, H = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.n_head
             qkv = (E * Rq + Rq + Rq * H * cfg.head_dim
@@ -2184,9 +2411,9 @@ class GPT:
                    + R * H * (cfg.head_dim - cfg.qk_rope_dim + cfg.v_head_dim))
         else:
             qkv = E * cfg.qkv_dim + b * cfg.qkv_dim      # qkv (GQA-sized)
-        per_block = (qkv + cfg.n_head * cfg.v_head_dim * E + b * E  # attn out
-                     + mlp + qk_norm + 2 * norm)
-        total = cfg.padded_vocab * E + L * per_block + norm
+        per_block = (qkv + gate + cfg.n_head * cfg.v_head_dim * E + b * E  # attn out
+                     + mlp + qk_norm + (4 if cfg.norm_sandwich else 2) * norm)
+        total = cfg.padded_vocab * E + L * per_block + lead + norm
         if cfg.position_encoding == "learned":
             total += cfg.n_positions * E
         if cfg.untied_head:

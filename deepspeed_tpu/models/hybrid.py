@@ -452,8 +452,8 @@ def _rows_that_carry(fn, xs, chunk: int, live):
 def _gated_out(p, o, h, dt, chunk: int, live):
     """``W_o(o * sigmoid(W_g h))``: both kinds' output gate and projection."""
     return _rows_that_carry(
-        lambda o, h: (o * jax.nn.sigmoid(h @ gpt._wget(p, "gate_w", dt)))
-        @ gpt._wget(p, "out_w", dt), (o, h), chunk, live)
+        lambda o, h: gpt.out_gate(p, o, h, dt) @ gpt._wget(p, "out_w", dt),
+        (o, h), chunk, live)
 
 
 # --------------------------------------------------------------------------- #

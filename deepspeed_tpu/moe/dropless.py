@@ -46,12 +46,14 @@ def softmax_topk(logits: Array, k: int, renormalise: bool = False
 
 
 def sigmoid_topk(logits: Array, k: int, bias: Optional[Array] = None,
-                 renormalise: bool = True) -> Tuple[Array, Array, Array]:
+                 renormalise: bool = True, scale: float = 1.0
+                 ) -> Tuple[Array, Array, Array]:
     """As :func:`softmax_topk` for the router of the DeepSeek-V3 line: an
     expert's score is the SIGMOID of its own logit, in float32; the ``k``
     largest of ``score + bias`` are chosen (the score-correction bias
     chooses and never weighs) and weighed by their scores, with
-    ``renormalise`` divided by their sum (``norm_topk_prob``).  The first
+    ``renormalise`` divided by their sum (``norm_topk_prob``), then times
+    ``scale`` (``routed_scaling_factor``, ``route_scale``).  The first
     result is the scores over their sum, for the load-balance loss."""
     scores = jax.nn.sigmoid(logits.astype(jnp.float32))
     chosen = scores if bias is None else scores + bias.astype(jnp.float32)
@@ -59,6 +61,8 @@ def sigmoid_topk(logits: Array, k: int, bias: Optional[Array] = None,
     weights = jnp.take_along_axis(scores, experts, axis=-1)
     if renormalise:
         weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    if scale != 1.0:
+        weights = weights * scale
     return (scores / jnp.sum(scores, axis=-1, keepdims=True), weights,
             experts.astype(jnp.int32))
 
@@ -118,7 +122,8 @@ def expert_counts(experts: Array, num_experts: int,
 def dropless_moe(x: Array, weights: Array, experts: Array, num_experts: int,
                  expert_fn: Callable,
                  held: Optional[Tuple[int, int]] = None,
-                 layer: Optional[Array] = None) -> Array:
+                 layer: Optional[Array] = None,
+                 live: Optional[Array] = None) -> Array:
     """``x [T, M]`` through its ``k`` experts each, weighted and summed, in
     float32.
 
@@ -127,7 +132,13 @@ def dropless_moe(x: Array, weights: Array, experts: Array, num_experts: int,
     assignment to an expert that is not here sorts behind the last group and
     lies in none, as the rows added for whole tiles do, so the bank computes
     nothing for it, and it adds nothing to the sum: the result is this
-    bank's PART of the layer.
+    bank's PART of the layer.  ``live [T]``: the rows that carry a request
+    (a serve step's idle slots and idle chunk rows do not); the others'
+    assignments lie in no group either and their result is zero.  Nobody
+    reads an idle row, but idle rows hold one token at one position and so
+    choose the SAME experts: left in, each of those experts multiplied
+    hundreds of rows for nobody, and which of them a held share holds is
+    the seed's (PERF.md § 6, PR 55).
 
     ``expert_fn(rows, matmul, pick)`` is the expert's own arithmetic on the
     sorted rows ``[T*k, M]``: ``matmul(rows, w)`` multiplies each row by ITS
@@ -143,10 +154,15 @@ def dropless_moe(x: Array, weights: Array, experts: Array, num_experts: int,
     # row tiles hold them (0 where they already do, or no kernel runs): they
     # lie in no group, so the bank computes nothing for them, and are cut off
     pad = rows_to_whole_tiles(T * k, x.shape[1], x.dtype)
+    here = None             # [T, k]: the assignments this bank computes (None: all)
     if held is not None:
         first, num_experts = held
         here = (experts >= first) & (experts < first + num_experts)
-        experts = jnp.where(here, experts - first, num_experts)
+        experts = experts - first
+    if live is not None:
+        here = live[:, None] if here is None else here & live[:, None]
+    if here is not None:
+        experts = jnp.where(here, experts, num_experts)
     with jax.named_scope("moe_dispatch"):
         flat = experts.reshape(-1)
         order = jnp.argsort(flat, stable=True)        # assignments by expert
@@ -164,7 +180,7 @@ def dropless_moe(x: Array, weights: Array, experts: Array, num_experts: int,
         # back to token order by a gather (no scatter-add), then the
         # weighted sum over the k choices
         y = y[jnp.argsort(order)].reshape(T, k, -1)
-        if held is not None:
+        if here is not None:
             # a row in no group is whatever the kernel's output buffer held
             y = jnp.where(here[..., None], y, 0)
         return jnp.einsum("tkm,tk->tm", y.astype(jnp.float32), weights)

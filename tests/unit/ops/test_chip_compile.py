@@ -895,3 +895,42 @@ def test_the_keye_vl2_step_selects_tokens_and_reads_its_bank_in_place(chip):
     assert sorts and all("index_topk/top_k" in l and "branch_1_fun" not in l for l in sorts)
     # a layer's pages are never copied out: [1025, 64, 512] K or V, [1025, 64, 64] index keys
     assert not re.search(r"bf16\[1025,64,(512|64)\]\S* (dynamic-slice|copy)\(", text)
+
+
+def test_the_trinity_step_reads_its_bank_behind_a_dense_lead(chip):
+    """The whole step of Trinity-Large-Preview's cell at ONE period, the
+    dense lead and three expert layers at the published widths with 16 of
+    256 experts held, 32 slots and a chunk of 512, the full group's table
+    2,400 columns wide: ``paged_gqa_attention`` at a group of SIX query heads
+    runs the decode rows and the packed chunk in every layer, the bank's
+    stack ``[3, 16, K, N]`` (the EXPERT layers alone) goes to
+    ``grouped_matmul`` whole, twice an expert layer, and the program makes
+    no array of one layer's bank."""
+    from deepspeed_tpu.models import gpt
+    from deepspeed_tpu.serving.kv_cache import init_arena, window_table_blocks
+    slots, chunk, BS, blocks = 32, 512, 16, 1025
+    cfg = gpt.trinity_config(n_layer=4, dense_layers=1, vocab_size=25024,
+                             vocab_multiple=64, experts_held=(0, 16), dtype=BF16)
+    model, rows = gpt.GPT(cfg), slots + chunk
+    shape = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=chip)
+    on_chip = lambda tree: jax.tree.map(lambda a: shape(a.shape, a.dtype), tree)
+    params = jax.tree.map(lambda p: shape(p.shape, BF16),
+                          jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
+    kp, vp = on_chip(jax.eval_shape(lambda: init_arena(cfg, blocks, BS, dtype=BF16)))
+    widths = [2400 if kind.window is None
+              else window_table_blocks(kind.window, chunk, BS) for kind in cfg.pattern]
+    assert widths == [289, 289, 289, 2400]
+    tables = tuple(shape((rows, w), jnp.int32) for w in widths)
+    coords = tuple(shape((rows, 1), jnp.int32) for _ in widths)
+    step = lambda *a: model.paged_step(*a, chunk=chunk, with_expert_counts=True)
+    text = jax.jit(step).lower(
+        params, shape((rows, 1), jnp.int32), shape((rows,), jnp.int32), kp, vp,
+        tables, coords, shape((rows, 1), jnp.int32)).compile().as_text()
+    Sq = da.paged_chunk_queries(chunk, 6, 8, 128, 128, 128, BF16)
+    assert Sq > 1 and _kernel_rows(text, "paged_gqa_attention") == sorted(
+        [slots, chunk // Sq] * 4)
+    calls = _bank_calls(text)
+    assert len(calls) == 2 * 3
+    for K, N in ((3072, 6144), (3072, 3072)):
+        assert sum(f"bf16[3,16,{K},{N}]" in call for call in calls) == 3
+        assert not _bank_copies(text, 16, K, N)

@@ -3,8 +3,9 @@ stacked expert bank ``[L, experts, ...]`` handed to ``grouped_matmul`` whole
 with the layer's index, against the SAME step with each layer's bank sliced
 out of the stack by hand and handed over as one layer's: the two read the
 same numbers, so logits, arena and expert counts are equal bit for bit.
-Shared by ``test_olmoe.py``, ``test_smallthinker.py`` and ``test_mistral4.py``
-(a period of one layer, of four, and one with ``held`` experts)."""
+Shared by ``test_olmoe.py``, ``test_smallthinker.py``, ``test_mistral4.py``
+and ``test_trinity.py`` (a period of one layer, of four, one with ``held``
+experts, and one behind a dense lead)."""
 
 import functools
 
@@ -45,7 +46,13 @@ def bank_in_place_equals_bank_sliced(cfg, params, path, kernels, monkeypatch,
         (w.shape, False)) or real_ragged(a, w, s))
     in_place = step()
     stack = params["blocks"]["moe"]["experts"]["wi"].shape
-    assert len(calls) == 2 * P and all(kernel == (path == "kernel") for _, kernel in calls)
+    # the bank's layers as traced: a period in the scan's body, and behind a
+    # dense lead the expert layers walked before the scan, each apart
+    lead = cfg.moe_dense_layers
+    walked = -(-lead // P) * P
+    traced = (walked - lead) + (P if walked < cfg.n_layer else 0)
+    assert len(calls) == 2 * traced and all(
+        kernel == (path == "kernel") for _, kernel in calls)
     # the kernel is handed the stack of ALL layers; ragged_dot the layer,
     # indexed inside grouped_matmul
     assert {len(shape) for shape, _ in calls} == {4 if path == "kernel" else 3}
@@ -58,10 +65,10 @@ def bank_in_place_equals_bank_sliced(cfg, params, path, kernels, monkeypatch,
     del calls[:]
     sliced = step()
     assert [shape[0] for shape, kernel in calls if kernel] == [1] * (
-        2 * P if path == "kernel" else 0)
+        2 * traced if path == "kernel" else 0)
     for got, want in zip(in_place, sliced):
         if want is not None:
             np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     logits, kp, _, counts = in_place
     assert float(jnp.abs(kp).max()) > 0 and np.isfinite(np.asarray(logits)).all()
-    assert int(counts.sum()) == int(live.sum()) * cfg.moe_top_k * cfg.n_layer
+    assert int(counts.sum()) == int(live.sum()) * cfg.moe_top_k * (cfg.n_layer - lead)

@@ -254,7 +254,8 @@ def test_a_slot_rebound_to_a_shorter_sequence_scores_no_former_tenant(loud):
 def test_the_softmax_router_routes_as_the_periodic_path_does():
     """OLMoE's tiny preset through both: ``gpt.py:_ffn`` over a layer's
     leaves, and the walk's ``moe_softmax`` feed-forward over the same leaves
-    stacked: the same output, the same expert counts; and renormalised."""
+    stacked: the same output for the live rows, the same expert counts; and
+    renormalised."""
     kw = dict(vocab_size=256, n_positions=64, n_embd=32, n_head=4, n_layer=2,
               intermediate_size=16, num_experts=8, top_k=2, dtype=jnp.float32)
     rng = np.random.default_rng(0)
@@ -272,7 +273,10 @@ def test_the_softmax_router_routes_as_the_periodic_path_does():
         got, stream, got_counts = hybrid.FEED_FORWARDS["moe_softmax"](
             cfg, leaves, bank, jnp.int32(1), x, None, step)
         assert stream is None
-        assert np.abs(np.asarray(got) - np.asarray(want)).max() < 1e-6
+        # the rows that carry a request: the periodic path's bank computes
+        # nothing for the idle one (``dropless_moe(live=)``), the walk's does
+        gap = np.abs(np.asarray(got) - np.asarray(want))
+        assert gap[:5].max() < 1e-6 and float(np.abs(np.asarray(want))[5].max()) == 0.0
         assert np.array_equal(np.asarray(got_counts), np.asarray(counts))
         assert int(np.asarray(counts).sum()) == 5 * 2
 

@@ -4,7 +4,9 @@ reference, at the published widths: the chip comparison of the
 ``serve`` block and a ``reference`` (``benchmarks/configs/olmoe-1b-7b.json``,
 ``smallthinker-21b-a3b.json``, ``mistral-small-4-119b.json``,
 ``minicpm-sala-9b.json``, ``zaya1-8b.json``, ``olmo-hybrid-7b.json``,
-``keye-vl-2.0-30b-a3b.json``).
+``keye-vl-2.0-30b-a3b.json``, ``trinity-large-preview.json``: a stack whose
+``n_layer`` is whole periods, so its float32 run is ``"n_layer": 4``, the dense
+lead and three expert layers).
 
 Run standalone on a TPU host (``chiprun --chips 1 -- python
 tools/serve_parity.py benchmarks/configs/smallthinker-21b-a3b.json``); any
