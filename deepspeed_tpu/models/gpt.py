@@ -36,6 +36,7 @@ TPU-first design decisions:
 
 import dataclasses
 import math
+from collections.abc import Mapping
 from contextlib import nullcontext
 from functools import partial
 from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
@@ -1385,39 +1386,107 @@ def _maybe_actq(cfg: "GPTConfig", h: Array) -> Array:
                                quant_type=cfg.activation_quant_type)
 
 
-def _scan_layers(n_kinds: int, layer_fn: Callable, carry, xs, start: int = 0):
-    """The walk that IS a loop (the serving steps always, the dense forward
-    where ``layer_walk`` says "scan"): ``lax.scan`` over the stacked layers
-    ``xs`` (leaves ``[L, ...]``) of a stack that repeats in a period of
-    ``n_kinds`` layers:
-    ``layer_fn(j, carry, x) -> (carry, y)`` runs one layer of the period's
-    ``j``-th kind (static).  The leaves are read as ``[periods, period,
-    ...]``: the scan runs over periods and its body walks the period, taking
-    layer ``period * n_kinds + j`` out of each leaf itself (one dynamic
-    slice of the whole stack a layer, as a scan over layers takes it: a
-    slice of a slice is a copy of the period's weights).  A period of one is
-    the plain scan over layers.  A slice that feeds XLA's own dot is read in
-    place; one that feeds a Pallas call is COPIED out first, so a caller
-    keeps such a leaf out of ``xs`` and hands the kernel the stack and the
-    layer's index (``gpt_paged_step`` and the expert bank).  ``start``
-    (whole periods) leaves the first layers to the caller, who has walked
-    them itself."""
-    if n_kinds == 1 and not start:
-        return jax.lax.scan(partial(layer_fn, 0), carry, xs)
-    n_layer = jax.tree.leaves(xs)[0].shape[0]
-
+def _walk_layers(n_kinds: int, layer_fn: Callable, carry, n_layer: int,
+                 start: int = 0):
+    """``lax.scan`` over the periods of a stack of ``n_layer`` layers that
+    repeats in a period of ``n_kinds``: ``layer_fn(j, carry, l) -> (carry,
+    y)`` runs layer ``l`` (an int32 scalar, its index in the stack), which is
+    of the period's ``j``-th kind (static), and takes what it reads of layer
+    ``l`` out of the stack ITSELF.  ``start`` (whole periods) leaves the
+    first layers to the caller, who has walked them itself."""
     def period(carry, i):
         ys = []
         for j in range(n_kinds):
-            x = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
-                a, i * n_kinds + j, 0, keepdims=False), xs)
-            carry, y = layer_fn(j, carry, x)
+            carry, y = layer_fn(j, carry, i * n_kinds + j)
             ys.append(y)
         return carry, jax.tree.map(lambda *a: jnp.stack(a), *ys)
 
     carry, ys = jax.lax.scan(period, carry,
                              jnp.arange(start // n_kinds, n_layer // n_kinds))
     return carry, jax.tree.map(lambda a: a.reshape(-1, *a.shape[2:]), ys)
+
+
+def _scan_layers(n_kinds: int, layer_fn: Callable, carry, xs):
+    """The dense forward's walk where ``layer_walk`` says "scan":
+    ``lax.scan`` over the stacked layers ``xs`` (leaves ``[L, ...]``) of a
+    stack that repeats in a period of ``n_kinds`` layers:
+    ``layer_fn(j, carry, x) -> (carry, y)`` runs one layer of the period's
+    ``j``-th kind (static) on its slice ``x`` of every leaf.  A period of one
+    is the plain scan over layers; a longer one is :func:`_walk_layers`
+    taking layer ``period * n_kinds + j`` out of each leaf (one dynamic
+    slice of the whole stack a layer, as a scan over layers takes it: a
+    slice of a slice is a copy of the period's weights).  A slice that feeds
+    XLA's own dot is read in place.  One that feeds a Pallas call, or that
+    is made OUTSIDE a ``lax.cond`` whose branch reads it, is COPIED out
+    first: the serving step hands its layers the stack and an index instead
+    (``gpt_paged_step``: :func:`_walk_layers`, :class:`_LayerLeaves`)."""
+    if n_kinds == 1:
+        return jax.lax.scan(partial(layer_fn, 0), carry, xs)
+    return _walk_layers(
+        n_kinds,
+        lambda j, carry, l: layer_fn(j, carry, jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, l, 0, keepdims=False), xs)),
+        carry, jax.tree.leaves(xs)[0].shape[0])
+
+
+class _LayerLeaves(Mapping):
+    """Layer ``i``'s leaves of a stack (leaves ``[L, ...]``), read like the
+    layer's own tree (``p["qkv_w"]``, ``p["moe"]["gate"]["wg"]``, ``"moe" in
+    p``), each taken out of the stack WHERE IT IS READ: a slice made once
+    outside a ``lax.cond`` would be the branch's operand, and XLA copies an
+    operand out (a hybrid layer's 570 MB a layer a step, PERF.md § 6, PR 39;
+    1.79 GB a step of Trinity's), where a slice made inside the branch feeds
+    its dot in place.  ``beside(stack, i)`` adds the leaves of another stack
+    at an index of its own (behind a dense lead a layer's feed-forward lies
+    in its kind's stack); a name is looked up in the stacks in order."""
+
+    def __init__(self, stack: Dict, i, *more):
+        self.parts = ((stack, i),) + more
+
+    def beside(self, stack: Dict, i) -> "_LayerLeaves":
+        return _LayerLeaves(*self.parts[0], *self.parts[1:], (stack, i))
+
+    def __iter__(self):
+        return iter(dict.fromkeys(k for stack, _ in self.parts for k in stack))
+
+    def __len__(self) -> int:
+        return len(tuple(self))
+
+    def __getitem__(self, name: str):
+        from deepspeed_tpu.module_inject.quantization import is_quantized_leaf
+        for stack, i in self.parts:
+            if name not in stack:
+                continue
+            if isinstance(stack[name], dict) and not is_quantized_leaf(stack[name]):
+                return _LayerLeaves(stack[name], i)
+            return jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
+                a, i, 0, keepdims=False), stack[name])
+        raise KeyError(name)
+
+
+def _rows_that_carry(fn, xs, chunk: int, live, totals: int = 0):
+    """``fn(*xs)``, a function of each row alone (a projection, the MLP, the
+    head; an array or a tuple of them): over all rows in a step with a prompt
+    chunk, over the decode rows alone (zeros behind them) in a step without
+    one, where the chunk's rows carry nothing and nobody reads what they
+    give.  ``xs`` are by row (``live [rows]`` among them where ``fn`` reads
+    it); the last ``totals`` of a tuple's results are sums over the live rows
+    (an expert's count) and come back as they are.  Most steps of a long run
+    carry no chunk, and the chunk's rows (512 of 544) would go through every
+    matrix for nobody.  What ``fn`` reads of a layer it takes out of the
+    stack itself (:class:`_LayerLeaves`)."""
+    if not chunk:
+        return fn(*xs)
+    n_dec = xs[0].shape[0] - chunk
+    behind = lambda y: jnp.pad(y, ((0, chunk),) + ((0, 0),) * (y.ndim - 1))
+
+    def decode_rows_alone():
+        ys = fn(*(x[:n_dec] for x in xs))
+        if not totals:
+            return jax.tree.map(behind, ys)
+        return (*jax.tree.map(behind, ys[:-totals]), *ys[-totals:])
+
+    return jax.lax.cond(live[n_dec], lambda: fn(*xs), decode_rows_alone)
 
 
 def layer_walk(cfg: "GPTConfig") -> str:
@@ -2048,24 +2117,34 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
     expert ``[experts]`` int32, summed over layers, of the rows that carry a
     request (those whose K/V does not go to the trash block).
 
+    The walk (:func:`_walk_layers`) hands a layer the STACK and the layer's
+    index, and every leaf is sliced where it is read (:class:`_LayerLeaves`).
     Under the dropless router the expert bank (``params["blocks"]["moe"]
-    ["experts"]``, leaves ``[L, experts, ...]``) is NOT among the leaves the
-    layer scan slices: the step closes over it, a layer hands
-    ``grouped_matmul`` the stack and its own index, and the kernel reads the
-    layer's tiles where they lie.  (A slice handed to a Pallas call is
-    copied out first: sliced like the other leaves, every layer's bank
-    would be written and read once more a step, more device time than its
-    matmuls: PERF.md § 6, PR 38.)  The router, a shared expert and every
-    dense leaf stay in the scan: XLA's own dots read a slice in place.
-    Behind a dense lead (``moe_dense_layers``) the bank's stack is
-    ``[expert layers, experts, ...]`` and a layer hands the kernel its index
-    among the EXPERT layers.
+    ["experts"]``, leaves ``[L, experts, ...]``) is not sliced at all: a
+    layer hands ``grouped_matmul`` the stack and its own index, and the
+    kernel reads the layer's tiles where they lie.  (A slice handed to a
+    Pallas call is copied out first: every layer's bank would be written
+    and read once more a step, more device time than its matmuls: PERF.md
+    § 6, PR 38.)  Behind a dense lead (``moe_dense_layers``) the bank's
+    stack is ``[expert layers, experts, ...]`` and a layer hands the kernel
+    its index among the EXPERT layers.
 
     Rows without a request (idle decode slots, the rows past a prompt
-    chunk's tokens) run through every layer like the others and are discarded by the
-    caller.  Under the dropless router they displace nothing; a router with
-    a capacity would let them push live tokens out, so ``init_serving``
-    refuses it.
+    chunk's tokens) are discarded by the caller.  In a step WITH a chunk
+    they run through every layer like the others (the bank alone computes
+    nothing for them: ``dropless_moe(live=)``).  In a step WITHOUT one, which
+    the step reads off its own input (the chunk's first row writes to the
+    trash block), the chunk's rows go through nothing that is a function of
+    a row alone (:func:`_rows_that_carry`: the norms, the projections, the
+    gate, the MLP or the router and its shared expert, the head: two
+    ``lax.cond`` a layer, before the scatter and after attention, and one
+    round the head; each region a jitted function of the stacks and the
+    layer's index, one trace for all layers of a kind); they come back as
+    zeros, their K/V goes to the trash block and their logits are zeros.  The scatter and attention stay
+    outside: the arena is no branch's operand.  Under the dropless router
+    idle rows displace nothing; a router with a capacity would let them
+    push live tokens out (and would count its capacity from the rows it is
+    given), so ``init_serving`` refuses it.
     """
     assert cfg.scan_layers, "paged serving path requires scan_layers"
     B, S = input_ids.shape
@@ -2080,7 +2159,10 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
     T = MB * BS
     dt = cfg.dtype
     pos2d = positions[:, None] + jnp.arange(S)[None]          # [B, S]
-    live = (write_blocks[0] != 0).reshape(-1) if with_expert_counts else None
+    # the rows that carry a request; ``live[B - chunk]``, the chunk's first
+    # row, says whether the step carries a chunk at all
+    live = (write_blocks[0] != 0).reshape(-1)
+    by_row = partial(_rows_that_carry, chunk=chunk, live=live)
 
     x = _embed(cfg, params["wte"], input_ids, dt)
     if cfg.position_encoding == "learned":
@@ -2103,12 +2185,13 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
         moe = dict(blocks["moe"])
         bank = moe.pop("experts")
         blocks = {**blocks, "moe": moe}
-    # behind a dense lead the feed-forward leaves are stacked by kind: the
-    # walk slices the attention leaves, a layer its own row of its kind
+    # behind a dense lead the feed-forward leaves are stacked by kind: a
+    # layer reads the attention leaves at its index, its feed-forward's at
+    # its index among the layers of its kind
     lead, by_kind = cfg.moe_dense_layers, None
     if lead:
         blocks = dict(blocks)
-        by_kind = {"lead": blocks.pop("lead"), "moe": blocks.pop("moe")}
+        by_kind = {"lead": blocks.pop("lead"), "moe": {"moe": blocks.pop("moe")}}
     # which tiles of each group's tables its kernel fetches with one copy
     # (None: it copies page by page): the same for every layer, so worked
     # out here and not in the scan
@@ -2116,65 +2199,84 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
                             k_pages.dtype)
     tile_runs = [plan.tile_runs(tables, k_pages.shape[1])
                  for plan, tables in zip(plans, block_tables)]
+    W, pages_dt, dr = k_pages.shape[-1], k_pages.dtype, cfg.qk_rope_dim
+    lanes = lambda t, width: jnp.pad(
+        t, ((0, 0),) * (t.ndim - 1) + ((0, width - t.shape[-1]),))
 
-    def layer(j, carry, p, at=None):
-        # ``li``: the layer's index inside its group ``j`` (the period);
-        # ``at``: its index in the stack where that is static (the layers
-        # walked before the scan), which says whether it is of the dense lead
+    # What is a function of a row alone runs in TWO regions a layer, before
+    # the scatter and after attention, over the rows that carry (``by_row``);
+    # the arena stays out of both.  Each region is a jitted function of the
+    # STACKS and the layer's index, so that the layers of one kind share ONE
+    # trace and one lowering of each of its two bodies (all rows; the decode
+    # rows alone): traced a layer, the second bodies added 3.6 s to the start
+    # of an engine of eight layers (PERF.md § 6, PR 56)
+    @partial(jax.jit, static_argnums=0)
+    def project(j, blocks, l, x, pos):
+        """-> the normed input, the queries as attention takes them and what
+        the arena caches of each token (K and V, or the latent)."""
+        p = _LayerLeaves(blocks, l)
+        h = _norm(cfg, x, p["ln1_g"], p["ln1_b"])
+        if not cfg.kv_lora_rank:
+            q, k, v = _project_qkv(cfg, p, h, dt, pos, cfg.pattern[j])
+            return (h, q, k.astype(pages_dt).reshape(*k.shape[:2], -1),
+                    v.astype(pages_dt).reshape(*v.shape[:2], -1))
+        q, cache = _latent_project(cfg, p, h, dt, pos)
+        # a head's query in the cached vector's lanes: its no-position part
+        # through W_UK, its rotated part as it is
+        q = jnp.concatenate(
+            [jnp.einsum("bshd,rhd->bshr", q[..., :-dr],
+                        _latent_up(cfg, p, dt)[0]), q[..., -dr:]], axis=-1)
+        return h, lanes(q, W), lanes(cache.astype(pages_dt), W), None
+
+    @partial(jax.jit, static_argnums=0)
+    def tail(dense, stacks, l, x, h, o, live):
+        """-> the block's output and its expert counts, from attention's
+        output; ``dense``: a layer of the dense lead."""
+        blocks, by_kind, bank = stacks
+        p = _LayerLeaves(blocks, l)
+        if dense:
+            p = p.beside(by_kind["lead"], l)
+        elif lead:
+            p = p.beside(by_kind["moe"], l - lead)
+        with jax.named_scope("attn"):
+            if cfg.kv_lora_rank:
+                o = jnp.einsum("bshr,rhd->bshd", o, _latent_up(cfg, p, dt)[1])
+            o = _attn_out(cfg, p, o.reshape(*o.shape[:2], -1), h, dt)
+        with jax.named_scope("mlp"):
+            return _block_tail(
+                cfg, p, x, h, o, dt, live,
+                bank_at=None if bank is None or dense else (bank, l - lead))
+
+    def layer(j, carry, l):
+        # ``l``: the layer's index in the stack, a Python int for the layers
+        # walked before the scan, which says whether it is of the dense lead;
+        # ``li``: its index inside its group ``j`` (the period)
         x, kp, vp, li = carry
         kind, wblocks = cfg.pattern[j], write_blocks[j]
+        dense = isinstance(l, int) and l < lead
+        at = jnp.asarray(l, jnp.int32)
         with jax.named_scope("attn"):
-            h = _norm(cfg, x, p["ln1_g"], p["ln1_b"])
+            h, q, k, v = by_row(partial(project, j, blocks, at), (x, pos2d))
+            # scatter the new K/V into the arena through the write map; rows
+            # that must not write (padding, inactive slots) carry trash-block
+            # coordinates, so the scatter itself needs no predication
+            kp = kp.at[li, wblocks, write_offsets].set(k)
             if cfg.kv_lora_rank:
-                W = kp.shape[-1]
-                q, cache = _latent_project(cfg, p, h, dt, pos2d)
-                w_uk, w_uv = _latent_up(cfg, p, dt)
-                kp = kp.at[li, wblocks, write_offsets].set(jnp.pad(
-                    cache.astype(kp.dtype),
-                    ((0, 0), (0, 0), (0, W - cache.shape[-1]))))
-                # a head's query in the cached vector's lanes: its
-                # no-position part through W_UK, its rotated part as it is
-                dr = cfg.qk_rope_dim
-                q = jnp.concatenate(
-                    [jnp.einsum("bshd,rhd->bshr", q[..., :-dr], w_uk),
-                     q[..., -dr:]], axis=-1)
-                q = jnp.pad(q, ((0, 0),) * 3 + ((0, W - q.shape[-1]),))
                 with jax.named_scope("attn_latent"):
                     o = plans[j].attend(
                         q, (kp, None), li, block_tables[j], positions,
                         tile_runs=tile_runs[j], chunk=chunk)
-                o = jnp.einsum("bshr,rhd->bshd", o, w_uv).reshape(B, S, -1)
             else:
-                q, k, v = _project_qkv(cfg, p, h, dt, pos2d, kind)
-                # scatter the new K/V into the arena through the write map;
-                # rows that must not write (padding, inactive slots) carry
-                # trash-block coordinates, so the scatter itself needs no
-                # predication
-                kp = kp.at[li, wblocks, write_offsets].set(
-                    k.astype(kp.dtype).reshape(B, S, -1))
-                vp = vp.at[li, wblocks, write_offsets].set(
-                    v.astype(vp.dtype).reshape(B, S, -1))
+                vp = vp.at[li, wblocks, write_offsets].set(v)
                 with jax.named_scope(
                         "attn_full" if kind.window is None else "attn_window"):
                     o = plans[j].attend(
                         q, (kp, vp), li, block_tables[j], positions,
-                        tile_runs=tile_runs[j], chunk=chunk,
-                        bias=attn_bias).reshape(B, S, cfg.attn_dim)
-            o = _attn_out(cfg, p, o, h, dt)
-        with jax.named_scope("mlp"):
-            dense = at is not None and at < lead
-            of_bank = (li * n_kinds + j if at is None else at) - lead
-            if dense:
-                p = {**p, **_row(by_kind["lead"], at)}
-            elif lead:
-                p = {**p, "moe": jax.tree.map(
-                    lambda a: jax.lax.dynamic_index_in_dim(
-                        a, of_bank, 0, keepdims=False), by_kind["moe"])}
-            x, counts = _block_tail(
-                cfg, p, x, h, o, dt, live,
-                bank_at=None if bank is None or dense else (bank, of_bank))
-            if dense:
-                counts = jnp.zeros((cfg.moe_num_experts,), jnp.int32)
+                        tile_runs=tile_runs[j], chunk=chunk, bias=attn_bias)
+        x, counts = by_row(partial(tail, dense, (blocks, by_kind, bank), at),
+                           (x, h, o, live), totals=1)
+        if dense:
+            counts = jnp.zeros((cfg.moe_num_experts,), jnp.int32)
         return (x, kp, vp, li + int(j == n_kinds - 1)), counts
 
     # behind a dense lead, the layers up to the first whole period of expert
@@ -2182,18 +2284,23 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
     carry = (x, k_pages, v_pages, jnp.zeros((), jnp.int32))
     first, walked = -(-lead // n_kinds) * n_kinds, []
     for l in range(first):
-        carry, c = layer(l % n_kinds, carry, _row(blocks, l), at=l)
+        carry, c = layer(l % n_kinds, carry, l)
         walked.append(c)
-    (x, k_pages, v_pages, _), counts = _scan_layers(
-        n_kinds, layer, carry, blocks, first)
+    (x, k_pages, v_pages, _), counts = _walk_layers(
+        n_kinds, layer, carry, cfg.n_layer, first)
     if walked:
         counts = jnp.concatenate([jnp.stack(walked), counts])
-    with jax.named_scope("head"):
+
+    def to_logits(x):
         x = _norm(cfg, x, params["lnf_g"], params["lnf_b"])
         head = params["lm_head"] if cfg.untied_head else params["wte"]
         logits = (x @ head.astype(dt).T).astype(jnp.float32)
         if cfg.head_bias:
             logits = logits + params["lm_head_b"].astype(jnp.float32)
+        return logits
+
+    with jax.named_scope("head"):
+        logits = by_row(to_logits, (x,))
     if with_expert_counts:
         return logits, k_pages, v_pages, counts.sum(axis=0)
     return logits, k_pages, v_pages

@@ -86,6 +86,7 @@ import numpy as np
 from jax.sharding import PartitionSpec
 
 from deepspeed_tpu.models import gpt
+from deepspeed_tpu.models.gpt import _LayerLeaves, _rows_that_carry
 
 Array = jax.Array
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -413,40 +414,6 @@ class _Step(NamedTuple):
     dt: Any
     plan: Any
     tile_runs: Any = None
-
-
-class _LayerLeaves:
-    """Layer ``i``'s leaves of a kind's stack, each taken out of the stack
-    WHERE IT IS READ: a slice made once outside a ``lax.cond`` would be the
-    branch's operand, and XLA copies an operand out (a layer's 570 MB a
-    layer a step), where a slice made inside the branch feeds its dot in
-    place."""
-
-    def __init__(self, stack: Dict, i):
-        self.stack, self.i = stack, i
-
-    def __getitem__(self, name: str) -> Array:
-        return jax.lax.dynamic_index_in_dim(self.stack[name], self.i, 0,
-                                            keepdims=False)
-
-
-def _rows_that_carry(fn, xs, chunk: int, live):
-    """``fn(*xs)``, a function of each row alone (a projection, the MLP, the
-    head; an array or a tuple of them): over all rows in a step with a prompt chunk, over the decode rows
-    alone (zeros behind them) in a step without one, where the chunk's rows
-    carry nothing and nobody reads what they give.  Two of three steps of a
-    long run carry no chunk, and 512 of their 528 rows would go through every
-    matrix for nobody."""
-    if not chunk:
-        return fn(*xs)
-    n_dec = xs[0].shape[0] - chunk
-
-    def decode_rows_alone():
-        return jax.tree.map(
-            lambda y: jnp.pad(y, ((0, chunk),) + ((0, 0),) * (y.ndim - 1)),
-            fn(*(x[:n_dec] for x in xs)))
-
-    return jax.lax.cond(live[n_dec], lambda: fn(*xs), decode_rows_alone)
 
 
 def _gated_out(p, o, h, dt, chunk: int, live):

@@ -111,7 +111,10 @@ from deepspeed_tpu.utils.logging import log_dist
 #: ``rid``, and its chunks' programs on ``rid`` and ``program``.
 #: ``serve.stats`` carries what the step's tables and attention cost
 #: (``table_edits``, ``table_reloads``, ``upload_bytes``, ``tile_runs_pct``,
-#: ``chunk_queries_per_row``, ``attention_rows``), ``dispatched_ahead`` (1:
+#: ``chunk_queries_per_row``, ``attention_rows``), ``dense_rows`` (the rows
+#: the program took through the model's dense matrices: every slot's, and the
+#: chunk's only where the step carries one; ``_rows_that_carry``),
+#: ``dispatched_ahead`` (1:
 #: this step's program was launched before the row of the program before it
 #: was on the host), ``program`` (the one this step launched; absent where it
 #: launched none) and, in a step that follows a step with a program, the
@@ -1242,14 +1245,17 @@ class ServingEngine:
             if self.registry is not None:
                 self._h_turnaround.observe(turnaround["turnaround_ms"])
         # how attention took the step: the queries a row of the chunk held
-        # (0: no chunk in the step) and the rows its calls ran; whether the
-        # step was dispatched ahead, and its own turn-round, where there was
-        # one; the program it launched
+        # (0: no chunk in the step) and the rows its calls ran; the rows that
+        # went through the dense matrices (the chunk's only with a chunk);
+        # whether the step was dispatched ahead, and its own turn-round, where
+        # there was one; the program it launched
         self.steps_dispatched_ahead += ahead
         on_span = dict(
             table_stats, tile_runs_pct=self._tile_runs_pct(),
             chunk_queries_per_row=self.chunk_queries_per_row if n_chunk else 0,
             attention_rows=self.attention_rows if runs else 0,
+            dense_rows=(self._layout.rows if n_chunk
+                        else self._layout.slots) if runs else 0,
             dispatched_ahead=ahead, **launched_stats, **turnaround)
         with self._span("serve.stats", **on_span):
             stats = self._close_step(len(decode), n_chunk, int(runs),

@@ -700,8 +700,11 @@ def test_the_step_program_reads_the_bank_in_place(chip, cell):
     ``grouped_matmul`` the stacked bank ``[L, experts, K, N]`` itself, twice
     a layer kind, and makes NO array of one layer's bank (a bank sliced by
     the scan like any leaf is copied out for the Pallas call, more device
-    time than its matmuls: PERF.md § 6, PR 38).  GPT-2 has no bank: no
-    ``grouped_matmul``, and the same attention rows as at one period."""
+    time than its matmuls: PERF.md § 6, PR 38).  The bank stands in BOTH
+    branches of the block's tail (``_rows_that_carry``: all rows, or the
+    decode rows alone in a step without a chunk), attention in neither.
+    GPT-2 has no bank: no ``grouped_matmul``, and the same attention rows as
+    at one period."""
     _, slots, chunk, _, kernel, Sq = SERVE_CELLS[cell]
     cfg, text = _step_text(chip, cell, periods=2)
     assert _kernel_rows(text, kernel) == sorted(
@@ -713,11 +716,12 @@ def test_the_step_program_reads_the_bank_in_place(chip, cell):
     from deepspeed_tpu.models.gpt import GPT
     leaves = jax.eval_shape(GPT(cfg).init_params, jax.random.PRNGKey(0))[
         "blocks"]["moe"]["experts"]
-    assert len(calls) == 2 * len(cfg.pattern)
+    assert len(calls) == 2 * 2 * len(cfg.pattern)
     for leaf in leaves.values():
         L, G, K, N = leaf.shape
         assert L == cfg.n_layer and not _bank_copies(text, G, K, N)
-        assert sum(f"bf16[{L},{G},{K},{N}]" in call for call in calls) == len(cfg.pattern)
+        assert sum(f"bf16[{L},{G},{K},{N}]" in call
+                   for call in calls) == 2 * len(cfg.pattern)
 
 
 # the four serve cells' attention: (chunk, rows a query, products, key lanes,
@@ -904,8 +908,9 @@ def test_the_trinity_step_reads_its_bank_behind_a_dense_lead(chip):
     2,400 columns wide: ``paged_gqa_attention`` at a group of SIX query heads
     runs the decode rows and the packed chunk in every layer, the bank's
     stack ``[3, 16, K, N]`` (the EXPERT layers alone) goes to
-    ``grouped_matmul`` whole, twice an expert layer, and the program makes
-    no array of one layer's bank."""
+    ``grouped_matmul`` whole, twice an expert layer in each branch of the
+    block's tail (all rows; the decode rows alone), and the program makes no
+    array of one layer's bank."""
     from deepspeed_tpu.models import gpt
     from deepspeed_tpu.serving.kv_cache import init_arena, window_table_blocks
     slots, chunk, BS, blocks = 32, 512, 16, 1025
@@ -930,7 +935,7 @@ def test_the_trinity_step_reads_its_bank_behind_a_dense_lead(chip):
     assert Sq > 1 and _kernel_rows(text, "paged_gqa_attention") == sorted(
         [slots, chunk // Sq] * 4)
     calls = _bank_calls(text)
-    assert len(calls) == 2 * 3
+    assert len(calls) == 2 * 2 * 3
     for K, N in ((3072, 6144), (3072, 3072)):
-        assert sum(f"bf16[3,16,{K},{N}]" in call for call in calls) == 3
+        assert sum(f"bf16[3,16,{K},{N}]" in call for call in calls) == 2 * 3
         assert not _bank_copies(text, 16, K, N)

@@ -46,11 +46,10 @@ def bank_in_place_equals_bank_sliced(cfg, params, path, kernels, monkeypatch,
         (w.shape, False)) or real_ragged(a, w, s))
     in_place = step()
     stack = params["blocks"]["moe"]["experts"]["wi"].shape
-    # the bank's layers as traced: a period in the scan's body, and behind a
-    # dense lead the expert layers walked before the scan, each apart
-    lead = cfg.moe_dense_layers
-    walked = -(-lead // P) * P
-    traced = (walked - lead) + (P if walked < cfg.n_layer else 0)
+    # the bank's layers as traced: ONE, whatever walks them (the scan's
+    # body, or behind a dense lead the loop before it): a block's tail is a
+    # jitted function of the stacks and the layer's index
+    lead, traced = cfg.moe_dense_layers, 1
     assert len(calls) == 2 * traced and all(
         kernel == (path == "kernel") for _, kernel in calls)
     # the kernel is handed the stack of ALL layers; ragged_dot the layer,

@@ -265,7 +265,8 @@ def test_spans_carry_counts_where_the_work_happens(tiny_model):
     assert (stats["programs"], stats["prefill_tokens"], stats["decode_batch"]) == (1, 8, 0)
     # how attention took the step: the chunk's 8 tokens the queries of ONE
     # row beside the 4 slots' rows, on the span and in the stats
-    packed = {"chunk_queries_per_row": 8, "attention_rows": 4 + 1}
+    # and all 4 + 8 rows through the dense matrices
+    packed = {"chunk_queries_per_row": 8, "attention_rows": 4 + 1, "dense_rows": 4 + 8}
     assert {k: by["serve.stats"][0][k] for k in packed} == packed
     assert {k: stats[k] for k in packed} == packed
     by, stats = _step_spans(tr, eng)            # last chunk: the first token
@@ -301,7 +302,7 @@ def test_spans_carry_counts_where_the_work_happens(tiny_model):
              "upload_bytes": 4 * eng._layout.packed_size}
     (on_span,) = by["serve.stats"]
     assert on_span == dict(table, chunk_queries_per_row=0, attention_rows=5,
-                           dispatched_ahead=0, program=3,
+                           dense_rows=4, dispatched_ahead=0, program=3,
                            **{k: stats[k] for k in TURNAROUND_STATS})
     assert {k: stats[k] for k in table} == table
     assert stats["paged_tile_pages"] == eng.paged_tile_pages == 0   # the einsum
@@ -311,7 +312,8 @@ def test_spans_carry_counts_where_the_work_happens(tiny_model):
     idle = eng.step()                           # nothing to run: no program,
     assert (idle["programs"], idle["upload_bytes"]) == (0, 0)       # no upload
     assert "program" not in idle
-    assert (idle["chunk_queries_per_row"], idle["attention_rows"]) == (0, 0)
+    assert (idle["chunk_queries_per_row"], idle["attention_rows"],
+            idle["dense_rows"]) == (0, 0, 0)
     eng.close()
 
 
