@@ -59,8 +59,9 @@ class LayerKind(NamedTuple):
     the blocks the query chooses (``sparse``: :class:`SparseSpec`), a
     ``linear`` recurrence whose cache is a state, attention inside a latent
     convolved over time (``cca``), the gated ``delta`` rule whose state is
-    corrected before it is written, attention over the TOKENS an indexer
-    scores highest (``indexed``: :class:`IndexerSpec`), or, beside one of
+    corrected before it is written, Mamba-1's selective scan whose state is
+    a diagonal recurrence by channel (``mamba``), attention over the TOKENS an
+    indexer scores highest (``indexed``: :class:`IndexerSpec`), or, beside one of
     those in the same walk, ``full`` softmax attention over pages (all
     ``models/hybrid.py``).
     ``depth`` is the layer's index in the PUBLISHED stack where that differs
@@ -300,6 +301,15 @@ class GPTConfig:
     delta_value_dim: int = 0
     delta_conv: int = 4
     delta_neg_eigval: bool = False
+    # --- Mamba-1's selective scan (the Jamba family's ``mamba`` layers,
+    # ``models/hybrid.py:mamba_mixer``): ``mamba_inner`` channels, each a
+    # recurrence over ``mamba_state`` states with a decay of its own, behind
+    # a causal convolution over time of ``mamba_conv`` taps with a bias; the
+    # token's step a channel comes up from ``mamba_dt_rank`` lanes ---------- #
+    mamba_inner: int = 0
+    mamba_state: int = 0
+    mamba_conv: int = 4
+    mamba_dt_rank: int = 0
     # where a block's two norms sit: on each sublayer's OUTPUT (the OLMo 2/3
     # block, ``x + norm(f(x))``: mixer and MLP read the residual as it is)
     # and not on its input.  The hybrid walk reads it; the dense paths are
@@ -364,13 +374,16 @@ class GPTConfig:
         self.hybrid = any(m != "softmax" for m in self.mixers)
         if self.hybrid:
             assert len(self.pattern) == self.n_layer and all(
-                m in ("sparse", "linear", "cca", "delta", "indexed", "full")
+                m in ("sparse", "linear", "cca", "delta", "mamba", "indexed", "full")
                 for m in self.mixers) and set(self.mixers) != {"full"}, (
                     "a hybrid stack names every layer: sparse, linear, cca, "
-                    "delta, indexed, or full beside one of them")
+                    "delta, mamba, indexed, or full beside one of them")
             assert "delta" not in self.mixers or (
                 self.delta_heads and self.delta_key_dim and self.delta_value_dim
                 and self.delta_conv >= 2), "a delta layer's heads and widths"
+            assert "mamba" not in self.mixers or (
+                self.mamba_inner and self.mamba_state and self.mamba_dt_rank
+                and self.mamba_conv >= 2), "a mamba layer's channels and widths"
             self.indexer = IndexerSpec(*(self.indexer or ()))
             self.sparse = SparseSpec(*(self.sparse or ()))
             sp = self.sparse
@@ -728,6 +741,37 @@ def olmo_hybrid_config(vocab_size=100352, n_positions=65536, n_embd=3840,
     kw.update(overrides)
     return llama_config(vocab_size=vocab_size, n_positions=n_positions,
                         n_embd=n_embd, n_layer=len(pattern), n_head=n_head,
+                        intermediate_size=intermediate_size, **kw)
+
+
+def jamba_config(vocab_size=65536, n_positions=262144, n_embd=2560, n_layer=28,
+                 n_head=20, n_kv_head=1, head_dim=128, intermediate_size=8192,
+                 attn_layer_period=14, attn_layer_offset=7, mamba_expand=2,
+                 mamba_d_state=16, mamba_d_conv=4, mamba_dt_rank=160,
+                 **overrides) -> GPTConfig:
+    """Jamba family, its dense members (defaults: AI21-Jamba2-3B's widths):
+    layer ``i`` plain softmax attention where ``i % attn_layer_period ==
+    attn_layer_offset`` (``n_head`` query heads on ``n_kv_head`` K/V heads, NO
+    position encoding, no q/k norm: the Mamba layers order the tokens) and
+    every other layer a Mamba-1 mixer (``mamba_expand * n_embd`` channels
+    behind a causal convolution of ``mamba_d_conv`` taps with a bias, each
+    channel a recurrence over ``mamba_d_state`` states whose step comes up
+    from ``mamba_dt_rank`` lanes, the family's three inner norms on the step,
+    the input and the output weights: ``models/hybrid.py:mamba_mixer``);
+    pre-norm blocks, every feed-forward a dense SwiGLU MLP (``num_experts``
+    1).  RMSNorm (eps 1e-6), no bias but the convolution's and the step's,
+    the head tied to the embedding.  Served through ``init_serving()``
+    (``models/hybrid.py``); the dense paths refuse it."""
+    pattern = tuple(
+        LayerKind(None, False, "full" if i % attn_layer_period == attn_layer_offset
+                  else "mamba") for i in range(n_layer))
+    kw = dict(n_kv_head=n_kv_head, head_dim=head_dim, ln_eps=1e-6,
+              layer_pattern=pattern, untied_head=False,
+              mamba_inner=mamba_expand * n_embd, mamba_state=mamba_d_state,
+              mamba_conv=mamba_d_conv, mamba_dt_rank=mamba_dt_rank)
+    kw.update(overrides)
+    return llama_config(vocab_size=vocab_size, n_positions=n_positions,
+                        n_embd=n_embd, n_layer=n_layer, n_head=n_head,
                         intermediate_size=intermediate_size, **kw)
 
 

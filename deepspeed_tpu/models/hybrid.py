@@ -6,9 +6,11 @@ in runs of a mixer, dispatching on both.  The MiniCPM-SALA family
 period over a dense MLP), the ZAYA1 family (``zaya_config``: ``cca`` layers
 over an expert bank), the Olmo-Hybrid family (``olmo_hybrid_config``:
 ``delta`` layers, three to every ``full`` layer, each norm on its sublayer's
-output) and the Keye-VL-2.0 family (``keye_vl2_config``: ``indexed`` layers
-over a bank behind a linear softmax router) are entries of :data:`MIXERS`
-and :data:`FEED_FORWARDS`.  The mixers (``cca`` is described at
+output), the Keye-VL-2.0 family (``keye_vl2_config``: ``indexed`` layers
+over a bank behind a linear softmax router) and the Jamba family
+(``jamba_config``: ``mamba`` layers, thirteen to every ``full`` layer of
+multi-query attention) are entries of :data:`MIXERS` and
+:data:`FEED_FORWARDS`.  The mixers (``cca`` is described at
 :func:`cca_mixer`):
 
 * ``indexed`` (DeepSeek Sparse Attention under grouped-query attention, the
@@ -58,9 +60,20 @@ and :data:`FEED_FORWARDS`.  The mixers (``cca`` is described at
   a prompt chunk the chunked form with a unit lower-triangular solve a head
   (:func:`delta_chunk`).  Both states start from zero BY POSITION, as the
   linear layers' do;
+* ``mamba`` (Mamba-1's selective scan, arXiv:2312.00752, with the Jamba
+  family's three inner norms): a channel's cache is ``mamba_state`` float32
+  numbers a slot, a DIAGONAL recurrence ``h_t[n, d] = exp(dt_t[d] A[n, d])
+  h_{t-1}[n, d] + dt_t[d] B_t[n] c_t[d]``, ``y_t[d] = sum_n C_t[n] h_t[n, d]
+  + D[d] c_t[d]``: a decay of its own for every (channel, state) pair, so no
+  chunked matrix form exists and a prompt chunk is scanned token by token
+  with the state in registers (``ops/pallas/selective_scan.py``, which also
+  says why the state lies ``[states, channels]``), behind a causal
+  convolution over time with a bias whose last ``taps - 1`` rows a slot
+  keeps too.  Both states start from zero BY POSITION;
 * ``full``: plain softmax attention over its own token's K and V, beside a
   mixer that owns no pages: ``models/gpt.py``'s projection (with its q/k
-  norm over all lanes) and the page group's plan, CALLED from the walk.
+  norm over all lanes where the configuration has one) and the page group's
+  plan, CALLED from the walk.
 
 The leaves are stacked BY MIXER (``params["blocks"]["sparse"]`` ``[4, ...]``,
 ``["linear"]`` ``[12, ...]``: no projection is padded to another kind's
@@ -132,9 +145,9 @@ def arena_layout(cfg) -> Tuple[int, int, Tuple[int, ...]]:
     head, so the kernel walks a list of pages a head); the cca layers own
     them, a page a block of all K/V heads like any grouped-query model's,
     and so do the indexed and the full layers (an indexed layer's index keys
-    ride in ``aux`` under the same tables); the linear and the delta layers
-    own none."""
-    paged = {m for m in cfg.mixers if m not in ("linear", "delta")}
+    ride in ``aux`` under the same tables); the linear, the delta and the
+    mamba layers own none."""
+    paged = {m for m in cfg.mixers if m not in ("linear", "delta", "mamba")}
     assert len(paged) <= 1, f"one mixer's layers own the pages, not {paged}"
     for m in ("cca", "indexed", "full"):
         if m in paged:
@@ -155,6 +168,9 @@ def what_a_dense_path_lacks(cfg) -> str:
              "delta": f"no chunked delta-rule scan (nor its backward) and no "
                       f"convolution over time of the packed q/k/v for the "
                       f"{n('delta')} delta layers",
+             "mamba": f"no selective scan (nor its backward) and no "
+                      f"convolution over time of the channels for the "
+                      f"{n('mamba')} mamba layers",
              "indexed": f"no lightning indexer, no cache of index keys and no "
                         f"selection of the tokens a query attends for the "
                         f"{n('indexed')} indexed layers"}
@@ -171,6 +187,8 @@ def what_no_block_carries(cfg) -> str:
                     f"(the last two packed latents and the next token's "
                     f"shifted value half)",
              "delta": f"{n('delta')} delta layers hold a recurrent state and "
+                      f"a convolution state a slot",
+             "mamba": f"{n('mamba')} mamba layers hold a recurrent state and "
                       f"a convolution state a slot",
              "indexed": f"{n('indexed')} indexed layers a cache of index keys "
                         f"the selection scores"}
@@ -206,8 +224,22 @@ def _mixer_shapes(cfg, mixer: str) -> Dict:
     if mixer == "linear":
         return dict(_GATED(cfg), qkv_w=(E, 3 * A), onorm_g=(A,))
     if mixer == "full":
-        return {"qkv_w": (E, cfg.qkv_dim), "out_w": (A, E),
-                "q_norm_g": (A,), "k_norm_g": (cfg.kv_heads * D,)}
+        # the q/k norm over all lanes where the configuration has one
+        norms = ({"q_norm_g": (A,), "k_norm_g": (cfg.kv_heads * D,)}
+                 if cfg.qk_norm else {})
+        return {"qkv_w": (E, cfg.qkv_dim), "out_w": (A, E), **norms}
+    if mixer == "mamba":
+        # in_w: [W_u | W_z], the channels and their gate; conv_w (tap 0 the
+        # oldest token's) and conv_b the depthwise convolution; x_w: [W_r |
+        # W_B | W_C], the step's ``mamba_dt_rank`` lanes and the token's
+        # input and output weights, each under a norm of its own; dt_w and
+        # dt_b bring the step up to a channel; scan_a_log ``[states, channels]``
+        # as the state lies (ops/pallas/selective_scan.py); skip_d is ``D``
+        N, S, R = cfg.mamba_inner, cfg.mamba_state, cfg.mamba_dt_rank
+        return {"in_w": (E, 2 * N), "conv_w": (cfg.mamba_conv, N), "conv_b": (N,),
+                "x_w": (N, R + 2 * S), "dt_norm_g": (R,), "b_norm_g": (S,),
+                "c_norm_g": (S,), "dt_w": (R, N), "dt_b": (N,),
+                "scan_a_log": (S, N), "skip_d": (N,), "out_w": (N, E)}
     if mixer == "indexed":
         # index_w: [W_qI (heads x lanes) | W_kI (lanes) | W_w (heads)]; the
         # index key's LayerNorm has a gain and a bias; q and k a norm a head
@@ -266,20 +298,37 @@ def init_blocks(cfg, rng: Array) -> Dict:
     """``{mixer: leaves [that mixer's layers, ...]}``: gains (``*_g``) 1, the
     balancing bias, the decay's bias and the index key's norm's 0, a delta head ``h``'s ``a_log``
     ``log(0.02 (h + 1))`` (with ``a_t`` near 0 the heads then forget over 72
-    down to 2.4 tokens: none is dead, none unbounded), every other leaf
-    normal 0.02."""
-    def leaf(name, key, shape):
-        if name.endswith("_g"):
+    down to 2.4 tokens: none is dead, none unbounded); Mamba's own for a
+    mamba layer: state ``n``'s ``scan_a_log`` ``log(n + 1)``, ``skip_d`` 1,
+    ``dt_b`` the inverse softplus of a step drawn log-uniformly in [1e-3,
+    1e-1] a channel (decays of 0.999 down to 0.2 a token), the convolution's
+    taps and bias uniform within ``taps ** -0.5`` and ``dt_w`` within
+    ``dt_rank ** -0.5`` (at 0.02 the convolved input is a fortieth of the
+    convolution's input and the mixer's output a sixtieth of the MLP's: the
+    logits would not depend on the layer); every other leaf normal 0.02."""
+    def leaf(name, key, shape, mixer):
+        if mixer == "mamba" and name in ("conv_w", "conv_b", "dt_w"):
+            bound = (cfg.mamba_dt_rank if name == "dt_w" else cfg.mamba_conv) ** -0.5
+            return jax.random.uniform(key, shape, jnp.float32, -bound, bound)
+        if name.endswith("_g") or name == "skip_d":
             return jnp.ones(shape, jnp.float32)
         if name in ("balance_bias", "dt_bias", "ik_norm_b"):
             return jnp.zeros(shape, jnp.float32)
+        if name == "dt_b":
+            step = jnp.exp(jax.random.uniform(
+                key, shape, jnp.float32, math.log(1e-3), math.log(1e-1)))
+            return step + jnp.log(-jnp.expm1(-step))
+        if name == "scan_a_log":
+            return jnp.broadcast_to(jnp.log(jnp.arange(
+                1, shape[0] + 1, dtype=jnp.float32))[:, None], shape)
         if name == "a_log":
             return jnp.log(0.02 * (jnp.arange(shape[0], dtype=jnp.float32) + 1.0))
         return gpt._dense_init(key, shape[0], shape)
 
-    def one(shapes, key):
+    def one(shapes, key, mixer):
         keys = jax.random.split(key, len(shapes))
-        return {name: leaf(name, k, shape) if _is_shape(shape) else one(shape, k)
+        return {name: (leaf(name, k, shape, mixer) if _is_shape(shape)
+                       else one(shape, k, mixer))
                 for k, (name, shape) in zip(keys, sorted(shapes.items()))}
 
     out = {}
@@ -288,13 +337,13 @@ def init_blocks(cfg, rng: Array) -> Dict:
         if count:
             # a layer at a time: one layer's random bits are a gigabyte
             out[mixer] = jax.lax.map(
-                lambda k, mixer=mixer: one(_leaf_shapes(cfg, mixer), k),
+                lambda k, mixer=mixer: one(_leaf_shapes(cfg, mixer), k, mixer),
                 jax.random.split(jax.random.fold_in(rng, n), count))
     return out
 
 
 def block_partition_specs(cfg) -> Dict:
-    column = {"q_w", "kv_w", "qkv_w", "gate_w", "fc_w"}
+    column = {"q_w", "kv_w", "qkv_w", "gate_w", "fc_w", "in_w"}
     row = {"out_w", "proj_w"}
     bank = {"wi": ("expert", None, "tensor"), "wo": ("expert", "tensor", None)}
 
@@ -340,7 +389,11 @@ def init_aux(cfg, num_blocks: int, block_size: int, slots: int, dtype) -> Dict:
     d_k, H * d_v]`` float32 (a head's ``[d_k, d_v]`` beside the other heads'
     on the lanes: ``ops/pallas/delta_rule.py`` says why) and ``delta_conv
     [delta layers, slots, taps - 1, U]``: a slot's last packed ``[q | k |
-    v]`` rows, the oldest first; ``ki [indexed layers, num_blocks, block_size,
+    v]`` rows, the oldest first; ``mamba_state [mamba layers, slots, states,
+    channels]`` float32 (the CHANNELS on the lanes:
+    ``ops/pallas/selective_scan.py`` says why) and ``mamba_conv [mamba layers,
+    slots, taps - 1, channels]``: a slot's last input rows, the oldest first;
+    ``ki [indexed layers, num_blocks, block_size,
     index lanes]``: the index keys of a page, reached through the block
     tables like K and V (allocated, freed and re-bound with them: a slot
     bound to a new sequence scores no former tenant's, BY POSITION)."""
@@ -355,6 +408,11 @@ def init_aux(cfg, num_blocks: int, block_size: int, slots: int, dtype) -> Dict:
                                    H * cfg.delta_value_dim), jnp.float32),
             delta_conv=jnp.zeros((L, slots, cfg.delta_conv - 1,
                                   _delta_lanes(cfg)), dtype))
+    if "mamba" in cfg.mixers:
+        L, N = cfg.mixers.count("mamba"), cfg.mamba_inner
+        out.update(
+            mamba_state=jnp.zeros((L, slots, cfg.mamba_state, N), jnp.float32),
+            mamba_conv=jnp.zeros((L, slots, cfg.mamba_conv - 1, N), dtype))
     if "cca" in cfg.mixers:
         U, Vh = _cca_widths(cfg)
         out["cca_state"] = jnp.zeros((cfg.mixers.count("cca"), slots,
@@ -753,18 +811,29 @@ def delta_chunk(q, k, v, g, beta, s_in, live):
     return o, s_out
 
 
-def _delta_neighbours(u, c_all, slots, live, first, chunk: int):
-    """Each row's ``taps - 1`` packed rows before it, and the convolution
-    state after the step.  ``u [B, U]``; ``c_all [slots, taps - 1, U]`` the
-    layer's states, the oldest row first.  A decode row (row ``s`` is slot
-    ``s``) reads its slot's state and shifts its own row in; the prompt
-    chunk's rows read the rows before them, its first ones the state of ITS
-    slot (zero where the chunk starts at position ``first == 0``), and leave
-    the last live tokens' there.  -> (the rows before ``[B, taps - 1, U]``,
+def _conv_windows(seq, chunk: int):
+    """``seq [T + chunk, U]`` (a sequence from ``T`` rows before a chunk on)
+    -> each of the chunk's rows' ``T`` rows before it ``[chunk, T, U]``."""
+    T = seq.shape[0] - chunk
+    return jnp.stack([seq[j:j + chunk] for j in range(T)], axis=1)
+
+
+def _conv_neighbours(u, c_all, slots, live, first, chunk: int):
+    """What a causal convolution over time reads beside each row's own, and
+    its state after the step: the delta layers' over the packed ``[q | k |
+    v]``, the mamba layers' over the channels.  ``u [B, U]``; ``c_all [slots,
+    taps - 1, U]`` the layer's states, the oldest row first.  A decode row
+    (row ``s`` is slot ``s``) reads its slot's state and shifts its own row
+    in; the prompt chunk's rows read the rows before them, its first ones the
+    state of ITS slot (zero where the chunk starts at position ``first ==
+    0``), and leave the last live tokens' there.  -> (the decode rows' rows
+    before ``[slots, taps - 1, U]``, the chunk's sequence from ``taps - 1``
+    rows before it on ``[taps - 1 + chunk, U]``, of which
+    :func:`_conv_windows` gives its rows' rows before (None without a chunk),
     c_all)."""
     n_dec, T = u.shape[0] - chunk, c_all.shape[1]
     assert c_all.shape[0] == n_dec, "a decode row a slot"
-    before = c_all
+    before, seq = c_all, None
     grown = jnp.concatenate([c_all[:, 1:], u[:n_dec, None].astype(c_all.dtype)], 1)
     c_all = jnp.where(live[:n_dec, None, None], grown, c_all)
     if chunk:
@@ -772,14 +841,12 @@ def _delta_neighbours(u, c_all, slots, live, first, chunk: int):
         kept = jax.lax.dynamic_index_in_dim(c_all, slot, 0, keepdims=False)
         seq = jnp.concatenate([jnp.where(first == 0, 0, kept),
                                u[n_dec:].astype(kept.dtype)])          # [T + C, U]
-        before = jnp.concatenate([before, jnp.stack(
-            [seq[j:j + chunk] for j in range(T)], axis=1)])
         # behind the chunk's ``n`` live tokens lie the rows n .. n + T - 1
         # (a step without a chunk leaves the slot its rows name as it is)
         n = jnp.sum(live[n_dec:])
         c_all = jax.lax.dynamic_update_index_in_dim(c_all, jnp.where(
             live[n_dec], jax.lax.dynamic_slice_in_dim(seq, n, T), kept), slot, 0)
-    return before, c_all
+    return before, seq, c_all
 
 
 def delta_mixer(cfg, p, h, kp, vp, held, li, step: _Step):
@@ -801,7 +868,9 @@ def delta_mixer(cfg, p, h, kp, vp, held, li, step: _Step):
         (h,), chunk, live)
     with jax.named_scope("delta_conv"):
         c_all = jax.lax.dynamic_index_in_dim(held["delta_conv"], li, 0, keepdims=False)
-        before, c_all = _delta_neighbours(u, c_all, slots, live, first, chunk)
+        before, seq, c_all = _conv_neighbours(u, c_all, slots, live, first, chunk)
+        if chunk:
+            before = jnp.concatenate([before, _conv_windows(seq, chunk)])
         conv = jax.lax.dynamic_update_index_in_dim(held["delta_conv"], c_all, li, 0)
         w = f32("conv_w")
         c = jax.nn.silu(jnp.einsum("btu,tu->bu", before.astype(jnp.float32), w[:-1])
@@ -842,6 +911,79 @@ def delta_mixer(cfg, p, h, kp, vp, held, li, step: _Step):
     y = (y * jax.nn.silu(z.astype(jnp.float32))).astype(dt)
     out = _rows_that_carry(lambda r: r @ gpt._wget(p, "out_w", dt), (y,), chunk, live)
     return out, kp, vp, dict(held, delta_state=state, delta_conv=conv)
+
+
+# --------------------------------------------------------------------------- #
+# The mamba mixer
+# --------------------------------------------------------------------------- #
+def mamba_mixer(cfg, p, h, kp, vp, held, li, step: _Step):
+    """One mamba layer over the rows ``h [B, E]``: a decode row moves the
+    state of its slot on by its token (row ``s`` is slot ``s``) and reads it;
+    the prompt chunk is scanned token by token from the state of ITS slot,
+    from zero where it starts at position 0.  -> (output ``[B, E]``, the
+    pages as they came, held with ``mamba_state`` and ``mamba_conv`` moved
+    on).  Between the projections everything is float32: the convolution's
+    sum, the three inner norms, the step, the recurrence and the gate."""
+    from deepspeed_tpu.ops.pallas.selective_scan import (
+        mamba_chunk_scan, mamba_state_update)
+    positions, live, slots, _, _, _, chunk, dt, _, _ = step
+    N, S, R = cfg.mamba_inner, cfg.mamba_state, cfg.mamba_dt_rank
+    n_dec = h.shape[0] - chunk
+    first = positions[n_dec] if chunk else None
+    f32 = lambda name: p[name].astype(jnp.float32)
+    uz = _rows_that_carry(lambda r: r @ gpt._wget(p, "in_w", dt), (h,), chunk, live)
+    u, z = uz[:, :N], uz[:, N:]
+    with jax.named_scope("mamba_conv"):
+        c_all = jax.lax.dynamic_index_in_dim(held["mamba_conv"], li, 0, keepdims=False)
+        before, seq, c_all = _conv_neighbours(u, c_all, slots, live, first, chunk)
+        conv = jax.lax.dynamic_update_index_in_dim(held["mamba_conv"], c_all, li, 0)
+
+    def of_the_rows(u, before):
+        """What the recurrence reads of rows ``u [n, N]`` whose ``taps - 1``
+        rows before them are ``before [n, taps - 1, N]``: the convolved input
+        ``c`` and the step ``[n, N]``, ``B`` and ``C`` ``[n, S]``."""
+        with jax.named_scope("mamba_conv"):
+            w, taps = f32("conv_w"), before.shape[1]
+            c = jax.nn.silu(sum(before[:, j].astype(jnp.float32) * w[j] for j in range(taps))
+                            + u.astype(jnp.float32) * w[-1] + f32("conv_b"))
+        with jax.named_scope("mamba_params"):
+            x = (c.astype(dt) @ gpt._wget(p, "x_w", dt)).astype(jnp.float32)
+            norm = lambda t, g: gpt.rms_norm(t, f32(g), eps=cfg.ln_eps)
+            r = norm(x[:, :R], "dt_norm_g")
+            moves = jax.nn.softplus(
+                (r.astype(dt) @ gpt._wget(p, "dt_w", dt)).astype(jnp.float32)
+                + f32("dt_b"))
+            return (c, moves, norm(x[:, R:R + S], "b_norm_g"),
+                    norm(x[:, R + S:], "c_norm_g"))
+
+    A, D = -jnp.exp(f32("scan_a_log")), f32("skip_d")
+    # the decode rows in every step; the chunk's rows, their windows and
+    # their scan under the branch a step without a chunk (two of three
+    # here) takes the other side of, which leaves the slot its rows name
+    # (slot 0) as it is
+    rows = of_the_rows(u[:n_dec], before)
+    with jax.named_scope("mamba_scan"):
+        state, y = mamba_state_update(held["mamba_state"], li, *rows, A, D, live[:n_dec])
+    if chunk:
+        at = (li, slots[n_dec], 0, 0)
+        kept = jax.lax.dynamic_slice(state, at, (1, 1, S, N))
+
+        def over_the_chunk():
+            rows = of_the_rows(u[n_dec:], _conv_windows(seq, chunk))
+            with jax.named_scope("mamba_scan"):
+                s_out, yc = mamba_chunk_scan(jnp.where(first == 0, 0, kept[0, 0]),
+                                             *rows, A, D, live[n_dec:])
+            return yc, s_out[None, None]
+
+        yc, s_out = jax.lax.cond(
+            live[n_dec], over_the_chunk,
+            lambda: (jnp.zeros((chunk, N), jnp.float32), kept))
+        state = jax.lax.dynamic_update_slice(state, s_out, at)
+        y = jnp.concatenate([y, yc])
+    out = _rows_that_carry(
+        lambda y, z: (y * jax.nn.silu(z.astype(jnp.float32))).astype(dt)
+        @ gpt._wget(p, "out_w", dt), (y, z), chunk, live)
+    return out, kp, vp, dict(held, mamba_state=state, mamba_conv=conv)
 
 
 # --------------------------------------------------------------------------- #
@@ -1151,13 +1293,15 @@ def _moe_ffn(route):
 # the entries the walk dispatches on: a layer names one of each
 # (``LayerKind.mixer``, ``LayerKind.ffn``); a mixer's is its function and the
 # scope its device ops are traced under.  The order is the order of the
-# mixers' stacks in ``init_blocks``
+# mixers' stacks in ``init_blocks`` (a stack's seed is its place here: a new
+# mixer goes last)
 MIXERS = {"sparse": (sparse_mixer, lambda: jax.named_scope("attn_sparse")),
           "linear": (linear_mixer, lambda: jax.named_scope("attn_linear")),
           "cca": (cca_mixer, lambda: jax.named_scope("attn_cca")),
           "delta": (delta_mixer, lambda: jax.named_scope("attn_delta")),
           "indexed": (indexed_mixer, lambda: jax.named_scope("attn_indexed")),
-          "full": (full_mixer, lambda: jax.named_scope("attn_full"))}
+          "full": (full_mixer, lambda: jax.named_scope("attn_full")),
+          "mamba": (mamba_mixer, lambda: jax.named_scope("attn_mamba"))}
 FEED_FORWARDS = {"mlp": mlp_ffn, "moe": _moe_ffn(_stream_route),
                  "moe_softmax": _moe_ffn(_softmax_route)}
 

@@ -35,7 +35,8 @@ def use_kernel(name: str) -> bool:
     the kernel's (``ce``, ``fused_adam``, ``flash_attention``,
     ``decode_attention``, ``paged_attention``, ``paged_gqa_attention``,
     ``paged_mla_attention``, ``paged_sparse_attention``, ``grouped_matmul``,
-    ``delta_state_update``) and is not read here: a test's replacement
+    ``delta_state_update``, ``mamba_state_update``, ``mamba_chunk_scan``) and
+    is not read here: a test's replacement
     answers for one kernel by it.  ``fused_adam`` is the NVMe offload
     walk's: no compiled step program holds it."""
     del name

@@ -713,9 +713,10 @@ class ServingEngine:
         state started from zero (a chunk at position 0) and bytes of state
         held (``state_bytes`` the linear layers', ``cca_state_bytes`` the cca
         layers', ``delta_state_bytes`` and ``delta_conv_bytes`` the delta
-        layers'); ``delta_state_moves``: the delta layers' states read and
-        written, one a live decode row a layer and one a layer for the
-        step's chunk; of the sparse layers, keys the live rows attended against
+        layers', ``mamba_state_bytes`` and ``mamba_conv_bytes`` the mamba
+        layers'); ``delta_state_moves`` / ``mamba_state_moves``: the delta /
+        mamba layers' states read and written, one a live decode row a layer
+        and one a layer for the step's chunk; of the sparse layers, keys the live rows attended against
         the keys resident before them, summed over sparse layers and K/V
         heads, and live rows at or under ``dense_len``; of the indexed layers,
         index keys the live rows scored (every key at or before them), keys
@@ -726,7 +727,7 @@ class ServingEngine:
         first = rows[self._config.max_batch_size]
         out = {"state_slots_reset": int(first[3] != 0 and first[1] == 0)}
         out.update({name + "_bytes": int(a.nbytes) for name, a in self._aux.items()
-                    if name.endswith(("state", "delta_conv"))})
+                    if name.endswith(("state", "delta_conv", "mamba_conv"))})
         if "indexed" in mcfg.mixers:
             t = rows[rows[:, 3] != 0, 1]
             per = mcfg.mixers.count("indexed")
@@ -735,9 +736,10 @@ class ServingEngine:
                 index_keys_scored=resident, indexed_keys_resident=resident,
                 indexed_keys_attended=int(hybrid.indexed_keys_attended(mcfg, t).sum()) * per,
                 index_key_bytes=int(self._aux["ki"].nbytes))
-        if "delta" in mcfg.mixers:
-            decoding = int((rows[:self._config.max_batch_size, 3] != 0).sum())
-            out["delta_state_moves"] = (decoding + int(first[3] != 0)) * mcfg.mixers.count("delta")
+        for mixer, stat in (("delta", "delta_state_moves"), ("mamba", "mamba_state_moves")):
+            if mixer in mcfg.mixers:
+                decoding = int((rows[:self._config.max_batch_size, 3] != 0).sum())
+                out[stat] = (decoding + int(first[3] != 0)) * mcfg.mixers.count(mixer)
         if "sparse" in mcfg.mixers:
             t = rows[rows[:, 3] != 0, 1]
             per = mcfg.mixers.count("sparse") * mcfg.kv_heads

@@ -939,3 +939,79 @@ def test_the_trinity_step_reads_its_bank_behind_a_dense_lead(chip):
     for K, N in ((3072, 6144), (3072, 3072)):
         assert sum(f"bf16[3,16,{K},{N}]" in call for call in calls) == 2 * 3
         assert not _bank_copies(text, 16, K, N)
+
+
+def _mamba_state_calls(text):
+    """(layers, slots) of the stacked states each call of the kernel
+    ``mamba_state_update`` takes and gives back."""
+    return [(int(a), int(b)) for a, b in re.findall(
+        r"%mamba_state_update[.\d]* = \(f32\[(\d+),(\d+),16,5120\][^\n]*tpu_custom_call", text)]
+
+
+def test_the_selective_scan_kernels_compile_at_jamba2_states(chip):
+    """Both kernels of ``ops/pallas/selective_scan.py`` at the published
+    widths: the decode rows' update of 384 slots of ``16 x 5120`` float32,
+    the stack WHOLE with the layer a scalar and updated in place; the chunk's
+    scan of 512 tokens, which makes nothing of ``[tokens, 5120, 16]``."""
+    from deepspeed_tpu.ops.pallas import selective_scan as ss
+    L, n, S, N, T = 26, 384, 16, 5120, 512
+    assert ss.kernel_shape_ok(n, S, N, jnp.float32) and ss.kernel_shape_ok(T, S, N, jnp.float32)
+    f32, sd = jnp.float32, lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=chip)
+    compiled = jax.jit(ss.mamba_state_update, donate_argnums=0).lower(
+        sd((L, n, S, N), f32), sd((), jnp.int32), sd((n, N), f32), sd((n, N), f32),
+        sd((n, S), f32), sd((n, S), f32), sd((S, N), f32), sd((N,), f32),
+        sd((n,), jnp.bool_)).compile()
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    assert _mamba_state_calls(text) == [(L, n)]                  # the stack, in place
+    state = L * n * S * N * 4
+    assert memory.alias_size_in_bytes >= state and memory.temp_size_in_bytes < 64 << 20
+    assert memory.argument_size_in_bytes - state < 32 << 20      # not a byte of padding
+    compiled = jax.jit(ss.mamba_chunk_scan).lower(
+        sd((S, N), f32), sd((T, N), f32), sd((T, N), f32), sd((T, S), f32),
+        sd((T, S), f32), sd((S, N), f32), sd((N,), f32), sd((T,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert "mamba_chunk_scan" in text and "tpu_custom_call" in text
+    assert not re.search(rf"f32\[{T},({N},{S}|{S},{N})\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def test_the_jamba2_step_scans_its_states_in_place(chip):
+    """The whole step of a slice of AI21-Jamba2-3B at the published widths
+    (mamba x 2, full, mamba), 384 slots and a chunk of 512: the mamba layers'
+    states go through the decode kernel on the stacked array (no layer's 126
+    MB sliced or copied out), the chunk's scan sits under the branch a step
+    without a prompt chunk takes the other side of, the full layer's
+    attention is the paged GQA kernel at a group of TWENTY query heads on one
+    K/V head, and nothing of ``[tokens, 5120, 16]`` is made."""
+    from deepspeed_tpu.models import gpt, hybrid
+    from deepspeed_tpu.serving.kv_cache import init_arena
+    cfg = gpt.jamba_config(n_layer=4, attn_layer_period=4, attn_layer_offset=2, dtype=BF16)
+    assert cfg.mixers == ("mamba", "mamba", "full", "mamba")
+    model, slots, chunk, BS, NB, MB = gpt.GPT(cfg), 384, 512, 16, 4097, 256
+    rows = slots + chunk
+    shape = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=chip)
+    on_chip = lambda tree: jax.tree.map(lambda a: shape(a.shape, a.dtype), tree)
+    params = jax.tree.map(lambda p: shape(p.shape, BF16),
+                          jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
+    kp, vp = on_chip(jax.eval_shape(lambda: init_arena(cfg, NB, BS, dtype=BF16)))
+    assert kp.shape == (1, NB, BS, 128)                          # ONE K/V head of 128
+    aux = on_chip(jax.eval_shape(lambda: hybrid.init_aux(cfg, NB, BS, slots, BF16)))
+    assert aux["mamba_state"].shape == (3, slots, 16, 5120) and aux["mamba_state"].dtype == jnp.float32
+    assert aux["mamba_conv"].shape == (3, slots, 3, 5120)
+    step = lambda p, ids, pos, kp, vp, tb, wb, wo, aux, sl, live: model.paged_step(
+        p, ids, pos, kp, vp, tb, wb, wo, chunk=chunk, aux=aux, slots=sl, live=live)
+    compiled = jax.jit(step, donate_argnums=(3, 4, 8)).lower(
+        params, shape((rows, 1), jnp.int32), shape((rows,), jnp.int32), kp, vp,
+        shape((rows, MB), jnp.int32), shape((rows, 1), jnp.int32),
+        shape((rows, 1), jnp.int32), aux, shape((rows,), jnp.int32),
+        shape((rows,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    plan = da.softmax_plan(20, 1, 128, BS, MB, chunk, BF16)
+    assert plan.kernel == "paged_gqa_attention" and plan.chunk_queries > 1
+    assert _kernel_rows(text, "paged_gqa_attention") == [chunk // plan.chunk_queries, slots]
+    assert _mamba_state_calls(text) == 2 * [(3, slots)]          # a call a run of the walk
+    assert "mamba_chunk_scan" in text and text.count("conditional(") >= 2
+    # nothing as large as a layer's states is made beside them, and nothing
+    # of a token's states a channel
+    assert not re.search(rf"f32\[({slots}|{chunk}|{rows}),(16,5120|5120,16)\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30

@@ -6,7 +6,9 @@ reference, at the published widths: the chip comparison of the
 ``minicpm-sala-9b.json``, ``zaya1-8b.json``, ``olmo-hybrid-7b.json``,
 ``keye-vl-2.0-30b-a3b.json``, ``trinity-large-preview.json``: a stack whose
 ``n_layer`` is whole periods, so its float32 run is ``"n_layer": 4``, the dense
-lead and three expert layers).
+lead and three expert layers; ``jamba2-3b.json``: the whole model as served,
+and its float32 run a few layers, ``"n_layer": 4, "attn_layer_period": 4,
+"attn_layer_offset": 2`` in ``model.kwargs`` and ``reference.kwargs``).
 
 Run standalone on a TPU host (``chiprun --chips 1 -- python
 tools/serve_parity.py benchmarks/configs/smallthinker-21b-a3b.json``); any
@@ -15,7 +17,7 @@ prompts (one of them longer than the model's window, where it has one, than
 the original positions of its YaRN rope, than the ``dense_len`` under
 which its sparse layers attend every key, than the ``topk`` tokens its
 indexed layers keep, or than two prompt chunks where its delta layers'
-chunked form must enter with a state) goes
+chunked form or its mamba layers' scan must enter with a state) goes
 through ``init_serving()`` / ``submit().result()`` with the file's slots and
 chunk (prefill in chunks, then decode, on the program's kernels), and each
 served sequence through the file's reference in one full float32 forward pass:
@@ -41,7 +43,12 @@ expert bank rounded through that type: the reading a limit must REFUSE, exit 1;
 ``--weights float8_e4m3fn`` does the same to every matrix of the blocks (a
 dense model's control); ``--index-keys float8_e4m3fn`` caches an indexed
 stack's index keys in that type (the weights whole: the selection alone is
-rounded).  ``--patch JSON`` lays a patch over the configuration
+rounded); ``--state bfloat16`` keeps a mamba stack's recurrent state in
+that type (on LOGITS, which is all this tool compares, it reads a fifth over
+what float32 reads and exits 0; what refuses it is the cell's check of the
+state itself, ``benchmarks/kinds/serve_backlog_resident_mamba.py``: ``--set
+planted='"state-bfloat16"'``, PERF.md § 6, PR 57).
+``--patch JSON`` lays a patch over the configuration
 file first, and ``--long TOKENS`` adds a prompt that long (a long-context
 cell's own lengths): the program in FLOAT32 against the reference, at a few layers of
 the published widths, is ``--patch '{"dtype": "float32", "serve": {"serving":
@@ -117,6 +124,9 @@ def main(argv=None) -> int:
     ap.add_argument("--index-keys", default=None, metavar="DTYPE",
                     help="cache an indexed stack's index keys in this type "
                          "(models/hybrid.py:init_aux's ``ki``)")
+    ap.add_argument("--state", default=None, metavar="DTYPE",
+                    help="keep a mamba stack's recurrent state in this type "
+                         "(models/hybrid.py:init_aux's ``mamba_state``)")
     ap.add_argument("--long", type=int, default=0, metavar="TOKENS",
                     help="one more prompt, this long (the cell's contexts: "
                          "the tool's arena holds 65,600 tokens in all)")
@@ -164,11 +174,13 @@ def main(argv=None) -> int:
                 getattr(k, "key", None) == "experts" for k in path) else w, p["blocks"])),
             donate_argnums=0)(served_params)
         params = None
-    if args.index_keys:
+    retyped = {name: jnp.dtype(to) for name, to in (
+        ("ki", args.index_keys), ("mamba_state", args.state)) if to}
+    if retyped:
         from deepspeed_tpu.models import hybrid
         init_aux = hybrid.init_aux
         hybrid.init_aux = lambda *a: {
-            name: leaf.astype(jnp.dtype(args.index_keys)) if name == "ki" else leaf
+            name: leaf.astype(retyped.get(name, leaf.dtype))
             for name, leaf in init_aux(*a).items()}
     eng = deepspeed_tpu.init_serving(model=model, params=served_params, config={
         "serving": dict(config["serve"]["serving"], num_blocks=PARITY_BLOCKS)})
@@ -183,7 +195,7 @@ def main(argv=None) -> int:
         lengths.append(cfg.sparse.dense_len + 200)
     if "indexed" in cfg.mixers:                 # and past where every token is kept
         lengths.append(cfg.indexer.topk + 200)
-    if "delta" in cfg.mixers:                   # and a third chunk entered with both states
+    if {"delta", "mamba"} & set(cfg.mixers):    # and a third chunk entered with both states
         lengths.append(2 * config["serve"]["serving"]["prefill_chunk"] + 77)
     if args.long:
         lengths.append(args.long)
@@ -201,7 +213,8 @@ def main(argv=None) -> int:
            "device": jax.devices()[0].device_kind, "paged_tile_pages": tile_pages,
            "margin": args.margin, "bank": args.bank or config["dtype"],
            "weights": args.weights or config["dtype"],
-           "index_keys": args.index_keys or config["dtype"], "sequences": []}
+           "index_keys": args.index_keys or config["dtype"],
+           "state": args.state or "float32", "sequences": []}
     kw = ref["kwargs"]
     check = None
     if "hidden" in ref:          # a long context: the comparison of its cell's kind
