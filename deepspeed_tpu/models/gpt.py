@@ -475,7 +475,8 @@ class GPTConfig:
         table ``widths[group]`` columns wide), a ``ops/pallas/
         decode_attention.py:PagedAttention`` each: THE one place a model's
         fields name a family of kernels.  ``init_serving`` reads its stats
-        and the allocator's ``run_blocks`` from the plans, and the step
+        and the allocator's ``run_blocks`` from the plans
+        (:meth:`paged_layout`), and the step
         functions, which are also called without an engine, build them again
         from the shapes of the arena they are handed, the same arguments: a
         plan handed down beside them would be a second way in."""
@@ -498,6 +499,23 @@ class GPTConfig:
             H, Hkv, D, block_size, MB, chunk, dtype,
             bias=self.position_encoding == "alibi", window=window)
             for window, MB in zip(self.page_groups, widths))
+
+    def paged_layout(self, block_size: int, max_blocks_per_seq: int,
+                     chunk: int, dtype):
+        """-> (``run_blocks``, the groups' table widths, their plans) of an
+        engine: the blocks the allocator lays down together are the largest
+        ``run_pages`` of the plans (the groups share their lanes, hence the
+        tile; a plan that copies page by page says 0), read at the widths of
+        single blocks because a window group's ring is as wide as its runs
+        want; THE widths (the allocator's, the device tables', the step's
+        plans') are ``serving/kv_cache.py:table_widths`` at that tile."""
+        from deepspeed_tpu.serving.kv_cache import table_widths
+        widths_at = lambda run_blocks: table_widths(
+            self.page_groups, max_blocks_per_seq, chunk, block_size, run_blocks)
+        plans_at = lambda run_blocks: self.paged_plans(
+            block_size, widths_at(run_blocks), chunk, dtype)
+        run_blocks = max(1, *(plan.run_pages for plan in plans_at(1)))
+        return run_blocks, widths_at(run_blocks), plans_at(run_blocks)
 
 
 # Model zoo (GPT-2 sizes; the 1.5B "xl" is the north-star model).

@@ -441,9 +441,11 @@ def paged_tile_runs(block_tables, pages: int, G: int):
     every ``j < G`` and ``tbl[t*G] + G <= pages``; no alignment is asked, so
     what a prompt's blocks happen to form counts as what
     ``serving/kv_cache.py`` lays down in runs).  A short last tile (``MB %
-    G``) is none.  None where ``G`` is 0 (no kernel that fetches runs reads
-    these tables).  The same for every layer: the step works it out once,
-    outside its scan over layers."""
+    G``) is none.  A window group's ring is read the same way, ring tile by
+    ring tile: it wants nothing but a width that is a multiple of ``G``
+    (logical tile ``T`` is then ring tile ``T % (MB / G)``).  None where ``G``
+    is 0 (no kernel that fetches runs reads these tables).  The same for
+    every layer: the step works it out once, outside its scan over layers."""
     if not G:
         return None
     B, MB = block_tables.shape
@@ -666,17 +668,25 @@ def _paged_gqa_kernel(lay_ref, len_ref, tbl_ref, nxt_ref, *refs, scale, bs, Sq,
       the table as a ring (logical block ``b`` in column ``b % MB``).  A
       query whose every key of a tile is masked (``Sq > 1`` only) adds
       exactly nothing there: its probabilities are forced to 0;
-    * with ``runs`` (a full group whose tables grow in runs of a tile) the
-      row's flags of :func:`paged_tile_runs` and the next row's follow the
-      tables as SMEM blocks (``run_ref``, ``nrun_ref`` ``[1, tiles]``), the
-      arenas come viewed ``[layers, pages * bs, lanes]``, and a tile whose
-      flag is set and whose ``G`` pages are all live is ONE copy an operand
+    * with ``runs`` (a group whose tables grow in runs of a tile, a full
+      group's or a window group's ring) the row's flags of
+      :func:`paged_tile_runs` and the next row's follow the tables as SMEM
+      blocks (``run_ref``, ``nrun_ref`` ``[1, tiles]``), the arenas come
+      viewed ``[layers, pages * bs, lanes]``, and a tile whose flag is set and
+      whose ``G`` pages are all live is ONE copy an operand
       (:func:`_tile_copies`).  There a tile is the unit of the copy alone: it
       is attended in steps of ``A`` keys, the tile of a call without flags,
       one online-softmax update each, in order (a step past the row's last
-      key adds exactly nothing), so the flags and the tile's size change
-      nothing in what comes out.  Without ``runs`` the kernel is what it
-      was: a copy a page, the tile attended whole."""
+      key, or below a window's first, adds exactly nothing), so the flags
+      change nothing in what comes out.  Without ``runs`` the kernel is what
+      it was: a copy a page, the tile attended whole;
+    * with a ``window`` AND ``runs`` the row's tiles are cut on the runs'
+      boundaries: its first tile is the run that holds the window's first
+      page (that page rounded down to ``G``; the mask discards what lies
+      below the window), ``MB`` is a multiple of ``G``, and logical tile ``T``
+      reads the flag of ring tile ``T % (MB / G)``, whose ``G`` columns hold
+      it.  ``serving/kv_cache.py`` keeps a run until its last key is out of
+      the window, so that first tile is still a run."""
     if runs:
         run_ref, nrun_ref, *refs = refs
     q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, slot_ref = refs
@@ -689,7 +699,8 @@ def _paged_gqa_kernel(lay_ref, len_ref, tbl_ref, nxt_ref, *refs, scale, bs, Sq,
     def first_page(row):
         if window is None:
             return 0
-        return jnp.maximum(len_ref[row] - (window - 1), 0) // bs
+        page = jnp.maximum(len_ref[row] - (window - 1), 0) // bs
+        return page - page % G if runs else page
 
     def pages_of(row):                            # live (DMA'd) pages
         newest = (len_ref[row] + Sq - 1) // bs
@@ -717,7 +728,8 @@ def _paged_gqa_kernel(lay_ref, len_ref, tbl_ref, nxt_ref, *refs, scale, bs, Sq,
         live = pages_of(row) - t * G
         run = None
         if runs and k_hbm.shape[1] >= rows_t:   # else an arena under a tile
-            run = jnp.where(row == b, run_ref[0, t], nrun_ref[0, t])
+            at = t if window is None else (first // G + t) % (MB // G)
+            run = jnp.where(row == b, run_ref[0, at], nrun_ref[0, at])
         _tile_copies(do, [operand(k_hbm, k_buf, 0), operand(v_hbm, v_buf, 1)],
                      entry, lambda: first + t * G, live, G, bs, run)
 
@@ -805,8 +817,10 @@ def _paged_gqa_call(q, k_arena, v_arena, layer, block_tables, lengths, plan,
                    lambda b, *_: (jnp.minimum(b + 1, B - 1), 0, 0))]
     tables, specs = [block_tables, block_tables], smem(MB)
     if tile_runs is not None:
-        assert plan.run_pages, "a ring holds no runs, nor a tile of chosen pages"
+        assert plan.run_pages, "a tile of chosen pages holds no runs"
         G = plan.run_pages
+        assert window is None or MB % G == 0, (
+            f"a ring of {MB} pages is no whole number of runs of {G}")
         tile_runs = jnp.asarray(tile_runs, jnp.int32)[:, None, :]
         tiles = tile_runs.shape[2]
         assert tiles == -(-MB // G), (tile_runs.shape, MB, G)
@@ -1060,7 +1074,10 @@ class PagedAttention(NamedTuple):
     allocator's ``run_blocks``, the flags of :meth:`tile_runs` and the tile a
     kernel copies are all ``run_pages`` of the one plan, so they cannot
     differ (if they did the tokens would stay right and every tile fall back
-    to a copy a page: 11-28% of a step, PERF.md section 6, PRs 40 and 44)."""
+    to a copy a page: 11-28% of a step, PERF.md section 6, PRs 40 and 44).
+    A window group's plan asks for runs as a full group's does: the groups of
+    a model share their lanes and their pages, hence their ``run_pages``, and
+    a ring is laid down in the same runs (``serving/kv_cache.py``)."""
     kernel: Optional[str]       # the ``pallas_call``'s name; None: the gather reference
     tile_pages: int             # pages a tile of the ATTEND holds (0 on a reference)
     run_pages: int              # pages ONE copy brings where they lie together
@@ -1073,8 +1090,13 @@ class PagedAttention(NamedTuple):
 
     def tile_runs(self, block_tables, pages: int):
         """:func:`paged_tile_runs` of a step's tables at ``run_pages``: the
-        same for every layer, so worked out once a group, outside the scan."""
-        return paged_tile_runs(block_tables, pages, self.run_pages)
+        same for every layer, so worked out once a group, outside the scan.
+        None for a ring that is no whole number of runs wide (its allocator
+        deals in single blocks): a copy a page."""
+        G = self.run_pages
+        if self.window is not None and G and block_tables.shape[1] % G:
+            G = 0
+        return paged_tile_runs(block_tables, pages, G)
 
     def attend(self, q, arenas, layer, block_tables, lengths, *, tile_runs=None,
                chunk: int = 0, bias=None):
@@ -1124,10 +1146,11 @@ def softmax_plan(H, Hkv, D, BS, MB, chunk, dtype, bias=False, window=None,
     an additive ``bias`` (ALiBi) or a ``window``.  THE rule: grouped K/V
     heads, a window, or multi-head attention whose heads are whole 128-lane
     tiles (OLMoE's 16 of 128: a group of one) go to ``paged_gqa_attention``,
-    which takes the arena whole and, over every key, a tile of pages that lie
-    together with one copy (:func:`paged_run_tile_pages`; not a window
-    group's ring, which gives pages back: runs in a ring are a design of
-    their own, ROADMAP S3 (b)).  Multi-head attention at ``D = 64`` over
+    which takes the arena whole and a tile of pages that lie together with
+    one copy (:func:`paged_run_tile_pages`), over every key and under a
+    window alike: a window group's ring is laid down in runs and gives a run
+    back whole (``serving/kv_cache.py``), and its kernel cuts a row's tiles on
+    the runs' boundaries.  Multi-head attention at ``D = 64`` over
     every key keeps the layer sliced out of the arena and ``paged_attention``:
     the successor has no two-heads-a-lane-slice case (``_attend_block``), and
     ``decode-heavy``'s backlog cannot outlast a 124M step without the copy
@@ -1151,7 +1174,7 @@ def softmax_plan(H, Hkv, D, BS, MB, chunk, dtype, bias=False, window=None,
     else:
         W = _lane_slices(H, D)[0]
         queries = paged_chunk_queries(chunk, 1, H, W, W, G * BS, dtype)
-    in_runs = runs and whole and window is None
+    in_runs = runs and whole
     return PagedAttention(
         name if runs else None, G if runs else 0,
         paged_run_tile_pages(BS, MB, Hkv * D, dtype) if in_runs else 0,

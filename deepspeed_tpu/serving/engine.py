@@ -67,7 +67,7 @@ from deepspeed_tpu.comm.bounded import BoundedCollective, CollectiveTimeout
 from deepspeed_tpu.runtime.offload import StagingError
 from deepspeed_tpu.serving.config import DeepSpeedServingConfig
 from deepspeed_tpu.serving.kv_cache import (ArenaExhausted, PagedKVAllocator,
-                                            init_arena, table_widths)
+                                            init_arena)
 from deepspeed_tpu.serving.kv_tiering import KVTieringManager
 from deepspeed_tpu.serving.prefix_cache import PrefixCache
 from deepspeed_tpu.serving.scheduler import (DECODE, EXPIRED, FINISHED,
@@ -536,18 +536,15 @@ class ServingEngine:
         # ``chunk_queries_per_row`` consecutive tokens a row, so they run
         # ``attention_rows`` rows where the program holds ``slots + chunk``
         # tokens.  The allocator takes ONE ``run_blocks``, the blocks it lays
-        # down together: the ``run_pages`` of the group over every key, whose
-        # kernel fetches a tile of consecutive pages with one copy (a window
-        # group's plan says 0, as every plan that copies page by page)
-        plans = mcfg.paged_plans(
-            cfg.block_size, table_widths(self._windows, self.max_blocks_per_seq,
-                                         cfg.prefill_chunk, cfg.block_size),
-            cfg.prefill_chunk, self.dtype)
+        # down together: the ``run_pages`` of the groups whose kernel fetches
+        # a tile of consecutive pages with one copy, a full group's and a
+        # window group's ring alike (a plan that copies page by page says 0)
+        self._run_blocks, _, plans = mcfg.paged_layout(
+            cfg.block_size, self.max_blocks_per_seq, cfg.prefill_chunk, self.dtype)
         self.paged_tile_pages = plans[0].tile_pages
         self.chunk_queries_per_row = queries = plans[0].chunk_queries
         self.attention_rows = (cfg.max_batch_size + cfg.prefill_chunk
                                // queries) * plans[0].rows_a_token
-        self._run_blocks = max(1, *(plan.run_pages for plan in plans))
         # bytes the arena holds a token a layer (every array of the cache
         # spec)
         self.cache_bytes_per_token = (sum(mcfg.cache_lanes)
@@ -1311,12 +1308,12 @@ class ServingEngine:
         return feeds
 
     def _tile_runs_pct(self) -> float:
-        """Of the tiles the live sequences' full-attention tables hold, the
-        share that are whole runs of consecutive pages: what the attention
-        kernel fetches with one copy where it can (the allocator's own
-        counts, nothing read from the device; ``paged_mla_attention`` and,
-        over a group without a window, ``paged_gqa_attention``).  0 where
-        the kernel copies page by page (``run_blocks`` 1: no tile is
+        """Of the tiles the live sequences' tables hold, a window group's
+        ring as a full group's table, the share that are whole runs of
+        consecutive pages: what the attention kernel fetches with one copy
+        where it can (the allocator's own counts, nothing read from the
+        device; ``paged_mla_attention`` and ``paged_gqa_attention``).  0
+        where the kernel copies page by page (``run_blocks`` 1: no tile is
         counted)."""
         held = self.alloc.tiles_held
         return 100.0 * self.alloc.tiles_run / held if held else 0.0

@@ -12,8 +12,8 @@ latent attention); this module owns the host-side bookkeeping:
   block: padded/inactive tokens scatter their K/V there, so the compiled
   step needs no write predication); where the kernel that reads the arena
   fetches a tile of consecutive pages with one copy, the free blocks are
-  also kept as whole RUNS of a tile, and a full-attention table grows a run
-  at a time (``run_blocks``, below);
+  also kept as whole RUNS of a tile, and a table grows a run at a time, a
+  window group's ring as a full group's (``run_blocks``, below);
 * a per-sequence block table in logical order, padded to
   ``max_blocks_per_seq`` with trash for the traced ``[B, MB]`` input;
 * **per-block refcounts**: a block may be shared by several sequences (the
@@ -46,32 +46,43 @@ class ArenaExhausted(Exception):
     """No free blocks and the caller chose not to (or could not) evict."""
 
 
-def window_table_blocks(window: int, chunk: int, block_size: int) -> int:
+def window_table_blocks(window: int, chunk: int, block_size: int,
+                        run_blocks: int = 1) -> int:
     """Width of a window group's block table: the blocks that hold the keys
     ``start - window + 1 .. start + chunk - 1`` a prompt chunk at ``start``
-    reads and writes, wherever ``start`` falls in a block."""
-    return -(-(window + chunk - 1) // block_size) + 1
+    reads and writes, wherever ``start`` falls in a block.  Where the tables
+    grow in runs of ``run_blocks`` the ring is a whole number of runs wide
+    and holds, beside those, the ``run_blocks - 1`` blocks below the
+    window's first that share its run: a run is kept until its LAST key is
+    out of the window, so the tile that holds the window's first page is
+    still a run when the kernel reads it."""
+    blocks = -(-(window + chunk - 1) // block_size) + 1
+    return -(-(blocks + run_blocks - 1) // run_blocks) * run_blocks
 
 
 def table_widths(windows, max_blocks_per_seq: int, chunk: int,
-                 block_size: int) -> Tuple[int, ...]:
+                 block_size: int, run_blocks: int = 1) -> Tuple[int, ...]:
     """Columns of each group's block table: a full group's grows to
-    ``max_blocks_per_seq``, a window group's is its ring."""
+    ``max_blocks_per_seq``, a window group's is its ring.  THE widths: the
+    allocator's, hence the engine's device tables' and the ones the step
+    builds its plans from."""
     return tuple(
         max_blocks_per_seq if w is None else min(
-            max_blocks_per_seq, window_table_blocks(w, chunk, block_size))
+            max_blocks_per_seq,
+            window_table_blocks(w, chunk, block_size, run_blocks))
         for w in windows)
 
 
 class _Run:
     """One sequence's blocks in ONE group, in logical order: ``blocks[i]`` is
-    logical block ``first + i``.  A full group's ``first`` stays 0."""
+    logical block ``first + i``.  A full group's ``first`` stays 0; a ring's
+    that grows in runs stays a multiple of the run."""
     __slots__ = ("first", "blocks", "given_back", "grow", "streak", "tiles_run")
 
     def __init__(self):
         self.first, self.blocks, self.given_back, self.grow = 0, [], 0, 0
-        # under ``run_blocks > 1``, of a full group: how many of the last
-        # blocks are physically consecutive, and how many whole tiles are
+        # of a group that grows in runs: how many of the last blocks are
+        # physically consecutive, and how many whole tiles are
         self.streak = self.tiles_run = 0
 
     @property
@@ -99,11 +110,11 @@ class PagedKVAllocator:
     homogeneous model has.
 
     ``run_blocks`` (``G``; the engine passes the pages of a tile of the
-    kernel that reads a full group, where that kernel fetches ``G``
+    kernel that reads the groups, where that kernel fetches ``G``
     consecutive pages with one copy; 1, the default, is the allocator as it
     was, block id for block id).  A RUN is ``G`` physically consecutive
-    blocks aligned to ``G``.  A full group's table grows in runs: when a
-    sequence first needs logical block ``k*G`` it takes a whole free run for
+    blocks aligned to ``G``.  A table grows in runs: when a sequence first
+    needs logical block ``k*G`` of a group it takes a whole free run for
     logical blocks ``k*G .. k*G+G-1`` and is handed the run's blocks in
     order as it grows, so every full tile of its table is one run.  What it
     has not been handed yet is EARMARKED, NOT OWNED: those blocks have no
@@ -112,13 +123,23 @@ class PagedKVAllocator:
     on the free list.  A growth that the loose free blocks and the whole
     free runs (broken up, if need be) cannot cover takes earmarked blocks
     from the end of their runs, the youngest earmark first, before
-    :meth:`allocate` returns False: nothing is refused that ``run_blocks =
-    1`` would grant.  (The sequence robbed goes on with loose blocks for the
-    rest of that tile, which is then no run.)  A freed block joins the loose
-    ones, and when all ``G`` of a run are loose the run is whole again.
-    Window groups, :meth:`adopt`, :meth:`ref` and :meth:`unref` deal in
-    single blocks as before.  ``tiles_held`` and ``tiles_run`` count, over
-    the full groups of the live sequences, the tiles their tables hold (the
+    :meth:`allocate` returns False: nothing is refused for the runs' sake
+    that the same count of free pages would grant under ``run_blocks = 1``.
+    (The sequence robbed goes on with loose blocks for the rest of that
+    tile, which is then no run.)  A freed block joins the loose ones, and
+    when all ``G`` of a run are loose the run is whole again.
+    A window group's ring grows the same way: it is a whole number of runs
+    wide (:func:`window_table_blocks`), so logical tile ``b // G`` is ring
+    tile ``(b // G) % (width // G)``, ``G`` adjacent columns, and it GIVES
+    BACK A RUN AT A TIME, the blocks of a tile when the tile's last key is
+    out of every later query's window (:meth:`first_live_block`): the tile
+    that holds the window's first page is still a run when the kernel reads
+    it, at up to ``G - 1`` more pages a group held.  (A ring whose width is
+    cut to ``max_blocks_per_seq`` and is no whole number of runs deals in
+    single blocks, as every group does under ``run_blocks = 1``.)
+    :meth:`adopt`, :meth:`ref` and :meth:`unref` deal in single blocks as
+    before.  ``tiles_held`` and ``tiles_run`` count, over every group of
+    the live sequences that grows in runs, the tiles their tables hold (the
     last one may be short) and those that are whole runs in order, aligned
     or not: what the kernel fetches with one copy.
     """
@@ -137,14 +158,18 @@ class PagedKVAllocator:
         self.max_blocks_per_seq = int(max_blocks_per_seq)
         self.windows = tuple(windows)
         self.n_groups = len(self.windows)
+        G = self.run_blocks = int(run_blocks)
         self.widths = table_widths(self.windows, self.max_blocks_per_seq,
-                                   chunk, self.block_size)
+                                   chunk, self.block_size, G)
+        # the groups whose tables grow in runs: every one but a ring that is
+        # no whole number of runs wide
+        self._in_runs = tuple(G > 1 and (w is None or width % G == 0)
+                              for w, width in zip(self.windows, self.widths))
         # LIFO free list: recently-freed blocks are reused first (their
         # pages are hot, and stale contents are fully overwritten before
         # any masked-in position can read them).  A dict in insertion order:
         # ``popitem`` is the list's ``pop``, and a run that re-forms takes
         # its blocks out of the middle
-        G = self.run_blocks = int(run_blocks)
         whole = range(G, self.num_blocks - G + 1, G) if G > 1 else ()
         self._free: Dict[int, None] = dict.fromkeys(
             b for b in range(self.num_blocks - 1, 0, -1)
@@ -200,10 +225,15 @@ class PagedKVAllocator:
         return -(-max(0, int(n_tokens)) // self.block_size)
 
     def first_live_block(self, group: int, resident: int) -> int:
-        """The oldest logical block of ``group`` that a query at position
-        ``resident`` or later can still see."""
+        """The oldest logical block of ``group`` a sequence keeps with
+        ``resident`` tokens behind it: the one that holds the oldest key a
+        query at position ``resident`` or later can still see, or, where the
+        ring grows in runs, the first of that block's run."""
         w = self.windows[group]
-        return 0 if w is None else max(0, resident - w + 1) // self.block_size
+        if w is None:
+            return 0
+        first = max(0, resident - w + 1) // self.block_size
+        return first - first % self.run_blocks if self._in_runs[group] else first
 
     def pages_for_tokens(self, n_tokens: int, resident: int = 0) -> int:
         """Pages a sequence holds, over all groups, with ``resident`` tokens
@@ -256,7 +286,7 @@ class PagedKVAllocator:
         for g, (run, window, width) in enumerate(
                 zip(runs, self.windows, self.widths)):
             if window is not None:
-                self._give_back(run, max(0, resident - window + 1) // bs, g, slot)
+                self._give_back(run, self.first_live_block(g, resident), g, slot)
             run.grow = min(need, run.first + width) - run.first - len(run.blocks)
             if run.grow > 0:
                 total += run.grow
@@ -266,14 +296,14 @@ class PagedKVAllocator:
             return False
         if new:
             self._owned[seq_id] = runs
-        in_runs = self.run_blocks > 1
+        any_runs = self.run_blocks > 1
         for g, (run, window) in enumerate(zip(runs, self.windows)):
             end = run.end
-            if in_runs and window is None:
+            if self._in_runs[g]:
                 self._grow_in_runs(run, run.grow)
             else:
                 for _ in range(run.grow):
-                    b = self._take_loose() if in_runs else self._free.popitem()[0]
+                    b = self._take_loose() if any_runs else self._free.popitem()[0]
                     self._refs[b] = 1
                     run.blocks.append(b)
             if run.grow > 0 and slot is not None:
@@ -286,8 +316,17 @@ class PagedKVAllocator:
 
     def _give_back(self, run: _Run, first_live: int, group: int,
                    slot: Optional[int]) -> None:
-        """A window group's blocks below ``first_live`` return to the pool."""
+        """A window group's blocks below ``first_live`` return to the pool
+        (whole tiles where the ring grows in runs: ``first_live`` is a run's
+        first block, and ``run.first`` stays one)."""
         n = min(max(0, first_live - run.first), len(run.blocks))
+        if self._in_runs[group]:
+            G = self.run_blocks
+            n -= n % G
+            whole = sum(self._is_run(run.blocks[i:i + G]) for i in range(0, n, G))
+            run.tiles_run -= whole
+            self.tiles_run -= whole
+            self.tiles_held -= n // G
         for b in run.blocks[:n]:
             self.unref(b)
         if n and slot is not None:
@@ -320,11 +359,16 @@ class PagedKVAllocator:
         self._loose_in[b - b % G] -= 1
         return b
 
+    def _is_run(self, tile: List[int]) -> bool:
+        """``tile``, ``run_blocks`` blocks of a table, lies together in order."""
+        return tile == list(range(tile[0], tile[0] + self.run_blocks))
+
     def _grow_in_runs(self, run: _Run, n: int) -> None:
-        """``n`` more blocks for a full group's ``run``, each tile of its
-        table a whole free run while there is one."""
+        """``n`` more blocks for ``run`` from LOGICAL block ``run.end`` on (a
+        ring's first block is not block 0), each tile of its table a whole
+        free run while there is one."""
         G, blocks = self.run_blocks, run.blocks
-        for i in range(len(blocks), len(blocks) + n):
+        for i in range(run.end, run.end + n):
             j = i % G
             # an open earmark of its own continues the table: the run whose
             # first ``j`` blocks are the table's last ``j``
@@ -346,9 +390,9 @@ class PagedKVAllocator:
             self._count_tile(run, i)
 
     def _count_tile(self, run: _Run, i: int) -> None:
-        """Logical block ``i`` has joined a full group's ``run``."""
-        blocks = run.blocks
-        run.streak = run.streak + 1 if i and blocks[i] == blocks[i - 1] + 1 else 1
+        """Logical block ``i`` has joined ``run``."""
+        blocks, k = run.blocks, i - run.first
+        run.streak = run.streak + 1 if k and blocks[k] == blocks[k - 1] + 1 else 1
         j = i % self.run_blocks
         if j == 0:
             self.tiles_held += 1
@@ -373,7 +417,7 @@ class PagedKVAllocator:
     def _drop_earmark(self, run: _Run) -> None:
         """What was earmarked for ``run`` (it is being freed) is loose."""
         G = self.run_blocks
-        j = len(run.blocks) % G
+        j = run.end % G
         base = run.blocks[-1] - j + 1 if j else None
         mark = self._earmarks.get(base)
         if mark is not None and mark[0] is run:
@@ -392,9 +436,8 @@ class PagedKVAllocator:
             # the row goes to trash whole, whatever was still to be said of it
             self._edits.pop(slot, None)
             self._cleared.add(slot)
-        in_runs = self.run_blocks > 1
         for g, run in enumerate(self._owned.pop(seq_id, ())):
-            if in_runs and self.windows[g] is None and run.blocks:
+            if self._in_runs[g] and run.blocks:
                 self._drop_earmark(run)
                 self.tiles_held -= -(-len(run.blocks) // self.run_blocks)
                 self.tiles_run -= run.tiles_run
@@ -542,7 +585,8 @@ class PagedKVAllocator:
         prefix cache's pin); a run fits its group's table; the page counts
         are the runs'.  Free is: loose, in a whole free run, or earmarked
         (an earmark is the rest of the aligned run whose first blocks end its
-        owner's table), and the tile counts are the tables'.  Raises
+        owner's table), and the tile counts are the tables', a ring's as a
+        table's that only grows.  Raises
         AssertionError on violation."""
         owners: Dict[int, int] = {}
         full = window = given_back = 0
@@ -555,14 +599,16 @@ class PagedKVAllocator:
                     f"{seq_id}: {len(run.blocks)} blocks in group {g}'s "
                     f"table of {self.widths[g]}")
                 assert run.first == 0 or self.windows[g] is not None
+                if self._in_runs[g]:
+                    assert run.first % G == 0, (
+                        f"{seq_id}: group {g}'s ring starts inside a run")
+                    tiles = [run.blocks[i:i + G]
+                             for i in range(0, len(run.blocks), G)]
+                    n = sum(map(self._is_run, tiles))
+                    assert n == run.tiles_run, f"{seq_id}: runs miscounted"
+                    held, in_order = held + len(tiles), in_order + n
                 if self.windows[g] is None:
                     full += len(run.blocks)
-                    if G > 1:
-                        tiles = [run.blocks[i:i + G]
-                                 for i in range(0, len(run.blocks), G)]
-                        n = sum(t == list(range(t[0], t[0] + G)) for t in tiles)
-                        assert n == run.tiles_run, f"{seq_id}: runs miscounted"
-                        held, in_order = held + len(tiles), in_order + n
                 else:
                     window += len(run.blocks)
                     given_back += run.given_back
@@ -591,7 +637,7 @@ class PagedKVAllocator:
             assert base % G == 0 and base < lo < hi <= base + G, (
                 f"earmark {base}: {lo}..{hi}")
             assert run.blocks[-(lo - base):] == list(range(base, lo)) and (
-                len(run.blocks) % G == lo - base), (
+                run.end % G == lo - base), (
                 f"earmark {base} does not continue its owner's table")
             assert any(run is r for runs in self._owned.values() for r in runs)
             free.update(range(lo, hi))

@@ -372,26 +372,35 @@ def _operands(call):
     return call[call.index("operand_layout_constraints="):call.index("frontend_attributes=")]
 
 
-@pytest.mark.parametrize("H,Hkv,BS,slots,chunk,MB,arena,attend,copy", [
-    (8, 2, 64, 48, 208, 256, (20, 4000), 4, 8),          # 256 keys an update, 512 a copy
-    (16, 16, 16, 128, 64, 256, (16, 4097), 8, 8),        # 128 keys: 1 MiB already
-    (28, 4, 16, 32, 224, 1024, (2, 57344), 8, 32),       # 128 and 512
-], ids=["zaya", "olmoe", "smallthinker-full"])
+@pytest.mark.parametrize("H,Hkv,BS,slots,chunk,MB,arena,attend,copy,window", [
+    (8, 2, 64, 48, 208, 256, (20, 4000), 4, 8, None),    # 256 keys an update, 512 a copy
+    (16, 16, 16, 128, 64, 256, (16, 4097), 8, 8, None),  # 128 keys: 1 MiB already
+    (28, 4, 16, 32, 224, 1024, (2, 57344), 8, 32, None),     # 128 and 512
+    (28, 4, 16, 32, 224, 320, (2, 57344), 8, 32, 4096),      # a ring of 10 runs
+    (48, 8, 16, 32, 512, 2400, (2, 49152), 8, 16, None),     # 128 and 256: 1 MiB
+    (48, 8, 16, 32, 512, 304, (2, 49152), 8, 16, 4096),      # a ring of 19 runs
+], ids=["zaya", "olmoe", "smallthinker-full", "smallthinker-window",
+        "trinity-full", "trinity-window"])
 def test_paged_gqa_kernel_compiles_with_run_flags(chip, H, Hkv, BS, slots, chunk, MB,
-                                                  arena, attend, copy):
-    """The full group of each cell ``paged_gqa_attention`` serves, as its step
-    calls it since PR 44: beside its row's table and the next row's, their
-    flags (a word a tile of ``copy`` pages) as SMEM blocks, and the arenas
-    viewed ``[layers, pages * BS, lanes]`` (a bitcast: no copy of them is made
-    for the kernel), so that a tile of pages that lie together is one DMA an
-    operand; the attend keeps the tile of a call without flags."""
+                                                  arena, attend, copy, window):
+    """Each group of each cell ``paged_gqa_attention`` serves, as its step
+    calls it: beside its row's table and the next row's, their flags (a word
+    a tile of ``copy`` pages) as SMEM blocks, and the arenas viewed ``[layers,
+    pages * BS, lanes]`` (a bitcast: no copy of them is made for the kernel),
+    so that a tile of pages that lie together is one DMA an operand; the
+    attend keeps the tile of a call without flags.  A window group's ring is
+    as wide as the allocator makes it, a whole number of runs, and its kernel
+    reads the flag of the ring tile a logical tile lies in (a dynamic index
+    into the SMEM block, where a full group's is the loop's counter)."""
+    from deepspeed_tpu.serving.kv_cache import window_table_blocks
     D128, rows, (L, NB) = 128, slots + chunk, arena
-    plan = da.softmax_plan(H, Hkv, D128, BS, MB, chunk, BF16)
+    plan = da.softmax_plan(H, Hkv, D128, BS, MB, chunk, BF16, window=window)
     assert plan.tile_pages == attend
     assert plan.run_pages == copy
+    assert window is None or MB == window_table_blocks(window, chunk, BS, copy)
     tiles = MB // copy
     fn = lambda q, k, v, layer, tables, lengths: da.paged_layer_attention(
-        q, k, v, layer, tables, lengths, chunk=chunk,
+        q, k, v, layer, tables, lengths, chunk=chunk, window=window,
         tile_runs=plan.tile_runs(tables, NB))
     pages = ((L, NB, BS, Hkv * D128), BF16)
     text = _compiled_text(chip, fn, ((rows, 1, H, D128), BF16), pages, pages,
@@ -580,7 +589,7 @@ def _step_text(chip, cell, periods=1, blocks=1025):
     """(config, compiled text) of the whole step of a serve configuration
     at ``periods`` periods of its layers, over an arena of ``blocks``."""
     from deepspeed_tpu.models import gpt
-    from deepspeed_tpu.serving.kv_cache import init_arena, window_table_blocks
+    from deepspeed_tpu.serving.kv_cache import init_arena
     make, slots, chunk, positions, kernel, Sq = SERVE_CELLS[cell]
     cfg = make(gpt)
     cfg = dataclasses.replace(cfg, n_layer=cfg.n_layer * periods)
@@ -592,8 +601,8 @@ def _step_text(chip, cell, periods=1, blocks=1025):
                         else p.dtype),
         jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
     kp, vp = on_chip(jax.eval_shape(lambda: init_arena(cfg, blocks, BS, dtype=BF16)))
-    widths = [positions // BS if kind.window is None
-              else window_table_blocks(kind.window, chunk, BS) for kind in cfg.pattern]
+    # as ``init_serving`` has them: a window group's ring a whole number of runs
+    _, widths, _ = cfg.paged_layout(BS, positions // BS, chunk, BF16)
     tables = tuple(shape((rows, w), jnp.int32) for w in widths)
     coords = tuple(shape((rows, 1), jnp.int32) for _ in widths)
     step = lambda *a: model.paged_step(*a, chunk=chunk)
@@ -661,29 +670,32 @@ def test_the_mistral_step_hands_the_latent_kernel_its_run_flags(chip, periods):
     assert not [line for line in made if line in body and " fusion(" in line]
 
 
-@pytest.mark.parametrize("cell,tiles,windows", [
-    ("olmoe-1b-7b", 32, 0), ("smallthinker-21b-a3b", 32, 3)])
-def test_the_step_hands_a_full_group_its_run_flags(chip, cell, tiles, windows):
-    """The whole step of the two ``gpt_paged_step`` models whose full group
-    ``paged_gqa_attention`` serves: its two calls (decode rows, packed chunk)
-    take the flags of their rows' tables beside them and the arena viewed
-    ``[layers, pages * 16, lanes]``; a window group's calls take the tables
-    alone and the arena with its pages a dimension, as they always did.  The
-    flags are made once, outside the loop over layers."""
+@pytest.mark.parametrize("cell,tiles,windows,ring", [
+    ("olmoe-1b-7b", 32, 0, 0), ("smallthinker-21b-a3b", 32, 3, 320)])
+def test_the_step_hands_every_group_its_run_flags(chip, cell, tiles, windows, ring):
+    """The whole step of the two ``gpt_paged_step`` models that
+    ``paged_gqa_attention`` serves: a group's two calls (decode rows, packed
+    chunk) take the flags of their rows' tables beside them and the arena
+    viewed ``[layers, pages * 16, lanes]``, a window group's over its ring
+    (SmallThinker: 10 runs of 32 pages) as the full group's over its table;
+    no call is left that copies page by page.  The flags are made once,
+    outside the loop over layers."""
     _, slots, chunk, positions, kernel, Sq = SERVE_CELLS[cell]
     cfg, text = _step_text(chip, cell, periods=2)
     MB, lanes = positions // 16, cfg.kv_heads * cfg.head_dim
     groups, pages = len(cfg.pattern), 1025 * len(cfg.pattern)
     flagged = [c for c in _gqa_calls(text) if f"bf16[2,{pages * 16},{lanes}]" in _operands(c)]
     plain = [c for c in _gqa_calls(text) if f"bf16[2,{pages},16,{lanes}]" in _operands(c)]
-    assert len(flagged) == 2 and len(plain) == 2 * windows == 2 * (groups - 1)
-    for call in flagged:
-        operands = _operands(call)
-        n = slots if f"s32[{slots},1,{MB}]" in operands else chunk // Sq
-        assert operands.count(f"s32[{n},1,{MB}]") == 2
-        assert operands.count(f"s32[{n},1,{tiles}]") == 2
-    for call in plain:
-        assert f",1,{tiles}]" not in _operands(call)
+    assert len(flagged) == 2 * groups == 2 * (1 + windows) and not plain
+    full = [c for c in flagged if f",1,{MB}]" in _operands(c)]
+    assert len(full) == 2
+    rings = [c for c in flagged if c not in full]
+    for width, flags, calls in ((MB, tiles, full), (ring, ring // 32, rings)):
+        for call in calls:
+            operands = _operands(call)
+            n = slots if f"s32[{slots},1,{width}]" in operands else chunk // Sq
+            assert operands.count(f"s32[{n},1,{width}]") == 2
+            assert operands.count(f"s32[{n},1,{flags}]") == 2
     rows = slots + chunk
     made = [line for line in text.splitlines()
             if f"s32[{rows},{tiles},{MB // tiles}]" in line and " = " in line
@@ -912,7 +924,7 @@ def test_the_trinity_step_reads_its_bank_behind_a_dense_lead(chip):
     block's tail (all rows; the decode rows alone), and the program makes no
     array of one layer's bank."""
     from deepspeed_tpu.models import gpt
-    from deepspeed_tpu.serving.kv_cache import init_arena, window_table_blocks
+    from deepspeed_tpu.serving.kv_cache import init_arena
     slots, chunk, BS, blocks = 32, 512, 16, 1025
     cfg = gpt.trinity_config(n_layer=4, dense_layers=1, vocab_size=25024,
                              vocab_multiple=64, experts_held=(0, 16), dtype=BF16)
@@ -922,9 +934,10 @@ def test_the_trinity_step_reads_its_bank_behind_a_dense_lead(chip):
     params = jax.tree.map(lambda p: shape(p.shape, BF16),
                           jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
     kp, vp = on_chip(jax.eval_shape(lambda: init_arena(cfg, blocks, BS, dtype=BF16)))
-    widths = [2400 if kind.window is None
-              else window_table_blocks(kind.window, chunk, BS) for kind in cfg.pattern]
-    assert widths == [289, 289, 289, 2400]
+    # a ring of 19 runs of 16 pages: the 289 the window and a chunk want and
+    # the 15 that share the run of the window's first page
+    _, widths, _ = cfg.paged_layout(BS, 2400, chunk, BF16)
+    assert widths == (304, 304, 304, 2400)
     tables = tuple(shape((rows, w), jnp.int32) for w in widths)
     coords = tuple(shape((rows, 1), jnp.int32) for _ in widths)
     step = lambda *a: model.paged_step(*a, chunk=chunk, with_expert_counts=True)

@@ -29,7 +29,7 @@ CELLS = {
         gpt.olmoe_config, 16, 256, 64, [(GQA, 8, 8, 64, 1)]),
     "smallthinker-21b-a3b.serve-long-context": (
         gpt.smallthinker_config, 16, 1024, 224,
-        [(GQA, 8, 32, 32, 1)] + 3 * [(GQA, 8, 0, 32, 1)]),
+        4 * [(GQA, 8, 32, 32, 1)]),      # the rings in the full group's runs
     "mistral-small-4-119b.serve-reasoning-batch": (
         gpt.mistral4_config, 16, 1024, 384, [(MLA, 32, 32, 16, 1)]),
     "minicpm-sala-9b.serve-long-mixed": (
@@ -50,8 +50,11 @@ def test_a_cells_plans_are_what_the_rules_gave(kernels, cell, device):
     else:
         kernels(PLAIN, GQA, MLA, SPARSE)
     cfg = preset()
-    plans = cfg.paged_plans(block, table_widths(cfg.page_groups, MB, chunk, block),
-                            chunk, jnp.bfloat16)
+    # as ``init_serving``: the tile off the plans at the widths of single
+    # blocks, the plans at the widths of that tile
+    run_blocks, widths, plans = cfg.paged_layout(block, MB, chunk, jnp.bfloat16)
+    assert run_blocks == max(1, *(run for _, _, run, _, _ in want))
+    assert widths == table_widths(cfg.page_groups, MB, chunk, block, run_blocks)
     assert [tuple(plan[:5]) for plan in plans] == want
 
 
@@ -88,7 +91,9 @@ def test_allocator_flags_and_copy_read_one_field_of_one_plan(
     try:
         plans = model.cfg.paged_plans(BS, eng.alloc.widths, SERVING["prefill_chunk"],
                                       eng._k_pages.dtype)
-        assert [plan.run_pages for plan in plans] == [runs] + [0] * (len(plans) - 1)
+        # every group of a model the same tile: they share their lanes
+        assert [plan.run_pages for plan in plans] == [runs] * len(plans)
+        assert all(w % max(1, runs) == 0 for w in eng.alloc.widths)
         assert eng.alloc.run_blocks == max(1, runs)
         assert eng.paged_tile_pages == plans[0].tile_pages > 0
         assert eng.chunk_queries_per_row == plans[0].chunk_queries

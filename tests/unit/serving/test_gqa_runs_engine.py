@@ -1,8 +1,9 @@
 """``init_serving`` tells the allocator a tile's size wherever the selected
-kernel fetches runs (``paged_gqa_attention`` over a group without a window,
-beside the latent kernel of ``test_mistral4.py``), the step hands the kernel
-the flags, the stat counts the tiles that are runs, and the tokens are those
-of the same engine under ``run_blocks = 1``."""
+kernel fetches runs (``paged_gqa_attention`` over a full group and over a
+window group's ring, beside the latent kernel of ``test_mistral4.py``), the
+step hands the kernel the flags, the stat counts the tiles that are runs in
+both kinds of group, and the tokens are those of the same engine under
+``run_blocks = 1``."""
 
 import numpy as np
 import pytest
@@ -123,3 +124,42 @@ def test_the_engine_lays_runs_and_serves_the_tokens_of_run_blocks_1(
     plain, plain_stats, run_blocks = served(model, params, prompts, new)
     assert run_blocks == 1 and all(s["tile_runs_pct"] == 0.0 for s in plain_stats)
     assert tokens == plain and [len(t) for t in tokens] == list(new)
+
+
+def test_a_full_and_three_window_groups_lay_runs_and_the_stat_counts_both_kinds(
+        kernels, small_tiles):
+    """The tiny SmallThinker, a full group beside three rings of 8 pages (two
+    runs of 4: the window of 16 keys, a chunk of 8 and the 3 pages that share
+    the window's run): every group's table grows in runs, the rings give runs
+    back, every call of the kernel takes flags, and ``tile_runs_pct`` is the
+    share of whole runs among the tiles of ALL FOUR tables of the live
+    sequences."""
+    model, params = built("smallthinker", seed=1)
+    kernels("paged_gqa_attention")
+    eng = engine(model, params)
+    try:
+        alloc, G = eng.alloc, eng.alloc.run_blocks
+        assert G == 4 and alloc.widths == (32, 8, 8, 8)
+        assert alloc._in_runs == (True,) * 4
+        futures = [eng.submit(_ids(n, seed=n), max_new_tokens=new)
+                   for n, new in ((45, 25), (13, 30), (70, 12))]
+        ring_tiles = ring_runs = 0
+        while not all(f.done for f in futures):
+            stats = eng.step()
+            alloc.check_consistent()
+            tiles = [[run.blocks[i:i + G] for i in range(0, len(run.blocks), G)]
+                     for runs in alloc._owned.values() for run in runs]
+            held = sum(map(len, tiles))
+            whole = sum(alloc._is_run(t) for table in tiles for t in table)
+            assert (alloc.tiles_held, alloc.tiles_run) == (held, whole)
+            assert stats["tile_runs_pct"] == pytest.approx(
+                100.0 * whole / held if held else 0.0)
+            rings = [t for runs in alloc._owned.values() for run in runs[1:]
+                     for t in (run.blocks[i:i + G] for i in range(0, len(run.blocks), G))]
+            ring_tiles += len(rings)
+            ring_runs += sum(map(alloc._is_run, rings))
+        assert ring_runs > 0.5 * ring_tiles > 0
+        assert alloc.given_back_ever > 0 and alloc.given_back_ever % G == 0
+        assert [len(f.result()) for f in futures] == [25, 30, 12]
+    finally:
+        eng.close()

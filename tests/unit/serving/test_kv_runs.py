@@ -1,7 +1,8 @@
-"""PagedKVAllocator with ``run_blocks > 1``: a full-attention table grows in
-runs of a kernel tile, what is not handed out yet is earmarked and free, and
-nothing is refused that the single-block allocator would grant.  Pure host
-logic, no jax."""
+"""PagedKVAllocator with ``run_blocks > 1``: a table grows in runs of a kernel
+tile, a window group's ring as a full group's table, and a ring gives a run
+back whole; what is not handed out yet is earmarked and free, and nothing is
+refused that the single-block allocator would grant of the same free pages.
+Pure host logic, no jax."""
 
 import numpy as np
 import pytest
@@ -147,26 +148,178 @@ def test_adopt_ref_unref_and_evict_deal_in_single_blocks():
     a.check_consistent()
 
 
-def test_a_window_ring_beside_a_group_in_runs_gives_back_and_takes_single_blocks():
-    bs, window, g = 4, 16, 4
-    a = PagedKVAllocator(2 * 64, bs, 32, windows=(None, window), chunk=8,
+def ring_allocator(g, pages=2 * 64, bs=4, window=16, chunk=8, max_blocks=32,
+                   groups=1):
+    return PagedKVAllocator(pages, bs, max_blocks,
+                            windows=(None,) + (window,) * groups, chunk=chunk,
+                            run_blocks=g)
+
+
+@pytest.mark.parametrize("bs,window,chunk,g,single,want", [
+    (16, 4096, 512, 16, 289, 304),       # Trinity: 19 runs of 16 pages
+    (16, 4096, 224, 32, 271, 320),       # SmallThinker: 10 runs of 32
+    (4, 16, 8, 4, 7, 12),
+    (4, 16, 8, 1, 7, 7),                 # run_blocks 1: the ring as it was
+])
+def test_a_ring_is_whole_runs_wide_and_holds_the_run_of_the_windows_first_page(
+        bs, window, chunk, g, single, want):
+    a = PagedKVAllocator(4096, bs, 4096, windows=(None, window), chunk=chunk,
                          run_blocks=g)
+    assert PagedKVAllocator(4096, bs, 4096, windows=(None, window),
+                            chunk=chunk).widths[1] == single
+    assert a.widths == (4096, want) and want % g == 0
+    # whatever ``start``: the run that holds the window's first block up to
+    # the chunk's last block is inside the ring
+    for start in range(0, 3 * want * bs, 7):
+        first = a.first_live_block(1, start)
+        assert first % g == 0
+        assert first <= max(0, start - window + 1) // bs < first + g
+        assert (start + chunk - 1) // bs - first < want
+
+
+def test_a_ring_cut_to_a_table_that_is_no_whole_runs_deals_in_single_blocks():
+    a = PagedKVAllocator(128, 4, 10, windows=(None, 64), chunk=8, run_blocks=4)
+    assert a.widths == (10, 10) and a._in_runs == (True, False)
+    for n in range(1, 40):
+        assert a.allocate("s", n, resident=n - 1)
+    assert is_aligned_run(tiles(a, "s", 0)[0], 4)
+    assert a.tiles_held == len(tiles(a, "s", 0))         # the full group's alone
+    a.check_consistent()
+
+
+@pytest.mark.parametrize("g", [4, 8])
+def test_every_whole_tile_of_a_ring_is_a_run_while_free_runs_last(g):
+    """Grown a token at a time with everything before the token resident,
+    twice round the ring: the ring starts on a run, every whole tile is an
+    aligned run, the run of the window's first block is still held, and the
+    edits add up to the table with logical tile ``t`` in ring tile ``t %
+    (width / g)``."""
+    bs, window = 4, 16
+    a = ring_allocator(g, pages=40 * g, max_blocks=64, groups=2)
     a.bind("s", 0)
     ring = a.widths[1]
-    for n in range(1, 100):
+    assert ring % g == 0 and a._in_runs == (True, True, True)
+    tables = [np.zeros(w, np.int32) for w in a.widths]
+    for n in range(1, 2 * ring * bs + 9):
         assert a.allocate("s", n, resident=n - 1)
         a.check_consistent()
-        assert len(a.owned_blocks("s", 1)) <= ring
-    assert a.given_back_ever > 0
-    full = tiles(a, "s", 0)
-    assert all(is_aligned_run(t, g) for t in full[:-1])
-    cleared, edits = a.drain_edits()
-    tables = [np.zeros(w, np.int32) for w in a.widths]
-    for grp, slot, col, b in edits:
-        assert slot == 0
-        tables[grp][col] = b
-    for grp in range(2):
-        np.testing.assert_array_equal(tables[grp], a.block_table("s", grp))
+        for grp in (1, 2):
+            run = a._owned["s"][grp]
+            assert run.first == a.first_live_block(grp, n - 1) and run.first % g == 0
+            assert run.first <= max(0, n - window) // bs and run.end * bs >= n
+            assert len(run.blocks) <= ring
+            *whole, last = tiles(a, "s", grp)
+            assert all(is_aligned_run(t, g) for t in whole)
+            assert last == list(range(last[0], last[0] + len(last)))
+        _, edits = a.drain_edits()
+        for grp, slot, col, b in edits:
+            assert slot == 0
+            tables[grp][col] = b
+        for grp in range(3):
+            np.testing.assert_array_equal(tables[grp], a.block_table("s", grp))
+        run = a._owned["s"][1]
+        for t in range(run.first // g, run.end // g):    # the whole tiles
+            at = t % (ring // g) * g
+            assert tables[1][at:at + g].tolist() == run.blocks[
+                t * g - run.first:(t + 1) * g - run.first]
+    assert a.given_back_ever > 0 and a.given_back_ever % g == 0
+    held = sum(len(tiles(a, "s", grp)) for grp in range(3))
+    assert a.tiles_held == held and a.tiles_run >= held - 3
+    assert a.pages_window == sum(len(a.owned_blocks("s", grp)) for grp in (1, 2))
+
+
+def test_a_run_given_back_whole_is_a_free_run_again():
+    g, bs = 4, 4
+    a = ring_allocator(g, pages=8 * g)
+    assert sorted(a._free_runs) == list(range(g, 8 * g, g))
+    for n in range(1, 16 + 3 * g * bs + 1):
+        assert a.allocate("s", n, resident=n - 1)
+        # a block leaves with its whole run or not at all: what is free is
+        # loose blocks that never made a run, whole runs and earmarks
+        assert not a._loose_in or set(a._loose_in) == {0}
+    assert a.given_back_ever == 3 * g
+    ring_runs = {t[0] for t in tiles(a, "s", 1)}
+    full_runs = {t[0] for t in tiles(a, "s", 0)}
+    assert set(a._free_runs) == set(range(g, 8 * g, g)) - ring_runs - full_runs
+    assert len(a._free_runs) == 7 - len(ring_runs) - len(full_runs)
+    a.check_consistent()
+    a.free("s")
+    assert sorted(a._free_runs) == list(range(g, 8 * g, g)) and not a._earmarks
+    assert (a.tiles_held, a.tiles_run, a.pages_window, a.given_back_total) == (0, 0, 0, 0)
+    a.check_consistent()
+
+
+def test_a_prompt_longer_than_the_ring_takes_the_ring_and_asks_again_a_chunk():
+    g, bs, chunk = 4, 4, 8
+    a = ring_allocator(g, pages=64 * g, max_blocks=64, chunk=chunk)
+    ring = a.widths[1]
+    prompt = 5 * ring * bs + 3
+    assert a.allocate("p", prompt)                       # admission: nothing resident
+    assert len(a.owned_blocks("p", 1)) == ring
+    assert all(is_aligned_run(t, g) for t in tiles(a, "p", 1))
+    for start in range(0, prompt, chunk):
+        assert a.allocate("p", prompt, resident=start)
+        lo, hi = max(0, start - 16 + 1) // bs, (min(start + chunk, prompt) - 1) // bs
+        run = a._owned["p"][1]
+        assert run.first <= lo and hi < run.end      # what the chunk reads and writes
+        a.write_map("p", start, min(chunk, prompt - start), 1)
+        a.check_consistent()
+    assert all(is_aligned_run(t, g) for t in tiles(a, "p", 1)[:-1])
+
+
+def ring_script(seed, steps=500, seqs=10):
+    rng = np.random.default_rng(seed)
+    for _ in range(steps):
+        yield (str(rng.choice(["grow", "grow", "grow", "chunk", "admit", "free",
+                               "evict"])),
+               int(rng.integers(seqs)), int(rng.integers(1, 120)))
+
+
+@pytest.mark.parametrize("seed", range(5))
+@pytest.mark.parametrize("g", [4, 8])
+def test_a_random_script_over_rings_stays_consistent_and_refuses_for_want_of_pages_alone(
+        g, seed):
+    """Random admit / grow / give back / free over a full group and two rings
+    in a tight arena: consistent after every call, a growth is refused only
+    when it is more than the free pages, earmarked ones and all (nothing
+    that ``run_blocks = 1`` would grant of the same count), a refusal changes
+    nothing it owns, and a sequence holds what ``pages_for_tokens`` says."""
+    bs, max_blocks = 2, 64
+    a = PagedKVAllocator(14 * g + 3, bs, max_blocks, windows=(None, 12, 12),
+                         chunk=6, run_blocks=g)
+    assert a._in_runs == (True, True, True)
+    size = {}                                            # seq -> (tokens, resident)
+    held = lambda s: sum(len(a.owned_blocks(s, grp)) for grp in range(3))
+    refused = 0
+    for kind, s, n in ring_script(seed):
+        if kind in ("free", "evict"):
+            (a.free if kind == "free" else a.evict)(s)
+            size.pop(s, None)
+        else:
+            tokens, resident = size.get(s, (0, 0))
+            if kind == "admit" and s not in size:
+                tokens, resident = n, 0
+            elif kind == "chunk":
+                resident = min(tokens, resident + 6)
+                tokens = max(tokens, min(resident + 6, max_blocks * bs))
+            else:
+                resident, tokens = tokens, min(tokens + 1, max_blocks * bs)
+            want = a.pages_for_tokens(tokens, resident)
+            room = held(s) + a.free_pages
+            before = [a.owned_blocks(s, grp)[-1:] for grp in range(3)]
+            ok = a.allocate(s, tokens, resident)
+            assert ok == (want <= room)
+            if ok:
+                size[s] = (tokens, resident)
+                assert held(s) == want
+            else:
+                refused += 1
+                assert before == [a.owned_blocks(s, grp)[-1:] for grp in range(3)]
+                if s not in size:
+                    assert s not in a._owned
+        a.check_consistent()
+        assert a.pages_full + a.pages_window == sum(held(q) for q in a._owned)
+    assert refused and a.given_back_ever
 
 
 def script(seed, steps=400, seqs=12, max_tokens=150):
