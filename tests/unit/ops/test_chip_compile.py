@@ -25,6 +25,7 @@ from deepspeed_tpu.ops.pallas import cross_entropy as ce
 from deepspeed_tpu.ops.pallas import decode_attention as da
 from deepspeed_tpu.ops.pallas import flash_attention as fa
 from deepspeed_tpu.ops.pallas import fused_optim as fo
+from tests.unit.serving_helpers import compiled_text
 
 BF16 = jnp.bfloat16
 # (heads, n_embd); D = 64 and vocab 50304 (50257 padded) for both
@@ -63,9 +64,52 @@ def on_the_chip(monkeypatch):
     monkeypatch.setattr(pallas, "interpret", lambda: False)
 
 
+def _compile(fn, *args, donate=()):
+    """THE ahead-of-time compile of this file: ``args`` are shapes on the
+    described chip."""
+    return jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+
+
 def _compiled_text(chip, fn, *shapes):
-    args = [jax.ShapeDtypeStruct(s, dt, sharding=chip) for s, dt in shapes]
-    return jax.jit(fn).lower(*args).compile().as_text()
+    return _compile(fn, *(jax.ShapeDtypeStruct(s, dt, sharding=chip)
+                          for s, dt in shapes)).as_text()
+
+
+def _step_program(chip, cfg, slots, chunk, BS, blocks, MB, counts=False, donate=False):
+    """The whole serve step of ``cfg`` compiled for the chip, as
+    ``init_serving`` builds it: ``slots`` decode rows and a chunk of ``chunk``
+    over an arena of ``blocks`` pages of ``BS`` tokens, bf16.  The periodic
+    walk takes a table a page group, ``MB`` blocks wide or a window group's
+    ring (a whole number of runs: ``cfg.paged_layout``); a hybrid stack takes
+    one table and what it caches beside K and V (``aux``), each row's slot and
+    whether it is live.  ``counts``: the step hands back its expert counts;
+    ``donate``: arena and ``aux`` are donated.  -> (compiled, arena's K, aux)."""
+    from deepspeed_tpu.models import gpt, hybrid
+    from deepspeed_tpu.serving.kv_cache import init_arena
+    model, rows = gpt.GPT(cfg), slots + chunk
+    shape = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=chip)
+    ints = lambda *s: shape(s, jnp.int32)
+    on_chip = lambda tree: jax.tree.map(lambda a: shape(a.shape, a.dtype), tree)
+    params = jax.tree.map(
+        lambda p: shape(p.shape, BF16 if jnp.issubdtype(p.dtype, jnp.floating)
+                        else p.dtype),
+        jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
+    kp, vp = on_chip(jax.eval_shape(lambda: init_arena(cfg, blocks, BS, dtype=BF16)))
+    kw = dict(chunk=chunk, **({"with_expert_counts": True} if counts else {}))
+    if cfg.hybrid:
+        aux = on_chip(jax.eval_shape(lambda: hybrid.init_aux(cfg, blocks, BS, slots, BF16)))
+        tables, coords = ints(rows, MB), ints(rows, 1)
+        more = (aux, ints(rows), shape((rows,), jnp.bool_))
+        step = lambda *a: model.paged_step(*a[:8], aux=a[8], slots=a[9], live=a[10], **kw)
+    else:
+        aux, more = None, ()
+        _, widths, _ = cfg.paged_layout(BS, MB, chunk, BF16)
+        tables = tuple(ints(rows, w) for w in widths)
+        coords = tuple(ints(rows, 1) for _ in widths)
+        step = lambda *a: model.paged_step(*a, **kw)
+    compiled = _compile(step, params, ints(rows, 1), ints(rows), kp, vp, tables, coords,
+                        ints(rows, 1), *more, donate=(3, 4, 8) if donate else ())
+    return compiled, kp, aux
 
 
 def _flash_fwd(H, E):
@@ -314,25 +358,12 @@ def test_the_hybrid_step_walks_its_runs_of_layers(chip):
     once for the 16 decode slots and once, under the branch a step without a
     prompt takes the other side of, for the chunk's 512 tokens (a row a
     K/V head each); and no gather of chosen keys into a dense array."""
-    from deepspeed_tpu.models import gpt, hybrid
-    from deepspeed_tpu.serving.kv_cache import init_arena
+    from deepspeed_tpu.models import gpt
     S, L = "minicpm4", "lightning-attn"
     cfg = gpt.minicpm_sala_config(mixer_types=[S, L, L, S, S], first_layer=9, dtype=BF16)
-    model, slots, chunk, BS, NB, MB = gpt.GPT(cfg), 16, 512, 64, 1025, 768
+    slots, chunk = 16, 512
     rows = slots + chunk
-    shape = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=chip)
-    on_chip = lambda tree: jax.tree.map(lambda a: shape(a.shape, a.dtype), tree)
-    params = jax.tree.map(lambda p: shape(p.shape, BF16),
-                          jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
-    kp, vp = on_chip(jax.eval_shape(lambda: init_arena(cfg, NB, BS, dtype=BF16)))
-    aux = on_chip(jax.eval_shape(lambda: hybrid.init_aux(cfg, NB, BS, slots, BF16)))
-    step = lambda p, ids, pos, kp, vp, tb, wb, wo, aux, sl, live: model.paged_step(
-        p, ids, pos, kp, vp, tb, wb, wo, chunk=chunk, aux=aux, slots=sl, live=live)
-    text = jax.jit(step).lower(
-        params, shape((rows, 1), jnp.int32), shape((rows,), jnp.int32), kp, vp,
-        shape((rows, MB), jnp.int32), shape((rows, 1), jnp.int32),
-        shape((rows, 1), jnp.int32), aux, shape((rows,), jnp.int32),
-        shape((rows,), jnp.bool_)).compile().as_text()
+    text = _step_program(chip, cfg, slots, chunk, 64, 1025, 768)[0].as_text()
     assert _kernel_rows(text, "paged_sparse_attention") == [
         2 * slots, 2 * slots, 2 * chunk, 2 * chunk]              # S, and S S
     assert text.count("conditional(") >= 2
@@ -426,30 +457,17 @@ def test_the_zaya_step_reads_its_bank_and_its_pages_where_they_lie(chip):
     leaves (no layer's bank sliced or copied out) at 256 assignments (two
     whole row tiles) and, under the branch a step without a prompt chunk
     takes, at the 48 decode rows alone (one tile)."""
-    from deepspeed_tpu.models import gpt, hybrid
+    from deepspeed_tpu.models import gpt
     from deepspeed_tpu.ops.pallas import grouped_matmul as gm
-    from deepspeed_tpu.serving.kv_cache import init_arena
     cfg = gpt.zaya_config(n_layer=2, dtype=BF16)
-    model, slots, chunk, BS, NB, MB = gpt.GPT(cfg), 48, 208, 64, 257, 256
+    slots, chunk, BS, NB, MB = 48, 208, 64, 257, 256
     rows = slots + chunk
     assert gm.kernel_shape_ok(rows, 2048, 4096, BF16) and gm.kernel_shape_ok(rows, 2048, 2048, BF16)
     assert gm.rows_to_whole_tiles(rows, 2048, BF16) == 0
     assert gm.rows_to_whole_tiles(slots, 2048, BF16) == 80        # 48 -> 128
-    shape = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=chip)
-    on_chip = lambda tree: jax.tree.map(lambda a: shape(a.shape, a.dtype), tree)
-    params = jax.tree.map(lambda p: shape(p.shape, BF16),
-                          jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
-    kp, vp = on_chip(jax.eval_shape(lambda: init_arena(cfg, NB, BS, dtype=BF16)))
-    aux = on_chip(jax.eval_shape(lambda: hybrid.init_aux(cfg, NB, BS, slots, BF16)))
+    compiled, _, aux = _step_program(chip, cfg, slots, chunk, BS, NB, MB, counts=True)
     assert aux["cca_state"].shape == (2, slots, 2 * 1280 + 128)
-    step = lambda p, ids, pos, kp, vp, tb, wb, wo, aux, sl, live: model.paged_step(
-        p, ids, pos, kp, vp, tb, wb, wo, chunk=chunk, aux=aux, slots=sl, live=live,
-        with_expert_counts=True)
-    text = jax.jit(step).lower(
-        params, shape((rows, 1), jnp.int32), shape((rows,), jnp.int32), kp, vp,
-        shape((rows, MB), jnp.int32), shape((rows, 1), jnp.int32),
-        shape((rows, 1), jnp.int32), aux, shape((rows,), jnp.int32),
-        shape((rows,), jnp.bool_)).compile().as_text()
+    text = compiled.as_text()
     assert _kernel_rows(text, "paged_gqa_attention") == [chunk // 104, slots]
     # each takes its rows' run flags (32 tiles of 8 pages) and the arena viewed
     # ``[layers, pages * 64, 256]``
@@ -535,8 +553,7 @@ def test_generate_keeps_its_cache_zero_filled(chip):
         lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=chip),
         jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
     ids = jax.ShapeDtypeStruct((1, prompt), jnp.int32, sharding=chip)
-    text = jax.jit(lambda p, i: model.generate(p, i, new)).lower(
-        params, ids).compile().as_text()
+    text = _compile(lambda p, i: model.generate(p, i, new), params, ids).as_text()
     cache = f"bf16[{cfg.n_layer},1,{prompt + new},{cfg.n_embd}]"
     assert cache in text
     assert not [line for line in text.splitlines()
@@ -585,30 +602,17 @@ SERVE_CELLS = {
 }
 
 
-def _step_text(chip, cell, periods=1, blocks=1025):
+def _step_text(chip, cell, periods=2, blocks=1025):
     """(config, compiled text) of the whole step of a serve configuration
-    at ``periods`` periods of its layers, over an arena of ``blocks``."""
+    at ``periods`` periods of its layers, over an arena of ``blocks``:
+    compiled once a (cell, periods, blocks) whoever reads it
+    (``serving_helpers.py:compiled_text``)."""
     from deepspeed_tpu.models import gpt
-    from deepspeed_tpu.serving.kv_cache import init_arena
     make, slots, chunk, positions, kernel, Sq = SERVE_CELLS[cell]
     cfg = make(gpt)
     cfg = dataclasses.replace(cfg, n_layer=cfg.n_layer * periods)
-    model, rows, BS = gpt.GPT(cfg), slots + chunk, 16
-    shape = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=chip)
-    on_chip = lambda tree: jax.tree.map(lambda a: shape(a.shape, a.dtype), tree)
-    params = jax.tree.map(
-        lambda p: shape(p.shape, BF16 if jnp.issubdtype(p.dtype, jnp.floating)
-                        else p.dtype),
-        jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
-    kp, vp = on_chip(jax.eval_shape(lambda: init_arena(cfg, blocks, BS, dtype=BF16)))
-    # as ``init_serving`` has them: a window group's ring a whole number of runs
-    _, widths, _ = cfg.paged_layout(BS, positions // BS, chunk, BF16)
-    tables = tuple(shape((rows, w), jnp.int32) for w in widths)
-    coords = tuple(shape((rows, 1), jnp.int32) for _ in widths)
-    step = lambda *a: model.paged_step(*a, chunk=chunk)
-    return cfg, jax.jit(step).lower(
-        params, shape((rows, 1), jnp.int32), shape((rows,), jnp.int32), kp, vp,
-        tables, coords, shape((rows, 1), jnp.int32)).compile().as_text()
+    return cfg, compiled_text(chip, (cell, periods, blocks), lambda: _step_program(
+        chip, cfg, slots, chunk, 16, blocks, positions // 16)[0].as_text())
 
 
 @pytest.mark.parametrize("cell", list(SERVE_CELLS))
@@ -616,7 +620,10 @@ def test_the_step_program_attends_the_chunk_packed(chip, cell):
     """The whole step of each serve configuration, compiled ahead of time:
     every layer kind holds its paged kernel at TWO shapes, the decode slots a
     query a row and the prompt chunk ``Sq > 1`` queries a row, and no
-    attention call runs ``slots + chunk`` rows."""
+    attention call runs ``slots + chunk`` rows.  (Read off the text at two
+    periods of layers, which ``..._reads_the_bank_in_place`` and the run
+    flags' tests compile anyway: the layer scan is a loop, so the rows a layer
+    kind are the one period's.)"""
     _, slots, chunk, _, kernel, Sq = SERVE_CELLS[cell]
     cfg, text = _step_text(chip, cell)
     assert chunk % Sq == 0 and Sq > 1
@@ -631,7 +638,7 @@ def test_the_olmoe_step_reads_its_pages_where_they_lie(chip):
     makes NO array of one layer's K or V (0.27 GB each, copied out in every
     layer of every step until PR 43: PERF.md § 6)."""
     _, slots, chunk, _, kernel, Sq = SERVE_CELLS["olmoe-1b-7b"]
-    cfg, text = _step_text(chip, "olmoe-1b-7b", periods=2, blocks=4097)
+    cfg, text = _step_text(chip, "olmoe-1b-7b", blocks=4097)
     assert cfg.n_layer == 2 and cfg.n_head == cfg.kv_heads == 16 and cfg.head_dim == 128
     assert _kernel_rows(text, kernel) == [chunk // Sq, slots]
     assert not _kernel_rows(text, "paged_attention")
@@ -681,7 +688,7 @@ def test_the_step_hands_every_group_its_run_flags(chip, cell, tiles, windows, ri
     no call is left that copies page by page.  The flags are made once,
     outside the loop over layers."""
     _, slots, chunk, positions, kernel, Sq = SERVE_CELLS[cell]
-    cfg, text = _step_text(chip, cell, periods=2)
+    cfg, text = _step_text(chip, cell)
     MB, lanes = positions // 16, cfg.kv_heads * cfg.head_dim
     groups, pages = len(cfg.pattern), 1025 * len(cfg.pattern)
     flagged = [c for c in _gqa_calls(text) if f"bf16[2,{pages * 16},{lanes}]" in _operands(c)]
@@ -718,7 +725,7 @@ def test_the_step_program_reads_the_bank_in_place(chip, cell):
     GPT-2 has no bank: no ``grouped_matmul``, and the same attention rows as
     at one period."""
     _, slots, chunk, _, kernel, Sq = SERVE_CELLS[cell]
-    cfg, text = _step_text(chip, cell, periods=2)
+    cfg, text = _step_text(chip, cell)
     assert _kernel_rows(text, kernel) == sorted(
         [slots, chunk // Sq] * len(cfg.pattern))
     calls = _bank_calls(text)
@@ -814,10 +821,11 @@ def test_the_delta_state_kernel_compiles_at_olmo_hybrid_states(chip):
     L, n, H, dk, dv = 12, 80, 30, 96, 192
     assert delta_rule.kernel_shape_ok(H, dk, dv, jnp.float32)
     f32, sd = jnp.float32, lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=chip)
-    compiled = jax.jit(delta_rule.delta_state_update, donate_argnums=0).lower(
+    compiled = _compile(
+        delta_rule.delta_state_update,
         sd((L, n, dk, H * dv), f32), sd((), jnp.int32), sd((n, H, dk), f32),
         sd((n, H, dk), f32), sd((n, H, dv), f32), sd((n, H), f32), sd((n, H), f32),
-        sd((n,), jnp.bool_)).compile()
+        sd((n,), jnp.bool_), donate=0)
     text, memory = compiled.as_text(), compiled.memory_analysis()
     assert _state_calls(text) == [(L, n)]                        # the stack, in place
     state = L * n * dk * H * dv * 4
@@ -833,27 +841,13 @@ def test_the_olmo_hybrid_step_updates_its_states_in_place(chip):
     copied out), the full layer's attention is the paged GQA kernel at two
     shapes, and the chunked form with its solve sits under the branch a step
     without a prompt chunk takes the other side of."""
-    from deepspeed_tpu.models import gpt, hybrid
-    from deepspeed_tpu.serving.kv_cache import init_arena
+    from deepspeed_tpu.models import gpt
     cfg = gpt.olmo_hybrid_config(
         layer_types=3 * ["linear_attention"] + ["full_attention"], dtype=BF16)
-    model, slots, chunk, BS, NB, MB = gpt.GPT(cfg), 80, 176, 16, 1025, 128
-    rows = slots + chunk
-    shape = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=chip)
-    on_chip = lambda tree: jax.tree.map(lambda a: shape(a.shape, a.dtype), tree)
-    params = jax.tree.map(lambda p: shape(p.shape, BF16),
-                          jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
-    kp, vp = on_chip(jax.eval_shape(lambda: init_arena(cfg, NB, BS, dtype=BF16)))
-    aux = on_chip(jax.eval_shape(lambda: hybrid.init_aux(cfg, NB, BS, slots, BF16)))
+    slots, chunk, BS, NB, MB = 80, 176, 16, 1025, 128
+    compiled, _, aux = _step_program(chip, cfg, slots, chunk, BS, NB, MB, donate=True)
     assert aux["delta_state"].shape == (3, slots, 96, 5760) and aux["delta_state"].dtype == jnp.float32
     assert aux["delta_conv"].shape == (3, slots, 3, 11520)
-    step = lambda p, ids, pos, kp, vp, tb, wb, wo, aux, sl, live: model.paged_step(
-        p, ids, pos, kp, vp, tb, wb, wo, chunk=chunk, aux=aux, slots=sl, live=live)
-    compiled = jax.jit(step, donate_argnums=(3, 4, 8)).lower(
-        params, shape((rows, 1), jnp.int32), shape((rows,), jnp.int32), kp, vp,
-        shape((rows, MB), jnp.int32), shape((rows, 1), jnp.int32),
-        shape((rows, 1), jnp.int32), aux, shape((rows,), jnp.int32),
-        shape((rows,), jnp.bool_)).compile()
     text = compiled.as_text()
     plan = da.softmax_plan(30, 30, 128, BS, MB, chunk, BF16)
     assert _kernel_rows(text, "paged_gqa_attention") == [chunk // plan.chunk_queries, slots]
@@ -886,25 +880,10 @@ def test_the_keye_vl2_step_selects_tokens_and_reads_its_bank_in_place(chip):
     matmuls over the stacked leaves, ONE sort in the mixer (the 8 decode
     rows' ``top_k``; the chunk's selection is a bisection), and no layer of K,
     V or index keys sliced out of its arena."""
-    from deepspeed_tpu.models import gpt, hybrid
+    from deepspeed_tpu.models import gpt
     from deepspeed_tpu.ops.pallas import indexed_attention as ia
-    from deepspeed_tpu.serving.kv_cache import init_arena
     cfg = gpt.keye_vl2_config(n_layer=2, dtype=BF16)
-    model, slots, chunk, BS, NB, MB = gpt.GPT(cfg), 8, 512, 64, 1025, 720
-    rows = slots + chunk
-    shape = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=chip)
-    on_chip = lambda tree: jax.tree.map(lambda a: shape(a.shape, a.dtype), tree)
-    params = jax.tree.map(lambda p: shape(p.shape, BF16),
-                          jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
-    kp, vp = on_chip(jax.eval_shape(lambda: init_arena(cfg, NB, BS, dtype=BF16)))
-    aux = on_chip(jax.eval_shape(lambda: hybrid.init_aux(cfg, NB, BS, slots, BF16)))
-    step = lambda p, ids, pos, kp, vp, tb, wb, wo, aux, sl, live: model.paged_step(
-        p, ids, pos, kp, vp, tb, wb, wo, chunk=chunk, aux=aux, slots=sl, live=live)
-    text = jax.jit(step).lower(
-        params, shape((rows, 1), jnp.int32), shape((rows,), jnp.int32), kp, vp,
-        shape((rows, MB), jnp.int32), shape((rows, 1), jnp.int32),
-        shape((rows, 1), jnp.int32), aux, shape((rows,), jnp.int32),
-        shape((rows,), jnp.bool_)).compile().as_text()
+    text = _step_program(chip, cfg, 8, 512, 64, 1025, 720)[0].as_text()
     assert ia.KERNEL in text and "grouped_matmul" in text
     assert text.count("conditional(") >= 2
     sorts = [l for l in text.splitlines() if " sort(" in l and "attn_indexed" in l]
@@ -924,26 +903,14 @@ def test_the_trinity_step_reads_its_bank_behind_a_dense_lead(chip):
     block's tail (all rows; the decode rows alone), and the program makes no
     array of one layer's bank."""
     from deepspeed_tpu.models import gpt
-    from deepspeed_tpu.serving.kv_cache import init_arena
     slots, chunk, BS, blocks = 32, 512, 16, 1025
     cfg = gpt.trinity_config(n_layer=4, dense_layers=1, vocab_size=25024,
                              vocab_multiple=64, experts_held=(0, 16), dtype=BF16)
-    model, rows = gpt.GPT(cfg), slots + chunk
-    shape = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=chip)
-    on_chip = lambda tree: jax.tree.map(lambda a: shape(a.shape, a.dtype), tree)
-    params = jax.tree.map(lambda p: shape(p.shape, BF16),
-                          jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
-    kp, vp = on_chip(jax.eval_shape(lambda: init_arena(cfg, blocks, BS, dtype=BF16)))
     # a ring of 19 runs of 16 pages: the 289 the window and a chunk want and
     # the 15 that share the run of the window's first page
-    _, widths, _ = cfg.paged_layout(BS, 2400, chunk, BF16)
-    assert widths == (304, 304, 304, 2400)
-    tables = tuple(shape((rows, w), jnp.int32) for w in widths)
-    coords = tuple(shape((rows, 1), jnp.int32) for _ in widths)
-    step = lambda *a: model.paged_step(*a, chunk=chunk, with_expert_counts=True)
-    text = jax.jit(step).lower(
-        params, shape((rows, 1), jnp.int32), shape((rows,), jnp.int32), kp, vp,
-        tables, coords, shape((rows, 1), jnp.int32)).compile().as_text()
+    assert cfg.paged_layout(BS, 2400, chunk, BF16)[1] == (304, 304, 304, 2400)
+    text = _step_program(chip, cfg, slots, chunk, BS, blocks, 2400,
+                         counts=True)[0].as_text()
     Sq = da.paged_chunk_queries(chunk, 6, 8, 128, 128, 128, BF16)
     assert Sq > 1 and _kernel_rows(text, "paged_gqa_attention") == sorted(
         [slots, chunk // Sq] * 4)
@@ -970,18 +937,20 @@ def test_the_selective_scan_kernels_compile_at_jamba2_states(chip):
     L, n, S, N, T = 26, 384, 16, 5120, 512
     assert ss.kernel_shape_ok(n, S, N, jnp.float32) and ss.kernel_shape_ok(T, S, N, jnp.float32)
     f32, sd = jnp.float32, lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=chip)
-    compiled = jax.jit(ss.mamba_state_update, donate_argnums=0).lower(
+    compiled = _compile(
+        ss.mamba_state_update,
         sd((L, n, S, N), f32), sd((), jnp.int32), sd((n, N), f32), sd((n, N), f32),
         sd((n, S), f32), sd((n, S), f32), sd((S, N), f32), sd((N,), f32),
-        sd((n,), jnp.bool_)).compile()
+        sd((n,), jnp.bool_), donate=0)
     text, memory = compiled.as_text(), compiled.memory_analysis()
     assert _mamba_state_calls(text) == [(L, n)]                  # the stack, in place
     state = L * n * S * N * 4
     assert memory.alias_size_in_bytes >= state and memory.temp_size_in_bytes < 64 << 20
     assert memory.argument_size_in_bytes - state < 32 << 20      # not a byte of padding
-    compiled = jax.jit(ss.mamba_chunk_scan).lower(
+    compiled = _compile(
+        ss.mamba_chunk_scan,
         sd((S, N), f32), sd((T, N), f32), sd((T, N), f32), sd((T, S), f32),
-        sd((T, S), f32), sd((S, N), f32), sd((N,), f32), sd((T,), jnp.bool_)).compile()
+        sd((T, S), f32), sd((S, N), f32), sd((N,), f32), sd((T,), jnp.bool_))
     text = compiled.as_text()
     assert "mamba_chunk_scan" in text and "tpu_custom_call" in text
     assert not re.search(rf"f32\[{T},({N},{S}|{S},{N})\]", text)
@@ -996,28 +965,15 @@ def test_the_jamba2_step_scans_its_states_in_place(chip):
     without a prompt chunk takes the other side of, the full layer's
     attention is the paged GQA kernel at a group of TWENTY query heads on one
     K/V head, and nothing of ``[tokens, 5120, 16]`` is made."""
-    from deepspeed_tpu.models import gpt, hybrid
-    from deepspeed_tpu.serving.kv_cache import init_arena
+    from deepspeed_tpu.models import gpt
     cfg = gpt.jamba_config(n_layer=4, attn_layer_period=4, attn_layer_offset=2, dtype=BF16)
     assert cfg.mixers == ("mamba", "mamba", "full", "mamba")
-    model, slots, chunk, BS, NB, MB = gpt.GPT(cfg), 384, 512, 16, 4097, 256
+    slots, chunk, BS, NB, MB = 384, 512, 16, 4097, 256
     rows = slots + chunk
-    shape = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=chip)
-    on_chip = lambda tree: jax.tree.map(lambda a: shape(a.shape, a.dtype), tree)
-    params = jax.tree.map(lambda p: shape(p.shape, BF16),
-                          jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
-    kp, vp = on_chip(jax.eval_shape(lambda: init_arena(cfg, NB, BS, dtype=BF16)))
+    compiled, kp, aux = _step_program(chip, cfg, slots, chunk, BS, NB, MB, donate=True)
     assert kp.shape == (1, NB, BS, 128)                          # ONE K/V head of 128
-    aux = on_chip(jax.eval_shape(lambda: hybrid.init_aux(cfg, NB, BS, slots, BF16)))
     assert aux["mamba_state"].shape == (3, slots, 16, 5120) and aux["mamba_state"].dtype == jnp.float32
     assert aux["mamba_conv"].shape == (3, slots, 3, 5120)
-    step = lambda p, ids, pos, kp, vp, tb, wb, wo, aux, sl, live: model.paged_step(
-        p, ids, pos, kp, vp, tb, wb, wo, chunk=chunk, aux=aux, slots=sl, live=live)
-    compiled = jax.jit(step, donate_argnums=(3, 4, 8)).lower(
-        params, shape((rows, 1), jnp.int32), shape((rows,), jnp.int32), kp, vp,
-        shape((rows, MB), jnp.int32), shape((rows, 1), jnp.int32),
-        shape((rows, 1), jnp.int32), aux, shape((rows,), jnp.int32),
-        shape((rows,), jnp.bool_)).compile()
     text = compiled.as_text()
     plan = da.softmax_plan(20, 1, 128, BS, MB, chunk, BF16)
     assert plan.kernel == "paged_gqa_attention" and plan.chunk_queries > 1

@@ -11,8 +11,8 @@ import pytest
 from deepspeed_tpu.models import gpt
 from deepspeed_tpu.ops.pallas import decode_attention as da
 from deepspeed_tpu.serving.kv_cache import table_widths
-from tests.unit.serving.test_gqa_runs_engine import (  # noqa: F401  (a fixture)
-    BS, SERVING, _ids, built, engine, small_tiles)
+from tests.unit.serving.test_gqa_runs_engine import BS, SERVING, _ids, built, engine
+from tests.unit.serving_helpers import small_tiles  # noqa: F401  (a fixture)
 
 PLAIN, GQA, MLA, SPARSE = ("paged_attention", "paged_gqa_attention",
                            "paged_mla_attention", "paged_sparse_attention")

@@ -165,12 +165,16 @@ class TestTieredStore:
     def test_get_not_blocked_by_write_backpressure(self, tmp_path,
                                                    monkeypatch):
         """put() blocked on the staging depth cap must not hold the store
-        lock — concurrent get() of a host-resident key stays fast."""
+        lock: a concurrent get() of a host-resident key returns WHILE the
+        saturating thread is still held at the cap (an order of events, not
+        a duration: the slow writes stay parked until the get is back)."""
         orig = StagingPool._do_write
+        writing, got = threading.Event(), threading.Event()
 
         def slow(self, key, array):
             if key.startswith("slow"):
-                time.sleep(0.5)
+                writing.set()
+                assert got.wait(30.0), "get() never came back"
             orig(self, key, array)
 
         monkeypatch.setattr(StagingPool, "_do_write", slow)
@@ -178,17 +182,23 @@ class TestTieredStore:
         store = TieredStore(pool)
         x = np.arange(4, dtype=np.float32)
         store.put("x", x)
+        blocked = threading.Event()
 
         def saturate():
             store.put("slow0", np.zeros((4,), np.float32))
+            blocked.set()
             store.put("slow1", np.zeros((4,), np.float32))  # blocks on cap
 
         t = threading.Thread(target=saturate)
         t.start()
+        # the first slow write is parked in the pool's one thread and the
+        # second put is on its way into the cap behind it
+        assert writing.wait(30.0) and blocked.wait(30.0)
         time.sleep(0.1)                    # let the thread hit the cap
-        t0 = time.perf_counter()
         np.testing.assert_array_equal(store.get("x"), x)
-        assert time.perf_counter() - t0 < 0.25
+        # the saturating thread was held for the whole of the get
+        assert t.is_alive() and not got.is_set()
+        got.set()
         t.join()
         pool.close()
 

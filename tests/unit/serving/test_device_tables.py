@@ -31,6 +31,8 @@ from deepspeed_tpu.serving import DeepSpeedServingConfig, ServingEngine
 from deepspeed_tpu.serving.engine import StepLayout, unpack_step
 from deepspeed_tpu.serving.scheduler import DECODE
 from deepspeed_tpu.testing import fault_injection
+from tests.unit.serving_helpers import (  # noqa: F401  (a fixture among them)
+    deadline_on_the_wedge_alone, sequential_tokens)
 
 V = 128
 SERVING = dict(block_size=8, num_blocks=64, max_batch_size=4, prefill_chunk=8,
@@ -352,7 +354,8 @@ def test_tokens_are_the_parents_token_for_token(models, name, num_blocks, at_onc
     eng.close()
 
 
-def test_a_wedged_step_empties_the_tables_and_the_stream_goes_on(models):
+def test_a_wedged_step_empties_the_tables_and_the_stream_goes_on(
+        models, deadline_on_the_wedge_alone):
     """Incident recovery re-jits the program and rebuilds the arena; the
     table state is rebuilt empty with them and every request recomputes
     through edits, token-identical."""
@@ -376,8 +379,7 @@ def test_a_wedged_step_empties_the_tables_and_the_stream_goes_on(models):
     while eng.sched.has_work:
         w.step()
     for p, f in zip(prompts, futs):
-        want = model.generate(params, np.asarray(p, np.int32)[None], 10)
-        assert f.token_ids == list(np.asarray(want)[0, len(p):])
+        assert f.token_ids == sequential_tokens(model, params, p, 10)
     assert w.reloads == 0
     eng.close()
 

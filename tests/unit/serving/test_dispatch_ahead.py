@@ -27,6 +27,8 @@ from deepspeed_tpu.serving.engine import (ServeStepTimeout, StepLayout,
 from deepspeed_tpu.serving.kv_cache import PagedKVAllocator
 from deepspeed_tpu.serving.scheduler import EXPIRED
 from deepspeed_tpu.testing import fault_injection
+from tests.unit.serving_helpers import (  # noqa: F401  (a fixture among them)
+    deadline_on_the_wedge_alone, idle, sequential_tokens, tiny_engine)
 
 V, SLOTS, CHUNK = 128, 3, 8
 SERVING = dict(block_size=8, num_blocks=96, max_batch_size=SLOTS,
@@ -69,6 +71,9 @@ def models():
 
 
 def engine(mp, **over):
+    """A NEW engine: the tests below read its counters as totals, count its
+    programs from zero, stand in its ``_dispatch`` and ``_drain``, wedge it
+    or close it with a row in flight."""
     model, params = mp
     return ServingEngine(model, config=DeepSpeedServingConfig(**dict(SERVING, **over)),
                          params=params)
@@ -80,18 +85,19 @@ def prompts_of(seed, lens):
 
 
 def alone(mp, prompt, new, **over):
-    """The request with the engine to itself: free slots, an empty queue.
-    The rule dispatches a step ahead only while prompt is left behind its
-    chunk; from the last chunk on every step is launch, fetch, commit."""
-    eng = engine(mp, **over)
+    """The request with the engine to itself: free slots, an empty queue,
+    which an idle engine IS (``tiny_engine``: the worker's engine of this
+    configuration, one compile for every request that asks).  The rule
+    dispatches a step ahead only while prompt is left behind its chunk; from
+    the last chunk on every step is launch, fetch, commit."""
+    eng = tiny_engine(*mp, **dict(SERVING, **over))
     fut = eng.submit(prompt, max_new_tokens=new)
     ahead = []
     while not fut.done:
         ahead.append(eng.step()["dispatched_ahead"])
     chunks = -(-len(prompt) // CHUNK)
     assert ahead[:chunks] == [0] + [1] * (chunks - 1) and not any(ahead[chunks:])
-    assert eng._flight is None
-    eng.close()
+    assert idle(eng)
     return fut.token_ids
 
 
@@ -119,8 +125,7 @@ def test_a_backlog_serves_every_request_the_tokens_it_gets_alone(models, family)
         assert f.done and len(f.token_ids) == new
         assert f.token_ids == alone(mp, prompt, new), (family, len(prompt), new)
         if family in HAS_GENERATE:
-            dense = model.generate(params, np.asarray(prompt, np.int32)[None], new)
-            assert f.token_ids == list(np.asarray(dense)[0, len(prompt):])
+            assert f.token_ids == sequential_tokens(model, params, prompt, new)
     ran = [s for s in stats if s["programs"]]
     ahead = sum(s["dispatched_ahead"] for s in ran)
     # seven requests on three slots: a queue until the last is admitted, and
@@ -327,7 +332,8 @@ def test_a_snapshot_lands_the_row_in_flight_and_restores_to_the_same_tokens(mode
     eng.close()
 
 
-def test_a_wedged_fetch_is_the_bounded_call_and_the_stream_goes_on(models):
+def test_a_wedged_fetch_is_the_bounded_call_and_the_stream_goes_on(
+        models, deadline_on_the_wedge_alone):
     """Under ``serve_step_timeout_s`` the fetch of a row is what the deadline
     bounds; the launch runs inline.  A wedged row takes the program launched
     behind it along: both are computed again, token for token."""

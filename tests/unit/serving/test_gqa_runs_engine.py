@@ -5,6 +5,8 @@ step hands the kernel the flags, the stat counts the tiles that are runs in
 both kinds of group, and the tokens are those of the same engine under
 ``run_blocks = 1``."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -14,6 +16,7 @@ import jax.numpy as jnp
 import deepspeed_tpu
 from deepspeed_tpu.models import gpt
 from deepspeed_tpu.ops.pallas import decode_attention as da
+from tests.unit.serving_helpers import small_tiles  # noqa: F401  (a fixture)
 
 V, BS = 512, 8
 SERVING = dict(block_size=BS, num_blocks=64, max_batch_size=3, prefill_chunk=8,
@@ -52,21 +55,15 @@ MODELS = {
 }
 
 
-@pytest.fixture
-def small_tiles(monkeypatch):
-    """The rule's constants lowered for the size of a test: an attend step of
-    2 pages (16 keys), a copy's tile of 4 (32 keys)."""
-    monkeypatch.setattr(da, "_TILE_ROWS", 16)
-    monkeypatch.setattr(da, "_TILE_PAGES", 2)
-    monkeypatch.setattr(da, "_RUN_TILE_ROWS", 32)
-
-
+@functools.lru_cache(maxsize=None)
 def built(name, seed=0):
     model = gpt.GPT(MODELS[name]())
     return model, model.init_params(jax.random.PRNGKey(seed))
 
 
 def engine(model, params):
+    """A NEW engine: every test here (and ``test_paged_plans.py``) reads where
+    a new allocator lays its runs, under constants ``small_tiles`` lowered."""
     return deepspeed_tpu.init_serving(model=model, params=params,
                                       config={"serving": SERVING})
 

@@ -23,6 +23,7 @@ from deepspeed_tpu.ops.pallas import decode_attention as da
 from deepspeed_tpu.serving import DeepSpeedServingConfig, ServingEngine
 from deepspeed_tpu.serving.engine import unpack_step
 from deepspeed_tpu.telemetry.tracing import Tracer
+from tests.unit.serving_helpers import sequential_tokens, tiny_engine
 
 MODELS = {
     "learned": {},
@@ -39,18 +40,23 @@ def model_and_params(request):
     return model, model.init_params(jax.random.PRNGKey(3))
 
 
-def reference(model, params, prompt, n_new):
-    out = model.generate(params, np.asarray(prompt, np.int32)[None], n_new)
-    return list(np.asarray(out)[0, len(prompt):])
+# sequential ``generate()``, one program a model (``serving_helpers.py``)
+reference = sequential_tokens
+SERVING = dict(block_size=8, num_blocks=64, max_batch_size=SLOTS,
+               prefill_chunk=CHUNK, dtype="float32")
 
 
 def engine(model_and_params, tracer=None, **over):
+    """A NEW engine: for a prefix cache's pins, counters read as totals, a
+    snapshot taken mid-flight, a tracer, a stand-in for ``_dispatch``."""
     model, params = model_and_params
-    cfg = dict(block_size=8, num_blocks=64, max_batch_size=SLOTS,
-               prefill_chunk=CHUNK, dtype="float32")
-    cfg.update(over)
-    return ServingEngine(model, config=DeepSpeedServingConfig(**cfg), params=params,
-                         tracer=tracer)
+    return ServingEngine(model, config=DeepSpeedServingConfig(**dict(SERVING, **over)),
+                         params=params, tracer=tracer)
+
+
+def kept_engine(model_and_params, **over):
+    """The worker's engine of this configuration, idle (``tiny_engine``)."""
+    return tiny_engine(*model_and_params, **dict(SERVING, **over))
 
 
 def prompts_of(seed, lens):
@@ -62,7 +68,7 @@ def prompts_of(seed, lens):
 def multi_chunk_arrival(mp):
     """A prompt of four chunks arrives while two requests decode: each of its
     chunks shares a program with their rows."""
-    eng = engine(mp)
+    eng = kept_engine(mp)
     prompts, new = prompts_of(0, (5, 9, 29, 3)), (14, 12, 6, 9)
     futs = [eng.submit(p, max_new_tokens=m) for p, m in zip(prompts[:2], new)]
     for _ in range(4):
@@ -121,7 +127,7 @@ def snapshot_restore(mp):
     snap = json.loads(json.dumps(old.snapshot()))
     assert states() == [(7, 3), (16, 0)]
     old.close()
-    eng = engine(mp, max_batch_size=2)
+    eng = kept_engine(mp, max_batch_size=2)
     futs = eng.restore(snap)
     eng.run()
     return eng, prompts, new, futs
@@ -138,7 +144,8 @@ def test_mixed_traffic_is_token_identical_in_one_program(model_and_params, traff
         assert f.done and f.token_ids == reference(model, params, p, m)
     assert eng.compiled_programs() == 1
     eng.alloc.check_consistent()
-    eng.close()
+    if traffic in (prefix_hit, preempt_recompute):
+        eng.close()                 # their own; the others' is the worker's
 
 
 def test_a_host_that_polls_for_the_token_row_serves_the_same_tokens(model_and_params):
@@ -146,7 +153,7 @@ def test_a_host_that_polls_for_the_token_row_serves_the_same_tokens(model_and_pa
     and then reads it; the tokens, the stamps and the one program are those
     of a host that sleeps on it."""
     model, params = model_and_params
-    eng = engine(model_and_params, poll_token_row=True)
+    eng = kept_engine(model_and_params, poll_token_row=True)
     prompts, new = prompts_of(9, (5, 19, 8)), (10, 6, 12)
     futs = [eng.submit(p, max_new_tokens=m) for p, m in zip(prompts, new)]
     waits = []
@@ -160,7 +167,6 @@ def test_a_host_that_polls_for_the_token_row_serves_the_same_tokens(model_and_pa
     # for no row, and the second waits for the first's)
     assert all(w is not None and w >= 0 for w in waits[1:]) and len(waits) > 8
     assert eng.compiled_programs() == 1
-    eng.close()
 
 
 # ---- the step itself ------------------------------------------------------------ #
@@ -258,7 +264,7 @@ def test_a_step_opens_at_most_one_dispatch_and_every_program_one_fetch(model_and
 def test_the_last_chunk_gives_the_first_token_and_decode_starts_a_step_later(
         model_and_params):
     model, params = model_and_params
-    eng = engine(model_and_params)
+    eng = kept_engine(model_and_params)
     prompt = prompts_of(9, (CHUNK + 3,))[0]
     r = eng.submit(prompt, max_new_tokens=4).request
     got = []
@@ -269,4 +275,3 @@ def test_the_last_chunk_gives_the_first_token_and_decode_starts_a_step_later(
     # (the first chunk stays in flight: there is prompt left behind it)
     assert got == [(CHUNK, 0), (n, 1), (n + 1, 2), (n + 2, 3), (n + 3, 4)]
     assert r.generated == reference(model, params, prompt, 4)
-    eng.close()

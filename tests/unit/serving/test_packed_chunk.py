@@ -12,6 +12,8 @@ gives these shapes (the whole chunk one row) and at half and a quarter of it
 (rows that are part live, rows that are all trash).
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -57,19 +59,30 @@ def highest():
         yield
 
 
+@functools.lru_cache(maxsize=None)
+def built(family):
+    """(model, seeded weights, its step with one row a token) of a family,
+    once a module: the reference program (``chunk=0``) packs nothing and asks
+    the rule nothing, so the three ``Sq`` of a family share its compile."""
+    model = GPT(FAMILIES[family]())
+    moe = {"with_expert_counts": True} if model.cfg.moe_num_experts else {}
+    return (model, model.init_params(jax.random.PRNGKey(7)), jax.jit(
+        lambda params, *a: model.paged_step(params, *a, chunk=0, **moe)[0]))
+
+
 class Both:
     """Stands in ``eng._dispatch``: before the step runs, the model over the
     step's inputs and arena with the chunk packed and with one row a token;
     keeps what kinds of step it saw."""
 
-    def __init__(self, eng, Sq):
+    def __init__(self, eng, Sq, a_row_a_token):
         self.eng, self.Sq, self._dispatch = eng, Sq, eng._dispatch
         self.unpack = jax.jit(unpack_step, static_argnums=0)
         moe = {"with_expert_counts": True} if eng._moe_experts else {}
-        step = lambda chunk: jax.jit(
-            lambda params, *a: eng.module.paged_step(params, *a, chunk=chunk,
-                                                     **moe)[0])
-        self.packed, self.a_row_a_token = step(CHUNK), step(0)
+        # packed under THIS case's rule: a new function, a new trace
+        self.packed = jax.jit(lambda params, *a: eng.module.paged_step(
+            params, *a, chunk=CHUNK, **moe)[0])
+        self.a_row_a_token = a_row_a_token
         self.seen, self.worst = set(), 0.0
         eng._dispatch = self
 
@@ -106,13 +119,13 @@ def test_packed_chunk_gives_the_logits_of_a_row_a_token(monkeypatch, family, Sq)
     29 tokens) and one of two (11) come in; then decode alone.  The rule is
     replaced for the smaller ``Sq`` only: the shapes here give the whole
     chunk."""
-    model = GPT(FAMILIES[family]())
-    params = model.init_params(jax.random.PRNGKey(7))
+    model, params, a_row_a_token = built(family)
     assert da.paged_chunk_queries(CHUNK, 1, 4, 128, 128, 128, jnp.float32) == CHUNK
     monkeypatch.setattr(da, "paged_chunk_queries", lambda *shape: Sq)
+    # an engine of its own: its program is traced under this case's rule
     eng = ServingEngine(model, config=DeepSpeedServingConfig(**SERVING),
                         params=params)
-    both = Both(eng, Sq)
+    both = Both(eng, Sq, a_row_a_token)
     rng = np.random.default_rng(5)
     prompts = [list(map(int, rng.integers(1, V, size=n))) for n in (5, 9, 29, 11)]
     futs = [eng.submit(p, max_new_tokens=m) for p, m in zip(prompts[:2], (30, 26))]

@@ -15,6 +15,7 @@ import jax
 from deepspeed_tpu.models.gpt import GPT, GPTConfig
 from deepspeed_tpu.serving import DeepSpeedServingConfig, ServingEngine
 from deepspeed_tpu.telemetry.hub import RingBufferSink, TelemetryHub
+from tests.unit.serving_helpers import sequential_tokens
 
 
 @pytest.fixture(scope="module")
@@ -26,9 +27,8 @@ def tiny_model():
     return model, params
 
 
-def sequential_reference(model, params, prompt, n_new):
-    out = model.generate(params, np.asarray(prompt, np.int32)[None], n_new)
-    return list(np.asarray(out)[0, len(prompt):])
+# sequential ``generate()``, one program a model (``serving_helpers.py``)
+sequential_reference = sequential_tokens
 
 
 def test_continuous_batching_token_identical(tiny_model):

@@ -28,6 +28,8 @@ from deepspeed_tpu.serving.scheduler import (
 from deepspeed_tpu.telemetry.hub import RingBufferSink, TelemetryHub
 from deepspeed_tpu.telemetry.ledger import GoodputLedger
 from deepspeed_tpu.testing import fault_injection as fi
+from tests.unit.serving_helpers import (  # noqa: F401  (a fixture among them)
+    deadline_on_the_wedge_alone, sequential_tokens)
 
 
 @pytest.fixture(scope="module")
@@ -45,9 +47,8 @@ def _clean_fault_plan():
     fi.clear_plan()
 
 
-def sequential_reference(model, params, prompt, n_new):
-    out = model.generate(params, np.asarray(prompt, np.int32)[None], n_new)
-    return list(np.asarray(out)[0, len(prompt):])
+# sequential ``generate()``, one program a model (``serving_helpers.py``)
+sequential_reference = sequential_tokens
 
 
 class FakeClock:
@@ -292,7 +293,7 @@ def test_shed_level_gauge_fed_via_metrics_sink(tiny_model):
 # wedge incidents
 # --------------------------------------------------------------------- #
 
-def test_wedged_step_recovers_token_identical(tiny_model):
+def test_wedged_step_recovers_token_identical(tiny_model, deadline_on_the_wedge_alone):
     model, params = tiny_model
     ring = RingBufferSink(capacity=2048)
     hub = TelemetryHub(sinks=[ring], flush_every=0)
@@ -343,7 +344,8 @@ def test_wedged_step_recovers_token_identical(tiny_model):
     eng.close()
 
 
-def test_result_tolerates_wedge_and_timeout_s_bounds_the_wait(tiny_model):
+def test_result_tolerates_wedge_and_timeout_s_bounds_the_wait(
+        tiny_model, deadline_on_the_wedge_alone):
     model, params = tiny_model
     scfg = DeepSpeedServingConfig(block_size=8, num_blocks=32,
                                   max_batch_size=2, prefill_chunk=8,

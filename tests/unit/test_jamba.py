@@ -8,6 +8,8 @@ the recurrence written out token by token; each part of the mixer left out
 FAILS the comparison; a slot reused and a request preempted; the published
 parameter count; and what ``init_serving`` and the dense paths refuse."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -20,6 +22,8 @@ from deepspeed_tpu.models import hybrid
 from deepspeed_tpu.models.gpt import GPT, jamba_config
 from deepspeed_tpu.ops.pallas import selective_scan
 from deepspeed_tpu.serving.kv_cache import init_arena
+from tests.unit import serving_helpers
+from tests.unit.serving_helpers import Driver, jitted, served_tokens
 
 # mamba x 2, full, mamba x 2; 20 queries on one K/V head
 WIDTHS = dict(vocab_size=512, n_positions=256, n_embd=64, n_layer=5, n_head=20,
@@ -73,68 +77,19 @@ def loud(tiny):
 def reference_logits(params, seq, **kw):
     ids = np.zeros(-(-len(seq) // 32) * 32, np.int32)
     ids[:len(seq)] = seq
-    return np.asarray(ref.jamba_logits(params, jnp.asarray(ids), **dict(REF, **kw)))[:len(seq)]
+    fn = jitted(ref.jamba_logits, **dict(REF, **kw))
+    return np.asarray(fn(params, jnp.asarray(ids)))[:len(seq)]
 
 
 def _ids(n, seed):
     return np.random.default_rng(seed).integers(0, 512, n).astype(np.int32)
 
 
-class Driver:
-    """``model.paged_step`` driven by hand, as the engine's step drives it:
-    ``slots`` decode rows and a prompt chunk of ``chunk`` rows; slot ``s``
-    owns the blocks ``1 + s * MB ..`` in logical order.  ``round_state``
-    rounds the mamba layers' state through that type after every step (a
-    planted lower precision); ``forget`` zeroes it before every chunk but a
-    sequence's first (a chunk that does not carry its state in)."""
-
-    def __init__(self, model, params, chunk=CHUNK, slots=SLOTS, round_state=None,
-                 forget=False):
-        cfg = model.cfg
-        self.chunk, self.slots = chunk, slots
-        self.round_state, self.forget = round_state, forget
-        self.kp, self.vp = init_arena(cfg, 1 + slots * MB, BS, jnp.float32)
-        self.aux = hybrid.init_aux(cfg, 1 + slots * MB, BS, slots, jnp.float32)
-        self.fn = jax.jit(lambda *a, **kw: model.paged_step(params, *a, chunk=chunk, **kw))
-
-    def step(self, decode=(), chunk=None):
-        """``decode``: (slot, token, position) a decode row; ``chunk``: (slot,
-        first position, tokens).  -> logits ``[slots + chunk, vocab]``."""
-        R = self.slots + self.chunk
-        ids, pos, slot = (np.zeros(R, np.int32) for _ in range(3))
-        live = np.zeros(R, bool)
-        for s, token, t in decode:
-            ids[s], pos[s], slot[s], live[s] = token, t, s, True
-        if chunk is not None:
-            s, start, tokens = chunk
-            at = slice(self.slots, self.slots + len(tokens))
-            ids[at], pos[at], slot[at], live[at] = tokens, start + np.arange(len(tokens)), s, True
-            if self.forget and start:
-                self.aux = dict(self.aux, mamba_state=jnp.zeros_like(self.aux["mamba_state"]))
-        tables = np.where(live[:, None], 1 + slot[:, None] * MB + np.arange(MB)[None], 0)
-        wb = np.where(live, tables[np.arange(R), pos // BS], 0)
-        wo = np.where(live, pos % BS, 0)
-        logits, self.kp, self.vp, self.aux = self.fn(
-            jnp.asarray(ids)[:, None], jnp.asarray(pos), self.kp, self.vp,
-            jnp.asarray(tables, jnp.int32), jnp.asarray(wb, jnp.int32)[:, None],
-            jnp.asarray(wo, jnp.int32)[:, None], aux=self.aux,
-            slots=jnp.asarray(slot), live=jnp.asarray(live))
-        if self.round_state is not None:
-            s = self.aux["mamba_state"]
-            self.aux = dict(self.aux, mamba_state=s.astype(self.round_state).astype(s.dtype))
-        return np.asarray(logits)[:, 0]
-
-    def sequence(self, seq, chunks, slot=0):
-        """Logits of every position of ``seq``: its prompt prefilled in
-        chunks of the lengths ``chunks``, the rest decoded a token a step."""
-        out, start = [], 0
-        for n in chunks:
-            rows = self.step(chunk=(slot, start, seq[start:start + n]))
-            out.append(rows[self.slots:self.slots + n])
-            start += n
-        for t in range(start, len(seq)):
-            out.append(self.step(decode=[(slot, seq[t], t)])[slot][None])
-        return np.concatenate(out)
+# ``round_through=`` rounds the mamba layers' state through that type after
+# every step; ``forget=STATE`` zeroes it before a chunk that is not the first
+STATE = ("mamba_state",)
+driver = functools.partial(Driver, slots=SLOTS, chunk=CHUNK, block_size=BS,
+                           blocks_a_slot=MB, leaves=STATE)
 
 
 # ---- the served logits against the reference's full forward pass ---------------- #
@@ -149,7 +104,7 @@ CHUNKS = {"whole": (8, 8, 4), "ragged": (5, 1, 1, 8, 3, 2), "single": (1,) * 6}
 def test_prefill_then_decode_agree_with_the_reference(tiny, loud, weights, chunks):
     model, params = tiny if weights == "seeded" else loud
     seq = _ids(44, seed=len(chunks))
-    got = Driver(model, params).sequence(seq, CHUNKS[chunks])
+    got = driver(model, params).sequence(seq, CHUNKS[chunks])
     want = reference_logits(params, seq)
     assert np.abs(got - want).max() < TOL
     assert np.abs(want).max() > 0.5
@@ -163,10 +118,10 @@ def test_a_part_left_out_fails_the_comparison(loud, part):
     model, params = loud
     seq = _ids(44, seed=3)
     if part == "carried_state":
-        got = Driver(model, params, forget=True).sequence(seq, CHUNKS["whole"])
+        got = driver(model, params, forget=STATE).sequence(seq, CHUNKS["whole"])
         want = reference_logits(params, seq)
     else:
-        got = Driver(model, params).sequence(seq, CHUNKS["whole"])
+        got = driver(model, params).sequence(seq, CHUNKS["whole"])
         assert np.abs(got - reference_logits(params, seq)).max() < TOL
         want = reference_logits(params, seq, parts=tuple(
             p for p in ref.ALL_PARTS if p != part))
@@ -178,7 +133,7 @@ def test_a_bf16_state_fails_the_tolerance(loud):
     through bf16 after every step."""
     model, params = loud
     seq = _ids(44, seed=5)
-    got = Driver(model, params, round_state=jnp.bfloat16).sequence(seq, CHUNKS["ragged"])
+    got = driver(model, params, round_through=jnp.bfloat16).sequence(seq, CHUNKS["ragged"])
     assert np.abs(got - reference_logits(params, seq)).max() > 20 * TOL
 
 
@@ -186,7 +141,7 @@ def test_bf16_weights_fail_the_tolerance(loud):
     model, params = loud
     seq = _ids(44, seed=5)
     rounded = jax.tree.map(lambda a: a.astype(jnp.bfloat16).astype(jnp.float32), params)
-    got = Driver(model, rounded).sequence(seq, CHUNKS["whole"])
+    got = driver(model, rounded).sequence(seq, CHUNKS["whole"])
     assert np.abs(got - reference_logits(params, seq)).max() > 50 * TOL
 
 
@@ -196,7 +151,7 @@ def test_a_step_with_decode_rows_and_a_chunk_together(loud):
     sequence's, and so is every slot's state."""
     model, params = loud
     a, b, c = _ids(60, 1), _ids(40, 2), _ids(40, 3)
-    d = Driver(model, params)
+    d = driver(model, params)
     d.sequence(a[:30], (8, 8, 8, 6), slot=0)
     d.sequence(b[:11], (8, 3), slot=1)
     got = {0: [], 1: [], 2: []}
@@ -303,7 +258,7 @@ def test_the_walk_runs_both_kernels_where_their_gates_admit(kernels):
     params = model.init_params(jax.random.PRNGKey(1))
     seq = _ids(24, seed=2)
     kernels("mamba_state_update", "mamba_chunk_scan")
-    d = Driver(model, params, slots=8)
+    d = driver(model, params, slots=8)
     text = str(jax.make_jaxpr(lambda *a, **kw: model.paged_step(params, *a, chunk=CHUNK, **kw))(
         jnp.zeros((16, 1), jnp.int32), jnp.zeros(16, jnp.int32), d.kp, d.vp,
         jnp.zeros((16, MB), jnp.int32), jnp.zeros((16, 1), jnp.int32),
@@ -322,9 +277,9 @@ def test_a_decode_rows_states_k_and_v_are_one_whole_sequence_pass(loud):
     rows left in the slot."""
     model, params = loud
     seq = _ids(40, seed=11)
-    steps = Driver(model, params)
+    steps = driver(model, params)
     steps.sequence(seq, (5,))                     # 5 prefilled, 35 decode rows
-    whole = Driver(model, params, chunk=40)
+    whole = driver(model, params, chunk=40)
     whole.sequence(seq, (40,))
     pages = slice(1, 1 + 3)                       # slot 0's first three blocks
     for a, b in ((steps.kp, whole.kp), (steps.vp, whole.vp)):
@@ -335,7 +290,7 @@ def test_a_decode_rows_states_k_and_v_are_one_whole_sequence_pass(loud):
         a, b = (np.asarray(d.aux[name][:, 0]) for d in (steps, whole))
         assert a.shape == shape and np.abs(a - b).max() < TOL and np.abs(b).max() > 0.01, name
     # the convolution's state is the last three input rows, the oldest first
-    before = Driver(model, params, chunk=40)
+    before = driver(model, params, chunk=40)
     before.sequence(seq[:39], (39,))
     conv = np.asarray(whole.aux["mamba_conv"][:, 0])
     assert np.abs(np.asarray(before.aux["mamba_conv"][:, 0, 1:]) - conv[:, :2]).max() < TOL
@@ -377,19 +332,10 @@ def test_the_leaves_are_two_stacks_and_the_states_a_slot(tiny):
 
 # ---- through the engine ---------------------------------------------------------------- #
 def served(model, params, prompts, new, **serving):
-    eng = deepspeed_tpu.init_serving(model=model, params=params,
-                                     config={"serving": dict(SERVING, **serving)})
-    try:
-        futures = [eng.submit(p, max_new_tokens=n) for p, n in zip(prompts, new)]
-        return [f.result() for f in futures], eng
-    finally:
-        eng.close()
+    return served_tokens(model, params, prompts, new, **dict(SERVING, **serving))
 
 
-def reference_tokens(params, prompt, tokens):
-    seq = np.concatenate([prompt, tokens]).astype(np.int32)
-    lg = reference_logits(params, seq)[len(prompt) - 1:len(seq) - 1]
-    return lg.argmax(-1).tolist(), float((lg.max(-1) - lg[np.arange(len(tokens)), tokens]).max())
+reference_tokens = functools.partial(serving_helpers.reference_tokens, reference_logits)
 
 
 def test_the_engine_serves_the_references_tokens_in_one_program(loud):
@@ -426,22 +372,13 @@ def test_a_preempted_request_resumes_to_the_same_tokens(loud):
     model, params = loud
     prompts = [_ids(n, seed=40 + n) for n in (70, 60, 50)]
     alone = [served(model, params, [p], (40,))[0][0] for p in prompts]
-    eng = deepspeed_tpu.init_serving(model=model, params=params, config={
-        "serving": dict(SERVING, num_blocks=17)})
-    futures = [eng.submit(p, max_new_tokens=40) for p in prompts]
-    reset = 0
-    while not all(f.done for f in futures):
-        st = eng.step()
-        eng.alloc.check_consistent()
-        reset += st.get("state_slots_reset", 0)
-        if st["programs"]:
-            assert st["mamba_state_moves"] == 4 * (st["decode_batch"] + (st["prefill_tokens"] > 0))
-            assert st["mamba_state_bytes"] == 4 * SLOTS * 16 * 128 * 4
-            assert st["mamba_conv_bytes"] == 4 * SLOTS * 3 * 128 * 4
-    assert st["preemptions"] >= 1
-    assert reset == 3 + st["preemptions"]       # a first chunk, and each again
-    assert [f.token_ids for f in futures] == alone
-    eng.close()
+
+    def moves(st):
+        assert st["mamba_state_moves"] == 4 * (st["decode_batch"] + (st["prefill_tokens"] > 0))
+        assert st["mamba_state_bytes"] == 4 * SLOTS * 16 * 128 * 4
+        assert st["mamba_conv_bytes"] == 4 * SLOTS * 3 * 128 * 4
+    assert serving_helpers.preempted(model, params, prompts, 40, moves,
+                                     **dict(SERVING, num_blocks=17)) == alone
 
 
 def test_a_snapshot_restores_by_recompute(loud):
@@ -450,16 +387,7 @@ def test_a_snapshot_restores_by_recompute(loud):
     model, params = loud
     p = _ids(45, seed=8)
     (whole, _) = served(model, params, [p], (30,))
-    eng = deepspeed_tpu.init_serving(model=model, params=params, config={"serving": SERVING})
-    f = eng.submit(p, max_new_tokens=30)
-    while len(f.token_ids) < 11:
-        eng.step()
-    snap = eng.snapshot()
-    eng.close()
-    eng = deepspeed_tpu.init_serving(model=model, params=params, config={"serving": SERVING})
-    (g,) = eng.restore(snap)
-    assert g.result() == whole[0]
-    eng.close()
+    assert serving_helpers.restored_tokens(model, params, p, 30, 11, **SERVING) == whole[0]
 
 
 # ---- the published parameter count ------------------------------------------------------ #
@@ -508,11 +436,6 @@ def test_init_serving_refuses_what_carries_no_state(tiny, knob, mechanism):
 @pytest.mark.parametrize("path", ["forward", "generate", "loss"])
 def test_the_dense_paths_refuse_the_stack_by_what_they_lack(tiny, path):
     model, params = tiny
-    ids = jnp.asarray(_ids(16, 0))[None]
-    call = {"forward": lambda: model.forward_logits(params, ids),
-            "generate": lambda: model.generate(params, ids, 4),
-            "loss": lambda: model(params, (ids, ids), None, False)}[path]
-    with pytest.raises(NotImplementedError) as e:
-        call()
-    assert "no selective scan (nor its backward)" in str(e.value)
-    assert "4 mamba layers" in str(e.value) and "init_serving()" in str(e.value)
+    said = serving_helpers.dense_path_refusal(model, params, path, _ids(16, 0))
+    assert "no selective scan (nor its backward)" in said
+    assert "4 mamba layers" in said and "init_serving()" in said

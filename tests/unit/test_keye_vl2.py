@@ -21,7 +21,8 @@ import deepspeed_tpu
 from benchmarks.lib import reference_keye_vl2 as ref
 from deepspeed_tpu.models import gpt, hybrid
 from deepspeed_tpu.models.gpt import GPT, keye_vl2_config, olmoe_config
-from deepspeed_tpu.serving.kv_cache import init_arena
+from tests.unit import serving_helpers
+from tests.unit.serving_helpers import Driver, served_tokens, tiny_engine
 
 TOPK = 24
 WIDTHS = dict(vocab_size=512, n_positions=256, n_embd=64, n_layer=3, n_head=4,
@@ -78,54 +79,10 @@ def _ids(n, seed):
     return np.random.default_rng(seed).integers(0, 512, n).astype(np.int32)
 
 
-class Driver:
-    """``model.paged_step`` driven by hand, as the engine's step drives it:
-    ``SLOTS`` decode rows and a prompt chunk of ``chunk`` rows; slot ``s``
-    owns the blocks ``1 + s * MB ..`` in logical order."""
-
-    programs = {}           # model -> its jitted step, compiled once a module
-
-    def __init__(self, model, params, chunk=CHUNK):
-        cfg = model.cfg
-        self.chunk = chunk
-        self.kp, self.vp = init_arena(cfg, 1 + SLOTS * MB, BS, jnp.float32)
-        self.aux = hybrid.init_aux(cfg, 1 + SLOTS * MB, BS, SLOTS, jnp.float32)
-        step = self.programs.setdefault((id(model), chunk), jax.jit(
-            lambda params, *a, **kw: model.paged_step(params, *a, chunk=chunk, **kw)))
-        self.fn = functools.partial(step, params)
-
-    def step(self, decode=(), chunk=None):
-        """``decode``: (slot, token, position) a decode row; ``chunk``: (slot,
-        first position, tokens).  -> logits ``[SLOTS + chunk, vocab]``."""
-        R = SLOTS + self.chunk
-        ids, pos, slot = (np.zeros(R, np.int32) for _ in range(3))
-        live = np.zeros(R, bool)
-        for s, token, t in decode:
-            ids[s], pos[s], slot[s], live[s] = token, t, s, True
-        if chunk is not None:
-            s, start, tokens = chunk
-            at = slice(SLOTS, SLOTS + len(tokens))
-            ids[at], pos[at], slot[at], live[at] = tokens, start + np.arange(len(tokens)), s, True
-        tables = np.where(live[:, None], 1 + slot[:, None] * MB + np.arange(MB)[None], 0)
-        wb = np.where(live, tables[np.arange(R), pos // BS], 0)
-        wo = np.where(live, pos % BS, 0)
-        logits, self.kp, self.vp, self.aux = self.fn(
-            jnp.asarray(ids)[:, None], jnp.asarray(pos), self.kp, self.vp,
-            jnp.asarray(tables, jnp.int32), jnp.asarray(wb, jnp.int32)[:, None],
-            jnp.asarray(wo, jnp.int32)[:, None], aux=self.aux,
-            slots=jnp.asarray(slot), live=jnp.asarray(live))
-        return np.asarray(logits)[:, 0]
-
-    def sequence(self, seq, chunks, slot=0):
-        """Logits of every position of ``seq``: its prompt prefilled in
-        chunks of the lengths ``chunks``, the rest decoded a token a step."""
-        out, start = [], 0
-        for n in chunks:
-            out.append(self.step(chunk=(slot, start, seq[start:start + n]))[SLOTS:SLOTS + n])
-            start += n
-        for t in range(start, len(seq)):
-            out.append(self.step(decode=[(slot, seq[t], t)])[slot][None])
-        return np.concatenate(out)
+# ``round_through=`` rounds the cached index keys through that type after
+# every step (a planted lower precision)
+driver = functools.partial(Driver, slots=SLOTS, chunk=CHUNK, block_size=BS,
+                           blocks_a_slot=MB, leaves=("ki",))
 
 
 # ---- (a) the served logits against the reference's full forward pass ---------- #
@@ -140,7 +97,7 @@ CHUNKS = {"under_topk": (8, 5), "over_topk": (8, 8, 8, 8), "ragged": (7, 5, 8, 3
 def test_prefill_then_decode_agree_with_the_reference(loud, chunks):
     model, params = loud
     seq = _ids(70, seed=len(chunks))
-    got = Driver(model, params).sequence(seq, CHUNKS[chunks])
+    got = driver(model, params).sequence(seq, CHUNKS[chunks])
     want = reference_logits(params, seq)
     assert np.abs(got - want).max() < TOL
     assert np.abs(want).max() > 0.1
@@ -153,14 +110,7 @@ def test_bf16_index_keys_fail_the_tolerance(loud):
     through bf16 swap chosen tokens."""
     model, params = loud
     seq = _ids(70, seed=5)
-    d = Driver(model, params)
-    step = d.step
-
-    def rounding(*a, **kw):
-        out = step(*a, **kw)
-        d.aux = dict(d.aux, ki=d.aux["ki"].astype(jnp.bfloat16).astype(jnp.float32))
-        return out
-    d.step = rounding
+    d = driver(model, params, round_through=jnp.bfloat16)
     assert np.abs(d.sequence(seq, CHUNKS["over_topk"]) - reference_logits(params, seq)).max() > 5 * TOL
 
 
@@ -170,7 +120,7 @@ def test_a_step_with_decode_rows_and_a_chunk_together(loud):
     sequence's."""
     model, params = loud
     a, b, c = _ids(60, 1), _ids(40, 2), _ids(40, 3)
-    d = Driver(model, params)
+    d = driver(model, params)
     d.sequence(a[:30], (8, 8, 8, 6), slot=0)
     d.sequence(b[:11], (8, 3), slot=1)
     got = {0: [], 1: [], 2: []}
@@ -192,11 +142,11 @@ def test_under_topk_keys_the_mixer_is_plain_causal_attention():
     ``topk`` no context reaches), whatever the indexer's weights are."""
     model, params = build(topk=128)
     seq = _ids(70, seed=9)
-    got = Driver(model, params).sequence(seq, CHUNKS["ragged"])
+    got = driver(model, params).sequence(seq, CHUNKS["ragged"])
     assert np.abs(got - reference_logits(params, seq, topk=10 ** 6)).max() < TOL
     ix = dict(params["blocks"]["indexed"])
     ix["index_w"] = ix["index_w"][:, ::-1] * 3.0
-    other = Driver(model, dict(params, blocks={"indexed": ix})).sequence(seq, CHUNKS["ragged"])
+    other = driver(model, dict(params, blocks={"indexed": ix})).sequence(seq, CHUNKS["ragged"])
     assert np.abs(got - other).max() < TOL
 
 
@@ -243,7 +193,7 @@ def test_a_slot_rebound_to_a_shorter_sequence_scores_no_former_tenant(loud):
     chosen, because a key after the query's position is never seen."""
     model, params = loud
     long, short = _ids(100, seed=5), _ids(40, seed=6)
-    d = Driver(model, params)
+    d = driver(model, params)
     d.sequence(long, (8,) * 12)
     d.aux = dict(d.aux, ki=d.aux["ki"] * 1e3)
     got = d.sequence(short, (8, 8, 8))
@@ -283,19 +233,10 @@ def test_the_softmax_router_routes_as_the_periodic_path_does():
 
 # ---- (f) the engine ------------------------------------------------------------------ #
 def served(model, params, prompts, new, **serving):
-    eng = deepspeed_tpu.init_serving(model=model, params=params,
-                                     config={"serving": dict(SERVING, **serving)})
-    futures = [eng.submit(p, max_new_tokens=n) for p, n in zip(prompts, new)]
-    out = [f.result() for f in futures]
-    eng.close()
-    return out, eng
+    return served_tokens(model, params, prompts, new, **dict(SERVING, **serving))
 
 
-def reference_tokens(params, prompt, generated):
-    seq = np.concatenate([prompt, generated]).astype(np.int32)
-    lg = reference_logits(params, seq)[len(prompt) - 1:len(seq) - 1]
-    best = lg.argmax(-1)
-    return best.tolist(), float((lg.max(-1) - lg[np.arange(len(best)), generated]).max())
+reference_tokens = functools.partial(serving_helpers.reference_tokens, reference_logits)
 
 
 def test_the_engine_serves_the_references_tokens_in_one_program(loud):
@@ -319,14 +260,13 @@ def test_a_slot_reused_by_the_engine_serves_what_an_engine_of_its_own_does(loud)
 
 def test_the_steps_stats_count_the_index_keys(loud):
     model, params = loud
-    eng = deepspeed_tpu.init_serving(model=model, params=params, config={"serving": SERVING})
+    eng = tiny_engine(model, params, **SERVING)
     f = eng.submit(_ids(40, 1), max_new_tokens=6)
     seen = []
     while not f.done:
         st = eng.step()
         if "index_keys_scored" in st:
             seen.append(st)
-    eng.close()
     assert seen and all(st["index_key_bytes"] == 3 * 64 * BS * 8 * 4 for st in seen)
     first, last = seen[0], seen[-1]
     # the first chunk: positions 0..7 in three layers; a decode row past topk
@@ -373,14 +313,9 @@ def test_init_serving_refuses_what_carries_no_index_keys(loud, knob, mechanism):
 @pytest.mark.parametrize("path", ["forward", "generate", "loss"])
 def test_the_dense_paths_refuse_the_stack_by_what_they_lack(loud, path):
     model, params = loud
-    ids = jnp.asarray(_ids(16, 0))[None]
-    call = {"forward": lambda: model.forward_logits(params, ids),
-            "generate": lambda: model.generate(params, ids, 4),
-            "loss": lambda: model(params, (ids, ids), None, False)}[path]
-    with pytest.raises(NotImplementedError) as e:
-        call()
-    assert "no lightning indexer, no cache of index keys" in str(e.value)
-    assert "3 indexed layers" in str(e.value) and "init_serving()" in str(e.value)
+    said = serving_helpers.dense_path_refusal(model, params, path, _ids(16, 0))
+    assert "no lightning indexer, no cache of index keys" in said
+    assert "3 indexed layers" in said and "init_serving()" in said
 
 
 @pytest.mark.parametrize("kw, said", [

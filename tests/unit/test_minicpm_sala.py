@@ -7,6 +7,7 @@ and a chunk together, a slot reused, a request preempted, the step's stats,
 and what ``init_serving`` and the dense paths refuse."""
 
 import dataclasses
+import functools
 
 import numpy as np
 import pytest
@@ -19,6 +20,8 @@ from benchmarks.lib import reference_minicpm_sala as ref
 from deepspeed_tpu.models import hybrid
 from deepspeed_tpu.models.gpt import GPT, minicpm_sala_config
 from deepspeed_tpu.serving.kv_cache import init_arena
+from tests.unit import serving_helpers
+from tests.unit.serving_helpers import Driver, jitted, served_tokens
 
 MIXERS = ["minicpm4", "lightning-attn", "lightning-attn", "minicpm4", "minicpm4",
           "lightning-attn"]
@@ -50,65 +53,26 @@ def tiny():
     return model, model.init_params(jax.random.PRNGKey(0))
 
 
-def reference_logits(params, seq, sparse=SPARSE):
+def reference_logits(params, seq):
     ids = np.zeros(-(-len(seq) // 32) * 32, np.int32)
     ids[:len(seq)] = seq
-    return np.asarray(ref.sala_logits(params, jnp.asarray(ids), sparse=sparse, **REF))[:len(seq)]
+    fn = jitted(ref.sala_logits, sparse=SPARSE, **REF)
+    return np.asarray(fn(params, jnp.asarray(ids)))[:len(seq)]
 
 
 def _ids(n, seed):
     return np.random.default_rng(seed).integers(0, 512, n).astype(np.int32)
 
 
-class Driver:
-    """``model.paged_step`` driven by hand, as the engine's step drives it:
-    ``SLOTS`` decode rows and a prompt chunk of ``CHUNK`` rows; slot ``s``
-    owns the blocks ``1 + s * MB ..`` in logical order.  ``state_dtype``
-    rounds the linear layers' states through that type after every step (a
-    planted lower precision)."""
+# ``round_through=`` rounds the linear layers' states through that type
+# after every step (a planted lower precision)
+driver = functools.partial(Driver, slots=SLOTS, chunk=CHUNK, block_size=BS,
+                           blocks_a_slot=MB, leaves=("state",))
 
-    def __init__(self, model, params, state_dtype=None):
-        cfg = model.cfg
-        self.kp, self.vp = init_arena(cfg, 1 + SLOTS * MB, BS, jnp.float32)
-        self.aux = hybrid.init_aux(cfg, 1 + SLOTS * MB, BS, SLOTS, jnp.float32)
-        self.state_dtype = state_dtype
-        self.fn = jax.jit(lambda *a, **kw: model.paged_step(params, *a, chunk=CHUNK, **kw))
 
-    def step(self, decode=(), chunk=None):
-        """``decode``: (slot, token, position) a decode row; ``chunk``: (slot,
-        first position, tokens).  -> logits ``[SLOTS + CHUNK, vocab]``."""
-        R = SLOTS + CHUNK
-        ids, pos, slot = (np.zeros(R, np.int32) for _ in range(3))
-        live = np.zeros(R, bool)
-        for s, token, t in decode:
-            ids[s], pos[s], slot[s], live[s] = token, t, s, True
-        if chunk is not None:
-            s, start, tokens = chunk
-            at = slice(SLOTS, SLOTS + len(tokens))
-            ids[at], pos[at], slot[at], live[at] = tokens, start + np.arange(len(tokens)), s, True
-        tables = np.where(live[:, None], 1 + slot[:, None] * MB + np.arange(MB)[None], 0)
-        wb = np.where(live, tables[np.arange(R), pos // BS], 0)
-        wo = np.where(live, pos % BS, 0)
-        logits, self.kp, self.vp, self.aux = self.fn(
-            jnp.asarray(ids)[:, None], jnp.asarray(pos), self.kp, self.vp,
-            jnp.asarray(tables, jnp.int32), jnp.asarray(wb, jnp.int32)[:, None],
-            jnp.asarray(wo, jnp.int32)[:, None], aux=self.aux,
-            slots=jnp.asarray(slot), live=jnp.asarray(live))
-        if self.state_dtype is not None:
-            self.aux = dict(self.aux, state=self.aux["state"].astype(
-                self.state_dtype).astype(jnp.float32))
-        return np.asarray(logits)[:, 0]
-
-    def sequence(self, seq, prompt, slot=0):
-        """Logits of every position of ``seq``: its first ``prompt`` tokens
-        prefilled in chunks, the rest decoded a token a step."""
-        out = []
-        for start in range(0, prompt, CHUNK):
-            tokens = seq[start:min(start + CHUNK, prompt)]
-            out.append(self.step(chunk=(slot, start, tokens))[SLOTS:SLOTS + len(tokens)])
-        for t in range(prompt, len(seq)):
-            out.append(self.step(decode=[(slot, seq[t], t)])[slot][None])
-        return np.concatenate(out)
+def chunks_of(prompt):
+    """A prompt of ``prompt`` tokens in whole chunks and what is left."""
+    return (CHUNK,) * (prompt // CHUNK) + ((prompt % CHUNK,) if prompt % CHUNK else ())
 
 
 # ---- the served logits against the reference's full forward pass --------------- #
@@ -121,7 +85,7 @@ def test_prefill_then_decode_agree_with_the_reference_on_both_sides_of_dense_len
     4 are attended)."""
     model, params = tiny
     seq = _ids(total, seed=total)
-    got = Driver(model, params).sequence(seq, prompt)
+    got = driver(model, params).sequence(seq, chunks_of(prompt))
     want = reference_logits(params, seq)[:, :512]
     assert np.abs(got[:, :512] - want).max() < TOL
     assert np.abs(want).max() > 0.1
@@ -132,7 +96,7 @@ def test_a_bf16_state_fails_the_tolerance(tiny):
     every step read 30 times the tolerance or more."""
     model, params = tiny
     seq = _ids(140, seed=140)
-    got = Driver(model, params, jnp.bfloat16).sequence(seq, 100)
+    got = driver(model, params, round_through=jnp.bfloat16).sequence(seq, chunks_of(100))
     assert np.abs(got[:, :512] - reference_logits(params, seq)[:, :512]).max() > 30 * TOL
 
 
@@ -142,9 +106,9 @@ def test_a_step_with_decode_rows_and_a_chunk_together(tiny):
     sequence's."""
     model, params = tiny
     a, b, c = _ids(120, 1), _ids(60, 2), _ids(96, 3)
-    d = Driver(model, params)
-    d.sequence(a[:80], 80, slot=0)
-    d.sequence(b[:24], 24, slot=1)
+    d = driver(model, params)
+    d.sequence(a[:80], chunks_of(80), slot=0)
+    d.sequence(b[:24], chunks_of(24), slot=1)
     got = {0: [], 1: [], 2: []}
     for i, start in enumerate(range(0, len(c), CHUNK)):
         rows = d.step(decode=[(0, a[80 + i], 80 + i), (1, b[24 + i], 24 + i)],
@@ -206,11 +170,11 @@ def test_a_selection_wide_enough_to_hold_every_block_equals_dense_attention(tiny
     seq = _ids(130, seed=9)
     wide = GPT(config(dict(SPARSE, topk=16)))
     dense = GPT(config(dict(SPARSE, dense_len=256)))
-    got = Driver(wide, params).sequence(seq, 90)
-    want = Driver(dense, params).sequence(seq, 90)
+    got = driver(wide, params).sequence(seq, chunks_of(90))
+    want = driver(dense, params).sequence(seq, chunks_of(90))
     assert np.abs(got - want).max() < 1e-5
     # and the narrow selection of the other tests is NOT dense attention
-    assert np.abs(Driver(model, params).sequence(seq, 90) - want).max() > 100 * TOL
+    assert np.abs(driver(model, params).sequence(seq, chunks_of(90)) - want).max() > 100 * TOL
 
 
 def test_keys_attended_follow_the_rows_lengths():
@@ -225,19 +189,10 @@ def test_keys_attended_follow_the_rows_lengths():
 
 # ---- through the engine ---------------------------------------------------------------- #
 def served(model, params, prompts, new, **serving):
-    eng = deepspeed_tpu.init_serving(model=model, params=params,
-                                     config={"serving": dict(SERVING, **serving)})
-    try:
-        futures = [eng.submit(p, max_new_tokens=n) for p, n in zip(prompts, new)]
-        return [f.result() for f in futures], eng
-    finally:
-        eng.close()
+    return served_tokens(model, params, prompts, new, **dict(SERVING, **serving))
 
 
-def reference_tokens(params, prompt, tokens):
-    seq = np.concatenate([prompt, tokens]).astype(np.int32)
-    lg = reference_logits(params, seq)[len(prompt) - 1:len(seq) - 1, :512]
-    return lg.argmax(-1).tolist(), float((lg.max(-1) - lg[np.arange(len(tokens)), tokens]).max())
+reference_tokens = functools.partial(serving_helpers.reference_tokens, reference_logits, vocab=512)
 
 
 def test_the_engine_serves_the_references_tokens_in_one_program(tiny):
@@ -284,18 +239,8 @@ def test_a_preempted_request_resumes_to_the_same_tokens(tiny):
     model, params = tiny
     prompts = [_ids(n, seed=40 + n) for n in (70, 60, 50)]
     alone = [served(model, params, [p], (40,))[0][0] for p in prompts]
-    eng = deepspeed_tpu.init_serving(model=model, params=params, config={
-        "serving": dict(SERVING, num_blocks=17)})
-    futures = [eng.submit(p, max_new_tokens=40) for p in prompts]
-    reset = 0
-    while not all(f.done for f in futures):
-        st = eng.step()
-        eng.alloc.check_consistent()
-        reset += st.get("state_slots_reset", 0)
-    assert st["preemptions"] >= 1
-    assert reset == 3 + st["preemptions"]       # a first chunk, and each again
-    assert [f.token_ids for f in futures] == alone
-    eng.close()
+    assert serving_helpers.preempted(model, params, prompts, 40,
+                                     **dict(SERVING, num_blocks=17)) == alone
 
 
 def test_the_steps_stats_are_what_the_rows_lengths_give(tiny):
@@ -334,16 +279,7 @@ def test_a_snapshot_restores_by_recompute(tiny):
     model, params = tiny
     p = _ids(80, seed=8)
     (whole, _) = served(model, params, [p], (30,))
-    eng = deepspeed_tpu.init_serving(model=model, params=params, config={"serving": SERVING})
-    f = eng.submit(p, max_new_tokens=30)
-    while len(f.token_ids) < 11:
-        eng.step()
-    snap = eng.snapshot()
-    eng.close()
-    eng = deepspeed_tpu.init_serving(model=model, params=params, config={"serving": SERVING})
-    (g,) = eng.restore(snap)
-    assert g.result() == whole[0]
-    eng.close()
+    assert serving_helpers.restored_tokens(model, params, p, 30, 11, **SERVING) == whole[0]
 
 
 # ---- what is refused, by the mechanism's name ------------------------------------------ #
@@ -371,14 +307,9 @@ def test_a_chunk_that_is_not_whole_strides_is_refused(tiny):
 @pytest.mark.parametrize("path", ["forward", "generate", "loss"])
 def test_the_dense_paths_refuse_the_stack_by_what_they_lack(tiny, path):
     model, params = tiny
-    ids = jnp.asarray(_ids(16, 0))[None]
-    call = {"forward": lambda: model.forward_logits(params, ids),
-            "generate": lambda: model.generate(params, ids, 4),
-            "loss": lambda: model(params, (ids, ids), None, False)}[path]
-    with pytest.raises(NotImplementedError) as e:
-        call()
-    assert "chunked linear-attention scan" in str(e.value)
-    assert "init_serving()" in str(e.value)
+    said = serving_helpers.dense_path_refusal(model, params, path, _ids(16, 0))
+    assert "chunked linear-attention scan" in said
+    assert "init_serving()" in said
 
 
 def test_the_leaves_are_stacked_by_kind_at_their_own_widths(tiny):

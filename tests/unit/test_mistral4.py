@@ -31,6 +31,7 @@ from benchmarks.lib import reference_mistral4 as ref
 from deepspeed_tpu.models import gpt
 from deepspeed_tpu.models.gpt import GPT, YarnRope, mistral4_config
 from tests.unit.paged_bank import PATHS, bank_in_place_equals_bank_sliced
+from tests.unit.serving_helpers import jitted, served_logits
 from deepspeed_tpu.moe import dropless
 from deepspeed_tpu.ops.pallas import decode_attention as da
 
@@ -88,6 +89,19 @@ def tiny():
 
 def _ids(n, seed=3):
     return jax.random.randint(jax.random.PRNGKey(seed), (n,), 0, V)
+
+
+def mistral4_logits(params, ids, **kw):
+    """The reference's forward pass, compiled once a set of its keywords."""
+    return jitted(ref.mistral4_logits, **kw)(params, ids)
+
+
+@pytest.fixture(scope="module")
+def want70(tiny):
+    """The reference's logits of the 70 tokens every dense-path case compares
+    against, once a module."""
+    with jax.default_matmul_precision("highest"):
+        return mistral4_logits(tiny[1], _ids(70), **REF)
 
 
 # what a wrong model is: each moves the logits by far more than TOL
@@ -261,20 +275,19 @@ def test_forward_logits_equal_the_reference(held):
     model = GPT(tiny_config(experts_held=held))
     params = lively(model.init_params(jax.random.PRNGKey(0)))
     ids = _ids(70)
-    want = ref.mistral4_logits(params, ids, experts_held=held, **REF)
+    want = mistral4_logits(params, ids, experts_held=held, **REF)
     got = model.forward_logits(params, ids[None])[0, :, :V]
     assert float(jnp.abs(got - want).max()) < TOL
-    some = ref.mistral4_logits(params, ids, lo=30, hi=37, experts_held=held, **REF)
+    some = mistral4_logits(params, ids, lo=30, hi=37, experts_held=held, **REF)
     np.testing.assert_array_equal(np.asarray(some), np.asarray(want[30:37]))
-    blocked = ref.mistral4_logits(params, ids[:64], experts_held=held, q_block=16, **REF)
+    blocked = mistral4_logits(params, ids[:64], experts_held=held, q_block=16, **REF)
     assert float(jnp.abs(blocked - want[:64]).max()) < TOL
 
 
 @pytest.mark.parametrize("wrong", list(WRONG))
-def test_the_tolerance_refuses_a_wrong_model_on_the_dense_path(tiny, wrong):
+def test_the_tolerance_refuses_a_wrong_model_on_the_dense_path(tiny, want70, wrong):
     _, params = tiny
-    ids = _ids(70)
-    want = ref.mistral4_logits(params, ids, **REF)
+    ids, want = _ids(70), want70
     got = GPT(tiny_config(**WRONG[wrong])).forward_logits(params, ids[None])[0, :, :V]
     gap = float(jnp.abs(got.astype(jnp.float32) - want).max())
     assert gap > 50 * TOL, gap
@@ -358,53 +371,14 @@ def test_paged_mla_kernel_equals_the_gather_reference(kernels, Sq):
 
 
 # ---- through the engine ----------------------------------------------------- #
-class Recording(GPT):
-    """The model as served, its step's logits kept: the engine fetches
-    tokens alone, and the comparison is on logits."""
-
-    def __init__(self, cfg):
-        super().__init__(cfg)
-        self.logits = []
-
-    def paged_step(self, *args, **kw):
-        out = super().paged_step(*args, **kw)
-        jax.debug.callback(lambda lg: self.logits.append(np.asarray(lg[:, 0, :V])),
-                           out[0])
-        return out
-
-
-def served_logits(cfg, params, prompt, new, serving=SERVING):
-    """``prompt`` through ``ServingEngine`` for ``new`` tokens -> (tokens,
-    the logits of every position it computed ``[len - 1, V]``, the engine's
-    stats a step)."""
-    model = Recording(cfg)
-    eng = deepspeed_tpu.init_serving(model=model, params=params,
-                                     config={"serving": serving})
-    fut = eng.submit(prompt, max_new_tokens=new)
-    rows, stats = {}, []
-    while not fut.done:
-        req, slot, at = fut.request, fut.request.slot, fut.request.prefilled
-        stats.append(eng.step())
-        eng.alloc.check_consistent()
-        jax.effects_barrier()
-        lg, st = model.logits[-1], stats[-1]
-        for i in range(st["prefill_tokens"]):
-            rows[at + i] = lg[serving["max_batch_size"] + i]
-        if st["decode_batch"]:
-            rows[at] = lg[slot]
-    assert eng.compiled_programs() == 1
-    arrays = (eng._k_pages, eng._v_pages)
-    eng.close()
-    return req.generated, np.stack([rows[t] for t in range(len(rows))]), stats, arrays
-
-
 @pytest.fixture(scope="module")
 def served():
     cfg = tiny_config(experts_held=(2, 4))
     params = lively(GPT(cfg).init_params(jax.random.PRNGKey(0)))
     prompt = list(map(int, _ids(21, seed=6)))
     with jax.default_matmul_precision("highest"):
-        return (cfg, params, prompt, *served_logits(cfg, params, prompt, 30))
+        tokens, got, stats, eng = served_logits(cfg, params, prompt, 30, SERVING, V)
+        return cfg, params, prompt, tokens, got, stats, (eng._k_pages, eng._v_pages)
 
 
 def test_prefill_in_chunks_then_decode_equals_the_reference(served):
@@ -414,7 +388,7 @@ def test_prefill_in_chunks_then_decode_equals_the_reference(served):
     held."""
     cfg, params, prompt, tokens, got, stats, (arena, none) = served
     seq = jnp.asarray(prompt + tokens)
-    want = ref.mistral4_logits(params, seq, experts_held=(2, 4), **REF)
+    want = mistral4_logits(params, seq, experts_held=(2, 4), **REF)
     assert got.shape == (len(seq) - 1, V)
     assert float(np.abs(got - np.asarray(want[:-1])).max()) < TOL
     assert sum(s["prefill_tokens"] > 0 for s in stats) == 3
@@ -436,10 +410,10 @@ def test_the_tolerance_refuses_a_wrong_model_on_the_served_path(served, wrong):
     prompt (every position a row of a chunk, through the pages)."""
     cfg, params, prompt, tokens = served[:4]
     seq = prompt + tokens
-    want = ref.mistral4_logits(params, jnp.asarray(seq), experts_held=(2, 4), **REF)
+    want = mistral4_logits(params, jnp.asarray(seq), experts_held=(2, 4), **REF)
     kw = dict(WRONG[wrong], experts_held=(2, 4))
     serving = dict(SERVING, dtype="bfloat16") if kw.pop("dtype", None) else SERVING
-    _, got, _, _ = served_logits(tiny_config(**kw), params, seq, 1, serving)
+    _, got, _, _ = served_logits(tiny_config(**kw), params, seq, 1, serving, V)
     gap = float(np.abs(got.astype(np.float32) - np.asarray(want)).max())
     assert gap > 50 * TOL, gap
 
@@ -451,9 +425,10 @@ def test_the_engine_on_the_kernel_serves_the_reference_paths_logits(kernels):
     cfg = tiny_config(v_head_dim=32)
     params = lively(GPT(cfg).init_params(jax.random.PRNGKey(1)))
     prompt = list(map(int, _ids(19, seed=8)))
-    want_tokens, want, _, _ = served_logits(cfg, params, prompt, 9)
+    want_tokens, want, _, _ = served_logits(cfg, params, prompt, 9, SERVING, V)
     kernels("paged_mla_attention")
-    tokens, got, stats, _ = served_logits(cfg, params, prompt, 9)
+    # a new engine: ``tile_runs_pct`` below is read off a new allocator's runs
+    tokens, got, stats, _ = served_logits(cfg, params, prompt, 9, SERVING, V, new_engine=True)
     assert stats[0]["paged_tile_pages"] == 64 and tokens == want_tokens
     assert float(np.abs(got - want).max()) < TOL
     # 39 pages hold no run of 64, and the reference path lays none
@@ -473,15 +448,14 @@ def test_the_engine_lays_runs_of_a_tile_and_the_kernel_fetches_them(
     params = lively(GPT(cfg).init_params(jax.random.PRNGKey(1)))
     prompt = list(map(int, _ids(53, seed=8)))
     serving = dict(SERVING, num_blocks=num_blocks)
-    want_tokens, want, _, _ = served_logits(cfg, params, prompt, 20, serving)
+    want_tokens, want, _, _ = served_logits(cfg, params, prompt, 20, serving, V)
     kernels("paged_mla_attention")
     monkeypatch.setattr(da, "_MLA_TILE_ROWS", 32)
-    model = Recording(cfg)
-    eng = deepspeed_tpu.init_serving(model=model, params=params,
-                                     config={"serving": serving})
+    # a new engine: where a NEW allocator lays its runs is the claim, and the
+    # constant lowered here is read when an engine is built and traced
+    tokens, got, stats, eng = served_logits(cfg, params, prompt, 20, serving, V,
+                                            new_engine=True)
     assert eng.paged_tile_pages == eng.alloc.run_blocks == 4
-    eng.close()
-    tokens, got, stats, _ = served_logits(cfg, params, prompt, 20, serving)
     assert tokens == want_tokens and float(np.abs(got - want).max()) < TOL
     # before the last step frees them, 72 tokens are 9 pages: two whole
     # tiles and a short one

@@ -21,10 +21,12 @@ import numpy as np
 import pytest
 
 import deepspeed_tpu
-from benchmarks.lib.reference_olmoe import olmoe_logits, olmoe_loss_sum
+from benchmarks.lib import reference_olmoe
+from benchmarks.lib.reference_olmoe import olmoe_loss_sum
 from deepspeed_tpu.models import gpt as gpt_lib
 from deepspeed_tpu.models.gpt import GPT, olmoe_config
 from tests.unit.paged_bank import PATHS, bank_in_place_equals_bank_sliced
+from tests.unit.serving_helpers import jitted
 
 TOL = 2e-5
 V, H = 500, 4
@@ -60,6 +62,19 @@ def _ids(n, seed=3):
     return jax.random.randint(jax.random.PRNGKey(seed), (n,), 0, V)
 
 
+def olmoe_logits(params, ids, **kw):
+    """The reference's forward pass, compiled once a set of its keywords."""
+    return jitted(reference_olmoe.olmoe_logits, **kw)(params, ids)
+
+
+@pytest.fixture(scope="module")
+def want40(tiny):
+    """The reference's logits of the 40 tokens both dense-path tests compare
+    against, once a module."""
+    with jax.default_matmul_precision("highest"):
+        return olmoe_logits(tiny[1], _ids(40), **REF)
+
+
 def test_config_is_the_published_layer():
     cfg = olmoe_config()
     assert (cfg.n_embd, cfg.n_layer, cfg.n_head, cfg.head_dim) == (2048, 16, 16, 128)
@@ -86,18 +101,16 @@ def test_top_k_follows_the_router():
     gpt_lib.GPTConfig(moe_num_experts=8, moe_top_k=8, moe_router="dropless")
 
 
-def test_forward_logits_equal_the_reference(tiny):
+def test_forward_logits_equal_the_reference(tiny, want40):
     model, params = tiny
-    ids = _ids(40)
-    want = olmoe_logits(params, ids, **REF)
+    ids, want = _ids(40), want40
     got = model.forward_logits(params, ids[None])[0, :, :V]
     assert float(jnp.abs(got - want).max()) < TOL
 
 
-def test_the_tolerance_refuses_bf16_and_a_norm_after_the_split(tiny, monkeypatch):
+def test_the_tolerance_refuses_bf16_and_a_norm_after_the_split(tiny, want40, monkeypatch):
     model, params = tiny
-    ids = _ids(40)
-    want = olmoe_logits(params, ids, **REF)
+    ids, want = _ids(40), want40
     low = GPT(dataclasses.replace(model.cfg, dtype=jnp.bfloat16))
     gap = float(jnp.abs(low.forward_logits(params, ids[None])[0, :, :V] - want).max())
     assert gap > 50 * TOL, gap

@@ -23,9 +23,10 @@ import numpy as np
 import pytest
 
 import deepspeed_tpu
-from benchmarks.lib.reference_smallthinker import smallthinker_logits
+from benchmarks.lib import reference_smallthinker
 from deepspeed_tpu.models.gpt import GPT, LayerKind, smallthinker_config
 from tests.unit.paged_bank import PATHS, bank_in_place_equals_bank_sliced
+from tests.unit.serving_helpers import jitted, served_logits, tiny_engine
 
 TOL = 2e-5
 V, W, LAYERS = 500, 16, 8
@@ -70,6 +71,19 @@ def _ids(n, seed=3):
     return jax.random.randint(jax.random.PRNGKey(seed), (n,), 0, V)
 
 
+def smallthinker_logits(params, ids, **kw):
+    """The reference's forward pass, compiled once a set of its keywords."""
+    return jitted(reference_smallthinker.smallthinker_logits, **kw)(params, ids)
+
+
+@pytest.fixture(scope="module")
+def want40(tiny):
+    """The reference's logits of the 40 tokens every dense-path case compares
+    against, once a module."""
+    with jax.default_matmul_precision("highest"):
+        return smallthinker_logits(tiny[1], _ids(40), **REF)
+
+
 # what a wrong model is: each moves the logits by far more than TOL
 WRONG = {
     "bf16": dict(dtype=jnp.bfloat16),
@@ -110,10 +124,9 @@ def test_config_is_the_published_layer():
         smallthinker_config(n_layer=6)
 
 
-def test_forward_logits_equal_the_reference(tiny):
+def test_forward_logits_equal_the_reference(tiny, want40):
     model, params = tiny
-    ids = _ids(40)
-    want = smallthinker_logits(params, ids, **REF)
+    ids, want = _ids(40), want40
     got = model.forward_logits(params, ids[None])[0, :, :V]
     assert float(jnp.abs(got - want).max()) < TOL
     # a range of positions is those rows of the whole
@@ -122,53 +135,12 @@ def test_forward_logits_equal_the_reference(tiny):
 
 
 @pytest.mark.parametrize("wrong", list(WRONG))
-def test_the_tolerance_refuses_a_wrong_model_on_the_dense_path(tiny, wrong):
+def test_the_tolerance_refuses_a_wrong_model_on_the_dense_path(tiny, want40, wrong):
     _, params = tiny
-    ids = _ids(40)
-    want = smallthinker_logits(params, ids, **REF)
+    ids, want = _ids(40), want40
     got = GPT(tiny_config(**WRONG[wrong])).forward_logits(params, ids[None])[0, :, :V]
     gap = float(jnp.abs(got.astype(jnp.float32) - want).max())
     assert gap > 50 * TOL, gap
-
-
-class Recording(GPT):
-    """The model as served, its step's logits kept: the engine fetches
-    tokens alone, and the comparison is on logits."""
-
-    def __init__(self, cfg):
-        super().__init__(cfg)
-        self.logits = []
-
-    def paged_step(self, *args, **kw):
-        out = super().paged_step(*args, **kw)
-        jax.debug.callback(lambda lg: self.logits.append(np.asarray(lg[:, 0, :V])),
-                           out[0])
-        return out
-
-
-def served_logits(cfg, params, prompt, new, serving=SERVING):
-    """``prompt`` through ``ServingEngine`` for ``new`` tokens -> (tokens,
-    the logits of every position it computed ``[len - 1, V]``, the engine's
-    stats a step): a prompt token is a row behind the slots, a decode step
-    the row of the request's slot."""
-    model = Recording(cfg)
-    eng = deepspeed_tpu.init_serving(model=model, params=params,
-                                     config={"serving": serving})
-    fut = eng.submit(prompt, max_new_tokens=new)
-    rows, stats = {}, []
-    while not fut.done:
-        req, slot, at = fut.request, fut.request.slot, fut.request.prefilled
-        stats.append(eng.step())
-        eng.alloc.check_consistent()
-        jax.effects_barrier()
-        lg, st = model.logits[-1], stats[-1]
-        for i in range(st["prefill_tokens"]):
-            rows[at + i] = lg[serving["max_batch_size"] + i]
-        if st["decode_batch"]:
-            rows[at] = lg[slot]
-    assert eng.compiled_programs() == 1
-    eng.close()
-    return req.generated, np.stack([rows[t] for t in range(len(rows))]), stats
 
 
 @pytest.fixture(scope="module")
@@ -176,7 +148,7 @@ def served(tiny):
     model, params = tiny
     prompt = list(map(int, _ids(21, seed=6)))
     with jax.default_matmul_precision("highest"):
-        return (prompt, *served_logits(model.cfg, params, prompt, 20))
+        return (prompt, *served_logits(model.cfg, params, prompt, 20, SERVING, V)[:3])
 
 
 def test_prefill_in_chunks_then_decode_past_the_window_equals_the_reference(
@@ -211,7 +183,7 @@ def test_the_tolerance_refuses_a_wrong_model_on_the_served_path(tiny, served, wr
     want = smallthinker_logits(params, jnp.asarray(seq), **REF)
     kw = dict(WRONG[wrong])
     serving = dict(SERVING, dtype="bfloat16") if kw.pop("dtype", None) else SERVING
-    _, got, _ = served_logits(tiny_config(**kw), params, seq, 1, serving)
+    _, got, _, _ = served_logits(tiny_config(**kw), params, seq, 1, serving, V)
     gap = float(np.abs(got.astype(np.float32) - np.asarray(want)).max())
     assert gap > 50 * TOL, gap
 
@@ -225,9 +197,9 @@ def test_the_engine_on_the_kernel_serves_the_reference_paths_logits(tiny, kernel
     params = GPT(cfg).init_params(jax.random.PRNGKey(1))
     prompt = list(map(int, _ids(19, seed=8)))
     serving = dict(SERVING, block_size=8)
-    want_tokens, want, _ = served_logits(cfg, params, prompt, 9, serving)
+    want_tokens, want, _, _ = served_logits(cfg, params, prompt, 9, serving, V)
     kernels("paged_gqa_attention")
-    tokens, got, stats = served_logits(cfg, params, prompt, 9, serving)
+    tokens, got, stats, _ = served_logits(cfg, params, prompt, 9, serving, V)
     assert stats[0]["paged_tile_pages"] == 16 and tokens == want_tokens
     assert float(np.abs(got - want).max()) < TOL
 
@@ -238,14 +210,11 @@ def test_preemption_and_resume_keep_the_pages_consistent(tiny):
     are those it gets alone; the allocator's books hold at every step."""
     model, params = tiny
     prompts = [list(map(int, _ids(n, seed=30 + n))) for n in (30, 26, 22)]
-    alone = []
-    for p in prompts:
-        eng = deepspeed_tpu.init_serving(model=model, params=params,
-                                         config={"serving": SERVING})
-        alone.append(eng.submit(p, max_new_tokens=30).result())
-        eng.close()
-    # 13 blocks of all layers = 52 pages: one request at 60 tokens holds
-    # 15 + 3 x 5 = 30, three cannot grow together
+    alone = [tiny_engine(model, params, **SERVING).submit(p, max_new_tokens=30).result()
+             for p in prompts]
+    # an engine of its own (``preemptions`` is read as a total), of 13 blocks
+    # of all layers = 52 pages: one request at 60 tokens holds 15 + 3 x 5 =
+    # 30, three cannot grow together
     eng = deepspeed_tpu.init_serving(model=model, params=params, config={
         "serving": dict(SERVING, num_blocks=13)})
     futures = [eng.submit(p, max_new_tokens=30) for p in prompts]

@@ -754,3 +754,30 @@ class TestPallasDisciplinePass:
         assert any(r.endswith("decode_attention.py") for r in rels)
         assert any(r.endswith("cross_entropy.py") for r in rels)
         assert any(r.endswith("fused_optim.py") for r in rels)
+
+
+# ---- the tests' own harness stays ONE harness -------------------------------- #
+def test_the_serving_tests_share_one_harness():
+    """``tests/unit/serving_helpers.py`` holds the one ``Driver``, the one
+    ``Recording`` and the one ``served_logits``: a copy in a test file builds
+    a new jitted function (or engine) a case, which is what cost the suite
+    half its time (ROADMAP D9).  And ``test_chip_compile.py`` compiles ahead
+    of time in ONE place, ``_compile``.  Reads sources; runs nothing."""
+    import re
+    unit = os.path.join(REPO_ROOT, "tests", "unit")
+    copies = re.compile(r"^(class Driver\b|class Recording\b|def served_logits\b)", re.M)
+    found = {}
+    for folder, _, names in os.walk(unit):
+        for name in names:
+            if name.endswith(".py"):
+                path = os.path.join(folder, name)
+                with open(path) as f:
+                    hits = copies.findall(f.read())
+                if hits:
+                    found[os.path.relpath(path, unit)] = sorted(hits)
+    assert found == {"serving_helpers.py": [
+        "class Driver", "class Recording", "def served_logits"]}, found
+    with open(os.path.join(unit, "ops", "test_chip_compile.py")) as f:
+        source = f.read()
+    assert len(re.findall(r"\.compile\(\)", source)) == 1
+    assert len(re.findall(r"\.lower\(", source)) == 1

@@ -25,11 +25,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import deepspeed_tpu
-from benchmarks.lib.reference_trinity import trinity_logits
+from benchmarks.lib import reference_trinity
 from deepspeed_tpu.models import gpt
 from deepspeed_tpu.models.gpt import GPT, LayerKind, trinity_config
 from tests.unit.paged_bank import PATHS, bank_in_place_equals_bank_sliced
+from tests.unit.serving_helpers import jitted, served_logits
 
 TOL = 5e-5
 V, W, LAYERS, N, K = 500, 16, 8, 16, 4
@@ -82,6 +82,19 @@ def tiny():
 
 def _ids(n, seed=3):
     return jax.random.randint(jax.random.PRNGKey(seed), (n,), 0, V)
+
+
+def trinity_logits(params, ids, **kw):
+    """The reference's forward pass, compiled once a set of its keywords."""
+    return jitted(reference_trinity.trinity_logits, **kw)(params, ids)
+
+
+@pytest.fixture(scope="module")
+def want48(tiny):
+    """The reference's logits of the 48 tokens every dense-path case compares
+    against, once a module."""
+    with jax.default_matmul_precision("highest"):
+        return trinity_logits(tiny[1], _ids(48), **REF)
 
 
 # what a wrong model is: each moves the logits by far more than TOL
@@ -222,10 +235,9 @@ def test_forward_logits_equal_the_reference(held):
 
 
 @pytest.mark.parametrize("wrong", list(WRONG))
-def test_the_tolerance_refuses_a_wrong_model_on_the_dense_path(tiny, wrong):
+def test_the_tolerance_refuses_a_wrong_model_on_the_dense_path(tiny, want48, wrong):
     _, params = tiny
-    ids = _ids(48)
-    want = trinity_logits(params, ids, **REF)
+    ids, want = _ids(48), want48
     got = GPT(tiny_config(**WRONG[wrong])).forward_logits(params, ids[None])[0, :, :V]
     gap = float(jnp.abs(got.astype(jnp.float32) - want).max())
     assert gap > 50 * TOL, gap
@@ -240,52 +252,12 @@ def test_the_training_paths_that_scan_refuse_the_stack_by_name(tiny):
         model.generate(params, _ids(8)[None], 2)
 
 
-class Recording(GPT):
-    """The model as served, its step's logits kept: the engine fetches
-    tokens alone, and the comparison is on logits."""
-
-    def __init__(self, cfg):
-        super().__init__(cfg)
-        self.logits = []
-
-    def paged_step(self, *args, **kw):
-        out = super().paged_step(*args, **kw)
-        jax.debug.callback(lambda lg: self.logits.append(np.asarray(lg[:, 0, :V])),
-                           out[0])
-        return out
-
-
-def served_logits(cfg, params, prompt, new, serving=SERVING):
-    """``prompt`` through ``ServingEngine`` for ``new`` tokens -> (tokens,
-    the logits of every position it computed ``[len - 1, V]``, the engine's
-    stats a step): a prompt token is a row behind the slots, a decode step
-    the row of the request's slot."""
-    model = Recording(cfg)
-    eng = deepspeed_tpu.init_serving(model=model, params=params,
-                                     config={"serving": serving})
-    fut = eng.submit(prompt, max_new_tokens=new)
-    rows, stats = {}, []
-    while not fut.done:
-        req, slot, at = fut.request, fut.request.slot, fut.request.prefilled
-        stats.append(eng.step())
-        eng.alloc.check_consistent()
-        jax.effects_barrier()
-        lg, st = model.logits[-1], stats[-1]
-        for i in range(st["prefill_tokens"]):
-            rows[at + i] = lg[serving["max_batch_size"] + i]
-        if st["decode_batch"]:
-            rows[at] = lg[slot]
-    assert eng.compiled_programs() == 1
-    eng.close()
-    return req.generated, np.stack([rows[t] for t in range(len(rows))]), stats
-
-
 @pytest.fixture(scope="module")
 def served(tiny):
     model, params = tiny
     prompt = list(map(int, _ids(21, seed=6)))
     with jax.default_matmul_precision("highest"):
-        return (prompt, *served_logits(model.cfg, params, prompt, 20))
+        return (prompt, *served_logits(model.cfg, params, prompt, 20, SERVING, V)[:3])
 
 
 def test_prefill_in_chunks_then_decode_past_the_window_equals_the_reference(
@@ -325,7 +297,7 @@ def test_the_tolerance_refuses_a_wrong_model_on_the_served_path(tiny, served, wr
     want = trinity_logits(params, jnp.asarray(seq), **REF)
     kw = dict(WRONG[wrong])
     serving = dict(SERVING, dtype="bfloat16") if kw.pop("dtype", None) else SERVING
-    _, got, _ = served_logits(tiny_config(**kw), params, seq, 1, serving)
+    _, got, _, _ = served_logits(tiny_config(**kw), params, seq, 1, serving, V)
     gap = float(np.abs(got.astype(np.float32) - np.asarray(want)).max())
     assert gap > 50 * TOL, gap
 
@@ -337,7 +309,7 @@ def test_a_held_share_is_served_as_the_reference_makes_it(tiny):
     cfg = tiny_config(experts_held=(4, 4))
     params = lively(GPT(cfg).init_params(jax.random.PRNGKey(0)))
     prompt = list(map(int, _ids(19, seed=8)))
-    tokens, got, stats = served_logits(cfg, params, prompt, 6)
+    tokens, got, stats, _ = served_logits(cfg, params, prompt, 6, SERVING, V)
     want = trinity_logits(params, jnp.asarray(prompt + tokens), experts_held=(4, 4), **REF)
     assert float(np.abs(got - np.asarray(want[:-1])).max()) < TOL
     routed = [s for s in stats if "moe_assignments" in s]

@@ -7,6 +7,8 @@ request preempted; the routers' stream; top-1 routing in the step's stats; the
 published parameter count; and what ``init_serving`` and the dense paths
 refuse."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -19,6 +21,8 @@ from deepspeed_tpu.models import hybrid
 from deepspeed_tpu.models.gpt import GPT, zaya_config
 from deepspeed_tpu.moe import dropless
 from deepspeed_tpu.serving.kv_cache import init_arena
+from tests.unit import serving_helpers
+from tests.unit.serving_helpers import Driver, jitted, served_tokens
 
 WIDTHS = dict(vocab_size=512, n_positions=256, n_embd=64, n_layer=3, n_head=4,
               n_kv_head=2, head_dim=16, intermediate_size=32, num_experts=4,
@@ -59,73 +63,29 @@ def loud(tiny):
     return model, dict(params, blocks={"cca": cca})
 
 
-def reference_logits(params, seq, **kw):
+def _padded(seq):
     ids = np.zeros(-(-len(seq) // 32) * 32, np.int32)
     ids[:len(seq)] = seq
-    return np.asarray(ref.zaya_logits(params, jnp.asarray(ids), **REF, **kw))[:len(seq)]
+    return jnp.asarray(ids)
+
+
+def reference_logits(params, seq):
+    return np.asarray(jitted(ref.zaya_logits, **REF)(params, _padded(seq)))[:len(seq)]
 
 
 def reference_experts(params, seq):
-    ids = np.zeros(-(-len(seq) // 32) * 32, np.int32)
-    ids[:len(seq)] = seq
-    return np.asarray(ref.zaya_hidden(params, jnp.asarray(ids), with_experts=True,
-                                      **REF)[1])[:, :len(seq)]
+    hidden = jitted(ref.zaya_hidden, with_experts=True, **REF)
+    return np.asarray(hidden(params, _padded(seq))[1])[:, :len(seq)]
 
 
 def _ids(n, seed):
     return np.random.default_rng(seed).integers(0, 512, n).astype(np.int32)
 
 
-class Driver:
-    """``model.paged_step`` driven by hand, as the engine's step drives it:
-    ``SLOTS`` decode rows and a prompt chunk of ``chunk`` rows; slot ``s``
-    owns the blocks ``1 + s * MB ..`` in logical order.  ``round_state``
-    rounds the slots' convolution states through that type after every step
-    (a planted lower precision)."""
-
-    def __init__(self, model, params, chunk=CHUNK, round_state=None):
-        cfg = model.cfg
-        self.chunk, self.round_state = chunk, round_state
-        self.kp, self.vp = init_arena(cfg, 1 + SLOTS * MB, BS, jnp.float32)
-        self.aux = hybrid.init_aux(cfg, 1 + SLOTS * MB, BS, SLOTS, jnp.float32)
-        self.fn = jax.jit(lambda *a, **kw: model.paged_step(
-            params, *a, chunk=chunk, with_expert_counts=True, **kw))
-
-    def step(self, decode=(), chunk=None):
-        """``decode``: (slot, token, position) a decode row; ``chunk``: (slot,
-        first position, tokens).  -> logits ``[SLOTS + chunk, vocab]``."""
-        R = SLOTS + self.chunk
-        ids, pos, slot = (np.zeros(R, np.int32) for _ in range(3))
-        live = np.zeros(R, bool)
-        for s, token, t in decode:
-            ids[s], pos[s], slot[s], live[s] = token, t, s, True
-        if chunk is not None:
-            s, start, tokens = chunk
-            at = slice(SLOTS, SLOTS + len(tokens))
-            ids[at], pos[at], slot[at], live[at] = tokens, start + np.arange(len(tokens)), s, True
-        tables = np.where(live[:, None], 1 + slot[:, None] * MB + np.arange(MB)[None], 0)
-        wb = np.where(live, tables[np.arange(R), pos // BS], 0)
-        wo = np.where(live, pos % BS, 0)
-        logits, self.kp, self.vp, self.aux, self.counts = self.fn(
-            jnp.asarray(ids)[:, None], jnp.asarray(pos), self.kp, self.vp,
-            jnp.asarray(tables, jnp.int32), jnp.asarray(wb, jnp.int32)[:, None],
-            jnp.asarray(wo, jnp.int32)[:, None], aux=self.aux,
-            slots=jnp.asarray(slot), live=jnp.asarray(live))
-        if self.round_state is not None:
-            self.aux = jax.tree.map(lambda a: a.astype(self.round_state).astype(a.dtype),
-                                    self.aux)
-        return np.asarray(logits)[:, 0]
-
-    def sequence(self, seq, chunks, slot=0):
-        """Logits of every position of ``seq``: its prompt prefilled in
-        chunks of the lengths ``chunks``, the rest decoded a token a step."""
-        out, start = [], 0
-        for n in chunks:
-            out.append(self.step(chunk=(slot, start, seq[start:start + n]))[SLOTS:SLOTS + n])
-            start += n
-        for t in range(start, len(seq)):
-            out.append(self.step(decode=[(slot, seq[t], t)])[slot][None])
-        return np.concatenate(out)
+# the step's expert counts kept; ``round_through=`` rounds the slots'
+# convolution states through that type after every step
+driver = functools.partial(Driver, slots=SLOTS, chunk=CHUNK, block_size=BS,
+                           blocks_a_slot=MB, static={"with_expert_counts": True})
 
 
 # ---- (a) the served logits against the reference's full forward pass ------------ #
@@ -139,7 +99,7 @@ CHUNKS = {"whole": (8, 8, 8), "ragged": (7, 5, 8, 3, 1), "single": (1,) * 6}
 def test_prefill_then_decode_agree_with_the_reference(tiny, loud, weights, chunks):
     model, params = tiny if weights == "seeded" else loud
     seq = _ids(44, seed=len(chunks))
-    got = Driver(model, params).sequence(seq, CHUNKS[chunks])
+    got = driver(model, params).sequence(seq, CHUNKS[chunks])
     want = reference_logits(params, seq)
     assert np.abs(got - want).max() < TOL
     assert np.abs(want).max() > 0.1
@@ -151,7 +111,7 @@ def test_a_bf16_state_fails_the_tolerance(loud):
     ten times the tolerance."""
     model, params = loud
     seq = _ids(44, seed=5)
-    got = Driver(model, params, round_state=jnp.bfloat16).sequence(seq, CHUNKS["ragged"])
+    got = driver(model, params, round_through=jnp.bfloat16).sequence(seq, CHUNKS["ragged"])
     assert np.abs(got - reference_logits(params, seq)).max() > 5 * TOL
 
 
@@ -159,7 +119,7 @@ def test_bf16_weights_fail_the_tolerance(loud):
     model, params = loud
     seq = _ids(44, seed=5)
     rounded = jax.tree.map(lambda a: a.astype(jnp.bfloat16).astype(jnp.float32), params)
-    got = Driver(model, rounded).sequence(seq, CHUNKS["whole"])
+    got = driver(model, rounded).sequence(seq, CHUNKS["whole"])
     assert np.abs(got - reference_logits(params, seq)).max() > 50 * TOL
 
 
@@ -169,7 +129,7 @@ def test_a_step_with_decode_rows_and_a_chunk_together(loud):
     sequence's, and so is every slot's state."""
     model, params = loud
     a, b, c = _ids(60, 1), _ids(40, 2), _ids(40, 3)
-    d = Driver(model, params)
+    d = driver(model, params)
     d.sequence(a[:30], (8, 8, 8, 6), slot=0)
     d.sequence(b[:11], (8, 3), slot=1)
     got = {0: [], 1: [], 2: []}
@@ -191,9 +151,9 @@ def test_a_decode_rows_k_v_and_state_are_one_whole_sequence_pass(loud):
     K and V in the pages, the same state left in the slot."""
     model, params = loud
     seq = _ids(40, seed=11)
-    steps = Driver(model, params)
+    steps = driver(model, params)
     steps.sequence(seq, (5,))                     # 5 prefilled, 35 decode rows
-    whole = Driver(model, params, chunk=40)
+    whole = driver(model, params, chunk=40)
     whole.sequence(seq, (40,))
     pages = slice(1, 1 + 3)                       # slot 0's first three blocks
     for a, b in ((steps.kp, whole.kp), (steps.vp, whole.vp)):
@@ -204,7 +164,7 @@ def test_a_decode_rows_k_v_and_state_are_one_whole_sequence_pass(loud):
     assert a.shape == (3, 2 * 96 + 16) and np.abs(a - b).max() < 1e-5
     # the state is [u_{t-1} | u_{t-2} | W_v2 h_{t-1}]: shifted by one token,
     # the first becomes the second
-    before = Driver(model, params, chunk=40)
+    before = driver(model, params, chunk=40)
     before.sequence(seq[:39], (39,))
     assert np.abs(np.asarray(before.aux["cca_state"][:, 0, :96]) - b[:, 96:192]).max() < 1e-5
     # the second K/V head's value is the PREVIOUS token's: position 0 holds zeros
@@ -243,8 +203,8 @@ def test_a_layers_router_reads_the_stream_of_the_layer_before(loud):
     cut = dict(params["blocks"]["cca"])
     cut["stream_g"] = cut["stream_g"].at[1:].set(0.0)
     cut = dict(params, blocks={"cca": cut})
-    with_stream = Driver(model, params).sequence(seq, (8, 8, 8))
-    without = Driver(model, cut).sequence(seq, (8, 8, 8))
+    with_stream = driver(model, params).sequence(seq, (8, 8, 8))
+    without = driver(model, cut).sequence(seq, (8, 8, 8))
     assert np.abs(with_stream - without).max() > 100 * TOL
     assert np.abs(without - reference_logits(cut, seq)).max() < TOL
     assert (reference_experts(params, seq)[1:] != reference_experts(cut, seq)[1:]).any()
@@ -273,19 +233,10 @@ def test_the_stream_router_and_the_biased_choice():
 
 # ---- through the engine ------------------------------------------------------------ #
 def served(model, params, prompts, new, **serving):
-    eng = deepspeed_tpu.init_serving(model=model, params=params,
-                                     config={"serving": dict(SERVING, **serving)})
-    try:
-        futures = [eng.submit(p, max_new_tokens=n) for p, n in zip(prompts, new)]
-        return [f.result() for f in futures], eng
-    finally:
-        eng.close()
+    return served_tokens(model, params, prompts, new, **dict(SERVING, **serving))
 
 
-def reference_tokens(params, prompt, tokens):
-    seq = np.concatenate([prompt, tokens]).astype(np.int32)
-    lg = reference_logits(params, seq)[len(prompt) - 1:len(seq) - 1]
-    return lg.argmax(-1).tolist(), float((lg.max(-1) - lg[np.arange(len(tokens)), tokens]).max())
+reference_tokens = functools.partial(serving_helpers.reference_tokens, reference_logits)
 
 
 def test_the_engine_serves_the_references_tokens_in_one_program(loud):
@@ -320,34 +271,15 @@ def test_a_preempted_request_resumes_to_the_same_tokens(loud):
     model, params = loud
     prompts = [_ids(n, seed=40 + n) for n in (70, 60, 50)]
     alone = [served(model, params, [p], (40,))[0][0] for p in prompts]
-    eng = deepspeed_tpu.init_serving(model=model, params=params, config={
-        "serving": dict(SERVING, num_blocks=17)})
-    futures = [eng.submit(p, max_new_tokens=40) for p in prompts]
-    reset = 0
-    while not all(f.done for f in futures):
-        st = eng.step()
-        eng.alloc.check_consistent()
-        reset += st.get("state_slots_reset", 0)
-    assert st["preemptions"] >= 1
-    assert reset == 3 + st["preemptions"]       # a first chunk, and each again
-    assert [f.token_ids for f in futures] == alone
-    eng.close()
+    assert serving_helpers.preempted(model, params, prompts, 40,
+                                     **dict(SERVING, num_blocks=17)) == alone
 
 
 def test_a_snapshot_restores_by_recompute(loud):
     model, params = loud
     p = _ids(45, seed=8)
     (whole, _) = served(model, params, [p], (30,))
-    eng = deepspeed_tpu.init_serving(model=model, params=params, config={"serving": SERVING})
-    f = eng.submit(p, max_new_tokens=30)
-    while len(f.token_ids) < 11:
-        eng.step()
-    snap = eng.snapshot()
-    eng.close()
-    eng = deepspeed_tpu.init_serving(model=model, params=params, config={"serving": SERVING})
-    (g,) = eng.restore(snap)
-    assert g.result() == whole[0]
-    eng.close()
+    assert serving_helpers.restored_tokens(model, params, p, 30, 11, **SERVING) == whole[0]
 
 
 # ---- (e) top-1 in the step's stats --------------------------------------------------- #
@@ -425,15 +357,10 @@ def test_init_serving_refuses_what_carries_no_state(tiny, knob, mechanism):
 @pytest.mark.parametrize("path", ["forward", "generate", "loss"])
 def test_the_dense_paths_refuse_the_stack_by_what_they_lack(tiny, path):
     model, params = tiny
-    ids = jnp.asarray(_ids(16, 0))[None]
-    call = {"forward": lambda: model.forward_logits(params, ids),
-            "generate": lambda: model.generate(params, ids, 4),
-            "loss": lambda: model(params, (ids, ids), None, False)}[path]
-    with pytest.raises(NotImplementedError) as e:
-        call()
-    assert "convolution over time of the packed q/k latents" in str(e.value)
-    assert "second carry for the router's stream" in str(e.value)
-    assert "init_serving()" in str(e.value)
+    said = serving_helpers.dense_path_refusal(model, params, path, _ids(16, 0))
+    assert "convolution over time of the packed q/k latents" in said
+    assert "second carry for the router's stream" in said
+    assert "init_serving()" in said
 
 
 def test_a_stream_router_outside_the_hybrid_walk_is_refused():
