@@ -37,6 +37,11 @@ multi-query attention) are entries of :data:`MIXERS` and
   at or before it, summed over the query heads of a K/V head, a block's the
   max over the compressed keys that overlap it; the first ``init_blocks``
   and the blocks of the last ``window`` keys are chosen whatever they score.
+  On the chip the heads' scores never leave VMEM
+  (``ops/pallas/sparse_select.py`` writes the block scores alone) and the
+  ``topk`` highest are found by the ``indexed`` mixer's bisection, no sort;
+  the plain form (:func:`_select`) is the reference, and runs where the
+  kernel's tiles do not fit.
   A block is a PAGE, and the selection goes INTO the block table:
   ``ops/pallas/decode_attention.py:paged_sparse_attention`` walks, a row a
   (token, K/V head), the chosen pages in logical order under a length that
@@ -520,17 +525,17 @@ def _write_compressed(cfg, kp, kc, li, positions, live, tables, chunk):
     return kc.at[li, dest[:, None], lanes].set(mean.reshape(-1, Hkv * D))
 
 
-def _select(cfg, q, kc_rows, positions, BS: int):
-    """The blocks each query attends, as a table's columns.  ``q [n, Hkv, g,
+def _block_scores(cfg, q, kc_rows, positions, BS: int):
+    """Each query's score of each block of its K/V head.  ``q [n, Hkv, g,
     D]``; ``kc_rows [n | 1, MB * r, Hkv, D]``: the compressed keys under the
     queries' tables in logical order (entry ``f`` is key ``f - 1``; one row
-    where all queries share a table); ``positions [n]``.  -> (logical blocks
-    ``[n, Hkv, W]`` in rising order, the position of the query among the
-    keys of those blocks ``[n]``)."""
+    where all queries share a table); ``positions [n]``.  -> ``[n, Hkv, MB]``
+    float32, ``+inf`` the blocks a query is made to attend, ``-inf`` those
+    past it."""
     sp = cfg.sparse
     n, Hkv, g, D = q.shape
     F = kc_rows.shape[1]
-    r, MB, W = keys_a_page(cfg), F // keys_a_page(cfg), table_columns(cfg, BS)
+    r, MB = keys_a_page(cfg), F // keys_a_page(cfg)
     t = positions
     if kc_rows.shape[0] == 1:
         s = jnp.einsum("nhgd,fhd->nhgf", q, kc_rows[0],
@@ -551,17 +556,82 @@ def _select(cfg, q, kc_rows, positions, BS: int):
     tq = t[:, None, None]
     forced = (b < sp.init_blocks) | (b >= jnp.maximum(tq - sp.window + 1, 0) // BS)
     score = jnp.where(forced, jnp.inf, score)
-    score = jnp.where(b <= tq // BS, score, -jnp.inf)
+    return jnp.where(b <= tq // BS, score, -jnp.inf)
+
+
+def _select(cfg, q, kc_rows, positions, BS: int):
+    """The blocks each query attends, as a table's columns (arguments as
+    :func:`_block_scores`'): -> (logical blocks ``[n, Hkv, W]`` in rising
+    order, the position of the query among the keys of those blocks
+    ``[n]``).  The selection in plain ``jax.numpy``: what runs where the
+    kernel's tiles do not fit the shapes, and the reference
+    :func:`_select_on_chip` is held to."""
+    sp, MB = cfg.sparse, kc_rows.shape[1] // keys_a_page(cfg)
+    score = _block_scores(cfg, q, kc_rows, positions, BS)
     # the chosen blocks in rising order, those of no key (a query with fewer
     # than topk blocks behind it) last: the query's own block ends the list
     top, chosen = jax.lax.top_k(score, min(sp.topk, MB))
     chosen = jnp.sort(jnp.where(top > -jnp.inf, chosen, MB), axis=-1)
-    chosen = jnp.pad(jnp.minimum(chosen, MB - 1),
-                     ((0, 0), (0, 0), (0, W - chosen.shape[-1])))
+    return _as_columns(cfg, jnp.minimum(chosen, MB - 1), positions, BS)
+
+
+def _as_columns(cfg, chosen, t, BS: int):
+    """The blocks the queries at ``t [n]`` chose (``chosen [n, Hkv, <=
+    topk]``, rising, the blocks of no key last) as a table's ``W`` columns:
+    every block in order for a query at or under ``dense_len``.  -> (blocks
+    ``[n, Hkv, W]``, the position of the query among their keys ``[n]``)."""
+    sp, W = cfg.sparse, table_columns(cfg, BS)
+    chosen = jnp.pad(chosen, ((0, 0), (0, 0), (0, W - chosen.shape[-1])))
     dense = (t + 1 <= sp.dense_len)
     blocks = jnp.where(dense[:, None, None], jnp.arange(W)[None, None], chosen)
     held = jnp.minimum(sp.topk, t // BS + 1)
     return blocks, jnp.where(dense, t, (held - 1) * BS + t % BS)
+
+
+# blocks whose running count is one triangular product: a lane tile (the
+# kernel's gate admits whole lane tiles of blocks)
+_RANK_GROUP = 128
+
+
+def _select_on_chip(cfg, q, kc_pages, positions, BS: int):
+    """:func:`_select` without the heads' scores in HBM and without a sort:
+    the block scores from ``ops/pallas/sparse_select.py`` (``kc_pages [n | 1,
+    MB, r * Hkv * D]``: the compressed keys as the pages hold them), the
+    ``topk`` highest of a row by :func:`chosen_tokens`' bisection (ties to
+    the lower block, as ``top_k``'s), and the mask as a list by rank: column
+    ``c`` is the block with ``c`` chosen blocks before it, which is how many
+    blocks' running count stays at or under ``c`` (``MB`` of a column past
+    the row's last: the block of no key)."""
+    from deepspeed_tpu.ops.pallas.sparse_select import sparse_block_scores
+    sp = cfg.sparse
+    n, Hkv, MB = q.shape[0], q.shape[1], kc_pages.shape[1]
+    score = sparse_block_scores(
+        q, kc_pages, positions, stride=sp.stride, block=BS,
+        init_blocks=sp.init_blocks, window=sp.window).reshape(n * Hkv, MB)
+    K = min(sp.topk, MB)
+    before = _running_count(chosen_tokens(score, K, _RANK_GROUP), _RANK_GROUP)
+    chosen = jnp.sum(before[:, None, :] <= jnp.arange(K, dtype=jnp.float32)[None, :, None],
+                     axis=-1, dtype=jnp.int32)
+    return _as_columns(cfg, jnp.minimum(chosen, MB - 1).reshape(n, Hkv, K), positions, BS)
+
+
+def _entries_at(tables, blocks):
+    """``tables [n | 1, MB]`` at the columns ``blocks [n, Hkv, W]``, as a
+    compare and a sum over the table's columns: the chip gathers 131,072
+    entries a layer one by one (1.35 ms; PERF.md section 6, PR 60)."""
+    b = jnp.arange(tables.shape[1])
+    return jnp.sum(jnp.where(blocks[..., None] == b, tables[:, None, None, :], 0), axis=-1)
+
+
+def selects_on_chip(cfg, n: int, blocks: int, shared: bool) -> bool:
+    """Whether ``n`` queries over tables of ``blocks`` (one table where
+    ``shared``) select through the kernel: on a TPU, at shapes its tiles
+    take; :func:`_select` everywhere else."""
+    from deepspeed_tpu.ops import pallas
+    from deepspeed_tpu.ops.pallas import sparse_select
+    return (pallas.use_kernel(sparse_select.KERNEL) and pallas.single_device()
+            and sparse_select.kernel_shape_ok(
+                n, cfg.n_head // cfg.kv_heads, cfg.head_dim, blocks, shared))
 
 
 def sparse_mixer(cfg, p, h, kp, vp, held, li, step: _Step):
@@ -592,11 +662,16 @@ def sparse_mixer(cfg, p, h, kp, vp, held, li, step: _Step):
         them.  ``shared``: they are one sequence's, under one table."""
         tb, n = tables[rows], q[rows].shape[0]
         with jax.named_scope("sparse_select"):
-            under = kc[li, tb[:1] if shared else tb].reshape(
-                1 if shared else n, -1, Hkv, D)
-            blocks, at = _select(cfg, q[rows], under, positions[rows], BS)
+            of = tb[:1] if shared else tb
+            under = kc[li, of]                                    # [n | 1, MB, r Hkv D]
             # logical -> physical through the row's table, a row a (token, head)
-            chosen = jnp.take_along_axis(tb[:, None], blocks, axis=2)
+            if selects_on_chip(cfg, n, tb.shape[1], shared):
+                blocks, at = _select_on_chip(cfg, q[rows], under, positions[rows], BS)
+                chosen = _entries_at(of, blocks)
+            else:
+                blocks, at = _select(cfg, q[rows], under.reshape(
+                    under.shape[0], -1, Hkv, D), positions[rows], BS)
+                chosen = jnp.take_along_axis(tb[:, None], blocks, axis=2)
             chosen = (chosen * Hkv + jnp.arange(Hkv)[None, :, None]).reshape(n * Hkv, -1)
         with jax.named_scope("sparse_attend"):
             return plan.attend(
@@ -1019,6 +1094,13 @@ def _counts_before(x: Array, G: int):
     return inside, jnp.cumsum(inside[..., -1], axis=1)
 
 
+def _running_count(x: Array, G: int) -> Array:
+    """``x [n, T]`` of 0 / 1 -> how many of ``x[:, :i + 1]`` are set ``[n,
+    T]``, float32 and exact."""
+    inside, groups = _counts_before(x, G)
+    return (inside + (groups - inside[..., -1])[..., None]).reshape(x.shape)
+
+
 def chosen_tokens(scores: Array, k: int, G: int) -> Array:
     """Which ``k`` of ``scores [n, T]`` (float32) a row are largest, EXACTLY
     (of equal scores the lower positions; never one at -inf, so a row with
@@ -1040,8 +1122,7 @@ def chosen_tokens(scores: Array, k: int, G: int) -> Array:
         kth = kth | (jnp.sum(enough, axis=-1).astype(jnp.uint32) << shift)
     above, equal = u > kth[:, None], u == kth[:, None]
     wanted = (k - jnp.sum(above, axis=-1)).astype(jnp.float32)     # of the equal ones
-    inside, groups = _counts_before(equal, G)
-    rank = (inside + (groups - inside[..., -1])[..., None]).reshape(n, T)
+    rank = _running_count(equal, G)
     return (above | (equal & (rank <= wanted[:, None]))) & (scores > -jnp.inf)
 
 

@@ -34,10 +34,10 @@ def use_kernel(name: str) -> bool:
     kernel's own shape gate and sharding rule decide after it.  ``name`` is
     the kernel's (``ce``, ``fused_adam``, ``flash_attention``,
     ``decode_attention``, ``paged_attention``, ``paged_gqa_attention``,
-    ``paged_mla_attention``, ``paged_sparse_attention``, ``grouped_matmul``,
-    ``delta_state_update``, ``mamba_state_update``, ``mamba_chunk_scan``) and
-    is not read here: a test's replacement
-    answers for one kernel by it.  ``fused_adam`` is the NVMe offload
+    ``paged_mla_attention``, ``paged_sparse_attention``,
+    ``sparse_block_scores``, ``grouped_matmul``, ``delta_state_update``,
+    ``mamba_state_update``, ``mamba_chunk_scan``) and is not read here: a
+    test's replacement answers for one kernel by it.  ``fused_adam`` is the NVMe offload
     walk's: no compiled step program holds it."""
     del name
     return platform() == "tpu"

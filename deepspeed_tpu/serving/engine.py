@@ -715,7 +715,9 @@ class ServingEngine:
         mamba layers' states read and written, one a live decode row a layer
         and one a layer for the step's chunk; of the sparse layers, keys the live rows attended against
         the keys resident before them, summed over sparse layers and K/V
-        heads, and live rows at or under ``dense_len``; of the indexed layers,
+        heads, live rows at or under ``dense_len`` and, summed over sparse
+        layers, live rows past it (the rows whose table a layer's selection
+        wrote); of the indexed layers,
         index keys the live rows scored (every key at or before them), keys
         they attended (``topk`` at most) and keys resident before them,
         summed over indexed layers, and the bytes of index keys held."""
@@ -739,11 +741,14 @@ class ServingEngine:
                 out[stat] = (decoding + int(first[3] != 0)) * mcfg.mixers.count(mixer)
         if "sparse" in mcfg.mixers:
             t = rows[rows[:, 3] != 0, 1]
-            per = mcfg.mixers.count("sparse") * mcfg.kv_heads
+            layers = mcfg.mixers.count("sparse")
+            per = layers * mcfg.kv_heads
+            dense = int((t + 1 <= mcfg.sparse.dense_len).sum())
             out.update(
                 sparse_keys_attended=int(hybrid.keys_attended(mcfg, t).sum()) * per,
                 sparse_keys_resident=int((t + 1).sum()) * per,
-                sparse_rows_dense=int((t + 1 <= mcfg.sparse.dense_len).sum()))
+                sparse_rows_dense=dense,
+                sparse_rows_selected=(len(t) - dense) * layers)
         return out
 
     def _no_tokens(self):

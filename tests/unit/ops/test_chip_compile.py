@@ -352,6 +352,27 @@ def test_paged_sparse_kernel_compiles_at_minicpm_sala_heads(chip):
     assert da.paged_tile_pages(16, 1024, 1, 4 * D128, BF16) == 8      # SmallThinker's, as it was
 
 
+@pytest.mark.parametrize("n,tables", [(512, 1), (16, 16)], ids=["chunk", "decode_rows"])
+def test_sparse_block_scores_compiles_at_minicpm_sala_heads(chip, n, tables):
+    """MiniCPM-SALA's selection as served: the chunk's 512 queries under one
+    table and the 16 decode rows under their own, 16 query heads of 128 lanes
+    on each of 2 K/V heads over the 3,072 compressed keys of 768 pages; the
+    kernel, then a bisection and a list by rank: no sort, and nothing as
+    large as the heads' scores."""
+    from deepspeed_tpu.models import gpt, hybrid
+    from deepspeed_tpu.ops.pallas import sparse_select as ss
+    cfg = gpt.minicpm_sala_config(mixer_types=["minicpm4"], first_layer=9, dtype=BF16)
+    g, MB, D128 = 16, 768, 128
+    assert hybrid.selects_on_chip(cfg, n, MB, tables == 1)
+    text = _compiled_text(
+        chip, lambda q, kc, at: hybrid._select_on_chip(cfg, q, kc, at, 64),
+        ((n, 2, g, D128), BF16), ((tables, MB, 4 * 2 * D128), BF16), ((n,), jnp.int32))
+    # ``readers/sala.py`` reads the attend kernel's time by ITS name
+    assert ss.KERNEL in text and "paged_sparse_attention" not in ss.KERNEL
+    assert " sort(" not in text
+    assert not re.search(rf"f32\[{n},2,{g},{4 * MB}\]", text)
+
+
 def test_the_hybrid_step_walks_its_runs_of_layers(chip):
     """The whole step of a MiniCPM-SALA stack of S L L S S at the published
     widths: a scan a run of one kind; in a run of sparse layers the kernel
@@ -370,6 +391,12 @@ def test_the_hybrid_step_walks_its_runs_of_layers(chip):
     # nothing as large as a row's chosen keys (64 pages x 64 keys x 128 lanes)
     # times the rows is ever made: the selection went into the table
     assert not re.search(rf"bf16\[{2 * rows},(64|128),64,128\]", text)
+    # the selection: the kernel for the decode rows and for the chunk, a layer
+    # (S, and S S under one scan); no sort under its scope, and the 16 heads'
+    # scores of the chunk's queries over 3,072 compressed keys never in HBM
+    assert text.count("sparse_block_scores") >= 4
+    assert not [l for l in text.splitlines() if " sort(" in l and "sparse_select" in l]
+    assert not re.search(rf"f32\[{chunk},2,16,3072\]", text)
 
 
 def test_paged_gqa_kernel_compiles_at_zaya_heads(chip):

@@ -37,8 +37,8 @@ from deepspeed_tpu.testing import fault_injection
 # the names ``ops.pallas.use_kernel`` is asked (its docstring's list)
 KERNELS = ("ce", "fused_adam", "flash_attention", "decode_attention", "paged_attention",
            "paged_gqa_attention", "paged_mla_attention", "paged_sparse_attention",
-           "grouped_matmul", "delta_state_update", "mamba_state_update",
-           "mamba_chunk_scan")
+           "sparse_block_scores", "grouped_matmul", "delta_state_update",
+           "mamba_state_update", "mamba_chunk_scan")
 
 
 def _traced_under():

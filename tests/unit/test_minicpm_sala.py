@@ -264,12 +264,15 @@ def test_the_steps_stats_are_what_the_rows_lengths_give(tiny):
         assert st["sparse_keys_attended"] == per * int(
             hybrid.keys_attended(model.cfg, t).sum())
         assert st["sparse_rows_dense"] == int((t + 1 <= 64).sum())
+        assert st["sparse_rows_selected"] == 3 * int((t + 1 > 64).sum())
+        assert st["sparse_rows_selected"] + 3 * st["sparse_rows_dense"] == 3 * len(t)
         assert st["state_bytes"] == 3 * SLOTS * 4 * 16 * 16 * 4
     eng.close()
     assert sum(s["state_slots_reset"] for s in seen if "state_slots_reset" in s) == 2
     checked = [s for s in seen if s.get("sparse_keys_attended")]
     assert any(s["sparse_keys_attended"] < s["sparse_keys_resident"] for s in checked)
     assert any(s["sparse_rows_dense"] for s in checked)
+    assert any(s["sparse_rows_selected"] for s in checked)
     assert all("pages_full" in s for s in seen if s["programs"])
 
 
