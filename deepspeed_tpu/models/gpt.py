@@ -96,7 +96,10 @@ class IndexerSpec(NamedTuple):
     the Keye-VL-2.0 line's ``sa_config``): ``heads`` index queries of
     ``head_dim`` lanes a token score every cached index key (ONE of
     ``head_dim`` lanes a token a layer), and the query attends the ``topk``
-    TOKENS it scores highest (``models/hybrid.py:indexed_mixer``)."""
+    TOKENS it scores highest: over K and V heads the hybrid walk's
+    ``indexed`` mixer (``models/hybrid.py:indexed_mixer``), over a LATENT
+    cache (``kv_lora_rank``) the periodic walk's own (``gpt_paged_step``),
+    both through ``models/hybrid.py:select_and_attend``."""
     heads: int = 16
     head_dim: int = 64
     topk: int = 2048
@@ -262,6 +265,12 @@ class GPTConfig:
     # chip's share of an expert-parallel layer); what the others would add
     # is left out and the partial result goes on
     moe_experts_held: Optional[Tuple[int, int]] = None
+    # the sigmoid router chooses inside ``moe_topk_group`` of ``moe_n_group``
+    # groups of consecutive experts, a group scored by the sum of its two
+    # largest ``score + bias`` (the DeepSeek-V3 line's ``n_group``,
+    # ``topk_group``; 1 and 1: no limit)
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
     # --- latent attention (MLA, the DeepSeek-V2 line); ``kv_lora_rank``
     # selects it.  The query goes down to ``q_lora_rank``, through an RMSNorm
     # and up to ``n_head`` heads of ``head_dim``, whose LAST ``qk_rope_dim``
@@ -288,7 +297,12 @@ class GPTConfig:
     residual_scale: float = 1.0
     head_divisor: float = 1.0
     published_layers: Optional[int] = None
-    # the ``indexed`` layers' indexer (``models/hybrid.py:indexed_mixer``)
+    # the ``indexed`` layers' indexer (``models/hybrid.py:indexed_mixer``);
+    # beside ``kv_lora_rank`` in a periodic stack, EVERY layer's: the index
+    # queries come from the query's latent, the first ``qk_rope_dim`` lanes
+    # of an index head are rotated (half-split pairs, the model's
+    # frequencies), and the chosen tokens are rows of the latent cache
+    # (DeepSeek-V3.2-Exp: ``gpt_paged_step``)
     indexer: Optional[IndexerSpec] = None
     # --- the gated delta rule (the Olmo-Hybrid family's ``delta`` layers,
     # ``models/hybrid.py:delta_mixer``): ``delta_heads`` heads, a key of
@@ -355,6 +369,14 @@ class GPTConfig:
                     f"{self.moe_router} router")
         # (first, count) of the experts the bank holds, whole or a share
         self.bank_experts = self.moe_experts_held or (0, self.moe_num_experts)
+        assert 1 <= self.moe_topk_group <= self.moe_n_group and (
+            self.moe_n_group == 1 or (
+                self.moe_scoring == "sigmoid" and self.moe_router == "dropless"
+                and self.moe_num_experts % self.moe_n_group == 0
+                and self.moe_num_experts // self.moe_n_group >= 2)), (
+                    f"moe_topk_group {self.moe_topk_group} of moe_n_group "
+                    f"{self.moe_n_group}: whole groups of two experts or more "
+                    f"behind the dropless sigmoid router")
         self.v_head_dim = self.v_head_dim or self.head_dim
         if self.rope_yarn is not None:
             self.rope_yarn = YarnRope(*self.rope_yarn)
@@ -435,6 +457,26 @@ class GPTConfig:
             assert not self.moe_router_hidden, (
                 "the router's stream is a second carry of the layer walk, "
                 "which models/hybrid.py alone has")
+            if self.indexer is not None:
+                self.indexer = IndexerSpec(*self.indexer)
+                assert self.kv_lora_rank, (
+                    "an indexer in a periodic stack selects rows of a LATENT "
+                    "cache (kv_lora_rank); over K and V heads it is the "
+                    "hybrid walk's 'indexed' mixer (LayerKind.mixer)")
+                assert self.pattern[0].window is None, (
+                    "an indexer over a latent under a window is not written")
+                assert self.qk_rope_dim <= self.indexer.head_dim, (
+                    "the first qk_rope_dim lanes of an index head are rotated")
+
+    @property
+    def indexed_layers(self) -> int:
+        """Layers with a lightning indexer, each a page of index keys under
+        every block (``models/hybrid.py:init_aux``'s ``ki``): a hybrid
+        stack's ``indexed`` layers, or every layer of a periodic stack whose
+        latent attention has an ``indexer``."""
+        if self.hybrid:
+            return self.mixers.count("indexed")
+        return self.n_layer if self.indexer is not None else 0
 
     @property
     def arena_layout(self) -> Tuple[int, int, Tuple[int, ...]]:
@@ -648,6 +690,50 @@ def trinity_config(vocab_size=200192, n_positions=262144, n_embd=3072,
               moe_dense_layers=dense_layers, moe_router="dropless",
               moe_scoring="sigmoid", moe_norm_topk=True,
               moe_route_scale=route_scale, moe_shared_experts=shared_experts,
+              moe_experts_held=tuple(experts_held) if experts_held else None)
+    kw.update(overrides)
+    return llama_config(vocab_size=vocab_size, n_positions=n_positions,
+                        n_embd=n_embd, n_layer=n_layer, n_head=n_head,
+                        intermediate_size=intermediate_size, **kw)
+
+
+def deepseek_v32_config(vocab_size=129280, n_positions=163840, n_embd=7168,
+                        n_layer=61, n_head=128, head_dim=192, q_lora_rank=1536,
+                        kv_lora_rank=512, qk_rope_dim=64, v_head_dim=128,
+                        intermediate_size=18432, moe_intermediate_size=2048,
+                        num_experts=256, top_k=8, n_group=8, topk_group=4,
+                        shared_experts=1, dense_layers=3, route_scale=2.5,
+                        experts_held=None, indexer=(64, 128, 2048),
+                        rope_yarn=(40.0, 4096, 32.0, 1.0, 1.0, 1.0, 0.0),
+                        **overrides) -> GPTConfig:
+    """DeepSeek-V3.2-Exp family (``model_type`` deepseek_v32; defaults: the
+    published 671B-A37B): Mistral 4's layer (:func:`mistral4_config`: latent
+    attention, ``head_dim`` a head's key lanes, the last ``qk_rope_dim``
+    rotated in interleaved pairs under YaRN) under DeepSeek Sparse Attention:
+    a lightning ``indexer`` (heads, lanes a head, ``topk``:
+    :class:`IndexerSpec`) whose queries come from the QUERY'S LATENT scores
+    every cached token against ONE index key a token (a LayerNorm, the first
+    ``qk_rope_dim`` lanes rotated in half-split pairs), and the 128 heads
+    attend the ``topk`` rows of the latent cache it scores highest, in the
+    absorbed form; the first ``dense_layers`` layers a dense SwiGLU
+    ``intermediate_size`` wide, every later one a bank of SwiGLU experts
+    ``moe_intermediate_size`` wide behind a dropless sigmoid router limited
+    to ``topk_group`` of ``n_group`` groups (the ``top_k`` largest of score +
+    bias among them, weighed by their scores renormalised times
+    ``route_scale``) beside ``shared_experts`` every token goes through;
+    ``experts_held = (first, count)`` keeps one chip's share of the bank.
+    RMSNorm (eps 1e-6), no bias, untied head.  No multi-token prediction
+    module.  Served through ``init_serving()``; the dense paths refuse it."""
+    kw = dict(head_dim=head_dim, q_lora_rank=q_lora_rank,
+              kv_lora_rank=kv_lora_rank, qk_rope_dim=qk_rope_dim,
+              v_head_dim=v_head_dim, rope_interleaved=True,
+              rope_yarn=tuple(rope_yarn), ln_eps=1e-6, indexer=tuple(indexer),
+              moe_num_experts=num_experts, moe_top_k=top_k,
+              moe_expert_hidden=moe_intermediate_size,
+              moe_dense_layers=dense_layers, moe_router="dropless",
+              moe_scoring="sigmoid", moe_norm_topk=True,
+              moe_route_scale=route_scale, moe_n_group=n_group,
+              moe_topk_group=topk_group, moe_shared_experts=shared_experts,
               moe_experts_held=tuple(experts_held) if experts_held else None)
     kw.update(overrides)
     return llama_config(vocab_size=vocab_size, n_positions=n_positions,
@@ -886,6 +972,16 @@ def _init_attn(cfg: GPTConfig, rng: Array) -> Dict:
             kv_a_norm_g=jnp.ones((R,), jnp.float32),
             kv_b_w=_dense_init(ka[3], R, (R, H * (
                 cfg.head_dim - cfg.qk_rope_dim + cfg.v_head_dim))))
+        if cfg.indexer is not None:
+            # the lightning indexer: index queries from the query's latent;
+            # [W_kI | W_w] from the layer's input; the index key's LayerNorm
+            ix = cfg.indexer
+            ki = jax.random.split(jax.random.fold_in(rng, 8642), 2)
+            out.update(
+                index_q_w=_dense_init(ki[0], Rq, (Rq, ix.heads * ix.head_dim)),
+                index_kw_w=_dense_init(ki[1], E, (E, ix.head_dim + ix.heads)),
+                ik_norm_g=jnp.ones((ix.head_dim,), jnp.float32),
+                ik_norm_b=jnp.zeros((ix.head_dim,), jnp.float32))
     return out
 
 
@@ -1036,6 +1132,10 @@ def gpt_partition_specs(cfg: GPTConfig) -> Dict:
                         kv_a_w=PartitionSpec(None, None),
                         kv_a_norm_g=PartitionSpec(),
                         kv_b_w=PartitionSpec(None, "tensor"))
+            if cfg.indexer is not None:
+                keys.update(index_q_w=PartitionSpec(None, None),
+                            index_kw_w=PartitionSpec(None, None),
+                            ik_norm_g=PartitionSpec(), ik_norm_b=PartitionSpec())
         specs = {k: PartitionSpec(*pre, *s) for k, s in keys.items()}
         if cfg.moe_dense_layers:
             # the dense lead's MLP, a stack of its own
@@ -1122,17 +1222,22 @@ def yarn_inv_freq(rd: int, theta: float, yarn: YarnRope) -> Array:
     pair ``i`` keeps ``theta^(-2i/rd)`` below the correction dimension of
     ``beta_fast`` (it turns often enough in the original range), takes it
     over ``factor`` above that of ``beta_slow``, and blends the two on the
-    linear ramp between."""
+    linear ramp between.  Worked out on the host in float64 and rounded
+    once: the chip's float32 ``pow`` is good to about 1e-6, which 30,000
+    positions on turn into 0.03 rad of the fastest pairs (PERF.md section 6,
+    PR 61: the program in float32 then read a noise scale of 0.46 against
+    its reference there, and 0.0 under 5,000 positions)."""
+    import numpy as np
     half = rd // 2
-    base = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    i = np.arange(half, dtype=np.float64)
+    base = float(theta) ** (-i / half)
     # the pair that makes ``turns`` turns over the original positions
     dim_of = lambda turns: rd * math.log(
         yarn.original_positions / (turns * 2 * math.pi)) / (2 * math.log(theta))
     low = max(math.floor(dim_of(yarn.beta_fast)), 0)
     high = min(math.ceil(dim_of(yarn.beta_slow)), rd - 1)
-    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
-                    / max(high - low, 1e-3), 0.0, 1.0)
-    return base / yarn.factor * ramp + base * (1.0 - ramp)
+    ramp = np.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return jnp.asarray(base / yarn.factor * ramp + base * (1.0 - ramp), jnp.float32)
 
 
 def apply_rope(x: Array, positions: Array, theta: float = 10000.0,
@@ -1222,20 +1327,49 @@ def _project_qkv(cfg: "GPTConfig", p: Dict, h: Array, dt, positions: Array,
     return q, k, v
 
 
-def _latent_project(cfg: "GPTConfig", p: Dict, h: Array, dt, positions: Array):
+def _query_latent(cfg: "GPTConfig", p: Dict, h: Array, dt) -> Array:
+    """The query's latent ``c^Q = RMSNorm(W_DQ h)`` ``[B, S, q_lora_rank]``:
+    what the heads' queries and, under an indexer, the index queries come up
+    from."""
+    return rms_norm(h @ _wget(p, "q_a_w", dt), p["q_a_norm_g"], eps=cfg.ln_eps)
+
+
+def _index_project(cfg: "GPTConfig", p: Dict, cq: Array, h: Array, dt,
+                   positions: Array):
+    """The lightning indexer's projections over a latent layer: -> (index
+    queries ``[B, S, heads, lanes]`` from the query's latent ``cq``, the
+    token's ONE index key ``[B, S, lanes]`` (a LayerNorm with gain and bias)
+    and the heads' weights ``[B, S, heads]`` float32 from the normed input
+    ``h``).  The first ``qk_rope_dim`` lanes of an index head and of the key
+    are rotated, HALF-SPLIT pairs at the model's own frequencies (the
+    attention's rope pairs are interleaved)."""
+    ix, B, S = cfg.indexer, *h.shape[:2]
+    rope = lambda t: apply_rope(t, positions, cfg.rope_theta,
+                                rope_dim=cfg.qk_rope_dim, yarn=cfg.rope_yarn)
+    qi = rope((cq @ _wget(p, "index_q_w", dt)).reshape(B, S, ix.heads, ix.head_dim))
+    kw = h @ _wget(p, "index_kw_w", dt)
+    ki = layer_norm(kw[..., :ix.head_dim], p["ik_norm_g"], p["ik_norm_b"],
+                    eps=cfg.ln_eps)
+    return qi, rope(ki[:, :, None])[:, :, 0], kw[..., ix.head_dim:].astype(jnp.float32)
+
+
+def _latent_project(cfg: "GPTConfig", p: Dict, h: Array, dt, positions: Array,
+                    cq: Optional[Array] = None):
     """Latent attention's projections of the normed input ``h [B, S, E]`` ->
     (q ``[B,S,H,head_dim]``: a head's lanes ``[no position | rotated]``,
     cache ``[B,S,kv_lora_rank + qk_rope_dim]``: ``[normed latent | the ONE
     rotated key]``), which is all that either form of the attention reads.
     The softmax's YaRN scale (``mscale_all_dim``: the scores times
     :func:`yarn_mscale` squared) and the query's scale by its position are
-    IN q, so both forms divide by ``sqrt(head_dim)`` and nothing else."""
+    IN q, so both forms divide by ``sqrt(head_dim)`` and nothing else.
+    ``cq``: the query's latent where the caller has it (:func:`_query_latent`)."""
     B, S, _ = h.shape
     H, R, dr = cfg.n_head, cfg.kv_lora_rank, cfg.qk_rope_dim
     yarn = cfg.rope_yarn
     rope = lambda t: apply_rope(t, positions, cfg.rope_theta,
                                 interleaved=cfg.rope_interleaved, yarn=yarn)
-    cq = rms_norm(h @ _wget(p, "q_a_w", dt), p["q_a_norm_g"], eps=cfg.ln_eps)
+    if cq is None:
+        cq = _query_latent(cfg, p, h, dt)
     q = (cq @ _wget(p, "q_b_w", dt)).reshape(B, S, H, cfg.head_dim)
     q = jnp.concatenate([q[..., :-dr], rope(q[..., -dr:])], axis=-1)
     if yarn is not None:
@@ -1371,7 +1505,8 @@ def _ffn(cfg: "GPTConfig", p: Dict, h: Array, dt, rng=None,
                 if cfg.moe_scoring == "sigmoid":
                     probs, weights, experts = dropless.sigmoid_topk(
                         logits, cfg.moe_top_k, p["moe"]["gate"]["bias"],
-                        cfg.moe_norm_topk, cfg.moe_route_scale)
+                        cfg.moe_norm_topk, cfg.moe_route_scale,
+                        cfg.moe_n_group, cfg.moe_topk_group)
                 else:
                     probs, weights, experts = dropless.softmax_topk(
                         logits, cfg.moe_top_k, cfg.moe_norm_topk)
@@ -1597,6 +1732,12 @@ def _refuse_hybrid(cfg: "GPTConfig", path: str) -> None:
         raise NotImplementedError(
             f"{path} has {hybrid.what_a_dense_path_lacks(cfg)}; serve this "
             f"stack through init_serving() (models/hybrid.py)")
+    if cfg.indexer is not None:
+        raise NotImplementedError(
+            f"{path} has no lightning indexer, no cache of index keys and no "
+            f"selection of the rows of the latent cache a query attends for "
+            f"the {cfg.n_layer} layers; serve this stack through "
+            f"init_serving() (gpt_paged_step)")
 
 
 def _window_bias(S: int, window: int) -> Array:
@@ -2139,11 +2280,19 @@ def gpt_generate(cfg: GPTConfig, params: Dict, input_ids: Array,
 # through each row's block table, so batch composition can change every step
 # without recompiling (tables/positions are traced int32 inputs).
 # --------------------------------------------------------------------------- #
+# Extents of its table a prompt chunk of an indexed latent layer is compiled
+# for (``models/hybrid.py:select_and_attend``): a chunk scores, selects among
+# and attends the least eighth-multiple of the table that holds its last
+# position, so a prompt's cost grows with what it has cached
+CHUNK_EXTENTS = 8
+
+
 def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
                    positions: Array, k_pages: Array, v_pages: Array,
                    block_tables: Array, write_blocks: Array,
                    write_offsets: Array, with_expert_counts: bool = False,
-                   chunk: int = 0):
+                   chunk: int = 0, aux: Optional[Dict] = None, slots=None,
+                   live=None):
     """One fused step over the paged arena.
 
     ``input_ids`` [B, S] — a row holds S consecutive tokens of one sequence;
@@ -2178,6 +2327,20 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
     ``with_expert_counts`` (an MoE model) a fourth: the assignments per
     expert ``[experts]`` int32, summed over layers, of the rows that carry a
     request (those whose K/V does not go to the trash block).
+
+    Latent attention under an ``indexer`` (``cfg.indexed_layers``; the
+    DeepSeek-V3.2-Exp line) takes and returns ``aux`` behind the pages, as a
+    hybrid stack's step does (``{"ki": [n_layer, blocks, BS, index lanes]}``,
+    ``models/hybrid.py:init_aux``; ``slots`` and ``live`` are the hybrid
+    walk's and are not read here): a token's index key is written under the
+    same table as its latent, the layer's rows select through
+    ``models/hybrid.py:select_and_attend`` and attend the chosen rows of the
+    latent cache (``ops/pallas/indexed_attention.py``: a decode row
+    ``chosen_latent_attention``, the absorbed form over gathered rows; the
+    prompt chunk ``masked_latent_attention``, the plain form over its
+    sequence under a mask), under the scope ``attn_indexed``.  Tables of
+    ``topk`` positions or fewer select nothing: the layer is
+    ``paged_mla_attention`` over every key.
 
     The walk (:func:`_walk_layers`) hands a layer the STACK and the layer's
     index, and every leaf is sliced where it is read (:class:`_LayerLeaves`).
@@ -2223,8 +2386,17 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
     pos2d = positions[:, None] + jnp.arange(S)[None]          # [B, S]
     # the rows that carry a request; ``live[B - chunk]``, the chunk's first
     # row, says whether the step carries a chunk at all
+    del slots, live
     live = (write_blocks[0] != 0).reshape(-1)
     by_row = partial(_rows_that_carry, chunk=chunk, live=live)
+    # whether the layers select the rows of the latent cache they attend
+    indexed = cfg.indexer is not None and T > cfg.indexer.topk
+    assert not indexed or (aux is not None and S == 1 and (
+        not mesh_lib.has_mesh() or mesh_lib.get_mesh().size == 1)), (
+            "an indexer over a latent: a token a row, the index keys' pages "
+            "in aux, one device (under a mesh it is not written)")
+    scope = lambda name: jax.named_scope(name) if indexed else nullcontext()
+    mixer_scope = partial(scope, "attn_indexed")
 
     x = _embed(cfg, params["wte"], input_ids, dt)
     if cfg.position_encoding == "learned":
@@ -2282,13 +2454,20 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
             q, k, v = _project_qkv(cfg, p, h, dt, pos, cfg.pattern[j])
             return (h, q, k.astype(pages_dt).reshape(*k.shape[:2], -1),
                     v.astype(pages_dt).reshape(*v.shape[:2], -1))
-        q, cache = _latent_project(cfg, p, h, dt, pos)
-        # a head's query in the cached vector's lanes: its no-position part
-        # through W_UK, its rotated part as it is
-        q = jnp.concatenate(
-            [jnp.einsum("bshd,rhd->bshr", q[..., :-dr],
-                        _latent_up(cfg, p, dt)[0]), q[..., -dr:]], axis=-1)
-        return h, lanes(q, W), lanes(cache.astype(pages_dt), W), None
+        cq = _query_latent(cfg, p, h, dt)
+        with scope("latent_project"):
+            plain, cache = _latent_project(cfg, p, h, dt, pos, cq)
+            # a head's query in the cached vector's lanes: its no-position
+            # part through W_UK, its rotated part as it is
+            q = jnp.concatenate(
+                [jnp.einsum("bshd,rhd->bshr", plain[..., :-dr],
+                            _latent_up(cfg, p, dt)[0]), plain[..., -dr:]], axis=-1)
+        out = h, lanes(q, W), lanes(cache.astype(pages_dt), W), None
+        if not indexed:
+            return out
+        with jax.named_scope("index_score"):
+            qi, ki, w = _index_project(cfg, p, cq, h, dt, pos)
+        return (*out, plain, qi[:, 0], ki[:, 0], w[:, 0])
 
     @partial(jax.jit, static_argnums=0)
     def tail(dense, stacks, l, x, h, o, live):
@@ -2300,30 +2479,72 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
             p = p.beside(by_kind["lead"], l)
         elif lead:
             p = p.beside(by_kind["moe"], l - lead)
-        with jax.named_scope("attn"):
-            if cfg.kv_lora_rank:
-                o = jnp.einsum("bshr,rhd->bshd", o, _latent_up(cfg, p, dt)[1])
+        with jax.named_scope("attn"), mixer_scope():
+            if cfg.kv_lora_rank and not indexed:    # an indexed layer's heads are up already
+                with scope("latent_project"):
+                    o = jnp.einsum("bshr,rhd->bshd", o, _latent_up(cfg, p, dt)[1])
             o = _attn_out(cfg, p, o.reshape(*o.shape[:2], -1), h, dt)
         with jax.named_scope("mlp"):
             return _block_tail(
                 cfg, p, x, h, o, dt, live,
                 bank_at=None if bank is None or dense else (bank, l - lead))
 
+    def attend_chosen(q, plain, up, kp, index, ki, li, j):
+        """The rows' attention over the rows of the latent cache their
+        indexer chose: -> (the heads' outputs ``[B, 1, H, v_head_dim]``, the
+        index keys' pages with the rows' keys written).  A decode row gathers
+        its ``topk`` rows of layer ``li``'s pages and attends them in the
+        absorbed form (``q [B, 1, H, W]``), its output brought up through
+        W_UV; the prompt chunk's queries (``plain [B, 1, H, head_dim]``), a
+        set of its own each, attend their sequence's rows, read once, in the
+        plain form under the selection's mask, and no more of them than the
+        chunk can see (:data:`CHUNK_EXTENTS`).  ``up``: the layer's W_UK and
+        W_UV (:func:`_latent_up`)."""
+        from deepspeed_tpu.models import hybrid
+        from deepspeed_tpu.ops.pallas.indexed_attention import (
+            chosen_latent_attention, masked_latent_attention)
+        n_dec = B - chunk
+
+        def attend_rows(tb, at, real):
+            rows = kp[li, jnp.take_along_axis(tb, at // BS, axis=1), at % BS]
+            o = chosen_latent_attention(q[:n_dec, 0], rows, real, scale=plans[j].scale,
+                                        value_lanes=cfg.kv_lora_rank)
+            return jnp.einsum("nhr,rhd->nhd", o, up[1])
+
+        def attend_chunk(tb, chosen, last):
+            del last
+            return masked_latent_attention(
+                plain[n_dec:, 0], kp[li, tb[0]].reshape(chosen.shape[1], -1), chosen,
+                *up, scale=plans[j].scale)
+
+        step = hybrid._Step(positions, live, None, block_tables[j],
+                            write_blocks[j], write_offsets, chunk, dt, None)
+        o, ki = hybrid.select_and_attend(
+            cfg, *index, ki, li, step, BS, attend_rows, attend_chunk,
+            extents=CHUNK_EXTENTS)
+        return o[:, None], ki
+
     def layer(j, carry, l):
         # ``l``: the layer's index in the stack, a Python int for the layers
         # walked before the scan, which says whether it is of the dense lead;
-        # ``li``: its index inside its group ``j`` (the period)
-        x, kp, vp, li = carry
+        # ``li``: its index inside its group ``j`` (the period); ``ki``: the
+        # index keys' pages (None without an indexer)
+        x, kp, vp, li, ki = carry
         kind, wblocks = cfg.pattern[j], write_blocks[j]
         dense = isinstance(l, int) and l < lead
         at = jnp.asarray(l, jnp.int32)
-        with jax.named_scope("attn"):
-            h, q, k, v = by_row(partial(project, j, blocks, at), (x, pos2d))
+        with jax.named_scope("attn"), mixer_scope():
+            h, q, k, v, *index = by_row(partial(project, j, blocks, at), (x, pos2d))
             # scatter the new K/V into the arena through the write map; rows
             # that must not write (padding, inactive slots) carry trash-block
             # coordinates, so the scatter itself needs no predication
             kp = kp.at[li, wblocks, write_offsets].set(k)
-            if cfg.kv_lora_rank:
+            if indexed:
+                plain, *index = index
+                with scope("latent_project"):
+                    up = _latent_up(cfg, _LayerLeaves(blocks, at), dt)
+                o, ki = attend_chosen(q, plain, up, kp, index, ki, li, j)
+            elif cfg.kv_lora_rank:
                 with jax.named_scope("attn_latent"):
                     o = plans[j].attend(
                         q, (kp, None), li, block_tables[j], positions,
@@ -2339,16 +2560,17 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
                            (x, h, o, live), totals=1)
         if dense:
             counts = jnp.zeros((cfg.moe_num_experts,), jnp.int32)
-        return (x, kp, vp, li + int(j == n_kinds - 1)), counts
+        return (x, kp, vp, li + int(j == n_kinds - 1), ki), counts
 
     # behind a dense lead, the layers up to the first whole period of expert
     # layers are walked here at static indices; the scan takes the rest
-    carry = (x, k_pages, v_pages, jnp.zeros((), jnp.int32))
+    carry = (x, k_pages, v_pages, jnp.zeros((), jnp.int32),
+             aux["ki"] if indexed else None)
     first, walked = -(-lead // n_kinds) * n_kinds, []
     for l in range(first):
         carry, c = layer(l % n_kinds, carry, l)
         walked.append(c)
-    (x, k_pages, v_pages, _), counts = _walk_layers(
+    (x, k_pages, v_pages, _, ki), counts = _walk_layers(
         n_kinds, layer, carry, cfg.n_layer, first)
     if walked:
         counts = jnp.concatenate([jnp.stack(walked), counts])
@@ -2363,9 +2585,12 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
 
     with jax.named_scope("head"):
         logits = by_row(to_logits, (x,))
+    out = (logits, k_pages, v_pages)
+    if aux is not None:
+        out += (dict(aux, ki=ki) if indexed else aux,)
     if with_expert_counts:
-        return logits, k_pages, v_pages, counts.sum(axis=0)
-    return logits, k_pages, v_pages
+        out += (counts.sum(axis=0),)
+    return out
 
 
 # --------------------------------------------------------------------------- #
@@ -2578,6 +2803,10 @@ class GPT:
             qkv = (E * Rq + Rq + Rq * H * cfg.head_dim
                    + E * (R + cfg.qk_rope_dim) + R
                    + R * H * (cfg.head_dim - cfg.qk_rope_dim + cfg.v_head_dim))
+            if cfg.indexer is not None:     # queries, [key | weights], LayerNorm
+                ix = cfg.indexer
+                qkv += (Rq * ix.heads * ix.head_dim
+                        + E * (ix.head_dim + ix.heads) + 2 * ix.head_dim)
         else:
             qkv = E * cfg.qkv_dim + b * cfg.qkv_dim      # qkv (GQA-sized)
         per_block = (qkv + gate + cfg.n_head * cfg.v_head_dim * E + b * E  # attn out

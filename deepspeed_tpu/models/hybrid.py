@@ -96,6 +96,7 @@ rows alone (:func:`_rows_that_carry`).
 """
 
 import math
+from functools import partial
 from typing import Any, Dict, List, NamedTuple, Tuple
 
 import jax
@@ -401,10 +402,12 @@ def init_aux(cfg, num_blocks: int, block_size: int, slots: int, dtype) -> Dict:
     ``ki [indexed layers, num_blocks, block_size,
     index lanes]``: the index keys of a page, reached through the block
     tables like K and V (allocated, freed and re-bound with them: a slot
-    bound to a new sequence scores no former tenant's, BY POSITION)."""
+    bound to a new sequence scores no former tenant's, BY POSITION); the one
+    entry a periodic stack with an indexer over its latent cache has
+    (``GPTConfig.indexed_layers``)."""
     D, out = cfg.head_dim, {}
-    if "indexed" in cfg.mixers:
-        out["ki"] = jnp.zeros((cfg.mixers.count("indexed"), num_blocks,
+    if cfg.indexed_layers:
+        out["ki"] = jnp.zeros((cfg.indexed_layers, num_blocks,
                                block_size, cfg.indexer.head_dim), dtype)
     if "delta" in cfg.mixers:
         L, H = cfg.mixers.count("delta"), cfg.delta_heads
@@ -1151,17 +1154,108 @@ def index_scores(cfg, qi: Array, w: Array, keys: Array) -> Array:
         ix.head_dim * ix.heads)
 
 
-# queries of a prompt chunk that are scored and select together: a tile's
-# scores are ``[tile, heads, keys]`` float32 (128 x 16 x 46,080 x 4 B = 377 MB)
+# queries of a prompt chunk that are scored and select together at 16 index
+# heads: a tile's scores are ``[tile, heads, keys]`` float32 (128 x 16 x 46,080
+# x 4 B = 377 MB); an indexer of more heads takes as many fewer queries, one
+# of fewer no more
 _CHUNK_TILE = 128
 
 
-def indexed_chunk_tile(chunk: int) -> int:
-    """Queries of a prompt chunk of ``chunk`` that select together: the whole
-    chunk, or a tile of it; 0 where the chunk is not whole tiles (which
-    ``init_serving`` refuses)."""
-    tile = min(chunk, _CHUNK_TILE)
+def indexed_chunk_tile(chunk: int, heads: int = 16) -> int:
+    """Queries of a prompt chunk of ``chunk`` that select together under an
+    indexer of ``heads`` heads: the whole chunk, or a tile of it; 0 where the
+    chunk is not whole tiles (which ``init_serving`` refuses)."""
+    tile = min(chunk, _CHUNK_TILE, max(_CHUNK_TILE * 16 // heads, 8))
     return tile if chunk % tile == 0 else 0
+
+
+def select_and_attend(cfg, qi, ki, w, pages, li, step: _Step, BS: int,
+                      attend_rows, attend_chunk, extents: int = 1):
+    """What every indexed layer does between its projections and its
+    attention, whatever it caches (K and V heads: :func:`indexed_mixer`; a
+    latent: ``models/gpt.py:gpt_paged_step``).  ``qi [B, heads, lanes]``, ``ki
+    [B, lanes]`` and ``w [B, heads]`` float32 are the rows' index queries, index
+    key and heads' weights; ``pages [layers, blocks, BS, lanes]`` the index
+    keys' pages, layer ``li`` of which gets the rows' keys written under
+    their tables.  Then
+
+        I_{t,s} = (lanes heads)^-1/2 sum_j w_{t,j} relu(qI_{t,j} . kI_s), s <= t
+        S_t = the topk positions s <= t of largest I_{t,s} (ties: the lower)
+
+    A decode row gathers its index keys under its own table and sorts its
+    scores for the positions: ``attend_rows(tables [n, MB], at [n, K], real
+    [n, K]) -> [n, ...]``.  The prompt chunk's rows share one table and
+    select a tile of queries at a time (:func:`indexed_chunk_tile`), by
+    bisection a mask: ``attend_chunk(table [1, pages], chosen [chunk, pages
+    * BS], last) -> [chunk, ...]``, ``last`` the chunk's last live position.
+    With ``extents`` above 1 the chunk scores, selects among and attends the
+    first ``i / extents`` of its table alone, the least that holds ``last``
+    (a branch of one ``switch`` an extent: a prompt's early chunks pay for
+    the keys they can see and not for the table's width).  A step
+    without a prompt chunk skips the chunk rows' scores, their selection and
+    their attend: nobody reads them.  The scopes ``index_score``,
+    ``index_topk`` and ``index_attend`` are opened here.  -> (the rows'
+    attention ``[B, ...]``, pages)."""
+    positions, live, _, tables, write_blocks, write_offsets, chunk, _, _, _ = step
+    ix = cfg.indexer
+    B, T = qi.shape[0], tables.shape[1] * BS
+    K, n_dec = min(ix.topk, T), B - chunk
+    with jax.named_scope("index_score"):
+        pages = pages.at[li, write_blocks, write_offsets].set(
+            ki.astype(pages.dtype)[:, None])
+
+    def scores_of(qi, w, at, keys):
+        """``I_{t,s}`` of the queries at positions ``at [n]`` over their
+        sequences' index keys ``keys [n | 1, keys, lanes]``, -inf past each."""
+        with jax.named_scope("index_score"):
+            return jnp.where(jnp.arange(keys.shape[1])[None] <= at[:, None],
+                             index_scores(cfg, qi, w, keys), -jnp.inf)
+
+    def under(tb):
+        """The index keys under the tables ``tb [n, pages]``, in logical order."""
+        with jax.named_scope("index_score"):
+            return pages[li, tb].reshape(tb.shape[0], -1, ix.head_dim)
+
+    def decode_rows():
+        tb = tables[:n_dec]
+        scores = scores_of(qi[:n_dec], w[:n_dec], positions[:n_dec], under(tb))
+        with jax.named_scope("index_topk"):
+            at, real = chosen_positions(scores, K)
+        with jax.named_scope("index_attend"):
+            return attend_rows(tb, at, real)
+
+    def over_the_chunk(last, n_pages):
+        """The chunk's rows over the first ``n_pages`` of their table."""
+        tile = indexed_chunk_tile(chunk, ix.heads)
+        assert tile, f"a chunk of {chunk} is not whole tiles of queries that select together"
+        tb = tables[n_dec:n_dec + 1, :n_pages]
+        keys = under(tb)
+        tiles = lambda a: a[n_dec:].reshape(chunk // tile, tile, *a.shape[1:])
+
+        def choose(a):
+            scores = scores_of(*a, keys)
+            with jax.named_scope("index_topk"):
+                return chosen_tokens(scores, min(ix.topk, n_pages * BS), BS)
+
+        chosen = jax.lax.map(choose, (tiles(qi), tiles(w), tiles(positions)))
+        with jax.named_scope("index_attend"):
+            return attend_chunk(tb, chosen.reshape(chunk, -1), last)
+
+    def chunk_rows():
+        last = jnp.max(jnp.where(live[n_dec:], positions[n_dec:], 0))
+        per = -(-tables.shape[1] // extents)
+        widths = sorted({min(i * per, tables.shape[1]) for i in range(1, extents + 1)})
+        if len(widths) == 1:
+            return over_the_chunk(last, widths[0])
+        return jax.lax.switch(last // (per * BS),
+                              [partial(over_the_chunk, n_pages=n) for n in widths], last)
+
+    o = decode_rows()
+    if chunk:
+        o = jnp.concatenate([o, jax.lax.cond(
+            live[n_dec], chunk_rows,
+            lambda: jnp.zeros((chunk, *o.shape[1:]), o.dtype))])
+    return o, pages
 
 
 def indexed_mixer(cfg, p, h, kp, vp, held, li, step: _Step):
@@ -1171,25 +1265,24 @@ def indexed_mixer(cfg, p, h, kp, vp, held, li, step: _Step):
 
         q_t = rope(rms_h(W_q h_t)), k_t = rope(rms_h(W_k h_t)), v_t = W_v h_t
         qI_{t,j} = rope(W_qI h_t)_j, kI_t = rope(LN(W_kI h_t)), w_t = W_w h_t
-        I_{t,s} = (lanes heads)^-1/2 sum_j w_{t,j} relu(qI_{t,j} . kI_s), s <= t
-        S_t = the topk positions s <= t of largest I_{t,s} (ties: the lower)
+        S_t = the tokens :func:`select_and_attend` chooses
         o_{t,h} = sum_{s in S_t} softmax_s(q_{t,h} . k_{s,g(h)} / sqrt(D)) v_{s,g(h)}
 
     ``rms_h`` an RMS norm over a head's lanes with one gain for all heads,
     ``LN`` a LayerNorm with gain and bias, rope the half-split rotation over
     all lanes of a head (of an index head too).  The index keys are cached in
-    the pages' type; the scores are float32.  A decode row gathers its index
-    keys under its own table, sorts its scores for the ``topk`` positions and
-    gathers those tokens' K and V; the prompt chunk's rows share one table,
-    select a tile of :data:`_CHUNK_TILE` queries at a time, and attend the
-    sequence's K and V under the selection's mask
-    (``ops/pallas/indexed_attention.py``): the same mathematics."""
+    the pages' type; the scores are float32.  A decode row gathers the chosen
+    tokens' K and V out of the pages a token at a time (a token's K/V heads
+    lie side by side in a page: one row a key) and attends them densely; the
+    prompt chunk attends the sequence's K and V (read once) under the
+    selection's mask (``ops/pallas/indexed_attention.py``): the same
+    mathematics."""
     positions, live, _, tables, write_blocks, write_offsets, chunk, dt, _, _ = step
     ix = cfg.indexer
     B = h.shape[0]
     H, Hkv, D = cfg.n_head, cfg.kv_heads, cfg.head_dim
     g, BS, T = H // Hkv, kp.shape[2], tables.shape[1] * kp.shape[2]
-    K, n_dec = min(ix.topk, T), B - chunk
+    n_dec = B - chunk
     rope = lambda t, at: gpt.apply_rope(t[:, None], at[:, None], cfg.rope_theta)[:, 0]
 
     def project(r, at):
@@ -1209,70 +1302,27 @@ def indexed_mixer(cfg, p, h, kp, vp, held, li, step: _Step):
     vp = vp.at[li, write_blocks, write_offsets].set(v.astype(vp.dtype).reshape(B, 1, -1))
     with jax.named_scope("index_score"):
         qi, ki, w = _rows_that_carry(index_project, (h, positions), chunk, live)
-        pages = held["ki"].at[li, write_blocks, write_offsets].set(
-            ki.astype(held["ki"].dtype)[:, None])
 
-    def scores_of(qi, w, at, keys):
-        """``I_{t,s}`` of the queries at positions ``at [n]`` over their
-        sequences' index keys ``keys [n | 1, T, lanes]``, -inf past each."""
-        with jax.named_scope("index_score"):
-            return jnp.where(jnp.arange(T)[None] <= at[:, None],
-                             index_scores(cfg, qi, w, keys), -jnp.inf)
+    def attend_rows(tb, at, real):
+        K = at.shape[1]
+        page = jnp.take_along_axis(tb, at // BS, axis=1)
+        kg = kp[li, page, at % BS].reshape(n_dec, K, Hkv, D)
+        vg = vp[li, page, at % BS].reshape(n_dec, K, Hkv, D)
+        s = jnp.einsum("nhgd,nkhd->nhgk", q[:n_dec].reshape(n_dec, Hkv, g, D), kg,
+                       preferred_element_type=jnp.float32) / math.sqrt(D)
+        a = jax.nn.softmax(jnp.where(real[:, None, None], s, -1e30), axis=-1)
+        return jnp.einsum("nhgk,nkhd->nhgd", a.astype(vg.dtype), vg,
+                          preferred_element_type=jnp.float32
+                          ).astype(dt).reshape(n_dec, H * D)
 
-    def under(tb):
-        """The index keys under the tables ``tb [n, MB]``, in logical order."""
-        with jax.named_scope("index_score"):
-            return pages[li, tb].reshape(tb.shape[0], T, ix.head_dim)
-
-    def decode_rows():
-        """A row its own sequence: the chosen tokens' K and V gathered out of
-        the pages a token at a time (a token's K/V heads lie side by side in
-        a page: one row a key), then attended densely."""
-        tb = tables[:n_dec]
-        scores = scores_of(qi[:n_dec], w[:n_dec], positions[:n_dec], under(tb))
-        with jax.named_scope("index_topk"):
-            at, real = chosen_positions(scores, K)
-        with jax.named_scope("index_attend"):
-            page = jnp.take_along_axis(tb, at // BS, axis=1)
-            kg = kp[li, page, at % BS].reshape(n_dec, K, Hkv, D)
-            vg = vp[li, page, at % BS].reshape(n_dec, K, Hkv, D)
-            s = jnp.einsum("nhgd,nkhd->nhgk", q[:n_dec].reshape(n_dec, Hkv, g, D), kg,
-                           preferred_element_type=jnp.float32) / math.sqrt(D)
-            a = jax.nn.softmax(jnp.where(real[:, None, None], s, -1e30), axis=-1)
-            return jnp.einsum("nhgk,nkhd->nhgd", a.astype(vg.dtype), vg,
-                              preferred_element_type=jnp.float32
-                              ).astype(dt).reshape(n_dec, H * D)
-
-    def over_the_chunk():
-        """One sequence under one table: each query chooses its tokens, a
-        tile of queries at a time, and the chunk attends the sequence's K
-        and V (read once) under the selection's mask."""
+    def attend_chunk(tb, chosen, last):
         from deepspeed_tpu.ops.pallas.indexed_attention import masked_chunk_attention
-        tile = indexed_chunk_tile(chunk)
-        assert tile, f"a chunk of {chunk} is not whole tiles of {_CHUNK_TILE} queries"
-        tb = tables[n_dec:n_dec + 1]
-        keys = under(tb)
-        tiles = lambda a: a[n_dec:].reshape(chunk // tile, tile, *a.shape[1:])
+        return masked_chunk_attention(
+            q[n_dec:], kp[li, tb[0]].reshape(T, Hkv * D),
+            vp[li, tb[0]].reshape(T, Hkv * D), chosen, last).astype(dt)
 
-        def choose(a):
-            scores = scores_of(*a, keys)
-            with jax.named_scope("index_topk"):
-                return chosen_tokens(scores, K, BS)
-
-        chosen = jax.lax.map(choose, (tiles(qi), tiles(w), tiles(positions))
-                             ).reshape(chunk, T)
-        with jax.named_scope("index_attend"):
-            last = jnp.max(jnp.where(live[n_dec:], positions[n_dec:], 0))
-            return masked_chunk_attention(
-                q[n_dec:], kp[li, tb[0]].reshape(T, Hkv * D),
-                vp[li, tb[0]].reshape(T, Hkv * D), chosen, last).astype(dt)
-
-    o = decode_rows()
-    if chunk:
-        # a step without a prompt chunk skips the chunk rows' scores, their
-        # selection and their attend: nobody reads them
-        o = jnp.concatenate([o, jax.lax.cond(
-            live[n_dec], over_the_chunk, lambda: jnp.zeros((chunk, H * D), o.dtype))])
+    o, pages = select_and_attend(cfg, qi, ki, w, held["ki"], li, step, BS,
+                                 attend_rows, attend_chunk)
     o = _rows_that_carry(lambda r: r @ gpt._wget(p, "out_w", dt), (o,), chunk, live)
     return o, kp, vp, dict(held, ki=pages)
 

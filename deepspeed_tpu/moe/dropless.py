@@ -45,18 +45,39 @@ def softmax_topk(logits: Array, k: int, renormalise: bool = False
     return probs, weights, experts.astype(jnp.int32)
 
 
+def limit_to_groups(chosen: Array, n_group: int, topk_group: int) -> Array:
+    """``chosen [T, E]`` (what the router chooses by) with all but
+    ``topk_group`` of its ``n_group`` groups of ``E / n_group`` consecutive
+    experts at -inf: a group's score is the sum of its TWO largest entries,
+    the groups that score highest stay (of equal scores the lower group)."""
+    T, E = chosen.shape
+    assert E % n_group == 0 and 1 <= topk_group <= n_group, (E, n_group, topk_group)
+    groups = chosen.reshape(T, n_group, E // n_group)
+    score = jnp.sum(jax.lax.top_k(groups, 2)[0], axis=-1)           # [T, n_group]
+    kept = jax.lax.top_k(score, topk_group)[1]
+    stays = jnp.any(kept[:, :, None] == jnp.arange(n_group)[None, None], axis=1)
+    return jnp.where(stays[:, :, None], groups, -jnp.inf).reshape(T, E)
+
+
 def sigmoid_topk(logits: Array, k: int, bias: Optional[Array] = None,
-                 renormalise: bool = True, scale: float = 1.0
+                 renormalise: bool = True, scale: float = 1.0,
+                 n_group: int = 1, topk_group: int = 1
                  ) -> Tuple[Array, Array, Array]:
     """As :func:`softmax_topk` for the router of the DeepSeek-V3 line: an
     expert's score is the SIGMOID of its own logit, in float32; the ``k``
     largest of ``score + bias`` are chosen (the score-correction bias
-    chooses and never weighs) and weighed by their scores, with
+    chooses and never weighs), with ``topk_group`` under ``n_group`` among
+    the groups that stay alone (:func:`limit_to_groups`, under the scope
+    ``route_groups``; at 1 and 1 there is no limit), and weighed by their
+    scores, with
     ``renormalise`` divided by their sum (``norm_topk_prob``), then times
     ``scale`` (``routed_scaling_factor``, ``route_scale``).  The first
     result is the scores over their sum, for the load-balance loss."""
     scores = jax.nn.sigmoid(logits.astype(jnp.float32))
     chosen = scores if bias is None else scores + bias.astype(jnp.float32)
+    if topk_group < n_group:
+        with jax.named_scope("route_groups"):
+            chosen = limit_to_groups(chosen, n_group, topk_group)
     experts = jax.lax.top_k(chosen, k)[1]
     weights = jnp.take_along_axis(scores, experts, axis=-1)
     if renormalise:

@@ -155,3 +155,75 @@ def masked_chunk_attention(q, k, v, chosen, last):
         return _call(q, k, v, chosen.astype(jnp.bfloat16), last // key_tile(T) + 1)
     heads = lambda a: a.reshape(T, -1, D)
     return masked_attention_reference(q, heads(k), heads(v), chosen)
+
+
+# scores a step of :func:`masked_latent_attention` holds at once, in float32,
+# and the queries it takes a head: 128 queries of 4 heads over 46,080 keys are
+# 94 MB.  Measured on v5e on the same pass in the absorbed form (PERF.md
+# section 6, PR 61): a step of twice the scores took 1.4 to 1.5 times as long
+# at every extent, one of half the scores the same
+_MASKED_STEP_SCORES = 4 * 128 * 46_080
+_MASKED_STEP_QUERIES = 128
+
+
+def chosen_latent_attention(q, rows, real, *, scale, value_lanes):
+    """Latent attention (MLA in its absorbed form) of each query over the
+    rows of the latent cache IT chose: ``q [n, H, W]`` (a head's query moved
+    into the cached vector's lanes), ``rows [n, K, W]`` the chosen tokens'
+    cached vectors, all ``W`` lanes the key and the first ``value_lanes`` the
+    value for every head, ``real [n, K]`` which of them are tokens (a query
+    with fewer than ``K`` keys before it chose fewer) -> ``[n, H,
+    value_lanes]``; the softmax in float32, the logits times ``scale``.  Plain
+    jnp: the rows are gathered by the caller (``models/gpt.py:gpt_paged_step``,
+    a row of 1,280 B a chosen token: a decode row's), and a kernel that copies
+    a chosen row under scalar-prefetched positions would take this function's
+    place and its name as the reference."""
+    s = jnp.einsum("nhw,nkw->nhk", q, rows,
+                   preferred_element_type=jnp.float32) * scale
+    a = jax.nn.softmax(jnp.where(real[:, None], s, NEG_INF), axis=-1)
+    return jnp.einsum("nhk,nkv->nhv", a.astype(rows.dtype), rows[..., :value_lanes],
+                      preferred_element_type=jnp.float32).astype(q.dtype)
+
+
+def masked_latent_attention(q, c, chosen, w_uk, w_uv, *, scale):
+    """Latent attention in its PLAIN form of a prompt chunk's queries ``q [C,
+    H, D]`` (a head's lanes ``[no position | rotated]``, as
+    ``models/gpt.py:_latent_project`` makes them) over the cached vectors ``c
+    [T, lanes]`` of their ONE sequence (``[latent | the one rotated key |
+    padding]``), read once, under the selection's mask ``chosen [C, T]`` ->
+    ``[C, H, v lanes]``: every head's own keys and values are made from the
+    latent (``w_uk [R, H, D - rotated]``, ``w_uv [R, H, v lanes]``), a group of
+    heads at a time, and a tile of queries attends them densely (the caller
+    hands in no more of the sequence than the chunk can see); the softmax in
+    float32, the logits times ``scale``.  Plain jnp.  512 queries share what
+    the up-projection of a key costs, so a key and a head take 2 x (192 + 128)
+    operations and not the absorbed form's 2 x (576 + 512), which is the right
+    form for a decode row's ONE query (:func:`chosen_latent_attention`)."""
+    C, H, D = q.shape
+    T, R, dn = c.shape[0], w_uk.shape[0], w_uk.shape[2]
+    latent, k_rope = c[:, :R], c[:, R:R + D - dn]
+    n = math.gcd(C, _MASKED_STEP_QUERIES)
+    G = H
+    while n * G * T > _MASKED_STEP_SCORES and G % 2 == 0:
+        G //= 2
+    by_group = lambda a, axis: jnp.moveaxis(
+        a.reshape(*a.shape[:axis], H // G, G, *a.shape[axis + 1:]), axis, 0)
+    tiles = lambda a: a.reshape(C // n, n, *a.shape[1:])
+
+    def group(a):
+        wk, wv, qg = a                                        # [R, G, .], [C, G, D]
+        k = jnp.concatenate([jnp.einsum("tr,rgd->gtd", latent, wk),
+                             jnp.broadcast_to(k_rope, (G, *k_rope.shape))], axis=-1)
+        v = jnp.einsum("tr,rgd->gtd", latent, wv)
+
+        def tile(b):
+            qt, keep = b
+            s = jnp.einsum("ngd,gtd->gnt", qt, k, preferred_element_type=jnp.float32) * scale
+            p = jax.nn.softmax(jnp.where(keep[None], s, NEG_INF), axis=-1)
+            return jnp.einsum("gnt,gtd->ngd", p.astype(v.dtype), v,
+                              preferred_element_type=jnp.float32).astype(q.dtype)
+
+        return jax.lax.map(tile, (tiles(qg), tiles(chosen))).reshape(C, G, -1)
+
+    o = jax.lax.map(group, (by_group(w_uk, 1), by_group(w_uv, 1), by_group(q, 1)))
+    return jnp.moveaxis(o, 0, 1).reshape(C, H, -1)

@@ -512,12 +512,19 @@ class ServingEngine:
                 raise ValueError(
                     f"init_serving: prefill_chunk {cfg.prefill_chunk} is not "
                     f"whole strides of {mcfg.sparse.stride} compressed keys")
-            if ("indexed" in mcfg.mixers
-                    and not hybrid.indexed_chunk_tile(cfg.prefill_chunk)):
+        if mcfg.indexed_layers:
+            from deepspeed_tpu.models import hybrid
+            if not hybrid.indexed_chunk_tile(cfg.prefill_chunk, mcfg.indexer.heads):
                 raise ValueError(
                     f"init_serving: prefill_chunk {cfg.prefill_chunk} is not "
                     f"whole tiles of queries that select their tokens together "
                     f"(models/hybrid.py:indexed_chunk_tile)")
+            if cfg.kv_tiering or cfg.prefix_cache:
+                raise ValueError(
+                    "init_serving: kv_tiering and prefix_cache spill and share "
+                    "blocks of K and V; this model's indexed layers hold a "
+                    "cache of index keys the selection scores, which no block "
+                    "of K and V carries")
         if len(mcfg.cache_lanes) != 2 and (cfg.kv_tiering or cfg.prefix_cache):
             raise ValueError(
                 "init_serving: kv_tiering and prefix_cache spill and share "
@@ -611,7 +618,7 @@ class ServingEngine:
         # arena and table donation = in-place update; CPU can't donate (jax
         # warns and copies), so only donate on real accelerators.  The token
         # array is never donated: the host fetches it behind the next launch
-        donate = (3, 4, 5) + (6,) * self._hybrid
+        donate = (3, 4, 5) + (6,) * (self._aux is not None)
         if jax.default_backend() == "cpu":
             donate = ()
         self._raw_step_fn = step_fn
@@ -694,15 +701,14 @@ class ServingEngine:
                                 run_blocks=self._run_blocks)
 
     def _new_aux(self):
-        """A hybrid stack's compressed keys and states, zeroed (None for
-        every other model).  Nothing else ever zeroes a state: a prompt chunk
-        at position 0 starts from zero whatever the slot holds."""
-        if not self._hybrid:
-            return None
+        """A hybrid stack's compressed keys and states and an indexer's index
+        keys, zeroed (None for a model that caches K and V, or a latent,
+        alone).  Nothing else ever zeroes a state: a prompt chunk at position 0
+        starts from zero whatever the slot holds."""
         from deepspeed_tpu.models import hybrid
         cfg = self._config
         return hybrid.init_aux(self.module.cfg, cfg.num_blocks, cfg.block_size,
-                               cfg.max_batch_size, self.dtype)
+                               cfg.max_batch_size, self.dtype) or None
 
     def _hybrid_stats(self, rows) -> Dict[str, int]:
         """What a hybrid stack's layers did in a step, from its rows'
@@ -727,9 +733,9 @@ class ServingEngine:
         out = {"state_slots_reset": int(first[3] != 0 and first[1] == 0)}
         out.update({name + "_bytes": int(a.nbytes) for name, a in self._aux.items()
                     if name.endswith(("state", "delta_conv", "mamba_conv"))})
-        if "indexed" in mcfg.mixers:
+        if mcfg.indexed_layers:
             t = rows[rows[:, 3] != 0, 1]
-            per = mcfg.mixers.count("indexed")
+            per = mcfg.indexed_layers
             resident = int((t + 1).sum()) * per         # every one of them is scored
             out.update(
                 index_keys_scored=resident, indexed_keys_resident=resident,
@@ -1234,7 +1240,7 @@ class ServingEngine:
                 pf and (req, req.slot, start + n_chunk >= req.prefill_len, chunk),
                 self._launched(decode, pf), t_launch, ahead,
                 sum(host.values()) if host else None)
-            if self._hybrid:
+            if self._aux is not None:
                 table_stats.update(self._hybrid_stats(rows))
         moe_stats = self._drain()       # the program before: behind the launch
         if runs and self._stays_ahead():

@@ -107,6 +107,10 @@ def _step_program(chip, cfg, slots, chunk, BS, blocks, MB, counts=False, donate=
         tables = tuple(ints(rows, w) for w in widths)
         coords = tuple(ints(rows, 1) for _ in widths)
         step = lambda *a: model.paged_step(*a, **kw)
+        if cfg.indexed_layers:          # an indexer over a latent: its index keys
+            aux = on_chip(jax.eval_shape(lambda: hybrid.init_aux(cfg, blocks, BS, slots, BF16)))
+            more = (aux,)
+            step = lambda *a: model.paged_step(*a[:8], aux=a[8], **kw)
     compiled = _compile(step, params, ints(rows, 1), ints(rows), kp, vp, tables, coords,
                         ints(rows, 1), *more, donate=(3, 4, 8) if donate else ())
     return compiled, kp, aux
@@ -912,11 +916,34 @@ def test_the_keye_vl2_step_selects_tokens_and_reads_its_bank_in_place(chip):
     cfg = gpt.keye_vl2_config(n_layer=2, dtype=BF16)
     text = _step_program(chip, cfg, 8, 512, 64, 1025, 720)[0].as_text()
     assert ia.KERNEL in text and "grouped_matmul" in text
-    assert text.count("conditional(") >= 2
+    assert text.count("conditional(") >= 4          # a chunk or none, and its extent, a body
     sorts = [l for l in text.splitlines() if " sort(" in l and "attn_indexed" in l]
     assert sorts and all("index_topk/top_k" in l and "branch_1_fun" not in l for l in sorts)
     # a layer's pages are never copied out: [1025, 64, 512] K or V, [1025, 64, 64] index keys
     assert not re.search(r"bf16\[1025,64,(512|64)\]\S* (dynamic-slice|copy)\(", text)
+
+
+def test_the_deepseek_v32_step_selects_rows_of_its_latent_cache(chip):
+    """The whole step of DeepSeek-V3.2-Exp's cell at the dense layer and ONE
+    expert layer, published widths with 16 of 256 experts held, 12 slots and
+    a chunk of 512 under tables of 720 pages: every layer selects under the
+    scope ``attn_indexed`` (the decode rows by a sort; the chunk, in the
+    branch a step without a prompt skips, by bisection, in one branch of a
+    ``switch`` an extent of its table), no layer runs ``paged_mla_attention``
+    (its contexts pass ``index_topk``), the bank's grouped matmuls read the
+    stacked leaves, and no layer of the latent cache or of the index keys is
+    sliced out of its arena."""
+    from deepspeed_tpu.models import gpt
+    cfg = gpt.deepseek_v32_config(n_layer=2, dense_layers=1, vocab_size=16160,
+                                  experts_held=(0, 16), dtype=BF16)
+    text = _step_program(chip, cfg, 12, 512, 64, 1025, 720, counts=True)[0].as_text()
+    assert "grouped_matmul" in text and "paged_mla_attention" not in text
+    assert text.count("conditional(") >= 4          # a chunk or none, and its extent, a body
+    sorts = [l for l in text.splitlines() if " sort(" in l and "attn_indexed" in l]
+    assert sorts and all("index_topk" in l for l in sorts)
+    assert "route_groups" in text and "latent_project" in text
+    # a layer's pages are never copied out: [1025, 64, 640] latents, [1025, 64, 128] index keys
+    assert not re.search(r"bf16\[1025,64,(640|128)\]\S* (dynamic-slice|copy)\(", text)
 
 
 def test_the_trinity_step_reads_its_bank_behind_a_dense_lead(chip):

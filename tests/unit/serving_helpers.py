@@ -84,8 +84,7 @@ class Driver:
         self.round_through, self.leaves, self.forget = round_through, leaves, forget
         blocks = 1 + slots * blocks_a_slot
         self.kp, self.vp = init_arena(cfg, blocks, block_size, dtype)
-        self.aux = (hybrid.init_aux(cfg, blocks, block_size, slots, dtype)
-                    if cfg.hybrid else None)
+        self.aux = hybrid.init_aux(cfg, blocks, block_size, slots, dtype) or None
         self.counts = None
         static = dict(static)
         key = _program_key(model, chunk, tuple(sorted(static.items())))

@@ -8,7 +8,9 @@ reference, at the published widths: the chip comparison of the
 ``n_layer`` is whole periods, so its float32 run is ``"n_layer": 4``, the dense
 lead and three expert layers; ``jamba2-3b.json``: the whole model as served,
 and its float32 run a few layers, ``"n_layer": 4, "attn_layer_period": 4,
-"attn_layer_offset": 2`` in ``model.kwargs`` and ``reference.kwargs``).
+"attn_layer_offset": 2`` in ``model.kwargs`` and ``reference.kwargs``;
+``deepseek-v3.2-exp.json``: its float32 run is ``"n_layer": 2``, the dense
+layer and one expert layer, with ``--long 30000`` for a context that selects).
 
 Run standalone on a TPU host (``chiprun --chips 1 -- python
 tools/serve_parity.py benchmarks/configs/smallthinker-21b-a3b.json``); any
@@ -193,7 +195,7 @@ def main(argv=None) -> int:
         lengths.append(cfg.rope_yarn.original_positions + 100)      # original range
     if "sparse" in cfg.mixers:                  # and past where every key is attended
         lengths.append(cfg.sparse.dense_len + 200)
-    if "indexed" in cfg.mixers:                 # and past where every token is kept
+    if cfg.indexed_layers:                      # and past where every token is kept
         lengths.append(cfg.indexer.topk + 200)
     if {"delta", "mamba"} & set(cfg.mixers):    # and a third chunk entered with both states
         lengths.append(2 * config["serve"]["serving"]["prefill_chunk"] + 77)
