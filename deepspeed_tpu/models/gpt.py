@@ -2338,7 +2338,10 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
     latent cache (``ops/pallas/indexed_attention.py``: a decode row
     ``chosen_latent_attention``, the absorbed form over gathered rows; the
     prompt chunk ``masked_latent_attention``, the plain form over its
-    sequence under a mask), under the scope ``attn_indexed``.  Tables of
+    sequence under a mask: the heads' keys and values made from the latent
+    by XLA, the attend a Pallas kernel that holds the chunk's queries in
+    VMEM and skips the tiles of keys past the chunk's last position), under
+    the scope ``attn_indexed``.  Tables of
     ``topk`` positions or fewer select nothing: the layer is
     ``paged_mla_attention`` over every key.
 
@@ -2497,8 +2500,10 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
         absorbed form (``q [B, 1, H, W]``), its output brought up through
         W_UV; the prompt chunk's queries (``plain [B, 1, H, head_dim]``), a
         set of its own each, attend their sequence's rows, read once, in the
-        plain form under the selection's mask, and no more of them than the
-        chunk can see (:data:`CHUNK_EXTENTS`).  ``up``: the layer's W_UK and
+        plain form under the selection's mask (the kernel
+        ``masked_latent_attention``), and no more of them than the chunk can
+        see: the least extent that holds it (:data:`CHUNK_EXTENTS`), and of
+        that the key tiles up to its last position.  ``up``: the layer's W_UK and
         W_UV (:func:`_latent_up`)."""
         from deepspeed_tpu.models import hybrid
         from deepspeed_tpu.ops.pallas.indexed_attention import (
@@ -2512,10 +2517,9 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
             return jnp.einsum("nhr,rhd->nhd", o, up[1])
 
         def attend_chunk(tb, chosen, last):
-            del last
             return masked_latent_attention(
                 plain[n_dec:, 0], kp[li, tb[0]].reshape(chosen.shape[1], -1), chosen,
-                *up, scale=plans[j].scale)
+                last, *up, scale=plans[j].scale)
 
         step = hybrid._Step(positions, live, None, block_tables[j],
                             write_blocks[j], write_offsets, chunk, dt, None)

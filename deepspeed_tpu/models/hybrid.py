@@ -1169,6 +1169,15 @@ def indexed_chunk_tile(chunk: int, heads: int = 16) -> int:
     return tile if chunk % tile == 0 else 0
 
 
+def chunk_extent_widths(pages: int, extents: int) -> List[int]:
+    """The widths in pages a prompt chunk is compiled for under a table of
+    ``pages`` pages taken in ``extents`` parts: the multiples of ``ceil(pages
+    / extents)``, the last the table itself.  A chunk whose last position is
+    ``last`` takes the ``last // (widths[0] * BS)``-th."""
+    per = -(-pages // extents)
+    return sorted({min(i * per, pages) for i in range(1, extents + 1)})
+
+
 def select_and_attend(cfg, qi, ki, w, pages, li, step: _Step, BS: int,
                       attend_rows, attend_chunk, extents: int = 1):
     """What every indexed layer does between its projections and its
@@ -1243,11 +1252,10 @@ def select_and_attend(cfg, qi, ki, w, pages, li, step: _Step, BS: int,
 
     def chunk_rows():
         last = jnp.max(jnp.where(live[n_dec:], positions[n_dec:], 0))
-        per = -(-tables.shape[1] // extents)
-        widths = sorted({min(i * per, tables.shape[1]) for i in range(1, extents + 1)})
+        widths = chunk_extent_widths(tables.shape[1], extents)
         if len(widths) == 1:
             return over_the_chunk(last, widths[0])
-        return jax.lax.switch(last // (per * BS),
+        return jax.lax.switch(last // (widths[0] * BS),
                               [partial(over_the_chunk, n_pages=n) for n in widths], last)
 
     o = decode_rows()

@@ -36,7 +36,8 @@ def use_kernel(name: str) -> bool:
     ``decode_attention``, ``paged_attention``, ``paged_gqa_attention``,
     ``paged_mla_attention``, ``paged_sparse_attention``,
     ``sparse_block_scores``, ``grouped_matmul``, ``delta_state_update``,
-    ``mamba_state_update``, ``mamba_chunk_scan``) and is not read here: a
+    ``mamba_state_update``, ``mamba_chunk_scan``, ``masked_chunk_attention``,
+    ``masked_latent_attention``) and is not read here: a
     test's replacement answers for one kernel by it.  ``fused_adam`` is the NVMe offload
     walk's: no compiled step program holds it."""
     del name

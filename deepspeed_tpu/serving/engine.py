@@ -111,7 +111,12 @@ from deepspeed_tpu.utils.logging import log_dist
 #: ``rid``, and its chunks' programs on ``rid`` and ``program``.
 #: ``serve.stats`` carries what the step's tables and attention cost
 #: (``table_edits``, ``table_reloads``, ``upload_bytes``, ``tile_runs_pct``,
-#: ``chunk_queries_per_row``, ``attention_rows``), ``dense_rows`` (the rows
+#: ``chunk_queries_per_row``, ``attention_rows``; under an indexer over a
+#: latent cache ``chunk_keys_extent`` and ``chunk_keys_passed``: the keys of
+#: the extent of its table the step's chunk was compiled for and the keys in
+#: the tiles its masked pass walked, each times the indexed layers, so their
+#: ratio says how far the skip past the chunk's last position engages),
+#: ``dense_rows`` (the rows
 #: the program took through the model's dense matrices: every slot's, and the
 #: chunk's only where the step carries one; ``_rows_that_carry``),
 #: ``dispatched_ahead`` (1:
@@ -726,7 +731,10 @@ class ServingEngine:
         wrote); of the indexed layers,
         index keys the live rows scored (every key at or before them), keys
         they attended (``topk`` at most) and keys resident before them,
-        summed over indexed layers, and the bytes of index keys held."""
+        summed over indexed layers, and the bytes of index keys held; of a
+        latent model's indexed layers in a step with a chunk, the keys of the
+        extent of its table the chunk was compiled for and the keys of it the
+        masked pass walked (``chunk_keys_extent``, ``chunk_keys_passed``)."""
         from deepspeed_tpu.models import hybrid
         mcfg = self.module.cfg
         first = rows[self._config.max_batch_size]
@@ -741,6 +749,9 @@ class ServingEngine:
                 index_keys_scored=resident, indexed_keys_resident=resident,
                 indexed_keys_attended=int(hybrid.indexed_keys_attended(mcfg, t).sum()) * per,
                 index_key_bytes=int(self._aux["ki"].nbytes))
+            if mcfg.kv_lora_rank and first[3] != 0:     # a latent's chunk: its last position
+                chunk = rows[self._config.max_batch_size:]
+                out.update(self._chunk_keys(int(chunk[chunk[:, 3] != 0, 1].max())))
         for mixer, stat in (("delta", "delta_state_moves"), ("mamba", "mamba_state_moves")):
             if mixer in mcfg.mixers:
                 decoding = int((rows[:self._config.max_batch_size, 3] != 0).sum())
@@ -756,6 +767,25 @@ class ServingEngine:
                 sparse_rows_dense=dense,
                 sparse_rows_selected=(len(t) - dense) * layers)
         return out
+
+    def _chunk_keys(self, last: int) -> Dict[str, int]:
+        """What a latent model's indexed layers passed over for a prompt
+        chunk whose last position is ``last``, from that position alone: the
+        keys of the extent the chunk's branch was compiled for
+        (``models/gpt.py:CHUNK_EXTENTS``) and the keys in the tiles the
+        masked pass walked (``ops/pallas/indexed_attention.py``), each times
+        the indexed layers."""
+        from deepspeed_tpu.models import gpt, hybrid
+        from deepspeed_tpu.ops.pallas.indexed_attention import latent_keys_walked
+        mcfg, BS = self.module.cfg, self._config.block_size
+        widths = hybrid.chunk_extent_widths(self.max_blocks_per_seq, gpt.CHUNK_EXTENTS)
+        extent = widths[min(last // (widths[0] * BS), len(widths) - 1)] * BS
+        dr = mcfg.qk_rope_dim
+        walked = latent_keys_walked(
+            self._config.prefill_chunk, mcfg.n_head, mcfg.head_dim - dr, dr,
+            mcfg.v_head_dim, extent, self.dtype, last)
+        return {"chunk_keys_extent": extent * mcfg.indexed_layers,
+                "chunk_keys_passed": walked * mcfg.indexed_layers}
 
     def _no_tokens(self):
         """What a program before the first would have handed on: the token

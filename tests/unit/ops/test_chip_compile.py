@@ -904,6 +904,29 @@ def test_masked_chunk_attention_compiles_at_keye_vl2_heads(chip):
     assert not re.search(rf"f32\[\d+,\d+,{T}\]", text)
 
 
+@pytest.mark.parametrize("T", [5760, 46080])
+def test_masked_latent_attention_compiles_at_deepseek_v32_heads(chip, T):
+    """DeepSeek-V3.2-Exp's prompt chunk as served: 512 queries of 128 heads of
+    128 + 64 lanes a key and 128 a value, over the cached vectors of the
+    first extent of a table of 720 pages of 64 and of the whole table, under
+    a mask a query and the chunk's last position: the kernel (23 MiB of VMEM
+    a grid step, which the compiler grants because the call stands alone in
+    the loop over head groups: its output is updated in place), the heads'
+    keys and values a group of 16 heads at a time, and nothing as large as
+    the dense scores."""
+    from deepspeed_tpu.ops.pallas import indexed_attention as ia
+    C, H, dn, dr, dv, R, W = 512, 128, 128, 64, 128, 512, 640
+    assert ia.latent_kernel_shape_ok(C, H, dn, dr, dv, T, BF16) and ia.latent_key_tile(T) == 1152
+    text = _compiled_text(
+        chip, lambda *a: ia.masked_latent_attention(*a, scale=0.1147),
+        ((C, H, dn + dr), BF16), ((T, W), BF16), ((C, T), jnp.bool_), ((), jnp.int32),
+        ((R, H, dn), BF16), ((R, H, dv), BF16))
+    assert f"%{ia.LATENT_KERNEL}" in text and "tpu_custom_call" in text
+    assert not re.search(rf"f32\[\d+,\d+,{T}\]", text)
+    assert re.search(rf"bf16\[{T},{16 * dn}\]", text)           # a group's keys
+    assert not re.search(rf"bf16\[(\d+,)?{T},{H * dn}\]", text)  # never all heads'
+
+
 def test_the_keye_vl2_step_selects_tokens_and_reads_its_bank_in_place(chip):
     """The whole step of two indexed layers at the published widths, 8 slots
     and a chunk of 512 under tables of 720 pages: the masked chunk attend
@@ -929,15 +952,24 @@ def test_the_deepseek_v32_step_selects_rows_of_its_latent_cache(chip):
     a chunk of 512 under tables of 720 pages: every layer selects under the
     scope ``attn_indexed`` (the decode rows by a sort; the chunk, in the
     branch a step without a prompt skips, by bisection, in one branch of a
-    ``switch`` an extent of its table), no layer runs ``paged_mla_attention``
+    ``switch`` an extent of its table, which attends through the kernel
+    ``masked_latent_attention`` and writes no float32 scores of an extent's
+    width), no layer runs ``paged_mla_attention``
     (its contexts pass ``index_topk``), the bank's grouped matmuls read the
     stacked leaves, and no layer of the latent cache or of the index keys is
     sliced out of its arena."""
     from deepspeed_tpu.models import gpt
+    from deepspeed_tpu.ops.pallas import indexed_attention as ia
     cfg = gpt.deepseek_v32_config(n_layer=2, dense_layers=1, vocab_size=16160,
                                   experts_held=(0, 16), dtype=BF16)
     text = _step_program(chip, cfg, 12, 512, 64, 1025, 720, counts=True)[0].as_text()
     assert "grouped_matmul" in text and "paged_mla_attention" not in text
+    # the chunk's attend: the kernel, in the branch of every extent
+    calls = [l for l in text.splitlines() if f"%{ia.LATENT_KERNEL}" in l and "custom-call(" in l]
+    assert len(calls) >= gpt.CHUNK_EXTENTS and all("index_attend" in l for l in calls)
+    # (the indexer's 64 heads' scores of a tile of 32 queries are its own)
+    assert not any(re.search(r"f32\[\d+,\d+,(5760|46080)\]", l)
+                   for l in text.splitlines() if "index_attend" in l)
     assert text.count("conditional(") >= 4          # a chunk or none, and its extent, a body
     sorts = [l for l in text.splitlines() if " sort(" in l and "attn_indexed" in l]
     assert sorts and all("index_topk" in l for l in sorts)

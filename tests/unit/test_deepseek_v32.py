@@ -242,16 +242,23 @@ def test_the_absorbed_form_is_the_plain_form():
     assert np.abs(plain).max() > 1e-3
 
 
-def test_the_masked_pass_is_the_gathered_rows():
-    """A prompt chunk's form against a decode row's: every head's own keys
-    and values made from the latent and attended under the selection's mask,
-    two groups of heads and two tiles of queries (the limits made small), and
-    the same queries absorbed over the rows gathered at the chosen positions
-    with the output brought up through W_UV; a query that chose fewer than the
-    others among them."""
+@pytest.mark.parametrize("path", ["reference", "kernel"])
+def test_the_masked_pass_is_the_gathered_rows(kernels, path):
+    """A prompt chunk's form against a decode row's, through the public name
+    on both of its paths: every head's own keys and values made from the
+    latent and attended under the selection's mask (the reference: two groups
+    of heads and two tiles of queries, the limits made small; the kernel
+    through the interpreter: two grid steps of four heads over three tiles of
+    keys), and the same queries absorbed over the rows gathered at the chosen
+    positions with the output brought up through W_UV; a query that chose
+    fewer than the others among them."""
     from deepspeed_tpu.ops.pallas import indexed_attention as ia
     rng = np.random.default_rng(1)
     C, H, dn, dr, dv, R, T, K = 32, 4, 12, 8, 10, 24, 96, 12
+    if path == "kernel":
+        kernels(ia.LATENT_KERNEL)
+        H, dn, dr, dv, T = 8, 128, 64, 128, 3 * 640
+        assert ia.latent_kernel_shape_ok(C, H, dn, dr, dv, T, jnp.float32)
     f = lambda *shape: jnp.asarray(rng.normal(0, 1, shape), jnp.float32)
     q, w_uk, w_uv = f(C, H, dn + dr), f(R, H, dn), f(R, H, dv)
     c = jnp.pad(f(T, R + dr), ((0, 0), (0, 8)))           # the pages' lanes past the key
@@ -262,11 +269,14 @@ def test_the_masked_pass_is_the_gathered_rows():
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(ia, "_MASKED_STEP_QUERIES", 16)
         mp.setattr(ia, "_MASKED_STEP_SCORES", 16 * 2 * T)
-        got = masked_latent_attention(q, c, jnp.asarray(chosen), w_uk, w_uv, scale=0.3)
+        got = jax.jit(lambda *a: masked_latent_attention(*a, scale=0.1))(
+            q, c, jnp.asarray(chosen), jnp.int32(T - 1), w_uk, w_uv)
     absorbed = jnp.concatenate([jnp.einsum("chd,rhd->chr", q[..., :dn], w_uk), q[..., dn:]], -1)
     want = jnp.einsum("chr,rhd->chd", chosen_latent_attention(
-        absorbed, c[at][..., :R + dr], jnp.asarray(real), scale=0.3, value_lanes=R), w_uv)
-    assert got.shape == (C, H, dv) and np.abs(got - want).max() < 2e-5
+        absorbed, c[at][..., :R + dr], jnp.asarray(real), scale=0.1, value_lanes=R), w_uv)
+    # float32's own rounding, of outputs that reach past 10 at 128 lanes a head
+    assert got.shape == (C, H, dv)
+    assert np.abs(got - want).max() < 2e-5 * max(1.0, float(np.abs(want).max()))
     assert np.abs(want).max() > 0.1
 
 
@@ -374,6 +384,15 @@ def test_the_engine_serves_the_references_tokens_and_counts_its_keys(loud):
     assert st["index_keys_scored"] == st["indexed_keys_resident"] == 3 * 36
     assert st["indexed_keys_attended"] == 3 * 36
     assert st["index_key_bytes"] == eng._aux["ki"].nbytes
+    # the chunk's branch: the first page of eight (``gpt.CHUNK_EXTENTS``), and
+    # on the CPU the reference walks every key of it
+    assert st["chunk_keys_extent"] == st["chunk_keys_passed"] == 3 * BS
+    seen = [st]
+    while len(seen) < 4:
+        st = eng.step()
+        seen += [st] * bool(st.get("chunk_keys_extent"))
+    # chunks of 8 at positions 8..15, 16..23, 24..29: a page, then two
+    assert [s["chunk_keys_extent"] for s in seen] == [3 * BS, 3 * BS, 6 * BS, 6 * BS]
     while eng.sched.has_work:
         eng.step()
 

@@ -9,20 +9,27 @@ ISSUE 61 asks to be measured against each other:
   a time (512 x 2,048 rows of 1,280 B, 1.34 GB a layer), and attended densely
   (``ops/pallas/indexed_attention.py:chosen_latent_attention``, what a decode
   row runs);
-* ``masked``: what ``models/gpt.py:gpt_paged_step`` ships for the chunk, a
+* ``kernel``: what ``models/gpt.py:gpt_paged_step`` ships for the chunk, a
   dense pass over the sequence's latent, read once, under the selection's
-  mask, through XLA in the PLAIN form (``masked_latent_attention``: every
-  head's keys and values made from the latent a group of heads at a time, 128
-  queries a tile; 3.5 T operations a layer at the whole table), at the whole
-  table and at each shorter extent of ``gpt.CHUNK_EXTENTS`` with the bisection
-  over the same keys; and ``masked_absorbed``, the same pass in the decode
-  rows' form (the 128 heads' scores over the latent itself, 7.0 T);
+  mask, in the PLAIN form (``masked_latent_attention``: every head's keys and
+  values made from the latent by XLA, a group of heads at a time, and attended
+  by the Pallas kernel, all 512 queries a grid step; 3.5 T operations a layer
+  at the whole table), at the whole table and at each shorter extent of
+  ``gpt.CHUNK_EXTENTS`` with the bisection over the same keys, the chunk at the
+  extent's END (no tile of keys skipped) and in its MIDDLE (``skip``: the
+  tiles past the chunk's last position neither fetched nor computed);
+* ``masked``: the same pass through XLA alone, which shipped before the kernel
+  and is its reference (``masked_latent_attention_reference``: 128 queries a
+  tile, a tile's float32 scores out to memory and back), at the same extents;
+  and ``masked_absorbed``, the same pass in the decode rows' form (the 128
+  heads' scores over the latent itself, 7.0 T);
 
 and beside them the two forms of the chunk's SELECTION at this indexer's 64
 heads (``chosen_positions``: a sort, which the gather needs; ``chosen_tokens``:
 the bisection's mask, which the masked pass takes), a tile of queries at a
-time, and the 12 decode rows' gather and attend.  The two forms of the attend
-are compared: the largest difference must be rounding's (exit 1 otherwise).
+time, and the 12 decode rows' gather and attend.  The forms of the attend are
+compared, the kernel with its reference at every extent with and without the
+skip: the largest difference must be rounding's (exit 1 otherwise).
 
 Run standalone on a TPU host (``chiprun --chips 1 -- python
 tools/latent_attend_probe.py``); any other platform is an error (exit 1;
@@ -67,12 +74,16 @@ def main(argv=None) -> int:
     import jax.numpy as jnp
     import numpy as np
     from deepspeed_tpu.models import gpt, hybrid
-    from deepspeed_tpu.ops.pallas.indexed_attention import (chosen_latent_attention,
-                                                            masked_latent_attention)
+    from deepspeed_tpu.ops import pallas
+    from deepspeed_tpu.ops.pallas.indexed_attention import (
+        LATENT_KERNEL, chosen_latent_attention, masked_latent_attention,
+        masked_latent_attention_reference)
 
     if jax.devices()[0].platform != "tpu" and not args.rehearse:
         print(f"FAIL: needs a TPU, found {jax.devices()[0].platform}")
         return 1
+    if args.rehearse:           # the kernel through the interpreter, where its gate admits
+        pallas.use_kernel = lambda name: name == LATENT_KERNEL
     chunk, slots, table, heads, k, context, dtype, repeats = (
         (32, 2, 8, 4, 48, 400, jnp.float32, 1) if args.rehearse else
         (CHUNK, SLOTS, TABLE, H, K, CONTEXT, jnp.bfloat16, args.repeats))
@@ -126,11 +137,18 @@ def main(argv=None) -> int:
     def gather_alone(pages, at):
         return jax.lax.map(lambda a: rows(pages, a).astype(jnp.float32).sum(1), tiles(at, n))
 
-    def masked(pages, plain, chosen):
-        """What ships: the plain form over the first ``chosen.shape[1]`` keys."""
-        n_pages = chosen.shape[1] // BS
-        return masked_latent_attention(plain, pages[tb[0, :n_pages]].reshape(-1, W), chosen,
+    under = lambda pages, chosen: pages[tb[0, :chosen.shape[1] // BS]].reshape(-1, W)
+
+    def kernel(pages, plain, chosen, last):
+        """What ships: the plain form over the first ``chosen.shape[1]`` keys,
+        the chunk's last position ``last``."""
+        return masked_latent_attention(plain, under(pages, chosen), chosen, last,
                                        w_uk, w_uv, scale=scale)
+
+    def masked(pages, plain, chosen):
+        """The kernel's reference: the same pass through XLA alone."""
+        return masked_latent_attention_reference(plain, under(pages, chosen), chosen,
+                                                 w_uk, w_uv, scale=scale)
 
     def masked_absorbed(pages, q, chosen, m=4 if not args.rehearse else 8):
         """The same pass in the decode rows' form: the 128 heads' queries in
@@ -152,26 +170,40 @@ def main(argv=None) -> int:
                                           repeats=repeats)
     out["attend_masked_absorbed_ms"], other = timed(jax.jit(masked_absorbed), pages, q, chosen,
                                                    repeats=repeats)
-    gap = float(jnp.abs(got.astype(jnp.float32) - want.astype(jnp.float32)).max())
-    gap = max(gap, float(jnp.abs(other.astype(jnp.float32) - want.astype(jnp.float32)).max()))
-    out["largest_difference"] = gap
+    differs = lambda a, b: float(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32)).max())
+    gap = max(differs(got, want), differs(other, want))
     out["gathered_gb"] = chunk * k * W * pages.dtype.itemsize / 1e9
     out["masked_tera_ops"] = 2 * T * (chunk * heads * (DN + DR + DV) + R * heads * (DN + DV)) / 1e12
     out["masked_absorbed_tera_ops"] = 2 * chunk * heads * T * (W + R) / 1e12
 
-    # ---- a chunk early in its prompt: the extents of ``gpt.CHUNK_EXTENTS`` ------- #
+    # ---- the kernel against its reference, a chunk at each extent's end and middle -- #
+    # (the extents of ``gpt.CHUNK_EXTENTS``: a chunk early in its prompt)
     out["extents"] = {}
-    for i in range(1, gpt.CHUNK_EXTENTS):
-        E = -(-table // gpt.CHUNK_EXTENTS) * i * BS
-        if E >= T or E < k:
+    per = -(-table // gpt.CHUNK_EXTENTS) * BS
+    select = jax.jit(lambda s: jax.lax.map(
+        lambda t: hybrid.chosen_tokens(t, min(k, s.shape[1]), BS), tiles(s, tile)
+    ).reshape(chunk, -1))
+    for i in range(1, gpt.CHUNK_EXTENTS + 1):
+        E = min(per * i, T)
+        if E < k or str(E) in out["extents"]:
             continue
-        s_e = jnp.where(jnp.arange(E)[None] <= (E - chunk + jnp.arange(chunk))[:, None],
-                        scores[:, :E], -jnp.inf)
-        select = jax.jit(lambda s: jax.lax.map(
-            lambda t: hybrid.chosen_tokens(t, k, BS), tiles(s, tile)).reshape(chunk, -1))
-        t_sel, chosen_e = timed(select, s_e, repeats=repeats)
-        t_att, _ = timed(jax.jit(masked), pages, plain, chosen_e, repeats=repeats)
-        out["extents"][E] = {"select_bisection_ms": t_sel, "attend_masked_ms": t_att}
+        row = out["extents"][str(E)] = {}
+        for name, last in (("", E - 1), ("_skip", E - per // 2 - 1)):
+            s_e = jnp.where(jnp.arange(E)[None] <= (last - chunk + 1 + jnp.arange(chunk))[:, None],
+                            scores[:, :E], -jnp.inf)
+            # the selection and the reference walk every key wherever the chunk
+            # lies: timed at the extent's end alone
+            once = 1 if name else repeats
+            t_sel, chosen_e = timed(select, s_e, repeats=once)
+            t_ref, want_e = timed(jax.jit(masked), pages, plain, chosen_e, repeats=once)
+            if not name:
+                row.update(select_bisection_ms=t_sel, attend_masked_ms=t_ref)
+            row["attend_kernel_ms" + name], got_e = timed(
+                jax.jit(kernel), pages, plain, chosen_e, jnp.int32(last), repeats=repeats)
+            row["difference" + name] = differs(got_e, want_e)
+            gap = max(gap, row["difference" + name])
+    out["attend_kernel_ms"] = out["extents"][str(T)]["attend_kernel_ms"]
+    out["largest_difference"] = gap
 
     # ---- the decode rows ------------------------------------------------------------ #
     dq, dat = q[:slots], at[:slots]
