@@ -19,9 +19,11 @@ multi-query attention) are entries of :data:`MIXERS` and
   block tables (``aux["ki"]``).  A query's 16 index heads score EVERY cached
   token (``I = sum_j w_j relu(qI_j . kI_s)``: 128 B a key a layer read), the
   ``topk`` tokens that score highest are found exactly (ties to the lower
-  position: a decode row's positions by a sort, a prompt chunk's mask by a
-  threshold found by bisection over the scores' bit patterns), and the
-  query's 32 heads attend those tokens alone.  A decode row gathers
+  position: a threshold found by bisection over the scores' bit patterns,
+  on the chip with the rows' scores held in VMEM,
+  ``ops/pallas/index_select.py``; a prompt chunk takes the mask, a decode
+  row the positions it gives by rank), and the query's 32 heads attend
+  those tokens alone.  A decode row gathers
   their K and V a token at a time out of the pages (a token's four K/V
   heads lie side by side in a page, so a key is one 1 KB row) and reads no
   key it did not choose; a prompt chunk's 512 queries, each with a set of
@@ -1104,18 +1106,33 @@ def _running_count(x: Array, G: int) -> Array:
     return (inside + (groups - inside[..., -1])[..., None]).reshape(x.shape)
 
 
+def selects_in_vmem(n: int, T: int, k: int) -> bool:
+    """Whether ``n`` rows of ``T`` scores choose their ``k`` largest through
+    the kernel (``ops/pallas/index_select.py``): on a TPU, at shapes its
+    tiles take; the bisection in plain ``jax.numpy`` everywhere else."""
+    from deepspeed_tpu.ops import pallas
+    from deepspeed_tpu.ops.pallas import index_select
+    return (pallas.use_kernel(index_select.KERNEL) and pallas.single_device()
+            and index_select.kernel_shape_ok(n, T, k))
+
+
 def chosen_tokens(scores: Array, k: int, G: int) -> Array:
     """Which ``k`` of ``scores [n, T]`` (float32) a row are largest, EXACTLY
     (of equal scores the lower positions; never one at -inf, so a row with
     fewer than ``k`` scores above it chooses fewer): ``[n, T]`` bool.  ``T``
     is whole groups of ``G``.  No sort: the ``k``-th largest score is found
-    by bisection over the scores' bit patterns, :data:`_BITS_A_PASS` bits a
-    pass; what lies above it and the first of what equals it is the set.
-    A mask is what a prompt chunk's attention takes; where the positions
-    themselves are wanted (a decode row's gather) ``jax.lax.top_k`` gives the
-    same set."""
+    by bisection over the scores' bit patterns; what lies above it and the
+    first of what equals it is the set.  On a TPU the whole bisection runs
+    with the rows' scores held in VMEM (:func:`selects_in_vmem`); elsewhere
+    in plain ``jax.numpy``, :data:`_BITS_A_PASS` bits a pass.  A mask is what
+    a prompt chunk's attention takes; where the positions themselves are
+    wanted (a decode row's gather) :func:`chosen_positions` gives the same
+    set."""
     n, T = scores.shape
     assert T % G == 0 and k <= T and 32 % _BITS_A_PASS == 0, (T, G, k)
+    if selects_in_vmem(n, T, k):
+        from deepspeed_tpu.ops.pallas.index_select import index_select
+        return index_select(scores, k)[0] != 0
     u = _sortable(scores)
     kth = jnp.zeros((n,), jnp.uint32)
     digits = jnp.arange(1, 1 << _BITS_A_PASS, dtype=jnp.uint32)
@@ -1131,13 +1148,30 @@ def chosen_tokens(scores: Array, k: int, G: int) -> Array:
 
 def chosen_positions(scores: Array, k: int):
     """The positions :func:`chosen_tokens` chooses, where they themselves are
-    wanted (a decode row's gather): -> (``[n, k]`` int32, which of them are
-    real ``[n, k]``: a row with fewer than ``k`` scores above -inf has
-    fewer).  A sort: of equal scores ``jax.lax.top_k`` takes the lower
-    position first, and over 8 rows it gives the positions as fast as the
-    bisection gives a mask (PERF.md section 6, PR 51)."""
-    top, at = jax.lax.top_k(scores, k)
-    return at, top > -jnp.inf
+    wanted (a decode row's gather): -> (``[n, k]`` int32 in RISING order,
+    which of them are real ``[n, k]``: a row with fewer than ``k`` scores
+    above -inf has fewer, and the slots past its last hold ``T - 1``).
+    No sort, scatter or gather: the keys are placed by the mask's running
+    count in two levels (:func:`_counts_before`), inside each group of 128
+    keys (of fewer where ``T`` is not whole lane tiles) and over the groups'
+    ends.  Slot ``j`` lies in the one group that starts at
+    or under ``j`` chosen keys and ends above them, and inside it at the lane
+    with ``j`` less the group's start chosen lanes at or under it: the
+    group's row of counts (128 at most: exact in bf16) comes by a one-hot
+    product, the lane by a compare and a sum."""
+    n, T = scores.shape
+    G = math.gcd(T, _RANK_GROUP)
+    inside, ends = _counts_before(chosen_tokens(scores, k, G), G)
+    inside, ends = inside.astype(jnp.bfloat16), ends.astype(jnp.int32)
+    slot = jnp.arange(k)[:, None]
+    starts = jnp.pad(ends[:, :-1], ((0, 0), (1, 0)))[:, None]
+    own = (starts <= slot) & (slot < ends[:, None])                    # [n, k, T / G]
+    group = jnp.sum(ends[:, None] <= slot, axis=-1, dtype=jnp.int32)
+    before = jnp.sum(jnp.where(own, starts, 0), axis=-1)
+    row = jnp.einsum("nkp,npg->nkg", own.astype(jnp.bfloat16), inside)
+    lane = jnp.sum(row <= (slot[:, 0] - before)[..., None].astype(jnp.bfloat16),
+                   axis=-1, dtype=jnp.int32)
+    return jnp.minimum(group * G + lane, T - 1), slot[:, 0] < ends[:, -1:]
 
 
 def index_scores(cfg, qi: Array, w: Array, keys: Array) -> Array:
@@ -1191,11 +1225,13 @@ def select_and_attend(cfg, qi, ki, w, pages, li, step: _Step, BS: int,
         I_{t,s} = (lanes heads)^-1/2 sum_j w_{t,j} relu(qI_{t,j} . kI_s), s <= t
         S_t = the topk positions s <= t of largest I_{t,s} (ties: the lower)
 
-    A decode row gathers its index keys under its own table and sorts its
-    scores for the positions: ``attend_rows(tables [n, MB], at [n, K], real
-    [n, K]) -> [n, ...]``.  The prompt chunk's rows share one table and
-    select a tile of queries at a time (:func:`indexed_chunk_tile`), by
-    bisection a mask: ``attend_chunk(table [1, pages], chosen [chunk, pages
+    Nothing sorts: the set is found by bisection (:func:`chosen_tokens`), on
+    a TPU with a tile of rows' scores held in VMEM.  A decode row gathers its
+    index keys under its own table and takes the set as positions, in rising
+    order (:func:`chosen_positions`): ``attend_rows(tables [n, MB], at [n,
+    K], real [n, K]) -> [n, ...]``.  The prompt chunk's rows share one table
+    and select a tile of queries at a time (:func:`indexed_chunk_tile`), as
+    a mask: ``attend_chunk(table [1, pages], chosen [chunk, pages
     * BS], last) -> [chunk, ...]``, ``last`` the chunk's last live position.
     With ``extents`` above 1 the chunk scores, selects among and attends the
     first ``i / extents`` of its table alone, the least that holds ``last``

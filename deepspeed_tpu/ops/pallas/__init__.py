@@ -37,7 +37,7 @@ def use_kernel(name: str) -> bool:
     ``paged_mla_attention``, ``paged_sparse_attention``,
     ``sparse_block_scores``, ``grouped_matmul``, ``delta_state_update``,
     ``mamba_state_update``, ``mamba_chunk_scan``, ``masked_chunk_attention``,
-    ``masked_latent_attention``) and is not read here: a
+    ``masked_latent_attention``, ``index_select``) and is not read here: a
     test's replacement answers for one kernel by it.  ``fused_adam`` is the NVMe offload
     walk's: no compiled step program holds it."""
     del name

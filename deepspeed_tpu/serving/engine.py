@@ -731,7 +731,10 @@ class ServingEngine:
         wrote); of the indexed layers,
         index keys the live rows scored (every key at or before them), keys
         they attended (``topk`` at most) and keys resident before them,
-        summed over indexed layers, and the bytes of index keys held; of a
+        summed over indexed layers, the bytes of index keys held, live rows at
+        or under ``topk`` keys (``index_rows_all``: they take every key) and,
+        summed over indexed layers, live rows past it (``index_rows_selected``:
+        the rows whose keys a layer's selection chose); of a
         latent model's indexed layers in a step with a chunk, the keys of the
         extent of its table the chunk was compiled for and the keys of it the
         masked pass walked (``chunk_keys_extent``, ``chunk_keys_passed``)."""
@@ -745,10 +748,12 @@ class ServingEngine:
             t = rows[rows[:, 3] != 0, 1]
             per = mcfg.indexed_layers
             resident = int((t + 1).sum()) * per         # every one of them is scored
+            every = int((t + 1 <= mcfg.indexer.topk).sum())
             out.update(
                 index_keys_scored=resident, indexed_keys_resident=resident,
                 indexed_keys_attended=int(hybrid.indexed_keys_attended(mcfg, t).sum()) * per,
-                index_key_bytes=int(self._aux["ki"].nbytes))
+                index_key_bytes=int(self._aux["ki"].nbytes),
+                index_rows_all=every, index_rows_selected=(len(t) - every) * per)
             if mcfg.kv_lora_rank and first[3] != 0:     # a latent's chunk: its last position
                 chunk = rows[self._config.max_batch_size:]
                 out.update(self._chunk_keys(int(chunk[chunk[:, 3] != 0, 1].max())))

@@ -927,21 +927,78 @@ def test_masked_latent_attention_compiles_at_deepseek_v32_heads(chip, T):
     assert not re.search(rf"bf16\[(\d+,)?{T},{H * dn}\]", text)  # never all heads'
 
 
+def _selects_in_vmem_and_sorts_nothing(text, calls):
+    """A step's indexed layers, from its compiled text: at least ``calls``
+    calls of the kernel ``index_select``, every one under ``index_topk``, and
+    no sort under the mixer's scope."""
+    from deepspeed_tpu.ops.pallas import index_select as ix
+    lines = text.splitlines()
+    selects = [l for l in lines if f"%{ix.KERNEL}" in l and "custom-call(" in l]
+    assert len(selects) >= calls and all("index_topk" in l for l in selects)
+    assert not [l for l in lines if " sort(" in l and "attn_indexed" in l]
+
+
+# a decode step's rows at Keye-VL-2.0's and DeepSeek-V3.2-Exp's slots, a
+# chunk's tile of queries at their 16 and 64 index heads over DeepSeek's
+# extents of a table of 720 pages of 64
+INDEX_SELECT_SHAPES = [(8, 46080, 2048), (12, 46080, 2048), (128, 46080, 2048),
+                       (128, 5760, 2048), (32, 5760, 2048), (32, 23040, 2048),
+                       (32, 46080, 2048)]
+
+
+@pytest.mark.parametrize("n,T,k", INDEX_SELECT_SHAPES)
+def test_index_select_compiles_at_the_served_shapes(chip, n, T, k):
+    """The selection's kernel at every shape a serve cell hands it: the
+    gate admits it, the mask comes out of the kernel, and nothing sorts."""
+    from deepspeed_tpu.models import hybrid
+    from deepspeed_tpu.ops.pallas import index_select as ix
+    assert hybrid.selects_in_vmem(n, T, k)
+    text = _compiled_text(chip, lambda s: hybrid.chosen_tokens(s, k, 64), ((n, T), jnp.float32))
+    assert f"%{ix.KERNEL}" in text and "tpu_custom_call" in text and " sort(" not in text
+
+
+@pytest.mark.parametrize("n", [32, 1024], ids=["decode_rows", "chunk"])
+def test_minicpm_salas_blocks_are_chosen_by_the_plain_bisection(chip, n):
+    """MiniCPM-SALA's (query, K/V head) rows choose 64 of 768 blocks: rows
+    too short to pay for the kernel's passes (PERF.md section 6, PR 63: 0.13
+    ms against the fusions' 0.065 at 1,024 rows), so its gate sends them to
+    the bisection in plain ``jax.numpy``; still no sort."""
+    from deepspeed_tpu.models import hybrid
+    from deepspeed_tpu.ops.pallas import index_select as ix
+    assert not hybrid.selects_in_vmem(n, 768, 64)
+    text = _compiled_text(chip, lambda s: hybrid.chosen_tokens(s, 64, 128), ((n, 768), jnp.float32))
+    assert f"%{ix.KERNEL}" not in text and " sort(" not in text
+
+
+@pytest.mark.parametrize("n", [8, 12])
+def test_a_decode_rows_positions_compile_without_a_sort(chip, n):
+    """A decode step's rows at the two indexed cells' slots: the positions
+    by rank behind the kernel, no sort, scatter or gather, and nothing as
+    large as a flat list by rank (``[n, 2048, 46080]``)."""
+    from deepspeed_tpu.models import hybrid
+    from deepspeed_tpu.ops.pallas import index_select as ix
+    T, k = 46080, 2048
+    text = _compiled_text(chip, lambda s: hybrid.chosen_positions(s, k), ((n, T), jnp.float32))
+    assert f"%{ix.KERNEL}" in text
+    assert not re.search(r" (sort|scatter|gather)\(", text)
+    assert not re.search(rf"\[{n},{k},{T}\]", text)
+
+
 def test_the_keye_vl2_step_selects_tokens_and_reads_its_bank_in_place(chip):
     """The whole step of two indexed layers at the published widths, 8 slots
     and a chunk of 512 under tables of 720 pages: the masked chunk attend
     under the branch a step without a prompt skips, the bank's grouped
-    matmuls over the stacked leaves, ONE sort in the mixer (the 8 decode
-    rows' ``top_k``; the chunk's selection is a bisection), and no layer of K,
-    V or index keys sliced out of its arena."""
+    matmuls over the stacked leaves, NO sort in the mixer (the 8 decode rows
+    and the chunk's tiles of 128 queries select through the kernel
+    ``index_select``, under the scope ``index_topk``), and no layer of K, V
+    or index keys sliced out of its arena."""
     from deepspeed_tpu.models import gpt
     from deepspeed_tpu.ops.pallas import indexed_attention as ia
     cfg = gpt.keye_vl2_config(n_layer=2, dtype=BF16)
     text = _step_program(chip, cfg, 8, 512, 64, 1025, 720)[0].as_text()
     assert ia.KERNEL in text and "grouped_matmul" in text
     assert text.count("conditional(") >= 4          # a chunk or none, and its extent, a body
-    sorts = [l for l in text.splitlines() if " sort(" in l and "attn_indexed" in l]
-    assert sorts and all("index_topk/top_k" in l and "branch_1_fun" not in l for l in sorts)
+    _selects_in_vmem_and_sorts_nothing(text, calls=2)   # decode rows, the chunk's tiles
     # a layer's pages are never copied out: [1025, 64, 512] K or V, [1025, 64, 64] index keys
     assert not re.search(r"bf16\[1025,64,(512|64)\]\S* (dynamic-slice|copy)\(", text)
 
@@ -950,8 +1007,9 @@ def test_the_deepseek_v32_step_selects_rows_of_its_latent_cache(chip):
     """The whole step of DeepSeek-V3.2-Exp's cell at the dense layer and ONE
     expert layer, published widths with 16 of 256 experts held, 12 slots and
     a chunk of 512 under tables of 720 pages: every layer selects under the
-    scope ``attn_indexed`` (the decode rows by a sort; the chunk, in the
-    branch a step without a prompt skips, by bisection, in one branch of a
+    scope ``attn_indexed`` (the decode rows and the chunk's tiles of 32
+    queries through the kernel ``index_select``: nothing sorts; the chunk in
+    the branch a step without a prompt skips, in one branch of a
     ``switch`` an extent of its table, which attends through the kernel
     ``masked_latent_attention`` and writes no float32 scores of an extent's
     width), no layer runs ``paged_mla_attention``
@@ -971,8 +1029,7 @@ def test_the_deepseek_v32_step_selects_rows_of_its_latent_cache(chip):
     assert not any(re.search(r"f32\[\d+,\d+,(5760|46080)\]", l)
                    for l in text.splitlines() if "index_attend" in l)
     assert text.count("conditional(") >= 4          # a chunk or none, and its extent, a body
-    sorts = [l for l in text.splitlines() if " sort(" in l and "attn_indexed" in l]
-    assert sorts and all("index_topk" in l for l in sorts)
+    _selects_in_vmem_and_sorts_nothing(text, calls=1 + gpt.CHUNK_EXTENTS)
     assert "route_groups" in text and "latent_project" in text
     # a layer's pages are never copied out: [1025, 64, 640] latents, [1025, 64, 128] index keys
     assert not re.search(r"bf16\[1025,64,(640|128)\]\S* (dynamic-slice|copy)\(", text)

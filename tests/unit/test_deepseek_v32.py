@@ -384,6 +384,7 @@ def test_the_engine_serves_the_references_tokens_and_counts_its_keys(loud):
     assert st["index_keys_scored"] == st["indexed_keys_resident"] == 3 * 36
     assert st["indexed_keys_attended"] == 3 * 36
     assert st["index_key_bytes"] == eng._aux["ki"].nbytes
+    assert (st["index_rows_all"], st["index_rows_selected"]) == (8, 0)
     # the chunk's branch: the first page of eight (``gpt.CHUNK_EXTENTS``), and
     # on the CPU the reference walks every key of it
     assert st["chunk_keys_extent"] == st["chunk_keys_passed"] == 3 * BS

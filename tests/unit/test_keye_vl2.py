@@ -151,13 +151,17 @@ def test_under_topk_keys_the_mixer_is_plain_causal_attention():
 
 
 # ---- (c) the chosen set, ties included ------------------------------------------ #
+@pytest.mark.parametrize("in_vmem", [False, True], ids=["plain", "in_vmem"])
 @pytest.mark.parametrize("n, T, k, G", [(3, 64, 8, 16), (2, 128, 128, 16), (4, 256, 17, 64),
-                                        (2, 192, 50, 64)])
-def test_the_chosen_set_is_the_references_with_ties(n, T, k, G):
+                                        (2, 192, 50, 64), (9, 2176, 48, 64), (5, 5760, 2048, 64)])
+def test_the_chosen_set_is_the_references_with_ties(kernels, in_vmem, n, T, k, G):
     """Seeded scores of which a third are whole numbers (ties at the k-th
     place), a row half unseen, a row all alike: the chunk rows' form (a mask)
     and the decode rows' (positions) choose the reference's set, which is a
-    stable sort's."""
+    stable sort's.  ``in_vmem``: through the kernel where its gate admits the
+    shape (2,048 keys or more in whole lane tiles: the last two)."""
+    kernels(*["index_select"] * in_vmem)
+    assert hybrid.selects_in_vmem(n, T, k) == (in_vmem and T >= 2048)
     rng = np.random.default_rng(T + k)
     s = rng.normal(size=(n, T)).astype(np.float32)
     s[:, ::3] = np.round(s[:, ::3])
@@ -273,6 +277,9 @@ def test_the_steps_stats_count_the_index_keys(loud):
     assert first["index_keys_scored"] == 3 * 36 == first["indexed_keys_attended"]
     assert last["indexed_keys_attended"] == 3 * TOPK < last["indexed_keys_resident"]
     assert last["index_keys_scored"] == last["indexed_keys_resident"]
+    # rows at or under topk keys take them all; a row past it selects in every layer
+    assert (first["index_rows_all"], first["index_rows_selected"]) == (8, 0)
+    assert (last["index_rows_all"], last["index_rows_selected"]) == (0, 3)
 
 
 # ---- (g) the published parameter count ------------------------------------------------ #

@@ -25,7 +25,8 @@ ISSUE 61 asks to be measured against each other:
   heads' scores over the latent itself, 7.0 T);
 
 and beside them the two forms of the chunk's SELECTION at this indexer's 64
-heads (``chosen_positions``: a sort, which the gather needs; ``chosen_tokens``:
+heads (``jax.lax.top_k``: a sort, what gave a gather its positions until PR 63
+(``tools/index_select_probe.py`` times what does since); ``chosen_tokens``:
 the bisection's mask, which the masked pass takes), a tile of queries at a
 time, and the 12 decode rows' gather and attend.  The forms of the attend are
 compared, the kernel with its reference at every extent with and without the
@@ -110,8 +111,12 @@ def main(argv=None) -> int:
            "gather_tile": min(GATHER_TILE, chunk)}
 
     # ---- the selection, a tile of queries at a time ------------------------------- #
-    by_sort = jax.jit(lambda s: jax.tree.map(lambda a: a.reshape(chunk, -1), jax.lax.map(
-        lambda t: hybrid.chosen_positions(t, k), tiles(s, tile))))
+    def sort(t):
+        top, at = jax.lax.top_k(t, k)
+        return at, top > -jnp.inf
+
+    by_sort = jax.jit(lambda s: jax.tree.map(lambda a: a.reshape(chunk, -1),
+                                             jax.lax.map(sort, tiles(s, tile))))
     by_bisection = jax.jit(lambda s: jax.lax.map(
         lambda t: hybrid.chosen_tokens(t, k, BS), tiles(s, tile)).reshape(chunk, T))
     out["select_sort_ms"], (at, real) = timed(by_sort, scores, repeats=repeats)
