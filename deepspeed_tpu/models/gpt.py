@@ -248,6 +248,10 @@ class GPTConfig:
     # experts every token goes through, beside the routed ones: ONE MLP of
     # ``moe_shared_experts * moe_expert_hidden``, added unweighted
     moe_shared_experts: int = 0
+    # the shared expert behind a gate of its own, ``sigmoid(x . w_s) *
+    # Shared(x)``, ``w_s [E]`` a leaf (the qwen3_next line).  The hybrid
+    # walk reads it; the periodic walk's shared expert is added unweighted
+    moe_shared_gate: bool = False
     # times the chosen experts' weights, after they are renormalised (the
     # DeepSeek-V3 line's ``routed_scaling_factor``, afmoe's ``route_scale``)
     moe_route_scale: float = 1.0
@@ -309,8 +313,13 @@ class GPTConfig:
     # ``delta_key_dim`` and a value of ``delta_value_dim`` lanes, behind a
     # causal convolution over time of ``delta_conv`` taps; with
     # ``delta_neg_eigval`` the write strength is ``2 sigmoid`` and the
-    # transition's eigenvalue reaches -1 --------------------------------- #
+    # transition's eigenvalue reaches -1.  ``delta_key_heads`` (0: as many
+    # as ``delta_heads``): the heads of q and k where they are fewer than the
+    # VALUE heads ``delta_heads``, a key head's q and k serving ``delta_heads
+    # / delta_key_heads`` value heads in a row, each with a state, a decay
+    # and a write strength of its own (the qwen3_next line) --------------- #
     delta_heads: int = 0
+    delta_key_heads: int = 0
     delta_key_dim: int = 0
     delta_value_dim: int = 0
     delta_conv: int = 4
@@ -400,9 +409,13 @@ class GPTConfig:
                 for m in self.mixers) and set(self.mixers) != {"full"}, (
                     "a hybrid stack names every layer: sparse, linear, cca, "
                     "delta, mamba, indexed, or full beside one of them")
+            self.delta_key_heads = self.delta_key_heads or self.delta_heads
             assert "delta" not in self.mixers or (
                 self.delta_heads and self.delta_key_dim and self.delta_value_dim
-                and self.delta_conv >= 2), "a delta layer's heads and widths"
+                and self.delta_conv >= 2
+                and self.delta_heads % self.delta_key_heads == 0), (
+                    "a delta layer's heads and widths, its value heads whole "
+                    "groups of a key head's")
             assert "mamba" not in self.mixers or (
                 self.mamba_inner and self.mamba_state and self.mamba_dt_rank
                 and self.mamba_conv >= 2), "a mamba layer's channels and widths"
@@ -415,19 +428,20 @@ class GPTConfig:
             assert (self.norm == "rmsnorm" and self.mlp_type == "swiglu"
                     and not self.use_bias and not self.kv_lora_rank
                     and self.block_type == "sequential")
-            # a hybrid stack's expert layers: the WHOLE bank, no token
-            # dropped, behind the MLP router with its stream ("moe") or the
-            # linear softmax router ("moe_softmax").  Still unwritten in the
-            # walk: the sigmoid router with its correction bias, a shared
-            # expert beside the bank, a held share of the bank, and a router
+            # a hybrid stack's expert layers: no token dropped, behind the
+            # MLP router with its stream ("moe") or the linear softmax router
+            # ("moe_softmax"); the bank whole or a held share of it
+            # (``moe_experts_held``), a shared expert beside it, behind a gate
+            # of its own where ``moe_shared_gate``.  Still unwritten in the
+            # walk: the sigmoid router with its correction bias and a router
             # that reads the mixer's input (ROADMAP M9 (b))
             banks = [f for f in self.ffns if f != "mlp"]
             assert all(f in ("moe", "moe_softmax") for f in banks), self.ffns
-            assert not banks or (
-                self.moe_router == "dropless" and not self.moe_shared_experts
-                and self.moe_experts_held is None), (
-                    "a hybrid stack's expert layers: the whole bank behind a "
-                    "dropless router, no shared expert beside it")
+            assert not banks or self.moe_router == "dropless", (
+                "a hybrid stack's expert layers: a bank behind a dropless "
+                "router")
+            assert not self.moe_shared_gate or (banks and self.moe_shared_experts), (
+                "moe_shared_gate is the gate of a shared expert beside a bank")
             assert "moe" not in banks or self.moe_router_hidden, (
                 "the 'moe' feed-forward is the MLP router with its stream: "
                 "moe_router_hidden")
@@ -440,9 +454,9 @@ class GPTConfig:
             assert not self.norm_after or not banks, (
                 "the norm on a sublayer's output is the dense MLP's")
         else:
-            assert not self.norm_after, (
-                "the norm on a sublayer's output is read by the hybrid walk "
-                "(models/hybrid.py) alone")
+            assert not self.norm_after and not self.moe_shared_gate, (
+                "the norm on a sublayer's output and the shared expert's gate "
+                "are read by the hybrid walk (models/hybrid.py) alone")
             assert self.qk_norm in (False, True, "head"), self.qk_norm
             assert not self.norm_sandwich or (
                 self.norm == "rmsnorm" and self.block_type == "sequential"), (
@@ -809,7 +823,9 @@ def zaya_config(vocab_size=262272, n_positions=131072, n_embd=2048, n_layer=40,
                         intermediate_size=intermediate_size, **kw)
 
 
-_OLMO_HYBRID_MIXERS = {"linear_attention": "delta", "full_attention": "full"}
+# the mixer of a ``layer_types`` entry of the families whose sources name their
+# layers so (Olmo-Hybrid, Qwen3-Next)
+_LAYER_TYPE_MIXERS = {"linear_attention": "delta", "full_attention": "full"}
 
 
 def olmo_hybrid_config(vocab_size=100352, n_positions=65536, n_embd=3840,
@@ -834,7 +850,7 @@ def olmo_hybrid_config(vocab_size=100352, n_positions=65536, n_embd=3840,
     through ``init_serving()`` (``models/hybrid.py``); the dense paths refuse
     it."""
     assert layer_types, "layer_types: 'linear_attention' or 'full_attention' a layer"
-    pattern = tuple(LayerKind(None, False, _OLMO_HYBRID_MIXERS[t])
+    pattern = tuple(LayerKind(None, False, _LAYER_TYPE_MIXERS[t])
                     for t in layer_types)
     kw = dict(n_kv_head=n_kv_head, head_dim=head_dim, ln_eps=1e-6,
               layer_pattern=pattern, qk_norm=True, norm_after=True,
@@ -905,6 +921,64 @@ def keye_vl2_config(vocab_size=151936, n_positions=262144, n_embd=2048,
     kw.update(overrides)
     return llama_config(vocab_size=vocab_size, n_positions=n_positions,
                         n_embd=n_embd, n_layer=n_layer, n_head=n_head,
+                        intermediate_size=intermediate_size, **kw)
+
+
+def qwen3_next_config(vocab_size=151936, n_positions=262144, n_embd=2048,
+                      n_head=16, n_kv_head=2, head_dim=256,
+                      intermediate_size=5120, layer_types=None,
+                      partial_rotary_factor=0.25, linear_heads=32,
+                      linear_key_heads=16, linear_key_head_dim=128,
+                      linear_value_head_dim=128, linear_conv_kernel_dim=4,
+                      num_experts=512, top_k=10, moe_intermediate_size=512,
+                      shared_expert_intermediate_size=512, experts_held=None,
+                      **overrides) -> GPTConfig:
+    """Qwen3-Next family (``model_type`` qwen3_next; defaults:
+    Qwen3-Next-80B-A3B's widths): layers of the gated delta rule
+    (``"linear_attention"``: ``linear_heads`` VALUE heads, each a float32
+    state ``[linear_key_head_dim, linear_value_head_dim]`` a slot, on
+    ``linear_key_heads`` heads of q and k, a key head's serving the value
+    heads ``j`` with ``j // (linear_heads / linear_key_heads)`` its index;
+    the write strength ``sigmoid(b)``; a causal convolution of
+    ``linear_conv_kernel_dim`` taps over the packed ``[q | k | v]``:
+    ``models/hybrid.py:delta_mixer``) among layers of gated softmax attention
+    (``"full_attention"``: ``n_head`` query heads on ``n_kv_head`` K/V heads,
+    an RMSNorm over each HEAD's lanes of q and of k, rope on the first
+    ``partial_rotary_factor`` of a head's lanes, the output under a sigmoid
+    gate a lane before ``W_o``), in the order ``layer_types`` gives (a slice
+    of the published list, three ``linear_attention`` to every
+    ``full_attention``, is a pipeline stage); pre-norm blocks; EVERY
+    feed-forward a bank of ``num_experts`` SwiGLU experts
+    ``moe_intermediate_size`` wide, ``top_k`` a token renormalised behind a
+    linear softmax router, no token dropped, beside ONE shared expert
+    ``shared_expert_intermediate_size`` wide behind a sigmoid gate of its
+    own; ``experts_held = (first, count)`` keeps one chip's share of the
+    bank.  RMSNorm (eps 1e-6; the family's zero-centred gain ``1 + w`` is
+    kept as the gain ``g``), no bias, untied head, rope theta 1e7.  No
+    multi-token-prediction module.  Served through ``init_serving()``
+    (``models/hybrid.py``); the dense paths refuse it."""
+    assert layer_types, "layer_types: 'linear_attention' or 'full_attention' a layer"
+    assert shared_expert_intermediate_size % moe_intermediate_size == 0, (
+        "the shared expert is whole experts wide (moe_shared_experts)")
+    pattern = tuple(LayerKind(None, True, _LAYER_TYPE_MIXERS[t], ffn="moe_softmax")
+                    for t in layer_types)
+    kw = dict(n_kv_head=n_kv_head, head_dim=head_dim, ln_eps=1e-6,
+              rope_theta=1e7, rope_dim=int(head_dim * partial_rotary_factor),
+              layer_pattern=pattern, qk_norm="head", attn_gate=True,
+              delta_heads=linear_heads, delta_key_heads=linear_key_heads,
+              delta_key_dim=linear_key_head_dim,
+              delta_value_dim=linear_value_head_dim,
+              delta_conv=linear_conv_kernel_dim,
+              moe_num_experts=num_experts, moe_top_k=top_k,
+              moe_expert_hidden=moe_intermediate_size,
+              moe_router="dropless", moe_norm_topk=True,
+              moe_shared_experts=(shared_expert_intermediate_size
+                                  // moe_intermediate_size),
+              moe_shared_gate=True,
+              moe_experts_held=tuple(experts_held) if experts_held else None)
+    kw.update(overrides)
+    return llama_config(vocab_size=vocab_size, n_positions=n_positions,
+                        n_embd=n_embd, n_layer=len(pattern), n_head=n_head,
                         intermediate_size=intermediate_size, **kw)
 
 
@@ -1461,6 +1535,20 @@ def _mlp(cfg: "GPTConfig", p: Dict, h: Array, dt, matmul=None,
     return out
 
 
+def shared_expert(cfg: "GPTConfig", wi, wo, x: Array, dt, gate_w=None) -> Array:
+    """The expert every row goes through beside the routed ones, both walks':
+    the block's own MLP on the leaves ``wi`` and ``wo``, and with ``gate_w
+    [E, 1]`` behind a gate of its own, ``sigmoid(x . w_s) * Shared(x)`` (the
+    gate's logit and the product float32).  The scope ``moe_shared`` holds
+    the expert AND its gate."""
+    with jax.named_scope("moe_shared"):
+        y = _mlp(cfg, {"fc_w": wi, "proj_w": wo}, x, dt)
+        if gate_w is not None:
+            y = y.astype(jnp.float32) * jax.nn.sigmoid(jnp.dot(
+                x, _wleaf(gate_w, dt), preferred_element_type=jnp.float32))
+        return y
+
+
 _EXPERT_LEAVES = {"wi": "fc_w", "bi": "fc_b", "wo": "proj_w", "bo": "proj_b"}
 
 
@@ -1529,10 +1617,8 @@ def _ffn(cfg: "GPTConfig", p: Dict, h: Array, dt, rng=None,
                 lambda q, rows: _mlp(cfg, q, rows, dt), bank)
             counts = counts.astype(jnp.int32)
         if cfg.moe_shared_experts:
-            with jax.named_scope("moe_shared"):
-                shared = {"fc_w": p["moe"]["shared"]["wi"],
-                          "proj_w": p["moe"]["shared"]["wo"]}
-                y = y + _mlp(cfg, shared, xt, dt).astype(y.dtype)
+            shared = p["moe"]["shared"]
+            y = y + shared_expert(cfg, shared["wi"], shared["wo"], xt, dt).astype(y.dtype)
     return y.reshape(*lead, E).astype(dt), l_aux.astype(jnp.float32), counts
 
 

@@ -6,7 +6,11 @@ in runs of a mixer, dispatching on both.  The MiniCPM-SALA family
 period over a dense MLP), the ZAYA1 family (``zaya_config``: ``cca`` layers
 over an expert bank), the Olmo-Hybrid family (``olmo_hybrid_config``:
 ``delta`` layers, three to every ``full`` layer, each norm on its sublayer's
-output), the Keye-VL-2.0 family (``keye_vl2_config``: ``indexed`` layers
+output), the Qwen3-Next family (``qwen3_next_config``: ``delta`` layers of
+more value heads than key heads, three to every ``full`` layer with a norm a
+head, rope on a quarter of its lanes and an output gate, every layer over a
+HELD share of a softmax bank beside a gated shared expert), the Keye-VL-2.0
+family (``keye_vl2_config``: ``indexed`` layers
 over a bank behind a linear softmax router) and the Jamba family
 (``jamba_config``: ``mamba`` layers, thirteen to every ``full`` layer of
 multi-query attention) are entries of :data:`MIXERS` and
@@ -62,7 +66,9 @@ multi-query attention) are entries of :data:`MIXERS` and
   the decay ``a_t`` and the write strength ``b_t`` (up to 2: the transition's
   eigenvalue reaches -1) computed from the token, behind a causal
   convolution over time of the packed ``[q | k | v]`` whose last ``taps -
-  1`` rows a slot keeps too.  One update a decode row
+  1`` rows a slot keeps too; q and k may have fewer heads than v
+  (``delta_key_heads``: a key head's serve the value heads in a row, each
+  with a state of its own).  One update a decode row
   (``ops/pallas/delta_rule.py``: the state read once and written once); over
   a prompt chunk the chunked form with a unit lower-triangular solve a head
   (:func:`delta_chunk`).  Both states start from zero BY POSITION, as the
@@ -79,8 +85,9 @@ multi-query attention) are entries of :data:`MIXERS` and
   keeps too.  Both states start from zero BY POSITION;
 * ``full``: plain softmax attention over its own token's K and V, beside a
   mixer that owns no pages: ``models/gpt.py``'s projection (with its q/k
-  norm over all lanes where the configuration has one) and the page group's
-  plan, CALLED from the walk.
+  norm over all lanes or over a head's, rope over ``rope_dim`` lanes), the
+  page group's plan and ``models/gpt.py``'s output gate (``attn_gate``),
+  CALLED from the walk: the fields the periodic walk reads.
 
 The leaves are stacked BY MIXER (``params["blocks"]["sparse"]`` ``[4, ...]``,
 ``["linear"]`` ``[12, ...]``: no projection is padded to another kind's
@@ -221,8 +228,10 @@ def _cca_widths(cfg) -> Tuple[int, int]:
 
 
 def _delta_lanes(cfg) -> int:
-    """Lanes of a delta layer's packed ``[q | k | v]``."""
-    return cfg.delta_heads * (2 * cfg.delta_key_dim + cfg.delta_value_dim)
+    """Lanes of a delta layer's packed ``[q | k | v]``: q and k a KEY head
+    (``delta_key_heads``), v a value head (``delta_heads``)."""
+    return (2 * cfg.delta_key_heads * cfg.delta_key_dim
+            + cfg.delta_heads * cfg.delta_value_dim)
 
 
 def _mixer_shapes(cfg, mixer: str) -> Dict:
@@ -232,10 +241,14 @@ def _mixer_shapes(cfg, mixer: str) -> Dict:
     if mixer == "linear":
         return dict(_GATED(cfg), qkv_w=(E, 3 * A), onorm_g=(A,))
     if mixer == "full":
-        # the q/k norm over all lanes where the configuration has one
-        norms = ({"q_norm_g": (A,), "k_norm_g": (cfg.kv_heads * D,)}
-                 if cfg.qk_norm else {})
-        return {"qkv_w": (E, cfg.qkv_dim), "out_w": (A, E), **norms}
+        # the q/k norm where the configuration has one, over all lanes or
+        # one gain of a HEAD's lanes the heads share; the output gate's
+        # projection where it has one: ``models/gpt.py``'s leaves
+        norms = ({} if not cfg.qk_norm else
+                 {"q_norm_g": (D,), "k_norm_g": (D,)} if cfg.qk_norm == "head" else
+                 {"q_norm_g": (A,), "k_norm_g": (cfg.kv_heads * D,)})
+        gate = {"gate_w": (E, A)} if cfg.attn_gate else {}
+        return {"qkv_w": (E, cfg.qkv_dim), "out_w": (A, E), **norms, **gate}
     if mixer == "mamba":
         # in_w: [W_u | W_z], the channels and their gate; conv_w (tap 0 the
         # oldest token's) and conv_b the depthwise convolution; x_w: [W_r |
@@ -260,7 +273,7 @@ def _mixer_shapes(cfg, mixer: str) -> Dict:
         # qkv_w: [W_q | W_k | W_v], the packed lanes the convolution runs
         # over (conv_w: tap 0 the oldest token's); gate_w the output gate z;
         # ba_w: [W_b | W_a], the write strength's and the decay's logit a
-        # head; onorm_g ONE gain for all heads
+        # VALUE head; onorm_g ONE gain for all heads
         H, U = cfg.delta_heads, _delta_lanes(cfg)
         return {"qkv_w": (E, U), "gate_w": (E, H * cfg.delta_value_dim),
                 "ba_w": (E, 2 * H), "conv_w": (cfg.delta_conv, U),
@@ -282,15 +295,24 @@ def _ffn_shapes(cfg, ffn: str) -> Dict:
     if ffn == "mlp":
         return {"fc_w": (E, 2 * cfg.ffn_dim), "proj_w": (cfg.ffn_dim, E)}
     N, R, I = cfg.moe_num_experts, cfg.moe_router_hidden, cfg.moe_expert_hidden or cfg.ffn_dim
-    experts = {"wi": (N, E, 2 * I), "wo": (N, I, E)}
+    G = cfg.bank_experts[1]     # held by the bank; the router stays N wide
+    experts = {"wi": (G, E, 2 * I), "wo": (G, I, E)}
+    # the expert every row goes through, ``moe_shared_experts`` experts wide,
+    # and the logit of its gate where it has one
+    shared = {}
+    if cfg.moe_shared_experts:
+        Is = cfg.moe_shared_experts * I
+        shared = {"shared_fc_w": (E, 2 * Is), "shared_proj_w": (Is, E)}
+        if cfg.moe_shared_gate:
+            shared["shared_gate_w"] = (E, 1)
     if ffn == "moe_softmax":
-        return {"router_w": (E, N), "experts": experts}
+        return {"router_w": (E, N), "experts": experts, **shared}
     # the router: down to its stream's width, the stream of the layer before
     # times stream_g, a norm, three matrices; balance_bias chooses and never
     # weighs.  The bank is a group of its own, as the other MoE families' is
     return {"router_in_w": (E, R), "stream_g": (), "router_norm_g": (R,),
             "router_w1": (R, R), "router_w2": (R, R), "router_w3": (R, N),
-            "balance_bias": (N,), "experts": experts}
+            "balance_bias": (N,), "experts": experts, **shared}
 
 
 def _leaf_shapes(cfg, mixer: str) -> Dict:
@@ -351,8 +373,8 @@ def init_blocks(cfg, rng: Array) -> Dict:
 
 
 def block_partition_specs(cfg) -> Dict:
-    column = {"q_w", "kv_w", "qkv_w", "gate_w", "fc_w", "in_w"}
-    row = {"out_w", "proj_w"}
+    column = {"q_w", "kv_w", "qkv_w", "gate_w", "fc_w", "in_w", "shared_fc_w"}
+    row = {"out_w", "proj_w", "shared_proj_w"}
     bank = {"wi": ("expert", None, "tensor"), "wo": ("expert", "tensor", None)}
 
     def spec(path, _):
@@ -940,6 +962,7 @@ def delta_mixer(cfg, p, h, kp, vp, held, li, step: _Step):
     positions, live, slots, _, _, _, chunk, dt, _, _ = step
     B = h.shape[0]
     H, dk, dv = cfg.delta_heads, cfg.delta_key_dim, cfg.delta_value_dim
+    Hk = cfg.delta_key_heads
     n_dec = B - chunk
     first = positions[n_dec] if chunk else None
     f32 = lambda name: p[name].astype(jnp.float32)
@@ -957,9 +980,12 @@ def delta_mixer(cfg, p, h, kp, vp, held, li, step: _Step):
                         + w[-1] * u.astype(jnp.float32))
         unit = lambda t: t * jax.lax.rsqrt(
             jnp.sum(jnp.square(t), axis=-1, keepdims=True) + 1e-6)
-        q = unit(c[:, :H * dk].reshape(B, H, dk)) / math.sqrt(dk)
-        k = unit(c[:, H * dk:2 * H * dk].reshape(B, H, dk))
-        v = c[:, 2 * H * dk:].reshape(B, H, dv)
+        q = unit(c[:, :Hk * dk].reshape(B, Hk, dk)) / math.sqrt(dk)
+        k = unit(c[:, Hk * dk:2 * Hk * dk].reshape(B, Hk, dk))
+        if Hk != H:
+            # a key head's q and k serve H / Hk value heads in a row
+            q, k = jnp.repeat(q, H // Hk, axis=1), jnp.repeat(k, H // Hk, axis=1)
+        v = c[:, 2 * Hk * dk:].reshape(B, H, dv)
         ba = ba.astype(jnp.float32)
         beta = (2.0 if cfg.delta_neg_eigval else 1.0) * jax.nn.sigmoid(ba[:, :H])
         g = -jnp.exp(f32("a_log")) * jax.nn.softplus(ba[:, H:] + f32("dt_bias"))
@@ -1377,9 +1403,11 @@ def indexed_mixer(cfg, p, h, kp, vp, held, li, step: _Step):
 def full_mixer(cfg, p, h, kp, vp, held, li, step: _Step):
     """One layer of plain softmax attention over its own token's K and V, the
     pages its own: ``models/gpt.py:_project_qkv`` (the fused projection, the
-    q/k norm over all lanes, rope where the layer's kind ropes) and the page
-    group's plan, as ``gpt_paged_step`` calls them.  -> (output ``[B, E]``,
-    kp, vp with the rows' K and V written, held as it came)."""
+    q/k norm over all lanes or a head's, rope where the layer's kind ropes,
+    over ``rope_dim`` lanes), the page group's plan and ``_attn_out`` (the
+    output gate where ``attn_gate``), as ``gpt_paged_step`` calls them.  ->
+    (output ``[B, E]``, kp, vp with the rows' K and V written, held as it
+    came)."""
     (positions, live, _, tables, write_blocks, write_offsets, chunk, dt, plan,
      tile_runs) = step
     B = h.shape[0]
@@ -1391,7 +1419,9 @@ def full_mixer(cfg, p, h, kp, vp, held, li, step: _Step):
     vp = vp.at[li, write_blocks, write_offsets].set(v.astype(vp.dtype).reshape(B, 1, -1))
     o = plan.attend(q, (kp, vp), li, tables, positions, chunk=chunk,
                     tile_runs=tile_runs).reshape(B, cfg.attn_dim)
-    o = _rows_that_carry(lambda r: r @ gpt._wget(p, "out_w", dt), (o,), chunk, live)
+    # through the output gate where the configuration has one, then ``W_o``
+    o = _rows_that_carry(lambda o, h: gpt._attn_out(cfg, p, o, h, dt), (o, h),
+                         chunk, live)
     return o, kp, vp, held
 
 
@@ -1438,17 +1468,23 @@ def _softmax_route(cfg, p, z, stream):
 
 def _moe_ffn(route):
     """A bank of SwiGLU experts with ``moe_top_k`` a token behind ``route(cfg,
-    p, z, stream) -> (stream, weights, experts)``.  ``bank``: the mixer's
-    stacked expert leaves ``[layers, experts, ...]``, which ``grouped_matmul``
-    reads at layer ``li`` where they lie.  -> (output, the stream for the
-    next layer, the live rows' assignments an expert ``[experts]`` int32)."""
+    p, z, stream) -> (stream, weights, experts)``, the pieces
+    ``models/gpt.py:_ffn`` is made of: ``dropless_moe`` over the experts the
+    bank HOLDS (``moe_experts_held``: the router chooses among all, the held
+    are computed and the partial result goes on; then the rows that carry no
+    request lie in no group either, as in ``_ffn``) and ``gpt.shared_expert``
+    beside it, behind its gate where ``moe_shared_gate``.  ``bank``: the
+    mixer's stacked expert leaves ``[layers, experts held, ...]``, which
+    ``grouped_matmul`` reads at layer ``li`` where they lie.  -> (output, the
+    stream for the next layer, the live rows' assignments an expert the
+    router chooses among ``[experts]`` int32)."""
     def ffn(cfg, p, bank, li, x, stream, step: _Step):
         from deepspeed_tpu.moe import dropless
         live, chunk, dt = step.live, step.chunk, step.dt
-        N = cfg.moe_num_experts
+        N, held = cfg.moe_num_experts, cfg.moe_experts_held
         leaves = {"fc_w": bank["wi"], "proj_w": bank["wo"]}
 
-        def rows(x, stream=None):
+        def rows(x, live, stream=None):
             z = gpt.rms_norm(x, p["ln2_g"], eps=cfg.ln_eps)
             with jax.named_scope("moe"):
                 with jax.named_scope("moe_router"):
@@ -1456,11 +1492,15 @@ def _moe_ffn(route):
                 y = dropless.dropless_moe(
                     z, weights, experts, N,
                     lambda r, matmul, pick: gpt._mlp(cfg, leaves, r, dt, matmul, pick),
-                    layer=li)
+                    held=held, layer=li, live=live if held else None)
+                if cfg.moe_shared_experts:
+                    y = y + gpt.shared_expert(
+                        cfg, p["shared_fc_w"], p["shared_proj_w"], z, dt,
+                        p["shared_gate_w"] if cfg.moe_shared_gate else None)
             return y.astype(dt), stream, experts
 
         y, stream, experts = _rows_that_carry(
-            rows, (x,) if stream is None else (x, stream), chunk, live)
+            rows, (x, live) if stream is None else (x, live, stream), chunk, live)
         return y, stream, dropless.expert_counts(experts, N, live)
     return ffn
 

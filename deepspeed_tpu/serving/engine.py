@@ -1368,15 +1368,17 @@ class ServingEngine:
         """How the last program's live rows spread over the experts the
         router chooses among (summed over layers); nothing for a dense
         model.  ``moe_experts_touched``: the experts with an assignment, of
-        each layer where the program counts a layer apart (a hybrid stack),
-        else of the sum over layers."""
+        the sum over layers, or where the program counts a layer apart (a
+        hybrid stack) of each layer and of the experts its bank HOLDS: what
+        the step reads of the bank here (a whole bank holds them all)."""
         by_layer = self._expert_counts.reshape(-1, self._moe_experts or 1)
         counts = by_layer.sum(axis=0)
         if not counts.size or not counts.any():
             return {}
         first, held = self.module.cfg.bank_experts
+        here = by_layer[:, first:first + held] if self._hybrid else by_layer
         return {"moe_load_max_over_mean": float(counts.max() / counts.mean()),
-                "moe_experts_touched": int((by_layer > 0).sum()),
+                "moe_experts_touched": int((here > 0).sum()),
                 # of the live rows' assignments, those on experts held here
                 "moe_assignments": int(counts.sum()),
                 "moe_assignments_held": int(counts[first:first + held].sum())}

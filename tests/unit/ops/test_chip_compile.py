@@ -1127,3 +1127,64 @@ def test_the_jamba2_step_scans_its_states_in_place(chip):
     # of a token's states a channel
     assert not re.search(rf"f32\[({slots}|{chunk}|{rows}),(16,5120|5120,16)\]", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+# ---- Qwen3-Next-80B-A3B: the most and the narrowest experts, heads of 256 ------------ #
+@pytest.mark.parametrize("stacked", [False, True], ids=["a_layer", "stacked"])
+@pytest.mark.parametrize("rows", [5504, 384], ids=["chunk_step", "decode_rows"])
+@pytest.mark.parametrize("K,N", [(2048, 1024), (512, 2048)])
+def test_grouped_matmul_compiles_at_qwen3_next_held_bank(chip, rows, K, N, stacked):
+    """The 256 held experts' bank (gate|up ``[256, 2048, 1024]``, down ``[256,
+    512, 2048]``: the most and the narrowest experts any cell holds) at the
+    serve cell's 544 rows x top 10 = 5,440 assignments (5,504 in whole row
+    tiles) and at the 32 decode rows' 320 (384), half of them in no group, 1
+    to 11 rows an expert: a walk of up to ``43 + 255`` visits."""
+    from deepspeed_tpu.ops.pallas import grouped_matmul as gm
+    assert gm.rows_to_whole_tiles(5440, K, BF16) == 64
+    assert gm.rows_to_whole_tiles(320, K, BF16) == 64
+    _bank_matmul_compiles(chip, rows, 256, K, N, stacked)
+
+
+def _qwen3_next_state_calls(text):
+    return [(int(a), int(b)) for a, b in re.findall(
+        r"%delta_state_update[.\d]* = \(f32\[(\d+),(\d+),128,4096\][^\n]*tpu_custom_call", text)]
+
+
+def test_the_qwen3_next_step_compiles_at_the_published_widths(chip):
+    """The whole step of one period (L L L F) of Qwen3-Next-80B-A3B at the
+    published widths over one chip's 256 of 512 experts, 32 slots and a chunk
+    of 512 over pages of 64 tokens under tables of 720: the full layer's
+    attention is the paged GQA kernel at D = 256, 16 query heads on 2 K/V
+    heads (a page row of 512 lanes), at two shapes; the delta layers' states
+    ``[128, 32 x 128]`` go through the kernel on the stacked array in place
+    (32 VALUE heads on 16 key heads); the bank's matmuls are the grouped
+    kernel over the stacked leaves of the 256 held, a call a layer's matrix
+    (gate|up and down) a run of the walk; the chunked form with its solve
+    sits under the branch a step without a prompt chunk takes the other side
+    of."""
+    from deepspeed_tpu.models import gpt
+    from deepspeed_tpu.ops.pallas import delta_rule
+    cfg = gpt.qwen3_next_config(
+        layer_types=3 * ["linear_attention"] + ["full_attention"],
+        experts_held=(0, 256), vocab_size=75968, vocab_multiple=64, dtype=BF16)
+    H, Hkv, D256, BS, slots, chunk, MB, NB = 16, 2, 256, 64, 32, 512, 720, 2049
+    assert da.gqa_kernel_shape_ok(H, Hkv, D256, BS, BF16)
+    assert delta_rule.kernel_shape_ok(32, 128, 128, jnp.float32)
+    plan = da.softmax_plan(H, Hkv, D256, BS, MB, chunk, BF16)
+    assert plan.kernel == "paged_gqa_attention" and chunk % plan.chunk_queries == 0
+    compiled, kp, aux = _step_program(chip, cfg, slots, chunk, BS, NB, MB,
+                                      counts=True, donate=True)
+    assert kp.shape == (1, NB, BS, Hkv * D256)                   # ONE layer of four pages
+    assert aux["delta_state"].shape == (3, slots, 128, 4096) and aux["delta_state"].dtype == jnp.float32
+    assert aux["delta_conv"].shape == (3, slots, 3, 8192)
+    text = compiled.as_text()
+    assert _kernel_rows(text, "paged_gqa_attention") == [chunk // plan.chunk_queries, slots]
+    assert _qwen3_next_state_calls(text) == [(3, slots)]
+    calls = _bank_calls(text)
+    assert calls and all("bf16[3,256," in c or "bf16[1,256," in c for c in calls)
+    # no layer's bank sliced or copied out of its stack (the full layer's
+    # stack of ONE is a parameter as it stands)
+    assert not re.search(r"= bf16\[256,(2048,1024|512,2048)\]", text)
+    assert text.count("conditional(") >= 2
+    assert not re.search(rf"f32\[{slots},128,4096\]", text)      # no layer's states copied
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 << 30

@@ -327,13 +327,29 @@ def test_the_dense_paths_refuse_the_stack_by_what_they_lack(loud, path):
 
 @pytest.mark.parametrize("kw, said", [
     (dict(moe_scoring="sigmoid"), "sigmoid router"),
-    (dict(moe_shared_experts=1), "no shared expert"),
-    (dict(moe_experts_held=(0, 4)), "whole bank"),
     (dict(moe_router_input="pre_attn"), "router before attention"),
 ])
 def test_the_walk_still_refuses_the_routers_it_has_not_written(kw, said):
     with pytest.raises(AssertionError, match=said):
         keye_vl2_config(**WIDTHS, **kw)
+
+
+@pytest.mark.parametrize("kw, leaf, shape", [
+    (dict(moe_shared_experts=1), "shared_fc_w", "E, 2 * I"),
+    (dict(moe_experts_held=(0, 4)), "experts", "4"),
+])
+def test_the_walk_takes_a_shared_expert_and_a_held_share(kw, leaf, shape):
+    """What the hybrid walk refused until PR 64 (Qwen3-Next): this stack
+    with a shared expert beside the bank, or with a held share of it, builds
+    the leaves ``models/hybrid.py:_ffn_shapes`` gives them."""
+    cfg = keye_vl2_config(**WIDTHS, **kw)
+    shapes = hybrid._leaf_shapes(cfg, "indexed")
+    E, I = cfg.n_embd, cfg.moe_expert_hidden or cfg.ffn_dim
+    if leaf == "experts":
+        assert shapes["experts"]["wi"] == (4, E, 2 * I) and shapes["router_w"] == (E, cfg.moe_num_experts)
+    else:
+        assert shapes[leaf] == (E, 2 * I) and shapes["shared_proj_w"] == (I, E)
+        assert "shared_gate_w" not in shapes
 
 
 def test_a_chunk_that_is_not_whole_tiles_of_queries_is_refused(loud):

@@ -10,7 +10,11 @@ lead and three expert layers; ``jamba2-3b.json``: the whole model as served,
 and its float32 run a few layers, ``"n_layer": 4, "attn_layer_period": 4,
 "attn_layer_offset": 2`` in ``model.kwargs`` and ``reference.kwargs``;
 ``deepseek-v3.2-exp.json``: its float32 run is ``"n_layer": 2``, the dense
-layer and one expert layer, with ``--long 30000`` for a context that selects).
+layer and one expert layer, with ``--long 30000`` for a context that selects;
+``qwen3-next-80b-a3b.json``: one period as served; its float32 run holds 64
+of the 512 experts, ``"experts_held": [0, 64]`` in ``model.kwargs`` and
+``reference.kwargs``, so that the tree fits in float32, under
+``JAX_DEFAULT_MATMUL_PRECISION=highest``).
 
 Run standalone on a TPU host (``chiprun --chips 1 -- python
 tools/serve_parity.py benchmarks/configs/smallthinker-21b-a3b.json``); any
