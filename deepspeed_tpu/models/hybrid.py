@@ -70,9 +70,10 @@ multi-query attention) are entries of :data:`MIXERS` and
   (``delta_key_heads``: a key head's serve the value heads in a row, each
   with a state of its own).  One update a decode row
   (``ops/pallas/delta_rule.py``: the state read once and written once); over
-  a prompt chunk the chunked form with a unit lower-triangular solve a head
-  (:func:`delta_chunk`).  Both states start from zero BY POSITION, as the
-  linear layers' do;
+  a prompt chunk the chunked form, whose unit lower-triangular system a head
+  is solved by products (:func:`delta_chunk`, :func:`unit_lower_solve`: no
+  substitution longer than sixteen rows).  Both states start from zero BY
+  POSITION, as the linear layers' do;
 * ``mamba`` (Mamba-1's selective scan, arXiv:2312.00752, with the Jamba
   family's three inner norms): a channel's cache is ``mamba_state`` float32
   numbers a slot, a DIAGONAL recurrence ``h_t[n, d] = exp(dt_t[d] A[n, d])
@@ -875,6 +876,60 @@ def cca_mixer(cfg, p, h, kp, vp, held, li, step: _Step):
 # --------------------------------------------------------------------------- #
 # The delta mixer
 # --------------------------------------------------------------------------- #
+# the solve's diagonal blocks: substitution rows up to the first width, two
+# blocks made one from there to the second, block rows above (PERF.md § 6,
+# PR 65: of the widths the chip was timed at, 16 and 64 at both cells' chunks)
+SOLVE_ROWS, SOLVE_BLOCK = 16, 64
+
+
+def unit_lower_solve(L, rhs):
+    """``(I + L)^-1 rhs`` for ``L [H, C, C]`` strictly lower triangular and
+    ``rhs [H, C, dv]`` in float32, by products and no substitution longer
+    than ``SOLVE_ROWS``: exact in exact arithmetic for every ``C``.  The
+    diagonal blocks of ``SOLVE_ROWS`` are inverted by substitution, a row a
+    step, every head's every block at once (``T_i = e_i - sum_{j < i} L_ij
+    T_j``: what a solve does, so what it keeps of the digits; the finite
+    product ``(I - L)(I + L^2)(I + L^4)...`` is as exact and loses them all
+    where keys repeat, since the powers grow before they cancel); two
+    inverted blocks and the block below the first make one of twice the
+    width (``[[A, 0], [X, B]]^-1 = [[A^-1, 0], [-B^-1 X A^-1, B^-1]]``) up to
+    ``SOLVE_BLOCK`` or the chunk; then a block row of the unknowns a step,
+    ``D_r = T_r (rhs_r - L_r,<r D_<r)``.  ``C`` is padded to whole blocks
+    with rows of the identity."""
+    H, C, _ = L.shape
+    m = SOLVE_ROWS
+    while m < min(C, SOLVE_BLOCK):
+        m *= 2
+    pad = -C % m
+    L = jnp.pad(L, ((0, 0), (0, pad), (0, pad)))
+    rhs = jnp.pad(rhs, ((0, 0), (0, pad), (0, 0)))
+    mm = partial(jnp.matmul, precision=HIGHEST)
+
+    def blocks(w, of, down):
+        """Every ``of``-th block of width ``w`` on the diagonal of ``L``,
+        or the one ``down`` blocks under it: ``[H, n, w, w]``."""
+        return jnp.stack([L[:, (p + down) * w:(p + down + 1) * w, p * w:(p + 1) * w]
+                          for p in range(0, (C + pad) // w, of)], axis=1)
+
+    w = SOLVE_ROWS
+    N, eye = -blocks(w, 1, 0), jnp.eye(w, dtype=L.dtype)
+    T = jnp.broadcast_to(eye[:1], N.shape[:2] + (1, w))
+    for i in range(1, w):
+        T = jnp.concatenate([T, eye[i] + jnp.sum(
+            jnp.swapaxes(N[:, :, i:i + 1, :i], 2, 3) * T, axis=2, keepdims=True)], 2)
+    while w < m:
+        pairs = T.reshape(H, -1, 2, w, w)
+        A, B = pairs[:, :, 0], pairs[:, :, 1]
+        X = -mm(mm(B, blocks(w, 2, 1)), A)
+        T = jnp.concatenate([jnp.concatenate([A, jnp.zeros_like(A)], -1),
+                             jnp.concatenate([X, B], -1)], -2)
+        w *= 2
+    D = mm(T[:, 0], rhs[:, :m])
+    for r in range(m, C + pad, m):
+        D = jnp.concatenate([D, mm(T[:, r // m], rhs[:, r:r + m] - mm(L[:, r:r + m, :r], D))], 1)
+    return D[:, :C]
+
+
 def delta_chunk(q, k, v, g, beta, s_in, live):
     """The gated delta rule over ``C`` consecutive tokens of one sequence
     that enters with the state ``s_in [H, dk, dv]``, in its chunked form.
@@ -886,9 +941,10 @@ def delta_chunk(q, k, v, g, beta, s_in, live):
     the tokens' writes ``D`` solve the unit lower-triangular ``(I + L) D =
     diag(beta) (V - diag(B) K S_in)``, ``L_ij = beta_i R_ij (k_i . k_j)`` for
     ``j < i`` (token ``i``'s correction reads what the tokens before it
-    wrote); then ``O = diag(B) Q S_in + tril(R . Q K^T) D`` and ``S_out = B_C
-    S_in + sum_j R_Cj k_j d_j^T``.  Every decay formed is the exponential of
-    a number ``<= 0``: the mask goes on the exponent."""
+    wrote; :func:`unit_lower_solve` gets them by products); then ``O =
+    diag(B) Q S_in + tril(R . Q K^T) D`` and ``S_out = B_C S_in + sum_j R_Cj
+    k_j d_j^T``.  Every decay formed is the exponential of a number ``<= 0``:
+    the mask goes on the exponent."""
     C = q.shape[0]
     g, beta = jnp.where(live[:, None], g, 0.0), jnp.where(live[:, None], beta, 0.0)
     G = jnp.cumsum(g, axis=0).T                                    # [H, C]
@@ -900,9 +956,7 @@ def delta_chunk(q, k, v, g, beta, s_in, live):
     L = jnp.where(i[:, None] > i[None, :], beta.T[:, :, None] * R * kk, 0.0)
     rhs = beta[:, :, None] * (v - B * jnp.einsum(
         "ihd,hde->ihe", k, s_in, precision=HIGHEST))
-    D = jax.lax.linalg.triangular_solve(
-        L, rhs.transpose(1, 0, 2), left_side=True, lower=True,
-        unit_diagonal=True)                                        # [H, C, dv]
+    D = unit_lower_solve(L, rhs.transpose(1, 0, 2))                # [H, C, dv]
     A = R * jnp.einsum("ihd,jhd->hij", q, k, precision=HIGHEST)
     o = (jnp.einsum("hij,hje->ihe", A, D, precision=HIGHEST)
          + B * jnp.einsum("ihd,hde->ihe", q, s_in, precision=HIGHEST))
@@ -1005,7 +1059,7 @@ def delta_mixer(cfg, p, h, kp, vp, held, li, step: _Step):
                                         live[n_dec:])
                 return oc, s_out.transpose(1, 0, 2).reshape(kept.shape)
 
-            # a step without a chunk (three of four here) skips the solve,
+            # a step without a chunk (three of four here) skips the form,
             # and leaves the slot its rows name (slot 0) as it is
             oc, s_out = jax.lax.cond(
                 live[n_dec], over_the_chunk,

@@ -149,9 +149,10 @@ def test_a_step_with_decode_rows_and_a_chunk_together(loud):
 
 
 # ---- (b) the chunked form against the recurrence -------------------------------------- #
-def _recurrence(q, k, v, g, beta, s_in, n):
-    """Step 5 of the specification, a token at a time in float64."""
-    S, out = np.asarray(s_in, np.float64), []
+def _recurrence(q, k, v, g, beta, s_in, n, dtype=np.float64):
+    """Step 5 of the specification, a token at a time in float64 (in the
+    arguments' own type under another ``dtype``)."""
+    S, out = np.asarray(s_in, dtype), []
     for t in range(n):
         S = np.exp(g[t])[:, None, None] * S
         m = np.einsum("hkv,hk->hv", S, k[t])
@@ -181,6 +182,49 @@ def test_the_chunked_form_is_the_recurrence(live):
     assert np.abs(np.asarray(o)[:live] - want_o).max() < 2e-5
     assert np.abs(np.asarray(s_out) - want_s).max() < 2e-5
     assert np.abs(want_o).max() > 0.1
+
+
+@pytest.mark.parametrize("decays", [True, False], ids=["decay", "no-decay-repeated-key"])
+@pytest.mark.parametrize("C, live", [(176, 176), (176, 41), (512, 512), (512, 300), (40, 33)])
+def test_the_chunked_form_at_the_cells_chunk_lengths(C, live, decays):
+    """The two cells' own chunks (176 and 512) and a ragged one, whole and
+    short of the chunk, write strengths up to 2: the writes got from
+    products (:func:`hybrid.unit_lower_solve`: substitution rows, doubled
+    blocks, block rows, the chunk padded to whole blocks) read what the
+    recurrence reads.  Without decay nothing is forgotten over the chunk, and
+    a third of the tokens share ONE key: the case in which the powers of
+    ``L`` grow before they cancel (a finite product in the rows' place is
+    wrong in the second digit there).  The tolerance is one the recurrence a
+    token at a time in float32 meets with room: at 512 tokens the form's own
+    sums of decays are good to 1e-5."""
+    q, k, v, g, beta, s_in = _tokens(C, 2, 16, 8, seed=C + live)
+    if not decays:
+        g, k[::3] = np.zeros_like(g), k[0]
+    assert beta.max() > 1.9
+    f32 = lambda a: jnp.asarray(a, jnp.float32)
+    o, s_out = jax.jit(hybrid.delta_chunk)(f32(q), f32(k), f32(v), f32(g), f32(beta),
+                                           f32(s_in), jnp.arange(C) < live)
+    want_o, want_s = _recurrence(q, k, v, g, beta, s_in, live)
+    single = [np.asarray(a, np.float32) for a in (q, k, v, g, beta, s_in)]
+    for got, want in zip(_recurrence(*single, live, np.float32), (want_o, want_s)):
+        assert np.abs(got - want).max() < 1e-5
+    assert np.abs(np.asarray(o)[:live] - want_o).max() < 3e-5
+    assert np.abs(np.asarray(s_out) - want_s).max() < 3e-5
+    assert np.abs(want_o).max() > 0.1
+
+
+def test_no_triangular_solve_is_left_in_a_chunk():
+    """The writes of a chunk of 512 lower to products: XLA's triangular
+    solve (a custom call on the chip, a row-by-row substitution of 1.4 ms a
+    layer; PERF.md § 6, PR 65) is in neither text of the program."""
+    shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
+    C, H, dk, dv = 512, 2, 16, 8
+    lowered = jax.jit(hybrid.delta_chunk).lower(
+        shape(C, H, dk), shape(C, H, dk), shape(C, H, dv), shape(C, H), shape(C, H),
+        shape(H, dk, dv), jax.ShapeDtypeStruct((C,), jnp.bool_))
+    for text in (lowered.as_text(), lowered.as_text(dialect="hlo")):
+        assert "dot" in text
+        assert "triangular_solve" not in text and "triangular-solve" not in text
 
 
 # ---- (e) a write strength past 1 ------------------------------------------------------- #
