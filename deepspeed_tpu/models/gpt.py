@@ -124,6 +124,26 @@ class YarnRope(NamedTuple):
     query_beta: float = 0.0
 
 
+class HyperSpec(NamedTuple):
+    """Manifold-constrained hyper-connections (mHC, arXiv:2512.24880, over
+    Hyper-Connections, arXiv:2409.19606; the xing4_0 line's ``hc_mult``,
+    ``hc_sinkhorn_iters``, ``hc_eps``, ``mhc_h_res_clamp_min/max``): a token
+    carries ``streams`` residual streams ``X [n, E]`` from layer to layer.
+    Each SUBLAYER ``F`` reads ``u = Hpre @ X`` and writes ``X' = Hres @ X +
+    outer(Hpost, F(u))``; the three maps come from the token's own streams
+    (:func:`hyper_maps`), ``Hres`` made doubly stochastic by
+    ``sinkhorn_iters`` rounds of Sinkhorn-Knopp on ``exp`` of its logits
+    clamped to ``[clamp_min, clamp_max]``; ``eps`` is the eps of both
+    normalisations and of the RMSNorm over all ``n E`` lanes.  The embedding
+    is copied to the streams and the streams are summed before the final
+    norm (:func:`gpt_paged_step`)."""
+    streams: int = 4
+    sinkhorn_iters: int = 20
+    eps: float = 1e-6
+    clamp_min: float = -30.0
+    clamp_max: float = 30.0
+
+
 @dataclasses.dataclass
 class GPTConfig:
     vocab_size: int = 50257
@@ -308,6 +328,12 @@ class GPTConfig:
     # frequencies), and the chosen tokens are rows of the latent cache
     # (DeepSeek-V3.2-Exp: ``gpt_paged_step``)
     indexer: Optional[IndexerSpec] = None
+    # the residual path where it is not ``x + f(norm(x))``: ``streams``
+    # residual streams a token, read and written through maps of the token's
+    # own (:class:`HyperSpec`; leaves ``hc_{attn,mlp}_{phi,b,alpha}``).  The
+    # periodic walk of ``gpt_paged_step`` reads it; the dense paths and the
+    # hybrid walk refuse it
+    hyper: Optional[HyperSpec] = None
     # --- the gated delta rule (the Olmo-Hybrid family's ``delta`` layers,
     # ``models/hybrid.py:delta_mixer``): ``delta_heads`` heads, a key of
     # ``delta_key_dim`` and a value of ``delta_value_dim`` lanes, behind a
@@ -389,6 +415,14 @@ class GPTConfig:
         self.v_head_dim = self.v_head_dim or self.head_dim
         if self.rope_yarn is not None:
             self.rope_yarn = YarnRope(*self.rope_yarn)
+        if self.hyper is not None:
+            self.hyper = HyperSpec(*self.hyper)
+            assert self.hyper.streams >= 2 and self.hyper.sinkhorn_iters >= 1 \
+                and self.block_type == "sequential" and self.scan_layers and \
+                all(k.mixer == "softmax" for k in self.pattern), (
+                    "residual streams: two or more, mixed round the two "
+                    "sublayers of a sequential block of the periodic walk "
+                    "(the hybrid walk carries one stream)")
         if self.kv_lora_rank:
             assert self.q_lora_rank and self.qk_rope_dim and \
                 self.qk_rope_dim < self.head_dim and len(self.pattern) == 1 \
@@ -741,7 +775,8 @@ def deepseek_v32_config(vocab_size=129280, n_positions=163840, n_embd=7168,
     kw = dict(head_dim=head_dim, q_lora_rank=q_lora_rank,
               kv_lora_rank=kv_lora_rank, qk_rope_dim=qk_rope_dim,
               v_head_dim=v_head_dim, rope_interleaved=True,
-              rope_yarn=tuple(rope_yarn), ln_eps=1e-6, indexer=tuple(indexer),
+              rope_yarn=tuple(rope_yarn), ln_eps=1e-6,
+              indexer=tuple(indexer) if indexer else None,
               moe_num_experts=num_experts, moe_top_k=top_k,
               moe_expert_hidden=moe_intermediate_size,
               moe_dense_layers=dense_layers, moe_router="dropless",
@@ -753,6 +788,32 @@ def deepseek_v32_config(vocab_size=129280, n_positions=163840, n_embd=7168,
     return llama_config(vocab_size=vocab_size, n_positions=n_positions,
                         n_embd=n_embd, n_layer=n_layer, n_head=n_head,
                         intermediate_size=intermediate_size, **kw)
+
+
+def xing4_config(vocab_size=131072, n_positions=262144, n_embd=3584, n_layer=40,
+                 n_head=32, q_lora_rank=768, intermediate_size=9216,
+                 moe_intermediate_size=1024, num_experts=64, top_k=4,
+                 dense_layers=2, route_scale=2.0,
+                 rope_yarn=(64.0, 4096, 32.0, 1.0, 1.0, 1.0, 0.0),
+                 hyper=(4, 20, 1e-6, -30.0, 30.0), **overrides) -> GPTConfig:
+    """Xing4.0 family (``model_type`` xing4_0; defaults: Xing4.0-29B-A4B):
+    :func:`deepseek_v32_config`'s layer WITHOUT the indexer and without router
+    groups (latent attention over every cached key, the first ``dense_layers``
+    layers a dense SwiGLU, every later one a bank behind the sigmoid router
+    beside a shared expert) on ``hyper`` residual streams mixed by
+    manifold-constrained hyper-connections round both sublayers
+    (:class:`HyperSpec`: streams, Sinkhorn iterations, eps, the clamp).  No
+    multi-token prediction module.  Served through ``init_serving()``; the
+    dense paths refuse it."""
+    kw = dict(indexer=None, n_group=1, topk_group=1, hyper=tuple(hyper))
+    kw.update(overrides)
+    return deepseek_v32_config(
+        vocab_size=vocab_size, n_positions=n_positions, n_embd=n_embd,
+        n_layer=n_layer, n_head=n_head, q_lora_rank=q_lora_rank,
+        intermediate_size=intermediate_size,
+        moe_intermediate_size=moe_intermediate_size, num_experts=num_experts,
+        top_k=top_k, dense_layers=dense_layers, route_scale=route_scale,
+        rope_yarn=rope_yarn, **kw)
 
 
 _SALA_MIXERS = {"minicpm4": "sparse", "lightning-attn": "linear"}
@@ -1032,6 +1093,9 @@ def _init_attn(cfg: GPTConfig, rng: Array) -> Dict:
     if cfg.norm_sandwich:
         out["post_attn_g"] = jnp.full((E,), cfg.post_attn_gain, jnp.float32)
         out["post_mlp_g"] = jnp.ones((E,), jnp.float32)
+    if cfg.hyper is not None:
+        for i, sub in enumerate(("attn", "mlp")):
+            out.update(_init_hyper(cfg, jax.random.fold_in(rng, 9753 + i), sub))
     if cfg.kv_lora_rank:
         # latent attention: the fused qkv gives way to the two low-rank query
         # projections and the joint K/V down- and up-projection
@@ -1057,6 +1121,30 @@ def _init_attn(cfg: GPTConfig, rng: Array) -> Dict:
                 ik_norm_g=jnp.ones((ix.head_dim,), jnp.float32),
                 ik_norm_b=jnp.zeros((ix.head_dim,), jnp.float32))
     return out
+
+
+# what the diagonal of a sublayer's ``Hres`` logits is seeded at, over 0
+# elsewhere: near the identity (0.71 on the diagonal after Sinkhorn-Knopp),
+# and far enough from it that the streams do mix at seeded weights
+HYPER_RES_DIAGONAL = 2.0
+
+
+def _init_hyper(cfg: GPTConfig, rng: Array, sub: str) -> Dict:
+    """The leaves of ONE sublayer's stream maps (:func:`hyper_maps`; ``sub``
+    "attn" or "mlp"): ``phi [n E, 2n + n n]`` (columns ``[pre | post | res``
+    row by row ``]``) like any matrix, ``alpha [3]`` (pre, post, res) at ``1 /
+    (0.02 sqrt(n E))``, at which the token's own part of every logit has a
+    deviation of 1, and ``b`` at an even read (``sigmoid = 1 / n``), a unit
+    write (``2 sigmoid = 1``) and :data:`HYPER_RES_DIAGONAL`.  Weights like any
+    other, trained away from these."""
+    n, E = cfg.hyper.streams, cfg.n_embd
+    b = jnp.concatenate([
+        jnp.full((n,), -math.log(n - 1.0)), jnp.zeros((n,)),
+        (HYPER_RES_DIAGONAL * jnp.eye(n)).reshape(-1)]).astype(jnp.float32)
+    return {f"hc_{sub}_phi": _dense_init(rng, n * E, (n * E, 2 * n + n * n)),
+            f"hc_{sub}_b": b,
+            f"hc_{sub}_alpha": jnp.full((3,), 1.0 / (0.02 * math.sqrt(n * E)),
+                                        jnp.float32)}
 
 
 def _init_mlp(cfg: GPTConfig, rng: Array, biases: bool = True) -> Dict:
@@ -1210,6 +1298,11 @@ def gpt_partition_specs(cfg: GPTConfig) -> Dict:
                 keys.update(index_q_w=PartitionSpec(None, None),
                             index_kw_w=PartitionSpec(None, None),
                             ik_norm_g=PartitionSpec(), ik_norm_b=PartitionSpec())
+        if cfg.hyper is not None:
+            for sub in ("attn", "mlp"):     # a token's maps read all its lanes
+                keys.update({f"hc_{sub}_phi": PartitionSpec(None, None),
+                             f"hc_{sub}_b": PartitionSpec(),
+                             f"hc_{sub}_alpha": PartitionSpec()})
         specs = {k: PartitionSpec(*pre, *s) for k, s in keys.items()}
         if cfg.moe_dense_layers:
             # the dense lead's MLP, a stack of its own
@@ -1622,19 +1715,99 @@ def _ffn(cfg: "GPTConfig", p: Dict, h: Array, dt, rng=None,
     return y.reshape(*lead, E).astype(dt), l_aux.astype(jnp.float32), counts
 
 
+def sinkhorn_knopp(m: Array, iters: int, eps: float) -> Array:
+    """``iters`` rounds over the positive ``m [n, n, ...]`` (row ``i``,
+    column ``j``, then whatever the matrices are batched over): every column
+    over its sum, then every row over its sum (each sum ``+ eps``).  Rows sum
+    to 1 and columns to 1 within the iteration's error.  Unrolled, and XLA
+    still makes kernels of every round: a divide with several users is never
+    duplicated into them, so a projection is some ninety small fusions
+    (compiled ahead of time for the v5e; ROADMAP M16 (a) is the kernel that
+    keeps a tile of tokens' matrices in VMEM for all of it)."""
+    for _ in range(iters):
+        m = m / (m.sum(axis=0, keepdims=True) + eps)
+        m = m / (m.sum(axis=1, keepdims=True) + eps)
+    return m
+
+
+def hyper_maps(cfg: "GPTConfig", p: Dict, sub: str, x: Array, compute=jnp.float32):
+    """The maps of ONE sublayer (``sub``: "attn" or "mlp") over a token's
+    streams ``x [..., n, E]`` (:class:`HyperSpec`): -> (``Hpre [..., n]``,
+    ``Hpost [..., n]``, ``Hres [..., n, n]``) in ``compute``.
+
+        xb    = RMSNorm(vec(x))                         n E lanes, no gain
+        H~    = alpha * (xb @ phi) + b                  [pre n | post n | res n n]
+        Hpre  = sigmoid(Hpre~);  Hpost = 2 sigmoid(Hpost~)
+        Hres  = Sinkhorn-Knopp(exp(clip(Hres~)))        doubly stochastic
+
+    The model computes them in float32 whatever the streams' type (the
+    product with ``phi`` too: a float32 dot is a bf16 one on the chip unless
+    it says ``HIGHEST``); ``compute`` is here for the benchmark's control that
+    plants a lower type and must be refused.  The logits are made TOKENS
+    LAST, ``[2n + n n, tokens]``: a token's 4 x 4 matrix in the two minor
+    dimensions would take a vector register of 1,024 numbers to itself in
+    each of the projection's hundred passes.  Scope ``hc_coeff``."""
+    hs = cfg.hyper
+    n, lead = hs.streams, x.shape[:-2]
+    with jax.named_scope("hc_coeff"):
+        xf = x.astype(compute).reshape(-1, n * x.shape[-1])
+        xb = xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + hs.eps)
+        raw = jnp.einsum("tk,kc->ct", xb, p[f"hc_{sub}_phi"].astype(compute),
+                         precision=jax.lax.Precision.HIGHEST)
+        alpha, b = p[f"hc_{sub}_alpha"].astype(compute), p[f"hc_{sub}_b"].astype(compute)
+        pre, post, res = (alpha[i] * raw[lo:hi] + b[lo:hi, None] for i, (lo, hi) in
+                          enumerate(((0, n), (n, 2 * n), (2 * n, 2 * n + n * n))))
+        hres = sinkhorn_knopp(
+            jnp.exp(jnp.clip(res, hs.clamp_min, hs.clamp_max)).reshape(n, n, -1),
+            hs.sinkhorn_iters, hs.eps)
+        # tokens first again: the walk slices and pads what it carries by row
+        return tuple(jnp.moveaxis(t, -1, 0).reshape(*lead, *t.shape[:-1])
+                     for t in (jax.nn.sigmoid(pre), 2.0 * jax.nn.sigmoid(post), hres))
+
+
+def hyper_read(cfg: "GPTConfig", p: Dict, sub: str, x: Array, dt):
+    """What ONE sublayer reads of a token's streams ``x [..., n, E]``, and
+    the maps it will write them back through (:func:`hyper_maps`): ->
+    (``u = Hpre @ x`` ``[..., E]`` in ``dt``, (``Hres``, ``Hpost``)); a
+    float32 product, the streams stay in their own type.  Scope ``hc_pre``."""
+    hpre, hpost, hres = hyper_maps(cfg, p, sub, x)
+    with jax.named_scope("hc_pre"):
+        xf = x.astype(jnp.float32)
+        u = sum(hpre[..., j, None] * xf[..., j, :] for j in range(x.shape[-2])).astype(dt)
+    return u, (hres, hpost)
+
+
+def hyper_write(x: Array, maps, f: Array) -> Array:
+    """``Hres @ x + outer(Hpost, f)``: the streams ``x [..., n, E]`` after a
+    sublayer whose output is ``f [..., E]``, through the ``maps`` its
+    :func:`hyper_read` gave; float32 products (``n`` explicit terms: one
+    fusion reads the streams once and writes them once), the streams' own
+    type out.  Scope ``hc_post``."""
+    hres, hpost = maps
+    with jax.named_scope("hc_post"):
+        xf, ff = x.astype(jnp.float32), f.astype(jnp.float32)
+        mixed = sum(hres[..., j, None] * xf[..., None, j, :] for j in range(x.shape[-2]))
+        return (mixed + hpost[..., None] * ff[..., None, :]).astype(x.dtype)
+
+
 def _block_tail(cfg: "GPTConfig", p: Dict, x: Array, h: Array, o: Array, dt,
                 live: Optional[Array] = None,
-                bank_at: Optional[Tuple[Dict, Array]] = None
-                ) -> Tuple[Array, Optional[Array]]:
+                bank_at: Optional[Tuple[Dict, Array]] = None,
+                maps=None) -> Tuple[Array, Optional[Array]]:
     """The block after attention, by ``block_type``, on the inference
     paths: ``x`` the block's input, ``h`` its normed form (what attention
     read), ``o`` attention's output; ``bank_at`` is ``_ffn``'s.  Returns the
-    block's output and ``_ffn``'s expert counts."""
+    block's output and ``_ffn``'s expert counts.  Under ``cfg.hyper`` ``x``
+    is the token's streams and ``maps`` what attention's :func:`hyper_read`
+    gave: each sublayer's output is written through its own maps where a
+    single stream adds it."""
+    add = lambda x, maps, f: x + f if maps is None else hyper_write(x, maps, f)
     if cfg.block_type == "sequential":
         if cfg.norm_sandwich:
             o = rms_norm(o, p["post_attn_g"], eps=cfg.ln_eps)
-        x = x + o
-        z = _norm(cfg, x, p["ln2_g"], p["ln2_b"])
+        x = add(x, maps, o)
+        u, maps = (x, None) if cfg.hyper is None else hyper_read(cfg, p, "mlp", x, dt)
+        z = _norm(cfg, u, p["ln2_g"], p["ln2_b"])
     else:
         z = h if cfg.block_type == "parallel_single_ln" else _norm(
             cfg, x, p["ln2_g"], p["ln2_b"])
@@ -1642,7 +1815,7 @@ def _block_tail(cfg: "GPTConfig", p: Dict, x: Array, h: Array, o: Array, dt,
     f, _, counts = _ffn(cfg, p, z, dt, live=live, attn_in=h, bank_at=bank_at)
     if cfg.norm_sandwich:
         f = rms_norm(f, p["post_mlp_g"], eps=cfg.ln_eps)
-    return x + f, counts
+    return add(x, maps, f), counts
 
 
 def layer_norm(x: Array, g: Array, b: Array, eps: float = 1e-5) -> Array:
@@ -1811,8 +1984,9 @@ def layer_walk(cfg: "GPTConfig") -> str:
 
 
 def _refuse_hybrid(cfg: "GPTConfig", path: str) -> None:
-    """The dense paths walk ONE stack of softmax layers: a stack with layers
-    of another mixer is refused by the mechanisms it would need."""
+    """The dense paths walk ONE stack of softmax layers over ONE residual
+    stream: a stack with layers of another mixer, an indexer or residual
+    streams is refused by the mechanisms it would need."""
     if cfg.hybrid:
         from deepspeed_tpu.models import hybrid
         raise NotImplementedError(
@@ -1824,6 +1998,13 @@ def _refuse_hybrid(cfg: "GPTConfig", path: str) -> None:
             f"selection of the rows of the latent cache a query attends for "
             f"the {cfg.n_layer} layers; serve this stack through "
             f"init_serving() (gpt_paged_step)")
+    if cfg.hyper is not None:
+        raise NotImplementedError(
+            f"{path} carries ONE residual stream a token: it has no "
+            f"{cfg.hyper.streams} streams, no maps of a token's own round a "
+            f"sublayer (hyper_read, hyper_write) and no Sinkhorn-Knopp "
+            f"projection for the {cfg.n_layer} layers; serve this stack "
+            f"through init_serving() (gpt_paged_step)")
 
 
 def _window_bias(S: int, window: int) -> Array:
@@ -2431,6 +2612,15 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
     ``topk`` positions or fewer select nothing: the layer is
     ``paged_mla_attention`` over every key.
 
+    Under ``cfg.hyper`` (residual streams: the xing4_0 line) what the walk
+    carries from layer to layer is a token's STREAMS ``[B, S, n, E]``, each
+    the embedding at the start and summed before the final norm: each of a
+    layer's two sublayers reads them through :func:`hyper_read` (in the
+    region before the scatter for attention, in the block's tail for the
+    feed-forward) and its output is written through :func:`hyper_write`
+    where a single stream adds it; attention's maps ride from the one region
+    to the other beside its output, by row like everything else there.
+
     The walk (:func:`_walk_layers`) hands a layer the STACK and the layer's
     index, and every leaf is sliced where it is read (:class:`_LayerLeaves`).
     Under the dropless router the expert bank (``params["blocks"]["moe"]
@@ -2491,7 +2681,10 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
     if cfg.position_encoding == "learned":
         x = x + params["wpe"].astype(dt)[
             jnp.clip(pos2d, 0, cfg.n_positions - 1)]
-    x = _constrain(x, mesh_lib.BATCH_AXES, None, None)
+    if cfg.hyper is not None:
+        # the carry is a token's STREAMS [B, S, n, E], each the embedding
+        x = jnp.repeat(x[:, :, None], cfg.hyper.streams, axis=2)
+    x = _constrain(x, mesh_lib.BATCH_AXES, *(None,) * (x.ndim - 1))
 
     if cfg.position_encoding == "alibi":
         from deepspeed_tpu.ops.attention import alibi_slopes
@@ -2535,14 +2728,17 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
     # of an engine of eight layers (PERF.md § 6, PR 56)
     @partial(jax.jit, static_argnums=0)
     def project(j, blocks, l, x, pos):
-        """-> the normed input, the queries as attention takes them and what
-        the arena caches of each token (K and V, or the latent)."""
+        """-> the normed input, the queries as attention takes them, what
+        the arena caches of each token (K and V, or the latent) and the maps
+        attention's output is written through (:func:`hyper_read`; () where a
+        token carries one stream)."""
         p = _LayerLeaves(blocks, l)
-        h = _norm(cfg, x, p["ln1_g"], p["ln1_b"])
+        u, maps = (x, ()) if cfg.hyper is None else hyper_read(cfg, p, "attn", x, dt)
+        h = _norm(cfg, u, p["ln1_g"], p["ln1_b"])
         if not cfg.kv_lora_rank:
             q, k, v = _project_qkv(cfg, p, h, dt, pos, cfg.pattern[j])
             return (h, q, k.astype(pages_dt).reshape(*k.shape[:2], -1),
-                    v.astype(pages_dt).reshape(*v.shape[:2], -1))
+                    v.astype(pages_dt).reshape(*v.shape[:2], -1), maps)
         cq = _query_latent(cfg, p, h, dt)
         with scope("latent_project"):
             plain, cache = _latent_project(cfg, p, h, dt, pos, cq)
@@ -2551,7 +2747,7 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
             q = jnp.concatenate(
                 [jnp.einsum("bshd,rhd->bshr", plain[..., :-dr],
                             _latent_up(cfg, p, dt)[0]), plain[..., -dr:]], axis=-1)
-        out = h, lanes(q, W), lanes(cache.astype(pages_dt), W), None
+        out = h, lanes(q, W), lanes(cache.astype(pages_dt), W), None, maps
         if not indexed:
             return out
         with jax.named_scope("index_score"):
@@ -2559,9 +2755,10 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
         return (*out, plain, qi[:, 0], ki[:, 0], w[:, 0])
 
     @partial(jax.jit, static_argnums=0)
-    def tail(dense, stacks, l, x, h, o, live):
+    def tail(dense, stacks, l, x, h, o, live, *maps):
         """-> the block's output and its expert counts, from attention's
-        output; ``dense``: a layer of the dense lead."""
+        output; ``dense``: a layer of the dense lead; ``maps``: what
+        ``project`` gave of them."""
         blocks, by_kind, bank = stacks
         p = _LayerLeaves(blocks, l)
         if dense:
@@ -2576,7 +2773,8 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
         with jax.named_scope("mlp"):
             return _block_tail(
                 cfg, p, x, h, o, dt, live,
-                bank_at=None if bank is None or dense else (bank, l - lead))
+                bank_at=None if bank is None or dense else (bank, l - lead),
+                maps=maps or None)
 
     def attend_chosen(q, plain, up, kp, index, ki, li, j):
         """The rows' attention over the rows of the latent cache their
@@ -2624,7 +2822,7 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
         dense = isinstance(l, int) and l < lead
         at = jnp.asarray(l, jnp.int32)
         with jax.named_scope("attn"), mixer_scope():
-            h, q, k, v, *index = by_row(partial(project, j, blocks, at), (x, pos2d))
+            h, q, k, v, maps, *index = by_row(partial(project, j, blocks, at), (x, pos2d))
             # scatter the new K/V into the arena through the write map; rows
             # that must not write (padding, inactive slots) carry trash-block
             # coordinates, so the scatter itself needs no predication
@@ -2647,7 +2845,7 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
                         q, (kp, vp), li, block_tables[j], positions,
                         tile_runs=tile_runs[j], chunk=chunk, bias=attn_bias)
         x, counts = by_row(partial(tail, dense, (blocks, by_kind, bank), at),
-                           (x, h, o, live), totals=1)
+                           (x, h, o, live, *maps), totals=1)
         if dense:
             counts = jnp.zeros((cfg.moe_num_experts,), jnp.int32)
         return (x, kp, vp, li + int(j == n_kinds - 1), ki), counts
@@ -2666,6 +2864,8 @@ def gpt_paged_step(cfg: GPTConfig, params: Dict, input_ids: Array,
         counts = jnp.concatenate([jnp.stack(walked), counts])
 
     def to_logits(x):
+        if cfg.hyper is not None:       # the streams leave as their sum
+            x = x.astype(jnp.float32).sum(axis=-2).astype(dt)
         x = _norm(cfg, x, params["lnf_g"], params["lnf_b"])
         head = params["lm_head"] if cfg.untied_head else params["wte"]
         logits = (x @ head.astype(dt).T).astype(jnp.float32)
@@ -2723,6 +2923,7 @@ class GPTBlockLayer:
         assert self.cfg.moe_num_experts == 0, (
             "MoE blocks in the pipeline engine are not supported yet — "
             "use the scan (non-pipeline) model for MoE training")
+        _refuse_hybrid(self.cfg, "the pipeline engine's block")
         x, _ = gpt_block(self.cfg, p, x, rng=rng, train=train,
                          attention_fn=get_attention_fn(self.cfg.attn_impl))
         return x
@@ -2899,8 +3100,12 @@ class GPT:
                         + E * (ix.head_dim + ix.heads) + 2 * ix.head_dim)
         else:
             qkv = E * cfg.qkv_dim + b * cfg.qkv_dim      # qkv (GQA-sized)
+        hyper = 0
+        if cfg.hyper is not None:   # phi, b and alpha, a sublayer
+            n = cfg.hyper.streams
+            hyper = 2 * ((n * E + 1) * (2 * n + n * n) + 3)
         per_block = (qkv + gate + cfg.n_head * cfg.v_head_dim * E + b * E  # attn out
-                     + mlp + qk_norm + (4 if cfg.norm_sandwich else 2) * norm)
+                     + mlp + qk_norm + (4 if cfg.norm_sandwich else 2) * norm + hyper)
         total = cfg.padded_vocab * E + L * per_block + lead + norm
         if cfg.position_encoding == "learned":
             total += cfg.n_positions * E

@@ -1188,3 +1188,69 @@ def test_the_qwen3_next_step_compiles_at_the_published_widths(chip):
     assert text.count("conditional(") >= 2
     assert not re.search(rf"f32\[{slots},128,4096\]", text)      # no layer's states copied
     assert compiled.memory_analysis().temp_size_in_bytes < 3 << 30
+
+
+def test_paged_mla_kernel_compiles_at_xing4_heads(chip):
+    """Xing4.0's latent attention as its serve cell runs it in nineteen steps
+    of twenty: a prompt chunk of 512 queries of 32 heads over up to 16,896
+    keys of 512 latent + 64 rope lanes (640 in the arena, pages of 64, tables
+    264 blocks wide) beside 16 decode rows, the one-array arena of 4,225
+    blocks WHOLE with the layer a scalar (Mistral's cell gives the kernel 384
+    queries over 12,288 keys of 384 lanes in a step of fourteen)."""
+    H, W, R, BS, slots, chunk, MB = 32, 640, 512, 64, 16, 512, 264
+    rows = slots + chunk
+    assert da.mla_kernel_shape_ok(W, R, BS, BF16)
+    fn = lambda q, arena, layer, tables, lengths: da.paged_mla_attention(
+        q, arena, layer, tables, lengths, scale=192 ** -0.5, value_lanes=R, chunk=chunk)
+    text = _compiled_text(chip, fn, ((rows, 1, H, W), BF16),
+                          ((7, 4225, BS, W), BF16), ((), jnp.int32),
+                          ((rows, MB), jnp.int32), ((rows,), jnp.int32))
+    plan = da.latent_plan(W, R, H, BS, MB, chunk, BF16, 192 ** -0.5)
+    assert plan.chunk_queries > 1 and chunk % plan.chunk_queries == 0
+    assert _kernel_rows(text, "paged_mla_attention") == sorted(
+        [chunk // plan.chunk_queries, slots])
+    assert "dynamic-slice" not in text        # no layer of the arena sliced out
+
+
+@pytest.mark.parametrize("stacked", [False, True], ids=["a_layer", "stacked"])
+@pytest.mark.parametrize("K,N", [(3584, 2048), (1024, 3584)])
+def test_grouped_matmul_compiles_at_xing4_bank(chip, K, N, stacked):
+    """Xing4.0's WHOLE bank (gate|up ``[64, 3584, 2048]``, down ``[64, 1024,
+    3584]``) at the serve cell's 528 rows x top 4 = 2,112 assignments, 33 an
+    expert, which the caller pads to seventeen whole row tiles."""
+    from deepspeed_tpu.ops.pallas import grouped_matmul as gm
+    assert gm.rows_to_whole_tiles(2112, K, BF16) == 64
+    _bank_matmul_compiles(chip, 2176, 64, K, N, stacked)
+
+
+def test_the_xing4_step_mixes_four_streams_round_every_sublayer(chip):
+    """The whole step of Xing4.0's cell at the published widths, the dense
+    layer and TWO expert layers with all 64 experts and the 131,072-row head,
+    16 slots and a chunk of 512 under tables of 264 pages: the carry is a
+    token's four streams (``bf16[528,1,4,3584]``), every sublayer reads and
+    writes them under the scopes ``hc_coeff`` / ``hc_pre`` / ``hc_post`` in
+    both bodies of the walk (all rows; the decode rows alone), the maps'
+    product with ``phi`` is a float32 dot over the 14,336 lanes, every layer
+    attends through ``paged_mla_attention`` at the decode rows and the packed
+    chunk, the bank's grouped matmuls read the stacked leaves, and no layer
+    of the latent cache is sliced out of its arena."""
+    from deepspeed_tpu.models import gpt
+    slots, chunk, BS, blocks, MB = 16, 512, 64, 1025, 264
+    cfg = gpt.xing4_config(n_layer=3, dense_layers=1, dtype=BF16)
+    compiled, kp, _ = _step_program(chip, cfg, slots, chunk, BS, blocks, MB,
+                                    counts=True, donate=True)
+    assert kp.shape == (3, blocks, BS, 640)
+    text = compiled.as_text()
+    assert f"bf16[{slots + chunk},1,4,3584]" in text and f"bf16[{slots},1,4,3584]" in text
+    for scope in ("hc_coeff", "hc_pre", "hc_post"):
+        assert scope in text, scope
+    assert re.search(r"f32\[24,(528|16)\][^\n]* (convolution|dot|fusion)\(", text)
+    plan = da.latent_plan(640, 512, 32, BS, MB, chunk, BF16, 192 ** -0.5)
+    assert _kernel_rows(text, "paged_mla_attention") == sorted(
+        [slots, chunk // plan.chunk_queries] * 2)
+    calls = _bank_calls(text)
+    assert len(calls) == 2 * 2 and all("bf16[2,64," in c for c in calls)
+    assert not _bank_copies(text, 64, 3584, 2048) and not _bank_copies(text, 64, 1024, 3584)
+    assert not re.search(r"bf16\[1025,64,640\]\S* (dynamic-slice|copy)\(", text)
+    assert text.count("conditional(") >= 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30

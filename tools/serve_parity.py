@@ -14,7 +14,10 @@ layer and one expert layer, with ``--long 30000`` for a context that selects;
 ``qwen3-next-80b-a3b.json``: one period as served; its float32 run holds 64
 of the 512 experts, ``"experts_held": [0, 64]`` in ``model.kwargs`` and
 ``reference.kwargs``, so that the tree fits in float32, under
-``JAX_DEFAULT_MATMUL_PRECISION=highest``).
+``JAX_DEFAULT_MATMUL_PRECISION=highest``; ``xing4.0-29b-a4b.json``: one
+pipeline stage whole as served; its float32 run is ``"n_layer": 2``, the
+dense layer and one expert layer on four residual streams, with ``--long
+16384`` for the cell's longest prompt).
 
 Run standalone on a TPU host (``chiprun --chips 1 -- python
 tools/serve_parity.py benchmarks/configs/smallthinker-21b-a3b.json``); any
