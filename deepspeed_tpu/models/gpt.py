@@ -1513,8 +1513,8 @@ def _index_project(cfg: "GPTConfig", p: Dict, cq: Array, h: Array, dt,
     ix, B, S = cfg.indexer, *h.shape[:2]
     rope = lambda t: apply_rope(t, positions, cfg.rope_theta,
                                 rope_dim=cfg.qk_rope_dim, yarn=cfg.rope_yarn)
-    qi = rope((cq @ _wget(p, "index_q_w", dt)).reshape(B, S, ix.heads, ix.head_dim))
-    kw = h @ _wget(p, "index_kw_w", dt)
+    qi = rope(_project(p, "index_q_w", cq, dt).reshape(B, S, ix.heads, ix.head_dim))
+    kw = _project(p, "index_kw_w", h, dt)
     ki = layer_norm(kw[..., :ix.head_dim], p["ik_norm_g"], p["ik_norm_b"],
                     eps=cfg.ln_eps)
     return qi, rope(ki[:, :, None])[:, :, 0], kw[..., ix.head_dim:].astype(jnp.float32)
@@ -1537,7 +1537,7 @@ def _latent_project(cfg: "GPTConfig", p: Dict, h: Array, dt, positions: Array,
                                 interleaved=cfg.rope_interleaved, yarn=yarn)
     if cq is None:
         cq = _query_latent(cfg, p, h, dt)
-    q = (cq @ _wget(p, "q_b_w", dt)).reshape(B, S, H, cfg.head_dim)
+    q = _project(p, "q_b_w", cq, dt).reshape(B, S, H, cfg.head_dim)
     q = jnp.concatenate([q[..., :-dr], rope(q[..., -dr:])], axis=-1)
     if yarn is not None:
         pos = positions if positions.ndim == 2 else positions[None]
@@ -1545,7 +1545,7 @@ def _latent_project(cfg: "GPTConfig", p: Dict, h: Array, dt, positions: Array,
             1.0 + yarn.query_beta * jnp.log1p(
                 (pos // yarn.original_positions).astype(jnp.float32)))
         q = (q.astype(jnp.float32) * by[:, :, None, None]).astype(dt)
-    kv = h @ _wget(p, "kv_a_w", dt)
+    kv = _project(p, "kv_a_w", h, dt)
     c = rms_norm(kv[..., :R], p["kv_a_norm_g"], eps=cfg.ln_eps)
     k_rope = rope(kv[..., None, R:])[:, :, 0]
     return q, jnp.concatenate([c, k_rope], axis=-1)
@@ -1554,7 +1554,7 @@ def _latent_project(cfg: "GPTConfig", p: Dict, h: Array, dt, positions: Array,
 def _latent_up(cfg: "GPTConfig", p: Dict, dt):
     """The K/V up-projection by head: ``W_UK [R, H, head_dim - qk_rope_dim]``
     and ``W_UV [R, H, v_head_dim]``."""
-    w = _wget(p, "kv_b_w", dt).reshape(cfg.kv_lora_rank, cfg.n_head, -1)
+    w = _proj_weight(p, "kv_b_w", dt).reshape(cfg.kv_lora_rank, cfg.n_head, -1)
     return w[..., :cfg.head_dim - cfg.qk_rope_dim], w[..., -cfg.v_head_dim:]
 
 
@@ -1585,6 +1585,72 @@ def _wleaf(w, dt) -> Array:
 
 def _wget(p: Dict, key: str, dt) -> Array:
     return _wleaf(p[key], dt)
+
+
+# The projections of a latent layer as a serving engine keeps them
+# (:func:`serving_params`): the canonical leaf ``[L, K, N]`` -> the name of
+# the same matrices TRANSPOSED, ``[L, N, K]``
+SERVING_LEAVES = {"q_b_w": "q_b_t", "kv_b_w": "kv_b_t", "index_q_w": "index_q_t",
+                  "kv_a_w": "kv_a_t", "index_kw_w": "index_kw_t"}
+
+
+def _proj_weight(p: Dict, key: str, dt) -> Array:
+    """A latent layer's projection ``key`` as ``[K, N]``, read where the
+    layer's tree holds it: the serving tree's transposed leaf through ``.T``
+    (:data:`SERVING_LEAVES`), or the canonical leaf.  One contraction over two
+    layouts of one operand.  XLA's dot reads its right operand with the
+    contraction dimension minor-most, and where the leaf is a layer of a
+    stack read at a traced index it relays the WHOLE stack to get it so, in
+    every region that reads it (DeepSeek-V3.2's five layers: 671 MB twice a
+    step, PERF.md § 6, PR 67); the serving tree's leaf lies so."""
+    if SERVING_LEAVES[key] in p:
+        return _wleaf(p[SERVING_LEAVES[key]], dt).T
+    return _wget(p, key, dt)
+
+
+def _project(p: Dict, key: str, x: Array, dt) -> Array:
+    """``x @ W`` for a latent layer's projection ``key``
+    (:func:`_proj_weight`).  Over the serving tree's leaf the product is held
+    whole (a barrier: the identity): its lanes go on to be split (a head's
+    rotated lanes from the others, a rope's pairs, the latent from the
+    rotated key), which XLA would make a split of the weight's columns,
+    copying the layer out of the stack to cut them (what the compiled step
+    holds: ``tests/unit/ops/test_chip_compile.py``)."""
+    y = x @ _proj_weight(p, key, dt)
+    return jax.lax.optimization_barrier(y) if SERVING_LEAVES[key] in p else y
+
+
+def serving_params(params: Dict) -> Tuple[Dict, Dict[str, int]]:
+    """The tree a serving engine keeps of ``params`` -> (the tree, the bytes
+    of each canonical leaf it relaid).  A stack's latent projections
+    (:data:`SERVING_LEAVES`) give way to their transposes over the last two
+    dimensions, made by ONE jitted program; every other leaf is the caller's
+    own array, and a tree that holds none of them (no latent; layers not
+    stacked) comes back as it is.  An int8-injected leaf stays as it is (its
+    scales run along the output channels).  Under a mesh a relaid leaf keeps
+    its leaf's ``PartitionSpec``, transposed with it.  The caller's tree is
+    not touched: checkpoints, the dense paths and training read the
+    canonical layout."""
+    blocks = params.get("blocks", {})
+    names = [k for k in SERVING_LEAVES if isinstance(blocks.get(k), jax.Array)]
+    if not names:
+        return params, {}
+
+    def relaid_sharding(a):
+        sharding = getattr(a, "sharding", None)
+        if not isinstance(sharding, NamedSharding):
+            return None
+        spec = (*sharding.spec, *(None,) * a.ndim)[:a.ndim]
+        return NamedSharding(sharding.mesh,
+                             PartitionSpec(*spec[:-2], spec[-1], spec[-2]))
+
+    relaid = jax.jit(
+        lambda ws: {SERVING_LEAVES[k]: jnp.swapaxes(w, -1, -2) for k, w in ws.items()},
+        out_shardings={SERVING_LEAVES[k]: relaid_sharding(blocks[k]) for k in names})(
+            {k: blocks[k] for k in names})
+    kept = {k: v for k, v in blocks.items() if k not in names}
+    return (dict(params, blocks={**kept, **relaid}),
+            {k: blocks[k].size * blocks[k].dtype.itemsize for k in names})
 
 
 def out_gate(p: Dict, o: Array, h: Array, dt) -> Array:
@@ -1904,6 +1970,9 @@ class _LayerLeaves(Mapping):
 
     def __iter__(self):
         return iter(dict.fromkeys(k for stack, _ in self.parts for k in stack))
+
+    def __contains__(self, name) -> bool:      # slices nothing
+        return any(name in stack for stack, _ in self.parts)
 
     def __len__(self) -> int:
         return len(tuple(self))
@@ -3045,6 +3114,11 @@ class GPT:
         return gpt_generate(self.cfg, params, input_ids, max_new_tokens,
                             rng=rng, temperature=temperature,
                             prompt_len=prompt_len)
+
+    def serving_params(self, params):
+        """Serving-engine protocol: the tree its step reads, made once as the
+        engine takes ``params`` (:func:`serving_params`)."""
+        return serving_params(params)
 
     def paged_step(self, params, input_ids, positions, k_pages, v_pages,
                    block_tables, write_blocks, write_offsets, **kw):

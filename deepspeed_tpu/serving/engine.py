@@ -455,6 +455,8 @@ class ServingEngine:
             self._h_step = r.histogram("serve_step_ms")
             self._h_turnaround = r.histogram("serve_turnaround_ms")
             self._h_program = r.histogram("serve_program_ms")
+            self._g_relaid_leaves = r.gauge("serve_relaid_leaves")
+            self._g_relaid_bytes = r.gauge("serve_relaid_bytes")
         self.dtype = cfg.jnp_dtype
         assert hasattr(model, "paged_step") and hasattr(model, "cfg"), (
             "ServingEngine needs a model with .cfg and .paged_step(...) "
@@ -487,10 +489,7 @@ class ServingEngine:
                 "pass params= or a model with init_params(rng)")
             params = model.init_params(
                 jax.random.PRNGKey(cfg.seed if seed is None else seed))
-        self.params = jax.tree.map(
-            lambda p: jnp.asarray(p, self.dtype)
-            if jnp.issubdtype(jnp.asarray(p).dtype, jnp.floating) else p,
-            params)
+        self.params = params
 
         # ---- paged arena + control plane --------------------------------- #
         self.max_blocks_per_seq = (cfg.max_blocks_per_seq
@@ -694,8 +693,33 @@ class ServingEngine:
             f"ServingEngine ready: slots={cfg.max_batch_size}, "
             f"arena={cfg.num_blocks}x{cfg.block_size} tok "
             f"(max {self.max_blocks_per_seq} blocks/seq), "
-            f"prefill_chunk={cfg.prefill_chunk}, dtype={self.dtype.__name__}",
+            f"prefill_chunk={cfg.prefill_chunk}, dtype={self.dtype.__name__}, "
+            f"relaid_leaves={self.relaid_leaves}, relaid_bytes={self.relaid_bytes}",
             ranks=[0])
+
+    # ------------------------------------------------------------------ #
+    @property
+    def params(self):
+        """The tree the step reads: the model's SERVING tree of the weights
+        the engine was given (``model.serving_params``: a latent stack's
+        up-projections transposed, every other leaf the caller's own), in the
+        engine's dtype.  Assigning a tree in checkpoint layout is the one way
+        weights enter; ``relaid_leaves`` and ``relaid_bytes`` say what the
+        engine holds in a layout of its own (0 and 0: the caller's arrays)."""
+        return self._params
+
+    @params.setter
+    def params(self, params) -> None:
+        import jax
+        import jax.numpy as jnp
+        self._params, relaid = self.module.serving_params(jax.tree.map(
+            lambda p: jnp.asarray(p, self.dtype)
+            if jnp.issubdtype(jnp.asarray(p).dtype, jnp.floating) else p,
+            params))
+        self.relaid_leaves, self.relaid_bytes = len(relaid), sum(relaid.values())
+        if self.registry is not None:
+            self._g_relaid_leaves.set(self.relaid_leaves)
+            self._g_relaid_bytes.set(self.relaid_bytes)
 
     # ------------------------------------------------------------------ #
     def _new_allocator(self) -> PagedKVAllocator:

@@ -65,8 +65,9 @@ def on_the_chip(monkeypatch):
 
 
 def _compile(fn, *args, donate=()):
-    """THE ahead-of-time compile of this file: ``args`` are shapes on the
-    described chip."""
+    """The ahead-of-time compile of this file's kernels and functions:
+    ``args`` are shapes on the described chip (a whole serve step compiles in
+    ``_step_program``)."""
     return jax.jit(fn, donate_argnums=donate).lower(*args).compile()
 
 
@@ -77,43 +78,10 @@ def _compiled_text(chip, fn, *shapes):
 
 def _step_program(chip, cfg, slots, chunk, BS, blocks, MB, counts=False, donate=False):
     """The whole serve step of ``cfg`` compiled for the chip, as
-    ``init_serving`` builds it: ``slots`` decode rows and a chunk of ``chunk``
-    over an arena of ``blocks`` pages of ``BS`` tokens, bf16.  The periodic
-    walk takes a table a page group, ``MB`` blocks wide or a window group's
-    ring (a whole number of runs: ``cfg.paged_layout``); a hybrid stack takes
-    one table and what it caches beside K and V (``aux``), each row's slot and
-    whether it is live.  ``counts``: the step hands back its expert counts;
-    ``donate``: arena and ``aux`` are donated.  -> (compiled, arena's K, aux)."""
-    from deepspeed_tpu.models import gpt, hybrid
-    from deepspeed_tpu.serving.kv_cache import init_arena
-    model, rows = gpt.GPT(cfg), slots + chunk
-    shape = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=chip)
-    ints = lambda *s: shape(s, jnp.int32)
-    on_chip = lambda tree: jax.tree.map(lambda a: shape(a.shape, a.dtype), tree)
-    params = jax.tree.map(
-        lambda p: shape(p.shape, BF16 if jnp.issubdtype(p.dtype, jnp.floating)
-                        else p.dtype),
-        jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
-    kp, vp = on_chip(jax.eval_shape(lambda: init_arena(cfg, blocks, BS, dtype=BF16)))
-    kw = dict(chunk=chunk, **({"with_expert_counts": True} if counts else {}))
-    if cfg.hybrid:
-        aux = on_chip(jax.eval_shape(lambda: hybrid.init_aux(cfg, blocks, BS, slots, BF16)))
-        tables, coords = ints(rows, MB), ints(rows, 1)
-        more = (aux, ints(rows), shape((rows,), jnp.bool_))
-        step = lambda *a: model.paged_step(*a[:8], aux=a[8], slots=a[9], live=a[10], **kw)
-    else:
-        aux, more = None, ()
-        _, widths, _ = cfg.paged_layout(BS, MB, chunk, BF16)
-        tables = tuple(ints(rows, w) for w in widths)
-        coords = tuple(ints(rows, 1) for _ in widths)
-        step = lambda *a: model.paged_step(*a, **kw)
-        if cfg.indexed_layers:          # an indexer over a latent: its index keys
-            aux = on_chip(jax.eval_shape(lambda: hybrid.init_aux(cfg, blocks, BS, slots, BF16)))
-            more = (aux,)
-            step = lambda *a: model.paged_step(*a[:8], aux=a[8], **kw)
-    compiled = _compile(step, params, ints(rows, 1), ints(rows), kp, vp, tables, coords,
-                        ints(rows, 1), *more, donate=(3, 4, 8) if donate else ())
-    return compiled, kp, aux
+    ``init_serving`` builds it (``tools/stack_copies.py:step_program``, which
+    says what the arguments are) -> (compiled, arena's K, aux)."""
+    from tools.stack_copies import step_program
+    return step_program(chip, cfg, slots, chunk, BS, blocks, MB, counts, donate)[:3]
 
 
 def _flash_fwd(H, E):
@@ -1254,3 +1222,66 @@ def test_the_xing4_step_mixes_four_streams_round_every_sublayer(chip):
     assert not re.search(r"bf16\[1025,64,640\]\S* (dynamic-slice|copy)\(", text)
     assert text.count("conditional(") >= 4
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+# a latent stack's cell: its configuration at the dense lead (where it has
+# one) and TWO layers of the scan, its slots, chunk, block and table
+LATENT_STEPS = {
+    "deepseek-v3.2-exp": (lambda m: m.deepseek_v32_config(
+        n_layer=3, dense_layers=1, vocab_size=16160, experts_held=(0, 16), dtype=BF16),
+                          12, 512, 64, 720, 5),
+    "mistral-small-4-119b": (lambda m: m.mistral4_config(
+        n_layer=2, experts_held=(0, 32), dtype=BF16), 128, 384, 16, 1024, 3),
+    "xing4.0-29b-a4b": (lambda m: m.xing4_config(n_layer=3, dense_layers=1, dtype=BF16),
+                        16, 512, 64, 264, 3),
+}
+
+
+@pytest.mark.parametrize("cell", list(LATENT_STEPS))
+def test_a_latent_step_relays_no_stack_of_its_up_projections(chip, cell):
+    """The whole step of each latent stack AS THE ENGINE BUILDS IT (the
+    model's serving tree), compiled for the chip: no ``copy``, ``transpose``,
+    slice or fusion of the program makes an array that holds a whole stacked
+    projection of the latent layers (``q_b_w [L, Rq, H hd]``, ``kv_b_w [L, R,
+    H (dn + dv)]``, ``index_q_w [L, Rq, heads lanes]``, ``kv_a_w``,
+    ``index_kw_w``, canonical or transposed, in whatever layout), and none
+    makes one LAYER of any but ``kv_b_w``: every product reads its layer of
+    the stack where it lies.  (W_UK and W_UV are cut out of ``kv_b``'s layer
+    for einsums over heads and for the chunk's kernel, and the layer is
+    copied out for them, 34 MB of DeepSeek's: PERF.md § 7.)  On the
+    canonical tree the same program held nine relays of a whole stack and a
+    copy of a layer out of each of three (PERF.md § 6, PR 67)."""
+    from deepspeed_tpu.models import gpt
+    from tools import stack_copies as sc
+    make, slots, chunk, BS, MB, relaid = LATENT_STEPS[cell]
+    cfg = make(gpt)
+    model = gpt.GPT(cfg)
+    canonical = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    tree, bytes_relaid = jax.eval_shape(lambda p: model.serving_params(p), canonical)
+    assert len(bytes_relaid) == relaid
+    leaves = {name: ("bf16", stack[name].shape) for stack, names in (
+        (canonical["blocks"], bytes_relaid),
+        (tree["blocks"], [gpt.SERVING_LEAVES[name] for name in bytes_relaid]))
+        for name in names}
+    compiled, *_ = sc.step_program(chip, cfg, slots, chunk, BS, 1025, MB,
+                                   counts=True, donate=True)
+    moved = {(i["placed"], i["name"]) for i in sc.stack_copies(
+        compiled.as_text(), leaves, ops=("copy", "transpose", "fusion", "slice",
+                                         "dynamic-slice")) if i["placed"]}
+    assert {leaf for (leaf, _), _ in moved} <= {"kv_b_w", "kv_b_t"}, moved
+    assert {part for (_, part), _ in moved} <= {"layer"}, moved
+
+
+@pytest.mark.parametrize("make", ["olmoe_config", "trinity_config"])
+def test_the_serving_tree_of_a_stack_without_a_latent_is_the_callers(make):
+    """No latent projection, nothing relaid: the tree the engine keeps is the
+    caller's own, leaf for leaf the same arrays, so its step is the program
+    it was."""
+    from deepspeed_tpu.models import gpt
+    kw = dict(vocab_size=256, n_embd=64, n_layer=2, n_head=4, num_experts=4, top_k=2)
+    if make == "trinity_config":
+        kw.update(n_layer=4, dense_layers=1, n_kv_head=2)
+    model = gpt.GPT(getattr(gpt, make)(**kw))
+    params = model.init_params(jax.random.PRNGKey(0))
+    tree, relaid = model.serving_params(params)
+    assert tree is params and relaid == {}

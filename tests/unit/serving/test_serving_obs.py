@@ -103,5 +103,8 @@ def test_engine_registers_gauges_without_ops_server(tiny_model, tmp_path):
     snap = hub.registry.snapshot()
     assert snap["histograms"]["serve_step_ms"]["count"] > 0
     assert snap["counters"]["serve_finished_total"]["value"] == 1
+    # what the engine keeps in a layout of its own: nothing of this stack
+    assert snap["gauges"]["serve_relaid_leaves"]["value"] == eng.relaid_leaves == 0
+    assert snap["gauges"]["serve_relaid_bytes"]["value"] == eng.relaid_bytes == 0
     eng.close()
     hub.close()

@@ -178,9 +178,7 @@ def tiny_engine(model, params, **serving):
         eng = _ENGINES[key] = deepspeed_tpu.init_serving(
             model=model, params=params, config={"serving": serving})
     else:
-        eng.params = jax.tree.map(
-            lambda p: jnp.asarray(p, eng.dtype)
-            if jnp.issubdtype(jnp.asarray(p).dtype, jnp.floating) else p, params)
+        eng.params = params
     # ``tests/conftest.py:_reset_mesh`` runs after every test: a kept engine
     # must hold no mesh (these are built without one)
     assert not mesh_lib.has_mesh()

@@ -398,6 +398,62 @@ def test_the_engine_serves_the_references_tokens_and_counts_its_keys(loud):
         eng.step()
 
 
+# ---- the serving tree: a latent layer's projections as the engine keeps them --------- #
+@pytest.mark.parametrize("chunks", ["over_topk", "single"])
+@pytest.mark.parametrize("indexed", [True, False], ids=["indexed", "every_key"])
+def test_the_serving_tree_serves_the_canonical_trees_logits(indexed, chunks):
+    """``paged_step`` over the tree the engine keeps (the five projections
+    transposed, :func:`gpt.serving_params`) against the canonical tree, a
+    prompt in chunks then decode rows, with the selection at work and with a
+    ``topk`` no table reaches: the same tokens, the same logits.  Not to the
+    bit on the CPU, whose dot sums a transposed operand's products in another
+    order (2e-7 here); the file's tolerance."""
+    model, params = build() if indexed else build(topk=10 ** 6)
+    tree, relaid = model.serving_params(params)
+    assert sorted(relaid) == sorted(gpt.SERVING_LEAVES)
+    assert not set(gpt.SERVING_LEAVES) & set(tree["blocks"])
+    seq = _ids(70, seed=7)
+    got = driver(model, tree).sequence(seq, CHUNKS[chunks])
+    want = driver(model, params).sequence(seq, CHUNKS[chunks])
+    assert (got.argmax(-1) == want.argmax(-1)).all()
+    assert np.abs(got - want).max() < TOL and np.abs(want).max() > 0.1
+
+
+def test_the_engine_holds_each_relaid_leaf_once(loud):
+    """The engine's tree holds the five projections of the three layers
+    transposed and none of them canonical, as many bytes as the caller's
+    leaves; every other leaf is the array it was given; and weights assigned
+    to a kept engine go through the same relay."""
+    model, params = loud
+    _, eng = served_tokens(model, params, [_ids(9, 12)], [3], **SERVING)
+    blocks = eng.params["blocks"]
+    assert eng.relaid_leaves == 5 and not set(gpt.SERVING_LEAVES) & set(blocks)
+    assert eng.relaid_bytes == sum(params["blocks"][k].nbytes for k in gpt.SERVING_LEAVES)
+    for name, relaid in gpt.SERVING_LEAVES.items():
+        np.testing.assert_array_equal(blocks[relaid], params["blocks"][name].swapaxes(-1, -2))
+    assert blocks["q_a_w"] is params["blocks"]["q_a_w"] and eng.params["wte"] is params["wte"]
+    eng.params = jax.tree.map(lambda a: a * 2, params)
+    assert eng.relaid_leaves == 5 and "q_b_w" not in eng.params["blocks"]
+    np.testing.assert_array_equal(eng.params["blocks"]["q_b_t"],
+                                  2 * params["blocks"]["q_b_w"].swapaxes(-1, -2))
+
+
+def test_an_int8_injected_latent_stack_still_serves(loud):
+    """A projection injected as int8 (``module_inject/quantization.py``) is
+    left where it is (its scales run along the output channels) beside the
+    others relaid, and the engine serves what the canonical int8 tree serves
+    by hand."""
+    from deepspeed_tpu.module_inject.quantization import quantize_block_params
+    model, params = loud
+    injected = quantize_block_params(params, keys=("q_b_w", "kv_b_w", "index_q_w", "out_w"))
+    prompt = _ids(30, 14)
+    (tokens,), eng = served_tokens(model, injected, [prompt], [6], **SERVING)
+    assert eng.relaid_leaves == 2 and "q8" in eng.params["blocks"]["q_b_w"]
+    seq = np.concatenate([prompt, tokens]).astype(np.int32)
+    want = driver(model, injected).sequence(seq, (8, 8, 8, 6))
+    assert list(tokens) == want[len(prompt) - 1:len(seq) - 1].argmax(-1).tolist()
+
+
 def test_the_parameter_count_is_the_trees(loud):
     model, params = loud
     zeros = sum(a.size for k, a in params["blocks"].items() if k in ("ln1_b", "ln2_b", "out_b"))

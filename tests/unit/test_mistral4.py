@@ -31,7 +31,7 @@ from benchmarks.lib import reference_mistral4 as ref
 from deepspeed_tpu.models import gpt
 from deepspeed_tpu.models.gpt import GPT, YarnRope, mistral4_config
 from tests.unit.paged_bank import PATHS, bank_in_place_equals_bank_sliced
-from tests.unit.serving_helpers import jitted, served_logits
+from tests.unit.serving_helpers import Driver, jitted, served_logits, served_tokens
 from deepspeed_tpu.moe import dropless
 from deepspeed_tpu.ops.pallas import decode_attention as da
 
@@ -461,6 +461,58 @@ def test_the_engine_lays_runs_of_a_tile_and_the_kernel_fetches_them(
     # tiles and a short one
     assert stats[-2]["tile_runs_pct"] == pytest.approx(
         100 * (2 if num_blocks == 40 else 1) / 3)
+
+
+@pytest.mark.parametrize("chunks", [(8, 8, 8, 8), (7, 5, 8, 3, 1), (1,)],
+                         ids=["whole", "ragged", "single"])
+def test_the_serving_tree_serves_the_canonical_trees_logits(tiny, chunks):
+    """``paged_step`` over the tree the engine keeps (``q_b_w``, ``kv_b_w``
+    and ``kv_a_w`` transposed, :func:`gpt.serving_params`) against the
+    canonical tree, a prompt in chunks then decode rows: the same tokens,
+    the same logits.  Not to the bit on the CPU, whose dot sums a transposed
+    operand's products in another order; the file's tolerance."""
+    model, params = tiny
+    tree, relaid = model.serving_params(params)
+    assert sorted(relaid) == ["kv_a_w", "kv_b_w", "q_b_w"]
+    assert sorted(k for k in tree["blocks"] if k.endswith("_t")) == [
+        "kv_a_t", "kv_b_t", "q_b_t"]
+    seq = np.asarray(_ids(60, seed=9))
+    by_hand = lambda weights: Driver(model, weights, slots=SLOTS, chunk=CHUNK, block_size=8,
+                                     blocks_a_slot=10).sequence(seq, chunks)
+    got, want = by_hand(tree), by_hand(params)
+    assert (got.argmax(-1) == want.argmax(-1)).all()
+    assert np.abs(got - want).max() < TOL and np.abs(want).max() > 0.1
+
+
+def test_the_engine_holds_each_relaid_leaf_once(tiny):
+    model, params = tiny
+    _, eng = served_tokens(model, params, [list(map(int, _ids(9, seed=5)))], [3], **SERVING)
+    names = ("q_b_w", "kv_b_w", "kv_a_w")
+    assert eng.relaid_leaves == 3 and not set(names) & set(eng.params["blocks"])
+    assert eng.relaid_bytes == sum(params["blocks"][k].nbytes for k in names)
+    assert eng.params["blocks"]["q_a_w"] is params["blocks"]["q_a_w"]
+
+
+def test_under_a_mesh_a_relaid_leaf_keeps_its_spec_transposed(tiny):
+    """``q_b_w`` and ``kv_b_w`` make heads and are column-parallel
+    (``gpt_partition_specs``): relaid ``[L, N, K]`` they are sharded over the
+    rows that were their columns; ``kv_a_w``, shared by all heads, stays
+    whole; an unplaced leaf goes where the compiler puts it."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    model, params = tiny
+    mesh = Mesh(np.asarray(jax.devices()[:2]), ("tensor",))
+    specs = model.partition_specs()["blocks"]
+    blocks = dict(params["blocks"])
+    for name in ("q_b_w", "kv_b_w"):
+        assert specs[name] == P(None, None, "tensor")
+        blocks[name] = jax.device_put(blocks[name], NamedSharding(mesh, specs[name]))
+    blocks["kv_a_w"] = jax.device_put(blocks["kv_a_w"], NamedSharding(mesh, P()))
+    tree, _ = model.serving_params(dict(params, blocks=blocks))
+    for name in ("q_b_t", "kv_b_t"):
+        assert tree["blocks"][name].sharding == NamedSharding(mesh, P(None, "tensor", None))
+    assert tree["blocks"]["kv_a_t"].sharding.is_fully_replicated
+    np.testing.assert_array_equal(tree["blocks"]["q_b_t"],
+                                  params["blocks"]["q_b_w"].swapaxes(-1, -2))
 
 
 def test_arena_bytes_are_the_cache_specs():

@@ -203,6 +203,10 @@ def test_prefill_in_chunks_then_decode_through_the_engine(tiny):
                          eng._previous, eng._k_pages, eng._v_pages,
                          eng._tables)[0]
     assert out.shape == (rows + 8,) and out.dtype == jnp.int32
+    # no latent projection: the engine relaid nothing and its tree is the
+    # caller's, leaf for leaf the same arrays
+    assert (eng.relaid_leaves, eng.relaid_bytes) == (0, 0)
+    assert all(a is b for a, b in zip(jax.tree.leaves(eng.params), jax.tree.leaves(params)))
     for prompt, f in zip(prompts, futures):
         seq = jnp.asarray(prompt + f.result())
         logits = olmoe_logits(params, seq, **REF)

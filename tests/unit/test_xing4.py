@@ -28,7 +28,7 @@ from benchmarks.lib.reference_mistral4 import _rms
 from deepspeed_tpu.models import gpt
 from deepspeed_tpu.models.gpt import GPT, HyperSpec, xing4_config
 from tests.unit import serving_helpers
-from tests.unit.serving_helpers import Driver, dense_path_refusal, served_logits
+from tests.unit.serving_helpers import Driver, dense_path_refusal, served_logits, served_tokens
 
 TOL = 2e-5
 V = 512
@@ -279,6 +279,33 @@ def test_the_engine_serves_the_references_tokens(loud):
     assert np.abs(got - want[:len(got)]).max() < TOL
     assert tokens == want[len(prompt) - 1:len(seq) - 1].argmax(-1).tolist()
     assert sum(s["prefill_tokens"] for s in stats) == len(prompt)
+
+
+@pytest.mark.parametrize("chunks", sorted(CHUNKS))
+def test_the_serving_tree_serves_the_canonical_trees_logits(loud, chunks):
+    """``paged_step`` over the tree the engine keeps (``q_b_w``, ``kv_b_w``
+    and ``kv_a_w`` transposed, :func:`gpt.serving_params`) against the
+    canonical tree, four streams a token, a prompt in chunks then decode
+    rows: the same tokens, the same logits.  Not to the bit on the CPU, whose
+    dot sums a transposed operand's products in another order; the file's
+    tolerance."""
+    model, params = loud
+    tree, relaid = model.serving_params(params)
+    assert sorted(relaid) == ["kv_a_w", "kv_b_w", "q_b_w"]
+    seq = _ids(60, seed=8)
+    got = driver(model, tree).sequence(seq, CHUNKS[chunks])
+    want = driver(model, params).sequence(seq, CHUNKS[chunks])
+    assert (got.argmax(-1) == want.argmax(-1)).all()
+    assert np.abs(got - want).max() < TOL and np.abs(want).max() > 0.1
+
+
+def test_the_engine_holds_each_relaid_leaf_once(loud):
+    model, params = loud
+    _, eng = served_tokens(model, params, [_ids(9, 5)], [3], **SERVING)
+    names = ("q_b_w", "kv_b_w", "kv_a_w")
+    assert eng.relaid_leaves == 3 and not set(names) & set(eng.params["blocks"])
+    assert eng.relaid_bytes == sum(params["blocks"][k].nbytes for k in names)
+    assert eng.params["blocks"]["hc_attn_phi"] is params["blocks"]["hc_attn_phi"]
 
 
 def test_the_parameter_count_is_the_trees(loud):
