@@ -36,9 +36,12 @@ What differs, and why it is a kind of its own:
   logits a row, 9.96 GB at 16,384 positions and 151,936 words.  The engine
   and its arena are let go first: the reference's activations take their
   place on the chip;
-* the attention kernel's operations and bytes count what the program ran:
-  every decode slot and every token of a prompt chunk a single-query row,
-  a window layer at the pages it can see (``lib/arith_window.py``).
+* the attention's operations and bytes are the algorithm's LEAST for what
+  the program ran (:func:`attention_counters` over ``lib/arith_window.py``,
+  the ONE count of every kind that calls this one): a decode slot a single
+  query at its position, a prompt's tokens the chunks they ran as, each
+  chunk's pages once for all its queries, a window layer at the pages it can
+  see, a row without a request nothing.
 """
 
 import gc
@@ -180,35 +183,65 @@ class Resident(Serving):
         return stats
 
 
-def attention_counters(srv, snaps, steps):
-    """Operations and bytes the attention kernel needed between two
-    snapshots: each request's prompt tokens and decode steps in between, a
-    single-query row each at its own position, in every layer at the pages
-    that layer's kind can see; the program's other rows (idle slots, the
-    rows past a chunk's tokens) a trash page a layer."""
-    mcfg = srv.model.cfg
-    positions = []
+def rows_between(srv, snaps):
+    """(decode, chunks) of what ran between two snapshots, from the lengths
+    alone: ``decode`` the position of every single-query row (each request's
+    decode steps in between; its rows end at ``resident - 1``), ``chunks`` the
+    ``(first position, tokens)`` of every prompt chunk (a request's prompt
+    tokens in between, cut as ``srv.chunk`` cut them)."""
+    decode, chunks = [], []
     for rid, (plen, res1, gen1) in snaps["after"].items():
         _, res0, gen0 = snaps["before"].get(rid, (plen, 0, 0))
-        if gen0 == 0 and res0 < plen:                 # prompt tokens run
-            positions.append(np.arange(res0, min(res1, plen)))
-            res0 = min(res1, plen)
-        # decode steps: each generated token but the one the last prompt
-        # chunk yields; their rows end at res1 - 1
-        d = (gen1 - gen0) - (1 if gen0 == 0 and gen1 > 0 else 0)
-        positions.append(np.arange(res1 - max(d, 0), res1))
-    positions = np.concatenate(positions) if positions else np.zeros(0, np.int64)
+        if gen0 == 0 and res0 < plen:                 # prompt chunks run
+            end = min(res1, plen)
+            chunks += [(first, min(srv.chunk, end - first))
+                       for first in range(res0, end, srv.chunk)]
+        # each generated token but the one the last prompt chunk yields
+        d = max((gen1 - gen0) - (1 if gen0 == 0 and gen1 > 0 else 0), 0)
+        decode.append(np.arange(res1 - d, res1))
+    return (np.concatenate(decode) if decode else np.zeros(0, np.int64)), chunks
+
+
+def live_positions(decode, chunks):
+    """The position of every live row: the decode rows', then each chunk's
+    consecutive queries'."""
+    return np.concatenate([decode] + [first + np.arange(n) for first, n in chunks])
+
+
+def row_counters(srv, steps, decode, chunks):
+    """What every kind's count leaves of the stretch's rows: the live ones,
+    the chunks they made, the rows of the programs run that carried no
+    request (which cost nothing), how many of a chunk's queries the
+    program's attention packs a row, and the live rows a step."""
+    live = len(decode) + sum(n for _, n in chunks)
+    programs = sum(1 for st in steps if st[2] > 0 or st[3] > 0)
+    return {"attention_rows_live": live, "attention_chunks": len(chunks),
+            "attention_rows_idle": max(programs * (srv.slots + srv.chunk) - live, 0),
+            "chunk_queries_per_row": getattr(getattr(srv, "engine", None),
+                                             "chunk_queries_per_row", 0),
+            "traced_step_rows": Serving.step_rows(steps)}
+
+
+def attention_counters(srv, snaps, steps):
+    """Operations and bytes attention needed between two snapshots, the
+    algorithm's least (``lib/arith_window.py``): each request's decode steps
+    in between a single-query row at its own position, its prompt tokens the
+    chunks they ran as, in every layer at the pages that layer's kind can
+    see.  ``paged_gqa_*`` are K and V pages' (``readers/paged_gqa.py``);
+    ``attention_keys_read`` and ``attention_key_products`` the same count in
+    keys, for a cache that is not K and V heads (``readers/paged_mla.py``)."""
+    mcfg = srv.model.cfg
+    decode, chunks = rows_between(srv, snaps)
     layers = {}
     for kind in mcfg.pattern:
         layers[kind.window] = layers.get(kind.window, 0) + mcfg.n_layer // len(mcfg.pattern)
-    programs = sum(1 for st in steps if st[2] > 0 or st[3] > 0)
-    idle = max(programs * (srv.slots + srv.chunk) - len(positions), 0)
-    flops, nbytes = arith_window.stack(
-        positions, idle, layers, srv.block, srv.lanes, mcfg.n_head,
-        mcfg.head_dim, srv.params["wte"].dtype.itemsize)
-    return {"paged_gqa_flops": flops, "paged_gqa_bytes": nbytes,
-            "attention_rows_live": len(positions), "attention_rows_idle": idle,
-            "traced_step_rows": Serving.step_rows(steps)}
+    rows = row_counters(srv, steps, decode, chunks)
+    read, products = arith_window.keys(decode, chunks, layers, srv.block)
+    flops, nbytes = arith_window.cost(
+        read, products, rows["attention_rows_live"] * mcfg.n_layer, srv.lanes,
+        mcfg.n_head, mcfg.head_dim, srv.params["wte"].dtype.itemsize)
+    return dict(rows, paged_gqa_flops=flops, paged_gqa_bytes=nbytes,
+                attention_keys_read=read, attention_key_products=products)
 
 
 def check_sample(model, params, reference, samples):

@@ -1,19 +1,9 @@
 """Traffic kind ``serve-backlog-resident-afmoe``: ``serve-backlog-resident``
-as it stands (its plan, its fill, its window and its check of the sample
-against one full pass of the plain reference are that module's, called, not
-copied), with
-
-* the cache's reads counted for THIS cell's lengths (:func:`attention_counters`
-  over ``lib/arith_trinity.py``, in the place of the resident kind's, as
-  ``serve_backlog_resident_indexed`` puts its own there): a prompt chunk the
-  consecutive queries of ONE sequence, whose pages are needed once a chunk and
-  not once a token, and a row without a request nothing.  The resident kind
-  counts every row a single query, which at SmallThinker's cohort of one age
-  reads ``paged_gqa_attention_roofline`` 101.9%; at a chunk of 512 inside
-  prompts of up to 32,768 it would ask for hundreds of times what any program
-  needs;
-* the two LIMITS of the comparison that decides ``correct`` found on the
-  model this cell serves, by ``serve_backlog_resident_routed4.py``'s method.
+as it stands (its plan, its fill, its window, its count of the cache's reads,
+which came from this kind, and its check of the sample against one full pass
+of the plain reference are that module's, called, not copied), with the two
+LIMITS of the comparison that decides ``correct`` found on the model this
+cell serves, by ``serve_backlog_resident_routed4.py``'s method.
 
 Why that model needs limits of its own (PERF.md § 6, PR 55).  A Trinity block
 norms every sublayer's OUTPUT before it is added: a rounding in the bank is
@@ -34,11 +24,10 @@ so the gaps are rounding, not a fault.
 Both readings a limit lies between are in PERF.md § 6.
 """
 
-import numpy as np
+import functools
 
 from benchmarks.kinds import serve_backlog_resident as resident
-from benchmarks.lib import arith_trinity
-from benchmarks.lib.serving import Serving
+from benchmarks.lib import resident_stack
 
 END_TO_END = resident.END_TO_END
 # 1.55 times the largest a bf16 run has read (2.26 over 80 requests of 20
@@ -53,64 +42,11 @@ LOGIT_MARGIN = 3.5
 NOISE_LIMIT = 0.07
 
 
-def judge(largest, noise_scales, median):
-    """Samples over the gross limit, and those over the noise limit when
-    their median is (``resident.check_sample``'s rule, these limits)."""
-    return sum(w > LOGIT_MARGIN or (median > NOISE_LIMIT and s > NOISE_LIMIT)
-               for w, s in zip(largest, noise_scales))
-
-
-def attention_counters(srv, snaps, steps):
-    """Operations and bytes the cache's reads needed between two snapshots,
-    from the lengths alone, under the names ``readers/paged_gqa.py`` reads:
-    each request's decode steps in between a single-query row at its own
-    position, its prompt tokens the chunks they ran as, in every layer at
-    the pages that layer's kind can see."""
-    mcfg = srv.model.cfg
-    decode, chunks = [], []
-    for rid, (plen, res1, gen1) in snaps["after"].items():
-        _, res0, gen0 = snaps["before"].get(rid, (plen, 0, 0))
-        if gen0 == 0 and res0 < plen:                 # prompt chunks run
-            end = min(res1, plen)
-            chunks += [(first, min(srv.chunk, end - first))
-                       for first in range(res0, end, srv.chunk)]
-        d = max((gen1 - gen0) - (1 if gen0 == 0 and gen1 > 0 else 0), 0)
-        decode.append(np.arange(res1 - d, res1))
-    decode = np.concatenate(decode) if decode else np.zeros(0, np.int64)
-    layers = {}
-    for kind in mcfg.pattern:
-        layers[kind.window] = layers.get(kind.window, 0) + mcfg.n_layer // len(mcfg.pattern)
-    flops, nbytes = arith_trinity.attention(
-        decode, chunks, layers, srv.block, srv.lanes, mcfg.n_head, mcfg.head_dim,
-        srv.params["wte"].dtype.itemsize)
-    live = len(decode) + sum(n for _, n in chunks)
-    programs = sum(1 for st in steps if st[2] > 0 or st[3] > 0)
-    return {"paged_gqa_flops": flops, "paged_gqa_bytes": nbytes,
-            "attention_rows_live": live, "attention_chunks": len(chunks),
-            "attention_rows_idle": max(programs * (srv.slots + srv.chunk) - live, 0),
-            "traced_step_rows": Serving.step_rows(steps)}
+judge = functools.partial(resident_stack.judge, logit_margin=LOGIT_MARGIN,
+                          noise_limit=NOISE_LIMIT)      # tools/serve_parity.py's
 
 
 def run(cell, args, ctx):
-    """``resident.run`` with this cell's count of the cache's reads, its
-    sample judged again by this module's limits."""
-    theirs, resident.attention_counters = resident.attention_counters, attention_counters
-    try:
-        out = resident.run(cell, args, ctx)
-    finally:
-        resident.attention_counters = theirs
-    notes = out["notes"]
-    if not notes["checked"]:
-        return out
-    other = out["failed"] - notes["wrong"]            # short or refused requests
-    wrong = judge(notes["logit_gaps"], notes["noise_scales"],
-                  notes["noise_scale_median"])
-    notes.update(wrong=wrong, tie_tolerance=LOGIT_MARGIN, noise_limit=NOISE_LIMIT)
-    out.setdefault("compared", {}).update(
-        largest_logit_gap=[max(notes["logit_gaps"]), LOGIT_MARGIN],
-        noise_scale_median=[notes["noise_scale_median"], NOISE_LIMIT],
-        requests_wrong=[wrong, 0])
-    out.update(failed=wrong + other,
-               correct=(wrong == 0 and other == 0 and not notes["backlog_ran_dry"]
-                        and notes["cohort_filled"]))
-    return out
+    """``resident.run``, its sample judged again by this module's limits."""
+    return resident_stack.run(cell, args, ctx, logit_margin=LOGIT_MARGIN,
+                              noise_limit=NOISE_LIMIT)

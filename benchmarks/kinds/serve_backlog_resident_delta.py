@@ -6,7 +6,8 @@ copied) for a stack of gated delta-rule layers among full-attention layers
 
 * the caches' work counted for THAT stack (:func:`attention_counters` over
   ``lib/arith_olmo_hybrid.py``, in the place of the resident kind's, which
-  counts pages in every layer): the pages a live row's full layers read, the
+  counts pages in every layer): the pages a decode row's full layers read
+  and a prompt chunk's once for all its queries, the
   states its delta layers read and write, the convolution states beside them;
 * the two LIMITS of the comparison that decides ``correct`` found on this
   model, as ``serve_backlog_resident_hybrid`` found its own.
@@ -33,11 +34,10 @@ The same at TWO periods (8 layers, 9.7 GB in float32): a gap of 0.0 again.
 Both readings a limit lies between are in PERF.md § 6 (PR 47).
 """
 
-import numpy as np
+import functools
 
 from benchmarks.kinds import serve_backlog_resident as resident
-from benchmarks.lib import arith_olmo_hybrid
-from benchmarks.lib.serving import Serving
+from benchmarks.lib import arith_olmo_hybrid, resident_stack
 
 END_TO_END = resident.END_TO_END
 # Twice the largest a bf16 run has read (1.001 over 240 requests of thirty
@@ -59,71 +59,42 @@ LOGIT_MARGIN = 2.0
 NOISE_LIMIT = 0.52
 
 
-def judge(largest, noise_scales, median):
-    """Samples over the gross limit, and those over the noise limit when
-    their median is (``resident.check_sample``'s rule, these limits)."""
-    return sum(w > LOGIT_MARGIN or (median > NOISE_LIMIT and s > NOISE_LIMIT)
-               for w, s in zip(largest, noise_scales))
+judge = functools.partial(resident_stack.judge, logit_margin=LOGIT_MARGIN,
+                          noise_limit=NOISE_LIMIT)      # tools/serve_parity.py's
 
 
 def attention_counters(srv, snaps, steps):
-    """What the caches cost between two snapshots, from the lengths alone:
-    each request's prompt tokens and decode steps in between a single-query
-    row at its own position in every full layer, the program's other rows a
-    trash page; a delta layer's state and convolution state moved once a
-    decode row and once a prompt chunk.  ``paged_gqa_*``, the names under
-    which the resident kind leaves "the cache's reads" for ``step_mfu_pct``
+    """What the caches cost between two snapshots, from the lengths alone
+    (``resident.rows_between``): each request's decode steps in between a
+    single-query row at its own position in every full layer, its prompt
+    tokens the chunks they ran as (a chunk's pages once a chunk); a delta
+    layer's state and convolution state moved once a decode row and once a
+    prompt chunk.  ``paged_gqa_*``, the names under which the resident kind
+    leaves "the cache's reads" for ``step_mfu_pct``
     (``readers/paged_gqa.py:work``), is ALL of it here: the pages, the states
     and the convolution states.  ``traced_step_state_moves`` and
     ``traced_step_decode_moves`` are the moves a step that ran a program
     (``readers/olmo_hybrid.py``)."""
     kw = srv.cell.config["model"]["kwargs"]
-    positions, moves = [], 0
-    for rid, (plen, res1, gen1) in snaps["after"].items():
-        _, res0, gen0 = snaps["before"].get(rid, (plen, 0, 0))
-        if gen0 == 0 and res0 < plen:                 # prompt tokens run
-            positions.append(np.arange(res0, min(res1, plen)))
-            moves += -(-(min(res1, plen) - res0) // srv.chunk)
-        d = max((gen1 - gen0) - (1 if gen0 == 0 and gen1 > 0 else 0), 0)
-        positions.append(np.arange(res1 - d, res1))
-        moves += d
-    positions = np.concatenate(positions) if positions else np.zeros(0, np.int64)
+    decode, chunks = resident.rows_between(srv, snaps)
+    rows = resident.row_counters(srv, steps, decode, chunks)
+    moves = len(decode) + len(chunks)
     ran = [st for st in steps if st[2] > 0 or st[3] > 0]
-    idle = max(len(ran) * (srv.slots + srv.chunk) - len(positions), 0)
     n_full = kw["layer_types"].count("full_attention")
     n_delta = len(kw["layer_types"]) - n_full
     itemsize = srv.params["wte"].dtype.itemsize
-    flops, nbytes = arith_olmo_hybrid.full_rows(positions, idle, n_full, srv.block, kw, itemsize)
-    d_flops, state, conv = arith_olmo_hybrid.delta_rows(len(positions), moves, n_delta, kw, itemsize)
-    return {"paged_gqa_flops": flops + d_flops, "paged_gqa_bytes": nbytes + state + conv,
-            "full_pages_bytes": nbytes, "delta_state_bytes_moved": state,
-            "delta_conv_bytes_moved": conv, "delta_state_moves": moves * n_delta,
-            "traced_step_state_moves": [int(st[2] + (st[3] > 0)) * n_delta for st in ran],
-            "traced_step_decode_moves": [int(st[2]) * n_delta for st in ran],
-            "attention_rows_live": len(positions), "attention_rows_idle": idle,
-            "traced_step_rows": Serving.step_rows(steps)}
+    flops, nbytes = arith_olmo_hybrid.full_rows(decode, chunks, n_full, srv.block, kw, itemsize)
+    d_flops, state, conv = arith_olmo_hybrid.delta_rows(
+        rows["attention_rows_live"], moves, n_delta, kw, itemsize)
+    return dict(rows, paged_gqa_flops=flops + d_flops, paged_gqa_bytes=nbytes + state + conv,
+                full_pages_bytes=nbytes, delta_state_bytes_moved=state,
+                delta_conv_bytes_moved=conv, delta_state_moves=moves * n_delta,
+                traced_step_state_moves=[int(st[2] + (st[3] > 0)) * n_delta for st in ran],
+                traced_step_decode_moves=[int(st[2]) * n_delta for st in ran])
 
 
 def run(cell, args, ctx):
     """``resident.run`` with this stack's count of the caches' work, its
     sample judged again by this module's limits."""
-    theirs, resident.attention_counters = resident.attention_counters, attention_counters
-    try:
-        out = resident.run(cell, args, ctx)
-    finally:
-        resident.attention_counters = theirs
-    notes = out["notes"]
-    if not notes["checked"]:
-        return out
-    other = out["failed"] - notes["wrong"]            # short or refused requests
-    wrong = judge(notes["logit_gaps"], notes["noise_scales"],
-                  notes["noise_scale_median"])
-    notes.update(wrong=wrong, tie_tolerance=LOGIT_MARGIN, noise_limit=NOISE_LIMIT)
-    out.setdefault("compared", {}).update(
-        largest_logit_gap=[max(notes["logit_gaps"]), LOGIT_MARGIN],
-        noise_scale_median=[notes["noise_scale_median"], NOISE_LIMIT],
-        requests_wrong=[wrong, 0])
-    out.update(failed=wrong + other,
-               correct=(wrong == 0 and other == 0 and not notes["backlog_ran_dry"]
-                        and notes["cohort_filled"]))
-    return out
+    return resident_stack.run(cell, args, ctx, logit_margin=LOGIT_MARGIN,
+                              noise_limit=NOISE_LIMIT, attention_counters=attention_counters)

@@ -12,12 +12,9 @@ softmax bank beside a gated shared expert (Qwen3-Next), with
   divides what the kernel read); the states the delta layers read and write
   and the convolution states beside them under names of their own, which
   ``readers/qwen3_next.py:work`` adds for ``step_mfu_pct``;
-* this stack's layers in the traced line's ``notes.qwen3_next_layers``
-  (:func:`layer_notes`): ``BENCHMARK.json``'s ``per_layer`` is full (PERF.md
-  § 7), and six accepted entries whose readers read this cell right
-  (:data:`PINNED_ELSEWHERE`) are each held to ONE cell by a test in a file no
-  PR of this kind may edit, so their readings wait there, under their own
-  names, for the ``benchmark`` PR that makes room and takes the pins out;
+* this stack's layers listed in ``BENCHMARK.json`` since PR 68: the cell
+  appended to the entries whose readers read it (the two kernels' rooflines
+  among them);
 * THREE limits on the comparison that decides ``correct``, found on this
   model, and the controls they were read against (:data:`PLANTED`: ``--set
   planted='"state-bfloat16"'`` keeps every delta layer's state rounded
@@ -53,12 +50,9 @@ import numpy as np
 from benchmarks.kinds import serve_backlog_resident as resident
 from benchmarks.kinds.serve_backlog_resident_latent_indexed import _weights_through
 from benchmarks.lib import arith_qwen3_next as arith_qn
-from benchmarks.lib import device, resident_stack
+from benchmarks.lib import resident_stack
 from benchmarks.lib.build import jax_seed
 from benchmarks.lib.cells import BenchmarkError, resolve
-from benchmarks.lib.serving import Serving
-from benchmarks.readers import afmoe, held_experts, moe, paged_gqa
-from benchmarks.readers.program_spans import scope_share_pct
 
 END_TO_END = resident.END_TO_END
 # The GROSS limit on every served token's gap.  1.54 times the largest a bf16
@@ -92,76 +86,35 @@ NOISE_LIMIT = 0.30
 # 0.00602-0.00635), every matrix through float8 0.0679.  The geometric middle:
 # 1.32 times the one, 0.75 of the other.
 STATE_LIMIT = 0.0046
-SCOPES = ("attn_delta", "delta_conv", "delta_update", "attn_full", "attn_gate",
-          "moe", "moe_router", "moe_dispatch", "moe_experts", "moe_combine",
-          "moe_shared", "head")
-
-# accepted entries that read this cell right and are not listed for it: a
-# test holds each to the one cell it came with (``tests/benchmarks/
-# test_trinity.py``: ``workloads == [CELL]`` for the afmoe entries;
-# ``test_smallthinker.py`` the same for ``attn_full_share_pct.gen``): entry ->
-# its reader over this run
-PINNED_ELSEWHERE = {
-    "attn_full_share_pct.gen": lambda run: scope_share_pct(run, ["attn_full"]),
-    "afmoe_attn_gate_share_pct.gen": lambda run: scope_share_pct(run, ["attn_gate"]),
-    "afmoe_shared_expert_share_pct.gen": lambda run: scope_share_pct(run, ["moe_shared"]),
-    "afmoe_assignments_held_pct.gen": held_experts.assignments_held_pct,
-    "afmoe_grouped_matmul_roofline": afmoe.grouped_matmul_roofline,
-    "afmoe_paged_gqa_roofline": paged_gqa.roofline}
-
 judge = functools.partial(resident_stack.judge, logit_margin=LOGIT_MARGIN,
                           noise_limit=NOISE_LIMIT)      # tools/serve_parity.py's
 
 
 def attention_counters(srv, snaps, steps):
-    """What the caches cost between two snapshots, from the lengths alone:
-    each request's decode steps in between a single-query row at its own
-    position in the full layer, its prompt tokens the chunks they ran as (a
-    chunk's pages once for all its queries); a delta layer's state and
-    convolution state moved once a decode row and once a prompt chunk.
-    ``traced_step_state_moves`` and ``traced_step_decode_moves`` are the moves
-    a step that ran a program (``readers/olmo_hybrid.py``)."""
+    """What the caches cost between two snapshots, from the lengths alone
+    (``resident.rows_between``): each request's decode steps in between a
+    single-query row at its own position in the full layer, its prompt tokens
+    the chunks they ran as (a chunk's pages once for all its queries); a
+    delta layer's state and convolution state moved once a decode row and
+    once a prompt chunk.  ``traced_step_state_moves`` and
+    ``traced_step_decode_moves`` are the moves a step that ran a program
+    (``readers/olmo_hybrid.py``)."""
     kw = srv.cell.config["model"]["kwargs"]
-    decode, chunks = [], []
-    for rid, (plen, res1, gen1) in snaps["after"].items():
-        _, res0, gen0 = snaps["before"].get(rid, (plen, 0, 0))
-        if gen0 == 0 and res0 < plen:                 # prompt chunks run
-            end = min(res1, plen)
-            chunks += [(first, min(srv.chunk, end - first))
-                       for first in range(res0, end, srv.chunk)]
-        d = max((gen1 - gen0) - (1 if gen0 == 0 and gen1 > 0 else 0), 0)
-        decode.append(np.arange(res1 - d, res1))
-    decode = np.concatenate(decode) if decode else np.zeros(0, np.int64)
-    live = len(decode) + sum(n for _, n in chunks)
+    decode, chunks = resident.rows_between(srv, snaps)
+    rows = resident.row_counters(srv, steps, decode, chunks)
     moves = len(decode) + len(chunks)
     ran = [st for st in steps if st[2] > 0 or st[3] > 0]
     n_full = kw["layer_types"].count("full_attention")
     n_delta = len(kw["layer_types"]) - n_full
     itemsize = srv.params["wte"].dtype.itemsize
     flops, nbytes = arith_qn.full_rows(decode, chunks, n_full, srv.block, kw, itemsize)
-    d_flops, state, conv = arith_qn.delta_rows(live, moves, n_delta, kw, itemsize)
-    return {"paged_gqa_flops": flops, "paged_gqa_bytes": nbytes,
-            "delta_flops": d_flops, "delta_state_bytes_moved": state,
-            "delta_conv_bytes_moved": conv, "delta_state_moves": moves * n_delta,
-            "traced_step_state_moves": [int(st[2] + (st[3] > 0)) * n_delta for st in ran],
-            "traced_step_decode_moves": [int(st[2]) * n_delta for st in ran],
-            "attention_rows_live": live, "attention_chunks": len(chunks),
-            "attention_rows_idle": max(len(ran) * (srv.slots + srv.chunk) - live, 0),
-            "traced_step_rows": Serving.step_rows(steps)}
-
-
-def layer_notes(run):
-    """What the traced stretch says of this stack's layers: the share of the
-    device's busy time under each of :data:`SCOPES`, the fullest expert's load
-    over the mean's, and what each entry of :data:`PINNED_ELSEWHERE` reads.
-    {} without a trace."""
-    if run["trace"] is None:
-        return {}
-    out = {f"{scope}_share_pct": scope_share_pct(run, [scope]) for scope in SCOPES}
-    out["moe_load_max_over_mean"] = moe.load_max_over_mean(run)
-    out["read_right_and_not_listed"] = {name: read(run) for name, read in
-                                        PINNED_ELSEWHERE.items()}
-    return out
+    d_flops, state, conv = arith_qn.delta_rows(
+        rows["attention_rows_live"], moves, n_delta, kw, itemsize)
+    return dict(rows, paged_gqa_flops=flops, paged_gqa_bytes=nbytes,
+                delta_flops=d_flops, delta_state_bytes_moved=state,
+                delta_conv_bytes_moved=conv, delta_state_moves=moves * n_delta,
+                traced_step_state_moves=[int(st[2] + (st[3] > 0)) * n_delta for st in ran],
+                traced_step_decode_moves=[int(st[2]) * n_delta for st in ran])
 
 
 # ---- the state itself ---------------------------------------------------------------- #
@@ -247,7 +200,7 @@ PLANTED = {None: contextlib.nullcontext,
 def run(cell, args, ctx):
     """``resident.run`` with this stack's count of the caches' work, its
     sample judged again by this module's limits, the kept slots' states held
-    to the reference's, and the layers' notes."""
+    to the reference's."""
     try:        # a program without this family (a parent commit) says so at once
         resolve(cell.config["model"]["config"])
     except AttributeError as e:
@@ -277,9 +230,6 @@ def run(cell, args, ctx):
             cell, args, ctx, logit_margin=LOGIT_MARGIN, noise_limit=NOISE_LIMIT,
             attention_counters=attention_counters, Resident=Keeping, check_sample=check)
     notes = out["notes"]
-    if out.get("trace") is not None:
-        notes["qwen3_next_layers"] = layer_notes(dict(
-            out, cell=cell, peaks=device.peaks(ctx["device"]["kind"])))
     if fault:
         notes["planted"] = fault
     if not notes["checked"]:

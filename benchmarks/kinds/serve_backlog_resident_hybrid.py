@@ -5,8 +5,8 @@ copied) for a stack of sparse and linear layers (MiniCPM-SALA), with
 
 * the cache's work counted for THAT stack (:func:`attention_counters` over
   ``lib/arith_sala.py``, in the place of the resident kind's, which counts
-  pages under a window): the pages a query chose, the compressed keys it
-  scored, the states its linear layers moved;
+  pages under a window): the pages a query chose (a prompt chunk's once a
+  chunk), the compressed keys it scored, the states its linear layers moved;
 * the two LIMITS of the comparison that decides ``correct`` found on this
   model, as ``serve_backlog_resident_routed4`` found its own.
 
@@ -31,11 +31,10 @@ at 4 layers, 8,440 positions), so the gaps are rounding, not a fault.
 Both readings a limit lies between are in PERF.md § 6.
 """
 
-import numpy as np
+import functools
 
 from benchmarks.kinds import serve_backlog_resident as resident
-from benchmarks.lib import arith_sala
-from benchmarks.lib.serving import Serving
+from benchmarks.lib import arith_sala, resident_stack
 
 END_TO_END = resident.END_TO_END
 # 10 times the largest a bf16 run has read (0.0141 over 60 requests of 15 runs
@@ -50,19 +49,17 @@ LOGIT_MARGIN = 0.15
 NOISE_LIMIT = 0.02
 
 
-def judge(largest, noise_scales, median):
-    """Samples over the gross limit, and those over the noise limit when
-    their median is (``resident.check_sample``'s rule, these limits)."""
-    return sum(w > LOGIT_MARGIN or (median > NOISE_LIMIT and s > NOISE_LIMIT)
-               for w, s in zip(largest, noise_scales))
+judge = functools.partial(resident_stack.judge, logit_margin=LOGIT_MARGIN,
+                          noise_limit=NOISE_LIMIT)      # tools/serve_parity.py's
 
 
 def attention_counters(srv, snaps, steps):
-    """What the caches cost between two snapshots, from the lengths alone:
-    each request's prompt tokens and decode steps in between a single-query
-    row at its own position in every sparse layer, the program's other rows a
-    trash page; a linear layer's state moved once a decode row and once a
-    prompt chunk.  ``paged_sparse_*`` is the kernel's part (the chosen pages:
+    """What the caches cost between two snapshots, from the lengths alone
+    (``resident.rows_between``): each request's decode steps in between a
+    single-query row at its own position in every sparse layer, its prompt
+    tokens the chunks they ran as (a chunk's chosen pages once a chunk); a
+    linear layer's state moved once a decode row and once a prompt chunk.
+    ``paged_sparse_*`` is the kernel's part (the chosen pages:
     ``readers/sala.py:roofline``); ``paged_gqa_*``, the names under which the
     resident kind leaves "the cache's reads" for ``step_mfu_pct``
     (``readers/paged_gqa.py:work``), is ALL of it here: the chosen pages, the
@@ -70,56 +67,28 @@ def attention_counters(srv, snaps, steps):
     mcfg = srv.model.cfg
     kw = srv.cell.config["model"]["kwargs"]
     sp = dict(arith_sala.SPARSE, **dict(zip(arith_sala.SPARSE, kw.get("sparse", ()))))
-    positions, moves = [], 0
-    for rid, (plen, res1, gen1) in snaps["after"].items():
-        _, res0, gen0 = snaps["before"].get(rid, (plen, 0, 0))
-        if gen0 == 0 and res0 < plen:                 # prompt tokens run
-            positions.append(np.arange(res0, min(res1, plen)))
-            moves += -(-(min(res1, plen) - res0) // srv.chunk)
-        d = max((gen1 - gen0) - (1 if gen0 == 0 and gen1 > 0 else 0), 0)
-        positions.append(np.arange(res1 - d, res1))
-        moves += d
-    positions = np.concatenate(positions) if positions else np.zeros(0, np.int64)
-    programs = sum(1 for st in steps if st[2] > 0 or st[3] > 0)
-    idle = max(programs * (srv.slots + srv.chunk) - len(positions), 0)
+    decode, chunks = resident.rows_between(srv, snaps)
+    positions = resident.live_positions(decode, chunks)
+    moves = len(decode) + len(chunks)
     n_sparse = sum(m == "minicpm4" for m in kw["mixer_types"])
     n_linear = len(kw["mixer_types"]) - n_sparse
     itemsize = srv.params["wte"].dtype.itemsize
     flops, nbytes, compressed = arith_sala.sparse_rows(
-        positions, idle, n_sparse, mcfg.n_head, mcfg.kv_heads, mcfg.head_dim, sp, itemsize)
+        decode, chunks, n_sparse, mcfg.n_head, mcfg.kv_heads, mcfg.head_dim, sp, itemsize)
     lin_flops, lin_bytes = arith_sala.linear_rows(
         len(positions), moves, n_linear, mcfg.n_head, mcfg.head_dim)
     per = n_sparse * mcfg.kv_heads
-    return {"paged_sparse_flops": flops, "paged_sparse_bytes": nbytes,
-            "paged_gqa_flops": flops + lin_flops,
-            "paged_gqa_bytes": nbytes + compressed + lin_bytes,
-            "sparse_keys_attended": int(arith_sala.keys_attended(positions, sp).sum()) * per,
-            "sparse_keys_resident": int((positions + 1).sum()) * per,
-            "state_bytes_moved": lin_bytes,
-            "attention_rows_live": len(positions), "attention_rows_idle": idle,
-            "traced_step_rows": Serving.step_rows(steps)}
+    return dict(resident.row_counters(srv, steps, decode, chunks),
+                paged_sparse_flops=flops, paged_sparse_bytes=nbytes,
+                paged_gqa_flops=flops + lin_flops,
+                paged_gqa_bytes=nbytes + compressed + lin_bytes,
+                sparse_keys_attended=int(arith_sala.keys_attended(positions, sp).sum()) * per,
+                sparse_keys_resident=int((positions + 1).sum()) * per,
+                state_bytes_moved=lin_bytes)
 
 
 def run(cell, args, ctx):
     """``resident.run`` with this stack's count of the cache's work, its
     sample judged again by this module's limits."""
-    theirs, resident.attention_counters = resident.attention_counters, attention_counters
-    try:
-        out = resident.run(cell, args, ctx)
-    finally:
-        resident.attention_counters = theirs
-    notes = out["notes"]
-    if not notes["checked"]:
-        return out
-    other = out["failed"] - notes["wrong"]            # short or refused requests
-    wrong = judge(notes["logit_gaps"], notes["noise_scales"],
-                  notes["noise_scale_median"])
-    notes.update(wrong=wrong, tie_tolerance=LOGIT_MARGIN, noise_limit=NOISE_LIMIT)
-    out.setdefault("compared", {}).update(
-        largest_logit_gap=[max(notes["logit_gaps"]), LOGIT_MARGIN],
-        noise_scale_median=[notes["noise_scale_median"], NOISE_LIMIT],
-        requests_wrong=[wrong, 0])
-    out.update(failed=wrong + other,
-               correct=(wrong == 0 and other == 0 and not notes["backlog_ran_dry"]
-                        and notes["cohort_filled"]))
-    return out
+    return resident_stack.run(cell, args, ctx, logit_margin=LOGIT_MARGIN,
+                              noise_limit=NOISE_LIMIT, attention_counters=attention_counters)

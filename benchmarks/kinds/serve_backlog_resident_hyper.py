@@ -10,18 +10,14 @@ token, mixed by manifold-constrained hyper-connections round both sublayers
   ``lib/arith_xing4.py``): every (query, key) pair's operations, a decode
   row's keys read once a row and a prompt chunk's ONCE for all its queries,
   under the names the resident kinds use (``paged_gqa_*``), which
-  ``readers/paged_gqa.py:work`` hands ``step_mfu_pct``.  The resident kind's
-  own count and ``readers/paged_mla.py`` read a chunk's keys once a TOKEN:
-  right where a step is decode rows (Mistral's cell carries a chunk in 7% of
-  its steps), 150% of a roofline here;
-* this stack's layers in the traced line's ``notes.xing4_layers``
-  (:func:`layer_notes`): ``BENCHMARK.json``'s ``per_layer`` is full (PERF.md
-  § 7), so the shares of the three ``hc_*`` scopes, of the attention kernel's
-  region, of the bank, the shared expert, the dense lead and the head, the
-  two kernels' shares of their rooflines and ``hc_mix_bytes_pct`` (the time
-  under the ``hc_*`` scopes against what ``lib/arith_xing4.py:mix_bytes``
-  says the mixes must move) wait there for the ``benchmark`` PR that makes
-  room;
+  ``readers/paged_gqa.py:work`` hands ``step_mfu_pct``, and as reads and
+  pairs to the key, which ``readers/paged_mla.py`` holds the latent kernel's
+  time to (the resident kind's count is the same by whole pages);
+* this stack's layers listed in ``BENCHMARK.json`` since PR 68: the shares of
+  the three ``hc_*`` scopes and of the attention kernel's region entries of
+  their own, ``hc_mix_bytes_pct.gen`` (``readers/xing4.py``), the cell
+  appended to the two kernels' rooflines and to the bank's, the shared
+  expert's, the dense lead's and the head's shares;
 * THREE limits on the comparison that decides ``correct``, found on this
   model, and the controls they were read against (:data:`PLANTED`: ``--set
   planted='"sinkhorn-1"'`` stops the Sinkhorn-Knopp projection after one
@@ -55,12 +51,9 @@ import numpy as np
 
 from benchmarks.kinds import serve_backlog_resident as resident
 from benchmarks.kinds.serve_backlog_resident_latent_indexed import _weights_through
-from benchmarks.lib import arith, arith_xing4, device, resident_stack
+from benchmarks.lib import arith_mla, arith_xing4, resident_stack
 from benchmarks.lib.build import jax_seed
 from benchmarks.lib.cells import BenchmarkError, resolve
-from benchmarks.lib.serving import Serving
-from benchmarks.readers import afmoe
-from benchmarks.readers.program_spans import scope_share_pct
 
 END_TO_END = resident.END_TO_END
 # The GROSS limit on every served token's gap, a request at a time (the noise
@@ -82,86 +75,27 @@ NOISE_LIMIT = 0.20
 # stopped after one iteration 0.33 to 0.42: 88 times the one, a fiftieth of
 # the next.
 MAPS_LIMIT = 1e-4
-SCOPES = ("hc_coeff", "hc_pre", "hc_post", "attn", "attn_latent", "mlp", "lead_mlp",
-          "moe", "moe_router", "moe_experts", "moe_shared", "head")
-MIX_SCOPES = ("hc_coeff", "hc_pre", "hc_post")
-KERNEL = "paged_mla_attention"
-
 judge = functools.partial(resident_stack.judge, logit_margin=LOGIT_MARGIN,
                           noise_limit=NOISE_LIMIT)      # tools/serve_parity.py's
 
 
 def attention_counters(srv, snaps, steps):
-    """What the cache cost between two snapshots, from the lengths alone:
-    each request's decode steps in between a single-query row at its own
-    position in every layer, its prompt tokens the chunks they ran as (a
-    chunk's keys once for all its queries)."""
+    """What the cache cost between two snapshots, from the lengths alone
+    (``resident.rows_between``): each request's decode steps in between a
+    single-query row at its own position in every layer, its prompt tokens
+    the chunks they ran as (a chunk's keys once for all its queries), to the
+    key.  ``paged_gqa_*`` for ``step_mfu_pct``; ``attention_keys_read`` and
+    ``attention_key_products`` for the latent kernel's roofline
+    (``readers/paged_mla.py``)."""
     mcfg = srv.model.cfg
-    decode, chunks = [], []
-    for rid, (plen, res1, gen1) in snaps["after"].items():
-        _, res0, gen0 = snaps["before"].get(rid, (plen, 0, 0))
-        if gen0 == 0 and res0 < plen:                 # prompt chunks run
-            end = min(res1, plen)
-            chunks += [(first, min(srv.chunk, end - first))
-                       for first in range(res0, end, srv.chunk)]
-        d = max((gen1 - gen0) - (1 if gen0 == 0 and gen1 > 0 else 0), 0)
-        decode.append(np.arange(res1 - d, res1))
-    decode = np.concatenate(decode) if decode else np.zeros(0, np.int64)
-    live = len(decode) + sum(n for _, n in chunks)
-    ran = [st for st in steps if st[2] > 0 or st[3] > 0]
-    flops, nbytes = arith_xing4.latent_rows(
-        decode, chunks, mcfg.n_layer, mcfg.n_head, mcfg.kv_lora_rank,
-        mcfg.qk_rope_dim, srv.params["wte"].dtype.itemsize)
-    return {"paged_gqa_flops": flops, "paged_gqa_bytes": nbytes,
-            "attention_rows_live": live, "attention_chunks": len(chunks),
-            "attention_rows_idle": max(len(ran) * (srv.slots + srv.chunk) - live, 0),
-            "traced_step_rows": Serving.step_rows(steps)}
-
-
-def kernel_roofline(run):
-    """The least time for :func:`attention_counters`' operations and bytes
-    over the attention kernel's time in the traced stretch."""
-    t, c = run["trace"], run["counters"]
-    took = t.op_seconds().get(KERNEL)
-    if not took or "paged_gqa_bytes" not in c:
-        return None
-    bound_s, which = arith.roofline_seconds(
-        c["paged_gqa_flops"], c["paged_gqa_bytes"], run["peaks"])
-    run["notes"].setdefault("roofline_bound", {})[KERNEL] = which
-    return 100.0 * bound_s / took
-
-
-def mix_bytes_pct(run):
-    """The time the chip's memory needs for what the mixes of the traced
-    stretch's steps must move (``arith_xing4.mix_bytes`` over the live rows
-    of the steps whose programs the device's trace holds) over the time
-    under the three ``hc_*`` scopes there."""
-    import jax.numpy as jnp
-    t, cfg = run["trace"], run["cell"].config
-    share = scope_share_pct(run, list(MIX_SCOPES))
-    rows = [r for r in run["counters"].get("traced_step_rows", ()) if r > 0]
-    held = t.program_runs()
-    kept = rows[-held:] if held else rows
-    if not share or not kept:
-        return None
-    kw = cfg["model"]["kwargs"]
-    nbytes = arith_xing4.mix_bytes(sum(kept), len(kept), kw["n_layer"], kw["hyper"][0],
-                                   kw["n_embd"], jnp.dtype(cfg["dtype"]).itemsize)
-    return 100.0 * (nbytes / run["peaks"]["hbm_bytes_per_s"]) / (share / 100.0 * t.busy_s())
-
-
-def layer_notes(run):
-    """What the traced stretch says of this stack's layers: the share of the
-    device's busy time under each of :data:`SCOPES`, the two kernels' shares
-    of their rooflines and the mixes' time against their bytes.  {} without a
-    trace."""
-    if run["trace"] is None:
-        return {}
-    out = {f"{scope}_share_pct": scope_share_pct(run, [scope]) for scope in SCOPES}
-    out.update(paged_mla_attention_roofline=kernel_roofline(run),
-               grouped_matmul_roofline=afmoe.grouped_matmul_roofline(run),
-               hc_mix_bytes_pct=mix_bytes_pct(run))
-    return out
+    decode, chunks = resident.rows_between(srv, snaps)
+    rows = resident.row_counters(srv, steps, decode, chunks)
+    read, pairs = arith_xing4.latent_keys(decode, chunks, mcfg.n_layer)
+    flops, nbytes = arith_mla.latent_attention(
+        read, pairs, mcfg.n_layer * rows["attention_rows_live"], mcfg.n_head,
+        mcfg.kv_lora_rank, mcfg.qk_rope_dim, srv.params["wte"].dtype.itemsize)
+    return dict(rows, paged_gqa_flops=flops, paged_gqa_bytes=nbytes,
+                attention_keys_read=read, attention_key_products=pairs)
 
 
 def maps_gaps(model, params, reference, samples):
@@ -229,7 +163,7 @@ PLANTED = {None: contextlib.nullcontext,
 def run(cell, args, ctx):
     """``resident.run`` with this traffic's count of the cache's work, its
     sample judged again by this module's limits, the first sublayer's maps
-    held to the reference's, and the layers' notes."""
+    held to the reference's."""
     try:        # a program without this family (a parent commit) says so at once
         resolve(cell.config["model"]["config"])
     except AttributeError as e:
@@ -252,9 +186,6 @@ def run(cell, args, ctx):
             cell, args, ctx, logit_margin=LOGIT_MARGIN, noise_limit=NOISE_LIMIT,
             attention_counters=attention_counters, check_sample=check)
     notes = out["notes"]
-    if out.get("trace") is not None:
-        notes["xing4_layers"] = layer_notes(dict(
-            out, cell=cell, peaks=device.peaks(ctx["device"]["kind"])))
     if fault:
         notes["planted"] = fault
     if not notes["checked"]:

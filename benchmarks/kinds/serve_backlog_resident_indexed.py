@@ -38,11 +38,11 @@ positions (``tools/serve_parity.py --long 30000`` at two layers: every gap
 Both readings a limit lies between are in PERF.md § 6.
 """
 
-import numpy as np
+import functools
 
 from benchmarks.kinds import serve_backlog_resident as resident
 from benchmarks.lib import arith_keye_vl2 as arith_keye
-from benchmarks.lib.serving import Serving
+from benchmarks.lib import resident_stack
 
 END_TO_END = resident.END_TO_END
 # 2.3 times the largest a bf16 run has read (0.882 over 36 requests of 9 runs
@@ -58,70 +58,38 @@ LOGIT_MARGIN = 2.0
 NOISE_LIMIT = 0.2
 
 
-def judge(largest, noise_scales, median):
-    """Samples over the gross limit, and those over the noise limit when
-    their median is (``resident.check_sample``'s rule, these limits)."""
-    return sum(w > LOGIT_MARGIN or (median > NOISE_LIMIT and s > NOISE_LIMIT)
-               for w, s in zip(largest, noise_scales))
+judge = functools.partial(resident_stack.judge, logit_margin=LOGIT_MARGIN,
+                          noise_limit=NOISE_LIMIT)      # tools/serve_parity.py's
 
 
 def attention_counters(srv, snaps, steps):
-    """What the caches cost between two snapshots, from the lengths alone:
-    each request's decode steps in between a single-query row at its own
-    position in every indexed layer, its prompt tokens chunks of one
-    sequence.  ``index_*`` is the scores' part, ``indexed_attend_*`` the
+    """What the caches cost between two snapshots, from the lengths alone
+    (``resident.rows_between``): each request's decode steps in between a
+    single-query row at its own position in every indexed layer, its prompt
+    tokens chunks of one sequence.  ``index_*`` is the scores' part, ``indexed_attend_*`` the
     chosen tokens' (``readers/keye_vl2.py:scope_roofline``); ``paged_gqa_*``,
     the names under which the resident kind leaves "the cache's reads" for
     ``step_mfu_pct`` (``readers/paged_gqa.py:work``), is ALL of it here."""
     mcfg = srv.model.cfg
     ix = arith_keye.indexer_of(srv.cell.config["model"]["kwargs"])
-    decode, chunks = [], []
-    for rid, (plen, res1, gen1) in snaps["after"].items():
-        _, res0, gen0 = snaps["before"].get(rid, (plen, 0, 0))
-        if gen0 == 0 and res0 < plen:                 # prompt chunks run
-            end = min(res1, plen)
-            chunks += [(first, min(srv.chunk, end - first))
-                       for first in range(res0, end, srv.chunk)]
-        d = max((gen1 - gen0) - (1 if gen0 == 0 and gen1 > 0 else 0), 0)
-        decode.append(np.arange(res1 - d, res1))
-    decode = np.concatenate(decode) if decode else np.zeros(0, np.int64)
-    positions = np.concatenate([decode] + [first + np.arange(n) for first, n in chunks])
-    programs = sum(1 for st in steps if st[2] > 0 or st[3] > 0)
-    idle = max(programs * (srv.slots + srv.chunk) - len(positions), 0)
+    decode, chunks = resident.rows_between(srv, snaps)
+    positions = resident.live_positions(decode, chunks)
     itemsize = srv.params["wte"].dtype.itemsize
     s_flops, s_bytes = arith_keye.score_rows(decode, chunks, mcfg.n_layer, ix, itemsize)
     a_flops, a_bytes = arith_keye.attend_rows(
         decode, chunks, mcfg.n_layer, mcfg.n_head, mcfg.kv_heads, mcfg.head_dim, ix, itemsize)
-    return {"index_flops": s_flops, "index_bytes": s_bytes,
-            "indexed_attend_flops": a_flops, "indexed_attend_bytes": a_bytes,
-            "paged_gqa_flops": s_flops + a_flops, "paged_gqa_bytes": s_bytes + a_bytes,
-            "index_keys_scored": int((positions + 1).sum()) * mcfg.n_layer,
-            "indexed_keys_attended": int(arith_keye.keys_attended(positions, ix).sum()) * mcfg.n_layer,
-            "indexed_keys_resident": int((positions + 1).sum()) * mcfg.n_layer,
-            "attention_rows_live": len(positions), "attention_rows_idle": idle,
-            "traced_step_rows": Serving.step_rows(steps)}
+    resident_keys = int((positions + 1).sum()) * mcfg.n_layer
+    return dict(resident.row_counters(srv, steps, decode, chunks),
+                index_flops=s_flops, index_bytes=s_bytes,
+                indexed_attend_flops=a_flops, indexed_attend_bytes=a_bytes,
+                paged_gqa_flops=s_flops + a_flops, paged_gqa_bytes=s_bytes + a_bytes,
+                index_keys_scored=resident_keys, indexed_keys_resident=resident_keys,
+                indexed_keys_attended=int(arith_keye.keys_attended(positions, ix).sum())
+                * mcfg.n_layer)
 
 
 def run(cell, args, ctx):
     """``resident.run`` with this stack's count of the cache's work, its
     sample judged again by this module's limits."""
-    theirs, resident.attention_counters = resident.attention_counters, attention_counters
-    try:
-        out = resident.run(cell, args, ctx)
-    finally:
-        resident.attention_counters = theirs
-    notes = out["notes"]
-    if not notes["checked"]:
-        return out
-    other = out["failed"] - notes["wrong"]            # short or refused requests
-    wrong = judge(notes["logit_gaps"], notes["noise_scales"],
-                  notes["noise_scale_median"])
-    notes.update(wrong=wrong, tie_tolerance=LOGIT_MARGIN, noise_limit=NOISE_LIMIT)
-    out.setdefault("compared", {}).update(
-        largest_logit_gap=[max(notes["logit_gaps"]), LOGIT_MARGIN],
-        noise_scale_median=[notes["noise_scale_median"], NOISE_LIMIT],
-        requests_wrong=[wrong, 0])
-    out.update(failed=wrong + other,
-               correct=(wrong == 0 and other == 0 and not notes["backlog_ran_dry"]
-                        and notes["cohort_filled"]))
-    return out
+    return resident_stack.run(cell, args, ctx, logit_margin=LOGIT_MARGIN,
+                              noise_limit=NOISE_LIMIT, attention_counters=attention_counters)

@@ -11,11 +11,9 @@ heads attend the 2,048 rows of the latent cache it scores highest), with
   vectors it chose, whatever implements either, under the counter names
   ``serve_backlog_resident_indexed`` leaves (``index_*``, ``indexed_*``), so
   that the metrics of ``readers/keye_vl2.py``, which name no model, read them;
-* this stack's layers in the traced line's ``notes.deepseek_layers``
-  (:func:`layer_notes`): ``BENCHMARK.json``'s ``per_layer`` is full (PERF.md
-  § 7), so the shares of ``latent_project``, ``moe``, ``moe_shared`` and
-  ``route_groups`` and the held share of the assignments wait there for the
-  ``benchmark`` PR that makes room;
+* this stack's layers listed in ``BENCHMARK.json`` since PR 68 (the shares of
+  ``latent_project`` and ``route_groups`` entries of their own, the cell
+  appended to the bank's, the shared expert's and the held assignments');
 * the two LIMITS of the comparison that decides ``correct`` found on this
   model, and the controls they were read against (:data:`PLANTED`: ``--set
   planted='"weights-float8"'`` serves every matrix rounded through
@@ -37,15 +35,10 @@ Both readings a limit lies between are in PERF.md § 6.
 import contextlib
 import functools
 
-import numpy as np
-
 from benchmarks.kinds import serve_backlog_resident as resident
 from benchmarks.lib import arith_deepseek_v32 as arith_ds
-from benchmarks.lib import device, resident_stack
+from benchmarks.lib import resident_stack
 from benchmarks.lib.build import jax_seed
-from benchmarks.lib.serving import Serving
-from benchmarks.readers import held_experts
-from benchmarks.readers.program_spans import scope_share_pct
 
 END_TO_END = resident.END_TO_END
 # 1.87 times the largest a bf16 run has read (2.68 over the 24 requests of
@@ -64,63 +57,36 @@ LOGIT_MARGIN = 5.0
 # largest median; both controls are past every scale (999.99: more tokens
 # flipped, 76-78% and 99.8%, than any noise explains).
 NOISE_LIMIT = 0.8
-SCOPES = ("attn_indexed", "latent_project", "index_score", "index_topk",
-          "index_attend", "lead_mlp", "moe", "moe_router", "route_groups",
-          "moe_experts", "moe_shared", "head")
-
 judge = functools.partial(resident_stack.judge, logit_margin=LOGIT_MARGIN,
                           noise_limit=NOISE_LIMIT)      # tools/serve_parity.py's
 
 
 def attention_counters(srv, snaps, steps):
-    """What the caches cost between two snapshots, from the lengths alone:
-    each request's decode steps in between a single-query row at its own
-    position in every layer, its prompt tokens chunks of one sequence.
+    """What the caches cost between two snapshots, from the lengths alone
+    (``resident.rows_between``): each request's decode steps in between a
+    single-query row at its own position in every layer, its prompt tokens
+    chunks of one sequence.
     ``index_*`` is the scores' part, ``indexed_attend_*`` the chosen rows'
     (``readers/keye_vl2.py:scope_roofline``); ``paged_gqa_*``, the names under
     which the resident kind leaves "the cache's reads" for ``step_mfu_pct``
     (``readers/paged_gqa.py:work``), is ALL of it here."""
     mcfg = srv.model.cfg
     ix = arith_ds.indexer_of(srv.cell.config["model"]["kwargs"])
-    decode, chunks = [], []
-    for rid, (plen, res1, gen1) in snaps["after"].items():
-        _, res0, gen0 = snaps["before"].get(rid, (plen, 0, 0))
-        if gen0 == 0 and res0 < plen:                 # prompt chunks run
-            end = min(res1, plen)
-            chunks += [(first, min(srv.chunk, end - first))
-                       for first in range(res0, end, srv.chunk)]
-        d = max((gen1 - gen0) - (1 if gen0 == 0 and gen1 > 0 else 0), 0)
-        decode.append(np.arange(res1 - d, res1))
-    decode = np.concatenate(decode) if decode else np.zeros(0, np.int64)
-    positions = np.concatenate([decode] + [first + np.arange(n) for first, n in chunks])
-    programs = sum(1 for st in steps if st[2] > 0 or st[3] > 0)
-    idle = max(programs * (srv.slots + srv.chunk) - len(positions), 0)
+    decode, chunks = resident.rows_between(srv, snaps)
+    positions = resident.live_positions(decode, chunks)
     itemsize = srv.params["wte"].dtype.itemsize
     s_flops, s_bytes = arith_ds.score_rows(decode, chunks, mcfg.n_layer, ix, itemsize)
     a_flops, a_bytes = arith_ds.attend_rows(
         decode, chunks, mcfg.n_layer, mcfg.n_head, mcfg.kv_lora_rank,
         mcfg.qk_rope_dim, ix, itemsize)
     resident_keys = int((positions + 1).sum()) * mcfg.n_layer
-    return {"index_flops": s_flops, "index_bytes": s_bytes,
-            "indexed_attend_flops": a_flops, "indexed_attend_bytes": a_bytes,
-            "paged_gqa_flops": s_flops + a_flops, "paged_gqa_bytes": s_bytes + a_bytes,
-            "index_keys_scored": resident_keys, "indexed_keys_resident": resident_keys,
-            "indexed_keys_attended": int(arith_ds.keys_attended(positions, ix).sum())
-            * mcfg.n_layer,
-            "attention_rows_live": len(positions), "attention_rows_idle": idle,
-            "traced_step_rows": Serving.step_rows(steps)}
-
-
-def layer_notes(run):
-    """What the traced stretch says of this stack's layers: the share of the
-    device's busy time under each of :data:`SCOPES`, and the share of the
-    live rows' assignments that fell on the experts held here.  {} without a
-    trace."""
-    if run["trace"] is None:
-        return {}
-    out = {f"{scope}_share_pct": scope_share_pct(run, [scope]) for scope in SCOPES}
-    out["moe_assignments_held_pct"] = held_experts.assignments_held_pct(run)
-    return out
+    return dict(resident.row_counters(srv, steps, decode, chunks),
+                index_flops=s_flops, index_bytes=s_bytes,
+                indexed_attend_flops=a_flops, indexed_attend_bytes=a_bytes,
+                paged_gqa_flops=s_flops + a_flops, paged_gqa_bytes=s_bytes + a_bytes,
+                index_keys_scored=resident_keys, indexed_keys_resident=resident_keys,
+                indexed_keys_attended=int(arith_ds.keys_attended(positions, ix).sum())
+                * mcfg.n_layer)
 
 
 # ---- the controls: what the limits must refuse ------------------------------------- #
@@ -161,7 +127,7 @@ PLANTED = {None: contextlib.nullcontext,
 
 def run(cell, args, ctx):
     """``resident.run`` with this stack's count of the caches' work, its
-    sample judged again by this module's limits, and the layers' notes."""
+    sample judged again by this module's limits."""
     fault = cell.traffic.get("planted")
     their_check = resident.check_sample
 
@@ -179,9 +145,6 @@ def run(cell, args, ctx):
         out = resident_stack.run(
             cell, args, ctx, logit_margin=LOGIT_MARGIN, noise_limit=NOISE_LIMIT,
             attention_counters=attention_counters, check_sample=check)
-    if out.get("trace") is not None:
-        out["notes"]["deepseek_layers"] = layer_notes(dict(
-            out, cell=cell, peaks=device.peaks(ctx["device"]["kind"])))
     if fault:
         out["notes"]["planted"] = fault
     return out

@@ -23,13 +23,11 @@ family), with
   zero all land on the far side of ``STATE_LIMIT``, where the logits' noise
   scale sees the first of them late or not at all (PERF.md section 6, PR 57);
 * the CONTROLS those limits were read against, planted by ``--set
-  planted='"<name>"'`` (:data:`PLANTED`): each must come out not correct;
-* what the trace says of the mamba layers left in the traced line's
-  ``notes.mamba_layers`` (:func:`layer_notes`): ``BENCHMARK.json``'s
-  ``per_layer`` stands at the 128 entries the driver's contract lets it hold
-  ("per_layer: 1 to 128 metrics"), so these cannot be listed as metrics until
-  a ``benchmark`` PR makes room; nothing checks them until then (PERF.md
-  section 7).
+  planted='"<name>"'`` (:data:`PLANTED`): each must come out not correct.
+
+What the trace says of the mamba layers is listed in ``BENCHMARK.json`` since
+PR 68 (the four scopes' shares, the two kernels' rooflines over
+``readers/jamba.py``, the states moved a step).
 
 The readings each limit lies between are in PERF.md section 6 (PR 57).
 """
@@ -41,11 +39,9 @@ import statistics
 import numpy as np
 
 from benchmarks.kinds import serve_backlog_resident as resident
-from benchmarks.lib import arith, arith_jamba, device, resident_stack
+from benchmarks.lib import arith_jamba, resident_stack
 from benchmarks.lib.build import jax_seed
 from benchmarks.lib.cells import resolve
-from benchmarks.lib.serving import Serving
-from benchmarks.readers.program_spans import scope_share_pct
 
 END_TO_END = resident.END_TO_END
 # The GROSS limit on every served token's gap.  1.9 times the largest a bf16
@@ -78,11 +74,6 @@ NOISE_LIMIT = 0.12
 # 0.074): 1,000 decoded tokens on most of the state is forgotten either way,
 # and the two limits on logits are what refuse THAT fault.
 STATE_LIMIT = 0.0065
-SCOPES = ("attn_mamba", "mamba_conv", "mamba_params", "mamba_scan", "attn_full",
-          "mlp", "head")
-# the kernels of ``ops/pallas/selective_scan.py``, and what counts a call's work
-KERNELS = {"mamba_state_update": ("traced_step_decode_rows", arith_jamba.state_update_call),
-           "mamba_chunk_scan": ("traced_step_chunk_tokens", arith_jamba.chunk_scan_call)}
 
 
 judge = functools.partial(resident_stack.judge, logit_margin=LOGIT_MARGIN,
@@ -90,71 +81,35 @@ judge = functools.partial(resident_stack.judge, logit_margin=LOGIT_MARGIN,
 
 
 def attention_counters(srv, snaps, steps):
-    """What the caches cost between two snapshots, from the lengths alone:
-    each request's prompt tokens and decode steps in between a single-query
-    row at its own position in every full layer, the program's other rows a
-    trash page; a mamba layer's state and convolution state moved once a
-    decode row and once a prompt chunk.  ``paged_gqa_*``, the names under
-    which the resident kind leaves "the cache's reads" for ``step_mfu_pct``
+    """What the caches cost between two snapshots, from the lengths alone
+    (``resident.rows_between``): each request's decode steps in between a
+    single-query row at its own position in every full layer, its prompt
+    tokens the chunks they ran as (a chunk's pages once a chunk); a mamba
+    layer's state and convolution state moved once a decode row and once a
+    prompt chunk.  ``paged_gqa_*``, the names under which the resident kind
+    leaves "the cache's reads" for ``step_mfu_pct``
     (``readers/paged_gqa.py:work``), is ALL of it here: the pages, the states
     and the convolution states.  ``traced_step_decode_rows`` and
     ``traced_step_chunk_tokens`` are the live decode rows and the prompt
     tokens of each step that ran a program: what the two kernels of
-    ``ops/pallas/selective_scan.py`` worked on, a layer."""
+    ``ops/pallas/selective_scan.py`` worked on, a layer
+    (``readers/jamba.py``)."""
     kw = srv.cell.config["model"]["kwargs"]
-    positions, moves = [], 0
-    for rid, (plen, res1, gen1) in snaps["after"].items():
-        _, res0, gen0 = snaps["before"].get(rid, (plen, 0, 0))
-        if gen0 == 0 and res0 < plen:                 # prompt tokens run
-            positions.append(np.arange(res0, min(res1, plen)))
-            moves += -(-(min(res1, plen) - res0) // srv.chunk)
-        d = max((gen1 - gen0) - (1 if gen0 == 0 and gen1 > 0 else 0), 0)
-        positions.append(np.arange(res1 - d, res1))
-        moves += d
-    positions = np.concatenate(positions) if positions else np.zeros(0, np.int64)
+    decode, chunks = resident.rows_between(srv, snaps)
+    rows = resident.row_counters(srv, steps, decode, chunks)
+    moves = len(decode) + len(chunks)
     ran = [st for st in steps if st[2] > 0 or st[3] > 0]
-    idle = max(len(ran) * (srv.slots + srv.chunk) - len(positions), 0)
     kinds = arith_jamba.layer_kinds(kw)
     n_full, n_mamba = kinds.count("full"), kinds.count("mamba")
     itemsize = srv.params["wte"].dtype.itemsize
-    flops, nbytes = arith_jamba.full_rows(positions, idle, n_full, srv.block, kw, itemsize)
-    m_flops, state, conv = arith_jamba.mamba_rows(len(positions), moves, n_mamba, kw, itemsize)
-    return {"paged_gqa_flops": flops + m_flops, "paged_gqa_bytes": nbytes + state + conv,
-            "full_pages_bytes": nbytes, "mamba_state_bytes_moved": state,
-            "mamba_conv_bytes_moved": conv, "mamba_state_moves": moves * n_mamba,
-            "traced_step_decode_rows": [int(st[2]) for st in ran],
-            "traced_step_chunk_tokens": [int(st[3]) for st in ran],
-            "attention_rows_live": len(positions), "attention_rows_idle": idle,
-            "traced_step_rows": Serving.step_rows(steps)}
-
-
-def layer_notes(run):
-    """What the traced stretch says of this stack's layers: the share of the
-    device's busy time under each of :data:`SCOPES`, and each kernel's share
-    of its roofline: the least time for what it worked on in the steps the
-    device line holds (the LAST ``Trace.program_runs()`` of them, as
-    ``readers/step_share.py`` reads them; ``arith_jamba.state_update_call`` /
-    ``chunk_scan_call`` a mamba layer a step) over its self time.  {} without
-    a trace."""
-    trace, counters = run["trace"], run["counters"]
-    if trace is None or "traced_step_decode_rows" not in counters:
-        return {}
-    kw = run["cell"].config["model"]["kwargs"]
-    layers = arith_jamba.layer_kinds(kw).count("mamba")
-    out = {f"{scope}_share_pct": scope_share_pct(run, [scope]) for scope in SCOPES}
-    held = trace.program_runs()
-    last = lambda name: counters[name][-held:] if held else counters[name]
-    seconds = trace.op_seconds()
-    for kernel, (rows, call) in KERNELS.items():
-        took, calls = seconds.get(kernel), [call(n, kw) for n in last(rows) if n]
-        if not took or not calls:
-            continue
-        flops, nbytes = (layers * sum(c[i] for c in calls) for i in (0, 1))
-        least, bound = arith.roofline_seconds(flops, nbytes, run["peaks"])
-        out.update({f"{kernel}_roofline": 100.0 * least / took,
-                    f"{kernel}_bound": bound, f"{kernel}_s": took,
-                    f"{kernel}_bytes": nbytes, f"{kernel}_flops": flops})
-    return out
+    flops, nbytes = arith_jamba.full_rows(decode, chunks, n_full, srv.block, kw, itemsize)
+    m_flops, state, conv = arith_jamba.mamba_rows(
+        rows["attention_rows_live"], moves, n_mamba, kw, itemsize)
+    return dict(rows, paged_gqa_flops=flops + m_flops, paged_gqa_bytes=nbytes + state + conv,
+                full_pages_bytes=nbytes, mamba_state_bytes_moved=state,
+                mamba_conv_bytes_moved=conv, mamba_state_moves=moves * n_mamba,
+                traced_step_decode_rows=[int(st[2]) for st in ran],
+                traced_step_chunk_tokens=[int(st[3]) for st in ran])
 
 
 def slots_kept(engine, k, seed):
@@ -239,7 +194,7 @@ PLANTED = {None: contextlib.nullcontext,
 def run(cell, args, ctx):
     """``resident.run`` with this stack's count of the caches' work, its
     sample judged again by this module's limits, the kept slots' states held
-    to the reference's, and the layers' notes."""
+    to the reference's."""
     kept, gaps = [], []
     fault = cell.traffic.get("planted")
     their_check = resident.check_sample
@@ -265,9 +220,6 @@ def run(cell, args, ctx):
             cell, args, ctx, logit_margin=LOGIT_MARGIN, noise_limit=NOISE_LIMIT,
             attention_counters=attention_counters, Resident=Keeping, check_sample=check)
     notes = out["notes"]
-    if out.get("trace") is not None:
-        notes["mamba_layers"] = layer_notes(dict(
-            out, cell=cell, peaks=device.peaks(ctx["device"]["kind"])))
     if fault:
         notes["planted"] = fault
     if not notes["checked"]:

@@ -31,7 +31,10 @@ the gaps are rounding, not a fault.
 Both readings a limit lies between are in PERF.md § 6.
 """
 
+import functools
+
 from benchmarks.kinds import serve_backlog_resident as resident
+from benchmarks.lib import resident_stack
 
 END_TO_END = resident.END_TO_END
 # 2.0 times the largest a bf16 run has read (3.03 over 24 requests of three
@@ -45,28 +48,11 @@ LOGIT_MARGIN = 6.0
 NOISE_LIMIT = 0.12
 
 
-def judge(largest, noise_scales, median):
-    """Samples over the gross limit, and those over the noise limit when
-    their median is (``resident.check_sample``'s rule, these limits)."""
-    return sum(w > LOGIT_MARGIN or (median > NOISE_LIMIT and s > NOISE_LIMIT)
-               for w, s in zip(largest, noise_scales))
+judge = functools.partial(resident_stack.judge, logit_margin=LOGIT_MARGIN,
+                          noise_limit=NOISE_LIMIT)      # tools/serve_parity.py's
 
 
 def run(cell, args, ctx):
     """``resident.run``, its sample judged again by this module's limits."""
-    out = resident.run(cell, args, ctx)
-    notes = out["notes"]
-    if not notes["checked"]:
-        return out
-    other = out["failed"] - notes["wrong"]            # short or refused requests
-    wrong = judge(notes["logit_gaps"], notes["noise_scales"],
-                  notes["noise_scale_median"])
-    notes.update(wrong=wrong, tie_tolerance=LOGIT_MARGIN, noise_limit=NOISE_LIMIT)
-    out.setdefault("compared", {}).update(
-        largest_logit_gap=[max(notes["logit_gaps"]), LOGIT_MARGIN],
-        noise_scale_median=[notes["noise_scale_median"], NOISE_LIMIT],
-        requests_wrong=[wrong, 0])
-    out.update(failed=wrong + other,
-               correct=(wrong == 0 and other == 0 and not notes["backlog_ran_dry"]
-                        and notes["cohort_filled"]))
-    return out
+    return resident_stack.run(cell, args, ctx, logit_margin=LOGIT_MARGIN,
+                              noise_limit=NOISE_LIMIT)

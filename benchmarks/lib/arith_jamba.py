@@ -8,8 +8,8 @@ same work whatever implements it.
 A row is one token at position ``t``.  What the ALGORITHM needs of it:
 
 * a FULL layer: the pages that hold the keys ``0 .. t``, of K and of V, the
-  ONE K/V head's keys once for all 20 query heads (``arith_window.stack``
-  with no window);
+  ONE K/V head's keys once for all 20 query heads, a decode row's once a row
+  and a prompt chunk's once a chunk (``arith_window.full_rows``);
 * a MAMBA layer: the state ``[channels, states]`` float32 read and written
   once a decode row, and once a prompt CHUNK (its tokens share the read and
   the write): a MOVE; beside it the convolution state, the last ``taps - 1``
@@ -19,11 +19,10 @@ A row is one token at position ``t``.  What the ALGORITHM needs of it:
   step times the input, ``D``'s product and sum) and ``2 x taps`` a channel
   for the convolution.
 
-A row that carries no request reads its one trash page a full layer, and no
-state.
+A row that carries no request needs nothing.
 """
 
-from benchmarks.lib import arith_window
+from benchmarks.lib.arith_window import full_rows  # noqa: F401  the full layers' pages
 
 
 def _sizes(kw):
@@ -79,14 +78,6 @@ def conv_state_bytes(kw, itemsize=2):
     """Bytes of ONE mamba layer's convolution state a slot."""
     N, _, _, taps = _sizes(kw)
     return (taps - 1) * N * itemsize
-
-
-def full_rows(positions, idle_rows, layers, block, kw, itemsize=2):
-    """(operations, bytes) of the ``layers`` full layers' attention: the live
-    rows at ``positions`` and ``idle_rows`` rows of one page each."""
-    return arith_window.stack(positions, idle_rows, {None: layers}, block,
-                              kw["n_kv_head"] * kw["head_dim"], kw["n_head"],
-                              kw["head_dim"], itemsize)
 
 
 def token_flops(kw):

@@ -7,8 +7,9 @@ op's name, so the count is the same work whatever implements it.
 A row is one token at position ``t``.  What the ALGORITHM needs of it:
 
 * a FULL layer: the pages that hold the keys ``0 .. t``, of K and of V, each
-  K/V head's keys once (``arith_window.stack`` with no window: what
-  ``paged_gqa_attention``'s roofline divides in the other resident cells);
+  K/V head's keys once a decode row and once a prompt chunk
+  (``arith_window.full_rows``: what ``paged_gqa_attention``'s roofline divides
+  in the other resident cells);
 * a DELTA layer: the state ``[heads, key_dim, value_dim]`` float32 read and
   written once a decode row, and once a prompt CHUNK (its tokens share the
   read and the write): a MOVE; beside it the convolution state, the last
@@ -17,11 +18,10 @@ A row is one token at position ``t``.  What the ALGORITHM needs of it:
   returns for its key, the write, what it returns for its query) and ``2 x
   taps`` a packed lane.
 
-A row that carries no request reads its one trash page a full layer, and no
-state.
+A row that carries no request needs nothing.
 """
 
-from benchmarks.lib import arith_window
+from benchmarks.lib.arith_window import full_rows  # noqa: F401  the full layers' pages
 
 
 def olmo_hybrid_weights(kw):
@@ -50,14 +50,6 @@ def state_bytes(kw):
     """Bytes of ONE delta layer's state a slot: float32."""
     return (kw["linear_heads"] * kw["linear_key_head_dim"]
             * kw["linear_value_head_dim"] * 4)
-
-
-def full_rows(positions, idle_rows, layers, block, kw, itemsize=2):
-    """(operations, bytes) of the ``layers`` full layers' attention: the live
-    rows at ``positions`` and ``idle_rows`` rows of one page each."""
-    return arith_window.stack(positions, idle_rows, {None: layers}, block,
-                              kw["n_kv_head"] * kw["head_dim"], kw["n_head"],
-                              kw["head_dim"], itemsize)
 
 
 def delta_rows(tokens, state_moves, layers, kw, itemsize=2):

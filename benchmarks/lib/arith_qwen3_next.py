@@ -8,8 +8,8 @@ A row is one token at position ``t``.  What the ALGORITHM needs of it:
 
 * a FULL layer: the pages that hold the keys ``0 .. t``, of K and of V, each
   K/V head's keys once; the rows of a prompt chunk are ONE sequence's and
-  need each page once for all of them (``arith_trinity.attention``: what the
-  kernel's packed rows read), a row that carries no request nothing;
+  need each page once for all of them (``arith_window.full_rows``), a row
+  that carries no request nothing;
 * a DELTA layer: the state ``[value heads, key_dim, value_dim]`` float32 read
   and written once a decode row, and once a prompt CHUNK (its tokens share
   the read and the write): a MOVE; beside it the convolution state, the last
@@ -21,7 +21,8 @@ A row is one token at position ``t``.  What the ALGORITHM needs of it:
   assignments.
 """
 
-from benchmarks.lib import arith_moe, arith_trinity
+from benchmarks.lib import arith_moe
+from benchmarks.lib.arith_window import full_rows  # noqa: F401  the full layer's pages
 from benchmarks.lib.arith_olmo_hybrid import state_bytes  # a VALUE head's [dk, dv] float32, all heads
 
 
@@ -59,15 +60,6 @@ def qwen3_next_weights(kw):
             "bank": {"layers": len(kw["layer_types"]), "experts": kw["num_experts"],
                      "held": held, "top_k": kw["top_k"], "hidden": E,
                      "width": kw["moe_intermediate_size"]}}
-
-
-def full_rows(decode, chunks, layers, block, kw, itemsize=2):
-    """(operations, bytes) of the ``layers`` full layers' attention: the
-    decode rows at the positions ``decode``, the prompt chunks ``(first,
-    tokens)`` each one sequence's queries."""
-    return arith_trinity.attention(
-        decode, chunks, {None: layers}, block, kw["n_kv_head"] * kw["head_dim"],
-        kw["n_head"], kw["head_dim"], itemsize)
 
 
 def delta_rows(tokens, state_moves, layers, kw, itemsize=2):

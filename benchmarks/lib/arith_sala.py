@@ -13,14 +13,19 @@ attends).  What the ALGORITHM needs of it:
   block is a page).  Of K and of V, each ``block x head_dim``; the ``g``
   query heads of a K/V head share the read.  And for the selection the
   compressed keys that end at or before ``t``, once: ``(t + 1) // stride -
-  1`` of ``head_dim`` (none where every key is attended anyway);
+  1`` of ``head_dim`` (none where every key is attended anyway).  The rows of
+  a prompt CHUNK are one sequence's: each chooses its own pages, but no form
+  of the attention must read a page more than once for all of them, nor more
+  than the sequence's pages up to the chunk's end (ONE masked pass over the
+  chunk's context reads exactly those), so the chunk's bytes are the smaller
+  of its rows' chosen pages summed and those; its rows share one read of the
+  compressed keys too, the last row's.  Operations: each row's own pages;
 * a LINEAR layer: the state ``[heads, head_dim, head_dim]`` float32 read and
   written once a decode row, and once a prompt CHUNK (its tokens share the
   read and the write); ``4 x head_dim^2`` operations a head a token (the
   update ``k^T v`` and the read ``q S``).
 
-A row that carries no request reads its one trash page a sparse layer and
-head, and no state.
+A row that carries no request needs nothing.
 """
 
 import numpy as np
@@ -66,19 +71,28 @@ def compressed_keys_read(positions, sp=SPARSE):
     return np.where(t + 1 <= sp["dense_len"], 0, np.maximum((t + 1) // sp["stride"] - 1, 0))
 
 
-def sparse_rows(positions, idle_rows, layers, heads, kv_heads, head_dim,
+def sparse_rows(decode, chunks, layers, heads, kv_heads, head_dim,
                 sp=SPARSE, itemsize=2):
     """(operations, bytes) of the attention over the chosen pages, all
-    ``layers`` sparse layers: the live rows at ``positions`` and ``idle_rows``
-    rows of one page each; and the bytes of compressed keys the selection
-    reads beside it."""
-    pages = int(pages_attended(positions, sp).sum()) + int(idle_rows)
-    rows = len(positions) + int(idle_rows)
-    keys = pages * sp["block"]
-    nbytes = (2 * keys * kv_heads * head_dim + 2 * rows * heads * head_dim) * itemsize
-    flops = 2 * 2 * keys * heads * head_dim
-    compressed = int(compressed_keys_read(positions, sp).sum()) * kv_heads * head_dim * itemsize
-    return layers * flops, layers * nbytes, layers * compressed
+    ``layers`` sparse layers: the decode rows at the positions ``decode``,
+    the prompt chunks ``(first, tokens)`` each one sequence's queries (their
+    pages once a chunk, no more than the sequence's up to the chunk's end);
+    and the bytes of compressed keys the selection reads beside it."""
+    decode = np.asarray(decode, np.int64)
+    attended = read = int(pages_attended(decode, sp).sum())
+    compressed = int(compressed_keys_read(decode, sp).sum())
+    rows = len(decode)
+    for first, n in chunks:
+        own = int(pages_attended(first + np.arange(n), sp).sum())
+        attended += own
+        read += min(own, (first + n - 1) // sp["block"] + 1)
+        compressed += int(compressed_keys_read([first + n - 1], sp)[0])
+        rows += n
+    nbytes = (2 * read * sp["block"] * kv_heads * head_dim
+              + 2 * rows * heads * head_dim) * itemsize
+    flops = 2 * 2 * attended * sp["block"] * heads * head_dim
+    return (layers * flops, layers * nbytes,
+            layers * compressed * kv_heads * head_dim * itemsize)
 
 
 def linear_rows(tokens, state_moves, layers, heads, head_dim):

@@ -12,20 +12,12 @@ chooses among (256) and ``held`` the experts here (16): ``step_work`` gives a
 bank that holds a share that share of the expected reach and of the
 assignments.
 
-And the cache's reads of this cell (:func:`attention`), where
-``arith_window.stack`` counts every row a single query: a prompt chunk is ``n``
-consecutive queries of ONE sequence, and what the algorithm needs of a layer
-is each page that holds a key one of them sees ONCE for all of them, not once
-a query (at a chunk of 512 deep in a prompt of 30,000 the row-a-token count
-asks for 512 times the pages; the kernel packs the chunk's queries and reads
-a key once a packed row).  A row that carries no request needs nothing: 512
-of a decode-only step's 544 rows.  Both make the count smaller, so the
-kernel's share of its roofline and ``step_mfu_pct.gen`` err low, never high.
+The cache's reads of this cell are every resident kind's
+(``arith_window.attention``, which came from this file: a chunk's pages once
+a chunk, a row without a request nothing).
 """
 
-import numpy as np
-
-from benchmarks.lib import arith_moe, arith_window
+from benchmarks.lib import arith_moe
 
 
 def attention_params(kw):
@@ -53,32 +45,3 @@ def trinity_weights(kw):
             "bank": {"layers": L - lead, "experts": kw["num_experts"], "held": held,
                      "top_k": kw["top_k"], "hidden": E,
                      "width": kw["moe_intermediate_size"]}}
-
-
-def chunk_rows(first, n, block, lanes, heads, head_dim, window=None, itemsize=2):
-    """(operations, bytes) of one layer's attention over a prompt chunk: the
-    queries at ``first .. first + n - 1`` of one sequence.  Bytes: the pages
-    from the first one the FIRST query sees to the one that holds the last
-    query's key, K and V, once; the queries read and the outputs written.
-    Operations: each query's products over the pages IT sees, as
-    ``arith_window.rows`` counts a row's."""
-    pages = arith_window.pages_seen(first + np.arange(n), block, window)
-    oldest = 0 if window is None else max(first - window + 1, 0) // block
-    span = (first + n - 1) // block + 1 - oldest
-    nbytes = (2 * span * block * lanes + 2 * n * heads * head_dim) * itemsize
-    return 2 * 2 * int(pages.sum()) * block * heads * head_dim, nbytes
-
-
-def attention(decode, chunks, layers_by_window, block, lanes, heads, head_dim,
-              itemsize=2):
-    """(operations, bytes) over the stack: ``decode`` the positions of the
-    single-query rows, ``chunks`` the prompt chunks run as ``(first, n)``,
-    ``layers_by_window`` a window (None: full) -> the layers of that kind."""
-    flops = nbytes = 0
-    for window, layers in layers_by_window.items():
-        f, b = arith_window.rows(decode, block, lanes, heads, head_dim, window, itemsize)
-        for first, n in chunks:
-            cf, cb = chunk_rows(first, n, block, lanes, heads, head_dim, window, itemsize)
-            f, b = f + cf, b + cb
-        flops, nbytes = flops + layers * f, nbytes + layers * b
-    return flops, nbytes

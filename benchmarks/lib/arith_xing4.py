@@ -13,9 +13,8 @@ LATENT ATTENTION.  A row is one query at position ``t``; each of its
 ((latent + rope) + latent)`` operations a (query, key) pair (69,632 at 32
 heads of 512 + 64).  A decode row reads its keys once; the rows of a prompt
 chunk are ONE sequence's and no form of the attention must read that
-sequence's vectors more than once for all of them (``arith_mla.py`` counts a
-read a row, which is right where a step is decode rows: under a chunk in
-nine steps of ten it would count a chunk's keys 512 times).  The arena's
+sequence's vectors more than once for all of them (``arith_mla.py`` takes
+the reads and the pairs apart for that).  The arena's
 padding of the vector to whole lane tiles (576 numbers in 640 lanes) is the
 layout's and is not counted.
 
@@ -28,7 +27,7 @@ themselves (``2n + n n`` numbers a token) are registers' or VMEM's.
 
 import numpy as np
 
-from benchmarks.lib import arith_moe
+from benchmarks.lib import arith_mla, arith_moe
 
 
 def mix_params(kw):
@@ -71,23 +70,27 @@ def xing4_weights(kw):
                      "width": kw["moe_intermediate_size"]}}
 
 
-def latent_rows(decode_positions, chunks, layers, heads, latent, rope, itemsize=2):
-    """(operations, bytes) of latent attention over every cached key, all
-    ``layers`` layers: a decode row at each of ``decode_positions`` (keys
-    ``0 .. t``, read once a row), each of ``chunks`` (first position, tokens)
-    its pairs' operations and its sequence's vectors read ONCE; a row's query
-    read and its output written, ``latent + rope`` and ``latent`` a head."""
+def latent_keys(decode_positions, chunks, layers):
+    """(cached vectors read, (query, key) pairs) over all ``layers`` layers,
+    to the key: a decode row at each of ``decode_positions`` reads the keys
+    ``0 .. t`` once, each of ``chunks`` (first position, tokens) its
+    sequence's vectors ONCE for all its queries' pairs."""
     decode_positions = np.asarray(decode_positions, np.int64)
-    pairs = int((decode_positions + 1).sum())
-    read = pairs
-    rows = len(decode_positions)
+    pairs = read = int((decode_positions + 1).sum())
     for first, n in chunks:
         pairs += int((first + np.arange(n) + 1).sum())
         read += first + n
-        rows += n
-    vector = latent + rope
-    return (layers * 2 * heads * (vector + latent) * pairs,
-            layers * (read * vector + rows * heads * (vector + latent)) * itemsize)
+    return layers * read, layers * pairs
+
+
+def latent_rows(decode_positions, chunks, layers, heads, latent, rope, itemsize=2):
+    """(operations, bytes) of latent attention over every cached key, all
+    ``layers`` layers: :func:`latent_keys`' reads and pairs at
+    ``arith_mla.latent_attention``'s cost of each; a row's query read and its
+    output written, ``latent + rope`` and ``latent`` a head."""
+    read, pairs = latent_keys(decode_positions, chunks, layers)
+    rows = layers * (len(decode_positions) + sum(n for _, n in chunks))
+    return arith_mla.latent_attention(read, pairs, rows, heads, latent, rope, itemsize)
 
 
 def mix_bytes(rows, steps, layers, streams, hidden, itemsize=2):

@@ -1,7 +1,6 @@
 """What a traffic kind shares with the others that CALL ``serve-backlog-
-resident`` for one stack (``kinds/serve_backlog_resident_{hybrid, delta,
-afmoe, indexed}.py`` each carry these lines as a copy of their own, and only a
-``benchmark`` PR may fold them in here): that module's names replaced for the
+resident`` for one stack (every ``kinds/serve_backlog_resident_*.py`` since
+PR 68 folded the older kinds' copies in here): that module's names replaced for the
 length of one ``resident.run`` (the stack's count of its caches' work in the
 place of ``attention_counters``, and whatever else the kind hands in), and
 the run's sample judged again under the kind's own two limits.  A new stack

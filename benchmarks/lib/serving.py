@@ -65,6 +65,11 @@ class Serving:
             lanes = mcfg.kv_heads * mcfg.head_dim
             per_block = 2 * mcfg.n_layer * block * lanes * dtype.itemsize
             serving["num_blocks"] = int(serve["arena_bytes"]) // per_block
+            # the queue admits the traffic's whole backlog (the program's
+            # default bound where the backlog is no deeper)
+            serving.setdefault("max_queue", max(
+                DeepSpeedServingConfig().max_queue,
+                int(cell.traffic.get("backlog_requests", 0))))
             self.engine = deepspeed_tpu.init_serving(
                 model=self.model, params=self.params, config={"serving": serving})
         self.slots = int(serving["max_batch_size"])

@@ -1,10 +1,14 @@
-"""Readers for a Trinity (``afmoe``) cell: an expert bank that holds a SHARE
-of the experts its router chooses among, behind a dense lead, so that the
+"""Readers for ANY expert bank, by what its configuration's ``step_work``
+function says of it (first Trinity's, ``afmoe``: a bank that holds a SHARE of
+the experts its router chooses among, behind a dense lead, so that the
 configuration's ``num_experts`` (the experts HELD) and ``num_hidden_layers``
 (dense lead included) are not what ``readers/moe.py:bank_least_seconds`` and
-``readers/zaya.py`` take them for.  The counts here come from the file's
-``step_work`` function (``lib/arith_trinity.py``: the expert layers, the
-router's width, the experts held).  A run without a trace, or a program
+``readers/zaya.py`` take them for).  The counts here come from the file's
+``step_work`` function (``{"layers", "experts", "held", "top_k", "hidden",
+"width"}``: the expert layers, the router's width, the experts held), so a
+whole bank in every layer (OLMoE) reads what ``readers/moe.py`` read of it to
+the digit, and ``grouped_matmul_roofline`` is ONE entry for every cell whose
+program runs the kernel.  A run without a trace, or a program
 whose spans carry no such stat (a parent commit), gives None and the metric
 is left out of the line."""
 

@@ -65,8 +65,9 @@ def delta_state_update_roofline(run):
     return 100.0 * least[0] / took
 
 
-def state_moves_per_step(run):
+def state_moves_per_step(run, stat=STAT):
     """Mean over the stretch's steps of the stat ``delta_state_moves`` of
-    ``serve.stats``: what the program says it moved, a live decode row a
-    delta layer and one a layer for the step's chunk."""
-    return turnaround.stat_mean_ms(run, STAT)
+    ``serve.stats`` (``stat``: a mamba stack's ``mamba_state_moves``): what
+    the program says it moved, a live decode row a layer that keeps a state
+    and one a layer for the step's chunk."""
+    return turnaround.stat_mean_ms(run, stat)

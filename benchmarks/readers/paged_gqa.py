@@ -1,9 +1,13 @@
 """Reader for the paged kernel over grouped K/V heads with a window
 (``paged_gqa_attention`` of ``ops/pallas/decode_attention.py``): its share of
-its roofline.  The operations and bytes are the kind's count of what the
-program ran (``kinds/serve_backlog_resident.py:attention_counters``, from
-``lib/arith_window.py``: every row, a window layer at the pages it can see),
-left in the run's counters.  A program without the kernel (a parent commit,
+its roofline.  The operations and bytes are the kind's count of the LEAST
+the algorithm needs for what the program ran
+(``kinds/serve_backlog_resident.py:attention_counters`` over
+``lib/arith_window.py``: a decode row a single query at its position, a
+prompt chunk's pages ONCE for all its queries, a window layer at the pages it
+can see, a row without a request nothing; the same count in every kind that
+leaves pages under these names, so one entry lists SmallThinker's, Trinity's
+and Qwen3-Next's cells), left in the run's counters.  A program without the kernel (a parent commit,
 a multi-head model) gives nothing to read: None, and the metric is left out
 of the line."""
 

@@ -32,12 +32,10 @@ import os
 import numpy as np
 
 from benchmarks.lib.cells import ROOT
-from benchmarks.lib.trace import (OPS_LINE, length, newest_xplane, self_times,
-                                  subtract, union)
+from benchmarks.lib.trace import OPS_LINE, length, newest_xplane, self_times, union
 
 TRACE_DIR = os.path.join(ROOT, ".bench_trace")
 STEP_SPAN = "bench.engine_step"      # the benchmark's span round engine.step()
-PROGRAM_PREFIX = "serve."            # what the program's spans start with
 FIRST_TOKEN = "serve.first_token"    # zero length, one a request
 SCOPE_STAT = "tf_op"                 # the XLA Ops stat that holds the name stack
 # the scopes the program opens (models/gpt.py, runtime/engine.py), most
@@ -47,28 +45,6 @@ PROGRAM_SCOPES = ("optimizer", "cross_entropy", "head", "attn", "mlp", "embed",
 
 
 # ---- host spans -------------------------------------------------------------- #
-def program_spans(trace):
-    """[(name, start, end)] of the program's spans on the benchmark's thread,
-    those of no length left out."""
-    return [(n, s, e) for n, s, e in trace.host_events
-            if n.startswith(PROGRAM_PREFIX) and e > s]
-
-
-def innermost(spans):
-    """[(name, start, end)], disjoint: each span's interval less the spans
-    nested inside it, so that a moment belongs to the innermost span over it."""
-    order = sorted(spans, key=lambda x: (x[1], -x[2]))
-    out = []
-    for i, (name, s, e) in enumerate(order):
-        inner = []
-        for _, s2, e2 in order[i + 1:]:
-            if s2 >= e:
-                break
-            inner.append((s2, min(e2, e)))
-        out += [(name, a, b) for a, b in subtract([[s, e]], union(inner))]
-    return out
-
-
 def span_ms_per_step(run, spans):
     """Summed duration of the named program spans over the number of
     ``engine.step()`` calls the benchmark made in the traced stretch."""
@@ -78,45 +54,6 @@ def span_ms_per_step(run, spans):
     steps = sum(1 for n, _, _ in t.host_spans if n == STEP_SPAN)
     found = [e - s for n, s, e in t.host_events if n in spans]
     return 1e3 * sum(found) / steps if steps and found else None
-
-
-def idle_by_span(trace):
-    """{span name: seconds the first chip was idle under it} with the idle
-    under no program span at ``"none"``, or None where the program wrote no
-    span.  A gap is cut at span borders, and a moment belongs to the
-    innermost span over it, so the values partition the chip's idle time."""
-    pieces = innermost(program_spans(trace))
-    if not pieces:
-        return None
-    gaps = trace.devices[0].gaps()
-    under = lambda cover: length(gaps) - length(subtract(gaps, union(cover)))
-    out = {name: under([(s, e) for n, s, e in pieces if n == name])
-           for name in {n for n, _, _ in pieces}}
-    out["none"] = length(gaps) - under([(s, e) for _, s, e in pieces])
-    return out
-
-
-def idle_under_pct(run, spans, invert=False):
-    """Share of the first chip's window in which it was idle while the host
-    was inside one of the named program spans (``invert``: inside any
-    program span but those; ``spans`` empty and not ``invert``: inside none
-    at all).  The notes get the whole table, by span."""
-    t = run["trace"]
-    if t is None:
-        return None
-    if "_idle_by_span" not in run:
-        run["_idle_by_span"] = idle_by_span(t)
-    by = run["_idle_by_span"]
-    if by is None:
-        return None
-    window = t.devices[0].window_s
-    run["notes"].setdefault("idle_by_span_pct", {
-        k: round(100.0 * v / window, 4) for k, v in sorted(by.items(), key=lambda kv: -kv[1])})
-    if spans or invert:
-        seconds = sum(v for k, v in by.items() if k != "none" and (k in spans) != invert)
-    else:
-        seconds = by["none"]
-    return 100.0 * seconds / window
 
 
 # ---- what needs the events' stats -------------------------------------------- #

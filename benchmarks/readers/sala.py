@@ -10,8 +10,10 @@ KERNEL = "paged_sparse_attention"
 
 def roofline(run):
     """The least time for the operations and bytes the kernel needed over
-    the traced stretch (the pages the live rows chose, of K and of V) over
-    its time there."""
+    the traced stretch (the pages the decode rows chose, and a prompt chunk's
+    chosen pages ONCE a chunk, no more than its sequence's pages up to its
+    end: what one masked pass over the chunk's context reads; of K and of V)
+    over its time there."""
     t, c = run["trace"], run["counters"]
     if t is None or "paged_sparse_bytes" not in c:
         return None

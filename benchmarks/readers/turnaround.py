@@ -6,8 +6,9 @@ subtracted, mean from mean over the same steps; no event of the host's line is
 ever placed against an event of the device's, so the offset at which the
 profiler lays the two lines (a millisecond and more, either way, from run to
 run: PERF.md § 6, PR 36) moves nothing here.  That offset itself is read into
-the notes, ``host_device_skew_ms``: it is the least error of every metric that
-does overlay the lines (``program_spans.idle_under_pct``).
+the notes, ``host_device_skew_ms``: it is the least error of anything that
+does overlay the lines (the three ``idle_*_pct`` metrics did, and went with
+PR 68).
 
 What a TPU trace holds of it: the plane ``/device:TPU:<n>`` has a line ``XLA
 Modules`` with one event a program run; a serve step runs one program, so the

@@ -1,4 +1,4 @@
-"""Reader for a ZAYA1 cell: the share of the stack's experts a step's live
+"""Reader for a routed stack (first ZAYA1's cell): the share of the stack's experts a step's live
 rows reach, from what the engine says of its routing on the span of a step's
 commit (``serve.decode.commit``'s stat ``moe_experts_touched``: the experts
 with an assignment, counted a LAYER, which is how ``ServingEngine`` counts
@@ -11,7 +11,7 @@ is left out of the line."""
 
 import numpy as np
 
-from benchmarks.readers import moe
+from benchmarks.readers import afmoe, moe
 
 STAT = "moe_experts_touched"
 
@@ -19,7 +19,12 @@ STAT = "moe_experts_touched"
 def experts_reached_pct(run):
     """Mean over the traced stretch's decode steps of the (layer, expert)
     pairs with a live assignment, over ``num_experts x num_hidden_layers`` of
-    the configuration file."""
+    the configuration file.  A configuration whose engine counts the SUM over
+    layers (a periodic stack: its file says ``"experts_touched":
+    "summed_over_layers"``) is read by ``readers/afmoe.py``, which inverts
+    the sum: the same share, of an expert layer's experts a step."""
+    if run["cell"].config.get("experts_touched") == "summed_over_layers":
+        return afmoe.experts_reached_pct(run)
     stats = moe.span_stats(run) or {}
     values = [s[STAT] for s in stats.get(moe.LOAD_SPAN, []) if STAT in s]
     if not values:

@@ -284,6 +284,141 @@ def test_every_metric_file_is_listed_and_every_config_used():
     assert len(four) <= max(1, len(BENCH["workloads"]) // 4)
 
 
+# ---- one entry a scope and a kernel (PR 68) ------------------------------------ #
+TRINITY, ZAYA, KEYE, QWEN, OLMO, MISTRAL = (
+    "trinity-large-preview.serve-mixed-lengths", "zaya1-8b.serve-reasoning-resident",
+    "keye-vl-2.0-30b-a3b.serve-long-indexed", "qwen3-next-80b-a3b.serve-long-delta-moe",
+    "olmo-hybrid-7b.serve-chat-resident", "mistral-small-4-119b.serve-reasoning-batch")
+# the entry PR 68 folded or renamed -> (the entry that reads the same thing, the
+# cells the old one listed): the ledger shows ``null`` under each old name
+FOLDED = {
+    "afmoe_attn_window_share_pct.gen": ("attn_window_share_pct.gen", [TRINITY]),
+    "afmoe_attn_full_share_pct.gen": ("attn_full_share_pct.gen", [TRINITY]),
+    "afmoe_attn_gate_share_pct.gen": ("attn_gate_share_pct.gen", [TRINITY]),
+    "afmoe_lead_mlp_share_pct.gen": ("lead_mlp_share_pct.gen", [TRINITY]),
+    "afmoe_bank_share_pct.gen": ("moe_experts_share_pct.gen", [TRINITY]),
+    "afmoe_shared_expert_share_pct.gen": ("moe_shared_expert_share_pct.gen", [TRINITY]),
+    "afmoe_head_share_pct.gen": ("lm_head_share_pct.gen", [TRINITY]),
+    "afmoe_assignments_held_pct.gen": ("moe_assignments_held_pct.gen", [TRINITY]),
+    "afmoe_experts_reached_pct.gen": ("experts_reached_pct.gen", [TRINITY]),
+    "afmoe_kv_window_freed_pct.gen": ("kv_window_freed_pct.gen", [TRINITY]),
+    "afmoe_paged_gqa_roofline": ("paged_gqa_attention_roofline", [TRINITY]),
+    "afmoe_grouped_matmul_roofline": ("grouped_matmul_roofline", [TRINITY]),
+    "expert_bank_share_pct.gen": ("moe_experts_share_pct.gen", [ZAYA]),
+    "softmax_bank_experts_share_pct.gen": ("moe_experts_share_pct.gen", [KEYE, QWEN]),
+    "moe_held_share_pct.gen": ("moe_share_pct.gen", [MISTRAL]),
+    "softmax_bank_share_pct.gen": ("moe_share_pct.gen", [KEYE, QWEN]),
+    "router_mlp_share_pct.gen": ("moe_router_share_pct.gen", [ZAYA]),
+    "softmax_router_share_pct.gen": ("moe_router_share_pct.gen", [KEYE, QWEN]),
+    "dense_mlp_share_pct.gen": ("mlp_share_pct.gen", [OLMO]),
+    "attn_mha_share_pct.gen": ("attn_full_share_pct.gen", [OLMO]),
+    "mla_attn_share_pct.gen": ("attn_share_pct.gen", [MISTRAL]),
+    "held_bank_copy_share_pct.gen": ("moe_bank_copy_share_pct.gen", [MISTRAL]),
+    "softmax_bank_reached_pct.gen": ("experts_reached_pct.gen", [KEYE, QWEN]),
+}
+RETIRED = [f"idle_{what}_pct.{suffix}" for what in ("host_work", "fetch", "unnamed")
+           for suffix in ("gen", "tpot")]
+LISTED = {m["name"]: m for m in BENCH["per_layer"]}
+
+
+@pytest.mark.parametrize("old", sorted(FOLDED))
+def test_a_folded_entry_is_read_by_the_entry_that_reads_the_same_thing(old):
+    """The old name is gone, list and file; every cell it listed is listed by
+    the entry it was folded into, whose reader resolves for that cell."""
+    new, listed_it = FOLDED[old]
+    assert old not in LISTED
+    assert not os.path.exists(os.path.join(ROOT, "benchmarks/metrics", old + ".json"))
+    assert set(listed_it) <= set(LISTED[new]["workloads"])
+    for cell in listed_it:
+        fn, args = cells.Cell(cell).reader(new)
+        assert callable(fn) and isinstance(args, dict)
+
+
+@pytest.mark.parametrize("old", RETIRED)
+def test_the_overlays_three_are_retired_and_their_sum_stays(old):
+    """``idle_host_work_pct``, ``idle_fetch_pct`` and ``idle_unnamed_pct``
+    were good to the profiler's offset only (PERF.md § 7 since PR 36); their
+    sum is ``device_idle_pct``, which every cell that listed them lists."""
+    assert old not in LISTED
+    assert not os.path.exists(os.path.join(ROOT, "benchmarks/metrics", old + ".json"))
+    idle = LISTED["device_idle_pct." + old.rsplit(".", 1)[1]]
+    assert idle["source"] == "device_trace" and len(idle["workloads"]) >= 1
+
+
+def test_one_entry_a_reader_its_arguments_and_the_metric_it_moves():
+    """No two entries that move the same end-to-end metric name the same
+    reader with the same arguments: a new cell APPENDS itself to the entry
+    that reads it.  No name carries a model family's prefix, the list holds
+    what the contract lets it, and the traced line's notes hold no layer's
+    metrics (``grep`` over the kinds)."""
+    seen = {}
+    for m in BENCH["per_layer"]:
+        spec = cells.load_json(os.path.join(ROOT, "benchmarks/metrics", m["name"] + ".json"))
+        key = (m["moves"], spec["reader"], json.dumps(spec.get("args", {}), sort_keys=True))
+        assert key not in seen, (m["name"], seen.get(key))
+        seen[key] = m["name"]
+        assert not m["name"].startswith(("afmoe_", "softmax_", "mamba_attn", "mamba_mlp",
+                                         "mamba_head"))
+    assert len(BENCH["per_layer"]) <= 128
+    kinds = os.path.join(ROOT, "benchmarks/kinds")
+    for name in os.listdir(kinds):
+        if name.endswith(".py"):
+            text = open(os.path.join(kinds, name)).read()
+            assert "_layers\"]" not in text and "read_right_and_not_listed" not in text, name
+            assert "def layer_notes" not in text and "PINNED_ELSEWHERE" not in text, name
+
+
+BACKLOG = [w["name"] for w in BENCH["workloads"] if ".serve-" in w["name"]
+           and w["name"] != "gpt2-124m.serve-chat-steady"]
+
+
+@pytest.mark.parametrize("name", ["compiles_in_window.gen", "serve_step_ms.gen",
+                                  "decode_batch_mean.gen", "kv_blocks_peak_pct.gen",
+                                  "preemptions.gen", "device_idle_pct.gen", "sched_host_ms.gen",
+                                  "table_build_ms.gen", "host_turnaround_ms.gen",
+                                  "step_outside_ms.gen", "idle_wire_ms.gen", "step_mfu_pct.gen",
+                                  "program_ms.gen", "chunk_program_time_pct.gen",
+                                  "dispatched_ahead_pct.gen", "host_occupancy_pct.gen"])
+def test_an_entry_meant_for_every_backlog_serve_cell_lists_them_all(name):
+    assert set(LISTED[name]["workloads"]) == set(BACKLOG)
+
+
+# every kernel a serve cell's program runs that has arithmetic under
+# ``benchmarks/lib/``: kernel -> its ONE listed roofline
+KERNEL_ROOFLINES = {"grouped_matmul": "grouped_matmul_roofline",
+                    "paged_mla_attention": "paged_mla_attention_roofline",
+                    "paged_sparse_attention": "paged_sparse_attention_roofline",
+                    "mamba_state_update": "mamba_state_update_roofline",
+                    "mamba_chunk_scan": "mamba_chunk_scan_roofline",
+                    "delta_state_update": "delta_state_update_roofline"}
+# the kernels each cell's traced run held (ledger, PR 67: ``breakdown.device_ops``)
+RUNS = {
+    "olmoe-1b-7b.serve-decode-heavy": ["grouped_matmul"],
+    "smallthinker-21b-a3b.serve-long-context": ["grouped_matmul"],
+    MISTRAL: ["grouped_matmul", "paged_mla_attention"],
+    "minicpm-sala-9b.serve-long-mixed": ["paged_sparse_attention"],
+    ZAYA: ["grouped_matmul"], OLMO: ["delta_state_update"], KEYE: ["grouped_matmul"],
+    TRINITY: ["grouped_matmul"],
+    "jamba2-3b.serve-chat-packed": ["mamba_state_update", "mamba_chunk_scan"],
+    "deepseek-v3.2-exp.serve-long-latent-indexed": ["grouped_matmul"],
+    QWEN: ["grouped_matmul", "delta_state_update"],
+    "xing4.0-29b-a4b.serve-prompt-heavy": ["grouped_matmul", "paged_mla_attention"],
+}
+
+
+@pytest.mark.parametrize("cell", sorted(RUNS))
+def test_every_kernel_a_cell_runs_has_one_roofline_that_lists_the_cell(cell):
+    for kernel in RUNS[cell]:
+        entry = LISTED[KERNEL_ROOFLINES[kernel]]
+        assert cell in entry["workloads"], (kernel, cell)
+        assert (entry["unit"], entry["better"], entry["source"]) == ("%", "higher", "device_trace")
+    # and the paged K/V kernel's, where the kind leaves its pages ALONE under
+    # ``paged_gqa_*`` (Olmo-Hybrid's and Jamba2's leave all the caches' work
+    # there, for ``step_mfu_pct``: PERF.md § 7)
+    for name in ("smallthinker-21b-a3b.serve-long-context", TRINITY, QWEN, ZAYA):
+        assert name in LISTED["paged_gqa_attention_roofline"]["workloads"]
+
+
 def test_a_later_pr_adds_one_of_each_without_editing_a_file(tmp_path):
     """A configuration, a traffic mix, a cell and a per-layer metric, each as
     a new file or a new entry, in a copy of the benchmark."""

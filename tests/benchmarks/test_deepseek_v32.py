@@ -28,6 +28,14 @@ JOINED = ("attn_indexed_share_pct.gen", "index_score_share_pct.gen",
           "index_topk_share_pct.gen", "index_attend_share_pct.gen",
           "indexed_keys_read_pct.gen", "index_score_roofline",
           "indexed_attention_roofline", "lm_head_share_pct.gen")
+# the cell's own, listed since PR 68 (PR 61 left them in
+# ``notes.deepseek_layers``): entry -> the scope it reads
+SCOPES = {"latent_project_share_pct.gen": "latent_project",
+          "route_groups_share_pct.gen": "route_groups", "lead_mlp_share_pct.gen": "lead_mlp",
+          "moe_share_pct.gen": "moe", "moe_router_share_pct.gen": "moe_router",
+          "moe_experts_share_pct.gen": "moe_experts",
+          "moe_shared_expert_share_pct.gen": "moe_shared"}
+OWN = set(SCOPES) | {"moe_assignments_held_pct.gen", "grouped_matmul_roofline"}
 
 
 def test_the_configuration_is_the_catalogs_but_for_its_share():
@@ -187,7 +195,7 @@ def test_a_rows_work_by_hand():
                                                            "topk": 2048}
 
 
-def test_attention_counters_take_every_row_at_its_own_position():
+def test_attention_counters_take_a_decode_row_at_its_position_and_a_chunk_once():
     """Over a stretch of 3 steps: one request decodes 3 tokens from 30,000
     keys, another runs two chunks of its prompt from 1,024; 12 + 512 rows a
     program."""
@@ -221,30 +229,27 @@ def test_the_cell_joins_the_metrics_that_read_its_scopes_and_counters():
     cell = cells.Cell(CELL)
     listed = {m["name"]: m for m in cell.per_layer}
     entry = next(w for w in bench["workloads"] if w["name"] == CELL)
-    assert entry == bench["workloads"][-1] and entry["chips"] == 1
-    assert entry["config"] == bench["configs"][-1]["name"] == "deepseek-v3.2-exp"
-    assert bench["configs"][-1]["reduced"] == REDUCED
-    assert len(entry["why"]) <= 200 and len(bench["configs"][-1]["why"]) <= 200
+    config = next(c for c in bench["configs"] if c["name"] == entry["config"])
+    assert entry["chips"] == 1 and config["name"] == "deepseek-v3.2-exp"
+    assert config["reduced"] == REDUCED
+    assert len(entry["why"]) <= 200 and len(config["why"]) <= 200
     assert "1/16" in entry["why"] and "share" in entry["why"]
     assert cell.kind is kind and [m["name"] for m in cell.end_to_end] == [
         "serve_tokens_per_s", "setup_s"]
-    # Keye's seven and the head's share, behind Keye's cell in each list
-    for name in JOINED:
-        assert listed[name]["workloads"] == [KEYE, CELL]
+    # Keye's seven and the head's share, with Keye's cell in each list; and
+    # the cell's own
+    for name in JOINED + tuple(OWN):
+        assert {KEYE, CELL} <= set(listed[name]["workloads"]) or name in OWN
+        assert CELL in listed[name]["workloads"]
+        assert listed[name]["moves"] == "serve_tokens_per_s"
         fn, args = cell.reader(name)
         assert callable(fn) and isinstance(args, dict)
-    # the nineteen every resident serve cell reports, as Jamba2's cell is
-    jamba = {m["name"] for m in cells.Cell("jamba2-3b.serve-chat-packed").per_layer
-             if CELL in m.get("workloads", [])}
-    assert len(jamba) == 19 and jamba <= set(listed)
+    # what every resident serve cell reports, as Jamba2's cell does
+    both = {m["name"] for m in cells.Cell("jamba2-3b.serve-chat-packed").per_layer
+            if CELL in m.get("workloads", [])}
     assert {"step_mfu_pct.gen", "program_ms.gen", "chunk_program_time_pct.gen",
-            "device_idle_pct.gen"} <= jamba
-    assert len(listed) == 19 + len(JOINED)
-    # the list is full and no metric was added; Mistral's four stay Mistral's
-    assert len(bench["per_layer"]) == 128
-    for name in ("mla_attn_share_pct.gen", "moe_held_share_pct.gen",
-                 "moe_shared_expert_share_pct.gen", "moe_assignments_held_pct.gen"):
-        assert name not in listed
+            "device_idle_pct.gen", "host_occupancy_pct.gen"} <= both <= set(listed)
+    assert len(bench["per_layer"]) <= 128
     step_work = cell.config["step_work"]
     assert cells.resolve(step_work["weights"]) is arith_ds.deepseek_v32_weights
     assert cells.resolve(step_work["attention"])({"counters": {
@@ -257,21 +262,23 @@ def test_the_scopes_the_metrics_and_the_notes_name_are_the_programs():
     from deepspeed_tpu.moe import dropless
     cell = cells.Cell(CELL)
     source = inspect.getsource(gpt) + inspect.getsource(hybrid) + inspect.getsource(dropless)
-    for scope in kind.SCOPES:
-        assert f'"{scope}"' in source, scope
     for name, scope in (("attn_indexed_share_pct.gen", "attn_indexed"),
                         ("index_score_share_pct.gen", "index_score"),
                         ("index_topk_share_pct.gen", "index_topk"),
                         ("index_attend_share_pct.gen", "index_attend"),
-                        ("lm_head_share_pct.gen", "head")):
+                        ("lm_head_share_pct.gen", "head"), *SCOPES.items()):
         assert cell.reader(name)[1]["scopes"] == [scope]
+        assert f'"{scope}"' in source, scope
     assert cell.reader("indexed_attention_roofline")[1] == {
         "scope": "index_attend", "flops": "indexed_attend_flops",
         "nbytes": "indexed_attend_bytes"}
     assert cell.reader("index_score_roofline")[1] == {
         "scope": "index_score", "flops": "index_flops", "nbytes": "index_bytes"}
-    # without a trace the notes are empty and nothing raises
-    assert kind.layer_notes({"trace": None, "counters": {}}) == {}
+    # without a trace there is nothing to read and nothing raises
+    bare = {"trace": None, "counters": {}, "notes": {}, "cell": cell}
+    for name in OWN:
+        fn, args = cell.reader(name)
+        assert fn(bare, **args) is None, name
 
 
 def test_the_traffic_is_long_indexeds_lengths_under_twelve_slots():

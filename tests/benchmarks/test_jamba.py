@@ -18,6 +18,7 @@ import pytest
 from benchmarks.kinds import serve_backlog_resident as resident
 from benchmarks.kinds import serve_backlog_resident_mamba as kind
 from benchmarks.lib import arith_jamba, arith_step, cells
+from benchmarks.readers import jamba
 
 CELL = "jamba2-3b.serve-chat-packed"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
@@ -27,10 +28,18 @@ PARAMS = 3_029_337_472
 # what every backlog serve cell reports
 SHARED = {"compiles_in_window.gen", "serve_step_ms.gen", "decode_batch_mean.gen",
           "kv_blocks_peak_pct.gen", "preemptions.gen", "device_idle_pct.gen",
-          "sched_host_ms.gen", "table_build_ms.gen", "idle_host_work_pct.gen",
-          "idle_fetch_pct.gen", "idle_unnamed_pct.gen", "host_turnaround_ms.gen",
+          "sched_host_ms.gen", "table_build_ms.gen", "host_turnaround_ms.gen",
           "step_outside_ms.gen", "idle_wire_ms.gen", "step_mfu_pct.gen", "program_ms.gen",
           "chunk_program_time_pct.gen", "dispatched_ahead_pct.gen", "host_occupancy_pct.gen"}
+# the cell's own, listed since PR 68 (PR 57 left them in ``notes.mamba_layers``):
+# entry -> the scope it reads
+SCOPES = {"attn_mamba_share_pct.gen": "attn_mamba", "mamba_conv_share_pct.gen": "mamba_conv",
+          "mamba_params_share_pct.gen": "mamba_params", "mamba_scan_share_pct.gen": "mamba_scan",
+          "attn_full_share_pct.gen": "attn_full", "mlp_share_pct.gen": "mlp",
+          "lm_head_share_pct.gen": "head"}
+KERNELS = {"mamba_state_update_roofline": "mamba_state_update",
+           "mamba_chunk_scan_roofline": "mamba_chunk_scan"}
+OWN = set(SCOPES) | set(KERNELS) | {"mamba_state_moves_per_step.gen"}
 
 
 def test_the_configuration_is_the_catalogs_uncut():
@@ -123,7 +132,7 @@ def test_a_steps_least_work_is_the_issues_arithmetic():
     # 81,920 exponentials a token a layer: 818 M a decode step
     assert 384 * 26 * 5120 * 16 == pytest.approx(818e6, rel=1e-3)
     assert ops == 384 * 26 * (7 * 81_920 + 4 * 5120 + 8 * 5120)
-    _, pages = arith_jamba.full_rows(np.full(384, 1599), 0, 2, 64, kw)
+    _, pages = arith_jamba.full_rows(np.full(384, 1599), [], 2, 64, kw)
     # 1,600 keys are 25 pages of 64; K and V of 128 lanes, two layers; q and o beside
     assert pages == 2 * (2 * 384 * 25 * 64 * 128 * 2 + 2 * 384 * 2560 * 2)
     assert pages == pytest.approx(0.63e9, rel=0.02)
@@ -176,8 +185,8 @@ def test_the_arena_and_the_states_are_the_engines():
 def test_the_cell_its_traffic_and_its_metrics_resolve():
     cell = cells.Cell(CELL)
     listed = {m["name"]: m for m in cell.per_layer}
-    assert SHARED <= set(listed)                  # at least these
-    for name in SHARED:
+    assert SHARED | OWN <= set(listed)            # at least these
+    for name in SHARED | OWN:
         fn, args = cell.reader(name)
         assert callable(fn) and isinstance(args, dict)
         assert CELL in listed[name]["workloads"] and listed[name]["moves"] == "serve_tokens_per_s"
@@ -224,15 +233,23 @@ def test_the_traffic_is_chat_lengths_at_384_slots():
     assert 512 * 1_920 / 48 == pytest.approx(20_480)
 
 
-def test_the_scopes_and_the_kernels_the_notes_name_are_the_programs():
+def test_the_scopes_and_the_kernels_the_metrics_name_are_the_programs():
     import inspect
-    from deepspeed_tpu.models import hybrid
+    from deepspeed_tpu.models import gpt, hybrid
     from deepspeed_tpu.ops.pallas import selective_scan
-    source = inspect.getsource(hybrid)
-    for scope in kind.SCOPES:
-        assert f'jax.named_scope("{scope}")' in source
-    for kernel in kind.KERNELS:
+    cell = cells.Cell(CELL)
+    source = inspect.getsource(hybrid) + inspect.getsource(gpt)
+    for name, scope in SCOPES.items():
+        assert cell.reader(name)[1] == {"scopes": [scope]}
+        assert f'named_scope("{scope}")' in source, scope
+    for name, kernel in KERNELS.items():
+        fn, args = cell.reader(name)
+        assert fn is jamba.kernel_roofline and args["kernel"] == kernel
         assert f'name="{kernel}"' in inspect.getsource(selective_scan)
+        assert callable(cells.resolve(args["call"]))
+    from benchmarks.readers import olmo_hybrid
+    assert cell.reader("mamba_state_moves_per_step.gen") == (
+        olmo_hybrid.state_moves_per_step, {"stat": "mamba_state_moves"})
     assert '"mamba_state_moves"' in inspect.getsource(
         __import__("deepspeed_tpu.serving.engine", fromlist=["x"]))
 
@@ -263,9 +280,11 @@ def test_the_kind_counts_pages_states_and_moves_from_the_lengths():
     assert c["mamba_state_bytes_moved"] == 769 * 26 * 2 * STATE
     assert c["mamba_conv_bytes_moved"] == 769 * 26 * 2 * CONV
     assert c["attention_rows_live"] == 1068 and c["attention_rows_idle"] == 2 * 896 - 1068
-    positions = np.concatenate([np.arange(1500 + r, 1502 + r) for r in range(384)]
-                               + [np.arange(300)])
-    flops, pages = arith_jamba.full_rows(positions, 2 * 896 - 1068, 2, 64, kw)
+    decode = np.concatenate([np.arange(1500 + r, 1502 + r) for r in range(384)])
+    flops, pages = arith_jamba.full_rows(decode, [(0, 300)], 2, 64, kw)
+    # the chunk's pages 0..4 once, K and V of the one K/V head's 128 lanes
+    assert pages == 2 * (2 * (int((decode // 64 + 1).sum()) + 5) * 64 * 128 * 2
+                         + 2 * 1068 * 2560 * 2)
     assert c["full_pages_bytes"] == pages
     assert c["paged_gqa_bytes"] == pages + c["mamba_state_bytes_moved"] + c["mamba_conv_bytes_moved"]
     assert c["paged_gqa_flops"] == flops + arith_jamba.mamba_rows(1068, 769, 26, kw)[0]
@@ -293,12 +312,12 @@ def _run(ops, runs=3, seconds=None, counters=None):
             "_program_stats": stats}
 
 
-def test_the_layers_notes_read_a_synthetic_trace():
+def test_the_cells_own_entries_read_a_synthetic_trace():
     """Three steps of 10 ms busy whose ops lie under the program's nested
     scopes: each share is the self time under its scope over the busy time;
     each kernel's roofline divides the least time of the LAST three steps'
     calls (the device line holds three of the host's four), 26 layers each,
-    by the kernel's self time."""
+    by the kernel's self time.  Through the entries' own files."""
     ops = [(("attn", "attn_mamba"), 6.0e-3),
            (("attn", "attn_mamba", "mamba_conv"), 3.0e-3),
            (("attn", "attn_mamba", "mamba_params"), 0.6e-3),
@@ -307,26 +326,28 @@ def test_the_layers_notes_read_a_synthetic_trace():
            (("mlp",), 7.5e-3),
            (("head",), 2.1e-3)]
     run = _run(ops)
-    notes = kind.layer_notes(run)
+    cell = run["cell"]
+    read = lambda name, r: cell.reader(name)[0](r, **cell.reader(name)[1])
     want = {"attn_mamba": 60.0, "mamba_conv": 10.0, "mamba_params": 2.0, "mamba_scan": 28.0,
             "attn_full": 8.0, "mlp": 25.0, "head": 7.0}
-    for scope, value in want.items():
-        assert notes[f"{scope}_share_pct"] == pytest.approx(value), scope
-    kw = run["cell"].config["model"]["kwargs"]
+    for name, scope in SCOPES.items():
+        assert read(name, run) == pytest.approx(want[scope]), scope
+    kw = cell.config["model"]["kwargs"]
     update = 26 * sum(arith_jamba.state_update_call(n, kw)[1] for n in (384, 383, 384))
-    assert notes["mamba_state_update_bytes"] == update
-    assert notes["mamba_state_update_roofline"] == pytest.approx(100 * update / 819e9 / 0.030)
-    assert notes["mamba_state_update_bound"] == "memory"
+    assert read("mamba_state_update_roofline", run) == pytest.approx(100 * update / 819e9 / 0.030)
     scan = 26 * sum(arith_jamba.chunk_scan_call(n, kw)[1] for n in (512, 300))
-    assert notes["mamba_chunk_scan_bytes"] == scan
-    assert notes["mamba_chunk_scan_roofline"] == pytest.approx(100 * scan / 819e9 / 0.012)
-    assert 0 < notes["mamba_chunk_scan_roofline"] < notes["mamba_state_update_roofline"] < 100
+    assert read("mamba_chunk_scan_roofline", run) == pytest.approx(100 * scan / 819e9 / 0.012)
+    assert run["notes"]["roofline_bound"] == {"mamba_state_update": "memory",
+                                              "mamba_chunk_scan": "memory"}
+    assert 0 < read("mamba_chunk_scan_roofline", run) < read("mamba_state_update_roofline", run) < 100
     # a program without the kernels, a kind that left no count, a run
     # without a trace: nothing to read, and nothing raised
-    gone = kind.layer_notes(_run([(("attn",), 1e-3)], seconds={}))
-    assert "mamba_state_update_roofline" not in gone and gone["mamba_scan_share_pct"] is None
-    assert kind.layer_notes(_run(ops, counters={})) == {}
-    assert kind.layer_notes({"trace": None, "counters": {}, "notes": {}}) == {}
+    gone = _run([(("attn",), 1e-3)], seconds={})
+    bare = {"trace": None, "counters": {}, "notes": {}, "cell": cell}
+    for name in KERNELS:
+        assert read(name, gone) is None and read(name, bare) is None
+        assert read(name, _run(ops, counters={})) is None
+    assert read("mamba_scan_share_pct.gen", gone) is None
 
 
 def test_the_kinds_limits_judge_a_sample():
@@ -452,7 +473,7 @@ def test_the_cell_rehearses_on_the_cpu():
     gap, limit = line["compared"]["mamba_state_gap_median"]
     assert gap < 1e-5 and limit == kind.STATE_LIMIT       # float32 against float32
     assert line["compared"]["slots_whose_state_is_wrong"] == [0, 0]
-    assert SHARED | {"serve_tokens_per_s", "setup_s"} <= set(line["would_report"])
+    assert SHARED | OWN | {"serve_tokens_per_s", "setup_s"} <= set(line["would_report"])
 
 
 def test_a_chunk_that_forgets_its_state_is_not_correct():

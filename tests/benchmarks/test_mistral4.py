@@ -16,9 +16,10 @@ from benchmarks.readers import held_experts, paged_mla
 
 CELL = "mistral-small-4-119b.serve-reasoning-batch"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
+# the entries this cell came with (PR 33), under the names PR 68 folded them into
 NEW = ("paged_mla_attention_share_pct.gen", "paged_mla_attention_roofline",
-       "mla_attn_share_pct.gen", "moe_held_share_pct.gen",
-       "moe_shared_expert_share_pct.gen", "held_bank_copy_share_pct.gen",
+       "attn_share_pct.gen", "moe_share_pct.gen",
+       "moe_shared_expert_share_pct.gen", "moe_bank_copy_share_pct.gen",
        "moe_assignments_held_pct.gen")
 PEAKS = {"bf16_flops_per_s": 1e14, "hbm_bytes_per_s": 1e12}
 
@@ -125,21 +126,26 @@ def test_both_numbers_of_the_arena_are_the_engines():
 
 # ---- the kernel's arithmetic by hand -------------------------------------------- #
 def test_latent_attention_by_hand():
-    """Three rows over two layers see 100 keys between them: each key's 320
-    cached numbers once, 2 x 32 x (320 + 256) operations a key; a row reads
-    32 queries of 320 and writes 32 outputs of 256."""
-    flops, nbytes = arith_mla.latent_attention(100, 3, 32, 256, 64)
+    """Three decode rows over two layers read 100 keys between them and
+    multiply as many pairs: each key's 320 cached numbers once, 2 x 32 x (320
+    + 256) operations a pair; a row reads 32 queries of 320 and writes 32
+    outputs of 256.  A chunk multiplies more pairs than it reads keys."""
+    flops, nbytes = arith_mla.latent_attention(100, 100, 3, 32, 256, 64)
     assert flops == 2 * 32 * (320 + 256) * 100 == 3_686_400
     assert nbytes == 100 * 640 + 3 * 32 * (320 + 256) * 2 == 174_592
+    assert arith_mla.latent_attention(100, 5000, 3, 32, 256, 64) == (50 * flops, nbytes)
 
 
-def test_the_keys_are_recovered_from_the_kinds_count():
-    """The kind counts for a K/V kernel: ``4 x heads x head_dim`` operations
-    a key.  Two live rows at positions 5 and 40 and one idle row, pages of
-    16, three layers: (1 + 3 + 1) pages x 16 keys x 3 layers."""
-    flops, _ = arith_window.stack(np.asarray([5, 40]), 1, {None: 3}, 16, 32 * 128,
-                                  32, 128)
-    assert arith_mla.keys_read(flops, 32, 128) == (1 + 3 + 1) * 16 * 3 == 240
+def test_the_kind_leaves_the_reads_and_the_pairs_in_keys():
+    """Two decode rows at positions 5 and 40, pages of 16, three layers: (1 +
+    3) pages x 16 keys x 3 layers read and multiplied, the idle row nothing;
+    a chunk of 20 from 30 reads the pages 0..3 once and multiplies each
+    query's own pages."""
+    read, pairs = arith_window.keys(np.asarray([5, 40]), [], {None: 3}, 16)
+    assert read == pairs == (1 + 3) * 16 * 3 == 192
+    read, pairs = arith_window.keys(np.zeros(0, np.int64), [(30, 20)], {None: 3}, 16)
+    assert read == 4 * 16 * 3
+    assert pairs == sum(t // 16 + 1 for t in range(30, 50)) * 16 * 3
 
 
 def _run(op_seconds, counters, config=None):
@@ -150,11 +156,11 @@ def _run(op_seconds, counters, config=None):
 
 
 def test_roofline_is_least_time_over_the_kernels_time():
-    flops, _ = arith_window.stack(np.asarray([5, 40]), 1, {None: 5}, 16, 4096, 32, 128)
-    counters = {"paged_gqa_flops": flops, "paged_gqa_bytes": 1,
+    read, pairs = arith_window.keys(np.asarray([5, 40]), [], {None: 5}, 16)
+    counters = {"attention_keys_read": read, "attention_key_products": pairs,
                 "attention_rows_live": 2, "attention_rows_idle": 1}
     run = _run({"paged_mla_attention": 2e-6}, counters)
-    keys, rows = 5 * 16 * 5, 3 * 5
+    keys, rows = 4 * 16 * 5, 2 * 5                      # the idle row reads nothing
     want_bytes = keys * 640 + rows * 32 * 576 * 2
     want_flops = 2 * 32 * 576 * keys
     least, which = arith.roofline_seconds(want_flops, want_bytes, PEAKS)
@@ -164,7 +170,8 @@ def test_roofline_is_least_time_over_the_kernels_time():
 
 
 def test_readers_give_none_where_there_is_nothing_to_read():
-    counters = {"paged_gqa_flops": 10, "attention_rows_live": 1, "attention_rows_idle": 0}
+    counters = {"attention_keys_read": 10, "attention_key_products": 10,
+                "attention_rows_live": 1, "attention_rows_idle": 0}
     assert paged_mla.roofline({"trace": None, "counters": counters, "notes": {}}) is None
     # a parent's program (no such kernel), a run without the kind's counters,
     # a model with K and V
@@ -188,14 +195,14 @@ def test_assignments_held_are_summed_over_the_steps():
 
 
 # ---- the files ------------------------------------------------------------------ #
-def test_the_new_metrics_are_listed_for_the_cell_alone():
+def test_the_new_metrics_list_the_cell():
     cell = cells.Cell(CELL)
     listed = {m["name"]: m for m in cell.per_layer}
     for name in NEW:
         fn, args = cell.reader(name)
         assert callable(fn) and isinstance(args, dict)
         m = listed[name]
-        assert m["workloads"] == [CELL] and m["unit"] == "%"
+        assert CELL in m["workloads"] and m["unit"] == "%"
         assert m["moves"] == "serve_tokens_per_s"
     assert cell.chips == 1 and cell.kind is kind
     assert [m["name"] for m in cell.end_to_end] == ["serve_tokens_per_s", "setup_s"]

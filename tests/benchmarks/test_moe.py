@@ -111,6 +111,42 @@ def test_scope_shares_by_their_metric_files(run, name):
     assert fn(run, **args) == pytest.approx(NEW[name])
 
 
+BANK_CELLS = next(m for m in BENCH["per_layer"]
+                  if m["name"] == "grouped_matmul_roofline")["workloads"]
+
+
+@pytest.mark.parametrize("cell_name", BANK_CELLS)
+def test_the_one_bank_roofline_reads_every_cell_whose_program_runs_the_kernel(run, cell_name):
+    """``grouped_matmul_roofline`` is ONE entry (PR 68 folded Trinity's copy
+    into it): its reader takes the bank from the configuration's
+    ``step_work`` function, an EXPERT layer a call at the held share of what
+    a bank of the router's width needs for the program's live rows.  By hand
+    over the synthetic trace's three programs (128, 100 and 16 live rows) and
+    the kernel's 6 us; OLMoE's whole bank in every layer reads what
+    ``readers/moe.py`` read of it to the digit."""
+    import jax.numpy as jnp
+    from benchmarks.lib import arith
+    from benchmarks.readers import afmoe
+    cell = cells.Cell(cell_name)
+    fn, args = cell.reader("grouped_matmul_roofline")
+    assert fn is afmoe.grouped_matmul_roofline and args == {}
+    bank = cells.resolve(cell.config["step_work"]["weights"])(
+        cell.config["model"]["kwargs"])["bank"]
+    least = 0.0
+    for rows in (128, 100, 16):
+        flops, nbytes = arith_moe.expert_bank_call(
+            rows, bank["experts"], bank["top_k"], bank["hidden"], bank["width"],
+            itemsize=jnp.dtype(cell.config["dtype"]).itemsize)
+        least += bank["layers"] * arith.roofline_seconds(flops, nbytes, PEAKS)[0]
+    least *= bank["held"] / bank["experts"]
+    mine = dict(run, cell=cell, notes={})
+    assert fn(mine) == pytest.approx(100 * least / (6 * US))
+    assert mine["notes"]["moe_bank_calls"] == 3 * bank["layers"]
+    if cell_name == CELL:
+        assert bank["held"] == bank["experts"] == cell.config["num_experts"]
+        assert fn(mine) == moe.grouped_matmul_roofline(dict(run, cell=cell, notes={}))
+
+
 READERS = [moe.load_max_over_mean, moe.experts_roofline, moe.grouped_matmul_roofline]
 
 
@@ -149,7 +185,7 @@ def test_every_new_metric_is_listed_for_the_cell_and_resolves():
         fn, args = cell.reader(name)
         assert callable(fn) and isinstance(args, dict)
         m = listed[name]
-        assert m["moves"] == "serve_tokens_per_s" and m["workloads"] == [CELL]
+        assert m["moves"] == "serve_tokens_per_s" and CELL in m["workloads"]
         assert m["unit"] == ("ratio" if "load" in name else "%")
     assert [m["name"] for m in cell.end_to_end] == ["serve_tokens_per_s", "setup_s"]
 

@@ -20,8 +20,8 @@ from benchmarks.readers import moe, olmo_hybrid
 CELL = "olmo-hybrid-7b.serve-chat-resident"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 SCOPES = {"attn_delta_share_pct.gen": "attn_delta", "delta_conv_share_pct.gen": "delta_conv",
-          "delta_update_share_pct.gen": "delta_update", "attn_mha_share_pct.gen": "attn_full",
-          "dense_mlp_share_pct.gen": "mlp"}
+          "delta_update_share_pct.gen": "delta_update", "attn_full_share_pct.gen": "attn_full",
+          "mlp_share_pct.gen": "mlp"}         # the last two folded from ``attn_mha``, ``dense_mlp`` (PR 68)
 NEW = tuple(SCOPES) + ("delta_state_roofline", "delta_state_update_roofline",
                        "delta_state_moves_per_step.gen")
 PEAKS = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
@@ -109,7 +109,7 @@ def test_a_steps_least_work_is_the_issues_arithmetic():
     _, state, conv = arith_olmo_hybrid.delta_rows(80, 80, 12, kw)
     assert state == 80 * 12 * 2 * STATE == pytest.approx(4.25e9, rel=1e-3)
     assert conv == 80 * 12 * 2 * 3 * 11_520 * 2
-    _, pages = arith_olmo_hybrid.full_rows(np.full(80, 679), 0, 4, 16, kw)
+    _, pages = arith_olmo_hybrid.full_rows(np.full(80, 679), [], 4, 16, kw)
     # 680 keys are 43 pages of 16; K and V of 3,840 lanes, four layers; q and o beside
     assert pages == 4 * (2 * 80 * 43 * 16 * 3840 * 2 + 2 * 80 * 3840 * 2)
     assert 54_400 * 61_440 == pytest.approx(3.34e9, rel=1e-3)
@@ -174,21 +174,21 @@ def test_the_cell_its_traffic_and_its_metrics_resolve():
         fn, args = cell.reader(name)
         assert callable(fn) and isinstance(args, dict)
         m = listed[name]
-        assert m["workloads"] == [CELL] and m["moves"] == "serve_tokens_per_s"
+        assert CELL in m["workloads"] and m["moves"] == "serve_tokens_per_s"
         assert m["unit"] == ("count" if name.startswith("delta_state_moves") else "%")
     assert cell.chips == 1 and [m["name"] for m in cell.end_to_end] == [
         "serve_tokens_per_s", "setup_s"]
-    # the fifteen every backlog serve cell reports
+    # the twelve every backlog serve cell reported when this one came (the
+    # overlay's three went with PR 68)
     assert {"compiles_in_window.gen", "serve_step_ms.gen", "decode_batch_mean.gen",
             "kv_blocks_peak_pct.gen", "preemptions.gen", "device_idle_pct.gen",
-            "sched_host_ms.gen", "table_build_ms.gen", "idle_host_work_pct.gen",
-            "idle_fetch_pct.gen", "idle_unnamed_pct.gen", "host_turnaround_ms.gen",
+            "sched_host_ms.gen", "table_build_ms.gen", "host_turnaround_ms.gen",
             "step_outside_ms.gen", "idle_wire_ms.gen", "step_mfu_pct.gen"} <= set(listed)
-    assert len(listed) == 15 + len(NEW)
-    # the metrics other tests pin to one cell alone are no part of this one
-    assert not {"paged_gqa_attention_roofline", "attn_full_share_pct.gen",
-                "attn_linear_share_pct.gen", "mlp_share_pct.gen",
-                "paged_gqa_attention_share_pct.gen"} & set(listed)
+    # ``paged_gqa_*`` is ALL the caches' work here, pages, states and
+    # convolution states: the paged kernel's roofline divides pages alone and
+    # does not list this cell; another family's layers are no part of it
+    assert not {"paged_gqa_attention_roofline", "attn_linear_share_pct.gen",
+                "attn_window_share_pct.gen"} & set(listed)
     assert cell.config["step_work"] == {
         "_about": cell.config["step_work"]["_about"],
         "weights": "benchmarks.lib.arith_olmo_hybrid:olmo_hybrid_weights",
@@ -196,57 +196,12 @@ def test_the_cell_its_traffic_and_its_metrics_resolve():
     bench = cells.load_benchmark()
     entry = next(c for c in bench["configs"] if c["name"] == "olmo-hybrid-7b")
     assert entry["reduced"] == cell.config["reduced"] and entry["source"] == cell.config["source"]
-    # WHERE in its list an entry stands is pinned by no test of this file: a
-    # later PR appends behind it (``conftest.py`` says what such a pin cost,
-    # and leaves the line behind ZAYA1's pin to this one: EVERY entry's why)
+    # WHERE in its list an entry stands is pinned by no test: a later PR
+    # appends behind it.  EVERY entry's why:
     workload = next(w for w in bench["workloads"] if w["name"] == CELL)
     assert (workload["config"], workload["traffic"]) == ("olmo-hybrid-7b", "chat-resident")
     assert all(0 < len(e["why"]) <= 200 for e in bench["configs"] + bench["workloads"])
     assert set(NEW) <= {m["name"] for m in bench["per_layer"]}
-
-
-PIN_CASES = {
-    # what failed in ``test_zaya.py``'s frame, over which lists -> excused?
-    "the_pin_over_lists_appended_to": ("THE_PIN", AssertionError, "appended", True),
-    "another_assertion_of_that_test": ("assert cell.chips == 4", AssertionError, "appended", False),
-    "the_pins_line_by_another_error": ("THE_PIN", KeyError, "appended", False),
-    "the_pin_with_zaya_moved": ("THE_PIN", AssertionError, "moved", False),
-    "the_pin_with_zaya_gone": ("THE_PIN", AssertionError, "gone", False),
-}
-
-
-@pytest.mark.parametrize("case", PIN_CASES)
-def test_only_the_pin_is_excused(case, tmp_path):
-    """``conftest.py`` turns ONE failure of ``test_zaya.py`` into an expected
-    one: an assertion raised by the last-place pin itself while ZAYA1's
-    entries stand where PR 42 put them; and the pin is still in that file
-    (where a ``benchmark`` PR takes it out, the conftest goes with it)."""
-    from tests.benchmarks import conftest
-    theirs = open(os.path.join(os.path.dirname(__file__), "test_zaya.py")).read()
-    assert theirs.count("    " + conftest.THE_PIN + "\n") == 1
-    assert conftest.PINNED.split("::")[1] in theirs
-    statement, error, lists, excused = PIN_CASES[case]
-    statement = conftest.THE_PIN if statement == "THE_PIN" else statement
-    bench = json.loads(json.dumps(cells.load_benchmark()))
-    if lists == "moved":
-        bench["workloads"].insert(0, bench["workloads"].pop())
-    elif lists == "gone":
-        bench["configs"] = [c for c in bench["configs"] if c["name"] != "zaya1-8b"]
-    else:       # as this PR leaves them: ZAYA1's no longer last
-        assert bench["workloads"][-1]["name"] != conftest.PLACES[1][2]
-    path = tmp_path / "test_zaya.py"
-    path.write_text("def body(bench, CELL, entry, cell):\n    " + statement + "\n")
-    scope = {}
-    exec(compile(path.read_text(), str(path), "exec"), scope)
-
-    class Lists(dict):      # the pin's own line, made to raise another error
-        def __getitem__(self, key):
-            raise KeyError(key)
-    entry = next((c for c in bench["configs"] if c["name"] == "zaya1-8b"), None)
-    with pytest.raises(error) as excinfo:
-        scope["body"](Lists() if error is KeyError else bench,
-                      conftest.PLACES[1][2], entry, cells.Cell(conftest.PLACES[1][2]))
-    assert conftest.only_the_pin_failed(excinfo, bench) is excused
 
 
 def test_the_traffic_is_chat_lengths_at_80_slots():
@@ -311,8 +266,11 @@ def test_the_kind_counts_pages_states_and_moves_from_the_lengths():
     assert c["delta_state_bytes_moved"] == 161 * 12 * 2 * STATE
     assert c["delta_conv_bytes_moved"] == 161 * 12 * 2 * 3 * 11_520 * 2
     assert c["attention_rows_live"] == 260 and c["attention_rows_idle"] == 2 * 256 - 260
-    positions = np.concatenate([np.arange(500 + r, 502 + r) for r in range(80)] + [np.arange(100)])
-    flops, pages = arith_olmo_hybrid.full_rows(positions, 252, 4, 16, kw)
+    decode = np.concatenate([np.arange(500 + r, 502 + r) for r in range(80)])
+    flops, pages = arith_olmo_hybrid.full_rows(decode, [(0, 100)], 4, 16, kw)
+    # the chunk's pages 0..6 once, K and V of 3,840 lanes, a full layer
+    assert pages == 4 * (2 * (int((decode // 16 + 1).sum()) + 7) * 16 * 3840 * 2
+                         + 2 * 260 * 3840 * 2)
     assert c["full_pages_bytes"] == pages
     assert c["paged_gqa_bytes"] == pages + c["delta_state_bytes_moved"] + c["delta_conv_bytes_moved"]
     assert c["paged_gqa_flops"] == flops + arith_olmo_hybrid.delta_rows(260, 161, 12, kw)[0]
@@ -354,8 +312,8 @@ def test_the_scopes_and_the_rooflines_read_a_synthetic_trace():
     run = _run(ops)
     cell = run["cell"]
     want = {"attn_delta_share_pct.gen": 40.0, "delta_conv_share_pct.gen": 5.0,
-            "delta_update_share_pct.gen": 25.0, "attn_mha_share_pct.gen": 20.0,
-            "dense_mlp_share_pct.gen": 30.0}
+            "delta_update_share_pct.gen": 25.0, "attn_full_share_pct.gen": 20.0,
+            "mlp_share_pct.gen": 30.0}
     for name, value in want.items():
         fn, args = cell.reader(name)
         assert fn(run, **args) == pytest.approx(value), name

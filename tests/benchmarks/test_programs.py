@@ -327,10 +327,10 @@ def test_metric_file_resolves_and_reads_the_synthetic_trace(tmp_path, name):
     assert (entry["layer"], entry["better"], entry["source"]) == (layer, better, "program_counter")
     assert entry["unit"] == ("ms" if quantity == "program_ms" else "%")
     if suffix == "gen":
-        assert set(entry["workloads"]) == SERVE - {CHAT} and len(entry["workloads"]) == 8
+        assert set(entry["workloads"]) == SERVE - {CHAT}        # every backlog serve cell
         assert entry["moves"] == "serve_tokens_per_s"
     else:
-        assert entry["workloads"] == [CHAT]
+        assert CHAT in entry["workloads"] and not set(entry["workloads"]) & (SERVE - {CHAT})
         assert entry["moves"] == {"tpot": "tpot_p90_ms", "ttft": "ttft_p90_ms"}[suffix]
     fn, args = cells.Cell(entry["workloads"][0]).reader(name)
     assert fn.__module__ == pg.__name__ and args == {}
@@ -339,9 +339,9 @@ def test_metric_file_resolves_and_reads_the_synthetic_trace(tmp_path, name):
     assert fn(_run(tmp_path, as_the_parent_writes_it(TEXT))) is None
 
 
-def test_the_new_entries_are_the_last_of_the_list():
-    assert [m["name"] for m in BENCH["per_layer"]][-len(NEW):] == [
-        f"{quantity}.{suffix}" for quantity, suffixes in (
-            ("program_ms", ("gen", "tpot")), ("chunk_program_time_pct", ("gen", "ttft")),
-            ("dispatched_ahead_pct", ("gen", "tpot")), ("host_occupancy_pct", ("gen", "tpot")))
-        for suffix in suffixes]
+def test_each_entry_is_listed_once_under_its_own_name():
+    """WHERE an entry stands in the list is nobody's to hold (a later PR
+    appends behind it, a ``benchmark`` PR folds what stands before it): each
+    of the eight is there, once."""
+    names = [m["name"] for m in BENCH["per_layer"]]
+    assert [names.count(name) for name in NEW] == [1] * len(NEW)

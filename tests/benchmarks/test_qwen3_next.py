@@ -16,20 +16,28 @@ import pytest
 from benchmarks.kinds import serve_backlog_resident as resident
 from benchmarks.kinds import serve_backlog_resident_delta_moe as kind
 from benchmarks.lib import arith_moe, arith_olmo_hybrid, arith_qwen3_next, arith_step, cells
-from benchmarks.readers import moe, olmo_hybrid, paged_gqa, qwen3_next, zaya
+from benchmarks.readers import (afmoe, held_experts, moe, olmo_hybrid, paged_gqa, qwen3_next,
+                                zaya)
 
 CELL = "qwen3-next-80b-a3b.serve-long-delta-moe"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 TYPES = 3 * ["linear_attention"] + ["full_attention"]
 # the accepted share metrics the cell joins, and the scope each reads
 SCOPES = {"attn_delta_share_pct.gen": "attn_delta", "delta_conv_share_pct.gen": "delta_conv",
-          "delta_update_share_pct.gen": "delta_update", "softmax_bank_share_pct.gen": "moe",
-          "softmax_router_share_pct.gen": "moe_router",
-          "softmax_bank_experts_share_pct.gen": "moe_experts", "lm_head_share_pct.gen": "head"}
+          "delta_update_share_pct.gen": "delta_update", "moe_share_pct.gen": "moe",
+          "moe_router_share_pct.gen": "moe_router",
+          "moe_experts_share_pct.gen": "moe_experts", "lm_head_share_pct.gen": "head",
+          # what PR 64 left in ``notes.qwen3_next_layers``, listed since PR 68
+          "attn_full_share_pct.gen": "attn_full", "attn_gate_share_pct.gen": "attn_gate",
+          "moe_shared_expert_share_pct.gen": "moe_shared"}
 OTHERS = {"delta_state_roofline": olmo_hybrid.delta_state_roofline,
           "delta_state_update_roofline": olmo_hybrid.delta_state_update_roofline,
           "delta_state_moves_per_step.gen": olmo_hybrid.state_moves_per_step,
-          "softmax_bank_reached_pct.gen": zaya.experts_reached_pct}
+          "experts_reached_pct.gen": zaya.experts_reached_pct,
+          "moe_assignments_held_pct.gen": held_experts.assignments_held_pct,
+          "grouped_matmul_roofline": afmoe.grouped_matmul_roofline,
+          "paged_gqa_attention_roofline": paged_gqa.roofline,
+          "moe_load_max_over_mean.gen": moe.load_max_over_mean}
 STATE = 32 * 128 * 128 * 4
 HELD = 3_677_613_120
 
@@ -203,11 +211,10 @@ def test_the_cell_its_traffic_and_its_metrics_resolve():
     assert cell.chips == 1 and [m["name"] for m in cell.end_to_end] == [
         "serve_tokens_per_s", "setup_s"]
     listed = {m["name"]: m for m in cell.per_layer}
-    # the nineteen every backlog serve cell reports
+    # the sixteen every backlog serve cell reports
     assert {"compiles_in_window.gen", "serve_step_ms.gen", "decode_batch_mean.gen",
             "kv_blocks_peak_pct.gen", "preemptions.gen", "device_idle_pct.gen",
-            "sched_host_ms.gen", "table_build_ms.gen", "idle_host_work_pct.gen",
-            "idle_fetch_pct.gen", "idle_unnamed_pct.gen", "host_turnaround_ms.gen",
+            "sched_host_ms.gen", "table_build_ms.gen", "host_turnaround_ms.gen",
             "step_outside_ms.gen", "idle_wire_ms.gen", "step_mfu_pct.gen", "program_ms.gen",
             "chunk_program_time_pct.gen", "dispatched_ahead_pct.gen",
             "host_occupancy_pct.gen"} <= set(listed)
@@ -217,7 +224,6 @@ def test_the_cell_its_traffic_and_its_metrics_resolve():
         fn, args = cell.reader(name)
         assert callable(fn) and isinstance(args, dict), name
         assert m["moves"] == "serve_tokens_per_s" and CELL in m["workloads"], name
-    # this PR adds no entry: the list holds what it may (ROADMAP S0 (w))
     assert len(bench["per_layer"]) <= 128
     assert cell.config["step_work"] == {
         "_about": cell.config["step_work"]["_about"],
@@ -264,12 +270,9 @@ def test_the_scopes_the_metrics_name_are_the_programs():
         assert f'jax.named_scope("{scope}")' in source
     for name, reader in OTHERS.items():
         assert cell.reader(name) == (reader, {})
-    # what reads the cell right and a pin elsewhere keeps off its list is an
-    # accepted entry, left in the traced line's notes
-    accepted = {m["name"] for m in cells.load_benchmark()["per_layer"]}
-    assert set(kind.PINNED_ELSEWHERE) <= accepted - listed
-    # every scope the kind's notes read is one the program opens
-    for scope in kind.SCOPES:
+    assert cell.reader("moe_dispatch_share_pct.gen")[1] == {
+        "scopes": ["moe_dispatch", "moe_combine"]}
+    for scope in ("moe_dispatch", "moe_combine"):
         assert f'jax.named_scope("{scope}")' in source, scope
     assert '"moe_assignments_held"' in inspect.getsource(
         __import__("deepspeed_tpu.serving.engine", fromlist=["x"]))

@@ -99,15 +99,16 @@ def test_attention_bytes_of_smallthinkers_row_by_hand():
     _, b_win = arith_window.rows([7900], 16, 512, 28, 128, window=4096)
     # keys 3,805..7,900: pages 237..493
     assert b_win == 2 * 257 * page + qo
-    # the 8-layer cut: 2 full and 6 window layers, and one idle row a page a layer
-    flops, nbytes = arith_window.stack([7900], 1, {None: 2, 4096: 6}, 16, 512, 28, 128)
-    assert nbytes == 2 * b_full + 6 * b_win + 8 * (2 * page + qo)
+    # the 8-layer cut: 2 full and 6 window layers; an idle row costs nothing
+    flops, nbytes = arith_window.attention(np.asarray([7900]), [], {None: 2, 4096: 6},
+                                           16, 512, 28, 128)
+    assert nbytes == 2 * b_full + 6 * b_win
     # about the 83 MB the cell's arithmetic gives a row (2 x 7,900 + 6 x 4,112 keys)
     assert 2 * b_full + 6 * b_win == pytest.approx(83e6, rel=0.02)
     assert flops > 0
 
 
-def test_attention_counters_take_every_row_at_its_own_position():
+def test_attention_counters_take_a_decode_row_at_its_position_and_a_chunk_once():
     """Between two snapshots: a request 30 tokens into a prompt of 100 runs
     24 more (a chunk of 24), another decodes 3 steps from 50 resident; 2
     programs of 4 + 24 rows."""
@@ -123,13 +124,24 @@ def test_attention_counters_take_every_row_at_its_own_position():
     c = kind.attention_counters(srv, snaps, steps)
     rows = list(range(30, 54)) + [50, 51, 52]
     assert c["attention_rows_live"] == 27 and c["attention_rows_idle"] == 2 * 28 - 27
-    want = arith_window.stack(rows, 29, {None: 2, 16: 2}, 4, 16, 4, 8, itemsize=2)
+    assert (c["attention_chunks"], c["chunk_queries_per_row"]) == (1, 0)
+    want = arith_window.attention(np.asarray([50, 51, 52]), [(30, 24)], {None: 2, 16: 2},
+                                  4, 16, 4, 8, itemsize=2)
     assert (c["paged_gqa_flops"], c["paged_gqa_bytes"]) == want
-    # by hand for the full layers: a row at t reads t // 4 + 1 pages of 4 x 16 x 2 B
+    # by hand.  OPERATIONS: every query over the pages it sees, a row at t
+    # t // 4 + 1 pages of 4 keys in a full layer
     full_pages = sum(t // 4 + 1 for t in rows)
     win_pages = sum(t // 4 + 1 - max(t - 15, 0) // 4 for t in rows)
-    assert want[1] == (2 * (full_pages + win_pages) * 2 * 4 * 16 * 2
-                       + 4 * 29 * 2 * 4 * 16 * 2 + 4 * (27 + 29) * 2 * 4 * 8 * 2)
+    assert want[0] == 2 * (full_pages + win_pages) * 4 * 4 * 4 * 8
+    # BYTES: the decode rows' pages, and the chunk's span ONCE: pages 0..13 in
+    # a full layer, 3..13 under the window (the first query, at 30, sees from
+    # key 15); the idle rows nothing; q and o of the 27 live rows a layer
+    read_full = sum(t // 4 + 1 for t in (50, 51, 52)) + 14
+    read_win = sum(t // 4 + 1 - max(t - 15, 0) // 4 for t in (50, 51, 52)) + 11
+    assert want[1] == (2 * (read_full + read_win) * 2 * 4 * 16 * 2
+                       + 4 * 27 * 2 * 4 * 8 * 2)
+    assert c["attention_keys_read"] == 2 * (read_full + read_win) * 4
+    assert c["attention_key_products"] == 2 * (full_pages + win_pages) * 4
 
 
 # ---- the resident first cohort --------------------------------------------------- #
@@ -218,7 +230,7 @@ def test_an_op_family_is_summed_over_the_compilers_suffixes():
     assert op_family.share_pct(run, ["paged_attention"]) == 0.0       # a program without the op
 
 
-def test_the_new_metrics_are_listed_for_the_cell_alone():
+def test_the_new_metrics_list_the_cell():
     cell = cells.Cell(CELL)
     listed = {m["name"]: m for m in cell.per_layer}
     for name in ("attn_full_share_pct.gen", "attn_window_share_pct.gen",
@@ -227,7 +239,7 @@ def test_the_new_metrics_are_listed_for_the_cell_alone():
                  "grouped_matmul_share_pct.gen"):
         fn, args = cell.reader(name)
         assert callable(fn) and isinstance(args, dict)
-        assert listed[name]["workloads"] == [CELL] and listed[name]["unit"] == "%"
+        assert CELL in listed[name]["workloads"] and listed[name]["unit"] == "%"
     assert cell.chips == 1 and cell.kind is kind
     assert [m["name"] for m in cell.end_to_end] == ["serve_tokens_per_s", "setup_s"]
 

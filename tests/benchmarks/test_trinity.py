@@ -26,12 +26,13 @@ CELL = "trinity-large-preview.serve-mixed-lengths"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 REDUCED = ["layer_types", "num_dense_layers", "num_experts", "num_hidden_layers",
            "vocab_size"]
-NEW = ("afmoe_attn_window_share_pct.gen", "afmoe_attn_full_share_pct.gen",
-       "afmoe_attn_gate_share_pct.gen", "afmoe_lead_mlp_share_pct.gen",
-       "afmoe_bank_share_pct.gen", "afmoe_shared_expert_share_pct.gen",
-       "afmoe_head_share_pct.gen", "afmoe_assignments_held_pct.gen",
-       "afmoe_experts_reached_pct.gen", "afmoe_kv_window_freed_pct.gen",
-       "afmoe_paged_gqa_roofline", "afmoe_grouped_matmul_roofline")
+# the entries this cell came with (PR 55), under the names PR 68 folded them into
+NEW = ("attn_window_share_pct.gen", "attn_full_share_pct.gen",
+       "attn_gate_share_pct.gen", "lead_mlp_share_pct.gen",
+       "moe_experts_share_pct.gen", "moe_shared_expert_share_pct.gen",
+       "lm_head_share_pct.gen", "moe_assignments_held_pct.gen",
+       "experts_reached_pct.gen", "kv_window_freed_pct.gen",
+       "paged_gqa_attention_roofline", "grouped_matmul_roofline")
 COMMON = ("compiles_in_window.gen", "serve_step_ms.gen", "decode_batch_mean.gen",
           "kv_blocks_peak_pct.gen", "preemptions.gen", "device_idle_pct.gen",
           "step_mfu_pct.gen", "program_ms.gen", "dispatched_ahead_pct.gen")
@@ -246,10 +247,10 @@ def test_the_cell_its_traffic_and_its_metrics_resolve():
     assert set(NEW) <= set(listed) and set(COMMON) <= set(listed)     # at least these
     for name in NEW:
         m = listed[name]
-        assert m["workloads"] == [CELL] and m["moves"] == "serve_tokens_per_s"
+        assert CELL in m["workloads"] and m["moves"] == "serve_tokens_per_s"
         fn, args = cell.reader(name)
         assert callable(fn) and isinstance(args, dict)
-    for name in ("afmoe_paged_gqa_roofline", "afmoe_grouped_matmul_roofline"):
+    for name in ("paged_gqa_attention_roofline", "grouped_matmul_roofline"):
         assert listed[name]["unit"] == "%" and listed[name]["source"] == "device_trace"
     # the limits of the comparison are this kind's own, found by routed4's method
     assert kind.LOGIT_MARGIN > kind.NOISE_LIMIT > 0
@@ -281,20 +282,20 @@ def test_a_chunks_pages_are_needed_once_a_chunk_by_hand():
     a window layer: from the page of key 8,192 - 4,095 to page 543.  The
     operations are the rows': each query over the pages it sees."""
     from benchmarks.lib import arith_window
-    f, b = arith_trinity.chunk_rows(8192, 512, 16, 1024, 48, 128)
+    f, b = arith_window.chunk_rows(8192, 512, 16, 1024, 48, 128)
     assert b == (2 * 544 * 16 * 1024 + 2 * 512 * 48 * 128) * 2
     rf, rb = arith_window.rows(8192 + np.arange(512), 16, 1024, 48, 128)
     assert f == rf and rb > 250 * b
-    fw, bw = arith_trinity.chunk_rows(8192, 512, 16, 1024, 48, 128, window=4096)
+    fw, bw = arith_window.chunk_rows(8192, 512, 16, 1024, 48, 128, window=4096)
     assert bw == (2 * (544 - 4097 // 16) * 16 * 1024 + 2 * 512 * 48 * 128) * 2
     assert fw == arith_window.rows(8192 + np.arange(512), 16, 1024, 48, 128, 4096)[0]
     # the stack: six window layers and two full; decode rows as the rows'
     decode = np.asarray([1500, 37000])
-    flops, nbytes = arith_trinity.attention(decode, [(8192, 512)], {4096: 6, None: 2},
+    flops, nbytes = arith_window.attention(decode, [(8192, 512)], {4096: 6, None: 2},
                                             16, 1024, 48, 128)
     d_full, d_win = (arith_window.rows(decode, 16, 1024, 48, 128, w) for w in (None, 4096))
     assert nbytes == 6 * (d_win[1] + bw) + 2 * (d_full[1] + b)
     assert flops == 6 * (d_win[0] + fw) + 2 * (d_full[0] + f)
     # a short last chunk and no chunk at all
-    assert arith_trinity.chunk_rows(0, 7, 16, 1024, 48, 128)[1] == (2 * 16 * 1024 + 2 * 7 * 6144) * 2
-    assert arith_trinity.attention(np.zeros(0, np.int64), [], {None: 2}, 16, 1024, 48, 128) == (0, 0)
+    assert arith_window.chunk_rows(0, 7, 16, 1024, 48, 128)[1] == (2 * 16 * 1024 + 2 * 7 * 6144) * 2
+    assert arith_window.attention(np.zeros(0, np.int64), [], {None: 2}, 16, 1024, 48, 128) == (0, 0)

@@ -28,12 +28,10 @@ import pytest
 
 from benchmarks.lib import cells
 from benchmarks.lib.trace import Trace
-from benchmarks.readers import program_spans as ps
 from benchmarks.readers import turnaround as ta
 from benchmarks.readers.device_trace import device_idle_pct
 
 ROOT = cells.ROOT
-FETCH = ["serve.prefill.fetch", "serve.decode.fetch"]
 RUNS = [(10.0, 20.0), (24.0, 34.0), (39.0, 49.0), (52.0, 62.0)]
 WAY_OUT, WAY_BACK = 0.5, 1.0
 # (commit_ms, outside_ms, prepare_ms) of the turn-round BEFORE each step's program
@@ -152,27 +150,28 @@ def test_a_step_that_followed_no_program_is_not_read(tmp_path):
 
 
 # ---- the profiler's offset between the two lines ---------------------------------- #
-@pytest.mark.parametrize("shift,skew,inside,fetch,host_work,unnamed", [
-    (0.0, 1.0, True, 4.5, 5.8, 1.7),
-    (+1.5, 2.5, False, 7.5, 2.9, 1.6),
-    (-1.5, -0.5, False, 6.0, 4.3, 1.7),
+@pytest.mark.parametrize("shift,skew,inside", [
+    (0.0, 1.0, True),
+    (+1.5, 2.5, False),
+    (-1.5, -0.5, False),
 ])
-def test_an_offset_between_the_lines_moves_the_overlay_and_not_the_durations(
-        tmp_path, shift, skew, inside, fetch, host_work, unnamed):
-    """The table of ISSUE 36's motivation, reproduced: one program, one host
-    path, and the split of the chip's idle time between "the fetch" and "host
-    work" follows where the profiler happened to lay the host's line.  The
-    three new metrics are the same to the microsecond, and the note says by
-    how much the lines were off (causality holds it to [0, wire])."""
+def test_an_offset_between_the_lines_moves_the_skew_and_not_the_durations(
+        tmp_path, shift, skew, inside):
+    """One program, one host path, and the profiler's host line laid at
+    three offsets: the three metrics are the same to the microsecond, and the
+    note says by how much the lines were off (causality holds it to [0,
+    wire]).  The overlay of the two lines, which split the chip's idle time
+    between "the fetch" and "host work" by where the host's line happened to
+    lie (4.5 / 5.8, 7.5 / 2.9 and 6.0 / 4.3 ms of these three runs: ISSUE
+    36's table), went with PR 68; what it summed to stays, and no shift moves
+    it."""
     run, plain = _run(tmp_path, shift=shift), _run(tmp_path)
     assert _three(run) == pytest.approx(_three(plain), abs=1e-3)      # ms: to 1 us
     assert _three(run) == pytest.approx((2.5, 1.7 / 3, 1.5), abs=1e-3)
     assert run["notes"]["host_device_skew_ms"] == pytest.approx(skew)
     assert (0.0 <= run["notes"]["host_device_skew_ms"] <= ta.wire_ms(run)) == inside
-    overlay = [ps.idle_under_pct(run, FETCH), ps.idle_under_pct(run, FETCH, invert=True),
-               ps.idle_under_pct(run, [])]
-    assert overlay == pytest.approx([100 * x / 52 for x in (fetch, host_work, unnamed)])
-    assert sum(overlay) == pytest.approx(device_idle_pct(run))
+    assert device_idle_pct(run) == pytest.approx(device_idle_pct(plain))
+    assert device_idle_pct(run) == pytest.approx(100 * 12.0 / 52)
     # the true split, which no shift moves: the host's 7.5 ms, the wire's 4.5
     assert 3 * ta.stat_mean_ms(run, "turnaround_ms") == pytest.approx(7.5)
     assert 3 * ta.wire_ms(run) == pytest.approx(4.5)
@@ -227,7 +226,7 @@ def test_metric_file_resolves_and_reads_the_synthetic_trace(tmp_path, name):
     assert entry["workloads"] and set(entry["workloads"]) <= SERVE
     assert (entry["unit"], entry["better"], entry["source"]) == ("ms", "lower", "program_span")
     if name.endswith(".tpot"):
-        assert entry["workloads"] == ["gpt2-124m.serve-chat-steady"]
+        assert "gpt2-124m.serve-chat-steady" in entry["workloads"]
     else:
         assert set(entry["workloads"]) == SERVE - {"gpt2-124m.serve-chat-steady"}
     fn, args = cells.Cell(entry["workloads"][0]).reader(name)

@@ -17,6 +17,7 @@ import pytest
 from benchmarks.kinds import serve_backlog_resident as resident
 from benchmarks.kinds import serve_backlog_resident_hyper as kind
 from benchmarks.lib import arith_step, arith_xing4, cells
+from benchmarks.readers import paged_mla, xing4
 
 CELL = "xing4.0-29b-a4b.serve-prompt-heavy"
 CONFIG = "xing4.0-29b-a4b"
@@ -25,10 +26,19 @@ HELD = 5_537_658_874
 EVERY_BACKLOG_CELLS = {
     "compiles_in_window.gen", "serve_step_ms.gen", "decode_batch_mean.gen",
     "kv_blocks_peak_pct.gen", "preemptions.gen", "device_idle_pct.gen", "sched_host_ms.gen",
-    "table_build_ms.gen", "idle_host_work_pct.gen", "idle_fetch_pct.gen",
-    "idle_unnamed_pct.gen", "host_turnaround_ms.gen", "step_outside_ms.gen",
+    "table_build_ms.gen", "host_turnaround_ms.gen", "step_outside_ms.gen",
     "idle_wire_ms.gen", "step_mfu_pct.gen", "program_ms.gen", "chunk_program_time_pct.gen",
     "dispatched_ahead_pct.gen", "host_occupancy_pct.gen"}
+# the cell's own, listed since PR 68 (PR 66 left them in ``notes.xing4_layers``):
+# entry -> the scope it reads
+SCOPES = {"hc_coeff_share_pct.gen": "hc_coeff", "hc_pre_share_pct.gen": "hc_pre",
+          "hc_post_share_pct.gen": "hc_post", "attn_share_pct.gen": "attn",
+          "attn_latent_share_pct.gen": "attn_latent", "mlp_share_pct.gen": "mlp",
+          "lead_mlp_share_pct.gen": "lead_mlp", "moe_share_pct.gen": "moe",
+          "moe_router_share_pct.gen": "moe_router", "moe_experts_share_pct.gen": "moe_experts",
+          "moe_shared_expert_share_pct.gen": "moe_shared", "lm_head_share_pct.gen": "head"}
+OWN = set(SCOPES) | {"paged_mla_attention_roofline", "grouped_matmul_roofline",
+                     "hc_mix_bytes_pct.gen"}
 
 
 def test_the_configuration_is_the_catalogs_but_for_its_depth():
@@ -157,7 +167,7 @@ def test_the_cell_its_traffic_and_its_metrics_resolve():
     assert cell.chips == 1 and {m["name"] for m in cell.end_to_end} == {
         "serve_tokens_per_s", "setup_s"}
     listed = {m["name"]: m for m in cell.per_layer}
-    assert EVERY_BACKLOG_CELLS | {"lm_head_share_pct.gen"} <= set(listed)
+    assert EVERY_BACKLOG_CELLS | OWN <= set(listed)
     # every metric listed for the cell resolves to a reader, and moves the
     # end-to-end metric the cell reports
     for name, m in listed.items():
@@ -202,16 +212,20 @@ def test_the_traffic_is_prompts_that_set_the_step():
     assert runs.min() > 17 and runs.max() < 24
 
 
-def test_the_scopes_the_notes_name_are_the_programs():
+def test_the_scopes_the_metrics_name_are_the_programs():
     import inspect
     from deepspeed_tpu.models import gpt
     from deepspeed_tpu.moe import dropless
+    cell = cells.Cell(CELL)
     source = inspect.getsource(gpt) + inspect.getsource(dropless)
-    for scope in kind.SCOPES:
+    for name, scope in SCOPES.items():
+        assert cell.reader(name)[1] == {"scopes": [scope]}
         assert f'named_scope("{scope}")' in source, scope
-    assert set(kind.MIX_SCOPES) <= set(kind.SCOPES)
+    assert set(xing4.MIX_SCOPES) <= set(SCOPES.values())
     from deepspeed_tpu.ops.pallas import decode_attention as da
-    assert f'"{kind.KERNEL}"' in inspect.getsource(da)
+    assert cell.reader("paged_mla_attention_roofline")[0] is paged_mla.roofline
+    assert f'"{paged_mla.KERNEL}"' in inspect.getsource(da)
+    assert cell.reader("hc_mix_bytes_pct.gen")[0] is xing4.mix_bytes_pct
 
 
 # ---- the kind's counters and notes ---------------------------------------------------- #
@@ -244,8 +258,10 @@ def test_the_kind_counts_a_chunks_keys_once():
     assert (c["attention_rows_live"], c["attention_chunks"]) == (rows, 2)
     assert c["attention_rows_idle"] == 3 * 528 - rows
     assert c["traced_step_rows"] == [513, 513, 1]
-    # the resident kind's own count reads a chunk's keys once a TOKEN
-    assert sum(range(1025, 2049)) > 300 * (1536 + 2048)
+    # the same count in keys, for the latent kernel's roofline; a chunk
+    # multiplies hundreds of times the keys it reads
+    assert (c["attention_keys_read"], c["attention_key_products"]) == (7 * read, 7 * pairs)
+    assert sum(range(1025, 2049)) > 300 * (1536 + 2048) / 2
 
 
 def test_the_mixes_bytes_are_one_read_and_one_write_of_the_streams():
@@ -270,22 +286,30 @@ class _Trace:
         return self._runs
 
 
-def test_the_notes_readers_read_synthetic_counts(monkeypatch):
+def test_the_cells_own_readers_read_synthetic_counts(monkeypatch):
+    """The latent kernel's roofline over the kind's own counters of one
+    chunk (``readers/paged_mla.py``: the listed entry, which PR 66 could only
+    leave in the notes), and the mixes' bytes against the ``hc_*`` scopes."""
     cell = cells.Cell(CELL)
     peaks = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
-    run = {"trace": _Trace({kind.KERNEL: 0.5}), "peaks": peaks, "cell": cell, "notes": {},
-           "counters": {"paged_gqa_flops": 197e12 * 0.2, "paged_gqa_bytes": 819e9 * 0.1,
-                        "traced_step_rows": [528, 0, 528, 528]}}
-    assert kind.kernel_roofline(run) == pytest.approx(40.0)
-    assert run["notes"]["roofline_bound"][kind.KERNEL] == "compute"
-    assert kind.kernel_roofline(dict(run, trace=_Trace({}))) is None
+    snaps = {"before": {2: (6000, 1024, 0)}, "after": {2: (6000, 1536, 0)}}
+    counters = kind.attention_counters(_Srv(), snaps, [(0.0, 0.1, 0, 512, 0, 0, 0)])
+    run = {"trace": _Trace({paged_mla.KERNEL: 0.5}), "peaks": peaks, "cell": cell,
+           "notes": {}, "counters": dict(counters, traced_step_rows=[528, 0, 528, 528])}
+    # what ``step_mfu_pct`` takes and what the kernel's roofline takes are
+    # ONE count: the reads and the pairs at ``arith_mla``'s cost of each
+    assert paged_mla.work(run) == (counters["paged_gqa_flops"], counters["paged_gqa_bytes"])
+    least = max(counters["paged_gqa_flops"] / 197e12, counters["paged_gqa_bytes"] / 819e9)
+    assert paged_mla.roofline(run) == pytest.approx(100 * least / 0.5)
+    assert run["notes"]["roofline_bound"][paged_mla.KERNEL] == "compute"
+    assert paged_mla.roofline(dict(run, trace=_Trace({}))) is None
     # the mixes: 5% of a busy second for the last two steps' bytes
-    monkeypatch.setattr(kind, "scope_share_pct", lambda run, scopes: 5.0)
+    monkeypatch.setattr(xing4, "scope_share_pct", lambda run, scopes: 5.0)
     least = arith_xing4.mix_bytes(2 * 528, 2, 7, 4, 3584, 2) / 819e9
-    assert kind.mix_bytes_pct(run) == pytest.approx(100 * least / 0.05)
-    monkeypatch.setattr(kind, "scope_share_pct", lambda run, scopes: None)
-    assert kind.mix_bytes_pct(run) is None          # a program without the scopes
-    assert kind.layer_notes(dict(run, trace=None)) == {}
+    assert xing4.mix_bytes_pct(run) == pytest.approx(100 * least / 0.05)
+    monkeypatch.setattr(xing4, "scope_share_pct", lambda run, scopes: None)
+    assert xing4.mix_bytes_pct(run) is None          # a program without the scopes
+    assert xing4.mix_bytes_pct(dict(run, trace=None)) is None
 
 
 # ---- the limits and the controls ------------------------------------------------------- #

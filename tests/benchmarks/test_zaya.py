@@ -19,8 +19,8 @@ from benchmarks.readers import moe, zaya
 CELL = "zaya1-8b.serve-reasoning-resident"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 SCOPES = {"attn_cca_share_pct.gen": "attn_cca", "cca_mix_share_pct.gen": "cca_mix",
-          "cca_attend_share_pct.gen": "cca_attend", "router_mlp_share_pct.gen": "moe_router",
-          "expert_bank_share_pct.gen": "moe_experts"}
+          "cca_attend_share_pct.gen": "cca_attend", "moe_router_share_pct.gen": "moe_router",
+          "moe_experts_share_pct.gen": "moe_experts"}
 NEW = tuple(SCOPES) + ("experts_reached_pct.gen",)
 
 
@@ -141,20 +141,21 @@ def test_the_cell_its_traffic_and_its_metrics_resolve():
         fn, args = cell.reader(name)
         assert callable(fn) and isinstance(args, dict)
         m = listed[name]
-        assert m["workloads"] == [CELL] and m["unit"] == "%"
+        assert CELL in m["workloads"] and m["unit"] == "%"
         assert m["moves"] == "serve_tokens_per_s"
     assert cell.chips == 1 and [m["name"] for m in cell.end_to_end] == [
         "serve_tokens_per_s", "setup_s"]
-    # the fifteen every backlog serve cell reports
+    # the twelve every backlog serve cell reported when this one came (the
+    # overlay's three went with PR 68)
     assert {"compiles_in_window.gen", "serve_step_ms.gen", "decode_batch_mean.gen",
             "kv_blocks_peak_pct.gen", "preemptions.gen", "device_idle_pct.gen",
-            "sched_host_ms.gen", "table_build_ms.gen", "idle_host_work_pct.gen",
-            "idle_fetch_pct.gen", "idle_unnamed_pct.gen", "host_turnaround_ms.gen",
+            "sched_host_ms.gen", "table_build_ms.gen", "host_turnaround_ms.gen",
             "step_outside_ms.gen", "idle_wire_ms.gen", "step_mfu_pct.gen"} <= set(listed)
-    # the metrics other tests pin to one cell alone are no part of this one
-    assert not {"grouped_matmul_roofline", "paged_gqa_attention_roofline",
-                "moe_experts_roofline", "moe_load_max_over_mean.gen",
-                "moe_router_share_pct.gen", "moe_experts_share_pct.gen"} & set(listed)
+    # the two kernels its program runs have ONE roofline each, which lists it
+    # (PR 68); what reads another bank's scopes or stats is no part of it
+    assert {"grouped_matmul_roofline", "paged_gqa_attention_roofline"} <= set(listed)
+    assert not {"moe_experts_roofline", "moe_load_max_over_mean.gen",
+                "moe_dispatch_share_pct.gen"} & set(listed)
     assert cell.config["step_work"] == {
         "_about": cell.config["step_work"]["_about"],
         "weights": "benchmarks.lib.arith_zaya:zaya_weights",
@@ -162,8 +163,9 @@ def test_the_cell_its_traffic_and_its_metrics_resolve():
     bench = cells.load_benchmark()
     entry = next(c for c in bench["configs"] if c["name"] == "zaya1-8b")
     assert entry["reduced"] == cell.config["reduced"] and entry["source"] == cell.config["source"]
-    assert bench["workloads"][-1]["name"] == CELL and bench["configs"][-1] is entry
-    assert all(len(e["why"]) <= 200 for e in (entry, bench["workloads"][-1]))
+    workload = next(w for w in bench["workloads"] if w["name"] == CELL)
+    assert workload["config"] == entry["name"] == "zaya1-8b"
+    assert all(len(e["why"]) <= 200 for e in (entry, workload))
 
 
 def test_the_traffic_is_reasoning_batchs_lengths_at_48_slots():
@@ -215,8 +217,8 @@ def test_the_five_scopes_read_a_synthetic_trace():
     run = {"trace": object(), "notes": {}, "counters": {}, "_program_stats": stats(ops)}
     cell = cells.Cell(CELL)
     want = {"attn_cca_share_pct.gen": 45.0, "cca_mix_share_pct.gen": 10.0,
-            "cca_attend_share_pct.gen": 30.0, "router_mlp_share_pct.gen": 5.0,
-            "expert_bank_share_pct.gen": 45.0}
+            "cca_attend_share_pct.gen": 30.0, "moe_router_share_pct.gen": 5.0,
+            "moe_experts_share_pct.gen": 45.0}
     for name, value in want.items():
         fn, args = cell.reader(name)
         assert fn(run, **args) == pytest.approx(value), name
